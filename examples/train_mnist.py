@@ -2,6 +2,9 @@
 
     python examples/train_mnist.py [--steps N]
 
+Runs on the accelerator JAX finds; JAX_PLATFORMS=cpu runs it on the CPU
+on purpose (TPUPlace refuses a CPU nobody asked for).
+
 Covers the core loop a reference (Fluid) user knows: build a Program
 with layers, minimize, run startup, feed batches, save/load an
 inference model. The whole train step (forward+backward+Adam) compiles
@@ -13,8 +16,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
-
-# PADDLE_TPU_PLATFORM=cpu forces the CPU backend (honored by paddle_tpu at import)
 
 import numpy as np
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--batch", type=int, default=64)
     ap.add_argument("--outdir", default="/tmp/mnist_model")
     args = ap.parse_args()
+    fluid.flags.enable_compile_cache()
 
     main_prog, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main_prog, startup):

@@ -1,13 +1,15 @@
 """Multi-chip SPMD training: dp x tp mesh with ZeRO-1 sharded moments.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    PADDLE_TPU_PLATFORM=cpu python examples/train_multichip.py
+    JAX_PLATFORMS=cpu python examples/train_multichip.py
 
 On real hardware drop the env overrides — the same script runs over
-the chips jax reports. The engine compiles ONE SPMD executable: feeds
-batch-shard over 'data', the fc weights column/row-shard over 'model'
-(megatron-style), every Adam moment shards 1/N over 'data' (ZeRO-1),
-and XLA inserts the all-reduces/gathers. For pipeline stages, MoE
+the chips jax reports (`chip_smoke.py --chips 4` runs this engine at
+BERT-base width on a four-chip host). The engine compiles ONE SPMD
+executable: feeds batch-shard over 'data', the fc weights
+column/row-shard over 'model' (megatron-style), every Adam moment
+shards 1/N over 'data' (ZeRO-1), and XLA inserts the
+all-reduces/gathers. For pipeline stages, MoE
 experts, or ring-attention sequence parallelism see
 docs/PARALLELISM.md — they ride the same engine.
 """
@@ -17,8 +19,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
-
-# PADDLE_TPU_PLATFORM=cpu forces the CPU backend (honored by paddle_tpu at import)
 
 import numpy as np
 
@@ -34,6 +34,7 @@ def main():
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=64)
     args = ap.parse_args()
+    fluid.flags.enable_compile_cache()
 
     import jax
 

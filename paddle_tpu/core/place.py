@@ -34,7 +34,20 @@ class Place:
             except RuntimeError:
                 return None  # cpu not a visible backend; let jax default
         devs = jax.devices()
+        if devs[0].platform == "cpu" and not _cpu_requested():
+            raise RuntimeError(
+                "%r: JAX found no accelerator (default backend is the "
+                "CPU). Set JAX_PLATFORMS=cpu to run on the CPU on "
+                "purpose, or use CPUPlace()." % (self,))
         return devs[self.device_id % len(devs)]
+
+
+def _cpu_requested() -> bool:
+    """True when the process chose the CPU backend itself
+    (``JAX_PLATFORMS=cpu`` / ``jax_platforms``), as the tests do."""
+    import jax
+
+    return (jax.config.jax_platforms or "").split(",")[0].strip() == "cpu"
 
 
 class CPUPlace(Place):
@@ -42,8 +55,10 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The accelerator place. On this build the accelerator is always the
-    default JAX backend (TPU on hardware, CPU in tests)."""
+    """The accelerator place: the default JAX backend. Resolving it on a
+    machine whose default backend is the CPU raises, unless the process
+    asked for the CPU explicitly (``JAX_PLATFORMS=cpu``, as the tests
+    do) — a missing chip must never pass for a slow one."""
 
 
 # The reference's CUDAPlace maps to the accelerator slot here; kept as an
@@ -60,7 +75,4 @@ class CUDAPinnedPlace(Place):
 def is_compiled_with_tpu() -> bool:
     import jax
 
-    try:
-        return any(d.platform != "cpu" for d in jax.devices())
-    except Exception:
-        return False
+    return any(d.platform == "tpu" for d in jax.devices())

@@ -156,8 +156,8 @@ class _rpc_call:
     RPCError — plus the deadline-expiration counter when the failing
     call actually burned the reconnect deadline (a fast failure, e.g.
     get_var exhausting its retry COUNT against a live server, is an
-    error but not an expiration — the distinction a wedged-tunnel
-    post-mortem needs). Also opens the ``rpc.client`` trace span, whose
+    error but not an expiration — the distinction a post-mortem of a
+    hung run needs). Also opens the ``rpc.client`` trace span, whose
     context is what ``_wire_name`` serializes onto the wire — so the
     server-side event parents to THIS call, not just the trainer."""
 

@@ -7,9 +7,9 @@ choice is MEASURED per (op, input signature) over a small grid of
 Mosaic-legal block-shape candidates plus the composed path, and the
 winner is persisted so no process ever pays the measurement twice.
 
-Cache layout (``PADDLE_TPU_KERNEL_CACHE_DIR``; default
-``~/.cache/paddle_tpu/kernels``; set to ``0`` to disable persistence):
-one JSON file ``tuned_kernels.json``::
+Cache layout (``PADDLE_TPU_KERNEL_CACHE_DIR``; persistence is OFF unless
+it names a directory, so dispatch never depends on a file outside the
+checkout that nobody asked for): one JSON file ``tuned_kernels.json``::
 
     {"version": 1,
      "entries": {"layernorm_residual|float32,4096,512":
@@ -60,16 +60,10 @@ _TMP_SEQ = itertools.count(1)
 
 
 def cache_dir() -> Optional[str]:
-    """Winner-cache directory, or None when persistence is disabled
-    (``PADDLE_TPU_KERNEL_CACHE_DIR=0`` or empty-string)."""
-    raw = os.environ.get("PADDLE_TPU_KERNEL_CACHE_DIR")
-    if raw is None:
-        return os.path.join(os.path.expanduser("~"), ".cache",
-                            "paddle_tpu", "kernels")
-    raw = raw.strip()
-    if raw in ("", "0"):
-        return None
-    return raw
+    """Winner-cache directory, or None when persistence is off
+    (``PADDLE_TPU_KERNEL_CACHE_DIR`` unset, empty or ``0``)."""
+    raw = os.environ.get("PADDLE_TPU_KERNEL_CACHE_DIR", "").strip()
+    return None if raw in ("", "0") else raw
 
 
 def cache_path() -> Optional[str]:

@@ -1315,8 +1315,11 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
         inputs["SegmentIds"] = [segment_ids]
     helper.append_op(type="fused_attention", inputs=inputs,
                      outputs={"Out": [out], "Mask": [mask]},
+                     # is_test: Program.clone(for_test=True) and the
+                     # Predictor flip it, which turns the output dropout
+                     # off at inference like the dropout op's
                      attrs={"scale": float(scale), "dropout": float(dropout),
-                            "causal": bool(causal)})
+                            "causal": bool(causal), "is_test": False})
     out.shape = q.shape
     return out
 

@@ -3,7 +3,7 @@
 The reference glues C++ to Python with pybind11 (paddle/fluid/pybind/);
 pybind11 isn't available in this image, so the native pieces expose a C
 API consumed through ctypes. Libraries are compiled on first use with g++
-and cached next to the source (rebuilt when the source is newer).
+and cached next to the source (rebuilt when a source or header is newer).
 """
 
 from __future__ import annotations
@@ -78,10 +78,14 @@ def _build(name: str) -> str:
     srcs = [os.path.join(_DIR, name + ".cc")] + [
         os.path.join(_DIR, s) for s in _EXTRA_SOURCES.get(name, ())]
     so = os.path.join(_DIR, "lib" + name + ".so")
+    # every local header counts as a dependency of every library
+    # (serving.cc and train.cc include embed_common.h)
+    deps = srcs + [os.path.join(_DIR, f) for f in os.listdir(_DIR)
+                   if f.endswith(".h")]
     with _BUILD_LOCK:
         if (not os.path.exists(so)
-                or os.path.getmtime(so) < max(os.path.getmtime(s)
-                                              for s in srcs)):
+                or os.path.getmtime(so) < max(os.path.getmtime(d)
+                                              for d in deps)):
             extra = _EXTRA_FLAGS.get(name)
             cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
                     "-pthread"] + srcs + (extra() if extra else [])

@@ -1,60 +1,38 @@
-"""Python wrapper over the native combined-tensor checkpoint file
-(save_combine_op.cc / load_combine_op.cc analog — see tensor_store.cc).
-Dtype codes come from the shared table in native/dtypes.py; writes go to
-a temp file and rename into place, so a failed save never clobbers an
-existing good checkpoint."""
+"""The combined-tensor checkpoint file (save_combine_op.cc /
+load_combine_op.cc analog), read and written in Python.
+
+The format is tensor_store.cc's, byte for byte — little-endian::
+
+    magic "PTCK" | u32 format_version | u32 n_tensors
+    per tensor: u32 name_len | name | u8 dtype | u8 ndim | i64 dims[ndim]
+                | u64 nbytes | raw data
+
+so the Python-free loaders (native/pjrt_serving.cc compiles
+tensor_store.cc in) read what this module writes, and
+tests/test_tensor_store.py holds the two implementations to each other.
+Python does its own I/O here: saving a model or a checkpoint — the
+train/serve main path — must not need a C++ compiler. Dtype codes come
+from the shared table in native/dtypes.py; writes go to a temp file and
+rename into place, so a failed save never clobbers an existing good
+checkpoint."""
 
 from __future__ import annotations
 
-import ctypes
 import glob as _glob
 import itertools as _itertools
 import os
+import struct
 from typing import Dict
 
 import numpy as np
 
-from . import load
 from .dtypes import code_of, dtype_of
 
 __all__ = ["save_tensors", "load_tensors", "MAGIC"]
 
 MAGIC = b"PTCK"
+_FORMAT_VERSION = 1  # tensor_store.cc's kVersion
 _TMP_SEQ = _itertools.count(1)  # thread-safe staging-file uniquifier
-
-
-def _lib():
-    lib = load("tensor_store")
-    if getattr(lib, "_ts_typed", False):
-        return lib
-    c = ctypes
-    lib.ts_write_begin.restype = c.c_void_p
-    lib.ts_write_begin.argtypes = [c.c_char_p]
-    lib.ts_write_add.restype = c.c_int
-    lib.ts_write_add.argtypes = [c.c_void_p, c.c_char_p, c.c_int, c.c_int,
-                                 c.POINTER(c.c_int64), c.c_void_p, c.c_int64]
-    lib.ts_write_end.restype = c.c_int
-    lib.ts_write_end.argtypes = [c.c_void_p]
-    lib.ts_read_open.restype = c.c_void_p
-    lib.ts_read_open.argtypes = [c.c_char_p]
-    lib.ts_read_count.restype = c.c_int
-    lib.ts_read_count.argtypes = [c.c_void_p]
-    lib.ts_read_name.restype = c.c_char_p
-    lib.ts_read_name.argtypes = [c.c_void_p, c.c_int]
-    lib.ts_read_dtype.restype = c.c_int
-    lib.ts_read_dtype.argtypes = [c.c_void_p, c.c_int]
-    lib.ts_read_ndim.restype = c.c_int
-    lib.ts_read_ndim.argtypes = [c.c_void_p, c.c_int]
-    lib.ts_read_dims.restype = None
-    lib.ts_read_dims.argtypes = [c.c_void_p, c.c_int, c.POINTER(c.c_int64)]
-    lib.ts_read_data.restype = c.c_void_p
-    lib.ts_read_data.argtypes = [c.c_void_p, c.c_int]
-    lib.ts_read_nbytes.restype = c.c_int64
-    lib.ts_read_nbytes.argtypes = [c.c_void_p, c.c_int]
-    lib.ts_read_close.restype = None
-    lib.ts_read_close.argtypes = [c.c_void_p]
-    lib._ts_typed = True
-    return lib
 
 
 def _pid_alive(pid: int) -> bool:
@@ -93,35 +71,36 @@ def _clean_orphan_tmps(path: str) -> None:
 
 
 def save_tensors(path: str, tensors: Dict[str, np.ndarray]) -> None:
-    lib = _lib()
     _clean_orphan_tmps(path)
     # normalize + dtype-check everything BEFORE touching the filesystem
     prepared = []
     for name, arr in tensors.items():
         a = np.asarray(arr)
         if not a.flags["C_CONTIGUOUS"]:
+            # (ascontiguousarray alone would promote a 0-d array to 1-d)
             a = np.ascontiguousarray(a).reshape(a.shape)
-        prepared.append((name, a, code_of(a.dtype)))
+        if a.ndim > 255:
+            raise ValueError("%r has %d dims; the format holds 255"
+                             % (name, a.ndim))
+        prepared.append((name.encode(), a, code_of(a.dtype)))
 
     # unique staging name: concurrent writers to the same target (e.g. a
     # sync save racing an async background write) each stage their own
     # temp file — the final os.replace is last-writer-wins, never a torn
     # or interleaved file
     tmp = "%s.tmp.%d.%d" % (path, os.getpid(), next(_TMP_SEQ))
-    h = lib.ts_write_begin(tmp.encode())
-    if not h:
-        raise IOError("cannot open %s for writing" % tmp)
-    ended = finished = False
+    finished = False
     try:
-        for name, a, code in prepared:
-            dims = (ctypes.c_int64 * max(a.ndim, 1))(*a.shape)
-            ok = lib.ts_write_add(h, name.encode(), code, a.ndim, dims,
-                                  a.ctypes.data_as(ctypes.c_void_p), a.nbytes)
-            if not ok:
-                raise IOError("write failed for %r in %s" % (name, tmp))
-        ended = True
-        if not lib.ts_write_end(h):
-            raise IOError("finalize failed for %s" % tmp)
+        # streams straight to disk: no second copy of a full checkpoint
+        with open(tmp, "wb") as f:
+            f.write(MAGIC + struct.pack("<II", _FORMAT_VERSION,
+                                        len(prepared)))
+            for name, a, code in prepared:
+                f.write(struct.pack("<I", len(name)) + name
+                        + struct.pack("<BB", code, a.ndim)
+                        + struct.pack("<%dq" % a.ndim, *a.shape)
+                        + struct.pack("<Q", a.nbytes))
+                f.write(_raw_bytes(a))
         # fault-injection site, placed EXACTLY in the crash window that
         # matters: the staged tmp is complete, the rename has not
         # happened — a 'crash' here leaves the litter a real power loss
@@ -134,8 +113,6 @@ def save_tensors(path: str, tensors: Dict[str, np.ndarray]) -> None:
         os.replace(tmp, path)
         finished = True
     finally:
-        if not ended:
-            lib.ts_write_end(h)  # closes and frees the native writer
         if not finished:
             try:
                 os.remove(tmp)
@@ -143,31 +120,46 @@ def save_tensors(path: str, tensors: Dict[str, np.ndarray]) -> None:
                 pass
 
 
+def _raw_bytes(a: np.ndarray) -> np.ndarray:
+    """The C-contiguous array's own memory as flat bytes (no copy; works
+    for bfloat16, which has no buffer-protocol format of its own)."""
+    return a.reshape(-1).view(np.uint8)
+
+
+def _read_exact(f, n: int, path: str) -> bytes:
+    buf = f.read(n)
+    if len(buf) != n:
+        raise IOError("cannot read checkpoint %s (truncated)" % path)
+    return buf
+
+
 def load_tensors(path: str) -> Dict[str, np.ndarray]:
-    lib = _lib()
-    h = lib.ts_read_open(path.encode())
-    if not h:
-        raise IOError("cannot read checkpoint %s (missing or bad header)"
-                      % path)
     try:
+        f = open(path, "rb")
+    except OSError:
+        raise IOError("cannot read checkpoint %s (missing or bad header)"
+                      % path) from None
+    with f:
+        header = f.read(12)
+        if len(header) != 12 or header[:4] != MAGIC \
+                or struct.unpack("<I", header[4:8])[0] != _FORMAT_VERSION:
+            raise IOError("cannot read checkpoint %s (missing or bad "
+                          "header)" % path)
         out: Dict[str, np.ndarray] = {}
-        for i in range(lib.ts_read_count(h)):
-            name = lib.ts_read_name(h, i).decode()
-            dt = dtype_of(lib.ts_read_dtype(h, i))
-            nd = lib.ts_read_ndim(h, i)
-            dims = (ctypes.c_int64 * max(nd, 1))()
-            if nd:
-                lib.ts_read_dims(h, i, dims)
-            shape = tuple(dims[j] for j in range(nd))
-            nbytes = int(lib.ts_read_nbytes(h, i))
-            if nbytes:
-                # one copy straight out of the reader's buffer
-                buf = (ctypes.c_uint8 * nbytes).from_address(
-                    lib.ts_read_data(h, i))
-                arr = np.frombuffer(buf, dtype=dt).reshape(shape).copy()
-            else:
-                arr = np.empty(shape, dtype=dt)
+        for _ in range(struct.unpack("<I", header[8:])[0]):
+            (nlen,) = struct.unpack("<I", _read_exact(f, 4, path))
+            name = _read_exact(f, nlen, path).decode()
+            code, nd = struct.unpack("<BB", _read_exact(f, 2, path))
+            shape = struct.unpack("<%dq" % nd, _read_exact(f, 8 * nd, path))
+            (nbytes,) = struct.unpack("<Q", _read_exact(f, 8, path))
+            dt = dtype_of(code)
+            if nbytes != int(np.prod(shape, dtype=np.int64)) * dt.itemsize:
+                raise IOError("cannot read checkpoint %s (%r: %d bytes for "
+                              "shape %s %s)" % (path, name, nbytes,
+                                                shape, dt))
+            # one read straight into the array's own buffer
+            arr = np.empty(shape, dtype=dt)
+            if f.readinto(_raw_bytes(arr)) != nbytes:
+                raise IOError("cannot read checkpoint %s (truncated)" % path)
             out[name] = arr
         return out
-    finally:
-        lib.ts_read_close(h)

@@ -26,8 +26,9 @@ pallas_call here, including in interpret mode, so the CPU test suite
 fails on any spec real TPU lowering would reject. Beyond the mirror,
 the REAL Mosaic lowering path runs in CI via TPU-target jax.export
 (tests/test_tpu_lowering.py): forward + both backward kernels lower to
-``tpu_custom_call`` on a CPU-only machine — only the Mosaic->LLO compile
-(VMEM limits) and execution remain hardware-gated.
+``tpu_custom_call`` on a CPU-only machine, and tests/test_chip_bringup.py
+compiles them for a described v5e (VMEM limits included) — only
+execution needs the chip (chip_smoke.py).
 
 Ragged sequence lengths are padded to the block size with key-side
 additive masking (-1e9) rather than falling back to whole-sequence
@@ -121,9 +122,8 @@ def flash_min_seq() -> int:
     (materialized [Sq,Sk] scores — fully fused by XLA, no kernel-launch
     or blocked-softmax overhead) instead of the Pallas kernel: at short
     S the score matrix is tiny and the blocked online-softmax scheme
-    costs more than it saves. The 2026-07-31 v5e window measured the
-    S=128 transformer at 93.6k tok/s on the flash path vs a 103.6k
-    composed baseline — but those static numbers are SUPERSEDED the
+    costs more than it saves. The threshold itself is not measured on
+    the current code (ROADMAP.md Queue 1 item 4); it is SUPERSEDED the
     moment a tuned kernel-tier entry exists for the sequence lengths in
     play (``tools/kernel_tune.py --op attention`` measures and persists
     the real flash-vs-composed winner per shape; docs/KERNELS.md).
@@ -179,7 +179,7 @@ def composed_attention(q, k, v, bias=None, scale=1.0, causal=False):
     expression XLA fuses end to end: scores and softmax in f32 (matching
     the kernel's in-VMEM accumulation dtype), output cast back to the
     input dtype. Used by ``flash_attention`` below ``flash_min_seq()``
-    and as the numerics reference everywhere (tpu_validate, parity
+    and as the numerics reference everywhere (chip_smoke.py, parity
     tests)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -278,6 +278,19 @@ def _block_visible(iq, ik, bq, bk):
 
 
 # --------------------------------------------------------------- forward
+def _dot_f32(a, b, ca, cb):
+    """``a`` contracted with ``b`` over axes ``ca``/``cb`` at the operands'
+    dtype (bf16 hits the MXU at full rate) with f32 accumulation. bf16
+    operands name DEFAULT precision outright: under a process-wide
+    ``jax_default_matmul_precision=highest`` (the CPU test suite sets it)
+    they would otherwise ask Mosaic for an fp32 contraction of bf16
+    vectors, which the TPU compiler refuses ("Bad lhs type")."""
+    precision = None if a.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, nk, causal, bq, bk):
     iq = pl.program_id(1)
@@ -296,8 +309,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         q = q_ref[0]                              # [bq, D]
         k = k_ref[0]                              # [bk, D]
         v = v_ref[0]                              # [bk, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot_f32(q, k, 1, 1) * scale
         if b_ref is not None:
             s = s + b_ref[0, 0].astype(jnp.float32)
         if causal:
@@ -310,9 +322,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         p = jnp.exp(s - m_new)                    # [bq, bk] f32
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha \
+            + _dot_f32(p.astype(v.dtype), v, 1, 0)
 
     @pl.when(ik == nk - 1)
     def _emit():
@@ -400,8 +411,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
         lse = lse_ref[0]                          # [bq, 1]
         delta = d_ref[0]                          # [bq, 1]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot_f32(q, k, 1, 1) * scale
         if b_ref is not None:
             s = s + b_ref[0, 0].astype(jnp.float32)
         if causal:
@@ -409,15 +419,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
         p = jnp.exp(s - lse)                      # [bq, bk] f32
 
         # dv += p^T g ; dp = g v^T ; ds = p*(dp-delta)*scale ; dk += ds^T q
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dv_acc[...] += _dot_f32(p.astype(g.dtype), g, 0, 0)
+        dp = _dot_f32(g, v, 1, 1)
         ds = p * (dp - delta) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_acc[...] += _dot_f32(ds.astype(q.dtype), q, 0, 0)
         if ds_ref is not None:
             # raw score gradient (pre-scale is ds/scale; bias adds after
             # the scale, so its cotangent drops the trailing *scale)
@@ -447,19 +452,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
         lse = lse_ref[0]                          # [bq, 1]
         delta = d_ref[0]                          # [bq, 1]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot_f32(q, k, 1, 1) * scale
         if b_ref is not None:
             s = s + b_ref[0, 0].astype(jnp.float32)
         if causal:
             s = _causal_mask(s, iq, ik, bq, bk)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot_f32(g, v, 1, 1)
         ds = p * (dp - delta) * scale             # [bq, bk] f32
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_acc[...] += _dot_f32(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(ik == nk - 1)
     def _emit():
@@ -906,7 +907,7 @@ def _fused_attention(ctx, ins, attrs):
         bias = bias.astype(jnp.float32)  # mask bias adds in f32 in-kernel
     out = _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal,
                                     seg=seg)
-    if dropout and not ctx.is_test:
+    if dropout and not (attrs.get("is_test", False) or ctx.is_test):
         # dropout on the *output* (weights-dropout does not commute with the
         # fused kernel; divergence from the layer-composed path documented).
         # The mask is a saved output so the grad op can replay it without

@@ -323,13 +323,8 @@ def _ring_attention_zigzag(q, k, v, scale, axis_name, kv_bias,
         # the kernel outputs under strict varying-manner checking
         o = jnp.zeros(qc.shape, jnp.float32)
         l = jnp.full(qc.shape[:3], -jnp.inf, jnp.float32)
-        if hasattr(lax, "pcast"):  # jax >= 0.8: pvary is deprecated
-            return (lax.pcast(o, axis_name, to="varying"),
-                    lax.pcast(l, axis_name, to="varying"))
-        try:
-            return lax.pvary(o, axis_name), lax.pvary(l, axis_name)
-        except AttributeError:  # older jax: vma analysis absent
-            return o, l
+        return (lax.pcast(o, axis_name, to="varying"),
+                lax.pcast(l, axis_name, to="varying"))
 
     def merge(acc, part):
         o_a, l_a = acc

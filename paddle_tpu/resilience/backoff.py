@@ -3,8 +3,8 @@
 One formula (AWS "full jitter": ``uniform(0, min(cap, base * 2^attempt))``)
 used by the resilience supervisor's recovery sleeps, the RPC client's
 ``get_var`` init-race polling and the bench backend-probe retries — so a
-fleet of restarting trainers never thundering-herds a recovering pserver
-or TPU tunnel, and chaos tests can pin the envelope deterministically by
+fleet of restarting trainers never thundering-herds a recovering
+pserver, and chaos tests can pin the envelope deterministically by
 passing a seeded ``random.Random``.
 """
 

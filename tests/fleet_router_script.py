@@ -53,6 +53,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

@@ -1,0 +1,439 @@
+"""Chip bring-up guards that need no chip (ISSUE 21).
+
+(a) The main path's kernels compile for a DESCRIBED TPU v5e at BERT-base
+    S512 shapes: the TPU compiler is installed here and compiles for a chip
+    that is not attached (on-chip-measurement guide, section 2.3), so a
+    fast-memory overrun or a slice off the tiling fails here, not on the
+    chip. Nothing runs: a compile that passes is not a chip run.
+(b) ``use_interpret`` decides from ``platform == "tpu"`` alone and raises
+    when the backend cannot be asked.
+(c) ``flags.enable_compile_cache`` is placed from outside.
+(d) ``bench.py``'s parent never imports jax and fails when any row fails.
+(e) ``chip_smoke.py`` fails on the CPU and never claims a TPU it did not see.
+(f) ``TPUPlace`` refuses a CPU nobody asked for.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+
+
+# ------------------------------------------------ (a) compiles for the v5e
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described v5e 2x2; the persistent compile
+    cache is off around these compiles (an entry written for a described
+    chip cannot be read back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # another process describing the chip must not lock this one out
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip("get_topology_desc cannot describe a v5e here: %s" % exc)
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "0")
+
+
+def _compile(fn, dev, *args):
+    """Compile ``fn`` for the described chip; ``args`` is a pytree of
+    (shape, dtype) leaves. Returns the count of Mosaic kernels in it."""
+    is_leaf = lambda x: isinstance(x, tuple) and len(x) == 2 \
+        and isinstance(x[0], tuple)  # noqa: E731
+    sds = jax.tree_util.tree_map(
+        lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], sharding=dev),
+        args, is_leaf=is_leaf)
+    text = jax.jit(fn).lower(*sds).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+FLASH_CASES = {
+    # name: (B, H, S, D), causal, bias shape or None, bias_grad, dtype
+    "bert_s512_maskbias": ((16, 12, 512, 64), False, (16, 1, 1, 512), False,
+                           BF16),
+    "causal_s1024": ((8, 12, 1024, 64), True, None, False, BF16),
+    "ragged_s500_maskbias": ((2, 12, 500, 64), False, (2, 1, 1, 500), False,
+                             BF16),
+    "causal_d128": ((2, 8, 512, 128), True, None, False, BF16),
+    "trainable_bias": ((2, 12, 512, 64), False, (1, 12, 512, 512), True,
+                       BF16),
+    "full_bias": ((2, 12, 512, 64), False, (2, 12, 512, 512), False, BF16),
+    # chip_smoke.py's kernels phase also runs float32 inputs
+    "f32_s256": ((2, 4, 256, 64), False, None, False, F32),
+    "f32_causal_s256": ((2, 4, 256, 64), True, None, False, F32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_attention_compiles_for_v5e(case, v5e, compiled_kernels):
+    from paddle_tpu.ops.attention import flash_attention
+
+    shape, causal, bias_shape, bias_grad, dtype = FLASH_CASES[case]
+    scale = shape[-1] ** -0.5
+
+    def loss(q, k, v, bias=None):
+        out = flash_attention(q, k, v, bias, scale, bias_grad=bias_grad,
+                              causal=causal)
+        return jnp.sum(out.astype(F32) ** 2)
+
+    args = [(shape, dtype)] * 3
+    argnums = (0, 1, 2)
+    if bias_shape is not None:
+        args.append((bias_shape, F32))
+        if bias_grad:
+            argnums += (3,)
+    n = _compile(jax.value_and_grad(loss, argnums=argnums), v5e, *args)
+    # forward, dK/dV and dQ (a layer of the step program holds four: its
+    # grad op runs the forward again)
+    assert n == 3, "%s: %d Mosaic kernels" % (case, n)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("block", [8, 64, 256])
+def test_layernorm_residual_compiles_for_v5e(dtype, block, v5e,
+                                             compiled_kernels):
+    from paddle_tpu.kernels import layernorm
+
+    n, d = 8192, 768  # B16 x S512 rows at BERT-base width
+    assert (block,) in layernorm._candidates(
+        layernorm.signature_for(n, d, dtype))
+
+    def loss(x, r, scale, bias):
+        y, s, _mean, _var = layernorm.layernorm_residual(
+            (block,), x, r, scale, bias)
+        return jnp.sum(y.astype(F32)) + jnp.sum(s.astype(F32))
+
+    count = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), v5e,
+        ((n, d), dtype), ((n, d), dtype), ((d,), dtype), ((d,), dtype))
+    assert count == 2  # forward and backward
+
+
+@pytest.mark.parametrize("kind", ["adam", "sgd"])
+def test_optimizer_sweep_compiles_for_v5e(kind, v5e, compiled_kernels):
+    """One candidate on a small group: the full-size sweep (13.75M
+    elements) takes 8-12 s per candidate to compile, and the kernel is
+    oblivious to the total length."""
+    from paddle_tpu.kernels import optimizer_update as ou
+
+    sizes = [768 * 768, 768 * 3072, 3072, 768]  # one encoder layer's kinds
+    sig = ou.signature_for(sum(sizes), "float32", len(sizes))
+    cfg = ou._candidates(sig)[-1]
+    vec = [((s,), F32) for s in sizes]
+    one = [((1,), F32) for _ in sizes]
+    ins = {"Param": vec, "Grad": vec, "LearningRate": one}
+    if kind == "adam":
+        ins.update(Moment1=vec, Moment2=vec, Beta1Pow=one, Beta2Pow=one)
+        fn = lambda ins: ou.adam_group_pallas(cfg, ins)  # noqa: E731
+    else:
+        fn = lambda ins: ou.sgd_group_pallas(cfg, ins)  # noqa: E731
+    assert _compile(fn, v5e, ins) == 1
+
+
+# ------------------------------------------------------ (b) use_interpret
+class _Dev:
+    def __init__(self, platform, device_kind="fake"):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", False),
+    ("cpu", "cpu", True),
+    # the platform decides, never a device_kind that merely says "TPU"
+    ("other", "TPU v5 lite", True),
+])
+def test_use_interpret_decides_from_platform(monkeypatch, platform, kind,
+                                             want):
+    from paddle_tpu.kernels.common import use_interpret
+
+    monkeypatch.delenv("PADDLE_TPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(platform, kind)])
+    assert use_interpret() is want
+
+
+def _no_backend(*_a):
+    raise RuntimeError("backend init failed")
+
+
+def test_use_interpret_raises_when_backend_cannot_be_asked(monkeypatch):
+    from paddle_tpu.kernels.common import use_interpret
+
+    monkeypatch.delenv("PADDLE_TPU_FLASH_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "devices", _no_backend)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        use_interpret()
+
+
+@pytest.mark.parametrize("knob,want", [("1", True), ("0", False)])
+def test_use_interpret_debug_knob_needs_no_backend(monkeypatch, knob, want):
+    from paddle_tpu.kernels.common import use_interpret
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", knob)
+    monkeypatch.setattr(jax, "devices", _no_backend)
+    assert use_interpret() is want
+
+
+# ------------------------------------------------------ (c) compile cache
+# flags.py is loaded by path: importing the whole package would cost each
+# probe process several seconds and change nothing about the answer
+_CACHE_PROBE = textwrap.dedent("""
+    import importlib.util, json
+    import jax
+    set_in_code = []
+    real_update = jax.config.update
+    def spy(name, value):
+        set_in_code.append(name)
+        return real_update(name, value)
+    jax.config.update = spy
+    spec = importlib.util.spec_from_file_location("flags", %r)
+    flags = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(flags)
+    returned = flags.enable_compile_cache()
+    print(json.dumps({"returned": returned, "set_in_code": set_in_code,
+                      "dir": jax.config.jax_compilation_cache_dir}))
+""") % os.path.join(ROOT, "paddle_tpu", "flags.py")
+
+
+def _probe_cache(cwd, cache_env):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("PYTHONPATH", None)
+    if cache_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_env
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=cwd,
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_dir_from_environment_is_not_set_in_code(tmp_path):
+    got = _probe_cache(str(tmp_path), str(tmp_path / "outside"))
+    assert got["dir"] == got["returned"] == str(tmp_path / "outside")
+    assert "jax_compilation_cache_dir" not in got["set_in_code"]
+    assert "jax_persistent_cache_min_compile_time_secs" in got["set_in_code"]
+
+
+def test_compile_cache_default_is_the_checkout_from_any_cwd(tmp_path):
+    (tmp_path / "elsewhere").mkdir()
+    a = _probe_cache(str(tmp_path), None)
+    b = _probe_cache(str(tmp_path / "elsewhere"), None)
+    assert a["dir"] == b["dir"] == os.path.join(ROOT, ".jax_cache")
+
+
+# ------------------------------------------------------ (d) bench.py parent
+_BENCH_PARENT = textwrap.dedent("""
+    import sys
+    sys.path.insert(0, %r)
+    sys.argv = ["bench.py"]
+    import bench
+
+    class _ProbeOk:
+        pid = 0
+        def __init__(self, *a, **k): pass
+        def wait(self, timeout=None): return 0
+    bench.subprocess.Popen = _ProbeOk
+    bench.ORDER = ["row_a", "row_b"]
+    outcomes = %r
+    bench._spawn_workload = lambda name, args, timeout_s: outcomes[name]
+    rc = bench.main()
+    assert "jax" not in sys.modules, "bench.py's parent imported jax"
+    assert "paddle_tpu" not in sys.modules
+    sys.exit(rc)
+""")
+
+
+@pytest.mark.parametrize("outcomes,want_rc", [
+    ({"row_a": True, "row_b": True}, 0),
+    ({"row_a": True, "row_b": False}, 1),   # one of two rows failed
+    ({"row_a": False, "row_b": True}, 1),
+])
+def test_bench_parent_stays_off_jax_and_fails_on_any_failed_row(outcomes,
+                                                                want_rc):
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PADDLE_TPU_BENCH_")}
+    out = subprocess.run(
+        [sys.executable, "-c", _BENCH_PARENT % (ROOT, outcomes)],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == want_rc, out.stderr[-2000:]
+
+
+def test_bench_attention_row_with_broken_kernel_fails_without_composed_row(
+        tmp_path):
+    """A kernel that cannot be built (an illegal block size) is an error
+    row and a non-zero exit: the row is never re-run on the composed
+    path."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PADDLE_TPU_FLASH_MIN_SEQ="0", PADDLE_TPU_FLASH_BQ="7",
+               PADDLE_TPU_TELEMETRY_DIR=str(tmp_path),
+               PADDLE_TPU_BENCH_WORKLOAD_TIMEOUT="300")
+    env.pop("XLA_FLAGS", None)
+    env.pop("PADDLE_TPU_FUSED_ATTENTION", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "bench.py"), "--quick",
+         "--only", "transformer"],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        text=True, timeout=400)
+    rows = [json.loads(l) for l in out.stdout.splitlines() if l.strip()]
+    assert out.returncode != 0
+    assert rows and all("error" in r for r in rows), rows
+    assert not any("value" in r or r.get("attention_path") == "composed"
+                   for r in rows)
+    assert any("PADDLE_TPU_FLASH_BQ" in r["error"] for r in rows), rows
+
+
+def test_bench_peak_flops_unknown_device_is_an_error(monkeypatch):
+    sys.path.insert(0, ROOT)
+    try:
+        import bench
+    finally:
+        sys.path.pop(0)
+    monkeypatch.setenv("PADDLE_TPU_PEAK_TFLOPS", "123")  # no longer read
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("cpu", "cpu")])
+    assert bench.peak_flops() is None
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_Dev("tpu", "TPU v5 lite")])
+    assert bench.peak_flops() == 197e12
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("tpu", "TPU v9")])
+    with pytest.raises(RuntimeError, match="TPU v9"):
+        bench.peak_flops()
+
+
+# ------------------------------------------------------- (e) chip_smoke.py
+def _chip_smoke(*args, cwd=ROOT, script=None, **env_extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, script or os.path.join(ROOT, "chip_smoke.py"),
+         *args],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=600)
+
+
+@pytest.mark.parametrize("args", [(), ("--chips", "4")])
+def test_chip_smoke_fails_on_the_cpu_without_a_result(args):
+    out = _chip_smoke(*args)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+def test_chip_smoke_alone_in_a_directory_fails_without_a_result(tmp_path):
+    import shutil
+
+    script = shutil.copy(os.path.join(ROOT, "chip_smoke.py"), str(tmp_path))
+    out = _chip_smoke(cwd=str(tmp_path), script=script, PYTHONPATH="")
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+
+
+def test_chip_smoke_rehearsal_passes_and_never_says_ok(tmp_path):
+    """The tiny CPU rehearsal drives every default phase (kernels, train,
+    serve) and still never prints the contract's result line."""
+    out = _chip_smoke("--cpu-rehearsal",
+                      JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(l) for l in out.stdout.splitlines() if l.strip()]
+    assert [l.get("phase") for l in lines[:-1]] == [
+        "device", "kernels", "train", "serve", "cache"]
+    assert all(l.get("ok", True) for l in lines)
+    assert '"ok": true' not in out.stdout.splitlines()[-1]
+    assert lines[-1] == {"rehearsal": "passed",
+                         "device": {"platform": "cpu", "kind": "cpu",
+                                    "count": 1}}
+    assert lines[0]["compile_cache_dir"] == str(tmp_path / "cache")
+    train = lines[2]
+    assert np.all(np.isfinite(train["losses"]))
+    assert train["kernel_tier"]["attention"]["choice"] == "flash"
+
+
+def test_chip_smoke_forced_failure_exits_nonzero(tmp_path):
+    """A phase that fails (here: the flash kernel cannot be built) ends
+    the run non-zero, with no result line."""
+    out = _chip_smoke("--cpu-rehearsal", PADDLE_TPU_FLASH_BQ="7")
+    assert out.returncode != 0
+    assert '"rehearsal": "passed"' not in out.stdout
+    assert '"ok": true' not in out.stdout
+    assert "chip_smoke: FAILED" in out.stderr
+
+
+# ------------------------------------------------------------ (f) TPUPlace
+def test_tpuplace_refuses_a_cpu_nobody_asked_for(monkeypatch):
+    import paddle_tpu as fluid
+
+    place = fluid.TPUPlace()
+    assert place.jax_device().platform == "cpu"  # the tests ask for it
+    monkeypatch.setattr("paddle_tpu.core.place._cpu_requested",
+                        lambda: False)
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        place.jax_device()
+    with pytest.raises(RuntimeError, match="no accelerator"):
+        fluid.Executor(place).run(fluid.Program())
+    # CPUPlace stays what it says
+    assert fluid.CPUPlace().jax_device().platform == "cpu"
+
+
+@pytest.mark.parametrize("platforms,want", [
+    ("cpu", True), ("cpu,tpu", True), ("tpu", False), ("tpu,cpu", False),
+    ("", False), (None, False)])
+def test_cpu_requested_reads_jax_platforms(monkeypatch, platforms, want):
+    from paddle_tpu.core import place
+
+    class _Cfg:
+        jax_platforms = platforms
+
+    monkeypatch.setattr(jax, "config", _Cfg)
+    assert place._cpu_requested() is want
+
+
+# --------------------------------- what the bring-up found on the way
+def test_fused_attention_dropout_is_off_in_a_for_test_clone(fresh_programs):
+    """Found by chip_smoke's serve phase: the fused-attention op kept its
+    output dropout in ``clone(for_test=True)`` and in the Predictor, so a
+    served BERT answered with random masks."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+
+    main, startup, scope = fresh_programs
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", [2, 16, 8], dtype="float32")
+        out = layers.fused_attention(x, x, x, scale=0.5, dropout=0.5)
+        test_prog = main.clone(for_test=True)
+    exe = fluid.Executor(fluid.TPUPlace())
+    feed = {"x": np.random.RandomState(0).randn(3, 2, 16, 8)
+            .astype("float32")}
+    a, = exe.run(test_prog, feed=feed, fetch_list=[out])
+    b, = exe.run(test_prog, feed=feed, fetch_list=[out])
+    np.testing.assert_array_equal(a, b)
+    t1, = exe.run(main, feed=feed, fetch_list=[out])
+    assert not np.array_equal(a, t1)  # training still drops
+    from paddle_tpu.ops.attention import composed_attention
+
+    want = composed_attention(feed["x"], feed["x"], feed["x"], None, 0.5)
+    np.testing.assert_allclose(a, np.asarray(want), atol=1e-5)

@@ -14,7 +14,7 @@ EX = os.path.join(ROOT, "examples")
 
 def _run(script, *args, env_extra=None, timeout=420):
     env = dict(os.environ)
-    env["PADDLE_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(env_extra or {})
     proc = subprocess.run(
         [sys.executable, os.path.join(EX, script), *args],

@@ -51,18 +51,6 @@ try:
 finally:
     sys.path.pop(0)
 
-import jax
-
-try:  # not auto-imported into the jax namespace — probe explicitly
-    import jax.export  # noqa: F401
-except ImportError:
-    pass
-
-needs_jax_export = pytest.mark.skipif(
-    not hasattr(jax, "export"),
-    reason="quarantined: this jax has no jax.export (the artifact's "
-           "AOT section is jax.export serialization)")
-
 
 def _feed_for(main, batch, seed=0):
     rng = np.random.RandomState(seed)
@@ -218,7 +206,6 @@ def test_seed_plan_installs_without_miss(tmp_path):
     assert fam.EXECUTOR_CACHE_HITS.value == hit0 + 1
 
 
-@needs_jax_export
 def test_aot_section_serves_first_token(tmp_path):
     """With a live AOT section the bucket run is served by the frozen
     jax.export executable — counted — and stays bitwise."""

@@ -8,13 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax keeps shard_map in jax.experimental
-    pytest.skip(
-        "quarantined on this jax: no top-level jax.shard_map (the "
-        "parallel lowering stack targets the finalized API)",
-        allow_module_level=True)
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.parallel.moe import moe_apply

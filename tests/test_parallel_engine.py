@@ -388,11 +388,6 @@ def test_engine_reduce_fetches_mean_on_mesh():
                                np.mean(per), rtol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="quarantined (ISSUE 10): the ring-attention segment-id "
-           "path lowers through top-level jax.shard_map, absent on "
-           "this jax")
 def test_packed_gpt_sp_rides_ring_with_segment_ids():
     """Packed causal LM training under a (data, seq) mesh: the fused op
     receives segment IDS (never the [S,S] pack bias), they ride the

@@ -732,7 +732,7 @@ def test_probe_backend_retries_transient_failures(monkeypatch):
     def flaky():
         calls.append(1)
         if len(calls) < 3:
-            raise RuntimeError("transient tunnel hiccup")
+            raise RuntimeError("transient backend hiccup")
         return "ok"
 
     a_ok = _value("paddle_backend_probe_attempts_total", outcome="ok")
@@ -809,35 +809,6 @@ def test_fit_probe_attempts_respects_workload_budget():
     assert bench._fit_probe_attempts(2000, 300, 3) == 3  # budget fits all
     assert bench._fit_probe_attempts(120, 300, 3) == 1   # always >= 1
     assert bench._fit_probe_attempts(900, 300, 1) == 1
-
-
-# -------------------------------------------------- tunnel_watch --rearm
-def test_tunnel_watch_rearm_captures_multiple_windows(monkeypatch,
-                                                      tmp_path):
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    import tunnel_watch as tw
-
-    monkeypatch.delenv("PADDLE_TPU_PLATFORM", raising=False)
-    monkeypatch.setattr(tw, "LOG", str(tmp_path / "watch.log"))
-    runs = []
-    monkeypatch.setattr(tw, "probe", lambda: True)
-    monkeypatch.setattr(tw, "run", lambda cmd, dl: runs.append(cmd) or 0)
-    monkeypatch.setattr(tw.time, "sleep", lambda s: None)
-    monkeypatch.setattr(sys, "argv",
-                        ["tunnel_watch.py", "--rearm", "2", "--quick"])
-    assert tw.main() == 0
-    assert len(runs) == 3  # first capture + 2 re-arms
-    assert all("--quick" in c for c in runs)
-
-    runs.clear()
-    monkeypatch.setattr(sys, "argv", ["tunnel_watch.py"])
-    assert tw.main() == 0
-    assert len(runs) == 1  # default keeps the one-shot contract
-
-    runs.clear()
-    monkeypatch.setattr(tw, "run", lambda cmd, dl: runs.append(cmd) or 1)
-    monkeypatch.setattr(sys, "argv", ["tunnel_watch.py", "--rearm", "1"])
-    assert tw.main() == 1  # any failed capture -> nonzero
 
 
 # --------------------------------------------------- the slow chaos proof

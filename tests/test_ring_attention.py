@@ -11,13 +11,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 import pytest
 
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax keeps shard_map in jax.experimental
-    pytest.skip(
-        "quarantined on this jax: no top-level jax.shard_map (the "
-        "parallel lowering stack targets the finalized API)",
-        allow_module_level=True)
+from jax import shard_map
 
 from paddle_tpu.ops.attention import _attention_reference
 from paddle_tpu.parallel.ring_attention import ring_attention

@@ -207,5 +207,5 @@ def main(argv=None):
 if __name__ == "__main__":
     # standalone CLI runs force the cpu backend BEFORE paddle_tpu imports
     # jax; NOT at module import — tests import this module in-process
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

@@ -146,5 +146,5 @@ if __name__ == "__main__":
     # standalone CLI runs force the cpu backend BEFORE paddle_tpu
     # imports jax (same contract as lint_program.py: NOT at module
     # import, which tests import in-process)
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

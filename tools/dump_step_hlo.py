@@ -1,7 +1,7 @@
 """Dump the compiled train step's performance artifacts for one
 workload: optimized HLO, XLA cost analysis, donation aliasing, dominant
-fusions — the inputs to the ResNet-50 MFU ladder (docs/PERF.md; SURVEY
-§6 self-measurement contract, VERDICT r3 task 2).
+fusions — the inputs to the ResNet-50 MFU ladder (ROADMAP.md Queue 1
+item 2; SURVEY §6 self-measurement contract).
 
 Runs on CPU (structure analysis: aliasing, host-callback scan, op mix)
 or on TPU (adds the real backend's compile). Usage:
@@ -66,11 +66,6 @@ def main():
     ap.add_argument("--quick", action="store_true", help="tiny batch")
     ap.add_argument("--fp32", action="store_true")
     args = ap.parse_args()
-
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     # reuse bench.py's workload builders via a light shim: build the
     # program/feeds exactly as the bench does, then introspect instead

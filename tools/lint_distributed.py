@@ -96,5 +96,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

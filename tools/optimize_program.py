@@ -156,5 +156,5 @@ if __name__ == "__main__":
     # jax; deliberately only under __main__ (tests import this module and
     # call main() in-process — see tools/lint_program.py for the leak
     # this avoids)
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

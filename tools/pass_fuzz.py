@@ -706,5 +706,5 @@ if __name__ == "__main__":
     # standalone CLI runs force the cpu backend BEFORE paddle_tpu
     # imports jax; only under __main__ (tests import this module — see
     # tools/lint_program.py for the env-leak this avoids)
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

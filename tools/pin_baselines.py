@@ -1,13 +1,11 @@
 """Pin measured bench rows into bench.py's BASELINES dict.
 
-The contract (VERDICT r3 weak #2): the first committed hardware numbers
-and the baseline pinning must land in the SAME commit, or regression
-tracking slips a round. This tool makes that a one-liner in the
-hardware window:
+The contract: committed hardware numbers and the baseline pinning land
+in the SAME commit, or regression tracking slips. This tool makes that a
+one-liner:
 
-    python bench.py | tee BENCH_r04.json
-    python tools/pin_baselines.py BENCH_r04.json
-    git add bench.py BENCH_r04.json && git commit ...
+    python bench.py | tee BENCH_rows.json
+    python tools/pin_baselines.py BENCH_rows.json
 
 Only rows with a real value pin; error rows are skipped. A row pins
 when it beats (or first sets) the current baseline — regressions are
@@ -26,9 +24,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "bench.py")
 
 
-def load_rows(path, require_value=True):
-    """Noise-tolerant bench JSON-lines parser (shared with
-    window_playbook): ``require_value=False`` keeps error rows too."""
+def load_rows(path):
+    """Noise-tolerant bench JSON-lines parser: result rows only (error
+    rows never pin)."""
     rows = []
     if not os.path.exists(path):
         return rows
@@ -43,7 +41,7 @@ def load_rows(path, require_value=True):
                 continue
             if not isinstance(row, dict):
                 continue
-            if require_value and not ("value" in row and "metric" in row):
+            if not ("value" in row and "metric" in row):
                 continue
             rows.append(row)
     return rows

@@ -20,6 +20,10 @@ speculative acceptance::
 
     python tools/serving_load.py --requests 64 --replicas 2 \
         --prefix-share 0.8 --tenants default:0.9,burst:0.1
+
+The fleet's replicas are threads of this one process, which therefore
+owns the chip; it runs on the backend JAX finds (JAX_PLATFORMS=cpu for
+a CPU rehearsal).
 """
 
 from __future__ import annotations
@@ -236,6 +240,9 @@ def main(argv=None) -> int:
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
+    from paddle_tpu.flags import enable_compile_cache
+
+    enable_compile_cache()
     mix = {}
     for part in args.tenants.split(","):
         name, _, p = part.partition(":")
@@ -284,9 +291,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # standalone CLI runs force the cpu backend BEFORE paddle_tpu
-    # imports jax; only under __main__ (bench/tests import this module
-    # and own their backend choice)
-    os.environ.setdefault("PADDLE_TPU_PLATFORM", "cpu")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

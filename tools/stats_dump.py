@@ -29,7 +29,8 @@ batch rows, bucket hit/miss + padding waste, slot occupancy, admission/
 retirement counters (docs/SERVING.md "Reading the telemetry") — so
 `--grep paddle_serving` is the one-look serving health view.
 
-Diagnosing a wedged TPU tunnel from a sidecar: see docs/OBSERVABILITY.md
+Diagnosing a backend that never came up, from a sidecar: see
+docs/OBSERVABILITY.md
 ("Reading a sidecar post-mortem") — the short version is to look at
 paddle_backend_probe_ok/_seconds first, then the executor cache + step
 counters to see how far init got, then the per-method RPC counters.
@@ -115,7 +116,7 @@ def render_table(snap, show_all=False, grep=None, out=sys.stdout):
                 ))
             else:
                 # gauges always render: a gauge at 0 is a signal
-                # (paddle_backend_probe_ok=0 IS the wedged-tunnel
+                # (paddle_backend_probe_ok=0 IS the failed-backend
                 # diagnosis), only zero counters are noise
                 if not show_all and m["type"] == "counter" \
                         and not s["value"]:
