@@ -26,6 +26,15 @@ def make_generic_grad(fwd_type: str):
     from .registry import OPS
 
     def _grad(ctx, ins: Dict[str, List[Any]], attrs: Dict[str, Any]):
+        # one scope round every grad op's lowering, custom or generic:
+        # whatever the grad op runs again of its forward (the jax.vjp
+        # below re-traces it, and XLA does not merge two Pallas custom
+        # calls) is then told apart, in the HLO and in a device profile,
+        # from the forward op's own run by this string
+        with jax.named_scope(fwd_type + "_grad"):
+            return _lower_grad(ctx, ins, attrs)
+
+    def _lower_grad(ctx, ins, attrs):
         fdef = OPS[fwd_type]
         if fdef.grad_lowering is not None:
             return fdef.grad_lowering(ctx, ins, attrs)

@@ -126,6 +126,11 @@ class Executor:
             run_pserver_loop(ops0[0].attrs, scope, executor=self)
             return []
 
+        with _call_span("run", 1):
+            return self._run(program, feed, fetch_list, scope,
+                             return_numpy)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy):
         plan, feeds, const_state, mut_state, rng = self._gather(
             program, feed, fetch_list, scope)
         from ..observe import observe_feed_gap
@@ -243,6 +248,13 @@ class Executor:
                                          reduce_fetches)
         program = program if program is not None else default_main_program()
         scope = scope if scope is not None else global_scope()
+        with _call_span("run_repeated", steps):
+            return self._run_repeated(program, feed, fetch_list, scope,
+                                      steps, return_numpy, feed_stacked,
+                                      reduce_fetches)
+
+    def _run_repeated(self, program, feed, fetch_list, scope, steps,
+                      return_numpy, feed_stacked, reduce_fetches):
         plan, feeds, const_state, mut_state, rng = self._gather(
             program, feed, fetch_list, scope)
         if feed_stacked:
@@ -806,7 +818,12 @@ class Executor:
 
     def _gather(self, program, feed, fetch_list, scope):
         """Shared run()/cost_analysis() plumbing: feed conversion, plan
-        cache lookup, and state/RNG argument gathering."""
+        cache lookup, and state/RNG argument gathering — the
+        ``executor.gather`` span (``executor.h2d`` nests in it)."""
+        with _tr.trace_span("executor.gather"):
+            return self._gather_args(program, feed, fetch_list, scope)
+
+    def _gather_args(self, program, feed, fetch_list, scope):
         feed = feed or {}
         if feed and _FEED_OBSERVERS:
             # calibration hook (analysis/ranges.Calibration.attach):
@@ -833,8 +850,7 @@ class Executor:
             plan = self._prepare(program, feed_vals, fetch_names, scope)
             # stable within-process tag for this (program, feed-sig,
             # fetch) plan: the trace spans' per-op attribution key
-            plan.sig = "%08x" % (zlib.crc32(repr(key).encode())
-                                 & 0xffffffff)
+            plan.sig = plan_tag(key)
             EXECUTOR_PREPARE_SECONDS.observe(time.perf_counter() - t0)
             self._cache[key] = plan
             while len(self._cache) > self._cache_size:
@@ -952,7 +968,7 @@ class Executor:
 
         t0 = time.perf_counter()
         plan = self._prepare(program, feed_vals, fetch_names, scope)
-        plan.sig = "%08x" % (zlib.crc32(repr(key).encode()) & 0xffffffff)
+        plan.sig = plan_tag(key)
         EXECUTOR_PREPARE_SECONDS.observe(time.perf_counter() - t0)
         self._cache[key] = plan
         while len(self._cache) > self._cache_size:
@@ -967,6 +983,20 @@ def _first_computation(cost) -> dict:
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else None
     return dict(cost or {})
+
+
+def plan_tag(cache_key) -> str:
+    """Stable within-process tag of a plan-cache key: what the
+    ``executor.dispatch`` span carries as ``plan``."""
+    return "%08x" % (zlib.crc32(repr(cache_key).encode()) & 0xffffffff)
+
+
+def _call_span(site, steps):
+    """The ``executor.call`` span of one run()/run_repeated()/
+    ParallelEngine._execute call: parent of the call's phase spans
+    (gather, h2d, place, dispatch, complete, write_back), so its
+    duration less theirs is the host time nobody has named yet."""
+    return _tr.trace_span("executor.call", site=site, steps=steps)
 
 
 @contextlib.contextmanager
@@ -1066,12 +1096,13 @@ def _write_back_state(plan, scope, new_mut, new_pure, new_rng):
     """Post-dispatch scope write-back shared by run()'s _finish and
     _pipelined_loop — the arrays may still be futures; the next dispatch
     chains on them device-side."""
-    for n, v in zip(plan.mut_state, new_mut):
-        scope.set_var(n, v)
-    for n, v in zip(plan.pure_written, new_pure):
-        scope.set_var(n, v)
-    if plan.needs_rng:
-        scope.set_var(RNG_VAR, new_rng)
+    with _tr.trace_span("executor.write_back"):
+        for n, v in zip(plan.mut_state, new_mut):
+            scope.set_var(n, v)
+        for n, v in zip(plan.pure_written, new_pure):
+            scope.set_var(n, v)
+        if plan.needs_rng:
+            scope.set_var(RNG_VAR, new_rng)
 
 
 def _check_fetches_finite(fetch_names, values, suffix=""):

@@ -69,12 +69,18 @@ def assert_mosaic_ok(block_shape, array_shape, what) -> None:
             f"divisible by (8, 128) or equal to the array dims")
 
 
-def checked_pallas_call(kern, *, grid, in_specs, operands, out_specs,
+def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
                         out_shape, scratch_shapes, interpret):
     """``pl.pallas_call`` with the Mosaic legality mirror applied to every
     operand/output spec first, and shard_map vma propagation (outputs
     vary over every mesh axis an operand does — ring attention runs the
-    flash kernels per shard)."""
+    flash kernels per shard).
+
+    ``name`` is required: it is the string by which the kernel is found
+    in a device profile and in the lowered HLO (Pallas enters a
+    ``jax.named_scope(name)`` round the call and hands Mosaic the same
+    ``kernel_name``), so it has to be one the call site chose and not
+    whatever autodiff wrapper happens to surround it."""
     from jax.experimental import pallas as pl
 
     single_out = not isinstance(out_specs, (list, tuple))
@@ -92,7 +98,7 @@ def checked_pallas_call(kern, *, grid, in_specs, operands, out_specs,
     return pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch_shapes,
-        interpret=interpret)(*operands)
+        interpret=interpret, name=name)(*operands)
 
 
 def ceil_to(n: int, b: int) -> int:

@@ -123,6 +123,7 @@ def _forward_pallas(cfg, x, r, scale, bias, eps):
     col = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     y, s, mean, var = checked_pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layernorm_residual_fwd",
         grid=(nb,),
         in_specs=[row, row, vec, vec],
         operands=[xp, rp, sc2, b2],
@@ -182,6 +183,7 @@ def _backward_pallas(cfg, s, mean, var, scale, gy, gres, eps):
     col = pl.BlockSpec((bn, 1), lambda i: (i, 0))
     dx, dsc, db = checked_pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="layernorm_residual_bwd",
         grid=(nb,),
         in_specs=[row, col, col, vec, row, row],
         operands=[sp, mp, vp, sc2, gyp, grp],

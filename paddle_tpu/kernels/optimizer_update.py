@@ -125,7 +125,7 @@ def _to2d(a, n):
     return flat.reshape(rows, _LANES)
 
 
-def _sweep(kern, cfg, flats, n, dtype, n_out):
+def _sweep(kern, name, cfg, flats, n, dtype, n_out):
     (br,) = cfg
     rows = ceil_to(max(n, 1), _LANES) // _LANES
     rp = pad_len(rows, br)
@@ -135,6 +135,7 @@ def _sweep(kern, cfg, flats, n, dtype, n_out):
     row = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
     outs = checked_pallas_call(
         kern,
+        name=name,
         grid=(rp // br,),
         in_specs=[row] * len(ops2d),
         operands=ops2d,
@@ -172,7 +173,8 @@ def adam_update(cfg, p, g, m, v, lrt, lrwd, *, beta1=0.9, beta2=0.999,
     kern = functools.partial(
         _adam_kernel, beta1=beta1, beta2=beta2, epsilon=epsilon,
         weight_decay=weight_decay)
-    return _sweep(kern, cfg, [p, g, m, v, lrt, lrwd], p.size, p.dtype, 3)
+    return _sweep(kern, "adam_sweep", cfg, [p, g, m, v, lrt, lrwd],
+                  p.size, p.dtype, 3)
 
 
 def sweep_group(cfg, kind, ins, hyper):
@@ -240,7 +242,8 @@ def sgd_update(cfg, p, g, lrv):
     1-tuple ``(p_new,)`` to mirror the fallback's pytree. No grad path —
     optimizer ops are ``no_grad`` by contract."""
     cfg = tuple(cfg) if cfg else (128,)
-    return _sweep(_sgd_kernel, cfg, [p, g, lrv], p.size, p.dtype, 1)
+    return _sweep(_sgd_kernel, "sgd_sweep", cfg, [p, g, lrv], p.size,
+                  p.dtype, 1)
 
 
 # ---------------------------------------------------- registry entries

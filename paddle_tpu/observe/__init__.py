@@ -4,9 +4,9 @@ The observability layer SURVEY §5's host-profiler only half covers:
 ``profiler.py`` answers "where did the time go" during an explicitly
 started profiling session; this package answers "what has the process
 done so far" at ANY moment — counters/gauges/histograms every hot
-subsystem updates unconditionally, plus span tracing that composes
-with ``profiler.RecordEvent`` so spans land in the same chrome-trace
-timeline when a session IS active.
+subsystem updates unconditionally, plus span tracing (``trace_span``:
+the flight recorder's ring, and the host plane of any ``jax.profiler``
+trace being taken).
 
     from paddle_tpu import observe
 
@@ -16,8 +16,8 @@ timeline when a session IS active.
 
     C = observe.counter("my_events_total", "what it counts")
     C.inc()
-    with observe.span("my_phase"):
-        ...                       # timed + chrome-traced
+    with observe.trace_span("executor.call"):
+        ...                       # a declared site (families.TRACE_SITES)
 
 `tools/stats_dump.py` pretty-prints a live or saved snapshot; bench.py
 drops a ``BENCH_<workload>.telemetry.json`` sidecar per row (including
@@ -38,8 +38,7 @@ from .promparse import ParseError, parse_prometheus  # noqa: F401
 from .shutdown import (install_shutdown_handlers,  # noqa: F401
                        uninstall_shutdown_handlers)
 from .slo import Breach, Objective, SloMonitor  # noqa: F401
-from .spans import (Span, mark_batch_produced,  # noqa: F401
-                    observe_feed_gap, span)
+from .spans import mark_batch_produced, observe_feed_gap  # noqa: F401
 from .timeseries import Ewma, TimeSeriesStore  # noqa: F401
 from .trace import (FlightRecorder, TraceContext, attach,  # noqa: F401
                     current, dump_flight_recorder, export_chrome_trace,
@@ -48,7 +47,7 @@ from .trace import (FlightRecorder, TraceContext, attach,  # noqa: F401
 
 __all__ = ["REGISTRY", "counter", "gauge", "histogram", "get_metric",
            "snapshot", "render_prometheus", "dump", "reset",
-           "span", "Span", "mark_batch_produced", "observe_feed_gap",
+           "mark_batch_produced", "observe_feed_gap",
            "Counter", "Gauge", "Histogram", "Family", "Registry",
            "DEFAULT_BUCKETS", "quantile_from_buckets",
            "TraceContext", "FlightRecorder", "trace_enabled", "new_trace",

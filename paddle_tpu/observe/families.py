@@ -277,6 +277,18 @@ SERVING_OCCUPANCY = REGISTRY.histogram(
     "effective batch efficiency; admissions raise it mid-run, "
     "retirements lower it (a lockstep batcher would hold the initial "
     "ratio until the LONGEST request finished)")
+SERVING_TTFT_SECONDS = REGISTRY.histogram(
+    "paddle_serving_ttft_seconds",
+    "Time to first token, one observation a request: submit to the end "
+    "of its admission (queue wait + prefill + splice + first sample), "
+    "when the first token exists on the host. The duration of the "
+    "request's serving.request.first_token span")
+SERVING_TOKEN_GAP_SECONDS = REGISTRY.histogram(
+    "paddle_serving_token_gap_seconds",
+    "Gap between tokens, one observation a decode step: this step's end "
+    "minus the previous emission's end on this engine (the previous "
+    "step, or an admission in between) — the gap every rider of both "
+    "saw, admissions that held the batch up included")
 SERVING_ADMITTED = REGISTRY.counter(
     "paddle_serving_slots_admitted_total",
     "Sequences admitted into a free decode slot (prefill-then-insert)")
@@ -922,20 +934,9 @@ for _op in _KERNEL_OPS:
         KERNEL_WINNERS.labels(op=_op, choice=_c)
         KERNEL_DISPATCHES.labels(op=_op, impl=_c)
 
-# ----------------------------------------------------------------- spans
-SPAN_SECONDS = REGISTRY.histogram(
-    "paddle_span_seconds",
-    "Generic named-span latency (spans without a dedicated histogram)",
-    labels=("span",))
-
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see
 # docs/OBSERVABILITY.md "Trace propagation")
-TRACE_EVENTS = REGISTRY.counter(
-    "paddle_trace_events_recorded_total",
-    "Events appended to the flight-recorder ring (begin/end/instant); "
-    "stays 0 when PADDLE_TPU_TRACE=0 — the disabled-tracing no-op test "
-    "pins exactly that")
 TRACE_DUMPS = REGISTRY.counter(
     "paddle_trace_flight_dumps_total",
     "Flight-recorder dumps written, by trigger ('signal' = the "
@@ -950,9 +951,16 @@ for _r in ("wedge", "crash", "atexit", "manual", "signal"):
 # otherwise fragment a trace across names tools/trace_view.py can't
 # group. Grammar: <subsystem>.<noun-or-phase>, dotted lowercase.
 TRACE_SITES = (
-    # executor (core/executor.py): one dispatch span per step, tagged
-    # with the plan-cache signature; complete = the host block on results
-    "executor.dispatch", "executor.complete", "executor.h2d",
+    # executor (core/executor.py, parallel/engine.py): one
+    # executor.call per run()/run_repeated()/ParallelEngine._execute,
+    # parent of the host's phases in it: gather (plan + state lookup,
+    # feed conversion; h2d nests in it), place (the mesh engine's
+    # device_put onto shardings), dispatch (tagged with the plan-cache
+    # signature), complete (the host block on results), write_back (new
+    # state into the scope). The call's time less its children's is what
+    # is still unnamed
+    "executor.call", "executor.gather", "executor.h2d", "executor.place",
+    "executor.dispatch", "executor.complete", "executor.write_back",
     # pipelined input (core/pipeline.py): fill-thread spans under the
     # loop context handed off explicitly by run_pipelined
     "pipeline.prefetch", "pipeline.const_lookup",
@@ -965,7 +973,8 @@ TRACE_SITES = (
     "serving.engine.admit", "serving.engine.prefill",
     "serving.engine.suffix_prefill", "serving.engine.splice",
     "serving.engine.step", "serving.engine.spec",
-    "serving.engine.retire",
+    "serving.engine.feeds", "serving.engine.sample",
+    "serving.engine.retire", "serving.request.first_token",
     "serving.router.route", "serving.router.drain",
     "serving.router.readmit",
     # rpc (distributed/rpc.py): client call spans; server events linked

@@ -1,13 +1,7 @@
-"""Step spans: timed scopes that feed BOTH the metrics registry and the
-profiler's chrome-trace timeline.
-
-A ``span`` is the composition the ISSUE prescribes: entering one starts
-a ``profiler.RecordEvent`` (so when the profiler is on, the span lands
-in the same aggregated event table and chrome://tracing JSON as every
-other host annotation) and, on exit, ALWAYS records the elapsed time
-into a histogram — metrics accumulate whether or not a profiling
-session is active. Instrumented call sites therefore never need two
-wrappers.
+"""The feed-to-run gap stamp: how long a produced batch waited for the
+executor. (Timed scopes are ``observe.trace.trace_span``: one span type,
+recorded in the flight recorder and, under a ``jax.profiler`` trace, in
+the profile's host plane.)
 """
 
 from __future__ import annotations
@@ -15,53 +9,9 @@ from __future__ import annotations
 import threading
 import time
 
-from .families import REGISTRY, SPAN_SECONDS  # noqa: F401  (REGISTRY is
-#   re-exported for span() declarers; the span family itself is declared
-#   in families.py so every family name lives in one module — the
-#   tools/repo_lint.py contract)
+from .families import FEED_TO_RUN_GAP_SECONDS
 
-__all__ = ["Span", "span", "mark_batch_produced", "observe_feed_gap"]
-
-
-class Span:
-    """Context manager: chrome-trace annotation + latency histogram.
-
-    ``histogram``: a Histogram child/family to record into (defaults to
-    the generic ``paddle_span_seconds{span=<name>}`` series).
-    ``counter``: optional Counter child/family inc'd once per exit.
-    """
-
-    __slots__ = ("name", "_hist", "_counter", "_t0", "_rec")
-
-    def __init__(self, name: str, histogram=None, counter=None):
-        self.name = name
-        self._hist = histogram
-        self._counter = counter
-        self._t0 = None
-        self._rec = None
-
-    def __enter__(self):
-        from ..profiler import RecordEvent
-
-        self._rec = RecordEvent(self.name)
-        self._rec.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._rec.__exit__(*exc)
-        self._rec = None
-        hist = self._hist if self._hist is not None \
-            else SPAN_SECONDS.labels(span=self.name)
-        hist.observe(dt)
-        if self._counter is not None:
-            self._counter.inc()
-        return False
-
-
-def span(name: str, histogram=None, counter=None) -> Span:
-    return Span(name, histogram=histogram, counter=counter)
+__all__ = ["mark_batch_produced", "observe_feed_gap"]
 
 
 # ------------------------------------------------------- feed-to-run gap
@@ -76,8 +26,6 @@ def span(name: str, histogram=None, counter=None) -> Span:
 # hand-off between stamp and observe, recording a gap against the wrong
 # batch. Thread-wrapping readers re-stamp at hand-off in the consumer.
 _batch_stamp = threading.local()
-
-from .families import FEED_TO_RUN_GAP_SECONDS  # noqa: E402
 
 
 def mark_batch_produced() -> None:

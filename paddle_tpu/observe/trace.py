@@ -30,6 +30,17 @@ it died*. Two pieces:
   chrome-trace; ``export_chrome_trace()`` merges the ring with the
   profiler's host timeline when a profiling session ran.
 
+* **The profiler's clock** — a span is also a
+  ``jax.profiler.TraceAnnotation`` of its site name. Whenever anyone is
+  taking a ``jax.profiler`` trace (``jax.profiler.trace``,
+  ``profiler.start_profiler``, a benchmark harness), every span entered
+  on any thread lands in the host plane of the same ``.xplane.pb`` as the
+  device operations, nested as in the ring; with no trace being taken an
+  annotation is a flag check inside the profiler. Retroactive spans
+  (``record_span``) and instant events have no annotation: the profiler
+  cannot be told of a time that has passed. ``jax`` is imported on the
+  first span, so this package stays importable without it.
+
 Event grammar (one dict per event in dumps; tuples in the ring):
 
     {"t": perf_counter_s, "ph": "B"|"E"|"I", "site": <TRACE_SITES name>,
@@ -64,7 +75,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from .families import TRACE_DUMPS, TRACE_EVENTS, TRACE_SITES  # noqa: F401
+from .families import TRACE_DUMPS, TRACE_SITES  # noqa: F401
 
 __all__ = ["TraceContext", "FlightRecorder", "NOOP", "trace_enabled",
            "set_trace_enabled", "new_trace", "current", "attach",
@@ -150,7 +161,6 @@ class FlightRecorder:
             self._ring.append((t, ph, site, trace_id, span_id, parent_id,
                                tid, dur, attrs))
             self._recorded += 1
-        TRACE_EVENTS.inc()
 
     def __len__(self) -> int:
         with self._lock:
@@ -293,15 +303,31 @@ class _NoopSpan:
 
 NOOP = _NoopSpan()
 
+# jax.profiler.TraceAnnotation, looked up on the first span (False until
+# then; None where jax cannot be imported: spans then go to the ring only)
+_ANNOTATION: Any = False
+
+
+def _annotation_type():
+    global _ANNOTATION
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 — observe works without jax
+        TraceAnnotation = None
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
+
 
 class Span:
     """A recorded span: ``B`` event at enter (so a dispatch that never
     returns is still visible in a dump as an OPEN span), ``E`` with the
     duration at exit. Entering installs the span's context thread-local
     so nested spans/events parent to it; ``attrs`` is mutable until exit
-    (schedulers attach e.g. the per-step active trace list late)."""
+    (schedulers attach e.g. the per-step active trace list late). The
+    span is a profiler annotation of its site name for the same stretch
+    (module doc, "The profiler's clock")."""
 
-    __slots__ = ("site", "ctx", "parent", "attrs", "_t0", "_prev")
+    __slots__ = ("site", "ctx", "parent", "attrs", "_t0", "_prev", "_ann")
 
     def __init__(self, site: str, parent: Optional[TraceContext],
                  attrs: Optional[dict]):
@@ -317,6 +343,12 @@ class Span:
     def __enter__(self) -> "Span":
         self._prev = getattr(_tls, "ctx", None)
         _tls.ctx = self.ctx
+        ann = _ANNOTATION if _ANNOTATION is not False \
+            else _annotation_type()
+        if ann is not None:
+            ann = ann(self.site)
+            ann.__enter__()
+        self._ann = ann
         self._t0 = time.perf_counter()
         RECORDER.record(self._t0, "B", self.site, self.ctx.trace_id,
                         self.ctx.span_id, self.parent,
@@ -330,6 +362,8 @@ class Span:
                         self.ctx.span_id, self.parent,
                         threading.get_ident(), dur=t1 - self._t0,
                         attrs=self.attrs or None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         _tls.ctx = self._prev
         return False
 

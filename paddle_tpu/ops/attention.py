@@ -332,7 +332,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         lse_ref[0] = m_ref[...] + jnp.log(l)  # [bq, 1]
 
 
-def _forward_pallas(q, k, v, bias, scale, causal=False):
+# The names under which the four kernel runs of a layer are found in a
+# device profile and in the HLO: XLA names a Pallas custom call after the
+# innermost name scope (``flash_fwd.3``; under autodiff
+# ``jvp_flash_refwd_.3``, ``jvp_flash_bwd_dkv_.3``), and only that reaches
+# the profiler's event. The forward kernel runs twice a layer in a train
+# step: once in the forward op, and again when the grad op differentiates
+# the forward lowering (``jax.vjp`` runs the ``custom_vjp`` forward rule
+# for its residuals; XLA does not merge two custom calls) — the rule's run
+# carries its own name so a trace can say whether it went away.
+KERNEL_FWD = "flash_fwd"
+KERNEL_REFWD = "flash_refwd"
+KERNEL_BWD_DKV = "flash_bwd_dkv"
+KERNEL_BWD_DQ = "flash_bwd_dq"
+
+
+def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD):
     B, H, S, D = q.shape
     Sk = k.shape[2]
     if causal and S != Sk:
@@ -369,6 +384,7 @@ def _forward_pallas(q, k, v, bias, scale, causal=False):
 
     out, lse = _checked_pallas_call(
         kern,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         operands=operands,
@@ -544,6 +560,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
             jax.ShapeDtypeStruct((B * H, Sp, Skp), jnp.float32))
     res = _checked_pallas_call(
         dkv_kern,
+        name=KERNEL_BWD_DKV,
         grid=(B * H, nk, nq),
         in_specs=in_specs,
         operands=operands,
@@ -587,6 +604,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     operands += [gf, lse3, delta]
     dq = _checked_pallas_call(
         kern,
+        name=KERNEL_BWD_DQ,
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         operands=operands,
@@ -630,7 +648,8 @@ def _fa_maskbias(q, k, v, bias, scale, causal=False):
 
 
 def _fa_maskbias_fwd(q, k, v, bias, scale, causal=False):
-    out, lse = _forward_pallas(q, k, v, bias, scale, causal=causal)
+    out, lse = _forward_pallas(q, k, v, bias, scale, causal=causal,
+                               name=KERNEL_REFWD)
     return out, (q, k, v, bias, out, lse)
 
 
@@ -655,7 +674,7 @@ def _fa_trainbias(q, k, v, bias, scale):
 
 
 def _fa_trainbias_fwd(q, k, v, bias, scale):
-    out, lse = _forward_pallas(q, k, v, bias, scale)
+    out, lse = _forward_pallas(q, k, v, bias, scale, name=KERNEL_REFWD)
     return out, (q, k, v, bias, out, lse)
 
 
@@ -676,7 +695,8 @@ def _fa_with_lse(q, k, v, bias, scale, causal=False):
 
 
 def _fa_with_lse_fwd(q, k, v, bias, scale, causal=False):
-    out, lse = _forward_pallas(q, k, v, bias, scale, causal=causal)
+    out, lse = _forward_pallas(q, k, v, bias, scale, causal=causal,
+                               name=KERNEL_REFWD)
     return (out, lse), (q, k, v, bias, out, lse)
 
 

@@ -24,12 +24,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.executor import (RNG_VAR, Executor, _feed_to_device,
-                             analyze_block, make_scan_fn,
+from ..core.executor import (RNG_VAR, Executor, _call_span,
+                             _dispatch_guard, _feed_to_device,
+                             analyze_block, make_scan_fn, plan_tag,
                              unstack_singleton_feed,
                              validate_stacked_feeds)
 from ..core.program import Program, Variable
 from ..core.scope import Scope, global_scope
+from ..observe import trace as _tr
 from .sharding import ShardingRules
 
 __all__ = ["ParallelEngine", "make_mesh"]
@@ -62,6 +64,11 @@ class _ParallelPlan:
         self.step = None   # raw (unjitted) step — run_repeated scans it
         self.multi = {}    # (steps, feed_stacked) -> jitted K-step fn
         self.feed_shapes = {}  # name -> shape the plan was prepared with
+        # what Executor's _dispatch_guard reads of a plan: the tag the
+        # executor.dispatch span carries, and the signatures dispatched
+        # before (a first dispatch compiles: the watchdog's longer grace)
+        self.sig = None
+        self.compiled_sigs = set()
 
 
 class ParallelEngine:
@@ -91,13 +98,14 @@ class ParallelEngine:
     def run(self, feed, fetch_list, scope: Optional[Scope] = None,
             return_numpy: bool = True):
         scope = scope if scope is not None else global_scope()
-        plan, feeds, const_state, mut_state, rng = self._gather(
-            feed, fetch_list, scope)
-        return self._execute(plan, plan.fn,
-                             [plan.feed_shardings[n]
-                              for n in plan.feed_names],
-                             feeds, const_state, mut_state, rng, scope,
-                             return_numpy, "", "engine_run", steps=1)
+        with _call_span("run", 1):
+            plan, feeds, const_state, mut_state, rng = self._gather(
+                feed, fetch_list, scope)
+            return self._execute(plan, plan.fn,
+                                 [plan.feed_shardings[n]
+                                  for n in plan.feed_names],
+                                 feeds, const_state, mut_state, rng, scope,
+                                 return_numpy, "", "engine_run", steps=1)
 
     def run_repeated(self, feed, fetch_list, scope: Optional[Scope] = None,
                      steps: int = 1, return_numpy: bool = True,
@@ -122,17 +130,18 @@ class ParallelEngine:
             if feed_stacked:
                 feed = unstack_singleton_feed(feed)
             return self.run(feed, fetch_list, scope, return_numpy)
-        plan, feeds, const_state, mut_state, rng = self._gather(
-            feed, fetch_list, scope)
-        if feed_stacked:
-            validate_stacked_feeds(plan.feed_names, feeds, steps)
-        fn, feed_in = self._multi_fn(plan, steps, feed_stacked,
-                                     reduce_fetches)
-        return self._execute(plan, fn, feed_in, feeds, const_state,
-                             mut_state, rng, scope, return_numpy,
-                             " after %d scanned steps" % steps,
-                             "engine_run_repeated[%d]" % steps,
-                             steps=steps)
+        with _call_span("run_repeated", steps):
+            plan, feeds, const_state, mut_state, rng = self._gather(
+                feed, fetch_list, scope)
+            if feed_stacked:
+                validate_stacked_feeds(plan.feed_names, feeds, steps)
+            fn, feed_in = self._multi_fn(plan, steps, feed_stacked,
+                                         reduce_fetches)
+            return self._execute(plan, fn, feed_in, feeds, const_state,
+                                 mut_state, rng, scope, return_numpy,
+                                 " after %d scanned steps" % steps,
+                                 "engine_run_repeated[%d]" % steps,
+                                 steps=steps)
 
     def _multi_fn(self, plan, steps, feed_stacked,
                   reduce_fetches="last"):
@@ -184,9 +193,13 @@ class ParallelEngine:
                  steps=1):
         """Place inputs per their shardings (feeds split over the data
         axis, state per its spec), run one compiled dispatch, write the
-        new state back to the scope. The epilogue (state write-back,
-        numpy conversion, FLAGS_check_nan_inf) is the Executor's — the
-        mesh path must not lose the NaN tripwire the plain path has."""
+        new state back to the scope. The dispatch goes through the
+        Executor's guard (heartbeat, ``executor.dispatch`` fault point
+        and span: a wedged mesh dispatch must be as visible to the
+        watchdog as a one-chip one), and the epilogue (state write-back,
+        numpy conversion, FLAGS_check_nan_inf) is the Executor's too —
+        the mesh path must not lose the NaN tripwire the plain path
+        has."""
         from ..observe import observe_feed_gap
         from ..observe.families import (ENGINE_DISPATCHES,
                                         ENGINE_RUN_SECONDS, EXECUTOR_STEPS)
@@ -196,30 +209,43 @@ class ParallelEngine:
         ENGINE_DISPATCHES.labels(site=site).inc()
         EXECUTOR_STEPS.inc(steps)
         t_dispatch = time.perf_counter()
-        feeds = [jax.device_put(v, s)
-                 for v, s in zip(feeds, feed_shardings)]
-        const_state = [
-            jax.device_put(v, plan.state_shardings[n])
-            for n, v in zip(plan.const_state, const_state)
-        ]
-        mut_state = [
-            jax.device_put(v, plan.state_shardings[n])
-            for n, v in zip(plan.mut_state, mut_state)
-        ]
-        rng = jax.device_put(rng, NamedSharding(self.mesh, P()))
+        with _tr.trace_span("executor.place") as sp:
+            moved = [0, 0]  # arrays, bytes not yet where they belong
+
+            def put(v, sharding):
+                if sp.attrs is not None \
+                        and getattr(v, "sharding", None) != sharding:
+                    moved[0] += 1
+                    moved[1] += int(getattr(v, "nbytes", 0))
+                return jax.device_put(v, sharding)
+
+            feeds = [put(v, s) for v, s in zip(feeds, feed_shardings)]
+            const_state = [put(v, plan.state_shardings[n])
+                           for n, v in zip(plan.const_state, const_state)]
+            mut_state = [put(v, plan.state_shardings[n])
+                         for n, v in zip(plan.mut_state, mut_state)]
+            rng = put(rng, NamedSharding(self.mesh, P()))
+            if sp.attrs is not None:
+                sp.attrs["arrays"], sp.attrs["bytes"] = moved
 
         from ..profiler import RecordEvent, is_profiler_enabled
 
+        # one executable per jitted fn of a plan: its first dispatch
+        # compiles, which the heartbeat tells the watchdog
+        sig = (site, fn)
         if is_profiler_enabled():
             with RecordEvent(event):
-                fetches, new_mut, new_pure, new_rng = fn(
-                    feeds, const_state, mut_state, rng)
+                with _dispatch_guard(plan, sig):
+                    fetches, new_mut, new_pure, new_rng = fn(
+                        feeds, const_state, mut_state, rng)
                 fetches = [f.block_until_ready()
                            if hasattr(f, "block_until_ready") else f
                            for f in fetches]
         else:
-            fetches, new_mut, new_pure, new_rng = fn(
-                feeds, const_state, mut_state, rng)
+            with _dispatch_guard(plan, sig):
+                fetches, new_mut, new_pure, new_rng = fn(
+                    feeds, const_state, mut_state, rng)
+        plan.compiled_sigs.add(sig)
         ENGINE_RUN_SECONDS.labels(site=site).observe(
             time.perf_counter() - t_dispatch)
         return Executor._finish(plan, scope, fetches, new_mut, new_pure,
@@ -266,7 +292,12 @@ class ParallelEngine:
     def _gather(self, feed, fetch_list, scope):
         """Shared run()/lowered_hlo() plumbing: feed conversion, plan
         cache lookup, state/RNG gathering (host-side values; run() then
-        device_puts them per the plan's shardings)."""
+        device_puts them per the plan's shardings) — the
+        ``executor.gather`` span."""
+        with _tr.trace_span("executor.gather"):
+            return self._gather_args(feed, fetch_list, scope)
+
+    def _gather_args(self, feed, fetch_list, scope):
         feed = feed or {}
         fetch_names = [
             v.name if isinstance(v, Variable) else str(v)
@@ -281,6 +312,7 @@ class ParallelEngine:
         plan = self._cache.get(key)
         if plan is None:
             plan = self._prepare(feed_vals, fetch_names, scope)
+            plan.sig = plan_tag(key)
             self._cache[key] = plan
         feeds = [feed_vals[n] for n in plan.feed_names]
         const_state = [_require(scope, n) for n in plan.const_state]

@@ -507,6 +507,10 @@ class DecodeEngine:
         # declares this engine wedged when it holds active slots and
         # the stamp goes stale (serving/router.py)
         self.last_progress = time.monotonic()
+        # perf_counter at the end of the last emission (a decode step or
+        # an admission's first token): the next step's end less this is
+        # the token gap its riders saw
+        self._last_emit: Optional[float] = None
 
     @classmethod
     def from_artifact(cls, artifact, **overrides) -> "DecodeEngine":
@@ -753,7 +757,8 @@ class DecodeEngine:
             block = False  # drain without blocking once something runs
 
     def _admit_one(self, slot_idx: int, req) -> None:
-        from ..observe.families import (SERVING_ADMITTED, SERVING_TOKENS)
+        from ..observe.families import (SERVING_ADMITTED, SERVING_TOKENS,
+                                        SERVING_TTFT_SECONDS)
 
         p = req.payload
         slot = _Slot(req, p["prompt"], p["n_new"], p["eos_id"],
@@ -773,13 +778,25 @@ class DecodeEngine:
                     slot_idx, p["prompt"],
                     prefix_store=self.prefix_store,
                     prefix_len=p.get("prefix_len"))
-                first = slot.sample(last)
+                with _tr.trace_span("serving.engine.sample", active=1):
+                    first = slot.sample(last)
                 if slot.spec and not slot.finished(first):
                     # mirror the prompt into the draft lane's slot so
                     # drafting starts cache-aligned with the target
                     # (the draft never consults the prefix store: its
                     # rows would be a different model's)
                     self._draft.prefill_insert(slot_idx, p["prompt"])
+        # the first token exists on the host: time to first token, from
+        # submit, under the request's own trace (a retroactive span, so
+        # it is in the ring and not among the profiler's annotations)
+        self._last_emit = time.perf_counter()
+        ttft = self._last_emit - req.submitted_perf
+        SERVING_TTFT_SECONDS.observe(ttft)
+        if req.trace is not None:
+            _tr.record_span("serving.request.first_token",
+                            req.submitted_perf, ttft, ctx=req.trace,
+                            prompt_len=len(p["prompt"]),
+                            queued_s=req.queued_s)
         SERVING_ADMITTED.inc()
         SERVING_TOKENS.inc()
         slot.tokens.append(first)
@@ -792,7 +809,8 @@ class DecodeEngine:
 
     # ------------------------------------------------------------- steps
     def _step(self) -> None:
-        from ..observe.families import SERVING_OCCUPANCY
+        from ..observe.families import (SERVING_OCCUPANCY,
+                                        SERVING_TOKEN_GAP_SECONDS)
 
         active = [i for i, s in enumerate(self._slots) if s is not None]
         SERVING_OCCUPANCY.observe(len(active) / float(self.b_max))
@@ -809,15 +827,20 @@ class DecodeEngine:
         else:
             self._plain_step(active,
                              advance_draft=bool(spec_slots))
+        now = time.perf_counter()
+        if self._last_emit is not None:
+            SERVING_TOKEN_GAP_SECONDS.observe(now - self._last_emit)
+        self._last_emit = now
 
     def _feeds(self, active):
-        token = np.zeros((self.b_max, 1), dtype="int64")
-        pos = np.zeros((self.b_max, 1), dtype="int64")
-        for i in active:
-            slot = self._slots[i]
-            token[i, 0] = slot.tokens[-1]
-            pos[i, 0] = len(slot.tokens) - 1
-        return token, pos
+        with _tr.trace_span("serving.engine.feeds"):
+            token = np.zeros((self.b_max, 1), dtype="int64")
+            pos = np.zeros((self.b_max, 1), dtype="int64")
+            for i in active:
+                slot = self._slots[i]
+                token[i, 0] = slot.tokens[-1]
+                pos[i, 0] = len(slot.tokens) - 1
+            return token, pos
 
     def _step_span(self, site, active):
         # one span per continuous-batching step under the engine thread;
@@ -840,10 +863,13 @@ class DecodeEngine:
                                         SERVING_SPEC_DRAFT_STEPS,
                                         SERVING_TOKENS)
 
-        token, pos = self._feeds(active)
-        # free slots keep token 0 at pos 0: the write lands in a row
+        # the step span holds all the host does for one token a rider:
+        # feeds, the Executor's phases (gather, h2d, dispatch, complete,
+        # write_back nest in it by the thread's context) and sampling.
+        # Free slots keep token 0 at pos 0: the write lands in a row
         # nobody reads (masked, and the next prefill-insert overwrites)
         with self._step_span("serving.engine.step", active):
+            token, pos = self._feeds(active)
             logits = self._lane.decode(token, pos)
             if advance_draft and self._draft is not None:
                 # keep the draft lane's caches mirror-aligned through
@@ -854,14 +880,16 @@ class DecodeEngine:
                 SERVING_SPEC_DRAFT_STEPS.inc()
             SERVING_DECODE_STEPS.inc()
             SERVING_TOKENS.inc(len(active))
-            for i in active:
-                slot = self._slots[i]
-                tok = slot.sample(logits[i, 0])
-                slot.tokens.append(tok)
-                if slot.finished(tok):
-                    self._slots[i] = None
-                    self._n_active -= 1
-                    self._retire(i, slot)
+            with _tr.trace_span("serving.engine.sample",
+                                active=len(active)):
+                for i in active:
+                    slot = self._slots[i]
+                    tok = slot.sample(logits[i, 0])
+                    slot.tokens.append(tok)
+                    if slot.finished(tok):
+                        self._slots[i] = None
+                        self._n_active -= 1
+                        self._retire(i, slot)
             self._set_active_gauge()
 
     def _spec_step(self, active, spec_slots) -> None:
@@ -917,43 +945,45 @@ class DecodeEngine:
             SERVING_SPEC_VERIFY_STEPS.inc()
             SERVING_SPEC_PROPOSED.inc(k * len(spec_slots))
             appended = 0
-            for i in active:
-                slot = self._slots[i]
-                if i not in drafts:
-                    # plain rider: position 0 IS its plain step
-                    tok = slot.sample(logits[i, 0])
-                    slot.tokens.append(tok)
-                    appended += 1
-                    if slot.finished(tok):
-                        self._slots[i] = None
-                        self._n_active -= 1
-                        self._retire(i, slot)
-                    continue
-                accepted = 0
-                for s in range(k + 1):
-                    # row s is valid iff every draft before it matched
-                    # the target's argmax chain — walked in order, so
-                    # reaching s proves it
-                    tok = slot.sample(logits[i, s])
-                    slot.tokens.append(tok)
-                    appended += 1
-                    matched = s < k and tok == drafts[i][s]
-                    if matched:
-                        # count BEFORE the finished-break: a drafted
-                        # EOS / final-budget token the verification
-                        # confirmed is an acceptance, not a drop —
-                        # accept_rate is THE switch-the-draft-off
-                        # signal and must not systematically undercount
-                        # request tails
-                        accepted += 1
-                    if slot.finished(tok):
-                        self._slots[i] = None
-                        self._n_active -= 1
-                        self._retire(i, slot)
-                        break
-                    if s < k and not matched:
-                        break  # mismatch: the draft chain is dead
-                SERVING_SPEC_ACCEPTED.inc(accepted)
+            with _tr.trace_span("serving.engine.sample",
+                                active=len(active)):
+                for i in active:
+                    slot = self._slots[i]
+                    if i not in drafts:
+                        # plain rider: position 0 IS its plain step
+                        tok = slot.sample(logits[i, 0])
+                        slot.tokens.append(tok)
+                        appended += 1
+                        if slot.finished(tok):
+                            self._slots[i] = None
+                            self._n_active -= 1
+                            self._retire(i, slot)
+                        continue
+                    accepted = 0
+                    for s in range(k + 1):
+                        # row s is valid iff every draft before it matched
+                        # the target's argmax chain — walked in order, so
+                        # reaching s proves it
+                        tok = slot.sample(logits[i, s])
+                        slot.tokens.append(tok)
+                        appended += 1
+                        matched = s < k and tok == drafts[i][s]
+                        if matched:
+                            # count BEFORE the finished-break: a drafted
+                            # EOS / final-budget token the verification
+                            # confirmed is an acceptance, not a drop —
+                            # accept_rate is THE switch-the-draft-off
+                            # signal and must not systematically undercount
+                            # request tails
+                            accepted += 1
+                        if slot.finished(tok):
+                            self._slots[i] = None
+                            self._n_active -= 1
+                            self._retire(i, slot)
+                            break
+                        if s < k and not matched:
+                            break  # mismatch: the draft chain is dead
+                    SERVING_SPEC_ACCEPTED.inc(accepted)
             SERVING_TOKENS.inc(appended)
             self._set_active_gauge()
 

@@ -67,7 +67,8 @@ class ServingRequest:
     scheduler set); ``cancel()`` succeeds only while still queued.
     """
 
-    __slots__ = ("payload", "rows", "submitted_at", "deadline", "trace",
+    __slots__ = ("payload", "rows", "submitted_at", "submitted_perf",
+                 "queued_s", "deadline", "trace",
                  "tenant", "report", "_lock", "_event", "_state",
                  "_value", "_exc", "_callbacks", "_finished")
 
@@ -88,6 +89,11 @@ class ServingRequest:
         # request, not per attempt
         self.report = bool(report)
         self.submitted_at = time.monotonic()
+        # the same instant on the clock the flight recorder stamps with:
+        # serving.queue.wait and serving.request.first_token start here.
+        # queued_s is the wait, known once the scheduler pops the request
+        self.submitted_perf = time.perf_counter()
+        self.queued_s: Optional[float] = None
         self.deadline = (self.submitted_at + deadline_s
                          if deadline_s is not None else None)
         # one trace per request, born at submit and pinned on the object
@@ -342,12 +348,13 @@ class RequestQueue:
                         continue
                     if not req._admit():
                         continue        # cancel raced the pop and won
-                    wait = time.monotonic() - req.submitted_at
+                    wait = time.perf_counter() - req.submitted_perf
+                    req.queued_s = wait
                     SERVING_QUEUE_WAIT_SECONDS.observe(wait)
                     if req.trace is not None:
                         # retroactive span: the wait is only known now
                         _tr.record_span("serving.queue.wait",
-                                        time.perf_counter() - wait, wait,
+                                        req.submitted_perf, wait,
                                         ctx=req.trace)
                     return req
                 if self._closed:

@@ -118,7 +118,7 @@ def test_histogram_quantile_shared_helper():
     assert om.quantile_from_buckets(
         dict(child.cumulative_buckets()), child.count, 0.5) == q50
     # +Inf overflow reports the highest finite edge, not infinity
-    h2 = reg.histogram("paddle_span_seconds")
+    h2 = reg.histogram("overflow_probe_seconds")
     h2.observe(5e4)
     assert np.isfinite(h2.quantile(0.99))
 

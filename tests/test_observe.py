@@ -386,29 +386,6 @@ def test_reset_clears_pending_feed_gap_stamp(fresh_programs):
     assert _value("paddle_feed_to_run_gap_seconds") == 0
 
 
-# ------------------------------------------------- span/profiler compose
-def test_span_lands_in_profiler_timeline(tmp_path, capsys):
-    from paddle_tpu import profiler
-
-    n0 = _value("paddle_span_seconds", span="obs_test_span")
-    path = str(tmp_path / "trace.json")
-    profiler.start_profiler(state="CPU")
-    with observe.span("obs_test_span"):
-        np.dot(np.ones((16, 16)), np.ones((16, 16)))
-    profiler.stop_profiler(profile_path=path)
-    out = capsys.readouterr().out
-    # same aggregated event table as any RecordEvent...
-    assert "obs_test_span" in out
-    # ...same chrome trace...
-    trace = json.load(open(path))
-    assert any(e["name"] == "obs_test_span" for e in trace["traceEvents"])
-    # ...AND the histogram, without needing the profiler at all
-    assert _value("paddle_span_seconds", span="obs_test_span") == n0 + 1
-    with observe.span("obs_test_span"):
-        pass
-    assert _value("paddle_span_seconds", span="obs_test_span") == n0 + 2
-
-
 def test_feed_to_run_gap(fresh_programs):
     main, startup, scope = fresh_programs
     with fluid.program_guard(main, startup):

@@ -25,7 +25,7 @@ def test_declared_families_parse():
     declared = repo_lint.declared_families(ROOT)
     assert "paddle_executor_steps_total" in declared
     assert "paddle_analysis_findings_total" in declared
-    assert "paddle_span_seconds" in declared
+    assert "paddle_serving_ttft_seconds" in declared
     assert len(declared) > 40
 
 

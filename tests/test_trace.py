@@ -167,9 +167,7 @@ def test_disabled_tracing_is_noop_on_the_hot_path(monkeypatch):
                 exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
         # the ring stayed empty and the recorded-events counter at 0
         assert len(trace.recorder()) == 0
-        snap = observe.snapshot()
-        rec = snap["metrics"]["paddle_trace_events_recorded_total"]
-        assert rec["samples"][0]["value"] == 0
+        assert trace.recorder().recorded == 0
         # span helpers hand back ONE shared singleton: nothing per-call
         assert trace.trace_span("executor." + "dispatch") is trace.NOOP
         s1, s2 = "x", "y"
