@@ -934,6 +934,16 @@ for _op in _KERNEL_OPS:
         KERNEL_WINNERS.labels(op=_op, choice=_c)
         KERNEL_DISPATCHES.labels(op=_op, impl=_c)
 
+FLASH_BLOCK_PLANS = REGISTRY.counter(
+    "paddle_flash_block_plans_total",
+    "Flash-attention Pallas calls lowered, by kernel name (flash_fwd, "
+    "flash_refwd, flash_bwd_dkv, flash_bwd_dq), the [bq]x[bk] block one "
+    "grid step computes, and whether a single block covered the kernel's "
+    "reduction axis so the carry was dropped ('1'). Counted at LOWERING "
+    "time like paddle_kernel_dispatches_total: it says which plan a "
+    "compiled step holds (ops/attention.py _block_plan)",
+    labels=("kernel", "block", "single_pass"))
+
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see
 # docs/OBSERVABILITY.md "Trace propagation")

@@ -1,4 +1,4 @@
-"""Flash attention: blocked-KV online-softmax Pallas kernels + custom VJP.
+"""Flash attention: blocked Pallas kernels + custom VJP.
 
 The reference has NO fused attention op — attention is composed from
 matmul/softmax/elementwise layer calls (SURVEY §5, e.g.
@@ -6,21 +6,61 @@ matmul/softmax/elementwise layer calls (SURVEY §5, e.g.
 This op is the TPU-first upgrade slot, implementing the FlashAttention-2
 scheme end to end:
 
-  forward:  grid (B*H, Sq/bq, Sk/bk) with the K axis innermost; running
-            max/denominator/accumulator live in VMEM scratch, so VMEM use
-            is O(bq*bk + bq*D + bk*D) regardless of S, and the [Sq,Sk]
-            score matrix never exists in HBM. Saves the logsumexp rows.
+  forward:  grid (B*H/heads, Sq/bq, Sk/bk) with the K axis innermost;
+            the [Sq,Sk] score matrix never exists in HBM. Saves the
+            logsumexp rows.
   backward: two Pallas kernels re-deriving the probabilities from the
             saved logsumexp — dK/dV sweeps query blocks per key block,
             dQ sweeps key blocks per query block, with
             delta = rowsum(dO*O) precomputed outside.
 
+How much of a head's score matrix one grid step computes is planned per
+kernel from the call's static shapes (``_block_plan``), because a grid
+step costs about as much as the MXU work of a 128x128 block of scores at
+D 64 (0.5 us on a v5e: pipeline bookkeeping, five to seven small DMAs,
+MXU fill and drain that nothing overlaps). The plan takes the axis a
+kernel reduces over in ONE block up to 1024 and gives the other axis
+what is left of a 512x512 float32 score tile (S <= 512: one pass over a
+whole head), and such a kernel is a different, shorter program, chosen
+at trace time:
+
+  * no online-softmax carry: one max, one exp, one sum, one write; no
+    m/l/acc scratch, no alpha rescale, no init/emit phases (likewise no
+    dK/dV or dQ accumulator);
+  * up to four heads a grid step where no [bq, bk] operand has to move
+    (no bias, or a key mask), so the per-step cost is paid once for them
+    and one head's matmuls overlap the next head's softmax.
+
+The dK/dV kernel computes the scores TRANSPOSED ([bk, bq] = k q^T)
+whenever the bias is absent or a key mask: dV = p^T g and dK = ds^T q
+then contract over the minor axis of p^T/ds^T like any matmul, where the
+[bq, bk] form first turns two whole score tiles round on the XLU; the
+row statistics reach it as lane-dense [1, bq] rows. Longer sequences
+keep the blocked scheme with running max/denominator/accumulator in VMEM
+scratch (S > 1024: 512x512 blocks). Causal calls skip whole blocks
+above the diagonal there, and mask in-kernel only the blocks the
+diagonal crosses.
+
+VMEM account of one grid step (v5e: 128 MiB, 16 MiB of it scoped to a
+kernel by default), at the largest plan, bq = bk = 512, D = 64, bf16:
+q/k/v/g/o blocks 5 x heads x 512 x 128 lanes x 2 B x 2 buffers = 5 MiB at
+four heads; float32 [512, 512] temporaries of 1 MiB each (scores,
+probabilities, dp, ds and their bf16 copies: five to six alive in the
+dK/dV kernel); the statistics are [1, bq] rows, 2 KiB apiece. A full
+[Sq, Sk] bias block adds 2 x 1 MiB and a trainable bias' ds output
+2 x 1 MiB more, at one head a step. tests/test_chip_bringup.py compiles
+every such case against the described chip's limit.
+
 Mosaic layout notes (the round-2 lesson): every operand/output block's
 last two dims must be (8,128)-divisible or equal to the array dims. The
-per-row logsumexp/delta vectors therefore travel as rank-3 [B*H, S, 1]
-arrays with (1, bq, 1) blocks — minor dim equal to the array's minor dim
-of 1 is Mosaic-legal and verified on TPU v5e — never as rank-2 [B*H, S]
-with (1, bq) blocks (1 is neither 8-divisible nor equal to B*H).
+per-row logsumexp/delta vectors therefore travel as rank-3 arrays,
+never as rank-2 [B*H, S] with (1, bq) blocks (1 is neither 8-divisible
+nor equal to B*H): lane-dense [B*H, 1, S] with (heads, 1, bq) blocks
+where bq is whole lane tiles (see "row statistics" below: the kernels
+turn a row into the [bq, 1] column they need in VMEM), else [B*H, S, 1]
+with (1, bq, 1) blocks — a minor dim equal to the array's minor dim of 1
+is Mosaic-legal and verified on TPU v5e, but every element of such a
+block travels as a 128-lane row.
 ``_assert_mosaic_ok`` re-implements that rule and gates every
 pallas_call here, including in interpret mode, so the CPU test suite
 fails on any spec real TPU lowering would reject. Beyond the mirror,
@@ -30,9 +70,10 @@ the REAL Mosaic lowering path runs in CI via TPU-target jax.export
 compiles them for a described v5e (VMEM limits included) — only
 execution needs the chip (chip_smoke.py).
 
-Ragged sequence lengths are padded to the block size with key-side
-additive masking (-1e9) rather than falling back to whole-sequence
-blocks, keeping VMEM bounded for any S.
+Ragged sequence lengths are padded to a whole number of lane tiles (128)
+with key-side additive masking (-1e9), never to a multiple of the block:
+every planned block divides the padded length, so VMEM stays bounded for
+any S and S 640 is not computed as 1024.
 
 Layout: q,k,v [B, H, S, D]; bias broadcastable [B|1, H|1, Sq|1, Sk],
 additive (-1e9 at masked positions). By default the bias is a constant
@@ -60,31 +101,43 @@ __all__ = ["flash_attention", "flash_attention_with_lse", "pallas_mode",
            "fused_attention_enabled", "flash_min_seq", "flash_effective",
            "composed_attention"]
 
-# Block sizes: env-tunable so hardware sweeps (VMEM vs occupancy per
-# chip generation) need no code edit. Defaults fit v5e comfortably.
-# Constraints (Mosaic tiling + the validator below): BQ % 8 == 0,
-# BK % 128 == 0.
+# Block plan. Each of the three kernels gets (bq, bk) from the call's own
+# static shapes (``_block_plan``); the environment may name an explicit
+# override of it (``_block_sizes``: tests, tools/kernel_tune.py, a
+# hardware A/B), validated at first kernel use. Constraints (Mosaic
+# tiling + the validator below): BQ % 8 == 0, BK % 128 == 0.
 import os as _os
 
+_LANE = 128        # sequences pad to whole lane tiles, blocks are made of them
+_MAX_BLOCK = 512   # longest block edge: a 512x512 float32 score tile is 1 MiB
+                   # of VMEM, and each kernel holds a handful of them
+_HEADS_PER_STEP = 4  # most heads one grid step of a single-pass kernel takes
+
+
 def _block_sizes():
-    """Parse and validate block sizes at first kernel use, not import:
-    a malformed PADDLE_TPU_FLASH_BQ must not make `import paddle_tpu`
-    fail for workflows that never touch attention."""
-    raw_bq = _os.environ.get("PADDLE_TPU_FLASH_BQ", "128")
-    raw_bk = _os.environ.get("PADDLE_TPU_FLASH_BK", "128")
+    """The environment's block override as ``(bq, bk)``, ``None`` for
+    a variable that is not set. Parsed and validated at first kernel use,
+    not import: a malformed PADDLE_TPU_FLASH_BQ must not make
+    `import paddle_tpu` fail for workflows that never touch attention."""
+    raw_bq = _os.environ.get("PADDLE_TPU_FLASH_BQ") or None
+    raw_bk = _os.environ.get("PADDLE_TPU_FLASH_BK") or None
     try:
-        bq, bk = int(raw_bq), int(raw_bk)
+        bq = None if raw_bq is None else int(raw_bq)
+        bk = None if raw_bk is None else int(raw_bk)
     except ValueError:
         raise ValueError(
             "PADDLE_TPU_FLASH_BQ/BK must be decimal integers "
             "(multiple of 8 / multiple of 128); got %r/%r"
             % (raw_bq, raw_bk)) from None
-    if bq % 8 or bk % 128 or bq <= 0 or bk <= 0:
+    if (bq is not None and (bq % 8 or bq <= 0)) \
+            or (bk is not None and (bk % 128 or bk <= 0)):
         raise ValueError(
             "PADDLE_TPU_FLASH_BQ must be a positive multiple of 8 and "
-            "PADDLE_TPU_FLASH_BK a positive multiple of 128; got %d/%d"
+            "PADDLE_TPU_FLASH_BK a positive multiple of 128; got %s/%s"
             % (bq, bk))
     return bq, bk
+
+
 _MASK = -1e9  # additive mask for padded key columns
 
 
@@ -240,41 +293,207 @@ def _pad_bias(bias, Sq, Sqp, Sk, Skp):
     return bias
 
 
-def _bias_spec_and_operand(bias, H, bq, bk, iq_pos, ik_pos):
+def _bias_spec_and_operand(bias, H, heads, bq, bk, iq_pos, ik_pos,
+                           column=False):
     """BlockSpec + operand for a broadcastable bias.
 
     iq_pos/ik_pos say which grid axes carry the q/k block indices (the
-    forward and the two backward kernels order their grids differently)."""
+    forward and the two backward kernels order their grids differently);
+    grid axis 0 counts groups of ``heads`` consecutive rows of the
+    flattened B*H axis (``heads`` divides H, so a group never straddles
+    two batch entries). ``column`` hands a key-mask bias [B|1,H|1,1,Sk]
+    over as [.., Sk, 1] blocks, for the kernel that computes the scores
+    transposed."""
+    if column:
+        bias = bias.reshape(bias.shape[:2] + (bias.shape[3], 1))
+        iq_pos, ik_pos = ik_pos, iq_pos  # keys ride the row axis now
+        bq, bk = bk, 1
     Bb, Hb, Sqb, Skb = bias.shape
+    blk_h = heads if Hb > 1 else 1
     blk_q = bq if Sqb > 1 else 1
     blk_k = bk if Skb > 1 else 1
 
     def bias_map(*idx, Bb=Bb, Hb=Hb, Sqb=Sqb, Skb=Skb, H=H):
-        bh = idx[0]
+        bh = idx[0] * heads
         b = (bh // H) if Bb > 1 else 0
-        h = (bh % H) if Hb > 1 else 0
+        h = ((bh % H) // heads) if Hb > 1 else 0
         return (b, h,
                 idx[iq_pos] if Sqb > 1 else 0,
                 idx[ik_pos] if Skb > 1 else 0)
 
-    return pl.BlockSpec((1, 1, blk_q, blk_k), bias_map), bias
+    return pl.BlockSpec((1, blk_h, blk_q, blk_k), bias_map), bias
+
+
+def _bias_block(b_ref, h):
+    """Head ``h``'s float32 bias block out of a (1, heads|1, ., .) ref."""
+    return b_ref[0, h if b_ref.shape[1] > 1 else 0].astype(jnp.float32)
+
+
+# --------------------------------------------------------- kernel names
+# The names under which the four kernel runs of a layer are found in a
+# device profile and in the HLO: XLA names a Pallas custom call after the
+# innermost name scope (``flash_fwd.3``; under autodiff
+# ``jvp_flash_refwd_.3``, ``jvp_flash_bwd_dkv_.3``), and only that reaches
+# the profiler's event. The forward kernel runs twice a layer in a train
+# step: once in the forward op, and again when the grad op differentiates
+# the forward lowering (``jax.vjp`` runs the ``custom_vjp`` forward rule
+# for its residuals; XLA does not merge two custom calls) — the rule's run
+# carries its own name so a trace can say whether it went away.
+KERNEL_FWD = "flash_fwd"
+KERNEL_REFWD = "flash_refwd"
+KERNEL_BWD_DKV = "flash_bwd_dkv"
+KERNEL_BWD_DQ = "flash_bwd_dq"
+
+
+# ----------------------------------------------------------- block plan
+def _largest_block(Sp, cap):
+    """The longest block of whole lane tiles that divides the padded
+    length ``Sp`` and is at most ``cap`` (never under one tile); a length
+    of at most one lane tile is its own block."""
+    if Sp <= _LANE:
+        return Sp
+    n = Sp // _LANE
+    return _LANE * max(d for d in range(1, n + 1)
+                       if n % d == 0 and (d == 1 or d * _LANE <= cap))
+
+
+def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False):
+    """``(bq, bk)`` of one grid step of ``kernel`` (one of the KERNEL_*
+    names; the forward's rerun plans like the forward), from what is
+    static at trace time. Pure: the environment's override
+    (``_block_sizes``) is applied by ``_resolve_blocks``.
+
+    A sequence pads to whole lane tiles (128) and never to a multiple of
+    the block: each block divides the padded length, so S 500 is one 512
+    block and S 640 stays 640. The axis a kernel REDUCES over (keys for
+    the forward and dQ, queries for dK/dV) is planned first and taken
+    whole up to 1024, because a kernel whose single block covers its
+    reduction drops the carry (see the kernels); past that it is cut in
+    ``_MAX_BLOCK`` pieces. The other axis takes what is left of a
+    ``_MAX_BLOCK``² score tile: 512x512 at S 512, 256x1024 at S 1024,
+    128x640 at S 640 (five lane tiles divide by nothing else).
+
+    Measured on a v5e (PERF.md §6, PR 25), which is why these inputs do
+    not move the plan: ``causal`` — the larger block won at every length
+    tried although it skips fewer blocks above the diagonal (S 1024
+    forward: 2.85 ms at 128, 1.45 at 256, 0.87 at 512, 0.65 with the key
+    axis whole and nothing skipped); ``want_db``, ``D``, ``dtype`` — the
+    score tiles are float32 [bq, bk] whatever the operands, and a full
+    [bq, bk] bias or ds block fits beside them at one head a step
+    (``_heads_per_step``)."""
+    del D, dtype, causal, want_db
+    sq, sk = _pad_len(Sq, _LANE), _pad_len(Sk, _LANE)
+    dkv = kernel == KERNEL_BWD_DKV
+    red, par = (sq, sk) if dkv else (sk, sq)
+    b_red = red if red <= 2 * _MAX_BLOCK else _largest_block(red, _MAX_BLOCK)
+    b_par = _largest_block(par, _MAX_BLOCK * _MAX_BLOCK // b_red)
+    return (b_red, b_par) if dkv else (b_par, b_red)
+
+
+def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db):
+    """``(Sqp, Skp, bq, bk)``: the plan, or the environment's explicit
+    override of it. An overridden axis keeps the old contract: the length
+    pads to a multiple of the forced block."""
+    fq, fk = _block_sizes()
+    bq, bk = _block_plan(kernel, Sq, Sk, D, dtype, causal, want_db)
+    Sqp, Skp = _pad_len(Sq, fq or _LANE), _pad_len(Sk, fk or _LANE)
+    if fq:
+        bq = min(fq, Sqp)
+    if fk:
+        bk = min(fk, Skp)
+    return Sqp, Skp, bq, bk
+
+
+def _heads_per_step(H, single_pass, bias, want_db=False):
+    """How many (batch, head) rows one grid step takes. A single-pass
+    kernel with no [bq, bk] tile to move (no bias or a key mask, no
+    score-gradient output) takes up to ``_HEADS_PER_STEP`` heads a step,
+    the largest count that divides H, so that a group stays inside one
+    batch entry and one [B,1,1,S] bias block serves it: the per-step
+    overhead is paid once, and one head's matmuls overlap the next
+    head's softmax. Every other kernel keeps one head a step: a
+    [heads, bq, bk] float32 bias or ds block would not fit VMEM."""
+    slim = not want_db and (bias is None or bias.shape[2] == 1)
+    if not (single_pass and slim):
+        return 1
+    return max(g for g in range(1, _HEADS_PER_STEP + 1) if H % g == 0)
+
+
+def _note_plan(kernel, bq, bk, single_pass):
+    from ..observe.families import FLASH_BLOCK_PLANS
+
+    FLASH_BLOCK_PLANS.labels(kernel=kernel, block="%dx%d" % (bq, bk),
+                             single_pass="1" if single_pass else "0").inc()
 
 
 # --------------------------------------------------------------- causal
-def _causal_mask(s, iq, ik, bq, bk):
-    """Lower-triangular mask for the (iq, ik) block: s[r, c] survives iff
-    global query position iq*bq+r >= key position ik*bk+c."""
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+def _causal_mask(s, iq, ik, bq, bk, transposed=False):
+    """Lower-triangular mask for the (iq, ik) block: a score survives iff
+    its global query position iq*bq+r >= its key position ik*bk+c.
+    Queries run along the rows of ``s``, or along its columns when the
+    kernel computed the scores transposed."""
+    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              1 if transposed else 0)
+    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                              0 if transposed else 1)
     return jnp.where(qpos >= kpos, s, _MASK)
 
 
-def _block_visible(iq, ik, bq, bk):
-    """False when the (iq, ik) block lies entirely above the causal
-    diagonal (every key position > every query position) — the kernels
-    wrap their compute in pl.when(visible), so Mosaic skips the block's
-    MXU work entirely: ~2x step FLOPs saved at long causal S."""
-    return ik * bk <= iq * bq + bq - 1
+def _for_block(body, causal, iq, ik, bq, bk):
+    """Run ``body(masked)`` for the (iq, ik) block. A causal call skips it
+    when it lies entirely above the diagonal (every key position > every
+    query position: Mosaic then skips the block's MXU work, ~2x step
+    FLOPs saved at long causal S), runs it without the in-kernel mask
+    when it lies entirely on or below, and masks only where the diagonal
+    crosses it."""
+    if not causal:
+        body(False)
+        return
+    below = ik * bk + bk - 1 <= iq * bq
+    visible = ik * bk <= iq * bq + bq - 1
+    pl.when(below)(lambda: body(False))
+    pl.when(jnp.logical_and(visible, jnp.logical_not(below)))(
+        lambda: body(True))
+
+
+# ------------------------------------------------------- row statistics
+# The per-row logsumexp/delta vectors live in HBM as lane-dense
+# [B*H, 1, S] rows wherever a block of them is whole lane tiles: a
+# [bq, 1] block of a [B*H, S, 1] array pads every element to a 128-lane
+# row (256 KiB of DMA for 2 KiB of numbers at bq 512; measured on a v5e:
+# the S 512 forward 0.94 -> 0.74 ms and dQ 1.50 -> 0.96 ms a call).
+# The kernels that want them down the rows of a [bq, bk] tile turn
+# them round in VMEM (four 128x128 XLU tiles at bq 512). A block that
+# is not whole lane tiles (S < 128, an odd forced BQ) keeps the
+# [B*H, S, 1] layout: a (1, bq) block would not be Mosaic-legal.
+def _stat_rows(bq):
+    return bq % _LANE == 0
+
+
+def _stat_spec(heads, bq, rows, iq_pos):
+    """BlockSpec of a statistics block, [heads, 1, bq] of a [B*H, 1, S]
+    array or [heads, bq, 1] of a [B*H, S, 1] one; ``iq_pos`` is the grid
+    axis that counts query blocks."""
+    if rows:
+        return pl.BlockSpec((heads, 1, bq),
+                            lambda *idx: (idx[0], 0, idx[iq_pos]))
+    return pl.BlockSpec((heads, bq, 1),
+                        lambda *idx: (idx[0], idx[iq_pos], 0))
+
+
+def _stat_operand(x, rows):
+    """[B*H, S] statistics in the layout ``_stat_spec`` blocks."""
+    return x[:, None, :] if rows else x[:, :, None]
+
+
+def _to_row(col):
+    """[r, 1] column -> lane-dense [1, r] row (r in whole lane tiles)."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANE)).T[:1]
+
+
+def _to_col(row):
+    """Lane-dense [1, r] row -> [r, 1] column."""
+    return jnp.broadcast_to(row, (_LANE, row.shape[1])).T[:, :1]
 
 
 # --------------------------------------------------------------- forward
@@ -291,10 +510,37 @@ def _dot_f32(a, b, ca, cb):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, nk, causal, bq, bk):
+def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
+    q_ref, k_ref, v_ref = refs[:3]
+    b_ref = refs[3] if has_bias else None
+    o_ref, lse_ref = refs[3 + has_bias:5 + has_bias]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
+
+    def scores(h, masked):
+        # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
+        # with f32 accumulation; only the softmax state is explicitly f32
+        s = _dot_f32(q_ref[h], k_ref[h], 1, 1) * scale    # [bq, bk]
+        if b_ref is not None:
+            s = s + _bias_block(b_ref, h)
+        return _causal_mask(s, iq, ik, bq, bk) if masked else s
+
+    if nk == 1:
+        # one block holds every key of the row: one softmax and one
+        # write, no running max/denominator/accumulator to carry
+        for h in range(heads):
+            v = v_ref[h]                                  # [bk, D]
+            s = scores(h, causal)
+            m = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
+            p = jnp.exp(s - m)                            # [bq, bk] f32
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            o_ref[h] = (_dot_f32(p.astype(v.dtype), v, 1, 0)
+                        / l).astype(o_ref.dtype)
+            lse = m + jnp.log(l)
+            lse_ref[h] = _to_row(lse) if rows else lse
+        return
+
+    acc_ref, m_ref, l_ref = refs[5 + has_bias:]
 
     @pl.when(ik == 0)
     def _init():
@@ -302,19 +548,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(_block_visible(iq, ik, bq, bk) if causal else True)
-    def _compute():
-        # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
-        # with f32 accumulation; only the softmax state is explicitly f32
-        q = q_ref[0]                              # [bq, D]
-        k = k_ref[0]                              # [bk, D]
+    def _compute(masked):
         v = v_ref[0]                              # [bk, D]
-        s = _dot_f32(q, k, 1, 1) * scale
-        if b_ref is not None:
-            s = s + b_ref[0, 0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, iq, ik, bq, bk)
-
+        s = scores(0, masked)
         m_prev = m_ref[...]                       # [bq, 1]
         l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -325,26 +561,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * alpha \
             + _dot_f32(p.astype(v.dtype), v, 1, 0)
 
+    _for_block(_compute, causal, iq, ik, bq, bk)
+
     @pl.when(ik == nk - 1)
     def _emit():
         l = l_ref[...]
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)  # [bq, 1]
-
-
-# The names under which the four kernel runs of a layer are found in a
-# device profile and in the HLO: XLA names a Pallas custom call after the
-# innermost name scope (``flash_fwd.3``; under autodiff
-# ``jvp_flash_refwd_.3``, ``jvp_flash_bwd_dkv_.3``), and only that reaches
-# the profiler's event. The forward kernel runs twice a layer in a train
-# step: once in the forward op, and again when the grad op differentiates
-# the forward lowering (``jax.vjp`` runs the ``custom_vjp`` forward rule
-# for its residuals; XLA does not merge two custom calls) — the rule's run
-# carries its own name so a trace can say whether it went away.
-KERNEL_FWD = "flash_fwd"
-KERNEL_REFWD = "flash_refwd"
-KERNEL_BWD_DKV = "flash_bwd_dkv"
-KERNEL_BWD_DQ = "flash_bwd_dq"
+        lse = m_ref[...] + jnp.log(l)         # [bq, 1]
+        lse_ref[0] = _to_row(lse) if rows else lse
 
 
 def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD):
@@ -354,95 +578,120 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD):
         raise ValueError(
             "causal flash attention requires Sq == Sk (self-attention); "
             "got %d/%d" % (S, Sk))
-    _BQ, _BK = _block_sizes()
-    Sp, Skp = _pad_len(S, _BQ), _pad_len(Sk, _BK)
+    Sp, Skp, bq, bk = _resolve_blocks(KERNEL_FWD, S, Sk, D, q.dtype, causal,
+                                      False)
+    nq, nk = Sp // bq, Skp // bk
+    _note_plan(name, bq, bk, nk == 1)
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
+    heads = _heads_per_step(H, nk == 1, bias)
+    rows = _stat_rows(bq)
     q = _pad_axis(q, 2, Sp)
     k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
-    bq, nq = min(_BQ, Sp), Sp // min(_BQ, Sp)
-    bk, nk = min(_BK, Skp), Skp // min(_BK, Skp)
     qf, kf, vf = (t.reshape(B * H, t.shape[2], D) for t in (q, k, v))
-    grid = (B * H, nq, nk)
 
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+        pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
+        pl.BlockSpec((heads, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+        pl.BlockSpec((heads, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
     ]
     operands = [qf, kf, vf]
     if bias is not None:
-        spec, opnd = _bias_spec_and_operand(bias, H, bq, bk, 1, 2)
+        spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 1, 2)
         in_specs.append(spec)
         operands.append(opnd)
-        kern = functools.partial(_fwd_kernel, scale=scale, nk=nk,
-                                 causal=causal, bq=bq, bk=bk)
-    else:
-        def kern(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l):
-            _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                        acc, m, l, scale=scale, nk=nk, causal=causal,
-                        bq=bq, bk=bk)
 
+    kern = functools.partial(_fwd_kernel, scale=scale, nk=nk, causal=causal,
+                             bq=bq, bk=bk, heads=heads,
+                             has_bias=bias is not None, rows=rows)
     out, lse = _checked_pallas_call(
         kern,
         name=name,
-        grid=grid,
+        grid=(B * H // heads, nq, nk),
         in_specs=in_specs,
         operands=operands,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
+            _stat_spec(heads, bq, rows, 1),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Sp) if rows else (B * H, Sp, 1),
+                                 jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=_use_interpret(),
     )
-    return out[:, :S].reshape(B, H, S, D), lse[:, :S, 0]
+    lse = lse[:, 0, :S] if rows else lse[:, :S, 0]
+    return out[:, :S].reshape(B, H, S, D), lse
 
 
 # -------------------------------------------------------------- backward
-def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
-                dk_ref, dv_ref, ds_ref, dk_acc, dv_acc, *, scale, nq,
-                causal, bq, bk):
+def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
+                transposed, rows):
+    q_ref, k_ref, v_ref = refs[:3]
+    b_ref = refs[3] if has_bias else None
+    i = 3 + has_bias
+    g_ref, lse_ref, d_ref, dk_ref, dv_ref = refs[i:i + 5]
+    ds_ref = refs[i + 5] if want_db else None
     ik = pl.program_id(1)
     iq = pl.program_id(2)
+    # the scores as [bq, bk], or transposed as [bk, bq]: then p^T g and
+    # ds^T q contract over the minor axis of p/ds like any matmul, where
+    # the [bq, bk] form has to turn both tiles round first; the row
+    # statistics arrive as [1, bq] rows and a key mask as a [bk, 1] column
+    c = 1 if transposed else 0
+
+    def grads(h, masked):
+        q = q_ref[h]                              # [bq, D]
+        k = k_ref[h]                              # [bk, D]
+        v = v_ref[h]                              # [bk, D]
+        g = g_ref[h]                              # [bq, D]
+        lse, delta = lse_ref[h], d_ref[h]         # [1, bq] rows, or [bq, 1]
+        if rows and not transposed:
+            lse, delta = _to_col(lse), _to_col(delta)
+        s = (_dot_f32(k, q, 1, 1) if transposed
+             else _dot_f32(q, k, 1, 1)) * scale
+        if b_ref is not None:
+            s = s + _bias_block(b_ref, h)
+        if masked:
+            s = _causal_mask(s, iq, ik, bq, bk, transposed)
+        p = jnp.exp(s - lse)                      # [bq, bk] f32
+        # dv = p^T g ; dp = g v^T ; ds = p*(dp-delta)*scale ; dk = ds^T q
+        dv = _dot_f32(p.astype(g.dtype), g, c, 0)
+        dp = _dot_f32(v, g, 1, 1) if transposed else _dot_f32(g, v, 1, 1)
+        ds = p * (dp - delta) * scale
+        dk = _dot_f32(ds.astype(q.dtype), q, c, 0)
+        if ds_ref is not None:
+            # raw score gradient (pre-scale is ds/scale; bias adds after
+            # the scale, so its cotangent drops the trailing *scale)
+            ds_ref[h] = p * (dp - delta)
+        return dk, dv
+
+    if nq == 1:
+        # one block holds every query: nothing to accumulate over
+        for h in range(heads):
+            dk, dv = grads(h, causal)
+            dk_ref[h] = dk.astype(dk_ref.dtype)
+            dv_ref[h] = dv.astype(dv_ref.dtype)
+        return
+
+    dk_acc, dv_acc = refs[i + 5 + want_db:]
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_block_visible(iq, ik, bq, bk) if causal else True)
-    def _compute():
-        q = q_ref[0]                              # [bq, D]
-        k = k_ref[0]                              # [bk, D]
-        v = v_ref[0]                              # [bk, D]
-        g = g_ref[0]                              # [bq, D]
-        lse = lse_ref[0]                          # [bq, 1]
-        delta = d_ref[0]                          # [bq, 1]
+    def _compute(masked):
+        dk, dv = grads(0, masked)
+        dk_acc[...] += dk
+        dv_acc[...] += dv
 
-        s = _dot_f32(q, k, 1, 1) * scale
-        if b_ref is not None:
-            s = s + b_ref[0, 0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, iq, ik, bq, bk)
-        p = jnp.exp(s - lse)                      # [bq, bk] f32
-
-        # dv += p^T g ; dp = g v^T ; ds = p*(dp-delta)*scale ; dk += ds^T q
-        dv_acc[...] += _dot_f32(p.astype(g.dtype), g, 0, 0)
-        dp = _dot_f32(g, v, 1, 1)
-        ds = p * (dp - delta) * scale
-        dk_acc[...] += _dot_f32(ds.astype(q.dtype), q, 0, 0)
-        if ds_ref is not None:
-            # raw score gradient (pre-scale is ds/scale; bias adds after
-            # the scale, so its cotangent drops the trailing *scale)
-            ds_ref[0] = p * (dp - delta)
+    _for_block(_compute, causal, iq, ik, bq, bk)
 
     @pl.when(iq == nq - 1)
     def _emit():
@@ -450,33 +699,44 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, lse_ref, d_ref,
-               dq_ref, dq_acc, *, scale, nk, causal, bq, bk):
+def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
+    q_ref, k_ref, v_ref = refs[:3]
+    b_ref = refs[3] if has_bias else None
+    i = 3 + has_bias
+    g_ref, lse_ref, d_ref, dq_ref = refs[i:i + 4]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
+
+    def grad(h, masked):
+        k = k_ref[h]
+        s = _dot_f32(q_ref[h], k, 1, 1) * scale
+        if b_ref is not None:
+            s = s + _bias_block(b_ref, h)
+        if masked:
+            s = _causal_mask(s, iq, ik, bq, bk)
+        lse, delta = lse_ref[h], d_ref[h]         # [bq, 1], or [1, bq] rows
+        if rows:
+            lse, delta = _to_col(lse), _to_col(delta)
+        p = jnp.exp(s - lse)
+        dp = _dot_f32(g_ref[h], v_ref[h], 1, 1)
+        ds = p * (dp - delta) * scale             # [bq, bk] f32
+        return _dot_f32(ds.astype(k.dtype), k, 1, 0)
+
+    if nk == 1:
+        for h in range(heads):
+            dq_ref[h] = grad(h, causal).astype(dq_ref.dtype)
+        return
+
+    dq_acc, = refs[i + 4:]
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_block_visible(iq, ik, bq, bk) if causal else True)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        g = g_ref[0]
-        lse = lse_ref[0]                          # [bq, 1]
-        delta = d_ref[0]                          # [bq, 1]
+    def _compute(masked):
+        dq_acc[...] += grad(0, masked)
 
-        s = _dot_f32(q, k, 1, 1) * scale
-        if b_ref is not None:
-            s = s + b_ref[0, 0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, iq, ik, bq, bk)
-        p = jnp.exp(s - lse)
-        dp = _dot_f32(g, v, 1, 1)
-        ds = p * (dp - delta) * scale             # [bq, bk] f32
-        dq_acc[...] += _dot_f32(ds.astype(k.dtype), k, 1, 0)
+    _for_block(_compute, causal, iq, ik, bq, bk)
 
     @pl.when(ik == nk - 1)
     def _emit():
@@ -487,86 +747,84 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
                      g_lse=None, causal=False):
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    _BQ, _BK = _block_sizes()
-    Sp, Skp = _pad_len(S, _BQ), _pad_len(Sk, _BK)
+    BH = B * H
+    plan = lambda kernel: _resolve_blocks(  # noqa: E731
+        kernel, S, Sk, D, q.dtype, causal, want_db)
+    Sp, Skp, bq, bk = plan(KERNEL_BWD_DKV)
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
     q = _pad_axis(q, 2, Sp)
     k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
-    bq, nq = min(_BQ, Sp), Sp // min(_BQ, Sp)
-    bk, nk = min(_BK, Skp), Skp // min(_BK, Skp)
-    qf, kf, vf = (t.reshape(B * H, t.shape[2], D) for t in (q, k, v))
-    gf = _pad_axis(g.reshape(B * H, S, D), 1, Sp)
-    of = _pad_axis(o.reshape(B * H, S, D), 1, Sp)
+    qf, kf, vf = (t.reshape(BH, t.shape[2], D) for t in (q, k, v))
+    gf = _pad_axis(g.reshape(BH, S, D), 1, Sp)
+    of = _pad_axis(o.reshape(BH, S, D), 1, Sp)
     delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)    # [BH, Sp, 1]
+                    axis=-1)                   # [BH, Sp]
     if g_lse is not None:
         # lse cotangent: dlse_i/ds_ij = p_ij, so ds gains +p*g_lse_i —
         # algebraically a -g_lse shift of delta (ds = p*(dp - delta))
         delta = delta - _pad_axis(
-            g_lse.reshape(B * H, S, 1).astype(jnp.float32), 1, Sp)
+            g_lse.reshape(BH, S).astype(jnp.float32), 1, Sp)
     # padded lse rows pair with zero g rows, so their p values are
     # harmless (ds and p^T g both vanish); zero-fill keeps exp() finite
-    lse3 = _pad_axis(lse[:, :, None], 1, Sp)
+    lse = _pad_axis(lse, 1, Sp)                # [BH, Sp]
     interp = _use_interpret()
+    has_bias = bias is not None
 
     # dK/dV: one key block per (bh, ik), sweep query blocks innermost
+    nq, nk = Sp // bq, Skp // bk
+    heads = _heads_per_step(H, nq == 1, bias, want_db)
+    _note_plan(KERNEL_BWD_DKV, bq, bk, nq == 1)
+    # transposed scores take the statistics as [1, bq] rows (one block
+    # over all of a short S is legal too) and a bias that is a key mask;
+    # a [Sq, Sk] bias, and the ds tile a trainable one wants back, keep
+    # the [bq, bk] orientation
+    transposed = (not want_db and (_stat_rows(bq) or bq == Sp)
+                  and (not has_bias or bias.shape[2] == 1))
+    rows = transposed or _stat_rows(bq)
+    q_map = lambda bh, ik, iq: (bh, iq, 0)  # noqa: E731
+    k_map = lambda bh, ik, iq: (bh, ik, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, ik, iq: (bh, iq, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
+        pl.BlockSpec((heads, bq, D), q_map),
+        pl.BlockSpec((heads, bk, D), k_map),
+        pl.BlockSpec((heads, bk, D), k_map),
     ]
     operands = [qf, kf, vf]
-    has_bias = bias is not None
     if has_bias:
-        spec, opnd = _bias_spec_and_operand(bias, H, bq, bk, 2, 1)
+        spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 2, 1,
+                                            column=transposed)
         in_specs.append(spec)
         operands.append(opnd)
-
-    def dkv_kern(*refs):
-        i = 3 + int(has_bias)
-        q_r, k_r, v_r = refs[0], refs[1], refs[2]
-        b_r = refs[3] if has_bias else None
-        g_r, lse_r, d_r = refs[i], refs[i + 1], refs[i + 2]
-        outs = refs[i + 3:]
-        if want_db:
-            dk_r, dv_r, ds_r, dka, dva = outs
-        else:
-            dk_r, dv_r, dka, dva = outs
-            ds_r = None
-        _dkv_kernel(q_r, k_r, v_r, b_r, g_r, lse_r, d_r,
-                    dk_r, dv_r, ds_r, dka, dva, scale=scale, nq=nq,
-                    causal=causal, bq=bq, bk=bk)
-
-    in_specs += [
-        pl.BlockSpec((1, bq, D), lambda bh, ik, iq: (bh, iq, 0)),
-        pl.BlockSpec((1, bq, 1), lambda bh, ik, iq: (bh, iq, 0)),
-        pl.BlockSpec((1, bq, 1), lambda bh, ik, iq: (bh, iq, 0)),
-    ]
-    operands += [gf, lse3, delta]
+    stat = _stat_spec(heads, bq, rows, 2)
+    in_specs += [pl.BlockSpec((heads, bq, D), q_map), stat, stat]
+    operands += [gf, _stat_operand(lse, rows), _stat_operand(delta, rows)]
     out_specs = [
-        pl.BlockSpec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, ik, iq: (bh, ik, 0)),
+        pl.BlockSpec((heads, bk, D), k_map),
+        pl.BlockSpec((heads, bk, D), k_map),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((B * H, Skp, D), k.dtype),
-        jax.ShapeDtypeStruct((B * H, Skp, D), v.dtype),
+        jax.ShapeDtypeStruct((BH, Skp, D), k.dtype),
+        jax.ShapeDtypeStruct((BH, Skp, D), v.dtype),
     ]
     if want_db:
         # per-block score grads, written once per grid cell (O(S^2) HBM —
         # only materialized when a trainable bias asks for it)
         out_specs.append(
-            pl.BlockSpec((1, bq, bk), lambda bh, ik, iq: (bh, iq, ik)))
+            pl.BlockSpec((heads, bq, bk), lambda bh, ik, iq: (bh, iq, ik)))
         out_shape.append(
-            jax.ShapeDtypeStruct((B * H, Sp, Skp), jnp.float32))
+            jax.ShapeDtypeStruct((BH, Sp, Skp), jnp.float32))
+    kern = functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,
+                             bq=bq, bk=bk, heads=heads, has_bias=has_bias,
+                             want_db=want_db, transposed=transposed,
+                             rows=rows)
     res = _checked_pallas_call(
-        dkv_kern,
+        kern,
         name=KERNEL_BWD_DKV,
-        grid=(B * H, nk, nq),
+        grid=(BH // heads, nk, nq),
         in_specs=in_specs,
         operands=operands,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
+        scratch_shapes=[] if nq == 1 else [
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
@@ -578,39 +836,40 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
         dk, dv = res
         ds_full = None
 
-    # dQ: one query block per (bh, iq), sweep key blocks innermost
+    # dQ: one query block per (bh, iq), sweep key blocks innermost. Its
+    # plan may differ from dK/dV's in the blocks, never in the padding.
+    _, _, bq, bk = plan(KERNEL_BWD_DQ)
+    nq, nk = Sp // bq, Skp // bk
+    heads = _heads_per_step(H, nk == 1, bias)
+    _note_plan(KERNEL_BWD_DQ, bq, bk, nk == 1)
+    q_map = lambda bh, iq, ik: (bh, iq, 0)  # noqa: E731
+    k_map = lambda bh, iq, ik: (bh, ik, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-        pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+        pl.BlockSpec((heads, bq, D), q_map),
+        pl.BlockSpec((heads, bk, D), k_map),
+        pl.BlockSpec((heads, bk, D), k_map),
     ]
     operands = [qf, kf, vf]
     if has_bias:
-        spec, opnd = _bias_spec_and_operand(bias, H, bq, bk, 1, 2)
+        spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 1, 2)
         in_specs.append(spec)
         operands.append(opnd)
-        kern = functools.partial(_dq_kernel, scale=scale, nk=nk,
-                                 causal=causal, bq=bq, bk=bk)
-    else:
-        def kern(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, dq_ref, dqa):
-            _dq_kernel(q_ref, k_ref, v_ref, None, g_ref, lse_ref, d_ref,
-                       dq_ref, dqa, scale=scale, nk=nk, causal=causal,
-                       bq=bq, bk=bk)
-    in_specs += [
-        pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((1, bq, 1), lambda bh, iq, ik: (bh, iq, 0)),
-    ]
-    operands += [gf, lse3, delta]
+    rows = _stat_rows(bq)
+    stat = _stat_spec(heads, bq, rows, 1)
+    in_specs += [pl.BlockSpec((heads, bq, D), q_map), stat, stat]
+    operands += [gf, _stat_operand(lse, rows), _stat_operand(delta, rows)]
+    kern = functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
+                             bq=bq, bk=bk, heads=heads, has_bias=has_bias,
+                             rows=rows)
     dq = _checked_pallas_call(
         kern,
         name=KERNEL_BWD_DQ,
-        grid=(B * H, nq, nk),
+        grid=(BH // heads, nq, nk),
         in_specs=in_specs,
         operands=operands,
-        out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=pl.BlockSpec((heads, bq, D), q_map),
+        out_shape=jax.ShapeDtypeStruct((BH, Sp, D), q.dtype),
+        scratch_shapes=[] if nk == 1 else [pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interp,
     )
 
@@ -960,7 +1219,7 @@ def _attn_candidates(sig):
     sq, sk = sig
     cands = []
     for bq in (128, 256, 512):
-        for bk in (128, 256):
+        for bk in (128, 256, 512):
             if bq <= _pad_len(int(sq), bq) and bk <= _pad_len(int(sk), bk):
                 cands.append((bq, bk))
     return cands or [(128, 128)]

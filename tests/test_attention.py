@@ -7,6 +7,8 @@ dropout placement, which the fused path applies to the output).
 """
 
 import numpy as np
+import pytest
+
 import jax
 import jax.numpy as jnp
 
@@ -314,3 +316,196 @@ def test_flash_causal_bias_grad_none_bias_is_plain_causal():
     expect = _attention_reference(q, k, v, causal_bias, scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------- block plan (ISSUE 25)
+def _reference_with_lse(q, k, v, bias, scale, causal):
+    """composed_attention's scores again, for the row log-sum-exps the
+    kernels save (the reference returns only the output)."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if bias is not None:
+        s = s + bias
+    if causal:
+        n = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e9)
+    from paddle_tpu.ops.attention import composed_attention
+
+    return (composed_attention(q, k, v, bias, scale, causal),
+            jax.nn.logsumexp(s, axis=-1))
+
+
+PLAN_PARITY_CASES = {
+    # name: (B, H, S, D), dtype, bias kind, causal, atol forward, atol grads
+    # (the tolerances the tests above hold the same paths to)
+    "s512_maskbias_bf16": ((1, 2, 512, 32), "bfloat16", "mask", False,
+                           2e-2, 3e-2),
+    "s512_maskbias_f32": ((1, 2, 512, 32), "float32", "mask", False,
+                          1e-4, 1e-4),
+    "ragged_s500": ((1, 2, 500, 16), "float32", "mask", False, 1e-4, 1e-4),
+    "s640": ((1, 2, 640, 16), "float32", "mask", False, 1e-4, 1e-4),
+    "causal_s1024": ((1, 1, 1024, 16), "float32", None, True, 2e-5, 3e-4),
+    "trainable_bias": ((2, 2, 256, 16), "float32", "trainable", False,
+                       1e-4, 2e-4),
+    "full_bias": ((2, 2, 256, 16), "float32", "full", False, 1e-4, 1e-4),
+}
+
+
+def _plan_parity_inputs(case):
+    shape, dtype, bias_kind, causal, atol_f, atol_g = PLAN_PARITY_CASES[case]
+    B, H, S, D = shape
+    rs = np.random.RandomState(sum(map(ord, case)))
+    q, k, v = (jnp.asarray(rs.randn(*shape).astype("float32")).astype(dtype)
+               for _ in range(3))
+    bias = None
+    if bias_kind == "mask":
+        bias = jnp.asarray(
+            np.where(rs.rand(B, 1, 1, S) > 0.2, 0, -1e9).astype("float32"))
+    elif bias_kind == "trainable":
+        bias = jnp.asarray(rs.randn(1, H, S, S).astype("float32") * 0.1)
+    elif bias_kind == "full":
+        bias = jnp.asarray(rs.randn(B, H, S, S).astype("float32") * 0.1)
+    w_lse = jnp.asarray(rs.randn(B, H, S).astype("float32") * 0.1)
+    return q, k, v, bias, w_lse
+
+
+@pytest.mark.parametrize("blocks", ["planned", "forced_128x128"])
+@pytest.mark.parametrize("case", sorted(PLAN_PARITY_CASES))
+def test_flash_block_plan_parity(case, blocks, monkeypatch):
+    """Forward, dq/dk/dv, the bias cotangent where the bias is trainable
+    and the lse cotangent where the path has an lse output, against
+    ``composed_attention``: under the plan the shapes give, and with the
+    blocks forced to 128x128 through the override, so that the
+    multi-block carry stays covered where the plan takes one block."""
+    from paddle_tpu.observe.families import FLASH_BLOCK_PLANS
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    if blocks == "forced_128x128":
+        monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128")
+        monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "128")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_FLASH_BQ", raising=False)
+        monkeypatch.delenv("PADDLE_TPU_FLASH_BK", raising=False)
+    shape, dtype, bias_kind, causal, atol_f, atol_g = PLAN_PARITY_CASES[case]
+    D = shape[-1]
+    scale = D ** -0.5
+    q, k, v, bias, w_lse = _plan_parity_inputs(case)
+    trainable = bias_kind == "trainable"
+    f32 = jnp.float32
+
+    def flash_loss(q, k, v, bias):
+        if trainable:  # no lse output on the trainable-bias path
+            out = attention.flash_attention(q, k, v, bias, scale,
+                                            bias_grad=True)
+            return jnp.sum(out.astype(f32) ** 2), out
+        out, lse = attention.flash_attention_with_lse(q, k, v, bias, scale,
+                                                      causal)
+        return jnp.sum(out.astype(f32) ** 2) + jnp.sum(lse * w_lse), \
+            (out, lse)
+
+    def ref_loss(q, k, v, bias):
+        out, lse = _reference_with_lse(q, k, v, bias, scale, causal)
+        if trainable:
+            return jnp.sum(out.astype(f32) ** 2), out
+        return jnp.sum(out.astype(f32) ** 2) + jnp.sum(lse * w_lse), \
+            (out, lse)
+
+    argnums = (0, 1, 2, 3) if trainable else (0, 1, 2)
+    # (under autodiff the custom_vjp's forward rule runs: the rerun's name)
+    single = FLASH_BLOCK_PLANS.labels(
+        kernel=attention.KERNEL_REFWD,
+        block="%dx%d" % attention._block_plan(
+            attention.KERNEL_FWD, shape[2], shape[2], D, q.dtype, causal),
+        single_pass="1")
+    before = single.value
+    (_, got), g_got = jax.value_and_grad(flash_loss, argnums,
+                                         has_aux=True)(q, k, v, bias)
+    (_, want), g_want = jax.value_and_grad(ref_loss, argnums,
+                                           has_aux=True)(q, k, v, bias)
+    as_np = lambda t: np.asarray(t, dtype=np.float32)  # noqa: E731
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(as_np(a), as_np(b), atol=atol_f,
+                                   rtol=atol_f)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(as_np(a), as_np(b), atol=atol_g,
+                                   rtol=atol_g)
+    # the counter says which plan was lowered: the whole-axis single pass
+    # under the plan, never under the forced 128x128 blocks at these
+    # lengths
+    assert (single.value > before) == (blocks == "planned")
+
+
+_PLAN_LENGTHS = [32, 100, 128, 200, 256, 384, 500, 512, 640, 768, 896, 1000,
+                 1024, 1152, 2048, 4096]
+
+
+@pytest.mark.parametrize("S", _PLAN_LENGTHS)
+def test_flash_block_plan_is_legal_and_pads_under_a_lane_tile(S):
+    """The plan function alone: every block divides the padded length,
+    the padding stays under one lane tile (S 640 never becomes 1024),
+    Mosaic's (8, 128) rule holds for every block, and the causal and
+    want_db inputs change the plan only as its docstring says."""
+    from paddle_tpu.kernels.common import mosaic_ok, pad_len
+    from paddle_tpu.ops import attention as A
+
+    kernels = (A.KERNEL_FWD, A.KERNEL_REFWD, A.KERNEL_BWD_DKV,
+               A.KERNEL_BWD_DQ)
+    Sp = pad_len(S, 128)
+    assert 0 <= Sp - S < 128
+    for kernel in kernels:
+        for causal in (False, True):
+            for Sk in ((S,) if causal else (S, 384)):
+                Skp = pad_len(Sk, 128)
+                bq, bk = A._block_plan(kernel, S, Sk, 64, jnp.bfloat16,
+                                       causal)
+                assert Sp % bq == 0 and Skp % bk == 0, (kernel, bq, bk)
+                assert bq * bk <= A._MAX_BLOCK ** 2
+                # q/k/v blocks, the [bq, 1] statistics, a [bq, bk] bias
+                assert mosaic_ok((1, bq, 64), (4, Sp, 64))
+                assert mosaic_ok((1, bk, 64), (4, Skp, 64))
+                assert mosaic_ok((1, bq, 1), (4, Sp, 1))
+                assert mosaic_ok((1, 1, bq, bk), (1, 1, Sp, Skp))
+                # the axis the kernel reduces over is whole up to 1024
+                red, b_red = (Sp, bq) if kernel == A.KERNEL_BWD_DKV \
+                    else (Skp, bk)
+                assert b_red == red if red <= 1024 else b_red <= 512
+                # causal and a trainable bias (want_db) plan like a plain
+                # call (measured: the docstring says why)
+                assert (bq, bk) == A._block_plan(
+                    kernel, S, Sk, 64, jnp.bfloat16, False, want_db=True)
+                assert (bq, bk) == A._block_plan(
+                    kernel, S, Sk, 64, jnp.bfloat16, not causal)
+                # pure in D and dtype: the score tile is float32 [bq, bk]
+                assert (bq, bk) == A._block_plan(kernel, S, Sk, 128,
+                                                 jnp.float32, causal)
+    # the forward's rerun plans like the forward
+    assert A._block_plan(A.KERNEL_REFWD, S, S, 64, jnp.bfloat16) \
+        == A._block_plan(A.KERNEL_FWD, S, S, 64, jnp.bfloat16)
+    if S <= 512:  # one block over the whole (padded) sequence
+        assert A._block_plan(A.KERNEL_FWD, S, S, 64, jnp.bfloat16) \
+            == (Sp, Sp)
+    want = {640: (128, 640), 1024: (256, 1024), 2048: (512, 512)}.get(S)
+    if want:
+        assert A._block_plan(A.KERNEL_FWD, S, S, 64, jnp.bfloat16) == want
+        assert A._block_plan(A.KERNEL_BWD_DQ, S, S, 64, jnp.bfloat16) == want
+        assert A._block_plan(A.KERNEL_BWD_DKV, S, S, 64, jnp.bfloat16) \
+            == want[::-1]
+
+
+def test_flash_block_override_keeps_its_old_contract(monkeypatch):
+    """PADDLE_TPU_FLASH_BQ/BK override the plan axis by axis; a forced
+    axis pads to a multiple of the forced block as it always did."""
+    from paddle_tpu.ops import attention as A
+
+    args = (A.KERNEL_FWD, 500, 500, 64, jnp.bfloat16, False, False)
+    monkeypatch.delenv("PADDLE_TPU_FLASH_BQ", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_FLASH_BK", raising=False)
+    assert A._block_sizes() == (None, None)
+    assert A._resolve_blocks(*args) == (512, 512, 512, 512)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "96")
+    assert A._resolve_blocks(*args) == (576, 512, 96, 512)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "256")
+    assert A._resolve_blocks(*args) == (576, 512, 96, 256)

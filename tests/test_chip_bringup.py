@@ -87,6 +87,11 @@ FLASH_CASES = {
     # chip_smoke.py's kernels phase also runs float32 inputs
     "f32_s256": ((2, 4, 256, 64), False, None, False, F32),
     "f32_causal_s256": ((2, 4, 256, 64), True, None, False, F32),
+    # the S512 benchmark cells' exact call, and the longest causal call:
+    # the block plan's caps against the described chip's VMEM
+    "bert_cell_b32_s512": ((32, 12, 512, 64), False, (32, 1, 1, 512), False,
+                           BF16),
+    "causal_s2048": ((2, 12, 2048, 64), True, None, False, BF16),
 }
 
 

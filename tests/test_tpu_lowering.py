@@ -136,6 +136,67 @@ def test_transformer_fused_train_step_lowers_for_tpu():
     assert "tpu_custom_call" in txt  # the fused kernel survived AMP+Adam
 
 
+def test_bert_s512_train_step_holds_four_named_kernels_a_layer(monkeypatch):
+    """A two-layer BERT-shaped fused train step at S 512 exports with
+    exactly 8 ``tpu_custom_call``: forward, the grad op's rerun of the
+    forward, dK/dV and dQ for each layer, each under the name
+    ops/attention.py chose. The S512 benchmark cells check the same on
+    the chip (``expect_tpu_custom_calls`` 48 for twelve layers) and read
+    their ``flash_*_ms.train`` metrics by these names."""
+    from paddle_tpu.core.executor import analyze_block
+    from paddle_tpu.models import bert
+    from paddle_tpu.observe.families import FLASH_BLOCK_PLANS
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "0")
+    names = (attention.KERNEL_FWD, attention.KERNEL_REFWD,
+             attention.KERNEL_BWD_DKV, attention.KERNEL_BWD_DQ)
+    plans = {n: FLASH_BLOCK_PLANS.labels(kernel=n, block="512x512",
+                                         single_pass="1") for n in names}
+    before = {n: c.value for n, c in plans.items()}
+    cfg = dict(vocab=256, d_model=128, n_head=2, n_layer=2, d_ff=256,
+               max_length=512, type_vocab=2, dropout=0.1)
+    B, S, M = 2, 512, 8
+    main, startup = fluid.Program(), fluid.Program()
+    scope = Scope()
+    with scope_guard(scope):
+        with fluid.program_guard(main, startup):
+            loss, _ = bert.build(cfg, seq_len=S, max_mask=M)
+            fluid.optimizer.Adam(learning_rate=1e-4).minimize(loss)
+        main.set_amp(True)
+        exe = fluid.Executor(fluid.TPUPlace())
+        exe.run(startup, scope=scope)
+        rs = np.random.RandomState(0)
+        feed = {
+            "src_ids": rs.randint(1, 256, (B, S)).astype("int32"),
+            "sent_ids": rs.randint(0, 2, (B, S)).astype("int32"),
+            "input_mask": np.ones((B, S), "float32"),
+            "mask_pos": rs.randint(0, B * S, (B, M)).astype("int32"),
+            "mask_label": rs.randint(0, 256, (B, M)).astype("int32"),
+            "mask_weight": np.ones((B, M), "float32"),
+        }
+        (feed_names, _fetch, const_state, mut_state, _written, _rng,
+         step) = analyze_block(main, sorted(feed), [loss.name], scope)
+        params = {n: np.asarray(scope.find_var(n))
+                  for n in const_state + mut_state}
+        rng = jax.random.PRNGKey(0)
+
+        def fn(feeds, const_vals, mut_vals):
+            fetches, new_mut, _, _ = step(feeds, const_vals, mut_vals, rng)
+            return fetches[0], new_mut
+
+        exp = _tpu_export(fn, [feed[n] for n in feed_names],
+                          [params[n] for n in const_state],
+                          [params[n] for n in mut_state])
+    txt = exp.mlir_module()
+    assert txt.count("stablehlo.custom_call @tpu_custom_call") == 8
+    for name in names:
+        assert txt.count('kernel_name = "%s"' % name) == cfg["n_layer"], name
+        # the plan the step holds, as the program's counter reports it: one
+        # 512x512 block a head, the carry dropped
+        assert plans[name].value - before[name] == cfg["n_layer"], name
+
+
 def test_ring_flash_attention_lowers_for_tpu_sharded(monkeypatch):
     """Sequence-parallel ring attention with the fused per-step flash
     kernel: the sharded (shard_map over an 'sp' axis) program lowers for

@@ -199,7 +199,7 @@ def main(argv=None) -> int:
                          "per BQxBK cell (requires --op attention)")
     ap.add_argument("--bq", default="128,256,512",
                     help="bench-sweep BQ values (multiples of 8)")
-    ap.add_argument("--bk", default="128,256",
+    ap.add_argument("--bk", default="128,256,512",
                     help="bench-sweep BK values (multiples of 128)")
     ap.add_argument("--timeout", type=int, default=900,
                     help="bench-sweep per-config deadline, seconds")
