@@ -340,6 +340,24 @@ def _cost_conv(ctx):
 # softmax, and the composed path materializes the [*, Sq, Sk] scores
 # (extra bytes beyond declared inputs/outputs — the memory engine's
 # _fp_attention budgets the same tensor)
+@register_cost_rule("moe_ffn")
+def _cost_moe_ffn(ctx):
+    """FLOPs: (token, expert) pairs x 2*D*F for each expert matrix (three
+    for swiglu experts: 6*D*F a pair; two for relu experts), plus the
+    router's 2*D*E a token. Bytes beyond the declared inputs and outputs:
+    none — the generic model already reads every stacked expert once,
+    which is the upper bound of "the experts touched"; a step of few
+    tokens touches fewer, and which ones is data."""
+    xs, w1 = ctx.input_shape("X"), ctx.input_shape("W1")
+    tokens = ctx.elems(None if xs is None else tuple(xs[:-1]))
+    if tokens is None or w1 is None or len(w1) != 3:
+        return ctx.out_elems()
+    E, D, F = (int(d) for d in w1)
+    mats = 3 if ctx.n_inputs("W1V") else 2
+    k = int(ctx.attr("top_k", 1) or 1)
+    return tokens.scaled(k * mats * 2 * D * F + 2 * D * E)
+
+
 @register_cost_rule("fused_attention")
 def _cost_attention(ctx):
     qs, ks = ctx.input_shape("Q"), ctx.input_shape("K")

@@ -357,6 +357,24 @@ def _fp_attention(ctx):
     return BytesPoly.from_dims(dims, 4)
 
 
+@register_footprint_rule("moe_ffn")
+def _fp_moe_ffn(ctx):
+    """The sorted pairs: top_k copies of the tokens at width D (the
+    gathered rows and the down-projection's output before the gated
+    sum) and at the experts' width F (the hidden rows; swiglu's two
+    up-projections fuse in the kernel and count once more for the
+    composed form). The stacked [E, D, F] parameters are persistable
+    state the baseline already holds."""
+    xs, w1 = ctx.input_shape("X"), ctx.input_shape("W1")
+    if xs is None or w1 is None or len(w1) != 3 or len(xs) < 1:
+        return None
+    k = int(ctx.op.attrs.get("top_k", 1) or 1)
+    tokens = tuple(xs[:-1])
+    D, F = int(w1[1]), int(w1[2])
+    per_row = 2 * D + (3 if ctx.op.inputs.get("W1V") else 2) * F
+    return BytesPoly.from_dims(tokens + (k * per_row,), 4)
+
+
 @register_footprint_rule("softmax", "log_softmax",
                          "softmax_with_cross_entropy", "cross_entropy")
 def _fp_softmax(ctx):

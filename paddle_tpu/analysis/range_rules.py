@@ -816,6 +816,34 @@ def _rr_kv_cache_write(ctx):
     ctx.set("Out", ctx.input_av("Cache").join(ctx.input_av("Value")))
 
 
+@register_range_rule("moe_ffn")
+def _rr_moe_ffn(ctx):
+    """Each token's output is a sum of top_k expert outputs, each scaled
+    by a gate in [0, 1]: top_k times the envelope of one expert — two
+    chained contractions (D-wide, then F-wide; swiglu multiplies the two
+    up-projections, |silu(g)| <= |g|) plus the biases where present."""
+    w1s = ctx.input_shape("W1")
+    D = w1s[1] if w1s and len(w1s) == 3 and w1s[1] >= 0 else None
+    F = w1s[2] if w1s and len(w1s) == 3 and w1s[2] >= 0 else None
+    x = ctx.input_av("X")
+    h = _contraction(ctx, x, ctx.input_av("W1"), D)
+    if ctx.num_inputs("B1"):
+        h = av_add(h, ctx.input_av("B1"))
+    if ctx.num_inputs("W1V"):
+        h = av_mul(_sym(h), _contraction(ctx, x, ctx.input_av("W1V"), D))
+    else:
+        h = av_max_const(h, 0.0)
+    y = _contraction(ctx, h, ctx.input_av("W2"), F)
+    if ctx.num_inputs("B2"):
+        y = av_add(y, ctx.input_av("B2"))
+    k = float(int(ctx.attr("top_k", 1) or 1))
+    ctx.set("Out", av_mul(_sym(y), av_interval(0.0, k)))
+    if ctx.op.outputs.get("AuxLoss"):
+        ctx.set("AuxLoss", AbstractValue(0.0, _INF, finite=x.bounded))
+    if ctx.op.outputs.get("CountsOut"):
+        ctx.set("CountsOut", AbstractValue(0.0, _INF))
+
+
 @register_range_rule("rope")
 def _rr_rope(ctx):
     # x*cos + rotate(x)*sin: magnitude at most sqrt(2) * max|x|

@@ -798,6 +798,50 @@ def _r_rms_norm(ctx):
         ctx.set("Y", xs)
 
 
+@register_shape_rule("moe_ffn")
+def _r_moe_ffn(ctx):
+    """Out is X's shape, AuxLoss a float32 scalar, the routing tally its
+    input's shape; the stacked parameters must agree with one another:
+    W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [D, E], biases [E, F] and
+    [E, D]. The group sizes are data: nothing in a shape depends on
+    them."""
+    xs = ctx.input_shape("X")
+    if xs is not None:
+        ctx.set("Out", xs)
+    ctx.set("AuxLoss", (), dtype="float32")
+    if "CountsOut" in ctx.op.outputs:
+        cs = ctx.input_shape("Counts")
+        if cs is not None:
+            ctx.set("CountsOut", cs, dtype="int32")
+    w1, w2 = ctx.input_shape("W1"), ctx.input_shape("W2")
+    gate = ctx.input_shape("Gate")
+    E = int(ctx.attr("n_experts", 0) or 0)
+    if not all(is_concrete(s) for s in (w1, w2, gate) if s is not None):
+        return
+    if w1 is not None and w2 is not None and (
+            len(w1) != 3 or len(w2) != 3
+            or (w1[0], w1[2], w1[1]) != tuple(w2)):
+        ctx.fail("expert weights W1 %s and W2 %s are not [E, D, F] and "
+                 "[E, F, D]" % (w1, w2))
+    for slot, want in (("W1V", w1),
+                       ("B1", None if w1 is None else (w1[0], w1[2])),
+                       ("B2", None if w2 is None else (w2[0], w2[2])),
+                       ("Gate", None if w1 is None else (w1[1], w1[0]))):
+        got = ctx.input_shape(slot)
+        if got is not None and want is not None and is_concrete(got) \
+                and tuple(got) != tuple(want):
+            ctx.fail("%s is %s, the expert weights ask for %s"
+                     % (slot, got, want))
+    if w1 is not None and E and w1[0] != E:
+        ctx.fail("n_experts=%d but W1 stacks %d experts" % (E, w1[0]))
+    if xs is not None and w1 is not None and xs[-1] >= 0 \
+            and xs[-1] != w1[1]:
+        ctx.fail("X's width %d is not the experts' %d" % (xs[-1], w1[1]))
+    k = int(ctx.attr("top_k", 1) or 1)
+    if E and not 1 <= k <= E:
+        ctx.fail("top_k=%d outside [1, n_experts=%d]" % (k, E))
+
+
 @register_shape_rule("group_norm")
 def _r_group_norm(ctx):
     xs = ctx.input_shape("X")

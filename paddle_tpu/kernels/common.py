@@ -70,7 +70,8 @@ def assert_mosaic_ok(block_shape, array_shape, what) -> None:
 
 
 def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
-                        out_shape, scratch_shapes, interpret):
+                        out_shape, scratch_shapes, interpret,
+                        scalar_prefetch=(), compiler_params=None):
     """``pl.pallas_call`` with the Mosaic legality mirror applied to every
     operand/output spec first, and shard_map vma propagation (outputs
     vary over every mesh axis an operand does — ring attention runs the
@@ -80,7 +81,12 @@ def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
     in a device profile and in the lowered HLO (Pallas enters a
     ``jax.named_scope(name)`` round the call and hands Mosaic the same
     ``kernel_name``), so it has to be one the call site chose and not
-    whatever autodiff wrapper happens to surround it."""
+    whatever autodiff wrapper happens to surround it.
+
+    ``scalar_prefetch`` arrays (int32, SMEM) come before the operands in
+    the kernel's arguments and after the grid indices in every index
+    map (``pltpu.PrefetchScalarGridSpec``); ``compiler_params`` goes to
+    Mosaic as given."""
     from jax.experimental import pallas as pl
 
     single_out = not isinstance(out_specs, (list, tuple))
@@ -95,10 +101,23 @@ def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
         shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma)
                   for s in shapes]
         out_shape = shapes if not single_out else shapes[0]
+    extra = {} if compiler_params is None \
+        else {"compiler_params": compiler_params}
+    if scalar_prefetch:
+        from jax.experimental.pallas import tpu as pltpu
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalar_prefetch), grid=grid,
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes)
+        return pl.pallas_call(
+            kern, grid_spec=grid_spec, out_shape=out_shape,
+            interpret=interpret, name=name, **extra)(
+                *scalar_prefetch, *operands)
     return pl.pallas_call(
         kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch_shapes,
-        interpret=interpret, name=name)(*operands)
+        interpret=interpret, name=name, **extra)(*operands)
 
 
 def ceil_to(n: int, b: int) -> int:

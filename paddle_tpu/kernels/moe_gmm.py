@@ -1,0 +1,235 @@
+"""Grouped matmul over ragged groups: the expert layer's two products.
+
+``lhs [M, K]`` holds rows sorted by group (expert), ``group_sizes [E]``
+says how many consecutive rows each group owns, ``rhs [E, K, N]`` is one
+matrix a group; row ``r`` of group ``g`` becomes ``lhs[r] @ rhs[g]``.
+Rows past ``sum(group_sizes)`` belong to no group and come out zero.
+
+Two forms of the one function, as ``composed_attention`` stands beside
+the flash kernels:
+
+* ``gmm_composed`` — ``jax.lax.ragged_dot``. What the CPU runs, what the
+  tests compare the kernel with, and what differentiates.
+* ``gmm_pallas`` — the Pallas kernel. The grid is (column tiles, work
+  tiles, reduction tiles); a WORK TILE is one (group, row tile) meeting,
+  so a row tile that straddles a group boundary is visited once a group
+  and stores under a row mask, and the group's ``[tk, tn]`` weight block
+  is fetched once for all the row tiles the group touches (consecutive
+  work tiles of one group keep the block index, so Pallas issues no new
+  DMA). Group ids, row-tile ids and group offsets are scalar-prefetched;
+  the static grid holds the worst case ``tiles_m + E - 1`` work tiles and
+  the ones past the real count repeat the last block indices and skip
+  their body. With two ``rhs`` (gate and up) the kernel accumulates both
+  products in one pass over ``lhs`` and stores ``silu(gate) * up``.
+
+``gmm`` chooses: the kernel where Pallas compiles (``use_interpret()`` is
+false, i.e. on a TPU) and the tile plan is legal, the composed form
+elsewhere. Its gradient is the composed form's (``custom_vjp``), so a
+training step differentiates whichever form its forward holds.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .common import ceil_to, checked_pallas_call, mosaic_ok, use_interpret
+
+__all__ = ["gmm", "gmm_composed", "gmm_pallas", "gmm_plan",
+           "KERNEL_UP", "KERNEL_DOWN"]
+
+# the names the device trace and the HLO show the two calls under
+KERNEL_UP = "moe_gmm_up"
+KERNEL_DOWN = "moe_gmm_down"
+
+_TM = 128                      # row tile: one MXU pass of rows
+_TK_CHOICES = (1024, 512, 256, 128)
+_TN_CHOICES = (512, 256, 128)
+_MAX_RHS_BLOCK_BYTES = 4 << 20
+_VMEM_LIMIT_BYTES = 48 << 20   # of the v5e's 128 MiB; blocks use ~12
+
+
+def _silu_mul(gate, up):
+    return gate * jax.nn.sigmoid(gate) * up
+
+
+def gmm_composed(lhs, rhs, group_sizes):
+    """``jax.lax.ragged_dot`` per ``rhs``; two give ``silu(a) * b``."""
+    rhs = rhs if isinstance(rhs, (list, tuple)) else (rhs,)
+    outs = [jax.lax.ragged_dot(lhs, w.astype(lhs.dtype),
+                               group_sizes.astype(jnp.int32))
+            for w in rhs]
+    return outs[0] if len(outs) == 1 else _silu_mul(*outs)
+
+
+def gmm_plan(M, K, N, itemsize=4):
+    """``(tm, tk, tn)`` or None where no legal plan exists (the caller
+    then takes the composed form). ``tk``/``tn`` must divide ``K``/``N``
+    — padding ``rhs`` would copy every expert's weights — or be the
+    whole axis."""
+    tm = min(_TM, ceil_to(max(int(M), 1), 8))
+    tk = next((t for t in _TK_CHOICES if K % t == 0), K)
+    tn = next((t for t in _TN_CHOICES if N % t == 0), N)
+    if tk * tn * itemsize > _MAX_RHS_BLOCK_BYTES:
+        return None
+    if not (mosaic_ok((1, tk, tn), (1, K, N))
+            and mosaic_ok((tm, tk), (ceil_to(M, tm), K))):
+        return None
+    return tm, tk, tn
+
+
+def _work_tiles(group_sizes, M, tm, n_work):
+    """Scalar-prefetch metadata: for each of the ``n_work`` static work
+    tiles its group and row tile, the group offsets, and how many work
+    tiles are real."""
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first_tile = starts // tm
+    touched = jnp.where(sizes > 0, (ends - 1) // tm - first_tile + 1, 0)
+    tile_ends = jnp.cumsum(touched)
+    num = tile_ends[-1]
+    w = jnp.arange(n_work, dtype=jnp.int32)
+    # tiles past the real count repeat the last real one: same block
+    # indices, so nothing new is fetched, and the body is skipped
+    wc = jnp.minimum(w, jnp.maximum(num - 1, 0))
+    gid = jnp.searchsorted(tile_ends, wc, side="right").astype(jnp.int32)
+    gid = jnp.minimum(gid, sizes.shape[0] - 1)
+    mid = first_tile[gid] + (wc - (tile_ends[gid] - touched[gid]))
+    mid = jnp.clip(mid, 0, ceil_to(M, tm) // tm - 1).astype(jnp.int32)
+    return gid, mid, offsets, num.reshape((1,)).astype(jnp.int32)
+
+
+def _kernel(n_rhs, tm, nk, mxu_dtype, gid_ref, mid_ref, off_ref, num_ref,
+            lhs_ref, *refs):
+    from jax.experimental import pallas as pl
+
+    rhs_refs, out_ref, acc_refs = (refs[:n_rhs], refs[n_rhs],
+                                   refs[n_rhs + 1:])
+    w, k = pl.program_id(1), pl.program_id(2)
+    active = w < num_ref[0]
+
+    @pl.when(jnp.logical_and(active, k == 0))
+    def _():
+        for acc in acc_refs:
+            acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(active)
+    def _():
+        a = lhs_ref[...].astype(mxu_dtype)
+        for rhs, acc in zip(rhs_refs, acc_refs):
+            # bf16 operands are one MXU pass whatever the ambient
+            # default_matmul_precision asks of float32 products
+            acc[...] += jnp.dot(a, rhs[0].astype(mxu_dtype),
+                                precision=jax.lax.Precision.DEFAULT,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(active, k == nk - 1))
+    def _():
+        g, m = gid_ref[w], mid_ref[w]
+        rows = m * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mine = jnp.logical_and(rows >= off_ref[g], rows < off_ref[g + 1])
+        # the first visit of a row tile clears what no group owns
+        first = jnp.logical_or(w == 0, mid_ref[jnp.maximum(w - 1, 0)] != m)
+        val = acc_refs[0][...] if n_rhs == 1 \
+            else _silu_mul(acc_refs[0][...], acc_refs[1][...])
+        keep = jnp.where(first, jnp.zeros_like(val),
+                         out_ref[...].astype(val.dtype))
+        out_ref[...] = jnp.where(mine, val, keep).astype(out_ref.dtype)
+
+
+def gmm_pallas(lhs, rhs, group_sizes, *, name, plan=None, interpret=None,
+               mxu_dtype=None):
+    """The kernel. ``mxu_dtype`` is what the operands are cast to for
+    the matmul (accumulation is float32 always): None keeps their own
+    dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rhs = tuple(rhs) if isinstance(rhs, (list, tuple)) else (rhs,)
+    M, K = lhs.shape
+    E, _, N = rhs[0].shape
+    plan = plan or gmm_plan(M, K, N, rhs[0].dtype.itemsize)
+    if plan is None:
+        raise ValueError("moe_gmm: no legal tile plan for [%d, %d] x "
+                         "[%d, %d, %d]" % (M, K, E, K, N))
+    tm, tk, tn = plan
+    if interpret is None:
+        interpret = use_interpret()
+    Mp = ceil_to(M, tm)
+    if Mp != M:
+        lhs = jnp.pad(lhs, ((0, Mp - M), (0, 0)))
+    tiles_m, nk, nn = Mp // tm, K // tk, N // tn
+    n_work = tiles_m + E - 1
+    gid, mid, offsets, num = _work_tiles(group_sizes, M, tm, n_work)
+
+    _note_plan(name, plan, "pallas")
+    in_specs = [pl.BlockSpec((tm, tk),
+                             lambda n, w, k, gid, mid, off, num:
+                             (mid[w], k))]
+    in_specs += [pl.BlockSpec((1, tk, tn),
+                              lambda n, w, k, gid, mid, off, num:
+                              (gid[w], k, n))] * len(rhs)
+    out = checked_pallas_call(
+        functools.partial(_kernel, len(rhs), tm, nk,
+                          mxu_dtype or lhs.dtype),
+        name=name, grid=(nn, n_work, nk), in_specs=in_specs,
+        operands=(lhs,) + rhs,
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda n, w, k, gid, mid, off, num:
+                               (mid[w], n)),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), lhs.dtype),
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * len(rhs),
+        interpret=interpret,
+        scalar_prefetch=(gid, mid, offsets, num),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES))
+    # row tiles that no work tile visited were never written
+    owned = jnp.arange(Mp, dtype=jnp.int32)[:, None] < offsets[-1]
+    return jnp.where(owned, out, jnp.zeros_like(out))[:M]
+
+
+def _note_plan(name, plan, form):
+    from ..observe.families import MOE_GMM_PLANS
+
+    tile = "-" if plan is None else "%dx%dx%d" % plan
+    MOE_GMM_PLANS.labels(kernel=name, tile=tile, form=form).inc()
+
+
+def gmm(lhs, rhs, group_sizes, *, name):
+    """``lhs @ rhs[group of each row]`` in whichever form this backend
+    holds: the Pallas kernel where it compiles and a tile plan exists,
+    the composed form otherwise (CPU: interpret mode is for tests of the
+    kernel, not for running models). float32 operands meet the MXU as
+    bfloat16, as every other float32 matmul of a compiled step does at
+    the TPU's default precision. Differentiable through the composed
+    form."""
+    rhs = tuple(rhs) if isinstance(rhs, (list, tuple)) else (rhs,)
+    M, K = lhs.shape
+    N = rhs[0].shape[2]
+    plan = None if use_interpret() else gmm_plan(
+        M, K, N, rhs[0].dtype.itemsize)
+    if plan is None:
+        _note_plan(name, None, "composed")
+        return gmm_composed(lhs, rhs, group_sizes)
+    mxu = jnp.bfloat16 if lhs.dtype == jnp.float32 else None
+
+    @jax.custom_vjp
+    def run(lhs, rhs):
+        return gmm_pallas(lhs, rhs, group_sizes, name=name, plan=plan,
+                          interpret=False, mxu_dtype=mxu)
+
+    def fwd(lhs, rhs):
+        return run(lhs, rhs), (lhs, rhs)
+
+    def bwd(res, g):
+        _, vjp = jax.vjp(
+            lambda a, b: gmm_composed(a, b, group_sizes), *res)
+        return vjp(g)
+
+    run.defvjp(fwd, bwd)
+    return run(lhs, rhs)

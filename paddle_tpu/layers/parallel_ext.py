@@ -132,8 +132,11 @@ def pipeline(x: Variable, n_stages: int,
 
 def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             capacity: Optional[int] = None, top_k: int = 1,
-            z_loss: float = 0.0, name: Optional[str] = None):
-    """Switch/GShard mixture-of-experts FFN (see ops/moe_ops.py).
+            z_loss: float = 0.0, name: Optional[str] = None,
+            act: str = "relu", dropless: bool = False,
+            norm_topk: Optional[bool] = None, param_prefix=None,
+            counts: Optional[Variable] = None, counts_row: int = 0):
+    """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
     where out has x's shape and aux is the Switch load-balancing loss
@@ -147,38 +150,80 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     mesh with an 'expert' axis of size n_experts the tokens shuffle to
     their expert's device with all_to_all, otherwise every expert
     computes locally (identical math).
+
+    ``act``: 'relu' experts ``relu(x W1 + b1) W2 + b2`` (the default) or
+    'swiglu' experts ``(silu(x Wg) * (x Wu)) Wd`` without biases.
+    ``dropless=True`` computes every (token, expert) pair; otherwise
+    ``capacity`` (default ``ceil(2 T top_k / E)``) bounds the pairs an
+    expert takes and the overflow contributes zero. ``norm_topk``: None
+    renormalises the k gates when top_k > 1 (GShard), False keeps the
+    raw router probabilities, True always renormalises.
+    ``param_prefix`` names the parameters ``<prefix>_{gate,up,down,
+    router}.w_0`` (swiglu) so that several programs share them by name.
+    ``counts`` is a persistable [rows, n_experts] int32 var: the op adds
+    the pairs it routed to each expert to row ``counts_row``, in place
+    on the device.
     """
     if not 1 <= int(top_k) <= int(n_experts):
         raise ValueError(
             "moe_ffn top_k must be in [1, n_experts]; got top_k=%s with "
             "n_experts=%s" % (top_k, n_experts))
+    if act not in ("relu", "swiglu"):
+        raise ValueError("moe_ffn act must be 'relu' or 'swiglu'; got %r"
+                         % (act,))
+    if dropless and capacity:
+        raise ValueError("moe_ffn: dropless=True takes no capacity")
     helper = LayerHelper("moe_ffn", name=name)
     D = int(x.shape[-1])
     mk = helper.create_parameter  # stacked expert weights + router
-    w1 = mk(ParamAttr(), [n_experts, D, d_hidden], "float32")
-    b1 = mk(ParamAttr(initializer=Constant(0.0)), [n_experts, d_hidden],
-            "float32", is_bias=True)
-    w2 = mk(ParamAttr(), [n_experts, d_hidden, D], "float32")
-    b2 = mk(ParamAttr(initializer=Constant(0.0)), [n_experts, D],
-            "float32", is_bias=True)
-    gate = mk(ParamAttr(), [D, n_experts], "float32")
+
+    def attr(part, **kw):
+        return ParamAttr(name=None if param_prefix is None
+                         else "%s_%s.w_0" % (param_prefix, part), **kw)
+
+    inputs = {"X": [x]}
+    if act == "swiglu":
+        inputs["W1"] = [mk(attr("gate"), [n_experts, D, d_hidden],
+                           "float32")]
+        inputs["W1V"] = [mk(attr("up"), [n_experts, D, d_hidden],
+                            "float32")]
+        inputs["W2"] = [mk(attr("down"), [n_experts, d_hidden, D],
+                           "float32")]
+    else:
+        inputs["W1"] = [mk(ParamAttr(), [n_experts, D, d_hidden],
+                           "float32")]
+        inputs["B1"] = [mk(ParamAttr(initializer=Constant(0.0)),
+                           [n_experts, d_hidden], "float32",
+                           is_bias=True)]
+        inputs["W2"] = [mk(ParamAttr(), [n_experts, d_hidden, D],
+                           "float32")]
+        inputs["B2"] = [mk(ParamAttr(initializer=Constant(0.0)),
+                           [n_experts, D], "float32", is_bias=True)]
+    inputs["Gate"] = [mk(attr("router"), [D, n_experts], "float32")]
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference("float32")
-    helper.append_op(
-        type="moe_ffn",
-        inputs={"X": [x], "W1": [w1], "B1": [b1], "W2": [w2], "B2": [b2],
-                "Gate": [gate]},
-        outputs={"Out": [out], "AuxLoss": [aux]},
-        attrs={"n_experts": int(n_experts),
-               "capacity": int(capacity) if capacity else 0,
-               "top_k": int(top_k),
-               "z_loss": float(z_loss),
-               "axis": "expert"})
+    outputs = {"Out": [out], "AuxLoss": [aux]}
+    attrs = {"n_experts": int(n_experts),
+             "capacity": int(capacity) if capacity else 0,
+             "top_k": int(top_k),
+             "z_loss": float(z_loss),
+             "act": act,
+             "dropless": bool(dropless),
+             "axis": "expert"}
+    if norm_topk is not None:
+        attrs["norm_topk"] = bool(norm_topk)
+    if counts is not None:
+        inputs["Counts"] = [counts]
+        outputs["CountsOut"] = [counts]
+        attrs["counts_row"] = int(counts_row)
+    helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     out.shape = x.shape
     aux.shape = ()
     prog = helper.main_program
     ep = getattr(prog, "_expert_params", None)
     if ep is None:
         ep = prog._expert_params = []
-    ep.extend([w1.name, b1.name, w2.name, b2.name])
+    ep.extend(v[0].name for slot, v in inputs.items()
+              if slot in ("W1", "W1V", "B1", "W2", "B2"))
     return out, aux

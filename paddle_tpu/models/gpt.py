@@ -16,7 +16,7 @@ masked out of the loss.
 from .. import layers
 from ..param_attr import ParamAttr
 from .transformer import (_causal_bias, _ffn, _pad_bias, _prenorm,
-                          multi_head_attention)
+                          multi_head_attention, qk_norm)
 
 __all__ = ["base_config", "build"]
 
@@ -27,7 +27,28 @@ def base_config():
     smaller k/v projections and an H/Hkv-times smaller KV cache;
     ``pos_emb='rope'`` — rotary positions instead of the learned
     table; ``norm='rms'`` — RMSNorm (scale-only, f32 rsqrt);
-    ``ffn_act='swiglu'`` — the gated FFN; ``tie_embeddings=True`` — one table serves lookup and LM head."""
+    ``ffn_act='swiglu'`` — the gated FFN; ``tie_embeddings=True`` — one table serves lookup and LM head.
+
+    Sparse experts in place of the dense FFN (``layers.moe_ffn``,
+    dropless, SwiGLU experts without biases): ``n_expert`` experts of
+    width ``d_expert``, ``expert_top_k`` a token. ``norm_topk`` says
+    whether the k router probabilities of a token are renormalised to
+    sum to one: OLMoE does not renormalise the eight (``False``, the
+    default here); most later models do. ``qk_norm=True`` — RMSNorm on
+    the projected q and k before the head split; ``norm_eps`` — the
+    RMSNorm epsilon (default 1e-6); ``rope_theta`` — the RoPE base
+    (default 10000). OLMoE-1B-7B, as a worked example (published widths,
+    all 16 layers; ``d_ff`` is not read when ``n_expert`` is set)::
+
+        dict(d_model=2048, n_head=16, n_layer=16, vocab=50304,
+             max_length=4096, dropout=0.0, pos_emb="rope", norm="rms",
+             norm_eps=1e-5, rope_theta=10000.0, qk_norm=True,
+             n_expert=64, expert_top_k=8, d_expert=1024,
+             norm_topk=False)
+
+    Expert weights are stacked parameters ``gpt_<i>_moe_{gate,up,down}
+    .w_0`` ([E, D, F], [E, D, F], [E, F, D]) and ``gpt_<i>_moe_router
+    .w_0`` ([D, E]), the same names in every build."""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -35,8 +56,13 @@ def base_config():
 _CFG_KEYS = frozenset([
     "d_model", "d_ff", "n_head", "n_layer", "vocab", "max_length",
     "dropout", "n_kv_head", "pos_emb", "norm", "ffn_act",
-    "tie_embeddings",
+    "tie_embeddings", "n_expert", "expert_top_k", "d_expert",
+    "norm_topk", "qk_norm", "norm_eps", "rope_theta",
 ])
+
+# the device-side tally of routed (token, expert) pairs the serving
+# decode step adds to: [n_layer, n_expert] int32, persistable
+ROUTED_PAIRS_VAR = "gpt_moe_routed_pairs"
 
 
 def _check_cfg(cfg):
@@ -56,6 +82,16 @@ def _check_cfg(cfg):
         if val is not None and val not in allowed:
             raise ValueError("cfg[%r] must be one of %s; got %r"
                              % (key, allowed, val))
+    if cfg.get("n_expert"):
+        for key in ("expert_top_k", "d_expert"):
+            if not cfg.get(key):
+                raise ValueError("cfg['n_expert'] needs cfg[%r]" % key)
+        if not 1 <= cfg["expert_top_k"] <= cfg["n_expert"]:
+            raise ValueError(
+                "cfg['expert_top_k'] must be in [1, n_expert]; got %r of "
+                "%r" % (cfg["expert_top_k"], cfg["n_expert"]))
+    elif "d_ff" not in cfg:
+        raise ValueError("cfg needs 'd_ff' (or 'n_expert' experts)")
 
 
 def _lm_head(cfg, x):
@@ -73,11 +109,57 @@ def _lm_head(cfg, x):
                      param_attr=ParamAttr(name="gpt_out_proj.w_0"))
 
 
+def _rms_eps(cfg):
+    return cfg.get("norm_eps") or 1e-6
+
+
+def _rope_base(cfg):
+    return cfg.get("rope_theta") or 10000.0
+
+
+def _rope(cfg, x, pos):
+    return layers.rope(x, pos, base=_rope_base(cfg))
+
+
+def _qk_norm(cfg, q, k, nm):
+    """cfg['qk_norm']: RMSNorm of the projected q and k before the head
+    split (inference graphs; parameter names as multi_head_attention)."""
+    if not cfg.get("qk_norm"):
+        return q, k
+    return qk_norm(q, k, nm + "_att", _rms_eps(cfg))
+
+
+def _routed_pairs_var(cfg, helper):
+    """The persistable tally the serving decode step's expert layers add
+    to, or None for a dense model."""
+    if not cfg.get("n_expert"):
+        return None
+    return helper.create_global_variable(
+        name=ROUTED_PAIRS_VAR, shape=(cfg["n_layer"], cfg["n_expert"]),
+        dtype="int32")
+
+
+def _mlp(cfg, h, nm, layer, counts=None):
+    """The block's second half, behind every builder's one call: the
+    dense FFN, or — cfg['n_expert'] — dropless top-k routing over SwiGLU
+    experts (the load-balancing loss is not part of the LM loss here)."""
+    if not cfg.get("n_expert"):
+        return _ffn(h, cfg["d_model"], cfg["d_ff"], nm,
+                    act=cfg.get("ffn_act", "relu"))
+    out, _aux = layers.moe_ffn(
+        h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
+        act="swiglu", dropless=True,
+        norm_topk=bool(cfg.get("norm_topk", False)),
+        param_prefix=nm + "_moe", counts=counts, counts_row=layer)
+    return out
+
+
 def _final_norm(cfg, x):
     """The shared final norm (training build + decode step use the SAME
     parameter names, so decode can overwrite by name)."""
     if cfg.get("norm", "layer") == "rms":
         return layers.rms_norm(x, begin_norm_axis=2,
+                               epsilon=_rms_eps(cfg),
                                param_attr=ParamAttr(name="gpt_ln_f_s"))
     return layers.layer_norm(x, begin_norm_axis=2,
                              param_attr=ParamAttr(name="gpt_ln_f_s"),
@@ -89,6 +171,7 @@ def _norm_of(cfg, t, prefix):
     matching the training build's _prenorm parameter names."""
     if cfg.get("norm", "layer") == "rms":
         return layers.rms_norm(t, begin_norm_axis=2,
+                               epsilon=_rms_eps(cfg),
                                param_attr=ParamAttr(name=prefix + "_ln_s"))
     return layers.layer_norm(t, begin_norm_axis=2,
                              param_attr=ParamAttr(name=prefix + "_ln_s"),
@@ -186,19 +269,20 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
         x = layers.dropout(x, cfg["dropout"], is_test=is_test)
 
     norm = cfg.get("norm", "layer")
-    ffn_act = cfg.get("ffn_act", "relu")
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
             h, h, self_bias, cfg["d_model"], cfg["n_head"], cfg["dropout"],
             is_test, nm + "_att", use_fused_attention,
             causal=self_causal, n_kv_head=cfg.get("n_kv_head"),
-            rope_pos=rope_pos, segment_ids=self_seg),
-            cfg["dropout"], is_test, nm + "_pre1", norm=norm)
-        x = _prenorm(x, lambda h, nm=nm: _ffn(h, cfg["d_model"],
-                                              cfg["d_ff"], nm,
-                                              act=ffn_act),
-                     cfg["dropout"], is_test, nm + "_pre2", norm=norm)
+            rope_pos=rope_pos, segment_ids=self_seg,
+            qk_norm_eps=_rms_eps(cfg) if cfg.get("qk_norm") else None,
+            rope_base=_rope_base(cfg)),
+            cfg["dropout"], is_test, nm + "_pre1", norm=norm,
+            rms_eps=_rms_eps(cfg))
+        x = _prenorm(x, lambda h, nm=nm, i=i: _mlp(cfg, h, nm, i),
+                     cfg["dropout"], is_test, nm + "_pre2", norm=norm,
+                     rms_eps=_rms_eps(cfg))
         if checkpoints is not None:
             checkpoints.append(x)
     x = _final_norm(cfg, x)
@@ -275,6 +359,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         x = layers.elementwise_add(word, pos)
 
     bias = _causal_bias(P)
+    routed = None      # only the serving decode step tallies its routing
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
@@ -293,6 +378,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
                       bias_attr=False,
                       param_attr=ParamAttr(name=nm + "_att_v.w_0"))
+        q, k = _qk_norm(cfg, q, k, nm)
 
         def heads(t, n):
             t = layers.reshape(t, [-1, P, n, d_head])
@@ -300,8 +386,8 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
 
         q, k, v = heads(q, n_head), heads(k, n_kv), heads(v, n_kv)
         if use_rope:
-            q = layers.rope(q, pos_range)
-            k = layers.rope(k, pos_range)
+            q = _rope(cfg, q, pos_range)
+            k = _rope(cfg, k, pos_range)
         # one slab write per layer: the cache holds rotated keys
         layers.kv_cache_write(ck, k, zero)
         layers.kv_cache_write(cv, v, zero)
@@ -320,8 +406,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         x = layers.elementwise_add(x, att)
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _ffn(h2, d_model, cfg["d_ff"], nm,
-                 act=cfg.get("ffn_act", "relu"))
+        f = _mlp(cfg, h2, nm, i, counts=routed)
         x = layers.elementwise_add(x, f)
 
     x = _final_norm(cfg, x)
@@ -404,6 +489,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         bias, [-1 if per_slot_pos else 1, 1, 1, max_len])
 
     n_kv, g = _kv_heads_of(cfg)
+    routed = _routed_pairs_var(cfg, helper) if per_slot_pos else None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
@@ -424,6 +510,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
                       bias_attr=False,
                       param_attr=ParamAttr(name=nm + "_att_v.w_0"))
+        q, k = _qk_norm(cfg, q, k, nm)
 
         def kv_heads(t):
             t = layers.reshape(t, [-1, 1, n_kv, d_head])
@@ -435,7 +522,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             # so dot products against it are relative-position exact.
             # Per-slot [B, 1] positions broadcast per-row angles over
             # the head axis — each slot rotates at ITS position
-            k = layers.rope(k, pos)
+            k = _rope(cfg, k, pos)
         ck = layers.kv_cache_write(ck, k, pos)   # per-row vmapped when
         cv = layers.kv_cache_write(cv, v, pos)   # pos is [B]/[B, 1]
         # GQA grouped attention: query heads fold as [B, Hkv, g, Dh]
@@ -450,7 +537,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             # every leading layout ([B, 1] per-slot pos: [B,1,1,Dh/2])
             # — rotating the folded q directly is exact: all g query
             # heads of a row sit at that row's position
-            q = layers.rope(q, pos)
+            q = _rope(cfg, q, pos)
         scores = layers.matmul(q, ck, transpose_y=True,
                                alpha=d_head ** -0.5)    # [B,Hkv,g,S]
         scores = layers.elementwise_add(scores, bias)
@@ -462,8 +549,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         x = layers.elementwise_add(x, att)
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _ffn(h2, d_model, cfg["d_ff"], nm,
-                 act=cfg.get("ffn_act", "relu"))
+        f = _mlp(cfg, h2, nm, i, counts=routed)
         x = layers.elementwise_add(x, f)
 
     x = _final_norm(cfg, x)
@@ -563,6 +649,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
         biases.append(layers.reshape(b_s, [-1, 1, 1, max_len]))
 
+    routed = None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
@@ -581,6 +668,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
                       bias_attr=False,
                       param_attr=ParamAttr(name=nm + "_att_v.w_0"))
+        q, k = _qk_norm(cfg, q, k, nm)
 
         def kv_heads(t):
             t = layers.reshape(t, [-1, S, n_kv, d_head])
@@ -591,7 +679,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             # [B, S] positions -> per-(row, step) angles broadcast over
             # the kv-head axis (elementwise — bitwise the per-position
             # rotation); the cache stores rotated keys
-            k = layers.rope(k, pos)
+            k = _rope(cfg, k, pos)
         # ONE vmapped slab write per cache tensor at the per-row start
         # (rows are contiguous by contract)
         ck = layers.kv_cache_write(ck, k, pos_cols[0])
@@ -606,7 +694,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
                 layers.slice(q, axes=[1], starts=[s], ends=[s + 1]),
                 [-1, n_kv, g, d_head])
             if use_rope:
-                q_s = layers.rope(q_s, pos_cols[s])
+                q_s = _rope(cfg, q_s, pos_cols[s])
             scores = layers.matmul(q_s, ck, transpose_y=True,
                                    alpha=d_head ** -0.5)  # [B,n_kv,g,S']
             scores = layers.elementwise_add(scores, biases[s])
@@ -620,8 +708,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         x = layers.elementwise_add(x, att)
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _ffn(h2, d_model, cfg["d_ff"], nm,
-                 act=cfg.get("ffn_act", "relu"))
+        f = _mlp(cfg, h2, nm, i, counts=routed)
         x = layers.elementwise_add(x, f)
 
     x = _final_norm(cfg, x)

@@ -73,10 +73,22 @@ def repeat_kv_heads(x, n_kv_head, n_head, seq_len, d_head):
     return layers.reshape(x, [-1, n_head, seq_len, d_head])
 
 
+def qk_norm(q, k, name, eps):
+    """RMSNorm of the projected q and k ([B, S, width]) before the head
+    split; parameters ``<name>_qnorm_s`` / ``<name>_knorm_s``, shared by
+    name between the training build and the inference graphs."""
+    q = layers.rms_norm(q, begin_norm_axis=2, epsilon=eps,
+                        param_attr=ParamAttr(name=name + "_qnorm_s"))
+    k = layers.rms_norm(k, begin_norm_axis=2, epsilon=eps,
+                        param_attr=ParamAttr(name=name + "_knorm_s"))
+    return q, k
+
+
 def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
                          is_test, name, use_fused_attention=False,
                          causal=False, n_kv_head=None, rope_pos=None,
-                         segment_ids=None):
+                         segment_ids=None, qk_norm_eps=None,
+                         rope_base=10000.0):
     """causal=True only affects the fused path (in-kernel triangular
     mask + above-diagonal block skipping); the composed path expects the
     causal mask folded into `bias` as before. ``n_kv_head < n_head``
@@ -85,7 +97,10 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
     on the decode path (models/gpt.py build_decode_step), an
     H/Hkv-times smaller KV cache. ``rope_pos`` (a [S] int position
     var) applies rotary position embeddings to q and k after the head
-    split (self-attention only: the positions index both sides)."""
+    split (self-attention only: the positions index both sides), at
+    ``rope_base``. ``qk_norm_eps`` (a number) applies RMSNorm with that
+    epsilon to the projected q and k over their whole width, before
+    the head split, each with a scale of its own (OLMoE)."""
     n_kv_head = n_kv_head or n_head
     if n_head % n_kv_head:
         raise ValueError("n_head %d must divide by n_kv_head %d"
@@ -108,14 +123,16 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
     v = layers.fc(kv_in, n_kv_head * d_head, num_flatten_dims=2,
                   bias_attr=False,
                   param_attr=ParamAttr(name=name + "_v.w_0"))
+    if qk_norm_eps is not None:
+        q, k = qk_norm(q, k, name, qk_norm_eps)
     q = _split_heads(q, seq_q, n_head, d_head)
     k = _split_heads(k, seq_kv, n_kv_head, d_head)
     v = _split_heads(v, seq_kv, n_kv_head, d_head)
     if rope_pos is not None:
         # per-head-dim rotation, head-count blind: rotate k at its
         # n_kv_head width, before any GQA repeat
-        q = layers.rope(q, rope_pos)
-        k = layers.rope(k, rope_pos)
+        q = layers.rope(q, rope_pos, base=rope_base)
+        k = layers.rope(k, rope_pos, base=rope_base)
     k = repeat_kv_heads(k, n_kv_head, n_head, seq_kv, d_head)
     v = repeat_kv_heads(v, n_kv_head, n_head, seq_kv, d_head)
     if use_fused_attention:
@@ -154,9 +171,10 @@ def _ffn(x, d_model, d_ff, name, act="relu"):
                      param_attr=ParamAttr(name=name + "_ffn2.w_0"))
 
 
-def _prenorm(x, sub_fn, dropout, is_test, name, norm="layer"):
+def _prenorm(x, sub_fn, dropout, is_test, name, norm="layer",
+             rms_eps=1e-6):
     if norm == "rms":
-        h = layers.rms_norm(x, begin_norm_axis=2,
+        h = layers.rms_norm(x, begin_norm_axis=2, epsilon=rms_eps,
                             param_attr=ParamAttr(name=name + "_ln_s"))
     else:
         h = layers.layer_norm(x, begin_norm_axis=2,

@@ -944,6 +944,23 @@ FLASH_BLOCK_PLANS = REGISTRY.counter(
     "compiled step holds (ops/attention.py _block_plan)",
     labels=("kernel", "block", "single_pass"))
 
+MOE_GMM_PLANS = REGISTRY.counter(
+    "paddle_moe_gmm_plans_total",
+    "Grouped-matmul calls of the expert layer lowered, by kernel name "
+    "(moe_gmm_up, moe_gmm_down), the [tm]x[tk]x[tn] tile one grid step "
+    "computes ('-' for the composed form) and the form the step holds "
+    "('pallas' or 'composed'). Counted at LOWERING time like "
+    "paddle_flash_block_plans_total (kernels/moe_gmm.py gmm_plan)",
+    labels=("kernel", "tile", "form"))
+
+MOE_ROUTED_PAIRS = REGISTRY.gauge(
+    "paddle_moe_routed_pairs",
+    "Token-expert pairs the decode step has routed to each expert of "
+    "each layer since the engine started: a copy of the device-side "
+    "[n_layer, n_expert] int32 the step adds to, refreshed when "
+    "DecodeEngine.routed_pairs() is called (no fetch a step)",
+    labels=("layer", "expert"))
+
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see
 # docs/OBSERVABILITY.md "Trace propagation")

@@ -1,20 +1,30 @@
-"""moe_ffn op: switch-routed expert FFN as one graph op (top-1
-Switch by default, top_k=2 GShard-style).
+"""moe_ffn op: a mixture-of-experts FFN as one graph op — top-k routing
+over stacked experts, Switch/GShard-style under a capacity or dropless.
 
 The reference (Fluid v1.3) has no mixture-of-experts; this op promotes
 `parallel/moe.py` into the Program/layers API (the 'ep' axis). Expert
-weights arrive stacked [E, ...]; under a ParallelEngine mesh with an
-'expert' axis of size E each device computes ITS expert on the tokens
-routed to it and the [capacity, D] results all_gather back — with the
-engine's replicated activations every device already holds the full
-token set, so this costs ONE collective and capacity rows per expert
-(the general token-sharded case, where tokens must first travel to
-their expert's device via all_to_all, lives in `parallel/moe.py`'s
-``moe_apply`` for shard_map users). Without the axis, every expert
-computes locally. All paths share ``route_tokens``, so single-device
+weights arrive stacked [E, ...].
+
+On one device (``_experts``): router in float32 -> top_k -> the (token,
+expert) pairs sorted by expert -> a grouped matmul over the ragged
+groups (``kernels/moe_gmm.py``: a Pallas kernel on the TPU, ragged_dot
+elsewhere) -> activation -> a second grouped matmul -> the gate-weighted
+sum back per token. ``act='relu'`` experts carry biases, ``'swiglu'``
+experts (gate, up, down) none. ``dropless`` computes every pair; a
+``capacity`` drops the overflow exactly as ``route_tokens`` says, by
+giving the dropped pairs to no group before the sort — one path for both.
+
+Under a ParallelEngine mesh with an 'expert' axis of size E each device
+computes ITS expert on the tokens routed to it and the [capacity, D]
+results all_gather back — with the engine's replicated activations every
+device already holds the full token set, so this costs ONE collective and
+capacity rows per expert (the general token-sharded case, where tokens
+must first travel to their expert's device via all_to_all, lives in
+`parallel/moe.py`'s ``moe_apply`` for shard_map users); ReLU experts
+under a capacity only. All paths share ``route_tokens``, so single-device
 and expert-parallel runs agree exactly (the parity contract the tests
-pin): Switch/GShard discipline — static capacity with choice-major
-priority, overflow tokens contribute zero, aux load-balancing loss.
+pin): static capacity with choice-major priority, overflow tokens
+contribute zero, aux load-balancing loss.
 """
 
 from __future__ import annotations
@@ -31,44 +41,79 @@ from ..core.registry import register_op
 __all__: List[str] = []
 
 
-def _moe_local(x, w1, b1, w2, b2, gate_w, E, capacity, top_k=1,
-               z_loss=0.0):
-    """Single-device path: every expert computes on the full token set,
-    outputs select by routing — matching the parallel path's keep/drop
-    discipline through the shared route_tokens."""
-    from ..parallel.moe import route_tokens
+def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
+             norm_topk, z_loss):
+    """Single-device path, dropless or capacity-bound: the (token,
+    expert) pairs sorted by expert, two grouped matmuls over the ragged
+    groups (kernels/moe_gmm.py), the gate-weighted sum back per token.
 
-    expert_idx, gate, _pos, keep, aux = route_tokens(x, gate_w, E,
-                                                     capacity, top_k,
-                                                     z_loss)
-    out = jnp.zeros_like(x)
-    for e in range(E):
-        h = jax.nn.relu(x @ w1[e] + b1[e])
-        y = h @ w2[e] + b2[e]
-        for kk in range(top_k):
-            sel = ((expert_idx[kk] == e) & keep[kk])[:, None]
-            out = out + jnp.where(sel, y * gate[kk][:, None], 0.0)
-    return out, aux
+    ``capacity`` None is dropless: every pair computes. A number is the
+    Switch/GShard discipline of ``route_tokens``: the pairs past an
+    expert's capacity are given to no group before the sort, so they
+    compute nothing and contribute zero — the same path, and the same
+    answer as the expert-parallel branch below.
+
+    Returns (out [T, D], aux, pairs routed to each expert [E] int32)."""
+    from ..kernels.moe_gmm import KERNEL_DOWN, KERNEL_UP, gmm
+    from ..parallel.moe import route_tokens, router
+
+    T = x.shape[0]
+    if capacity is None:
+        expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss,
+                                       norm_topk)
+        flat_e = expert_idx.reshape(-1)                  # [K*T]
+    else:
+        expert_idx, gate, _pos, keep, aux = route_tokens(
+            x, gate_w, E, capacity, top_k, z_loss, norm_topk)
+        # a dropped pair belongs to no expert: it sorts behind them all
+        flat_e = jnp.where(keep, expert_idx, E).reshape(-1)
+        gate = jnp.where(keep, gate, 0)
+    sizes = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
+                    dtype=jnp.int32)                     # [E]
+    order = jnp.argsort(flat_e, stable=True)             # pair -> sorted
+    sorted_e = jnp.minimum(flat_e[order], E - 1)
+    xs = x[order % T]                                    # [K*T, D]
+    if act == "swiglu":
+        h = gmm(xs, (w1, w1v), sizes, name=KERNEL_UP)
+    else:
+        h = jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)
+                        + b1[sorted_e])
+    y = gmm(h, w2, sizes, name=KERNEL_DOWN)
+    if b2 is not None:
+        y = y + b2[sorted_e]
+    # back to pair order (choice-major), then the k gated terms of a
+    # token add in choice order: a gather and a fixed sum, no scatter
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = y[back].reshape(top_k, T, -1) * gate[:, :, None]
+    return jnp.sum(y, axis=0), aux, sizes
 
 
 @register_op("moe_ffn",
-             diff_inputs=["X", "W1", "B1", "W2", "B2", "Gate"],
+             diff_inputs=["X", "W1", "W1V", "B1", "W2", "B2", "Gate"],
              needs_env=False)
 def _moe_ffn(ctx, ins, attrs):
     from ..parallel.moe import route_tokens
 
+    def opt(slot):
+        return ins[slot][0] if ins.get(slot) else None
+
     x = ins["X"][0]
-    w1, b1, w2, b2 = ins["W1"][0], ins["B1"][0], ins["W2"][0], ins["B2"][0]
-    gate_w = ins["Gate"][0]
+    w1, w2, gate_w = ins["W1"][0], ins["W2"][0], ins["Gate"][0]
+    w1v, b1, b2, counts = opt("W1V"), opt("B1"), opt("B2"), opt("Counts")
     E = int(attrs["n_experts"])
     axis = attrs.get("axis", "expert")
     top_k = int(attrs.get("top_k", 1))
     z_loss = float(attrs.get("z_loss", 0.0))
+    act = attrs.get("act", "relu")
+    norm_topk = attrs.get("norm_topk")
+    dropless = bool(attrs.get("dropless", False))
 
     D = x.shape[-1]
     xf = x.reshape(-1, D)
     T = xf.shape[0]
-    capacity = int(attrs.get("capacity") or -(-2 * T * top_k // E))
+    capacity = None if dropless else \
+        int(attrs.get("capacity") or -(-2 * T * top_k // E))
 
     mesh = ctx.mesh
     use_ep = mesh is not None and axis in mesh.axis_names \
@@ -78,20 +123,28 @@ def _moe_ffn(ctx, ins, attrs):
             "moe_ffn with n_experts=%d under a mesh whose %r axis has %d "
             "devices — experts map one-per-device" % (E, axis,
                                                       mesh.shape[axis]))
+    if use_ep and (dropless or act != "relu"):
+        raise NotImplementedError(
+            "moe_ffn: the expert-parallel branch runs ReLU experts under "
+            "a capacity; dropless or swiglu experts run on one device")
 
     if not use_ep:
-        out, aux = _moe_local(xf, w1, b1, w2, b2, gate_w, E, capacity,
-                              top_k, z_loss)
-        return {"Out": out.reshape(x.shape), "AuxLoss": aux}
+        out, aux, sizes = _experts(xf, w1, w1v, b1, w2, b2, gate_w, E,
+                                   top_k, capacity, act, norm_topk, z_loss)
+        outs = {"Out": out.reshape(x.shape), "AuxLoss": aux}
+        if counts is not None:
+            # the device-side tally of routed pairs: this layer's row
+            outs["CountsOut"] = counts.at[int(attrs["counts_row"])].add(
+                sizes.astype(counts.dtype))
+        return outs
 
     def shard_body(xl, w1l, b1l, w2l, b2l, gl):
         # xl replicated on the axis -> routing is identical everywhere;
         # each device fills the send buffer, runs ITS expert on its
         # [capacity, D] slice, and one all_gather rebuilds [E, capacity,
         # D] results for the (replicated) token-side gather.
-        expert_idx, gate, pos, keep, aux = route_tokens(xl, gl, E,
-                                                        capacity, top_k,
-                                                        z_loss)
+        expert_idx, gate, pos, keep, aux = route_tokens(
+            xl, gl, E, capacity, top_k, z_loss, norm_topk)
         safe_e = jnp.where(keep, expert_idx, 0)       # [K, T]
         safe_p = jnp.where(keep, pos, 0)
         buf = jnp.zeros((E, capacity, D), xl.dtype)

@@ -25,17 +25,48 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["moe_apply", "route_tokens"]
+__all__ = ["moe_apply", "route_tokens", "router"]
 
 
-def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0):
+def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None):
+    """Router softmax in float32, the k best experts a token and their
+    gates. ``norm_topk`` renormalises the k gates to sum to one: None is
+    the Switch/GShard rule (raw for top_k=1, renormalised above), False
+    keeps the raw probabilities (OLMoE), True always renormalises.
+
+    Returns (expert_idx [K,T], gate [K,T], aux scalar)."""
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)    # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
+    top_p, top_e = jax.lax.top_k(probs, top_k)           # [T, K] each
+    if norm_topk is None:
+        # Switch: the output scales by the RAW router probability — that
+        # product is how gradients reach the router at all; GShard:
+        # gates renormalized over the chosen experts
+        norm_topk = top_k > 1
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    gate = top_p.T.astype(x.dtype)                       # [K, T]
+    expert_idx = top_e.T                                 # [K, T]
+
+    onehot1 = jax.nn.one_hot(expert_idx[0], E)
+    aux = E * jnp.sum(jnp.mean(onehot1, axis=0) * jnp.mean(probs, axis=0))
+    if z_loss:
+        aux = aux + z_loss * jnp.mean(
+            jax.nn.logsumexp(logits, axis=-1) ** 2)
+    return expert_idx, gate, aux
+
+
+def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0,
+                 norm_topk=None):
     """Shared top-k routing/capacity math — the ONE derivation both the
-    distributed paths and the single-device dense fallback
-    (ops/moe_ops.py) use, so their exact-parity contract can't drift.
+    distributed paths and the single-device path (ops/moe_ops.py) use,
+    so their exact-parity contract can't drift.
 
     top_k=1 is Switch routing; top_k>1 is GShard-style: each token goes
     to its k best experts with gates renormalized over the chosen
-    probabilities, and capacity claims happen in CHOICE-MAJOR priority
+    probabilities (``norm_topk``, see ``router``), and capacity claims
+    happen in CHOICE-MAJOR priority
     (every token's 1st choice before any 2nd choice — a token never
     loses its primary expert slot to another token's secondary).
 
@@ -48,23 +79,7 @@ def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0):
     changing which experts win.
     """
     T = x.shape[0]
-    logits = x @ gate_w                                  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
-    top_p, top_e = jax.lax.top_k(probs, top_k)           # [T, K] each
-    if top_k == 1:
-        # Switch: the output scales by the RAW router probability — that
-        # product is how gradients reach the router at all
-        gate = top_p.T                                   # [1, T]
-    else:
-        # GShard: gates renormalized over the chosen experts
-        gate = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)).T
-    expert_idx = top_e.T                                 # [K, T]
-
-    onehot1 = jax.nn.one_hot(expert_idx[0], E)
-    aux = E * jnp.sum(jnp.mean(onehot1, axis=0) * jnp.mean(probs, axis=0))
-    if z_loss:
-        aux = aux + z_loss * jnp.mean(
-            jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1) ** 2)
+    expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss, norm_topk)
 
     # positions: flatten choice-major so cumsum gives 1st choices
     # priority over 2nd within each expert's capacity

@@ -147,10 +147,12 @@ class _Lane:
                 self._logits, self.cache_names = \
                     gpt.build_serving_decode_step(
                         cfg, batch=b_max, max_len=max_len)
-            exe.run(dec_start, scope=self.scope)
-            for n, v in (params or {}).items():
-                if self.scope.find_var(n) is not None:
-                    self.scope.set_var(n, v)
+            declared = self._decode_prog.global_block().vars
+            given = {n: v for n, v in (params or {}).items()
+                     if n in declared}
+            self._run_startup(dec_start, self.scope, given.__contains__)
+            for n, v in given.items():
+                self.scope.set_var(n, v)
         import jax
 
         def _splice(bigs, smalls, idx):
@@ -172,6 +174,24 @@ class _Lane:
         # them (recompiled per distinct prefix length, like the suffix
         # programs themselves)
         self._prefix_splice = jax.jit(_prefix_splice, donate_argnums=0)
+
+    def _run_startup(self, start, scope, supplied) -> None:
+        """Run a copy of a startup program without its initialisers of
+        the names ``supplied(name)`` says the caller is about to set: a
+        model whose weights are most of the chip (OLMoE's experts) cannot
+        hold a drawn copy beside the given one, even for a moment. An
+        initialiser is an op with no inputs whose outputs (it has some)
+        are all supplied; every other op stays, and ``start`` itself is
+        left as it was built."""
+        def skipped(op):
+            outs = [n for names in op.outputs.values() for n in names]
+            return bool(outs) and all(map(supplied, outs)) and not any(
+                n for names in op.inputs.values() for n in names)
+
+        pruned = start.clone()
+        block = pruned.global_block()
+        block.ops = [op for op in block.ops if not skipped(op)]
+        self._exe.run(pruned, scope=scope)
 
     # ---------------------------------------------------------- dispatch
     def _cold(self, prog) -> bool:
@@ -290,7 +310,9 @@ class _Lane:
             with fluid.program_guard(prog, start):
                 logits_var, cache_names = self._gpt.build_prefill_step(
                     self.cfg, batch=1, prompt_len=P, max_len=self.max_len)
-            self._exe.run(start, scope=self._prefill_scope)
+            self._run_startup(
+                start, self._prefill_scope,
+                set(self._shared_names(prog, {"tokens"})).__contains__)
             self._share_weights(prog, skip={"tokens"})
         SERVING_PREFILL_PROGRAMS.inc()
         self._prefill[P] = (prog, logits_var)
@@ -355,13 +377,15 @@ class _Lane:
         """Point the prefill scope at the engine scope's weight ARRAYS
         by name (cheap reference copies); never the caches — their
         batch dim differs."""
+        for n in self._shared_names(prog, skip):
+            self._prefill_scope.set_var(n, self.scope.find_var(n))
+
+    def _shared_names(self, prog, skip):
+        """The program's names the engine scope already holds, the
+        caches and ``skip`` apart."""
         skip = set(self.cache_names) | set(skip)
-        for n in prog.global_block().vars:
-            if n in skip:
-                continue
-            v = self.scope.find_var(n)
-            if v is not None:
-                self._prefill_scope.set_var(n, v)
+        return [n for n in prog.global_block().vars
+                if n not in skip and self.scope.find_var(n) is not None]
 
     # ------------------------------------------------- memory estimation
     def memory_footprint(self) -> dict:
@@ -607,6 +631,27 @@ class DecodeEngine:
         return self.queue.submit(payload, deadline_s=deadline_s,
                                  tenant=tenant, trace_ctx=trace_ctx,
                                  report=report)
+
+    def routed_pairs(self) -> Optional[np.ndarray]:
+        """``[n_layer, n_expert]`` (token, expert) pairs the decode step
+        has routed since the engine was built, for a model with sparse
+        experts (None for a dense one). The step adds to the tally on
+        the device and nothing fetches it a step: this call is the one
+        transfer, and it refreshes ``paddle_moe_routed_pairs``. Rows of
+        free slots are routed like any other, so at low occupancy the
+        tally holds their garbage too."""
+        from ..models.gpt import ROUTED_PAIRS_VAR
+        from ..observe.families import MOE_ROUTED_PAIRS
+
+        var = self._lane.scope.find_var(ROUTED_PAIRS_VAR)
+        if var is None:
+            return None
+        tally = np.asarray(var)
+        for layer, row in enumerate(tally):
+            for expert, n in enumerate(row):
+                MOE_ROUTED_PAIRS.labels(layer=str(layer),
+                                        expert=str(expert)).set(int(n))
+        return tally
 
     def predicted_resident_bytes(self) -> Optional[int]:
         """Static estimate of this engine's resident device bytes

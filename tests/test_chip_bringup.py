@@ -119,6 +119,29 @@ def test_flash_attention_compiles_for_v5e(case, v5e, compiled_kernels):
     assert n == 3, "%s: %d Mosaic kernels" % (case, n)
 
 
+@pytest.mark.parametrize("tokens", [32, 64, 512])
+def test_expert_layer_compiles_for_v5e(tokens, v5e, compiled_kernels):
+    """OLMoE's expert layer at published widths — the decode step's 32
+    rows, the shortest and the longest prefill of the benchmark's mix —
+    holds both grouped-matmul kernels, under the names the device trace
+    shows them by."""
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.ops.moe_ops import _experts
+
+    D, F, E, k = 2048, 1024, 64, 8
+
+    def layer(x, router, gate, up, down):
+        out, _aux, sizes = _experts(x, gate, up, None, down, None, router,
+                                    E, k, None, "swiglu", False, 0.0)
+        return out, sizes
+
+    sds = [jax.ShapeDtypeStruct(shape, F32, sharding=v5e) for shape in (
+        (tokens, D), (D, E), (E, D, F), (E, D, F), (E, F, D))]
+    text = jax.jit(layer).lower(*sds).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("block", [8, 64, 256])
 def test_layernorm_residual_compiles_for_v5e(dtype, block, v5e,
