@@ -37,7 +37,8 @@ from .common import (assert_mosaic_ok, checked_pallas_call,  # noqa: F401
                      mosaic_ok, use_interpret)
 from .registry import (KERNELS, KernelDef, all_kernels,  # noqa: F401
                        get_kernel, has_kernel, register_kernel)
-from . import layernorm, optimizer_update  # noqa: F401  (register entries)
+from . import (kv_cache_write, layernorm,  # noqa: F401  (register entries)
+               optimizer_update)
 
 __all__ = [
     "kernels_enabled", "run_kernel", "decide", "decide_and_note",

@@ -71,7 +71,8 @@ def assert_mosaic_ok(block_shape, array_shape, what) -> None:
 
 def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
                         out_shape, scratch_shapes, interpret,
-                        scalar_prefetch=(), compiler_params=None):
+                        scalar_prefetch=(), compiler_params=None,
+                        input_output_aliases=None):
     """``pl.pallas_call`` with the Mosaic legality mirror applied to every
     operand/output spec first, and shard_map vma propagation (outputs
     vary over every mesh axis an operand does — ring attention runs the
@@ -86,7 +87,9 @@ def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
     ``scalar_prefetch`` arrays (int32, SMEM) come before the operands in
     the kernel's arguments and after the grid indices in every index
     map (``pltpu.PrefetchScalarGridSpec``); ``compiler_params`` goes to
-    Mosaic as given."""
+    Mosaic as given. ``input_output_aliases`` maps an input's index —
+    counted over ``scalar_prefetch`` then ``operands`` — to the output
+    that reuses its buffer (an in-place update)."""
     from jax.experimental import pallas as pl
 
     single_out = not isinstance(out_specs, (list, tuple))
@@ -103,6 +106,8 @@ def checked_pallas_call(kern, *, name, grid, in_specs, operands, out_specs,
         out_shape = shapes if not single_out else shapes[0]
     extra = {} if compiler_params is None \
         else {"compiler_params": compiler_params}
+    if input_output_aliases:
+        extra["input_output_aliases"] = dict(input_output_aliases)
     if scalar_prefetch:
         from jax.experimental.pallas import tpu as pltpu
 

@@ -523,7 +523,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             # Per-slot [B, 1] positions broadcast per-row angles over
             # the head axis — each slot rotates at ITS position
             k = _rope(cfg, k, pos)
-        ck = layers.kv_cache_write(ck, k, pos)   # per-row vmapped when
+        ck = layers.kv_cache_write(ck, k, pos)   # per-slot rows when
         cv = layers.kv_cache_write(cv, v, pos)   # pos is [B]/[B, 1]
         # GQA grouped attention: query heads fold as [B, Hkv, g, Dh]
         # (h = kv*g + j, row-major — the same h//g mapping as
@@ -725,7 +725,7 @@ def build_serving_decode_step(cfg=None, batch=1, max_len=None):
     mid-flight while its neighbors keep decoding, and retires finished
     slots without draining the batch. Every per-slot op is row-local
     (embedding lookup, fc = per-row dots, rope with [B, 1] positions,
-    per-row visibility bias, vmapped kv_cache_write), so an active
+    per-row visibility bias, per-slot kv_cache_write), so an active
     slot's logits are bitwise those of the same tokens run through a
     smaller-batch ``build_decode_step`` — the engine's parity contract
     with ``generate`` rests on it.

@@ -927,8 +927,8 @@ KERNEL_DISPATCHES = REGISTRY.counter(
 # the window tuner's op (core/window_tune.py WINDOW_OP: the training-
 # loop window length K rides the same tuner/winner cache without being
 # a Pallas kernel registry entry)
-_KERNEL_OPS = ("adam_update", "attention", "layernorm_residual",
-               "sgd_update", "train_window")
+_KERNEL_OPS = ("adam_update", "attention", "kv_cache_write",
+               "layernorm_residual", "sgd_update", "train_window")
 for _op in _KERNEL_OPS:
     for _c in ("pallas", "composed"):
         KERNEL_WINNERS.labels(op=_op, choice=_c)
@@ -952,6 +952,16 @@ MOE_GMM_PLANS = REGISTRY.counter(
     "('pallas' or 'composed'). Counted at LOWERING time like "
     "paddle_flash_block_plans_total (kernels/moe_gmm.py gmm_plan)",
     labels=("kernel", "tile", "form"))
+
+KV_CACHE_WRITE_PLANS = REGISTRY.counter(
+    "paddle_kv_cache_write_plans_total",
+    "KV-cache writes lowered, by the form the step holds ('pallas': one "
+    "in-place kernel call a cache tensor; 'composed': "
+    "lax.dynamic_update_slice, vmapped for per-slot positions) and the "
+    "rows a slot writes (1 in a decode step, the prompt's length in a "
+    "prefill). Counted at LOWERING time like paddle_moe_gmm_plans_total "
+    "(kernels/kv_cache_write.py kv_cache_write)",
+    labels=("form", "rows"))
 
 MOE_ROUTED_PAIRS = REGISTRY.gauge(
     "paddle_moe_routed_pairs",

@@ -287,34 +287,22 @@ def _meshgrid(ctx, ins, attrs):
 @register_op("kv_cache_write", no_grad=True)
 def _kv_cache_write(ctx, ins, attrs):
     """Write a decode step's K or V rows into a [B, H, S, D] cache at a
-    runtime position (lax.dynamic_update_slice on the sequence axis) —
-    the incremental-decoding primitive (models/gpt.py decode step). The
-    cache is persistable state: the executor donates it, so the update
-    is in-place on device. Inference-only (no_grad).
+    runtime position — the incremental-decoding primitive (models/gpt.py
+    decode step). The cache is persistable state: the executor donates
+    it, so the update is in-place on device. Inference-only (no_grad).
 
     Pos is a [1] scalar (every batch row writes the same position — the
-    classic lockstep decode step) or [B]/[B, 1] per-row positions (each
-    cache slot advances independently — the continuous-batching serving
-    step, models/gpt.py build_serving_decode_step): the per-row form
-    vmaps the slice update over the batch axis."""
-    import jax
+    classic lockstep decode step, one lax.dynamic_update_slice on the
+    sequence axis) or [B]/[B, 1] per-row positions (each cache slot
+    advances independently — the continuous-batching serving step,
+    models/gpt.py build_serving_decode_step): one in-place Pallas call
+    where a slot writes one row and Pallas compiles, the slice update
+    vmapped over the batch axis elsewhere (kernels/kv_cache_write.py
+    chooses and counts which)."""
+    from ..kernels.kv_cache_write import kv_cache_write
 
-    cache, upd, pos = ins["Cache"][0], ins["Update"][0], ins["Pos"][0]
-    zero = jnp.int32(0)
-    if pos.size > 1:
-        # per-slot positions [B] (or [B, 1]): one independent sequence
-        # position per batch row
-        posb = pos.reshape((-1,)).astype(jnp.int32)
-        upd = upd.astype(cache.dtype)
-
-        def _write_row(c, u, p):
-            return jax.lax.dynamic_update_slice(c, u, (zero, p, zero))
-
-        return {"Out": [jax.vmap(_write_row)(cache, upd, posb)]}
-    pos = pos.reshape(()).astype(jnp.int32)
-    out = jax.lax.dynamic_update_slice(cache, upd.astype(cache.dtype),
-                                       (zero, zero, pos, zero))
-    return {"Out": [out]}
+    return {"Out": [kv_cache_write(ins["Cache"][0], ins["Update"][0],
+                                   ins["Pos"][0])]}
 
 
 @register_op("rope", diff_inputs=["X"])

@@ -1,7 +1,8 @@
 """Chip bring-up guards that need no chip (ISSUE 21).
 
 (a) The main path's kernels compile for a DESCRIBED TPU v5e at BERT-base
-    S512 shapes: the TPU compiler is installed here and compiles for a chip
+    S512 shapes (and, PR 27, the serving cells' cache write with the whole
+    ``gpt2-medium`` decode step round it): the TPU compiler is installed here and compiles for a chip
     that is not attached (on-chip-measurement guide, section 2.3), so a
     fast-memory overrun or a slice off the tiling fails here, not on the
     chip. Nothing runs: a compile that passes is not a chip run.
@@ -182,6 +183,107 @@ def test_optimizer_sweep_compiles_for_v5e(kind, v5e, compiled_kernels):
     else:
         fn = lambda ins: ou.sgd_group_pallas(cfg, ins)  # noqa: E731
     assert _compile(fn, v5e, ins) == 1
+
+
+def _cache_sized(text, shape):
+    """HLO lines that produce a cache-sized array by a copy, a transpose
+    or a fusion (either orientation of the two minor axes)."""
+    import re
+
+    b, h, s, d = shape
+    made = re.compile(r"= \S+\[%d,%d,(%d,%d|%d,%d)\]\S* "
+                      r"(copy|transpose|fusion)\(" % (b, h, s, d, d, s))
+    return [line.strip()[:160] for line in text.splitlines()
+            if made.search(line)]
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+def test_kv_cache_write_compiles_in_place_for_v5e(d_head, v5e,
+                                                  compiled_kernels):
+    """The serving cells' cache tensors (``gpt2-medium``: 16 heads of 64,
+    stored S-minor by the TPU; OLMoE: 16 of 128, row-major), float32, 32
+    slots of 1,024 positions: one Mosaic kernel, the donated cache
+    aliased to the output, and no relayout of the slab round the call —
+    the kernel's block orientation matches the layout the TPU chose."""
+    from paddle_tpu.kernels import kv_cache_write as kvw
+
+    shape = (32, 16, 1024, d_head)
+    assert kvw.write_plan(shape, F32)[0] == {64: "cols", 128: "rows"}[d_head]
+    sds = [jax.ShapeDtypeStruct(sh, dt, sharding=v5e) for sh, dt in (
+        (shape, F32), ((32, 16, 1, d_head), F32), ((32, 1), jnp.int32))]
+    text = jax.jit(
+        lambda c, u, p: kvw.kv_cache_write_pallas(None, c, u, p,
+                                                  interpret=False),
+        donate_argnums=0).lower(*sds).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert kvw.KERNEL in text
+    assert "input_output_alias={ {}: (0, {}, may-alias) }" in text
+    assert _cache_sized(text, shape) == []
+
+
+def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
+        v5e, compiled_kernels):
+    """The whole ``gpt2-medium`` serving decode step (32 slots, 1,024
+    positions, float32) compiled for the described chip: 48 Pallas calls
+    (K and V of 24 layers), no ``scatter`` left of the vmapped update,
+    every cache donated into its output, none copied or relaid — and the
+    program's counter says 48 ``pallas``, 0 ``composed``."""
+    import re
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import analyze_block
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.models import gpt
+    from paddle_tpu.observe.families import KV_CACHE_WRITE_PLANS
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "gpt2-medium.json")) as f:
+        conf = json.load(f)
+    cfg = dict(gpt.base_config(), **conf["model"])
+    batch, max_len = conf["serving"]["b_max"], conf["serving"]["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        logits, caches = gpt.build_serving_decode_step(cfg, batch=batch,
+                                                       max_len=max_len)
+
+    class _Initialised:                 # nothing is run: shapes only
+        def has_var(self, name):
+            return True
+
+    (feed_names, _fetch, const_state, mut_state, _written, _rng,
+     step) = analyze_block(main, ["token", "pos"], [logits.name],
+                           _Initialised())
+    assert sorted(mut_state) == sorted(caches)
+    block = main.global_block()
+
+    def sds(name):
+        var = block.vars[name]
+        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(var.dtype),
+                                    sharding=v5e)
+
+    def fn(feeds, const_vals, mut_vals):
+        fetches, new_mut, _, _ = step(feeds, const_vals, mut_vals, None)
+        return fetches, new_mut
+
+    plans = {form: KV_CACHE_WRITE_PLANS.labels(form=form, rows="1")
+             for form in ("pallas", "composed")}
+    before = {form: c.value for form, c in plans.items()}
+    lowered = jax.jit(fn, donate_argnums=(2,)).lower(
+        [jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=v5e)
+         for _ in feed_names],
+        [sds(n) for n in const_state], [sds(n) for n in mut_state])
+    assert "scatter" not in lowered.as_text()
+    text = lowered.compile().as_text()
+    n_cache = 2 * cfg["n_layer"]
+    assert {f: c.value - before[f] for f, c in plans.items()} == {
+        "pallas": n_cache, "composed": 0}
+    assert text.count('custom_call_target="tpu_custom_call"') == n_cache
+    assert len(re.findall(r"%s[.\d]* = " % kvw.KERNEL, text)) == n_cache
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert len(re.findall(r"may-alias|must-alias",
+                          aliases.group(1))) == n_cache
+    assert _cache_sized(text, (batch, cfg["n_head"], max_len,
+                               cfg["d_model"] // cfg["n_head"])) == []
 
 
 # ------------------------------------------------------ (b) use_interpret

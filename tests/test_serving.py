@@ -443,6 +443,50 @@ def test_engine_stop_cancels_queued_requests():
         eng.submit(np.array([1], dtype="int64"), 2)
 
 
+def test_engine_tokens_are_the_same_with_the_cache_write_kernel(
+        monkeypatch):
+    """The serving decode step with its per-slot cache writes as the
+    in-place Pallas kernel (interpret mode here: the dispatch is told
+    Pallas compiles, the kernel that it does not) answers with the same
+    tokens as the composed form: 10 requests of mixed prompt and answer
+    lengths over 4 slots, so slots retire and are written again by a
+    later request at other positions."""
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.observe.families import KV_CACHE_WRITE_PLANS
+
+    cfg = dict(CFG, max_length=128)      # 2 layers, 2 heads of 16
+    assert kvw.write_plan((4, 2, 128, 16), "float32") == (
+        "cols", (1, 2, 16, 128))
+    rs = np.random.RandomState(7)
+    prompts = [rs.randint(1, 64, (n,)).astype("int64")
+               for n in (3, 9, 5, 3, 9, 5, 9, 3, 5, 9)]
+    budgets = [4, 12, 2, 7, 3, 9, 5, 11, 6, 2]
+
+    def serve():
+        eng = DecodeEngine(cfg, b_max=4, max_len=128, queue_capacity=16)
+        eng.start()
+        try:
+            reqs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+            return [r.result(timeout=300) for r in reqs]
+        finally:
+            eng.stop()
+
+    composed = serve()
+    kernel = KV_CACHE_WRITE_PLANS.labels(form="pallas", rows="1")
+    before = kernel.value
+    run = kvw.kv_cache_write_pallas
+    monkeypatch.setattr(kvw, "use_interpret", lambda: False)
+    monkeypatch.setattr(
+        kvw, "kv_cache_write_pallas",
+        lambda cfg_, cache, upd, pos, interpret=None:
+        run(cfg_, cache, upd, pos, interpret=True))
+    with_kernel = serve()
+    assert kernel.value - before == 2 * cfg["n_layer"]   # K and V a layer
+    for got, want, p, n in zip(with_kernel, composed, prompts, budgets):
+        assert len(want) == len(p) + n
+        np.testing.assert_array_equal(got, want)
+
+
 # ------------------------------------------------------ the occupancy proof
 @pytest.mark.slow
 def test_continuous_batching_beats_sequential_generate():
