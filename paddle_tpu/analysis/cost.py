@@ -41,7 +41,7 @@ tests/test_cost.py holds predicted within ``ZOO_COST_GATE_FACTOR``
 anchored-to-ground-truth contract as the memory engine's 2x gate.
 
 ``PADDLE_TPU_COST_MODEL=0`` disarms every consumer (the autotuner
-measures everything, bench's predicted columns go null) and no
+measures everything) and no
 ``paddle_cost_*`` family moves — the degrade-to-today contract
 tests/test_autotune.py pins.
 """
@@ -73,8 +73,11 @@ DEVICE_MODEL_VERSION = 1
 DEVICE_MODEL_FILE = "device_model.json"
 
 # chip peak FLOP/s and HBM bandwidth by device_kind substring
-# (lowercase) — the bench.py PEAKS convention; probing a real TPU would
-# measure achieved-not-peak, so known generations resolve statically
+# (lowercase); probing a real TPU would measure achieved-not-peak, so
+# known generations resolve statically. The benchmark keeps its own table
+# (benchmarks/lib/peaks.py, the ledger's yardstick, which no PR that
+# claims a gain may edit); tests/test_cost.py holds the two equal on the
+# chip the ledger is measured on
 _TPU_PEAK_FLOPS = {
     "v5p": 459e12, "v5e": 197e12, "v5 lite": 197e12, "v5litepod": 197e12,
     "v6e": 918e12, "v6": 918e12, "v4": 275e12, "v3": 123e12, "v2": 45e12,
@@ -104,8 +107,7 @@ _MODEL_CACHE: Dict[tuple, "DeviceModel"] = {}
 
 def cost_model_enabled() -> bool:
     """``PADDLE_TPU_COST_MODEL=0`` disarms every cost-model consumer:
-    the unified autotuner degrades to measure-everything, bench's
-    ``predicted_seconds``/``cost_model_ratio`` columns go null, and no
+    the unified autotuner degrades to measure-everything and no
     ``paddle_cost_*`` family moves (default ON)."""
     return os.environ.get("PADDLE_TPU_COST_MODEL", "1") != "0"
 

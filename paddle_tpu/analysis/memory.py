@@ -47,9 +47,8 @@ Consumers: the memory lint rules (``analysis/lint.py``:
 memory-over-budget / max-safe-batch / dead-persistable),
 ``core/window_tune.py`` (candidates whose predicted peak exceeds the
 device budget are pruned before measurement), the serving engine's
-predicted-bytes admission guard (``serving/engine.py``),
-``tools/memory_report.py``, and the bench's ``peak_bytes_predicted``
-row field. ``paddle_analysis_memory_*`` observe families count
+predicted-bytes admission guard (``serving/engine.py``) and
+``tools/memory_report.py``. ``paddle_analysis_memory_*`` observe families count
 analyses, window-candidate prunes, and wall time.
 """
 

@@ -134,34 +134,14 @@ class Executor:
         plan, feeds, const_state, mut_state, rng = self._gather(
             program, feed, fetch_list, scope)
         from ..observe import observe_feed_gap
-        from ..profiler import RecordEvent, is_profiler_enabled
 
         observe_feed_gap()
         t0 = time.perf_counter()
-        if is_profiler_enabled():
-            # whole-step annotation: the analog of the per-op RecordEvent in
-            # the reference's interpreter loop (operator.cc:180) — ops fuse
-            # into this one launch
-            with RecordEvent("executor_run"):
-                with _dispatch_guard(plan, "run"):
-                    fetches, new_mut, new_pure, new_rng = plan.fn(
-                        feeds, const_state, mut_state, rng)
-                steady = _record_dispatch(plan, "run", "run", 1,
-                                          time.perf_counter() - t0)
-                with _wait_guard():
-                    fetches = [f.block_until_ready()
-                               if hasattr(f, "block_until_ready")
-                               else f for f in fetches]
-                if fetches:  # an empty fetch_list never blocks
-                    _record_completion(steady, "run",
-                                       time.perf_counter() - t0)
-                t0 = None  # completion observed here; _finish must not re-record
-        else:
-            with _dispatch_guard(plan, "run"):
-                fetches, new_mut, new_pure, new_rng = plan.fn(
-                    feeds, const_state, mut_state, rng)
-            steady = _record_dispatch(plan, "run", "run", 1,
-                                      time.perf_counter() - t0)
+        with _dispatch_guard(plan, "run"):
+            fetches, new_mut, new_pure, new_rng = plan.fn(
+                feeds, const_state, mut_state, rng)
+        steady = _record_dispatch(plan, "run", "run", 1,
+                                  time.perf_counter() - t0)
 
         return self._finish(plan, scope, fetches, new_mut, new_pure,
                             new_rng, return_numpy, "",
@@ -174,8 +154,7 @@ class Executor:
         store, numpy conversion, FLAGS_check_nan_inf. ``completion`` is
         ``(steady, site, t0)``: when the numpy conversion blocks on the
         result, the dispatch-to-ready latency is observed as the
-        ``complete`` phase (t0=None when the caller already recorded it
-        or never blocks). ``run_pipelined`` reuses the same two helpers
+        ``complete`` phase. ``run_pipelined`` reuses the same two helpers
         from its loop and ``FetchHandle.result()`` so the paths cannot
         drift."""
         _write_back_state(plan, scope, new_mut, new_pure, new_rng)
@@ -183,7 +162,7 @@ class Executor:
         if return_numpy:
             if fetches:
                 # the conversion is the host block where a wedged device
-                # hangs an unprofiled run — keep it heartbeat-stamped
+                # hangs a run — keep it heartbeat-stamped
                 with _wait_guard():
                     out = [np.asarray(v) for v in fetches]
             else:
@@ -191,7 +170,7 @@ class Executor:
             # `complete` only when the conversion actually blocked on a
             # result: an empty fetch_list never waits, and recording it
             # would fill the histogram with dispatch-only samples
-            if out and completion is not None and completion[2] is not None:
+            if out and completion is not None:
                 _record_completion(completion[0], completion[1],
                                    time.perf_counter() - completion[2])
             _check_fetches_finite(plan.fetch_names, out, nan_suffix)
@@ -266,32 +245,15 @@ class Executor:
             plan.multi[key] = fn
 
         from ..observe import observe_feed_gap
-        from ..profiler import RecordEvent, is_profiler_enabled
 
         observe_feed_gap()
         sig = ("run_repeated",) + key
         t0 = time.perf_counter()
-        if is_profiler_enabled():
-            with RecordEvent("executor_run_repeated[%d]" % steps):
-                with _dispatch_guard(plan, sig):
-                    fetches, new_mut, new_pure, new_rng = fn(
-                        feeds, const_state, mut_state, rng)
-                steady = _record_dispatch(plan, sig, "run_repeated",
-                                          steps, time.perf_counter() - t0)
-                with _wait_guard():
-                    fetches = [f.block_until_ready()
-                               if hasattr(f, "block_until_ready") else f
-                               for f in fetches]
-                if fetches:  # an empty fetch_list never blocks
-                    _record_completion(steady, "run_repeated",
-                                       time.perf_counter() - t0)
-                t0 = None
-        else:
-            with _dispatch_guard(plan, sig):
-                fetches, new_mut, new_pure, new_rng = fn(
-                    feeds, const_state, mut_state, rng)
-            steady = _record_dispatch(plan, sig, "run_repeated",
-                                      steps, time.perf_counter() - t0)
+        with _dispatch_guard(plan, sig):
+            fetches, new_mut, new_pure, new_rng = fn(
+                feeds, const_state, mut_state, rng)
+        steady = _record_dispatch(plan, sig, "run_repeated",
+                                  steps, time.perf_counter() - t0)
         return self._finish(plan, scope, fetches, new_mut, new_pure,
                             new_rng, return_numpy,
                             " after %d scanned steps" % steps,
@@ -585,8 +547,7 @@ class Executor:
             PIPELINE_WINDOW_SIZE.set(kk)
             if src == "tuned":
                 # a tuner-table decision shaped this loop: note it like
-                # any kernel-tier dispatch (bench rows carry the map;
-                # per-loop, not per-step)
+                # any kernel-tier dispatch (per-loop, not per-step)
                 from .. import kernels as _k
                 from ..observe.families import KERNEL_DISPATCHES
 

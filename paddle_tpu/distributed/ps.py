@@ -26,17 +26,7 @@ from ..core.program import Program
 from ..core.scope import Scope
 from .rpc import RPCServer, SelectedRows, parse_endpoint
 
-__all__ = ["run_pserver_loop", "register_prebound_server"]
-
-# endpoint -> RPCServer bound ahead of run_pserver_loop: a launcher can
-# bind port 0 ITSELF (kernel-assigned, held from bind to serve — no
-# bind/close/rebind TOCTOU) and advertise the real port to the cluster
-# before entering the loop. See bench.py's --dist-ctr-pserver entry.
-_PREBOUND: Dict[str, RPCServer] = {}
-
-
-def register_prebound_server(endpoint: str, server: RPCServer) -> None:
-    _PREBOUND[endpoint] = server
+__all__ = ["run_pserver_loop"]
 
 
 def _sparse_apply(table: np.ndarray, grads: List[SelectedRows], lr: float,
@@ -80,16 +70,8 @@ def run_pserver_loop(attrs: Dict, scope: Scope, executor=None):
             raise ProgramVerifyError(findings)
 
     exe = executor or Executor()
-    server = _PREBOUND.pop(endpoint, None)
-    if server is None:
-        _, port = parse_endpoint(endpoint)
-        server = RPCServer(port=port, num_trainers=num_trainers, sync=sync)
-    elif server.num_trainers != num_trainers or server.sync != sync:
-        raise ValueError(
-            "prebound server for %s was created with num_trainers=%d "
-            "sync=%s but the pserver program wants num_trainers=%d "
-            "sync=%s" % (endpoint, server.num_trainers, server.sync,
-                         num_trainers, sync))
+    _, port = parse_endpoint(endpoint)
+    server = RPCServer(port=port, num_trainers=num_trainers, sync=sync)
 
     param_blocks = {s["param_block"]: s for s in specs}
     grad_to_param = {s["grad_block"]: s["param_block"] for s in specs}

@@ -84,7 +84,7 @@ _DEFAULT_COMPILE_CACHE = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
-    directory. Entry points (chip_smoke.py, the bench worker, examples,
+    directory. Entry points (chip_smoke.py, benchmarks/run.py, examples,
     tools) call this once before their first compile; library code and
     the tests never do.
 

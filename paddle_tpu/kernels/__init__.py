@@ -22,8 +22,9 @@ Dispatch contract:
   explicit env > tuned entry > static threshold — ops/attention.py).
 
 Every decision taken since the last ``reset_decisions()`` is recorded in
-``decisions_seen()`` — bench rows carry the map so a regression is
-attributable to a specific kernel choice. See docs/KERNELS.md.
+``decisions_seen()`` — chip_smoke.py and benchmarks/run.py report the
+map so a regression is attributable to a specific kernel choice. See
+docs/KERNELS.md.
 """
 
 from __future__ import annotations
@@ -61,18 +62,17 @@ def kernels_enabled() -> bool:
 
 
 def note_decision(op: str, choice: str, tuned: bool = False) -> None:
-    """Record a dispatch decision for bench row labeling (``kernel_tier``
-    map). Last decision per op wins within a run; ``tuned`` marks
-    choices that came from a tuner entry rather than the default path —
-    pin_baselines treats those rows as incomparable."""
+    """Record a dispatch decision (the ``kernel_tier`` map a run
+    reports). Last decision per op wins within a run; ``tuned`` marks
+    choices that came from a tuner entry rather than the default
+    path."""
     with _DEC_LOCK:
         _DECISIONS[op] = {"choice": choice, "tuned": bool(tuned)}
 
 
 def decisions_seen() -> Dict[str, Dict[str, Any]]:
     """op -> {"choice", "tuned"} for every kernel-tier dispatch since
-    the last ``reset_decisions()`` (bench reads this after each
-    workload)."""
+    the last ``reset_decisions()``."""
     with _DEC_LOCK:
         return {k: dict(v) for k, v in _DECISIONS.items()}
 
@@ -108,8 +108,8 @@ def tuned_choice(op: str, sig: Tuple) -> Optional[str]:
 def decide_and_note(op: str, sig: Tuple,
                     attrs: Optional[Dict[str, Any]] = None):
     """THE shared dispatch protocol — tuned-decision lookup (+ inline
-    tune under PADDLE_TPU_KERNEL_TUNE=1), decision-ledger note in the
-    bench-row format ('pallas:<cfg>' / 'composed', tuned flag), and the
+    tune under PADDLE_TPU_KERNEL_TUNE=1), decision-ledger note
+    ('pallas:<cfg>' / 'composed', tuned flag), and the
     per-compile ``paddle_kernel_dispatches_total`` count — used by
     ``run_kernel`` and every fused-op lowering so the three sites can
     never drift on ledger format or counter semantics. Returns

@@ -1,9 +1,9 @@
-"""Model zoo: the BASELINE workload set, built on the paddle_tpu layer API.
+"""Model zoo, built on the paddle_tpu layer API.
 
 Mirrors /root/reference/benchmark/fluid/models/ (mnist, resnet, vgg,
 machine_translation) plus the distributed-test models
-(unittests/dist_transformer.py, dist_ctr.py) and the BASELINE.json
-workloads (BERT-base MLM, DeepFM/Wide&Deep). Every model is a pure
+(unittests/dist_transformer.py, dist_ctr.py), BERT-base MLM and
+DeepFM/Wide&Deep. Every model is a pure
 program-builder: call inside a fluid.program_guard and it appends ops to
 the current main/startup programs, returning the loss/feed variables.
 """

@@ -1,7 +1,7 @@
 """BERT-base masked-LM pretraining graph.
 
-BASELINE.json workload "BERT-base MLM pretraining (mixed precision,
-pod-scale allreduce)". The reference repo has no BERT in-tree; this is
+The benchmark's training configuration (``benchmarks/configs/bert-base.json``
+builds it). The reference repo has no BERT in-tree; this is
 built from the same fluid-style layer calls its transformer test uses
 (/root/reference/python/paddle/fluid/tests/unittests/dist_transformer.py),
 with the standard BERT embedding sum (word+position+segment) and a

@@ -1,8 +1,8 @@
 """CTR models: Wide&Deep and DeepFM over high-dim sparse id features.
 
 Reference: /root/reference/python/paddle/fluid/tests/unittests/dist_ctr.py
-(dnn+lr over sparse embeddings trained through the parameter-server path)
-and the BASELINE.json "DeepFM / Wide&Deep CTR" workload. The reference
+(dnn+lr over sparse embeddings trained through the parameter-server path).
+The reference
 streams SelectedRows sparse grads to pservers; on TPU the embedding grad is
 a scatter-add inside the one-step XLA computation, and giant tables shard
 over the mesh (rules in parallel.sharding) or live on the DCN parameter

@@ -19,9 +19,8 @@ trace being taken).
     with observe.trace_span("executor.call"):
         ...                       # a declared site (families.TRACE_SITES)
 
-`tools/stats_dump.py` pretty-prints a live or saved snapshot; bench.py
-drops a ``BENCH_<workload>.telemetry.json`` sidecar per row (including
-failed ones) built from these snapshots. See docs/OBSERVABILITY.md.
+`tools/stats_dump.py` pretty-prints a live or saved snapshot. See
+docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations

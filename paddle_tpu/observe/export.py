@@ -15,8 +15,7 @@ serving
 Enablement is strictly opt-in, like ``PADDLE_TPU_TRACE``: with
 ``PADDLE_TPU_METRICS_PORT`` unset, :func:`start_from_env` returns None
 — no thread, no socket, zero movement on any ``paddle_export_*``
-family (tests pin exactly that). Port assignment follows the pserver
-rendezvous pattern (bench.py ``_run_dist_ctr_pserver``): bind port 0
+family (tests pin exactly that). Port assignment: bind port 0
 OURSELVES (no TOCTOU), then publish the real ``host:port`` atomically
 to ``PADDLE_TPU_METRICS_PORT_FILE`` for whoever launched us —
 tools/fleet_top.py and the fleet demo test read that file instead of
@@ -42,7 +41,7 @@ ENV_PORT_FILE = "PADDLE_TPU_METRICS_PORT_FILE"
 
 def default_instance() -> str:
     """This process's fleet identity: ``host:pid`` — unique across the
-    single-host process fleets the tests/bench spawn, stable for the
+    single-host process fleets the tests spawn, stable for the
     process lifetime, and human-readable in a dashboard row."""
     return "%s:%d" % (socket.gethostname(), os.getpid())
 

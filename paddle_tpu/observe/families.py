@@ -2,11 +2,10 @@
 
 Declared HERE (not in the subsystems) so that importing
 ``paddle_tpu.observe`` alone materializes every family with zeroed
-default children: a telemetry sidecar written by a process that died
-before reaching the executor (e.g. the bench backend probe failing to
-initialise) still carries the full executor/RPC schema — the
-diagnosis is "0 cache misses, 0 RPC calls, probe took 300s", not an
-absent file. Subsystems import their families from here and only ever
+default children: a snapshot dumped by a process that died before
+reaching the executor still carries the full executor/RPC schema — the
+diagnosis is "0 cache misses, 0 RPC calls", not an absent file.
+Subsystems import their families from here and only ever
 increment/observe.
 """
 
@@ -305,10 +304,6 @@ SERVING_TOKENS = REGISTRY.counter(
     "paddle_serving_tokens_total",
     "Tokens generated by the continuous-batching engine (prefill-"
     "sampled first tokens included)")
-SERVING_TOKENS_PER_SEC = REGISTRY.gauge(
-    "paddle_serving_tokens_per_sec",
-    "Aggregate engine throughput over the last completed drive "
-    "interval (set by the serving bench; 0 outside bench runs)")
 SERVING_PREFILL_PROGRAMS = REGISTRY.counter(
     "paddle_serving_prefill_programs_total",
     "Distinct prompt lengths the engine compiled a prefill executable "
@@ -361,10 +356,6 @@ SERVING_SPEC_DRAFT_STEPS = REGISTRY.counter(
     "Draft-model decode dispatches (k per verify step, plus the "
     "mirror-advance step a plain iteration takes while speculative "
     "slots are in the batch)")
-SERVING_SPEC_ACCEPT_RATE = REGISTRY.gauge(
-    "paddle_serving_spec_accept_rate",
-    "accepted/proposed draft-token ratio over the last completed "
-    "bench drive interval (set by the serving bench; 0 outside runs)")
 SERVING_ROUTER_ROUTED = REGISTRY.counter(
     "paddle_serving_router_routed_total",
     "Requests the router dispatched, by replica slot index (stable "
@@ -441,7 +432,7 @@ RESILIENCE_WEDGES = REGISTRY.counter(
     "Watchdog wedge detections: a heartbeat-stamped operation ran past "
     "its deadline with no progress stamp (one count per stalled "
     "operation, not per poll)", labels=("site",))
-for _site in ("executor.dispatch", "executor.wait", "backend.probe"):
+for _site in ("executor.dispatch", "executor.wait"):
     RESILIENCE_WEDGES.labels(site=_site)
 RESILIENCE_HEARTBEAT_AGE = REGISTRY.gauge(
     "paddle_resilience_heartbeat_age_seconds",
@@ -623,12 +614,12 @@ ANALYSIS_MEMORY_PROGRAMS = REGISTRY.counter(
     "(MemoryAnalysis construction), by trigger: 'lint' = the memory "
     "lint rules, 'cli' = tools/memory_report.py, 'window_tune' = the "
     "window-candidate budget pruner, 'serving' = the engine admission "
-    "guard, 'bench' = the peak_bytes_predicted row field, 'dist' = the "
+    "guard, 'dist' = the "
     "distributed verifier's per-pserver shard-fit proof, 'api' = "
     "direct callers (contrib.memory_usage_calc and user code)",
     labels=("site",))
-for _s in ("api", "lint", "cli", "window_tune", "serving", "bench",
-           "capture", "dist"):
+for _s in ("api", "lint", "cli", "window_tune", "serving", "capture",
+           "dist"):
     ANALYSIS_MEMORY_PROGRAMS.labels(site=_s)
 ANALYSIS_MEMORY_SECONDS = REGISTRY.histogram(
     "paddle_analysis_memory_seconds",
@@ -649,11 +640,10 @@ ANALYSIS_COST_PROGRAMS = REGISTRY.counter(
     "paddle_cost_programs_total",
     "Programs run through the roofline cost engine (CostAnalysis "
     "construction), by trigger: 'autotune' = the unified autotuner's "
-    "predict-then-prune ranking, 'bench' = analytic step FLOPs + "
-    "predicted_seconds row fields, 'cli' = tools/cost_report.py, "
+    "predict-then-prune ranking, 'cli' = tools/cost_report.py, "
     "'api' = direct callers",
     labels=("site",))
-for _s in ("api", "cli", "bench", "autotune"):
+for _s in ("api", "cli", "autotune"):
     ANALYSIS_COST_PROGRAMS.labels(site=_s)
 ANALYSIS_COST_SECONDS = REGISTRY.histogram(
     "paddle_cost_seconds",
@@ -895,7 +885,7 @@ KERNEL_TUNER_HITS = REGISTRY.counter(
     "this process already held the decision, 'disk' = the persisted "
     "winner cache (PADDLE_TPU_KERNEL_CACHE_DIR) supplied it — a warmed "
     "second process shows all-disk hits and zero tunes. Lookups, not "
-    "dispatches: flash_effective probes and bench row labeling consult "
+    "dispatches: flash_effective probes consult "
     "the table too; dispatches_total below counts actual dispatches",
     labels=("tier",))
 for _t in ("memory", "disk"):
@@ -1040,36 +1030,6 @@ TRACE_SITES = (
     # the existing serving.router.drain span with reason="roll"
     "export.save", "export.load",
 )
-
-# -------------------------------------------------------- backend/bench
-BACKEND_PROBE_SECONDS = REGISTRY.gauge(
-    "paddle_backend_probe_seconds",
-    "Wall time of the last jax backend-init probe attempt (bench.py)")
-BACKEND_PROBE_OK = REGISTRY.gauge(
-    "paddle_backend_probe_ok",
-    "1 if the last backend probe completed, 0 if it timed out")
-BACKEND_PROBE_ATTEMPTS = REGISTRY.counter(
-    "paddle_backend_probe_attempts_total",
-    "Backend init probe attempts by outcome — the bench retries "
-    "transient wedges (PADDLE_TPU_BENCH_INIT_ATTEMPTS) instead of "
-    "zeroing the round on the first one", labels=("outcome",))
-for _o in ("ok", "timeout", "error"):
-    BACKEND_PROBE_ATTEMPTS.labels(outcome=_o)
-BACKEND_PROBE_ATTEMPT_SECONDS = REGISTRY.histogram(
-    "paddle_backend_probe_attempt_seconds",
-    "Per-attempt backend init probe wall time (the gauge keeps only "
-    "the last attempt; the histogram keeps every retry, so a "
-    "post-mortem sees 'wedged 300s, wedged 300s, ok in 4s')")
-BENCH_ROWS = REGISTRY.counter(
-    "paddle_bench_rows_total",
-    "Bench rows emitted by outcome", labels=("status",))
-BENCH_MFU = REGISTRY.gauge(
-    "paddle_bench_mfu",
-    "Model-flops utilization of the LAST bench row that measured one "
-    "(bench.py _mfu_fields; XLA cost_analysis flops / chip peak). "
-    "Stays 0 when no row measured MFU — the row fields keep the "
-    "null-never-zero contract; this gauge is the live-dashboard "
-    "mirror (tools/fleet_top.py MFU column)")
 
 # ------------------------------------------------------ fleet telemetry
 # (observe/export.py, fleet.py, slo.py, shutdown.py — the live metrics

@@ -52,10 +52,10 @@ def quantile_from_buckets(buckets, count, q):
     (prometheus-style linear interpolation within the winning bucket;
     the open-ended +Inf bucket reports its lower edge).
 
-    THE shared percentile implementation: ``Histogram.quantile``, the
-    bench serving sidecars, ``tools/serving_load.py`` and
-    ``tools/stats_dump.py`` all route through this one function so a
-    p99 means the same thing everywhere it is printed."""
+    THE shared percentile implementation: ``Histogram.quantile``,
+    ``observe/slo.py`` and ``tools/stats_dump.py`` all route through
+    this one function so a p99 means the same thing everywhere it is
+    printed."""
     if not count:
         return None
     target = q * count

@@ -1,8 +1,8 @@
 """Graceful-shutdown handlers: dump the evidence BEFORE dying.
 
 The flight recorder dumps on crash/atexit (trace.py) and the registry
-dumps when bench rows finish — but a SIGTERM from an orchestrator (or
-a ctrl-C) kills the process through an exception path neither covers
+dumps when its owner calls ``observe.dump`` — but a SIGTERM from an
+orchestrator (or a ctrl-C) kills the process through an exception path neither covers
 reliably: daemon threads (the MetricsExporter) die mid-request, atexit
 may never run if a second signal lands. This module installs
 SIGTERM/SIGINT handlers that, in order:

@@ -27,8 +27,8 @@ it died*. Two pieces:
   diagnosable post-mortem from its open span (a ``B`` with no ``E``):
   trace id, site, plan signature, and the events leading up to it.
   ``tools/trace_view.py`` summarizes/validates a dump and exports
-  chrome-trace; ``export_chrome_trace()`` merges the ring with the
-  profiler's host timeline when a profiling session ran.
+  chrome-trace; ``export_chrome_trace()`` writes the ring, which holds a
+  profiling session's ``RecordEvent`` markers too (they are spans).
 
 * **The profiler's clock** — a span is also a
   ``jax.profiler.TraceAnnotation`` of its site name. Whenever anyone is
@@ -488,15 +488,12 @@ atexit.register(_atexit_dump)
 
 # -------------------------------------------------------- chrome export
 def to_chrome_events(events: List[Dict[str, Any]],
-                     base_t: Optional[float] = None,
                      pid: Optional[int] = None) -> List[dict]:
     """Convert event dicts to chrome://tracing entries. Matched B/E
     pairs (by span id) become complete ``X`` slices; an unmatched B —
     the wedged-dispatch signature — stays a ``B`` so it renders as an
-    open slice; instants map to ``i``. ``base_t`` anchors ts=0 (pass the
-    profiler's start to merge timelines)."""
-    if base_t is None:
-        base_t = min((e["t"] for e in events), default=0.0)
+    open slice; instants map to ``i``. The oldest event is ts=0."""
+    base_t = min((e["t"] for e in events), default=0.0)
     pid = pid if pid is not None else os.getpid()
     ends = {e["span"]: e for e in events if e["ph"] == "E"}
     out = []
@@ -525,22 +522,10 @@ def to_chrome_events(events: List[Dict[str, Any]],
 
 
 def export_chrome_trace(path: str) -> str:
-    """Write the ring as chrome://tracing JSON, MERGED with the host
-    profiler's RecordEvent timeline when a profiling session recorded
-    one — span slices and profiler slices share the clock (both are
-    ``time.perf_counter``), so one chrome://tracing load shows both."""
-    from .. import profiler as _prof
-
-    events = RECORDER.events()
-    prof_events = list(_prof._events)
-    base = _prof._start_ts if (prof_events and _prof._start_ts is not None) \
-        else None
-    trace = to_chrome_events(events, base_t=base)
-    if prof_events and base is not None:
-        for name, s_us, e_us, tid in prof_events:
-            trace.append({"name": name, "cat": "host", "ph": "X",
-                          "ts": s_us, "dur": e_us - s_us,
-                          "pid": os.getpid(), "tid": tid})
+    """Write the ring as chrome://tracing JSON: the program's spans and
+    a profiling session's ``RecordEvent`` markers are the same span
+    type, so one list holds both."""
+    trace = to_chrome_events(RECORDER.events())
     with open(path, "w") as f:
         json.dump({"traceEvents": trace, "displayTimeUnit": "ms"}, f)
     return path

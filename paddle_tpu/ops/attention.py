@@ -161,8 +161,8 @@ _use_interpret = use_interpret
 
 def fused_attention_enabled() -> bool:
     """Single source of truth for the PADDLE_TPU_FUSED_ATTENTION knob
-    (default on): models and bench must agree on which path a run
-    exercises, or rows get mislabeled."""
+    (default on): models and whoever labels a run must agree on which
+    path it exercises."""
     return _os.environ.get("PADDLE_TPU_FUSED_ATTENTION", "1") != "0"
 
 
@@ -196,8 +196,9 @@ def flash_min_seq() -> int:
 
 def flash_effective(seq_len: int, kv_len: int = None) -> bool:
     """Whether the fused-attention op would actually run the Pallas
-    kernel at these sequence lengths (bench rows label flash vs composed
-    from this, so a short-S run never claims a kernel measurement).
+    kernel at these sequence lengths (chip_smoke.py and the benchmark
+    label flash vs composed from this, so a short-S run never claims a
+    kernel measurement).
 
     Three-tier precedence, tested in tests/test_flash_dispatch.py:
 

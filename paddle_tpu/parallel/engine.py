@@ -105,7 +105,7 @@ class ParallelEngine:
                                  [plan.feed_shardings[n]
                                   for n in plan.feed_names],
                                  feeds, const_state, mut_state, rng, scope,
-                                 return_numpy, "", "engine_run", steps=1)
+                                 return_numpy, "", steps=1)
 
     def run_repeated(self, feed, fetch_list, scope: Optional[Scope] = None,
                      steps: int = 1, return_numpy: bool = True,
@@ -140,7 +140,6 @@ class ParallelEngine:
             return self._execute(plan, fn, feed_in, feeds, const_state,
                                  mut_state, rng, scope, return_numpy,
                                  " after %d scanned steps" % steps,
-                                 "engine_run_repeated[%d]" % steps,
                                  steps=steps)
 
     def _multi_fn(self, plan, steps, feed_stacked,
@@ -189,7 +188,7 @@ class ParallelEngine:
         return fn, feed_in
 
     def _execute(self, plan, fn, feed_shardings, feeds, const_state,
-                 mut_state, rng, scope, return_numpy, nan_suffix, event,
+                 mut_state, rng, scope, return_numpy, nan_suffix,
                  steps=1):
         """Place inputs per their shardings (feeds split over the data
         axis, state per its spec), run one compiled dispatch, write the
@@ -228,23 +227,12 @@ class ParallelEngine:
             if sp.attrs is not None:
                 sp.attrs["arrays"], sp.attrs["bytes"] = moved
 
-        from ..profiler import RecordEvent, is_profiler_enabled
-
         # one executable per jitted fn of a plan: its first dispatch
         # compiles, which the heartbeat tells the watchdog
         sig = (site, fn)
-        if is_profiler_enabled():
-            with RecordEvent(event):
-                with _dispatch_guard(plan, sig):
-                    fetches, new_mut, new_pure, new_rng = fn(
-                        feeds, const_state, mut_state, rng)
-                fetches = [f.block_until_ready()
-                           if hasattr(f, "block_until_ready") else f
-                           for f in fetches]
-        else:
-            with _dispatch_guard(plan, sig):
-                fetches, new_mut, new_pure, new_rng = fn(
-                    feeds, const_state, mut_state, rng)
+        with _dispatch_guard(plan, sig):
+            fetches, new_mut, new_pure, new_rng = fn(
+                feeds, const_state, mut_state, rng)
         plan.compiled_sigs.add(sig)
         ENGINE_RUN_SECONDS.labels(site=site).observe(
             time.perf_counter() - t_dispatch)

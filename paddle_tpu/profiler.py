@@ -1,53 +1,48 @@
-"""Profiler: host annotations + aggregation tables + chrome trace export.
+"""Profiler: the Fluid session API over the program's one span type.
 
 Analog of the reference profiling stack (SURVEY §5):
-* `RecordEvent` RAII markers — platform/profiler.h:81 (placed around every
-  op run in operator.cc:180; here around every compiled-step launch, since
-  ops fuse into one XLA executable)
+* `RecordEvent` RAII markers — platform/profiler.h:81. Here a
+  `RecordEvent(name)` IS an `observe.trace` span named `name`: it lands
+  in the flight recorder's ring beside the program's own spans
+  (`executor.call` and its children, `serving.engine.step`, ...) and, as
+  every span does, in a running `jax.profiler` trace.
 * `EnableProfiler/DisableProfiler` + aggregated event tables —
-  platform/profiler.cc (calls / total / min / max / avg per event key)
-* chrome://tracing JSON — tools/timeline.py converts the reference's
-  profiler.proto; here the host events serialize straight to the chrome
-  trace format, no converter needed
+  platform/profiler.cc (calls / total / min / max / avg per event key).
+  A session keeps no event list of its own: `start_profiler` notes the
+  time, `stop_profiler` aggregates the spans the ring recorded since.
+* chrome://tracing JSON — `observe.trace.export_chrome_trace`.
 * device side — DeviceTracer hooked CUPTI; the XLA/TPU analog is
-  jax.profiler's trace (TensorBoard/Perfetto), started alongside the host
-  recorder when state includes the device.
+  jax.profiler's trace (TensorBoard/Perfetto), started with the session
+  when state includes the device.
 """
 
 from __future__ import annotations
 
 import contextlib
-import json
-import os
-import threading
 import time
 from typing import Dict, List, Optional
+
+from .observe import trace as _trace
 
 __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
            "cuda_profiler", "RecordEvent", "is_profiler_enabled"]
 
-_lock = threading.Lock()
-_enabled = False
+_mark: Optional[float] = None  # perf_counter at session start; None = off
 _xla_trace = False
-_events: List[tuple] = []  # (name, start_us, end_us, thread_id)
-_start_ts: Optional[float] = None
 
 
 def is_profiler_enabled() -> bool:
-    return _enabled
+    return _mark is not None
 
 
 def start_profiler(state: str = "All",
                    trace_dir: str = "/tmp/paddle_tpu_trace"):
     """EnableProfiler analog (profiler.h:166). state: CPU|GPU|All — GPU/All
     also starts the XLA device trace (DeviceTracer/CUPTI analog)."""
-    global _enabled, _xla_trace, _start_ts
-    with _lock:
-        if _enabled:
-            return
-        _events.clear()
-        _enabled = True
-        _start_ts = time.perf_counter()
+    global _mark, _xla_trace
+    if _mark is not None:
+        return
+    _mark = time.perf_counter()
     if state in ("GPU", "All"):
         import jax
 
@@ -60,14 +55,13 @@ def start_profiler(state: str = "All",
 
 def stop_profiler(sorted_key: Optional[str] = None,
                   profile_path: Optional[str] = None):
-    """DisableProfiler analog: stop traces, print the aggregated event
-    table, optionally dump a chrome://tracing JSON to profile_path."""
-    global _enabled, _xla_trace
-    with _lock:
-        if not _enabled:
-            return
-        _enabled = False
-        events = list(_events)
+    """DisableProfiler analog: stop traces, print the aggregated table of
+    the spans recorded since `start_profiler`, optionally dump the ring as
+    chrome://tracing JSON to profile_path."""
+    global _mark, _xla_trace
+    if _mark is None:
+        return
+    mark, _mark = _mark, None
     if _xla_trace:
         import jax
 
@@ -75,14 +69,17 @@ def stop_profiler(sorted_key: Optional[str] = None,
             jax.profiler.stop_trace()
         finally:
             _xla_trace = False
-    _print_table(events, sorted_key)
+    _print_table(mark, sorted_key)
     if profile_path:
-        _write_chrome_trace(events, profile_path)
+        _trace.export_chrome_trace(profile_path)
 
 
 def reset_profiler():
-    with _lock:
-        _events.clear()
+    """Drop what the running session has seen so far: its table starts
+    again from now."""
+    global _mark
+    if _mark is not None:
+        _mark = time.perf_counter()
 
 
 @contextlib.contextmanager
@@ -104,41 +101,21 @@ def cuda_profiler(*a, **kw):  # name kept for porting ease; maps to XLA trace
 
 
 class RecordEvent:
-    """RAII trace annotation (platform/profiler.h:81). Always feeds the
-    host aggregation table; additionally shows up in the XLA device trace
-    when one is running."""
+    """RAII marker (platform/profiler.h:81): `observe.trace.trace_span`
+    under its Fluid name."""
 
     def __init__(self, name: str):
         self.name = name
-        self._t0 = None
-        self._ann = None
+        self._span = None
 
     def __enter__(self):
-        if _enabled:
-            self._t0 = time.perf_counter()
-        if _xla_trace:
-            import jax
-
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
+        self._span = _trace.trace_span(self.name)
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
-        if self._t0 is not None:
-            t1 = time.perf_counter()
-            with _lock:
-                if _enabled:
-                    _events.append((
-                        self.name,
-                        (self._t0 - _start_ts) * 1e6,
-                        (t1 - _start_ts) * 1e6,
-                        threading.get_ident(),
-                    ))
-            self._t0 = None
-        return False
+        span, self._span = self._span, None
+        return span.__exit__(*exc)
 
 
 def record_event(name: str) -> RecordEvent:
@@ -146,46 +123,36 @@ def record_event(name: str) -> RecordEvent:
 
 
 # ---------------------------------------------------------------- reporting
-def _print_table(events, sorted_key=None):
-    if not events:
-        return
-    agg: Dict[str, List[float]] = {}
-    for name, s, e, _tid in events:
-        agg.setdefault(name, []).append(e - s)
-    rows = []
-    for name, ds in agg.items():
-        rows.append((name, len(ds), sum(ds), sum(ds) / len(ds), min(ds),
-                     max(ds)))
-    keyfn = {
-        None: lambda r: -r[2],
-        "default": lambda r: -r[2],
-        "total": lambda r: -r[2],
-        "calls": lambda r: -r[1],
-        "ave": lambda r: -r[3],
-        "min": lambda r: r[4],
-        "max": lambda r: -r[5],
-    }.get(sorted_key, lambda r: -r[2])
-    rows.sort(key=keyfn)
+_SORT_KEYS = {
+    "calls": lambda r: -r[1],
+    "total": lambda r: -r[2],
+    "ave": lambda r: -r[3],
+    "min": lambda r: r[4],
+    "max": lambda r: -r[5],
+}
+
+
+def _print_table(mark: float, sorted_key=None):
     print("-------------------------  Profiling Report  "
           "-------------------------")
+    if not _trace.trace_enabled():
+        print("tracing is off (%s=0): no span was recorded"
+              % _trace.ENV_TRACE)
+        return
+    events = _trace.RECORDER.events()
+    if len(events) == _trace.RECORDER.capacity and events[0]["t"] > mark:
+        print("the ring holds the last %d events (%s): the session's "
+              "earlier spans are not counted"
+              % (len(events), _trace.ENV_EVENTS))
+    agg: Dict[str, List[float]] = {}
+    for e in events:
+        if e["ph"] == "E" and e["t"] - e["dur"] >= mark:
+            agg.setdefault(e["site"], []).append(e["dur"] * 1e6)
+    rows = [(name, len(ds), sum(ds), sum(ds) / len(ds), min(ds), max(ds))
+            for name, ds in agg.items()]
+    rows.sort(key=_SORT_KEYS.get(sorted_key, _SORT_KEYS["total"]))
     print("%-40s %8s %12s %12s %12s %12s" %
           ("Event", "Calls", "Total(us)", "Avg(us)", "Min(us)", "Max(us)"))
     for name, calls, total, avg, mn, mx in rows:
         print("%-40s %8d %12.1f %12.1f %12.1f %12.1f" %
               (name[:40], calls, total, avg, mn, mx))
-
-
-def _write_chrome_trace(events, path: str):
-    """chrome://tracing JSON (tools/timeline.py output format analog)."""
-    tids = {}
-    trace = []
-    for name, s, e, tid in events:
-        tids.setdefault(tid, len(tids))
-        trace.append({
-            "name": name, "cat": "host", "ph": "X",
-            "ts": s, "dur": e - s, "pid": os.getpid(),
-            "tid": tids[tid],
-        })
-    with open(path, "w") as f:
-        json.dump({"traceEvents": trace,
-                   "displayTimeUnit": "ms"}, f)

@@ -1,8 +1,8 @@
 """Full-jitter exponential backoff, shared by every retry loop.
 
 One formula (AWS "full jitter": ``uniform(0, min(cap, base * 2^attempt))``)
-used by the resilience supervisor's recovery sleeps, the RPC client's
-``get_var`` init-race polling and the bench backend-probe retries — so a
+used by the resilience supervisor's recovery sleeps and the RPC client's
+``get_var`` init-race polling — so a
 fleet of restarting trainers never thundering-herds a recovering
 pserver, and chaos tests can pin the envelope deterministically by
 passing a seeded ``random.Random``.

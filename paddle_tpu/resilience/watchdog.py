@@ -22,10 +22,9 @@ and then escalates through the policy ladder:
 3. **kill** — ``kill=True`` SIGKILLs the whole process GROUP, the only
    exit from a C-level hang (default off).
 
-``run_with_deadline`` is the bounded-call primitive the old
-``bench.py:_probe_backend`` hand-rolled inline — run a possibly-wedging
-callable on a daemon thread, give up at the deadline, report which of
-ok/error/timeout happened and how long it took.
+``run_with_deadline`` is the bounded-call primitive — run a
+possibly-wedging callable on a daemon thread, give up at the deadline,
+report which of ok/error/timeout happened and how long it took.
 """
 
 from __future__ import annotations
@@ -294,7 +293,7 @@ def run_with_deadline(fn: Callable, timeout_s: float, poll_s: float = 0.25):
     t.start()
     deadline = t0 + timeout_s
     # poll instead of one long join: an instant failure must not burn
-    # the full wedge timeout (the bench probe's round-5 lesson)
+    # the full wedge timeout
     while t.is_alive() and time.perf_counter() < deadline:
         t.join(min(poll_s, max(deadline - time.perf_counter(), 0.001)))
     dt = time.perf_counter() - t0

@@ -29,8 +29,8 @@ pieces (docs/SERVING.md has the architecture):
 
 All five report through ``paddle_tpu.observe`` (queue depth,
 time-in-queue, occupancy, padding waste, tokens/sec, prefix hit rate,
-speculative acceptance, router restarts) and are exercised by the
-``PADDLE_TPU_BENCH_SERVING=1`` bench mode.
+speculative acceptance, router restarts); the benchmark's serving cells
+(``benchmarks/run.py``) drive ``DecodeEngine`` on the chip.
 """
 
 from __future__ import annotations

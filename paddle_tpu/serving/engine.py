@@ -437,7 +437,7 @@ class DecodeEngine:
 
     ``params`` maps parameter name -> array (the training scope's
     persistables, ``gpt_*`` names); None keeps the startup
-    initialization (bench/synthetic runs). ``submit`` returns a
+    initialization (synthetic runs). ``submit`` returns a
     ``ServingRequest`` whose ``result()`` is the full int64 token
     sequence ``[P + generated]`` (budget ``n_new``, or shorter when
     ``eos_id`` is sampled — the EOS token is included). Deadlines
@@ -656,8 +656,7 @@ class DecodeEngine:
     def predicted_resident_bytes(self) -> Optional[int]:
         """Static estimate of this engine's resident device bytes
         (target + draft weights, 2L cache slabs, one decode step's
-        activations) — None when the byte model could not be built.
-        The bench's serving ``peak_bytes_predicted`` field."""
+        activations) — None when the byte model could not be built."""
         return None if self._mem is None else int(self._mem["resident"])
 
     def predicted_bytes(self, prompt_len: int) -> Optional[int]:

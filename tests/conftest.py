@@ -91,10 +91,6 @@ SLOW_TESTS = {
     "test_examples.py::test_train_gpt_tpu_example",
     "test_examples.py::test_train_multichip_example",
     "test_attention.py::test_transformer_with_fused_attention_trains",
-    "test_bench_cli.py::test_bench_fused_row_records_pallas_mode",
-    "test_bench_cli.py::test_bench_orchestrator_happy_path",
-    "test_bench_cli.py::test_bench_orchestrator_kills_hung_workload",
-    "test_chip_bringup.py::test_bench_attention_row_with_broken_kernel_fails_without_composed_row",
     "test_imperative_capture.py::test_captured_replay_2x_faster_than_eager",
     "test_book.py::test_image_classification_cifar_conv_bn",
     "test_book.py::test_label_semantic_roles_crf",

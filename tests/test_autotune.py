@@ -84,7 +84,6 @@ def _value(name, **labels):
 def _cost_family_totals():
     return (_value("paddle_cost_programs_total", site="api")
             + _value("paddle_cost_programs_total", site="cli")
-            + _value("paddle_cost_programs_total", site="bench")
             + _value("paddle_cost_programs_total", site="autotune"),
             _value("paddle_cost_seconds"),
             _value("paddle_cost_unruled_ops_total"))

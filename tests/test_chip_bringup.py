@@ -9,9 +9,8 @@
 (b) ``use_interpret`` decides from ``platform == "tpu"`` alone and raises
     when the backend cannot be asked.
 (c) ``flags.enable_compile_cache`` is placed from outside.
-(d) ``bench.py``'s parent never imports jax and fails when any row fails.
-(e) ``chip_smoke.py`` fails on the CPU and never claims a TPU it did not see.
-(f) ``TPUPlace`` refuses a CPU nobody asked for.
+(d) ``chip_smoke.py`` fails on the CPU and never claims a TPU it did not see.
+(e) ``TPUPlace`` refuses a CPU nobody asked for.
 """
 
 import json
@@ -378,85 +377,7 @@ def test_compile_cache_default_is_the_checkout_from_any_cwd(tmp_path):
     assert a["dir"] == b["dir"] == os.path.join(ROOT, ".jax_cache")
 
 
-# ------------------------------------------------------ (d) bench.py parent
-_BENCH_PARENT = textwrap.dedent("""
-    import sys
-    sys.path.insert(0, %r)
-    sys.argv = ["bench.py"]
-    import bench
-
-    class _ProbeOk:
-        pid = 0
-        def __init__(self, *a, **k): pass
-        def wait(self, timeout=None): return 0
-    bench.subprocess.Popen = _ProbeOk
-    bench.ORDER = ["row_a", "row_b"]
-    outcomes = %r
-    bench._spawn_workload = lambda name, args, timeout_s: outcomes[name]
-    rc = bench.main()
-    assert "jax" not in sys.modules, "bench.py's parent imported jax"
-    assert "paddle_tpu" not in sys.modules
-    sys.exit(rc)
-""")
-
-
-@pytest.mark.parametrize("outcomes,want_rc", [
-    ({"row_a": True, "row_b": True}, 0),
-    ({"row_a": True, "row_b": False}, 1),   # one of two rows failed
-    ({"row_a": False, "row_b": True}, 1),
-])
-def test_bench_parent_stays_off_jax_and_fails_on_any_failed_row(outcomes,
-                                                                want_rc):
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("PADDLE_TPU_BENCH_")}
-    out = subprocess.run(
-        [sys.executable, "-c", _BENCH_PARENT % (ROOT, outcomes)],
-        env=env, capture_output=True, text=True, timeout=120)
-    assert out.returncode == want_rc, out.stderr[-2000:]
-
-
-def test_bench_attention_row_with_broken_kernel_fails_without_composed_row(
-        tmp_path):
-    """A kernel that cannot be built (an illegal block size) is an error
-    row and a non-zero exit: the row is never re-run on the composed
-    path."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_FLASH_MIN_SEQ="0", PADDLE_TPU_FLASH_BQ="7",
-               PADDLE_TPU_TELEMETRY_DIR=str(tmp_path),
-               PADDLE_TPU_BENCH_WORKLOAD_TIMEOUT="300")
-    env.pop("XLA_FLAGS", None)
-    env.pop("PADDLE_TPU_FUSED_ATTENTION", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"), "--quick",
-         "--only", "transformer"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        text=True, timeout=400)
-    rows = [json.loads(l) for l in out.stdout.splitlines() if l.strip()]
-    assert out.returncode != 0
-    assert rows and all("error" in r for r in rows), rows
-    assert not any("value" in r or r.get("attention_path") == "composed"
-                   for r in rows)
-    assert any("PADDLE_TPU_FLASH_BQ" in r["error"] for r in rows), rows
-
-
-def test_bench_peak_flops_unknown_device_is_an_error(monkeypatch):
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    monkeypatch.setenv("PADDLE_TPU_PEAK_TFLOPS", "123")  # no longer read
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("cpu", "cpu")])
-    assert bench.peak_flops() is None
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_Dev("tpu", "TPU v5 lite")])
-    assert bench.peak_flops() == 197e12
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("tpu", "TPU v9")])
-    with pytest.raises(RuntimeError, match="TPU v9"):
-        bench.peak_flops()
-
-
-# ------------------------------------------------------- (e) chip_smoke.py
+# ------------------------------------------------------- (d) chip_smoke.py
 def _chip_smoke(*args, cwd=ROOT, script=None, **env_extra):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
     env.pop("XLA_FLAGS", None)
@@ -513,7 +434,7 @@ def test_chip_smoke_forced_failure_exits_nonzero(tmp_path):
     assert "chip_smoke: FAILED" in out.stderr
 
 
-# ------------------------------------------------------------ (f) TPUPlace
+# ------------------------------------------------------------ (e) TPUPlace
 def test_tpuplace_refuses_a_cpu_nobody_asked_for(monkeypatch):
     import paddle_tpu as fluid
 

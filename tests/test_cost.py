@@ -164,6 +164,30 @@ def test_device_model_table_and_partial_env_layering(monkeypatch):
     assert dev2.conv_peak_flops == 275e12  # preserved: flops not pinned
 
 
+@pytest.mark.parametrize("field,attr", [
+    ("bf16_flops_per_s", "peak_flops"),
+    ("hbm_bytes_per_s", "peak_bandwidth")])
+def test_program_peaks_agree_with_the_benchmarks(monkeypatch, field, attr):
+    """Two tables of chip peaks stay, on purpose: the yardstick's
+    (benchmarks/lib/peaks.py, keyed by the exact ``device_kind``) and
+    the cost model's (``cost._TPU_PEAK_*``, by substring). On the chip
+    the ledger is measured on they must give the same numbers."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "_bench_peaks", os.path.join(ROOT, "benchmarks", "lib", "peaks.py"))
+    peaks = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(peaks)
+    for k in ("PADDLE_TPU_PEAK_TFLOPS", "PADDLE_TPU_PEAK_GBPS"):
+        monkeypatch.delenv(k, raising=False)
+    for kind, row in peaks.PEAKS.items():
+        monkeypatch.setattr(DeviceModel, "_device_kind",
+                            staticmethod(lambda kind=kind: "tpu:" + kind))
+        dev = DeviceModel.current()
+        assert dev.source == "table", (kind, dev)
+        assert getattr(dev, attr) == row[field], (kind, field)
+
+
 def test_device_model_malformed_env_raises(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_PEAK_TFLOPS", "fast")
     with pytest.raises(ValueError, match="PADDLE_TPU_PEAK_TFLOPS"):

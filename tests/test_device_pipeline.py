@@ -666,8 +666,8 @@ def test_pipelined_beats_naive_loop_with_slow_reader(tmp_path):
     """Acceptance criterion: on an artificially slow reader (sleep per
     batch) and a non-trivial step, run_pipelined >= 1.5x the steps/sec
     of the naive run() loop, numerically identical fetches, and the
-    feed->run gap histogram shrinking — demonstrated through the same
-    telemetry sidecars bench.py writes, diffed by stats_dump --diff."""
+    feed->run gap histogram shrinking — demonstrated through two
+    ``observe.dump`` snapshots, diffed by stats_dump --diff."""
     # sized so the step is genuinely non-trivial on the CPU backend:
     # the overlap win is (sleep+step)/max(sleep,step), maximal when the
     # reader sleep matches the step time

@@ -183,8 +183,7 @@ def test_flash_env_keys_the_plan_cache(monkeypatch):
 def test_tuned_dispatch_same_numerics(monkeypatch, tmp_path):
     """A tuned 'composed' entry at a kernel-eligible S produces the
     composed result exactly (the dispatch flip is numerics-neutral),
-    and the decision ledger marks the choice as tuned — what bench rows
-    record as kernel_tuned (pin_baselines then skips them)."""
+    and the decision ledger marks the choice as tuned."""
     from paddle_tpu import kernels
     from paddle_tpu.kernels import tune
     from paddle_tpu.ops import attention as A

@@ -5,8 +5,8 @@ rates, SLO objectives, graceful shutdown, and the live dashboards.
 Contracts pinned here:
 
 * ``Histogram.quantile`` / ``quantile_from_buckets`` — THE shared
-  percentile estimator (bench, serving_load, slo.py all route through
-  it; the hand-rolled percentiles are gone).
+  percentile estimator (slo.py and every snapshot reader route
+  through it).
 * promparse — render → parse → render is byte-identical across every
   declared family, including multi-label ordering and HELP/label
   escaping; a counter that merely LOOKS like a histogram suffix is not
@@ -124,17 +124,13 @@ def test_histogram_quantile_shared_helper():
 
 
 def test_quantile_pin_against_handrolled_percentiles():
-    """Satellite 5 pin: bench/serving_load switched from nearest-rank
-    percentiles to the shared bucket quantile; the hand-rolled helpers
-    are gone and the new values agree within one bucket."""
-    import bench
-    import serving_load
-
-    assert not hasattr(serving_load, "_pctl")
-    assert not hasattr(bench, "_serving_pctl")
+    """The shared bucket quantile against the nearest-rank percentile
+    load drivers used to hand-roll: the two agree within one bucket."""
     rs = np.random.RandomState(3)
     lat = sorted(rs.gamma(2.0, 0.01, size=200))
-    hist = serving_load._latency_hist(lat)
+    hist = om.Registry().histogram("paddle_serving_request_seconds")
+    for v in lat:
+        hist.observe(v)
     bounds = sorted(om.DEFAULT_BUCKETS)
     for q in (0.50, 0.99):
         old = lat[min(len(lat) - 1,
@@ -302,7 +298,7 @@ def test_zero_overhead_off_switch(monkeypatch):
         "paddle_fleet_instances_expired_total",
         "paddle_slo_evaluations_total", "paddle_slo_breaches_total",
         "paddle_shutdown_signals_total",
-        "paddle_serving_memory_headroom_bytes", "paddle_bench_mfu")
+        "paddle_serving_memory_headroom_bytes")
     before = observe.snapshot()
     n_threads = threading.active_count()
     assert start_from_env() is None
@@ -606,7 +602,7 @@ def test_fleet_top_once_json(tmp_path):
         row = out["rows"][0]
         assert row["state"] == "live"
         assert set(row) >= {"instance", "steps_per_sec",
-                            "tokens_per_sec", "mfu", "queue_depth",
+                            "tokens_per_sec", "queue_depth",
                             "slots_active", "headroom_bytes"}
         assert out["breaches"] == []  # first tick is baseline-only
     finally:

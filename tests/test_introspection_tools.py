@@ -1,13 +1,11 @@
-"""contrib.memory_usage_calc / contrib.op_frequence / debugger /
-tools/timeline.py — program-introspection parity surface.
+"""contrib.memory_usage_calc / contrib.op_frequence / debugger —
+program-introspection parity surface.
 
 Reference analogs: contrib/memory_usage_calc.py:46, contrib/
-op_frequence.py:23, fluid/debugger.py, tools/timeline.py.
+op_frequence.py:23, fluid/debugger.py.
 """
 
-import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -98,50 +96,6 @@ def test_debugger_pprint_and_dot(tmp_path):
     assert os.path.exists(dot_path)
     assert "digraph" in dot and 'fillcolor="yellow"' in dot
     assert dot.count('shape="ellipse"') == len(main.global_block().ops)
-
-
-def test_timeline_merge(tmp_path):
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    import timeline
-
-    def fake_trace(path, name):
-        with open(path, "w") as f:
-            json.dump({"traceEvents": [
-                {"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-                 "args": {"name": "old"}},
-                {"name": name, "ph": "X", "pid": 0, "tid": 1,
-                 "ts": 1, "dur": 2, "cat": "op"},
-            ]}, f)
-
-    p0, p1 = str(tmp_path / "a.json"), str(tmp_path / "b.json")
-    fake_trace(p0, "step_a")
-    fake_trace(p1, "step_b")
-    out = timeline.merge_traces([("t0", p0), ("t1", p1)])
-    evs = out["traceEvents"]
-    lanes = [e for e in evs if e.get("name") == "process_name"]
-    assert {l["args"]["name"] for l in lanes} == {"t0", "t1"}
-    assert {e["pid"] for e in evs if e.get("ph") == "X"} == {0, 1}
-
-
-def test_timeline_profiler_roundtrip(tmp_path):
-    """End-to-end: run a step under the profiler, dump a chrome trace,
-    merge it with itself via the tool."""
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    import timeline
-
-    from paddle_tpu import profiler
-
-    main, startup, loss = _small_program()
-    exe = fluid.Executor(fluid.TPUPlace())
-    exe.run(startup)
-    prof_path = str(tmp_path / "prof.json")
-    with profiler.profiler(profile_path=prof_path):
-        exe.run(main, feed={"x": np.zeros((2, 4), "float32")},
-                fetch_list=[loss])
-    assert os.path.exists(prof_path)
-    merged = timeline.merge_traces([("t0", prof_path), ("t1", prof_path)])
-    assert len([e for e in merged["traceEvents"]
-                if e.get("name") == "process_name"]) == 2
 
 
 def test_graphviz_and_net_drawer(tmp_path):

@@ -1,7 +1,7 @@
 """Telemetry-layer tests (observe/): registry semantics under threads,
 label families, snapshot/prometheus round-trip, executor cache metrics,
 RPC retry/deadline counters via the in-process RPC harness, span/profiler
-composition, and the bench telemetry sidecar + stats_dump CLI."""
+composition, and the dumped snapshot + stats_dump CLI."""
 
 import json
 import os
@@ -17,7 +17,6 @@ import paddle_tpu as fluid
 from paddle_tpu import observe
 
 ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
-BENCH = os.path.join(ROOT, "bench.py")
 STATS_DUMP = os.path.join(ROOT, "tools", "stats_dump.py")
 
 
@@ -414,55 +413,117 @@ def test_reader_batch_counts():
                   source="reader.batch") == b0 + 3
 
 
-# ------------------------------------------- bench sidecar + stats_dump
-def _run_bench_probe(tmp_path, platform):
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": platform,
-                "PADDLE_TPU_TELEMETRY_DIR": str(tmp_path),
-                "PADDLE_TPU_BENCH_INIT_TIMEOUT": "60"})
-    env.pop("XLA_FLAGS", None)
-    return subprocess.run(
-        [sys.executable, BENCH, "--probe"], env=env, timeout=240,
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
+# ------------------------------------------------- dump + stats_dump
+def _stats_dump(*args):
+    return subprocess.run([sys.executable, STATS_DUMP] + list(args),
+                          timeout=120, stdout=subprocess.PIPE,
+                          stderr=subprocess.PIPE, text=True)
 
 
-def test_bench_probe_writes_sidecar_and_stats_dump_renders(tmp_path):
-    proc = _run_bench_probe(tmp_path, "cpu")
-    assert proc.returncode == 0
-    sidecar = tmp_path / "BENCH_probe.telemetry.json"
-    assert sidecar.exists()
-    snap = json.loads(sidecar.read_text())
-    # executor + RPC metric families are non-empty even though this
-    # process never ran a step (acceptance criterion)
-    assert snap["metrics"]["paddle_executor_cache_misses_total"]["samples"]
+def _fc_program(fresh_programs):
+    main, startup, scope = fresh_programs
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[2], dtype="float32")
+        y = fluid.layers.fc(x, size=2)
+    exe = fluid.Executor()
+    exe.run(startup, scope=scope)
+    return lambda: exe.run(main, feed={"x": np.ones((2, 2), np.float32)},
+                           fetch_list=[y.name], scope=scope)
+
+
+def test_dump_renders_through_stats_dump(tmp_path, fresh_programs):
+    step = _fc_program(fresh_programs)
+    s0 = _value("paddle_executor_steps_total")
+    for _ in range(3):
+        step()
+    path = tmp_path / "snap.json"
+    observe.dump(str(path))
+    snap = json.loads(path.read_text())
+    assert snap["metrics"]["paddle_executor_steps_total"]["samples"][0][
+        "value"] == s0 + 3
+    # families of subsystems this process never entered are in the dump,
+    # zeroed: absent and zero are different diagnoses
     assert snap["metrics"]["paddle_rpc_client_calls_total"]["samples"]
-    assert snap["metrics"]["paddle_backend_probe_ok"]["samples"][0][
-        "value"] == 1.0
 
-    out = subprocess.run(
-        [sys.executable, STATS_DUMP, str(sidecar)], timeout=120,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-    assert out.returncode == 0, out.stdout
-    assert "paddle_backend_probe_seconds" in out.stdout
-
-    promo = subprocess.run(
-        [sys.executable, STATS_DUMP, str(sidecar), "--prometheus"],
-        timeout=120, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        text=True)
+    out = _stats_dump(str(path))
+    assert out.returncode == 0, out.stderr
+    assert "paddle_executor_steps_total" in out.stdout
+    assert "paddle_executor_run_seconds" in out.stdout   # a histogram row
+    promo = _stats_dump(str(path), "--prometheus")
     assert promo.returncode == 0
     _assert_valid_exposition(promo.stdout)
 
 
-def test_bench_probe_failure_still_writes_sidecar(tmp_path):
-    # the round-5 scenario: backend init fails -> the run must still
-    # leave a diagnosable sidecar, not just an error row
-    proc = _run_bench_probe(tmp_path, "bogus_backend")
-    assert proc.returncode == 1
-    rows = [json.loads(l) for l in proc.stdout.splitlines() if l]
-    assert any(r.get("metric") == "backend_init" and "error" in r
-               for r in rows)
-    snap = json.loads(
-        (tmp_path / "BENCH_probe.telemetry.json").read_text())
-    assert snap["metrics"]["paddle_backend_probe_ok"]["samples"][0][
-        "value"] == 0.0
-    assert snap["metrics"]["paddle_rpc_client_calls_total"]["samples"]
+def test_dump_after_a_failed_run_still_renders(tmp_path, fresh_programs):
+    """A process whose step failed must still leave a diagnosable
+    snapshot: every family present, the failure's own counter moved."""
+    from paddle_tpu.resilience.faults import FaultPlan, InjectedFault
+
+    step = _fc_program(fresh_programs)
+    f0 = _value("paddle_resilience_faults_injected_total",
+                site="executor.dispatch", mode="raise")
+    with FaultPlan().arm("executor.dispatch", every=True):
+        with pytest.raises(InjectedFault):
+            step()
+    path = tmp_path / "after_failure.json"
+    observe.dump(str(path))
+    snap = json.loads(path.read_text())
+    assert set(snap["metrics"]) == set(observe.snapshot()["metrics"])
+    out = _stats_dump(str(path), "--grep", "paddle_resilience")
+    assert out.returncode == 0, out.stderr
+    row = [l for l in out.stdout.splitlines()
+           if l.startswith("paddle_resilience_faults_injected_total")
+           and "executor.dispatch" in l and "raise" in l]
+    assert row and float(row[0].split()[-1]) == f0 + 1, out.stdout
+    # a gauge at 0 renders (zero-suppression only drops counters)
+    assert "paddle_resilience_watchdog_armed" in out.stdout
+
+
+def _mini_snap(steps, gap_bucket_counts):
+    """Minimal valid telemetry snapshot for stats_dump --diff tests."""
+    total = sum(gap_bucket_counts.values())
+    acc, buckets = 0, {}
+    for le in sorted(gap_bucket_counts, key=float):
+        acc += gap_bucket_counts[le]
+        buckets[le] = acc
+    buckets["+Inf"] = total
+    return {
+        "version": 1, "pid": 1, "unix_time": 0.0,
+        "metrics": {
+            "paddle_executor_steps_total": {
+                "type": "counter", "help": "", "labelnames": [],
+                "samples": [{"labels": {}, "value": steps}]},
+            "paddle_feed_to_run_gap_seconds": {
+                "type": "histogram", "help": "", "labelnames": [],
+                "samples": [{"labels": {}, "sum": 0.1 * total,
+                             "count": total, "buckets": buckets}]},
+            "paddle_resilience_watchdog_armed": {
+                "type": "gauge", "help": "", "labelnames": [],
+                "samples": [{"labels": {}, "value": 0}]},
+        }}
+
+
+def test_stats_dump_diff_prints_per_family_deltas(tmp_path):
+    a, b = tmp_path / "a.json", tmp_path / "b.json"
+    a.write_text(json.dumps(_mini_snap(10, {"0.01": 10})))
+    b.write_text(json.dumps(_mini_snap(25, {"0.001": 15})))
+    out = _stats_dump("--diff", str(a), str(b))
+    assert out.returncode == 0, out.stderr
+    # counter delta and side-by-side histogram stats both render
+    assert "paddle_executor_steps_total" in out.stdout
+    assert "+15" in out.stdout
+    assert "paddle_feed_to_run_gap_seconds" in out.stdout
+    line = [l for l in out.stdout.splitlines()
+            if l.startswith("paddle_feed_to_run_gap_seconds")][0]
+    cols = line.split()
+    assert cols[1] == "10" and cols[2] == "15"  # cnt A, cnt B
+    # a gauge at 0 in BOTH snapshots still renders (zero-suppression
+    # only drops counters)
+    assert "paddle_resilience_watchdog_armed" in out.stdout
+
+    # a non-snapshot file is a usage error, not a traceback
+    junk = tmp_path / "junk.json"
+    junk.write_text("{}")
+    bad = _stats_dump("--diff", str(a), str(junk))
+    assert bad.returncode == 2
+    assert "not a telemetry snapshot" in bad.stderr

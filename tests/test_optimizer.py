@@ -572,8 +572,8 @@ def test_level_keys_plan_cache_and_program_untouched(monkeypatch):
 
 def test_optimizer_stats_reach_telemetry_snapshot(monkeypatch):
     """The paddle_optimizer_* families move under a level-2 run — the
-    same registry snapshot bench.py dumps into per-workload telemetry
-    sidecars (stats_dump --grep paddle_optimizer reads them)."""
+    registry snapshot ``observe.dump`` writes (stats_dump --grep
+    paddle_optimizer reads it)."""
     monkeypatch.setenv("PADDLE_TPU_OPTIMIZE", "2")
     before = _optimizer_counters()
     main, startup, loss = _tiny_train()

@@ -12,6 +12,7 @@
   reach the lowered HLO.
 """
 
+import contextlib
 import glob
 import os
 import sys
@@ -176,6 +177,43 @@ def _engine(scope, main, loss, n=4):
 
     return ParallelEngine(main, loss_name=loss.name,
                           mesh=make_mesh(jax.devices()[:n]))
+
+
+@pytest.mark.parametrize("path", ["run", "run_repeated", "engine.run",
+                                  "engine.run_repeated"])
+def test_one_dispatch_body(path, capsys):
+    """A ``profiler.profiler()`` session is a reader of the ring, not a
+    second path through the call: the span sequence and the fetches of
+    two calls inside a session equal those of two calls outside one."""
+    from paddle_tpu import profiler
+
+    def two_calls(in_session):
+        exe, main, scope, loss = _mlp()
+        feed = {"x": np.arange(32, dtype="float32").reshape(8, 4)}
+        on_mesh = path.startswith("engine.")
+        target = _engine(scope, main, loss) if on_mesh else exe
+        args = (feed, [loss], scope) if on_mesh else (main, feed, [loss],
+                                                      scope)
+        kw = {} if path.endswith("run") else {"steps": 3}
+        call = target.run_repeated if kw else target.run
+        session = profiler.profiler(state="CPU") if in_session \
+            else contextlib.nullcontext()
+        with scope_guard(scope), session:
+            fetched = [call(*args, **kw) for _ in range(2)]
+        ids = {e["span"]: e["site"] for e in _ended()}
+        spans = [(e["site"], ids.get(e["parent"]),
+                  e["attrs"] if e["site"] == "executor.call" else None)
+                 for e in trace.recorder().events() if e["ph"] == "B"]
+        return spans, fetched
+
+    plain, plain_out = two_calls(False)
+    observe.reset()
+    profiled, profiled_out = two_calls(True)
+    assert "executor.call" in capsys.readouterr().out  # the session's table
+    assert [s for s, _p, _a in plain].count("executor.call") == 2
+    assert profiled == plain
+    np.testing.assert_array_equal(np.asarray(profiled_out),
+                                  np.asarray(plain_out))
 
 
 def test_parallel_engine_call_holds_the_phases_and_counts_what_moves():

@@ -441,7 +441,7 @@ def test_env_knob_scan_matches_real_tree():
 # -------------------------------------------------- rule 9: dead families
 def test_dead_family_detected(tmp_path):
     """A family declared in families.py but never referenced anywhere
-    in paddle_tpu/, tools/ or bench.py is a forever-zero series —
+    in paddle_tpu/ or tools/ is a forever-zero series —
     rule 9 names it."""
     root = _fake_repo(tmp_path, "x = 1\n", "x = 1\n")
     os.remove(os.path.join(root, "tools", "use_families.py"))

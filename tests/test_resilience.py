@@ -6,7 +6,6 @@ durations and detection deadlines plus event/counter assertions — no
 absolute-millisecond timing (this box throttles to ~2 cpu shares with
 20-60ms scheduler noise)."""
 
-import json
 import os
 import random
 import subprocess
@@ -33,12 +32,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _value(name, **labels):
     fam = observe.get_metric(name)
     return fam.labels(**labels).value if labels else fam.value
-
-
-def _hist_count(name, **labels):
-    fam = observe.get_metric(name)
-    child = fam.labels(**labels) if labels else fam.labels()
-    return child.count
 
 
 # ------------------------------------------------------------ fault plan
@@ -719,96 +712,6 @@ def test_orphan_cleanup_spares_live_writers(tmp_path):
     open(live, "w").write("staged-by-a-live-writer")
     save_tensors(target, {"w": np.ones(2, dtype="float32")})
     assert os.path.exists(live)
-
-
-# ----------------------------------------------------- bench probe retry
-def test_probe_backend_retries_transient_failures(monkeypatch):
-    sys.path.insert(0, ROOT)
-    import bench
-
-    monkeypatch.setenv("PADDLE_TPU_BENCH_INIT_BACKOFF_MS", "1")
-    calls = []
-
-    def flaky():
-        calls.append(1)
-        if len(calls) < 3:
-            raise RuntimeError("transient backend hiccup")
-        return "ok"
-
-    a_ok = _value("paddle_backend_probe_attempts_total", outcome="ok")
-    a_err = _value("paddle_backend_probe_attempts_total", outcome="error")
-    h0 = _hist_count("paddle_backend_probe_attempt_seconds")
-    bench._probe_backend(timeout_s=60, attempts=3, probe_fn=flaky)
-    assert len(calls) == 3
-    assert _value("paddle_backend_probe_ok") == 1
-    assert _value("paddle_backend_probe_attempts_total",
-                  outcome="ok") == a_ok + 1
-    assert _value("paddle_backend_probe_attempts_total",
-                  outcome="error") == a_err + 2
-    assert _hist_count("paddle_backend_probe_attempt_seconds") == h0 + 3
-
-
-def test_probe_backend_exhausts_attempts_then_exits(monkeypatch, tmp_path,
-                                                    capsys):
-    sys.path.insert(0, ROOT)
-    import bench
-
-    monkeypatch.setenv("PADDLE_TPU_BENCH_INIT_BACKOFF_MS", "1")
-    monkeypatch.setenv("PADDLE_TPU_TELEMETRY_DIR", str(tmp_path))
-
-    class _Exit(BaseException):
-        pass
-
-    def fake_exit(code):
-        raise _Exit(code)
-
-    monkeypatch.setattr(bench.os, "_exit", fake_exit)
-    with pytest.raises(_Exit):
-        bench._probe_backend(
-            timeout_s=60, attempts=2,
-            probe_fn=lambda: (_ for _ in ()).throw(RuntimeError("down")))
-    assert _value("paddle_backend_probe_ok") == 0
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert row["metric"] == "backend_init" and "2 attempts" in row["error"]
-    # the sidecar landed even though the probe died
-    assert (tmp_path / "BENCH_probe.telemetry.json").exists()
-
-
-def test_probe_backend_counts_wedge_on_timeout(monkeypatch):
-    sys.path.insert(0, ROOT)
-    import bench
-
-    monkeypatch.setenv("PADDLE_TPU_BENCH_INIT_BACKOFF_MS", "1")
-    w0 = _value("paddle_resilience_wedges_detected_total",
-                site="backend.probe")
-    release = threading.Event()
-    calls = []
-
-    def wedge_once():
-        calls.append(1)
-        if len(calls) == 1:
-            release.wait(30)  # wedged vs the 0.3s per-attempt deadline
-        return "ok"
-
-    try:
-        bench._probe_backend(timeout_s=0.3, attempts=2,
-                             probe_fn=wedge_once)
-    finally:
-        release.set()
-    assert _value("paddle_resilience_wedges_detected_total",
-                  site="backend.probe") == w0 + 1
-    assert _value("paddle_backend_probe_ok") == 1
-
-
-def test_fit_probe_attempts_respects_workload_budget():
-    sys.path.insert(0, ROOT)
-    import bench
-
-    # defaults: 3 x (300+30) would outlive the 900s workload deadline
-    assert bench._fit_probe_attempts(900, 300, 3) == 2
-    assert bench._fit_probe_attempts(2000, 300, 3) == 3  # budget fits all
-    assert bench._fit_probe_attempts(120, 300, 3) == 1   # always >= 1
-    assert bench._fit_probe_attempts(900, 300, 1) == 1
 
 
 # --------------------------------------------------- the slow chaos proof

@@ -6,6 +6,7 @@ step's fetches all match the unrolled sequence.
 """
 
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
@@ -525,3 +526,62 @@ def test_reduce_fetches_validated_even_at_steps_one():
         with pytest.raises(ValueError, match="last|mean|sum"):
             exe.run_repeated(main, feed=_feed(), fetch_list=[loss],
                              scope=scope, steps=1, reduce_fetches="avg")
+
+
+# ------------------------------------------------ the zoo through a window
+def _zoo_case(name):
+    """(build, one fixed batch, lr) at the tiny sizes tests/test_models.py
+    trains each model at."""
+    from paddle_tpu.models import bert, ctr, resnet, transformer, vgg
+
+    rs = np.random.RandomState(0)
+    if name in ("resnet50", "vgg16"):
+        mod = resnet if name == "resnet50" else vgg
+        return (lambda: mod.build(class_dim=10, image_shape=(3, 32, 32)),
+                {"img": rs.rand(2, 3, 32, 32).astype("float32"),
+                 "label": rs.randint(0, 10, (2, 1)).astype("int64")}, 1e-4)
+    if name == "deepfm":
+        return (lambda: ctr.build("deepfm", vocab=1000, emb_dim=8),
+                {"sparse_ids": rs.randint(0, 1000, (8, 26)).astype("int64"),
+                 "dense": rs.rand(8, 13).astype("float32"),
+                 "label": rs.randint(0, 2, (8, 1)).astype("int64")}, 1e-3)
+    if name == "transformer":
+        cfg = dict(d_model=32, d_ff=64, n_head=4, n_layer=2, src_vocab=100,
+                   trg_vocab=100, max_length=16, dropout=0.1)
+        return (lambda: transformer.build(cfg, seq_len=16),
+                {k: rs.randint(1, 100, (4, 16)).astype("int64")
+                 for k in ("src_ids", "trg_ids", "lbl_ids")}, 1e-3)
+    cfg = dict(d_model=32, d_ff=64, n_head=4, n_layer=2, vocab=100,
+               type_vocab=2, max_length=64, dropout=0.1)
+    B, S, M = 4, 16, 4
+    return (lambda: bert.build(cfg, seq_len=S, max_mask=M),
+            {"src_ids": rs.randint(1, 100, (B, S)).astype("int64"),
+             "sent_ids": rs.randint(0, 2, (B, S)).astype("int64"),
+             "input_mask": np.ones((B, S), "float32"),
+             "mask_pos": rs.randint(0, B * S, (B, M)).astype("int64"),
+             "mask_label": rs.randint(1, 100, (B, M)).astype("int64"),
+             "mask_weight": np.ones((B, M), "float32")}, 1e-3)
+
+
+@pytest.mark.parametrize("name", ["resnet50", "vgg16", "deepfm",
+                                  "transformer", "bert"])
+def test_zoo_model_trains_through_a_window(name):
+    """The zoo's builders through ``run_repeated(steps=2,
+    feed_stacked=True)``, the path the benchmark's train cells take:
+    on one fixed batch every window's loss is finite and the loss
+    falls (the judgement tests/test_models.py makes over ``run``)."""
+    build, batch, lr = _zoo_case(name)
+    main, startup = fluid.Program(), fluid.Program()
+    scope = Scope()
+    with scope_guard(scope):
+        with fluid.program_guard(main, startup):
+            loss = build()[0]
+            fluid.optimizer.Adam(learning_rate=lr).minimize(loss)
+        exe = fluid.Executor(fluid.TPUPlace())
+        exe.run(startup, scope=scope)
+        window = {k: np.stack([v, v]) for k, v in batch.items()}
+        losses = [np.asarray(exe.run_repeated(
+            main, feed=window, fetch_list=[loss], scope=scope, steps=2,
+            feed_stacked=True)[0]).item() for _ in range(3)]
+    assert np.all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], losses

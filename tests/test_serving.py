@@ -185,10 +185,25 @@ def predictor(tmp_path_factory):
     return create_paddle_predictor(config)
 
 
-def test_batcher_coalesces_backlog_into_one_dispatch(predictor):
-    rs = np.random.RandomState(0)
-    feeds = [{"x": rs.randn(1, 8).astype("float32")} for _ in range(3)]
-    solo = [predictor.run(f)[0] for f in feeds]
+@pytest.mark.parametrize("rows", [(1, 1, 1), (2, 1), (4,), (3, 1)],
+                         ids=["1+1+1", "2+1", "4", "3+1"])
+def test_batcher_rows_equal_the_same_dispatch_run_directly(predictor, rows):
+    """The contract ``MicroBatcher`` keeps on every backend: a backlog
+    rides ONE dispatch, and each request gets back exactly its rows of
+    it — bitwise what ``predictor.run`` returns for the concatenated
+    backlog (which pads to the same [1, 4] bucket) sliced at the
+    requests' boundaries.
+
+    A request run ALONE is not the reference: on a CPU a ``[1,8]·[8,4]``
+    product and a ``[4,8]·[8,4]`` product reduce in different orders and
+    differ in the last bit, so alone-versus-batched is equal only within
+    rounding. The benchmark's row-locality probes hold that stronger
+    property where it matters, on the chip's decode step."""
+    rs = np.random.RandomState(sum(rows) * 10 + len(rows))
+    feeds = [{"x": rs.randn(n, 8).astype("float32")} for n in rows]
+    whole = predictor.run(
+        {"x": np.concatenate([f["x"] for f in feeds])})[0]
+    assert whole.shape == (sum(rows), 4)
 
     b0 = _value("paddle_serving_batches_total")
     rows0 = _hist("paddle_serving_batch_rows")
@@ -200,28 +215,16 @@ def test_batcher_coalesces_backlog_into_one_dispatch(predictor):
         outs = [r.result(timeout=30) for r in reqs]
     finally:
         mb.close()
-    # ONE dispatch carried all three requests (3 rows pre-padding)...
+    # ONE dispatch carried the whole backlog (its rows pre-padding)...
     assert _value("paddle_serving_batches_total") == b0 + 1
     rows1 = _hist("paddle_serving_batch_rows")
-    assert rows1[0] == rows0[0] + 1 and rows1[1] == rows0[1] + 3
-    # ...and each request got bitwise its own rows back
-    for got, ref in zip(outs, solo):
-        assert len(got) == 1 and got[0].shape == (1, 4)
-        np.testing.assert_array_equal(got[0], ref)
-
-
-def test_batcher_multi_row_requests_slice_back_out(predictor):
-    rs = np.random.RandomState(1)
-    f2 = {"x": rs.randn(2, 8).astype("float32")}
-    f1 = {"x": rs.randn(1, 8).astype("float32")}
-    with MicroBatcher(predictor, max_rows=4, max_wait_s=0.2,
-                      autostart=False) as mb:
-        r2, r1 = mb.submit(f2), mb.submit(f1)
-        mb.start()
-        np.testing.assert_array_equal(r2.result(timeout=30)[0],
-                                      predictor.run(f2)[0])
-        np.testing.assert_array_equal(r1.result(timeout=30)[0],
-                                      predictor.run(f1)[0])
+    assert rows1[0] == rows0[0] + 1 and rows1[1] == rows0[1] + sum(rows)
+    # ...and each request got bitwise its own rows of it back
+    lo = 0
+    for got, n in zip(outs, rows):
+        assert len(got) == 1 and got[0].shape == (n, 4)
+        np.testing.assert_array_equal(got[0], whole[lo:lo + n])
+        lo += n
 
 
 def test_batcher_validates_feeds(predictor):

@@ -569,38 +569,6 @@ def test_requests_total_tenant_schema_pinned():
         assert set(s["labels"]) == {"outcome", "tenant"}, s
 
 
-def test_serving_load_driver_stats(seq_ref):
-    """tools/serving_load.drive: the shared open-loop driver reports
-    outcome-complete stats, prefix hit rate and latency percentiles."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    try:
-        from serving_load import drive
-    finally:
-        sys.path.pop(0)
-    store = PrefixStore(16 << 20)
-    router = ReplicaRouter(_mk_factory(seq_ref, store=store),
-                           n_replicas=2)
-    try:
-        warm = np.arange(1, 13, dtype="int64")
-        router.submit(warm, 4).result(timeout=240)
-        stats = drive(router, 8, 0.01, seed=2, prompt_len=12, n_new=4,
-                      prefix_share=1.0, prefix_len=6, timeout_s=240)
-        assert stats["outcomes"].get("ok") == 8
-        assert sum(stats["outcomes"].values()) == 8
-        assert stats["tokens"] == 8 * 4
-        assert stats["p50_ms"] is not None and stats["p99_ms"] is not None
-        # every request shared the one seeded head: after the first
-        # miss, hits dominate
-        assert stats["prefix_hit_rate"] >= 0.5
-        assert stats["prefix_tokens_saved"] >= 6
-    finally:
-        router.close()
-
-
 # ------------------------------------------------------- perf acceptance
 def _collect_params(c, max_len):
     scope = Scope()

@@ -530,11 +530,10 @@ def test_gpt_causal_train_step_lowers_for_tpu():
 
 def test_fused_train_step_scan_lowers_for_tpu():
     """run_repeated's K-step lax.scan around the fused AMP Adam train
-    step — the bench's steady-state executable now that
-    steps_per_call defaults to 10 — must lower for TPU: the Mosaic
-    kernel has to be legal INSIDE the scan body (constant feed and
-    stacked-window variants), or the next hardware window burns time
-    rediscovering it."""
+    step — the benchmark's train cells' executable — must lower for
+    TPU: the Mosaic kernel has to be legal INSIDE the scan body
+    (constant feed and stacked-window variants), or the next hardware
+    window burns time rediscovering it."""
     import os
 
     from paddle_tpu.core.executor import analyze_block, make_scan_fn

@@ -538,27 +538,33 @@ def test_decode_request_spans_cover_90pct_of_wall_time():
 
 
 # ------------------------------------------------------- chrome export
-def test_chrome_export_merges_profiler_timeline(tmp_path):
+def test_chrome_export_holds_session_markers_and_executor_spans(tmp_path):
     from paddle_tpu import profiler
 
     exe, main, scope, loss = _tiny_model()
     feed = {"x": np.ones((2, 4), "float32")}
-    out = str(tmp_path / "merged.json")
+    out = str(tmp_path / "one_list.json")
     with scope_guard(scope):
-        with profiler.profiler(state="CPU"):
-            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        trace.export_chrome_trace(out)
-    merged = json.load(open(out))
-    cats = {t["cat"] for t in merged["traceEvents"]}
-    # one timeline, two sources: flight-recorder spans + profiler host
-    # RecordEvents, on the same clock
-    assert cats == {"trace", "host"}
-    names = {t["name"] for t in merged["traceEvents"]}
-    assert "executor." + "dispatch" in names
-    assert "executor_run" in names  # the profiler's whole-step marker
-    # every trace slice carries its trace id for grouping
-    assert all("trace" in t["args"] for t in merged["traceEvents"]
-               if t["cat"] == "trace")
+        with profiler.profiler(state="CPU", profile_path=out):
+            with profiler.RecordEvent("user_step"):
+                exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    slices = json.load(open(out))["traceEvents"]
+    # one timeline from one source: a session's RecordEvent markers are
+    # flight-recorder spans like the executor's own
+    assert {t["cat"] for t in slices} == {"trace"}
+    by_name = {t["name"]: t for t in slices}
+    assert "executor." + "dispatch" in by_name
+    user, call = by_name["user_step"], by_name["executor." + "call"]
+    assert user["ts"] <= call["ts"] \
+        and call["ts"] + call["dur"] <= user["ts"] + user["dur"]
+    assert user["args"]["trace"] == call["args"]["trace"]  # nested
+    # every slice carries its trace id for grouping
+    assert all("trace" in t["args"] for t in slices)
+    # export_chrome_trace writes the same ring
+    again = str(tmp_path / "again.json")
+    trace.export_chrome_trace(again)
+    assert {t["name"] for t in json.load(open(again))["traceEvents"]} \
+        >= set(by_name)
 
 
 def test_flight_dump_counter_and_unconfigured_noop(tmp_path,

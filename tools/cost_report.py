@@ -19,9 +19,7 @@ resolved device model.
 The prediction is the PRE-COMPILE analytic bracket (it cannot see XLA
 fusion — docs/ANALYSIS.md "The cost engine" has the honesty note);
 tests/test_cost.py holds it within a stated factor of the measured
-step across the zoo, and the bench rows carry the live
-``predicted_seconds`` / ``cost_model_ratio`` columns next to every
-measurement. Device peaks come from ``DeviceModel.current()``
+step across the zoo. Device peaks come from ``DeviceModel.current()``
 (env overrides > TPU table > persisted calibration > probe).
 
 Exit code: 0 ok, 2 bad usage.

@@ -9,8 +9,8 @@ line — the CLI face of ``paddle_tpu.export`` (docs/DEPLOYMENT.md).
     python tools/export_artifact.py --validate mnist.pdz
 
 ``--model`` freezes one of the model-zoo forward-only programs (the
-same tiny configs lint_program.py verifies and bench.py's artifact
-mode times — builders are shared, not duplicated): startup-initialized
+same tiny configs lint_program.py verifies — builders are shared, not
+duplicated): startup-initialized
 weights, inference rewrite, live-config optimize with TV forced on,
 params checksummed, winner-table slice, memory polynomial and (unless
 ``--no-aot``) one jax.export executable per ``--buckets`` entry.

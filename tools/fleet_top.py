@@ -5,11 +5,10 @@ Scrapes one or more MetricsExporter endpoints (``/metrics``, parsed by
 observe/promparse.py via ``FleetCollector.scrape``) on an interval and
 renders one row per instance:
 
-    instance        state  steps/s  tok/s  mfu  queue  slots  headroom
+    instance        state  steps/s  tok/s  queue  slots  headroom
 
 * steps/s  — windowed rate of ``paddle_executor_steps_total``
-* tok/s    — ``paddle_serving_tokens_per_sec`` (gauge)
-* mfu      — ``paddle_bench_mfu`` (gauge; '-' when never measured)
+* tok/s    — windowed rate of ``paddle_serving_tokens_total``
 * queue    — ``paddle_serving_queue_depth``
 * slots    — ``paddle_serving_slots_active``
 * headroom — ``paddle_serving_memory_headroom_bytes`` (the engine
@@ -43,8 +42,7 @@ if _ROOT not in sys.path:
 
 # the metric names behind each dashboard column
 STEPS = "paddle_executor_steps_total"
-TOKENS = "paddle_serving_tokens_per_sec"
-MFU = "paddle_bench_mfu"
+TOKENS = "paddle_serving_tokens_total"
 QUEUE = "paddle_serving_queue_depth"
 SLOTS = "paddle_serving_slots_active"
 HEADROOM = "paddle_serving_memory_headroom_bytes"
@@ -91,6 +89,16 @@ class FleetTop:
             self.mon.objective(name, expr)
         self.last_breaches = []
 
+    def _rate(self, store, snap, name):
+        """Windowed per-second rate of a counter (None when absent)."""
+        from paddle_tpu.observe.timeseries import series_key
+
+        m = snap["metrics"].get(name)
+        if not m:
+            return None
+        return store.rate(series_key(name, m["samples"][0]["labels"]),
+                          window_s=self.window_s)
+
     def tick(self):
         """One scrape round; returns the row dicts."""
         for ep in self.endpoints:
@@ -107,21 +115,12 @@ class FleetTop:
             if store is None:
                 store = self.ts[inst] = self._mk_store()
             store.sample(snap=snap)
-            steps_rate = None
-            if snap["metrics"].get(STEPS):
-                from paddle_tpu.observe.timeseries import series_key
-
-                key = series_key(STEPS,
-                                 snap["metrics"][STEPS]["samples"][0]
-                                 ["labels"])
-                steps_rate = store.rate(key, window_s=self.window_s)
             rows.append({
                 "instance": inst,
                 "state": ("unreachable" if inst in self.unreachable
                           else "stale" if meta["stale"] else "live"),
-                "steps_per_sec": steps_rate,
-                "tokens_per_sec": _value(snap, TOKENS),
-                "mfu": _value(snap, MFU) or None,  # 0 = never measured
+                "steps_per_sec": self._rate(store, snap, STEPS),
+                "tokens_per_sec": self._rate(store, snap, TOKENS),
                 "queue_depth": _value(snap, QUEUE),
                 "slots_active": _value(snap, SLOTS),
                 "headroom_bytes": _value(snap, HEADROOM),
@@ -130,17 +129,17 @@ class FleetTop:
         return rows
 
     def render(self, rows, out=sys.stdout):
-        cols = ("instance", "state", "steps/s", "tok/s", "mfu",
-                "queue", "slots", "headroom")
+        cols = ("instance", "state", "steps/s", "tok/s", "queue",
+                "slots", "headroom")
         w = max([len("instance")] + [len(r["instance"]) for r in rows])
-        print("%-*s %-11s %8s %8s %6s %6s %6s %9s" % ((w,) + cols),
+        print("%-*s %-11s %8s %8s %6s %6s %9s" % ((w,) + cols),
               file=out)
         for r in rows:
-            print("%-*s %-11s %8s %8s %6s %6s %6s %9s"
+            print("%-*s %-11s %8s %8s %6s %6s %9s"
                   % (w, r["instance"], r["state"],
                      _fmt(r["steps_per_sec"], 2),
                      _fmt(r["tokens_per_sec"]),
-                     _fmt(r["mfu"], 3), _fmt(r["queue_depth"], 0),
+                     _fmt(r["queue_depth"], 0),
                      _fmt(r["slots_active"], 0),
                      _fmt(r["headroom_bytes"])), file=out)
         if self.mon._objectives:

@@ -14,21 +14,11 @@ ONE CLI for every kernel-tier tuning job (it absorbed the old
 * ``--auto``: route each grid through the unified autotuner
   (``kernels/autotune.py``): rank candidates by roofline-predicted
   cost, measure only the surviving top half, report what was pruned.
-* ``--bench-sweep WORKLOAD`` (with ``--op attention``): the old
-  flash_tune end-to-end sweep — run ``bench.py --only WORKLOAD`` in
-  killable subprocesses across the BQ x BK grid (PADDLE_TPU_FLASH_BQ/BK
-  env) and report the best throughput. Serial on purpose: a chip
-  belongs to one process at a time, so this parent never initialises a
-  jax backend (which is why ``--auto``, whose pruning asks the device
-  for its kind, is refused here).
 
     python tools/kernel_tune.py --op layernorm_residual --shapes 4096x512
     python tools/kernel_tune.py --op adam_update --shapes 1000000 --json
     python tools/kernel_tune.py --op attention --shapes 1024:1024 --auto
     python tools/kernel_tune.py                    # every op, zoo shapes
-    python tools/kernel_tune.py --op attention --bench-sweep transformer_long
-    python tools/kernel_tune.py --op attention --bench-sweep transformer \\
-        --bq 128,256 --bk 128,256
 
 Shape grammar (one comma-separated list): ``NxD`` rows for
 ``layernorm_residual``, ``N[:K]`` (total elements across a K-param
@@ -100,81 +90,6 @@ def parse_candidates(op: str, text: str):
     return out
 
 
-def run_config(workload, bq, bk, timeout_s, quick):
-    """One bench-sweep cell: ``bench.py --only workload`` in its own
-    process group under PADDLE_TPU_FLASH_BQ/BK, killpg'd on timeout (a
-    hung config must not leak a live TPU process into the next cell —
-    one process per chip). FLASH_MIN_SEQ is pinned to 0 so a short-S
-    workload can't silently sweep the composed path, where BQ/BK are
-    meaningless; a crashing BQ/BK is an error row (bench.py never
-    re-runs a failed row on the composed path)."""
-    import signal
-    import subprocess
-
-    env = dict(os.environ)
-    env["PADDLE_TPU_FLASH_BQ"] = str(bq)
-    env["PADDLE_TPU_FLASH_BK"] = str(bk)
-    env["PADDLE_TPU_FLASH_MIN_SEQ"] = "0"
-    # keep bench's own deadlines INSIDE ours so its killpg cleanup runs
-    # before we ever have to kill anything
-    env["PADDLE_TPU_BENCH_WORKLOAD_TIMEOUT"] = str(max(60, timeout_s - 90))
-    env["PADDLE_TPU_BENCH_TOTAL_BUDGET"] = str(timeout_s)
-    cmd = [sys.executable, os.path.join(REPO, "bench.py"),
-           "--only", workload]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
-                            stderr=subprocess.DEVNULL, text=True,
-                            start_new_session=True)
-    try:
-        stdout, _ = proc.communicate(timeout=timeout_s)
-    except subprocess.TimeoutExpired:
-        try:
-            os.killpg(proc.pid, signal.SIGKILL)
-        except OSError:
-            pass
-        proc.wait()
-        return {"bq": bq, "bk": bk, "error": "timeout"}
-    for line in stdout.splitlines():
-        try:
-            row = json.loads(line)
-        except ValueError:
-            continue
-        if not (isinstance(row, dict) and "value" in row):
-            continue
-        return {"bq": bq, "bk": bk, "value": row["value"],
-                "unit": row.get("unit"), "mfu": row.get("mfu"),
-                "pallas_mode": row.get("pallas_mode")}
-    return {"bq": bq, "bk": bk,
-            "error": "no result row (rc=%s)" % proc.returncode}
-
-
-def bench_sweep(args) -> int:
-    """The end-to-end flash sweep (the old flash_tune CLI): every
-    (bq, bk) cell is one full bench run, one after another."""
-    grid = [(bq, bk)
-            for bq in (int(v) for v in args.bq.split(","))
-            for bk in (int(v) for v in args.bk.split(","))]
-    results = []
-    for bq, bk in grid:
-        row = run_config(args.bench_sweep, bq, bk, args.timeout,
-                         args.quick)
-        print(json.dumps(row), flush=True)
-        results.append(row)
-
-    ok = [r for r in results if "value" in r]
-    if not ok:
-        print(json.dumps({"best": None,
-                          "error": "no configuration produced a row"}),
-              flush=True)
-        return 1
-    best = max(ok, key=lambda r: r["value"])
-    print(json.dumps({"best": best,
-                      "env": "PADDLE_TPU_FLASH_BQ=%d PADDLE_TPU_FLASH_BK=%d"
-                             % (best["bq"], best["bk"])}), flush=True)
-    return 0
-
-
 def main(argv=None) -> int:
     from paddle_tpu import kernels
     from paddle_tpu.kernels import tune
@@ -194,27 +109,7 @@ def main(argv=None) -> int:
     ap.add_argument("--auto", action="store_true",
                     help="unified autotuner: roofline-prune each grid, "
                          "measure only the surviving top half")
-    ap.add_argument("--bench-sweep", metavar="WORKLOAD", default=None,
-                    help="end-to-end sweep: run bench.py --only WORKLOAD "
-                         "per BQxBK cell (requires --op attention)")
-    ap.add_argument("--bq", default="128,256,512",
-                    help="bench-sweep BQ values (multiples of 8)")
-    ap.add_argument("--bk", default="128,256,512",
-                    help="bench-sweep BK values (multiples of 128)")
-    ap.add_argument("--timeout", type=int, default=900,
-                    help="bench-sweep per-config deadline, seconds")
-    ap.add_argument("--quick", action="store_true",
-                    help="bench-sweep: pass --quick through to bench.py")
     args = ap.parse_args(argv)
-    if args.bench_sweep:
-        if args.op != "attention":
-            ap.error("--bench-sweep requires --op attention (the sweep "
-                     "drives PADDLE_TPU_FLASH_BQ/BK)")
-        if args.auto:
-            ap.error("--bench-sweep cannot take --auto: pruning asks the "
-                     "device for its kind, and a parent that holds the "
-                     "chip starves its bench children")
-        return bench_sweep(args)
     if args.shapes and not args.op:
         # each op has its own shape grammar; a bare --shapes cannot
         # apply to all of them

@@ -74,10 +74,9 @@ tests/test_repo_lint.py):
    loops over literal tuples.
 
 9. **dead-family** — the reverse of rule 2: every family declared in
-   ``families.py`` must be REFERENCED somewhere in ``paddle_tpu/``,
-   ``tools/`` or ``bench.py`` (by the module-level variable it is
-   assigned to, or by its name in a string literal). A declared-but-
-   never-written family is schema noise: it renders as a forever-zero
+   ``families.py`` must be REFERENCED somewhere in ``paddle_tpu/`` or
+   ``tools/`` (by the module-level variable it is assigned to, or by
+   its name in a string literal). A declared-but-never-written family is schema noise: it renders as a forever-zero
    series that reads like "this subsystem did nothing" when the truth
    is "nothing ever reports here". Tests/examples do not count as
    references — a family only a test touches measures nothing.
@@ -137,8 +136,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # directories whose bare excepts are load-bearing bugs (the fault/serving
 # planes must never absorb KeyboardInterrupt/SystemExit). Every serving/
 # module — including the fleet tier's prefix store and router — rides the
-# directory entry; the load driver is the serving plane's test harness
-# and holds the same contract. distributed/ joined with the elastic
+# directory entry. distributed/ joined with the elastic
 # tier: rpc.py/ps.py/membership.py sit under the same supervisor-kill
 # discipline as resilience/ (an absorbed SIGTERM would wedge a whole
 # generation teardown).
@@ -146,7 +144,6 @@ BARE_EXCEPT_PATHS = (
     os.path.join("paddle_tpu", "resilience"),
     os.path.join("paddle_tpu", "serving"),
     os.path.join("paddle_tpu", "distributed"),
-    os.path.join("tools", "serving_load.py"),
     os.path.join("tools", "elastic_demo.py"),
 )
 
@@ -167,9 +164,6 @@ def iter_py_files(root: str) -> List[str]:
             dirnames[:] = [d for d in dirnames if d != "__pycache__"]
             out.extend(os.path.join(dirpath, f) for f in filenames
                        if f.endswith(".py"))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        out.append(bench)
     return sorted(out)
 
 
@@ -223,8 +217,8 @@ def declared_family_vars(root: str) -> Dict[str, str]:
 
 def dead_family_violations(root: str, files=None) -> List[str]:
     """Rule 9: declared ⊆ referenced. A reference is the family's
-    assignment variable used (or imported) in ``paddle_tpu/``,
-    ``tools/`` or ``bench.py``, or the family name appearing inside a
+    assignment variable used (or imported) in ``paddle_tpu/`` or
+    ``tools/``, or the family name appearing inside a
     string literal there (the ``REGISTRY.get("...")``/snapshot-reader
     idiom). families.py itself and the tests/examples trees never
     count."""
@@ -256,7 +250,7 @@ def dead_family_violations(root: str, files=None) -> List[str]:
     for name in sorted(declared - referenced):
         violations.append(
             "%s: family %r is declared but never referenced in "
-            "paddle_tpu/, tools/ or bench.py (a forever-zero series is "
+            "paddle_tpu/ or tools/ (a forever-zero series is "
             "schema noise — wire it up or remove the declaration)"
             % (FAMILIES_FILE, name))
     return violations
@@ -634,8 +628,8 @@ def cost_rule_coverage_violations(root: str) -> List[str]:
 
 
 # ------------------------------------------------- rule 8: env knobs
-# the trees whose env reads are user-facing knobs (tests/bench drive
-# internals and document their knobs next to the workloads they shape)
+# the trees whose env reads are user-facing knobs (tests drive
+# internals and document their knobs next to the cases they shape)
 ENV_KNOB_ROOTS = ("paddle_tpu", "tools")
 _ENV_KNOB_PREFIX = "PADDLE_TPU_"
 _ENV_GET_FNS = ("get", "getenv", "setdefault", "pop")

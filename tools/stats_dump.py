@@ -18,22 +18,17 @@ Usage:
                                                  # later ones the diff vs
                                                  # the previous scrape
 
-Reads the JSON written by `paddle_tpu.observe.dump()` (bench.py drops one
-per workload row, including failed rows) and renders counters/gauges as a
-table and histograms with count/sum/mean and estimated p50/p90/p99.
+Reads the JSON written by `paddle_tpu.observe.dump()` and renders
+counters/gauges as a table and histograms with count/sum/mean and
+estimated p50/p90/p99.
 `--prometheus` re-renders the snapshot in text exposition format instead.
 
-The serving sidecars (PADDLE_TPU_BENCH_SERVING=1 bench rows, one per
-scheduler) carry the paddle_serving_* families — queue depth/wait,
-batch rows, bucket hit/miss + padding waste, slot occupancy, admission/
-retirement counters (docs/SERVING.md "Reading the telemetry") — so
-`--grep paddle_serving` is the one-look serving health view.
-
-Diagnosing a backend that never came up, from a sidecar: see
-docs/OBSERVABILITY.md
-("Reading a sidecar post-mortem") — the short version is to look at
-paddle_backend_probe_ok/_seconds first, then the executor cache + step
-counters to see how far init got, then the per-method RPC counters.
+A serving process's snapshot carries the paddle_serving_* families —
+queue depth/wait, batch rows, bucket hit/miss + padding waste, slot
+occupancy, admission/retirement counters (docs/SERVING.md "Reading the
+telemetry") — so `--grep paddle_serving` is the one-look serving health
+view. Diagnosing a process that died early, from its dump: see
+docs/OBSERVABILITY.md ("Reading a sidecar post-mortem").
 """
 
 from __future__ import annotations
@@ -116,8 +111,8 @@ def render_table(snap, show_all=False, grep=None, out=sys.stdout):
                 ))
             else:
                 # gauges always render: a gauge at 0 is a signal
-                # (paddle_backend_probe_ok=0 IS the failed-backend
-                # diagnosis), only zero counters are noise
+                # (paddle_resilience_watchdog_armed=0), only zero
+                # counters are noise
                 if not show_all and m["type"] == "counter" \
                         and not s["value"]:
                     continue
@@ -145,9 +140,9 @@ def render_diff(snap_a, snap_b, name_a="A", name_b="B", show_all=False,
                 grep=None, out=sys.stdout):
     """Per-series comparison of two snapshots: counters/gauges print
     value A, value B and the delta; histograms print count/mean/p50/p99
-    side by side. Built for comparing bench telemetry sidecars — e.g. a
-    pipelined vs unpipelined row — at a glance. Series present in only
-    one snapshot render with '-' on the missing side."""
+    side by side. Built for comparing two ``observe.dump`` snapshots —
+    e.g. a pipelined vs an unpipelined run — at a glance. Series present
+    in only one snapshot render with '-' on the missing side."""
     print("diff: A=%s  B=%s" % (name_a, name_b), file=out)
 
     def _series(snap):
@@ -196,7 +191,7 @@ def render_diff(snap_a, snap_b, name_a="A", name_b="B", show_all=False,
             va = a["value"] if a is not None else None
             vb = b["value"] if b is not None else None
             # gauges always render, as in render_table: a gauge at 0 in
-            # both snapshots (backend_probe_ok) IS the diagnosis
+            # both snapshots is a signal too
             if not show_all and kind != "gauge" and not va and not vb \
                     and schema_note is None:
                 continue
