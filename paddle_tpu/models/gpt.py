@@ -64,6 +64,15 @@ _CFG_KEYS = frozenset([
 # decode step adds to: [n_layer, n_expert] int32, persistable
 ROUTED_PAIRS_VAR = "gpt_moe_routed_pairs"
 
+# what the decode and the prefill step choose on the device, under names
+# a caller fetches INSTEAD of the logits (the builders keep returning
+# those): the greedy next token a row ([B] int32: the decode step's one
+# position, the prefill's LAST prompt position), and — prefill only —
+# that last position's logits row [B, vocab] for a host-side sampler.
+# A plan that fetches neither holds neither (DCE)
+NEXT_TOKEN_VAR = "gpt_next_token"
+LAST_LOGITS_VAR = "gpt_last_logits"
+
 
 def _check_cfg(cfg):
     """Knob typos must fail at build time, not silently fall back to
@@ -107,6 +116,23 @@ def _lm_head(cfg, x):
     return layers.fc(x, cfg["vocab"], num_flatten_dims=2,
                      bias_attr=False,
                      param_attr=ParamAttr(name="gpt_out_proj.w_0"))
+
+
+def _expose(var, name):
+    """Bind ``var`` to the well-known ``name`` in the main program."""
+    from ..core.program import default_main_program
+
+    out = default_main_program().global_block().create_var(
+        name=name, dtype=var.dtype, shape=var.shape)
+    return layers.assign(var, output=out)
+
+
+def _greedy_token(rows):
+    """``NEXT_TOKEN_VAR``: argmax over the vocabulary of float32 logits
+    ``rows`` [B, vocab]. The first maximum wins and a NaN counts as one,
+    as in ``sample_token``'s ``np.argmax`` over the float64 cast (exact
+    and monotone, so the same index, ties and all)."""
+    return _expose(layers.argmax(rows, axis=1), NEXT_TOKEN_VAR)
 
 
 def _rms_eps(cfg):
@@ -329,7 +355,10 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     (shared cache/weight names) and drive both via ``generate(...,
     prefill_prog=...)`` — prompt latency drops from P dispatches to 1.
 
-    Returns (logits_var, cache_names)."""
+    Returns (logits_var, cache_names). The program also holds the last
+    prompt position's row as ``LAST_LOGITS_VAR`` [B, vocab] and its
+    argmax as ``NEXT_TOKEN_VAR`` [B]: an admission fetches one of the
+    two by name and the [B, P, vocab] logits never leave the device."""
     cfg = cfg or base_config()
     _check_cfg(cfg)
     if max_len is None:
@@ -347,15 +376,22 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     zero = layers.fill_constant([1], "int64", 0)
 
     use_rope = cfg.get("pos_emb", "learned") == "rope"
-    word = layers.embedding(tokens, [cfg["vocab"], d_model],
-                            param_attr=ParamAttr(name="gpt_word_emb"))
+    # lookup_table squeezes a trailing-1 id dim (reference semantics):
+    # a one-token prompt's [B, 1] ids come back [B, D], so the
+    # [B, P, D] layout is restored explicitly (a no-op for P > 1)
+    word = layers.reshape(
+        layers.embedding(tokens, [cfg["vocab"], d_model],
+                         param_attr=ParamAttr(name="gpt_word_emb")),
+        [-1, P, d_model])
     pos_range = layers.range(0, P, 1, "int64")
     if use_rope:
         x = word
     else:
-        pos = layers.embedding(layers.reshape(pos_range, [1, P]),
-                               [cfg["max_length"], d_model],
-                               param_attr=ParamAttr(name="gpt_pos_emb"))
+        pos = layers.reshape(
+            layers.embedding(layers.reshape(pos_range, [1, P]),
+                             [cfg["max_length"], d_model],
+                             param_attr=ParamAttr(name="gpt_pos_emb")),
+            [1, P, d_model])
         x = layers.elementwise_add(word, pos)
 
     bias = _causal_bias(P)
@@ -411,6 +447,12 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
+    # the one row an admission needs, cut AFTER the head: the same
+    # numbers as logits[:, P - 1], whatever order the head reduces in
+    last = _expose(layers.reshape(
+        layers.slice(logits, axes=[1], starts=[P - 1], ends=[P]),
+        [-1, cfg["vocab"]]), LAST_LOGITS_VAR)
+    _greedy_token(last)
     return logits, cache_names
 
 
@@ -432,7 +474,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     (gpt_*), so after running this program's startup, overwrite them
     with trained values (same names) — see `generate`.
 
-    Returns (logits_var, cache_names). Fetch logits [B, 1, vocab].
+    Returns (logits_var, cache_names). Fetch logits [B, 1, vocab], or
+    ``NEXT_TOKEN_VAR`` [B] int32, their argmax a row, by name.
     """
     cfg = cfg or base_config()
     _check_cfg(cfg)
@@ -554,6 +597,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
+    _greedy_token(layers.reshape(logits, [-1, cfg["vocab"]]))
     return logits, cache_names
 
 

@@ -300,6 +300,18 @@ SERVING_DECODE_STEPS = REGISTRY.counter(
     "Continuous-batching PLAIN decode dispatches (each advances every "
     "active slot by one token); speculative iterations count into "
     "paddle_serving_spec_verify_steps_total instead")
+SERVING_FETCHES = REGISTRY.counter(
+    "paddle_serving_fetches_total",
+    "What the engine brought to the host to choose tokens from, counted "
+    "where it picks the fetch: a plain decode step (site='step') "
+    "fetches 'tokens' (b_max ids the program's own argmax chose) when "
+    "every rider is at temperature 0 and 'logits' ([b_max, 1, vocab]) "
+    "when one samples; an admission (site='admit') fetches 'tokens' "
+    "(one id) for a greedy request and 'logits' (the last prompt "
+    "position's row; a prefix hit's suffix dispatch: every suffix "
+    "position's) for a sampled one. Speculative iterations are not "
+    "counted",
+    labels=("site", "fetch"))
 SERVING_TOKENS = REGISTRY.counter(
     "paddle_serving_tokens_total",
     "Tokens generated by the continuous-batching engine (prefill-"

@@ -41,10 +41,17 @@ fleet tier"):
   verify dispatch using only its first position.
 
 Requests enter through a bounded ``RequestQueue`` (backpressure,
-deadlines over queue time, cancellation — serving/queue.py). Sampling
-is host-side and per-request (its own seeded RandomState), so a
+deadlines over queue time, cancellation — serving/queue.py). The greedy
+choice is made on the device (the step's and the prefill's own argmax,
+``gpt.NEXT_TOKEN_VAR``): a plain step whose riders are all at
+temperature 0 fetches ``b_max`` ids, an admission of a greedy request
+one id; the logits cross to the host only for a sampled rider (the
+step's ``[b_max, 1, vocab]``, an admission's one last row), whose
+sampling is host-side and per-request (its own seeded RandomState)
+— ``paddle_serving_fetches_total`` counts which. Either way a
 request's output is bitwise what ``generate()`` would produce for it
-alone — tests/test_serving.py and tests/test_serving_fleet.py pin that
+alone — tests/test_serving.py, tests/test_serving_token_fetch.py and
+tests/test_serving_fleet.py pin that
 parity with the fleet levers on and off. Occupancy telemetry:
 ``paddle_serving_slot_occupancy_ratio`` per decode step,
 ``paddle_serving_slots_active``, tokens/steps/spec/prefix counters
@@ -132,12 +139,12 @@ class _Lane:
         self._fluid, self._exe, self._gpt = fluid, exe, gpt
         self._scope_guard = scope_guard
         self._mark = mark if mark is not None else _null_mark
-        self._warm: set = set()   # program ids already dispatched once
+        self._warm: set = set()   # (program id, fetch) dispatched once
         self.cfg = cfg
         self.b_max, self.max_len = b_max, max_len
         self.scope = Scope()
         self._prefill_scope = Scope()
-        self._prefill: Dict[int, tuple] = {}   # P -> (prog, logits_var)
+        self._prefill: Dict[int, object] = {}  # P -> prog
         self._suffix: Dict[int, tuple] = {}    # S -> (prog, logits_var)
         self._multi: Dict[int, tuple] = {}     # S -> (prog, logits_var)
         self._decode_prog = fluid.Program()
@@ -194,23 +201,31 @@ class _Lane:
         self._exe.run(pruned, scope=scope)
 
     # ---------------------------------------------------------- dispatch
-    def _cold(self, prog) -> bool:
-        """True on a program's FIRST dispatch through this lane (jax
-        trace + XLA compile ride it) — the busy marker's
-        compiling-grace signal for replica supervision."""
-        if id(prog) in self._warm:
+    def _cold(self, prog, fetch=None) -> bool:
+        """True on the FIRST dispatch of a program (with this fetch)
+        through this lane (jax trace + XLA compile ride it) — the busy
+        marker's compiling-grace signal for replica supervision."""
+        key = (id(prog), fetch)
+        if key in self._warm:
             return False
-        self._warm.add(id(prog))
+        self._warm.add(key)
         return True
 
-    def decode(self, token, pos):
-        """One plain per-slot decode step; logits [B, 1, vocab]."""
-        with self._mark("decode", self._cold(self._decode_prog)):
+    def decode(self, token, pos, greedy=False):
+        """One plain per-slot decode step; logits [B, 1, vocab], or —
+        ``greedy`` — only the [B] token ids the program chose from them
+        (``gpt.NEXT_TOKEN_VAR``). One program either way: the fetch list
+        is part of the Executor's plan key, and the plan that fetches
+        the ids holds no logits output. Each plan compiles at its first
+        use, so ``_cold`` is asked per (program, fetch)."""
+        fetch = self._gpt.NEXT_TOKEN_VAR if greedy else self._logits
+        with self._mark("decode",
+                        self._cold(self._decode_prog, greedy)):
             with self._scope_guard(self.scope):
-                (logits,) = self._exe.run(
+                (out,) = self._exe.run(
                     self._decode_prog, feed={"token": token, "pos": pos},
-                    fetch_list=[self._logits], scope=self.scope)
-        return logits
+                    fetch_list=[fetch], scope=self.scope)
+        return out
 
     def multi_decode(self, token, pos):
         """One multi-token step over the big caches (speculative
@@ -227,15 +242,20 @@ class _Lane:
 
     # ----------------------------------------------------------- prefill
     def prefill_insert(self, slot_idx, prompt, prefix_store=None,
-                       prefix_len=None):
+                       prefix_len=None, greedy=False):
         """Admission prefill: fill the prefill scope's batch=1 cache
         rows for the whole prompt — via one full-prompt dispatch, or,
         on a prefix-store hit, a donated splice of the stored rows plus
         one suffix dispatch — then splice the rows into the big caches
         at ``slot_idx`` (ONE jitted donated dispatch for all 2*n_layer
         tensors). Registers ``prompt[:prefix_len]`` with the store on
-        first sighting. Returns the last prompt position's logits row
-        (the caller samples the first token from it)."""
+        first sighting. Returns ``(fetch, value)``, what it brought to
+        the host for the first token: ``("tokens", id)``, the last
+        prompt position's argmax chosen by the program, when ``greedy``;
+        else ``("logits", row)``, that position's logits row for the
+        caller's sampler. Every other position's logits stay on the
+        device. (The suffix dispatch of a prefix hit still fetches its
+        whole [1, S, vocab] and hands back the row.)"""
         import jax.numpy as jnp
 
         P = prompt.shape[0]
@@ -265,16 +285,17 @@ class _Lane:
                                     "pos": pos},
                         fetch_list=[logits_var],
                         scope=self._prefill_scope)
-            last = full[0, P - L - 1]
+            fetch, last = "logits", full[0, P - L - 1]
         else:
-            prog, logits_var = self._prefill_program(P)
+            prog = self._prefill_program(P)
+            fetch, var = (("tokens", self._gpt.NEXT_TOKEN_VAR) if greedy
+                          else ("logits", self._gpt.LAST_LOGITS_VAR))
             with _tr.trace_span("serving.engine.prefill", prompt_len=P):
                 with self._scope_guard(self._prefill_scope):
-                    (full,) = self._exe.run(
+                    (out,) = self._exe.run(
                         prog, feed={"tokens": prompt[None, :]},
-                        fetch_list=[logits_var],
-                        scope=self._prefill_scope)
-            last = full[0, P - 1]
+                        fetch_list=[var], scope=self._prefill_scope)
+            last = out[0]
         if prefix_store is not None and prefix_len:
             key = prompt[:prefix_len]
             if not prefix_store.contains(key):
@@ -291,7 +312,7 @@ class _Lane:
             for n, out in zip(self.cache_names,
                               self._splice(bigs, smalls, slot_idx)):
                 self.scope.set_var(n, out)
-        return last
+        return fetch, last
 
     # ---------------------------------------------------------- programs
     def _prefill_program(self, P: int):
@@ -308,15 +329,15 @@ class _Lane:
         prog, start = fluid.Program(), fluid.Program()
         with self._scope_guard(self._prefill_scope):
             with fluid.program_guard(prog, start):
-                logits_var, cache_names = self._gpt.build_prefill_step(
+                self._gpt.build_prefill_step(
                     self.cfg, batch=1, prompt_len=P, max_len=self.max_len)
             self._run_startup(
                 start, self._prefill_scope,
                 set(self._shared_names(prog, {"tokens"})).__contains__)
             self._share_weights(prog, skip={"tokens"})
         SERVING_PREFILL_PROGRAMS.inc()
-        self._prefill[P] = (prog, logits_var)
-        return self._prefill[P]
+        self._prefill[P] = prog
+        return prog
 
     def _suffix_program(self, S: int):
         """Batch=1 multi-token executable for suffix length S, cached
@@ -801,7 +822,8 @@ class DecodeEngine:
             block = False  # drain without blocking once something runs
 
     def _admit_one(self, slot_idx: int, req) -> None:
-        from ..observe.families import (SERVING_ADMITTED, SERVING_TOKENS,
+        from ..observe.families import (SERVING_ADMITTED, SERVING_FETCHES,
+                                        SERVING_TOKENS,
                                         SERVING_TTFT_SECONDS)
 
         p = req.payload
@@ -818,18 +840,26 @@ class DecodeEngine:
             with _tr.trace_span("serving.engine.admit", ctx=req.trace,
                                 slot=slot_idx,
                                 prompt_len=len(p["prompt"])):
-                last = self._lane.prefill_insert(
+                # a greedy request's first token is chosen by the
+                # prefill program and one id comes back; a sampled one
+                # gets the last position's row for its own sampler
+                fetch, last = self._lane.prefill_insert(
                     slot_idx, p["prompt"],
                     prefix_store=self.prefix_store,
-                    prefix_len=p.get("prefix_len"))
+                    prefix_len=p.get("prefix_len"),
+                    greedy=slot.temperature == 0)
+                SERVING_FETCHES.labels(site="admit", fetch=fetch).inc()
                 with _tr.trace_span("serving.engine.sample", active=1):
-                    first = slot.sample(last)
+                    first = (int(last) if fetch == "tokens"
+                             else slot.sample(last))
                 if slot.spec and not slot.finished(first):
                     # mirror the prompt into the draft lane's slot so
                     # drafting starts cache-aligned with the target
                     # (the draft never consults the prefix store: its
-                    # rows would be a different model's)
-                    self._draft.prefill_insert(slot_idx, p["prompt"])
+                    # rows would be a different model's); only the
+                    # cache rows matter, so it fetches the least
+                    self._draft.prefill_insert(slot_idx, p["prompt"],
+                                               greedy=True)
         # the first token exists on the host: time to first token, from
         # submit, under the request's own trace (a retroactive span, so
         # it is in the ring and not among the profiler's annotations)
@@ -904,9 +934,16 @@ class DecodeEngine:
 
     def _plain_step(self, active, advance_draft=False) -> None:
         from ..observe.families import (SERVING_DECODE_STEPS,
+                                        SERVING_FETCHES,
                                         SERVING_SPEC_DRAFT_STEPS,
                                         SERVING_TOKENS)
 
+        # what this step brings to the host follows from its riders:
+        # all at temperature 0, the program's own argmax a slot (b_max
+        # ids); one sampled rider, the [b_max, 1, vocab] logits for
+        # every rider's host sampler, as before. The logits plan
+        # compiles when the first sampled rider rides a step
+        greedy = all(self._slots[i].temperature == 0 for i in active)
         # the step span holds all the host does for one token a rider:
         # feeds, the Executor's phases (gather, h2d, dispatch, complete,
         # write_back nest in it by the thread's context) and sampling.
@@ -914,7 +951,9 @@ class DecodeEngine:
         # nobody reads (masked, and the next prefill-insert overwrites)
         with self._step_span("serving.engine.step", active):
             token, pos = self._feeds(active)
-            logits = self._lane.decode(token, pos)
+            out = self._lane.decode(token, pos, greedy=greedy)
+            SERVING_FETCHES.labels(
+                site="step", fetch="tokens" if greedy else "logits").inc()
             if advance_draft and self._draft is not None:
                 # keep the draft lane's caches mirror-aligned through
                 # plain iterations: a skipped position would leave a
@@ -926,9 +965,11 @@ class DecodeEngine:
             SERVING_TOKENS.inc(len(active))
             with _tr.trace_span("serving.engine.sample",
                                 active=len(active)):
+                chosen = out.tolist() if greedy else None
                 for i in active:
                     slot = self._slots[i]
-                    tok = slot.sample(logits[i, 0])
+                    tok = (chosen[i] if greedy
+                           else slot.sample(out[i, 0]))
                     slot.tokens.append(tok)
                     if slot.finished(tok):
                         self._slots[i] = None

@@ -100,7 +100,7 @@ def _decode_in_company(cfg, params, prompts, n_new):
     toks = [list(p) for p in prompts]
     rows = [[] for _ in prompts]
     for s, p in enumerate(prompts):
-        last = lane.prefill_insert(s, np.asarray(p, "int64"))
+        _, last = lane.prefill_insert(s, np.asarray(p, "int64"))
         rows[s].append(np.asarray(last))
         toks[s].append(int(np.argmax(last)))
     for _ in range(n_new - 1):
@@ -137,6 +137,38 @@ def test_prefill_then_decode_through_cache_matches_reference(top_k):
     from paddle_tpu.observe.families import MOE_ROUTED_PAIRS
     assert MOE_ROUTED_PAIRS.labels(layer="1", expert="0").value \
         == int(tally[1, 0])
+
+
+def test_engine_greedy_tokens_are_the_logits_paths_argmax():
+    """At OLMoE's vocabulary (50,304, untied head) the ids the engine's
+    programs choose on the device are the argmax of the logits the same
+    programs hand back: four requests served greedy through
+    ``submit`` (ids fetched) against the same prompts driven through
+    the lane with the logits fetched and ``np.argmax`` on the host."""
+    from paddle_tpu.observe.families import SERVING_FETCHES
+
+    cfg = tiny_cfg(8, vocab=50304)
+    assert not cfg.get("tie_embeddings")
+    params = seeded_params(cfg, 17)
+    assert params["gpt_out_proj.w_0"].shape == (64, 50304)
+    rng = np.random.default_rng(19)
+    prompts = [rng.integers(1, 50304, n) for n in (5, 9, 3, 7)]
+    eng, toks, _rows = _decode_in_company(cfg, params, prompts, 10)
+    assert max(map(max, toks)) > 97     # the wide head is in play
+    ids = {(site, fetch): SERVING_FETCHES.labels(site=site, fetch=fetch)
+           for site in ("step", "admit") for fetch in ("tokens", "logits")}
+    before = {k: c.value for k, c in ids.items()}
+    eng.start()
+    try:
+        got = [r.result(timeout=300) for r in
+               [eng.submit(np.asarray(p, "int64"), 10) for p in prompts]]
+    finally:
+        eng.stop()
+    for g, t in zip(got, toks):
+        assert g.tolist() == [int(x) for x in t]
+    moved = {k: c.value - before[k] for k, c in ids.items()}
+    assert moved[("admit", "tokens")] == 4 and moved[("step", "tokens")] > 0
+    assert moved[("admit", "logits")] == moved[("step", "logits")] == 0
 
 
 def test_dropless_every_token_to_the_same_experts():
