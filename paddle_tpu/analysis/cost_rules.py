@@ -355,7 +355,10 @@ def _cost_moe_ffn(ctx):
     E, D, F = (int(d) for d in w1)
     mats = 3 if ctx.n_inputs("W1V") else 2
     k = int(ctx.attr("top_k", 1) or 1)
-    return tokens.scaled(k * mats * 2 * D * F + 2 * D * E)
+    # a share computes its held experts' part of the k pairs a token
+    # (held / n_experts of them in expectation); the router scores all
+    n_all = int(ctx.attr("n_experts", 0) or 0) or E
+    return tokens.scaled(k * mats * 2 * D * F * E // n_all + 2 * D * n_all)
 
 
 @register_cost_rule("fused_attention")
@@ -368,4 +371,9 @@ def _cost_attention(ctx):
     if q_elems is None or scores is None:
         return ctx.out_elems()
     flops = _contract_scaled(q_elems, ks[-2]).scaled(2) + scores.scaled(10)
+    window = int(ctx.attr("window", 0) or 0)
+    if window and isinstance(ks[-2], int) and 0 < window < ks[-2]:
+        # a band of at most ``window`` keys a query, not all Sk
+        flops = flops.scaled(float(window) / ks[-2])
+        scores = scores.scaled(float(window) / ks[-2])
     return flops, scores.scaled(2 * 4)  # score matrix written + read, f32

@@ -836,12 +836,30 @@ def _rr_moe_ffn(ctx):
     y = _contraction(ctx, h, ctx.input_av("W2"), F)
     if ctx.num_inputs("B2"):
         y = av_add(y, ctx.input_av("B2"))
-    k = float(int(ctx.attr("top_k", 1) or 1))
+    # gates lie in [0, 1] (softmax or sigmoid scores, renormalised or
+    # not) before ``route_scale`` multiplies them
+    k = float(int(ctx.attr("top_k", 1) or 1)) \
+        * abs(float(ctx.attr("route_scale", 1.0) or 1.0))
     ctx.set("Out", av_mul(_sym(y), av_interval(0.0, k)))
     if ctx.op.outputs.get("AuxLoss"):
         ctx.set("AuxLoss", AbstractValue(0.0, _INF, finite=x.bounded))
-    if ctx.op.outputs.get("CountsOut"):
-        ctx.set("CountsOut", AbstractValue(0.0, _INF))
+    for slot in ("CountsOut", "TouchedOut"):
+        if ctx.op.outputs.get(slot):
+            ctx.set(slot, AbstractValue(0.0, _INF))
+
+
+@register_range_rule("fused_attention")
+def _rr_fused_attention(ctx):
+    """Each output row is a convex combination of V's rows (softmax
+    weights), whatever the mask, the window or the grouping; the output
+    dropout scales it by 0 or 1/keep."""
+    v = _sym(ctx.input_av("V")).drop_const()
+    drop = float(ctx.attr("dropout", 0.0) or 0.0)
+    if drop and not ctx.attr("is_test", False):
+        v = av_mul(v, av_interval(0.0, 1.0 / max(1e-6, 1.0 - drop)))
+    ctx.set("Out", v)
+    if ctx.op.outputs.get("Mask"):
+        ctx.set("Mask", av_interval(0.0, 1.0 / max(1e-6, 1.0 - drop)))
 
 
 @register_range_rule("rope")

@@ -123,6 +123,30 @@ for _t in ("scale", "clip", "clip_by_norm", "sign", "increment",
     register_shape_rule(_t)(_same_shape("X"))
 
 register_shape_rule("rope")(_same_shape("X"))
+
+
+@register_shape_rule("fused_attention")
+def _r_fused_attention(ctx):
+    """Out (and the dropout mask) have Q's shape [B, H, Sq, D]; K and V
+    are [B, Hkv, Sk, D] with Hkv dividing H (grouped heads), and a
+    window is a causal call's."""
+    qs, ks, vs = (ctx.input_shape(s) for s in ("Q", "K", "V"))
+    if qs is not None:
+        ctx.set("Out", qs)
+        if "Mask" in ctx.op.outputs:
+            ctx.set("Mask", qs)
+    if ctx.attr("window", 0) and not ctx.attr("causal", False):
+        ctx.fail("a window needs causal=True")
+    if qs is None or ks is None or not (is_concrete(qs[1:])
+                                        and is_concrete(ks[1:])):
+        return
+    if len(qs) != 4 or len(ks) != 4 or qs[-1] != ks[-1] \
+            or ks[1] <= 0 or qs[1] % ks[1]:
+        ctx.fail("Q %s and K %s are not [B, H, Sq, D] and [B, Hkv, Sk, D] "
+                 "with Hkv dividing H" % (qs, ks))
+    if vs is not None and is_concrete(vs[1:]) \
+            and tuple(vs[1:]) != tuple(ks[1:]):
+        ctx.fail("V %s is not K's shape %s" % (vs, ks))
 register_shape_rule("kv_cache_write")(_same_shape("Cache"))
 register_shape_rule("scatter")(_same_shape("X"))
 
@@ -804,7 +828,8 @@ def _r_moe_ffn(ctx):
     input's shape; the stacked parameters must agree with one another:
     W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [D, E], biases [E, F] and
     [E, D]. The group sizes are data: nothing in a shape depends on
-    them."""
+    them. A share (``n_local``) stacks ``n_local`` experts where the
+    router and its selection bias keep all ``n_experts``."""
     xs = ctx.input_shape("X")
     if xs is not None:
         ctx.set("Out", xs)
@@ -813,9 +838,14 @@ def _r_moe_ffn(ctx):
         cs = ctx.input_shape("Counts")
         if cs is not None:
             ctx.set("CountsOut", cs, dtype="int32")
+    if "TouchedOut" in ctx.op.outputs:
+        ts = ctx.input_shape("Touched")
+        if ts is not None:
+            ctx.set("TouchedOut", ts, dtype="int32")
     w1, w2 = ctx.input_shape("W1"), ctx.input_shape("W2")
     gate = ctx.input_shape("Gate")
     E = int(ctx.attr("n_experts", 0) or 0)
+    held = int(ctx.attr("n_local", 0) or 0) or E
     if not all(is_concrete(s) for s in (w1, w2, gate) if s is not None):
         return
     if w1 is not None and w2 is not None and (
@@ -826,14 +856,20 @@ def _r_moe_ffn(ctx):
     for slot, want in (("W1V", w1),
                        ("B1", None if w1 is None else (w1[0], w1[2])),
                        ("B2", None if w2 is None else (w2[0], w2[2])),
-                       ("Gate", None if w1 is None else (w1[1], w1[0]))):
+                       ("Gate", None if w1 is None
+                        else (w1[1], E or w1[0])),
+                       ("RouterBias", (E,) if E else None)):
         got = ctx.input_shape(slot)
         if got is not None and want is not None and is_concrete(got) \
                 and tuple(got) != tuple(want):
             ctx.fail("%s is %s, the expert weights ask for %s"
                      % (slot, got, want))
-    if w1 is not None and E and w1[0] != E:
-        ctx.fail("n_experts=%d but W1 stacks %d experts" % (E, w1[0]))
+    if w1 is not None and held and w1[0] != held:
+        ctx.fail("n_experts=%d (held: %d) but W1 stacks %d experts"
+                 % (E, held, w1[0]))
+    if not 0 <= int(ctx.attr("expert_first", 0) or 0) <= E - held:
+        ctx.fail("experts %d.. are not a share of n_experts=%d"
+                 % (int(ctx.attr("expert_first", 0)), E))
     if xs is not None and w1 is not None and xs[-1] >= 0 \
             and xs[-1] != w1[1]:
         ctx.fail("X's width %d is not the experts' %d" % (xs[-1], w1[1]))

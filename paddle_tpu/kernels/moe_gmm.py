@@ -18,8 +18,12 @@ the flash kernels:
   work tiles of one group keep the block index, so Pallas issues no new
   DMA). Group ids, row-tile ids and group offsets are scalar-prefetched;
   the static grid holds the worst case ``tiles_m + E - 1`` work tiles and
-  the ones past the real count repeat the last block indices and skip
-  their body. With two ``rhs`` (gate and up) the kernel accumulates both
+  the ones past the real count repeat the last block indices — group,
+  row tile AND reduction tile: an idle tile that still walked the
+  reduction axis would fetch a weight block a step (a share of the
+  experts leaves most work tiles idle: 31 of 32 rows belong to no held
+  group) — and skip their body. With two ``rhs`` (gate and up) the
+  kernel accumulates both
   products in one pass over ``lhs`` and stores ``silu(gate) * up``.
 
 ``gmm`` chooses: the kernel where Pallas compiles (``use_interpret()`` is
@@ -167,12 +171,19 @@ def gmm_pallas(lhs, rhs, group_sizes, *, name, plan=None, interpret=None,
     gid, mid, offsets, num = _work_tiles(group_sizes, M, tm, n_work)
 
     _note_plan(name, plan, "pallas")
+
+    def kt(w, k, num):
+        # an idle work tile stays on the last reduction tile the last
+        # real one ended on: its block indices do not move, nothing is
+        # fetched for it
+        return jnp.where(w < num[0], k, nk - 1)
+
     in_specs = [pl.BlockSpec((tm, tk),
                              lambda n, w, k, gid, mid, off, num:
-                             (mid[w], k))]
+                             (mid[w], kt(w, k, num)))]
     in_specs += [pl.BlockSpec((1, tk, tn),
                               lambda n, w, k, gid, mid, off, num:
-                              (gid[w], k, n))] * len(rhs)
+                              (gid[w], kt(w, k, num), n))] * len(rhs)
     out = checked_pallas_call(
         functools.partial(_kernel, len(rhs), tm, nk,
                           mxu_dtype or lhs.dtype),

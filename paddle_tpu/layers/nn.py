@@ -1290,7 +1290,7 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32", name=None
 
 
 def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
-                    causal=False, segment_ids=None, name=None):
+                    causal=False, segment_ids=None, window=None, name=None):
     """Single-kernel scaled-dot-product attention over [B,H,S,D] tensors
     (Pallas flash kernel; see ops/attention.py). The reference composes
     this from matmul+softmax layer calls — SURVEY §5. ``causal=True``
@@ -1303,7 +1303,16 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     training WITHOUT materializing the [S,S] pack bias: single-device it
     folds to a mask once; under a sequence-parallel mesh the ids ride
     the ring and each pair builds its block mask from two [B,S/n] id
-    vectors."""
+    vectors.
+
+    ``window`` (an int, with ``causal``) keeps key j for query i iff
+    ``0 <= i - j < window``: the kernel skips the key blocks wholly
+    outside the band on both sides (device name ``flash_fwd_win``).
+    ``k``/``v`` may hold fewer heads than ``q`` (grouped heads, no
+    repeated copy). Both are forward-only (a serving prefill)."""
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("fused_attention: window=%r needs causal=True and "
+                         "window >= 1" % (window,))
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     mask = helper.create_variable_for_type_inference(q.dtype)
@@ -1318,8 +1327,11 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                      # is_test: Program.clone(for_test=True) and the
                      # Predictor flip it, which turns the output dropout
                      # off at inference like the dropout op's
-                     attrs={"scale": float(scale), "dropout": float(dropout),
-                            "causal": bool(causal), "is_test": False})
+                     attrs=dict({"scale": float(scale),
+                                 "dropout": float(dropout),
+                                 "causal": bool(causal), "is_test": False},
+                                **({"window": int(window)} if window
+                                   else {})))
     out.shape = q.shape
     return out
 

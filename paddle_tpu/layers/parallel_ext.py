@@ -135,7 +135,12 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             z_loss: float = 0.0, name: Optional[str] = None,
             act: str = "relu", dropless: bool = False,
             norm_topk: Optional[bool] = None, param_prefix=None,
-            counts: Optional[Variable] = None, counts_row: int = 0):
+            counts: Optional[Variable] = None, counts_row: int = 0,
+            router_score: str = "softmax", router_bias: bool = False,
+            route_scale: float = 1.0,
+            n_expert_local: Optional[int] = None, expert_first: int = 0,
+            n_shared_expert: int = 0,
+            touched: Optional[Variable] = None):
     """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
@@ -163,6 +168,23 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     ``counts`` is a persistable [rows, n_experts] int32 var: the op adds
     the pairs it routed to each expert to row ``counts_row``, in place
     on the device.
+
+    ``router_score`` 'softmax' or 'sigmoid'; ``router_bias`` adds a
+    parameter ``<prefix>_router_bias`` [n_experts] to the scores for the
+    SELECTION only (the gates stay the raw scores); ``route_scale``
+    multiplies the gates after ``norm_topk``. ``n_expert_local`` <
+    ``n_experts`` is a SHARE of an expert-parallel deployment: the
+    router still scores all ``n_experts``, the stacked weights hold only
+    experts ``expert_first .. expert_first + n_expert_local - 1`` and
+    the output is their part of the layer (pairs routed elsewhere are
+    given to no group before the sort, as a ``capacity`` run gives its
+    overflow to none; the parts of all shares add up to the whole
+    layer). ``n_shared_expert`` adds that many always-on swiglu experts
+    of width ``d_hidden`` (one bias-free SwiGLU of their joint width,
+    ``<prefix>_shared_{gate,up,down}.w_0``) to the output, whole on
+    every share. ``touched`` is a persistable [rows, n_expert_local]
+    int32 var: row ``counts_row`` counts the calls in which each held
+    expert was given at least one pair.
     """
     if not 1 <= int(top_k) <= int(n_experts):
         raise ValueError(
@@ -173,6 +195,17 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
                          % (act,))
     if dropless and capacity:
         raise ValueError("moe_ffn: dropless=True takes no capacity")
+    if router_score not in ("softmax", "sigmoid"):
+        raise ValueError("moe_ffn router_score must be 'softmax' or "
+                         "'sigmoid'; got %r" % (router_score,))
+    n_local = int(n_expert_local or n_experts)
+    if not (1 <= n_local <= int(n_experts)
+            and 0 <= int(expert_first) <= int(n_experts) - n_local):
+        raise ValueError(
+            "moe_ffn: experts %d..%d are not a share of %d"
+            % (expert_first, int(expert_first) + n_local - 1, n_experts))
+    if n_shared_expert and act != "swiglu":
+        raise ValueError("moe_ffn: shared experts are swiglu experts")
     helper = LayerHelper("moe_ffn", name=name)
     D = int(x.shape[-1])
     mk = helper.create_parameter  # stacked expert weights + router
@@ -183,23 +216,29 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
 
     inputs = {"X": [x]}
     if act == "swiglu":
-        inputs["W1"] = [mk(attr("gate"), [n_experts, D, d_hidden],
+        inputs["W1"] = [mk(attr("gate"), [n_local, D, d_hidden],
                            "float32")]
-        inputs["W1V"] = [mk(attr("up"), [n_experts, D, d_hidden],
+        inputs["W1V"] = [mk(attr("up"), [n_local, D, d_hidden],
                             "float32")]
-        inputs["W2"] = [mk(attr("down"), [n_experts, d_hidden, D],
+        inputs["W2"] = [mk(attr("down"), [n_local, d_hidden, D],
                            "float32")]
     else:
-        inputs["W1"] = [mk(ParamAttr(), [n_experts, D, d_hidden],
+        inputs["W1"] = [mk(ParamAttr(), [n_local, D, d_hidden],
                            "float32")]
         inputs["B1"] = [mk(ParamAttr(initializer=Constant(0.0)),
-                           [n_experts, d_hidden], "float32",
+                           [n_local, d_hidden], "float32",
                            is_bias=True)]
-        inputs["W2"] = [mk(ParamAttr(), [n_experts, d_hidden, D],
+        inputs["W2"] = [mk(ParamAttr(), [n_local, d_hidden, D],
                            "float32")]
         inputs["B2"] = [mk(ParamAttr(initializer=Constant(0.0)),
-                           [n_experts, D], "float32", is_bias=True)]
+                           [n_local, D], "float32", is_bias=True)]
     inputs["Gate"] = [mk(attr("router"), [D, n_experts], "float32")]
+    if router_bias:
+        inputs["RouterBias"] = [mk(
+            ParamAttr(name=None if param_prefix is None
+                      else param_prefix + "_router_bias",
+                      initializer=Constant(0.0)),
+            [n_experts], "float32", is_bias=True)]
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference("float32")
     outputs = {"Out": [out], "AuxLoss": [aux]}
@@ -212,14 +251,38 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
              "axis": "expert"}
     if norm_topk is not None:
         attrs["norm_topk"] = bool(norm_topk)
+    # the keys of the widened router and of a share are written only
+    # where they are used: a program without them is the one it was
+    if router_score != "softmax":
+        attrs["router_score"] = router_score
+    if float(route_scale) != 1.0:
+        attrs["route_scale"] = float(route_scale)
+    if n_local != int(n_experts):
+        attrs["n_local"] = n_local
+        attrs["expert_first"] = int(expert_first)
     if counts is not None:
         inputs["Counts"] = [counts]
         outputs["CountsOut"] = [counts]
+    if touched is not None:
+        inputs["Touched"] = [touched]
+        outputs["TouchedOut"] = [touched]
+    if counts is not None or touched is not None:
         attrs["counts_row"] = int(counts_row)
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)
     out.shape = x.shape
     aux.shape = ()
+    if n_shared_expert:
+        from . import nn as _nn
+
+        def fc(t, width, part, **kw):
+            return _nn.fc(t, width, num_flatten_dims=len(x.shape) - 1,
+                          bias_attr=False, param_attr=attr(part), **kw)
+
+        wide = int(n_shared_expert) * int(d_hidden)
+        hid = _nn.elementwise_mul(fc(x, wide, "shared_gate", act="swish"),
+                                  fc(x, wide, "shared_up"))
+        out = _nn.elementwise_add(out, fc(hid, D, "shared_down"))
     prog = helper.main_program
     ep = getattr(prog, "_expert_params", None)
     if ep is None:

@@ -48,7 +48,53 @@ def base_config():
 
     Expert weights are stacked parameters ``gpt_<i>_moe_{gate,up,down}
     .w_0`` ([E, D, F], [E, D, F], [E, F, D]) and ``gpt_<i>_moe_router
-    .w_0`` ([D, E]), the same names in every build."""
+    .w_0`` ([D, E]), the same names in every build.
+
+    Two kinds of attention layer in one model, and what came with them
+    (every key optional; a cfg without them builds what it built):
+
+    * ``d_head`` — a head size of its own (``n_head * d_head`` need not
+      be ``d_model``); ``layer_types`` — one of ``"sliding"``/``"full"``
+      a layer, with ``window``: in a sliding layer key j is visible to
+      query i iff ``0 <= i - j < window``, and its decode cache is a
+      RING of ``min(window, max_len)`` rows (position p lives in row
+      ``p mod window``) where a full layer keeps a slab of ``max_len``;
+      ``rope_layers`` — ``"all"`` (default) or ``"sliding"``: which
+      layers rotate q and k under ``pos_emb='rope'`` (the others carry
+      no position at all);
+    * ``qk_norm="head"`` — RMSNorm of q and k per head over ``d_head``,
+      one ``[d_head]`` scale shared by the heads (``True`` stays the
+      whole-vector form); ``attn_gate`` — ``ctx * sigmoid(h Wg)`` before
+      the output projection (``gpt_<i>_att_g.w_0``); ``sandwich_norm``
+      — a second norm on each sub-block's OUTPUT before the residual
+      add (``gpt_<i>_post{1,2}_ln_s``); ``emb_scale`` — a number the
+      embedding row is multiplied by;
+    * ``n_dense_layer`` — that many leading layers keep the dense FFN
+      of width ``d_ff`` (bias-free once any key of this list is set)
+      before the expert layers; ``n_shared_expert`` — always-on SwiGLU
+      experts of width ``d_expert`` beside the routed ones;
+      ``router_score`` ``"softmax"``|``"sigmoid"``, ``router_bias`` (a
+      ``[n_expert]`` bias for the selection only), ``route_scale``;
+    * the share of an expert-parallel deployment: ``n_expert`` stays
+      the router's width while ``n_expert_local`` and ``expert_first``
+      say which experts THIS chip holds; the layer computes their part
+      (``layers.moe_ffn``), with the shared expert whole.
+
+    Trinity-Large-Preview (``model_type`` afmoe), as the worked example
+    — published widths, all 60 layers, every expert::
+
+        dict(d_model=3072, n_head=48, n_kv_head=8, d_head=128,
+             n_layer=60, vocab=200192, max_length=262144, dropout=0.0,
+             pos_emb="rope", rope_theta=10000.0, rope_layers="sliding",
+             layer_types=["sliding", "sliding", "sliding", "full"] * 15,
+             window=4096, norm="rms", norm_eps=1e-5, qk_norm="head",
+             attn_gate=True, sandwich_norm=True, emb_scale=3072 ** 0.5,
+             ffn_act="swiglu", d_ff=12288, n_dense_layer=6,
+             n_expert=256, expert_top_k=4, d_expert=3072,
+             n_shared_expert=1, router_score="sigmoid",
+             router_bias=True, norm_topk=True, route_scale=2.448)
+
+    (one chip's share adds ``n_expert_local=8, expert_first=0``)."""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -58,11 +104,26 @@ _CFG_KEYS = frozenset([
     "dropout", "n_kv_head", "pos_emb", "norm", "ffn_act",
     "tie_embeddings", "n_expert", "expert_top_k", "d_expert",
     "norm_topk", "qk_norm", "norm_eps", "rope_theta",
+    "d_head", "layer_types", "window", "rope_layers", "attn_gate",
+    "sandwich_norm", "emb_scale", "n_dense_layer", "n_shared_expert",
+    "router_score", "router_bias", "route_scale", "n_expert_local",
+    "expert_first",
+])
+# the keys after which a dense FFN carries no biases and the training
+# build composes its attention here (``_attention``)
+_NEW_LAYER_KEYS = frozenset([
+    "d_head", "layer_types", "attn_gate", "sandwich_norm", "emb_scale",
+    "n_dense_layer", "rope_layers",
 ])
 
 # the device-side tally of routed (token, expert) pairs the serving
 # decode step adds to: [n_layer, n_expert] int32, persistable
 ROUTED_PAIRS_VAR = "gpt_moe_routed_pairs"
+# beside it, per layer and HELD expert, the steps in which the expert
+# was given at least one pair: [n_layer, n_expert_local] int32 (the
+# grouped matmul fetches no weights for an empty group, so the bytes a
+# step streams follow this tally, not the count of experts)
+EXPERTS_TOUCHED_VAR = "gpt_moe_experts_touched"
 
 # what the decode and the prefill step choose on the device, under names
 # a caller fetches INSTEAD of the logits (the builders keep returning
@@ -86,7 +147,10 @@ def _check_cfg(cfg):
     for key, allowed in (("pos_emb", ("learned", "rope")),
                          ("norm", ("layer", "rms")),
                          ("ffn_act", ("relu", "gelu", "swish",
-                                      "swiglu"))):
+                                      "swiglu")),
+                         ("qk_norm", (True, False, "head")),
+                         ("rope_layers", ("all", "sliding")),
+                         ("router_score", ("softmax", "sigmoid"))):
         val = cfg.get(key)
         if val is not None and val not in allowed:
             raise ValueError("cfg[%r] must be one of %s; got %r"
@@ -99,8 +163,44 @@ def _check_cfg(cfg):
             raise ValueError(
                 "cfg['expert_top_k'] must be in [1, n_expert]; got %r of "
                 "%r" % (cfg["expert_top_k"], cfg["n_expert"]))
-    elif "d_ff" not in cfg:
-        raise ValueError("cfg needs 'd_ff' (or 'n_expert' experts)")
+        n_local = cfg.get("n_expert_local") or cfg["n_expert"]
+        first = cfg.get("expert_first") or 0
+        if not (1 <= n_local <= cfg["n_expert"]
+                and 0 <= first <= cfg["n_expert"] - n_local):
+            raise ValueError(
+                "cfg['expert_first']=%r with cfg['n_expert_local']=%r is "
+                "not a share of n_expert=%r"
+                % (first, n_local, cfg["n_expert"]))
+        if not 0 <= (cfg.get("n_dense_layer") or 0) <= cfg["n_layer"]:
+            raise ValueError("cfg['n_dense_layer'] must be in [0, n_layer]"
+                             "; got %r" % (cfg["n_dense_layer"],))
+        if cfg.get("n_dense_layer") and "d_ff" not in cfg:
+            raise ValueError("cfg['n_dense_layer'] needs cfg['d_ff']")
+    else:
+        if "d_ff" not in cfg:
+            raise ValueError("cfg needs 'd_ff' (or 'n_expert' experts)")
+        for key in ("n_dense_layer", "n_shared_expert", "router_score",
+                    "router_bias", "route_scale", "n_expert_local",
+                    "expert_first"):
+            if cfg.get(key):
+                raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
+    types = cfg.get("layer_types")
+    if types is not None:
+        if len(types) != cfg["n_layer"] or \
+                any(t not in ("sliding", "full") for t in types):
+            raise ValueError(
+                "cfg['layer_types'] must name 'sliding' or 'full' for each "
+                "of the %d layers; got %r" % (cfg["n_layer"], types))
+        if "sliding" in types and not int(cfg.get("window") or 0) >= 1:
+            raise ValueError("a 'sliding' layer needs cfg['window'] >= 1")
+    elif cfg.get("window"):
+        raise ValueError("cfg['window'] needs cfg['layer_types']")
+    if cfg.get("rope_layers", "all") != "all" \
+            and cfg.get("pos_emb", "learned") != "rope":
+        raise ValueError("cfg['rope_layers'] needs pos_emb='rope'")
+    if _d_head(cfg) % 2 and cfg.get("pos_emb", "learned") == "rope":
+        raise ValueError("rope needs an even head size; got %d"
+                         % _d_head(cfg))
 
 
 def _lm_head(cfg, x):
@@ -139,6 +239,128 @@ def _rms_eps(cfg):
     return cfg.get("norm_eps") or 1e-6
 
 
+def _d_head(cfg):
+    """The head size: cfg['d_head'], else ``d_model // n_head``."""
+    return int(cfg.get("d_head") or cfg["d_model"] // cfg["n_head"])
+
+
+def _new_style(cfg):
+    """Whether the cfg holds a key that only this file's own layer
+    (``_block``) builds for training."""
+    return any(cfg.get(k) for k in _NEW_LAYER_KEYS) \
+        or cfg.get("qk_norm") == "head"
+
+
+def layer_window(cfg, i):
+    """Layer ``i``'s attention window, None for a full layer."""
+    types = cfg.get("layer_types")
+    if types and types[i] == "sliding":
+        return int(cfg["window"])
+    return None
+
+
+def cache_rows(cfg, i, max_len):
+    """Rows of layer ``i``'s decode cache: a sliding layer keeps a ring
+    of its window (never more than ``max_len``), a full layer a slab of
+    ``max_len``."""
+    window = layer_window(cfg, i)
+    return max_len if window is None else min(window, max_len)
+
+
+def has_rings(cfg, max_len=None):
+    """Whether some layer's cache is a ring shorter than ``max_len``
+    (any sliding layer, where ``max_len`` is not given)."""
+    return any(layer_window(cfg, i) is not None
+               and (max_len is None or layer_window(cfg, i) < max_len)
+               for i in range(cfg["n_layer"]))
+
+
+def _rotates(cfg, i):
+    """Whether layer ``i`` rotates q and k (``pos_emb='rope'``, and the
+    layer's kind among cfg['rope_layers'])."""
+    if cfg.get("pos_emb", "learned") != "rope":
+        return False
+    return cfg.get("rope_layers", "all") == "all" \
+        or layer_window(cfg, i) is not None
+
+
+def _embed(cfg, tokens, shape):
+    """The token rows as ``shape`` (lookup_table squeezes a trailing-1 id
+    dim, so the layout is restored explicitly), times cfg['emb_scale']."""
+    word = layers.reshape(
+        layers.embedding(tokens, [cfg["vocab"], cfg["d_model"]],
+                         param_attr=ParamAttr(name="gpt_word_emb")), shape)
+    if cfg.get("emb_scale"):
+        word = layers.scale(word, scale=float(cfg["emb_scale"]))
+    return word
+
+
+def _qkv(cfg, h, nm):
+    """The three bias-free projections of the normed input, q at
+    ``n_head * d_head`` wide, k and v at ``n_kv * d_head``, and the
+    whole-vector q/k norm where the cfg asks for it."""
+    n_kv, _g = _kv_heads_of(cfg)
+    d_head = _d_head(cfg)
+    q = layers.fc(h, cfg["n_head"] * d_head, num_flatten_dims=2,
+                  bias_attr=False,
+                  param_attr=ParamAttr(name=nm + "_att_q.w_0"))
+    k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
+                  bias_attr=False,
+                  param_attr=ParamAttr(name=nm + "_att_k.w_0"))
+    v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
+                  bias_attr=False,
+                  param_attr=ParamAttr(name=nm + "_att_v.w_0"))
+    q, k = _qk_norm(cfg, q, k, nm)
+    return q, k, v
+
+
+def _head_norm(cfg, t, nm, which):
+    """cfg['qk_norm'] == 'head': RMSNorm of ``t [..., d_head]`` (q or k
+    already split into heads) over its last axis, with the one
+    ``[d_head]`` scale ``<nm>_att_{q,k}norm_s`` all heads share."""
+    if cfg.get("qk_norm") != "head":
+        return t
+    return layers.rms_norm(
+        t, begin_norm_axis=len(t.shape) - 1, epsilon=_rms_eps(cfg),
+        param_attr=ParamAttr(name="%s_att_%snorm_s" % (nm, which)))
+
+
+def _attn_out(cfg, h, ctxv, nm):
+    """The attention sub-block's tail on the merged heads ``ctxv
+    [B, S, n_head * d_head]``: cfg['attn_gate'] multiplies by
+    ``sigmoid(h Wg)``, then the output projection."""
+    if cfg.get("attn_gate"):
+        gate = layers.fc(h, cfg["n_head"] * _d_head(cfg),
+                         num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=nm + "_att_g.w_0"))
+        ctxv = layers.elementwise_mul(ctxv, layers.sigmoid(gate))
+    return layers.fc(ctxv, cfg["d_model"], num_flatten_dims=2,
+                     bias_attr=False,
+                     param_attr=ParamAttr(name=nm + "_att_o.w_0"))
+
+
+def _residual(cfg, x, y, prefix):
+    """``x + y``, with cfg['sandwich_norm'] the sub-block's output
+    normed first (``<prefix>_ln_s``)."""
+    if cfg.get("sandwich_norm"):
+        y = _norm_of(cfg, y, prefix)
+    return layers.elementwise_add(x, y)
+
+
+def _visibility_bias(ar_rows, pos, lead):
+    """[lead, 1, 1, rows] additive bias of a decode step: row r of a
+    cache is visible iff ``r <= pos``. For a slab that is "positions up
+    to mine"; for a ring of W rows it is the same test, because row r
+    holds position ``pos - ((pos - r) mod W)``, which is >= 0 exactly
+    when ``r <= pos`` (every row, once pos >= W - 1) — so a row a
+    previous tenant of the slot wrote is never visible before this
+    sequence has overwritten it."""
+    vis = layers.cast(layers.less_equal(ar_rows, pos), "float32")
+    bias = layers.scale(layers.elementwise_sub(
+        layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
+    return layers.reshape(bias, [lead, 1, 1, int(ar_rows.shape[-1])])
+
+
 def _rope_base(cfg):
     return cfg.get("rope_theta") or 10000.0
 
@@ -148,9 +370,10 @@ def _rope(cfg, x, pos):
 
 
 def _qk_norm(cfg, q, k, nm):
-    """cfg['qk_norm']: RMSNorm of the projected q and k before the head
-    split (inference graphs; parameter names as multi_head_attention)."""
-    if not cfg.get("qk_norm"):
+    """cfg['qk_norm'] is True: RMSNorm of the projected q and k over
+    their whole width, before the head split (inference graphs;
+    parameter names as multi_head_attention). 'head' is ``_head_norm``'s."""
+    if cfg.get("qk_norm") is not True:
         return q, k
     return qk_norm(q, k, nm + "_att", _rms_eps(cfg))
 
@@ -165,18 +388,37 @@ def _routed_pairs_var(cfg, helper):
         dtype="int32")
 
 
-def _mlp(cfg, h, nm, layer, counts=None):
+def _experts_touched_var(cfg, helper):
+    """The second tally of the serving decode step, for a cfg that holds
+    a share of its experts (None otherwise)."""
+    if not cfg.get("n_expert") or not cfg.get("n_expert_local"):
+        return None
+    return helper.create_global_variable(
+        name=EXPERTS_TOUCHED_VAR,
+        shape=(cfg["n_layer"], cfg["n_expert_local"]), dtype="int32")
+
+
+def _mlp(cfg, h, nm, layer, counts=None, touched=None):
     """The block's second half, behind every builder's one call: the
-    dense FFN, or — cfg['n_expert'] — dropless top-k routing over SwiGLU
-    experts (the load-balancing loss is not part of the LM loss here)."""
-    if not cfg.get("n_expert"):
+    dense FFN (every layer of a dense model, the first
+    cfg['n_dense_layer'] of a sparse one), or — cfg['n_expert'] —
+    dropless top-k routing over SwiGLU experts, with the shared expert,
+    the router's scoring and the share of the experts this chip holds
+    (the load-balancing loss is not part of the LM loss here)."""
+    if not cfg.get("n_expert") or layer < (cfg.get("n_dense_layer") or 0):
         return _ffn(h, cfg["d_model"], cfg["d_ff"], nm,
-                    act=cfg.get("ffn_act", "relu"))
+                    act=cfg.get("ffn_act", "relu"),
+                    bias=not _new_style(cfg))
+    extra = {k: cfg[k] for k in ("router_score", "router_bias",
+                                 "route_scale", "n_expert_local",
+                                 "expert_first", "n_shared_expert")
+             if cfg.get(k)}
     out, _aux = layers.moe_ffn(
         h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
         act="swiglu", dropless=True,
         norm_topk=bool(cfg.get("norm_topk", False)),
-        param_prefix=nm + "_moe", counts=counts, counts_row=layer)
+        param_prefix=nm + "_moe", counts=counts, counts_row=layer,
+        touched=touched, **extra)
     return out
 
 
@@ -232,12 +474,19 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
     targets never cross a segment boundary. Padding-free long-context
     training — no FLOPs spent on pad rows.
     """
+    cfg = cfg or base_config()
+    _check_cfg(cfg)
+    new_style = _new_style(cfg)
+    if new_style:
+        # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
+        # (``_block``): a sliding layer's band is a bias there and
+        # autodiff gives its gradients; the flash backward kernels have
+        # no band (the serving prefill needs the forward only)
+        use_fused_attention = False
     if use_fused_attention is None:
         from ..ops.attention import fused_attention_enabled
 
         use_fused_attention = fused_attention_enabled()
-    cfg = cfg or base_config()
-    _check_cfg(cfg)
     ids = layers.data("ids", [seq_len], dtype="int64")
     seg = pos_feed = None
     self_seg = None
@@ -275,6 +524,8 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
     use_rope = cfg.get("pos_emb", "learned") == "rope"
     word = layers.embedding(ids, [cfg["vocab"], cfg["d_model"]],
                             param_attr=ParamAttr(name="gpt_word_emb"))
+    if cfg.get("emb_scale"):
+        word = layers.scale(word, scale=float(cfg["emb_scale"]))
     rope_pos = None
     if use_rope:
         # positions enter through the per-layer q/k rotation instead of
@@ -295,8 +546,21 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
         x = layers.dropout(x, cfg["dropout"], is_test=is_test)
 
     norm = cfg.get("norm", "layer")
+    band_bias = {None: self_bias}     # window -> pack + band bias
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
+        if new_style:
+            window = layer_window(cfg, i)
+            if window is not None and window >= seq_len:
+                window = None
+            if window not in band_bias:
+                band_bias[window] = layers.elementwise_add(
+                    pack_bias, _band_bias(seq_len, window))
+            x = _block(cfg, x, i, seq_len, band_bias[window], rope_pos,
+                       is_test)
+            if checkpoints is not None:
+                checkpoints.append(x)
+            continue
         x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
             h, h, self_bias, cfg["d_model"], cfg["n_head"], cfg["dropout"],
             is_test, nm + "_att", use_fused_attention,
@@ -346,6 +610,64 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
 
 
 
+def _band_bias(seq_len, window):
+    """[1,1,S,S] additive bias of a sliding layer: 0 where key j is among
+    query i's last ``window`` positions (``0 <= i - j < window``),
+    -1e9 elsewhere."""
+    r = layers.range(0, seq_len, 1, "int64")
+    row = layers.unsqueeze(r, [1])           # [S,1] query index i
+    col = layers.unsqueeze(r, [0])           # [1,S] key index j
+    behind = layers.elementwise_sub(row, col)            # i - j  [S,S]
+    allowed = layers.elementwise_mul(
+        layers.cast(layers.less_equal(col, row), "float32"),
+        layers.cast(layers.less_than(
+            behind, layers.fill_constant([1], "int64", int(window))),
+            "float32"))
+    bias = layers.scale(layers.elementwise_sub(
+        layers.fill_constant([1], "float32", 1.0), allowed), scale=-1e9)
+    return layers.unsqueeze(layers.unsqueeze(bias, [0]), [0])
+
+
+def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
+    """One layer of the training build for a cfg with the newer keys,
+    from the same helpers (and so the same parameter names) as the
+    prefill: composed attention under ``bias`` (pad or pack mask plus the
+    layer's causal triangle or band), then the FFN or the experts."""
+    from .transformer import repeat_kv_heads
+
+    nm = "gpt_%d" % i
+    n_head, d_head = cfg["n_head"], _d_head(cfg)
+    n_kv, _g = _kv_heads_of(cfg)
+    drop = cfg["dropout"]
+
+    def dropped(t):
+        return layers.dropout(t, drop, is_test=is_test) if drop else t
+
+    h = _norm_of(cfg, x, nm + "_pre1")
+    q, k, v = _qkv(cfg, h, nm)
+
+    def heads(t, n, which=None):
+        t = layers.reshape(t, [-1, seq_len, n, d_head])
+        if which:
+            t = _head_norm(cfg, t, nm, which)
+        return layers.transpose(t, perm=[0, 2, 1, 3])      # [B,n,S,Dh]
+
+    q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), heads(v, n_kv)
+    if _rotates(cfg, i):
+        q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
+    k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
+    v = repeat_kv_heads(v, n_kv, n_head, seq_len, d_head)
+    scores = layers.elementwise_add(
+        layers.matmul(q, k, transpose_y=True, alpha=d_head ** -0.5), bias)
+    ctxv = layers.matmul(dropped(layers.softmax(scores)), v)
+    ctxv = layers.reshape(layers.transpose(ctxv, perm=[0, 2, 1, 3]),
+                          [-1, seq_len, n_head * d_head])
+    x = _residual(cfg, x, dropped(_attn_out(cfg, h, ctxv, nm)),
+                  nm + "_post1")
+    f = _mlp(cfg, _norm_of(cfg, x, nm + "_pre2"), nm, i)
+    return _residual(cfg, x, dropped(f), nm + "_post2")
+
+
 def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     """Prompt prefill as ONE dispatch: forward over the whole [B, P]
     prompt with causal attention, writing every layer's K/V slab into
@@ -365,8 +687,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         max_len = cfg["max_length"]
     P = int(prompt_len)
     assert 0 < P <= max_len, (P, max_len)
-    d_model, n_head = cfg["d_model"], cfg["n_head"]
-    d_head = d_model // n_head
+    n_head, d_head = cfg["n_head"], _d_head(cfg)
     n_kv, _g = _kv_heads_of(cfg)
     from ..layer_helper import LayerHelper
     from .transformer import repeat_kv_heads
@@ -376,74 +697,72 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     zero = layers.fill_constant([1], "int64", 0)
 
     use_rope = cfg.get("pos_emb", "learned") == "rope"
-    # lookup_table squeezes a trailing-1 id dim (reference semantics):
-    # a one-token prompt's [B, 1] ids come back [B, D], so the
-    # [B, P, D] layout is restored explicitly (a no-op for P > 1)
-    word = layers.reshape(
-        layers.embedding(tokens, [cfg["vocab"], d_model],
-                         param_attr=ParamAttr(name="gpt_word_emb")),
-        [-1, P, d_model])
+    word = _embed(cfg, tokens, [-1, P, cfg["d_model"]])
     pos_range = layers.range(0, P, 1, "int64")
     if use_rope:
         x = word
     else:
         pos = layers.reshape(
             layers.embedding(layers.reshape(pos_range, [1, P]),
-                             [cfg["max_length"], d_model],
+                             [cfg["max_length"], cfg["d_model"]],
                              param_attr=ParamAttr(name="gpt_pos_emb")),
-            [1, P, d_model])
+            [1, P, cfg["d_model"]])
         x = layers.elementwise_add(word, pos)
 
-    bias = _causal_bias(P)
+    # a cfg with two kinds of layer prefills through the fused attention
+    # op (causal, a window where the prompt is longer than it, grouped
+    # heads): the flash forward at P >= flash_min_seq, whose [P, P]
+    # scores never exist. Every other cfg composes them, as it did
+    fused = bool(cfg.get("layer_types"))
+    bias = None if fused else _causal_bias(P)
     routed = None      # only the serving decode step tallies its routing
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
+        rows = cache_rows(cfg, i, max_len)
         ck = helper.create_global_variable(
-            name=nm + "_cache_k", shape=(batch, n_kv, max_len, d_head))
+            name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
         cv = helper.create_global_variable(
-            name=nm + "_cache_v", shape=(batch, n_kv, max_len, d_head))
+            name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
         cache_names += [ck.name, cv.name]
 
         h = _norm_of(cfg, x, nm + "_pre1")
-        q = layers.fc(h, d_model, num_flatten_dims=2, bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_q.w_0"))
-        k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_k.w_0"))
-        v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_v.w_0"))
-        q, k = _qk_norm(cfg, q, k, nm)
+        q, k, v = _qkv(cfg, h, nm)
 
-        def heads(t, n):
+        def heads(t, n, which=None):
             t = layers.reshape(t, [-1, P, n, d_head])
+            if which:
+                t = _head_norm(cfg, t, nm, which)
             return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,P,Dh]
 
-        q, k, v = heads(q, n_head), heads(k, n_kv), heads(v, n_kv)
-        if use_rope:
+        q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), heads(v, n_kv)
+        if _rotates(cfg, i):
             q = _rope(cfg, q, pos_range)
             k = _rope(cfg, k, pos_range)
         # one slab write per layer: the cache holds rotated keys
-        layers.kv_cache_write(ck, k, zero)
-        layers.kv_cache_write(cv, v, zero)
-        kr = repeat_kv_heads(k, n_kv, n_head, P, d_head)
-        vr = repeat_kv_heads(v, n_kv, n_head, P, d_head)
-        scores = layers.matmul(q, kr, transpose_y=True,
-                               alpha=d_head ** -0.5)   # [B,H,P,P]
-        scores = layers.elementwise_add(scores, bias)
-        w = layers.softmax(scores)
-        ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
+        _prefill_cache_write(ck, k, P, rows, zero)
+        _prefill_cache_write(cv, v, P, rows, zero)
+        if fused:
+            window = layer_window(cfg, i)
+            ctxv = layers.fused_attention(
+                q, k, v, scale=d_head ** -0.5, causal=True,
+                window=window if window is not None and window < P
+                else None)
+        else:
+            kr = repeat_kv_heads(k, n_kv, n_head, P, d_head)
+            vr = repeat_kv_heads(v, n_kv, n_head, P, d_head)
+            scores = layers.matmul(q, kr, transpose_y=True,
+                                   alpha=d_head ** -0.5)   # [B,H,P,P]
+            scores = layers.elementwise_add(scores, bias)
+            w = layers.softmax(scores)
+            ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
-        ctxv = layers.reshape(ctxv, [-1, P, d_model])
-        att = layers.fc(ctxv, d_model, num_flatten_dims=2,
-                        bias_attr=False,
-                        param_attr=ParamAttr(name=nm + "_att_o.w_0"))
-        x = layers.elementwise_add(x, att)
+        ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
+        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
         f = _mlp(cfg, h2, nm, i, counts=routed)
-        x = layers.elementwise_add(x, f)
+        x = _residual(cfg, x, f, nm + "_post2")
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -454,6 +773,25 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         [-1, cfg["vocab"]]), LAST_LOGITS_VAR)
     _greedy_token(last)
     return logits, cache_names
+
+
+def _prefill_cache_write(cache, kv, P, rows, zero):
+    """Leave a prompt's keys (or values) ``kv [B, n_kv, P, Dh]`` in a
+    cache of ``rows`` rows. They fit a slab, and a ring no shorter than
+    the prompt, from row 0 on. A prompt longer than the ring leaves its
+    LAST ``rows`` positions, each in the row the decode step will look
+    for it: position p in row ``p mod rows`` — a rotation of the tail
+    by ``(P - rows) mod rows``, static for a prompt length."""
+    if P > rows:
+        kv = layers.slice(kv, axes=[2], starts=[P - rows], ends=[P])
+        shift = (P - rows) % rows
+        if shift:
+            kv = layers.concat([
+                layers.slice(kv, axes=[2], starts=[rows - shift],
+                             ends=[rows]),
+                layers.slice(kv, axes=[2], starts=[0],
+                             ends=[rows - shift])], axis=2)
+    layers.kv_cache_write(cache, kv, zero)
 
 
 def build_decode_step(cfg=None, batch=1, max_len=None,
@@ -490,8 +828,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             "max_len=%d exceeds the learned position table "
             "(cfg['max_length']=%d) — raise max_length or use "
             "pos_emb='rope'" % (max_len, cfg["max_length"]))
-    d_model, n_head = cfg["d_model"], cfg["n_head"]
-    d_head = d_model // n_head
+    d_model, n_head, d_head = cfg["d_model"], cfg["n_head"], _d_head(cfg)
     from ..layer_helper import LayerHelper
 
     helper = LayerHelper("gpt_decode")
@@ -502,12 +839,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         pos = layers.data("pos", [1], dtype="int64",
                           append_batch_size=False)     # one shared [1]
 
-    # lookup_table squeezes trailing-1 id dims (reference semantics):
-    # [B,1] ids -> [B,D]; restore the [B,1,D] step layout explicitly
-    word = layers.reshape(
-        layers.embedding(token, [cfg["vocab"], d_model],
-                         param_attr=ParamAttr(name="gpt_word_emb")),
-        [-1, 1, d_model])
+    # [B,1] ids -> the [B,1,D] step layout
+    word = _embed(cfg, token, [-1, 1, d_model])
     if use_rope:
         x = word                              # positions rotate q/k below
     else:
@@ -521,61 +854,66 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     # visibility over cache rows: positions <= pos attend, later rows
     # mask out — zeros from init in the lockstep loop; per-slot, row b
     # attends to `cache row <= pos[b]`, so a retired neighbor's stale
-    # rows never leak into a live slot's attention
-    ar = layers.reshape(layers.range(0, max_len, 1, "int64"), [1, max_len])
-    vis = layers.cast(layers.less_equal(
-        ar, pos if per_slot_pos else layers.reshape(pos, [1, 1])),
-        "float32")                      # [B, S] per-slot, else [1, S]
-    bias = layers.scale(layers.elementwise_sub(
-        layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
-    bias = layers.reshape(
-        bias, [-1 if per_slot_pos else 1, 1, 1, max_len])
+    # rows never leak into a live slot's attention. One bias a cache
+    # shape: a slab's over max_len rows, a ring's over its window
+    # (``_visibility_bias`` says why the same test serves a ring)
+    pos_b, biases, ring_pos = None, {}, {}
+    for rows in dict.fromkeys(cache_rows(cfg, i, max_len)
+                              for i in range(cfg["n_layer"])):
+        ar = layers.reshape(layers.range(0, rows, 1, "int64"), [1, rows])
+        if pos_b is None:
+            pos_b = pos if per_slot_pos else layers.reshape(pos, [1, 1])
+        biases[rows] = _visibility_bias(ar, pos_b,
+                                        -1 if per_slot_pos else 1)
+        if rows < max_len:
+            # the ring row a position lives in
+            ring_pos[rows] = layers.elementwise_mod(
+                pos, layers.fill_constant([1], "int64", rows))
 
     n_kv, g = _kv_heads_of(cfg)
     routed = _routed_pairs_var(cfg, helper) if per_slot_pos else None
+    touched = _experts_touched_var(cfg, helper) if per_slot_pos else None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
+        rows = cache_rows(cfg, i, max_len)
         # GQA: the cache stores n_kv heads — H/Hkv-times less decode
         # HBM, the whole point of grouped-query attention at inference
         ck = helper.create_global_variable(
-            name=nm + "_cache_k", shape=(batch, n_kv, max_len, d_head))
+            name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
         cv = helper.create_global_variable(
-            name=nm + "_cache_v", shape=(batch, n_kv, max_len, d_head))
+            name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
         cache_names += [ck.name, cv.name]
 
         h = _norm_of(cfg, x, nm + "_pre1")
-        q = layers.fc(h, d_model, num_flatten_dims=2, bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_q.w_0"))
-        k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_k.w_0"))
-        v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_v.w_0"))
-        q, k = _qk_norm(cfg, q, k, nm)
+        q, k, v = _qkv(cfg, h, nm)
 
-        def kv_heads(t):
+        def kv_heads(t, which=None):
             t = layers.reshape(t, [-1, 1, n_kv, d_head])
+            if which:
+                t = _head_norm(cfg, t, nm, which)
             return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,Hkv,1,Dh]
 
-        k, v = kv_heads(k), kv_heads(v)
-        if use_rope:
+        k, v = kv_heads(k, "k"), kv_heads(v)
+        rotates = _rotates(cfg, i)
+        if rotates:
             # rotate at THIS position; the cache stores rotated keys,
             # so dot products against it are relative-position exact.
             # Per-slot [B, 1] positions broadcast per-row angles over
             # the head axis — each slot rotates at ITS position
             k = _rope(cfg, k, pos)
-        ck = layers.kv_cache_write(ck, k, pos)   # per-slot rows when
-        cv = layers.kv_cache_write(cv, v, pos)   # pos is [B]/[B, 1]
+        at = ring_pos.get(rows, pos)
+        ck = layers.kv_cache_write(ck, k, at)    # per-slot rows when
+        cv = layers.kv_cache_write(cv, v, at)    # pos is [B]/[B, 1]
         # GQA grouped attention: query heads fold as [B, Hkv, g, Dh]
         # (h = kv*g + j, row-major — the same h//g mapping as
         # transformer.repeat_kv_heads) and batch-matmul DIRECTLY
         # against the n_kv-head cache: no H-head repeated cache is
         # ever materialized, so the per-step working set stays at the
         # n_kv size too. g == 1 degenerates to plain MHA.
-        q = layers.reshape(q, [-1, n_kv, g, d_head])
-        if use_rope:
+        q = _head_norm(cfg, layers.reshape(q, [-1, n_kv, g, d_head]),
+                       nm, "q")
+        if rotates:
             # a [1] pos yields [1, Dh/2] sin/cos that broadcast over
             # every leading layout ([B, 1] per-slot pos: [B,1,1,Dh/2])
             # — rotating the folded q directly is exact: all g query
@@ -583,17 +921,15 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             q = _rope(cfg, q, pos)
         scores = layers.matmul(q, ck, transpose_y=True,
                                alpha=d_head ** -0.5)    # [B,Hkv,g,S]
-        scores = layers.elementwise_add(scores, bias)
+        scores = layers.elementwise_add(scores, biases[rows])
         w = layers.softmax(scores)
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
-        ctxv = layers.reshape(ctxv, [-1, 1, d_model])
-        att = layers.fc(ctxv, d_model, num_flatten_dims=2, bias_attr=False,
-                        param_attr=ParamAttr(name=nm + "_att_o.w_0"))
-        x = layers.elementwise_add(x, att)
+        ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
+        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _mlp(cfg, h2, nm, i, counts=routed)
-        x = layers.elementwise_add(x, f)
+        f = _mlp(cfg, h2, nm, i, counts=routed, touched=touched)
+        x = _residual(cfg, x, f, nm + "_post2")
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -640,11 +976,23 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     both uses (k+1 drafts, the un-cached prompt suffix), so the op
     count is bounded.
 
+    A cfg with ring caches (a sliding layer whose window is shorter
+    than ``max_len``) is REFUSED here: the one slab write at
+    ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a
+    rejected draft cannot be cut out of a ring that has wrapped.
+
     Returns (logits_var, cache_names); fetch logits [B, S, vocab]."""
     cfg = cfg or base_config()
     _check_cfg(cfg)
     if max_len is None:
         max_len = cfg["max_length"]
+    if has_rings(cfg, max_len):
+        raise ValueError(
+            "build_multi_token_decode_step: cfg['layer_types'] holds "
+            "sliding layers whose window %d is shorter than max_len=%d; "
+            "their caches are rings, which the multi-token step (suffix "
+            "prefill after a prefix hit, speculative verification) does "
+            "not write" % (cfg["window"], max_len))
     S = int(steps)
     assert 0 < S <= max_len, (S, max_len)
     use_rope = cfg.get("pos_emb", "learned") == "rope"
@@ -653,8 +1001,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             "max_len=%d exceeds the learned position table "
             "(cfg['max_length']=%d) — raise max_length or use "
             "pos_emb='rope'" % (max_len, cfg["max_length"]))
-    d_model, n_head = cfg["d_model"], cfg["n_head"]
-    d_head = d_model // n_head
+    d_model, n_head, d_head = cfg["d_model"], cfg["n_head"], _d_head(cfg)
     n_kv, g = _kv_heads_of(cfg)
     from ..layer_helper import LayerHelper
 
@@ -664,10 +1011,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
 
     # explicit [B, S, D] reshape: lookup_table squeezes trailing-1 id
     # dims, so S=1 (a one-token suffix) would otherwise come out [B, D]
-    word = layers.reshape(
-        layers.embedding(token, [cfg["vocab"], d_model],
-                         param_attr=ParamAttr(name="gpt_word_emb")),
-        [-1, S, d_model])
+    word = _embed(cfg, token, [-1, S, d_model])
     if use_rope:
         x = word                             # positions rotate q/k below
     else:
@@ -704,22 +1048,17 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         cache_names += [ck.name, cv.name]
 
         h = _norm_of(cfg, x, nm + "_pre1")
-        q = layers.fc(h, d_model, num_flatten_dims=2, bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_q.w_0"))
-        k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_k.w_0"))
-        v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                      bias_attr=False,
-                      param_attr=ParamAttr(name=nm + "_att_v.w_0"))
-        q, k = _qk_norm(cfg, q, k, nm)
+        q, k, v = _qkv(cfg, h, nm)
 
-        def kv_heads(t):
+        def kv_heads(t, which=None):
             t = layers.reshape(t, [-1, S, n_kv, d_head])
+            if which:
+                t = _head_norm(cfg, t, nm, which)
             return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n_kv,S,Dh]
 
-        k, v = kv_heads(k), kv_heads(v)
-        if use_rope:
+        k, v = kv_heads(k, "k"), kv_heads(v)
+        rotates = _rotates(cfg, i)
+        if rotates:
             # [B, S] positions -> per-(row, step) angles broadcast over
             # the kv-head axis (elementwise — bitwise the per-position
             # rotation); the cache stores rotated keys
@@ -734,26 +1073,23 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         # single-token step's bit for bit (an S-wide GEMM would not be)
         ctxs = []
         for s in range(S):
-            q_s = layers.reshape(
+            q_s = _head_norm(cfg, layers.reshape(
                 layers.slice(q, axes=[1], starts=[s], ends=[s + 1]),
-                [-1, n_kv, g, d_head])
-            if use_rope:
+                [-1, n_kv, g, d_head]), nm, "q")
+            if rotates:
                 q_s = _rope(cfg, q_s, pos_cols[s])
             scores = layers.matmul(q_s, ck, transpose_y=True,
                                    alpha=d_head ** -0.5)  # [B,n_kv,g,S']
             scores = layers.elementwise_add(scores, biases[s])
             w = layers.softmax(scores)
             ctxs.append(layers.reshape(layers.matmul(w, cv),
-                                       [-1, 1, d_model]))
+                                       [-1, 1, n_head * d_head]))
         ctxv = ctxs[0] if S == 1 else layers.concat(ctxs, axis=1)
-        att = layers.fc(ctxv, d_model, num_flatten_dims=2,
-                        bias_attr=False,
-                        param_attr=ParamAttr(name=nm + "_att_o.w_0"))
-        x = layers.elementwise_add(x, att)
+        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
 
         h2 = _norm_of(cfg, x, nm + "_pre2")
         f = _mlp(cfg, h2, nm, i, counts=routed)
-        x = layers.elementwise_add(x, f)
+        x = _residual(cfg, x, f, nm + "_post2")
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -775,7 +1111,9 @@ def build_serving_decode_step(cfg=None, batch=1, max_len=None):
     with ``generate`` rests on it.
 
     Cache/parameter names match ``build_decode_step``; caches are
-    [B, n_kv, max_len, Dh] donated state whose batch rows the engine
+    [B, n_kv, max_len, Dh] (a sliding layer's: [B, n_kv, window, Dh], a
+    ring written at ``pos mod window``) donated state whose batch rows
+    the engine
     treats as independent slots (a free slot's rows are garbage until
     the next prefill-then-insert overwrites them; the per-row mask
     ``cache row <= pos[b]`` keeps garbage out of every live slot's
@@ -828,10 +1166,10 @@ def generate(exe, decode_prog, logits_var, prompt_ids, n_new, scope,
 
     ids = np.asarray(prompt_ids, dtype="int64")
     B, P = ids.shape
-    max_len = None
+    max_len = None     # the slabs' rows: a ring is shorter and wraps
     for v in decode_prog.global_block().vars.values():
         if v.name.endswith("_cache_k"):
-            max_len = v.shape[2]
+            max_len = max(max_len or 0, v.shape[2])
     if max_len is not None and P + n_new > max_len:
         raise ValueError(
             "generate: prompt (%d) + new tokens (%d) exceeds the decode "

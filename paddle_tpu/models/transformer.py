@@ -154,20 +154,22 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
                      param_attr=ParamAttr(name=name + "_o.w_0"))
 
 
-def _ffn(x, d_model, d_ff, name, act="relu"):
+def _ffn(x, d_model, d_ff, name, act="relu", bias=True):
     """act='swiglu' is the gated variant (LLaMA-style): swish(x W_g)
     elementwise-times (x W_v), then the down projection — two up
-    projections instead of one, all three still plain MXU matmuls."""
+    projections instead of one, all three still plain MXU matmuls.
+    ``bias=False`` leaves the three biases out."""
+    b = None if bias else False
     if act == "swiglu":
-        g = layers.fc(x, d_ff, num_flatten_dims=2, act="swish",
+        g = layers.fc(x, d_ff, num_flatten_dims=2, act="swish", bias_attr=b,
                       param_attr=ParamAttr(name=name + "_ffn1.w_0"))
-        u = layers.fc(x, d_ff, num_flatten_dims=2,
+        u = layers.fc(x, d_ff, num_flatten_dims=2, bias_attr=b,
                       param_attr=ParamAttr(name=name + "_ffn1v.w_0"))
         h = layers.elementwise_mul(g, u)
     else:
-        h = layers.fc(x, d_ff, num_flatten_dims=2, act=act,
+        h = layers.fc(x, d_ff, num_flatten_dims=2, act=act, bias_attr=b,
                       param_attr=ParamAttr(name=name + "_ffn1.w_0"))
-    return layers.fc(h, d_model, num_flatten_dims=2,
+    return layers.fc(h, d_model, num_flatten_dims=2, bias_attr=b,
                      param_attr=ParamAttr(name=name + "_ffn2.w_0"))
 
 

@@ -973,6 +973,25 @@ MOE_ROUTED_PAIRS = REGISTRY.gauge(
     "DecodeEngine.routed_pairs() is called (no fetch a step)",
     labels=("layer", "expert"))
 
+MOE_EXPERTS_TOUCHED = REGISTRY.gauge(
+    "paddle_moe_experts_touched",
+    "Decode steps in which each HELD expert of each layer was given at "
+    "least one token-expert pair since the engine started (a cfg with "
+    "n_expert_local: one chip's share of the experts): a copy of the "
+    "device-side [n_layer, n_expert_local] int32 beside the routed-pairs "
+    "tally, refreshed with it by DecodeEngine.routed_pairs(). The grouped "
+    "matmul fetches no weights for an empty group, so the expert bytes a "
+    "step streams follow this count",
+    labels=("layer", "expert"))
+
+SERVING_CACHE_BYTES = REGISTRY.gauge(
+    "paddle_serving_cache_bytes",
+    "Bytes of the decode caches a serving lane built, by kind: 'ring' "
+    "(a sliding layer's [b_max, n_kv, window, Dh] tensors, position p in "
+    "row p mod window) and 'full' ([b_max, n_kv, max_len, Dh] slabs). "
+    "Set where the lane builds its caches; last lane wins",
+    labels=("kind",))
+
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see
 # docs/OBSERVABILITY.md "Trace propagation")

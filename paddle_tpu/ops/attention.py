@@ -227,21 +227,31 @@ def _flash_decision(seq_len: int, kv_len: int = None):
     return s >= flash_min_seq(), False      # tier 3: static threshold
 
 
-def composed_attention(q, k, v, bias=None, scale=1.0, causal=False):
+def composed_attention(q, k, v, bias=None, scale=1.0, causal=False,
+                       window=None):
     """The unfused attention math the reference composes from layer
     calls (matmul/softmax — SURVEY §5, dist_transformer.py), as one jnp
     expression XLA fuses end to end: scores and softmax in f32 (matching
     the kernel's in-VMEM accumulation dtype), output cast back to the
     input dtype. Used by ``flash_attention`` below ``flash_min_seq()``
     and as the numerics reference everywhere (chip_smoke.py, parity
-    tests)."""
+    tests). ``window`` (with ``causal``) keeps key j for query i iff
+    ``0 <= i - j < window``; k and v of fewer heads than q are grouped
+    (query head h reads key/value head ``h // (H / Hkv)``)."""
+    g = q.shape[1] // k.shape[1]
+    if g > 1:
+        k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
-        s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool)), s, _MASK)
+        keep = jnp.tril(jnp.ones((sq, sk), bool))
+        if window is not None:
+            keep = jnp.logical_and(
+                keep, jnp.triu(jnp.ones((sq, sk), bool), 1 - int(window)))
+        s = jnp.where(keep, s, _MASK)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -341,6 +351,7 @@ def _bias_block(b_ref, h):
 # for its residuals; XLA does not merge two custom calls) — the rule's run
 # carries its own name so a trace can say whether it went away.
 KERNEL_FWD = "flash_fwd"
+KERNEL_FWD_WIN = "flash_fwd_win"   # the forward under a causal window
 KERNEL_REFWD = "flash_refwd"
 KERNEL_BWD_DKV = "flash_bwd_dkv"
 KERNEL_BWD_DQ = "flash_bwd_dq"
@@ -358,7 +369,8 @@ def _largest_block(Sp, cap):
                        if n % d == 0 and (d == 1 or d * _LANE <= cap))
 
 
-def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False):
+def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False,
+                window=None):
     """``(bq, bk)`` of one grid step of ``kernel`` (one of the KERNEL_*
     names; the forward's rerun plans like the forward), from what is
     static at trace time. Pure: the environment's override
@@ -381,22 +393,31 @@ def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False):
     axis whole and nothing skipped); ``want_db``, ``D``, ``dtype`` — the
     score tiles are float32 [bq, bk] whatever the operands, and a full
     [bq, bk] bias or ds block fits beside them at one head a step
-    (``_heads_per_step``)."""
+    (``_heads_per_step``).
+
+    ``window`` (the forward only) does move it: a band narrower than the
+    keys is worth cutting the key axis for, because the blocks wholly
+    outside the band are skipped on both sides of it — so the key block
+    is at most the window rounded up to lane tiles (and ``_MAX_BLOCK``),
+    even where the whole key axis would fit in one block."""
     del D, dtype, causal, want_db
     sq, sk = _pad_len(Sq, _LANE), _pad_len(Sk, _LANE)
     dkv = kernel == KERNEL_BWD_DKV
     red, par = (sq, sk) if dkv else (sk, sq)
     b_red = red if red <= 2 * _MAX_BLOCK else _largest_block(red, _MAX_BLOCK)
+    if window is not None and not dkv and window < red:
+        b_red = _largest_block(
+            red, min(_MAX_BLOCK, _pad_len(int(window), _LANE)))
     b_par = _largest_block(par, _MAX_BLOCK * _MAX_BLOCK // b_red)
     return (b_red, b_par) if dkv else (b_par, b_red)
 
 
-def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db):
+def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db, window=None):
     """``(Sqp, Skp, bq, bk)``: the plan, or the environment's explicit
     override of it. An overridden axis keeps the old contract: the length
     pads to a multiple of the forced block."""
     fq, fk = _block_sizes()
-    bq, bk = _block_plan(kernel, Sq, Sk, D, dtype, causal, want_db)
+    bq, bk = _block_plan(kernel, Sq, Sk, D, dtype, causal, want_db, window)
     Sqp, Skp = _pad_len(Sq, fq or _LANE), _pad_len(Sk, fk or _LANE)
     if fq:
         bq = min(fq, Sqp)
@@ -420,38 +441,65 @@ def _heads_per_step(H, single_pass, bias, want_db=False):
     return max(g for g in range(1, _HEADS_PER_STEP + 1) if H % g == 0)
 
 
-def _note_plan(kernel, bq, bk, single_pass):
+def _note_plan(kernel, bq, bk, single_pass, visited=None):
+    """``visited`` = (blocks a windowed forward computes, blocks in the
+    square): it rides the block label, ``"512x512 76of256"``."""
     from ..observe.families import FLASH_BLOCK_PLANS
 
-    FLASH_BLOCK_PLANS.labels(kernel=kernel, block="%dx%d" % (bq, bk),
+    block = "%dx%d" % (bq, bk)
+    if visited is not None:
+        block += " %dof%d" % visited
+    FLASH_BLOCK_PLANS.labels(kernel=kernel, block=block,
                              single_pass="1" if single_pass else "0").inc()
 
 
+def _band_blocks(nq, nk, bq, bk, window):
+    """How many (iq, ik) blocks hold a visible (query, key) pair under a
+    causal window: the blocks ``_for_block`` runs."""
+    return sum(1 for iq in range(nq) for ik in range(nk)
+               if ik * bk <= iq * bq + bq - 1
+               and ik * bk + bk - 1 >= iq * bq - (window - 1))
+
+
 # --------------------------------------------------------------- causal
-def _causal_mask(s, iq, ik, bq, bk, transposed=False):
+def _causal_mask(s, iq, ik, bq, bk, transposed=False, window=None):
     """Lower-triangular mask for the (iq, ik) block: a score survives iff
-    its global query position iq*bq+r >= its key position ik*bk+c.
+    its global query position iq*bq+r >= its key position ik*bk+c — and,
+    under a ``window``, iff the key is also among the query's last
+    ``window`` positions (qpos - kpos < window).
     Queries run along the rows of ``s``, or along its columns when the
     kernel computed the scores transposed."""
     qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                               1 if transposed else 0)
     kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                               0 if transposed else 1)
-    return jnp.where(qpos >= kpos, s, _MASK)
+    keep = qpos >= kpos
+    if window is not None:
+        keep = jnp.logical_and(keep, qpos - kpos < window)
+    return jnp.where(keep, s, _MASK)
 
 
-def _for_block(body, causal, iq, ik, bq, bk):
+def _for_block(body, causal, iq, ik, bq, bk, window=None):
     """Run ``body(masked)`` for the (iq, ik) block. A causal call skips it
     when it lies entirely above the diagonal (every key position > every
     query position: Mosaic then skips the block's MXU work, ~2x step
     FLOPs saved at long causal S), runs it without the in-kernel mask
     when it lies entirely on or below, and masks only where the diagonal
-    crosses it."""
+    crosses it. A ``window`` adds the band's lower edge: a block whose
+    every key is older than every query's window is skipped too, and one
+    the edge crosses is masked."""
     if not causal:
         body(False)
         return
     below = ik * bk + bk - 1 <= iq * bq
     visible = ik * bk <= iq * bq + bq - 1
+    if window is not None:
+        # the oldest key the block's LAST query sees is at or before the
+        # block's first key: the whole block is inside every query's band
+        below = jnp.logical_and(
+            below, ik * bk >= iq * bq + bq - 1 - (window - 1))
+        visible = jnp.logical_and(
+            visible, ik * bk + bk - 1 >= iq * bq - (window - 1))
     pl.when(below)(lambda: body(False))
     pl.when(jnp.logical_and(visible, jnp.logical_not(below)))(
         lambda: body(True))
@@ -511,7 +559,8 @@ def _dot_f32(a, b, ca, cb):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
+def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
+                window=None):
     q_ref, k_ref, v_ref = refs[:3]
     b_ref = refs[3] if has_bias else None
     o_ref, lse_ref = refs[3 + has_bias:5 + has_bias]
@@ -524,7 +573,8 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
         s = _dot_f32(q_ref[h], k_ref[h], 1, 1) * scale    # [bq, bk]
         if b_ref is not None:
             s = s + _bias_block(b_ref, h)
-        return _causal_mask(s, iq, ik, bq, bk) if masked else s
+        return _causal_mask(s, iq, ik, bq, bk, window=window) \
+            if masked else s
 
     if nk == 1:
         # one block holds every key of the row: one softmax and one
@@ -562,7 +612,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
         acc_ref[...] = acc_ref[...] * alpha \
             + _dot_f32(p.astype(v.dtype), v, 1, 0)
 
-    _for_block(_compute, causal, iq, ik, bq, bk)
+    _for_block(_compute, causal, iq, ik, bq, bk, window)
 
     @pl.when(ik == nk - 1)
     def _emit():
@@ -572,28 +622,58 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
         lse_ref[0] = _to_row(lse) if rows else lse
 
 
-def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD):
+def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
+                    window=None):
+    """The forward kernel's call. ``window`` (an int, with ``causal``)
+    bands the mask: blocks wholly outside the band are skipped on both
+    sides and never fetched (the key block index is held inside the band,
+    and a block index that does not move is not copied again). ``k`` and
+    ``v`` may hold fewer heads than ``q`` (grouped heads): query head
+    ``h`` reads key/value head ``h // (H / Hkv)`` through the block
+    index, so no repeated copy of K and V exists."""
     B, H, S, D = q.shape
-    Sk = k.shape[2]
+    Sk, Hkv = k.shape[2], k.shape[1]
     if causal and S != Sk:
         raise ValueError(
             "causal flash attention requires Sq == Sk (self-attention); "
             "got %d/%d" % (S, Sk))
+    if H % Hkv:
+        raise ValueError("query heads %d must divide by key/value heads %d"
+                         % (H, Hkv))
+    group = H // Hkv
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError("a window needs causal=True and window >= 1; "
+                             "got causal=%r window=%r" % (causal, window))
+        if window >= S:
+            window = None      # the band is the whole triangle
     Sp, Skp, bq, bk = _resolve_blocks(KERNEL_FWD, S, Sk, D, q.dtype, causal,
-                                      False)
+                                      False, window)
     nq, nk = Sp // bq, Skp // bk
-    _note_plan(name, bq, bk, nk == 1)
+    _note_plan(name, bq, bk, nk == 1,
+               None if window is None
+               else (_band_blocks(nq, nk, bq, bk, window), nq * nk))
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
-    heads = _heads_per_step(H, nk == 1, bias)
+    heads = 1 if group > 1 else _heads_per_step(H, nk == 1, bias)
     rows = _stat_rows(bq)
     q = _pad_axis(q, 2, Sp)
     k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
-    qf, kf, vf = (t.reshape(B * H, t.shape[2], D) for t in (q, k, v))
+    qf = q.reshape(B * H, Sp, D)
+    kf, vf = (t.reshape(B * Hkv, Skp, D) for t in (k, v))
+
+    def kv_map(bh, iq, ik):
+        if window is not None:
+            # hold the index inside the band: the skipped blocks on
+            # either side repeat a neighbour's index and move no bytes
+            ik = jnp.clip(ik, jnp.maximum(iq * bq - (window - 1), 0) // bk,
+                          (iq * bq + bq - 1) // bk)
+        return (bh // group if group > 1 else bh, ik, 0)
 
     in_specs = [
         pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((heads, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-        pl.BlockSpec((heads, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+        pl.BlockSpec((heads, bk, D), kv_map),
+        pl.BlockSpec((heads, bk, D), kv_map),
     ]
     operands = [qf, kf, vf]
     if bias is not None:
@@ -603,7 +683,8 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD):
 
     kern = functools.partial(_fwd_kernel, scale=scale, nk=nk, causal=causal,
                              bq=bq, bk=bk, heads=heads,
-                             has_bias=bias is not None, rows=rows)
+                             has_bias=bias is not None, rows=rows,
+                             window=window)
     out, lse = _checked_pallas_call(
         kern,
         name=name,
@@ -989,7 +1070,7 @@ def flash_attention_with_lse(q, k, v, bias=None, scale=1.0, causal=False):
 
 
 def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
-                    causal=False):
+                    causal=False, window=None):
     """Fused attention. ``bias`` is a constant additive mask by default
     (non-differentiable: stop_gradient is applied); pass
     ``bias_grad=True`` to get the true bias cotangent, at the cost of an
@@ -1002,7 +1083,21 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
     still be passed alongside). Requires Sq == Sk. Composes with
     ``bias_grad=True`` by materializing the triangular mask into the
     bias term (the trainable-bias kernels keep dense blocks anyway, so
-    no block-skip is lost relative to that path)."""
+    no block-skip is lost relative to that path).
+
+    ``window`` (an int, with ``causal``) keeps key j for query i iff
+    ``0 <= i - j < window``; ``k``/``v`` with fewer heads than ``q`` are
+    grouped heads. Either is the serving prefill's FORWARD-ONLY call: the
+    kernel runs under the name ``flash_fwd_win`` (a window) or
+    ``flash_fwd`` and no backward rule exists for it (the training build
+    of a windowed layer composes its band bias instead)."""
+    grouped = k.shape[1] != q.shape[1]
+    if window is not None or grouped:
+        if bias_grad:
+            raise ValueError("a window or grouped key/value heads take no "
+                             "trainable bias: the call is forward-only")
+        if window is not None and not causal:
+            raise ValueError("window=%r needs causal=True" % (window,))
     if causal and bias_grad:
         # trainable bias + causal (e.g. a learned relative-position
         # bias on a decoder): materialize the triangular mask INTO the
@@ -1046,7 +1141,12 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
         # true bias cotangent, like the trainable-bias kernel)
         cbias = bias if (bias is None or bias_grad) \
             else jax.lax.stop_gradient(bias)
-        return composed_attention(q, k, v, cbias, scale, causal)
+        return composed_attention(q, k, v, cbias, scale, causal, window)
+    if window is not None or grouped:
+        banded = window is not None and int(window) < q.shape[2]
+        return _forward_pallas(
+            q, k, v, bias, scale, causal=causal, window=window,
+            name=KERNEL_FWD_WIN if banded else KERNEL_FWD)[0]
     if bias is None:
         return _fa_maskbias(q, k, v, None, scale, causal)
     if bias_grad:
@@ -1066,7 +1166,7 @@ def _seg_mask_full(seg):
 
 
 def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
-                              seg=None):
+                              seg=None, window=None):
     """Mosaic kernels cannot be auto-partitioned by the SPMD partitioner
     (jax raises at multi-device lowering), so under a ParallelEngine mesh
     the op-level flash call wraps itself in shard_map: batch shards over
@@ -1084,6 +1184,15 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
     test_dp_tp_train_step_lowers_for_tpu (NotImplementedError without
     the wrap) and the sp ring tests."""
     mesh = getattr(ctx, "mesh", None)
+    if window is not None or k.shape[1] != q.shape[1]:
+        # the serving prefill's forward-only call: one device, no ids
+        if seg is not None or (mesh is not None and mesh.size > 1
+                               and not _in_manual_mesh()):
+            raise NotImplementedError(
+                "fused_attention with a window or grouped key/value heads "
+                "runs on one device and takes no segment ids")
+        return flash_attention(q, k, v, bias, scale, causal=causal,
+                               window=window)
     if mesh is None or mesh.size <= 1 or _in_manual_mesh():
         # _in_manual_mesh: already inside a shard_map region (pipeline
         # stage bodies, ring steps) — Mosaic-in-manual-mesh is the
@@ -1183,10 +1292,11 @@ def _fused_attention(ctx, ins, attrs):
     scale = attrs.get("scale", 1.0)
     dropout = attrs.get("dropout", 0.0)
     causal = bool(attrs.get("causal", False))
+    window = int(attrs.get("window", 0) or 0) or None
     if bias is not None:
         bias = bias.astype(jnp.float32)  # mask bias adds in f32 in-kernel
     out = _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal,
-                                    seg=seg)
+                                    seg=seg, window=window)
     if dropout and not (attrs.get("is_test", False) or ctx.is_test):
         # dropout on the *output* (weights-dropout does not commute with the
         # fused kernel; divergence from the layer-composed path documented).
@@ -1287,6 +1397,11 @@ def _fused_attention_grad(ctx, ins, attrs):
     seg = (ins.get("SegmentIds") or [None])[0]
     mask = (ins.get("Mask") or [None])[0]
     g = ins["Out@GRAD"][0]
+    if attrs.get("window") or k.shape[1] != q.shape[1]:
+        raise NotImplementedError(
+            "fused_attention with a window or grouped key/value heads is "
+            "forward-only; a training build composes its band bias "
+            "(models/gpt.py build)")
     if mask is not None:
         g = (g * mask).astype(q.dtype)
     if bias is not None:

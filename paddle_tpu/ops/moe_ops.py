@@ -42,34 +42,51 @@ __all__: List[str] = []
 
 
 def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
-             norm_topk, z_loss):
-    """Single-device path, dropless or capacity-bound: the (token,
-    expert) pairs sorted by expert, two grouped matmuls over the ragged
-    groups (kernels/moe_gmm.py), the gate-weighted sum back per token.
+             norm_topk, z_loss, scoring=None, share=None):
+    """Single-device path, dropless, capacity-bound or a share: the
+    (token, expert) pairs sorted by expert, two grouped matmuls over the
+    ragged groups (kernels/moe_gmm.py), the gate-weighted sum back per
+    token.
 
     ``capacity`` None is dropless: every pair computes. A number is the
     Switch/GShard discipline of ``route_tokens``: the pairs past an
     expert's capacity are given to no group before the sort, so they
     compute nothing and contribute zero — the same path, and the same
-    answer as the expert-parallel branch below.
+    answer as the expert-parallel branch below. ``share`` =
+    ``(expert_first, n_local)`` says the stacked weights hold only the
+    experts ``expert_first .. expert_first + n_local - 1`` of the ``E``
+    the router scores: the pairs routed to an absent expert are given to
+    no group in the same way, and this chip's output is its own experts'
+    part of the layer (the parts of all shares add up to the whole).
+    ``scoring`` is ``router``'s ``score``/``bias``/``route_scale``.
 
-    Returns (out [T, D], aux, pairs routed to each expert [E] int32)."""
+    Returns (out [T, D], aux, pairs given to each of the ``E`` experts
+    the router scores [E] int32 — a share's own groups are its slice)."""
     from ..kernels.moe_gmm import KERNEL_DOWN, KERNEL_UP, gmm
     from ..parallel.moe import route_tokens, router
 
     T = x.shape[0]
+    scoring = scoring or {}
     if capacity is None:
         expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss,
-                                       norm_topk)
+                                       norm_topk, **scoring)
         flat_e = expert_idx.reshape(-1)                  # [K*T]
     else:
         expert_idx, gate, _pos, keep, aux = route_tokens(
-            x, gate_w, E, capacity, top_k, z_loss, norm_topk)
+            x, gate_w, E, capacity, top_k, z_loss, norm_topk, **scoring)
         # a dropped pair belongs to no expert: it sorts behind them all
         flat_e = jnp.where(keep, expert_idx, E).reshape(-1)
         gate = jnp.where(keep, gate, 0)
-    sizes = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
-                    dtype=jnp.int32)                     # [E]
+    routed = sizes = jnp.sum(
+        flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
+        dtype=jnp.int32)                                 # [E]
+    if share is not None:
+        first, E = share              # from here on E counts held groups
+        local = flat_e - first
+        held = jnp.logical_and(local >= 0, local < E)
+        flat_e = jnp.where(held, local, E)
+        gate = jnp.where(held.reshape(gate.shape), gate, 0)
+        sizes = routed[first:first + E]
     order = jnp.argsort(flat_e, stable=True)             # pair -> sorted
     sorted_e = jnp.minimum(flat_e[order], E - 1)
     xs = x[order % T]                                    # [K*T, D]
@@ -86,7 +103,7 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
     y = y[back].reshape(top_k, T, -1) * gate[:, :, None]
-    return jnp.sum(y, axis=0), aux, sizes
+    return jnp.sum(y, axis=0), aux, routed
 
 
 @register_op("moe_ffn",
@@ -101,7 +118,14 @@ def _moe_ffn(ctx, ins, attrs):
     x = ins["X"][0]
     w1, w2, gate_w = ins["W1"][0], ins["W2"][0], ins["Gate"][0]
     w1v, b1, b2, counts = opt("W1V"), opt("B1"), opt("B2"), opt("Counts")
+    touched = opt("Touched")
     E = int(attrs["n_experts"])
+    scoring = {"score": attrs.get("router_score", "softmax"),
+               "bias": opt("RouterBias"),
+               "route_scale": float(attrs.get("route_scale", 1.0))}
+    n_local = int(attrs.get("n_local") or E)
+    share = None if n_local == E else \
+        (int(attrs.get("expert_first", 0)), n_local)
     axis = attrs.get("axis", "expert")
     top_k = int(attrs.get("top_k", 1))
     z_loss = float(attrs.get("z_loss", 0.0))
@@ -123,19 +147,32 @@ def _moe_ffn(ctx, ins, attrs):
             "moe_ffn with n_experts=%d under a mesh whose %r axis has %d "
             "devices — experts map one-per-device" % (E, axis,
                                                       mesh.shape[axis]))
-    if use_ep and (dropless or act != "relu"):
+    plain = share is None and scoring["score"] == "softmax" \
+        and scoring["bias"] is None and scoring["route_scale"] == 1.0
+    if use_ep and (dropless or act != "relu" or not plain):
         raise NotImplementedError(
             "moe_ffn: the expert-parallel branch runs ReLU experts under "
-            "a capacity; dropless or swiglu experts run on one device")
+            "a capacity with the softmax router; dropless or swiglu "
+            "experts, a share of the experts and the sigmoid router run "
+            "on one device")
 
     if not use_ep:
-        out, aux, sizes = _experts(xf, w1, w1v, b1, w2, b2, gate_w, E,
-                                   top_k, capacity, act, norm_topk, z_loss)
+        out, aux, routed = _experts(
+            xf, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
+            norm_topk, z_loss, scoring, share)
         outs = {"Out": out.reshape(x.shape), "AuxLoss": aux}
+        row = int(attrs.get("counts_row", 0))
         if counts is not None:
-            # the device-side tally of routed pairs: this layer's row
-            outs["CountsOut"] = counts.at[int(attrs["counts_row"])].add(
-                sizes.astype(counts.dtype))
+            # the device-side tally of routed pairs: this layer's row,
+            # over all the experts the router scores
+            outs["CountsOut"] = counts.at[row].add(
+                routed.astype(counts.dtype))
+        if touched is not None:
+            # calls in which each HELD expert was given a pair: the
+            # grouped matmul fetches no weights for an empty group
+            first = share[0] if share is not None else 0
+            outs["TouchedOut"] = touched.at[row].add(
+                (routed[first:first + n_local] > 0).astype(touched.dtype))
         return outs
 
     def shard_body(xl, w1l, b1l, w2l, b2l, gl):

@@ -28,28 +28,49 @@ from jax import lax
 __all__ = ["moe_apply", "route_tokens", "router"]
 
 
-def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None):
-    """Router softmax in float32, the k best experts a token and their
-    gates. ``norm_topk`` renormalises the k gates to sum to one: None is
-    the Switch/GShard rule (raw for top_k=1, renormalised above), False
-    keeps the raw probabilities (OLMoE), True always renormalises.
+def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None,
+           score="softmax", bias=None, route_scale=1.0):
+    """Router scores in float32, the k best experts a token and their
+    gates. ``score`` 'softmax' (over the experts) or 'sigmoid' (each
+    expert's own). ``bias`` [E] is added to the scores for the SELECTION
+    only: it changes who is chosen and never a gate, which is the chosen
+    expert's raw score. ``norm_topk`` renormalises the k gates to sum to
+    one: None is the Switch/GShard rule (raw for top_k=1, renormalised
+    above), False keeps the raw scores (OLMoE), True always renormalises
+    (sigmoid scores over ``sum + 1e-20``); ``route_scale`` multiplies the
+    gates after that.
 
     Returns (expert_idx [K,T], gate [K,T], aux scalar)."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError("router score must be 'softmax' or 'sigmoid'; "
+                         "got %r" % (score,))
     logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)    # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
-    top_p, top_e = jax.lax.top_k(probs, top_k)           # [T, K] each
+    sigmoid = score == "sigmoid"
+    probs = jax.nn.sigmoid(logits) if sigmoid \
+        else jax.nn.softmax(logits, axis=-1)             # [T, E]
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(probs, top_k)       # [T, K] each
+    else:
+        _, top_e = jax.lax.top_k(
+            probs + bias.astype(jnp.float32)[None, :], top_k)
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
     if norm_topk is None:
         # Switch: the output scales by the RAW router probability — that
         # product is how gradients reach the router at all; GShard:
         # gates renormalized over the chosen experts
         norm_topk = top_k > 1
     if norm_topk:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        denom = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (denom + 1e-20 if sigmoid else denom)
+    if route_scale != 1.0:
+        top_p = top_p * route_scale
     gate = top_p.T.astype(x.dtype)                       # [K, T]
     expert_idx = top_e.T                                 # [K, T]
 
     onehot1 = jax.nn.one_hot(expert_idx[0], E)
+    if sigmoid:   # the balance term wants a distribution over the experts
+        probs = probs / (jnp.sum(probs, axis=-1, keepdims=True) + 1e-20)
     aux = E * jnp.sum(jnp.mean(onehot1, axis=0) * jnp.mean(probs, axis=0))
     if z_loss:
         aux = aux + z_loss * jnp.mean(
@@ -58,7 +79,7 @@ def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None):
 
 
 def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0,
-                 norm_topk=None):
+                 norm_topk=None, **scoring):
     """Shared top-k routing/capacity math — the ONE derivation both the
     distributed paths and the single-device path (ops/moe_ops.py) use,
     so their exact-parity contract can't drift.
@@ -71,7 +92,8 @@ def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0,
     loses its primary expert slot to another token's secondary).
 
     Returns (expert_idx [K,T], gate [K,T], pos [K,T], keep [K,T],
-    aux scalar). The aux load-balancing loss follows Switch/GShard:
+    aux scalar). ``scoring`` is ``router``'s ``score``/``bias``/
+    ``route_scale``. The aux load-balancing loss follows Switch/GShard:
     first-choice dispatch fraction x mean router probability. With
     ``z_loss > 0`` the ST-MoE router z-loss —
     ``z_loss * mean(logsumexp(logits)^2)`` — folds into aux: it keeps
@@ -79,7 +101,8 @@ def route_tokens(x, gate_w, E, capacity, top_k=1, z_loss=0.0,
     changing which experts win.
     """
     T = x.shape[0]
-    expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss, norm_topk)
+    expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss, norm_topk,
+                                   **scoring)
 
     # positions: flatten choice-major so cumsum gives 1st choices
     # priority over 2nd within each expert's capacity

@@ -15,8 +15,11 @@ batch instead:
   prompt prefills through a batch=1 ``build_prefill_step`` executable
   (one dispatch, its own scope sharing the weight arrays by name),
   then the slot's cache rows are spliced into the big caches with one
-  ``dynamic_update_slice`` per layer tensor. Prefill executables are
-  cached per prompt length
+  ``dynamic_update_slice`` per layer tensor, each by its own shape: a
+  model with sliding-window layers (``cfg['layer_types']``) keeps
+  ``[b_max, n_kv, window, Dh]`` RINGS for those beside the full layers'
+  ``[b_max, n_kv, max_len, Dh]`` slabs (docs/SERVING.md "The cache").
+  Prefill executables are cached per prompt length
   (``paddle_serving_prefill_programs_total`` counts compiles).
 * **Retirement** is immediate: a sequence that hits EOS or its token
   budget frees its slot at that step boundary
@@ -75,8 +78,9 @@ __all__ = ["DecodeEngine", "MemoryBudgetExceeded"]
 
 class MemoryBudgetExceeded(QueueFull):
     """Raised at submit when the predicted-bytes admission guard
-    refuses a prompt: engine-resident bytes (weights + the 2L
-    decode-cache slabs) plus the prompt's predicted prefill peak exceed
+    refuses a prompt: engine-resident bytes (weights + the decode
+    caches, each tensor at its own shape) plus the prompt's predicted
+    prefill peak exceed
     the engine's device budget (``device_budget=`` or
     ``PADDLE_TPU_DEVICE_HBM_BYTES``). A ``QueueFull`` subclass so the
     router's per-replica retry treats it like backpressure — but with
@@ -160,6 +164,7 @@ class _Lane:
             self._run_startup(dec_start, self.scope, given.__contains__)
             for n, v in given.items():
                 self.scope.set_var(n, v)
+        self._note_cache_bytes()
         import jax
 
         def _splice(bigs, smalls, idx):
@@ -181,6 +186,21 @@ class _Lane:
         # them (recompiled per distinct prefix length, like the suffix
         # programs themselves)
         self._prefix_splice = jax.jit(_prefix_splice, donate_argnums=0)
+
+    def _note_cache_bytes(self) -> None:
+        """``paddle_serving_cache_bytes{kind}``: what the caches this
+        lane just built hold, rings (shorter than ``max_len``) apart
+        from full slabs."""
+        from ..observe.families import SERVING_CACHE_BYTES
+
+        held = {"ring": 0, "full": 0}
+        for n in self.cache_names:
+            var = self._decode_prog.global_block().var(n)
+            kind = "ring" if var.shape[2] < self.max_len else "full"
+            held[kind] += int(np.prod(var.shape)) \
+                * np.dtype(var.dtype).itemsize
+        for kind, nbytes in held.items():
+            SERVING_CACHE_BYTES.labels(kind=kind).set(nbytes)
 
     def _run_startup(self, start, scope, supplied) -> None:
         """Run a copy of a startup program without its initialisers of
@@ -248,8 +268,10 @@ class _Lane:
         on a prefix-store hit, a donated splice of the stored rows plus
         one suffix dispatch — then splice the rows into the big caches
         at ``slot_idx`` (ONE jitted donated dispatch for all 2*n_layer
-        tensors). Registers ``prompt[:prefix_len]`` with the store on
-        first sighting. Returns ``(fetch, value)``, what it brought to
+        tensors, rings and slabs alike: each update is the prefill
+        scope's whole batch=1 tensor of the same trailing shape).
+        Registers ``prompt[:prefix_len]`` with the store on first
+        sighting. Returns ``(fetch, value)``, what it brought to
         the host for the first token: ``("tokens", id)``, the last
         prompt position's argmax chosen by the program, when ``greedy``;
         else ``("logits", row)``, that position's logits row for the
@@ -317,8 +339,8 @@ class _Lane:
     # ---------------------------------------------------------- programs
     def _prefill_program(self, P: int):
         """Batch=1 prefill executable for prompt length P, cached. All
-        P's share ONE prefill scope: the [1, n_kv, max_len, Dh] caches
-        have the same shape for every P, and weights are (re)copied
+        P's share ONE prefill scope: each layer's [1, n_kv, rows, Dh]
+        cache has the same shape for every P, and weights are (re)copied
         from the engine scope after each new program's startup."""
         hit = self._prefill.get(P)
         if hit is not None:
@@ -416,8 +438,10 @@ class _Lane:
         the thread that is already building this engine's programs.
 
         ``resident``: predicted peak of the decode-step program
-        (weights + the 2L ``[b_max, n_kv, max_len, Dh]`` cache slabs +
-        one step's activations). ``prefill_extra_lo``/``_hi``: the
+        (weights + the 2L cache tensors, each at its declared shape — a
+        sliding layer's ring is ``window`` rows, a full layer's slab
+        ``max_len`` — + one step's activations).
+        ``prefill_extra_lo``/``_hi``: the
         NON-shared bytes a batch=1 prefill adds on top (its own caches
         + activations + the P x P attention scores; weights shared with
         the decode scope are excluded) at the two endpoint prompt
@@ -478,6 +502,11 @@ class DecodeEngine:
       ``spec_k`` tokens per iteration, the target verifies them in one
       multi-token dispatch. The draft lane shares ``b_max``/``max_len``
       so its slots mirror the target's.
+
+    Both are REFUSED at construction for a model whose caches hold rings
+    (``cfg['layer_types']`` with a window shorter than ``max_len``): a
+    stored prefix cannot be cut out of, nor a rejected draft rolled back
+    in, a ring that has wrapped (``gpt.build_multi_token_decode_step``).
     """
 
     def __init__(self, cfg, params: Optional[Dict[str, np.ndarray]] = None,
@@ -505,6 +534,22 @@ class DecodeEngine:
         self.max_len = (self.cfg["max_length"] if max_len is None
                         else int(max_len))
         self.eos_id = eos_id
+        gpt._check_cfg(self.cfg)
+        multi = []            # the levers that need the multi-token step
+        if prefix_store is not None or prefix_cache_bytes > 0:
+            multi.append((self.cfg, "a prefix store (prefix_store= / "
+                          "prefix_cache_bytes=)"))
+        if draft_cfg is not None:
+            multi += [(self.cfg, "speculative decoding (draft_cfg=)"),
+                      (draft_cfg, "a draft model (draft_cfg=)")]
+        for model, lever in multi:
+            if gpt.has_rings(model, self.max_len):
+                raise ValueError(
+                    "DecodeEngine: %s cannot serve a model with "
+                    "cfg['layer_types'] sliding layers of window %d < "
+                    "max_len %d: their caches are rings, and the "
+                    "multi-token step does not write rings"
+                    % (lever, model["window"], self.max_len))
         self._exe = fluid.Executor(place if place is not None
                                    else fluid.TPUPlace())
         # busy-state stack for replica supervision (scheduler thread
@@ -664,20 +709,37 @@ class DecodeEngine:
         from ..models.gpt import ROUTED_PAIRS_VAR
         from ..observe.families import MOE_ROUTED_PAIRS
 
-        var = self._lane.scope.find_var(ROUTED_PAIRS_VAR)
+        tally = self._refresh_tally(ROUTED_PAIRS_VAR, MOE_ROUTED_PAIRS)
+        self.experts_touched()
+        return tally
+
+    def experts_touched(self) -> Optional[np.ndarray]:
+        """``[n_layer, n_expert_local]``: the decode steps in which each
+        expert THIS chip holds was given at least one pair, for a cfg
+        with ``n_expert_local`` (None otherwise). Counted on the device
+        beside the routed pairs; this call (and ``routed_pairs()``) is
+        the one transfer and refreshes ``paddle_moe_experts_touched``."""
+        from ..models.gpt import EXPERTS_TOUCHED_VAR
+        from ..observe.families import MOE_EXPERTS_TOUCHED
+
+        return self._refresh_tally(EXPERTS_TOUCHED_VAR, MOE_EXPERTS_TOUCHED)
+
+    def _refresh_tally(self, name, family) -> Optional[np.ndarray]:
+        var = self._lane.scope.find_var(name)
         if var is None:
             return None
         tally = np.asarray(var)
         for layer, row in enumerate(tally):
             for expert, n in enumerate(row):
-                MOE_ROUTED_PAIRS.labels(layer=str(layer),
-                                        expert=str(expert)).set(int(n))
+                family.labels(layer=str(layer),
+                              expert=str(expert)).set(int(n))
         return tally
 
     def predicted_resident_bytes(self) -> Optional[int]:
         """Static estimate of this engine's resident device bytes
-        (target + draft weights, 2L cache slabs, one decode step's
-        activations) — None when the byte model could not be built."""
+        (target + draft weights, the caches at their own shapes, one
+        decode step's activations) — None when the byte model could not
+        be built."""
         return None if self._mem is None else int(self._mem["resident"])
 
     def predicted_bytes(self, prompt_len: int) -> Optional[int]:

@@ -220,6 +220,35 @@ def test_kv_cache_write_compiles_in_place_for_v5e(d_head, v5e,
     assert _cache_sized(text, shape) == []
 
 
+def _lower_step(main, feeds, fetch, dev):
+    """Lower one program's step for the described chip from shapes alone
+    (nothing runs). Returns (lowered, names of the donated state)."""
+    from paddle_tpu.core.executor import analyze_block
+
+    class _Initialised:                 # nothing is run: shapes only
+        def has_var(self, name):
+            return True
+
+    (feed_names, _fetch, const_state, mut_state, _written, _rng,
+     step) = analyze_block(main, sorted(feeds), [fetch], _Initialised())
+    block = main.global_block()
+
+    def sds(name):
+        var = block.vars[name]
+        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(var.dtype),
+                                    sharding=dev)
+
+    def fn(feed_vals, const_vals, mut_vals):
+        fetches, new_mut, _, _ = step(feed_vals, const_vals, mut_vals, None)
+        return fetches, new_mut
+
+    lowered = jax.jit(fn, donate_argnums=(2,)).lower(
+        [jax.ShapeDtypeStruct(feeds[n], jnp.int32, sharding=dev)
+         for n in feed_names],
+        [sds(n) for n in const_state], [sds(n) for n in mut_state])
+    return lowered, mut_state
+
+
 def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
         v5e, compiled_kernels):
     """The whole ``gpt2-medium`` serving decode step (32 slots, 1,024
@@ -230,7 +259,6 @@ def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
     import re
 
     import paddle_tpu as fluid
-    from paddle_tpu.core.executor import analyze_block
     from paddle_tpu.kernels import kv_cache_write as kvw
     from paddle_tpu.models import gpt
     from paddle_tpu.observe.families import KV_CACHE_WRITE_PLANS
@@ -244,33 +272,12 @@ def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
     with fluid.program_guard(main, startup):
         logits, caches = gpt.build_serving_decode_step(cfg, batch=batch,
                                                        max_len=max_len)
-
-    class _Initialised:                 # nothing is run: shapes only
-        def has_var(self, name):
-            return True
-
-    (feed_names, _fetch, const_state, mut_state, _written, _rng,
-     step) = analyze_block(main, ["token", "pos"], [logits.name],
-                           _Initialised())
-    assert sorted(mut_state) == sorted(caches)
-    block = main.global_block()
-
-    def sds(name):
-        var = block.vars[name]
-        return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(var.dtype),
-                                    sharding=v5e)
-
-    def fn(feeds, const_vals, mut_vals):
-        fetches, new_mut, _, _ = step(feeds, const_vals, mut_vals, None)
-        return fetches, new_mut
-
     plans = {form: KV_CACHE_WRITE_PLANS.labels(form=form, rows="1")
              for form in ("pallas", "composed")}
     before = {form: c.value for form, c in plans.items()}
-    lowered = jax.jit(fn, donate_argnums=(2,)).lower(
-        [jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=v5e)
-         for _ in feed_names],
-        [sds(n) for n in const_state], [sds(n) for n in mut_state])
+    lowered, mut_state = _lower_step(
+        main, {"token": (batch, 1), "pos": (batch, 1)}, logits.name, v5e)
+    assert sorted(mut_state) == sorted(caches)
     assert "scatter" not in lowered.as_text()
     text = lowered.compile().as_text()
     n_cache = 2 * cfg["n_layer"]
@@ -283,6 +290,108 @@ def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
                           aliases.group(1))) == n_cache
     assert _cache_sized(text, (batch, cfg["n_head"], max_len,
                                cfg["d_model"] // cfg["n_head"])) == []
+
+
+TRINITY_PROMPTS = [512, 2048, 6144, 8192]
+
+
+@pytest.mark.parametrize("P", TRINITY_PROMPTS)
+def test_windowed_flash_forward_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The serving prefill's attention calls of ``trinity_serve_mixed`` at
+    published widths — 48 query heads over 8 key/value heads of 128,
+    float32, every prompt length of the mix: the band of 4,096 (one
+    kernel under the name ``flash_fwd_win`` where the prompt is longer
+    than the window) and the full causal call, grouped heads in both."""
+    from paddle_tpu.ops import attention as A
+
+    q = ((1, 48, P, 128), F32)
+    kv = ((1, 8, P, 128), F32)
+    for window in (4096, None):
+        fn = lambda q, k, v, w=window: A.flash_attention(  # noqa: E731
+            q, k, v, None, 128 ** -0.5, causal=True, window=w)
+        sds = [jax.ShapeDtypeStruct(sh, dt, sharding=v5e)
+               for sh, dt in (q, kv, kv)]
+        text = jax.jit(fn).lower(*sds).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+        banded = window is not None and window < P
+        assert (A.KERNEL_FWD_WIN in text) == banded
+        # grouped heads ride the block index: K and V are never repeated
+        assert "broadcast" not in text and "concatenate" not in text
+
+
+def _trinity():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "trinity-large-preview.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def test_trinity_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``trinity-large-preview`` serving decode step (16 slots,
+    rings of 4,096 beside slabs of 16,384, float32, 8 of 256 experts)
+    for the described chip: ten in-place Pallas cache writes (K and V of
+    four rings and one slab, the ring rows at ``pos mod 4096``), both
+    grouped matmuls of the four expert layers, the two tallies donated
+    beside the caches, and 10.7 GB of arguments."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.observe.families import KV_CACHE_WRITE_PLANS
+
+    gpt, cfg, serving = _trinity()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(
+            cfg, batch=serving["b_max"], max_len=serving["max_len"])
+    shapes = {n: tuple(main.global_block().vars[n].shape) for n in caches}
+    assert [shapes[n][2] for n in caches] == [4096] * 8 + [16384] * 2
+    plans = {form: KV_CACHE_WRITE_PLANS.labels(form=form, rows="1")
+             for form in ("pallas", "composed")}
+    before = {form: c.value for form, c in plans.items()}
+    lowered, mut = _lower_step(
+        main, {"token": (16, 1), "pos": (16, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert sorted(mut) == sorted(caches + [gpt.ROUTED_PAIRS_VAR,
+                                           gpt.EXPERTS_TOUCHED_VAR])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert {f: c.value - before[f] for f, c in plans.items()} == {
+        "pallas": 10, "composed": 0}
+    assert text.count('custom_call_target="tpu_custom_call"') == 10 + 8
+    assert text.count(kvw.KERNEL) >= 10
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+    mem = compiled.memory_analysis()
+    # 6.42 GB of weights and 4.29 GB of caches: 10.7 GB in all
+    assert 10.6e9 < mem.argument_size_in_bytes < 10.8e9
+    assert mem.temp_size_in_bytes < 1.0e9
+    print("trinity decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [512, 8192])
+def test_trinity_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix for the described chip: five flash forwards (four banded at
+    8,192, none at 512), no [P, P] score tensor, and temporaries that fit
+    beside the 10.7 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.ops import attention as A
+
+    gpt, cfg, serving = _trinity()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    assert (A.KERNEL_FWD_WIN in text) == (P > 4096)
+    assert "f32[1,48,%d,%d]" % (P, P) not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.0e9, mem
+    print("trinity prefill P=%d:" % P, mem)
 
 
 # ------------------------------------------------------ (b) use_interpret
