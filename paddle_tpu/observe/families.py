@@ -300,6 +300,21 @@ SERVING_DECODE_STEPS = REGISTRY.counter(
     "Continuous-batching PLAIN decode dispatches (each advances every "
     "active slot by one token); speculative iterations count into "
     "paddle_serving_spec_verify_steps_total instead")
+SERVING_STEP_DISPATCHES = REGISTRY.counter(
+    "paddle_serving_step_dispatches_total",
+    "Plain decode steps by how the loop dispatched them, counted where "
+    "it picks: 'ahead' went out before the step before it was read, its "
+    "tokens the ids that step leaves on the device (one step in flight: "
+    "every rider greedy, no draft lane); 'sync' went out with nothing "
+    "in flight, its tokens from the host (the first step after an idle "
+    "engine, and every step a sampled rider or a draft lane rides)",
+    labels=("dispatch",))
+SERVING_OVERRUN_ROWS = REGISTRY.counter(
+    "paddle_serving_overrun_rows_total",
+    "Rows a step dispatched ahead computed for a rider that the read of "
+    "the step before it found finished (its eos_id): written at pos + 1 "
+    "of a slot then free, id dropped. A rider that ends by length is "
+    "known at dispatch and never counts here")
 SERVING_FETCHES = REGISTRY.counter(
     "paddle_serving_fetches_total",
     "What the engine brought to the host to choose tokens from, counted "

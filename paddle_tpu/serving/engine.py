@@ -43,6 +43,13 @@ fleet tier"):
   and plain (sampled) rows coexist in one batch — plain slots ride the
   verify dispatch using only its first position.
 
+**One step in flight** (docs/SERVING.md): while every rider is greedy
+and no draft lane is attached, the loop dispatches step n+1 from the
+ids step n left on the device and only then reads step n, so feeds,
+gather, dispatch and the bookkeeping of a step run while the chip
+computes. A sampled rider, a draft lane and every admission keep their
+synchronous read.
+
 Requests enter through a bounded ``RequestQueue`` (backpressure,
 deadlines over queue time, cancellation — serving/queue.py). The greedy
 choice is made on the device (the step's and the prefill's own argmax,
@@ -63,6 +70,7 @@ parity with the fleet levers on and off. Occupancy telemetry:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
@@ -88,6 +96,12 @@ class MemoryBudgetExceeded(QueueFull):
     and router rejection reason (``memory``)."""
 
 
+# ``program_guard`` switches the PROCESS's default programs: two engines
+# of one process (a router's replicas) that build an admission's program
+# at the same moment would write their ops into each other's
+_BUILD_LOCK = threading.Lock()
+
+
 @contextlib.contextmanager
 def _null_mark(site, compiling):
     """Busy-marker no-op for lanes without a supervising engine."""
@@ -98,7 +112,7 @@ class _Slot:
     """One live sequence bound to a cache row."""
 
     __slots__ = ("request", "tokens", "target_len", "eos_id",
-                 "temperature", "top_k", "rng", "spec")
+                 "temperature", "top_k", "rng", "spec", "aboard")
 
     def __init__(self, request, prompt, n_new, eos_id, temperature,
                  top_k, seed, spec=False):
@@ -113,6 +127,9 @@ class _Slot:
         # attached: greedy verification is exact, sampled requests
         # take plain per-token steps in the same batch
         self.spec = bool(spec) and self.temperature == 0
+        # 1 while a step dispatched for this slot has not been read: its
+        # token is still on the device, its position already taken
+        self.aboard = 0
 
     def sample(self, logits_row) -> int:
         """THE sampler generate() uses, applied to this slot's row with
@@ -126,6 +143,11 @@ class _Slot:
     def finished(self, last_token: int) -> bool:
         return (len(self.tokens) >= self.target_len
                 or (self.eos_id is not None and last_token == self.eos_id))
+
+    def ends_by_length(self) -> bool:
+        """The steps dispatched for this slot fill its budget: known on
+        the host when the last of them goes out, before any is read."""
+        return len(self.tokens) + self.aboard >= self.target_len
 
 
 class _Lane:
@@ -187,6 +209,16 @@ class _Lane:
         # programs themselves)
         self._prefix_splice = jax.jit(_prefix_splice, donate_argnums=0)
 
+        def _carry(prev_ids, fresh):
+            return jax.numpy.where(fresh >= 0, fresh, prev_ids[:, None])
+
+        # the [b_max, 1] token feed of the step after the one that leaves
+        # ``prev_ids`` [b_max], built on the device: no id crosses to the
+        # host and back. ``fresh`` (int32) holds -1 for a slot that rode
+        # that step and the host's token for every other (a slot admitted
+        # since: the first token its admission fetched)
+        self.carry = jax.jit(_carry)
+
     def _note_cache_bytes(self) -> None:
         """``paddle_serving_cache_bytes{kind}``: what the caches this
         lane just built hold, rings (shorter than ``max_len``) apart
@@ -232,20 +264,35 @@ class _Lane:
         return True
 
     def decode(self, token, pos, greedy=False):
-        """One plain per-slot decode step; logits [B, 1, vocab], or —
-        ``greedy`` — only the [B] token ids the program chose from them
-        (``gpt.NEXT_TOKEN_VAR``). One program either way: the fetch list
-        is part of the Executor's plan key, and the plan that fetches
-        the ids holds no logits output. Each plan compiles at its first
-        use, so ``_cold`` is asked per (program, fetch)."""
+        """Dispatch one plain per-slot decode step and return what it
+        will leave on the device, un-awaited (``read`` blocks on it):
+        logits [B, 1, vocab], or — ``greedy`` — only the [B] token ids
+        the program chose from them (``gpt.NEXT_TOKEN_VAR``). One
+        program either way: the fetch list is part of the Executor's
+        plan key, and the plan that fetches the ids holds no logits
+        output. Each plan compiles at its first use, so ``_cold`` is
+        asked per (program, fetch). ``token`` is a host array or the
+        device array ``carry`` made: the same shape and dtype on the
+        device, so the same plan."""
         fetch = self._gpt.NEXT_TOKEN_VAR if greedy else self._logits
         with self._mark("decode",
                         self._cold(self._decode_prog, greedy)):
             with self._scope_guard(self.scope):
                 (out,) = self._exe.run(
                     self._decode_prog, feed={"token": token, "pos": pos},
-                    fetch_list=[fetch], scope=self.scope)
+                    fetch_list=[fetch], scope=self.scope,
+                    return_numpy=False)
         return out
+
+    @staticmethod
+    def read(out) -> np.ndarray:
+        """Block until a dispatched step's fetch is on the host: the
+        wait the synchronous ``Executor.run`` makes, under the same
+        heartbeat and ``executor.complete`` span."""
+        from ..core.executor import _wait_guard
+
+        with _wait_guard():
+            return np.asarray(out)
 
     def multi_decode(self, token, pos):
         """One multi-token step over the big caches (speculative
@@ -350,7 +397,7 @@ class _Lane:
         fluid = self._fluid
         prog, start = fluid.Program(), fluid.Program()
         with self._scope_guard(self._prefill_scope):
-            with fluid.program_guard(prog, start):
+            with _BUILD_LOCK, fluid.program_guard(prog, start):
                 self._gpt.build_prefill_step(
                     self.cfg, batch=1, prompt_len=P, max_len=self.max_len)
             self._run_startup(
@@ -404,7 +451,7 @@ class _Lane:
         fluid = self._fluid
         prog, start = fluid.Program(), fluid.Program()
         with self._scope_guard(scope):
-            with fluid.program_guard(prog, start):
+            with _BUILD_LOCK, fluid.program_guard(prog, start):
                 logits_var, _ = self._gpt.build_multi_token_decode_step(
                     self.cfg, batch=batch, steps=S, max_len=self.max_len)
         scratch = Scope()
@@ -488,8 +535,9 @@ class DecodeEngine:
     ``eos_id`` is sampled — the EOS token is included). Deadlines
     bound QUEUE time; once a sequence holds a slot it runs to
     completion. ``start()`` launches the scheduler thread; ``stop()``
-    drains nothing — in-flight and queued requests fail with
-    ``Cancelled``.
+    finishes no request — sequences mid-generation and queued requests
+    fail with ``Cancelled`` (a decode step already dispatched is awaited
+    and its ids dropped, so nothing stays queued on the device).
 
     Fleet-tier knobs (both default off; docs/SERVING.md):
 
@@ -587,6 +635,12 @@ class DecodeEngine:
         self.queue = RequestQueue(queue_capacity)
         self._slots: list = [None] * b_max
         self._n_active = 0
+        # decode steps dispatched and not yet read, oldest first, each
+        # (what it leaves on the device, {slot index: rider}, greedy).
+        # One between loop iterations while the loop runs ahead, none
+        # otherwise; two only inside an iteration, between the dispatch
+        # of step n+1 and the read of step n
+        self._flights: collections.deque = collections.deque()
         self._gauge_contrib = 0
         self._stop = threading.Event()
         self._error: Optional[BaseException] = None
@@ -824,15 +878,16 @@ class DecodeEngine:
             while not self._stop.is_set():
                 self.last_progress = time.monotonic()
                 # admit into free slots at the step boundary; block on
-                # the queue only when the whole batch is idle
-                self._admit(block=self._n_active == 0)
+                # the queue only when the whole batch is idle and no
+                # step is in flight (its riders wait for their tokens)
+                self._admit(block=not self._busy())
                 if self._stop.is_set():
                     return
-                if self._n_active == 0:
-                    continue
-                self._step()
+                if self._busy():
+                    self._step()
         except BaseException as exc:  # noqa: BLE001 — fail every caller loudly
             self._error = exc
+            self._drain()
             self._fail_slots(exc)  # a dead engine holds no live slots
             self.queue.close()  # pending requests fail as Cancelled
             if not isinstance(exc, Cancelled) and not self._stop.is_set():
@@ -851,13 +906,35 @@ class DecodeEngine:
                 # sweeps once more on its way out, so every admitted
                 # request reaches a terminal state no matter how the
                 # teardown interleaves.
+                self._drain()
                 self._fail_slots(_C("engine stopped mid-generation"))
 
+    def _busy(self) -> bool:
+        """A slot is held or a dispatched step has not been read."""
+        return self._n_active > 0 or bool(self._flights)
+
+    def _drain(self) -> None:
+        """Wait for every step still in flight and drop what it chose:
+        this thread leaves nothing queued on the device behind it, so
+        the donated caches and tallies in the scope are settled arrays
+        when ``stop()`` returns. A step that failed fails here again,
+        quietly: its riders are failed by ``_fail_slots`` either way."""
+        for out, _riders, _greedy in list(self._flights):
+            try:
+                self._lane.read(out)
+            except Exception:  # noqa: BLE001 — the caller holds the cause
+                pass
+
     def _fail_slots(self, exc: BaseException) -> None:
-        for i, slot in enumerate(self._slots):
-            if slot is not None:
-                slot.request.set_exception(exc)
-                self._slots[i] = None
+        # a rider of a step in flight may have left its slot already
+        # (its budget ends with that step): it is failed here too
+        flights = list(self._flights)
+        self._flights.clear()
+        riders = [slot for _out, aboard, _g in flights
+                  for slot in aboard.values()]
+        for slot in riders + [s for s in self._slots if s is not None]:
+            slot.request.set_exception(exc)    # a no-op once terminal
+        self._slots[:] = [None] * self.b_max
         self._n_active = 0
         self._set_active_gauge()
 
@@ -945,11 +1022,7 @@ class DecodeEngine:
 
     # ------------------------------------------------------------- steps
     def _step(self) -> None:
-        from ..observe.families import (SERVING_OCCUPANCY,
-                                        SERVING_TOKEN_GAP_SECONDS)
-
         active = [i for i, s in enumerate(self._slots) if s is not None]
-        SERVING_OCCUPANCY.observe(len(active) / float(self.b_max))
         self.last_progress = time.monotonic()
         spec_slots = [i for i in active if self._slots[i].spec]
         # a speculative iteration writes k+1 cache rows per slot; any
@@ -963,81 +1036,156 @@ class DecodeEngine:
         else:
             self._plain_step(active,
                              advance_draft=bool(spec_slots))
+
+    def _emitted(self) -> None:
+        """A step's tokens reached the host: the gap since the last
+        emission (a decode step's or an admission's first token) is the
+        token gap its riders saw."""
+        from ..observe.families import SERVING_TOKEN_GAP_SECONDS
+
         now = time.perf_counter()
         if self._last_emit is not None:
             SERVING_TOKEN_GAP_SECONDS.observe(now - self._last_emit)
         self._last_emit = now
 
     def _feeds(self, active):
+        """(token, pos) [b_max, 1] of the next step. A rider still
+        aboard the step in flight stands one position further than its
+        host tokens say, and its token is the one that step leaves on
+        the device; every other slot's comes from the host."""
         with _tr.trace_span("serving.engine.feeds"):
             token = np.zeros((self.b_max, 1), dtype="int64")
             pos = np.zeros((self.b_max, 1), dtype="int64")
             for i in active:
                 slot = self._slots[i]
-                token[i, 0] = slot.tokens[-1]
-                pos[i, 0] = len(slot.tokens) - 1
+                token[i, 0] = -1 if slot.aboard else slot.tokens[-1]
+                pos[i, 0] = len(slot.tokens) - 1 + slot.aboard
+            if self._flights:
+                token = self._lane.carry(self._flights[-1][0],
+                                         token.astype("int32"))
             return token, pos
 
-    def _step_span(self, site, active):
-        # one span per continuous-batching step under the engine thread;
-        # "traces" lists every rider's trace id so a request's share of
-        # the batched decode time is attributable post-hoc (the span is
-        # shared — B slots advance in ONE dispatch by design). Attrs are
-        # attached BEFORE entering: the ring copies attrs per event, so
-        # only enter-time keys ride the B event (and an unfinished step
-        # in a wedge dump must still name its riders)
+    def _step_span(self, site, n_active, reads, **attrs):
+        # one span per loop iteration under the engine thread, the
+        # admission apart; "traces" lists the trace id of every rider
+        # whose token this span hands out (``reads``: the riders of the
+        # step it waits for), so a request's share of the batched decode
+        # time is attributable post-hoc (the span is shared — B slots
+        # advance in ONE dispatch by design). Attrs are attached BEFORE
+        # entering: the ring copies attrs per event, so only enter-time
+        # keys ride the B event (and an unfinished step in a wedge dump
+        # must still name the riders it waits for)
         sp = _tr.trace_span(site, ctx=getattr(self, "_loop_trace", None))
         if sp.attrs is not None:
-            sp.attrs["active"] = len(active)
+            sp.attrs["active"] = n_active
             sp.attrs["traces"] = [
-                self._slots[i].request.trace.trace_id for i in active
-                if self._slots[i].request.trace is not None]
+                slot.request.trace.trace_id for slot in reads
+                if slot.request.trace is not None]
+            sp.attrs.update(attrs)
         return sp
 
     def _plain_step(self, active, advance_draft=False) -> None:
+        """One loop iteration but its admissions: dispatch the next
+        plain step, then read the oldest step in flight. While every
+        rider is greedy and no draft lane is attached the step just
+        dispatched STAYS in flight: the one read is the previous
+        step's, and the host's work for both ran while the chip
+        computed. Otherwise the loop is synchronous, from what it can
+        observe: a sampled rider's token does not exist before its
+        logits are read, and a draft lane is fed from the host. On the
+        way there an iteration only reads (nothing goes out before the
+        ids the host now needs are known), as does the last one of a
+        burst."""
         from ..observe.families import (SERVING_DECODE_STEPS,
                                         SERVING_FETCHES,
+                                        SERVING_OCCUPANCY,
                                         SERVING_SPEC_DRAFT_STEPS,
-                                        SERVING_TOKENS)
+                                        SERVING_STEP_DISPATCHES)
 
-        # what this step brings to the host follows from its riders:
-        # all at temperature 0, the program's own argmax a slot (b_max
+        # what a step brings to the host follows from its riders: all
+        # at temperature 0, the program's own argmax a slot (b_max
         # ids); one sampled rider, the [b_max, 1, vocab] logits for
-        # every rider's host sampler, as before. The logits plan
-        # compiles when the first sampled rider rides a step
+        # every rider's host sampler. The logits plan compiles when the
+        # first sampled rider rides a step
         greedy = all(self._slots[i].temperature == 0 for i in active)
-        # the step span holds all the host does for one token a rider:
-        # feeds, the Executor's phases (gather, h2d, dispatch, complete,
-        # write_back nest in it by the thread's context) and sampling.
-        # Free slots keep token 0 at pos 0: the write lands in a row
-        # nobody reads (masked, and the next prefill-insert overwrites)
-        with self._step_span("serving.engine.step", active):
-            token, pos = self._feeds(active)
-            out = self._lane.decode(token, pos, greedy=greedy)
-            SERVING_FETCHES.labels(
-                site="step", fetch="tokens" if greedy else "logits").inc()
-            if advance_draft and self._draft is not None:
-                # keep the draft lane's caches mirror-aligned through
-                # plain iterations: a skipped position would leave a
-                # never-written garbage row in every later draft's
-                # visible window, silently cratering acceptance
-                self._draft.decode(token, pos)
-                SERVING_SPEC_DRAFT_STEPS.inc()
-            SERVING_DECODE_STEPS.inc()
-            SERVING_TOKENS.inc(len(active))
-            with _tr.trace_span("serving.engine.sample",
-                                active=len(active)):
-                chosen = out.tolist() if greedy else None
-                for i in active:
-                    slot = self._slots[i]
-                    tok = (chosen[i] if greedy
-                           else slot.sample(out[i, 0]))
-                    slot.tokens.append(tok)
-                    if slot.finished(tok):
+        stays = greedy and self._draft is None
+        if self._flights and not stays:
+            active = []       # read first: the next iteration dispatches
+        riders = {i: self._slots[i] for i in active}
+        ahead = bool(riders and self._flights)
+        reads = (self._flights[0][1] if self._flights
+                 else {} if stays else riders)
+        # the step span holds all the host does in one iteration: feeds,
+        # the Executor's phases (gather, h2d, dispatch, write_back nest
+        # in executor.call by the thread's context), the wait
+        # (executor.complete) and sampling. With a step in flight the
+        # device work inside the span is the PREVIOUS step's. Free slots
+        # keep token 0 at pos 0: the write lands in a row nobody reads
+        # (masked, and the next prefill-insert overwrites)
+        with self._step_span("serving.engine.step", len(riders),
+                             reads.values(), ahead=ahead):
+            if riders:
+                SERVING_OCCUPANCY.observe(len(riders) / float(self.b_max))
+                token, pos = self._feeds(active)
+                out = self._lane.decode(token, pos, greedy=greedy)
+                SERVING_FETCHES.labels(
+                    site="step",
+                    fetch="tokens" if greedy else "logits").inc()
+                SERVING_STEP_DISPATCHES.labels(
+                    dispatch="ahead" if ahead else "sync").inc()
+                SERVING_DECODE_STEPS.inc()
+                if advance_draft and self._draft is not None:
+                    # keep the draft lane's caches mirror-aligned through
+                    # plain iterations: a skipped position would leave a
+                    # never-written garbage row in every later draft's
+                    # visible window, silently cratering acceptance
+                    self._draft.decode(token, pos)
+                    SERVING_SPEC_DRAFT_STEPS.inc()
+                self._flights.append((out, riders, greedy))
+                for i, slot in riders.items():
+                    slot.aboard += 1
+                    if slot.ends_by_length():
+                        # this step fills the rider's budget: it takes
+                        # no row of the next, and its slot is free for
+                        # the next admission while its token is awaited
                         self._slots[i] = None
                         self._n_active -= 1
-                        self._retire(i, slot)
+            while len(self._flights) > (1 if riders and stays else 0):
+                self._land()
             self._set_active_gauge()
+
+    def _land(self) -> None:
+        """Read the oldest step in flight (the one block of an
+        iteration) and do its bookkeeping: append, finished, retire,
+        resolve the request."""
+        from ..observe.families import (SERVING_OVERRUN_ROWS,
+                                        SERVING_TOKENS)
+
+        dev, riders, greedy = self._flights[0]
+        out = self._lane.read(dev)
+        with _tr.trace_span("serving.engine.sample", active=len(riders)):
+            chosen = out.tolist() if greedy else None
+            for i, slot in riders.items():
+                slot.aboard -= 1
+                tok = chosen[i] if greedy else slot.sample(out[i, 0])
+                slot.tokens.append(tok)
+                if not slot.finished(tok):
+                    continue
+                if self._slots[i] is slot:   # not freed at its dispatch
+                    self._slots[i] = None
+                    self._n_active -= 1
+                if slot.aboard:
+                    # found finished (eos_id) with the next step already
+                    # out and this rider aboard: that row is computed
+                    # for nobody, at pos + 1 of a slot now free (masked
+                    # for every neighbour, overwritten by the next
+                    # prefill-insert), and its id is dropped
+                    del self._flights[1][1][i]
+                    SERVING_OVERRUN_ROWS.inc()
+                self._retire(i, slot)
+        self._flights.popleft()
+        SERVING_TOKENS.inc(len(riders))
+        self._emitted()
 
     def _spec_step(self, active, spec_slots) -> None:
         """One speculative iteration: k greedy draft steps through the
@@ -1051,18 +1199,18 @@ class DecodeEngine:
         position; their extra rows are masked garbage the next real
         write overwrites."""
         from ..models.gpt import sample_token
-        from ..observe.families import (SERVING_SPEC_ACCEPTED,
+        from ..observe.families import (SERVING_OCCUPANCY,
+                                        SERVING_SPEC_ACCEPTED,
                                         SERVING_SPEC_DRAFT_STEPS,
                                         SERVING_SPEC_PROPOSED,
                                         SERVING_SPEC_VERIFY_STEPS,
                                         SERVING_TOKENS)
 
         k = self.spec_k
-        sp = self._step_span("serving.engine.spec", active)
-        if sp.attrs is not None:
-            sp.attrs["spec_slots"] = len(spec_slots)
-            sp.attrs["k"] = k
-        with sp:
+        SERVING_OCCUPANCY.observe(len(active) / float(self.b_max))
+        with self._step_span("serving.engine.spec", len(active),
+                             [self._slots[i] for i in active],
+                             spec_slots=len(spec_slots), k=k):
             # --- draft phase: k lockstep draft-lane steps; non-spec
             # rows re-feed their real (token, pos) every round — the
             # repeated write is idempotent and keeps the feeds simple
@@ -1070,7 +1218,7 @@ class DecodeEngine:
             drafts: Dict[int, List[int]] = {i: [] for i in spec_slots}
             greedy = np.random.RandomState(0)  # unused at temperature 0
             for _ in range(k):
-                logits = self._draft.decode(token, pos)
+                logits = self._draft.read(self._draft.decode(token, pos))
                 SERVING_SPEC_DRAFT_STEPS.inc()
                 for i in spec_slots:
                     d = sample_token(logits[i, 0], greedy)
@@ -1133,6 +1281,7 @@ class DecodeEngine:
                     SERVING_SPEC_ACCEPTED.inc(accepted)
             SERVING_TOKENS.inc(appended)
             self._set_active_gauge()
+        self._emitted()
 
     def _retire(self, slot_idx: int, slot: _Slot) -> None:
         from ..observe.families import SERVING_RETIRED

@@ -447,8 +447,7 @@ class ReplicaRouter:
                 eng = rep.engine
                 dead = eng._started and not eng.alive()
                 stalled = False
-                if self._stall_deadline_s is not None \
-                        and eng._n_active > 0:
+                if self._stall_deadline_s is not None and eng._busy():
                     age = time.monotonic() - eng.last_progress
                     # the Watchdog's wedge-vs-slow-compile distinction,
                     # replica-local: while the scheduler sits inside

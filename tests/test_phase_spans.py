@@ -328,7 +328,10 @@ def test_first_token_stamps_and_token_gaps_rebuilt_from_the_steps():
         assert sum(gaps) == pytest.approx(times[-1] - times[0])
         # the request is retired inside its last step, before the step ends
         assert times[-2] < done["t"] <= times[-1]
-    steps = [e for e in ended if e["site"] == "serving.engine.step"]
+    # a token gap for every iteration that handed tokens out (one that
+    # only dispatched a step ahead names no rider)
+    steps = [e for e in ended if e["site"] == "serving.engine.step"
+             and e["attrs"]["traces"]]
     snap = observe.snapshot()["metrics"]
     assert snap["paddle_serving_ttft_seconds"]["samples"][0]["count"] == 3
     assert snap["paddle_serving_token_gap_seconds"]["samples"][0][
@@ -336,19 +339,40 @@ def test_first_token_stamps_and_token_gaps_rebuilt_from_the_steps():
 
 
 def test_a_decode_step_decomposes_into_its_phases():
+    """One greedy request, four new tokens: the admission's, then three
+    steps. With one step in flight (docs/SERVING.md) that is four loop
+    iterations: the first only dispatches, two dispatch the next step
+    and read the one before, the last only reads."""
     eng = DecodeEngine(CFG, b_max=2, max_len=32, queue_capacity=4)
     with eng:
         eng.submit(np.arange(1, 6, dtype="int64"), 4).result(timeout=300)
     ended = _ended()
-    step = [e for e in ended if e["site"] == "serving.engine.step"][-1]
-    kids = [e for e in ended if e["parent"] == step["span"]]
-    assert [e["site"] for e in sorted(kids, key=lambda e: e["t"])] == [
-        "serving.engine.feeds", "executor.call", "serving.engine.sample"]
-    (call,) = [e for e in kids if e["site"] == "executor.call"]
-    assert {e["site"] for e in ended if e["parent"] == call["span"]} \
-        == PHASES
-    (sample,) = [e for e in kids if e["site"] == "serving.engine.sample"]
-    assert sample["attrs"]["active"] == step["attrs"]["active"] == 1
+    steps = [e for e in ended if e["site"] == "serving.engine.step"]
+
+    def kids_of(step):
+        return sorted((e for e in ended if e["parent"] == step["span"]),
+                      key=lambda e: e["t"])
+
+    assert [[e["site"] for e in kids_of(s)] for s in steps] == [
+        ["serving.engine.feeds", "executor.call"],
+        ["serving.engine.feeds", "executor.call", "executor.complete",
+         "serving.engine.sample"],
+        ["serving.engine.feeds", "executor.call", "executor.complete",
+         "serving.engine.sample"],
+        ["executor.complete", "serving.engine.sample"]]
+    assert [(s["attrs"]["active"], s["attrs"]["ahead"],
+             len(s["attrs"]["traces"])) for s in steps] == [
+        (1, False, 0), (1, True, 1), (1, True, 1), (0, False, 1)]
+    # the dispatch returns without waiting: the call holds every phase
+    # but the wait, which is the step span's own child
+    for step in steps[:3]:
+        (call,) = [e for e in kids_of(step)
+                   if e["site"] == "executor.call"]
+        assert {e["site"] for e in ended if e["parent"] == call["span"]} \
+            == PHASES - {"executor.complete"}
+    (sample,) = [e for e in kids_of(steps[-1])
+                 if e["site"] == "serving.engine.sample"]
+    assert sample["attrs"]["active"] == 1
 
 
 # --------------------------------------------------------- kernel names

@@ -654,13 +654,16 @@ def test_spec_decode_throughput_on_draft_friendly_workload():
     acceptance rate visible in telemetry, outputs bitwise the
     spec-off engine's. Draft-friendly means two things here: the
     models AGREE (both output heads zeroed -> identical greedy
-    chains), and the target is big enough (d512/l3) that its step is
+    chains), and the target is big enough (d1024/l3) that its step is
     weight-streaming-bound — so the k+1-position verify dispatch
     costs ~2 steps, not k+1, while the d32/l1 draft steps are cheap.
+    (d512 was enough while the spec-off engine paid its host time
+    every step; with one step in flight it no longer does, and the
+    draft lane's iterations are still synchronous.)
     That is the same regime that makes speculative decoding pay on a
     memory-bound accelerator. Engines built once; calibrated
     best-of-5 ratio, no absolute-ms asserts."""
-    cfg = dict(d_model=512, d_ff=2048, n_head=8, n_layer=3, vocab=512,
+    cfg = dict(d_model=1024, d_ff=4096, n_head=8, n_layer=3, vocab=512,
                max_length=96, dropout=0.0)
     draft = dict(d_model=32, d_ff=64, n_head=2, n_layer=1, vocab=512,
                  max_length=96, dropout=0.0)
