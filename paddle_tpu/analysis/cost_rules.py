@@ -361,6 +361,31 @@ def _cost_moe_ffn(ctx):
     return tokens.scaled(k * mats * 2 * D * F * E // n_all + 2 * D * n_all)
 
 
+@register_cost_rule("mla_decode")
+def _cost_mla_decode(ctx):
+    """Per slot and head: the two folds of W (d_c x d_nope, d_c x d_v)
+    and, per cache row, a score over the row's width and a value sum
+    over d_c. Bytes: the latent slab once (the kernel's pass; the
+    composed form reads it twice) and W."""
+    qn, qr, cs, ws = (ctx.input_shape(s)
+                      for s in ("QNope", "QRope", "Cache", "W"))
+    if None in (qn, qr, cs, ws) or len(qn) != 4 or len(cs) != 4:
+        return ctx.out_elems()
+    heads = ctx.elems(tuple(qn[:3]))                 # B * H
+    if heads is None:
+        return ctx.out_elems()
+    dc, dn, dv = int(ws[0]), int(qn[3]), int(ctx.attr("d_v", 0) or 0)
+    S, W = int(cs[2]), int(cs[3])
+    flops = heads.scaled(2 * dc * (dn + dv) + 2 * S * (W + dc) + 10 * S)
+    cache_b = ctx.elems(cs)
+    item = {"bfloat16": 2}.get(ctx.input_dtype("Cache"), 4)
+    w_item = {"bfloat16": 2}.get(ctx.input_dtype("W"), 4)
+    if cache_b is None:
+        return flops
+    return flops, cache_b.scaled(item) + BytesPoly.from_dims(
+        tuple(ws), w_item)
+
+
 @register_cost_rule("fused_attention")
 def _cost_attention(ctx):
     qs, ks = ctx.input_shape("Q"), ctx.input_shape("K")
@@ -370,7 +395,13 @@ def _cost_attention(ctx):
     scores = ctx.elems(tuple(qs[:-1]) + (ks[-2],))
     if q_elems is None or scores is None:
         return ctx.out_elems()
-    flops = _contract_scaled(q_elems, ks[-2]).scaled(2) + scores.scaled(10)
+    # q k^T over Q's width, then p v over V's (they differ in latent
+    # attention's expanded form)
+    vs = ctx.input_shape("V")
+    v_elems = q_elems if vs is None or len(vs) != len(qs) \
+        else ctx.elems(tuple(qs[:-1]) + (vs[-1],)) or q_elems
+    flops = _contract_scaled(q_elems, ks[-2]) \
+        + _contract_scaled(v_elems, ks[-2]) + scores.scaled(10)
     window = int(ctx.attr("window", 0) or 0)
     if window and isinstance(ks[-2], int) and 0 < window < ks[-2]:
         # a band of at most ``window`` keys a query, not all Sk

@@ -356,6 +356,17 @@ def _fp_attention(ctx):
     return BytesPoly.from_dims(dims, 4)
 
 
+@register_footprint_rule("mla_decode")
+def _fp_mla_decode(ctx):
+    """The composed form's score tensor [B, H, S] (the Pallas kernel
+    streams it: an upper bracket either way) beside the absorbed query
+    and output [B, H, d_c + d_rope] and [B, H, d_c]."""
+    qn, cs = ctx.input_shape("QNope"), ctx.input_shape("Cache")
+    if qn is None or cs is None or len(qn) != 4 or len(cs) != 4:
+        return None
+    return BytesPoly.from_dims((qn[0], qn[2], cs[2] + 2 * cs[3]), 4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

@@ -816,6 +816,16 @@ def _rr_kv_cache_write(ctx):
     ctx.set("Out", ctx.input_av("Cache").join(ctx.input_av("Value")))
 
 
+@register_range_rule("mla_decode")
+def _rr_mla_decode(ctx):
+    """``o`` is a convex combination of latent rows (softmax weights),
+    then one contraction over d_c with W's value half."""
+    ws = ctx.input_shape("W")
+    dc = ws[0] if ws and len(ws) == 2 and ws[0] >= 0 else None
+    o = _sym(ctx.input_av("Cache")).drop_const()
+    ctx.set("Out", _contraction(ctx, o, ctx.input_av("W"), dc))
+
+
 @register_range_rule("moe_ffn")
 def _rr_moe_ffn(ctx):
     """Each token's output is a sum of top_k expert outputs, each scaled

@@ -127,14 +127,16 @@ register_shape_rule("rope")(_same_shape("X"))
 
 @register_shape_rule("fused_attention")
 def _r_fused_attention(ctx):
-    """Out (and the dropout mask) have Q's shape [B, H, Sq, D]; K and V
-    are [B, Hkv, Sk, D] with Hkv dividing H (grouped heads), and a
-    window is a causal call's."""
+    """Out (and the dropout mask) are Q's shape [B, H, Sq, D] at V's
+    width; K is [B, Hkv, Sk, D] and V [B, Hkv, Sk, Dv] with Hkv dividing
+    H (grouped heads), and a window is a causal call's."""
     qs, ks, vs = (ctx.input_shape(s) for s in ("Q", "K", "V"))
     if qs is not None:
-        ctx.set("Out", qs)
+        out = qs if vs is None or len(vs) != len(qs) \
+            else tuple(qs[:-1]) + (vs[-1],)
+        ctx.set("Out", out)
         if "Mask" in ctx.op.outputs:
-            ctx.set("Mask", qs)
+            ctx.set("Mask", out)
     if ctx.attr("window", 0) and not ctx.attr("causal", False):
         ctx.fail("a window needs causal=True")
     if qs is None or ks is None or not (is_concrete(qs[1:])
@@ -145,9 +147,32 @@ def _r_fused_attention(ctx):
         ctx.fail("Q %s and K %s are not [B, H, Sq, D] and [B, Hkv, Sk, D] "
                  "with Hkv dividing H" % (qs, ks))
     if vs is not None and is_concrete(vs[1:]) \
-            and tuple(vs[1:]) != tuple(ks[1:]):
-        ctx.fail("V %s is not K's shape %s" % (vs, ks))
+            and tuple(vs[1:-1]) != tuple(ks[1:-1]):
+        ctx.fail("V %s is not K's shape %s up to its width" % (vs, ks))
 register_shape_rule("kv_cache_write")(_same_shape("Cache"))
+
+
+@register_shape_rule("mla_decode")
+def _r_mla_decode(ctx):
+    """Out is [B, 1, H * d_v]; QNope [B, 1, H, d_nope] and QRope
+    [B, 1, H, d_rope] meet a cache [B, 1, S, d_c + d_rope] and the
+    up-projection W [d_c, H * (d_nope + d_v)]."""
+    qn, qr, cs, ws = (ctx.input_shape(s)
+                      for s in ("QNope", "QRope", "Cache", "W"))
+    dv = int(ctx.attr("d_v", 0) or 0)
+    if qn is not None and len(qn) == 4:
+        ctx.set("Out", (qn[0], 1, qn[2] * dv if qn[2] >= 0 else -1))
+    if None in (qn, qr, cs, ws) or not all(
+            is_concrete(t[1:]) for t in (qn, qr, cs)) \
+            or not is_concrete(ws):
+        return
+    if len(qn) != 4 or len(qr) != 4 or len(cs) != 4 or len(ws) != 2 \
+            or qn[1] != 1 or qr[1:3] != qn[1:3] or cs[1] != 1 \
+            or cs[3] != ws[0] + qr[3] or ws[1] != qn[2] * (qn[3] + dv):
+        ctx.fail("QNope %s, QRope %s, Cache %s and W %s are not "
+                 "[B, 1, H, d_nope], [B, 1, H, d_rope], [B, 1, S, d_c + "
+                 "d_rope] and [d_c, H (d_nope + d_v=%d)]"
+                 % (qn, qr, cs, ws, dv))
 register_shape_rule("scatter")(_same_shape("X"))
 
 

@@ -19,7 +19,7 @@ from .core.program import (
 from .initializer import Constant, Xavier
 from .param_attr import ParamAttr
 
-__all__ = ["LayerHelper"]
+__all__ = ["LayerHelper", "stored_dtype"]
 
 # Active parameter-stacking guards (innermost last): while a
 # layers.scan_layers body builds, every create_parameter call is
@@ -28,6 +28,38 @@ __all__ = ["LayerHelper"]
 # (fc, layer_norm, fused_attention, ...) runs unchanged inside the
 # scanned body. See layers/scan_ext.py.
 _PARAM_STACKERS = []
+
+# The dtype float32 MATRICES (rank >= 2) are stored in while a builder
+# runs under ``stored_dtype`` (innermost last; None = as asked). Vectors
+# — norm scales, biases — and every activation keep their own: an op that
+# multiplies a float32 activation by such a matrix widens the matrix
+# where it multiplies and accumulates in float32 (models/gpt.py
+# cfg['weight_dtype']).
+_STORED_DTYPE = []
+
+
+def _stored(dtype, shape):
+    """The dtype a parameter asked for as ``dtype`` is created in."""
+    narrow = _STORED_DTYPE[-1] if _STORED_DTYPE else None
+    return narrow if narrow and dtype == "float32" and len(shape) >= 2 \
+        else dtype
+
+
+class stored_dtype:
+    """``with stored_dtype("bfloat16"):`` — parameters of rank >= 2 that
+    a layer asks for in float32 are created, and initialised, in that
+    dtype; ``None`` changes nothing."""
+
+    def __init__(self, dtype):
+        self.dtype = None if dtype in (None, "float32") else str(dtype)
+
+    def __enter__(self):
+        _STORED_DTYPE.append(self.dtype)
+        return self
+
+    def __exit__(self, *exc):
+        _STORED_DTYPE.pop()
+        return False
 
 
 class _ParamStacker:
@@ -107,6 +139,7 @@ class LayerHelper:
         attr = ParamAttr._to_attr(attr)
         if attr is False:
             return None
+        dtype = _stored(dtype, shape)
         suffix = "b" if is_bias else "w"
         name = attr.name or unique_name.generate("%s.%s" % (self.name, suffix))
         init = attr.initializer or default_initializer or (

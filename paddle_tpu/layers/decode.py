@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from ..layer_helper import LayerHelper
 
-__all__ = ["kv_cache_write", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+__all__ = ["kv_cache_write", "mla_decode", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -111,6 +111,30 @@ def rope(x, pos, base=10000.0, name=None):
     helper.append_op(type="rope", inputs={"X": [x], "Pos": [pos]},
                      outputs={"Out": [out]}, attrs={"base": float(base)})
     out.shape = x.shape
+    return out
+
+
+def mla_decode(q_nope, q_rope, cache, pos, w_shape, d_v, scale,
+               param_attr=None, name=None):
+    """Absorbed latent attention of one decode position (op
+    ``mla_decode``, kernels/mla_decode.py): ``q_nope [B, 1, H, d_nope]``
+    and the rotated ``q_rope [B, 1, H, d_rope]`` against the latent cache
+    ``cache [B, 1, S, d_c + d_rope]`` at ``pos`` ([1] shared, or [B, 1]
+    per slot: slot b sees rows ``<= pos[b]``), with the up-projection
+    ``W_ukv [d_c, H (d_nope + d_v)]`` (``param_attr``; the prefill's
+    expanded form multiplies by the same parameter) folded into the
+    query (``q_lat = q_nope W_uk^T``) and the output (``ctx = o W_uv``).
+    Returns ``[B, 1, H d_v]``."""
+    helper = LayerHelper("mla_decode", name=name)
+    w = helper.create_parameter(param_attr, list(w_shape), dtype=q_nope.dtype)
+    out = helper.create_variable_for_type_inference(q_nope.dtype)
+    helper.append_op(
+        type="mla_decode",
+        inputs={"QNope": [q_nope], "QRope": [q_rope], "Cache": [cache],
+                "Pos": [pos], "W": [w]},
+        outputs={"Out": [out]},
+        attrs={"d_v": int(d_v), "scale": float(scale)})
+    out.shape = (q_nope.shape[0], 1, int(q_nope.shape[2]) * int(d_v))
     return out
 
 

@@ -218,7 +218,10 @@ def embedding(
     TPU path the scatter-add grad is already sparse-friendly under XLA."""
     helper = LayerHelper("embedding")
     w = helper.create_parameter(param_attr, size, dtype)
-    out = helper.create_variable_for_type_inference(dtype)
+    # a row comes out in the dtype the table is STORED in (narrower than
+    # asked under layer_helper.stored_dtype; the caller widens it)
+    out = helper.create_variable_for_type_inference(
+        w.dtype if w is not None else dtype)
     pad = -1 if padding_idx is None else (
         padding_idx if padding_idx >= 0 else size[0] + padding_idx
     )
@@ -1290,7 +1293,8 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32", name=None
 
 
 def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
-                    causal=False, segment_ids=None, window=None, name=None):
+                    causal=False, segment_ids=None, window=None, name=None,
+                    mxu_dtype=None, flash_min_seq=None):
     """Single-kernel scaled-dot-product attention over [B,H,S,D] tensors
     (Pallas flash kernel; see ops/attention.py). The reference composes
     this from matmul+softmax layer calls — SURVEY §5. ``causal=True``
@@ -1309,7 +1313,13 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     ``0 <= i - j < window``: the kernel skips the key blocks wholly
     outside the band on both sides (device name ``flash_fwd_win``).
     ``k``/``v`` may hold fewer heads than ``q`` (grouped heads, no
-    repeated copy). Both are forward-only (a serving prefill)."""
+    repeated copy) and ``v`` a last axis of its own, which the output
+    takes. ``mxu_dtype`` (``"bfloat16"``) rounds float32 operands to it
+    where the kernel multiplies: one MXU pass, the precision XLA's own
+    float32 products have on the chip. ``flash_min_seq`` is this call's
+    threshold for the kernel in place of the static default (the
+    environment's ``PADDLE_TPU_FLASH_MIN_SEQ`` and a tuned entry still
+    win). All of these are forward-only (a serving prefill)."""
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("fused_attention: window=%r needs causal=True and "
                          "window >= 1" % (window,))
@@ -1331,8 +1341,12 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                                  "dropout": float(dropout),
                                  "causal": bool(causal), "is_test": False},
                                 **({"window": int(window)} if window
-                                   else {})))
-    out.shape = q.shape
+                                   else {}),
+                                **({"mxu_dtype": str(mxu_dtype)}
+                                   if mxu_dtype else {}),
+                                **({"flash_min_seq": int(flash_min_seq)}
+                                   if flash_min_seq else {})))
+    out.shape = tuple(q.shape[:-1]) + tuple(v.shape[-1:])
     return out
 
 

@@ -13,7 +13,10 @@ with the final position dropped (labels are ids shifted left), pad id 0
 masked out of the loss.
 """
 
+import functools
+
 from .. import layers
+from ..layer_helper import stored_dtype
 from ..param_attr import ParamAttr
 from .transformer import (_causal_bias, _ffn, _pad_bias, _prenorm,
                           multi_head_attention, qk_norm)
@@ -94,7 +97,55 @@ def base_config():
              n_shared_expert=1, router_score="sigmoid",
              router_bias=True, norm_topk=True, route_scale=2.448)
 
-    (one chip's share adds ``n_expert_local=8, expert_first=0``)."""
+    (one chip's share adds ``n_expert_local=8, expert_first=0``).
+
+    Latent attention (``attn="mla"``; every key below is checked by
+    ``_check_cfg``, and a cfg without them builds what it built):
+
+    * ``q_lora_rank``, ``kv_lora_rank`` (``d_c``), ``d_nope``, ``d_rope``
+      and ``d_v``. Of a token the layer keeps ONE row
+      ``[c | k_r]``: ``c = RMSNorm(h W_dkv[:, :d_c])`` and the one rotated
+      key part ``k_r = RoPE(h W_dkv[:, d_c:])`` all heads share — the
+      decode cache is one tensor a layer, ``gpt_<i>_cache_c [B, 1,
+      max_len, d_c + d_rope]``, not a K/V pair. Queries are
+      ``RMSNorm(h W_dq) W_uq``, per
+      head ``[q_nope | q_rope]`` with the rope part rotated; the softmax
+      scale is ``(d_nope + d_rope) ** -0.5``.
+    * two forms of the same numbers. EXPANDED (the prefill, through the
+      flash forward at q/k ``d_nope + d_rope`` and v ``d_v`` wide, and
+      the training build, composed): per head ``[k_nope | v] = c W_ukv``,
+      ``k = [k_nope | k_r]``. ABSORBED (the decode steps, op
+      ``mla_decode``): ``q_lat = q_nope W_uk^T``, score ``q_lat . c +
+      q_rope . k_r``, ``o = sum p c``, ``ctx = o W_uv`` — keys and values
+      are read out of the latent row itself (``kernels/mla_decode.py``).
+    * parameters ``gpt_<i>_att_qa.w_0 [D, q_lora_rank]``,
+      ``gpt_<i>_att_qa_ln_s``, ``gpt_<i>_att_qb.w_0 [q_lora_rank,
+      H (d_nope + d_rope)]``, ``gpt_<i>_att_kva.w_0 [D, d_c + d_rope]``,
+      ``gpt_<i>_att_kva_ln_s [d_c]``, ``gpt_<i>_att_kvb.w_0 [d_c,
+      H (d_nope + d_v)]``, ``gpt_<i>_att_o.w_0 [H d_v, D]``, the same
+      names in every build.
+    * it needs ``pos_emb="rope"`` and takes none of ``n_kv_head``,
+      ``d_head``, ``layer_types``/``window``, ``qk_norm``, ``attn_gate``.
+
+    ``weight_dtype`` (``"float32"`` | ``"bfloat16"``): the dtype the
+    SERVING programs (prefill, decode steps) store their matrices in —
+    every float32 parameter of rank >= 2: projections, stacked experts,
+    routers, the token table and the head. Norm scales, activations,
+    router scores and the caches stay float32; a product widens the
+    matrix where it multiplies and accumulates in float32. The training
+    build refuses it.
+
+    openPangu-Ultra-MoE-718B (``model_type`` pangu_ultra_moe), as the
+    worked example — published widths, all 61 layers, every expert::
+
+        dict(d_model=7680, n_head=128, n_layer=61, vocab=153600,
+             max_length=131072, dropout=0.0, pos_emb="rope",
+             rope_theta=25600000.0, norm="rms", norm_eps=1e-5,
+             attn="mla", q_lora_rank=1536, kv_lora_rank=512, d_nope=128,
+             d_rope=64, d_v=128, sandwich_norm=True, ffn_act="swiglu",
+             d_ff=18432, n_dense_layer=3, n_expert=256, expert_top_k=8,
+             d_expert=2048, n_shared_expert=1, router_score="sigmoid",
+             norm_topk=True, route_scale=2.5, weight_dtype="bfloat16")"""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -108,12 +159,15 @@ _CFG_KEYS = frozenset([
     "sandwich_norm", "emb_scale", "n_dense_layer", "n_shared_expert",
     "router_score", "router_bias", "route_scale", "n_expert_local",
     "expert_first",
+    "attn", "q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v",
+    "weight_dtype",
 ])
+_MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
 _NEW_LAYER_KEYS = frozenset([
     "d_head", "layer_types", "attn_gate", "sandwich_norm", "emb_scale",
-    "n_dense_layer", "rope_layers",
+    "n_dense_layer", "rope_layers", "attn",
 ])
 
 # the device-side tally of routed (token, expert) pairs the serving
@@ -150,7 +204,9 @@ def _check_cfg(cfg):
                                       "swiglu")),
                          ("qk_norm", (True, False, "head")),
                          ("rope_layers", ("all", "sliding")),
-                         ("router_score", ("softmax", "sigmoid"))):
+                         ("router_score", ("softmax", "sigmoid")),
+                         ("attn", ("mla",)),
+                         ("weight_dtype", ("float32", "bfloat16"))):
         val = cfg.get(key)
         if val is not None and val not in allowed:
             raise ValueError("cfg[%r] must be one of %s; got %r"
@@ -198,6 +254,23 @@ def _check_cfg(cfg):
     if cfg.get("rope_layers", "all") != "all" \
             and cfg.get("pos_emb", "learned") != "rope":
         raise ValueError("cfg['rope_layers'] needs pos_emb='rope'")
+    if has_latent(cfg):
+        for key in _MLA_KEYS:
+            if not int(cfg.get(key) or 0) >= 1:
+                raise ValueError("cfg['attn']='mla' needs cfg[%r] >= 1"
+                                 % key)
+        if cfg.get("pos_emb", "learned") != "rope" or cfg["d_rope"] % 2:
+            raise ValueError("cfg['attn']='mla' needs pos_emb='rope' and "
+                             "an even cfg['d_rope']; got %r, %r"
+                             % (cfg.get("pos_emb"), cfg["d_rope"]))
+        for key in ("n_kv_head", "d_head", "layer_types", "window",
+                    "qk_norm", "attn_gate"):
+            if cfg.get(key):
+                raise ValueError("cfg['attn']='mla' takes no cfg[%r]" % key)
+        return
+    for key in _MLA_KEYS:
+        if cfg.get(key):
+            raise ValueError("cfg[%r] needs cfg['attn']='mla'" % key)
     if _d_head(cfg) % 2 and cfg.get("pos_emb", "learned") == "rope":
         raise ValueError("rope needs an even head size; got %d"
                          % _d_head(cfg))
@@ -244,6 +317,28 @@ def _d_head(cfg):
     return int(cfg.get("d_head") or cfg["d_model"] // cfg["n_head"])
 
 
+def has_latent(cfg):
+    """Whether the cfg's attention is latent (``attn='mla'``): its decode
+    cache is one ``[B, 1, max_len, kv_lora_rank + d_rope]`` tensor a
+    layer (``gpt_<i>_cache_c``) and not a K/V pair."""
+    return cfg.get("attn") == "mla"
+
+
+def latent_width(cfg):
+    """Values a token leaves in a latent cache: ``c`` and ``k_r``."""
+    return int(cfg["kv_lora_rank"]) + int(cfg["d_rope"])
+
+
+def _stores_weights(builder):
+    """Run ``builder(cfg, ...)`` with its matrices created in
+    cfg['weight_dtype'] (``layer_helper.stored_dtype``)."""
+    @functools.wraps(builder)
+    def build_stored(cfg=None, *args, **kw):
+        with stored_dtype((cfg or {}).get("weight_dtype")):
+            return builder(cfg, *args, **kw)
+    return build_stored
+
+
 def _new_style(cfg):
     """Whether the cfg holds a key that only this file's own layer
     (``_block``) builds for training."""
@@ -287,9 +382,12 @@ def _rotates(cfg, i):
 def _embed(cfg, tokens, shape):
     """The token rows as ``shape`` (lookup_table squeezes a trailing-1 id
     dim, so the layout is restored explicitly), times cfg['emb_scale']."""
-    word = layers.reshape(
-        layers.embedding(tokens, [cfg["vocab"], cfg["d_model"]],
-                         param_attr=ParamAttr(name="gpt_word_emb")), shape)
+    word = layers.embedding(tokens, [cfg["vocab"], cfg["d_model"]],
+                            param_attr=ParamAttr(name="gpt_word_emb"))
+    if word.dtype != "float32":
+        # a table stored in cfg['weight_dtype']: the row widens here
+        word = layers.cast(word, "float32")
+    word = layers.reshape(word, shape)
     if cfg.get("emb_scale"):
         word = layers.scale(word, scale=float(cfg["emb_scale"]))
     return word
@@ -337,6 +435,100 @@ def _attn_out(cfg, h, ctxv, nm):
     return layers.fc(ctxv, cfg["d_model"], num_flatten_dims=2,
                      bias_attr=False,
                      param_attr=ParamAttr(name=nm + "_att_o.w_0"))
+
+
+def _fc(x, width, name):
+    return layers.fc(x, width, num_flatten_dims=2, bias_attr=False,
+                     param_attr=ParamAttr(name=name))
+
+
+def _mla_q(cfg, h, nm, S, pos):
+    """Latent attention's queries of ``h [B, S, D]`` as ``[B, S, H,
+    d_nope]`` and ``[B, S, H, d_rope]``, the second rotated at ``pos``
+    ([S], or per-slot [B, 1] where S is 1: the angles then broadcast
+    over the head axis)."""
+    n_head, dn, dr = cfg["n_head"], cfg["d_nope"], cfg["d_rope"]
+    h = layers.rms_norm(
+        _fc(h, cfg["q_lora_rank"], nm + "_att_qa.w_0"),
+        begin_norm_axis=2, epsilon=_rms_eps(cfg),
+        param_attr=ParamAttr(name=nm + "_att_qa_ln_s"))
+    q = layers.reshape(_fc(h, n_head * (dn + dr), nm + "_att_qb.w_0"),
+                       [-1, S, n_head, dn + dr])
+    q_nope = layers.slice(q, axes=[3], starts=[0], ends=[dn])
+    q_rope = layers.slice(q, axes=[3], starts=[dn], ends=[dn + dr])
+    if S > 1:
+        # positions index the axis before the last: [B, H, S, d_rope]
+        q_rope = layers.transpose(
+            _rope(cfg, layers.transpose(q_rope, perm=[0, 2, 1, 3]), pos),
+            perm=[0, 2, 1, 3])
+    else:
+        q_rope = _rope(cfg, q_rope, pos)
+    return q_nope, q_rope
+
+
+def _mla_row(cfg, h, nm, S, pos):
+    """What a latent layer keeps of each token of ``h [B, S, D]``:
+    ``[B, 1, S, d_c + d_rope]`` = the normed latent ``c`` beside the one
+    rotated key part ``k_r`` all heads share."""
+    dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
+    kv = _fc(h, dc + dr, nm + "_att_kva.w_0")
+    c = layers.rms_norm(
+        layers.slice(kv, axes=[2], starts=[0], ends=[dc]),
+        begin_norm_axis=2, epsilon=_rms_eps(cfg),
+        param_attr=ParamAttr(name=nm + "_att_kva_ln_s"))
+    k_r = _rope(cfg, layers.reshape(
+        layers.slice(kv, axes=[2], starts=[dc], ends=[dc + dr]),
+        [-1, 1, S, dr]), pos)
+    return layers.concat([layers.reshape(c, [-1, 1, S, dc]), k_r], axis=3)
+
+
+def _mla_expanded(cfg, h, nm, S, pos):
+    """The expanded form's operands over ``h [B, S, D]``: ``(q, k, v,
+    row)`` with q and k ``[B, H, S, d_nope + d_rope]``, v ``[B, H, S,
+    d_v]`` (every head's keys and values rebuilt from the latent) and
+    ``row`` the cache rows ``_mla_row`` gives."""
+    n_head, dn, dv = cfg["n_head"], cfg["d_nope"], cfg["d_v"]
+    dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
+    row = _mla_row(cfg, h, nm, S, pos)
+    c = layers.reshape(layers.slice(row, axes=[3], starts=[0], ends=[dc]),
+                       [-1, S, dc])
+    kv = layers.transpose(layers.reshape(
+        _fc(c, n_head * (dn + dv), nm + "_att_kvb.w_0"),
+        [-1, S, n_head, dn + dv]), perm=[0, 2, 1, 3])      # [B,H,S,dn+dv]
+    k_r = layers.slice(row, axes=[3], starts=[dc], ends=[dc + dr])
+    k = layers.concat([
+        layers.slice(kv, axes=[3], starts=[0], ends=[dn]),
+        layers.expand(k_r, [1, n_head, 1, 1])], axis=3)
+    v = layers.slice(kv, axes=[3], starts=[dn], ends=[dn + dv])
+    q_nope, q_rope = _mla_q(cfg, h, nm, S, pos)
+    q = layers.transpose(layers.concat([q_nope, q_rope], axis=3),
+                         perm=[0, 2, 1, 3])
+    return q, k, v, row
+
+
+def _mla_scale(cfg):
+    return float(cfg["d_nope"] + cfg["d_rope"]) ** -0.5
+
+
+def _note_mla_expanded(cfg, kernel):
+    """A layer of the expanded form was built: counted beside the
+    absorbed form's calls (``kernels/mla_decode.py``). ``kernel`` is the
+    op the layer's attention went into; which flash kernel and block that
+    op lowers to is ``paddle_flash_block_plans_total``'s to say."""
+    from ..observe.families import MLA_ATTENTION_PLANS
+
+    MLA_ATTENTION_PLANS.labels(
+        form="expanded", kernel=kernel, block="-",
+        widths="%dx%d" % (cfg["d_nope"] + cfg["d_rope"], cfg["d_v"])).inc()
+
+
+def _block_tail(cfg, x, h, ctxv, nm, i, **tally):
+    """What follows a layer's attention in the serving programs: the
+    output projection on the merged heads ``ctxv``, the residual, then
+    the FFN or the experts (``tally``: ``_mlp``'s counts) and theirs."""
+    x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
+    f = _mlp(cfg, _norm_of(cfg, x, nm + "_pre2"), nm, i, **tally)
+    return _residual(cfg, x, f, nm + "_post2")
 
 
 def _residual(cfg, x, y, prefix):
@@ -476,6 +668,11 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
     """
     cfg = cfg or base_config()
     _check_cfg(cfg)
+    if cfg.get("weight_dtype", "float32") != "float32":
+        raise ValueError(
+            "cfg['weight_dtype']=%r is the serving programs' (prefill, "
+            "decode steps): the training build keeps float32 parameters"
+            % (cfg["weight_dtype"],))
     new_style = _new_style(cfg)
     if new_style:
         # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
@@ -644,21 +841,28 @@ def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
         return layers.dropout(t, drop, is_test=is_test) if drop else t
 
     h = _norm_of(cfg, x, nm + "_pre1")
-    q, k, v = _qkv(cfg, h, nm)
+    if has_latent(cfg):
+        q, k, v, _row = _mla_expanded(cfg, h, nm, seq_len, rope_pos)
+        scale, d_head = _mla_scale(cfg), cfg["d_v"]
+        _note_mla_expanded(cfg, "composed")
+    else:
+        q, k, v = _qkv(cfg, h, nm)
 
-    def heads(t, n, which=None):
-        t = layers.reshape(t, [-1, seq_len, n, d_head])
-        if which:
-            t = _head_norm(cfg, t, nm, which)
-        return layers.transpose(t, perm=[0, 2, 1, 3])      # [B,n,S,Dh]
+        def heads(t, n, which=None):
+            t = layers.reshape(t, [-1, seq_len, n, d_head])
+            if which:
+                t = _head_norm(cfg, t, nm, which)
+            return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,S,Dh]
 
-    q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), heads(v, n_kv)
-    if _rotates(cfg, i):
-        q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
-    k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
-    v = repeat_kv_heads(v, n_kv, n_head, seq_len, d_head)
+        q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), \
+            heads(v, n_kv)
+        if _rotates(cfg, i):
+            q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
+        k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
+        v = repeat_kv_heads(v, n_kv, n_head, seq_len, d_head)
+        scale = d_head ** -0.5
     scores = layers.elementwise_add(
-        layers.matmul(q, k, transpose_y=True, alpha=d_head ** -0.5), bias)
+        layers.matmul(q, k, transpose_y=True, alpha=scale), bias)
     ctxv = layers.matmul(dropped(layers.softmax(scores)), v)
     ctxv = layers.reshape(layers.transpose(ctxv, perm=[0, 2, 1, 3]),
                           [-1, seq_len, n_head * d_head])
@@ -668,6 +872,7 @@ def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
     return _residual(cfg, x, dropped(f), nm + "_post2")
 
 
+@_stores_weights
 def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     """Prompt prefill as ONE dispatch: forward over the whole [B, P]
     prompt with causal attention, writing every layer's K/V slab into
@@ -713,20 +918,44 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     # op (causal, a window where the prompt is longer than it, grouped
     # heads): the flash forward at P >= flash_min_seq, whose [P, P]
     # scores never exist. Every other cfg composes them, as it did
-    fused = bool(cfg.get("layer_types"))
+    # (so does latent attention: its expanded form, q and k wider than v)
+    latent = has_latent(cfg)
+    fused = bool(cfg.get("layer_types")) or latent
     bias = None if fused else _causal_bias(P)
     routed = None      # only the serving decode step tallies its routing
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         rows = cache_rows(cfg, i, max_len)
+        h = _norm_of(cfg, x, nm + "_pre1")
+        if latent:
+            # the expanded form through the flash forward; what stays of
+            # the prompt is ONE slab of latent rows
+            cc = helper.create_global_variable(
+                name=nm + "_cache_c",
+                shape=(batch, 1, rows, latent_width(cfg)))
+            cache_names.append(cc.name)
+            q, k, v, row = _mla_expanded(cfg, h, nm, P, pos_range)
+            _prefill_cache_write(cc, row, P, rows, zero)
+            # compute-bound at 128 heads: the kernel from one lane tile
+            # on (every prompt length of a cell runs, and is measured
+            # as, the one attention form) and on bfloat16 MXU operands,
+            # as kernels/moe_gmm.py rounds its float32 ones
+            ctxv = layers.fused_attention(
+                q, k, v, scale=_mla_scale(cfg), causal=True,
+                mxu_dtype="bfloat16", flash_min_seq=128)
+            _note_mla_expanded(cfg, "fused_attention")
+            ctxv = layers.reshape(
+                layers.transpose(ctxv, perm=[0, 2, 1, 3]),
+                [-1, P, n_head * cfg["d_v"]])
+            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
+            continue
         ck = helper.create_global_variable(
             name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
         cv = helper.create_global_variable(
             name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
         cache_names += [ck.name, cv.name]
 
-        h = _norm_of(cfg, x, nm + "_pre1")
         q, k, v = _qkv(cfg, h, nm)
 
         def heads(t, n, which=None):
@@ -758,11 +987,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
-
-        h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _mlp(cfg, h2, nm, i, counts=routed)
-        x = _residual(cfg, x, f, nm + "_post2")
+        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -794,6 +1019,7 @@ def _prefill_cache_write(cache, kv, P, rows, zero):
     layers.kv_cache_write(cache, kv, zero)
 
 
+@_stores_weights
 def build_decode_step(cfg=None, batch=1, max_len=None,
                       per_slot_pos=False):
     """Incremental decoding step graph with donated KV caches.
@@ -858,8 +1084,10 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     # shape: a slab's over max_len rows, a ring's over its window
     # (``_visibility_bias`` says why the same test serves a ring)
     pos_b, biases, ring_pos = None, {}, {}
+    latent = has_latent(cfg)     # its visibility is ``mla_decode``'s own
     for rows in dict.fromkeys(cache_rows(cfg, i, max_len)
-                              for i in range(cfg["n_layer"])):
+                              for i in range(0 if latent
+                                             else cfg["n_layer"])):
         ar = layers.reshape(layers.range(0, rows, 1, "int64"), [1, rows])
         if pos_b is None:
             pos_b = pos if per_slot_pos else layers.reshape(pos, [1, 1])
@@ -877,6 +1105,25 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         rows = cache_rows(cfg, i, max_len)
+        if latent:
+            # the absorbed form: one latent row written, and every head
+            # reads keys AND values out of the slot's one slab
+            cc = helper.create_global_variable(
+                name=nm + "_cache_c",
+                shape=(batch, 1, rows, latent_width(cfg)))
+            cache_names.append(cc.name)
+            h = _norm_of(cfg, x, nm + "_pre1")
+            cc = layers.kv_cache_write(cc, _mla_row(cfg, h, nm, 1, pos),
+                                       pos)
+            q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
+            ctxv = layers.mla_decode(
+                q_nope, q_rope, cc, pos,
+                [cfg["kv_lora_rank"], n_head * (cfg["d_nope"] + cfg["d_v"])],
+                d_v=cfg["d_v"], scale=_mla_scale(cfg),
+                param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
+            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed,
+                            touched=touched)
+            continue
         # GQA: the cache stores n_kv heads — H/Hkv-times less decode
         # HBM, the whole point of grouped-query attention at inference
         ck = helper.create_global_variable(
@@ -925,11 +1172,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         w = layers.softmax(scores)
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
         ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
-        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
-
-        h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _mlp(cfg, h2, nm, i, counts=routed, touched=touched)
-        x = _residual(cfg, x, f, nm + "_post2")
+        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed,
+                        touched=touched)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -937,6 +1181,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     return logits, cache_names
 
 
+@_stores_weights
 def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
                                   max_len=None):
     """S tokens per slot in ONE dispatch, against the decode caches.
@@ -976,8 +1221,9 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     both uses (k+1 drafts, the un-cached prompt suffix), so the op
     count is bounded.
 
-    A cfg with ring caches (a sliding layer whose window is shorter
-    than ``max_len``) is REFUSED here: the one slab write at
+    A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is a
+    cfg with ring caches (a sliding layer whose window is shorter than
+    ``max_len``): the one slab write at
     ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a
     rejected draft cannot be cut out of a ring that has wrapped.
 
@@ -986,6 +1232,13 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     _check_cfg(cfg)
     if max_len is None:
         max_len = cfg["max_length"]
+    if has_latent(cfg):
+        raise ValueError(
+            "build_multi_token_decode_step: cfg['attn']='mla' keeps a "
+            "latent cache (one [B, 1, max_len, %d] tensor a layer), which "
+            "the multi-token step (suffix prefill after a prefix hit, "
+            "speculative verification) does not read or write"
+            % latent_width(cfg))
     if has_rings(cfg, max_len):
         raise ValueError(
             "build_multi_token_decode_step: cfg['layer_types'] holds "
@@ -1085,11 +1338,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             ctxs.append(layers.reshape(layers.matmul(w, cv),
                                        [-1, 1, n_head * d_head]))
         ctxv = ctxs[0] if S == 1 else layers.concat(ctxs, axis=1)
-        x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
-
-        h2 = _norm_of(cfg, x, nm + "_pre2")
-        f = _mlp(cfg, h2, nm, i, counts=routed)
-        x = _residual(cfg, x, f, nm + "_post2")
+        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -1168,7 +1417,7 @@ def generate(exe, decode_prog, logits_var, prompt_ids, n_new, scope,
     B, P = ids.shape
     max_len = None     # the slabs' rows: a ring is shorter and wraps
     for v in decode_prog.global_block().vars.values():
-        if v.name.endswith("_cache_k"):
+        if v.name.endswith(("_cache_k", "_cache_c")):
             max_len = max(max_len or 0, v.shape[2])
     if max_len is not None and P + n_new > max_len:
         raise ValueError(

@@ -1003,9 +1003,33 @@ SERVING_CACHE_BYTES = REGISTRY.gauge(
     "paddle_serving_cache_bytes",
     "Bytes of the decode caches a serving lane built, by kind: 'ring' "
     "(a sliding layer's [b_max, n_kv, window, Dh] tensors, position p in "
-    "row p mod window) and 'full' ([b_max, n_kv, max_len, Dh] slabs). "
-    "Set where the lane builds its caches; last lane wins",
+    "row p mod window), 'full' ([b_max, n_kv, max_len, Dh] slabs) and "
+    "'latent' (a latent-attention layer's ONE [b_max, 1, max_len, "
+    "kv_lora_rank + d_rope] tensor: keys and values are read out of the "
+    "same row). Set where the lane builds its caches; last lane wins",
     labels=("kind",))
+
+SERVING_WEIGHT_BYTES = REGISTRY.gauge(
+    "paddle_serving_weight_bytes",
+    "Bytes of the parameters a serving lane's decode program declares, by "
+    "the dtype each is STORED in (cfg['weight_dtype'] stores the matrices "
+    "of a gpt cfg in bfloat16; norm scales and every other vector stay "
+    "float32). Set where the lane builds its programs; last lane wins",
+    labels=("dtype",))
+
+MLA_ATTENTION_PLANS = REGISTRY.counter(
+    "paddle_mla_attention_plans_total",
+    "Which latent-attention (cfg['attn'] = 'mla') form a program holds: "
+    "'expanded' (per-head keys and values rebuilt from the latent) counts "
+    "a layer where models/gpt.py BUILDS one, kernel 'fused_attention' "
+    "(the prefill; the flash kernel and block it lowers to are "
+    "paddle_flash_block_plans_total's) or 'composed' (the training "
+    "build), block '-'; 'absorbed' (the decode step's, scores and values "
+    "read out of the latent row itself) counts a call of mla_decode at "
+    "LOWERING, kernel 'pallas' with the rows one grid step takes or "
+    "'composed' for jax.numpy. widths is [q/k]x[v] of the call ('192x128' "
+    "expanded, '576x512' absorbed at the published sizes)",
+    labels=("form", "kernel", "block", "widths"))
 
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see

@@ -212,8 +212,10 @@ def flash_effective(seq_len: int, kv_len: int = None) -> bool:
     return _flash_decision(seq_len, kv_len)[0]
 
 
-def _flash_decision(seq_len: int, kv_len: int = None):
-    """(use_flash, from_tuned_entry) per the three-tier precedence."""
+def _flash_decision(seq_len: int, kv_len: int = None, min_seq: int = None):
+    """(use_flash, from_tuned_entry) per the three-tier precedence.
+    ``min_seq`` is a caller's own static threshold in place of tier 3's
+    ``flash_min_seq()`` (``fused_attention(flash_min_seq=)``)."""
     sq = int(seq_len)
     sk = int(kv_len) if kv_len is not None else sq
     s = max(sq, sk)
@@ -224,7 +226,8 @@ def _flash_decision(seq_len: int, kv_len: int = None):
     choice = kernels.tuned_choice("attention", (sq, sk))
     if choice is not None:
         return choice == "pallas", True     # tier 2: measured winner
-    return s >= flash_min_seq(), False      # tier 3: static threshold
+    # tier 3: static threshold
+    return s >= (flash_min_seq() if min_seq is None else int(min_seq)), False
 
 
 def composed_attention(q, k, v, bias=None, scale=1.0, causal=False,
@@ -426,7 +429,7 @@ def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db, window=None):
     return Sqp, Skp, bq, bk
 
 
-def _heads_per_step(H, single_pass, bias, want_db=False):
+def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE):
     """How many (batch, head) rows one grid step takes. A single-pass
     kernel with no [bq, bk] tile to move (no bias or a key mask, no
     score-gradient output) takes up to ``_HEADS_PER_STEP`` heads a step,
@@ -434,11 +437,17 @@ def _heads_per_step(H, single_pass, bias, want_db=False):
     batch entry and one [B,1,1,S] bias block serves it: the per-step
     overhead is paid once, and one head's matmuls overlap the next
     head's softmax. Every other kernel keeps one head a step: a
-    [heads, bq, bk] float32 bias or ds block would not fit VMEM."""
+    [heads, bq, bk] float32 bias or ds block would not fit VMEM.
+    ``width`` is the widest operand's last axis: ``_HEADS_PER_STEP`` is
+    what fits at one lane tile of width, and blocks wider than that take
+    as many fewer heads as they take more lanes (four heads of a
+    256x1024 plan at q/k 192 wide, 256 lanes in VMEM, want 47.6 MB of
+    the 46 MB scoped limit: found on the chip, PR 32)."""
     slim = not want_db and (bias is None or bias.shape[2] == 1)
     if not (single_pass and slim):
         return 1
-    return max(g for g in range(1, _HEADS_PER_STEP + 1) if H % g == 0)
+    most = max(1, _HEADS_PER_STEP // -(-int(width) // _LANE))
+    return max(g for g in range(1, most + 1) if H % g == 0)
 
 
 def _note_plan(kernel, bq, bk, single_pass, visited=None):
@@ -560,8 +569,15 @@ def _dot_f32(a, b, ca, cb):
 
 
 def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
-                window=None):
+                window=None, mxu_dtype=None):
     q_ref, k_ref, v_ref = refs[:3]
+
+    def mxu(t):
+        # float32 operands rounded for the MXU where the call asks for it
+        # (one bf16 pass, as XLA's own float32 products at the TPU's
+        # default precision; Mosaic multiplies float32 in several)
+        return t if mxu_dtype is None else t.astype(mxu_dtype)
+
     b_ref = refs[3] if has_bias else None
     o_ref, lse_ref = refs[3 + has_bias:5 + has_bias]
     iq = pl.program_id(1)
@@ -570,7 +586,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
     def scores(h, masked):
         # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
         # with f32 accumulation; only the softmax state is explicitly f32
-        s = _dot_f32(q_ref[h], k_ref[h], 1, 1) * scale    # [bq, bk]
+        s = _dot_f32(mxu(q_ref[h]), mxu(k_ref[h]), 1, 1) * scale  # [bq,bk]
         if b_ref is not None:
             s = s + _bias_block(b_ref, h)
         return _causal_mask(s, iq, ik, bq, bk, window=window) \
@@ -580,7 +596,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         # one block holds every key of the row: one softmax and one
         # write, no running max/denominator/accumulator to carry
         for h in range(heads):
-            v = v_ref[h]                                  # [bk, D]
+            v = mxu(v_ref[h])                             # [bk, D]
             s = scores(h, causal)
             m = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
             p = jnp.exp(s - m)                            # [bq, bk] f32
@@ -600,7 +616,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute(masked):
-        v = v_ref[0]                              # [bk, D]
+        v = mxu(v_ref[0])                         # [bk, D]
         s = scores(0, masked)
         m_prev = m_ref[...]                       # [bq, 1]
         l_prev = l_ref[...]
@@ -623,16 +639,20 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
 
 
 def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
-                    window=None):
+                    window=None, mxu_dtype=None):
     """The forward kernel's call. ``window`` (an int, with ``causal``)
     bands the mask: blocks wholly outside the band are skipped on both
     sides and never fetched (the key block index is held inside the band,
     and a block index that does not move is not copied again). ``k`` and
     ``v`` may hold fewer heads than ``q`` (grouped heads): query head
     ``h`` reads key/value head ``h // (H / Hkv)`` through the block
-    index, so no repeated copy of K and V exists."""
+    index, so no repeated copy of K and V exists. ``v`` may have a
+    width of its own (latent attention's expanded form: q and k 192 wide,
+    v 128): its blocks, the accumulator and the output take ``v``'s, the
+    score tile is [bq, bk] whatever the widths. ``mxu_dtype`` rounds the
+    three operands to it where they meet the MXU (``_fwd_kernel``)."""
     B, H, S, D = q.shape
-    Sk, Hkv = k.shape[2], k.shape[1]
+    Sk, Hkv, Dv = k.shape[2], k.shape[1], v.shape[3]
     if causal and S != Sk:
         raise ValueError(
             "causal flash attention requires Sq == Sk (self-attention); "
@@ -650,19 +670,37 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
             window = None      # the band is the whole triangle
     Sp, Skp, bq, bk = _resolve_blocks(KERNEL_FWD, S, Sk, D, q.dtype, causal,
                                       False, window)
+    if causal and window is None and Skp > bk \
+            and min(bq, bk) <= _MAX_BLOCK // 2 \
+            and _block_sizes() == (None, None):
+        # a causal length past one key block whose lane tiles divide by
+        # no block over 256 (3,328 = 26 tiles: 256x256 blocks, 91 of them
+        # a head): pad to whole ``_MAX_BLOCK`` blocks instead. The causal
+        # mask already keeps every padded key from every real query, and
+        # 28 blocks of 512x512 took half the time of the 91 (97 -> 49 ms
+        # for five calls of 128 heads on the chip, PR 32; PERF.md
+        # section 6 has the same ratio at S 1024, PR 25)
+        Sp, Skp, bq, bk = _resolve_blocks(
+            KERNEL_FWD, _pad_len(S, _MAX_BLOCK), _pad_len(Sk, _MAX_BLOCK),
+            D, q.dtype, causal, False, window)
     nq, nk = Sp // bq, Skp // bk
     _note_plan(name, bq, bk, nk == 1,
                None if window is None
                else (_band_blocks(nq, nk, bq, bk, window), nq * nk))
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
-    heads = 1 if group > 1 else _heads_per_step(H, nk == 1, bias)
+    heads = 1 if group > 1 \
+        else _heads_per_step(H, nk == 1, bias, width=max(D, Dv))
     rows = _stat_rows(bq)
     q = _pad_axis(q, 2, Sp)
     k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
     qf = q.reshape(B * H, Sp, D)
-    kf, vf = (t.reshape(B * Hkv, Skp, D) for t in (k, v))
+    kf, vf = k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, Dv)
 
     def kv_map(bh, iq, ik):
+        if causal and window is None:
+            # hold the index at the diagonal: a block above it is skipped
+            # and, repeating its neighbour's index, moves no bytes
+            ik = jnp.minimum(ik, (iq * bq + bq - 1) // bk)
         if window is not None:
             # hold the index inside the band: the skipped blocks on
             # either side repeat a neighbour's index and move no bytes
@@ -673,7 +711,7 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     in_specs = [
         pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
         pl.BlockSpec((heads, bk, D), kv_map),
-        pl.BlockSpec((heads, bk, D), kv_map),
+        pl.BlockSpec((heads, bk, Dv), kv_map),
     ]
     operands = [qf, kf, vf]
     if bias is not None:
@@ -681,10 +719,14 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         in_specs.append(spec)
         operands.append(opnd)
 
+    if mxu_dtype is not None and (jnp.dtype(mxu_dtype) == q.dtype
+                                  or _use_interpret()):
+        mxu_dtype = None    # nothing to round; interpret mode multiplies
+        #                     float32 exactly, as the composed form does
     kern = functools.partial(_fwd_kernel, scale=scale, nk=nk, causal=causal,
                              bq=bq, bk=bk, heads=heads,
                              has_bias=bias is not None, rows=rows,
-                             window=window)
+                             window=window, mxu_dtype=mxu_dtype)
     out, lse = _checked_pallas_call(
         kern,
         name=name,
@@ -692,23 +734,23 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         in_specs=in_specs,
         operands=operands,
         out_specs=[
-            pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
+            pl.BlockSpec((heads, bq, Dv), lambda bh, iq, ik: (bh, iq, 0)),
             _stat_spec(heads, bq, rows, 1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sp) if rows else (B * H, Sp, 1),
                                  jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=_use_interpret(),
     )
     lse = lse[:, 0, :S] if rows else lse[:, :S, 0]
-    return out[:, :S].reshape(B, H, S, D), lse
+    return out[:, :S].reshape(B, H, S, Dv), lse
 
 
 # -------------------------------------------------------------- backward
@@ -1070,7 +1112,8 @@ def flash_attention_with_lse(q, k, v, bias=None, scale=1.0, causal=False):
 
 
 def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
-                    causal=False, window=None):
+                    causal=False, window=None, mxu_dtype=None,
+                    min_seq=None):
     """Fused attention. ``bias`` is a constant additive mask by default
     (non-differentiable: stop_gradient is applied); pass
     ``bias_grad=True`` to get the true bias cotangent, at the cost of an
@@ -1087,14 +1130,22 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
 
     ``window`` (an int, with ``causal``) keeps key j for query i iff
     ``0 <= i - j < window``; ``k``/``v`` with fewer heads than ``q`` are
-    grouped heads. Either is the serving prefill's FORWARD-ONLY call: the
+    grouped heads; ``v`` narrower or wider than ``q``/``k`` gives an
+    output of ``v``'s width; ``mxu_dtype`` rounds float32 operands to it
+    where the kernel multiplies (one bf16 MXU pass, as XLA's own float32
+    products at the TPU's default precision; the composed form is left
+    to XLA's). Each is the serving prefill's FORWARD-ONLY call: the
     kernel runs under the name ``flash_fwd_win`` (a window) or
     ``flash_fwd`` and no backward rule exists for it (the training build
-    of a windowed layer composes its band bias instead)."""
-    grouped = k.shape[1] != q.shape[1]
+    of a windowed layer composes its band bias instead). ``min_seq`` is
+    the caller's threshold for the kernel in place of the static
+    ``flash_min_seq()``; the environment's and a tuned entry still win."""
+    grouped = k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1] \
+        or mxu_dtype is not None
     if window is not None or grouped:
         if bias_grad:
-            raise ValueError("a window or grouped key/value heads take no "
+            raise ValueError("a window, grouped key/value heads, a value "
+                             "width of its own or mxu_dtype take no "
                              "trainable bias: the call is forward-only")
         if window is not None and not causal:
             raise ValueError("window=%r needs causal=True" % (window,))
@@ -1121,7 +1172,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
             causal = False
     from .. import kernels
 
-    use_flash, tuned = _flash_decision(q.shape[2], k.shape[2])
+    use_flash, tuned = _flash_decision(q.shape[2], k.shape[2], min_seq)
     kernels.note_decision("attention", "flash" if use_flash else "composed",
                           tuned=tuned)
     if kernels.kernels_enabled():
@@ -1146,7 +1197,8 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
         banded = window is not None and int(window) < q.shape[2]
         return _forward_pallas(
             q, k, v, bias, scale, causal=causal, window=window,
-            name=KERNEL_FWD_WIN if banded else KERNEL_FWD)[0]
+            name=KERNEL_FWD_WIN if banded else KERNEL_FWD,
+            mxu_dtype=mxu_dtype)[0]
     if bias is None:
         return _fa_maskbias(q, k, v, None, scale, causal)
     if bias_grad:
@@ -1166,7 +1218,8 @@ def _seg_mask_full(seg):
 
 
 def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
-                              seg=None, window=None):
+                              seg=None, window=None, mxu_dtype=None,
+                              min_seq=None):
     """Mosaic kernels cannot be auto-partitioned by the SPMD partitioner
     (jax raises at multi-device lowering), so under a ParallelEngine mesh
     the op-level flash call wraps itself in shard_map: batch shards over
@@ -1184,15 +1237,19 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
     test_dp_tp_train_step_lowers_for_tpu (NotImplementedError without
     the wrap) and the sp ring tests."""
     mesh = getattr(ctx, "mesh", None)
-    if window is not None or k.shape[1] != q.shape[1]:
+    if window is not None or k.shape[1] != q.shape[1] \
+            or v.shape[-1] != q.shape[-1] or mxu_dtype is not None \
+            or min_seq is not None:
         # the serving prefill's forward-only call: one device, no ids
         if seg is not None or (mesh is not None and mesh.size > 1
                                and not _in_manual_mesh()):
             raise NotImplementedError(
-                "fused_attention with a window or grouped key/value heads "
+                "fused_attention with a window, grouped key/value heads, "
+                "a value width of its own, mxu_dtype or flash_min_seq "
                 "runs on one device and takes no segment ids")
         return flash_attention(q, k, v, bias, scale, causal=causal,
-                               window=window)
+                               window=window, mxu_dtype=mxu_dtype,
+                               min_seq=min_seq)
     if mesh is None or mesh.size <= 1 or _in_manual_mesh():
         # _in_manual_mesh: already inside a shard_map region (pipeline
         # stage bodies, ring steps) — Mosaic-in-manual-mesh is the
@@ -1295,8 +1352,10 @@ def _fused_attention(ctx, ins, attrs):
     window = int(attrs.get("window", 0) or 0) or None
     if bias is not None:
         bias = bias.astype(jnp.float32)  # mask bias adds in f32 in-kernel
-    out = _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal,
-                                    seg=seg, window=window)
+    out = _maybe_shard_mapped_flash(
+        ctx, q, k, v, bias, scale, causal, seg=seg, window=window,
+        mxu_dtype=attrs.get("mxu_dtype") or None,
+        min_seq=attrs.get("flash_min_seq") or None)
     if dropout and not (attrs.get("is_test", False) or ctx.is_test):
         # dropout on the *output* (weights-dropout does not commute with the
         # fused kernel; divergence from the layer-composed path documented).
@@ -1397,9 +1456,12 @@ def _fused_attention_grad(ctx, ins, attrs):
     seg = (ins.get("SegmentIds") or [None])[0]
     mask = (ins.get("Mask") or [None])[0]
     g = ins["Out@GRAD"][0]
-    if attrs.get("window") or k.shape[1] != q.shape[1]:
+    if attrs.get("window") or k.shape[1] != q.shape[1] \
+            or v.shape[-1] != q.shape[-1] or attrs.get("mxu_dtype") \
+            or attrs.get("flash_min_seq"):
         raise NotImplementedError(
-            "fused_attention with a window or grouped key/value heads is "
+            "fused_attention with a window, grouped key/value heads, a "
+            "value width of its own, mxu_dtype or flash_min_seq is "
             "forward-only; a training build composes its band bias "
             "(models/gpt.py build)")
     if mask is not None:

@@ -305,6 +305,31 @@ def _kv_cache_write(ctx, ins, attrs):
                                    ins["Pos"][0])]}
 
 
+@register_op("mla_decode", no_grad=True)
+def _mla_decode(ctx, ins, attrs):
+    """Latent attention's absorbed form for one decode position
+    (models/gpt.py cfg['attn']='mla'; kernels/mla_decode.py holds the
+    attention itself, a Pallas kernel on the TPU). The up-projection
+    ``W [d_c, H (d_nope + d_v)]`` is folded in on both sides: ``q_lat =
+    q_nope W_uk^T`` before, ``ctx = o W_uv`` after, each widened to the
+    activations' dtype where it multiplies; what lies between reads
+    keys and values out of the one latent slab. Inference-only."""
+    from ..kernels.mla_decode import mla_decode
+
+    qn, qr = ins["QNope"][0], ins["QRope"][0]      # [B, 1, H, dn | dr]
+    cache, pos, w = ins["Cache"][0], ins["Pos"][0], ins["W"][0]
+    B, _, H, dn = qn.shape
+    dc, dv = w.shape[0], int(attrs["d_v"])
+    w = w.reshape(dc, H, dn + dv).astype(qn.dtype)
+    q_lat = jnp.einsum("bhd,chd->bhc", qn[:, 0], w[:, :, :dn])
+    q = jnp.concatenate([q_lat, qr[:, 0]], axis=-1)  # [B, H, dc + dr]
+    if pos.size == 1:                                # one shared position
+        pos = jnp.broadcast_to(pos.reshape(()), (B,))
+    o = mla_decode(q, cache, pos, d_c=dc, scale=float(attrs["scale"]))
+    out = jnp.einsum("bhc,chd->bhd", o.astype(qn.dtype), w[:, :, dn:])
+    return {"Out": [out.reshape(B, 1, H * dv)]}
+
+
 @register_op("rope", diff_inputs=["X"])
 def _rope(ctx, ins, attrs):
     """Rotary position embedding (rotate-half convention) on [..., S, D]

@@ -187,11 +187,14 @@ class _Lane:
             for n, v in given.items():
                 self.scope.set_var(n, v)
         self._note_cache_bytes()
+        self._note_weight_bytes()
         import jax
 
         def _splice(bigs, smalls, idx):
+            # each update is the prefill scope's whole batch=1 tensor, so
+            # the start is the slot and zeros, at the tensor's own rank
             return [jax.lax.dynamic_update_slice(
-                        b, s.astype(b.dtype), (idx, 0, 0, 0))
+                        b, s.astype(b.dtype), (idx,) + (0,) * (b.ndim - 1))
                     for b, s in zip(bigs, smalls)]
 
         # one compiled dispatch splices a prefilled slot into ALL the
@@ -200,7 +203,7 @@ class _Lane:
 
         def _prefix_splice(smalls, rows):
             return [jax.lax.dynamic_update_slice(
-                        s, r.astype(s.dtype), (0, 0, 0, 0))
+                        s, r.astype(s.dtype), (0,) * s.ndim)
                     for s, r in zip(smalls, rows)]
 
         # same trick on the prefill-scope caches: one donated dispatch
@@ -221,18 +224,34 @@ class _Lane:
 
     def _note_cache_bytes(self) -> None:
         """``paddle_serving_cache_bytes{kind}``: what the caches this
-        lane just built hold, rings (shorter than ``max_len``) apart
-        from full slabs."""
+        lane just built hold, by each tensor's own name and shape: a
+        latent layer's one tensor (``_cache_c``), rings (shorter than
+        ``max_len``) and full slabs."""
         from ..observe.families import SERVING_CACHE_BYTES
 
-        held = {"ring": 0, "full": 0}
+        held = {"ring": 0, "full": 0, "latent": 0}
         for n in self.cache_names:
             var = self._decode_prog.global_block().var(n)
-            kind = "ring" if var.shape[2] < self.max_len else "full"
+            kind = "latent" if n.endswith("_cache_c") else \
+                "ring" if var.shape[2] < self.max_len else "full"
             held[kind] += int(np.prod(var.shape)) \
                 * np.dtype(var.dtype).itemsize
         for kind, nbytes in held.items():
             SERVING_CACHE_BYTES.labels(kind=kind).set(nbytes)
+
+    def _note_weight_bytes(self) -> None:
+        """``paddle_serving_weight_bytes{dtype}``: the decode program's
+        parameters by the dtype each is stored in."""
+        from ..analysis.memory import dtype_bytes
+        from ..observe.families import SERVING_WEIGHT_BYTES
+
+        held: Dict[str, int] = {}
+        for p in self._decode_prog.global_block().all_parameters():
+            held[str(p.dtype)] = held.get(str(p.dtype), 0) \
+                + int(np.prod(p.shape)) * dtype_bytes(str(p.dtype),
+                                                      warn=False)
+        for dtype, nbytes in held.items():
+            SERVING_WEIGHT_BYTES.labels(dtype=dtype).set(nbytes)
 
     def _run_startup(self, start, scope, supplied) -> None:
         """Run a copy of a startup program without its initialisers of
@@ -554,7 +573,9 @@ class DecodeEngine:
     Both are REFUSED at construction for a model whose caches hold rings
     (``cfg['layer_types']`` with a window shorter than ``max_len``): a
     stored prefix cannot be cut out of, nor a rejected draft rolled back
-    in, a ring that has wrapped (``gpt.build_multi_token_decode_step``).
+    in, a ring that has wrapped (``gpt.build_multi_token_decode_step``);
+    and for a model whose cache is latent (``cfg['attn']='mla'``), which
+    the multi-token step neither reads nor writes.
     """
 
     def __init__(self, cfg, params: Optional[Dict[str, np.ndarray]] = None,
@@ -591,6 +612,13 @@ class DecodeEngine:
             multi += [(self.cfg, "speculative decoding (draft_cfg=)"),
                       (draft_cfg, "a draft model (draft_cfg=)")]
         for model, lever in multi:
+            if gpt.has_latent(model):
+                raise ValueError(
+                    "DecodeEngine: %s cannot serve a model with "
+                    "cfg['attn']='mla': its cache is latent (one [b_max, "
+                    "1, max_len, %d] tensor a layer), and the multi-token "
+                    "step does not read or write a latent cache"
+                    % (lever, gpt.latent_width(model)))
             if gpt.has_rings(model, self.max_len):
                 raise ValueError(
                     "DecodeEngine: %s cannot serve a model with "

@@ -222,3 +222,26 @@ def fresh_programs():
 @pytest.fixture(autouse=True)
 def _seed_numpy():
     np.random.seed(0)
+
+
+@pytest.fixture(autouse=True)
+def _one_traced_rehearsal_at_a_time(request):
+    """``benchmarks/run.py`` keeps ONE ``<checkout>/.bench_trace`` and
+    empties it around every traced run, so two ``--trace 1`` rehearsals
+    from this checkout at once (xdist runs the files of tests/benchmarks
+    side by side) delete each other's profile. A test of that directory
+    with a true ``trace`` parameter holds a lock between processes."""
+    spec = getattr(request.node, "callspec", None)
+    if spec is None or not spec.params.get("trace") \
+            or "benchmarks" not in str(request.node.fspath).split(os.sep):
+        yield
+        return
+    import fcntl
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, ".bench_trace.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            yield
+        finally:
+            fcntl.flock(lock, fcntl.LOCK_UN)

@@ -394,6 +394,111 @@ def test_trinity_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     print("trinity prefill P=%d:" % P, mem)
 
 
+def _pangu():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "openpangu-ultra-moe-718b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def test_pangu_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``openpangu-ultra-moe-718b`` serving decode step (64
+    slots of 4,096 latent rows, bf16 matrices, 8 of 256 experts) for the
+    described chip: five in-place Pallas writes of one 576-value row a
+    slot, five ``mla_decode`` calls over the S-minor view of the slab (no
+    copy or relayout of a cache), both grouped matmuls of the four expert
+    layers on bf16 right-hand sides, no float32 copy of a matrix, and
+    9.84 GB of arguments."""
+    import re
+
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.kernels import mla_decode, moe_gmm
+    from paddle_tpu.observe.families import (KV_CACHE_WRITE_PLANS,
+                                             MLA_ATTENTION_PLANS)
+
+    gpt, cfg, serving = _pangu()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert caches == ["gpt_%d_cache_c" % i for i in range(5)]
+    assert all(tuple(main.global_block().vars[n].shape) == (B, 1, S, 576)
+               for n in caches)
+    write = KV_CACHE_WRITE_PLANS.labels(form="pallas", rows="1")
+    absorbed = MLA_ATTENTION_PLANS.labels(form="absorbed", kernel="pallas",
+                                          block="512", widths="576x512")
+    before = write.value, absorbed.value
+    lowered, mut = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert sorted(mut) == sorted(caches + [gpt.ROUTED_PAIRS_VAR,
+                                           gpt.EXPERTS_TOUCHED_VAR])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert (write.value - before[0], absorbed.value - before[1]) == (5, 5)
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 5 + 8
+    assert len(re.findall(r"%s[.\d]* = " % mla_decode.KERNEL, text)) == 5
+    assert len(re.findall(r"%s[.\d]* = " % kvw.KERNEL, text)) == 5
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+    # neither the slab nor its S-minor view is copied or relaid
+    assert _cache_sized(text, (B, 1, S, 576)) == []
+    assert _cache_sized(text, (B, 1, 576, S)) == []
+    relaid = re.compile(r"= \S+\[%d,(576,%d|%d,576)\]\S* "
+                        r"(copy|transpose|fusion)\(" % (B, S, S))
+    assert not relaid.search(text)
+    # no float32 copy of a stored matrix (the largest: an expert stack)
+    assert not re.search(r"f32\[8,7680,2048\][^ ]* (copy|convert)\(", text)
+    assert not re.search(r"f32\[7680,18432\][^ ]* (copy|convert)\(", text)
+    mem = compiled.memory_analysis()
+    # 6.82 GB of bf16 matrices and 3.02 GB of latent cache
+    assert 9.8e9 < mem.argument_size_in_bytes < 9.9e9, mem
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    print("pangu decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [128, 512, 1024, 3328])
+def test_pangu_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of every prompt length of the mix: five flash
+    forwards at q/k 192 and v 128 wide (the kernel at 128 too; single
+    pass up to 1,024, where four heads a step overran the scoped VMEM on
+    the chip: two a step at 256 lanes of width), no [P, P] score tensor,
+    and temporaries that fit beside the 9.84 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.observe.families import (FLASH_BLOCK_PLANS,
+                                             MLA_ATTENTION_PLANS)
+
+    gpt, cfg, serving = _pangu()
+    form = MLA_ATTENTION_PLANS.labels(form="expanded",
+                                      kernel="fused_attention", block="-",
+                                      widths="192x128")
+    built = form.value
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    assert form.value == built + 5
+    block = {128: "128x128", 512: "512x512", 1024: "256x1024",
+             3328: "512x512"}[P]       # 3,328 pads to 7 blocks of 512
+    plan = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block=block,
+                                    single_pass="0" if P == 3328 else "1")
+    before = plan.value
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    assert plan.value == before + 5
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    assert text.count("flash_fwd") >= 5
+    if P > 128:       # [1, 128, 128, 128] is also a head tensor's shape
+        assert "f32[1,128,%d,%d]" % (P, P) not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4.5e9, mem
+    print("pangu prefill P=%d:" % P, mem)
+
+
 # ------------------------------------------------------ (b) use_interpret
 class _Dev:
     def __init__(self, platform, device_kind="fake"):

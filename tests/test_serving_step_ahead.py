@@ -51,26 +51,37 @@ RING_CFG = dict(d_model=32, n_head=4, n_kv_head=2, d_head=8, n_layer=3,
                 router_score="sigmoid", norm_topk=True)
 
 
-def _value(name, **labels):
-    for s in observe.snapshot()["metrics"][name]["samples"]:
-        if s["labels"] == labels:
-            return s["value"]
-    return 0.0
-
-
 def _counts():
-    return {"ahead": _value("paddle_serving_step_dispatches_total",
-                            dispatch="ahead"),
-            "sync": _value("paddle_serving_step_dispatches_total",
-                           dispatch="sync"),
-            "steps": _value("paddle_serving_decode_steps_total"),
-            "overrun": _value("paddle_serving_overrun_rows_total"),
-            "logits": _value("paddle_serving_fetches_total", site="step",
-                             fetch="logits")}
+    # one snapshot: the engine's thread moves these a few lines apart
+    metrics = observe.snapshot()["metrics"]
+
+    def value(name, **labels):
+        for s in metrics[name]["samples"]:
+            if s["labels"] == labels:
+                return s["value"]
+        return 0.0
+
+    return {"ahead": value("paddle_serving_step_dispatches_total",
+                           dispatch="ahead"),
+            "sync": value("paddle_serving_step_dispatches_total",
+                          dispatch="sync"),
+            "steps": value("paddle_serving_decode_steps_total"),
+            "overrun": value("paddle_serving_overrun_rows_total"),
+            "logits": value("paddle_serving_fetches_total", site="step",
+                            fetch="logits")}
 
 
 def _moved(before):
-    return {k: v - before[k] for k, v in _counts().items()}
+    """What the counters gained. Two readings that agree were taken
+    between two steps of an engine that is still running (the chaperone
+    of the synchronous case keeps it so)."""
+    now = _counts()
+    for _ in range(100):
+        again = _counts()
+        if again == now:
+            break
+        now = again
+    return {k: v - before[k] for k, v in now.items()}
 
 
 class _SeqRef:
