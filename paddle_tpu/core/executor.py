@@ -1465,7 +1465,8 @@ def _feed_host_array(name: str, val, var) -> np.ndarray:
 
 def _feed_to_device(name: str, val, var):
     """Convert ONE feed to a device array at its on-device dtype (kept for
-    per-array callers, e.g. the ParallelEngine's sharded placement; the
+    per-array callers: the pipelined loop's window stacking, and the
+    ParallelEngine for feeds that are device arrays already; the
     executor's own hot path batches via feeds_to_device)."""
     want = as_jax_dtype(var.dtype) if var is not None else None
     if isinstance(val, jax.Array):

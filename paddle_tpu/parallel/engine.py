@@ -20,14 +20,13 @@ import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.executor import (RNG_VAR, Executor, _call_span,
-                             _dispatch_guard, _feed_to_device,
-                             analyze_block, make_scan_fn, plan_tag,
-                             unstack_singleton_feed,
+                             _dispatch_guard, _feed_host_array,
+                             _feed_to_device, analyze_block, make_scan_fn,
+                             plan_tag, unstack_singleton_feed,
                              validate_stacked_feeds)
 from ..core.program import Program, Variable
 from ..core.scope import Scope, global_scope
@@ -190,9 +189,11 @@ class ParallelEngine:
     def _execute(self, plan, fn, feed_shardings, feeds, const_state,
                  mut_state, rng, scope, return_numpy, nan_suffix,
                  steps=1):
-        """Place inputs per their shardings (feeds split over the data
-        axis, state per its spec), run one compiled dispatch, write the
-        new state back to the scope. The dispatch goes through the
+        """Place what is not yet where the plan wants it (a host feed
+        goes to its sharding in one transfer; an array already committed
+        to the plan's sharding, as everything a step wrote is, IS the
+        argument), run one compiled dispatch, write the new state back
+        to the scope. The dispatch goes through the
         Executor's guard (heartbeat, ``executor.dispatch`` fault point
         and span: a wedged mesh dispatch must be as visible to the
         watchdog as a one-chip one), and the epilogue (state write-back,
@@ -209,23 +210,36 @@ class ParallelEngine:
         EXECUTOR_STEPS.inc(steps)
         t_dispatch = time.perf_counter()
         with _tr.trace_span("executor.place") as sp:
-            moved = [0, 0]  # arrays, bytes not yet where they belong
+            count = [0, 0, 0]  # arrays and bytes placed, arrays resident
 
             def put(v, sharding):
-                if sp.attrs is not None \
-                        and getattr(v, "sharding", None) != sharding:
-                    moved[0] += 1
-                    moved[1] += int(getattr(v, "nbytes", 0))
+                # the previous call's outputs carry the out_shardings
+                # they are now put to: device_put would hand the same
+                # object back, after its dispatch overhead
+                if isinstance(v, jax.Array) and v.sharding == sharding:
+                    count[2] += 1
+                    return v
+                count[0] += 1
+                count[1] += int(getattr(v, "nbytes", 0))
                 return jax.device_put(v, sharding)
 
+            def put_kept(name, v, sharding):
+                # what no step writes stays in the scope as placed, so
+                # the next call finds it resident
+                placed = put(v, sharding)
+                if placed is not v:
+                    scope.set_var(name, placed)
+                return placed
+
             feeds = [put(v, s) for v, s in zip(feeds, feed_shardings)]
-            const_state = [put(v, plan.state_shardings[n])
+            const_state = [put_kept(n, v, plan.state_shardings[n])
                            for n, v in zip(plan.const_state, const_state)]
             mut_state = [put(v, plan.state_shardings[n])
                          for n, v in zip(plan.mut_state, mut_state)]
-            rng = put(rng, NamedSharding(self.mesh, P()))
+            rng = put_kept(RNG_VAR, rng, NamedSharding(self.mesh, P()))
             if sp.attrs is not None:
-                sp.attrs["arrays"], sp.attrs["bytes"] = moved
+                (sp.attrs["arrays"], sp.attrs["bytes"],
+                 sp.attrs["resident"]) = count
 
         # one executable per jitted fn of a plan: its first dispatch
         # compiles, which the heartbeat tells the watchdog
@@ -278,9 +292,10 @@ class ParallelEngine:
         return merged_ext_rules(self.program, self.mesh, self.rules)
 
     def _gather(self, feed, fetch_list, scope):
-        """Shared run()/lowered_hlo() plumbing: feed conversion, plan
-        cache lookup, state/RNG gathering (host-side values; run() then
-        device_puts them per the plan's shardings) — the
+        """Shared run()/lowered_hlo() plumbing: feed conversion (a host
+        feed stays on the host at its device dtype), plan cache lookup,
+        state/RNG gathering (the scope's own objects; ``_execute`` then
+        places what is not where the plan wants it) — the
         ``executor.gather`` span."""
         with _tr.trace_span("executor.gather"):
             return self._gather_args(feed, fetch_list, scope)
@@ -293,7 +308,7 @@ class ParallelEngine:
         ]
         block = self.program.global_block()
         feed_vals = {
-            n: _feed_to_device(n, v, block.vars.get(n))
+            n: _feed_for_placement(n, v, block.vars.get(n))
             for n, v in feed.items()
         }
         key = self._cache_key(feed_vals, fetch_names)
@@ -404,6 +419,21 @@ def merged_ext_rules(program, mesh, rules: ShardingRules) -> ShardingRules:
         (_re.compile(pat), spec) for pat, spec in ext]
     merged.feed_rules = list(rules.feed_rules)
     return merged
+
+
+def _feed_for_placement(name, val, var):
+    """ONE feed at its on-device dtype, ready for ``_execute`` to place:
+    a ``jax.Array`` as ``_feed_to_device`` leaves it (cast on the device
+    if the dtype differs); anything else converted on the HOST (the
+    int64 range check included) and left there, so its placement is one
+    transfer to the feed's sharding and not one to chip 0 and a scatter
+    from there."""
+    if isinstance(val, jax.Array):
+        return _feed_to_device(name, val, var)
+    arr = _feed_host_array(name, val, var)
+    # the dtype jnp.asarray would give it (x64 is off: 64 bits narrow),
+    # which is what the plan cache keys
+    return arr.astype(jax.dtypes.canonicalize_dtype(arr.dtype), copy=False)
 
 
 def _require(scope, name):

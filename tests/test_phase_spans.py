@@ -235,12 +235,15 @@ def test_parallel_engine_call_holds_the_phases_and_counts_what_moves():
     kids = [e for e in _ended() if e["parent"] == call["span"]]
     assert {e["site"] for e in kids} == PHASES | {"executor.place"}
     (place,) = [e for e in kids if e["site"] == "executor.place"]
-    # the feeds and the state the step wrote are where they belong and
-    # count 0; what still moves every call is what no step ever writes
-    # (the learning rate) and the fresh RNG key of a program without one
+    # the feeds, the state the step wrote, and what no step ever writes
+    # (the learning rate, the RNG key of a program that draws none: the
+    # first call left them in the scope as placed) are where they
+    # belong: nothing moves, every argument is handed through
     assert first["attrs"]["bytes"] >= 8 * 4 * 4 + (4 * 2 + 2) * 4
-    assert place["attrs"]["arrays"] <= len(plan.const_state) + 1
-    assert place["attrs"]["bytes"] <= 4 * len(plan.const_state) + 8
+    assert first["attrs"]["resident"] == 0
+    assert place["attrs"]["arrays"] == 0 and place["attrs"]["bytes"] == 0
+    assert place["attrs"]["resident"] == \
+        len(feed) + len(plan.const_state) + len(plan.mut_state) + 1
     (dispatch,) = [e for e in kids if e["site"] == "executor.dispatch"]
     assert dispatch["attrs"]["plan"] == plan.sig
     order = [e["site"] for e in sorted(kids, key=lambda e: e["t"])]
