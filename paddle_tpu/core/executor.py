@@ -70,9 +70,17 @@ class _Plan:
         #                    Python loop instead of a compiled lax.scan
         self.hlo_text = {}  # stage -> lowered_hlo() text (AOT compiles
         #                     can't reuse the jit cache; amortize them)
-        self.compiled_sigs = set()  # dispatch signatures already compiled:
-        #                    the first dispatch of each lands in the
-        #                    compile-time histogram, not the run histogram
+        self.compiled_sigs = set()  # dispatch signatures dispatched
+        #                    before: what the heartbeat must guess BEFORE
+        #                    the call (a first dispatch compiles: the
+        #                    watchdog's longer grace). What a dispatch did
+        #                    load is the listener's to say (_dispatch_guard)
+        self.loads = {}    # dispatch signature -> dispatches of it in
+        #                    which JAX ran a backend stage (XLA compile or
+        #                    cache load): 2 = the program loaded again
+        self.load_args = {}  # dispatch signature -> how its arguments sat
+        #                    (committed, sharding) at its last load: what a
+        #                    second load is compared with
         self.sig = None   # short hex of the plan-cache key — stamped on
         #                    every dispatch/complete trace span so per-op
         #                    cost attribution falls out of a trace dump
@@ -98,6 +106,7 @@ class Executor:
             raise ValueError("cache_size must be >= 1, got %d" % cache_size)
         self._cache_size = cache_size
         self._cache: "OrderedDict[Tuple, _Plan]" = OrderedDict()
+        _tr.watch_program_loads()
 
     # ------------------------------------------------------------------ run
     def run(
@@ -137,11 +146,12 @@ class Executor:
 
         observe_feed_gap()
         t0 = time.perf_counter()
-        with _dispatch_guard(plan, "run"):
+        with _dispatch_guard(plan, "run", (feeds, const_state, mut_state,
+                                           rng)) as loads:
             fetches, new_mut, new_pure, new_rng = plan.fn(
                 feeds, const_state, mut_state, rng)
         steady = _record_dispatch(plan, "run", "run", 1,
-                                  time.perf_counter() - t0)
+                                  time.perf_counter() - t0, loads)
 
         return self._finish(plan, scope, fetches, new_mut, new_pure,
                             new_rng, return_numpy, "",
@@ -249,11 +259,12 @@ class Executor:
         observe_feed_gap()
         sig = ("run_repeated",) + key
         t0 = time.perf_counter()
-        with _dispatch_guard(plan, sig):
+        with _dispatch_guard(plan, sig, (feeds, const_state, mut_state,
+                                         rng)) as loads:
             fetches, new_mut, new_pure, new_rng = fn(
                 feeds, const_state, mut_state, rng)
         steady = _record_dispatch(plan, sig, "run_repeated",
-                                  steps, time.perf_counter() - t0)
+                                  steps, time.perf_counter() - t0, loads)
         return self._finish(plan, scope, fetches, new_mut, new_pure,
                             new_rng, return_numpy,
                             " after %d scanned steps" % steps,
@@ -469,14 +480,16 @@ class Executor:
                 plan, feed_list, const_state, mut_state, rng = \
                     self._gather(program, feeds, fetch_list, scope)
                 t0 = time.perf_counter()
-                with _dispatch_guard(plan, "run"):
+                with _dispatch_guard(plan, "run",
+                                     (feed_list, const_state, mut_state,
+                                      rng)) as loads:
                     fetches, new_mut, new_pure, new_rng = plan.fn(
                         feed_list, const_state, mut_state, rng)
                 # sig "run": same executable as run(), so a run()
                 # warmup already paid this signature's compile
                 steady = _record_dispatch(plan, "run",
                                           "run_pipelined", 1,
-                                          time.perf_counter() - t0)
+                                          time.perf_counter() - t0, loads)
             # state write-back WITHOUT blocking: the new arrays are
             # futures; the next dispatch chains on them device-side
             _write_back_state(plan, scope, new_mut, new_pure, new_rng)
@@ -515,12 +528,14 @@ class Executor:
                     plan.multi[key] = fn
                 sig = ("run_repeated",) + key
                 t0 = time.perf_counter()
-                with _dispatch_guard(plan, sig):
+                with _dispatch_guard(plan, sig,
+                                     (feed_list, const_state, mut_state,
+                                      rng)) as loads:
                     fetches, new_mut, new_pure, new_rng = fn(
                         feed_list, const_state, mut_state, rng)
                 dt = time.perf_counter() - t0
                 steady = _record_dispatch(plan, sig, "run_pipelined",
-                                          k, dt)
+                                          k, dt, loads)
                 if steady:
                     PIPELINE_WINDOW_SECONDS.labels(
                         phase="dispatch").observe(dt)
@@ -808,10 +823,13 @@ class Executor:
 
             EXECUTOR_CACHE_MISSES.inc()
             t0 = time.perf_counter()
-            plan = self._prepare(program, feed_vals, fetch_names, scope)
             # stable within-process tag for this (program, feed-sig,
             # fetch) plan: the trace spans' per-op attribution key
-            plan.sig = plan_tag(key)
+            sig = plan_tag(key)
+            with _prepare_span(sig, program) as sp:
+                plan = self._prepare(program, feed_vals, fetch_names,
+                                     scope, span=sp)
+            plan.sig = sig
             EXECUTOR_PREPARE_SECONDS.observe(time.perf_counter() - t0)
             self._cache[key] = plan
             while len(self._cache) > self._cache_size:
@@ -858,7 +876,8 @@ class Executor:
         return (program._serial, program.version, _optimizer_config_key(),
                 _kernels.config_key(), sig, tuple(fetch_names))
 
-    def _prepare(self, program: Program, feed_vals, fetch_names, scope) -> _Plan:
+    def _prepare(self, program: Program, feed_vals, fetch_names, scope,
+                 span=_tr.NOOP) -> _Plan:
         from ..analysis import validation_enabled, verify_program
 
         if validation_enabled():
@@ -886,6 +905,8 @@ class Executor:
             # contract (and the config_key load check guarantees the
             # frozen pipeline config matches this process's).
             program = optimize_for_execution(program, fetch_names, scope=scope)
+        if span.attrs is not None:
+            span.attrs["ops_out"] = len(program.global_block().ops)
         feed_names = sorted(feed_vals)
         (feed_names, fetch_names, const_state, mut_state, pure_written,
          needs_rng, step) = analyze_block(program, feed_names, fetch_names, scope)
@@ -928,8 +949,11 @@ class Executor:
                                         EXECUTOR_PREPARE_SECONDS)
 
         t0 = time.perf_counter()
-        plan = self._prepare(program, feed_vals, fetch_names, scope)
-        plan.sig = plan_tag(key)
+        sig = plan_tag(key)
+        with _prepare_span(sig, program) as sp:
+            plan = self._prepare(program, feed_vals, fetch_names, scope,
+                                 span=sp)
+        plan.sig = sig
         EXECUTOR_PREPARE_SECONDS.observe(time.perf_counter() - t0)
         self._cache[key] = plan
         while len(self._cache) > self._cache_size:
@@ -950,6 +974,15 @@ def plan_tag(cache_key) -> str:
     """Stable within-process tag of a plan-cache key: what the
     ``executor.dispatch`` span carries as ``plan``."""
     return "%08x" % (zlib.crc32(repr(cache_key).encode()) & 0xffffffff)
+
+
+def _prepare_span(sig, program):
+    """The ``executor.prepare`` span of one plan-cache miss (or one
+    ``seed_plan``): verification, the pass pipeline and block analysis,
+    parent of the ``optimizer.*`` spans. ``ops_in`` is the program as
+    given; ``_prepare`` adds ``ops_out``, what is left to lower."""
+    return _tr.trace_span("executor.prepare", plan=sig,
+                          ops_in=len(program.global_block().ops))
 
 
 def _call_span(site, steps):
@@ -983,7 +1016,7 @@ def _wait_guard(step=None):
 
 
 @contextlib.contextmanager
-def _dispatch_guard(plan, sig):
+def _dispatch_guard(plan, sig, args=()):
     """Resilience wrapper around ONE XLA dispatch, shared by run()/
     run_repeated()/run_pipelined(): stamps the process heartbeat (with
     ``compiling=True`` for a plan's first dispatch per signature, so
@@ -996,39 +1029,89 @@ def _dispatch_guard(plan, sig):
     BEFORE the fault point for the same reason: a wedged dispatch must
     sit in the flight recorder as an OPEN ``executor.dispatch`` span
     (tagged with the plan signature) when the dump lands. Tracing
-    disabled is one bool check — no span, no allocations."""
+    disabled is one bool check — no span, no allocations.
+
+    Yields the dispatch's ``LoadScope`` (None with tracing off): what
+    JAX traced, lowered, compiled or loaded inside it, as the listener
+    of observe/trace.py saw it. ``args`` are the call's arguments; they
+    are looked at only when a backend stage ran (``_note_load``)."""
     hb = heartbeat()
     tok = hb.begin("executor.dispatch",
                    compiling=sig not in plan.compiled_sigs)
     sp = _tr.trace_span("executor.dispatch", plan=plan.sig) \
         if _tr.trace_enabled() else None
+    loads = None
     if sp is not None:
         sp.__enter__()
+        loads = _tr.open_loads(plan.sig, plan.loads.get(sig, 0) + 1)
     try:
         fault_point("executor.dispatch")
-        yield
+        yield loads
     finally:
+        if loads is not None:
+            _tr.close_loads(loads)
+            if loads.backend:
+                plan.loads[sig] = loads.nth
+                _note_load(plan, sig, args, loads.nth, sp.attrs)
         if sp is not None:
             sp.__exit__(None, None, None)
         hb.end("executor.dispatch", tok)
 
 
-def _record_dispatch(plan, sig, site, steps, dt):
+def _note_load(plan, sig, args, nth, attrs):
+    """A backend stage ran in this dispatch: keep how the arguments sat
+    (committed or not, on which sharding: readable of a donated array
+    too) and, when the program had been loaded before (``nth`` >= 2),
+    put on the dispatch span what differs from the last load —
+    ``uncommitted``: arguments whose committed flag changed (startup
+    arrays are uncommitted, a step's outputs committed), ``resharded``:
+    arguments on another sharding or device. Never runs in a steady
+    dispatch."""
+    now = []
+    for group in args:
+        for a in (group if isinstance(group, (list, tuple)) else (group,)):
+            now.append((bool(getattr(a, "committed", False)),
+                        getattr(a, "sharding", None)))
+    then = plan.load_args.get(sig)
+    plan.load_args[sig] = now
+    if nth < 2:
+        return
+    attrs["nth"] = nth
+    if then is not None and len(then) == len(now):
+        attrs["uncommitted"] = sum(a[0] != b[0] for a, b in zip(then, now))
+        attrs["resharded"] = sum(a[1] != b[1] for a, b in zip(then, now))
+
+
+def _loaded(plan, sig, loads):
+    """Whether the dispatch just made loaded its program: what the
+    listener saw (``loads.stages``), or, with tracing off (``loads`` is
+    None), whether it was the first of its signature. Notes the
+    signature as dispatched either way (the heartbeat's guess for the
+    next one)."""
+    first = sig not in plan.compiled_sigs
+    if first:
+        plan.compiled_sigs.add(sig)
+    return bool(loads.stages) if loads is not None else first
+
+
+def _record_dispatch(plan, sig, site, steps, dt, loads=None):
     """Telemetry shared by run()/run_repeated()/run_pipelined(): count the
-    steps and route the wall time — a plan's FIRST dispatch per signature
-    is dominated by jax trace + XLA compile and lands in the compile
-    histogram; steady-state dispatches land in the run histogram's
+    steps and route the wall time — a dispatch in which JAX traced,
+    lowered, compiled or loaded a program (``loads.stages``: the first
+    of a signature, and any later one that loaded again) lands in the
+    compile histogram; every other lands in the run histogram's
     ``dispatch`` phase (the async hand-off the host actually pays per
-    step). Returns True for a steady-state dispatch so the caller knows
-    whether a matching ``complete`` observation belongs in the run
-    histogram (a compile event's completion would fatten the run tail
-    with compile time)."""
+    step). With tracing off nothing listens (``loads`` is None) and the
+    first dispatch of a signature is taken for the loading one. Returns
+    True for a steady-state dispatch so the caller knows whether a
+    matching ``complete`` observation belongs in the run histogram (a
+    compile event's completion would fatten the run tail with compile
+    time)."""
     from ..observe.families import (EXECUTOR_COMPILE_SECONDS,
                                     EXECUTOR_RUN_SECONDS, EXECUTOR_STEPS)
 
     EXECUTOR_STEPS.inc(steps)
-    if sig not in plan.compiled_sigs:
-        plan.compiled_sigs.add(sig)
+    if _loaded(plan, sig, loads):
         EXECUTOR_COMPILE_SECONDS.observe(dt)
         return False
     EXECUTOR_RUN_SECONDS.labels(site=site, phase="dispatch").observe(dt)

@@ -91,9 +91,19 @@ def enable_compile_cache() -> str:
     The directory is placed from outside: where
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
     directory is set here; otherwise the fixed ``<checkout>/.jax_cache``
-    is used. Programs that took over 2 s to compile are cached."""
+    is used. Programs that took over 2 s to compile are cached.
+
+    It also registers the program-load listener (observe/trace.py
+    "Program loads"), which the first ``Executor`` would register
+    anyway: an entry point's OWN jitted programs (a benchmark drawing
+    its weights on the device) compile before any Executor exists, and
+    are then in the ring as loads with no ``plan``."""
     import jax
 
+    if __package__:   # not when this file is loaded by path, on its own
+        from .observe import trace as _tr
+
+        _tr.watch_program_loads()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           _DEFAULT_COMPILE_CACHE)

@@ -23,17 +23,48 @@ EXECUTOR_CACHE_HITS = REGISTRY.counter(
     "Plan-cache hits in Executor._gather (program+feed-signature key)")
 EXECUTOR_CACHE_MISSES = REGISTRY.counter(
     "paddle_executor_cache_misses_total",
-    "Plan-cache misses (each one costs an analyze_block + jit wrap)")
+    "Plan-cache misses of Executor and ParallelEngine (each one costs "
+    "an analyze_block + jit wrap: the executor.prepare span)")
 EXECUTOR_STEPS = REGISTRY.counter(
     "paddle_executor_steps_total",
     "Train/eval steps executed (run_repeated counts all K scanned steps)")
 EXECUTOR_PREPARE_SECONDS = REGISTRY.histogram(
     "paddle_executor_prepare_seconds",
-    "Wall time of Executor._prepare (block analysis + step trace wrap)")
+    "Wall time of Executor._prepare / ParallelEngine._prepare "
+    "(verification, pass pipeline, block analysis, jit wrap): the "
+    "executor.prepare span's duration")
 EXECUTOR_COMPILE_SECONDS = REGISTRY.histogram(
     "paddle_executor_compile_seconds",
-    "Wall time of the FIRST dispatch of a plan (jax trace + XLA compile "
-    "+ one step); later dispatches land in paddle_executor_run_seconds")
+    "Wall time of a dispatch inside which JAX traced, lowered, compiled "
+    "or loaded a program (the listener of observe/trace.py saw a stage "
+    "end in it), whichever time round: a plan's first dispatch, and "
+    "every later one that loaded its program again. Every other "
+    "dispatch lands in paddle_executor_run_seconds. With "
+    "PADDLE_TPU_TRACE=0 nothing listens and the first dispatch of each "
+    "signature is taken for the loading one")
+PROGRAM_LOADS = REGISTRY.counter(
+    "paddle_executor_program_loads_total",
+    "Backend stages JAX ran (one a program it made executable), by "
+    "where the executable came from: cache='hit' loaded from the "
+    "persistent compilation cache, 'miss' compiled by XLA and written "
+    "to it, 'off' compiled with no cache entry written (cache not "
+    "enabled, or the program under its thresholds); again='1' = this "
+    "(plan, function) had a backend stage before in this process. "
+    "again='1' rising in a serving process is a recompilation in "
+    "production (docs/OBSERVABILITY.md)", labels=("cache", "again"))
+for _c in ("hit", "miss", "off"):
+    for _a in ("0", "1"):
+        PROGRAM_LOADS.labels(cache=_c, again=_a)
+PROGRAM_LOAD_SECONDS = REGISTRY.counter(
+    "paddle_executor_program_load_seconds_total",
+    "Seconds JAX spent making programs executable, by stage: 'trace' "
+    "(function to jaxpr; a trace nested in another's is counted once), "
+    "'lower' (jaxpr to MLIR module), 'backend' (XLA compile or "
+    "persistent-cache load). A restarted replica's cold start by stage; "
+    "the ring's executor.load.* spans say which program",
+    labels=("stage",))
+for _s in ("trace", "lower", "backend"):
+    PROGRAM_LOAD_SECONDS.labels(stage=_s)
 EXECUTOR_RUN_SECONDS = REGISTRY.histogram(
     "paddle_executor_run_seconds",
     "Steady-state step latency, split by phase: 'dispatch' is the async "
@@ -732,8 +763,9 @@ IMPERATIVE_CAPTURES = REGISTRY.counter(
 IMPERATIVE_CAPTURE_SECONDS = REGISTRY.histogram(
     "paddle_imperative_capture_seconds",
     "Wall time of ONE capture: the eager trace, Program construction "
-    "and capture-time verification (excludes the replay-side XLA "
-    "compile, which lands in paddle_executor_compile_seconds)")
+    "and capture-time verification (excludes the replay-side trace "
+    "and XLA compile: the replay's loading dispatch lands in "
+    "paddle_executor_compile_seconds)")
 IMPERATIVE_CAPTURED_OPS = REGISTRY.histogram(
     "paddle_imperative_captured_ops",
     "Ops per captured Program block (forward + captured backward + "
@@ -1058,6 +1090,15 @@ TRACE_SITES = (
     # is still unnamed
     "executor.call", "executor.gather", "executor.h2d", "executor.place",
     "executor.dispatch", "executor.complete", "executor.write_back",
+    # set-up (observe/trace.py "Program loads"): executor.prepare is the
+    # plan-cache miss path (verification, the pass pipeline, block
+    # analysis; parent of the optimizer.* spans), in executor.gather or
+    # under seed_plan. executor.load.* are retroactive spans of JAX's
+    # own stages, children of the executor.dispatch that caused them
+    # (attrs fun, plan; on .backend also cache, nth, retrieval_s). A
+    # steady dispatch has none of the four
+    "executor.prepare", "executor.load.trace", "executor.load.lower",
+    "executor.load.backend",
     # pipelined input (core/pipeline.py): fill-thread spans under the
     # loop context handed off explicitly by run_pipelined
     "pipeline.prefetch", "pipeline.const_lookup",
@@ -1072,6 +1113,9 @@ TRACE_SITES = (
     "serving.engine.step", "serving.engine.spec",
     "serving.engine.feeds", "serving.engine.sample",
     "serving.engine.retire", "serving.request.first_token",
+    # the engine's own set-up: one build span a program constructed
+    # (attrs program, P, ops), one load_params a lane's given weights
+    "serving.engine.build", "serving.engine.load_params",
     "serving.router.route", "serving.router.drain",
     "serving.router.readmit",
     # rpc (distributed/rpc.py): client call spans; server events linked

@@ -30,6 +30,20 @@ it died*. Two pieces:
   chrome-trace; ``export_chrome_trace()`` writes the ring, which holds a
   profiling session's ``RecordEvent`` markers too (they are spans).
 
+* **Program loads** — ``watch_program_loads()`` (called by the first
+  ``Executor``/``ParallelEngine``, and by ``flags.enable_compile_cache``
+  for an entry point's own programs) registers ONE ``jax.monitoring``
+  listener. JAX calls it on the thread that ran a stage, at the stage's
+  end, and it records a retroactive span for each: ``executor.load.trace``
+  (a function traced to a jaxpr), ``executor.load.lower`` (jaxpr to an
+  MLIR module), ``executor.load.backend`` (XLA compile, or a load from
+  the persistent cache: ``cache`` says which). Recorded under the
+  thread's current context they nest in the ``executor.dispatch`` that
+  caused them and carry its ``plan``; a load outside any dispatch has no
+  ``plan``. ``nth`` on a backend span says which loading dispatch of its
+  plan signature this is: 2 means the plan loaded its program again.
+  Nothing here runs in a steady dispatch.
+
 * **The profiler's clock** — a span is also a
   ``jax.profiler.TraceAnnotation`` of its site name. Whenever anyone is
   taking a ``jax.profiler`` trace (``jax.profiler.trace``,
@@ -75,13 +89,15 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from .families import TRACE_DUMPS, TRACE_SITES  # noqa: F401
+from .families import (PROGRAM_LOAD_SECONDS, PROGRAM_LOADS,  # noqa: F401
+                       TRACE_DUMPS, TRACE_SITES)
 
 __all__ = ["TraceContext", "FlightRecorder", "NOOP", "trace_enabled",
            "set_trace_enabled", "new_trace", "current", "attach",
            "trace_span", "trace_event", "record_span", "recorder",
            "dump_flight_recorder", "export_chrome_trace",
-           "wire_metadata", "from_wire"]
+           "wire_metadata", "from_wire", "watch_program_loads",
+           "open_loads", "close_loads"]
 
 ENV_TRACE = "PADDLE_TPU_TRACE"
 ENV_PATH = "PADDLE_TPU_FLIGHT_RECORDER_PATH"
@@ -416,6 +432,148 @@ def record_span(site: str, t0: float, dur: float, /,
                     dur=dur, attrs=a)
 
 
+# -------------------------------------------------------- program loads
+_JAX = "/jax/core/compile/"
+# JAX's duration event -> (span site, the counter's ``stage`` label)
+_LOAD_STAGES = {
+    _JAX + "jaxpr_trace_duration": ("executor.load.trace", "trace"),
+    _JAX + "jaxpr_to_mlir_module_duration": ("executor.load.lower",
+                                             "lower"),
+    _JAX + "backend_compile_duration": ("executor.load.backend",
+                                        "backend"),
+}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# jnp's own helpers (add, where, _mean ...) are jitted functions, traced
+# once a signature INSIDE a program's trace: some 10,000 events of a few
+# microseconds each in a 12-layer BERT step, 0.2 s in all, which would
+# turn the ring over five times. A trace shorter than this is neither
+# recorded nor counted; the outer trace's span holds its time
+_TRACE_FLOOR_S = 1e-3
+_WATCHING = False
+_LOAD_LOCK = threading.Lock()
+
+
+class LoadScope:
+    """What JAX loaded inside one ``executor.dispatch``: ``stages``
+    counts the trace/lower/backend stages that ended in it (0 = a steady
+    dispatch), ``backend`` the backend stages among them. ``nth`` is
+    given by the opener: which loading dispatch of its plan signature
+    this one is if a backend stage runs in it (2 = the plan loads its
+    program again); every backend span of the dispatch carries it."""
+
+    __slots__ = ("plan", "nth", "stages", "backend", "_prev")
+
+    def __init__(self, plan, nth):
+        self.plan = plan
+        self.nth = nth
+        self.stages = self.backend = 0
+
+
+def open_loads(plan: Optional[str], nth: int = 1) -> Optional[LoadScope]:
+    """Begin the dispatch's load scope on this thread (None while
+    tracing is off: nothing listens, so nothing could be told)."""
+    if not _ON:
+        return None
+    scope = LoadScope(plan, nth)
+    scope._prev = getattr(_tls, "loads", None)
+    _tls.loads = scope
+    # a dispatch starts with no trace open on its thread: what the
+    # nesting ledger holds can no longer be inside anything
+    done = getattr(_tls, "traced", None)
+    if done:
+        done.clear()
+    return scope
+
+
+def close_loads(scope: LoadScope) -> None:
+    _tls.loads = scope._prev
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is not None and _ON:
+        _tls.cache = kind
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if not _ON:
+        return
+    stage = _LOAD_STAGES.get(event)
+    if stage is None:
+        if event == _CACHE_RETRIEVAL:
+            _tls.retrieval = duration
+        return
+    site, label = stage
+    if label == "trace" and duration < _TRACE_FLOOR_S:
+        return
+    # JAX times a stage on the wall clock, which can step back; a
+    # listener that raised would fail the compile it watches
+    duration = max(duration, 0.0)
+    t0 = time.perf_counter() - duration
+    scope = getattr(_tls, "loads", None)
+    attrs = {"fun": str(kw.get("fun_name"))}
+    if scope is not None:
+        scope.stages += 1
+        if scope.plan is not None:
+            attrs["plan"] = scope.plan
+    counted = duration
+    if label == "trace":
+        # JAX reports a function traced inside another's trace on its
+        # own AND inside the outer duration. The spans keep both (they
+        # nest in time); the counter takes each second once: this
+        # trace's duration less the recorded traces that began inside it
+        done = getattr(_tls, "traced", None)
+        if done is None:
+            done = _tls.traced = []
+        while done and done[-1][0] >= t0 - 5e-5:
+            counted -= done.pop()[1]
+        done.append((t0, duration))
+        if len(done) > 1024:
+            del done[:512]
+        counted = max(counted, 0.0)
+    elif label == "backend":
+        attrs["cache"] = cache = getattr(_tls, "cache", None) or "off"
+        retrieval = getattr(_tls, "retrieval", None)
+        _tls.cache = _tls.retrieval = None
+        if retrieval is not None:
+            attrs["retrieval_s"] = retrieval
+        again = "0"
+        if scope is not None:
+            # a program loaded by nobody's plan has no ``nth``: there
+            # is nothing it could be the second load of
+            scope.backend += 1
+            attrs["nth"] = scope.nth
+            again = "1" if scope.nth >= 2 else "0"
+        PROGRAM_LOADS.labels(cache=cache, again=again).inc()
+    PROGRAM_LOAD_SECONDS.labels(stage=label).inc(counted)
+    record_span(site, t0, duration, **attrs)
+
+
+def watch_program_loads() -> bool:
+    """Register the program-load listener with ``jax.monitoring``, once
+    a process (idempotent; the first ``Executor``/``ParallelEngine``
+    and ``flags.enable_compile_cache`` call it, so importing ``observe``
+    never imports jax). With tracing off nothing is registered; a later
+    call with tracing on registers. Returns whether the listener is
+    registered."""
+    global _WATCHING
+    if _WATCHING or not _ON:
+        return _WATCHING
+    with _LOAD_LOCK:
+        if _WATCHING:
+            return True
+        try:
+            from jax import monitoring
+        except Exception:  # noqa: BLE001 — observe works without jax
+            return False
+        monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _WATCHING = True
+    return True
+
+
 # -------------------------------------------------------- wire metadata
 # serialized context for message-riding propagation (RPC name suffix);
 # kept dense and separator-free so any framed string field can carry it
@@ -538,3 +696,4 @@ def _reset() -> None:
     RECORDER.clear()
     _CRITICAL_DUMPED = False
     _tls.ctx = None
+    _tls.loads = None

@@ -25,8 +25,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.executor import (RNG_VAR, Executor, _call_span,
                              _dispatch_guard, _feed_host_array,
-                             _feed_to_device, analyze_block, make_scan_fn,
-                             plan_tag, unstack_singleton_feed,
+                             _feed_to_device, _loaded, _prepare_span,
+                             analyze_block,
+                             make_scan_fn, plan_tag, unstack_singleton_feed,
                              validate_stacked_feeds)
 from ..core.program import Program, Variable
 from ..core.scope import Scope, global_scope
@@ -64,10 +65,14 @@ class _ParallelPlan:
         self.multi = {}    # (steps, feed_stacked) -> jitted K-step fn
         self.feed_shapes = {}  # name -> shape the plan was prepared with
         # what Executor's _dispatch_guard reads of a plan: the tag the
-        # executor.dispatch span carries, and the signatures dispatched
-        # before (a first dispatch compiles: the watchdog's longer grace)
+        # executor.dispatch span carries, the signatures dispatched
+        # before (a first dispatch compiles: the watchdog's longer grace),
+        # its loading dispatches so far and how its arguments sat at the
+        # last of them
         self.sig = None
         self.compiled_sigs = set()
+        self.loads = {}
+        self.load_args = {}
 
 
 class ParallelEngine:
@@ -88,6 +93,7 @@ class ParallelEngine:
         from ..observe.families import ENGINE_DEVICES
 
         ENGINE_DEVICES.set(self.device_count)
+        _tr.watch_program_loads()
 
     @property
     def device_count(self) -> int:
@@ -202,7 +208,9 @@ class ParallelEngine:
         has."""
         from ..observe import observe_feed_gap
         from ..observe.families import (ENGINE_DISPATCHES,
-                                        ENGINE_RUN_SECONDS, EXECUTOR_STEPS)
+                                        ENGINE_RUN_SECONDS,
+                                        EXECUTOR_COMPILE_SECONDS,
+                                        EXECUTOR_STEPS)
 
         observe_feed_gap()
         site = "run_repeated" if steps > 1 else "run"
@@ -244,12 +252,17 @@ class ParallelEngine:
         # one executable per jitted fn of a plan: its first dispatch
         # compiles, which the heartbeat tells the watchdog
         sig = (site, fn)
-        with _dispatch_guard(plan, sig):
+        t_call = time.perf_counter()
+        with _dispatch_guard(plan, sig, (feeds, const_state, mut_state,
+                                         rng)) as loads:
             fetches, new_mut, new_pure, new_rng = fn(
                 feeds, const_state, mut_state, rng)
-        plan.compiled_sigs.add(sig)
-        ENGINE_RUN_SECONDS.labels(site=site).observe(
-            time.perf_counter() - t_dispatch)
+        t_done = time.perf_counter()
+        # a dispatch that loaded its program is a compile-time sample,
+        # as on the one-chip path
+        if _loaded(plan, sig, loads):
+            EXECUTOR_COMPILE_SECONDS.observe(t_done - t_call)
+        ENGINE_RUN_SECONDS.labels(site=site).observe(t_done - t_dispatch)
         return Executor._finish(plan, scope, fetches, new_mut, new_pure,
                                 new_rng, return_numpy, nan_suffix)
 
@@ -314,8 +327,20 @@ class ParallelEngine:
         key = self._cache_key(feed_vals, fetch_names)
         plan = self._cache.get(key)
         if plan is None:
-            plan = self._prepare(feed_vals, fetch_names, scope)
-            plan.sig = plan_tag(key)
+            from ..observe.families import (EXECUTOR_CACHE_MISSES,
+                                            EXECUTOR_PREPARE_SECONDS)
+
+            EXECUTOR_CACHE_MISSES.inc()
+            t0 = time.perf_counter()
+            sig = plan_tag(key)
+            with _prepare_span(sig, self.program) as sp:
+                plan = self._prepare(feed_vals, fetch_names, scope)
+                if sp.attrs is not None:
+                    # the mesh engine runs no pass pipeline: the block
+                    # is lowered as given
+                    sp.attrs["ops_out"] = sp.attrs["ops_in"]
+            plan.sig = sig
+            EXECUTOR_PREPARE_SECONDS.observe(time.perf_counter() - t0)
             self._cache[key] = plan
         feeds = [feed_vals[n] for n in plan.feed_names]
         const_state = [_require(scope, n) for n in plan.const_state]

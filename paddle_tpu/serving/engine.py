@@ -159,10 +159,12 @@ class _Lane:
     mirrors slot i of the target."""
 
     def __init__(self, fluid, exe, cfg, b_max, max_len, params,
-                 scope_guard, gpt, mark=None):
+                 scope_guard, gpt, mark=None, role=""):
         from ..core.scope import Scope
 
         self._fluid, self._exe, self._gpt = fluid, exe, gpt
+        self._role = role   # "draft_" on the draft lane: the prefix of
+        #                     its serving.engine.build spans' ``program``
         self._scope_guard = scope_guard
         self._mark = mark if mark is not None else _null_mark
         self._warm: set = set()   # (program id, fetch) dispatched once
@@ -176,7 +178,8 @@ class _Lane:
         self._decode_prog = fluid.Program()
         dec_start = fluid.Program()
         with scope_guard(self.scope):
-            with fluid.program_guard(self._decode_prog, dec_start):
+            with self._build_span("decode", self._decode_prog), \
+                    fluid.program_guard(self._decode_prog, dec_start):
                 self._logits, self.cache_names = \
                     gpt.build_serving_decode_step(
                         cfg, batch=b_max, max_len=max_len)
@@ -184,8 +187,16 @@ class _Lane:
             given = {n: v for n, v in (params or {}).items()
                      if n in declared}
             self._run_startup(dec_start, self.scope, given.__contains__)
-            for n, v in given.items():
-                self.scope.set_var(n, v)
+            with _tr.trace_span("serving.engine.load_params") as sp:
+                for n, v in given.items():
+                    self.scope.set_var(n, v)
+                if sp.attrs is not None:
+                    sp.attrs["arrays"] = len(given)
+                    sp.attrs["bytes"] = sum(
+                        int(getattr(v, "nbytes", 0)) for v in given.values())
+                    sp.attrs["dtype"] = ",".join(sorted(
+                        {str(getattr(v, "dtype", "?"))
+                         for v in given.values()}))
         self._note_cache_bytes()
         self._note_weight_bytes()
         import jax
@@ -252,6 +263,17 @@ class _Lane:
                                                       warn=False)
         for dtype, nbytes in held.items():
             SERVING_WEIGHT_BYTES.labels(dtype=dtype).set(nbytes)
+
+    @contextlib.contextmanager
+    def _build_span(self, program, prog, **attrs):
+        """``serving.engine.build`` round one program's construction
+        (the IR only; its startup run is an ``executor.call``):
+        ``program`` names which, ``ops`` what came of it."""
+        with _tr.trace_span("serving.engine.build",
+                            program=self._role + program, **attrs) as sp:
+            yield
+            if sp.attrs is not None:
+                sp.attrs["ops"] = len(prog.global_block().ops)
 
     def _run_startup(self, start, scope, supplied) -> None:
         """Run a copy of a startup program without its initialisers of
@@ -416,7 +438,8 @@ class _Lane:
         fluid = self._fluid
         prog, start = fluid.Program(), fluid.Program()
         with self._scope_guard(self._prefill_scope):
-            with _BUILD_LOCK, fluid.program_guard(prog, start):
+            with self._build_span("prefill", prog, P=P), \
+                    _BUILD_LOCK, fluid.program_guard(prog, start):
                 self._gpt.build_prefill_step(
                     self.cfg, batch=1, prompt_len=P, max_len=self.max_len)
             self._run_startup(
@@ -470,7 +493,8 @@ class _Lane:
         fluid = self._fluid
         prog, start = fluid.Program(), fluid.Program()
         with self._scope_guard(scope):
-            with _BUILD_LOCK, fluid.program_guard(prog, start):
+            with self._build_span("multi", prog, P=S), \
+                    _BUILD_LOCK, fluid.program_guard(prog, start):
                 logits_var, _ = self._gpt.build_multi_token_decode_step(
                     self.cfg, batch=batch, steps=S, max_len=self.max_len)
         scratch = Scope()
@@ -525,7 +549,8 @@ class _Lane:
         def extra(P: int) -> int:
             fluid = self._fluid
             prog, start = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, start):
+            with self._build_span("footprint", prog, P=P), \
+                    fluid.program_guard(prog, start):
                 # IR only: no startup run, no compile — the analysis
                 # walks the graph, the throwaway programs are dropped
                 self._gpt.build_prefill_step(
@@ -641,7 +666,7 @@ class DecodeEngine:
         if draft_cfg is not None and self.spec_k >= 1:
             self._draft = _Lane(fluid, self._exe, dict(draft_cfg), b_max,
                                 self.max_len, draft_params, scope_guard,
-                                gpt, mark=self._busy_mark)
+                                gpt, mark=self._busy_mark, role="draft_")
         if prefix_store is None and prefix_cache_bytes > 0:
             prefix_store = PrefixStore(prefix_cache_bytes)
         self.prefix_store = prefix_store

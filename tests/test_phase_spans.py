@@ -140,8 +140,12 @@ def test_trace_off_records_nothing_and_makes_no_annotation(monkeypatch):
         trace.set_trace_enabled(prior)
     with scope_guard(scope):
         exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-    # on again: one annotation a span, none for retroactive spans
-    assert sorted(made) == sorted(e["site"] for e in _ended())
+    # on again: one annotation a span, none for retroactive spans (the
+    # main program's first dispatch loads it: executor.load.*)
+    assert sorted(made) == sorted(
+        e["site"] for e in _ended()
+        if not e["site"].startswith("executor.load."))
+    assert any(e["site"] == "executor.load.backend" for e in _ended())
     assert "executor.call" in made
 
 
@@ -203,7 +207,10 @@ def test_one_dispatch_body(path, capsys):
         ids = {e["span"]: e["site"] for e in _ended()}
         spans = [(e["site"], ids.get(e["parent"]),
                   e["attrs"] if e["site"] == "executor.call" else None)
-                 for e in trace.recorder().events() if e["ph"] == "B"]
+                 for e in trace.recorder().events() if e["ph"] == "B"
+                 # which stages JAX runs again depends on what its
+                 # in-memory caches hold from the other pair of calls
+                 and not e["site"].startswith("executor.load.")]
         return spans, fetched
 
     plain, plain_out = two_calls(False)

@@ -16,7 +16,10 @@ reason, the recorded wedge/fault context, and every OPEN span (a ``B``
 with no matching ``E`` — the operation that never returned), each with
 its trace id, site, tags and how long it had been open when the dump
 landed. Then per-site span counts/totals, so "where did the time go"
-falls out of the same file.
+falls out of the same file, and the "program loads" table: one row a
+dispatch in which JAX traced, lowered and compiled or loaded a program
+(plan, function, ``nth`` = which load of the plan's signature, cache
+hit/miss/off, seconds a stage, and why a plan loaded AGAIN).
 
 ``--validate`` holds the dump to the recorder's own grammar: every
 ``E`` has a matching ``B``, durations are non-negative and consistent
@@ -61,6 +64,86 @@ def open_spans(dump: dict):
     return out
 
 
+_LOAD = "executor.load."
+
+
+def _covered(spans) -> float:
+    """Seconds the spans' intervals cover, each second once (a function
+    traced inside another's trace is a span inside the outer span)."""
+    total, end = 0.0, None
+    for a, b in sorted((e["t"] - e["dur"], e["t"]) for e in spans):
+        if end is None or a > end:
+            total, end = total + b - a, b
+        elif b > end:
+            total, end = total + b - end, b
+    return total
+
+
+def program_loads(dump: dict):
+    """One row a dispatch in which JAX made a program executable, oldest
+    first: the plan, JAX's name of the program, which loading dispatch
+    of the plan's signature it was (``nth``; 2 = loaded AGAIN), where
+    the executable came from (``cache``), the seconds of each stage, and
+    for a second load what the dispatch span says differed
+    (``uncommitted``/``resharded`` arguments). Programs loaded outside
+    any dispatch (no plan: eager helpers, a benchmark's own jits) are
+    one row a function, at the end."""
+    ends = [e for e in dump["events"]
+            if e["ph"] == "E" and e.get("dur") is not None]
+    dispatch = {e["span"]: e for e in ends
+                if e["site"] == "executor.dispatch"}
+    groups, loose = {}, {}
+    for e in ends:
+        if not e["site"].startswith(_LOAD):
+            continue
+        if e.get("parent") in dispatch:
+            groups.setdefault(e["parent"], []).append(e)
+        else:
+            fun = (e["attrs"] or {}).get("fun", "?")
+            if fun.startswith("jit(") and fun.endswith(")"):
+                fun = fun[4:-1]   # the trace stage says f, the others jit(f)
+            loose.setdefault(fun, []).append(e)
+    rows = []
+    for key, stages in list(groups.items()) + list(loose.items()):
+        back = [e for e in stages if e["site"] == _LOAD + "backend"]
+        if not back:
+            continue   # traced or lowered only (lowered_hlo, a cached jit)
+        last = back[-1]["attrs"] or {}
+        why = dispatch.get(key, {}).get("attrs") or {}
+        rows.append({
+            "t": min(e["t"] - e["dur"] for e in stages),
+            "plan": last.get("plan", "-"),
+            "fun": last.get("fun", "?"), "loads": len(back),
+            "nth": last.get("nth", "-"),
+            "cache": "/".join(sorted({(e["attrs"] or {}).get("cache", "?")
+                                      for e in back})),
+            "trace_s": _covered([e for e in stages
+                                 if e["site"] == _LOAD + "trace"]),
+            "lower_s": _covered([e for e in stages
+                                 if e["site"] == _LOAD + "lower"]),
+            "backend_s": _covered(back),
+            "why": " ".join("%s=%s" % (k, why[k]) for k in
+                            ("uncommitted", "resharded") if k in why)})
+    rows.sort(key=lambda r: (r["plan"] == "-", r["t"]))
+    return rows
+
+
+def print_program_loads(dump: dict, out=sys.stdout) -> None:
+    rows = program_loads(dump)
+    if not rows:
+        return
+    print("\nprogram loads (JAX trace / lower / backend stages, "
+          "observe/trace.py):", file=out)
+    print("%-9s %-28s %5s %4s %-8s %9s %9s %10s  %s"
+          % ("plan", "fun", "loads", "nth", "cache", "trace(s)",
+             "lower(s)", "backend(s)", "why again"), file=out)
+    for r in rows:
+        print("%-9s %-28s %5d %4s %-8s %9.3f %9.3f %10.3f  %s"
+              % (r["plan"], r["fun"][:28], r["loads"], r["nth"],
+                 r["cache"], r["trace_s"], r["lower_s"], r["backend_s"],
+                 r["why"]), file=out)
+
+
 def summarize(dump: dict, out=sys.stdout) -> None:
     evs = dump["events"]
     print("flight recorder dump: pid=%s reason=%s events=%d "
@@ -100,6 +183,7 @@ def summarize(dump: dict, out=sys.stdout) -> None:
         print("\n%-24s %8s" % ("instant site", "count"), file=out)
         for site in sorted(instants):
             print("%-24s %8d" % (site, instants[site]), file=out)
+    print_program_loads(dump, out)
     traces = {e["trace"] for e in evs}
     print("\n%d distinct trace(s)" % len(traces), file=out)
 
