@@ -993,6 +993,16 @@ FLASH_BLOCK_PLANS = REGISTRY.counter(
     "compiled step holds (ops/attention.py _block_plan)",
     labels=("kernel", "block", "single_pass"))
 
+DROPOUT_MASK_PLANS = REGISTRY.counter(
+    "paddle_dropout_mask_plans_total",
+    "Dropout keep masks lowered, by the op that draws (site 'dropout' or "
+    "'fused_attention') and where the bits come from ('rbg_u32': one "
+    "32-bit RngBitGenerator draw an element, compared as integers, the "
+    "mask saved for the grad op). Counted at LOWERING time like "
+    "paddle_flash_block_plans_total: a compiled bert-base train step "
+    "holds 37 (ops/random_mask.py keep_mask)",
+    labels=("site", "bits"))
+
 MOE_GMM_PLANS = REGISTRY.counter(
     "paddle_moe_gmm_plans_total",
     "Grouped-matmul calls of the expert layer lowered, by kernel name "

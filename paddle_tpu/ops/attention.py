@@ -96,6 +96,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.registry import register_grad_lowering, register_op
 from ..kernels.common import (assert_mosaic_ok, ceil_to, checked_pallas_call,
                               pad_axis, pad_len, use_interpret)
+from .random_mask import keep_mask
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "pallas_mode",
            "fused_attention_enabled", "flash_min_seq", "flash_effective",
@@ -1362,8 +1363,8 @@ def _fused_attention(ctx, ins, attrs):
         # The mask is a saved output so the grad op can replay it without
         # RNG (same pattern as the dropout op, ops/nn.py).
         keep = 1.0 - dropout
-        mask = jax.random.bernoulli(
-            ctx.next_rng(), keep, out.shape).astype(out.dtype) / keep
+        mask = keep_mask(ctx, ctx.next_rng(), keep, out.shape,
+                         "fused_attention").astype(out.dtype) / keep
     else:
         mask = jnp.ones_like(out)
     return {"Out": [out * mask], "Mask": [mask]}

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.registry import register_grad_lowering, register_op
+from .random_mask import keep_mask
 
 
 def _pair(v):
@@ -414,7 +415,7 @@ def _dropout(ctx, ins, attrs):
         return {"Out": [x * (1.0 - p)], "Mask": [jnp.ones_like(x)]}
     seed = attrs.get("seed", 0)
     key = jax.random.PRNGKey(seed) if attrs.get("fix_seed", False) else ctx.next_rng()
-    keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
+    keep = keep_mask(ctx, key, 1.0 - p, x.shape, "dropout")
     if impl == "upscale_in_train":
         mask = keep.astype(x.dtype) / max(1.0 - p, 1e-8)
     else:

@@ -30,13 +30,12 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
 
 # ------------------------------------------------ (a) compiles for the v5e
 @pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one chip of a described v5e 2x2; the persistent compile
-    cache is off around these compiles (an entry written for a described
-    chip cannot be read back without one)."""
+def v5e_topology():
+    """A described v5e 2x2; the persistent compile cache is off around
+    these compiles (an entry written for a described chip cannot be read
+    back without one)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     # another process describing the chip must not lock this one out
@@ -49,9 +48,17 @@ def v5e():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_topology):
+    """Sharding on one chip of the described v5e 2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_topology.devices[0])
 
 
 @pytest.fixture
@@ -220,9 +227,11 @@ def test_kv_cache_write_compiles_in_place_for_v5e(d_head, v5e,
     assert _cache_sized(text, shape) == []
 
 
-def _lower_step(main, feeds, fetch, dev):
+def _lower_step(main, feeds, fetch, dev, rng=False):
     """Lower one program's step for the described chip from shapes alone
-    (nothing runs). Returns (lowered, names of the donated state)."""
+    (nothing runs). ``feeds`` maps a name to its shape (int32) or to
+    (shape, dtype); ``rng`` hands the step a key, which a training program
+    that draws needs. Returns (lowered, names of the donated state)."""
     from paddle_tpu.core.executor import analyze_block
 
     class _Initialised:                 # nothing is run: shapes only
@@ -238,14 +247,21 @@ def _lower_step(main, feeds, fetch, dev):
         return jax.ShapeDtypeStruct(tuple(var.shape), jnp.dtype(var.dtype),
                                     sharding=dev)
 
-    def fn(feed_vals, const_vals, mut_vals):
-        fetches, new_mut, _, _ = step(feed_vals, const_vals, mut_vals, None)
-        return fetches, new_mut
+    def feed_sds(name):
+        shape, dtype = feeds[name] if isinstance(feeds[name][0], tuple) \
+            else (feeds[name], jnp.int32)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
 
+    def fn(feed_vals, const_vals, mut_vals, key=None):
+        fetches, new_mut, _, new_key = step(feed_vals, const_vals, mut_vals,
+                                            key)
+        return fetches, new_mut, new_key
+
+    key = (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=dev),) \
+        if rng else ()
     lowered = jax.jit(fn, donate_argnums=(2,)).lower(
-        [jax.ShapeDtypeStruct(feeds[n], jnp.int32, sharding=dev)
-         for n in feed_names],
-        [sds(n) for n in const_state], [sds(n) for n in mut_state])
+        [feed_sds(n) for n in feed_names],
+        [sds(n) for n in const_state], [sds(n) for n in mut_state], *key)
     return lowered, mut_state
 
 
@@ -675,6 +691,113 @@ def test_cpu_requested_reads_jax_platforms(monkeypatch, platforms, want):
 
     monkeypatch.setattr(jax, "config", _Cfg)
     assert place._cpu_requested() is want
+
+
+# ------------------------------- (a) the train step's dropout masks, PR 35
+BERT_CELLS = {
+    # cell: (seq, batch, masks, Pallas calls the traffic file expects)
+    "bert_train_s512": (512, 32, 80, 48),
+    "bert_train_s128": (128, 128, 20, 0),
+}
+
+
+def _mask_sized(text, op, least=1 << 20):
+    """Instructions ``op`` of the module whose result is a u32 array of at
+    least ``least`` elements (a dropout mask's bits; nothing else in the
+    step is u32 and that large)."""
+    import re
+
+    found = []
+    for m in re.finditer(r"= \(?u32\[([\d,]+)\]\S*(?:, [^)]*\))? %s\(" % op,
+                         text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if int(np.prod(dims)) >= least:
+            found.append(tuple(dims))
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(BERT_CELLS))
+def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
+                                                      compiled_kernels,
+                                                      monkeypatch):
+    """The ``bert-base`` train step as ``benchmarks/lib/train_loop.py``
+    builds it (``bert.build`` + ``Adam.minimize`` + bf16 AMP), compiled for
+    the described chip: one ``rng-bit-generator`` a dropout (37), no
+    threefry over a mask (its rounds are ``shift-right-logical`` on the
+    mask's u32 bits, which XLA cloned into every consumer: PERF.md
+    section 6, PR 35), the cell's count of Pallas calls, and the plan
+    counter reads 37."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import bert
+    from paddle_tpu.observe.families import DROPOUT_MASK_PLANS
+
+    seq, batch, masks, n_calls = BERT_CELLS[cell]
+    # the cells run under the static threshold (composed attention below
+    # S 256), not under the suite's "always the kernel"
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "bert-base.json")) as f:
+        conf = json.load(f)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        loss, _feeds = bert.build(dict(conf["model"]), seq_len=seq,
+                                  max_mask=masks)
+        fluid.optimizer.Adam(
+            learning_rate=conf["train"]["learning_rate"]).minimize(loss)
+    main.set_amp(conf["train"]["amp"] == "bf16")
+    plans = {site: DROPOUT_MASK_PLANS.labels(site=site, bits="rbg_u32")
+             for site in ("dropout", "fused_attention")}
+    before = {site: c.value for site, c in plans.items()}
+    feeds = {"src_ids": (batch, seq), "sent_ids": (batch, seq),
+             "input_mask": ((batch, seq), jnp.float32),
+             "mask_pos": (batch, masks), "mask_label": (batch, masks),
+             "mask_weight": ((batch, masks), jnp.float32)}
+    lowered, _ = _lower_step(main, feeds, loss.name, v5e, rng=True)
+    n_layer = conf["model"]["n_layer"]
+    assert {s: c.value - before[s] for s, c in plans.items()} == {
+        "dropout": 2 * n_layer + 1, "fused_attention": n_layer}
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    drawn = _mask_sized(text, "rng-bit-generator")
+    assert len(drawn) == 3 * n_layer + 1 == 37
+    assert {int(np.prod(d)) for d in drawn} == {batch * seq * 768}
+    assert text.count(" rng-bit-generator(") == len(drawn)
+    assert _mask_sized(text, "shift-right-logical") == []
+
+
+def test_dropout_mask_is_drawn_per_shard_on_the_v5e_mesh(v5e_topology):
+    """The dropout op over the four chips of the described 2x2, operand
+    ``[128 * 512, 768]`` bf16 sharded on the data axis as ParallelEngine
+    jits a step: SPMD cannot partition ``rng-bit-generator`` (a draw of
+    the global shape comes out whole on every chip, then sliced), so the
+    lowering draws inside a ``shard_map``. Every generator call holds one
+    chip's 16,384 rows and no mask-sized u32 is sliced."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    mesh = Mesh(np.array(v5e_topology.devices).reshape(4, 1),
+                ("data", "model"))
+    rows, width = 128 * 512, 768
+
+    def fwd(x, key):
+        ctx = LowerContext(rng=key, mesh=mesh)
+        outs = get_op("dropout").lowering(
+            ctx, {"X": [x]}, {"dropout_prob": 0.1,
+                              "dropout_implementation": "upscale_in_train"})
+        return outs["Out"][0], outs["Mask"][0]
+
+    data, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    with mesh:
+        text = jax.jit(fwd, out_shardings=(data, data)).lower(
+            jax.ShapeDtypeStruct((rows, width), BF16, sharding=data),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+        ).compile().as_text()
+    assert _mask_sized(text, "rng-bit-generator") == [(rows // 4, width)]
+    assert text.count(" rng-bit-generator(") == 1
+    assert _mask_sized(text, "dynamic-slice") == []
+    assert _mask_sized(text, "shift-right-logical") == []
 
 
 # --------------------------------- what the bring-up found on the way

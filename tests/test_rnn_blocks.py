@@ -96,9 +96,12 @@ def test_static_rnn_with_dropout_trains(fresh_programs):
     exe.run(startup, scope=scope)
     xs = np.random.RandomState(4).randn(T, B, D).astype("float32")
     losses = [float(exe.run(main, feed={"x": xs}, fetch_list=[loss],
-                            scope=scope)[0]) for _ in range(10)]
+                            scope=scope)[0]) for _ in range(40)]
     assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0]
+    # every step's loss carries its own mask's noise (96 hidden values at
+    # p = 0.3), so the trend is read over five steps at either end and not
+    # off two single draws, whatever stream the masks come from
+    assert np.mean(losses[-5:]) < np.mean(losses[:5]), losses
 
 
 def test_ifelse_one_sided_raises(fresh_programs):
