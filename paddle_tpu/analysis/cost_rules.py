@@ -386,6 +386,47 @@ def _cost_mla_decode(ctx):
         tuple(ws), w_item)
 
 
+@register_cost_rule("mhc_pre")
+def _cost_mhc_pre(ctx):
+    """Per row: the sum of squares and the mix over the n C values (2 +
+    2 operations a value), the projection onto n (n + 2) columns, and
+    the mappings (two sigmoids, 2 n^2 divisions a Sinkhorn round).
+    Bytes: X once (the kernel's pass; the composed form reads it three
+    times), H and the coefficients out, Phi once a call."""
+    xs, ps = ctx.input_shape("X"), ctx.input_shape("Phi")
+    x_elems = None if xs is None else ctx.elems(xs)
+    rows = None if xs is None else ctx.elems(tuple(xs[:-1]))
+    if x_elems is None or rows is None:
+        return ctx.out_elems()
+    n = int(ctx.attr("n", 1) or 1)
+    k = n * (n + 2)
+    iters = int(ctx.attr("sinkhorn_iters", 0) or 0)
+    flops = x_elems.scaled(4 + 2 * k) \
+        + rows.scaled(10 * k + iters * 4 * n * n)
+    p_item = {"bfloat16": 2}.get(ctx.input_dtype("Phi"), 4)
+    nbytes = x_elems.scaled(4) + x_elems.scaled(4).scaled(1.0 / n) \
+        + rows.scaled(4 * k)
+    if ps is not None:
+        nbytes = nbytes + BytesPoly.from_dims(tuple(ps), p_item)
+    return flops, nbytes
+
+
+@register_cost_rule("mhc_post")
+def _cost_mhc_post(ctx):
+    """Per row and stream a sum over n streams and the sub-block's
+    output: 2 (n + 1) operations a value of X. Bytes: X in and out, Y
+    and the coefficients in."""
+    xs = ctx.input_shape("X")
+    x_elems = None if xs is None else ctx.elems(xs)
+    rows = None if xs is None else ctx.elems(tuple(xs[:-1]))
+    if x_elems is None or rows is None:
+        return ctx.out_elems()
+    n = int(ctx.attr("n", 1) or 1)
+    return x_elems.scaled(2 * (n + 1)), \
+        x_elems.scaled(8) + x_elems.scaled(4).scaled(1.0 / n) \
+        + rows.scaled(4 * n * (n + 2))
+
+
 @register_cost_rule("fused_attention")
 def _cost_attention(ctx):
     qs, ks = ctx.input_shape("Q"), ctx.input_shape("K")

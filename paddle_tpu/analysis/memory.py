@@ -367,6 +367,19 @@ def _fp_mla_decode(ctx):
     return BytesPoly.from_dims((qn[0], qn[2], cs[2] + 2 * cs[3]), 4)
 
 
+@register_footprint_rule("mhc_pre")
+def _fp_mhc_pre(ctx):
+    """The composed form's normalised copy of X (the Pallas kernel keeps
+    a block of it in VMEM: an upper bracket either way) beside the raw
+    mappings of every row."""
+    xs = ctx.input_shape("X")
+    if xs is None:
+        return None
+    n = int(ctx.attr("n", 1) or 1)
+    return BytesPoly.from_dims(tuple(xs), 4) \
+        + BytesPoly.from_dims(tuple(xs[:-1]) + (2 * n * (n + 2),), 4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

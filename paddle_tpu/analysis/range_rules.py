@@ -826,6 +826,29 @@ def _rr_mla_decode(ctx):
     ctx.set("Out", _contraction(ctx, o, ctx.input_av("W"), dc))
 
 
+@register_range_rule("mhc_pre")
+def _rr_mhc_pre(ctx):
+    """``H`` is a sum of n streams, each weighted by a sigmoid; every
+    coefficient lies in [0, 2] (``H_pre`` and ``H_res`` in [0, 1],
+    ``H_post`` in [0, 2]); the deviation is a distance from one."""
+    n = int(ctx.attr("n", 1) or 1)
+    x = _sym(ctx.input_av("X"))
+    ctx.set("H", AbstractValue(n * x.lo, n * x.hi, finite=x.finite))
+    ctx.set("Coef", av_interval(0.0, 2.0))
+    ctx.set("DevOut", av_interval(0.0, float(n)))
+
+
+@register_range_rule("mhc_post")
+def _rr_mhc_post(ctx):
+    """A row of ``H_res`` sums to one up to its rounds' error (no entry
+    over one), and ``H_post`` is at most two: ``n |X| + 2 |Y|``."""
+    n = int(ctx.attr("n", 1) or 1)
+    x, y = _sym(ctx.input_av("X")), _sym(ctx.input_av("Y"))
+    hi = n * x.hi + 2.0 * y.hi
+    ctx.set("Out", AbstractValue(-hi, hi, finite=x.finite and y.finite
+                                 and math.isfinite(hi) and hi <= F32_MAX))
+
+
 @register_range_rule("moe_ffn")
 def _rr_moe_ffn(ctx):
     """Each token's output is a sum of top_k expert outputs, each scaled

@@ -175,6 +175,47 @@ def _r_mla_decode(ctx):
                  % (qn, qr, cs, ws, dv))
 register_shape_rule("scatter")(_same_shape("X"))
 
+@register_shape_rule("mhc_pre")
+def _r_mhc_pre(ctx):
+    """H is X's shape at a stream's width, Coef at n (n + 2); X is
+    [..., n C], Phi [n C, n (n + 2)], Alpha [3], B [n (n + 2)]."""
+    xs, ps = ctx.input_shape("X"), ctx.input_shape("Phi")
+    n = int(ctx.attr("n", 0) or 0)
+    if n < 1:
+        ctx.fail("mhc_pre needs n >= 1 streams")
+        return
+    k = n * (n + 2)
+    if xs is not None:
+        wide = xs[-1]
+        ctx.set("H", tuple(xs[:-1]) + (wide // n if wide >= 0 else -1,))
+        ctx.set("Coef", tuple(xs[:-1]) + (k,))
+        if wide >= 0 and wide % n:
+            ctx.fail("X %s is not [..., %d * C]" % (xs, n))
+    if "DevOut" in ctx.op.outputs:
+        ctx.set("DevOut", ctx.input_shape("Dev"))
+    if xs is not None and ps is not None and is_concrete(ps) \
+            and xs[-1] >= 0 and tuple(ps) != (xs[-1], k):
+        ctx.fail("Phi %s is not [n C, n (n + 2)] = [%d, %d]"
+                 % (ps, xs[-1], k))
+
+
+@register_shape_rule("mhc_post")
+def _r_mhc_post(ctx):
+    """Out is X's shape [..., n C]; Y is [..., C] and Coef [..., n (n +
+    2)] over the same rows."""
+    xs, ys, cs = (ctx.input_shape(s) for s in ("X", "Y", "Coef"))
+    n = int(ctx.attr("n", 0) or 0)
+    if xs is not None:
+        ctx.set("Out", xs)
+    if None in (xs, ys, cs) or n < 1 or not all(
+            is_concrete(t[1:]) for t in (xs, ys, cs)):
+        return
+    if tuple(xs[1:-1]) != tuple(ys[1:-1]) or xs[-1] != n * ys[-1] \
+            or tuple(cs[1:]) != tuple(xs[1:-1]) + (n * (n + 2),):
+        ctx.fail("X %s, Y %s and Coef %s are not [..., n C], [..., C] and "
+                 "[..., n (n + 2)] at n = %d" % (xs, ys, cs, n))
+
+
 
 @register_shape_rule("cast")
 def _r_cast(ctx):

@@ -9,8 +9,9 @@ here instead of a LoD level (see ops/beam_search_ops.py).
 from __future__ import annotations
 
 from ..layer_helper import LayerHelper
+from ..param_attr import ParamAttr
 
-__all__ = ["kv_cache_write", "mla_decode", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+__all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -92,7 +93,7 @@ def beam_gather(x, parent_idx, name=None):
     return out
 
 
-def rope(x, pos, base=10000.0, name=None):
+def rope(x, pos, base=10000.0, name=None, yarn=None):
     """Rotary position embedding on a head tensor [..., S, D] (D even,
     rotate-half convention): position i rotates pair (x_j, x_{j+D/2})
     by angle pos_i * base^(-2j/D). `pos` is a [S] int var (or [1] for
@@ -100,7 +101,12 @@ def rope(x, pos, base=10000.0, name=None):
     reset at segment starts) — runtime positions, one executable for
     every step. Apply to q and k after head split, BEFORE attention
     (and before any GQA head repeat — the rotation is per head-dim,
-    head-count blind)."""
+    head-count blind). ``yarn`` — ``dict(factor=, low=, high=,
+    mscale=1.0)`` — scales the frequencies per dimension (YaRN):
+    dimension j keeps ``base^(-2j/D)`` below ``low``, has it divided by
+    ``factor`` above ``high`` and a linear blend between, and cos and
+    sin are multiplied by ``mscale``; factor 1 is the plain rotation bit
+    for bit."""
     if x.shape is not None and x.shape[-1] is not None \
             and int(x.shape[-1]) % 2:
         raise ValueError(
@@ -108,8 +114,71 @@ def rope(x, pos, base=10000.0, name=None):
             % (x.shape[-1],))
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"base": float(base)}
+    if yarn:
+        attrs.update(yarn_factor=float(yarn["factor"]),
+                     yarn_low=float(yarn["low"]),
+                     yarn_high=float(yarn["high"]),
+                     yarn_mscale=float(yarn.get("mscale", 1.0)))
     helper.append_op(type="rope", inputs={"X": [x], "Pos": [pos]},
-                     outputs={"Out": [out]}, attrs={"base": float(base)})
+                     outputs={"Out": [out]}, attrs=attrs)
+    out.shape = x.shape
+    return out
+
+
+def mhc_pre(x, n, epsilon, sinkhorn_iters, hc_eps, clamp, prefix,
+            dev=None, name=None):
+    """What a sub-block reads of ``n`` residual streams (op ``mhc_pre``,
+    kernels/mhc.py): ``x [B, S, n C]`` holds stream ``i`` in lanes
+    ``i C .. (i + 1) C``. Returns ``(h [B, S, C], coef [B, S, n (n +
+    2)])``: the mixed vector ``sum_i H_pre[i] x[i]`` and the row's
+    mappings ``[H_pre | H_post | H_res]`` for ``mhc_post``. Parameters
+    ``<prefix>_phi.w_0 [n C, n (n + 2)]`` (``[phi_pre | phi_post |
+    phi_res]``), ``<prefix>_alpha [3]`` (one gate a group) and
+    ``<prefix>_b [n (n + 2)]``. ``dev`` is a persistable ``[1]`` float32
+    var: the op keeps in it the largest ``|sum - 1|`` any row or column
+    of an ``H_res`` has shown."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("mhc_pre", name=name)
+    n, wide = int(n), int(x.shape[-1])
+    k = n * (n + 2)
+    phi = helper.create_parameter(ParamAttr(name=prefix + "_phi.w_0"),
+                                  [wide, k], dtype="float32")
+    alpha = helper.create_parameter(
+        ParamAttr(name=prefix + "_alpha", initializer=Constant(1.0)),
+        [3], dtype="float32")
+    b = helper.create_parameter(
+        ParamAttr(name=prefix + "_b", initializer=Constant(0.0)),
+        [k], dtype="float32", is_bias=True)
+    h = helper.create_variable_for_type_inference(x.dtype)
+    coef = helper.create_variable_for_type_inference("float32")
+    inputs = {"X": [x], "Phi": [phi], "Alpha": [alpha], "B": [b]}
+    outputs = {"H": [h], "Coef": [coef]}
+    if dev is not None:
+        inputs["Dev"] = [dev]
+        outputs["DevOut"] = [dev]
+    helper.append_op(
+        type="mhc_pre", inputs=inputs, outputs=outputs,
+        attrs={"n": n, "epsilon": float(epsilon),
+               "sinkhorn_iters": int(sinkhorn_iters),
+               "hc_eps": float(hc_eps), "clamp_min": float(clamp[0]),
+               "clamp_max": float(clamp[1])})
+    h.shape = tuple(x.shape[:-1]) + (wide // n,)
+    coef.shape = tuple(x.shape[:-1]) + (k,)
+    return h, coef
+
+
+def mhc_post(x, y, coef, n, name=None):
+    """What a sub-block writes back (op ``mhc_post``): ``out[i] = sum_j
+    H_res[i, j] x[j] + H_post[i] y`` over the ``n`` streams of ``x
+    [B, S, n C]``, with ``y [B, S, C]`` the sub-block's output and
+    ``coef`` as ``mhc_pre`` returned it."""
+    helper = LayerHelper("mhc_post", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="mhc_post",
+                     inputs={"X": [x], "Y": [y], "Coef": [coef]},
+                     outputs={"Out": [out]}, attrs={"n": int(n)})
     out.shape = x.shape
     return out
 

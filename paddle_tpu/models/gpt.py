@@ -14,6 +14,7 @@ masked out of the loss.
 """
 
 import functools
+import math
 
 from .. import layers
 from ..layer_helper import stored_dtype
@@ -145,7 +146,54 @@ def base_config():
              d_rope=64, d_v=128, sandwich_norm=True, ffn_act="swiglu",
              d_ff=18432, n_dense_layer=3, n_expert=256, expert_top_k=8,
              d_expert=2048, n_shared_expert=1, router_score="sigmoid",
-             norm_topk=True, route_scale=2.5, weight_dtype="bfloat16")"""
+             norm_topk=True, route_scale=2.5, weight_dtype="bfloat16")
+
+    A changed residual path (``residual="mhc"``: manifold-constrained
+    hyper-connections, arXiv:2512.24880; serving programs only). A
+    token's state is ``hc_mult`` streams of ``d_model`` values, kept as
+    ONE ``[B, S, hc_mult * d_model]`` tensor (stream i in lanes ``i *
+    d_model ..``). It starts as the embedding row copied to every
+    stream; each sub-block (attention, then the FFN or the experts)
+    reads ``h = sum_i H_pre[i] X[i]`` through the layer's own pre-norm
+    and writes ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y`` (ops
+    ``mhc_pre`` / ``mhc_post``, kernels/mhc.py); the final norm reads
+    the sum of the streams. ``H_pre = sigmoid(.)``, ``H_post = 2
+    sigmoid(.)`` and ``H_res`` — ``hc_sinkhorn_iters`` (20) Sinkhorn
+    rounds, columns then rows with ``hc_eps`` (1e-6) in each divisor,
+    of ``exp(clip(., *hc_res_clamp))`` (``(-30, 30)``) — come per token
+    from ``alpha * (x~ phi) + b`` with ``x~`` the row RMS-normalised
+    over all its streams (``norm_eps``). Parameters a sub-block k in
+    {1, 2}: ``gpt_<i>_hc<k>_phi.w_0 [hc_mult d_model, hc_mult (hc_mult +
+    2)]`` (``[phi_pre | phi_post | phi_res]``), ``gpt_<i>_hc<k>_alpha
+    [3]``, ``gpt_<i>_hc<k>_b [hc_mult (hc_mult + 2)]``. The decode
+    caches are whatever the attention keeps: the streams live inside a
+    program.
+
+    ``rope_scaling`` (with ``attn="mla"``): ``dict(type="yarn", factor,
+    original_max_position_embeddings, beta_fast=32, beta_slow=1,
+    mscale=1, mscale_all_dim=0)`` as DeepSeek-V3's public implementation
+    reads it — per-dimension frequencies (``layers.rope(yarn=)``), cos
+    and sin times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)`` and the softmax scale times ``yarn_mscale(factor,
+    mscale_all_dim) ** 2``, ``yarn_mscale(f, m) = 0.1 m ln f + 1``.
+
+    Xing4.0-29B-A4B (``model_type`` xing4_0), as the worked example —
+    published widths, all 40 layers::
+
+        dict(d_model=3584, n_head=32, n_layer=40, vocab=131072,
+             max_length=262144, dropout=0.0, pos_emb="rope",
+             rope_theta=10000.0, rope_scaling=dict(
+                 type="yarn", factor=64, beta_fast=32, beta_slow=1,
+                 mscale=1, mscale_all_dim=1,
+                 original_max_position_embeddings=4096),
+             norm="rms", norm_eps=1e-6, attn="mla", q_lora_rank=768,
+             kv_lora_rank=512, d_nope=128, d_rope=64, d_v=128,
+             residual="mhc", hc_mult=4, hc_sinkhorn_iters=20,
+             hc_eps=1e-6, hc_res_clamp=(-30, 30), ffn_act="swiglu",
+             d_ff=9216, n_dense_layer=2, n_expert=64, expert_top_k=4,
+             d_expert=1024, n_shared_expert=1, router_score="sigmoid",
+             router_bias=True, norm_topk=True, route_scale=2.0,
+             weight_dtype="bfloat16")"""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -161,6 +209,8 @@ _CFG_KEYS = frozenset([
     "expert_first",
     "attn", "q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v",
     "weight_dtype",
+    "residual", "hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
+    "rope_scaling",
 ])
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
@@ -178,6 +228,11 @@ ROUTED_PAIRS_VAR = "gpt_moe_routed_pairs"
 # grouped matmul fetches no weights for an empty group, so the bytes a
 # step streams follow this tally, not the count of experts)
 EXPERTS_TOUCHED_VAR = "gpt_moe_experts_touched"
+
+# the largest |row sum - 1| or |column sum - 1| any residual mapping
+# H_res has shown in the serving decode step (cfg['residual'] = 'mhc'):
+# [1] float32, persistable, a running maximum kept on the device
+MHC_RES_DEV_VAR = "gpt_mhc_res_dev"
 
 # what the decode and the prefill step choose on the device, under names
 # a caller fetches INSTEAD of the logits (the builders keep returning
@@ -206,6 +261,7 @@ def _check_cfg(cfg):
                          ("rope_layers", ("all", "sliding")),
                          ("router_score", ("softmax", "sigmoid")),
                          ("attn", ("mla",)),
+                         ("residual", ("mhc",)),
                          ("weight_dtype", ("float32", "bfloat16"))):
         val = cfg.get(key)
         if val is not None and val not in allowed:
@@ -240,6 +296,37 @@ def _check_cfg(cfg):
                     "expert_first"):
             if cfg.get(key):
                 raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
+    if has_streams(cfg):
+        if not int(cfg.get("hc_mult") or 0) >= 1:
+            raise ValueError("cfg['residual']='mhc' needs cfg['hc_mult'] "
+                             ">= 1 streams")
+        if cfg.get("norm", "layer") != "rms":
+            raise ValueError("cfg['residual']='mhc' needs norm='rms': the "
+                             "mappings read the RMS-normalised streams")
+        if cfg.get("pos_emb", "learned") != "rope":
+            raise ValueError("cfg['residual']='mhc' needs pos_emb='rope': "
+                             "a learned position row has no stream to go to")
+        lo, hi = _hc_clamp(cfg)
+        if not lo < hi:
+            raise ValueError("cfg['hc_res_clamp'] must be (min, max) with "
+                             "min < max; got %r" % (cfg["hc_res_clamp"],))
+    else:
+        for key in ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                    "hc_res_clamp"):
+            if cfg.get(key):
+                raise ValueError("cfg[%r] needs cfg['residual']='mhc'" % key)
+    if cfg.get("rope_scaling"):
+        rs = cfg["rope_scaling"]
+        if not has_latent(cfg):
+            raise ValueError("cfg['rope_scaling'] needs cfg['attn']='mla': "
+                             "no other attention here scales its softmax")
+        if rs.get("type") != "yarn" or not float(rs.get("factor") or 0) \
+                >= 1 or not int(rs.get(
+                    "original_max_position_embeddings") or 0) >= 1:
+            raise ValueError(
+                "cfg['rope_scaling'] must be a YaRN dict (type='yarn', "
+                "factor >= 1, original_max_position_embeddings); got %r"
+                % (rs,))
     types = cfg.get("layer_types")
     if types is not None:
         if len(types) != cfg["n_layer"] or \
@@ -329,6 +416,25 @@ def latent_width(cfg):
     return int(cfg["kv_lora_rank"]) + int(cfg["d_rope"])
 
 
+def has_streams(cfg):
+    """Whether a token's state is ``hc_mult`` residual streams
+    (``residual='mhc'``) and not one vector."""
+    return cfg.get("residual") == "mhc"
+
+
+def _hc_clamp(cfg):
+    lo, hi = cfg.get("hc_res_clamp") or (-30.0, 30.0)
+    return float(lo), float(hi)
+
+
+def _refuse_streams(cfg, who, why):
+    if has_streams(cfg):
+        raise ValueError(
+            "%s: cfg['residual']='mhc' keeps %d residual streams a token "
+            "(ops mhc_pre / mhc_post), %s"
+            % (who, int(cfg["hc_mult"]), why))
+
+
 def _stores_weights(builder):
     """Run ``builder(cfg, ...)`` with its matrices created in
     cfg['weight_dtype'] (``layer_helper.stored_dtype``)."""
@@ -390,6 +496,10 @@ def _embed(cfg, tokens, shape):
     word = layers.reshape(word, shape)
     if cfg.get("emb_scale"):
         word = layers.scale(word, scale=float(cfg["emb_scale"]))
+    if has_streams(cfg):
+        # every stream starts as the embedding row
+        word = layers.expand(word, [1] * (len(shape) - 1)
+                             + [int(cfg["hc_mult"])])
     return word
 
 
@@ -507,7 +617,13 @@ def _mla_expanded(cfg, h, nm, S, pos):
 
 
 def _mla_scale(cfg):
-    return float(cfg["d_nope"] + cfg["d_rope"]) ** -0.5
+    """``1 / sqrt(d_nope + d_rope)``, times YaRN's ``yarn_mscale(factor,
+    mscale_all_dim) ** 2`` under cfg['rope_scaling']."""
+    scale = float(cfg["d_nope"] + cfg["d_rope"]) ** -0.5
+    rs = cfg.get("rope_scaling")
+    if rs and rs.get("mscale_all_dim"):
+        scale *= _yarn_mscale(rs["factor"], rs["mscale_all_dim"]) ** 2
+    return scale
 
 
 def _note_mla_expanded(cfg, kernel):
@@ -522,20 +638,45 @@ def _note_mla_expanded(cfg, kernel):
         widths="%dx%d" % (cfg["d_nope"] + cfg["d_rope"], cfg["d_v"])).inc()
 
 
-def _block_tail(cfg, x, h, ctxv, nm, i, **tally):
+def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
     """What follows a layer's attention in the serving programs: the
-    output projection on the merged heads ``ctxv``, the residual, then
-    the FFN or the experts (``tally``: ``_mlp``'s counts) and theirs."""
-    x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1")
-    f = _mlp(cfg, _norm_of(cfg, x, nm + "_pre2"), nm, i, **tally)
-    return _residual(cfg, x, f, nm + "_post2")
+    output projection on the merged heads ``ctxv``, the residual
+    (``mix``: the attention sub-block's mappings from ``_sub_input``),
+    then the FFN or the experts (``tally``: ``_mlp``'s counts) and
+    theirs."""
+    x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1", mix)
+    h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
+    f = _mlp(cfg, h2, nm, i, **tally)
+    return _residual(cfg, x, f, nm + "_post2", mix2)
 
 
-def _residual(cfg, x, y, prefix):
+def _sub_input(cfg, x, nm, k, dev=None):
+    """What sub-block ``k`` (1: attention, 2: the FFN or the experts) of
+    layer ``nm`` reads: ``(the layer's pre-norm of it, mix)``. One
+    vector a token: the norm of ``x``, and ``mix`` None. With
+    cfg['residual']='mhc' the norm of the learnt mixture of the streams
+    (``layers.mhc_pre``), and ``mix`` the token's mappings for
+    ``_residual`` to write back with; ``dev`` is the step's health
+    reading (``MHC_RES_DEV_VAR``)."""
+    mix = None
+    if has_streams(cfg):
+        x, mix = layers.mhc_pre(
+            x, cfg["hc_mult"], _rms_eps(cfg),
+            int(cfg.get("hc_sinkhorn_iters") or 20),
+            float(cfg.get("hc_eps") or 1e-6), _hc_clamp(cfg),
+            "%s_hc%d" % (nm, k), dev=dev)
+    return _norm_of(cfg, x, "%s_pre%d" % (nm, k)), mix
+
+
+def _residual(cfg, x, y, prefix, mix=None):
     """``x + y``, with cfg['sandwich_norm'] the sub-block's output
-    normed first (``<prefix>_ln_s``)."""
+    normed first (``<prefix>_ln_s``); over streams (``mix`` from
+    ``_sub_input``) every stream takes its doubly stochastic share of
+    the others and its own share of ``y`` (``layers.mhc_post``)."""
     if cfg.get("sandwich_norm"):
         y = _norm_of(cfg, y, prefix)
+    if mix is not None:
+        return layers.mhc_post(x, y, mix, cfg["hc_mult"])
     return layers.elementwise_add(x, y)
 
 
@@ -557,8 +698,38 @@ def _rope_base(cfg):
     return cfg.get("rope_theta") or 10000.0
 
 
+def _yarn_mscale(factor, mscale):
+    return 0.1 * float(mscale) * math.log(factor) + 1.0 \
+        if factor > 1 else 1.0
+
+
+def _yarn(cfg, dim):
+    """``layers.rope``'s ``yarn`` for cfg['rope_scaling'] over ``dim``
+    rotated dimensions, None without it: the correction range as
+    DeepSeek-V3's ``yarn_find_correction_range`` finds it (the dimension
+    that turns ``beta`` times within the original context), clamped to
+    the ``dim / 2`` frequencies."""
+    rs = cfg.get("rope_scaling")
+    if not rs:
+        return None
+    base, orig = _rope_base(cfg), int(rs["original_max_position_embeddings"])
+
+    def at(beta):
+        return dim * math.log(orig / (beta * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    top = dim // 2 - 1
+    low = min(max(math.floor(at(float(rs.get("beta_fast", 32)))), 0), top)
+    high = min(max(math.ceil(at(float(rs.get("beta_slow", 1)))), 0), top)
+    factor = float(rs["factor"])
+    return dict(factor=factor, low=low, high=high,
+                mscale=_yarn_mscale(factor, rs.get("mscale", 1))
+                / _yarn_mscale(factor, rs.get("mscale_all_dim", 0)))
+
+
 def _rope(cfg, x, pos):
-    return layers.rope(x, pos, base=_rope_base(cfg))
+    return layers.rope(x, pos, base=_rope_base(cfg),
+                       yarn=_yarn(cfg, int(x.shape[-1])))
 
 
 def _qk_norm(cfg, q, k, nm):
@@ -578,6 +749,15 @@ def _routed_pairs_var(cfg, helper):
     return helper.create_global_variable(
         name=ROUTED_PAIRS_VAR, shape=(cfg["n_layer"], cfg["n_expert"]),
         dtype="int32")
+
+
+def _mhc_dev_var(cfg, helper):
+    """The serving decode step's health reading of its residual
+    mappings (``MHC_RES_DEV_VAR``), None without streams."""
+    if not has_streams(cfg):
+        return None
+    return helper.create_global_variable(name=MHC_RES_DEV_VAR, shape=(1,),
+                                         dtype="float32")
 
 
 def _experts_touched_var(cfg, helper):
@@ -616,7 +796,16 @@ def _mlp(cfg, h, nm, layer, counts=None, touched=None):
 
 def _final_norm(cfg, x):
     """The shared final norm (training build + decode step use the SAME
-    parameter names, so decode can overwrite by name)."""
+    parameter names, so decode can overwrite by name); of the SUM of the
+    streams where a token has several."""
+    if has_streams(cfg):
+        d = cfg["d_model"]
+        parts = [layers.slice(x, axes=[2], starts=[i * d],
+                              ends=[(i + 1) * d])
+                 for i in range(int(cfg["hc_mult"]))]
+        x = parts[0]
+        for part in parts[1:]:
+            x = layers.elementwise_add(x, part)
     if cfg.get("norm", "layer") == "rms":
         return layers.rms_norm(x, begin_norm_axis=2,
                                epsilon=_rms_eps(cfg),
@@ -673,6 +862,8 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
             "cfg['weight_dtype']=%r is the serving programs' (prefill, "
             "decode steps): the training build keeps float32 parameters"
             % (cfg["weight_dtype"],))
+    _refuse_streams(cfg, "build", "which have no backward: the training "
+                    "build cannot take them")
     new_style = _new_style(cfg)
     if new_style:
         # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
@@ -927,7 +1118,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         rows = cache_rows(cfg, i, max_len)
-        h = _norm_of(cfg, x, nm + "_pre1")
+        h, mix = _sub_input(cfg, x, nm, 1)
         if latent:
             # the expanded form through the flash forward; what stays of
             # the prompt is ONE slab of latent rows
@@ -948,7 +1139,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.reshape(
                 layers.transpose(ctxv, perm=[0, 2, 1, 3]),
                 [-1, P, n_head * cfg["d_v"]])
-            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
+            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, counts=routed)
             continue
         ck = helper.create_global_variable(
             name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
@@ -987,15 +1178,24 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
+        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, counts=routed)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
-    # the one row an admission needs, cut AFTER the head: the same
-    # numbers as logits[:, P - 1], whatever order the head reduces in
-    last = _expose(layers.reshape(
-        layers.slice(logits, axes=[1], starts=[P - 1], ends=[P]),
-        [-1, cfg["vocab"]]), LAST_LOGITS_VAR)
+    if has_streams(cfg):
+        # the one row an admission needs, cut BEFORE the head: a plan
+        # that fetches the row or its argmax holds a [1, vocab] head,
+        # and only one that fetches ``logits`` (``generate``) the
+        # [P, vocab] one (DCE) — 4.3 GB at P 8,192 and 131,072 ids
+        last = _lm_head(cfg, layers.slice(x, axes=[1], starts=[P - 1],
+                                          ends=[P]))
+    else:
+        # the older configurations' (their op lists are pinned): cut
+        # AFTER the head, the same numbers as logits[:, P - 1] whatever
+        # order the head reduces in
+        last = layers.slice(logits, axes=[1], starts=[P - 1], ends=[P])
+    last = _expose(layers.reshape(last, [-1, cfg["vocab"]]),
+                   LAST_LOGITS_VAR)
     _greedy_token(last)
     return logits, cache_names
 
@@ -1101,6 +1301,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     n_kv, g = _kv_heads_of(cfg)
     routed = _routed_pairs_var(cfg, helper) if per_slot_pos else None
     touched = _experts_touched_var(cfg, helper) if per_slot_pos else None
+    dev = _mhc_dev_var(cfg, helper) if per_slot_pos else None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
@@ -1112,7 +1313,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
                 name=nm + "_cache_c",
                 shape=(batch, 1, rows, latent_width(cfg)))
             cache_names.append(cc.name)
-            h = _norm_of(cfg, x, nm + "_pre1")
+            h, mix = _sub_input(cfg, x, nm, 1, dev)
             cc = layers.kv_cache_write(cc, _mla_row(cfg, h, nm, 1, pos),
                                        pos)
             q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
@@ -1121,8 +1322,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
                 [cfg["kv_lora_rank"], n_head * (cfg["d_nope"] + cfg["d_v"])],
                 d_v=cfg["d_v"], scale=_mla_scale(cfg),
                 param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
-            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed,
-                            touched=touched)
+            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
+                            counts=routed, touched=touched)
             continue
         # GQA: the cache stores n_kv heads — H/Hkv-times less decode
         # HBM, the whole point of grouped-query attention at inference
@@ -1132,7 +1333,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
         cache_names += [ck.name, cv.name]
 
-        h = _norm_of(cfg, x, nm + "_pre1")
+        h, mix = _sub_input(cfg, x, nm, 1, dev)
         q, k, v = _qkv(cfg, h, nm)
 
         def kv_heads(t, which=None):
@@ -1172,8 +1373,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         w = layers.softmax(scores)
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
         ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed,
-                        touched=touched)
+        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
+                        counts=routed, touched=touched)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -1239,6 +1440,9 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             "the multi-token step (suffix prefill after a prefix hit, "
             "speculative verification) does not read or write"
             % latent_width(cfg))
+    _refuse_streams(cfg, "build_multi_token_decode_step",
+                    "which the multi-token step (suffix prefill after a "
+                    "prefix hit, speculative verification) does not carry")
     if has_rings(cfg, max_len):
         raise ValueError(
             "build_multi_token_decode_step: cfg['layer_types'] holds "

@@ -1073,6 +1073,29 @@ MLA_ATTENTION_PLANS = REGISTRY.counter(
     "expanded, '576x512' absorbed at the published sizes)",
     labels=("form", "kernel", "block", "widths"))
 
+RESIDUAL_PLANS = REGISTRY.counter(
+    "paddle_residual_plans_total",
+    "Which form of a changed residual path a program holds (gpt "
+    "cfg['residual'] = 'mhc': n streams a token, kernels/mhc.py): one "
+    "count a call of mhc_pre (op 'pre': what a sub-block reads of the "
+    "streams, and the row's mappings) or mhc_post (op 'post': what it "
+    "writes back, in place) at LOWERING, kernel 'pallas' (one pass over "
+    "the stream) or 'composed' (jax.numpy: every CPU run, and "
+    "PADDLE_TPU_KERNELS=0). A prefill or a decode step of L layers lowers "
+    "2 L of each",
+    labels=("form", "op", "kernel", "streams"))
+
+MHC_RES_DEVIATION = REGISTRY.gauge(
+    "paddle_mhc_res_deviation",
+    "The largest |row sum - 1| or |column sum - 1| any residual mapping "
+    "H_res (doubly stochastic up to its Sinkhorn rounds' error) has shown "
+    "in a decode step of the engine since it was built, over the step's "
+    "rows (free slots too) and sub-blocks: the step keeps the running "
+    "maximum on the device (gpt.MHC_RES_DEV_VAR) and "
+    "DecodeEngine.mhc_res_deviation() is the one transfer that refreshes "
+    "this gauge. A kernel of fewer rounds, or a narrower mapping "
+    "arithmetic, moves it")
+
 # ---------------------------------------------------------------- tracing
 # (observe/trace.py: trace contexts + the crash flight recorder — see
 # docs/OBSERVABILITY.md "Trace propagation")

@@ -330,6 +330,48 @@ def _mla_decode(ctx, ins, attrs):
     return {"Out": [out.reshape(B, 1, H * dv)]}
 
 
+@register_op("mhc_pre", no_grad=True)
+def _mhc_pre(ctx, ins, attrs):
+    """What a sub-block reads of a token's ``n`` residual streams
+    (models/gpt.py cfg['residual']='mhc'; kernels/mhc.py holds the
+    arithmetic, a Pallas kernel on the TPU): ``H`` = the mixed vector
+    ``sum_i H_pre[i] X[i]`` and ``Coef`` = ``[H_pre | H_post | H_res]`` a
+    row, from ``X [..., n C]`` (stream ``i`` in lanes ``i C ..``). With
+    ``Dev`` (a persistable ``[1]``) the largest deviation of any
+    ``H_res`` row or column sum from one is kept as a running maximum.
+    Inference-only."""
+    from ..kernels.mhc import mhc_pre
+
+    x = ins["X"][0]
+    n = int(attrs["n"])
+    h, coef, dev = mhc_pre(
+        x.reshape(-1, x.shape[-1]), ins["Phi"][0], ins["Alpha"][0],
+        ins["B"][0], n=n, eps=float(attrs["epsilon"]),
+        iters=int(attrs["sinkhorn_iters"]), hc_eps=float(attrs["hc_eps"]),
+        clamp=(float(attrs["clamp_min"]), float(attrs["clamp_max"])))
+    lead = x.shape[:-1]
+    outs = {"H": [h.reshape(lead + (x.shape[-1] // n,))],
+            "Coef": [coef.reshape(lead + (n * (n + 2),))]}
+    if ins.get("Dev"):
+        seen = ins["Dev"][0]
+        outs["DevOut"] = [jnp.maximum(seen, dev.astype(seen.dtype))]
+    return outs
+
+
+@register_op("mhc_post", no_grad=True)
+def _mhc_post(ctx, ins, attrs):
+    """What a sub-block writes back to the ``n`` streams: ``Out[i] =
+    sum_j H_res[i, j] X[j] + H_post[i] Y`` with ``Coef`` as ``mhc_pre``
+    left it; on the TPU in place, into ``X``'s buffer (kernels/mhc.py).
+    Inference-only."""
+    from ..kernels.mhc import mhc_post
+
+    x, y, coef = ins["X"][0], ins["Y"][0], ins["Coef"][0]
+    out = mhc_post(x.reshape(-1, x.shape[-1]), y.reshape(-1, y.shape[-1]),
+                   coef.reshape(-1, coef.shape[-1]), n=int(attrs["n"]))
+    return {"Out": [out.reshape(x.shape)]}
+
+
 @register_op("rope", diff_inputs=["X"])
 def _rope(ctx, ins, attrs):
     """Rotary position embedding (rotate-half convention) on [..., S, D]
@@ -345,6 +387,16 @@ def _rope(ctx, ins, attrs):
     d = x.shape[-1]
     half = d // 2
     inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    factor = float(attrs.get("yarn_factor", 0.0) or 0.0)
+    if factor:
+        # YaRN: dimension i keeps its frequency below ``yarn_low``, has
+        # it divided by ``factor`` above ``yarn_high`` and a linear blend
+        # between: theta_i (1 - r_i) + (theta_i / factor) r_i, written so
+        # that factor 1 leaves every frequency as it was, bit for bit
+        low, high = float(attrs["yarn_low"]), float(attrs["yarn_high"])
+        ramp = jnp.clip((jnp.arange(0, half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv * (1.0 - ramp * (1.0 - 1.0 / factor))
     if pos.ndim == 2:
         # per-row positions [B, S] (packed sequences: positions reset
         # at segment starts): angles [B, 1, S, half] broadcast over
@@ -361,6 +413,9 @@ def _rope(ctx, ins, attrs):
         ang = pos.reshape(-1).astype(jnp.float32)[:, None] * inv[None, :]
         sin = jnp.sin(ang).astype(x.dtype)  # [S, half]
         cos = jnp.cos(ang).astype(x.dtype)
+    mscale = float(attrs.get("yarn_mscale", 1.0) or 1.0)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x1 * sin + x2 * cos], axis=-1)

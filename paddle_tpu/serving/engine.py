@@ -599,8 +599,10 @@ class DecodeEngine:
     (``cfg['layer_types']`` with a window shorter than ``max_len``): a
     stored prefix cannot be cut out of, nor a rejected draft rolled back
     in, a ring that has wrapped (``gpt.build_multi_token_decode_step``);
-    and for a model whose cache is latent (``cfg['attn']='mla'``), which
-    the multi-token step neither reads nor writes.
+    for a model whose cache is latent (``cfg['attn']='mla'``), which the
+    multi-token step neither reads nor writes; and for a model whose
+    tokens are several residual streams (``cfg['residual']='mhc'``),
+    which it does not carry.
     """
 
     def __init__(self, cfg, params: Optional[Dict[str, np.ndarray]] = None,
@@ -644,6 +646,12 @@ class DecodeEngine:
                     "1, max_len, %d] tensor a layer), and the multi-token "
                     "step does not read or write a latent cache"
                     % (lever, gpt.latent_width(model)))
+            if gpt.has_streams(model):
+                raise ValueError(
+                    "DecodeEngine: %s cannot serve a model with "
+                    "cfg['residual']='mhc': its tokens are %d residual "
+                    "streams, and the multi-token step does not carry "
+                    "streams" % (lever, int(model["hc_mult"])))
             if gpt.has_rings(model, self.max_len):
                 raise ValueError(
                     "DecodeEngine: %s cannot serve a model with "
@@ -830,6 +838,23 @@ class DecodeEngine:
         from ..observe.families import MOE_EXPERTS_TOUCHED
 
         return self._refresh_tally(EXPERTS_TOUCHED_VAR, MOE_EXPERTS_TOUCHED)
+
+    def mhc_res_deviation(self) -> Optional[float]:
+        """The largest ``|row sum - 1|`` or ``|column sum - 1|`` any
+        residual mapping ``H_res`` has shown in a decode step since the
+        engine was built, for a cfg with ``residual='mhc'`` (None
+        otherwise). The step keeps the running maximum on the device;
+        this call is the one transfer and refreshes
+        ``paddle_mhc_res_deviation``."""
+        from ..models.gpt import MHC_RES_DEV_VAR
+        from ..observe.families import MHC_RES_DEVIATION
+
+        var = self._lane.scope.find_var(MHC_RES_DEV_VAR)
+        if var is None:
+            return None
+        dev = float(np.asarray(var).reshape(-1)[0])
+        MHC_RES_DEVIATION.set(dev)
+        return dev
 
     def _refresh_tally(self, name, family) -> Optional[np.ndarray]:
         var = self._lane.scope.find_var(name)

@@ -15,6 +15,7 @@
 
 import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -798,6 +799,88 @@ def test_dropout_mask_is_drawn_per_shard_on_the_v5e_mesh(v5e_topology):
     assert text.count(" rng-bit-generator(") == 1
     assert _mask_sized(text, "dynamic-slice") == []
     assert _mask_sized(text, "shift-right-logical") == []
+
+
+def _xing():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def _mhc_plans():
+    from paddle_tpu.observe.families import RESIDUAL_PLANS
+
+    return {(op, kernel): RESIDUAL_PLANS.labels(
+        form="mhc", op=op, kernel=kernel, streams="4").value
+        for op in ("pre", "post") for kernel in ("pallas", "composed")}
+
+
+def test_xing_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``xing4.0-29b-a4b`` serving decode step (32 slots of
+    8,448 latent rows, four residual streams, all 64 experts, the whole
+    vocabulary) for the described chip: ten ``mhc_pre`` and ten
+    ``mhc_post`` Pallas calls (two sub-blocks a layer), five
+    ``mla_decode`` calls, and 11.2 GB of arguments."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mhc, mla_decode
+
+    gpt, cfg, serving = _xing()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert caches == ["gpt_%d_cache_c" % i for i in range(5)]
+    before = _mhc_plans()
+    lowered, _ = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    after = _mhc_plans()
+    assert {k: after[k] - before[k] for k in after} == {
+        ("pre", "pallas"): 10, ("post", "pallas"): 10,
+        ("pre", "composed"): 0, ("post", "composed"): 0}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name, n in ((mhc.KERNEL_PRE, 10), (mhc.KERNEL_POST, 10),
+                    (mla_decode.KERNEL, 5)):
+        assert len(set(re.findall(r"%%(%s[.\d]*) = " % name, text))) == n, \
+            name
+    mem = compiled.memory_analysis()
+    assert 11.1e9 < mem.argument_size_in_bytes < 11.4e9, mem
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    print("xing decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [512, 8192])
+def test_xing_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix for the described chip: the residual kernels and five flash
+    forwards, a head on ONE row (no [P, vocab] logits where the plan
+    fetches the token), the streams written in place, and temporaries
+    that fit beside the 11.2 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mhc
+
+    gpt, cfg, serving = _xing()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mhc.KERNEL_PRE,
+                              text))) == 10
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mhc.KERNEL_POST,
+                              text))) == 10
+    assert "f32[1,%d,131072]" % P not in text
+    assert "f32[%d,131072]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.5e9, mem
+    print("xing prefill P=%d:" % P, mem)
 
 
 # --------------------------------- what the bring-up found on the way
