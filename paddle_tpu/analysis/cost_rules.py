@@ -436,6 +436,9 @@ def _cost_attention(ctx):
     scores = ctx.elems(tuple(qs[:-1]) + (ks[-2],))
     if q_elems is None or scores is None:
         return ctx.out_elems()
+    if len(qs) == 3:
+        # [B, S, H*D] operands: a score matrix a head all the same
+        scores = scores.scaled(int(ctx.attr("n_head", 1) or 1))
     # q k^T over Q's width, then p v over V's (they differ in latent
     # attention's expanded form)
     vs = ctx.input_shape("V")

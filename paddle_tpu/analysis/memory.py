@@ -353,6 +353,8 @@ def _fp_attention(ctx):
     if qs is None or ks is None or len(qs) < 2 or len(ks) < 2:
         return None
     dims = tuple(qs[:-1]) + (ks[-2],)
+    if len(qs) == 3:   # [B, S, H*D] operands: a score matrix a head
+        dims = (int(ctx.attr("n_head", 1) or 1),) + dims
     return BytesPoly.from_dims(dims, 4)
 
 

@@ -129,7 +129,9 @@ register_shape_rule("rope")(_same_shape("X"))
 def _r_fused_attention(ctx):
     """Out (and the dropout mask) are Q's shape [B, H, Sq, D] at V's
     width; K is [B, Hkv, Sk, D] and V [B, Hkv, Sk, Dv] with Hkv dividing
-    H (grouped heads), and a window is a causal call's."""
+    H (grouped heads), and a window is a causal call's. Rank-3 Q, K, V
+    are [B, Sq, H*D], [B, Sk, H*D] and [B, Sk, H*D] with ``n_head`` heads
+    and take no window."""
     qs, ks, vs = (ctx.input_shape(s) for s in ("Q", "K", "V"))
     if qs is not None:
         out = qs if vs is None or len(vs) != len(qs) \
@@ -141,6 +143,16 @@ def _r_fused_attention(ctx):
         ctx.fail("a window needs causal=True")
     if qs is None or ks is None or not (is_concrete(qs[1:])
                                         and is_concrete(ks[1:])):
+        return
+    if len(qs) == 3:
+        n_head = int(ctx.attr("n_head", 0) or 0)
+        if len(ks) != 3 or qs[-1] != ks[-1] or n_head <= 0 \
+                or qs[-1] % n_head or ctx.attr("window", 0) \
+                or (vs is not None and is_concrete(vs[1:])
+                    and tuple(vs[1:]) != tuple(ks[1:])):
+            ctx.fail("Q %s, K %s and V %s are not [B, Sq, H*D], [B, Sk, "
+                     "H*D] and [B, Sk, H*D] with n_head=%d heads and no "
+                     "window" % (qs, ks, vs, n_head))
         return
     if len(qs) != 4 or len(ks) != 4 or qs[-1] != ks[-1] \
             or ks[1] <= 0 or qs[1] % ks[1]:

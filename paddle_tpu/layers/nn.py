@@ -1294,13 +1294,22 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32", name=None
 
 def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                     causal=False, segment_ids=None, window=None, name=None,
-                    mxu_dtype=None, flash_min_seq=None):
+                    mxu_dtype=None, flash_min_seq=None, n_head=None):
     """Single-kernel scaled-dot-product attention over [B,H,S,D] tensors
     (Pallas flash kernel; see ops/attention.py). The reference composes
     this from matmul+softmax layer calls — SURVEY §5. ``causal=True``
     applies the lower-triangular mask in-kernel and SKIPS above-diagonal
     key blocks (~2x decoder-self-attention FLOPs at long S) — pass it
     instead of materializing a [S,S] causal bias.
+
+    Rank-3 ``q``, ``k``, ``v`` are [B,S,H*D] with ``n_head`` heads, as
+    three projections leave them, and ``Out`` comes back [B,S,H*D] for
+    the output projection: the kernels then index the heads along the
+    last axis themselves and no transpose stands on either side (the
+    rank picks the layout; ``bias`` keeps [B|1,H|1,Sq|1,Sk]). A lowering
+    that cannot take the kernel so (a short S's composed form, the ring)
+    splits the heads inside and means the same. The forward-only
+    arguments below are rank-4 only.
 
     ``segment_ids`` ([B,S] int, 0 = padding — reader.pack_sequences
     layout) restricts attention to same-segment real keys for PACKED
@@ -1323,6 +1332,10 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("fused_attention: window=%r needs causal=True and "
                          "window >= 1" % (window,))
+    if len(q.shape) == 3 and (not n_head or q.shape[-1] % int(n_head)):
+        raise ValueError("fused_attention: rank-3 q, k, v are [B,S,H*D] and "
+                         "need n_head, a divisor of the last axis; got "
+                         "n_head=%r for %r" % (n_head, tuple(q.shape)))
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     mask = helper.create_variable_for_type_inference(q.dtype)
@@ -1345,7 +1358,9 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                                 **({"mxu_dtype": str(mxu_dtype)}
                                    if mxu_dtype else {}),
                                 **({"flash_min_seq": int(flash_min_seq)}
-                                   if flash_min_seq else {})))
+                                   if flash_min_seq else {}),
+                                **({"n_head": int(n_head)}
+                                   if len(q.shape) == 3 else {})))
     out.shape = tuple(q.shape[:-1]) + tuple(v.shape[-1:])
     return out
 

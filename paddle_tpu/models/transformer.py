@@ -91,7 +91,13 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
                          rope_base=10000.0):
     """causal=True only affects the fused path (in-kernel triangular
     mask + above-diagonal block skipping); the composed path expects the
-    causal mask folded into `bias` as before. ``n_kv_head < n_head``
+    causal mask folded into `bias` as before. The fused path without a
+    rotation or grouped heads hands the three projections [B, S, H*D] to
+    ``layers.fused_attention`` as they are and its result to the output
+    projection: the kernels index the heads themselves, and the layer
+    holds no transpose. Every other path splits the heads to
+    [B, H, S, D] first (rotation and the group repeat work there) and
+    merges them after. ``n_kv_head < n_head``
     is grouped-query attention (GQA): k/v project to fewer heads and
     group-repeat before the scores — fewer kv-projection FLOPs and,
     on the decode path (models/gpt.py build_decode_step), an
@@ -125,6 +131,13 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
                   param_attr=ParamAttr(name=name + "_v.w_0"))
     if qk_norm_eps is not None:
         q, k = qk_norm(q, k, name, qk_norm_eps)
+    if use_fused_attention and rope_pos is None and n_kv_head == n_head:
+        ctxv = layers.fused_attention(q, k, v, bias, scale=d_head ** -0.5,
+                                      dropout=dropout if not is_test else 0.0,
+                                      causal=causal, n_head=n_head,
+                                      segment_ids=segment_ids)
+        return layers.fc(ctxv, d_model, num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=name + "_o.w_0"))
     q = _split_heads(q, seq_q, n_head, d_head)
     k = _split_heads(k, seq_kv, n_kv_head, d_head)
     v = _split_heads(v, seq_kv, n_kv_head, d_head)

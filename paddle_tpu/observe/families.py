@@ -990,8 +990,12 @@ FLASH_BLOCK_PLANS = REGISTRY.counter(
     "grid step computes, and whether a single block covered the kernel's "
     "reduction axis so the carry was dropped ('1'). Counted at LOWERING "
     "time like paddle_kernel_dispatches_total: it says which plan a "
-    "compiled step holds (ops/attention.py _block_plan)",
-    labels=("kernel", "block", "single_pass"))
+    "compiled step holds (ops/attention.py _block_plan). layout is how "
+    "the operands came: 'heads' [B,H,S,D] (the serving prefills, a "
+    "rotated or grouped training layer) or 'lanes' [B,S,H*D] (the "
+    "projections as they are, the head a block index along the last "
+    "axis: no transpose round the call)",
+    labels=("kernel", "block", "single_pass", "layout"))
 
 DROPOUT_MASK_PLANS = REGISTRY.counter(
     "paddle_dropout_mask_plans_total",

@@ -90,6 +90,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -344,6 +345,113 @@ def _bias_block(b_ref, h):
     return b_ref[0, h if b_ref.shape[1] > 1 else 0].astype(jnp.float32)
 
 
+# ------------------------------------------------------- operand layout
+# The kernels take their operands in one of two layouts, picked by the
+# operands' rank (docs/KERNELS.md "Operand layouts of the flash kernels"):
+#
+#   heads  q, k, v [B, H, S, D]: a block is [heads, rows, D] of the
+#          flattened [B*H, S, D] array, and a kernel reads head h as
+#          ``ref[h]``.
+#   lanes  q, k, v [B, S, H*D], as the projections leave them: a block
+#          is [1, rows, heads*D] with heads*D whole lane tiles, the head
+#          group a block index along the LAST axis, so nothing is
+#          transposed round the call. A head narrower than a lane tile
+#          (D 64: two a tile) is read as the whole tile that holds it:
+#          where a product CONTRACTS over the lanes (q k^T, g v^T) one
+#          side has the other heads' lanes zeroed by a lane select (a
+#          v5e MXU pass is 128 deep, so the 64-deep product already paid
+#          for them); where the lanes are the product's columns (p v,
+#          p^T g, ds^T q, ds k) it runs at the tile's full width and the
+#          head keeps its own lanes of the result when the tile is
+#          written. No lane moves.
+class _Lanes:
+    """Where head h lies in a packed [1, rows, heads*D] block."""
+
+    def __init__(self, D):
+        self.D = D
+        self.W = D if D % _LANE == 0 else _LANE   # lanes a head is read in
+        self.per = self.W // D                    # heads a tile
+
+    def tile(self, h):
+        t = h // self.per
+        return slice(t * self.W, (t + 1) * self.W)
+
+    def own(self, shape, h):
+        """bool ``shape``: the lanes of head ``h`` inside its tile. (This
+        and the selects below are ``jax.lax`` primitives, not their
+        ``jax.numpy`` wrappers: a step's kernels hold some two thousand of
+        them, and each wrapper is a jit to trace — 8 s of a 50 s set-up.)"""
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+        lo = (h % self.per) * self.D
+        return jax.lax.bitwise_and(jax.lax.ge(lane, np.int32(lo)),
+                                   jax.lax.lt(lane, np.int32(lo + self.D)))
+
+
+def _lanes_ok(H, D):
+    """Whether H heads of width D can be blocked along the lanes: whole
+    lane tiles a head, or a whole number of heads a tile and of tiles a
+    batch entry."""
+    return D % _LANE == 0 or (_LANE % D == 0 and H % (_LANE // D) == 0)
+
+
+def _head(ref, h, lanes, own=False):
+    """Head ``h`` of an operand block. ``own`` (lanes layout, several
+    heads a tile) zeroes the other heads' lanes: the side of a
+    contraction over the lanes that picks the head."""
+    if lanes is None:
+        return ref[h]
+    x = ref[0, :, lanes.tile(h)]
+    if own and lanes.per > 1:
+        x = jax.lax.select(lanes.own(x.shape, h), x,
+                           jax.lax.full_like(x, 0))
+    return x
+
+
+class _HeadOut:
+    """Writes head results into an output block. In the lanes layout a
+    result comes a tile wide and holds its head in the head's own lanes;
+    the tile is written once, when all its heads are in."""
+
+    def __init__(self, ref, lanes):
+        self.ref, self.lanes, self.parts = ref, lanes, []
+
+    def put(self, h, val):
+        lanes = self.lanes
+        if lanes is None:
+            self.ref[h] = val.astype(self.ref.dtype)
+            return
+        self.parts.append(val)
+        if len(self.parts) < lanes.per:
+            return
+        out = self.parts[-1]
+        for i in range(lanes.per - 2, -1, -1):
+            out = jax.lax.select(lanes.own(out.shape, i), self.parts[i],
+                                 out)
+        self.ref[0, :, lanes.tile(h)] = out.astype(self.ref.dtype)
+        self.parts = []
+
+
+def _carry(ref, h, lanes):
+    """Head ``h``'s running state: the lanes layout keeps one a head of
+    the step (a multi-pass plan of the heads layout takes one head)."""
+    return ref if lanes is None else ref.at[h]
+
+
+def _block_spec(lanes, H, heads, rows, width, seq_of):
+    """BlockSpec of a q/k/v/g/o block of ``rows`` sequence positions;
+    ``seq_of(*grid indices)`` is its block index along the sequence.
+    Grid axis 0 counts groups of ``heads`` rows of the flattened B*H
+    axis in both layouts."""
+    if lanes is None:
+        return pl.BlockSpec((heads, rows, width),
+                            lambda *idx: (idx[0], seq_of(*idx), 0))
+    per_b = np.int32(H // heads)     # (lax: grid indices are not negative)
+    return pl.BlockSpec(
+        (1, rows, heads * width),
+        lambda *idx: (jax.lax.div(idx[0], per_b), seq_of(*idx),
+                      jax.lax.rem(idx[0], per_b)))
+
+
 # --------------------------------------------------------- kernel names
 # The names under which the four kernel runs of a layer are found in a
 # device profile and in the HLO: XLA names a Pallas custom call after the
@@ -430,14 +538,17 @@ def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db, window=None):
     return Sqp, Skp, bq, bk
 
 
-def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE):
+def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE,
+                    lanes=None):
     """How many (batch, head) rows one grid step takes. A single-pass
     kernel with no [bq, bk] tile to move (no bias or a key mask, no
     score-gradient output) takes up to ``_HEADS_PER_STEP`` heads a step,
     the largest count that divides H, so that a group stays inside one
     batch entry and one [B,1,1,S] bias block serves it: the per-step
     overhead is paid once, and one head's matmuls overlap the next
-    head's softmax. Every other kernel keeps one head a step: a
+    head's softmax. Every other kernel keeps one head a step (in the
+    lanes layout, the heads of one lane tile, ``lanes.per``: two at D 64,
+    each with a carry of its own; every count is a multiple of it): a
     [heads, bq, bk] float32 bias or ds block would not fit VMEM.
     ``width`` is the widest operand's last axis: ``_HEADS_PER_STEP`` is
     what fits at one lane tile of width, and blocks wider than that take
@@ -445,22 +556,27 @@ def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE):
     256x1024 plan at q/k 192 wide, 256 lanes in VMEM, want 47.6 MB of
     the 46 MB scoped limit: found on the chip, PR 32)."""
     slim = not want_db and (bias is None or bias.shape[2] == 1)
+    least = 1 if lanes is None else lanes.per
     if not (single_pass and slim):
-        return 1
-    most = max(1, _HEADS_PER_STEP // -(-int(width) // _LANE))
-    return max(g for g in range(1, most + 1) if H % g == 0)
+        return least
+    most = max(least, _HEADS_PER_STEP // -(-int(width) // _LANE))
+    return max(g for g in range(least, most + 1, least) if H % g == 0)
 
 
-def _note_plan(kernel, bq, bk, single_pass, visited=None):
+def _note_plan(kernel, bq, bk, single_pass, visited=None, lanes=None):
     """``visited`` = (blocks a windowed forward computes, blocks in the
-    square): it rides the block label, ``"512x512 76of256"``."""
+    square): it rides the block label, ``"512x512 76of256"``. ``layout``
+    says how the operands came: ``heads`` [B,H,S,D] or ``lanes``
+    [B,S,H*D]."""
     from ..observe.families import FLASH_BLOCK_PLANS
 
     block = "%dx%d" % (bq, bk)
     if visited is not None:
         block += " %dof%d" % visited
     FLASH_BLOCK_PLANS.labels(kernel=kernel, block=block,
-                             single_pass="1" if single_pass else "0").inc()
+                             single_pass="1" if single_pass else "0",
+                             layout="heads" if lanes is None
+                             else "lanes").inc()
 
 
 def _band_blocks(nq, nk, bq, bk, window):
@@ -570,7 +686,7 @@ def _dot_f32(a, b, ca, cb):
 
 
 def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
-                window=None, mxu_dtype=None):
+                window=None, mxu_dtype=None, lanes=None):
     q_ref, k_ref, v_ref = refs[:3]
 
     def mxu(t):
@@ -587,7 +703,8 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
     def scores(h, masked):
         # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
         # with f32 accumulation; only the softmax state is explicitly f32
-        s = _dot_f32(mxu(q_ref[h]), mxu(k_ref[h]), 1, 1) * scale  # [bq,bk]
+        s = _dot_f32(mxu(_head(q_ref, h, lanes, own=True)),
+                     mxu(_head(k_ref, h, lanes)), 1, 1) * scale  # [bq,bk]
         if b_ref is not None:
             s = s + _bias_block(b_ref, h)
         return _causal_mask(s, iq, ik, bq, bk, window=window) \
@@ -596,14 +713,14 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
     if nk == 1:
         # one block holds every key of the row: one softmax and one
         # write, no running max/denominator/accumulator to carry
+        out = _HeadOut(o_ref, lanes)
         for h in range(heads):
-            v = mxu(v_ref[h])                             # [bk, D]
+            v = mxu(_head(v_ref, h, lanes))               # [bk, D]
             s = scores(h, causal)
             m = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
             p = jnp.exp(s - m)                            # [bq, bk] f32
             l = jnp.sum(p, axis=-1, keepdims=True)
-            o_ref[h] = (_dot_f32(p.astype(v.dtype), v, 1, 0)
-                        / l).astype(o_ref.dtype)
+            out.put(h, _dot_f32(p.astype(v.dtype), v, 1, 0) / l)
             lse = m + jnp.log(l)
             lse_ref[h] = _to_row(lse) if rows else lse
         return
@@ -617,30 +734,42 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute(masked):
-        v = mxu(v_ref[0])                         # [bk, D]
-        s = scores(0, masked)
-        m_prev = m_ref[...]                       # [bq, 1]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                    # [bq, bk] f32
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha \
-            + _dot_f32(p.astype(v.dtype), v, 1, 0)
+        for h in range(heads):
+            m_h, l_h, acc_h = (_carry(r, h, lanes)
+                               for r in (m_ref, l_ref, acc_ref))
+            v = mxu(_head(v_ref, h, lanes))           # [bk, D]
+            s = scores(h, masked)
+            m_prev = m_h[...]                         # [bq, 1]
+            l_prev = l_h[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                    # [bq, bk] f32
+            l_h[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            m_h[...] = m_new
+            acc_h[...] = acc_h[...] * alpha \
+                + _dot_f32(p.astype(v.dtype), v, 1, 0)
 
     _for_block(_compute, causal, iq, ik, bq, bk, window)
 
     @pl.when(ik == nk - 1)
     def _emit():
-        l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse = m_ref[...] + jnp.log(l)         # [bq, 1]
-        lse_ref[0] = _to_row(lse) if rows else lse
+        out = _HeadOut(o_ref, lanes)
+        for h in range(heads):
+            l = _carry(l_ref, h, lanes)[...]
+            out.put(h, _carry(acc_ref, h, lanes)[...] / l)
+            lse = _carry(m_ref, h, lanes)[...] + jnp.log(l)   # [bq, 1]
+            lse_ref[h] = _to_row(lse) if rows else lse
+
+
+def _packed_dims(q, k, n_head):
+    """``(B, H, S, D, Sk)`` of a call in the lanes layout, [B, S, H*D]
+    operands with ``n_head`` heads."""
+    B, S, HD = q.shape
+    return B, int(n_head), S, HD // int(n_head), k.shape[1]
 
 
 def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
-                    window=None, mxu_dtype=None):
+                    window=None, mxu_dtype=None, n_head=None):
     """The forward kernel's call. ``window`` (an int, with ``causal``)
     bands the mask: blocks wholly outside the band are skipped on both
     sides and never fetched (the key block index is held inside the band,
@@ -651,9 +780,18 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     width of its own (latent attention's expanded form: q and k 192 wide,
     v 128): its blocks, the accumulator and the output take ``v``'s, the
     score tile is [bq, bk] whatever the widths. ``mxu_dtype`` rounds the
-    three operands to it where they meet the MXU (``_fwd_kernel``)."""
-    B, H, S, D = q.shape
-    Sk, Hkv, Dv = k.shape[2], k.shape[1], v.shape[3]
+    three operands to it where they meet the MXU (``_fwd_kernel``).
+    Rank-3 operands are the lanes layout ([B, S, H*D] with ``n_head``
+    heads, ``flash_attention`` has refused what it does not take): the
+    output comes back [B, S, H*D] too, the statistics [B*H, S] always."""
+    lanes = None
+    if q.ndim == 3:
+        B, H, S, D, Sk = _packed_dims(q, k, n_head)
+        Hkv, Dv, lanes = H, D, _Lanes(D)
+    else:
+        B, H, S, D = q.shape
+        Sk, Hkv, Dv = k.shape[2], k.shape[1], v.shape[3]
+    seq = 2 if lanes is None else 1      # the operands' sequence axis
     if causal and S != Sk:
         raise ValueError(
             "causal flash attention requires Sq == Sk (self-attention); "
@@ -687,17 +825,18 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     nq, nk = Sp // bq, Skp // bk
     _note_plan(name, bq, bk, nk == 1,
                None if window is None
-               else (_band_blocks(nq, nk, bq, bk, window), nq * nk))
+               else (_band_blocks(nq, nk, bq, bk, window), nq * nk), lanes)
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
     heads = 1 if group > 1 \
-        else _heads_per_step(H, nk == 1, bias, width=max(D, Dv))
+        else _heads_per_step(H, nk == 1, bias, width=max(D, Dv), lanes=lanes)
     rows = _stat_rows(bq)
-    q = _pad_axis(q, 2, Sp)
-    k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
-    qf = q.reshape(B * H, Sp, D)
-    kf, vf = k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, Dv)
+    q = _pad_axis(q, seq, Sp)
+    k, v = _pad_axis(k, seq, Skp), _pad_axis(v, seq, Skp)
+    if lanes is None:
+        q = q.reshape(B * H, Sp, D)
+        k, v = k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, Dv)
 
-    def kv_map(bh, iq, ik):
+    def kv_seq(bh, iq, ik):
         if causal and window is None:
             # hold the index at the diagonal: a block above it is skipped
             # and, repeating its neighbour's index, moves no bytes
@@ -707,14 +846,19 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
             # either side repeat a neighbour's index and move no bytes
             ik = jnp.clip(ik, jnp.maximum(iq * bq - (window - 1), 0) // bk,
                           (iq * bq + bq - 1) // bk)
-        return (bh // group if group > 1 else bh, ik, 0)
+        return ik
 
-    in_specs = [
-        pl.BlockSpec((heads, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-        pl.BlockSpec((heads, bk, D), kv_map),
-        pl.BlockSpec((heads, bk, Dv), kv_map),
-    ]
-    operands = [qf, kf, vf]
+    def kv_map(bh, iq, ik):
+        return (bh // group if group > 1 else bh, kv_seq(bh, iq, ik), 0)
+
+    q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, iq, ik: iq)
+    if lanes is None:
+        kv_specs = [pl.BlockSpec((heads, bk, D), kv_map),
+                    pl.BlockSpec((heads, bk, Dv), kv_map)]
+    else:
+        kv_specs = [_block_spec(lanes, H, heads, bk, D, kv_seq)] * 2
+    in_specs = [q_spec] + kv_specs
+    operands = [q, k, v]
     if bias is not None:
         spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 1, 2)
         in_specs.append(spec)
@@ -727,7 +871,11 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     kern = functools.partial(_fwd_kernel, scale=scale, nk=nk, causal=causal,
                              bq=bq, bk=bk, heads=heads,
                              has_bias=bias is not None, rows=rows,
-                             window=window, mxu_dtype=mxu_dtype)
+                             window=window, mxu_dtype=mxu_dtype, lanes=lanes)
+    # a multi-pass plan carries the output, the row maximum and the
+    # denominator in VMEM: one of each a head of the step
+    carry = lambda *shape: pltpu.VMEM(  # noqa: E731
+        shape if lanes is None else (heads,) + shape, jnp.float32)
     out, lse = _checked_pallas_call(
         kern,
         name=name,
@@ -735,28 +883,30 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         in_specs=in_specs,
         operands=operands,
         out_specs=[
-            pl.BlockSpec((heads, bq, Dv), lambda bh, iq, ik: (bh, iq, 0)),
+            _block_spec(lanes, H, heads, bq, Dv, lambda bh, iq, ik: iq),
             _stat_spec(heads, bq, rows, 1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Sp, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B * H, Sp, Dv) if lanes is None
+                                 else (B, Sp, H * Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sp) if rows else (B * H, Sp, 1),
                                  jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((bq, Dv), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            carry(bq, Dv if lanes is None else lanes.W),
+            carry(bq, 1),
+            carry(bq, 1),
         ],
         interpret=_use_interpret(),
     )
     lse = lse[:, 0, :S] if rows else lse[:, :S, 0]
-    return out[:, :S].reshape(B, H, S, Dv), lse
+    out = out[:, :S]
+    return (out.reshape(B, H, S, Dv) if lanes is None else out), lse
 
 
 # -------------------------------------------------------------- backward
 def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
-                transposed, rows):
+                transposed, rows, lanes=None):
     q_ref, k_ref, v_ref = refs[:3]
     b_ref = refs[3] if has_bias else None
     i = 3 + has_bias
@@ -771,10 +921,12 @@ def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
     c = 1 if transposed else 0
 
     def grads(h, masked):
-        q = q_ref[h]                              # [bq, D]
-        k = k_ref[h]                              # [bk, D]
-        v = v_ref[h]                              # [bk, D]
-        g = g_ref[h]                              # [bq, D]
+        # lanes layout: the key side picks the head where the lanes
+        # contract (k q^T, v g^T); p^T g and ds^T q run a tile wide
+        q = _head(q_ref, h, lanes)                # [bq, D]
+        k = _head(k_ref, h, lanes, own=True)      # [bk, D]
+        v = _head(v_ref, h, lanes, own=True)      # [bk, D]
+        g = _head(g_ref, h, lanes)                # [bq, D]
         lse, delta = lse_ref[h], d_ref[h]         # [1, bq] rows, or [bq, 1]
         if rows and not transposed:
             lse, delta = _to_col(lse), _to_col(delta)
@@ -798,10 +950,11 @@ def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
 
     if nq == 1:
         # one block holds every query: nothing to accumulate over
+        dk_out, dv_out = _HeadOut(dk_ref, lanes), _HeadOut(dv_ref, lanes)
         for h in range(heads):
             dk, dv = grads(h, causal)
-            dk_ref[h] = dk.astype(dk_ref.dtype)
-            dv_ref[h] = dv.astype(dv_ref.dtype)
+            dk_out.put(h, dk)
+            dv_out.put(h, dv)
         return
 
     dk_acc, dv_acc = refs[i + 5 + want_db:]
@@ -812,19 +965,23 @@ def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _compute(masked):
-        dk, dv = grads(0, masked)
-        dk_acc[...] += dk
-        dv_acc[...] += dv
+        for h in range(heads):
+            dk, dv = grads(h, masked)
+            _carry(dk_acc, h, lanes)[...] += dk
+            _carry(dv_acc, h, lanes)[...] += dv
 
     _for_block(_compute, causal, iq, ik, bq, bk)
 
     @pl.when(iq == nq - 1)
     def _emit():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_out, dv_out = _HeadOut(dk_ref, lanes), _HeadOut(dv_ref, lanes)
+        for h in range(heads):
+            dk_out.put(h, _carry(dk_acc, h, lanes)[...])
+            dv_out.put(h, _carry(dv_acc, h, lanes)[...])
 
 
-def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
+def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
+               lanes=None):
     q_ref, k_ref, v_ref = refs[:3]
     b_ref = refs[3] if has_bias else None
     i = 3 + has_bias
@@ -833,8 +990,10 @@ def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
     ik = pl.program_id(2)
 
     def grad(h, masked):
-        k = k_ref[h]
-        s = _dot_f32(q_ref[h], k, 1, 1) * scale
+        # lanes layout: the query side picks the head where the lanes
+        # contract (q k^T, g v^T); ds k runs a tile wide
+        k = _head(k_ref, h, lanes)
+        s = _dot_f32(_head(q_ref, h, lanes, own=True), k, 1, 1) * scale
         if b_ref is not None:
             s = s + _bias_block(b_ref, h)
         if masked:
@@ -843,13 +1002,15 @@ def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
         if rows:
             lse, delta = _to_col(lse), _to_col(delta)
         p = jnp.exp(s - lse)
-        dp = _dot_f32(g_ref[h], v_ref[h], 1, 1)
+        dp = _dot_f32(_head(g_ref, h, lanes, own=True),
+                      _head(v_ref, h, lanes), 1, 1)
         ds = p * (dp - delta) * scale             # [bq, bk] f32
         return _dot_f32(ds.astype(k.dtype), k, 1, 0)
 
     if nk == 1:
+        out = _HeadOut(dq_ref, lanes)
         for h in range(heads):
-            dq_ref[h] = grad(h, causal).astype(dq_ref.dtype)
+            out.put(h, grad(h, causal))
         return
 
     dq_acc, = refs[i + 4:]
@@ -859,31 +1020,48 @@ def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows):
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def _compute(masked):
-        dq_acc[...] += grad(0, masked)
+        for h in range(heads):
+            _carry(dq_acc, h, lanes)[...] += grad(h, masked)
 
     _for_block(_compute, causal, iq, ik, bq, bk)
 
     @pl.when(ik == nk - 1)
     def _emit():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        out = _HeadOut(dq_ref, lanes)
+        for h in range(heads):
+            out.put(h, _carry(dq_acc, h, lanes)[...])
 
 
 def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
-                     g_lse=None, causal=False):
-    B, H, S, D = q.shape
-    Sk = k.shape[2]
+                     g_lse=None, causal=False, n_head=None):
+    """dQ, dK, dV (and the score gradient a trainable bias wants) in the
+    operands' own layout; rank-3 operands are the lanes layout, as in
+    ``_forward_pallas``."""
+    lanes = None
+    if q.ndim == 3:
+        B, H, S, D, Sk = _packed_dims(q, k, n_head)
+        lanes = _Lanes(D)
+    else:
+        B, H, S, D = q.shape
+        Sk = k.shape[2]
     BH = B * H
+    seq = 2 if lanes is None else 1      # the operands' sequence axis
     plan = lambda kernel: _resolve_blocks(  # noqa: E731
         kernel, S, Sk, D, q.dtype, causal, want_db)
     Sp, Skp, bq, bk = plan(KERNEL_BWD_DKV)
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
-    q = _pad_axis(q, 2, Sp)
-    k, v = _pad_axis(k, 2, Skp), _pad_axis(v, 2, Skp)
-    qf, kf, vf = (t.reshape(BH, t.shape[2], D) for t in (q, k, v))
-    gf = _pad_axis(g.reshape(BH, S, D), 1, Sp)
-    of = _pad_axis(o.reshape(BH, S, D), 1, Sp)
-    delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1)                   # [BH, Sp]
+    q, gf, of = (_pad_axis(t, seq, Sp) for t in (q, g, o))
+    kf, vf = _pad_axis(k, seq, Skp), _pad_axis(v, seq, Skp)
+    if lanes is None:
+        qf, kf, vf, gf, of = (t.reshape(BH, t.shape[2], D)
+                              for t in (q, kf, vf, gf, of))
+        delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
+                        axis=-1)                   # [BH, Sp]
+    else:
+        qf = q
+        delta = jnp.sum((gf.astype(jnp.float32) * of.astype(jnp.float32))
+                        .reshape(B, Sp, H, D), axis=-1)
+        delta = delta.transpose(0, 2, 1).reshape(BH, Sp)
     if g_lse is not None:
         # lse cotangent: dlse_i/ds_ij = p_ij, so ds gains +p*g_lse_i —
         # algebraically a -g_lse shift of delta (ds = p*(dp - delta))
@@ -897,8 +1075,8 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
 
     # dK/dV: one key block per (bh, ik), sweep query blocks innermost
     nq, nk = Sp // bq, Skp // bk
-    heads = _heads_per_step(H, nq == 1, bias, want_db)
-    _note_plan(KERNEL_BWD_DKV, bq, bk, nq == 1)
+    heads = _heads_per_step(H, nq == 1, bias, want_db, lanes=lanes)
+    _note_plan(KERNEL_BWD_DKV, bq, bk, nq == 1, lanes=lanes)
     # transposed scores take the statistics as [1, bq] rows (one block
     # over all of a short S is legal too) and a bias that is a key mask;
     # a [Sq, Sk] bias, and the ds tile a trainable one wants back, keep
@@ -906,13 +1084,9 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     transposed = (not want_db and (_stat_rows(bq) or bq == Sp)
                   and (not has_bias or bias.shape[2] == 1))
     rows = transposed or _stat_rows(bq)
-    q_map = lambda bh, ik, iq: (bh, iq, 0)  # noqa: E731
-    k_map = lambda bh, ik, iq: (bh, ik, 0)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec((heads, bq, D), q_map),
-        pl.BlockSpec((heads, bk, D), k_map),
-        pl.BlockSpec((heads, bk, D), k_map),
-    ]
+    q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, ik, iq: iq)
+    k_spec = _block_spec(lanes, H, heads, bk, D, lambda bh, ik, iq: ik)
+    in_specs = [q_spec, k_spec, k_spec]
     operands = [qf, kf, vf]
     if has_bias:
         spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 2, 1,
@@ -920,16 +1094,15 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
         in_specs.append(spec)
         operands.append(opnd)
     stat = _stat_spec(heads, bq, rows, 2)
-    in_specs += [pl.BlockSpec((heads, bq, D), q_map), stat, stat]
+    in_specs += [q_spec, stat, stat]
     operands += [gf, _stat_operand(lse, rows), _stat_operand(delta, rows)]
-    out_specs = [
-        pl.BlockSpec((heads, bk, D), k_map),
-        pl.BlockSpec((heads, bk, D), k_map),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((BH, Skp, D), k.dtype),
-        jax.ShapeDtypeStruct((BH, Skp, D), v.dtype),
-    ]
+    out_specs = [k_spec, k_spec]
+    out_shape = [jax.ShapeDtypeStruct(kf.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vf.shape, v.dtype)]
+    # a multi-pass plan accumulates in VMEM: one accumulator a head
+    carry = lambda rows_: pltpu.VMEM(  # noqa: E731
+        (rows_, D) if lanes is None else (heads, rows_, lanes.W),
+        jnp.float32)
     if want_db:
         # per-block score grads, written once per grid cell (O(S^2) HBM —
         # only materialized when a trainable bias asks for it)
@@ -940,7 +1113,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     kern = functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,
                              bq=bq, bk=bk, heads=heads, has_bias=has_bias,
                              want_db=want_db, transposed=transposed,
-                             rows=rows)
+                             rows=rows, lanes=lanes)
     res = _checked_pallas_call(
         kern,
         name=KERNEL_BWD_DKV,
@@ -949,10 +1122,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
         operands=operands,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[] if nq == 1 else [
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
+        scratch_shapes=[] if nq == 1 else [carry(bk), carry(bk)],
         interpret=interp,
     )
     if want_db:
@@ -965,15 +1135,11 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     # plan may differ from dK/dV's in the blocks, never in the padding.
     _, _, bq, bk = plan(KERNEL_BWD_DQ)
     nq, nk = Sp // bq, Skp // bk
-    heads = _heads_per_step(H, nk == 1, bias)
-    _note_plan(KERNEL_BWD_DQ, bq, bk, nk == 1)
-    q_map = lambda bh, iq, ik: (bh, iq, 0)  # noqa: E731
-    k_map = lambda bh, iq, ik: (bh, ik, 0)  # noqa: E731
-    in_specs = [
-        pl.BlockSpec((heads, bq, D), q_map),
-        pl.BlockSpec((heads, bk, D), k_map),
-        pl.BlockSpec((heads, bk, D), k_map),
-    ]
+    heads = _heads_per_step(H, nk == 1, bias, lanes=lanes)
+    _note_plan(KERNEL_BWD_DQ, bq, bk, nk == 1, lanes=lanes)
+    q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, iq, ik: iq)
+    k_spec = _block_spec(lanes, H, heads, bk, D, lambda bh, iq, ik: ik)
+    in_specs = [q_spec, k_spec, k_spec]
     operands = [qf, kf, vf]
     if has_bias:
         spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 1, 2)
@@ -981,26 +1147,28 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
         operands.append(opnd)
     rows = _stat_rows(bq)
     stat = _stat_spec(heads, bq, rows, 1)
-    in_specs += [pl.BlockSpec((heads, bq, D), q_map), stat, stat]
+    in_specs += [q_spec, stat, stat]
     operands += [gf, _stat_operand(lse, rows), _stat_operand(delta, rows)]
     kern = functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
                              bq=bq, bk=bk, heads=heads, has_bias=has_bias,
-                             rows=rows)
+                             rows=rows, lanes=lanes)
     dq = _checked_pallas_call(
         kern,
         name=KERNEL_BWD_DQ,
         grid=(BH // heads, nq, nk),
         in_specs=in_specs,
         operands=operands,
-        out_specs=pl.BlockSpec((heads, bq, D), q_map),
-        out_shape=jax.ShapeDtypeStruct((BH, Sp, D), q.dtype),
-        scratch_shapes=[] if nk == 1 else [pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        scratch_shapes=[] if nk == 1 else [carry(bq)],
         interpret=interp,
     )
 
-    dq = dq[:, :S].reshape(B, H, S, D)
-    dk = dk[:, :Sk].reshape(B, H, Sk, D)
-    dv = dv[:, :Sk].reshape(B, H, Sk, D)
+    dq, dk, dv = dq[:, :S], dk[:, :Sk], dv[:, :Sk]
+    if lanes is None:
+        dq = dq.reshape(B, H, S, D)
+        dk = dk.reshape(B, H, Sk, D)
+        dv = dv.reshape(B, H, Sk, D)
     db = None
     if want_db:
         ds_full = ds_full[:, :S, :Sk].reshape(B, H, S, Sk)
@@ -1025,22 +1193,23 @@ def _attention_reference(q, k, v, bias, scale):
     return composed_attention(q, k, v, bias, scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _fa_maskbias(q, k, v, bias, scale, causal=False):
-    out, _ = _forward_pallas(q, k, v, bias, scale, causal=causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _fa_maskbias(q, k, v, bias, scale, causal=False, n_head=None):
+    out, _ = _forward_pallas(q, k, v, bias, scale, causal=causal,
+                             n_head=n_head)
     return out
 
 
-def _fa_maskbias_fwd(q, k, v, bias, scale, causal=False):
+def _fa_maskbias_fwd(q, k, v, bias, scale, causal=False, n_head=None):
     out, lse = _forward_pallas(q, k, v, bias, scale, causal=causal,
-                               name=KERNEL_REFWD)
+                               name=KERNEL_REFWD, n_head=n_head)
     return out, (q, k, v, bias, out, lse)
 
 
-def _fa_maskbias_bwd(scale, causal, res, g):
+def _fa_maskbias_bwd(scale, causal, n_head, res, g):
     q, k, v, bias, o, lse = res
     dq, dk, dv, _ = _backward_pallas(q, k, v, bias, o, lse, g, scale,
-                                     causal=causal)
+                                     causal=causal, n_head=n_head)
     # bias enters through stop_gradient (see flash_attention), so this
     # zero cotangent is discarded upstream — it is structural, not a
     # silently-wrong trainable-bias gradient.
@@ -1112,9 +1281,56 @@ def flash_attention_with_lse(q, k, v, bias=None, scale=1.0, causal=False):
     return out, lse.reshape(B, H, S)
 
 
+_FORWARD_ONLY = ("fused_attention with a window, grouped key/value heads, a "
+                 "value width of its own, mxu_dtype or flash_min_seq is "
+                 "forward-only; a training build composes its band bias "
+                 "(models/gpt.py build)")
+
+
+def _forward_only(q, k, v, window=None, mxu_dtype=None, min_seq=None):
+    """Whether a call asks for what only the serving prefill's forward
+    has: a window, fewer key/value heads, a value width of its own, the
+    MXU's dtype or a threshold of its own. None of it has a backward
+    rule, and none of it is taken in the lanes layout."""
+    if q.ndim == 3:    # [B, S, H*D]: grouped heads or a value width show
+        shaped = (k.shape[-1] != q.shape[-1]    # in the packed width
+                  or v.shape[-1] != q.shape[-1])
+    else:
+        shaped = k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1]
+    return bool(window) or shaped or bool(mxu_dtype) or bool(min_seq)
+
+
+def _packed_heads(q, n_head):
+    """The head count of [B, S, H*D] operands, checked."""
+    if not n_head or q.shape[-1] % int(n_head):
+        raise ValueError(
+            "rank-3 q, k, v are [B, S, H*D] and need n_head, a divisor of "
+            "the last axis; got n_head=%r for %r" % (n_head, q.shape))
+    return int(n_head)
+
+
+def _split_heads(x, n_head):
+    """[B, S, H*D] -> [B, H, S, D]."""
+    B, S, HD = x.shape
+    return x.reshape(B, S, n_head, HD // n_head).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    """[B, H, S, D] -> [B, S, H*D]."""
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+def _unpacked(fn, q, k, v, n_head):
+    """``fn`` over [B, H, S, D] operands for a caller that holds
+    [B, S, H*D]: what a lowering runs where the kernels cannot take the
+    lanes layout, so a Program means the same everywhere."""
+    return _merge_heads(fn(*(_split_heads(t, n_head) for t in (q, k, v))))
+
+
 def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
                     causal=False, window=None, mxu_dtype=None,
-                    min_seq=None):
+                    min_seq=None, n_head=None):
     """Fused attention. ``bias`` is a constant additive mask by default
     (non-differentiable: stop_gradient is applied); pass
     ``bias_grad=True`` to get the true bias cotangent, at the cost of an
@@ -1140,9 +1356,30 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
     ``flash_fwd`` and no backward rule exists for it (the training build
     of a windowed layer composes its band bias instead). ``min_seq`` is
     the caller's threshold for the kernel in place of the static
-    ``flash_min_seq()``; the environment's and a tuned entry still win."""
-    grouped = k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1] \
-        or mxu_dtype is not None
+    ``flash_min_seq()``; the environment's and a tuned entry still win.
+
+    Rank-3 ``q``, ``k``, ``v`` are [B, S, H*D] with ``n_head`` heads, as
+    the projections leave them, and the output comes back so: where the
+    kernel runs it blocks the heads along the lanes and nothing is
+    transposed round it; below the kernel's threshold, or at a head width
+    that does not tile the lanes, the call unpacks to [B, H, S, D] and
+    means the same. ``bias`` keeps its [B|1, H|1, Sq|1, Sk] form. The
+    forward-only features and a trainable bias are rank-4 only."""
+    if q.ndim == 3:
+        n_head = _packed_heads(q, n_head)
+        if bias_grad or _forward_only(q, k, v, window, mxu_dtype):
+            raise NotImplementedError(
+                _FORWARD_ONLY + "; [B, S, H*D] operands take none of them, "
+                "nor a trainable bias")
+        if not (_flash_decision(q.shape[1], k.shape[1], min_seq)[0]
+                and _lanes_ok(n_head, q.shape[-1] // n_head)):
+            return _unpacked(
+                lambda a, b, c: flash_attention(
+                    a, b, c, bias, scale, causal=causal, min_seq=min_seq),
+                q, k, v, n_head)
+    grouped = q.ndim == 4 and (k.shape[1] != q.shape[1]
+                               or v.shape[-1] != q.shape[-1]
+                               or mxu_dtype is not None)
     if window is not None or grouped:
         if bias_grad:
             raise ValueError("a window, grouped key/value heads, a value "
@@ -1173,7 +1410,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
             causal = False
     from .. import kernels
 
-    use_flash, tuned = _flash_decision(q.shape[2], k.shape[2], min_seq)
+    use_flash, tuned = _flash_decision(q.shape[-2], k.shape[-2], min_seq)
     kernels.note_decision("attention", "flash" if use_flash else "composed",
                           tuned=tuned)
     if kernels.kernels_enabled():
@@ -1201,11 +1438,11 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
             name=KERNEL_FWD_WIN if banded else KERNEL_FWD,
             mxu_dtype=mxu_dtype)[0]
     if bias is None:
-        return _fa_maskbias(q, k, v, None, scale, causal)
+        return _fa_maskbias(q, k, v, None, scale, causal, n_head)
     if bias_grad:
         return _fa_trainbias(q, k, v, bias, scale)
     return _fa_maskbias(q, k, v, jax.lax.stop_gradient(bias), scale,
-                        causal)
+                        causal, n_head)
 
 
 def _seg_mask_full(seg):
@@ -1220,7 +1457,7 @@ def _seg_mask_full(seg):
 
 def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
                               seg=None, window=None, mxu_dtype=None,
-                              min_seq=None):
+                              min_seq=None, n_head=None):
     """Mosaic kernels cannot be auto-partitioned by the SPMD partitioner
     (jax raises at multi-device lowering), so under a ParallelEngine mesh
     the op-level flash call wraps itself in shard_map: batch shards over
@@ -1236,11 +1473,19 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
     engages on the compiled path — CPU interpret mode lowers to
     partitionable jax ops. Pinned by tests/test_tpu_lowering.py::
     test_dp_tp_train_step_lowers_for_tpu (NotImplementedError without
-    the wrap) and the sp ring tests."""
+    the wrap) and the sp ring tests.
+
+    Rank-3 operands ([B, S, H*D], ``n_head`` heads) keep their layout
+    through the plain wrap (batch over the data axis, the packed axis
+    over 'model' where the heads divide); the ring, and a shard whose
+    heads do not tile the lanes, unpack to [B, H, S, D] here and run what
+    rank 4 runs."""
     mesh = getattr(ctx, "mesh", None)
-    if window is not None or k.shape[1] != q.shape[1] \
-            or v.shape[-1] != q.shape[-1] or mxu_dtype is not None \
-            or min_seq is not None:
+    packed = q.ndim == 3
+    if _forward_only(q, k, v, window, mxu_dtype, min_seq):
+        if packed:
+            raise NotImplementedError(
+                _FORWARD_ONLY + "; [B, S, H*D] operands take none of them")
         # the serving prefill's forward-only call: one device, no ids
         if seg is not None or (mesh is not None and mesh.size > 1
                                and not _in_manual_mesh()):
@@ -1251,6 +1496,11 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
         return flash_attention(q, k, v, bias, scale, causal=causal,
                                window=window, mxu_dtype=mxu_dtype,
                                min_seq=min_seq)
+
+    def local(a, b, c, d=None, heads=n_head):
+        return flash_attention(a, b, c, d, scale, causal=causal,
+                               n_head=heads)
+
     if mesh is None or mesh.size <= 1 or _in_manual_mesh():
         # _in_manual_mesh: already inside a shard_map region (pipeline
         # stage bodies, ring steps) — Mosaic-in-manual-mesh is the
@@ -1258,11 +1508,12 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
         if seg is not None:
             sm = _seg_mask_full(seg)
             bias = sm if bias is None else bias + sm
-        return flash_attention(q, k, v, bias, scale, causal=causal)
+        return local(q, k, v, bias)
 
     from jax.sharding import PartitionSpec as P
 
-    B, H, S, _D = q.shape
+    B, S = q.shape[0], q.shape[-2]
+    H = _packed_heads(q, n_head) if packed else q.shape[1]
     d_ax = getattr(ctx, "data_axis", "data")
     m_ax = getattr(ctx, "model_axis", "model")
     s_ax = getattr(ctx, "seq_axis", "seq")
@@ -1271,8 +1522,18 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
     h_ax = m_ax if (m_ax in mesh.axis_names
                     and mesh.shape[m_ax] > 1
                     and H % mesh.shape[m_ax] == 0) else None
+    seq_live = s_ax in mesh.axis_names and mesh.shape[s_ax] > 1
+    if packed:
+        if h_ax is not None:
+            n_head = H // mesh.shape[h_ax]      # a shard's heads
+        if seq_live or not _lanes_ok(n_head, q.shape[-1] // H):
+            # the ring shards [B, H, S, D] along S; a shard's heads that
+            # do not tile the lanes: run what rank 4 runs here
+            return _unpacked(
+                lambda a, b, c: _maybe_shard_mapped_flash(
+                    ctx, a, b, c, bias, scale, causal, seg=seg), q, k, v, H)
 
-    ring_ok = (s_ax in mesh.axis_names and mesh.shape[s_ax] > 1
+    ring_ok = (seq_live
                and q.shape == k.shape and S % mesh.shape[s_ax] == 0
                and (bias is None or (bias.shape[1] == 1
                                      and bias.shape[2] == 1
@@ -1311,22 +1572,17 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
         sm = _seg_mask_full(seg)
         bias = sm if bias is None else bias + sm
     if _use_interpret():
-        return flash_attention(q, k, v, bias, scale, causal=causal)
+        return local(q, k, v, bias)
 
-    qs = P(b_ax, h_ax)
-    if bias is None:
-        fn = jax.shard_map(
-            lambda a, b, c: flash_attention(a, b, c, None, scale,
-                                            causal=causal),
-            mesh=mesh, in_specs=(qs, qs, qs), out_specs=qs)
-        return fn(q, k, v)
-    bspec = P(b_ax if bias.shape[0] != 1 else None,
-              h_ax if bias.shape[1] != 1 else None)
-    fn = jax.shard_map(
-        lambda a, b, c, d: flash_attention(a, b, c, d, scale,
-                                           causal=causal),
-        mesh=mesh, in_specs=(qs, qs, qs, bspec), out_specs=qs)
-    return fn(q, k, v, bias)
+    # the heads: axis 1 of [B, H, S, D], the last of [B, S, H*D]
+    qs = P(b_ax, None, h_ax) if packed else P(b_ax, h_ax)
+    args, specs = (q, k, v), (qs, qs, qs)
+    if bias is not None:
+        args += (bias,)
+        specs += (P(b_ax if bias.shape[0] != 1 else None,
+                    h_ax if bias.shape[1] != 1 else None),)
+    return jax.shard_map(functools.partial(local, heads=n_head), mesh=mesh,
+                         in_specs=specs, out_specs=qs)(*args)
 
 
 def _in_manual_mesh() -> bool:
@@ -1356,7 +1612,8 @@ def _fused_attention(ctx, ins, attrs):
     out = _maybe_shard_mapped_flash(
         ctx, q, k, v, bias, scale, causal, seg=seg, window=window,
         mxu_dtype=attrs.get("mxu_dtype") or None,
-        min_seq=attrs.get("flash_min_seq") or None)
+        min_seq=attrs.get("flash_min_seq") or None,
+        n_head=attrs.get("n_head"))
     if dropout and not (attrs.get("is_test", False) or ctx.is_test):
         # dropout on the *output* (weights-dropout does not commute with the
         # fused kernel; divergence from the layer-composed path documented).
@@ -1457,14 +1714,9 @@ def _fused_attention_grad(ctx, ins, attrs):
     seg = (ins.get("SegmentIds") or [None])[0]
     mask = (ins.get("Mask") or [None])[0]
     g = ins["Out@GRAD"][0]
-    if attrs.get("window") or k.shape[1] != q.shape[1] \
-            or v.shape[-1] != q.shape[-1] or attrs.get("mxu_dtype") \
-            or attrs.get("flash_min_seq"):
-        raise NotImplementedError(
-            "fused_attention with a window, grouped key/value heads, a "
-            "value width of its own, mxu_dtype or flash_min_seq is "
-            "forward-only; a training build composes its band bias "
-            "(models/gpt.py build)")
+    if _forward_only(q, k, v, attrs.get("window"), attrs.get("mxu_dtype"),
+                     attrs.get("flash_min_seq")):
+        raise NotImplementedError(_FORWARD_ONLY)
     if mask is not None:
         g = (g * mask).astype(q.dtype)
     if bias is not None:
@@ -1472,8 +1724,8 @@ def _fused_attention_grad(ctx, ins, attrs):
     scale = attrs.get("scale", 1.0)
     causal = bool(attrs.get("causal", False))
     _, vjp = jax.vjp(
-        lambda a, b, c: _maybe_shard_mapped_flash(ctx, a, b, c, bias,
-                                                  scale, causal,
-                                                  seg=seg), q, k, v)
+        lambda a, b, c: _maybe_shard_mapped_flash(
+            ctx, a, b, c, bias, scale, causal, seg=seg,
+            n_head=attrs.get("n_head")), q, k, v)
     dq, dk, dv = vjp(g.astype(q.dtype))
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}

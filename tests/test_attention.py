@@ -417,7 +417,7 @@ def test_flash_block_plan_parity(case, blocks, monkeypatch):
         kernel=attention.KERNEL_REFWD,
         block="%dx%d" % attention._block_plan(
             attention.KERNEL_FWD, shape[2], shape[2], D, q.dtype, causal),
-        single_pass="1")
+        single_pass="1", layout="heads")
     before = single.value
     (_, got), g_got = jax.value_and_grad(flash_loss, argnums,
                                          has_aux=True)(q, k, v, bias)
@@ -509,3 +509,269 @@ def test_flash_block_override_keeps_its_old_contract(monkeypatch):
     assert A._resolve_blocks(*args) == (576, 512, 96, 512)
     monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "256")
     assert A._resolve_blocks(*args) == (576, 512, 96, 256)
+
+
+# ------------------------------------------ operand layouts (ISSUE 38)
+def _split(x, H):
+    B, S, HD = x.shape
+    return x.reshape(B, S, H, HD // H).transpose(0, 2, 1, 3)
+
+
+def _merge(x):
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+def _lane_plans():
+    """{(kernel, layout): count} of the flash plans lowered so far."""
+    from paddle_tpu.observe import REGISTRY
+
+    seen = {}
+    for s in REGISTRY.snapshot()["metrics"][
+            "paddle_flash_block_plans_total"]["samples"]:
+        key = (s["labels"]["kernel"], s["labels"]["layout"])
+        seen[key] = seen.get(key, 0) + s["value"]
+    return seen
+
+
+LAYOUT_CASES = [
+    # (H, D, S, causal, key-mask bias, dropout): twelve and sixteen heads
+    # of 64 (two a lane tile, four a grid step), eight of 128 (one a
+    # tile); S 384 is three lane tiles, 200 pads to 256
+    (12, 64, 256, False, True, 0.1),
+    (12, 64, 384, True, False, 0.0),
+    (12, 64, 512, False, True, 0.0),
+    (12, 64, 512, True, True, 0.1),
+    (16, 64, 256, True, True, 0.0),
+    (16, 64, 384, False, True, 0.1),
+    (16, 64, 512, False, False, 0.1),
+    (8, 128, 256, False, False, 0.1),
+    (8, 128, 384, True, True, 0.1),
+    (8, 128, 512, False, True, 0.0),
+    (6, 64, 200, False, True, 0.1),    # two heads a step: 4 does not divide 6
+    (8, 32, 256, True, True, 0.0),     # four heads a lane tile
+]
+
+
+@pytest.mark.parametrize("H,D,S,causal,masked,dropout", LAYOUT_CASES)
+def test_rank3_operands_equal_rank4_on_transposed_operands(
+        H, D, S, causal, masked, dropout, monkeypatch):
+    """The op over [B, S, H*D] operands (the kernels index the heads along
+    the lanes) against the op over the same values split to [B, H, S, D]:
+    ``Out`` is the plain result under the saved ``Mask``, and the grad op
+    replays that mask into the same three gradients."""
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    op = get_op("fused_attention")
+    rs = np.random.RandomState(H * 1000 + S + D)
+    B = 2 if S <= 256 else 1
+    q, k, v, g = (jnp.asarray(rs.randn(B, S, H * D).astype("float32"))
+                  for _ in range(4))
+    ins = {}
+    if masked:
+        ins["Bias"] = [jnp.asarray(np.where(rs.rand(B, 1, 1, S) > 0.2, 0,
+                                            -1e9).astype("float32"))]
+    attrs = {"scale": D ** -0.5, "causal": causal}
+    before = _lane_plans()
+    got = op.lowering(LowerContext(rng=jax.random.PRNGKey(S + H)),
+                      dict(ins, Q=[q], K=[k], V=[v]),
+                      dict(attrs, dropout=dropout, n_head=H))
+    out3, mask3 = got["Out"][0], got["Mask"][0]
+    lowered = {key: n - before.get(key, 0)
+               for key, n in _lane_plans().items() if n > before.get(key, 0)}
+    assert lowered == {("flash_fwd", "lanes"): 1}
+    assert out3.shape == mask3.shape == (B, S, H * D)
+    if dropout:
+        np.testing.assert_allclose(np.unique(np.asarray(mask3)),
+                                   [0.0, 1.0 / (1.0 - dropout)], rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(mask3), 1.0)
+    plain = op.lowering(LowerContext(rng=jax.random.PRNGKey(0)),
+                        dict(ins, Q=[_split(q, H)], K=[_split(k, H)],
+                             V=[_split(v, H)]),
+                        dict(attrs, dropout=0.0))["Out"][0]
+    np.testing.assert_allclose(np.asarray(out3),
+                               np.asarray(_merge(plain) * mask3),
+                               atol=1e-4, rtol=1e-4)
+    before = _lane_plans()
+    g3 = op.grad_lowering(
+        LowerContext(), dict(ins, Q=[q], K=[k], V=[v], Mask=[mask3],
+                             **{"Out@GRAD": [g]}),
+        dict(attrs, dropout=dropout, n_head=H))
+    lowered = {key for key, n in _lane_plans().items()
+               if n > before.get(key, 0)}
+    assert lowered == {("flash_refwd", "lanes"), ("flash_bwd_dkv", "lanes"),
+                       ("flash_bwd_dq", "lanes")}
+    g4 = op.grad_lowering(
+        LowerContext(), dict(ins, Q=[_split(q, H)], K=[_split(k, H)],
+                             V=[_split(v, H)], Mask=[_split(mask3, H)],
+                             **{"Out@GRAD": [_split(g, H)]}),
+        dict(attrs, dropout=dropout))
+    for slot in ("Q@GRAD", "K@GRAD", "V@GRAD"):
+        assert g3[slot][0].shape == (B, S, H * D)
+        np.testing.assert_allclose(np.asarray(g3[slot][0]),
+                                   np.asarray(_merge(g4[slot][0])),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256)])
+def test_rank3_multi_pass_carry_matches_rank4(blocks, monkeypatch):
+    """Blocks forced under the sequence: the lanes layout carries a
+    maximum, a denominator and an accumulator a head of the step (two at
+    D 64), and a causal call still skips above the diagonal."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", str(blocks[0]))
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", str(blocks[1]))
+    H, D, S = 4, 64, 512
+    rs = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rs.randn(1, S, H * D).astype("float32"))
+               for _ in range(3))
+    bias = jnp.asarray(
+        np.where(rs.rand(1, 1, 1, S) > 0.2, 0, -1e9).astype("float32"))
+
+    def packed(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, bias, D ** -0.5, causal=True,
+                                       n_head=H) ** 2)
+
+    def split(q, k, v):
+        return jnp.sum(flash_attention(_split(q, H), _split(k, H),
+                                       _split(v, H), bias, D ** -0.5,
+                                       causal=True) ** 2)
+
+    before = _lane_plans()
+    got = jax.value_and_grad(packed, (0, 1, 2))(q, k, v)
+    assert {key for key, n in _lane_plans().items()
+            if n > before.get(key, 0)} == {
+        ("flash_refwd", "lanes"), ("flash_bwd_dkv", "lanes"),
+        ("flash_bwd_dq", "lanes")}
+    want = jax.value_and_grad(split, (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+
+
+@pytest.mark.parametrize("what", ["window", "grouped_heads", "value_width",
+                                  "mxu_dtype", "flash_min_seq",
+                                  "trainable_bias", "no_n_head"])
+def test_rank3_operands_refuse_the_forward_only_features(what):
+    """[B, S, H*D] operands take what has a backward rule and nothing
+    else, under the message the grad op uses; and they need ``n_head``."""
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    H, D, S = 4, 64, 256
+    q = k = v = jnp.zeros((1, S, H * D), jnp.float32)
+    attrs = {"scale": 0.125, "causal": True, "n_head": H}
+    if what == "window":
+        attrs["window"] = 64
+    elif what == "grouped_heads":
+        k = v = jnp.zeros((1, S, 2 * D), jnp.float32)
+    elif what == "value_width":
+        v = jnp.zeros((1, S, H * 32), jnp.float32)
+    elif what == "mxu_dtype":
+        attrs["mxu_dtype"] = "bfloat16"
+    elif what == "flash_min_seq":
+        attrs["flash_min_seq"] = 128
+    elif what == "trainable_bias":
+        with pytest.raises(NotImplementedError, match="forward-only"):
+            flash_attention(q, k, v, jnp.zeros((1, H, S, S)), 0.125,
+                            bias_grad=True, n_head=H)
+        return
+    elif what == "no_n_head":
+        with pytest.raises(ValueError, match="n_head"):
+            flash_attention(q, k, v, None, 0.125)
+        with pytest.raises(ValueError, match="n_head"):
+            flash_attention(q, k, v, None, 0.125, n_head=7)
+        return
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    op = get_op("fused_attention")
+    with pytest.raises(NotImplementedError, match="forward-only"):
+        op.lowering(LowerContext(is_test=True), ins, attrs)
+    with pytest.raises(NotImplementedError, match="forward-only"):
+        op.grad_lowering(LowerContext(), dict(ins, **{"Out@GRAD": [q]}),
+                         attrs)
+
+
+@pytest.mark.parametrize("why", ["under_min_seq", "head_width_off_the_tile",
+                                 "odd_heads_a_tile"])
+def test_rank3_operands_unpack_where_the_kernel_cannot_take_them(
+        why, monkeypatch):
+    """Under ``flash_min_seq()`` the call runs the composed form, and a
+    head width (or count) that does not fill lane tiles runs the kernel
+    over [B, H, S, D]: either way inside the lowering, with the result of
+    the split call."""
+    from paddle_tpu.observe.families import KERNEL_DISPATCHES
+    from paddle_tpu.ops.attention import composed_attention
+
+    H, D, S = {"under_min_seq": (4, 64, 128),
+               "head_width_off_the_tile": (4, 48, 256),
+               "odd_heads_a_tile": (3, 64, 256)}[why]
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    rs = np.random.RandomState(9)
+    q, k, v = (jnp.asarray(rs.randn(2, S, H * D).astype("float32"))
+               for _ in range(3))
+    composed = KERNEL_DISPATCHES.labels(op="attention", impl="composed")
+    before, plans = composed.value, _lane_plans()
+
+    def packed(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, D ** -0.5, causal=True,
+                                       n_head=H) ** 2)
+
+    def reference(q, k, v):
+        return jnp.sum(_merge(composed_attention(
+            _split(q, H), _split(k, H), _split(v, H), None, D ** -0.5,
+            True)) ** 2)
+
+    got = jax.value_and_grad(packed, (0, 1, 2))(q, k, v)
+    lowered = {key for key, n in _lane_plans().items()
+               if n > plans.get(key, 0)}
+    if why == "under_min_seq":
+        assert composed.value > before and not lowered
+    else:
+        assert lowered == {("flash_refwd", "heads"),
+                           ("flash_bwd_dkv", "heads"),
+                           ("flash_bwd_dq", "heads")}
+    want = jax.value_and_grad(reference, (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+
+
+def _attention_ops(**kw):
+    """Op types of one ``multi_head_attention`` layer, in order."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", [256, 128], dtype="float32")
+        rope = kw.pop("rope", False)
+        if rope:
+            kw["rope_pos"] = fluid.layers.data(
+                "pos", [256], dtype="int64", append_batch_size=False)
+        transformer.multi_head_attention(
+            x, x, None, 128, 2, 0.1, False, "att", **kw)
+    return [op.type for op in main.global_block().ops]
+
+
+def test_fused_bert_layer_holds_no_transpose():
+    """The fused layer without rotation or grouped heads hands its three
+    projections to ``fused_attention`` as they are, [B, S, H*D] with
+    ``n_head``, and the result to the output projection."""
+    types = _attention_ops(use_fused_attention=True)
+    assert "transpose2" not in types and "reshape2" not in types
+    assert types.count("fused_attention") == 1
+    at = types.index("fused_attention")
+    assert types[:at].count("mul") == 3 and types[at + 1:].count("mul") == 1
+
+
+@pytest.mark.parametrize("kind", ["rope", "grouped_heads", "composed"])
+def test_rotated_grouped_and_composed_layers_keep_their_four_transposes(
+        kind):
+    kw = {"rope": dict(use_fused_attention=True, rope=True),
+          "grouped_heads": dict(use_fused_attention=True, n_kv_head=1),
+          "composed": dict(use_fused_attention=False)}[kind]
+    types = _attention_ops(**kw)
+    assert types.count("transpose2") == 4
+    assert types.count("fused_attention") == (kind != "composed")

@@ -127,6 +127,47 @@ def test_flash_attention_compiles_for_v5e(case, v5e, compiled_kernels):
     assert n == 3, "%s: %d Mosaic kernels" % (case, n)
 
 
+LANES_CASES = {
+    # name: (B, H, S, D), causal, bias shape or None, dtype — operands
+    # [B, S, H*D], the head a block index along the lanes (PR 38)
+    "bert_cell_b32_s512": ((32, 12, 512, 64), False, (32, 1, 1, 512), BF16),
+    "sixteen_heads": ((4, 16, 512, 64), False, (4, 1, 1, 512), BF16),
+    "causal_d128": ((2, 8, 512, 128), True, None, BF16),
+    "ragged_s384_maskbias": ((2, 12, 384, 64), False, (2, 1, 1, 384), BF16),
+    # multi-pass: two heads a step, each with a carry of its own
+    "causal_s2048": ((2, 12, 2048, 64), True, None, BF16),
+    # a full [Sq, Sk] bias: one lane tile of heads a step
+    "full_bias": ((2, 12, 512, 64), False, (2, 12, 512, 512), BF16),
+    "f32_s256": ((2, 4, 256, 64), False, None, F32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LANES_CASES))
+def test_flash_attention_over_lanes_compiles_for_v5e(case, v5e,
+                                                     compiled_kernels):
+    """The three kernels over [B, S, H*D] operands: Mosaic takes the
+    lane-tile blocks, the in-kernel lane selects and the per-head carry
+    at real sizes, and no transpose stands round the calls."""
+    from paddle_tpu.ops.attention import flash_attention
+
+    shape, causal, bias_shape, dtype = LANES_CASES[case]
+    B, H, S, D = shape
+
+    def loss(q, k, v, bias=None):
+        out = flash_attention(q, k, v, bias, D ** -0.5, causal=causal,
+                              n_head=H)
+        return jnp.sum(out.astype(F32) ** 2)
+
+    args = [((B, S, H * D), dtype)] * 3
+    if bias_shape is not None:
+        args.append((bias_shape, F32))
+    sds = [jax.ShapeDtypeStruct(sd[0], sd[1], sharding=v5e) for sd in args]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        *sds).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " transpose(" not in text
+
+
 @pytest.mark.parametrize("tokens", [32, 64, 512])
 def test_expert_layer_compiles_for_v5e(tokens, v5e, compiled_kernels):
     """OLMoE's expert layer at published widths — the decode step's 32
@@ -500,7 +541,8 @@ def test_pangu_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     block = {128: "128x128", 512: "512x512", 1024: "256x1024",
              3328: "512x512"}[P]       # 3,328 pads to 7 blocks of 512
     plan = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block=block,
-                                    single_pass="0" if P == 3328 else "1")
+                                    single_pass="0" if P == 3328 else "1",
+                                    layout="heads")
     before = plan.value
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
@@ -717,6 +759,18 @@ def _mask_sized(text, op, least=1 << 20):
     return found
 
 
+def _flash_plans_by_layout():
+    """{layout: flash kernel plans lowered so far}."""
+    from paddle_tpu.observe import REGISTRY
+
+    seen = {}
+    for s in REGISTRY.snapshot()["metrics"][
+            "paddle_flash_block_plans_total"]["samples"]:
+        lay = s["labels"]["layout"]
+        seen[lay] = seen.get(lay, 0) + s["value"]
+    return seen
+
+
 @pytest.mark.parametrize("cell", sorted(BERT_CELLS))
 def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
                                                       compiled_kernels,
@@ -749,6 +803,7 @@ def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
     plans = {site: DROPOUT_MASK_PLANS.labels(site=site, bits="rbg_u32")
              for site in ("dropout", "fused_attention")}
     before = {site: c.value for site, c in plans.items()}
+    flash_before = _flash_plans_by_layout()
     feeds = {"src_ids": (batch, seq), "sent_ids": (batch, seq),
              "input_mask": ((batch, seq), jnp.float32),
              "mask_pos": (batch, masks), "mask_label": (batch, masks),
@@ -759,6 +814,10 @@ def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
         "dropout": 2 * n_layer + 1, "fused_attention": n_layer}
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    # PR 38: every kernel of the step takes the projections as they are
+    flash_now = _flash_plans_by_layout()
+    assert {lay: flash_now.get(lay, 0) - flash_before.get(lay, 0)
+            for lay in ("lanes", "heads")} == {"lanes": n_calls, "heads": 0}
     drawn = _mask_sized(text, "rng-bit-generator")
     assert len(drawn) == 3 * n_layer + 1 == 37
     assert {int(np.prod(d)) for d in drawn} == {batch * seq * 768}
@@ -799,6 +858,47 @@ def test_dropout_mask_is_drawn_per_shard_on_the_v5e_mesh(v5e_topology):
     assert text.count(" rng-bit-generator(") == 1
     assert _mask_sized(text, "dynamic-slice") == []
     assert _mask_sized(text, "shift-right-logical") == []
+
+
+def test_packed_flash_is_wrapped_with_rank3_specs_on_the_v5e_mesh(
+        v5e_topology, compiled_kernels):
+    """``fused_attention`` and its grad op over [B, S, H*D] operands on
+    the four chips of the described 2x2, batch 128 sharded on the data
+    axis as ``bert_train_s512_dp4`` has it: the wrap hands each chip its
+    32 rows in the lanes layout, so a shard holds the four kernels and no
+    transpose."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    mesh = Mesh(np.array(v5e_topology.devices).reshape(4, 1),
+                ("data", "model"))
+    B, S, H, D = 128, 512, 12, 64
+    op = get_op("fused_attention")
+    attrs = {"scale": D ** -0.5, "n_head": H, "dropout": 0.0}
+
+    def step(q, k, v, bias, g):
+        ctx = LowerContext(mesh=mesh)
+        ins = {"Q": [q], "K": [k], "V": [v], "Bias": [bias]}
+        out = op.lowering(ctx, ins, attrs)["Out"][0]
+        grads = op.grad_lowering(ctx, dict(ins, **{"Out@GRAD": [g]}), attrs)
+        return (out,) + tuple(grads[s][0]
+                              for s in ("Q@GRAD", "K@GRAD", "V@GRAD"))
+
+    data = NamedSharding(mesh, P("data"))
+    act = jax.ShapeDtypeStruct((B, S, H * D), BF16, sharding=data)
+    bias = jax.ShapeDtypeStruct((B, 1, 1, S), F32, sharding=data)
+    before = _flash_plans_by_layout()
+    with mesh:
+        text = jax.jit(step, out_shardings=(data,) * 4).lower(
+            act, act, act, bias, act).compile().as_text()
+    now = _flash_plans_by_layout()
+    assert {lay: now.get(lay, 0) - before.get(lay, 0)
+            for lay in ("lanes", "heads")} == {"lanes": 4, "heads": 0}
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert " transpose(" not in text
+    assert "bf16[%d,%d,%d]" % (B // 4, S, H * D) in text
 
 
 def _xing():

@@ -230,3 +230,39 @@ def test_fused_attention_op_short_seq_trains(monkeypatch):
                   .randn(4, 2, 8, 32).astype("float32")},
             fetch_list=[loss])
     assert np.isfinite(np.asarray(val)).all()
+
+
+@pytest.mark.parametrize("layout", ["lanes", "heads"])
+def test_block_plan_counter_says_which_layout_a_call_took(layout,
+                                                          monkeypatch):
+    """``paddle_flash_block_plans_total{layout}``: a [B, S, H*D] call
+    counts its four kernel plans under ``lanes``, a [B, H, S, D] call
+    under ``heads``, and neither touches the other's series."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.observe.families import FLASH_BLOCK_PLANS
+    from paddle_tpu.ops import attention as A
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    assert FLASH_BLOCK_PLANS.labelnames == ("kernel", "block", "single_pass",
+                                            "layout")
+    q, k, v = _qkv(B=1, H=2, S=256, D=64)
+    if layout == "lanes":
+        q, k, v = (A._merge_heads(t) for t in (q, k, v))
+    n_head = 2 if layout == "lanes" else None
+    kernels = (A.KERNEL_FWD, A.KERNEL_REFWD, A.KERNEL_BWD_DKV,
+               A.KERNEL_BWD_DQ)
+    series = {(n, lay): FLASH_BLOCK_PLANS.labels(
+        kernel=n, block="256x256", single_pass="1", layout=lay)
+        for n in kernels for lay in ("lanes", "heads")}
+    before = {key: c.value for key, c in series.items()}
+
+    def loss(q, k, v):
+        return jnp.sum(A.flash_attention(q, k, v, None, 0.125,
+                                         n_head=n_head))
+
+    loss(q, k, v)                           # the forward's own name
+    jax.grad(loss, (0, 1, 2))(q, k, v)      # the rerun and both backwards
+    grew = {key for key, c in series.items() if c.value > before[key]}
+    assert grew == {(n, layout) for n in kernels}

@@ -493,7 +493,7 @@ def test_prefill_attention_is_the_flash_forward_at_every_length():
     cfg = tiny_cfg(n_layer=2)
     params = seeded_params(cfg, 43)
     flash = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="128x128",
-                                     single_pass="1")
+                                     single_pass="1", layout="heads")
     form = MLA_ATTENTION_PLANS.labels(form="expanded",
                                       kernel="fused_attention", block="-",
                                       widths="24x16")
@@ -525,9 +525,9 @@ def test_a_causal_length_with_no_whole_block_pads_to_whole_blocks(dk, dv):
     from paddle_tpu.ops import attention as A
 
     whole = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="512x512",
-                                     single_pass="0")
+                                     single_pass="0", layout="heads")
     one = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="128x640",
-                                   single_pass="1")
+                                   single_pass="1", layout="heads")
     before = whole.value, one.value
     rs = np.random.RandomState(dk)
     for S in (1280, 640):
@@ -632,7 +632,7 @@ def test_expanded_flash_call_pads_to_whole_blocks(monkeypatch):
     assert A._block_plan(A.KERNEL_FWD, 3328, 3328, 192, jnp.float32,
                          True) == (256, 256)
     wide = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="512x512",
-                                    single_pass="0")
+                                    single_pass="0", layout="heads")
     before = wide.value
     S, H = 640, 2
     monkeypatch.setattr(A, "_MAX_BLOCK", 256)   # 640 = 5 tiles: 128-blocks
@@ -641,7 +641,7 @@ def test_expanded_flash_call_pads_to_whole_blocks(monkeypatch):
             for _ in range(2))
     v = jnp.asarray(rs.randn(1, H, S, 16).astype("float32"))
     padded = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="256x256",
-                                      single_pass="0")
+                                      single_pass="0", layout="heads")
     b0 = padded.value
     got = A.flash_attention(q, k, v, None, 0.2, causal=True)
     assert padded.value == b0 + 1 and wide.value == before
@@ -654,7 +654,7 @@ def test_expanded_flash_call_pads_to_whole_blocks(monkeypatch):
         rtol=0)
     # not causal: the padded keys would need a mask of their own
     plain = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="128x128",
-                                     single_pass="0")
+                                     single_pass="0", layout="heads")
     p0 = plain.value
     A._forward_pallas(q, k, q, None, 0.2, causal=False)
     assert plain.value == p0 + 1

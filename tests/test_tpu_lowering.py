@@ -151,8 +151,10 @@ def test_bert_s512_train_step_holds_four_named_kernels_a_layer(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "0")
     names = (attention.KERNEL_FWD, attention.KERNEL_REFWD,
              attention.KERNEL_BWD_DKV, attention.KERNEL_BWD_DQ)
+    # (the encoder hands its projections over as they are: [B, S, H*D])
     plans = {n: FLASH_BLOCK_PLANS.labels(kernel=n, block="512x512",
-                                         single_pass="1") for n in names}
+                                         single_pass="1", layout="lanes")
+             for n in names}
     before = {n: c.value for n, c in plans.items()}
     cfg = dict(vocab=256, d_model=128, n_head=2, n_layer=2, d_ff=256,
                max_length=512, type_vocab=2, dropout=0.1)
