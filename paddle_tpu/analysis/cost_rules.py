@@ -427,6 +427,52 @@ def _cost_mhc_post(ctx):
         + rows.scaled(4 * n * (n + 2))
 
 
+def _ssm_sizes(ctx):
+    """(positions B T as a poly, H, P, G, N) or None."""
+    xs = ctx.input_shape("X")
+    H, G = int(ctx.attr("heads", 0) or 0), int(ctx.attr("groups", 0) or 0)
+    N = int(ctx.attr("state", 0) or 0)
+    if xs is None or not H or not G or xs[-1] < 0:
+        return None
+    pos = ctx.elems(tuple(xs[:-1]))
+    return None if pos is None else (pos, H, xs[-1] // H, G, N)
+
+
+@register_cost_rule("ssm_scan")
+def _cost_ssm_scan(ctx):
+    """A position and head, chunked in Q: the masked decay times
+    ``C B^T`` against ``x`` (2 Q P, and about 6 Q for the mask and its
+    exponential), the state's share (2 N P read out, 2 N P fed in); a
+    position and group ``C B^T`` (2 Q N). Bytes: the generic model's
+    (every operand once, ``Y`` and the final state)."""
+    sizes = _ssm_sizes(ctx)
+    if sizes is None:
+        return ctx.out_elems()
+    pos, H, P, G, N = sizes
+    Q = int(ctx.attr("chunk", 128) or 128)
+    return pos.scaled(H * (2 * Q * P + 4 * N * P + 6 * Q) + G * 2 * Q * N)
+
+
+@register_cost_rule("ssm_update")
+def _cost_ssm_update(ctx):
+    """Five operations a value of the state (decay, the outer product
+    fed in, the product with ``C`` summed over N). Bytes: the generic
+    model's — the state read and written once is what the step costs."""
+    st = ctx.input_shape("State")
+    state = None if st is None else ctx.elems(st)
+    return ctx.out_elems() if state is None else state.scaled(5)
+
+
+@register_cost_rule("causal_conv", "causal_conv_step")
+def _cost_causal_conv(ctx):
+    """2 K operations a value and about 5 for the silu."""
+    xs, ws = ctx.input_shape("X"), ctx.input_shape("W")
+    x = None if xs is None else ctx.elems(xs)
+    if x is None or ws is None or len(ws) != 2:
+        return ctx.out_elems()
+    return x.scaled(2 * int(ws[1]) + 5)
+
+
 @register_cost_rule("fused_attention")
 def _cost_attention(ctx):
     qs, ks = ctx.input_shape("Q"), ctx.input_shape("K")

@@ -382,6 +382,32 @@ def _fp_mhc_pre(ctx):
         + BytesPoly.from_dims(tuple(xs[:-1]) + (2 * n * (n + 2),), 4)
 
 
+@register_footprint_rule("ssm_scan")
+def _fp_ssm_scan(ctx):
+    """The operands regrouped and padded to whole chunks (x twice: in
+    and out), and a chunk's [Q, Q] masks a head of the composed form
+    (the Pallas kernel keeps one in VMEM: an upper bracket either way)."""
+    xs = ctx.input_shape("X")
+    if xs is None:
+        return None
+    H = int(ctx.attr("heads", 1) or 1)
+    Q = int(ctx.attr("chunk", 128) or 128)
+    return BytesPoly.from_dims(tuple(xs), 4).scaled(2) \
+        + BytesPoly.from_dims((xs[0], Q, Q, H), 4)
+
+
+@register_footprint_rule("ssm_update")
+def _fp_ssm_update(ctx):
+    """The per-lane rows and the columns the kernel reads beside the
+    state: [B, G, 8, L] and [B, G, N, 8]; the state itself is updated in
+    place."""
+    st = ctx.input_shape("State")
+    if st is None or len(st) != 4:
+        return None
+    return BytesPoly.from_dims((st[0], st[1], 8, st[3]), 4) \
+        + BytesPoly.from_dims((st[0], st[1], st[2], 8), 4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

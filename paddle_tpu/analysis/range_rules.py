@@ -849,6 +849,39 @@ def _rr_mhc_post(ctx):
                                  and math.isfinite(hi) and hi <= F32_MAX))
 
 
+@register_range_rule("ssm_scan", "ssm_update")
+def _rr_ssm(ctx):
+    """The state is a decaying sum (decay <= 1) of ``dt x (x) B``: finite
+    where the operands are, with no bound that a sequence's length does
+    not move; ``Y`` is its contraction with ``C`` plus ``D x``."""
+    finite = all(ctx.input_av(s).finite
+                 for s in ("X", "Dt", "Bm", "Cm", "ALog", "D", "DtBias"))
+    if ctx.op.type == "ssm_update":
+        finite = finite and ctx.input_av("State").finite
+    top = AbstractValue(-F32_MAX, F32_MAX, finite=finite)
+    ctx.set("Y", top)
+    ctx.set("StateOut", top)
+
+
+@register_range_rule("causal_conv", "causal_conv_step")
+def _rr_causal_conv(ctx):
+    """``silu`` of a K-term sum of products: at least silu's minimum
+    (-0.2785), at most the sum's bound; the carried rows are ``X``'s own
+    values (beside what ``Rows`` held)."""
+    ws = ctx.input_shape("W")
+    K = ws[1] if ws and len(ws) == 2 and ws[1] >= 0 else None
+    x = ctx.input_av("X")
+    acc = _contraction(ctx, x, ctx.input_av("W"), K)
+    if ctx.op.inputs.get("Bias"):
+        acc = av_add(acc, ctx.input_av("Bias"))
+    hi = max(acc.hi, 0.0)
+    ctx.set("Out", AbstractValue(-0.2785, hi, finite=acc.finite))
+    rows = x.join(av_const(0.0).drop_const())
+    if ctx.op.inputs.get("Rows"):
+        rows = rows.join(ctx.input_av("Rows"))
+    ctx.set("RowsOut", rows)
+
+
 @register_range_rule("moe_ffn")
 def _rr_moe_ffn(ctx):
     """Each token's output is a sum of top_k expert outputs, each scaled
@@ -858,7 +891,7 @@ def _rr_moe_ffn(ctx):
     w1s = ctx.input_shape("W1")
     D = w1s[1] if w1s and len(w1s) == 3 and w1s[1] >= 0 else None
     F = w1s[2] if w1s and len(w1s) == 3 and w1s[2] >= 0 else None
-    x = ctx.input_av("X")
+    x = ctx.input_av("XE" if ctx.num_inputs("XE") else "X")
     h = _contraction(ctx, x, ctx.input_av("W1"), D)
     if ctx.num_inputs("B1"):
         h = av_add(h, ctx.input_av("B1"))
@@ -866,6 +899,8 @@ def _rr_moe_ffn(ctx):
         h = av_mul(_sym(h), _contraction(ctx, x, ctx.input_av("W1V"), D))
     else:
         h = av_max_const(h, 0.0)
+        if ctx.attr("act") == "relu2":
+            h = av_mul(h, h)
     y = _contraction(ctx, h, ctx.input_av("W2"), F)
     if ctx.num_inputs("B2"):
         y = av_add(y, ctx.input_av("B2"))

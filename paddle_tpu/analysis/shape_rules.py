@@ -229,6 +229,65 @@ def _r_mhc_post(ctx):
 
 
 
+def _ssm_out(ctx, state_slot):
+    """``Y`` is ``X``'s shape; ``StateOut [B, G, N, (H / G) P]`` from the
+    op's widths (``state_slot`` names an input that already has it)."""
+    xs = ctx.input_shape("X")
+    H, G = int(ctx.attr("heads", 0) or 0), int(ctx.attr("groups", 0) or 0)
+    N = int(ctx.attr("state", 0) or 0)
+    if H < 1 or G < 1 or N < 1 or H % G:
+        ctx.fail("%s needs heads >= 1 in groups that divide them and "
+                 "state >= 1" % ctx.op.type)
+        return
+    if xs is None:
+        return
+    ctx.set("Y", xs)
+    wide = xs[-1]
+    if wide >= 0 and wide % H:
+        ctx.fail("X %s is not [..., %d heads * P]" % (xs, H))
+        return
+    st = ctx.input_shape(state_slot) if state_slot else None
+    if st is None and wide >= 0:
+        st = (xs[0], G, N, wide // G)
+    if st is not None:
+        ctx.set("StateOut", st)
+    for slot, want in (("Dt", H), ("Bm", G * N), ("Cm", G * N)):
+        got = ctx.input_shape(slot)
+        if got is not None and got[-1] >= 0 and got[-1] != want:
+            ctx.fail("%s %s is not [..., %d]" % (slot, got, want))
+
+
+@register_shape_rule("ssm_scan")
+def _r_ssm_scan(ctx):
+    _ssm_out(ctx, None)
+
+
+@register_shape_rule("ssm_update")
+def _r_ssm_update(ctx):
+    _ssm_out(ctx, "State")
+    xs = ctx.input_shape("X")
+    if xs is not None and len(xs) == 3 and xs[1] not in (1, -1):
+        ctx.fail("ssm_update takes one position a row; X is %s" % (xs,))
+
+
+@register_shape_rule("causal_conv", "causal_conv_step")
+def _r_causal_conv(ctx):
+    """Out is X's shape [B, T, C]; RowsOut [B, K - 1, C] under W [C, K]."""
+    xs, ws = ctx.input_shape("X"), ctx.input_shape("W")
+    if xs is not None:
+        ctx.set("Out", xs)
+    if xs is None or ws is None or len(xs) != 3 or len(ws) != 2:
+        return
+    ctx.set("RowsOut", (xs[0], ws[1] - 1, xs[2]))
+    if xs[2] >= 0 and ws[0] >= 0 and xs[2] != ws[0]:
+        ctx.fail("W %s is not [C, K] for X %s" % (ws, xs))
+    rows = ctx.input_shape("Rows")
+    if rows is not None and is_concrete(rows[1:]) and is_concrete(ws) \
+            and tuple(rows[1:]) != (ws[1] - 1, ws[0]):
+        ctx.fail("Rows %s is not [B, K - 1, C] = [B, %d, %d]"
+                 % (rows, ws[1] - 1, ws[0]))
+
+
 @register_shape_rule("cast")
 def _r_cast(ctx):
     ctx.set("Out", ctx.input_shape("X"), dtype=str(ctx.attr("out_dtype")))
@@ -902,15 +961,16 @@ def _r_rms_norm(ctx):
 
 @register_shape_rule("moe_ffn")
 def _r_moe_ffn(ctx):
-    """Out is X's shape, AuxLoss a float32 scalar, the routing tally its
+    """Out is X's shape (at XE's width where the experts read an input
+    of their own), AuxLoss a float32 scalar, the routing tally its
     input's shape; the stacked parameters must agree with one another:
-    W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [D, E], biases [E, F] and
-    [E, D]. The group sizes are data: nothing in a shape depends on
+    W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [X's width, E], biases
+    [E, F] and [E, D]. The group sizes are data: nothing in a shape depends on
     them. A share (``n_local``) stacks ``n_local`` experts where the
     router and its selection bias keep all ``n_experts``."""
-    xs = ctx.input_shape("X")
+    xs, xe = ctx.input_shape("X"), ctx.input_shape("XE")
     if xs is not None:
-        ctx.set("Out", xs)
+        ctx.set("Out", xs if xe is None else tuple(xs[:-1]) + (xe[-1],))
     ctx.set("AuxLoss", (), dtype="float32")
     if "CountsOut" in ctx.op.outputs:
         cs = ctx.input_shape("Counts")
@@ -934,8 +994,8 @@ def _r_moe_ffn(ctx):
     for slot, want in (("W1V", w1),
                        ("B1", None if w1 is None else (w1[0], w1[2])),
                        ("B2", None if w2 is None else (w2[0], w2[2])),
-                       ("Gate", None if w1 is None
-                        else (w1[1], E or w1[0])),
+                       ("Gate", None if w1 is None or xs is None
+                        or xs[-1] < 0 else (xs[-1], E or w1[0])),
                        ("RouterBias", (E,) if E else None)):
         got = ctx.input_shape(slot)
         if got is not None and want is not None and is_concrete(got) \
@@ -948,9 +1008,11 @@ def _r_moe_ffn(ctx):
     if not 0 <= int(ctx.attr("expert_first", 0) or 0) <= E - held:
         ctx.fail("experts %d.. are not a share of n_experts=%d"
                  % (int(ctx.attr("expert_first", 0)), E))
-    if xs is not None and w1 is not None and xs[-1] >= 0 \
-            and xs[-1] != w1[1]:
-        ctx.fail("X's width %d is not the experts' %d" % (xs[-1], w1[1]))
+    xin = xs if xe is None else xe
+    if xin is not None and w1 is not None and xin[-1] >= 0 \
+            and xin[-1] != w1[1]:
+        ctx.fail("%s's width %d is not the experts' %d"
+                 % ("X" if xe is None else "XE", xin[-1], w1[1]))
     k = int(ctx.attr("top_k", 1) or 1)
     if E and not 1 <= k <= E:
         ctx.fail("top_k=%d outside [1, n_experts=%d]" % (k, E))

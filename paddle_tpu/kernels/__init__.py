@@ -39,7 +39,7 @@ from .common import (assert_mosaic_ok, checked_pallas_call,  # noqa: F401
 from .registry import (KERNELS, KernelDef, all_kernels,  # noqa: F401
                        get_kernel, has_kernel, register_kernel)
 from . import (kv_cache_write, layernorm,  # noqa: F401  (register entries)
-               optimizer_update)
+               optimizer_update, ssm)
 
 __all__ = [
     "kernels_enabled", "run_kernel", "decide", "decide_and_note",

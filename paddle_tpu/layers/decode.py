@@ -11,7 +11,8 @@ from __future__ import annotations
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
-__all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+__all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post",
+           "ssm_mix", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -223,3 +224,73 @@ def kv_cache_write(cache, update, pos, name=None):
         outputs={"Out": [cache]},
         attrs={})
     return cache
+
+
+def causal_conv(x, width, prefix, rows, step=False, name=None):
+    """Causal depth-wise convolution over the sequence axis of ``x
+    [B, T, C]`` followed by silu (ops ``causal_conv`` /
+    ``causal_conv_step``, kernels/ssm.py): ``out[t] = silu(sum_j w[:, j]
+    x[t - width + 1 + j] + b)``, zeros before the sequence. ``rows`` is
+    a persistable ``[B, width - 1, C]`` var that keeps the last ``width -
+    1`` positions of ``x`` itself: a whole prompt (``step=False``)
+    overwrites it, one token (``step=True``, ``T`` = 1) reads the past
+    out of it and shifts it. Parameters ``<prefix>.w_0 [C, width]`` and
+    ``<prefix>.b_0 [C]``."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("causal_conv", name=name)
+    C = int(x.shape[-1])
+    w = helper.create_parameter(ParamAttr(name=prefix + ".w_0"),
+                                [C, int(width)], dtype="float32")
+    b = helper.create_parameter(
+        ParamAttr(name=prefix + ".b_0", initializer=Constant(0.0)),
+        [C], dtype="float32", is_bias=True)
+    out = helper.create_variable_for_type_inference("float32")
+    inputs = {"X": [x], "W": [w], "Bias": [b]}
+    if step:
+        inputs["Rows"] = [rows]
+    helper.append_op(type="causal_conv_step" if step else "causal_conv",
+                     inputs=inputs,
+                     outputs={"Out": [out], "RowsOut": [rows]},
+                     attrs={"act": True})
+    out.shape = x.shape
+    return out
+
+
+def ssm_mix(x, dt, bm, cm, state, heads, groups, n_state, prefix,
+            chunk=128, step=False, name=None):
+    """The selective state-space recurrence of a Mamba-2 layer (ops
+    ``ssm_scan`` / ``ssm_update``, kernels/ssm.py). ``x [B, T, H P]``
+    (the heads' inputs), ``dt [B, T, H]`` (raw), ``bm`` / ``cm`` ``[B,
+    T, G N]``. ``state`` is a persistable ``[B, G, N, (H / G) P]`` var:
+    a whole prompt (``step=False``) is scanned from a zero state in
+    chunks of ``chunk`` and leaves its final state there; one token
+    (``step=True``, ``T`` = 1) updates it in place. Returns ``y [B, T,
+    H P]``, the skip ``D_h x`` included. Parameters ``<prefix>_a_log``,
+    ``<prefix>_d`` and ``<prefix>_dt_b``, each ``[H]``: ``A_h = -exp(
+    a_log_h)``, ``dt = softplus(dt + dt_b)``."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("ssm_mix", name=name)
+    H = int(heads)
+
+    def vec(part, value):
+        return helper.create_parameter(
+            ParamAttr(name="%s_%s" % (prefix, part),
+                      initializer=Constant(value)),
+            [H], dtype="float32", is_bias=True)
+
+    inputs = {"X": [x], "Dt": [dt], "Bm": [bm], "Cm": [cm],
+              "ALog": [vec("a_log", 0.0)], "D": [vec("d", 1.0)],
+              "DtBias": [vec("dt_b", 0.0)]}
+    attrs = {"heads": H, "groups": int(groups), "state": int(n_state)}
+    if step:
+        inputs["State"] = [state]
+    else:
+        attrs["chunk"] = int(chunk)
+    y = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="ssm_update" if step else "ssm_scan",
+                     inputs=inputs,
+                     outputs={"Y": [y], "StateOut": [state]}, attrs=attrs)
+    y.shape = x.shape
+    return y

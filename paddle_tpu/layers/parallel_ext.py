@@ -140,7 +140,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             route_scale: float = 1.0,
             n_expert_local: Optional[int] = None, expert_first: int = 0,
             n_shared_expert: int = 0,
-            touched: Optional[Variable] = None):
+            touched: Optional[Variable] = None,
+            expert_input: Optional[Variable] = None):
     """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
@@ -156,8 +157,13 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     their expert's device with all_to_all, otherwise every expert
     computes locally (identical math).
 
-    ``act``: 'relu' experts ``relu(x W1 + b1) W2 + b2`` (the default) or
-    'swiglu' experts ``(silu(x Wg) * (x Wu)) Wd`` without biases.
+    ``act``: 'relu' experts ``relu(x W1 + b1) W2 + b2`` (the default),
+    'swiglu' experts ``(silu(x Wg) * (x Wu)) Wd`` without biases, or
+    'relu2' experts ``relu(x W1)^2 W2`` with neither a gate nor biases
+    (``<prefix>_{up,down}.w_0``). ``expert_input`` is a tensor of the
+    same leading shape as ``x`` and a width of its own that the EXPERTS
+    read (a latent of the tokens) while the router scores ``x``: the
+    expert matrices and ``out`` then have its width.
     ``dropless=True`` computes every (token, expert) pair; otherwise
     ``capacity`` (default ``ceil(2 T top_k / E)``) bounds the pairs an
     expert takes and the overflow contributes zero. ``norm_topk``: None
@@ -190,9 +196,9 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
         raise ValueError(
             "moe_ffn top_k must be in [1, n_experts]; got top_k=%s with "
             "n_experts=%s" % (top_k, n_experts))
-    if act not in ("relu", "swiglu"):
-        raise ValueError("moe_ffn act must be 'relu' or 'swiglu'; got %r"
-                         % (act,))
+    if act not in ("relu", "swiglu", "relu2"):
+        raise ValueError("moe_ffn act must be 'relu', 'swiglu' or 'relu2'; "
+                         "got %r" % (act,))
     if dropless and capacity:
         raise ValueError("moe_ffn: dropless=True takes no capacity")
     if router_score not in ("softmax", "sigmoid"):
@@ -215,11 +221,22 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
                          else "%s_%s.w_0" % (param_prefix, part), **kw)
 
     inputs = {"X": [x]}
+    D_router = D
+    if expert_input is not None:
+        if tuple(expert_input.shape[:-1]) != tuple(x.shape[:-1]):
+            raise ValueError("moe_ffn: expert_input %s does not hold x %s's "
+                             "tokens" % (expert_input.shape, x.shape))
+        inputs["XE"] = [expert_input]
+        D = int(expert_input.shape[-1])
     if act == "swiglu":
         inputs["W1"] = [mk(attr("gate"), [n_local, D, d_hidden],
                            "float32")]
         inputs["W1V"] = [mk(attr("up"), [n_local, D, d_hidden],
                             "float32")]
+        inputs["W2"] = [mk(attr("down"), [n_local, d_hidden, D],
+                           "float32")]
+    elif act == "relu2":
+        inputs["W1"] = [mk(attr("up"), [n_local, D, d_hidden], "float32")]
         inputs["W2"] = [mk(attr("down"), [n_local, d_hidden, D],
                            "float32")]
     else:
@@ -232,7 +249,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
                            "float32")]
         inputs["B2"] = [mk(ParamAttr(initializer=Constant(0.0)),
                            [n_local, D], "float32", is_bias=True)]
-    inputs["Gate"] = [mk(attr("router"), [D, n_experts], "float32")]
+    inputs["Gate"] = [mk(attr("router"), [D_router, n_experts],
+                         "float32")]
     if router_bias:
         inputs["RouterBias"] = [mk(
             ParamAttr(name=None if param_prefix is None
@@ -270,7 +288,7 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
         attrs["counts_row"] = int(counts_row)
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)
-    out.shape = x.shape
+    out.shape = tuple(x.shape[:-1]) + (D,)
     aux.shape = ()
     if n_shared_expert:
         from . import nn as _nn

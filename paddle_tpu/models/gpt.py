@@ -193,7 +193,69 @@ def base_config():
              d_ff=9216, n_dense_layer=2, n_expert=64, expert_top_k=4,
              d_expert=1024, n_shared_expert=1, router_score="sigmoid",
              router_bias=True, norm_topk=True, route_scale=2.0,
-             weight_dtype="bfloat16")"""
+             weight_dtype="bfloat16")
+
+    One mixer a layer (``mixers``; serving programs only): a list of
+    ``"ssm"`` | ``"attention"`` | ``"experts"``, one a layer, and each
+    layer is ``x + mixer(norm(x))`` behind its ONE pre-norm
+    (``gpt_<i>_pre1_ln_s``) — no attention-then-FFN pair.
+
+    * ``"ssm"`` — a Mamba-2 state-space mixer (arXiv:2405.21060):
+      ``ssm_heads`` (H) heads of ``ssm_head_dim`` (P) in ``ssm_groups``
+      (G) groups with ``ssm_state`` (N) values of state a head and
+      value, a causal depth-wise convolution of ``ssm_conv`` (K) taps,
+      the prompt scanned in chunks of ``ssm_chunk`` (default 128). With
+      ``u`` the normed input: ``[z | xBC | dt] = u W_in`` (``H P | H P +
+      2 G N | H``), ``xBC <- silu(conv(xBC) + b)``, split into ``x``,
+      ``B``, ``C``; ``dt = softplus(dt + dt_b)``; per head ``S <- exp(dt
+      A) S + dt x (x) B``, ``y = S C + D x`` with ``A = -exp(a_log)``;
+      then RMSNorm of ``y * silu(z)`` over each of the G groups of ``H P
+      / G`` values, times a scale ``[H P]``, and ``W_out``. What a
+      sequence KEEPS of such a layer has no position axis: the state
+      ``gpt_<i>_cache_s [B, G, N, (H / G) P]`` (kernels/ssm.py says why
+      it lies so) and the last ``K - 1`` rows of the un-convolved
+      ``xBC``, ``gpt_<i>_cache_x [B, K - 1, H P + 2 G N]`` — the same
+      bytes whatever its length (``cache_kind`` calls both ``state``).
+      The prefill scans the prompt (op ``ssm_scan``) and overwrites
+      both; a decode step updates them in place (``ssm_update``,
+      ``causal_conv_step``). Parameters ``gpt_<i>_ssm_in.w_0``,
+      ``gpt_<i>_ssm_conv.{w,b}_0``, ``gpt_<i>_ssm_{a_log,d,dt_b}``,
+      ``gpt_<i>_ssm_norm_s``, ``gpt_<i>_ssm_out.w_0``.
+    * ``"attention"`` — the grouped-head attention of the keys above
+      (``n_head``, ``n_kv_head``, ``d_head``; no biases), its slab and
+      the flash forward in the prefill. ``pos_emb="none"`` adds no
+      position anywhere (no table, no rotation): the recurrence of the
+      state-space layers orders the tokens, so it needs one.
+    * ``"experts"`` — the routed experts of the keys above with three
+      more: ``ffn_act="relu2"`` makes an expert ``relu(x W1)^2 W2`` with
+      neither a gate nor biases; ``d_expert_in`` is the width the
+      routed experts read and write — ``l = h W_lat_down`` (``d_model ->
+      d_expert_in``), the routed sum over experts of ``[d_expert_in,
+      d_expert]`` matrices, then ``W_lat_up`` back to ``d_model``
+      (``gpt_<i>_moe_lat_{down,up}.w_0``) — while the router and the
+      shared expert read the full ``h``; ``d_shared_expert`` is the
+      width of the ONE shared expert (of ``ffn_act``'s kind,
+      ``gpt_<i>_moe_shared_{up,down}.w_0``).
+
+    It takes none of ``attn``, ``residual``, ``layer_types``,
+    ``n_dense_layer``, ``sandwich_norm``; ``d_ff`` is not read. The
+    training build, the multi-token step, a prefix store and a draft
+    model refuse a cfg with a state-space layer by name.
+
+    NVIDIA-Nemotron-3-Super-120B-A12B (``model_type`` nemotron_h), as
+    the worked example — published widths, all 88 layers::
+
+        dict(d_model=4096, n_head=32, n_kv_head=2, d_head=128,
+             n_layer=88, vocab=131072, max_length=262144, dropout=0.0,
+             pos_emb="none", norm="rms", norm_eps=1e-5,
+             mixers=[{"M": "ssm", "*": "attention", "E": "experts"}[c]
+                     for c in hybrid_override_pattern],
+             ssm_heads=128, ssm_head_dim=64, ssm_groups=8,
+             ssm_state=128, ssm_conv=4, ssm_chunk=128,
+             ffn_act="relu2", n_expert=512, expert_top_k=22,
+             d_expert=2688, d_expert_in=1024, d_shared_expert=5376,
+             router_score="sigmoid", router_bias=True, norm_topk=True,
+             route_scale=5.0, weight_dtype="bfloat16")"""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -211,7 +273,12 @@ _CFG_KEYS = frozenset([
     "weight_dtype",
     "residual", "hc_mult", "hc_sinkhorn_iters", "hc_eps", "hc_res_clamp",
     "rope_scaling",
+    "mixers", "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
+    "ssm_conv", "ssm_chunk", "d_expert_in", "d_shared_expert",
 ])
+_SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
+             "ssm_conv")
+MIXER_KINDS = ("ssm", "attention", "experts")
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
@@ -253,10 +320,10 @@ def _check_cfg(cfg):
     if unknown:
         raise ValueError("unknown gpt cfg key(s) %s — known keys: %s"
                          % (sorted(unknown), sorted(_CFG_KEYS)))
-    for key, allowed in (("pos_emb", ("learned", "rope")),
+    for key, allowed in (("pos_emb", ("learned", "rope", "none")),
                          ("norm", ("layer", "rms")),
                          ("ffn_act", ("relu", "gelu", "swish",
-                                      "swiglu")),
+                                      "swiglu", "relu2")),
                          ("qk_norm", (True, False, "head")),
                          ("rope_layers", ("all", "sliding")),
                          ("router_score", ("softmax", "sigmoid")),
@@ -288,14 +355,25 @@ def _check_cfg(cfg):
                              "; got %r" % (cfg["n_dense_layer"],))
         if cfg.get("n_dense_layer") and "d_ff" not in cfg:
             raise ValueError("cfg['n_dense_layer'] needs cfg['d_ff']")
+        if cfg.get("d_shared_expert") and cfg.get("n_shared_expert"):
+            raise ValueError(
+                "cfg['d_shared_expert'] is the width of the ONE shared "
+                "expert: it takes no cfg['n_shared_expert']")
     else:
-        if "d_ff" not in cfg:
+        if "d_ff" not in cfg and not cfg.get("mixers"):
             raise ValueError("cfg needs 'd_ff' (or 'n_expert' experts)")
         for key in ("n_dense_layer", "n_shared_expert", "router_score",
                     "router_bias", "route_scale", "n_expert_local",
-                    "expert_first"):
+                    "expert_first", "d_expert_in", "d_shared_expert"):
             if cfg.get(key):
                 raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
+    if cfg.get("ffn_act") == "relu2" and (
+            not cfg.get("n_expert") or cfg.get("n_dense_layer")):
+        raise ValueError(
+            "cfg['ffn_act']='relu2' is the experts' (relu(x W1)^2 W2, no "
+            "gate, no biases): it needs cfg['n_expert'] and takes no "
+            "cfg['n_dense_layer'] (no dense FFN of that kind is built)")
+    _check_mixers(cfg)
     if has_streams(cfg):
         if not int(cfg.get("hc_mult") or 0) >= 1:
             raise ValueError("cfg['residual']='mhc' needs cfg['hc_mult'] "
@@ -363,6 +441,42 @@ def _check_cfg(cfg):
                          % _d_head(cfg))
 
 
+def _check_mixers(cfg):
+    """cfg['mixers'] and the keys that need it (``base_config``)."""
+    kinds = cfg.get("mixers") or ()
+    if "ssm" not in kinds:
+        for key in _SSM_KEYS + ("ssm_chunk",):
+            if cfg.get(key):
+                raise ValueError("cfg[%r] needs an 'ssm' layer in "
+                                 "cfg['mixers']" % key)
+        if cfg.get("pos_emb") == "none":
+            raise ValueError(
+                "cfg['pos_emb']='none' needs an 'ssm' layer in "
+                "cfg['mixers']: nothing else here orders the tokens")
+    if not kinds:
+        return
+    if len(kinds) != cfg["n_layer"] \
+            or any(k not in MIXER_KINDS for k in kinds):
+        raise ValueError(
+            "cfg['mixers'] must name one of %s for each of the %d layers; "
+            "got %r" % (MIXER_KINDS, cfg["n_layer"], kinds))
+    for key in ("attn", "residual", "layer_types", "n_dense_layer",
+                "sandwich_norm", "n_shared_expert"):
+        if cfg.get(key):
+            raise ValueError("cfg['mixers'] takes no cfg[%r]" % key)
+    if "experts" in kinds and not cfg.get("n_expert"):
+        raise ValueError("an 'experts' layer needs cfg['n_expert']")
+    if "ssm" in kinds:
+        for key in _SSM_KEYS:
+            if not int(cfg.get(key) or 0) >= 1:
+                raise ValueError("an 'ssm' layer needs cfg[%r] >= 1" % key)
+        if cfg["ssm_heads"] % cfg["ssm_groups"] or cfg["ssm_conv"] < 2:
+            raise ValueError(
+                "cfg['ssm_groups']=%r must divide cfg['ssm_heads']=%r, and "
+                "cfg['ssm_conv']=%r be >= 2 taps"
+                % (cfg["ssm_groups"], cfg["ssm_heads"], cfg["ssm_conv"]))
+
+
 def _lm_head(cfg, x):
     """Final projection to vocab logits. ``tie_embeddings=True`` reuses
     the input embedding (logits = x @ word_emb^T — no gpt_out_proj
@@ -420,6 +534,51 @@ def has_streams(cfg):
     """Whether a token's state is ``hc_mult`` residual streams
     (``residual='mhc'``) and not one vector."""
     return cfg.get("residual") == "mhc"
+
+
+def mixer_kind(cfg, i):
+    """Layer ``i``'s one mixer (``'ssm'`` | ``'attention'`` |
+    ``'experts'``) under cfg['mixers'], None for a cfg whose layers are
+    the attention-then-FFN pair."""
+    kinds = cfg.get("mixers")
+    return kinds[i] if kinds else None
+
+
+def has_state(cfg):
+    """Whether some layer keeps a recurrent state and not rows a
+    position (an ``'ssm'`` mixer): its caches, ``gpt_<i>_cache_s`` and
+    ``gpt_<i>_cache_x``, have no position axis, so nothing can be cut
+    out of them at a prefix's length nor rolled back by a position."""
+    return "ssm" in (cfg.get("mixers") or ())
+
+
+def ssm_widths(cfg):
+    """``(H, P, G, N, K, d_inner, conv width)`` of an 'ssm' layer."""
+    H, P = int(cfg["ssm_heads"]), int(cfg["ssm_head_dim"])
+    G, N = int(cfg["ssm_groups"]), int(cfg["ssm_state"])
+    return H, P, G, N, int(cfg["ssm_conv"]), H * P, H * P + 2 * G * N
+
+
+def cache_kind(cfg, name, max_len):
+    """What kind of cache tensor ``name`` (one of a builder's
+    ``cache_names``) is, from its name and its layer: ``'state'`` (a
+    state-space layer's state or convolution rows: no position axis),
+    ``'latent'`` (a latent layer's one tensor), ``'ring'`` (a sliding
+    layer's, shorter than ``max_len``) or ``'full'`` (a slab)."""
+    if name.endswith(("_cache_s", "_cache_x")):
+        return "state"
+    if name.endswith("_cache_c"):
+        return "latent"
+    layer = int(name.split("_")[1])
+    return "ring" if cache_rows(cfg, layer, max_len) < max_len else "full"
+
+
+def _refuse_state(cfg, who, why):
+    if has_state(cfg):
+        raise ValueError(
+            "%s: cfg['mixers'] holds 'ssm' layers, whose caches are a "
+            "recurrent state with no position axis (gpt_<i>_cache_s, "
+            "gpt_<i>_cache_x), %s" % (who, why))
 
 
 def _hc_clamp(cfg):
@@ -645,6 +804,8 @@ def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
     then the FFN or the experts (``tally``: ``_mlp``'s counts) and
     theirs."""
     x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1", mix)
+    if cfg.get("mixers"):
+        return x            # the attention was the layer's one mixer
     h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
     f = _mlp(cfg, h2, nm, i, **tally)
     return _residual(cfg, x, f, nm + "_post2", mix2)
@@ -785,13 +946,95 @@ def _mlp(cfg, h, nm, layer, counts=None, touched=None):
                                  "route_scale", "n_expert_local",
                                  "expert_first", "n_shared_expert")
              if cfg.get(k)}
+    act = "relu2" if cfg.get("ffn_act") == "relu2" else "swiglu"
+    if cfg.get("d_expert_in"):
+        # the routed experts work in a latent of the token; the router
+        # (and the shared expert) read the token itself
+        extra["expert_input"] = _fc(h, int(cfg["d_expert_in"]),
+                                    nm + "_moe_lat_down.w_0")
     out, _aux = layers.moe_ffn(
         h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
-        act="swiglu", dropless=True,
+        act=act, dropless=True,
         norm_topk=bool(cfg.get("norm_topk", False)),
         param_prefix=nm + "_moe", counts=counts, counts_row=layer,
         touched=touched, **extra)
+    if cfg.get("d_expert_in"):
+        out = _fc(out, cfg["d_model"], nm + "_moe_lat_up.w_0")
+    if cfg.get("d_shared_expert"):
+        out = layers.elementwise_add(out, _shared_expert(cfg, h, nm, act))
     return out
+
+
+def _shared_expert(cfg, h, nm, act):
+    """The one always-on expert of width cfg['d_shared_expert'] on the
+    token itself, of the routed experts' kind."""
+    wide = int(cfg["d_shared_expert"])
+    up = _fc(h, wide, nm + "_moe_shared_up.w_0")
+    if act == "relu2":
+        hid = layers.square(layers.relu(up))
+    else:
+        hid = layers.elementwise_mul(
+            layers.swish(_fc(h, wide, nm + "_moe_shared_gate.w_0")), up)
+    return _fc(hid, cfg["d_model"], nm + "_moe_shared_down.w_0")
+
+
+def _ssm_mixer(cfg, helper, h, nm, batch, T, step):
+    """A state-space mixer over the normed ``h [B, T, D]`` (``base_config``
+    has the equations): ``(out [B, T, D], [the two cache names])``. ``step``
+    is the decode form (``T`` = 1): the state and the convolution rows
+    are read and updated in place; otherwise the prompt is scanned from
+    a zero state and both are overwritten."""
+    from ..initializer import Constant
+    from ..kernels.ssm import state_shape
+
+    H, P, G, N, K, d_in, d_conv = ssm_widths(cfg)
+    proj = _fc(h, 2 * d_in + 2 * G * N + H, nm + "_ssm_in.w_0")
+
+    def cut(t, lo, hi):
+        return layers.slice(t, axes=[2], starts=[lo], ends=[hi])
+
+    z = cut(proj, 0, d_in)
+    rows = helper.create_global_variable(
+        name=nm + "_cache_x", shape=(batch, K - 1, d_conv))
+    state = helper.create_global_variable(
+        name=nm + "_cache_s", shape=state_shape(batch, H, P, G, N))
+    xbc = layers.causal_conv(cut(proj, d_in, d_in + d_conv), K,
+                             nm + "_ssm_conv", rows, step=step)
+    y = layers.ssm_mix(
+        cut(xbc, 0, d_in), cut(proj, d_in + d_conv, d_in + d_conv + H),
+        cut(xbc, d_in, d_in + G * N), cut(xbc, d_in + G * N, d_conv),
+        state, H, G, N, nm + "_ssm",
+        chunk=int(cfg.get("ssm_chunk") or 128), step=step)
+    # the gate BEFORE the norm, and the norm a group of d_in / G values
+    y = layers.reshape(layers.elementwise_mul(y, layers.swish(z)),
+                       [-1, T, G, d_in // G])
+    inv = layers.rsqrt(layers.scale(
+        layers.reduce_mean(layers.square(y), dim=[3], keep_dim=True),
+        bias=float(_rms_eps(cfg))))
+    y = layers.reshape(layers.elementwise_mul(y, inv), [-1, T, d_in])
+    scale = layers.create_parameter(
+        [d_in], "float32", name=nm + "_ssm_norm_s",
+        default_initializer=Constant(1.0))
+    y = layers.elementwise_mul(y, scale)
+    return _fc(y, cfg["d_model"], nm + "_ssm_out.w_0"), \
+        [rows.name, state.name]
+
+
+def _lone_mixer(cfg, helper, x, nm, i, batch, T, step, cache_names,
+                **tally):
+    """Layer ``i`` of a cfg['mixers'] model where its one mixer is not
+    attention: ``x`` after it, or None for an attention layer (the
+    builder's own code) and for a cfg without mixers."""
+    kind = mixer_kind(cfg, i)
+    if kind not in ("ssm", "experts"):
+        return None
+    h = _norm_of(cfg, x, nm + "_pre1")
+    if kind == "ssm":
+        y, names = _ssm_mixer(cfg, helper, h, nm, batch, T, step)
+        cache_names += names
+    else:
+        y = _mlp(cfg, h, nm, i, **tally)
+    return layers.elementwise_add(x, y)
 
 
 def _final_norm(cfg, x):
@@ -864,6 +1107,12 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
             % (cfg["weight_dtype"],))
     _refuse_streams(cfg, "build", "which have no backward: the training "
                     "build cannot take them")
+    if cfg.get("mixers"):
+        raise ValueError(
+            "build: cfg['mixers'] (one mixer a layer: %s) is the serving "
+            "programs' (prefill, decode steps) — the scan of an 'ssm' "
+            "layer has no backward and the training build keeps the "
+            "attention-then-FFN pair" % (MIXER_KINDS,))
     new_style = _new_style(cfg)
     if new_style:
         # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
@@ -1092,10 +1341,10 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     tokens = layers.data("tokens", [P], dtype="int64")
     zero = layers.fill_constant([1], "int64", 0)
 
-    use_rope = cfg.get("pos_emb", "learned") == "rope"
+    learned = cfg.get("pos_emb", "learned") == "learned"
     word = _embed(cfg, tokens, [-1, P, cfg["d_model"]])
     pos_range = layers.range(0, P, 1, "int64")
-    if use_rope:
+    if not learned:
         x = word
     else:
         pos = layers.reshape(
@@ -1111,12 +1360,17 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     # scores never exist. Every other cfg composes them, as it did
     # (so does latent attention: its expanded form, q and k wider than v)
     latent = has_latent(cfg)
-    fused = bool(cfg.get("layer_types")) or latent
+    fused = bool(cfg.get("layer_types")) or latent or bool(cfg.get("mixers"))
     bias = None if fused else _causal_bias(P)
     routed = None      # only the serving decode step tallies its routing
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
+        lone = _lone_mixer(cfg, helper, x, nm, i, batch, P, False,
+                           cache_names, counts=routed)
+        if lone is not None:
+            x = lone
+            continue
         rows = cache_rows(cfg, i, max_len)
         h, mix = _sub_input(cfg, x, nm, 1)
         if latent:
@@ -1182,7 +1436,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
-    if has_streams(cfg):
+    if has_streams(cfg) or cfg.get("mixers"):
         # the one row an admission needs, cut BEFORE the head: a plan
         # that fetches the row or its argmax holds a [1, vocab] head,
         # and only one that fetches ``logits`` (``generate``) the
@@ -1245,8 +1499,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     _check_cfg(cfg)
     if max_len is None:
         max_len = cfg["max_length"]
-    use_rope = cfg.get("pos_emb", "learned") == "rope"
-    if not use_rope and max_len > cfg["max_length"]:
+    learned = cfg.get("pos_emb", "learned") == "learned"
+    if learned and max_len > cfg["max_length"]:
         # the learned gpt_pos_emb table has cfg['max_length'] rows;
         # positions past it would CLAMP in the lookup (XLA gather) and
         # silently corrupt every token after that point
@@ -1267,8 +1521,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
 
     # [B,1] ids -> the [B,1,D] step layout
     word = _embed(cfg, token, [-1, 1, d_model])
-    if use_rope:
-        x = word                              # positions rotate q/k below
+    if not learned:
+        x = word            # positions rotate q/k below, or are not added
     else:
         pos_ids = pos if per_slot_pos else layers.reshape(pos, [1, 1])
         posv = layers.reshape(
@@ -1285,9 +1539,10 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     # (``_visibility_bias`` says why the same test serves a ring)
     pos_b, biases, ring_pos = None, {}, {}
     latent = has_latent(cfg)     # its visibility is ``mla_decode``'s own
-    for rows in dict.fromkeys(cache_rows(cfg, i, max_len)
-                              for i in range(0 if latent
-                                             else cfg["n_layer"])):
+    for rows in dict.fromkeys(
+            cache_rows(cfg, i, max_len)
+            for i in range(0 if latent else cfg["n_layer"])
+            if mixer_kind(cfg, i) in (None, "attention")):
         ar = layers.reshape(layers.range(0, rows, 1, "int64"), [1, rows])
         if pos_b is None:
             pos_b = pos if per_slot_pos else layers.reshape(pos, [1, 1])
@@ -1305,6 +1560,11 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
+        lone = _lone_mixer(cfg, helper, x, nm, i, batch, 1, True,
+                           cache_names, counts=routed, touched=touched)
+        if lone is not None:
+            x = lone
+            continue
         rows = cache_rows(cfg, i, max_len)
         if latent:
             # the absorbed form: one latent row written, and every head
@@ -1422,7 +1682,9 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     both uses (k+1 drafts, the un-cached prompt suffix), so the op
     count is bounded.
 
-    A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is a
+    A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is
+    one with a recurrent state (an ``'ssm'`` layer in ``mixers``: a
+    state has no position to resume at or rewind to) and a
     cfg with ring caches (a sliding layer whose window is shorter than
     ``max_len``): the one slab write at
     ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a
@@ -1443,6 +1705,15 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     _refuse_streams(cfg, "build_multi_token_decode_step",
                     "which the multi-token step (suffix prefill after a "
                     "prefix hit, speculative verification) does not carry")
+    _refuse_state(cfg, "build_multi_token_decode_step",
+                  "which the multi-token step (suffix prefill after a "
+                  "prefix hit, speculative verification) can neither "
+                  "resume at a prefix's length nor rewind past a rejected "
+                  "draft")
+    if cfg.get("mixers"):
+        raise ValueError(
+            "build_multi_token_decode_step: cfg['mixers'] (one mixer a "
+            "layer) is built by the prefill and the decode steps only")
     if has_rings(cfg, max_len):
         raise ValueError(
             "build_multi_token_decode_step: cfg['layer_types'] holds "

@@ -977,7 +977,8 @@ KERNEL_DISPATCHES = REGISTRY.counter(
 # loop window length K rides the same tuner/winner cache without being
 # a Pallas kernel registry entry)
 _KERNEL_OPS = ("adam_update", "attention", "kv_cache_write",
-               "layernorm_residual", "sgd_update", "train_window")
+               "layernorm_residual", "sgd_update", "ssm_scan", "ssm_update",
+               "train_window")
 for _op in _KERNEL_OPS:
     for _c in ("pallas", "composed"):
         KERNEL_WINNERS.labels(op=_op, choice=_c)
@@ -1052,7 +1053,11 @@ SERVING_CACHE_BYTES = REGISTRY.gauge(
     "row p mod window), 'full' ([b_max, n_kv, max_len, Dh] slabs) and "
     "'latent' (a latent-attention layer's ONE [b_max, 1, max_len, "
     "kv_lora_rank + d_rope] tensor: keys and values are read out of the "
-    "same row). Set where the lane builds its caches; last lane wins",
+    "same row) and 'state' (a state-space layer's [b_max, G, N, (H / G) P] "
+    "recurrent state and its [b_max, K - 1, C] convolution rows: no "
+    "position axis, the same bytes whatever a sequence's length). Kinds "
+    "are told apart by the tensor's name and layer (gpt.cache_kind). Set "
+    "where the lane builds its caches; last lane wins",
     labels=("kind",))
 
 SERVING_WEIGHT_BYTES = REGISTRY.gauge(
@@ -1088,6 +1093,18 @@ RESIDUAL_PLANS = REGISTRY.counter(
     "PADDLE_TPU_KERNELS=0). A prefill or a decode step of L layers lowers "
     "2 L of each",
     labels=("form", "op", "kernel", "streams"))
+
+SSM_PLANS = REGISTRY.counter(
+    "paddle_ssm_plans_total",
+    "Which form of the selective state-space recurrence a program holds "
+    "(gpt cfg['mixers'] with 'ssm' layers, kernels/ssm.py): one count a "
+    "call of ssm_scan (op 'scan': a whole prompt, chunked) or ssm_update "
+    "(op 'update': one token a slot into the state, in place) at "
+    "LOWERING, kernel 'pallas' or 'composed' (jax.numpy: every CPU run, "
+    "and PADDLE_TPU_KERNELS=0), with the chunk the scan blocks the "
+    "recurrence in (1 for the update). A prefill of a model with L "
+    "state-space layers lowers L scans, its decode step L updates",
+    labels=("op", "kernel", "chunk"))
 
 MHC_RES_DEVIATION = REGISTRY.gauge(
     "paddle_mhc_res_deviation",

@@ -27,5 +27,6 @@ from . import (  # noqa: F401
     pipeline_ops,
     scan_ops,
     sequence,
+    ssm_ops,
     tensor_ops,
 )

@@ -10,7 +10,10 @@ expert) pairs sorted by expert -> a grouped matmul over the ragged
 groups (``kernels/moe_gmm.py``: a Pallas kernel on the TPU, ragged_dot
 elsewhere) -> activation -> a second grouped matmul -> the gate-weighted
 sum back per token. ``act='relu'`` experts carry biases, ``'swiglu'``
-experts (gate, up, down) none. ``dropless`` computes every pair; a
+experts (gate, up, down) none, ``'relu2'`` experts (``relu(x W1)^2 W2``)
+neither a gate nor biases. With ``XE`` the experts read a tensor of
+their own width (a latent of the tokens) while the router still scores
+``X``: the output then has ``XE``'s width. ``dropless`` computes every pair; a
 ``capacity`` drops the overflow exactly as ``route_tokens`` says, by
 giving the dropped pairs to no group before the sort — one path for both.
 
@@ -42,7 +45,7 @@ __all__: List[str] = []
 
 
 def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
-             norm_topk, z_loss, scoring=None, share=None):
+             norm_topk, z_loss, scoring=None, share=None, xe=None):
     """Single-device path, dropless, capacity-bound or a share: the
     (token, expert) pairs sorted by expert, two grouped matmuls over the
     ragged groups (kernels/moe_gmm.py), the gate-weighted sum back per
@@ -59,8 +62,10 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     no group in the same way, and this chip's output is its own experts'
     part of the layer (the parts of all shares add up to the whole).
     ``scoring`` is ``router``'s ``score``/``bias``/``route_scale``.
+    ``xe [T, D']`` is what the experts read where that is not ``x`` (the
+    router's input).
 
-    Returns (out [T, D], aux, pairs given to each of the ``E`` experts
+    Returns (out [T, D] — ``D'`` with ``xe`` —, aux, pairs given to each of the ``E`` experts
     the router scores [E] int32 — a share's own groups are its slice)."""
     from ..kernels.moe_gmm import KERNEL_DOWN, KERNEL_UP, gmm
     from ..parallel.moe import route_tokens, router
@@ -89,9 +94,11 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
         sizes = routed[first:first + E]
     order = jnp.argsort(flat_e, stable=True)             # pair -> sorted
     sorted_e = jnp.minimum(flat_e[order], E - 1)
-    xs = x[order % T]                                    # [K*T, D]
+    xs = (x if xe is None else xe)[order % T]            # [K*T, D]
     if act == "swiglu":
         h = gmm(xs, (w1, w1v), sizes, name=KERNEL_UP)
+    elif act == "relu2":
+        h = jnp.square(jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)))
     else:
         h = jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)
                         + b1[sorted_e])
@@ -118,7 +125,7 @@ def _moe_ffn(ctx, ins, attrs):
     x = ins["X"][0]
     w1, w2, gate_w = ins["W1"][0], ins["W2"][0], ins["Gate"][0]
     w1v, b1, b2, counts = opt("W1V"), opt("B1"), opt("B2"), opt("Counts")
-    touched = opt("Touched")
+    touched, xe = opt("Touched"), opt("XE")
     E = int(attrs["n_experts"])
     scoring = {"score": attrs.get("router_score", "softmax"),
                "bias": opt("RouterBias"),
@@ -156,11 +163,17 @@ def _moe_ffn(ctx, ins, attrs):
             "experts, a share of the experts and the sigmoid router run "
             "on one device")
 
+    if use_ep and xe is not None:
+        raise NotImplementedError(
+            "moe_ffn: experts with an input of their own (XE) run on one "
+            "device")
     if not use_ep:
         out, aux, routed = _experts(
             xf, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
-            norm_topk, z_loss, scoring, share)
-        outs = {"Out": out.reshape(x.shape), "AuxLoss": aux}
+            norm_topk, z_loss, scoring, share,
+            None if xe is None else xe.reshape(T, -1))
+        outs = {"Out": out.reshape(x.shape[:-1] + out.shape[-1:]),
+                "AuxLoss": aux}
         row = int(attrs.get("counts_row", 0))
         if counts is not None:
             # the device-side tally of routed pairs: this layer's row,

@@ -235,16 +235,16 @@ class _Lane:
 
     def _note_cache_bytes(self) -> None:
         """``paddle_serving_cache_bytes{kind}``: what the caches this
-        lane just built hold, by each tensor's own name and shape: a
-        latent layer's one tensor (``_cache_c``), rings (shorter than
-        ``max_len``) and full slabs."""
+        lane just built hold, by each tensor's name and layer
+        (``gpt.cache_kind``): a state-space layer's state and
+        convolution rows (no position axis), a latent layer's one
+        tensor, rings (shorter than ``max_len``) and full slabs."""
         from ..observe.families import SERVING_CACHE_BYTES
 
-        held = {"ring": 0, "full": 0, "latent": 0}
+        held = {"ring": 0, "full": 0, "latent": 0, "state": 0}
         for n in self.cache_names:
             var = self._decode_prog.global_block().var(n)
-            kind = "latent" if n.endswith("_cache_c") else \
-                "ring" if var.shape[2] < self.max_len else "full"
+            kind = self._gpt.cache_kind(self.cfg, n, self.max_len)
             held[kind] += int(np.prod(var.shape)) \
                 * np.dtype(var.dtype).itemsize
         for kind, nbytes in held.items():
@@ -357,7 +357,9 @@ class _Lane:
         one suffix dispatch — then splice the rows into the big caches
         at ``slot_idx`` (ONE jitted donated dispatch for all 2*n_layer
         tensors, rings and slabs alike: each update is the prefill
-        scope's whole batch=1 tensor of the same trailing shape).
+        scope's whole batch=1 tensor of the same trailing shape; a
+        state-space layer's state and convolution rows go the same way,
+        so a slot's new tenant overwrites ALL of what the last one left).
         Registers ``prompt[:prefix_len]`` with the store on first
         sighting. Returns ``(fetch, value)``, what it brought to
         the host for the first token: ``("tokens", id)``, the last
@@ -400,7 +402,12 @@ class _Lane:
             prog = self._prefill_program(P)
             fetch, var = (("tokens", self._gpt.NEXT_TOKEN_VAR) if greedy
                           else ("logits", self._gpt.LAST_LOGITS_VAR))
-            with _tr.trace_span("serving.engine.prefill", prompt_len=P):
+            attrs = {"prompt_len": P}
+            if self._gpt.has_state(self.cfg):
+                # the chunks each state-space layer scans the prompt in
+                attrs["chunks"] = -(-P // int(
+                    self.cfg.get("ssm_chunk") or 128))
+            with _tr.trace_span("serving.engine.prefill", **attrs):
                 with self._scope_guard(self._prefill_scope):
                     (out,) = self._exe.run(
                         prog, feed={"tokens": prompt[None, :]},
@@ -602,7 +609,9 @@ class DecodeEngine:
     for a model whose cache is latent (``cfg['attn']='mla'``), which the
     multi-token step neither reads nor writes; and for a model whose
     tokens are several residual streams (``cfg['residual']='mhc'``),
-    which it does not carry.
+    which it does not carry; and for a model with state-space layers
+    (``'ssm'`` in ``cfg['mixers']``), whose state has no position to cut
+    a prefix at or rewind a draft to.
     """
 
     def __init__(self, cfg, params: Optional[Dict[str, np.ndarray]] = None,
@@ -652,6 +661,14 @@ class DecodeEngine:
                     "cfg['residual']='mhc': its tokens are %d residual "
                     "streams, and the multi-token step does not carry "
                     "streams" % (lever, int(model["hc_mult"])))
+            if gpt.has_state(model):
+                raise ValueError(
+                    "DecodeEngine: %s cannot serve a model with 'ssm' "
+                    "layers in cfg['mixers']: their caches are a "
+                    "recurrent state with no position axis "
+                    "(gpt_<i>_cache_s, gpt_<i>_cache_x) — a stored prefix "
+                    "cannot be cut out of one at its length, nor a "
+                    "rejected draft rolled back in one" % (lever,))
             if gpt.has_rings(model, self.max_len):
                 raise ValueError(
                     "DecodeEngine: %s cannot serve a model with "

@@ -983,6 +983,91 @@ def test_xing_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     print("xing prefill P=%d:" % P, mem)
 
 
+def _nemotron():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def _ssm_plans():
+    from paddle_tpu.observe.families import SSM_PLANS
+
+    return {(op, kernel): SSM_PLANS.labels(
+        op=op, kernel=kernel, chunk=chunk).value
+        for op, chunk in (("scan", "128"), ("update", "1"))
+        for kernel in ("pallas", "composed")}
+
+
+def test_nemotron_serving_decode_step_compiles_for_v5e(v5e,
+                                                       compiled_kernels):
+    """The whole ``nemotron-3-super-120b-a12b`` serving decode step (96
+    slots: 2.07 GB of state, one attention slab, 128 of 512 latent
+    experts a layer) for the described chip: five ``ssm_update`` Pallas
+    calls, every state donated into its output and none copied, 11.9 GB
+    of arguments and temporaries that fit beside them."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import ssm
+
+    gpt, cfg, serving = _nemotron()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert [gpt.cache_kind(cfg, n, S) for n in caches] \
+        == ["state"] * 10 + ["full"] * 2
+    before = _ssm_plans()
+    lowered, mut_state = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    after = _ssm_plans()
+    assert {k: after[k] - before[k] for k in after} == {
+        ("update", "pallas"): 5, ("update", "composed"): 0,
+        ("scan", "pallas"): 0, ("scan", "composed"): 0}
+    assert set(caches) <= set(mut_state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % ssm.KERNEL_UPDATE,
+                              text))) == 5
+    # no second copy of a layer's state: 96 x 8 x 128 x 1024 float32
+    assert _cache_sized(text, (B, 8, 128, 1024)) == []
+    mem = compiled.memory_analysis()
+    assert 11.8e9 < mem.argument_size_in_bytes < 12.1e9, mem
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    print("nemotron decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [128, 2048])
+def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix: five ``ssm_scan`` Pallas calls in chunks of 128, the flash
+    forward of the one attention layer, a head on ONE row, and
+    temporaries that fit beside the 11.9 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import ssm
+
+    gpt, cfg, serving = _nemotron()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    before = _ssm_plans()
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    after = _ssm_plans()
+    assert after[("scan", "pallas")] - before[("scan", "pallas")] == 5
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % ssm.KERNEL_SCAN,
+                              text))) == 5
+    assert "f32[1,%d,32768]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem
+    print("nemotron prefill P=%d:" % P, mem)
+
+
 # --------------------------------- what the bring-up found on the way
 def test_fused_attention_dropout_is_off_in_a_for_test_clone(fresh_programs):
     """Found by chip_smoke's serve phase: the fused-attention op kept its

@@ -35,7 +35,8 @@ def _clean_tuner(monkeypatch, tmp_path):
 def test_registry_catalog_contract():
     names = kernels.all_kernels()
     assert names == ["adam_update", "attention", "kv_cache_write",
-                     "layernorm_residual", "sgd_update"]
+                     "layernorm_residual", "sgd_update", "ssm_scan",
+                     "ssm_update"]
     for name in names:
         kdef = kernels.get_kernel(name)
         assert callable(kdef.fallback), name
