@@ -49,10 +49,14 @@ KERNEL_UP = "moe_gmm_up"
 KERNEL_DOWN = "moe_gmm_down"
 
 _TM = 128                      # row tile: one MXU pass of rows
-_TK_CHOICES = (1024, 512, 256, 128)
-_TN_CHOICES = (512, 256, 128)
+# an axis that one of these divides takes the first that does
+_TK_POW2 = (1024, 512, 256)
+_TN_POW2 = (512, 256)
+_LANES = 128
 _MAX_RHS_BLOCK_BYTES = 4 << 20
-_VMEM_LIMIT_BYTES = 48 << 20   # of the v5e's 128 MiB; blocks use ~12
+# of the v5e's 128 MiB; the widest plan's blocks use ~9, ~14 by
+# _vmem_bytes' count (a 2.6 MiB weight block of 2688 x 512 bf16)
+_VMEM_LIMIT_BYTES = 48 << 20
 
 
 def _silu_mul(gate, up):
@@ -68,20 +72,52 @@ def gmm_composed(lhs, rhs, group_sizes):
     return outs[0] if len(outs) == 1 else _silu_mul(*outs)
 
 
+def _tiles(axis, pow2):
+    """Candidate tiles of one axis, the preferred first: the power-of-two
+    tile that divides it, else its divisors that are multiples of 128
+    from the whole axis down, else the whole axis."""
+    for t in pow2:
+        if axis % t == 0:
+            return [t]
+    if axis % _LANES:
+        return [axis]
+    return [t for t in range(axis, 0, -_LANES) if axis % t == 0]
+
+
+def _vmem_bytes(tm, tk, tn, itemsize):
+    """What a plan keeps in VMEM at the most: float32 rows, two ``rhs``
+    (gate and up), every block double-buffered, a float32 accumulator a
+    ``rhs``."""
+    return (2 * tm * tk * 4 + 2 * 2 * tk * tn * itemsize
+            + 2 * tm * tn * 4 + 2 * tm * tn * 4)
+
+
 def gmm_plan(M, K, N, itemsize=4):
     """``(tm, tk, tn)`` or None where no legal plan exists (the caller
     then takes the composed form). ``tk``/``tn`` must divide ``K``/``N``
     — padding ``rhs`` would copy every expert's weights — or be the
-    whole axis."""
+    whole axis.
+
+    An axis that a power of two of 256 or more divides takes the largest
+    such tile up to 1024 rows (``tk``) or 512 columns (``tn``). Any other
+    axis (2688 = 21 x 128) takes the LARGEST of its divisors that are
+    multiples of 128, the whole axis included, whose weight block stays
+    within ``_MAX_RHS_BLOCK_BYTES`` and whose blocks fit VMEM, the
+    reduction before the columns: a grid step costs about 0.4 us whatever
+    it moves, and a reduction held whole re-reads no rows between the
+    work tiles of one row tile (docs/KERNELS.md "Tile plan of the grouped
+    matmul" has the sweep). An axis that 128 does not divide is taken
+    whole."""
     tm = min(_TM, ceil_to(max(int(M), 1), 8))
-    tk = next((t for t in _TK_CHOICES if K % t == 0), K)
-    tn = next((t for t in _TN_CHOICES if N % t == 0), N)
-    if tk * tn * itemsize > _MAX_RHS_BLOCK_BYTES:
-        return None
-    if not (mosaic_ok((1, tk, tn), (1, K, N))
-            and mosaic_ok((tm, tk), (ceil_to(M, tm), K))):
-        return None
-    return tm, tk, tn
+    for tk in _tiles(K, _TK_POW2):
+        for tn in _tiles(N, _TN_POW2):
+            if (tk * tn * itemsize <= _MAX_RHS_BLOCK_BYTES
+                    and _vmem_bytes(tm, tk, tn, itemsize)
+                    <= _VMEM_LIMIT_BYTES
+                    and mosaic_ok((1, tk, tn), (1, K, N))
+                    and mosaic_ok((tm, tk), (ceil_to(M, tm), K))):
+                return tm, tk, tn
+    return None
 
 
 def _work_tiles(group_sizes, M, tm, n_work):

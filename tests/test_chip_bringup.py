@@ -1001,6 +1001,31 @@ def _ssm_plans():
         for kernel in ("pallas", "composed")}
 
 
+def _gmm_plans():
+    """``{(kernel, tile, form): lowerings}`` of the grouped matmul."""
+    from paddle_tpu.observe import REGISTRY
+
+    family = REGISTRY.snapshot()["metrics"].get(
+        "paddle_moe_gmm_plans_total", {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["tile"],
+             s["labels"]["form"]): s["value"] for s in family["samples"]}
+
+
+def _assert_nemotron_gmm_plans(before, after):
+    """Five expert layers: five Pallas plans a product, none composed,
+    and the width of 2688 = 21 x 128 (the up product's columns, the down
+    product's reduction) never cut in tiles of 128."""
+    from paddle_tpu.kernels import moe_gmm
+
+    new = {k: v - before.get(k, 0) for k, v in after.items()
+           if v != before.get(k, 0)}
+    assert {form for _k, _t, form in new} == {"pallas"}, new
+    for kernel, axis in ((moe_gmm.KERNEL_UP, 2), (moe_gmm.KERNEL_DOWN, 1)):
+        mine = {tile: n for (k, tile, _f), n in new.items() if k == kernel}
+        assert sum(mine.values()) == 5, new
+        assert all(int(tile.split("x")[axis]) > 128 for tile in mine), new
+
+
 def test_nemotron_serving_decode_step_compiles_for_v5e(v5e,
                                                        compiled_kernels):
     """The whole ``nemotron-3-super-120b-a12b`` serving decode step (96
@@ -1019,10 +1044,11 @@ def test_nemotron_serving_decode_step_compiles_for_v5e(v5e,
                                                         max_len=S)
     assert [gpt.cache_kind(cfg, n, S) for n in caches] \
         == ["state"] * 10 + ["full"] * 2
-    before = _ssm_plans()
+    before, gmm_before = _ssm_plans(), _gmm_plans()
     lowered, mut_state = _lower_step(
         main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
     after = _ssm_plans()
+    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans())
     assert {k: after[k] - before[k] for k in after} == {
         ("update", "pallas"): 5, ("update", "composed"): 0,
         ("scan", "pallas"): 0, ("scan", "composed"): 0}
@@ -1053,10 +1079,11 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     with fluid.program_guard(main, startup):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
-    before = _ssm_plans()
+    before, gmm_before = _ssm_plans(), _gmm_plans()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     after = _ssm_plans()
+    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans())
     assert after[("scan", "pallas")] - before[("scan", "pallas")] == 5
     compiled = lowered.compile()
     text = compiled.as_text()

@@ -256,13 +256,18 @@ GROUPS = {
                                         [17, 130, 1, 0, 140]),
     "rows_past_the_groups": (40, 64, 32, [5, 5, 5]),
     "reduction_in_two_tiles": (20, 2048, 128, [7, 0, 13]),
+    # a width of 21 x 128 cut by its own divisors, under an explicit plan
+    "reduction_of_2688_in_three_tiles": (40, 2688, 128,
+                                         [0, 9, 0, 17, 5, 0], (40, 896, 128)),
+    "columns_of_2688_in_three_tiles": (40, 64, 2688,
+                                       [0, 9, 0, 17, 5, 0], (40, 64, 896)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(GROUPS))
 @pytest.mark.parametrize("n_rhs", [1, 2])
 def test_gmm_kernel_matches_composed_on_ragged_groups(case, n_rhs):
-    M, K, N, sizes = GROUPS[case]
+    M, K, N, sizes, *plan = GROUPS[case]
     rng = np.random.default_rng(len(case))
     lhs = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
     rhs = tuple(jnp.asarray(rng.standard_normal((len(sizes), K, N))
@@ -270,19 +275,75 @@ def test_gmm_kernel_matches_composed_on_ragged_groups(case, n_rhs):
                 for _ in range(n_rhs))
     gs = jnp.asarray(sizes, jnp.int32)
     got = moe_gmm.gmm_pallas(lhs, rhs, gs, name=moe_gmm.KERNEL_UP,
-                             interpret=True)
+                             plan=plan[0] if plan else None, interpret=True)
     want = moe_gmm.gmm_composed(lhs, rhs, gs)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
     assert not np.asarray(got[sum(sizes):]).any()
 
 
+# (M, K, N, itemsize) -> plan: both products of every benchmark
+# configuration's expert layer. The four older ones are pinned to the
+# tiles they had before PR 41 (their cells must not move); Nemotron's
+# width of 2688 = 21 x 128 takes its own largest divisor, never 128.
+PLANS = {
+    "olmoe_up": ((256, 2048, 1024, 4), (128, 1024, 512)),
+    "olmoe_down": ((256, 1024, 2048, 4), (128, 1024, 512)),
+    "olmoe_up_prefill": ((4096, 2048, 1024, 4), (128, 1024, 512)),
+    "trinity_up": ((64, 3072, 3072, 4), (64, 1024, 512)),
+    "trinity_down_prefill": ((8192, 3072, 3072, 4), (128, 1024, 512)),
+    "pangu_up": ((512, 7680, 2048, 2), (128, 512, 512)),
+    "pangu_down": ((512, 2048, 7680, 2), (128, 1024, 512)),
+    "xing_up": ((128, 3584, 1024, 2), (128, 512, 512)),
+    "xing_down": ((128, 1024, 3584, 2), (128, 1024, 512)),
+    "nemotron_up": ((2112, 1024, 2688, 2), (128, 1024, 896)),
+    "nemotron_down": ((2112, 2688, 1024, 2), (128, 2688, 512)),
+    "nemotron_up_prefill": ((45056, 1024, 2688, 2), (128, 1024, 896)),
+    "nemotron_down_prefill": ((45056, 2688, 1024, 2), (128, 2688, 512)),
+    # float32 experts of that width: the whole reduction's block is past
+    # the 4 MiB cap, the next divisor is not
+    "width_2688_float32_down": ((2112, 2688, 1024, 4), (128, 896, 512)),
+    "both_axes_2688": ((2112, 2688, 2688, 2), (128, 2688, 384)),
+    "five_lanes_taken_whole": ((64, 640, 256, 4), (64, 640, 256)),
+    "no_lane_multiple_taken_whole": ((10, 64, 32, 4), (16, 64, 32)),
+    "block_too_large": ((10, 3000, 4000, 4), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_gmm_plan(case):
+    args, want = PLANS[case]
+    assert moe_gmm.gmm_plan(*args) == want
+    if want is not None:
+        # 128 is the last resort of an axis no power of two divides
+        assert 128 not in [t for t, axis in zip(want[1:], args[1:3])
+                           if axis == 2688]
+
+
+def test_gmm_sweep_rehearses_on_the_cpu(tmp_path):
+    """``tools/gmm_sweep.py`` (the tool behind docs/KERNELS.md's table)
+    runs its cases at a tiny size in interpret mode and writes no time."""
+    import json
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "tools"))
+    try:
+        import gmm_sweep
+    finally:
+        sys.path.pop(0)
+    out = tmp_path / "sweep.json"
+    assert gmm_sweep.main(["--rehearse", "--only", "nemotron_down_decode",
+                           "--out", str(out)]) == 0
+    rows = json.loads(out.read_text())["rows"]
+    assert [r["plan"] for r in rows] == ["128x128x512", "128x384x512"]
+    assert all("device_ms" not in r and "refused" not in r
+               and r["max_abs_diff_vs_first"] < 1e-5 for r in rows)
+
+
 def test_gmm_plan_and_counter():
     from paddle_tpu.observe.families import MOE_GMM_PLANS
 
-    assert moe_gmm.gmm_plan(256, 2048, 1024) == (128, 1024, 512)
-    assert moe_gmm.gmm_plan(4096, 1024, 2048) == (128, 1024, 512)
-    assert moe_gmm.gmm_plan(10, 64, 32) == (16, 64, 32)
-    assert moe_gmm.gmm_plan(10, 3000, 4000) is None   # block too large
     child = MOE_GMM_PLANS.labels(kernel=moe_gmm.KERNEL_DOWN, tile="-",
                                  form="composed")
     before = child.value
