@@ -13,10 +13,16 @@ the flash kernels:
 * ``gmm_pallas`` — the Pallas kernel. The grid is (column tiles, work
   tiles, reduction tiles); a WORK TILE is one (group, row tile) meeting,
   so a row tile that straddles a group boundary is visited once a group
-  and stores under a row mask, and the group's ``[tk, tn]`` weight block
-  is fetched once for all the row tiles the group touches (consecutive
-  work tiles of one group keep the block index, so Pallas issues no new
-  DMA). Group ids, row-tile ids and group offsets are scalar-prefetched;
+  and stores under a row mask. Pallas issues no new DMA for a block
+  whose index the next grid step keeps, and the reduction is the
+  innermost axis: only a reduction held WHOLE (one reduction tile, which
+  is what ``gmm_plan`` chooses wherever the block fits) leaves the
+  ``[tk, tn]`` weight block's index unchanged across the consecutive
+  work tiles of one group, and the row block's across those of one row
+  tile, so that a group's weights are fetched once a column tile. A CUT
+  reduction walks its tiles inside every work tile and fetches the
+  group's ``[K, tn]`` weights, and the rows, again for each 128-row
+  tile. Group ids, row-tile ids and group offsets are scalar-prefetched;
   the static grid holds the worst case ``tiles_m + E - 1`` work tiles and
   the ones past the real count repeat the last block indices — group,
   row tile AND reduction tile: an idle tile that still walked the
@@ -49,13 +55,17 @@ KERNEL_UP = "moe_gmm_up"
 KERNEL_DOWN = "moe_gmm_down"
 
 _TM = 128                      # row tile: one MXU pass of rows
-# an axis that one of these divides takes the first that does
-_TK_POW2 = (1024, 512, 256)
-_TN_POW2 = (512, 256)
 _LANES = 128
-_MAX_RHS_BLOCK_BYTES = 4 << 20
-# of the v5e's 128 MiB; the widest plan's blocks use ~9, ~14 by
-# _vmem_bytes' count (a 2.6 MiB weight block of 2688 x 512 bf16)
+# columns: an axis that one of these divides takes the widest that fits
+_TN_POW2 = (512, 256)
+# any other axis its widest divisor up to this (1024 columns under a whole
+# reduction bought under 2% over 512 for twice the weight block)
+_TN_MAX = 1024
+# a [3584, 512] or [7680, 512] bf16 block and a [3072, 512] float32 one
+# hold their reduction whole within it
+_MAX_RHS_BLOCK_BYTES = 8 << 20
+# of the v5e's 128 MiB; the widest plan's blocks (128x7680x512 bf16, two
+# rhs) are ~39 MiB by _vmem_bytes' count
 _VMEM_LIMIT_BYTES = 48 << 20
 
 
@@ -72,16 +82,14 @@ def gmm_composed(lhs, rhs, group_sizes):
     return outs[0] if len(outs) == 1 else _silu_mul(*outs)
 
 
-def _tiles(axis, pow2):
-    """Candidate tiles of one axis, the preferred first: the power-of-two
-    tile that divides it, else its divisors that are multiples of 128
-    from the whole axis down, else the whole axis."""
-    for t in pow2:
-        if axis % t == 0:
-            return [t]
+def _tiles(axis, most=None):
+    """Tiles Mosaic takes of one axis, the largest first: its divisors
+    that are multiples of 128, the whole axis included (none wider than
+    ``most``); an axis 128 does not divide is taken whole."""
     if axis % _LANES:
         return [axis]
-    return [t for t in range(axis, 0, -_LANES) if axis % t == 0]
+    return [t for t in range(axis, 0, -_LANES)
+            if axis % t == 0 and (most is None or t <= most)]
 
 
 def _vmem_bytes(tm, tk, tn, itemsize):
@@ -98,19 +106,23 @@ def gmm_plan(M, K, N, itemsize=4):
     — padding ``rhs`` would copy every expert's weights — or be the
     whole axis.
 
-    An axis that a power of two of 256 or more divides takes the largest
-    such tile up to 1024 rows (``tk``) or 512 columns (``tn``). Any other
-    axis (2688 = 21 x 128) takes the LARGEST of its divisors that are
-    multiples of 128, the whole axis included, whose weight block stays
-    within ``_MAX_RHS_BLOCK_BYTES`` and whose blocks fit VMEM, the
-    reduction before the columns: a grid step costs about 0.4 us whatever
-    it moves, and a reduction held whole re-reads no rows between the
-    work tiles of one row tile (docs/KERNELS.md "Tile plan of the grouped
-    matmul" has the sweep). An axis that 128 does not divide is taken
-    whole."""
+    The reduction is held WHOLE (``tk = K``) wherever the ``[K, tn]``
+    weight block stays within ``_MAX_RHS_BLOCK_BYTES`` and the blocks fit
+    VMEM, at 512 columns or else at 256: only then do consecutive work
+    tiles of one group keep the weight block's index, and those of one
+    row tile the row block's, so a group's weights are fetched once a
+    column tile and not once a 128-row tile. A reduction that cannot be
+    held whole is cut by the LARGEST of its divisors that are multiples
+    of 128 that fits, the reduction chosen before the columns: a grid
+    step costs about 0.4 us whatever it moves. The columns take 512 or
+    256 where one divides them, else (2688 = 21 x 128) their largest
+    divisor that is a multiple of 128 up to ``_TN_MAX``; an axis that 128
+    does not divide is taken whole (docs/KERNELS.md "Tile plan of the
+    grouped matmul" has the sweeps)."""
     tm = min(_TM, ceil_to(max(int(M), 1), 8))
-    for tk in _tiles(K, _TK_POW2):
-        for tn in _tiles(N, _TN_POW2):
+    tns = [t for t in _TN_POW2 if N % t == 0] or _tiles(N, _TN_MAX)
+    for tk in _tiles(K):
+        for tn in tns:
             if (tk * tn * itemsize <= _MAX_RHS_BLOCK_BYTES
                     and _vmem_bytes(tm, tk, tn, itemsize)
                     <= _VMEM_LIMIT_BYTES

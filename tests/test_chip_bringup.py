@@ -1095,6 +1095,88 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     print("nemotron prefill P=%d:" % P, mem)
 
 
+# (M, K, N, itemsize) of the cells' expert products at their longest
+# prefill: pairs of the longest prompt x the stored width of a weight
+LONGEST_PREFILL_GMM = {
+    "olmoe_up": (4096, 2048, 1024, 4), "olmoe_down": (4096, 1024, 2048, 4),
+    "trinity_up": (32768, 3072, 3072, 4),
+    "trinity_down": (32768, 3072, 3072, 4),
+    "pangu_up": (26624, 7680, 2048, 2), "pangu_down": (26624, 2048, 7680, 2),
+    "xing_up": (32768, 3584, 1024, 2), "xing_down": (32768, 1024, 3584, 2),
+}
+
+
+def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
+    """PR 42: of the plans the cells' expert products take at their
+    longest prefill, the one that keeps the most VMEM by the kernel's own
+    count holds its reduction whole; the expert layer at that shape (a
+    share of 8 of 256 experts, bf16 matrices under float32 rows, gate and
+    up in one call) lowers for the described chip under that plan — the
+    counter is read round the lowering — and compiles."""
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.ops.moe_ops import _experts
+
+    def reckoned(shape):
+        return moe_gmm._vmem_bytes(*moe_gmm.gmm_plan(*shape), shape[3])
+
+    widest = max(LONGEST_PREFILL_GMM,
+                 key=lambda n: reckoned(LONGEST_PREFILL_GMM[n]))
+    assert widest == "pangu_up"
+    M, D, F, item = LONGEST_PREFILL_GMM[widest]
+    tm, tk, tn = moe_gmm.gmm_plan(M, D, F, item)
+    assert (tm, tk) == (128, D)
+    assert 32 << 20 < reckoned(LONGEST_PREFILL_GMM[widest]) \
+        <= moe_gmm._VMEM_LIMIT_BYTES
+    E, held, k = 256, 8, 8
+
+    def layer(x, router, gate, up, down):
+        out, _aux, sizes = _experts(x, gate, up, None, down, None, router,
+                                    E, k, None, "swiglu", True, 0.0,
+                                    share=(0, held))
+        return out, sizes
+
+    sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+           for shape, dtype in (((M // k, D), F32), ((D, E), BF16),
+                                ((held, D, F), BF16), ((held, D, F), BF16),
+                                ((held, F, D), BF16))]
+    before = _gmm_plans()
+    lowered = jax.jit(layer).lower(*sds)
+    after = _gmm_plans()
+    assert {key: v - before.get(key, 0) for key, v in after.items()
+            if v != before.get(key, 0)} == {
+        (moe_gmm.KERNEL_UP, "%dx%dx%d" % (tm, tk, tn), "pallas"): 1,
+        (moe_gmm.KERNEL_DOWN,
+         "%dx%dx%d" % moe_gmm.gmm_plan(*LONGEST_PREFILL_GMM["pangu_down"]),
+         "pallas"): 1}
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+
+
+@pytest.mark.parametrize("n_rhs", [1, 2])
+def test_whole_reduction_plan_matches_composed_on_ragged_groups(n_rhs):
+    """The plan Xing's up product takes (the reduction of 3,584 held
+    whole) in interpret mode against the composed form: a group that
+    straddles a row tile, an empty group, a group inside another's tile,
+    and rows past the last group, which come out zero."""
+    from paddle_tpu.kernels import moe_gmm
+
+    sizes = [130, 0, 150, 37, 0, 20]      # 337 of 400 rows are owned
+    M, K, N = 400, 3584, 1024
+    plan = moe_gmm.gmm_plan(M, K, N, 2)
+    assert plan[:2] == (128, K)
+    rng = np.random.default_rng(42)
+    lhs = jnp.asarray(rng.standard_normal((M, K)), F32)
+    rhs = tuple(jnp.asarray(rng.standard_normal((len(sizes), K, N))
+                            / K ** 0.5, BF16) for _ in range(n_rhs))
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = moe_gmm.gmm_pallas(lhs, rhs, gs, name=moe_gmm.KERNEL_UP,
+                             plan=plan, interpret=True)
+    want = moe_gmm.gmm_composed(lhs, rhs, gs)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    assert not np.asarray(got[sum(sizes):]).any()
+
+
 # --------------------------------- what the bring-up found on the way
 def test_fused_attention_dropout_is_off_in_a_for_test_clone(fresh_programs):
     """Found by chip_smoke's serve phase: the fused-attention op kept its

@@ -255,7 +255,8 @@ GROUPS = {
     "sizes_not_multiples_of_the_tile": (300, 256, 128,
                                         [17, 130, 1, 0, 140]),
     "rows_past_the_groups": (40, 64, 32, [5, 5, 5]),
-    "reduction_in_two_tiles": (20, 2048, 128, [7, 0, 13]),
+    "reduction_in_two_tiles": (20, 2048, 128, [7, 0, 13], (24, 1024, 128)),
+    "reduction_of_2048_whole": (20, 2048, 128, [7, 0, 13]),
     # a width of 21 x 128 cut by its own divisors, under an explicit plan
     "reduction_of_2688_in_three_tiles": (40, 2688, 128,
                                          [0, 9, 0, 17, 5, 0], (40, 896, 128)),
@@ -282,27 +283,43 @@ def test_gmm_kernel_matches_composed_on_ragged_groups(case, n_rhs):
 
 
 # (M, K, N, itemsize) -> plan: both products of every benchmark
-# configuration's expert layer. The four older ones are pinned to the
-# tiles they had before PR 41 (their cells must not move); Nemotron's
-# width of 2688 = 21 x 128 takes its own largest divisor, never 128.
+# configuration's expert layer, at the decode step and at the longest
+# prompt. PR 42: the reduction is held whole wherever its weight block
+# fits 8 MiB and VMEM, at 512 columns or else at 256; Nemotron's plans
+# (a width of 2688 = 21 x 128 takes its own largest divisor, never 128)
+# were whole already and are PR 41's string for string.
 PLANS = {
-    "olmoe_up": ((256, 2048, 1024, 4), (128, 1024, 512)),
+    "olmoe_up": ((256, 2048, 1024, 4), (128, 2048, 512)),
     "olmoe_down": ((256, 1024, 2048, 4), (128, 1024, 512)),
-    "olmoe_up_prefill": ((4096, 2048, 1024, 4), (128, 1024, 512)),
-    "trinity_up": ((64, 3072, 3072, 4), (64, 1024, 512)),
-    "trinity_down_prefill": ((8192, 3072, 3072, 4), (128, 1024, 512)),
-    "pangu_up": ((512, 7680, 2048, 2), (128, 512, 512)),
-    "pangu_down": ((512, 2048, 7680, 2), (128, 1024, 512)),
-    "xing_up": ((128, 3584, 1024, 2), (128, 512, 512)),
+    "olmoe_up_prefill": ((4096, 2048, 1024, 4), (128, 2048, 512)),
+    "trinity_up": ((64, 3072, 3072, 4), (64, 3072, 512)),
+    "trinity_down_prefill": ((8192, 3072, 3072, 4), (128, 3072, 512)),
+    "pangu_up": ((512, 7680, 2048, 2), (128, 7680, 512)),
+    "pangu_down": ((512, 2048, 7680, 2), (128, 2048, 512)),
+    "pangu_up_prefill": ((26624, 7680, 2048, 2), (128, 7680, 512)),
+    "pangu_down_prefill": ((26624, 2048, 7680, 2), (128, 2048, 512)),
+    "xing_up": ((128, 3584, 1024, 2), (128, 3584, 512)),
     "xing_down": ((128, 1024, 3584, 2), (128, 1024, 512)),
+    "xing_up_prefill": ((32768, 3584, 1024, 2), (128, 3584, 512)),
     "nemotron_up": ((2112, 1024, 2688, 2), (128, 1024, 896)),
     "nemotron_down": ((2112, 2688, 1024, 2), (128, 2688, 512)),
     "nemotron_up_prefill": ((45056, 1024, 2688, 2), (128, 1024, 896)),
     "nemotron_down_prefill": ((45056, 2688, 1024, 2), (128, 2688, 512)),
-    # float32 experts of that width: the whole reduction's block is past
-    # the 4 MiB cap, the next divisor is not
-    "width_2688_float32_down": ((2112, 2688, 1024, 4), (128, 896, 512)),
-    "both_axes_2688": ((2112, 2688, 2688, 2), (128, 2688, 384)),
+    # float32 experts of that width: 5.25 MiB a block, inside the cap
+    "width_2688_float32_down": ((2112, 2688, 1024, 4), (128, 2688, 512)),
+    "both_axes_2688": ((2112, 2688, 2688, 2), (128, 2688, 896)),
+    # the cap and the VMEM reckoning: a block of exactly 8 MiB is held
+    # whole (float32 needs 256 columns for it); past the cap at 512 and
+    # at 256 columns the reduction falls to its largest divisor that is
+    # a multiple of 128 and fits (8320 = 65 x 128: 13 x 128); a bf16
+    # block of 8 MiB at 256 columns whose float32 rows overrun VMEM
+    # falls to half the reduction
+    "whole_just_inside_the_cap": ((512, 8192, 1024, 2), (128, 8192, 512)),
+    "whole_float32_at_256_columns": ((512, 8192, 1024, 4),
+                                     (128, 8192, 256)),
+    "just_outside_falls_to_the_largest_cut": ((512, 8320, 1024, 4),
+                                              (128, 1664, 512)),
+    "whole_overruns_vmem": ((512, 16384, 1024, 2), (128, 8192, 512)),
     "five_lanes_taken_whole": ((64, 640, 256, 4), (64, 640, 256)),
     "no_lane_multiple_taken_whole": ((10, 64, 32, 4), (16, 64, 32)),
     "block_too_large": ((10, 3000, 4000, 4), None),

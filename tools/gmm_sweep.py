@@ -6,9 +6,9 @@ off a profiler trace (docs/KERNELS.md "Tile plan of the grouped matmul").
     JAX_PLATFORMS=cpu python tools/gmm_sweep.py --rehearse   # tiny, no times
 
 A CASE is one product of one configuration's expert layer at one row
-count: ``lhs [M, K]`` float32 rows sorted by expert, ``rhs [E, K, N]``
-bfloat16, ``n_rhs`` of them (2 for gated experts), the group sizes drawn
-as a router would leave them — ``tokens x top_k`` pairs over ``routed``
+count: ``lhs [M, K]`` float32 rows sorted by expert, ``rhs [E, K, N]`` in
+the configuration's weight dtype, ``n_rhs`` of them (2 for gated
+experts), the group sizes drawn as a router would leave them — ``tokens x top_k`` pairs over ``routed``
 experts of unequal popularity, of which this chip holds the first ``E``,
 so most rows belong to no group where it holds a share. Every candidate
 plan of a case runs ``--reps`` times inside one ``jax.profiler`` trace
@@ -33,11 +33,20 @@ sys.path.insert(0, REPO)
 
 TRACE_DIR = os.path.join(REPO, ".bench_trace", "gmm_sweep")
 
-# name: (tokens a call, top_k, routed, held E, K, N, n_rhs, [(tk, tn)...])
-# the first plan of a case is the one gmm_plan gave before PR 41
-NEMOTRON_UP = [(1024, 128), (1024, 384), (1024, 896)]
+# name: (tokens a call, top_k, routed, held E, K, N, n_rhs, weight dtype,
+#        [(tk, tn)...]); the first plan of a case is the one gmm_plan gave
+# before the PR that added the case (PR 41: Nemotron's; PR 42: the rest)
+NEMOTRON_UP = [(1024, 128), (1024, 384), (1024, 896), (1024, 2688)]
 NEMOTRON_DOWN = [(128, 512), (384, 512), (896, 512), (2688, 512),
                  (896, 1024)]
+# PR 42: the reduction held whole against its cuts, at 512 and 256 columns
+# and under a weight-block cap of 4 or 8 MiB
+XING_UP = [(512, 512), (1792, 512), (3584, 256), (3584, 512), (3584, 1024)]
+PANGU_UP = [(512, 512), (1536, 512), (3840, 512), (7680, 256), (7680, 512)]
+PANGU_DOWN = [(1024, 512), (2048, 256), (2048, 512)]
+TRINITY = [(1024, 512), (1536, 512), (3072, 256), (3072, 512)]
+OLMOE_UP = [(1024, 512), (2048, 256), (2048, 512)]
+XING_DOWN = [(1024, 512)]          # whole before PR 42: the reference
 
 
 def cases():
@@ -45,29 +54,40 @@ def cases():
     for tokens in (96, 128, 512, 2048):
         what = "decode" if tokens == 96 else "prefill%d" % tokens
         out["nemotron_up_" + what] = (tokens, 22, 512, 128, 1024, 2688, 1,
-                                      NEMOTRON_UP)
+                                      "bfloat16", NEMOTRON_UP)
         out["nemotron_down_" + what] = (tokens, 22, 512, 128, 2688, 1024,
-                                        1, NEMOTRON_DOWN)
-    # not adopted: the reductions of the two older bf16 configurations
-    # that a wider multiple of 128 also divides
-    out["pangu_up_decode"] = (64, 8, 256, 8, 7680, 2048, 2,
-                              [(512, 512), (768, 512), (1536, 512)])
-    out["pangu_up_prefill1024"] = (1024, 8, 256, 8, 7680, 2048, 2,
-                                   [(512, 512), (768, 512), (1536, 512)])
-    out["xing_up_decode"] = (32, 4, 64, 64, 3584, 1024, 2,
-                             [(512, 512), (896, 512), (1792, 512)])
-    out["xing_up_prefill2048"] = (2048, 4, 64, 64, 3584, 1024, 2,
-                                  [(512, 512), (896, 512), (1792, 512)])
+                                        1, "bfloat16", NEMOTRON_DOWN)
+
+    def add(stem, decode, prefills, *shape):
+        for tokens in (decode,) + prefills:
+            what = "decode" if tokens == decode else "prefill%d" % tokens
+            out["%s_%s" % (stem, what)] = (tokens,) + shape
+
+    # the decode call is one token a slot: b_max tokens
+    add("xing_up", 32, (512, 2048, 6144, 8192),
+        4, 64, 64, 3584, 1024, 2, "bfloat16", XING_UP)
+    add("xing_down", 32, (512, 2048, 6144, 8192),
+        4, 64, 64, 1024, 3584, 1, "bfloat16", XING_DOWN)
+    add("pangu_up", 64, (1024, 3328),
+        8, 256, 8, 7680, 2048, 2, "bfloat16", PANGU_UP)
+    add("pangu_down", 64, (1024, 3328),
+        8, 256, 8, 2048, 7680, 1, "bfloat16", PANGU_DOWN)
+    add("trinity_up", 16, (8192,),
+        4, 256, 8, 3072, 3072, 2, "float32", TRINITY)
+    add("trinity_down", 16, (8192,),
+        4, 256, 8, 3072, 3072, 1, "float32", TRINITY)
+    add("olmoe_up", 32, (512,),
+        8, 64, 64, 2048, 1024, 2, "float32", OLMOE_UP)
     return out
 
 
-def group_sizes(rng, tokens, top_k, routed, held):
+def group_sizes(rng, tokens, top_k, routed, held, sigma=1.0):
     """Pairs each held expert is given when ``tokens`` tokens choose
     ``top_k`` distinct experts of ``routed`` whose popularity is
     log-normal (a seeded router does not spread its pairs evenly:
     PERF.md section 5 reads 81% of the held experts touched where even
     routing would touch 98%)."""
-    logits = rng.normal(0.0, 1.0, routed)
+    logits = rng.normal(0.0, sigma, routed)
     noise = rng.gumbel(size=(tokens, routed))
     chosen = np.argsort(-(logits + noise), axis=1)[:, :top_k]
     return np.bincount(chosen.reshape(-1), minlength=routed)[:held]
@@ -77,6 +97,11 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--only", default="", help="substring of case names")
+    ap.add_argument("--seed", type=int, default=41,
+                    help="of the routers' draws (the tables: 41)")
+    ap.add_argument("--sigma", type=float, default=1.0,
+                    help="spread of the experts' log-popularity (the "
+                         "tables: 1; 0 is an even router)")
     ap.add_argument("--out", default=os.path.join(REPO, "chiprun_out",
                                                   "gmm_sweep.json"))
     ap.add_argument("--rehearse", action="store_true",
@@ -94,34 +119,35 @@ def main(argv=None):
     if not args.rehearse and dev.platform != "tpu":
         raise SystemExit("gmm_sweep: times come from a TPU; this is %s"
                          % dev.platform)
-    rng = np.random.default_rng(41)
+    rng = np.random.default_rng(args.seed)
     rows = []
-    for cname, (tokens, top_k, routed, E, K, N, n_rhs, plans) in \
+    for cname, (tokens, top_k, routed, E, K, N, n_rhs, dtype, plans) in \
             cases().items():
         if args.only not in cname:
             continue
         if args.rehearse:
             tokens, routed, E, plans = 16, 4 * min(E, 8), min(E, 8), plans[:2]
-        sizes = group_sizes(rng, tokens, top_k, routed, E)
+        sizes = group_sizes(rng, tokens, top_k, routed, E, args.sigma)
         M = tokens * top_k
         lhs = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
         rhs = tuple(
             (jax.random.normal(jax.random.PRNGKey(i), (E, K, N),
                                jnp.float32) / K ** 0.5
-             ).astype(jnp.bfloat16) for i in range(n_rhs))
+             ).astype(dtype) for i in range(n_rhs))
         gs = jnp.asarray(sizes, jnp.int32)
         tm = min(128, ceil_to(M, 8))
         runs, want = [], None
         for tk, tn in plans:
             key = "gmmsweep_%03d_end" % len(rows)
             row = {"case": cname, "M": M, "K": K, "N": N, "n_rhs": n_rhs,
+                   "weights": dtype,
                    "groups": E, "touched": int((sizes > 0).sum()),
                    "rows_owned": int(sizes.sum()),
                    "plan": "%dx%dx%d" % (tm, tk, tn),
                    "grid_steps": (N // tn) * (ceil_to(M, tm) // tm + E - 1)
                    * (K // tk),
-                   "weight_bytes": int((sizes > 0).sum()) * K * N * 2
-                   * n_rhs}
+                   "weight_bytes": int((sizes > 0).sum()) * K * N
+                   * jnp.dtype(dtype).itemsize * n_rhs}
             rows.append(row)
             try:
                 fn = jax.jit(lambda a, b, g, _p=(tm, tk, tn), _k=key:
