@@ -20,7 +20,7 @@ See docs/ANALYSIS.md for the rule catalog and how to write a rule.
 from . import range_rules  # noqa: F401  (attaches the transfer set)
 from . import shape_rules  # noqa: F401  (attaches the core rule set)
 from .cost import (CostAnalysis, DeviceModel,  # noqa: F401
-                   cost_model_enabled, predict_step_seconds)
+                   predict_step_seconds)
 from .cost_rules import register_cost_rule  # noqa: F401 (attaches rules)
 from .dataflow import Dataflow  # noqa: F401
 from .distributed import (BARRIER_OPS, WIRE_OPS,  # noqa: F401
@@ -59,7 +59,6 @@ __all__ = [
     "RangeContext",
     "RewriteViolation",
     "WIRE_OPS",
-    "cost_model_enabled",
     "decode_cache_bytes",
     "describe_rewrites",
     "device_budget",

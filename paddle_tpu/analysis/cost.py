@@ -10,25 +10,21 @@ roofline estimate
                                    bytes_op / peak_bandwidth)
                         + n_ops * op_overhead + call_overhead / K
 
-so every tuning decision in the framework can be RANKED before anything
-is measured. That is TVM's thesis (PAPERS.md, arXiv:1802.04799): a cost
-model prunes the candidate space and measurement only confirms the top
-few — ``kernels/autotune.py`` is the one global autotuner built on this
-engine. TPP (arXiv:2104.05755) supplies the shape: the whole-program
-estimate composes from per-primitive rules.
+so a program can be priced before anything is compiled
+(``tools/cost_report.py``; the cost lints). TPP (arXiv:2104.05755)
+supplies the shape: the whole-program estimate composes from
+per-primitive rules.
 
 Both FLOPs and bytes are :class:`~paddle_tpu.analysis.memory.BytesPoly`
 polynomials of the batch dim, so ONE analysis prices every batch size
 (and every window length K — the per-call host overhead amortizes by
-K, which is exactly what the train-window tuner trades off).
+K).
 
 Device peaks come from a small calibrated :class:`DeviceModel`: known
 TPU generations resolve from a static peak table; anything else (the
-CPU backend included) is probed once — a jitted GEMM for achievable
-FLOP/s, a jitted copy for achievable bandwidth, dispatch timings for
-the overhead terms — and persisted next to the kernel tier's
-``tuned_kernels.json`` (``device_model.json``, same atomic tmp+rename
-discipline), so no process ever pays the probe twice. Per-field env
+CPU backend included) is probed once a process — a jitted GEMM for
+achievable FLOP/s, a jitted copy for achievable bandwidth, dispatch
+timings for the overhead terms. Per-field env
 overrides (``PADDLE_TPU_PEAK_TFLOPS`` / ``PADDLE_TPU_PEAK_GBPS`` /
 ``PADDLE_TPU_OP_OVERHEAD_US`` / ``PADDLE_TPU_CALL_OVERHEAD_US``) pin
 the model exactly — deterministic tests set all four and never probe.
@@ -39,16 +35,10 @@ it brackets the step cost coarsely. The model-zoo gate in
 tests/test_cost.py holds predicted within ``ZOO_COST_GATE_FACTOR``
 (4x) of the measured step on >= 9/11 train programs, the same
 anchored-to-ground-truth contract as the memory engine's 2x gate.
-
-``PADDLE_TPU_COST_MODEL=0`` disarms every consumer (the autotuner
-measures everything) and no
-``paddle_cost_*`` family moves — the degrade-to-today contract
-tests/test_autotune.py pins.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 import time
@@ -60,7 +50,7 @@ from .dataflow import Dataflow
 from .memory import BytesPoly, dtype_bytes
 
 __all__ = ["CostAnalysis", "DeviceModel", "ZOO_COST_GATE_FACTOR",
-           "cost_model_enabled", "predict_step_seconds"]
+           "predict_step_seconds"]
 
 # the stated factor of the model-zoo ground-truth gate: predicted step
 # seconds must sit within [measured/F, measured*F] on >= 9/11 zoo train
@@ -68,9 +58,6 @@ __all__ = ["CostAnalysis", "DeviceModel", "ZOO_COST_GATE_FACTOR",
 # pre-compile roofline that cannot see XLA fusion or layout — the
 # memory engine gets 2x because bytes-at-rest is a far easier target
 ZOO_COST_GATE_FACTOR = 4.0
-
-DEVICE_MODEL_VERSION = 1
-DEVICE_MODEL_FILE = "device_model.json"
 
 # chip peak FLOP/s and HBM bandwidth by device_kind substring
 # (lowercase); probing a real TPU would measure achieved-not-peak, so
@@ -105,13 +92,6 @@ _MODEL_LOCK = threading.RLock()
 _MODEL_CACHE: Dict[tuple, "DeviceModel"] = {}
 
 
-def cost_model_enabled() -> bool:
-    """``PADDLE_TPU_COST_MODEL=0`` disarms every cost-model consumer:
-    the unified autotuner degrades to measure-everything and no
-    ``paddle_cost_*`` family moves (default ON)."""
-    return os.environ.get("PADDLE_TPU_COST_MODEL", "1") != "0"
-
-
 def _env_float(name: str, scale: float) -> Optional[float]:
     raw = os.environ.get(name, "").strip()
     if not raw:
@@ -139,8 +119,7 @@ class DeviceModel:
     individually tiny; ``call_overhead`` (seconds per dispatched call:
     host feed/fetch + dispatch round trip) is what a train window of
     length K divides by K. Resolution per field: env override >
-    persisted calibration > TPU peak table > one-shot probe
-    (persisted) > static defaults."""
+    TPU peak table > one probe a process > static defaults."""
 
     __slots__ = ("kind", "peak_flops", "peak_bandwidth", "op_overhead",
                  "call_overhead", "conv_peak_flops", "source")
@@ -220,8 +199,6 @@ class DeviceModel:
                 base = cls(kind, val, _TPU_PEAK_BW[key], source="table")
                 break
         if base is None:
-            base = cls._load_calibrated(kind)
-        if base is None:
             base = cls._calibrate(kind)
         if base is None:
             base = cls(kind, 50e9, 10e9, source="default")
@@ -237,74 +214,12 @@ class DeviceModel:
                        source="env")
         return base
 
-    # ------------------------------------------------------ persistence
-    @staticmethod
-    def _path() -> Optional[str]:
-        from ..kernels import tune
-
-        d = tune.cache_dir()
-        return os.path.join(d, DEVICE_MODEL_FILE) if d else None
-
-    @classmethod
-    def _load_calibrated(cls, kind: str) -> Optional["DeviceModel"]:
-        path = cls._path()
-        if not path or not os.path.exists(path):
-            return None
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except (ValueError, OSError):
-            return None
-        if not isinstance(data, dict) \
-                or data.get("version") != DEVICE_MODEL_VERSION:
-            return None
-        entry = (data.get("models") or {}).get(kind)
-        if not isinstance(entry, dict):
-            return None
-        try:
-            return cls(kind, float(entry["peak_flops"]),
-                       float(entry["peak_bandwidth"]),
-                       float(entry["op_overhead"]),
-                       float(entry["call_overhead"]),
-                       conv_peak_flops=float(
-                           entry.get("conv_peak_flops") or 0) or None,
-                       source="calibrated")
-        except (KeyError, TypeError, ValueError):
-            return None
-
-    def persist(self) -> None:
-        """Read-merge-write ``device_model.json`` atomically (the
-        tuned_kernels.json discipline: unique tmp name, os.replace)."""
-        path = self._path()
-        if not path:
-            return
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        models = {}
-        try:
-            with open(path) as f:
-                data = json.load(f)
-            if isinstance(data, dict) \
-                    and data.get("version") == DEVICE_MODEL_VERSION \
-                    and isinstance(data.get("models"), dict):
-                models = data["models"]
-        except (ValueError, OSError):
-            pass
-        entry = self.to_dict()
-        entry.pop("kind", None)
-        entry.pop("source", None)
-        models[self.kind] = entry
-        tmp = "%s.tmp.%d.%d" % (path, os.getpid(), id(self))
-        with open(tmp, "w") as f:
-            json.dump({"version": DEVICE_MODEL_VERSION, "models": models},
-                      f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-
     # ------------------------------------------------------ calibration
     @classmethod
     def _calibrate(cls, kind: str) -> Optional["DeviceModel"]:
         """Probe achievable GEMM FLOP/s, copy bandwidth and dispatch
-        overheads on the live backend; persist so the probe runs once
-        per machine. Any failure returns None (caller defaults)."""
+        overheads on the live backend (``current`` memoizes it, so once
+        a process). Any failure returns None (caller defaults)."""
         try:
             import jax
             import jax.numpy as jnp
@@ -356,15 +271,10 @@ class DeviceModel:
             op_overhead = max((t_chain - call_overhead) / k,
                               _CALIBRATED_OP_OVERHEAD_FLOOR)
 
-            model = cls(kind, peak_flops, peak_bw, op_overhead,
-                        call_overhead, conv_peak_flops=min(
-                            conv_peak, peak_flops),
-                        source="calibrated")
-            try:
-                model.persist()
-            except OSError:
-                pass
-            return model
+            return cls(kind, peak_flops, peak_bw, op_overhead,
+                       call_overhead, conv_peak_flops=min(
+                           conv_peak, peak_flops),
+                       source="calibrated")
         except Exception:
             return None
 

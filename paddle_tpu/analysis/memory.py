@@ -44,12 +44,10 @@ a stated factor (``ZOO_GATE_FACTOR``) of XLA's own answer so the
 estimate stays anchored to ground truth, not vibes.
 
 Consumers: the memory lint rules (``analysis/lint.py``:
-memory-over-budget / max-safe-batch / dead-persistable),
-``core/window_tune.py`` (candidates whose predicted peak exceeds the
-device budget are pruned before measurement), the serving engine's
-predicted-bytes admission guard (``serving/engine.py``) and
-``tools/memory_report.py``. ``paddle_analysis_memory_*`` observe families count
-analyses, window-candidate prunes, and wall time.
+memory-over-budget / max-safe-batch / dead-persistable), the serving
+engine's predicted-bytes admission guard (``serving/engine.py``) and
+``tools/memory_report.py``. ``paddle_analysis_memory_*`` observe families
+count analyses and wall time.
 """
 
 from __future__ import annotations

@@ -333,9 +333,7 @@ class Executor:
         ragged final window (reader ran dry, or a batch's shapes broke
         the window in progress) falls back to the per-step path rather
         than compiling a second scan length. ``steps_per_call=None``
-        resolves automatically: ``PADDLE_TPU_STEPS_PER_CALL`` if set,
-        else the tuned ``train_window`` winner for this (program, batch
-        shape) when one exists (``core.window_tune``), else 1.
+        is ``PADDLE_TPU_STEPS_PER_CALL`` if set, else 1.
 
         Abandoning the generator (break / close) stops the prefetch
         thread and drains in-flight work. The analog of the reference's
@@ -352,16 +350,10 @@ class Executor:
             raise ValueError("max_in_flight must be >= 1, got %d"
                              % max_in_flight)
         _check_reduce(reduce_fetches)
-        if steps_per_call is not None and int(steps_per_call) < 1:
-            raise ValueError("steps_per_call must be >= 1, got %r"
-                             % (steps_per_call,))
-        if steps_per_call is None:
-            # a malformed PADDLE_TPU_STEPS_PER_CALL must raise HERE,
-            # with the other argument validation — not from the
-            # prefetch fill thread (or mid-iteration) at the first
-            # batch; resolution proper still waits for the first feed
-            from .window_tune import env_steps_per_call
-            env_steps_per_call()
+        # a bad argument or a malformed PADDLE_TPU_STEPS_PER_CALL raises
+        # HERE, with the other argument validation — not from the
+        # prefetch fill thread (or mid-iteration) at the first batch
+        window = _resolve_steps_per_call(steps_per_call)
         if isinstance(reader, DevicePrefetcher):
             prefetcher = reader
             if prefetcher._closed:
@@ -399,34 +391,28 @@ class Executor:
             if const_feed_names:
                 prefetcher.const_cache.mark_constant(*const_feed_names)
         else:
-            from .window_tune import resolve_steps_per_call
-
             prefetcher = DevicePrefetcher(
                 reader, place=self.place, program=program,
                 depth=2 if prefetch_depth is None else prefetch_depth,
                 const_feed_names=const_feed_names,
                 const_dedup=True if const_dedup is None else const_dedup,
-                # whole-loop compilation: the fill thread resolves K
-                # from the first host batch (arg > env > tuned winner >
-                # 1) and, for K > 1, stacks K batches into ONE
-                # WindowFeed with a single device_put per window —
-                # per-batch H2D call overhead amortizes alongside the
-                # scan's dispatch overhead
-                window_resolver=lambda feed: resolve_steps_per_call(
-                    program, feed, steps_per_call))
+                # whole-loop compilation: for K > 1 the fill thread
+                # stacks K batches into ONE WindowFeed with a single
+                # device_put per window — per-batch H2D call overhead
+                # amortizes alongside the scan's dispatch overhead
+                window_resolver=lambda feed: (window, None))
         # validation + prefetcher setup are eager; only the loop itself is
         # a generator (a never-iterated result must not defer ValueErrors).
         # iter() stays lazy — it starts the fill thread, which must not
         # run for a generator that is never iterated
         return self._pipelined_loop(program, prefetcher, fetch_list, scope,
                                     max_in_flight, return_numpy,
-                                    steps_per_call, reduce_fetches)
+                                    window, reduce_fetches)
 
     def _pipelined_loop(self, program, prefetcher, fetch_list, scope,
-                        max_in_flight, return_numpy, steps_per_call=None,
+                        max_in_flight, return_numpy, steps_per_call=1,
                         reduce_fetches="last"):
         from .pipeline import FetchHandle, WindowFeed
-        from .window_tune import WINDOW_OP, resolve_steps_per_call
         from ..observe import observe_feed_gap
         from ..observe.families import (PIPELINE_IN_FLIGHT,
                                         PIPELINE_OVERLAP_RATIO,
@@ -556,23 +542,10 @@ class Executor:
             step_i += k
             return handle
 
-        def note_k(kk, src):
+        def note_k(kk, _src=None):
             nonlocal k
             k = kk
             PIPELINE_WINDOW_SIZE.set(kk)
-            if src == "tuned":
-                # a tuner-table decision shaped this loop: note it like
-                # any kernel-tier dispatch (per-loop, not per-step)
-                from .. import kernels as _k
-                from ..observe.families import KERNEL_DISPATCHES
-
-                _k.note_decision(
-                    WINDOW_OP,
-                    "pallas:%d" % kk if kk > 1 else "composed",
-                    tuned=True)
-                KERNEL_DISPATCHES.labels(
-                    op=WINDOW_OP,
-                    impl="pallas" if kk > 1 else "composed").inc()
 
         def flush_ragged(fs):
             # the per-step fallback for batches that never filled a
@@ -620,8 +593,7 @@ class Executor:
                     if prefetcher.resolved_window is not None:
                         note_k(*prefetcher.resolved_window)
                     else:
-                        note_k(*resolve_steps_per_call(program, feeds,
-                                                       steps_per_call))
+                        note_k(steps_per_call)
                 if k == 1:
                     yield dispatch_step(feeds)
                     continue
@@ -868,9 +840,7 @@ class Executor:
         # the optimizer config (level + every output-changing knob) keys
         # the cache too: a plan compiled from the optimized clone must
         # never serve a differently-configured run. Same deal for the
-        # kernel tier: its config_key carries the PADDLE_TPU_KERNELS
-        # switch and the tuned-decision table epoch, so a plan lowered
-        # against one set of tuned winners never serves another
+        # kernels' environment (PADDLE_TPU_KERNELS, FLASH_MIN_SEQ)
         from .. import kernels as _kernels
 
         return (program._serial, program.version, _optimizer_config_key(),
@@ -974,6 +944,30 @@ def plan_tag(cache_key) -> str:
     """Stable within-process tag of a plan-cache key: what the
     ``executor.dispatch`` span carries as ``plan``."""
     return "%08x" % (zlib.crc32(repr(cache_key).encode()) & 0xffffffff)
+
+
+def _resolve_steps_per_call(explicit: Optional[int] = None) -> int:
+    """The windowed loop's K: the argument if given, else
+    ``PADDLE_TPU_STEPS_PER_CALL`` if set, else 1. A value under 1 or not
+    an integer raises from either."""
+    if explicit is not None:
+        if int(explicit) < 1:
+            raise ValueError("steps_per_call must be >= 1, got %r"
+                             % (explicit,))
+        return int(explicit)
+    raw = os.environ.get("PADDLE_TPU_STEPS_PER_CALL", "").strip()
+    if not raw:
+        return 1
+    try:
+        k = int(raw)
+    except ValueError:
+        raise ValueError(
+            "PADDLE_TPU_STEPS_PER_CALL must be an integer; got %r"
+            % (raw,)) from None
+    if k < 1:
+        raise ValueError(
+            "PADDLE_TPU_STEPS_PER_CALL must be >= 1, got %d" % k)
+    return k
 
 
 def _prepare_span(sig, program):

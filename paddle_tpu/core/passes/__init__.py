@@ -22,9 +22,6 @@ post_training_quantize_pass              2   int8 PTQ weights (opt-in:
                                              PADDLE_TPU_OPTIMIZE_QUANT)
 amp_bf16_pass                            1   stamp bf16 policy onto the IR
                                              (range-aware f32 keep)
-fuse_kernel_tier_pass                    2   residual+layernorm pairs and
-                                             optimizer runs -> kernel-tier
-                                             fused ops (PADDLE_TPU_KERNELS)
 fuse_elementwise_pass                    2   chain -> one fused op
 ====================================== ===== ==============================
 
@@ -59,7 +56,7 @@ from typing import Dict, List, Optional, Sequence
 
 from ..ir import Graph, get_pass
 from ..program import Program
-from . import amp_pass, cse, fold, fuse, kernel_fuse  # noqa: F401
+from . import amp_pass, cse, fold, fuse  # noqa: F401
 from . import quantize_pass as _quantize_pass  # noqa: F401
 
 __all__ = [
@@ -94,11 +91,6 @@ PIPELINE = (
     # so stamped == table stays bitwise), and the range-aware f32 keep
     # can see ops a fused chain would otherwise swallow
     ("amp_bf16_pass", 1),
-    # kernel-tier fusion BEFORE generic elementwise fusion: the residual
-    # add would otherwise be swallowed into an elementwise chain and the
-    # add->layer_norm seam lost (kernel_fuse.py; PADDLE_TPU_KERNELS=0
-    # makes it a provable no-op)
-    ("fuse_kernel_tier_pass", 2),
     ("fuse_elementwise_pass", 2),
 )
 

@@ -85,42 +85,6 @@ def quant_min_elems() -> int:
         return 16
 
 
-def quantizable_weight_names(program) -> Dict[str, int]:
-    """Static preview of the weights the PTQ pass WOULD consider:
-    {weight name: element count} over every consumer-slot input
-    (``_WEIGHT_SLOTS``) that is a float32 persistable variable of
-    statically known shape at or above the size floor. The runtime-only
-    checks (scope presence, never-written, no grad, proven ranges)
-    still apply when the pass actually runs — this is the optimistic
-    upper bound the unified autotuner's quantize outlook prices
-    (``kernels/autotune.py``: each such weight stops moving 3/4 of its
-    bytes)."""
-    floor = quant_min_elems()
-    out: Dict[str, int] = {}
-    for block in program.blocks:
-        for op in block.ops:
-            slot = _WEIGHT_SLOTS.get(op.type)
-            if slot is None:
-                continue
-            names = op.inputs.get(slot) or []
-            for name in names:
-                var = block._find_var_recursive(name)
-                if var is None or not var.persistable:
-                    continue
-                if getattr(var, "dtype", None) != "float32":
-                    continue
-                shape = getattr(var, "shape", None)
-                if not shape or any(int(d) < 0 for d in shape):
-                    continue
-                elems = 1
-                for d in shape:
-                    elems *= int(d)
-                if elems < floor:
-                    continue
-                out[name] = elems
-    return out
-
-
 @register_pass("post_training_quantize_pass")
 class PostTrainingQuantizePass(Pass):
     """Rewrite eligible matmul/conv/mul weights to int8 storage with

@@ -3,9 +3,9 @@
 The reference's deployment tier (``save_inference_model`` →
 ``AnalysisPredictor``) re-runs analysis in every serving process; this
 subsystem freezes the expensive half ONCE — verified + optimized
-program, params, tuned-winner slice, memory prediction, AOT
+program, params, memory prediction, AOT
 executables — into one checksummed file, and a serving process
-rehydrates it as a file read: zero trace, zero optimize, zero tune,
+rehydrates it as a file read: zero trace, zero optimize,
 and (with the AOT section) zero compile. ``ReplicaRouter.roll`` closes
 the fleet loop: replicas replace one at a time with drain, zero
 stranded requests. See docs/DEPLOYMENT.md.

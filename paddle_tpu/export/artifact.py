@@ -2,14 +2,14 @@
 
 ``save_artifact`` runs the expensive half of the serving pipeline ONCE
 — verify, inference-rewrite, the level-N TV-checked optimizer pipeline,
-param checksums, winner-table slicing, memory prediction, AOT
+param checksums, memory prediction, AOT
 serialization — and writes the results into one validated file
 (format.py). ``load_artifact`` is the cheap half: a file read plus
 mandatory validation rehydrates a Predictor-ready bundle with ZERO
-trace, ZERO optimize, ZERO tune, and (with the AOT section) zero
+trace, ZERO optimize, and (with the AOT section) zero
 XLA re-lowering; the cold-start acceptance tests pin exactly which
 telemetry counters a load is allowed to move (none of the optimizer/
-tuner/plan-miss families).
+plan-miss families).
 
 Validation is mandatory, not advisory: config_key and TV-digest
 mismatches, param checksum failures, truncated files and future format
@@ -41,10 +41,7 @@ __all__ = ["save_artifact", "load_artifact", "LoadedArtifact"]
 def _config_record() -> dict:
     """The portable optimization config the artifact was frozen under:
     the pass pipeline's full config_key (level + fold + quant + AMP
-    knobs) and the kernel-tier master switch. Process-local kernel
-    state (cache dir, table epoch) deliberately does NOT ride along —
-    it could never match across hosts and the plan-cache key picks the
-    live value up at seed time anyway."""
+    knobs) and the ``PADDLE_TPU_KERNELS`` switch."""
     from .. import kernels
     from ..core import passes
 
@@ -164,24 +161,6 @@ def _params_blob(params: Dict[str, np.ndarray]) -> bytes:
     return buf.getvalue()
 
 
-def _tuned_slice(program) -> dict:
-    """The winner-table slice this program can consult: every entry
-    under an op type the frozen program contains, plus the
-    ``train_window`` schedule winners (keyed by program fingerprint —
-    harmless to carry, they only match their program). A serving-only
-    artifact (no program) carries the whole table: the engine's decode
-    step is built load-side, so its op set is unknown here."""
-    from ..kernels import tune
-
-    if program is None:
-        return {"version": tune.CACHE_VERSION,
-                "entries": tune.export_entries()}
-    ops = sorted({op.type for b in program.blocks for op in b.ops})
-    prefixes = ["%s|" % t for t in ops] + ["train_window|"]
-    return {"version": tune.CACHE_VERSION,
-            "entries": tune.export_entries(keys=prefixes)}
-
-
 def _memory_record(program, fetch_names, batch_sizes) -> Optional[dict]:
     try:
         from ..analysis.memory import MemoryAnalysis
@@ -290,8 +269,7 @@ def save_artifact(obj, path: str, *,
 
     What gets frozen: the verified + live-config-optimized program
     (TV forced on; ``exact_numerics`` captures freeze the unoptimized
-    sequence, exactly what would run), per-var-checksummed params, the
-    tuned-kernel + train_window winner slice the program can consult,
+    sequence, exactly what would run), per-var-checksummed params,
     the predicted peak-memory polynomial, the full config_key, the TV
     rewrite-log digest, and — for each ``batch_sizes`` bucket, unless
     ``aot=False`` or ``PADDLE_TPU_EXPORT_AOT=0`` — a
@@ -300,7 +278,7 @@ def save_artifact(obj, path: str, *,
     ``max_len``) for ``DecodeEngine.from_artifact`` and
     ``ReplicaRouter.roll``. ``obj=None`` with ``params=`` and
     ``serving=`` writes a serving-only artifact — no program section,
-    the engine rebuilds its decode step from ``cfg`` but re-tunes and
+    the engine rebuilds its decode step from ``cfg`` but
     re-checksums nothing. Returns ``path``."""
     import os as _os
 
@@ -348,9 +326,6 @@ def save_artifact(obj, path: str, *,
                           json.dumps(program.to_dict(),
                                      sort_keys=True).encode())
         write_section(blobs, manifest, "params", _params_blob(pvals))
-        write_section(blobs, manifest, "tuned_kernels",
-                      json.dumps(_tuned_slice(program),
-                                 sort_keys=True).encode())
         if rewrite_log is not None:
             log_blob = json.dumps(rewrite_log, sort_keys=True,
                                   default=repr).encode()
@@ -409,8 +384,8 @@ class _AotRunner:
 
 
 class LoadedArtifact:
-    """A validated, rehydrated artifact: the frozen program + params +
-    winner slice are already installed process-side; ``predictor()``
+    """A validated, rehydrated artifact: the frozen program + params
+    are already installed process-side; ``predictor()``
     hands back a serving-ready Predictor whose plan cache is seeded
     (zero misses for covered signatures) and whose bucket runs ride the
     AOT section when present."""
@@ -424,7 +399,6 @@ class LoadedArtifact:
         self.fetch_names: List[str] = list(manifest.get("fetch_names")
                                            or [])
         self.params: Dict[str, np.ndarray] = {}
-        self.tuned_imported = 0
         self.memory: Optional[dict] = None
         self.rewrite_log: Optional[list] = None
         self.aot: Dict[int, _AotRunner] = {}
@@ -525,9 +499,9 @@ def load_artifact(path: str) -> LoadedArtifact:
     (``section_checksum``), the TV rewrite-log digest (``tv_digest``),
     per-var param checksums (``param_checksum``). Any failure raises
     :class:`ArtifactSkewError`, counted by reason — never silently
-    served. Optional sections (tuned_kernels / memory / rewrite_log /
-    aot) degrade individually to recompute, counted by (section,
-    reason) in ``paddle_export_artifact_degraded_total``."""
+    served. Optional sections (memory / rewrite_log / aot) degrade
+    individually to recompute, counted by (section, reason) in
+    ``paddle_export_artifact_degraded_total``."""
     from ..observe.families import (ARTIFACT_DEGRADED, ARTIFACT_LOADS,
                                     ARTIFACT_SKEW)
 
@@ -555,7 +529,6 @@ def load_artifact(path: str) -> LoadedArtifact:
 
 def _load_validated(path, manifest, zf) -> LoadedArtifact:
     from ..io import _program_from_dict
-    from ..kernels import tune
 
     _check_config(manifest)
     art = LoadedArtifact(path, manifest)
@@ -603,19 +576,6 @@ def _load_validated(path, manifest, zf) -> LoadedArtifact:
         raise ArtifactError(
             "artifact %r carries no params section" % path)
     art.params = _load_params(manifest, par_blob, path)
-
-    # --- tuned winner slice (optional: absent/version-skewed slices
-    # degrade to re-tune, counted)
-    tk_blob = read_section(zf, manifest, "tuned_kernels")
-    if tk_blob is None:
-        art.degraded.append(("tuned_kernels", "absent"))
-    else:
-        rec = json.loads(tk_blob)
-        if rec.get("version") != tune.CACHE_VERSION:
-            art.degraded.append(("tuned_kernels", "version"))
-        else:
-            art.tuned_imported = tune.import_entries(
-                rec.get("entries") or {})
 
     # --- memory prediction (optional)
     mem_blob = read_section(zf, manifest, "memory")

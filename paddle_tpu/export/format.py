@@ -44,21 +44,22 @@ MANIFEST_NAME = "manifest.json"
 # runtime test pins manifests to it). Order is documentation order:
 #   program        frozen optimized Program (json, io._program_from_dict)
 #   params         weights (npz; per-var sha256 lives in the manifest)
-#   tuned_kernels  kernel + train_window winner-table slice (json)
 #   memory         predicted peak-bytes polynomial (json)
 #   rewrite_log    the optimizer pipeline's TV rewrite log (json; the
 #                  manifest's tv_digest is the sha256 of this blob)
 #   aot            jax.export-serialized executables, one per bucket
 #   serving        DecodeEngine construction record (cfg/b_max/max_len)
-SECTIONS = ("program", "params", "tuned_kernels", "memory",
-            "rewrite_log", "aot", "serving")
+# An older artifact may also list ``tuned_kernels``; sections are read
+# by name, so nothing reads it.
+SECTIONS = ("program", "params", "memory", "rewrite_log", "aot",
+            "serving")
 
 # each section carries its own schema version so ONE section can evolve
 # without invalidating whole artifacts: an unknown section version
 # degrades that section to recompute (optional sections) or refuses the
 # artifact (program/params — nothing to serve without them)
-SECTION_VERSIONS = {"program": 1, "params": 1, "tuned_kernels": 1,
-                    "memory": 1, "rewrite_log": 1, "aot": 1, "serving": 1}
+SECTION_VERSIONS = {"program": 1, "params": 1, "memory": 1,
+                    "rewrite_log": 1, "aot": 1, "serving": 1}
 
 _TMP_SEQ = itertools.count(1)
 

@@ -6,7 +6,7 @@ function eagerly — every ``trace_op`` dispatch ALSO records into a real
 ``Program`` — and subsequent calls replay that Program through the
 Executor's whole-block XLA plan, inheriting everything the static tier
 built: shape/dtype verification with eager-source provenance, the
-TV-checked pass pipeline, the unified autotuner, the plan cache, and
+TV-checked pass pipeline, the plan cache, and
 ``serving.Predictor``.
 
 Cache discipline (the executor plan cache's rules, applied one level
@@ -117,15 +117,13 @@ class CapturedFunction:
     """An eager callable backed by a signature-keyed cache of captured
     Programs. Construct via :func:`jit`."""
 
-    def __init__(self, fn, buckets=None, autotune: Optional[bool] = None,
-                 cache_size: Optional[int] = None,
+    def __init__(self, fn, buckets=None, cache_size: Optional[int] = None,
                  name: Optional[str] = None, exact_numerics: bool = True):
         self._fn = fn
         self.__name__ = name or getattr(fn, "__name__", "captured")
         self.__doc__ = getattr(fn, "__doc__", None)
         self._buckets = _env_buckets() if buckets is None else (
             buckets if buckets == "pow2" else sorted(set(buckets)))
-        self._autotune = autotune
         self._exact = bool(exact_numerics)
         self._cap = _cache_cap() if cache_size is None else int(cache_size)
         if self._cap < 1:
@@ -299,8 +297,6 @@ class CapturedFunction:
             tuple_result=isinstance(result, (list, tuple)),
             trainable=bool(ctx.param_grads), lead=lead,
             predicted_bytes=predicted, pass_stats=pass_stats)
-        if self._want_autotune():
-            self._tune(entry)
         self._insert(key, entry)
         self._last_entry = entry
         return self._slice_result(result, tensors, entry)
@@ -336,25 +332,6 @@ class CapturedFunction:
         out = [VarBase(v.value[:n], stop_gradient=True) if sl else v
                for v, sl in zip(vs, entry.fetch_slice)]
         return type(result)(out) if entry.tuple_result else out[0]
-
-    def _want_autotune(self) -> bool:
-        if self._autotune is not None:
-            return bool(self._autotune)
-        return os.environ.get("PADDLE_TPU_CAPTURE_AUTOTUNE", "") == "1"
-
-    def _tune(self, entry) -> None:
-        """Run the unified predict-prune-measure autotuner over the fresh
-        capture, in a scratch scope seeded with the CURRENT state (the
-        tuner's contract restores scope state bitwise, but measurement
-        runs must not race the live chain either way)."""
-        from ..kernels.autotune import autotune_program
-
-        scope = Scope()
-        for name, v in entry.state.items():
-            scope.set_var(name, jnp.copy(v.value))
-        scope.set_var(RNG_VAR, jnp.copy(self._chain_key()))
-        autotune_program(self._exe, entry.program, dict(entry.feed_values),
-                         entry.fetch_names, scope=scope)
 
     # ---------------------------------------------------------- replay
     def _match(self, entries, tensors) -> Optional[_Entry]:
@@ -485,24 +462,21 @@ class CapturedFunction:
                                  if sl])
 
 
-def jit(fn=None, *, buckets=None, autotune: Optional[bool] = None,
-        cache_size: Optional[int] = None, name: Optional[str] = None,
-        exact_numerics: bool = True):
+def jit(fn=None, *, buckets=None, cache_size: Optional[int] = None,
+        name: Optional[str] = None, exact_numerics: bool = True):
     """Decorate an eager function into a :class:`CapturedFunction`.
 
     ``buckets``: lead-dim bucketing — a sorted int list or ``"pow2"``
     (default: ``PADDLE_TPU_CAPTURE_BUCKETS``; unset = exact shapes).
-    ``autotune``: run the unified autotuner on each fresh capture
-    (default: ``PADDLE_TPU_CAPTURE_AUTOTUNE=1``). ``cache_size``: total
-    cached entries (default ``PADDLE_TPU_CAPTURE_CACHE_SIZE``, 16).
+    ``cache_size``: total cached entries (default
+    ``PADDLE_TPU_CAPTURE_CACHE_SIZE``, 16).
     ``exact_numerics`` (default True): compile replays bitwise-faithful
     to the eager dispatch sequence; pass False to allow full XLA fusion
     (fastest, numerics equal only to float tolerance).
     """
     def wrap(f):
-        return CapturedFunction(f, buckets=buckets, autotune=autotune,
-                                cache_size=cache_size, name=name,
-                                exact_numerics=exact_numerics)
+        return CapturedFunction(f, buckets=buckets, cache_size=cache_size,
+                                name=name, exact_numerics=exact_numerics)
 
     return wrap(fn) if fn is not None else wrap
 

@@ -1,8 +1,7 @@
-"""Shared Pallas kernel infrastructure for the kernel tier.
+"""Shared Pallas kernel infrastructure.
 
 Hoisted out of ops/attention.py (where flash attention grew it first) so
-every tier kernel — attention, layernorm+residual, the fused optimizer
-sweep, and whatever lands next — gates its ``pallas_call``s through the
+every kernel gates its ``pallas_call``s through the
 SAME Mosaic block-legality mirror and the same interpret-mode detection.
 A kernel that validated its own specs with a private copy of the rule
 would drift the moment Mosaic's constraint set moves.
@@ -12,8 +11,7 @@ jax/_src/pallas/mosaic/lowering.py ``_check_block_mappings``): every
 operand/output block's last two dims must be divisible by (8, 128)
 respectively or equal to the corresponding array dims. ``assert_mosaic_ok``
 runs on EVERY backend — including interpret mode — so the CPU test suite
-(and the autotuner's candidate grid) rejects block specs real-TPU
-lowering would refuse.
+rejects block specs real-TPU lowering would refuse.
 """
 
 from __future__ import annotations
@@ -31,8 +29,8 @@ def use_interpret() -> bool:
     (``jax.devices()[0].platform == "tpu"``). A backend that cannot be
     asked raises — it never reads as "interpret".
 
-    PADDLE_TPU_FLASH_INTERPRET overrides the detection for EVERY tier
-    kernel (the knob predates the tier and keeps its historical name):
+    PADDLE_TPU_FLASH_INTERPRET overrides the detection for EVERY
+    kernel (the knob keeps its historical name):
     "1" forces interpret mode (debugging numerics on any backend), "0"
     forces the compiled Mosaic path (ahead-of-time compiles for a
     described chip from a CPU host, tests/test_chip_bringup.py)."""
@@ -43,8 +41,8 @@ def use_interpret() -> bool:
 
 
 def mosaic_ok(block_shape, array_shape) -> bool:
-    """Non-raising form of ``assert_mosaic_ok`` — the tuner's candidate
-    filters use this; dispatch-time gates use the raising form so a bad
+    """Non-raising form of ``assert_mosaic_ok`` — the plan functions
+    filter with this; dispatch-time gates use the raising form so a bad
     spec carries its own diagnosis."""
     if len(block_shape) < 2 or len(array_shape) < 2:
         return True

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 
 from .common import ceil_to, checked_pallas_call, mosaic_ok, use_interpret
-from .registry import register_kernel
 
 __all__ = ["kv_cache_write", "kv_cache_write_composed",
            "kv_cache_write_pallas", "write_plan", "KERNEL"]
@@ -139,34 +138,7 @@ def _cols_kernel(pos_ref, upd_ref, cache_ref, out_ref):
                            cache_ref[0])
 
 
-# ------------------------------------------------------- registry entry
-def _signature(args):
-    cache = args[0]
-    return (str(jnp.dtype(cache.dtype)),) + tuple(int(d) for d in cache.shape)
-
-
-def _check(cfg, sig):
-    if write_plan(sig[1:], sig[0]) is None:
-        raise ValueError("kv_cache_write: no block plan for %s" % (sig,))
-
-
-def _candidates(sig):
-    return [()] if write_plan(sig[1:], sig[0]) is not None else []
-
-
-def _make_inputs(sig, rs):
-    dtype, (B, H, S, D) = sig[0], sig[1:]
-    return (jnp.asarray(rs.randn(B, H, S, D), dtype),
-            jnp.asarray(rs.randn(B, H, 1, D), jnp.float32),
-            jnp.asarray(rs.randint(0, S, (B,)), jnp.int32))
-
-
-@register_kernel(
-    KERNEL, fallback=kv_cache_write_composed, signature=_signature,
-    candidates=_candidates, check=_check, make_inputs=_make_inputs,
-    tol="exact (bit-equal to the composed form)",
-)
-def kv_cache_write_pallas(cfg, cache, upd, pos, *, interpret=None):
+def kv_cache_write_pallas(cache, upd, pos, *, interpret=None):
     """Write ``upd [B, n_kv, 1, Dh]`` into ``cache [B, n_kv, S, Dh]`` at
     per-slot rows ``pos [B]`` (``[B, 1]``) with one in-place Pallas call:
     a grid over the slots, each step rewriting the one tile-aligned
@@ -175,10 +147,9 @@ def kv_cache_write_pallas(cfg, cache, upd, pos, *, interpret=None):
     composed form bit for bit: the row is the one
     ``lax.dynamic_update_slice`` picks (negative counts from the end,
     then clamped), the update is cast to the cache's dtype, nothing else
-    changes. ``cfg`` is unused (one plan a shape)."""
+    changes."""
     from jax.experimental import pallas as pl
 
-    del cfg
     plan = write_plan(cache.shape, cache.dtype)
     if plan is None or upd.shape != cache.shape[:2] + (1, cache.shape[3]):
         raise ValueError("kv_cache_write: no block plan for cache %s %s, "
@@ -246,6 +217,6 @@ def kv_cache_write(cache, upd, pos):
             and not use_interpret()
             and write_plan(cache.shape, cache.dtype) is not None):
         _note_plan("pallas", rows)
-        return kv_cache_write_pallas(None, cache, upd, pos, interpret=False)
+        return kv_cache_write_pallas(cache, upd, pos, interpret=False)
     _note_plan("composed", rows)
     return kv_cache_write_composed(cache, upd, pos)

@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 
 from .common import ceil_to, checked_pallas_call, pad_axis, use_interpret
-from .registry import register_kernel
 
 __all__ = ["ssm_update", "ssm_scan", "ssm_update_composed",
            "ssm_update_pallas", "ssm_scan_composed", "ssm_scan_pallas",
@@ -120,40 +119,14 @@ def _update_plan(state_shape_):
     return (1, 1, N, L)
 
 
-def _sig_update(args):
-    return tuple(int(d) for d in args[0].shape)
-
-
-def _check_update(cfg, sig):
-    if _update_plan(sig) is None:
-        raise ValueError("ssm_update: no block plan for a state %s" % (sig,))
-
-
-def _inputs_update(sig, rs):
-    B, G, N, L = sig
-    H, P = G * 2, L // 2
-    f = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32)   # noqa: E731
-    return (f(B, G, N, L), f(B, H * P), jnp.abs(f(B, H)) * 0.1,
-            -jnp.abs(f(H)) - 0.1, f(B, G, N), f(B, G, N))
-
-
-@register_kernel(
-    KERNEL_UPDATE, fallback=ssm_update_composed, signature=_sig_update,
-    candidates=lambda sig: [()] if _update_plan(sig) else [],
-    check=_check_update, make_inputs=_inputs_update,
-    tol="float32 rounding (the same products, summed over N in another "
-        "order)",
-)
-def ssm_update_pallas(cfg, state, x, dt, a, bm, cm, *, interpret=None):
+def ssm_update_pallas(state, x, dt, a, bm, cm, *, interpret=None):
     """One token a slot into ``state [B, G, N, L]``, in place: a grid
     over (slot, group), each step one read and one write of the group's
     ``[N, L]`` block (``input_output_aliases`` ties the state to the
-    output) and the ``[L]`` of ``y``. ``cfg`` is unused (one plan a
-    shape)."""
+    output) and the ``[L]`` of ``y``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    del cfg
     block = _update_plan(state.shape)
     if block is None:
         raise ValueError("ssm_update: no block plan for a state %s"
@@ -287,43 +260,17 @@ def _scan_plan(T, H, P, G, N, chunk):
     return int(chunk)
 
 
-def _sig_scan(args):
-    x, dt, _a, bm = args[:4]
-    return (int(x.shape[1]), int(dt.shape[-1]),
-            int(x.shape[-1]) // int(dt.shape[-1]), int(bm.shape[-2]),
-            int(bm.shape[-1]))
-
-
-def _check_scan(cfg, sig):
-    if _scan_plan(*sig, chunk=(cfg or (128,))[0]) is None:
-        raise ValueError("ssm_scan: no block plan for %s" % (sig,))
-
-
-def _inputs_scan(sig, rs):
-    T, H, P, G, N = sig
-    f = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32)   # noqa: E731
-    return (f(1, T, H * P), jnp.abs(f(1, T, H)) * 0.1, -jnp.abs(f(H)) - 0.1,
-            f(1, T, G, N), f(1, T, G, N))
-
-
-@register_kernel(
-    KERNEL_SCAN, fallback=ssm_scan_composed, signature=_sig_scan,
-    candidates=lambda sig: [(128,)] if _scan_plan(*sig, chunk=128) else [],
-    check=_check_scan, make_inputs=_inputs_scan,
-    tol="float32 rounding: the same chunked products at the highest "
-        "matmul precision",
-)
-def ssm_scan_pallas(cfg, x, dt, a, bm, cm, *, chunk=128, interpret=None):
+def ssm_scan_pallas(x, dt, a, bm, cm, *, chunk=128, interpret=None):
     """The chunked scan of a whole prompt (module docstring): a grid
     over (batch x group, chunk), chunks innermost and sequential with
     the group's state in VMEM scratch; inside a chunk ``C B^T`` once a
     group and, a head, the masked decay times it against ``x`` on the
-    MXU. ``cfg`` is ``(chunk,)`` or None for the ``chunk`` argument."""
+    MXU."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     H, P, G, N, J = _dims(x, dt, bm)
-    Q = _scan_plan(x.shape[1], H, P, G, N, (cfg or (chunk,))[0])
+    Q = _scan_plan(x.shape[1], H, P, G, N, chunk)
     if Q is None:
         raise ValueError("ssm_scan: no block plan for x %s in %d groups "
                          "of state %d" % (x.shape, G, N))
@@ -390,8 +337,7 @@ def ssm_update(state, x, dt, a, bm, cm):
     block plan, the composed form elsewhere."""
     if _kernels_on() and _update_plan(state.shape) is not None:
         _note_plan("update", "pallas", 1)
-        return ssm_update_pallas(None, state, x, dt, a, bm, cm,
-                                 interpret=False)
+        return ssm_update_pallas(state, x, dt, a, bm, cm, interpret=False)
     _note_plan("update", "composed", 1)
     return ssm_update_composed(state, x, dt, a, bm, cm)
 
@@ -403,7 +349,7 @@ def ssm_scan(x, dt, a, bm, cm, *, chunk=128):
     Q = _scan_plan(x.shape[1], H, P, G, N, chunk) if _kernels_on() else None
     if Q is not None:
         _note_plan("scan", "pallas", Q)
-        return ssm_scan_pallas((Q,), x, dt, a, bm, cm, interpret=False)
+        return ssm_scan_pallas(x, dt, a, bm, cm, chunk=Q, interpret=False)
     _note_plan("scan", "composed", chunk)
     return ssm_scan_composed(x, dt, a, bm, cm, chunk=chunk)
 

@@ -1327,8 +1327,8 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     where the kernel multiplies: one MXU pass, the precision XLA's own
     float32 products have on the chip. ``flash_min_seq`` is this call's
     threshold for the kernel in place of the static default (the
-    environment's ``PADDLE_TPU_FLASH_MIN_SEQ`` and a tuned entry still
-    win). All of these are forward-only (a serving prefill)."""
+    environment's ``PADDLE_TPU_FLASH_MIN_SEQ`` still wins). All of these
+    are forward-only (a serving prefill)."""
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("fused_attention: window=%r needs causal=True and "
                          "window >= 1" % (window,))

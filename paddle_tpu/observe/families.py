@@ -145,8 +145,8 @@ PIPELINE_CONST_BYTES_SAVED = REGISTRY.counter(
 PIPELINE_WINDOW_SIZE = REGISTRY.gauge(
     "paddle_pipeline_window_size",
     "Resolved steps_per_call K of the last windowed run_pipelined loop "
-    "(explicit arg, PADDLE_TPU_STEPS_PER_CALL, or the tuned "
-    "train_window winner); 1 = the classic one-dispatch-per-step loop")
+    "(the explicit argument, else PADDLE_TPU_STEPS_PER_CALL, else 1 = "
+    "the classic one-dispatch-per-step loop)")
 PIPELINE_WINDOW_STEPS = REGISTRY.histogram(
     "paddle_pipeline_window_steps_per_dispatch",
     "Steps carried by each windowed scan dispatch — full windows "
@@ -670,38 +670,29 @@ ANALYSIS_MEMORY_PROGRAMS = REGISTRY.counter(
     "paddle_analysis_memory_programs_total",
     "Programs run through the liveness-based peak-HBM estimator "
     "(MemoryAnalysis construction), by trigger: 'lint' = the memory "
-    "lint rules, 'cli' = tools/memory_report.py, 'window_tune' = the "
-    "window-candidate budget pruner, 'serving' = the engine admission "
+    "lint rules, 'cli' = tools/memory_report.py, 'serving' = the engine "
+    "admission "
     "guard, 'dist' = the "
     "distributed verifier's per-pserver shard-fit proof, 'api' = "
     "direct callers (contrib.memory_usage_calc and user code)",
     labels=("site",))
-for _s in ("api", "lint", "cli", "window_tune", "serving", "capture",
-           "dist"):
+for _s in ("api", "lint", "cli", "serving", "capture", "dist"):
     ANALYSIS_MEMORY_PROGRAMS.labels(site=_s)
 ANALYSIS_MEMORY_SECONDS = REGISTRY.histogram(
     "paddle_analysis_memory_seconds",
     "Wall time of one whole-program memory analysis (scales with op "
     "count, never with tensor sizes — bytes ride shape algebra)")
-ANALYSIS_MEMORY_PRUNED = REGISTRY.counter(
-    "paddle_analysis_memory_pruned_total",
-    "Window-tune candidates skipped WITHOUT measurement because their "
-    "predicted peak exceeded the device budget "
-    "(PADDLE_TPU_DEVICE_HBM_BYTES) — each count is one avoided "
-    "compile-and-OOM; the K=1 composed fallback is never pruned")
 
 # ------------------------------------------------------------ cost engine
 # (paddle_tpu/analysis/cost.py: the roofline cost model — per-op
-# FLOPs/bytes rules composed into predicted step seconds; ZERO family
-# movement with PADDLE_TPU_COST_MODEL=0, pinned by tests/test_autotune)
+# FLOPs/bytes rules composed into predicted step seconds)
 ANALYSIS_COST_PROGRAMS = REGISTRY.counter(
     "paddle_cost_programs_total",
     "Programs run through the roofline cost engine (CostAnalysis "
-    "construction), by trigger: 'autotune' = the unified autotuner's "
-    "predict-then-prune ranking, 'cli' = tools/cost_report.py, "
+    "construction), by trigger: 'cli' = tools/cost_report.py, "
     "'api' = direct callers",
     labels=("site",))
-for _s in ("api", "cli", "autotune"):
+for _s in ("api", "cli"):
     ANALYSIS_COST_PROGRAMS.labels(site=_s)
 ANALYSIS_COST_SECONDS = REGISTRY.histogram(
     "paddle_cost_seconds",
@@ -792,33 +783,6 @@ IMPERATIVE_CACHE_EVICTIONS = REGISTRY.counter(
     "(PADDLE_TPU_CAPTURE_CACHE_SIZE); sustained growth = signature "
     "churn re-tracing in a loop")
 
-# ------------------------------------------------------ global autotuner
-# (paddle_tpu/kernels/autotune.py: predict with the cost engine, prune,
-# measure only survivors through kernels/tune.py + core/window_tune.py)
-AUTOTUNE_RUNS = REGISTRY.counter(
-    "paddle_autotune_runs_total",
-    "Unified-autotuner searches by axis ('kernel' = Pallas block "
-    "configs incl. the attention/flash grid, 'window' = train-window "
-    "K); one count per (axis, signature) searched",
-    labels=("axis",))
-AUTOTUNE_PRUNED = REGISTRY.counter(
-    "paddle_autotune_pruned_total",
-    "Joint-space candidates skipped WITHOUT measurement because the "
-    "roofline ranked them outside the survivor set — each count is "
-    "one avoided compile-and-measure; the composed/K=1 fallback is "
-    "never pruned. Frozen at zero when PADDLE_TPU_COST_MODEL=0",
-    labels=("axis",))
-AUTOTUNE_MEASURED = REGISTRY.counter(
-    "paddle_autotune_measured_total",
-    "Survivor candidates the autotuner actually measured through the "
-    "existing tuner machinery; measured+pruned = the full grid, and "
-    "the acceptance gate holds measured <= half of it",
-    labels=("axis",))
-for _a in ("kernel", "window"):
-    AUTOTUNE_RUNS.labels(axis=_a)
-    AUTOTUNE_PRUNED.labels(axis=_a)
-    AUTOTUNE_MEASURED.labels(axis=_a)
-
 # ------------------------------------------------------------- optimizer
 # (paddle_tpu/core/passes/: graph-optimizing pass pipeline — see
 # docs/OPTIMIZER.md. PADDLE_TPU_OPTIMIZE=0 bypasses the pipeline; tests
@@ -872,7 +836,6 @@ _OPTIMIZER_PASSES = (
     "dead_op_elimination_pass",
     "post_training_quantize_pass",
     "amp_bf16_pass",
-    "fuse_kernel_tier_pass",
     "fuse_elementwise_pass",
 )
 OPTIMIZER_TV_CHECKS = REGISTRY.counter(
@@ -935,54 +898,18 @@ QUANT_AMP_KEPT_F32 = REGISTRY.counter(
     "bf16 finite range — each count is a would-have-been inf")
 
 # --------------------------------------------------------------- kernels
-# (paddle_tpu/kernels/: the Pallas kernel tier + per-shape autotuner —
-# see docs/KERNELS.md. PADDLE_TPU_KERNELS=0 bypasses the tier; tests pin
-# that NONE of these families move then.)
-KERNEL_TUNER_HITS = REGISTRY.counter(
-    "paddle_kernel_tuner_hits_total",
-    "Tuned-table LOOKUPS served by a winner entry, by tier: 'memory' = "
-    "this process already held the decision, 'disk' = the persisted "
-    "winner cache (PADDLE_TPU_KERNEL_CACHE_DIR) supplied it — a warmed "
-    "second process shows all-disk hits and zero tunes. Lookups, not "
-    "dispatches: flash_effective probes consult "
-    "the table too; dispatches_total below counts actual dispatches",
-    labels=("tier",))
-for _t in ("memory", "disk"):
-    KERNEL_TUNER_HITS.labels(tier=_t)
-KERNEL_TUNER_MISSES = REGISTRY.counter(
-    "paddle_kernel_tuner_misses_total",
-    "Tuned-table lookups finding no entry anywhere — the caller takes "
-    "its composed/static default (and tunes inline only under "
-    "PADDLE_TPU_KERNEL_TUNE=1). Lookups, not dispatches — see "
-    "tuner_hits_total")
-KERNEL_TUNE_SECONDS = REGISTRY.histogram(
-    "paddle_kernel_tune_seconds",
-    "Wall time of one autotune run over an (op, signature): candidate "
-    "grid measurement + winner persistence; rides prepare, never the "
-    "steady-state step")
-KERNEL_WINNERS = REGISTRY.counter(
-    "paddle_kernel_winners_total",
-    "Tuned winners recorded, by op and choice — 'pallas' = a kernel "
-    "block config beat the composed path at that signature",
-    labels=("op", "choice"))
+# (paddle_tpu/kernels/ and ops/attention.py: each kernel's form comes
+# from its operands — see docs/KERNELS.md; the per-kernel plan families
+# follow)
 KERNEL_DISPATCHES = REGISTRY.counter(
     "paddle_kernel_dispatches_total",
-    "Kernel-tier dispatches by op and implementation taken. Counted at "
-    "LOWERING time (once per plan-cache miss), not per step — the same "
-    "per-compile semantics as paddle_engine_collectives_total",
+    "fused_attention lowerings by the form taken: impl='pallas' the flash "
+    "kernel, 'composed' the XLA math under the sequence threshold. "
+    "Counted at LOWERING time (once per plan-cache miss), not per step; "
+    "nothing moves under PADDLE_TPU_KERNELS=0",
     labels=("op", "impl"))
-# pre-materialize the op schema — kept as a plain tuple HERE (importing
-# kernels would cycle); tests pin it equal to kernels.all_kernels() plus
-# the window tuner's op (core/window_tune.py WINDOW_OP: the training-
-# loop window length K rides the same tuner/winner cache without being
-# a Pallas kernel registry entry)
-_KERNEL_OPS = ("adam_update", "attention", "kv_cache_write",
-               "layernorm_residual", "sgd_update", "ssm_scan", "ssm_update",
-               "train_window")
-for _op in _KERNEL_OPS:
-    for _c in ("pallas", "composed"):
-        KERNEL_WINNERS.labels(op=_op, choice=_c)
-        KERNEL_DISPATCHES.labels(op=_op, impl=_c)
+for _c in ("pallas", "composed"):
+    KERNEL_DISPATCHES.labels(op="attention", impl=_c)
 
 FLASH_BLOCK_PLANS = REGISTRY.counter(
     "paddle_flash_block_plans_total",
@@ -1187,9 +1114,6 @@ TRACE_SITES = (
     # validation span — optimization cost shows up in the flight
     # recorder next to the compile it feeds
     "optimizer.pipeline", "optimizer.pass", "optimizer.tv",
-    # kernel tier (kernels/tune.py): one span per autotune run, so a
-    # slow first-compile is attributable to measurement, not a wedge
-    "kernel.tune",
     # dygraph capture (imperative/jit.py): one span per trace capture
     # (tagged with the retrace reason) and one per cached replay
     "imperative.capture", "imperative.replay",
@@ -1257,7 +1181,7 @@ for _s in ("SIGTERM", "SIGINT"):
 # ------------------------------------------------- deployable artifacts
 # (paddle_tpu/export/: frozen single-file deployment artifacts — see
 # docs/DEPLOYMENT.md. Loading an artifact must move NONE of the
-# paddle_optimizer_*/tuner/plan-cache-miss families for the signatures
+# paddle_optimizer_*/plan-cache-miss families for the signatures
 # it covers; the cold-start acceptance test pins exactly that.)
 ARTIFACT_SAVES = REGISTRY.counter(
     "paddle_export_artifact_saves_total",
@@ -1280,8 +1204,8 @@ for _o in ("ok", "skew", "corrupt"):
 ARTIFACT_LOAD_SECONDS = REGISTRY.histogram(
     "paddle_export_artifact_load_seconds",
     "Wall time of one successful load_artifact: manifest + checksum "
-    "validation, program/param rehydration, winner-table import — the "
-    "cold-start cost the artifact reduces trace/optimize/tune to")
+    "validation, program/param rehydration — the cold-start cost the "
+    "artifact reduces trace/optimize to")
 # every refusal reason the validation ladder can produce, schema-first
 ARTIFACT_SKEW_REASONS = ("corrupt", "future_version", "section_checksum",
                          "config_key", "param_checksum", "tv_digest")
@@ -1308,7 +1232,6 @@ ARTIFACT_DEGRADED = REGISTRY.counter(
     "paddle_export_artifact_skew_total instead, never here",
     labels=("section", "reason"))
 for _sec, _r in (("aot", "absent"), ("aot", "version"), ("aot", "jax"),
-                 ("tuned_kernels", "absent"), ("tuned_kernels", "version"),
                  ("memory", "absent"), ("rewrite_log", "absent"),
                  ("serving", "absent")):
     ARTIFACT_DEGRADED.labels(section=_sec, reason=_r)

@@ -104,40 +104,14 @@ __all__ = ["flash_attention", "flash_attention_with_lse", "pallas_mode",
            "composed_attention"]
 
 # Block plan. Each of the three kernels gets (bq, bk) from the call's own
-# static shapes (``_block_plan``); the environment may name an explicit
-# override of it (``_block_sizes``: tests, tools/kernel_tune.py, a
-# hardware A/B), validated at first kernel use. Constraints (Mosaic
-# tiling + the validator below): BQ % 8 == 0, BK % 128 == 0.
+# static shapes (``_block_plan``) and from nothing else. Constraints
+# (Mosaic tiling + the validator below): BQ % 8 == 0, BK % 128 == 0.
 import os as _os
 
 _LANE = 128        # sequences pad to whole lane tiles, blocks are made of them
 _MAX_BLOCK = 512   # longest block edge: a 512x512 float32 score tile is 1 MiB
                    # of VMEM, and each kernel holds a handful of them
 _HEADS_PER_STEP = 4  # most heads one grid step of a single-pass kernel takes
-
-
-def _block_sizes():
-    """The environment's block override as ``(bq, bk)``, ``None`` for
-    a variable that is not set. Parsed and validated at first kernel use,
-    not import: a malformed PADDLE_TPU_FLASH_BQ must not make
-    `import paddle_tpu` fail for workflows that never touch attention."""
-    raw_bq = _os.environ.get("PADDLE_TPU_FLASH_BQ") or None
-    raw_bk = _os.environ.get("PADDLE_TPU_FLASH_BK") or None
-    try:
-        bq = None if raw_bq is None else int(raw_bq)
-        bk = None if raw_bk is None else int(raw_bk)
-    except ValueError:
-        raise ValueError(
-            "PADDLE_TPU_FLASH_BQ/BK must be decimal integers "
-            "(multiple of 8 / multiple of 128); got %r/%r"
-            % (raw_bq, raw_bk)) from None
-    if (bq is not None and (bq % 8 or bq <= 0)) \
-            or (bk is not None and (bk % 128 or bk <= 0)):
-        raise ValueError(
-            "PADDLE_TPU_FLASH_BQ must be a positive multiple of 8 and "
-            "PADDLE_TPU_FLASH_BK a positive multiple of 128; got %s/%s"
-            % (bq, bk))
-    return bq, bk
 
 
 _MASK = -1e9  # additive mask for padded key columns
@@ -169,24 +143,22 @@ def fused_attention_enabled() -> bool:
 
 
 def flash_min_seq() -> int:
-    """STATIC sequence-length dispatch threshold for the fused-attention
-    op — the last tier of the flash-vs-composed precedence (see
-    ``flash_effective``).
+    """The sequence length from which the fused-attention op runs the
+    Pallas kernel: ``PADDLE_TPU_FLASH_MIN_SEQ`` where it is set, else
+    256 (``flash_effective`` has the whole rule).
 
-    Below this, ``flash_attention`` lowers to the COMPOSED XLA math
+    Below it ``flash_attention`` lowers to the COMPOSED XLA math
     (materialized [Sq,Sk] scores — fully fused by XLA, no kernel-launch
-    or blocked-softmax overhead) instead of the Pallas kernel: at short
-    S the score matrix is tiny and the blocked online-softmax scheme
-    costs more than it saves. The threshold itself is not measured on
-    the current code (ROADMAP.md Queue 1 item 4); it is SUPERSEDED the
-    moment a tuned kernel-tier entry exists for the sequence lengths in
-    play (``tools/kernel_tune.py --op attention`` measures and persists
-    the real flash-vs-composed winner per shape; docs/KERNELS.md).
+    or blocked-softmax overhead): at short S the score matrix is tiny
+    and the blocked online-softmax scheme costs more than it saves. The
+    benchmark has a cell on each side (``bert_train_s128`` composed,
+    ``bert_train_s512`` the kernel); the 256 itself is not measured on
+    the current code (ROADMAP.md Queue 1).
 
-    PADDLE_TPU_FLASH_MIN_SEQ overrides BOTH the static default and any
-    tuned entry (0 forces the kernel always — the hardware A/B lever; a
-    huge value forces composed always). Parsed at call time, not
-    import, per the round-3 advisor rule."""
+    The environment's value is a user-set selection between two paths
+    (0 forces the kernel always — the hardware A/B lever and what the
+    kernel's tests at small S set; a huge value forces composed always):
+    a named debt, ROADMAP.md Queue 3. Parsed at call time, not import."""
     raw = _os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "256")
     try:
         return int(raw)
@@ -200,36 +172,23 @@ def flash_effective(seq_len: int, kv_len: int = None) -> bool:
     """Whether the fused-attention op would actually run the Pallas
     kernel at these sequence lengths (chip_smoke.py and the benchmark
     label flash vs composed from this, so a short-S run never claims a
-    kernel measurement).
-
-    Three-tier precedence, tested in tests/test_flash_dispatch.py:
-
-    1. an EXPLICIT ``PADDLE_TPU_FLASH_MIN_SEQ`` env value wins — the
-       operator's A/B lever stays absolute;
-    2. else a tuned kernel-tier entry for ``("attention", (Sq, Sk))``
-       decides (the measured winner persisted by ``tools/kernel_tune.py``
-       or a PADDLE_TPU_KERNEL_TUNE=1 run; keyed by sequence lengths —
-       batch/heads/head-dim are deliberately coarse, docs/KERNELS.md);
-    3. else the static ``flash_min_seq()`` default (256)."""
-    return _flash_decision(seq_len, kv_len)[0]
+    kernel measurement): ``max(Sq, Sk)`` at or over
+    ``PADDLE_TPU_FLASH_MIN_SEQ`` where it is set, else 256
+    (tests/test_flash_dispatch.py). An op built with its own
+    ``flash_min_seq=`` attribute (latent attention's 128) puts that in
+    256's place; the environment still wins."""
+    return _flash_decision(seq_len, kv_len)
 
 
 def _flash_decision(seq_len: int, kv_len: int = None, min_seq: int = None):
-    """(use_flash, from_tuned_entry) per the three-tier precedence.
-    ``min_seq`` is a caller's own static threshold in place of tier 3's
-    ``flash_min_seq()`` (``fused_attention(flash_min_seq=)``)."""
+    """``flash_effective`` with a caller's own threshold ``min_seq`` in
+    place of the static 256 (``fused_attention(flash_min_seq=)``)."""
     sq = int(seq_len)
-    sk = int(kv_len) if kv_len is not None else sq
-    s = max(sq, sk)
-    if _os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ") is not None:
-        return s >= flash_min_seq(), False  # tier 1: explicit env wins
-    from .. import kernels
-
-    choice = kernels.tuned_choice("attention", (sq, sk))
-    if choice is not None:
-        return choice == "pallas", True     # tier 2: measured winner
-    # tier 3: static threshold
-    return s >= (flash_min_seq() if min_seq is None else int(min_seq)), False
+    s = max(sq, int(kv_len) if kv_len is not None else sq)
+    if min_seq is None or _os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ") \
+            is not None:
+        return s >= flash_min_seq()
+    return s >= int(min_seq)
 
 
 def composed_attention(q, k, v, bias=None, scale=1.0, causal=False,
@@ -485,8 +444,7 @@ def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False,
                 window=None):
     """``(bq, bk)`` of one grid step of ``kernel`` (one of the KERNEL_*
     names; the forward's rerun plans like the forward), from what is
-    static at trace time. Pure: the environment's override
-    (``_block_sizes``) is applied by ``_resolve_blocks``.
+    static at trace time.
 
     A sequence pads to whole lane tiles (128) and never to a multiple of
     the block: each block divides the padded length, so S 500 is one 512
@@ -524,18 +482,31 @@ def _block_plan(kernel, Sq, Sk, D, dtype, causal=False, want_db=False,
     return (b_red, b_par) if dkv else (b_par, b_red)
 
 
-def _resolve_blocks(kernel, Sq, Sk, D, dtype, causal, want_db, window=None):
-    """``(Sqp, Skp, bq, bk)``: the plan, or the environment's explicit
-    override of it. An overridden axis keeps the old contract: the length
-    pads to a multiple of the forced block."""
-    fq, fk = _block_sizes()
+def _padded_plan(kernel, Sq, Sk, D, dtype, causal, want_db, window=None):
+    """``(Sqp, Skp, bq, bk)``: the lengths padded to whole lane tiles
+    and the plan's blocks, which divide them."""
     bq, bk = _block_plan(kernel, Sq, Sk, D, dtype, causal, want_db, window)
-    Sqp, Skp = _pad_len(Sq, fq or _LANE), _pad_len(Sk, fk or _LANE)
-    if fq:
-        bq = min(fq, Sqp)
-    if fk:
-        bk = min(fk, Skp)
-    return Sqp, Skp, bq, bk
+    return _pad_len(Sq, _LANE), _pad_len(Sk, _LANE), bq, bk
+
+
+def _forward_plan(S, Sk, D, dtype, causal, window=None):
+    """``_padded_plan`` of the forward kernel, whose causal calls may pad
+    further than a lane tile."""
+    Sp, Skp, bq, bk = _padded_plan(KERNEL_FWD, S, Sk, D, dtype, causal,
+                                   False, window)
+    if causal and window is None and Skp > bk \
+            and min(bq, bk) <= _MAX_BLOCK // 2:
+        # a causal length past one key block whose lane tiles divide by
+        # no block over 256 (3,328 = 26 tiles: 256x256 blocks, 91 of them
+        # a head): pad to whole ``_MAX_BLOCK`` blocks instead. The causal
+        # mask already keeps every padded key from every real query, and
+        # 28 blocks of 512x512 took half the time of the 91 (97 -> 49 ms
+        # for five calls of 128 heads on the chip, PR 32; PERF.md
+        # section 6 has the same ratio at S 1024, PR 25)
+        Sp, Skp, bq, bk = _padded_plan(
+            KERNEL_FWD, _pad_len(S, _MAX_BLOCK), _pad_len(Sk, _MAX_BLOCK),
+            D, dtype, causal, False, window)
+    return Sp, Skp, bq, bk
 
 
 def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE,
@@ -807,21 +778,7 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
                              "got causal=%r window=%r" % (causal, window))
         if window >= S:
             window = None      # the band is the whole triangle
-    Sp, Skp, bq, bk = _resolve_blocks(KERNEL_FWD, S, Sk, D, q.dtype, causal,
-                                      False, window)
-    if causal and window is None and Skp > bk \
-            and min(bq, bk) <= _MAX_BLOCK // 2 \
-            and _block_sizes() == (None, None):
-        # a causal length past one key block whose lane tiles divide by
-        # no block over 256 (3,328 = 26 tiles: 256x256 blocks, 91 of them
-        # a head): pad to whole ``_MAX_BLOCK`` blocks instead. The causal
-        # mask already keeps every padded key from every real query, and
-        # 28 blocks of 512x512 took half the time of the 91 (97 -> 49 ms
-        # for five calls of 128 heads on the chip, PR 32; PERF.md
-        # section 6 has the same ratio at S 1024, PR 25)
-        Sp, Skp, bq, bk = _resolve_blocks(
-            KERNEL_FWD, _pad_len(S, _MAX_BLOCK), _pad_len(Sk, _MAX_BLOCK),
-            D, q.dtype, causal, False, window)
+    Sp, Skp, bq, bk = _forward_plan(S, Sk, D, q.dtype, causal, window)
     nq, nk = Sp // bq, Skp // bk
     _note_plan(name, bq, bk, nk == 1,
                None if window is None
@@ -1046,7 +1003,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
         Sk = k.shape[2]
     BH = B * H
     seq = 2 if lanes is None else 1      # the operands' sequence axis
-    plan = lambda kernel: _resolve_blocks(  # noqa: E731
+    plan = lambda kernel: _padded_plan(  # noqa: E731
         kernel, S, Sk, D, q.dtype, causal, want_db)
     Sp, Skp, bq, bk = plan(KERNEL_BWD_DKV)
     bias = _pad_bias(bias, S, Sp, Sk, Skp)
@@ -1355,8 +1312,8 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
     kernel runs under the name ``flash_fwd_win`` (a window) or
     ``flash_fwd`` and no backward rule exists for it (the training build
     of a windowed layer composes its band bias instead). ``min_seq`` is
-    the caller's threshold for the kernel in place of the static
-    ``flash_min_seq()``; the environment's and a tuned entry still win.
+    the caller's threshold for the kernel in place of the static 256;
+    the environment's still wins.
 
     Rank-3 ``q``, ``k``, ``v`` are [B, S, H*D] with ``n_head`` heads, as
     the projections leave them, and the output comes back so: where the
@@ -1371,7 +1328,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
             raise NotImplementedError(
                 _FORWARD_ONLY + "; [B, S, H*D] operands take none of them, "
                 "nor a trainable bias")
-        if not (_flash_decision(q.shape[1], k.shape[1], min_seq)[0]
+        if not (_flash_decision(q.shape[1], k.shape[1], min_seq)
                 and _lanes_ok(n_head, q.shape[-1] // n_head)):
             return _unpacked(
                 lambda a, b, c: flash_attention(
@@ -1410,22 +1367,19 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
             causal = False
     from .. import kernels
 
-    use_flash, tuned = _flash_decision(q.shape[-2], k.shape[-2], min_seq)
-    kernels.note_decision("attention", "flash" if use_flash else "composed",
-                          tuned=tuned)
+    use_flash = _flash_decision(q.shape[-2], k.shape[-2], min_seq)
+    kernels.note_decision("attention", "flash" if use_flash else "composed")
     if kernels.kernels_enabled():
         from ..observe.families import KERNEL_DISPATCHES
 
-        # same per-compile semantics as the other tier ops (and the
-        # bypass contract: PADDLE_TPU_KERNELS=0 moves nothing)
+        # counted a compile, like the kernels' plan families (and
+        # PADDLE_TPU_KERNELS=0 moves nothing)
         KERNEL_DISPATCHES.labels(
             op="attention",
             impl="pallas" if use_flash else "composed").inc()
     if not use_flash:
         # short-S dispatch: the composed XLA path wins below the
-        # threshold (see flash_min_seq; a tuned kernel-tier entry
-        # supersedes the static default — precedence in
-        # flash_effective). Same numerics, same bias semantics
+        # threshold (flash_effective). Same numerics, same bias semantics
         # (constant mask unless bias_grad — autodiff then yields the
         # true bias cotangent, like the trainable-bias kernel)
         cbias = bias if (bias is None or bias_grad) \
@@ -1625,86 +1579,6 @@ def _fused_attention(ctx, ins, attrs):
     else:
         mask = jnp.ones_like(out)
     return {"Out": [out * mask], "Mask": [mask]}
-
-
-# ----------------------------------------------------- kernel-tier entry
-# (kernels/registry.py): flash attention in the same catalog as the
-# other tier kernels, so tools/kernel_tune.py can measure its BQ x BK
-# grid against the composed path and persist the winner the
-# flash_effective precedence (tier 2) then serves. Tuning signatures
-# are (Sq, Sk) only — batch/heads/head-dim are fixed at representative
-# values below, a deliberate coarseness documented in docs/KERNELS.md.
-from ..kernels.registry import register_kernel as _register_kernel
-
-_TUNE_B, _TUNE_H, _TUNE_D = 2, 4, 64
-
-
-def _attention_composed(q, k, v, *, scale=1.0, causal=False):
-    return composed_attention(q, k, v, None, scale, causal)
-
-
-def _attn_candidates(sig):
-    sq, sk = sig
-    cands = []
-    for bq in (128, 256, 512):
-        for bk in (128, 256, 512):
-            if bq <= _pad_len(int(sq), bq) and bk <= _pad_len(int(sk), bk):
-                cands.append((bq, bk))
-    return cands or [(128, 128)]
-
-
-def _attn_check(cfg, sig):
-    bq, bk = cfg
-    if bq % 8 or bk % 128 or bq <= 0 or bk <= 0:
-        raise ValueError(
-            "attention candidate (BQ=%s, BK=%s) violates the Mosaic "
-            "tiling rule: BQ must be a positive multiple of 8 and BK a "
-            "positive multiple of 128" % (bq, bk))
-    sq, sk = int(sig[0]), int(sig[1])
-    sp, skp = _pad_len(sq, bq), _pad_len(sk, bk)
-    assert_mosaic_ok((1, min(bq, sp), _TUNE_D), (1, sp, _TUNE_D),
-                     "attention q block")
-    assert_mosaic_ok((1, min(bk, skp), _TUNE_D), (1, skp, _TUNE_D),
-                     "attention k block")
-
-
-def _attn_make_inputs(sig, rs):
-    sq, sk = int(sig[0]), int(sig[1])
-    mk = lambda s: jnp.asarray(
-        rs.randn(_TUNE_B, _TUNE_H, s, _TUNE_D).astype("float32"))
-    return (mk(sq), mk(sk), mk(sk))
-
-
-@_register_kernel(
-    "attention",
-    fallback=_attention_composed,
-    signature=lambda args: (int(args[0].shape[2]), int(args[1].shape[2])),
-    candidates=_attn_candidates,
-    check=_attn_check,
-    make_inputs=_attn_make_inputs,
-    tol="atol 2e-5 fwd / 5e-5 bwd at float32 (tests/test_attention.py)",
-)
-def _attention_pallas(cfg, q, k, v, *, scale=1.0, causal=False):
-    """Flash attention at a forced (BQ, BK) block config: the tuner's
-    measurement wrapper around the production kernels above. The block
-    sizes ride the PADDLE_TPU_FLASH_BQ/BK env (saved and restored) —
-    production dispatch keeps reading those knobs, so a tuned winner is
-    REPORTED as the env pair to pin rather than silently threaded; the
-    tuned entry's flash-vs-composed CHOICE is what flash_effective
-    consumes (precedence tier 2)."""
-    bq, bk = cfg or (128, 128)
-    saved = {name: _os.environ.get(name)
-             for name in ("PADDLE_TPU_FLASH_BQ", "PADDLE_TPU_FLASH_BK")}
-    _os.environ["PADDLE_TPU_FLASH_BQ"] = str(bq)
-    _os.environ["PADDLE_TPU_FLASH_BK"] = str(bk)
-    try:
-        return _fa_maskbias(q, k, v, None, scale, causal)
-    finally:
-        for name, val in saved.items():
-            if val is None:
-                _os.environ.pop(name, None)
-            else:
-                _os.environ[name] = val
 
 
 @register_grad_lowering("fused_attention")

@@ -46,148 +46,3 @@ def _fused_elementwise(ctx, ins, attrs):
         tmps.append(val[0] if isinstance(val, (list, tuple)) else val)
     return {"Out": [tmps[-1]]}
 
-
-# --------------------------------------------------- kernel-tier fusions
-# (core/passes/kernel_fuse.py creates these two op types; their
-# lowerings dispatch through paddle_tpu.kernels — a tuned Pallas winner
-# when the autotuner table says so, else a composed path that preserves
-# the unfused program's numerics BITWISE. docs/KERNELS.md.)
-@register_op("fused_layernorm_residual",
-             diff_inputs=["X", "Residual", "Scale", "Bias"])
-def _fused_layernorm_residual(ctx, ins, attrs):
-    """``elementwise_add`` -> ``layer_norm`` collapsed into one op by
-    ``fuse_kernel_tier_pass``. Emits BOTH originals' outputs — the new
-    residual stream (``ResOut``, the add's name) and the norm's
-    ``Y``/``Mean``/``Variance`` — so the program's pre-built backward
-    ops keep reading the names they were appended against.
-
-    Composed path (the default, and always under AMP — the bf16 kernel
-    tile story is still open): REPLAYS the constituents' own registered
-    lowerings with their original attrs and per-op AMP casts, exactly
-    like ``fused_elementwise`` — bitwise the unfused pair by
-    construction. Pallas path (only under a tuned ``layernorm_residual``
-    winner): flattens to ``[N, D]`` rows and runs the fused kernel
-    (kernels/layernorm.py; fwd atol 1e-5 / bwd 5e-5 vs composed)."""
-    import math
-
-    from .. import kernels
-    from ..core.amp import amp_cast
-
-    x, r = ins["X"][0], ins["Residual"][0]
-    scale, bias = ins["Scale"][0], ins["Bias"][0]
-    add_attrs = dict(attrs.get("add_attrs") or {})
-    ln_attrs = dict(attrs.get("ln_attrs") or {})
-    eps = ln_attrs.get("epsilon", 1e-5)
-    begin = ln_attrs.get("begin_norm_axis", 1)
-    amp = getattr(ctx, "amp", False)
-
-    if kernels.kernels_enabled() and not amp and x.shape == r.shape:
-        n = math.prod(int(v) for v in x.shape[:begin])
-        d = math.prod(int(v) for v in x.shape[begin:])
-        from ..kernels import layernorm as _ln
-
-        choice, cfg = kernels.decide_and_note(
-            "layernorm_residual", _ln.signature_for(n, d, x.dtype),
-            {"eps": eps})
-        if choice == "pallas":
-            y2, s2, mean2, var2 = _ln.layernorm_residual(
-                cfg, x.reshape(n, d), r.reshape(n, d),
-                scale.reshape(-1), bias.reshape(-1), eps=eps)
-            return {"ResOut": [s2.reshape(x.shape)],
-                    "Y": [y2.reshape(x.shape)],
-                    "Mean": [mean2.reshape(-1)],
-                    "Variance": [var2.reshape(-1)]}
-    elif not kernels.kernels_enabled():
-        kernels.note_decision("layernorm_residual", "bypass")
-    else:
-        # AMP (or shape-mismatched) programs always take the composed
-        # replay without consulting the tuner — the row's decision map
-        # and the dispatch counter still say what ran (no tuner
-        # hit/miss: no lookup happened)
-        from ..observe.families import KERNEL_DISPATCHES
-
-        kernels.note_decision("layernorm_residual", "composed")
-        KERNEL_DISPATCHES.labels(op="layernorm_residual",
-                                 impl="composed").inc()
-
-    add_ins = {"X": [x], "Y": [r]}
-    if amp:
-        add_ins = amp_cast("elementwise_add", add_attrs, add_ins)
-    s = get_op("elementwise_add").lowering(ctx, add_ins, add_attrs)["Out"]
-    s = s[0] if isinstance(s, (list, tuple)) else s
-    ln_ins = {"X": [s], "Scale": [scale], "Bias": [bias]}
-    if amp:
-        ln_ins = amp_cast("layer_norm", ln_attrs, ln_ins)
-    outs = get_op("layer_norm").lowering(ctx, ln_ins, ln_attrs)
-    return {"ResOut": [s], "Y": outs["Y"], "Mean": outs["Mean"],
-            "Variance": outs["Variance"]}
-
-
-@register_op("fused_optimizer_update", no_grad=True)
-def _fused_optimizer_update(ctx, ins, attrs):
-    """A consecutive run of same-hyperparameter ``adam``/``sgd`` ops
-    collapsed into ONE op by ``fuse_kernel_tier_pass``.
-
-    Composed path (the default): REPLAYS each constituent's own
-    registered lowering in order with per-constituent AMP casts —
-    bitwise the unfused run by construction (the ``fused_elementwise``
-    contract), and the SAME XLA graph, so the default config pays
-    nothing at steady state. Pallas path (only under a tuned
-    ``adam_update``/``sgd_update`` winner): every param/grad/moment
-    flattens into one concatenated stream, per-param scalars broadcast
-    per element, and the whole group updates as a single ``[R, 128]``
-    kernel sweep (kernels/optimizer_update.py, atol 2e-6) — the layout
-    change (one concat in, K splits out) rides ONLY the measured-win
-    path, because XLA materializes the concatenation (measured 2.3x
-    steady-state cost on a big-param MLP on the CPU backend)."""
-    kind = attrs["kind"]
-    hyper = dict(attrs.get("hyper") or {})
-    from .. import kernels
-
-    if kernels.kernels_enabled():
-        from ..kernels import optimizer_update as _ou
-
-        n_total = sum(p.size for p in ins["Param"])
-        choice, cfg = kernels.decide_and_note(
-            kind + "_update",
-            _ou.signature_for(n_total, ins["Param"][0].dtype,
-                              len(ins["Param"])), hyper)
-        if choice == "pallas":
-            sub_ins = ins
-            if getattr(ctx, "amp", False):
-                from ..core.amp import amp_cast
-
-                sub_ins = amp_cast(
-                    kind,
-                    dict(hyper, **({"__amp__": attrs["amp_override"]}
-                                   if attrs.get("amp_override") else {})),
-                    ins)
-            return _ou.sweep_group(cfg, kind, sub_ins, hyper)
-    else:
-        kernels.note_decision(kind + "_update", "bypass")
-
-    # composed: replay the constituents' own lowerings (bitwise)
-    from ..kernels.optimizer_update import OPT_IN_SLOTS, OPT_OUT_SLOTS
-
-    amp = getattr(ctx, "amp", False)
-    # the constituents' per-op __amp__ user override (uniform across
-    # the group — it is part of the pass's group key) rides the fused
-    # attrs as "amp_override"; reinstate it for the per-constituent
-    # cast so the replay honors "user overrides win"
-    cast_attrs = dict(hyper)
-    if attrs.get("amp_override"):
-        cast_attrs["__amp__"] = attrs["amp_override"]
-    outs = {slot: [] for slot in OPT_OUT_SLOTS[kind]}
-    lowering = get_op(kind).lowering
-    for i in range(len(ins["Param"])):
-        sub_ins = {s: [ins[s][i]] for s in OPT_IN_SLOTS[kind]}
-        if amp:
-            from ..core.amp import amp_cast
-
-            sub_ins = amp_cast(kind, cast_attrs, sub_ins)
-        o = lowering(ctx, sub_ins, hyper)
-        for slot in outs:
-            val = o[slot]
-            outs[slot].append(val[0] if isinstance(val, (list, tuple))
-                              else val)
-    return outs

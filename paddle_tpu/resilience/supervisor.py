@@ -326,7 +326,7 @@ def resilient_train_loop(
     params to parameter servers before training resumes.
 
     **Windowed training** (``steps_per_call=K > 1``, or None to let the
-    loop resolve env/tuned-winner/1 — see ``Executor.run_pipelined``):
+    loop resolve env/1 — see ``Executor.run_pipelined``):
     the loop dispatches one K-step scanned executable per window, and
     checkpoints land ONLY at window boundaries — at the first boundary
     at-or-after each ``checkpoint_every`` multiple — so the snapshot is

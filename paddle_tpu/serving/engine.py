@@ -740,10 +740,8 @@ class DecodeEngine:
         (``export.save_artifact(..., serving={"cfg": ..., ...})``):
         the artifact's serving record supplies ``cfg``/``b_max``/
         ``max_len``/``eos_id``, its params section supplies the
-        weights (already per-var checksummed at load), and its
-        tuned-winner slice is already installed — a replica built this
-        way re-tunes nothing. ``artifact`` is a path or a
-        ``LoadedArtifact``; ``overrides`` pass through to the
+        weights (already per-var checksummed at load). ``artifact`` is
+        a path or a ``LoadedArtifact``; ``overrides`` pass through to the
         constructor (``queue_capacity``, ``prefix_store``, ``place``,
         ...). The engine is built but NOT started, matching the
         router's ``engine_factory`` contract."""

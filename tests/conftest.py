@@ -19,11 +19,6 @@ os.environ.setdefault("PADDLE_TPU_VALIDATE", "1")
 # CPU) regardless of the short-S composed dispatch; policy tests
 # monkeypatch PADDLE_TPU_FLASH_MIN_SEQ themselves
 os.environ.setdefault("PADDLE_TPU_FLASH_MIN_SEQ", "0")
-# the kernel tier's persisted winner cache is hermetically DISABLED
-# suite-wide: a developer's ~/.cache tuned entries must never change
-# which implementation a test's dispatch picks. Tuner tests point
-# PADDLE_TPU_KERNEL_CACHE_DIR at their own tmp_path via monkeypatch.
-os.environ.setdefault("PADDLE_TPU_KERNEL_CACHE_DIR", "0")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

@@ -166,24 +166,6 @@ def test_pallas_mode_env_override(monkeypatch):
     assert pallas_mode() == "interpret"
 
 
-def test_flash_block_size_env_validated_at_use(monkeypatch):
-    # a malformed env var must not make `import paddle_tpu` fail; it
-    # fails (with the curated message) at first kernel use instead
-    import pytest
-
-    from paddle_tpu.ops import attention
-
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128k")
-    with pytest.raises(ValueError, match="decimal integers"):
-        attention._block_sizes()
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "96")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "256")
-    assert attention._block_sizes() == (96, 256)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "7")
-    with pytest.raises(ValueError, match="multiple of 8"):
-        attention._block_sizes()
-
-
 def test_causal_flash_matches_dense_causal_reference():
     """In-kernel causal (block skip + intra-block triangle) must equal
     the composed path with a materialized causal bias — forward AND all
@@ -374,19 +356,17 @@ def _plan_parity_inputs(case):
 def test_flash_block_plan_parity(case, blocks, monkeypatch):
     """Forward, dq/dk/dv, the bias cotangent where the bias is trainable
     and the lse cotangent where the path has an lse output, against
-    ``composed_attention``: under the plan the shapes give, and with the
-    blocks forced to 128x128 through the override, so that the
-    multi-block carry stays covered where the plan takes one block."""
+    ``composed_attention``: under the plan the shapes give, and with a
+    plan of 128x128 in its place, so that the multi-block carry stays
+    covered where the plan takes one block."""
     from paddle_tpu.observe.families import FLASH_BLOCK_PLANS
     from paddle_tpu.ops import attention
 
     monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    planned = attention._block_plan
     if blocks == "forced_128x128":
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128")
-        monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "128")
-    else:
-        monkeypatch.delenv("PADDLE_TPU_FLASH_BQ", raising=False)
-        monkeypatch.delenv("PADDLE_TPU_FLASH_BK", raising=False)
+        monkeypatch.setattr(attention, "_block_plan",
+                            lambda *a, **k: (128, 128))
     shape, dtype, bias_kind, causal, atol_f, atol_g = PLAN_PARITY_CASES[case]
     D = shape[-1]
     scale = D ** -0.5
@@ -415,7 +395,7 @@ def test_flash_block_plan_parity(case, blocks, monkeypatch):
     # (under autodiff the custom_vjp's forward rule runs: the rerun's name)
     single = FLASH_BLOCK_PLANS.labels(
         kernel=attention.KERNEL_REFWD,
-        block="%dx%d" % attention._block_plan(
+        block="%dx%d" % planned(
             attention.KERNEL_FWD, shape[2], shape[2], D, q.dtype, causal),
         single_pass="1", layout="heads")
     before = single.value
@@ -493,22 +473,6 @@ def test_flash_block_plan_is_legal_and_pads_under_a_lane_tile(S):
         assert A._block_plan(A.KERNEL_BWD_DQ, S, S, 64, jnp.bfloat16) == want
         assert A._block_plan(A.KERNEL_BWD_DKV, S, S, 64, jnp.bfloat16) \
             == want[::-1]
-
-
-def test_flash_block_override_keeps_its_old_contract(monkeypatch):
-    """PADDLE_TPU_FLASH_BQ/BK override the plan axis by axis; a forced
-    axis pads to a multiple of the forced block as it always did."""
-    from paddle_tpu.ops import attention as A
-
-    args = (A.KERNEL_FWD, 500, 500, 64, jnp.bfloat16, False, False)
-    monkeypatch.delenv("PADDLE_TPU_FLASH_BQ", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_FLASH_BK", raising=False)
-    assert A._block_sizes() == (None, None)
-    assert A._resolve_blocks(*args) == (512, 512, 512, 512)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "96")
-    assert A._resolve_blocks(*args) == (576, 512, 96, 512)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "256")
-    assert A._resolve_blocks(*args) == (576, 512, 96, 256)
 
 
 # ------------------------------------------ operand layouts (ISSUE 38)
@@ -622,8 +586,9 @@ def test_rank3_multi_pass_carry_matches_rank4(blocks, monkeypatch):
     maximum, a denominator and an accumulator a head of the step (two at
     D 64), and a causal call still skips above the diagonal."""
     monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", str(blocks[0]))
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", str(blocks[1]))
+    from paddle_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_block_plan", lambda *a, **k: blocks)
     H, D, S = 4, 64, 512
     rs = np.random.RandomState(5)
     q, k, v = (jnp.asarray(rs.randn(1, S, H * D).astype("float32"))

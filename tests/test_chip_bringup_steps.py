@@ -1,0 +1,465 @@
+"""Chip bring-up guards that need no chip, second file: the whole step
+programs of the newer cells compiled for a DESCRIBED TPU v5e (the BERT
+train step and its dropout masks, the mesh wrappers, the Xing and Nemotron
+serving programs, the grouped matmul's widest plan). Split from
+``tests/test_chip_bringup.py`` by its own sections (PR 43) so that no one
+file holds a worker of the tier-1 run for most of its length; the
+fixtures and helpers are that file's, imported, so both describe the chip
+the same way (its ``v5e_topology`` lets two processes load the TPU's
+library side by side).
+"""
+
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from test_chip_bringup import (BF16, F32, ROOT, _cache_sized,  # noqa: F401
+                               _compile, _lower_step, compiled_kernels, v5e,
+                               v5e_topology)
+
+
+# ------------------------------- (a) the train step's dropout masks, PR 35
+BERT_CELLS = {
+    # cell: (seq, batch, masks, Pallas calls the traffic file expects)
+    "bert_train_s512": (512, 32, 80, 48),
+    "bert_train_s128": (128, 128, 20, 0),
+}
+
+
+def _mask_sized(text, op, least=1 << 20):
+    """Instructions ``op`` of the module whose result is a u32 array of at
+    least ``least`` elements (a dropout mask's bits; nothing else in the
+    step is u32 and that large)."""
+    import re
+
+    found = []
+    for m in re.finditer(r"= \(?u32\[([\d,]+)\]\S*(?:, [^)]*\))? %s\(" % op,
+                         text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if int(np.prod(dims)) >= least:
+            found.append(tuple(dims))
+    return found
+
+
+def _flash_plans_by_layout():
+    """{layout: flash kernel plans lowered so far}."""
+    from paddle_tpu.observe import REGISTRY
+
+    seen = {}
+    for s in REGISTRY.snapshot()["metrics"][
+            "paddle_flash_block_plans_total"]["samples"]:
+        lay = s["labels"]["layout"]
+        seen[lay] = seen.get(lay, 0) + s["value"]
+    return seen
+
+
+@pytest.mark.parametrize("cell", sorted(BERT_CELLS))
+def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
+                                                      compiled_kernels,
+                                                      monkeypatch):
+    """The ``bert-base`` train step as ``benchmarks/lib/train_loop.py``
+    builds it (``bert.build`` + ``Adam.minimize`` + bf16 AMP), compiled for
+    the described chip: one ``rng-bit-generator`` a dropout (37), no
+    threefry over a mask (its rounds are ``shift-right-logical`` on the
+    mask's u32 bits, which XLA cloned into every consumer: PERF.md
+    section 6, PR 35), the cell's count of Pallas calls, and the plan
+    counter reads 37."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import bert
+    from paddle_tpu.observe.families import DROPOUT_MASK_PLANS
+
+    seq, batch, masks, n_calls = BERT_CELLS[cell]
+    # the cells run under the static threshold (composed attention below
+    # S 256), not under the suite's "always the kernel"
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "bert-base.json")) as f:
+        conf = json.load(f)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        loss, _feeds = bert.build(dict(conf["model"]), seq_len=seq,
+                                  max_mask=masks)
+        fluid.optimizer.Adam(
+            learning_rate=conf["train"]["learning_rate"]).minimize(loss)
+    main.set_amp(conf["train"]["amp"] == "bf16")
+    plans = {site: DROPOUT_MASK_PLANS.labels(site=site, bits="rbg_u32")
+             for site in ("dropout", "fused_attention")}
+    before = {site: c.value for site, c in plans.items()}
+    flash_before = _flash_plans_by_layout()
+    feeds = {"src_ids": (batch, seq), "sent_ids": (batch, seq),
+             "input_mask": ((batch, seq), jnp.float32),
+             "mask_pos": (batch, masks), "mask_label": (batch, masks),
+             "mask_weight": ((batch, masks), jnp.float32)}
+    lowered, _ = _lower_step(main, feeds, loss.name, v5e, rng=True)
+    n_layer = conf["model"]["n_layer"]
+    assert {s: c.value - before[s] for s, c in plans.items()} == {
+        "dropout": 2 * n_layer + 1, "fused_attention": n_layer}
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == n_calls
+    # PR 38: every kernel of the step takes the projections as they are
+    flash_now = _flash_plans_by_layout()
+    assert {lay: flash_now.get(lay, 0) - flash_before.get(lay, 0)
+            for lay in ("lanes", "heads")} == {"lanes": n_calls, "heads": 0}
+    drawn = _mask_sized(text, "rng-bit-generator")
+    assert len(drawn) == 3 * n_layer + 1 == 37
+    assert {int(np.prod(d)) for d in drawn} == {batch * seq * 768}
+    assert text.count(" rng-bit-generator(") == len(drawn)
+    assert _mask_sized(text, "shift-right-logical") == []
+
+
+def test_dropout_mask_is_drawn_per_shard_on_the_v5e_mesh(v5e_topology):
+    """The dropout op over the four chips of the described 2x2, operand
+    ``[128 * 512, 768]`` bf16 sharded on the data axis as ParallelEngine
+    jits a step: SPMD cannot partition ``rng-bit-generator`` (a draw of
+    the global shape comes out whole on every chip, then sliced), so the
+    lowering draws inside a ``shard_map``. Every generator call holds one
+    chip's 16,384 rows and no mask-sized u32 is sliced."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    mesh = Mesh(np.array(v5e_topology.devices).reshape(4, 1),
+                ("data", "model"))
+    rows, width = 128 * 512, 768
+
+    def fwd(x, key):
+        ctx = LowerContext(rng=key, mesh=mesh)
+        outs = get_op("dropout").lowering(
+            ctx, {"X": [x]}, {"dropout_prob": 0.1,
+                              "dropout_implementation": "upscale_in_train"})
+        return outs["Out"][0], outs["Mask"][0]
+
+    data, repl = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    with mesh:
+        text = jax.jit(fwd, out_shardings=(data, data)).lower(
+            jax.ShapeDtypeStruct((rows, width), BF16, sharding=data),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+        ).compile().as_text()
+    assert _mask_sized(text, "rng-bit-generator") == [(rows // 4, width)]
+    assert text.count(" rng-bit-generator(") == 1
+    assert _mask_sized(text, "dynamic-slice") == []
+    assert _mask_sized(text, "shift-right-logical") == []
+
+
+def test_packed_flash_is_wrapped_with_rank3_specs_on_the_v5e_mesh(
+        v5e_topology, compiled_kernels):
+    """``fused_attention`` and its grad op over [B, S, H*D] operands on
+    the four chips of the described 2x2, batch 128 sharded on the data
+    axis as ``bert_train_s512_dp4`` has it: the wrap hands each chip its
+    32 rows in the lanes layout, so a shard holds the four kernels and no
+    transpose."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    mesh = Mesh(np.array(v5e_topology.devices).reshape(4, 1),
+                ("data", "model"))
+    B, S, H, D = 128, 512, 12, 64
+    op = get_op("fused_attention")
+    attrs = {"scale": D ** -0.5, "n_head": H, "dropout": 0.0}
+
+    def step(q, k, v, bias, g):
+        ctx = LowerContext(mesh=mesh)
+        ins = {"Q": [q], "K": [k], "V": [v], "Bias": [bias]}
+        out = op.lowering(ctx, ins, attrs)["Out"][0]
+        grads = op.grad_lowering(ctx, dict(ins, **{"Out@GRAD": [g]}), attrs)
+        return (out,) + tuple(grads[s][0]
+                              for s in ("Q@GRAD", "K@GRAD", "V@GRAD"))
+
+    data = NamedSharding(mesh, P("data"))
+    act = jax.ShapeDtypeStruct((B, S, H * D), BF16, sharding=data)
+    bias = jax.ShapeDtypeStruct((B, 1, 1, S), F32, sharding=data)
+    before = _flash_plans_by_layout()
+    with mesh:
+        text = jax.jit(step, out_shardings=(data,) * 4).lower(
+            act, act, act, bias, act).compile().as_text()
+    now = _flash_plans_by_layout()
+    assert {lay: now.get(lay, 0) - before.get(lay, 0)
+            for lay in ("lanes", "heads")} == {"lanes": 4, "heads": 0}
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert " transpose(" not in text
+    assert "bf16[%d,%d,%d]" % (B // 4, S, H * D) in text
+
+
+def _xing():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def _mhc_plans():
+    from paddle_tpu.observe.families import RESIDUAL_PLANS
+
+    return {(op, kernel): RESIDUAL_PLANS.labels(
+        form="mhc", op=op, kernel=kernel, streams="4").value
+        for op in ("pre", "post") for kernel in ("pallas", "composed")}
+
+
+def test_xing_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``xing4.0-29b-a4b`` serving decode step (32 slots of
+    8,448 latent rows, four residual streams, all 64 experts, the whole
+    vocabulary) for the described chip: ten ``mhc_pre`` and ten
+    ``mhc_post`` Pallas calls (two sub-blocks a layer), five
+    ``mla_decode`` calls, and 11.2 GB of arguments."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mhc, mla_decode
+
+    gpt, cfg, serving = _xing()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert caches == ["gpt_%d_cache_c" % i for i in range(5)]
+    before = _mhc_plans()
+    lowered, _ = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    after = _mhc_plans()
+    assert {k: after[k] - before[k] for k in after} == {
+        ("pre", "pallas"): 10, ("post", "pallas"): 10,
+        ("pre", "composed"): 0, ("post", "composed"): 0}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name, n in ((mhc.KERNEL_PRE, 10), (mhc.KERNEL_POST, 10),
+                    (mla_decode.KERNEL, 5)):
+        assert len(set(re.findall(r"%%(%s[.\d]*) = " % name, text))) == n, \
+            name
+    mem = compiled.memory_analysis()
+    assert 11.1e9 < mem.argument_size_in_bytes < 11.4e9, mem
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    print("xing decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [512, 8192])
+def test_xing_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix for the described chip: the residual kernels and five flash
+    forwards, a head on ONE row (no [P, vocab] logits where the plan
+    fetches the token), the streams written in place, and temporaries
+    that fit beside the 11.2 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mhc
+
+    gpt, cfg, serving = _xing()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mhc.KERNEL_PRE,
+                              text))) == 10
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mhc.KERNEL_POST,
+                              text))) == 10
+    assert "f32[1,%d,131072]" % P not in text
+    assert "f32[%d,131072]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.5e9, mem
+    print("xing prefill P=%d:" % P, mem)
+
+
+def _nemotron():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "nemotron-3-super-120b-a12b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def _ssm_plans():
+    from paddle_tpu.observe.families import SSM_PLANS
+
+    return {(op, kernel): SSM_PLANS.labels(
+        op=op, kernel=kernel, chunk=chunk).value
+        for op, chunk in (("scan", "128"), ("update", "1"))
+        for kernel in ("pallas", "composed")}
+
+
+def _gmm_plans():
+    """``{(kernel, tile, form): lowerings}`` of the grouped matmul."""
+    from paddle_tpu.observe import REGISTRY
+
+    family = REGISTRY.snapshot()["metrics"].get(
+        "paddle_moe_gmm_plans_total", {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["tile"],
+             s["labels"]["form"]): s["value"] for s in family["samples"]}
+
+
+def _assert_nemotron_gmm_plans(before, after):
+    """Five expert layers: five Pallas plans a product, none composed,
+    and the width of 2688 = 21 x 128 (the up product's columns, the down
+    product's reduction) never cut in tiles of 128."""
+    from paddle_tpu.kernels import moe_gmm
+
+    new = {k: v - before.get(k, 0) for k, v in after.items()
+           if v != before.get(k, 0)}
+    assert {form for _k, _t, form in new} == {"pallas"}, new
+    for kernel, axis in ((moe_gmm.KERNEL_UP, 2), (moe_gmm.KERNEL_DOWN, 1)):
+        mine = {tile: n for (k, tile, _f), n in new.items() if k == kernel}
+        assert sum(mine.values()) == 5, new
+        assert all(int(tile.split("x")[axis]) > 128 for tile in mine), new
+
+
+def test_nemotron_serving_decode_step_compiles_for_v5e(v5e,
+                                                       compiled_kernels):
+    """The whole ``nemotron-3-super-120b-a12b`` serving decode step (96
+    slots: 2.07 GB of state, one attention slab, 128 of 512 latent
+    experts a layer) for the described chip: five ``ssm_update`` Pallas
+    calls, every state donated into its output and none copied, 11.9 GB
+    of arguments and temporaries that fit beside them."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import ssm
+
+    gpt, cfg, serving = _nemotron()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert [gpt.cache_kind(cfg, n, S) for n in caches] \
+        == ["state"] * 10 + ["full"] * 2
+    before, gmm_before = _ssm_plans(), _gmm_plans()
+    lowered, mut_state = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    after = _ssm_plans()
+    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans())
+    assert {k: after[k] - before[k] for k in after} == {
+        ("update", "pallas"): 5, ("update", "composed"): 0,
+        ("scan", "pallas"): 0, ("scan", "composed"): 0}
+    assert set(caches) <= set(mut_state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % ssm.KERNEL_UPDATE,
+                              text))) == 5
+    # no second copy of a layer's state: 96 x 8 x 128 x 1024 float32
+    assert _cache_sized(text, (B, 8, 128, 1024)) == []
+    mem = compiled.memory_analysis()
+    assert 11.8e9 < mem.argument_size_in_bytes < 12.1e9, mem
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    print("nemotron decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [128, 2048])
+def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix: five ``ssm_scan`` Pallas calls in chunks of 128, the flash
+    forward of the one attention layer, a head on ONE row, and
+    temporaries that fit beside the 11.9 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import ssm
+
+    gpt, cfg, serving = _nemotron()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    before, gmm_before = _ssm_plans(), _gmm_plans()
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    after = _ssm_plans()
+    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans())
+    assert after[("scan", "pallas")] - before[("scan", "pallas")] == 5
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % ssm.KERNEL_SCAN,
+                              text))) == 5
+    assert "f32[1,%d,32768]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem
+    print("nemotron prefill P=%d:" % P, mem)
+
+
+# (M, K, N, itemsize) of the cells' expert products at their longest
+# prefill: pairs of the longest prompt x the stored width of a weight
+LONGEST_PREFILL_GMM = {
+    "olmoe_up": (4096, 2048, 1024, 4), "olmoe_down": (4096, 1024, 2048, 4),
+    "trinity_up": (32768, 3072, 3072, 4),
+    "trinity_down": (32768, 3072, 3072, 4),
+    "pangu_up": (26624, 7680, 2048, 2), "pangu_down": (26624, 2048, 7680, 2),
+    "xing_up": (32768, 3584, 1024, 2), "xing_down": (32768, 1024, 3584, 2),
+}
+
+
+def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
+    """PR 42: of the plans the cells' expert products take at their
+    longest prefill, the one that keeps the most VMEM by the kernel's own
+    count holds its reduction whole; the expert layer at that shape (a
+    share of 8 of 256 experts, bf16 matrices under float32 rows, gate and
+    up in one call) lowers for the described chip under that plan — the
+    counter is read round the lowering — and compiles."""
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.ops.moe_ops import _experts
+
+    def reckoned(shape):
+        return moe_gmm._vmem_bytes(*moe_gmm.gmm_plan(*shape), shape[3])
+
+    widest = max(LONGEST_PREFILL_GMM,
+                 key=lambda n: reckoned(LONGEST_PREFILL_GMM[n]))
+    assert widest == "pangu_up"
+    M, D, F, item = LONGEST_PREFILL_GMM[widest]
+    tm, tk, tn = moe_gmm.gmm_plan(M, D, F, item)
+    assert (tm, tk) == (128, D)
+    assert 32 << 20 < reckoned(LONGEST_PREFILL_GMM[widest]) \
+        <= moe_gmm._VMEM_LIMIT_BYTES
+    E, held, k = 256, 8, 8
+
+    def layer(x, router, gate, up, down):
+        out, _aux, sizes = _experts(x, gate, up, None, down, None, router,
+                                    E, k, None, "swiglu", True, 0.0,
+                                    share=(0, held))
+        return out, sizes
+
+    sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+           for shape, dtype in (((M // k, D), F32), ((D, E), BF16),
+                                ((held, D, F), BF16), ((held, D, F), BF16),
+                                ((held, F, D), BF16))]
+    before = _gmm_plans()
+    lowered = jax.jit(layer).lower(*sds)
+    after = _gmm_plans()
+    assert {key: v - before.get(key, 0) for key, v in after.items()
+            if v != before.get(key, 0)} == {
+        (moe_gmm.KERNEL_UP, "%dx%dx%d" % (tm, tk, tn), "pallas"): 1,
+        (moe_gmm.KERNEL_DOWN,
+         "%dx%dx%d" % moe_gmm.gmm_plan(*LONGEST_PREFILL_GMM["pangu_down"]),
+         "pallas"): 1}
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+
+
+@pytest.mark.parametrize("n_rhs", [1, 2])
+def test_whole_reduction_plan_matches_composed_on_ragged_groups(n_rhs):
+    """The plan Xing's up product takes (the reduction of 3,584 held
+    whole) in interpret mode against the composed form: a group that
+    straddles a row tile, an empty group, a group inside another's tile,
+    and rows past the last group, which come out zero."""
+    from paddle_tpu.kernels import moe_gmm
+
+    sizes = [130, 0, 150, 37, 0, 20]      # 337 of 400 rows are owned
+    M, K, N = 400, 3584, 1024
+    plan = moe_gmm.gmm_plan(M, K, N, 2)
+    assert plan[:2] == (128, K)
+    rng = np.random.default_rng(42)
+    lhs = jnp.asarray(rng.standard_normal((M, K)), F32)
+    rhs = tuple(jnp.asarray(rng.standard_normal((len(sizes), K, N))
+                            / K ** 0.5, BF16) for _ in range(n_rhs))
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = moe_gmm.gmm_pallas(lhs, rhs, gs, name=moe_gmm.KERNEL_UP,
+                             plan=plan, interpret=True)
+    want = moe_gmm.gmm_composed(lhs, rhs, gs)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    assert not np.asarray(got[sum(sizes):]).any()

@@ -13,15 +13,11 @@ import pytest
 
 
 @pytest.fixture(autouse=True)
-def _clean_kernel_tier():
-    """Injected tuned entries and dispatch-ledger state must never leak
-    into later tests — even when an assert fails mid-test (the
-    test_kernel_tune.py pattern)."""
+def _clean_decisions():
+    """The decision ledger must never leak into later tests."""
     yield
     from paddle_tpu import kernels
-    from paddle_tpu.kernels import tune
 
-    tune.reset()
     kernels.reset_decisions()
 
 
@@ -111,50 +107,64 @@ def test_short_seq_causal_and_bias_parity(monkeypatch):
                                atol=2e-5)
 
 
-def test_flash_dispatch_precedence_three_tiers(monkeypatch, tmp_path):
-    """Explicit env > tuned kernel-tier entry > static threshold — the
-    documented precedence (flash_effective docstring, docs/KERNELS.md),
-    each tier exercised in isolation."""
-    from paddle_tpu.kernels import tune
+SEQUENCE_CASES = {
+    # name: (PADDLE_TPU_FLASH_MIN_SEQ or None, the op's own flash_min_seq
+    #        or None, Sq, Sk, the kernel runs)
+    "bert_train_s128_cell": (None, None, 128, 128, False),
+    "bert_train_s512_cell": (None, None, 512, 512, True),
+    "one_under_256": (None, None, 255, 255, False),
+    "at_256": (None, None, 256, 256, True),
+    "longer_side_decides": (None, None, 64, 512, True),
+    "both_sides_short": (None, None, 64, 128, False),
+    "ops_own_128_in_place_of_256": (None, 128, 200, 200, True),
+    "under_the_ops_own_128": (None, 128, 127, 127, False),
+    "env_wins_over_the_ops_own": ("1024", 128, 512, 512, False),
+    "env_zero_forces_the_kernel": ("0", None, 8, 8, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEQUENCE_CASES))
+def test_the_sequence_decides(case, monkeypatch):
+    """Flash against composed is a threshold on ``max(Sq, Sk)``:
+    ``PADDLE_TPU_FLASH_MIN_SEQ`` where it is set, else the op's own
+    ``flash_min_seq=``, else 256 — and nothing else is asked.
+    ``decisions_seen()["attention"]`` (what benchmarks/lib/train_loop.py
+    reports) says which form the call lowered to, and below the
+    threshold the result IS the composed one."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import kernels
     from paddle_tpu.ops import attention as A
 
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_CACHE_DIR",
-                       str(tmp_path / "kc"))
-    tune.reset()
-
-    # tier 3: no env, no tuned entry -> the static 256 default
-    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
-    assert not A.flash_effective(128)
-    assert A.flash_effective(512)
-
-    # tier 2: a tuned entry supersedes the static threshold (both ways)
-    tune.set_entry("attention", (128, 128),
-                   {"choice": "pallas", "cfg": [128, 128]})
-    tune.set_entry("attention", (512, 512),
-                   {"choice": "composed", "cfg": None})
-    assert A.flash_effective(128)       # tuned flash below the default
-    assert not A.flash_effective(512)   # tuned composed above it
-    assert A.flash_effective(1024)      # untouched sig: static tier
-
-    # tier 1: an explicit env value wins over the tuned entries
-    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "1024")
-    assert not A.flash_effective(128)
-    assert not A.flash_effective(512)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
-    assert A.flash_effective(512)
-
-    # the kernel-tier bypass disables tier 2 (back to static), and the
-    # dispatch decision ledger records what ran
-    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
-    monkeypatch.setenv("PADDLE_TPU_KERNELS", "0")
-    assert not A.flash_effective(128)   # tuned flash entry ignored
+    env, own, sq, sk, want = SEQUENCE_CASES[case]
+    if env is None:
+        monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    else:
+        monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", env)
+    assert A._flash_decision(sq, sk, own) is want
+    if own is None:
+        assert A.flash_effective(sq, sk) is want
+    rs = np.random.RandomState(3)
+    q = jnp.asarray(rs.randn(1, 1, sq, 32).astype("float32"))
+    k, v = (jnp.asarray(rs.randn(1, 1, sk, 32).astype("float32"))
+            for _ in range(2))
+    kernels.reset_decisions()
+    out = A.flash_attention(q, k, v, None, 32 ** -0.5, min_seq=own)
+    assert kernels.decisions_seen()["attention"] == {
+        "choice": "flash" if want else "composed"}
+    ref = A.composed_attention(q, k, v, scale=32 ** -0.5)
+    if want:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+    else:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_flash_env_keys_the_plan_cache(monkeypatch):
-    """Changing PADDLE_TPU_FLASH_MIN_SEQ mid-process re-prepares: the
-    precedence's tier-1 lever is absolute, so a plan cached under one
-    env value must never be served under another (the flash knobs ride
-    kernels.config_key() into the executor's plan-cache key)."""
+    """Changing PADDLE_TPU_FLASH_MIN_SEQ mid-process re-prepares: a plan
+    cached under one env value must never be served under another (the
+    value rides kernels.config_key() into the executor's plan-cache
+    key)."""
     import paddle_tpu as fluid
     from paddle_tpu.core.scope import Scope, scope_guard
     from paddle_tpu.observe.families import EXECUTOR_CACHE_MISSES
@@ -178,32 +188,6 @@ def test_flash_env_keys_the_plan_cache(monkeypatch):
         assert EXECUTOR_CACHE_MISSES.value == m0 + 1  # re-prepared
         exe.run(main, feed={"x": X}, fetch_list=[loss], scope=scope)
         assert EXECUTOR_CACHE_MISSES.value == m0 + 1  # then cache-hits
-
-
-def test_tuned_dispatch_same_numerics(monkeypatch, tmp_path):
-    """A tuned 'composed' entry at a kernel-eligible S produces the
-    composed result exactly (the dispatch flip is numerics-neutral),
-    and the decision ledger marks the choice as tuned."""
-    from paddle_tpu import kernels
-    from paddle_tpu.kernels import tune
-    from paddle_tpu.ops import attention as A
-
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_CACHE_DIR",
-                       str(tmp_path / "kc"))
-    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
-    tune.reset()
-    kernels.reset_decisions()
-    q, k, v = _qkv(S=320)
-    scale = q.shape[-1] ** -0.5
-    tune.set_entry("attention", (320, 320),
-                   {"choice": "composed", "cfg": None})
-    out = A.flash_attention(q, k, v, scale=scale)
-    np.testing.assert_allclose(
-        np.asarray(out),
-        np.asarray(A.composed_attention(q, k, v, scale=scale)),
-        rtol=0, atol=0)  # identical: it IS the composed path
-    dec = kernels.decisions_seen()["attention"]
-    assert dec == {"choice": "composed", "tuned": True}
 
 
 def test_fused_attention_op_short_seq_trains(monkeypatch):

@@ -1,0 +1,379 @@
+"""Every shape a benchmark cell runs has a legal kernel plan.
+
+A kernel's form is decided beside the kernel, from its operands
+(docs/KERNELS.md). This file walks the programs the benchmark's
+configurations really build — the decode step at ``b_max`` slots and a
+prefill at each prompt length of the configuration's traffic
+(``benchmarks/configs/``, ``benchmarks/traffic/``, paired by
+``BENCHMARK.json``), the BERT train step at each cell's sequence — and,
+for every op a kernel stands behind, calls the kernel's own plan
+function with the op's own shapes: the plan divides or pads as the kernel
+states, fits the byte caps and the VMEM reckoning the kernel itself
+uses, and IS a plan (not ``None``, the composed fallback) wherever the
+ledger's device breakdown shows the kernel running in that cell.
+
+The programs are built as IR only and the plan functions are called
+directly: nothing is traced, compiled or run. (PR 40's miss was a width
+of 21 x 128 in a branch no cell had entered before; a case here would
+have said so on the CPU.)
+"""
+
+import functools
+import json
+import os
+import types
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _json(*path):
+    with open(os.path.join(ROOT, *path)) as f:
+        return json.load(f)
+
+
+MANIFEST = _json("BENCHMARK.json")
+CONFIG_FILES = {c["name"]: c["file"] for c in MANIFEST["configs"]}
+
+# the ops of each kind of program that a kernel stands behind; the test
+# holds a program to exactly its row, so a builder that starts (or stops)
+# reaching a kernel has to say so here
+REACHES = {
+    "bert-base": {"train": ("fused_attention",)},
+    "gpt2-medium": {"decode": ("kv_cache_write",),
+                    "prefill": ("kv_cache_write",)},
+    "olmoe-1b-7b": {"decode": ("kv_cache_write", "moe_ffn"),
+                    "prefill": ("kv_cache_write", "moe_ffn")},
+    "trinity-large-preview": {
+        "decode": ("kv_cache_write", "moe_ffn"),
+        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
+    "openpangu-ultra-moe-718b": {
+        "decode": ("kv_cache_write", "mla_decode", "moe_ffn"),
+        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
+    "xing4.0-29b-a4b": {
+        "decode": ("kv_cache_write", "mhc_post", "mhc_pre", "mla_decode",
+                   "moe_ffn"),
+        "prefill": ("fused_attention", "kv_cache_write", "mhc_post",
+                    "mhc_pre", "moe_ffn")},
+    "nemotron-3-super-120b-a12b": {
+        "decode": ("kv_cache_write", "moe_ffn", "ssm_update"),
+        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn",
+                    "ssm_scan")},
+}
+KERNEL_OPS = frozenset(t for kinds in REACHES.values()
+                       for types in kinds.values() for t in types)
+
+# (configuration, op) whose kernel the ledger's ``breakdown.device_ops``
+# names in that configuration's cells (PERF_LEDGER.jsonl, PR 42): there
+# the plan may not be None. Elsewhere a plan is legal or None.
+RUNS_ON_THE_CHIP = {
+    ("bert-base", "fused_attention"),             # flash_fwd/refwd/bwd_*
+    ("gpt2-medium", "kv_cache_write"),
+    ("olmoe-1b-7b", "kv_cache_write"), ("olmoe-1b-7b", "moe_ffn"),
+    ("trinity-large-preview", "fused_attention"),  # flash_fwd(_win)
+    ("trinity-large-preview", "moe_ffn"),
+    ("openpangu-ultra-moe-718b", "fused_attention"),
+    ("openpangu-ultra-moe-718b", "mla_decode"),
+    ("openpangu-ultra-moe-718b", "moe_ffn"),
+    ("xing4.0-29b-a4b", "fused_attention"), ("xing4.0-29b-a4b", "mla_decode"),
+    ("xing4.0-29b-a4b", "mhc_pre"), ("xing4.0-29b-a4b", "mhc_post"),
+    ("xing4.0-29b-a4b", "moe_ffn"),
+    ("nemotron-3-super-120b-a12b", "moe_ffn"),
+    ("nemotron-3-super-120b-a12b", "ssm_scan"),
+    ("nemotron-3-super-120b-a12b", "ssm_update"),
+}
+
+
+def _programs(config):
+    """The program ids of one configuration, from the traffic files of
+    its cells: ``train_s<S>`` a train cell, else ``decode`` and a
+    ``prefill_p<P>`` a prompt length."""
+    ids = []
+    for w in MANIFEST["workloads"]:
+        if w["config"] != config:
+            continue
+        traffic = _json("benchmarks", "traffic", w["traffic"] + ".json")
+        if "seq" in traffic:
+            ids.append("train_s%d" % traffic["seq"])
+        else:
+            ids.append("decode")
+            ids += ["prefill_p%d" % int(p)
+                    for p in sorted(traffic["prompt_lengths"], key=int)]
+    return sorted(set(ids), key=lambda i: (i.split("_")[0], _length(i)))
+
+
+def _length(program):
+    """512 of ``train_s512`` / ``prefill_p512``; 0 of ``decode``."""
+    return int("".join(c for c in program if c.isdigit()) or 0)
+
+
+CASES = [(config, program, op)
+         for config in sorted(REACHES) for program in _programs(config)
+         for op in REACHES[config][program.split("_")[0]]]
+
+
+@functools.lru_cache(maxsize=None)
+def _built(config, program):
+    """(the program's IR, the batch its ``-1`` stands for): built exactly
+    as the benchmark's kinds build it, nothing lowered."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import bert, gpt
+
+    conf = _json(CONFIG_FILES[config])
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        if program.startswith("train"):
+            traffic = next(
+                t for t in (_json("benchmarks", "traffic",
+                                  w["traffic"] + ".json")
+                            for w in MANIFEST["workloads"]
+                            if w["config"] == config)
+                if "train_s%d" % t["seq"] == program)
+            loss, _ = bert.build(dict(conf["model"]), seq_len=traffic["seq"],
+                                 max_mask=traffic["max_mask"])
+            fluid.optimizer.Adam(
+                learning_rate=conf["train"]["learning_rate"]).minimize(loss)
+            return main, traffic["batch"]
+        cfg = dict(gpt.base_config(), **conf["model"])
+        b_max, max_len = conf["serving"]["b_max"], conf["serving"]["max_len"]
+        if program == "decode":
+            gpt.build_serving_decode_step(cfg, batch=b_max, max_len=max_len)
+            return main, b_max
+        gpt.build_prefill_step(cfg, batch=1,
+                               prompt_len=_length(program),
+                               max_len=max_len)
+        return main, 1
+
+
+def _operand(block, op, slot, batch):
+    """(shape with the batch filled in, numpy dtype) of an op's input."""
+    import jax.numpy as jnp
+
+    var = block.vars[op.inputs[slot][0]]
+    return (tuple(batch if d < 0 else int(d) for d in var.shape),
+            jnp.dtype(var.dtype))
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The dispatch predicates as a TPU process evaluates them: kernels
+    compile (no interpret mode) and the sequence threshold is the
+    program's own, not the suite's "always the kernel"."""
+    monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "0")
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_KERNELS", raising=False)
+
+
+# ------------------------------------------------------- one check an op
+def _check_kv_cache_write(block, op, batch, must):
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    shape, dtype = _operand(block, op, "Cache", batch)
+    rows = _operand(block, op, "Update", batch)[0][2]
+    per_slot = int(np.prod(_operand(block, op, "Pos", batch)[0])) > 1
+    plan = kvw.write_plan(shape, dtype)
+    if not (per_slot and rows == 1):
+        # a prefill's slab write (or a scalar position): composed by the
+        # dispatch's own rule, whatever the plan
+        assert not must or rows > 1
+        return "composed"
+    if plan is None:
+        assert not must, "no block plan for the cache %s %s" % (shape, dtype)
+        return "composed"
+    form, blk = plan
+    B, H, S, D = shape
+    seen = (B, H, D, S) if form == "cols" else shape
+    assert form in ("rows", "cols") and mosaic_ok(blk, seen)
+    assert all(a % b == 0 for a, b in zip(seen, blk)), (seen, blk)
+    assert int(np.prod(blk)) * dtype.itemsize <= kvw._MAX_BLOCK_BYTES
+    return form
+
+
+def _check_moe_ffn(block, op, batch, must):
+    from paddle_tpu.kernels import moe_gmm
+    from paddle_tpu.kernels.common import ceil_to, mosaic_ok
+
+    x_shape, _ = _operand(block, op, "X", batch)
+    M = int(op.attrs["top_k"]) * int(np.prod(x_shape[:-1]))
+    got = []
+    for slot in ("W1", "W2"):
+        (_E, K, N), dtype = _operand(block, op, slot, batch)
+        plan = moe_gmm.gmm_plan(M, K, N, dtype.itemsize)
+        assert plan is not None or not must, (M, K, N, dtype)
+        if plan is None:
+            continue
+        tm, tk, tn = plan
+        assert tm % 8 == 0 and tm <= 128
+        # padding rhs would copy every expert's weights: whole divisors
+        assert K % tk == 0 and N % tn == 0
+        assert (tk % 128 == 0 or tk == K) and (tn % 128 == 0 or tn == N)
+        assert mosaic_ok((1, tk, tn), (1, K, N))
+        assert mosaic_ok((tm, tk), (ceil_to(M, tm), K))
+        assert tk * tn * dtype.itemsize <= moe_gmm._MAX_RHS_BLOCK_BYTES
+        assert moe_gmm._vmem_bytes(tm, tk, tn, dtype.itemsize) \
+            <= moe_gmm._VMEM_LIMIT_BYTES
+        got.append(plan)
+    return got
+
+
+def _check_mla_decode(block, op, batch, must):
+    from paddle_tpu.kernels import mla_decode as K
+
+    shape, dtype = _operand(block, op, "Cache", batch)
+    H = _operand(block, op, "QNope", batch)[0][2]
+    bs = K.decode_plan(shape, dtype, H)
+    assert bs is not None or not must, (shape, dtype, H)
+    if bs is not None:
+        S, W = shape[2], shape[3]
+        assert bs in K._BLOCK_CHOICES and S % bs == 0 and H % 8 == 0
+        assert bs * -(-W // 128) * 128 * dtype.itemsize \
+            <= K._MAX_BLOCK_BYTES
+    return bs
+
+
+def _check_mhc(block, op, batch, must):
+    from paddle_tpu.kernels import mhc
+    from paddle_tpu.kernels.common import ceil_to, mosaic_ok
+
+    shape, _ = _operand(block, op, "X", batch)
+    n, width, R = int(op.attrs["n"]), shape[-1], int(np.prod(shape[:-1]))
+    # ``_takes_kernel`` reads x's shape alone
+    takes = mhc._takes_kernel(types.SimpleNamespace(shape=(R, width)), n)
+    assert takes or not must, (shape, n)
+    if takes:
+        tr = mhc.block_rows(R)
+        assert tr % 8 == 0 and tr <= mhc._BLOCK_ROWS
+        assert mosaic_ok((tr, width), (ceil_to(R, tr), width))
+        # x in and out and the projected vector, float32, double-buffered
+        assert 2 * tr * (2 * width + width // n) * 4 \
+            <= mhc._VMEM_LIMIT_BYTES
+    return takes
+
+
+def _check_ssm_update(block, op, batch, must):
+    from paddle_tpu.kernels import ssm
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    shape, _ = _operand(block, op, "State", batch)
+    blk = ssm._update_plan(shape)
+    assert blk is not None or not must, shape
+    if blk is not None:
+        assert mosaic_ok(blk, shape)
+        assert all(a % b == 0 for a, b in zip(shape, blk))
+    return blk
+
+
+def _check_ssm_scan(block, op, batch, must):
+    from paddle_tpu.kernels import ssm
+
+    (_B, T, HP), _ = _operand(block, op, "X", batch)
+    H, G, N = (int(op.attrs[k]) for k in ("heads", "groups", "state"))
+    chunk = int(op.attrs["chunk"])
+    Q = ssm._scan_plan(T, H, HP // H, G, N, chunk)
+    assert Q is not None or not must, (T, H, HP // H, G, N, chunk)
+    if Q is not None:
+        assert Q == chunk and Q % 128 == 0 and HP // H <= Q
+    return Q
+
+
+def _check_fused_attention(block, op, batch, must):
+    """The forward's plan (banded and latent forms included) and, where
+    the program holds the grad op, both backward kernels'."""
+    from paddle_tpu.ops import attention as A
+
+    q, dtype = _operand(block, op, "Q", batch)
+    k, _ = _operand(block, op, "K", batch)
+    v, _ = _operand(block, op, "V", batch)
+    attrs = op.attrs
+    lanes = None
+    if len(q) == 3:                                  # [B, S, H*D]
+        H = int(attrs["n_head"])
+        S, Sk, D = q[1], k[1], q[2] // H
+        Dv, Hkv = D, H
+        assert A._lanes_ok(H, D)
+        lanes = A._Lanes(D)
+    else:
+        _, H, S, D = q
+        Hkv, Sk, Dv = k[1], k[2], v[3]
+    causal = bool(attrs.get("causal", False))
+    window = int(attrs.get("window", 0) or 0) or None
+    if not A._flash_decision(S, Sk, attrs.get("flash_min_seq") or None):
+        # under the threshold (256, latent attention's own 128): composed
+        assert max(S, Sk) < (attrs.get("flash_min_seq") or 256)
+        return "composed"
+    assert H % Hkv == 0 and (not causal or S == Sk)
+    if window is not None and window >= S:
+        window = None
+    Sp, Skp, bq, bk = A._forward_plan(S, Sk, D, dtype, causal, window)
+    plans = {A.KERNEL_FWD: (Sp, Skp, bq, bk)}
+    trains = any(o.type == "fused_attention_grad" for o in block.ops)
+    if trains:
+        for kern in (A.KERNEL_BWD_DKV, A.KERNEL_BWD_DQ):
+            plans[kern] = A._padded_plan(kern, S, Sk, D, dtype, causal,
+                                         False)
+    for kern, (Sp, Skp, bq, bk) in plans.items():
+        # pads to lane tiles, or (the causal forward) to whole 512 blocks
+        most = A._MAX_BLOCK if causal and kern == A.KERNEL_FWD else A._LANE
+        assert 0 <= Sp - S < most and 0 <= Skp - Sk < most, (kern, Sp, Skp)
+        assert Sp % bq == 0 and Skp % bk == 0, (kern, bq, bk)
+        assert bq % 8 == 0 and (bk % A._LANE == 0 or bk == Skp)
+        # one float32 score tile: what every kernel's VMEM account counts
+        assert bq * bk <= A._MAX_BLOCK * A._MAX_BLOCK
+        if window is not None and kern == A.KERNEL_FWD:
+            assert bk <= A._pad_len(window, A._LANE)
+    Sp, Skp, bq, bk = plans[A.KERNEL_FWD]
+    heads = 1 if H != Hkv else A._heads_per_step(
+        H, Skp == bk, None, width=max(D, Dv), lanes=lanes)
+    assert H % heads == 0
+    assert heads * -(-max(D, Dv) // A._LANE) <= max(
+        A._HEADS_PER_STEP, 1 if lanes is None else lanes.per)
+    return plans
+
+
+CHECKS = {
+    "fused_attention": _check_fused_attention,
+    "kv_cache_write": _check_kv_cache_write,
+    "mhc_post": _check_mhc, "mhc_pre": _check_mhc,
+    "mla_decode": _check_mla_decode,
+    "moe_ffn": _check_moe_ffn,
+    "ssm_scan": _check_ssm_scan, "ssm_update": _check_ssm_update,
+}
+
+
+@pytest.mark.parametrize("config,program,op_type", CASES,
+                         ids=["%s-%s-%s" % c for c in CASES])
+def test_every_cell_shape_has_a_legal_plan(config, program, op_type,
+                                           on_the_chip):
+    main, batch = _built(config, program)
+    block = main.global_block()
+    kind = program.split("_")[0]
+    # the program reaches the kernels its row says, and no other
+    assert tuple(sorted({op.type for op in block.ops} & KERNEL_OPS)) \
+        == REACHES[config][kind]
+    ops = [op for op in block.ops if op.type == op_type]
+    must = (config, op_type) in RUNS_ON_THE_CHIP
+    got = [CHECKS[op_type](block, op, batch, must) for op in ops]
+    if op_type == "kv_cache_write":
+        # a prefill writes its slab composed; the decode step writes one
+        # row a slot, the kernel's case
+        if kind == "prefill":
+            assert all(g == "composed" for g in got)
+        elif must:
+            assert all(g in ("rows", "cols") for g in got)
+    if op_type == "fused_attention" and must:
+        # the kernel runs at every prompt of the cell's traffic that
+        # reaches its threshold, and at the S 512 cell; S 128 is composed
+        S = _length(program)
+        least = 128 if "flash_min_seq" in ops[0].attrs else 256
+        assert all((g == "composed") == (S < least) for g in got)
+
+
+def test_the_case_list_covers_every_configuration_of_the_manifest():
+    assert sorted(REACHES) == sorted(CONFIG_FILES)
+    assert {c for c, _p, _o in CASES} == set(CONFIG_FILES)
+    # every cell's traffic contributed its programs
+    for w in MANIFEST["workloads"]:
+        assert _programs(w["config"])

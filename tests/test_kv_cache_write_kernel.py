@@ -9,7 +9,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu import kernels
 from paddle_tpu.kernels import kv_cache_write as kvw
 from paddle_tpu.observe.families import KV_CACHE_WRITE_PLANS
 
@@ -49,9 +48,8 @@ def test_kernel_equals_fallback_bit_for_bit(shape, dtype, positions):
     # a float32 update into either cache: the cast is the kernel's too
     upd = jnp.asarray(rs.randn(B, H, 1, D), F32)
     pos = jnp.asarray(_positions(positions, S), jnp.int32).reshape(B, 1)
-    kdef = kernels.get_kernel(kvw.KERNEL)
-    got = kdef.pallas(None, cache, upd, pos, interpret=True)
-    want = kdef.fallback(cache, upd, pos)
+    got = kvw.kv_cache_write_pallas(cache, upd, pos, interpret=True)
+    want = kvw.kv_cache_write_composed(cache, upd, pos)
     assert got.dtype == want.dtype == jnp.dtype(dtype)
     bits = np.uint32 if dtype == F32 else np.uint16
     got, want = (np.asarray(a).view(bits) for a in (got, want))
@@ -72,7 +70,7 @@ def test_no_plan_where_the_block_would_not_tile():
     assert kvw.write_plan((2, 128, 1024, 64), F32) is None
     with pytest.raises(ValueError, match="no block plan"):
         kvw.kv_cache_write_pallas(
-            None, jnp.zeros((3, 2, 1000, 64)), jnp.zeros((3, 2, 1, 64)),
+            jnp.zeros((3, 2, 1000, 64)), jnp.zeros((3, 2, 1, 64)),
             jnp.zeros((3,), jnp.int32), interpret=True)
 
 

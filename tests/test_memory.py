@@ -16,9 +16,6 @@
   synthetic over-budget program, stays silent without a budget /
   on the zoo; max-safe-batch solves the closed form; dead-persistable
   flags untouched resident state;
-* window-tune pruning: under a constrained budget, over-budget
-  candidates are provably skipped (counter + decision record) without
-  perturbing scope state;
 * serving: the predicted-bytes admission guard (engine + router) and
   ``decode_cache_bytes``;
 * tools/memory_report.py CLI: text + JSON + exit 1 on budget violation.
@@ -369,84 +366,6 @@ def test_zoo_stays_clean_under_memory_rules():
     noisy = [f.format() for f in findings
              if f.severity in ("error", "warning")]
     assert not noisy, noisy
-
-
-# ------------------------------------------------- window-tune pruning
-def test_window_tune_prunes_over_budget_candidates(monkeypatch, tmp_path):
-    """Under a constrained device budget, candidates whose predicted
-    peak exceeds it are skipped WITHOUT measurement (counter + pruned
-    decision records), the winner comes from the survivors, and scope
-    state stays bitwise untouched."""
-    from paddle_tpu.core import window_tune as wt
-    from paddle_tpu.kernels import tune
-
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_TUNE_DETERMINISTIC", "7")
-    tune.reset()
-    main, startup, loss = _fc_train()
-    batch = 8
-    feed = {"x": np.random.RandomState(0).randn(batch, 4)
-            .astype("float32")}
-    ma = MemoryAnalysis(main, fetch_names=[loss.name])
-    # budget holds K<=10 but provably not K=25/50
-    budget = ma.peak_bytes(batch, steps_per_call=10)
-    assert budget < ma.peak_bytes(batch, steps_per_call=25)
-    monkeypatch.setenv("PADDLE_TPU_DEVICE_HBM_BYTES", str(budget))
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        names = sorted(scope.local_var_names())
-        before_state = [(n, np.asarray(scope.find_var(n)).copy())
-                        for n in names]
-        pruned_before = _value("paddle_analysis_memory_pruned_total")
-        try:
-            dec = wt.tune_train_window(exe, main, feed,
-                                       fetch_list=[loss], scope=scope)
-        finally:
-            tune.reset()
-        assert _value("paddle_analysis_memory_pruned_total") \
-            == pruned_before + 2
-        by_label = {t["label"]: t for t in dec["timings"]}
-        for k in (25, 50):
-            t = by_label["window:%d" % k]
-            assert t.get("pruned") is True and t["seconds"] is None
-            assert t["predicted_peak_bytes"] > budget
-        for k in (4, 10):
-            assert "pruned" not in by_label["window:%d" % k]
-        assert "pruned" not in by_label["composed"]  # K=1 never pruned
-        # the winner came from the measured survivors
-        win_k = dec["cfg"][0] if dec["choice"] == "pallas" else 1
-        assert win_k in (1, 4, 10)
-        # scope state bitwise untouched (training semantics preserved)
-        for n, arr in before_state:
-            assert np.asarray(scope.find_var(n)).tobytes() \
-                == arr.tobytes(), n
-
-
-def test_window_tune_no_budget_moves_no_prune_counter(monkeypatch,
-                                                      tmp_path):
-    from paddle_tpu.core import window_tune as wt
-    from paddle_tpu.kernels import tune
-
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_TUNE_DETERMINISTIC", "7")
-    monkeypatch.delenv("PADDLE_TPU_DEVICE_HBM_BYTES", raising=False)
-    tune.reset()
-    main, startup, loss = _fc_train()
-    feed = {"x": np.zeros((8, 4), "float32")}
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        before = _value("paddle_analysis_memory_pruned_total")
-        try:
-            dec = wt.tune_train_window(exe, main, feed,
-                                       fetch_list=[loss], scope=scope)
-        finally:
-            tune.reset()
-    assert _value("paddle_analysis_memory_pruned_total") == before
-    assert all("pruned" not in t for t in dec["timings"])
 
 
 # ------------------------------------------------------ serving guard

@@ -518,6 +518,91 @@ def test_optimized_amp_training_is_bitwise_identical(monkeypatch):
         assert np.array_equal(p0[n], p2[n]), n
 
 
+# tiny presets of the two model families the benchmark's cells train and
+# serve: residual add -> layer_norm pairs a layer, and an ``adam`` op a
+# parameter (what the deleted kernel-tier fusion pass used to rewrite)
+_TINY = dict(d_model=32, d_ff=64, n_head=2, n_layer=2, vocab=64,
+             max_length=16, dropout=0.1)
+
+
+def _tiny_model(family, optimizer="adam", amp=False, seed=5):
+    from paddle_tpu.models import bert, gpt
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    rs = np.random.RandomState(seed)
+    B, S, M = 2, 8, 3
+    with fluid.program_guard(main, startup):
+        with fluid.unique_name.guard():
+            if family == "bert":
+                loss, _ = bert.build(dict(_TINY, type_vocab=2), seq_len=S,
+                                     max_mask=M)
+                feed = {
+                    "src_ids": rs.randint(1, 64, (B, S)).astype("int64"),
+                    "sent_ids": rs.randint(0, 2, (B, S)).astype("int64"),
+                    "input_mask": np.ones((B, S), "float32"),
+                    "mask_pos": rs.randint(0, B * S, (B, M)).astype("int64"),
+                    "mask_label": rs.randint(0, 64, (B, M)).astype("int64"),
+                    "mask_weight": np.ones((B, M), "float32"),
+                }
+            else:
+                loss, _ = gpt.build(dict(_TINY), seq_len=S)
+                feed = {"ids": rs.randint(1, 64, (B, S)).astype("int64")}
+            opt = (fluid.optimizer.Adam(1e-3) if optimizer == "adam"
+                   else fluid.optimizer.SGD(1e-2))
+            opt.minimize(loss)
+    main.set_amp(amp)
+    return main, startup, loss, feed
+
+
+@pytest.mark.parametrize("family", ["bert", "gpt"])
+def test_level2_leaves_add_layernorm_and_adam_runs_as_written(family):
+    """The pipeline that is left rewrites neither a residual add +
+    ``layer_norm`` pair nor a run of ``adam`` ops: every ``layer_norm``
+    and every ``adam`` of the program is still there at level 2, one op
+    each, and the only fused op types are the elementwise chain and the
+    attention op the builder wrote."""
+    main, _startup, loss, _feed = _tiny_model(family)
+    before = _ops(main)
+    after = _ops(optimize_program(main, fetch_list=[loss], level=2)[0])
+    assert before.count("layer_norm") >= 2 * _TINY["n_layer"]
+    for kind in ("layer_norm", "layer_norm_grad", "adam"):
+        assert after.count(kind) == before.count(kind) > 0, kind
+    assert {t for t in after if t.startswith("fused_")} <= {
+        "fused_elementwise", "fused_attention", "fused_attention_grad"}
+
+
+@pytest.mark.parametrize("case", ["sgd", "adam", "adam+amp"])
+def test_optimized_step_is_bitwise_the_unoptimized_one(case, monkeypatch):
+    """Three train steps of the tiny BERT (dropout on, so the RNG chain
+    is real) at level 2 and at level 0: losses and every parameter
+    bitwise equal, with and without bf16 AMP."""
+    optimizer, _, amp = case.partition("+")
+
+    def run(level):
+        monkeypatch.setenv("PADDLE_TPU_OPTIMIZE", str(level))
+        main, startup, loss, feed = _tiny_model("bert", optimizer,
+                                                amp=bool(amp))
+        scope = Scope()
+        with scope_guard(scope):
+            exe = fluid.Executor()
+            exe.run(startup, scope=scope)
+            losses = [np.asarray(exe.run(main, feed=feed, fetch_list=[loss],
+                                         scope=scope)[0])
+                      for _ in range(3)]
+            params = {p.name: np.asarray(scope.find_var(p.name))
+                      for p in main.global_block().all_parameters()}
+        return losses, params
+
+    l0, p0 = run(0)
+    l2, p2 = run(2)
+    assert len(p0) > 10 and np.isfinite(l0[-1]).all()
+    for a, b in zip(l0, l2):
+        assert np.array_equal(a, b)
+    for n in p0:
+        assert np.array_equal(p0[n], p2[n]), n
+
+
 def test_level0_provably_bypasses_pipeline(monkeypatch):
     """PADDLE_TPU_OPTIMIZE=0: zero movement across EVERY
     paddle_optimizer_* family while the program still runs."""

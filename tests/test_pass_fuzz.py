@@ -1,7 +1,7 @@
 """tools/pass_fuzz.py: the differential pass fuzzer, wired into CI.
 
 * fast tier: a fixed-seed ~25-program smoke (level 2 vs level 0 bitwise
-  + TV-clean) and the six-miscompile knock-out corpus — each corpus
+  + TV-clean) and the five-miscompile knock-out corpus — each corpus
   entry must be (a) differentially clean with its guard in place,
   (b) caught BY THE TRANSLATION VALIDATOR (a ``tv-*`` violation, not
   just a wrong number) with the guard knocked out, and (c) a REAL
@@ -44,7 +44,7 @@ def test_pass_fuzz_fixed_seed_smoke():
 
 @pytest.mark.parametrize("name", sorted(pass_fuzz.CORPUS))
 def test_miscompile_corpus_guarded_clean_and_tv_catches(name):
-    """The six historical miscompiles: guarded pipeline is clean; with
+    """The five historical miscompiles: guarded pipeline is clean; with
     the guard knocked out the translation validator trips (tv-* rule);
     with the guard out AND validation off the miscompile is real."""
     r = pass_fuzz.corpus_check(name)

@@ -413,43 +413,82 @@ def _flash_text():
     return jax.jit(both).lower(x, x, x).as_text(debug_info=True)
 
 
-def _layernorm_text():
-    from paddle_tpu.kernels import layernorm
-
-    x = jnp.ones((16, 128), jnp.float32)
-    v = jnp.ones((128,), jnp.float32)
-
-    def loss(x, r, s, b):
-        return jnp.sum(layernorm.layernorm_residual((8,), x, r, s, b,
-                                                    eps=1e-5)[0])
-
-    return jax.jit(jax.grad(loss)).lower(x, x, v, v).as_text(
-        debug_info=True)
+def _lowered(fn, *shapes):
+    args = [jnp.zeros(sh, dt) for sh, dt in shapes]
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
 
 
-def _adam_text():
-    from paddle_tpu.kernels.optimizer_update import adam_update
+def _kv_cache_write_text():
+    from paddle_tpu.kernels import kv_cache_write as kvw
 
-    p = jnp.ones((1024,), jnp.float32)
-    return jax.jit(lambda *a: adam_update((8,), *a)).lower(
-        p, p, p, p, p, p).as_text(debug_info=True)
+    return _lowered(
+        lambda c, u, p: kvw.kv_cache_write_pallas(c, u, p, interpret=True),
+        ((4, 2, 256, 64), jnp.float32), ((4, 2, 1, 64), jnp.float32),
+        ((4, 1), jnp.int32))
 
 
-def _sgd_text():
-    from paddle_tpu.kernels.optimizer_update import sgd_update
+def _gmm_text():
+    from paddle_tpu.kernels import moe_gmm
 
-    p = jnp.ones((1024,), jnp.float32)
-    return jax.jit(lambda *a: sgd_update((8,), *a)).lower(
-        p, p, p).as_text(debug_info=True)
+    def both(lhs, up, down, gs):
+        h = moe_gmm.gmm_pallas(lhs, (up,), gs, name=moe_gmm.KERNEL_UP,
+                               interpret=True)
+        return moe_gmm.gmm_pallas(h, (down,), gs, name=moe_gmm.KERNEL_DOWN,
+                                  interpret=True)
+
+    return _lowered(both, ((40, 64), jnp.float32),
+                    ((6, 64, 128), jnp.float32), ((6, 128, 64), jnp.float32),
+                    ((6,), jnp.int32))
+
+
+def _mla_decode_text():
+    from paddle_tpu.kernels import mla_decode as K
+
+    return _lowered(
+        lambda q, c, p: K.mla_decode_pallas(q, c, p, d_c=32, scale=0.1,
+                                            interpret=True),
+        ((4, 8, 40), jnp.float32), ((4, 1, 128, 40), jnp.float32),
+        ((4,), jnp.int32))
+
+
+def _mhc_text():
+    from paddle_tpu.kernels import mhc
+
+    def both(x, phi, alpha, b, y):
+        _h, coef, _dev = mhc.mhc_pre_pallas(
+            x, phi, alpha, b, n=4, eps=1e-6, iters=20, hc_eps=1e-6,
+            clamp=(-30.0, 30.0), interpret=True)
+        return mhc.mhc_post_pallas(x, y, coef, n=4, interpret=True)
+
+    return _lowered(both, ((37, 512), jnp.float32), ((512, 24), jnp.float32),
+                    ((3,), jnp.float32), ((24,), jnp.float32),
+                    ((37, 128), jnp.float32))
+
+
+def _ssm_text():
+    from paddle_tpu.kernels import ssm
+
+    def both(state, x, dt, a, bm, cm):
+        y, _ = ssm.ssm_scan_pallas(x, dt, a, bm, cm, chunk=128,
+                                   interpret=True)
+        return y, ssm.ssm_update_pallas(state, x[:, 0], dt[:, 0], a,
+                                        bm[:, 0], cm[:, 0], interpret=True)
+
+    f32 = jnp.float32
+    return _lowered(both, ((2, 2, 128, 128), f32), ((2, 128, 256), f32),
+                    ((2, 128, 4), f32), ((4,), f32), ((2, 128, 2, 128), f32),
+                    ((2, 128, 2, 128), f32))
 
 
 @pytest.mark.parametrize("lower,names", [
     (_flash_text, ["flash_fwd", "flash_refwd", "flash_bwd_dkv",
                    "flash_bwd_dq"]),
-    (_layernorm_text, ["layernorm_residual_fwd", "layernorm_residual_bwd"]),
-    (_adam_text, ["adam_sweep"]),
-    (_sgd_text, ["sgd_sweep"]),
-], ids=["flash", "layernorm", "adam", "sgd"])
+    (_kv_cache_write_text, ["kv_cache_write"]),
+    (_gmm_text, ["moe_gmm_up", "moe_gmm_down"]),
+    (_mla_decode_text, ["mla_decode"]),
+    (_mhc_text, ["mhc_pre", "mhc_post"]),
+    (_ssm_text, ["ssm_scan", "ssm_update"]),
+], ids=["flash", "kv_cache_write", "moe_gmm", "mla_decode", "mhc", "ssm"])
 def test_kernel_names_reach_the_lowered_stablehlo(lower, names):
     text = lower()
     for name in names:

@@ -481,8 +481,8 @@ def test_engine_tokens_are_the_same_with_the_cache_write_kernel(
     monkeypatch.setattr(kvw, "use_interpret", lambda: False)
     monkeypatch.setattr(
         kvw, "kv_cache_write_pallas",
-        lambda cfg_, cache, upd, pos, interpret=None:
-        run(cfg_, cache, upd, pos, interpret=True))
+        lambda cache, upd, pos, interpret=None:
+        run(cache, upd, pos, interpret=True))
     with_kernel = serve()
     assert kernel.value - before == 2 * cfg["n_layer"]   # K and V a layer
     for got, want, p, n in zip(with_kernel, composed, prompts, budgets):

@@ -188,7 +188,7 @@ def test_scan_kernel_matches_composed(T):
     """Interpret mode, at the smallest shapes the kernel has a plan for
     (state 128, chunk 128): a whole chunk and a ragged last one."""
     ops = _operands(T, 2, T, 4, 64, 2, 128)
-    y, S = ssm.ssm_scan_pallas(None, *ops, chunk=128, interpret=True)
+    y, S = ssm.ssm_scan_pallas(*ops, chunk=128, interpret=True)
     want_y, want_S = ssm.ssm_scan_composed(*ops, chunk=128)
     scale = float(jnp.abs(want_y).max())
     np.testing.assert_allclose(y, want_y, atol=2e-6 * scale)
@@ -201,18 +201,9 @@ def test_update_kernel_matches_composed():
                         jnp.float32)
     args = (state, x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
     want_y, want_S = ssm.ssm_update_composed(*args)
-    y, S = ssm.ssm_update_pallas(None, *args, interpret=True)
+    y, S = ssm.ssm_update_pallas(*args, interpret=True)
     np.testing.assert_allclose(y, want_y, atol=1e-4)
     np.testing.assert_allclose(S, want_S, atol=1e-6)
-
-
-def test_kernels_are_registered_with_their_fallbacks():
-    from paddle_tpu.kernels import get_kernel
-
-    for name, fallback in ((ssm.KERNEL_UPDATE, ssm.ssm_update_composed),
-                           (ssm.KERNEL_SCAN, ssm.ssm_scan_composed)):
-        kdef = get_kernel(name)
-        assert kdef.fallback is fallback and kdef.doc
 
 
 def test_dispatch_counts_the_form_and_chunk_it_took():

@@ -7,13 +7,7 @@ pipeline (ISSUE 13 tentpole).
 * a ragged final window (reader dry / shape change) falls back to the
   per-step path instead of compiling a second scan length, counted in
   ``paddle_pipeline_window_ragged_steps_total``;
-* ``resolve_steps_per_call`` precedence (arg > env > tuned winner > 1)
-  and validation;
-* the window-size autotuner (core/window_tune.py): deterministic-mode
-  selection, persistence to ``tuned_kernels.json``, disk serving, the
-  plan-cache re-key on a new winner, bitwise state restore after a
-  REAL measurement, and the PADDLE_TPU_KERNELS=0 bypass moving zero
-  ``paddle_kernel_*`` counters;
+* ``steps_per_call`` resolves as argument > env > 1, and validates;
 * crash-mid-window resume parity: ``resilient_train_loop`` with K>1
   checkpoints only at window boundaries, records ``steps_per_call`` in
   the manifest, and a crashed-and-recovered run ends bitwise identical
@@ -24,7 +18,6 @@ pipeline (ISSUE 13 tentpole).
   with bitwise parameter/RNG parity asserted alongside.
 """
 
-import json
 import os
 import time
 
@@ -33,10 +26,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe
-from paddle_tpu.core import window_tune as wt
-from paddle_tpu.core.executor import RNG_VAR
+from paddle_tpu.core.executor import RNG_VAR, _resolve_steps_per_call
 from paddle_tpu.core.scope import Scope, scope_guard
-from paddle_tpu.kernels import tune
 
 
 def _value(name, **labels):
@@ -255,23 +246,6 @@ def test_windowed_const_feed_ragged_tail_stays_bitwise():
     _assert_bitwise(s1, s4)
 
 
-def test_window_signature_host_and_device_feeds_agree():
-    """Review regression: resolution sees the HOST batch on the
-    executor-built prefetcher path but the already-converted DEVICE
-    feed on the caller-supplied path (int64 -> int32 under default
-    x64-off) — both must produce the tuner's persisted signature or a
-    tuned winner is silently ignored on one path."""
-    import jax.numpy as jnp
-
-    main, _, _ = _build()
-    host = {"ids": np.arange(6, dtype="int64"),
-            "x": np.zeros((2, 3), dtype="float64")}
-    dev = {"ids": jnp.asarray(np.arange(6), dtype=jnp.int32),
-           "x": jnp.zeros((2, 3), dtype=jnp.float32)}
-    assert wt.window_signature(main, host) == wt.window_signature(main,
-                                                                  dev)
-
-
 def test_windowed_const_feed_transfers_once():
     """const_feed_names in window mode: the stacked window caches by
     NAME — the first window transfers it, every later window reuses the
@@ -297,193 +271,23 @@ def test_windowed_const_feed_transfers_once():
 
 # -------------------------------------------------------- resolution
 def test_resolve_steps_per_call_precedence(monkeypatch):
-    main, _, _ = _build()
-    feed = _batches(1)[0]
-    # default: no env, no tuned entry -> 1
+    """The argument, else PADDLE_TPU_STEPS_PER_CALL, else 1; either
+    source raises on a value that is no integer >= 1, never a silent
+    clamp to the per-step loop."""
     monkeypatch.delenv("PADDLE_TPU_STEPS_PER_CALL", raising=False)
-    assert wt.resolve_steps_per_call(main, feed) == (1, "default")
-    # explicit arg wins over everything
+    assert _resolve_steps_per_call() == 1
     monkeypatch.setenv("PADDLE_TPU_STEPS_PER_CALL", "25")
-    assert wt.resolve_steps_per_call(main, feed, 4) == (4, "arg")
-    # env wins over tuned
-    assert wt.resolve_steps_per_call(main, feed) == (25, "env")
+    assert _resolve_steps_per_call(4) == 4
+    assert _resolve_steps_per_call() == 25
     monkeypatch.setenv("PADDLE_TPU_STEPS_PER_CALL", "bogus")
     with pytest.raises(ValueError, match="STEPS_PER_CALL"):
-        wt.resolve_steps_per_call(main, feed)
-    # same contract as the argument: < 1 raises, never a silent clamp
+        _resolve_steps_per_call()
     monkeypatch.setenv("PADDLE_TPU_STEPS_PER_CALL", "0")
     with pytest.raises(ValueError, match="STEPS_PER_CALL.*>= 1"):
-        wt.resolve_steps_per_call(main, feed)
+        _resolve_steps_per_call()
     monkeypatch.delenv("PADDLE_TPU_STEPS_PER_CALL")
-    # tuned entry resolves when present
-    tune.set_entry(wt.WINDOW_OP, wt.window_signature(main, feed),
-                   {"choice": "pallas", "cfg": [10], "seconds": 1e-4})
-    try:
-        assert wt.resolve_steps_per_call(main, feed) == (10, "tuned")
-    finally:
-        tune.reset()
     with pytest.raises(ValueError, match="steps_per_call"):
-        wt.resolve_steps_per_call(main, feed, 0)
-
-
-def test_window_candidates_env(monkeypatch):
-    monkeypatch.delenv("PADDLE_TPU_WINDOW_CANDIDATES", raising=False)
-    assert wt.window_candidates() == [1, 4, 10, 25, 50]
-    monkeypatch.setenv("PADDLE_TPU_WINDOW_CANDIDATES", "8,2")
-    assert wt.window_candidates() == [1, 2, 8]  # 1 always present
-    monkeypatch.setenv("PADDLE_TPU_WINDOW_CANDIDATES", "a,b")
-    with pytest.raises(ValueError, match="WINDOW_CANDIDATES"):
-        wt.window_candidates()
-
-
-# -------------------------------------------------------------- tuner
-@pytest.fixture
-def tuner_cache(monkeypatch, tmp_path):
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_CACHE_DIR", str(tmp_path))
-    monkeypatch.delenv("PADDLE_TPU_STEPS_PER_CALL", raising=False)
-    tune.reset()
-    yield tmp_path
-    tune.reset()
-
-
-def test_window_tuner_deterministic_selects_persists_and_rekeys(
-        tuner_cache, monkeypatch):
-    """Deterministic mode: selection is a pure function of the seed,
-    the winner persists to tuned_kernels.json (two-choice grammar:
-    K>1 = pallas cfg=[K], K=1 = composed), a fresh in-memory table
-    serves it from disk, installing it re-keys the executor plan
-    cache, and the next auto-resolved train_loop runs windowed."""
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_TUNE_DETERMINISTIC", "7")
-    main, startup, loss = _build()
-    feed = _batches(1)[0]
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        key0 = exe._cache_key(main, {}, ())
-        dec = wt.tune_train_window(exe, main, feed, fetch_list=[loss],
-                                   scope=scope)
-        assert dec["choice"] in ("pallas", "composed")
-        labels = [t["label"] for t in dec["timings"]]
-        assert "composed" in labels  # the mandatory per-step fallback
-        # a tuned table change re-prepares cached plans (epoch rides
-        # kernels.config_key into the plan-cache key)
-        assert exe._cache_key(main, {}, ()) != key0
-        # persisted, strict-JSON, and served from disk by a fresh table
-        data = json.load(open(tuner_cache / "tuned_kernels.json"))
-        (key,) = data["entries"].keys()
-        assert key.startswith("train_window|")
-        tune.reset()
-        k = wt.tuned_window(main, feed)
-        assert k is not None
-        assert (k > 1) == (dec["choice"] == "pallas")
-        if k > 1:
-            # the windowed loop picks the winner up with NO explicit arg
-            n, _, = exe.train_loop(main, iter(_batches(k)),
-                                   fetch_list=[loss], scope=scope)[:2]
-            assert n == k
-            assert _value("paddle_pipeline_window_size") == k
-            assert _value("paddle_kernel_dispatches_total",
-                          op="train_window",
-                          impl="pallas") >= 1
-
-
-def test_window_tuner_deterministic_is_stable(tuner_cache, monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_TUNE_DETERMINISTIC", "3")
-    main, startup, loss = _build()
-    feed = _batches(1)[0]
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        d1 = wt.tune_train_window(exe, main, feed, [loss], scope)
-        tune.reset()
-        d2 = wt.tune_train_window(exe, main, feed, [loss], scope)
-    assert (d1["choice"], d1["cfg"]) == (d2["choice"], d2["cfg"])
-
-
-def test_window_tuner_real_measurement_restores_state_bitwise(
-        tuner_cache, monkeypatch):
-    """A REAL (wall-clock) tune runs actual training dispatches — and
-    must leave params, optimizer slots and the RNG chain bitwise
-    untouched (training resumes from exactly the pre-tune state).
-
-    The before-state is captured as COPIES, never zero-copy numpy
-    views: a live view pins the device buffer, which silently disables
-    the measured dispatches' donate_argnums donation and would mask
-    the donated-snapshot bug this test exists to catch (a bare-
-    reference snapshot is a DELETED array by restore time — found by
-    review, reproduced, fixed with deep-copy snapshot/restore)."""
-    monkeypatch.delenv("PADDLE_TPU_KERNEL_TUNE_DETERMINISTIC",
-                       raising=False)
-    monkeypatch.setenv("PADDLE_TPU_KERNEL_TUNE_REPEATS", "1")
-    monkeypatch.setenv("PADDLE_TPU_WINDOW_CANDIDATES", "1,4")
-    main, startup, loss = _build()
-    feed = _batches(1)[0]
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        # one real step first: the snapshot covers mid-training state
-        # including a live RNG chain
-        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        names = sorted(scope.local_var_names(), key=lambda n: (len(n), n))
-        before = [(n, np.array(scope.find_var(n), copy=True))
-                  for n in names]
-        dec = wt.tune_train_window(exe, main, feed, [loss], scope)
-        after = [(n, np.array(scope.find_var(n), copy=True))
-                 for n in names]
-        _assert_bitwise(before, after)
-        # the scope is fully usable: the next training step must not
-        # trip over any donated-away buffer the tune left behind
-        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        secs = [t["seconds"] for t in dec["timings"]]
-        assert all(s > 0 for s in secs)
-
-
-def test_window_tuner_bypassed_with_kernels_off(tuner_cache, monkeypatch):
-    """PADDLE_TPU_KERNELS=0: tuned_window returns None (the loop runs
-    per-step) and the auto-resolution moves ZERO paddle_kernel_*
-    counters — the bypass contract the kernel tier pins."""
-    main, startup, loss = _build()
-    feed = _batches(1)[0]
-    tune.set_entry(wt.WINDOW_OP, wt.window_signature(main, feed),
-                   {"choice": "pallas", "cfg": [4], "seconds": 1e-4})
-    monkeypatch.setenv("PADDLE_TPU_KERNELS", "0")
-    assert wt.tuned_window(main, feed) is None
-    names = ["paddle_kernel_tuner_hits_total",
-             "paddle_kernel_tuner_misses_total",
-             "paddle_kernel_dispatches_total"]
-    snap0 = {n: json.dumps(observe.snapshot()["metrics"][n]["samples"],
-                           sort_keys=True) for n in names}
-    scope = Scope()
-    with scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup, scope=scope)
-        n, _ = exe.train_loop(main, iter(_batches(4)),
-                              fetch_list=[loss], scope=scope)[:2]
-    assert n == 4
-    assert _value("paddle_pipeline_window_size") == 1
-    for n_ in names:
-        assert json.dumps(observe.snapshot()["metrics"][n_]["samples"],
-                          sort_keys=True) == snap0[n_], n_
-
-
-def test_peek_moves_no_counters():
-    """tune.peek is the counter-free probe the per-loop resolution
-    rides; lookup still counts (the contract the acceptance tests
-    pin)."""
-    h0 = (_value("paddle_kernel_tuner_hits_total", tier="memory"),
-          _value("paddle_kernel_tuner_misses_total"))
-    assert tune.peek("train_window", ("nope",)) is None
-    tune.set_entry("train_window", ("yep",),
-                   {"choice": "pallas", "cfg": [4], "seconds": 1e-4})
-    try:
-        assert tune.peek("train_window", ("yep",))["cfg"] == [4]
-        assert (_value("paddle_kernel_tuner_hits_total", tier="memory"),
-                _value("paddle_kernel_tuner_misses_total")) == h0
-    finally:
-        tune.reset()
+        _resolve_steps_per_call(0)
 
 
 # -------------------------------------------------- supervisor windows

@@ -12,7 +12,7 @@ line — the CLI face of ``paddle_tpu.export`` (docs/DEPLOYMENT.md).
 same tiny configs lint_program.py verifies — builders are shared, not
 duplicated): startup-initialized
 weights, inference rewrite, live-config optimize with TV forced on,
-params checksummed, winner-table slice, memory polynomial and (unless
+params checksummed, memory polynomial and (unless
 ``--no-aot``) one jax.export executable per ``--buckets`` entry.
 
 ``--inspect`` prints the manifest without rehydrating anything: format
@@ -122,9 +122,9 @@ def _validate(path: str) -> int:
     except export.ArtifactError as e:
         print("INVALID: %s" % e, file=sys.stderr)
         return 1
-    print("OK %s: program=%s params=%d tuned_imported=%d aot=%s"
+    print("OK %s: program=%s params=%d aot=%s"
           % (path, "yes" if art.program is not None else "no",
-             len(art.params), art.tuned_imported,
+             len(art.params),
              ",".join(str(b) for b in sorted(art.aot)) or "-"))
     for section, reason in art.degraded:
         print("  degraded: %s (%s) -> recompute at serve time"

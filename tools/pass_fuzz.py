@@ -14,12 +14,12 @@ one fully deterministic replay (the seed is printed on every failure).
 
     python tools/pass_fuzz.py --seeds 200            # sweep
     python tools/pass_fuzz.py --seeds 1 --start 1234 # replay one seed
-    python tools/pass_fuzz.py --corpus               # the six miscompiles
+    python tools/pass_fuzz.py --corpus               # the five miscompiles
     python tools/pass_fuzz.py --json                 # machine-readable
 
-The **corpus** re-expresses the six confirmed historical miscompiles
+The **corpus** re-expresses the five confirmed historical miscompiles
 (CSE write-versioning, copy-prop aliasing, materialize ordering, fusion
-read-after-write, optimizer-group reorder, fused-replay RAW) as tiny
+read-after-write, a wrong quantization scale) as tiny
 programs, each paired with a **knock-out** that disables exactly the
 guard whose absence caused the original bug (the passes expose the
 guards as documented class-attr seams; the materialize knock-out
@@ -218,7 +218,7 @@ def _param_update_block(fluid, L, rng, vals, idx, seed):
     grad = L.scale(w, scale=0.3)  # reads w: RAW fodder around the sgd
     block = w.block
     _sgd(block, w, grad, lr)
-    if rng.random() < 0.5:  # a second, ADJACENT update: group fodder
+    if rng.random() < 0.5:  # a second, ADJACENT update
         w2 = L.create_parameter([D], "float32", name="fz_v_%d_%d"
                                 % (seed % 1000, idx))
         _sgd(block, w2, grad, lr)
@@ -362,7 +362,7 @@ def fuzz_one(seed, steps=2):
 
 
 # ------------------------------------------------------------- corpus
-# The six confirmed historical miscompiles, as programs + knock-outs.
+# The five confirmed historical miscompiles, as programs + knock-outs.
 def _corpus_cse_write_versioning(fluid, L):
     """PR 7: CSE merged identical reads AROUND an in-place write."""
     s = L.create_parameter([D], "float32", name="cwv_s")
@@ -407,19 +407,6 @@ def _corpus_fusion_read_after_write(fluid, L):
     return [out.name]
 
 
-def _corpus_optimizer_group_reorder(fluid, L):
-    """PR 8: two updates separated by a live read became 'consecutive'
-    under node-list adjacency and the first write moved past the read."""
-    w1 = L.create_parameter([D], "float32", name="ogr_w1")
-    w2 = L.create_parameter([D], "float32", name="ogr_w2")
-    lr = L.fill_constant([1], "float32", 0.5)
-    _sgd(w1.block, w1, L.scale(w1, scale=1.0), lr)
-    mid = L.scale(w1, scale=1.0)  # reads w1 BETWEEN the two updates
-    _sgd(w2.block, w2, L.scale(w2, scale=1.0), lr)
-    out = L.reduce_mean(mid)
-    return [out.name]
-
-
 def _corpus_quantize_wrong_scale(fluid, L):
     """PR 14: the int8 PTQ pass with deliberately wrong (quartered)
     per-channel scales — values past 25% of the channel max clip, so
@@ -432,19 +419,6 @@ def _corpus_quantize_wrong_scale(fluid, L):
     h = L.mul(x, w)
     out = L.reduce_mean(L.tanh(h))
     return [out.name, h.name]
-
-
-def _corpus_fused_replay_raw(fluid, L):
-    """PR 8: the fused replay fetches every input at op entry, so a
-    later constituent reading an earlier one's write saw stale state."""
-    a = L.create_parameter([D], "float32", name="frr_a")
-    b = L.create_parameter([D], "float32", name="frr_b")
-    g = L.fill_constant([D], "float32", 0.25)
-    lr = L.fill_constant([1], "float32", 0.5)
-    _sgd(a.block, a, g, lr)        # writes a
-    _sgd(b.block, b, a, lr)        # ADJACENT, reads the updated a
-    out = L.reduce_mean(L.elementwise_add(a, b))
-    return [out.name]
 
 
 @contextlib.contextmanager
@@ -479,22 +453,6 @@ def _knockout_fusion_raw():
     from paddle_tpu.core.passes.fuse import FuseElementwisePass as P
 
     with _patch_attr(P, "move_guard", False):
-        yield
-
-
-@contextlib.contextmanager
-def _knockout_group_adjacency():
-    from paddle_tpu.core.passes.kernel_fuse import FuseKernelTierPass as P
-
-    with _patch_attr(P, "adjacency_guard", False):
-        yield
-
-
-@contextlib.contextmanager
-def _knockout_replay_raw():
-    from paddle_tpu.core.passes.kernel_fuse import FuseKernelTierPass as P
-
-    with _patch_attr(P, "raw_guard", False):
         yield
 
 
@@ -551,9 +509,6 @@ CORPUS = {
                              _knockout_materialize),
     "fusion_read_after_write": (_corpus_fusion_read_after_write,
                                 _knockout_fusion_raw),
-    "optimizer_group_reorder": (_corpus_optimizer_group_reorder,
-                                _knockout_group_adjacency),
-    "fused_replay_raw": (_corpus_fused_replay_raw, _knockout_replay_raw),
     "quantize_wrong_scale": (_corpus_quantize_wrong_scale,
                              _knockout_quant_scale),
 }
@@ -667,7 +622,7 @@ def main(argv=None):
     p.add_argument("--steps", type=int, default=2,
                    help="executor steps per program (default 2)")
     p.add_argument("--corpus", action="store_true",
-                   help="run the six-miscompile knock-out corpus "
+                   help="run the five-miscompile knock-out corpus "
                         "instead of the random sweep")
     p.add_argument("--json", action="store_true")
     args = p.parse_args(argv)

@@ -71,7 +71,6 @@ MODULES = [
     "paddle_tpu.async_executor",
     "paddle_tpu.parallel",
     "paddle_tpu.core.passes",
-    "paddle_tpu.core.window_tune",
 ]
 
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """AST-based repo lint: cheap structural invariants CI can hold.
 
-Five rule families (all wired into the fast tier via
+Rule families (all wired into the fast tier via
 tests/test_repo_lint.py):
 
 1. **bare-except** — ``except:`` swallows KeyboardInterrupt/SystemExit;
@@ -28,13 +28,6 @@ tests/test_repo_lint.py):
    no stated contract is undiagnosable. (The ``paddle_optimizer_*``
    families a pass records are covered by rule 2 like every other
    family reference.)
-5. **kernel-registry** — every ``@register_kernel(...)`` entry must
-   declare a ``fallback=`` composed lowering AND the decorated Pallas
-   implementation must carry a docstring (the kernel registry is the
-   tier's catalog, docs/KERNELS.md — same contract as pass rule 4). A
-   kernel with no fallback has no parity baseline and no composed
-   dispatch target; registry.py enforces both at runtime too, but the
-   lint catches it before anything imports.
 6. **undeclared-fault-site** — the trace-site contract (rule 3) for the
    fault-injection plane: every literal site passed to ``fault_point``
    (the compiled-in hot-path stamps) or armed via ``FaultPlan.arm``
@@ -89,7 +82,7 @@ tests/test_repo_lint.py):
     metadata/layout ops that move no payload bytes and execute no
     FLOPs) — and the two sets must be disjoint. Without this, growing
     an op a shape rule silently prices it bytes-only: its FLOPs vanish
-    from predicted MFU and the autotuner's ranking, exactly the silent
+    from predicted MFU, exactly the silent
     widening rule 7 exists to prevent in the range engine. Same
     registration-idiom resolution as rule 7.
 
@@ -445,45 +438,6 @@ def pass_docstring_violations(root: str, files=None) -> List[str]:
                         "%s:%d: pass class %r is registered via "
                         "register_pass but has no docstring (the pass "
                         "registry is the optimizer's catalog)"
-                        % (rel, node.lineno, node.name))
-    return violations
-
-
-def kernel_registry_violations(root: str, files=None) -> List[str]:
-    """Every ``@register_kernel("...")``-decorated function needs a
-    ``fallback=`` keyword AND a docstring (rule 5 above)."""
-    violations = []
-    for path in (files or iter_py_files(root)):
-        rel = os.path.relpath(path, root)
-        for node in ast.walk(_parse(path)):
-            if not isinstance(node, (ast.FunctionDef,
-                                     ast.AsyncFunctionDef)):
-                continue
-            for deco in node.decorator_list:
-                if not isinstance(deco, ast.Call):
-                    continue
-                fn = deco.func
-                fn_name = fn.id if isinstance(fn, ast.Name) else (
-                    fn.attr if isinstance(fn, ast.Attribute) else None)
-                # endswith: an aliased import (`register_kernel as
-                # _register_kernel`, ops/attention.py) must not slip
-                # the rule
-                if fn_name is None or \
-                        not fn_name.endswith("register_kernel"):
-                    continue
-                kws = {k.arg for k in deco.keywords if k.arg}
-                if "fallback" not in kws:
-                    violations.append(
-                        "%s:%d: kernel %r is registered via "
-                        "register_kernel without a fallback= composed "
-                        "lowering (every tier kernel needs its parity "
-                        "baseline and composed dispatch target)"
-                        % (rel, deco.lineno, node.name))
-                if not ast.get_docstring(node):
-                    violations.append(
-                        "%s:%d: kernel %r is registered via "
-                        "register_kernel but has no docstring (the "
-                        "kernel registry is the tier's catalog)"
                         % (rel, node.lineno, node.name))
     return violations
 
@@ -862,7 +816,6 @@ def run(root: str = REPO_ROOT) -> List[str]:
     return (bare_except_violations(root) + family_ref_violations(root)
             + trace_site_violations(root)
             + pass_docstring_violations(root)
-            + kernel_registry_violations(root)
             + fault_site_violations(root)
             + range_rule_coverage_violations(root)
             + env_knob_violations(root)
