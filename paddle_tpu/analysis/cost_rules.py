@@ -465,12 +465,12 @@ def _cost_ssm_update(ctx):
 
 @register_cost_rule("causal_conv", "causal_conv_step")
 def _cost_causal_conv(ctx):
-    """2 K operations a value and about 5 for the silu."""
+    """2 K operations a value and about 5 for the silu (attr ``act``)."""
     xs, ws = ctx.input_shape("X"), ctx.input_shape("W")
     x = None if xs is None else ctx.elems(xs)
     if x is None or ws is None or len(ws) != 2:
         return ctx.out_elems()
-    return x.scaled(2 * int(ws[1]) + 5)
+    return x.scaled(2 * int(ws[1]) + (5 if ctx.attr("act", True) else 0))
 
 
 @register_cost_rule("fused_attention")

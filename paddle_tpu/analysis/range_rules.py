@@ -866,16 +866,18 @@ def _rr_ssm(ctx):
 @register_range_rule("causal_conv", "causal_conv_step")
 def _rr_causal_conv(ctx):
     """``silu`` of a K-term sum of products: at least silu's minimum
-    (-0.2785), at most the sum's bound; the carried rows are ``X``'s own
-    values (beside what ``Rows`` held)."""
+    (-0.2785), at most the sum's bound — the sum itself with attr
+    ``act`` off; the carried rows are ``X``'s own values (beside what
+    ``Rows`` held)."""
     ws = ctx.input_shape("W")
     K = ws[1] if ws and len(ws) == 2 and ws[1] >= 0 else None
     x = ctx.input_av("X")
     acc = _contraction(ctx, x, ctx.input_av("W"), K)
     if ctx.op.inputs.get("Bias"):
         acc = av_add(acc, ctx.input_av("Bias"))
-    hi = max(acc.hi, 0.0)
-    ctx.set("Out", AbstractValue(-0.2785, hi, finite=acc.finite))
+    if ctx.attr("act", True):
+        acc = AbstractValue(-0.2785, max(acc.hi, 0.0), finite=acc.finite)
+    ctx.set("Out", acc)
     rows = x.join(av_const(0.0).drop_const())
     if ctx.op.inputs.get("Rows"):
         rows = rows.join(ctx.input_av("Rows"))

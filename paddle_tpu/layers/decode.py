@@ -226,11 +226,13 @@ def kv_cache_write(cache, update, pos, name=None):
     return cache
 
 
-def causal_conv(x, width, prefix, rows, step=False, name=None):
+def causal_conv(x, width, prefix, rows, step=False, act=True, bias=True,
+                name=None):
     """Causal depth-wise convolution over the sequence axis of ``x
-    [B, T, C]`` followed by silu (ops ``causal_conv`` /
-    ``causal_conv_step``, kernels/ssm.py): ``out[t] = silu(sum_j w[:, j]
-    x[t - width + 1 + j] + b)``, zeros before the sequence. ``rows`` is
+    [B, T, C]`` (ops ``causal_conv`` / ``causal_conv_step``,
+    kernels/ssm.py): ``out[t] = silu(sum_j w[:, j] x[t - width + 1 + j]
+    + b)``, zeros before the sequence; ``act=False`` leaves the silu
+    out and ``bias=False`` creates no ``b``. ``rows`` is
     a persistable ``[B, width - 1, C]`` var that keeps the last ``width -
     1`` positions of ``x`` itself: a whole prompt (``step=False``)
     overwrites it, one token (``step=True``, ``T`` = 1) reads the past
@@ -242,17 +244,18 @@ def causal_conv(x, width, prefix, rows, step=False, name=None):
     C = int(x.shape[-1])
     w = helper.create_parameter(ParamAttr(name=prefix + ".w_0"),
                                 [C, int(width)], dtype="float32")
-    b = helper.create_parameter(
-        ParamAttr(name=prefix + ".b_0", initializer=Constant(0.0)),
-        [C], dtype="float32", is_bias=True)
+    inputs = {"X": [x], "W": [w]}
+    if bias:
+        inputs["Bias"] = [helper.create_parameter(
+            ParamAttr(name=prefix + ".b_0", initializer=Constant(0.0)),
+            [C], dtype="float32", is_bias=True)]
     out = helper.create_variable_for_type_inference("float32")
-    inputs = {"X": [x], "W": [w], "Bias": [b]}
     if step:
         inputs["Rows"] = [rows]
     helper.append_op(type="causal_conv_step" if step else "causal_conv",
                      inputs=inputs,
                      outputs={"Out": [out], "RowsOut": [rows]},
-                     attrs={"act": True})
+                     attrs={"act": bool(act)})
     out.shape = x.shape
     return out
 

@@ -141,7 +141,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             n_expert_local: Optional[int] = None, expert_first: int = 0,
             n_shared_expert: int = 0,
             touched: Optional[Variable] = None,
-            expert_input: Optional[Variable] = None):
+            expert_input: Optional[Variable] = None,
+            norm_topk_eps: Optional[float] = None):
     """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
@@ -178,7 +179,9 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     ``router_score`` 'softmax' or 'sigmoid'; ``router_bias`` adds a
     parameter ``<prefix>_router_bias`` [n_experts] to the scores for the
     SELECTION only (the gates stay the raw scores); ``route_scale``
-    multiplies the gates after ``norm_topk``. ``n_expert_local`` <
+    multiplies the gates after ``norm_topk``; ``norm_topk_eps`` is what
+    the sigmoid router adds to the sum it divides by (1e-20 where not
+    given). ``n_expert_local`` <
     ``n_experts`` is a SHARE of an expert-parallel deployment: the
     router still scores all ``n_experts``, the stacked weights hold only
     experts ``expert_first .. expert_first + n_expert_local - 1`` and
@@ -275,6 +278,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
         attrs["router_score"] = router_score
     if float(route_scale) != 1.0:
         attrs["route_scale"] = float(route_scale)
+    if norm_topk_eps:
+        attrs["norm_topk_eps"] = float(norm_topk_eps)
     if n_local != int(n_experts):
         attrs["n_local"] = n_local
         attrs["expert_first"] = int(expert_first)

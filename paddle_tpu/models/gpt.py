@@ -255,7 +255,44 @@ def base_config():
              ffn_act="relu2", n_expert=512, expert_top_k=22,
              d_expert=2688, d_expert_in=1024, d_shared_expert=5376,
              router_score="sigmoid", router_bias=True, norm_topk=True,
-             route_scale=5.0, weight_dtype="bfloat16")"""
+             route_scale=5.0, weight_dtype="bfloat16")
+
+    A gated short convolution as a layer's FIRST sub-block
+    (``layer_types`` entry ``"conv"``; serving programs only): the layer
+    stays the ordinary pair — ``h = x + conv(norm(x))``, then ``h +
+    ffn(norm(h))`` with the dense FFN or the experts — and only what
+    stands where attention stood changes. With ``u`` the normed input
+    and ``conv_taps`` (K, >= 2) taps: ``[B | C | X] = u W_in`` (``d_model
+    -> 3 d_model``, no bias, split in that order), ``v = B * X``, ``c_t
+    = sum_j w[:, j] v[t - K + 1 + j]`` (depth-wise, causal, zeros before
+    the sequence, neither bias nor activation), ``y = C * c``, output
+    ``y W_out``. The layer carries no position. What a sequence KEEPS
+    of it is the last ``K - 1`` rows of ``v``: ``gpt_<i>_cache_x [B, K -
+    1, d_model]`` (``cache_kind`` calls it ``state``: the same bytes
+    whatever the length), overwritten whole by a prefill and shifted by
+    a decode step (``layers.causal_conv(act=False, bias=False)``).
+    Parameters ``gpt_<i>_conv_in.w_0 [D, 3 D]``, ``gpt_<i>_conv.w_0 [D,
+    K]`` (the taps: float32 whatever ``weight_dtype``),
+    ``gpt_<i>_conv_out.w_0 [D, D]``. ``rope_layers`` rotates attention
+    layers only. It takes none of ``attn``, ``residual``, ``mixers``,
+    nor a ``window`` without a ``"sliding"`` layer; the training build,
+    the multi-token step, a prefix store and a draft model refuse it by
+    name. ``norm_topk_eps`` is what a sigmoid router adds to the sum of
+    the chosen scores it divides by (1e-20 where not given).
+
+    LFM2-24B-A2B (``model_type`` lfm2_moe), as the worked example —
+    published widths, all 40 layers (30 ``conv``, 10 ``full``)::
+
+        dict(d_model=2048, n_head=32, n_kv_head=8, d_head=64,
+             n_layer=40, vocab=65536, max_length=128000, dropout=0.0,
+             pos_emb="rope", rope_theta=1000000.0, norm="rms",
+             norm_eps=1e-5, qk_norm="head", tie_embeddings=True,
+             layer_types=["conv", "conv"]
+             + ["full", "conv", "conv", "conv"] * 9 + ["full", "conv"],
+             conv_taps=3, ffn_act="swiglu", d_ff=11776, n_dense_layer=2,
+             n_expert=64, expert_top_k=4, d_expert=1536,
+             router_score="sigmoid", router_bias=True, norm_topk=True,
+             norm_topk_eps=1e-6, weight_dtype="bfloat16")"""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -275,10 +312,12 @@ _CFG_KEYS = frozenset([
     "rope_scaling",
     "mixers", "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
     "ssm_conv", "ssm_chunk", "d_expert_in", "d_shared_expert",
+    "conv_taps", "norm_topk_eps",
 ])
 _SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
              "ssm_conv")
 MIXER_KINDS = ("ssm", "attention", "experts")
+LAYER_TYPES = ("sliding", "full", "conv")
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
@@ -364,7 +403,8 @@ def _check_cfg(cfg):
             raise ValueError("cfg needs 'd_ff' (or 'n_expert' experts)")
         for key in ("n_dense_layer", "n_shared_expert", "router_score",
                     "router_bias", "route_scale", "n_expert_local",
-                    "expert_first", "d_expert_in", "d_shared_expert"):
+                    "expert_first", "d_expert_in", "d_shared_expert",
+                    "norm_topk_eps"):
             if cfg.get(key):
                 raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
     if cfg.get("ffn_act") == "relu2" and (
@@ -408,14 +448,15 @@ def _check_cfg(cfg):
     types = cfg.get("layer_types")
     if types is not None:
         if len(types) != cfg["n_layer"] or \
-                any(t not in ("sliding", "full") for t in types):
+                any(t not in LAYER_TYPES for t in types):
             raise ValueError(
-                "cfg['layer_types'] must name 'sliding' or 'full' for each "
-                "of the %d layers; got %r" % (cfg["n_layer"], types))
+                "cfg['layer_types'] must name one of %s for each of the %d "
+                "layers; got %r" % (LAYER_TYPES, cfg["n_layer"], types))
         if "sliding" in types and not int(cfg.get("window") or 0) >= 1:
             raise ValueError("a 'sliding' layer needs cfg['window'] >= 1")
     elif cfg.get("window"):
         raise ValueError("cfg['window'] needs cfg['layer_types']")
+    _check_conv(cfg)
     if cfg.get("rope_layers", "all") != "all" \
             and cfg.get("pos_emb", "learned") != "rope":
         raise ValueError("cfg['rope_layers'] needs pos_emb='rope'")
@@ -477,6 +518,31 @@ def _check_mixers(cfg):
                 % (cfg["ssm_groups"], cfg["ssm_heads"], cfg["ssm_conv"]))
 
 
+def _check_conv(cfg):
+    """A ``'conv'`` entry of cfg['layer_types'] and the keys that go
+    with it (``base_config``)."""
+    if "conv" not in (cfg.get("layer_types") or ()):
+        if cfg.get("conv_taps"):
+            raise ValueError("cfg['conv_taps'] needs a 'conv' layer in "
+                             "cfg['layer_types']")
+        return
+    if not int(cfg.get("conv_taps") or 0) >= 2:
+        raise ValueError("a 'conv' layer needs cfg['conv_taps'] >= 2 (the "
+                         "taps of its causal depth-wise convolution)")
+    for key, why in (
+            ("attn", "latent attention has no layer kinds"),
+            ("residual", "the gated convolution is not written over "
+             "several residual streams"),
+            ("mixers", "one mixer a layer has no first sub-block")):
+        if cfg.get(key):
+            raise ValueError("a 'conv' layer takes no cfg[%r]: %s"
+                             % (key, why))
+    if cfg.get("window") and "sliding" not in cfg["layer_types"]:
+        raise ValueError(
+            "cfg['window'] needs a 'sliding' layer: a 'conv' layer keeps "
+            "its last cfg['conv_taps'] - 1 rows and has no window")
+
+
 def _lm_head(cfg, x):
     """Final projection to vocab logits. ``tie_embeddings=True`` reuses
     the input embedding (logits = x @ word_emb^T — no gpt_out_proj
@@ -486,6 +552,10 @@ def _lm_head(cfg, x):
         from ..core.program import default_main_program
 
         emb = default_main_program().global_block().var("gpt_word_emb")
+        if emb.dtype != "float32":
+            # a table stored in cfg['weight_dtype'] widens where it
+            # multiplies, as ``fc`` widens its matrix
+            emb = layers.cast(emb, "float32")
         return layers.matmul(x, emb, transpose_y=True)
     return layers.fc(x, cfg["vocab"], num_flatten_dims=2,
                      bias_attr=False,
@@ -544,12 +614,35 @@ def mixer_kind(cfg, i):
     return kinds[i] if kinds else None
 
 
+def is_conv(cfg, i):
+    """Whether layer ``i``'s first sub-block is the gated short
+    convolution (a ``'conv'`` entry of cfg['layer_types'])."""
+    types = cfg.get("layer_types")
+    return bool(types) and types[i] == "conv"
+
+
+def state_layers(cfg):
+    """The layers that keep a constant-size state and not rows a
+    position, whichever key brought them: an ``'ssm'`` entry of
+    cfg['mixers'] or a ``'conv'`` entry of cfg['layer_types']."""
+    return [i for i in range(cfg["n_layer"])
+            if mixer_kind(cfg, i) == "ssm" or is_conv(cfg, i)]
+
+
 def has_state(cfg):
-    """Whether some layer keeps a recurrent state and not rows a
-    position (an ``'ssm'`` mixer): its caches, ``gpt_<i>_cache_s`` and
-    ``gpt_<i>_cache_x``, have no position axis, so nothing can be cut
-    out of them at a prefix's length nor rolled back by a position."""
-    return "ssm" in (cfg.get("mixers") or ())
+    """Whether some layer keeps a state and not rows a position (an
+    ``'ssm'`` mixer, a ``'conv'`` layer): its caches, ``gpt_<i>_cache_s``
+    and ``gpt_<i>_cache_x``, have no position axis, so nothing can be
+    cut out of them at a prefix's length nor rolled back by a
+    position."""
+    return bool(state_layers(cfg))
+
+
+def _keeps_rows(cfg, i):
+    """Whether layer ``i`` keeps keys and values a position (a slab or
+    a ring): an attention layer that is not latent."""
+    return mixer_kind(cfg, i) in (None, "attention") \
+        and not is_conv(cfg, i)
 
 
 def ssm_widths(cfg):
@@ -562,7 +655,8 @@ def ssm_widths(cfg):
 def cache_kind(cfg, name, max_len):
     """What kind of cache tensor ``name`` (one of a builder's
     ``cache_names``) is, from its name and its layer: ``'state'`` (a
-    state-space layer's state or convolution rows: no position axis),
+    state-space layer's state or convolution rows, a gated convolution's
+    carried rows: no position axis),
     ``'latent'`` (a latent layer's one tensor), ``'ring'`` (a sliding
     layer's, shorter than ``max_len``) or ``'full'`` (a slab)."""
     if name.endswith(("_cache_s", "_cache_x")):
@@ -573,12 +667,21 @@ def cache_kind(cfg, name, max_len):
     return "ring" if cache_rows(cfg, layer, max_len) < max_len else "full"
 
 
+def state_refusal(cfg):
+    """What to say of a cfg whose layers keep a state, by the key that
+    brought them: ``"<the layers>, whose caches are <what> (<names>)"``."""
+    if "ssm" in (cfg.get("mixers") or ()):
+        return ("cfg['mixers'] holds 'ssm' layers, whose caches are a "
+                "recurrent state with no position axis (gpt_<i>_cache_s, "
+                "gpt_<i>_cache_x)")
+    return ("cfg['layer_types'] holds 'conv' layers, whose caches are the "
+            "last %d rows of a gated convolution's input with no position "
+            "axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
+
+
 def _refuse_state(cfg, who, why):
     if has_state(cfg):
-        raise ValueError(
-            "%s: cfg['mixers'] holds 'ssm' layers, whose caches are a "
-            "recurrent state with no position axis (gpt_<i>_cache_s, "
-            "gpt_<i>_cache_x), %s" % (who, why))
+        raise ValueError("%s: %s, %s" % (who, state_refusal(cfg), why))
 
 
 def _hc_clamp(cfg):
@@ -640,6 +743,8 @@ def _rotates(cfg, i):
     layer's kind among cfg['rope_layers'])."""
     if cfg.get("pos_emb", "learned") != "rope":
         return False
+    if is_conv(cfg, i):
+        return False        # no attention, so nothing to rotate
     return cfg.get("rope_layers", "all") == "all" \
         or layer_window(cfg, i) is not None
 
@@ -803,12 +908,43 @@ def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
     (``mix``: the attention sub-block's mappings from ``_sub_input``),
     then the FFN or the experts (``tally``: ``_mlp``'s counts) and
     theirs."""
-    x = _residual(cfg, x, _attn_out(cfg, h, ctxv, nm), nm + "_post1", mix)
+    return _layer_tail(cfg, x, _attn_out(cfg, h, ctxv, nm), nm, i, mix,
+                       dev, **tally)
+
+
+def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, **tally):
+    """A layer after its first sub-block's output ``y`` (attention's
+    projection, a gated convolution's): the residual, then the FFN or
+    the experts and theirs."""
+    x = _residual(cfg, x, y, nm + "_post1", mix)
     if cfg.get("mixers"):
         return x            # the attention was the layer's one mixer
     h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
     f = _mlp(cfg, h2, nm, i, **tally)
     return _residual(cfg, x, f, nm + "_post2", mix2)
+
+
+def _gated_conv(cfg, helper, h, nm, batch, step):
+    """The gated short convolution over the normed ``h [B, T, D]``
+    (``base_config`` has the equations): ``(out [B, T, D], the cache's
+    name)``. ``step`` is the decode form (``T`` = 1): the carried rows
+    are read and shifted in place; otherwise the prompt overwrites
+    them."""
+    D, K = cfg["d_model"], int(cfg["conv_taps"])
+    proj = _fc(h, 3 * D, nm + "_conv_in.w_0")
+
+    def cut(k):
+        return layers.slice(proj, axes=[2], starts=[k * D],
+                            ends=[(k + 1) * D])
+
+    rows = helper.create_global_variable(
+        name=nm + "_cache_x", shape=(batch, K - 1, D))
+    with stored_dtype(None):      # the taps stay float32, as a vector
+        c = layers.causal_conv(
+            layers.elementwise_mul(cut(0), cut(2)), K, nm + "_conv", rows,
+            step=step, act=False, bias=False)
+    return _fc(layers.elementwise_mul(cut(1), c), D,
+               nm + "_conv_out.w_0"), rows.name
 
 
 def _sub_input(cfg, x, nm, k, dev=None):
@@ -944,7 +1080,8 @@ def _mlp(cfg, h, nm, layer, counts=None, touched=None):
                     bias=not _new_style(cfg))
     extra = {k: cfg[k] for k in ("router_score", "router_bias",
                                  "route_scale", "n_expert_local",
-                                 "expert_first", "n_shared_expert")
+                                 "expert_first", "n_shared_expert",
+                                 "norm_topk_eps")
              if cfg.get(k)}
     act = "relu2" if cfg.get("ffn_act") == "relu2" else "swiglu"
     if cfg.get("d_expert_in"):
@@ -1113,6 +1250,9 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
             "programs' (prefill, decode steps) — the scan of an 'ssm' "
             "layer has no backward and the training build keeps the "
             "attention-then-FFN pair" % (MIXER_KINDS,))
+    _refuse_state(cfg, "build", "which is the serving programs' (prefill, "
+                  "decode steps): the carried-rows convolution has no "
+                  "backward")
     new_style = _new_style(cfg)
     if new_style:
         # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
@@ -1373,6 +1513,11 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             continue
         rows = cache_rows(cfg, i, max_len)
         h, mix = _sub_input(cfg, x, nm, 1)
+        if is_conv(cfg, i):
+            y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
+            cache_names.append(kept)
+            x = _layer_tail(cfg, x, y, nm, i, mix, counts=routed)
+            continue
         if latent:
             # the expanded form through the flash forward; what stays of
             # the prompt is ONE slab of latent rows
@@ -1436,7 +1581,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
-    if has_streams(cfg) or cfg.get("mixers"):
+    if has_streams(cfg) or has_state(cfg) or cfg.get("mixers"):
         # the one row an admission needs, cut BEFORE the head: a plan
         # that fetches the row or its argmax holds a [1, vocab] head,
         # and only one that fetches ``logits`` (``generate``) the
@@ -1542,7 +1687,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     for rows in dict.fromkeys(
             cache_rows(cfg, i, max_len)
             for i in range(0 if latent else cfg["n_layer"])
-            if mixer_kind(cfg, i) in (None, "attention")):
+            if _keeps_rows(cfg, i)):
         ar = layers.reshape(layers.range(0, rows, 1, "int64"), [1, rows])
         if pos_b is None:
             pos_b = pos if per_slot_pos else layers.reshape(pos, [1, 1])
@@ -1566,6 +1711,13 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             x = lone
             continue
         rows = cache_rows(cfg, i, max_len)
+        if is_conv(cfg, i):
+            h, mix = _sub_input(cfg, x, nm, 1, dev)
+            y, kept = _gated_conv(cfg, helper, h, nm, batch, True)
+            cache_names.append(kept)
+            x = _layer_tail(cfg, x, y, nm, i, mix, dev, counts=routed,
+                            touched=touched)
+            continue
         if latent:
             # the absorbed form: one latent row written, and every head
             # reads keys AND values out of the slot's one slab
@@ -1683,8 +1835,9 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     count is bounded.
 
     A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is
-    one with a recurrent state (an ``'ssm'`` layer in ``mixers``: a
-    state has no position to resume at or rewind to) and a
+    one with a layer that keeps a state (an ``'ssm'`` entry of
+    ``mixers``, a ``'conv'`` entry of ``layer_types``: a state has no
+    position to resume at or rewind to) and a
     cfg with ring caches (a sliding layer whose window is shorter than
     ``max_len``): the one slab write at
     ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a

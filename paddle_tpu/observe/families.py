@@ -981,10 +981,21 @@ SERVING_CACHE_BYTES = REGISTRY.gauge(
     "'latent' (a latent-attention layer's ONE [b_max, 1, max_len, "
     "kv_lora_rank + d_rope] tensor: keys and values are read out of the "
     "same row) and 'state' (a state-space layer's [b_max, G, N, (H / G) P] "
-    "recurrent state and its [b_max, K - 1, C] convolution rows: no "
+    "recurrent state and its [b_max, K - 1, C] convolution rows, a gated "
+    "convolution layer's [b_max, K - 1, d_model] carried rows: no "
     "position axis, the same bytes whatever a sequence's length). Kinds "
     "are told apart by the tensor's name and layer (gpt.cache_kind). Set "
     "where the lane builds its caches; last lane wins",
+    labels=("kind",))
+
+SERVING_POSITIONS = REGISTRY.counter(
+    "paddle_serving_positions_total",
+    "Cache positions a plain decode step stood over, summed over the "
+    "steps: 'live' adds the lengths its riders had reached (the rows "
+    "their attention may see), 'held' b_max x max_len (the rows a full "
+    "slab holds for every slot, which a step that reads slabs whole "
+    "streams whether a slot is live or not). live / held is the share of "
+    "a slab read that was of use; host integers, counted at dispatch",
     labels=("kind",))
 
 SERVING_WEIGHT_BYTES = REGISTRY.gauge(

@@ -130,6 +130,8 @@ def _moe_ffn(ctx, ins, attrs):
     scoring = {"score": attrs.get("router_score", "softmax"),
                "bias": opt("RouterBias"),
                "route_scale": float(attrs.get("route_scale", 1.0))}
+    if attrs.get("norm_topk_eps"):
+        scoring["norm_eps"] = float(attrs["norm_topk_eps"])
     n_local = int(attrs.get("n_local") or E)
     share = None if n_local == E else \
         (int(attrs.get("expert_first", 0)), n_local)

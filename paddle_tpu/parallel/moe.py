@@ -29,7 +29,7 @@ __all__ = ["moe_apply", "route_tokens", "router"]
 
 
 def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None,
-           score="softmax", bias=None, route_scale=1.0):
+           score="softmax", bias=None, route_scale=1.0, norm_eps=1e-20):
     """Router scores in float32, the k best experts a token and their
     gates. ``score`` 'softmax' (over the experts) or 'sigmoid' (each
     expert's own). ``bias`` [E] is added to the scores for the SELECTION
@@ -37,8 +37,8 @@ def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None,
     expert's raw score. ``norm_topk`` renormalises the k gates to sum to
     one: None is the Switch/GShard rule (raw for top_k=1, renormalised
     above), False keeps the raw scores (OLMoE), True always renormalises
-    (sigmoid scores over ``sum + 1e-20``); ``route_scale`` multiplies the
-    gates after that.
+    (sigmoid scores over ``sum + norm_eps``); ``route_scale`` multiplies
+    the gates after that.
 
     Returns (expert_idx [K,T], gate [K,T], aux scalar)."""
     if score not in ("softmax", "sigmoid"):
@@ -62,7 +62,7 @@ def router(x, gate_w, E, top_k=1, z_loss=0.0, norm_topk=None,
         norm_topk = top_k > 1
     if norm_topk:
         denom = jnp.sum(top_p, axis=-1, keepdims=True)
-        top_p = top_p / (denom + 1e-20 if sigmoid else denom)
+        top_p = top_p / (denom + norm_eps if sigmoid else denom)
     if route_scale != 1.0:
         top_p = top_p * route_scale
     gate = top_p.T.astype(x.dtype)                       # [K, T]

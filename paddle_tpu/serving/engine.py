@@ -169,6 +169,7 @@ class _Lane:
         self._mark = mark if mark is not None else _null_mark
         self._warm: set = set()   # (program id, fetch) dispatched once
         self.cfg = cfg
+        self._state_layers = len(gpt.state_layers(cfg))
         self.b_max, self.max_len = b_max, max_len
         self.scope = Scope()
         self._prefill_scope = Scope()
@@ -237,8 +238,9 @@ class _Lane:
         """``paddle_serving_cache_bytes{kind}``: what the caches this
         lane just built hold, by each tensor's name and layer
         (``gpt.cache_kind``): a state-space layer's state and
-        convolution rows (no position axis), a latent layer's one
-        tensor, rings (shorter than ``max_len``) and full slabs."""
+        convolution rows and a gated convolution's carried rows (no
+        position axis), a latent layer's one tensor, rings (shorter than
+        ``max_len``) and full slabs."""
         from ..observe.families import SERVING_CACHE_BYTES
 
         held = {"ring": 0, "full": 0, "latent": 0, "state": 0}
@@ -358,8 +360,9 @@ class _Lane:
         at ``slot_idx`` (ONE jitted donated dispatch for all 2*n_layer
         tensors, rings and slabs alike: each update is the prefill
         scope's whole batch=1 tensor of the same trailing shape; a
-        state-space layer's state and convolution rows go the same way,
-        so a slot's new tenant overwrites ALL of what the last one left).
+        state-space layer's state and convolution rows and a gated
+        convolution's carried rows go the same way, so a slot's new
+        tenant overwrites ALL of what the last one left).
         Registers ``prompt[:prefix_len]`` with the store on first
         sighting. Returns ``(fetch, value)``, what it brought to
         the host for the first token: ``("tokens", id)``, the last
@@ -403,7 +406,11 @@ class _Lane:
             fetch, var = (("tokens", self._gpt.NEXT_TOKEN_VAR) if greedy
                           else ("logits", self._gpt.LAST_LOGITS_VAR))
             attrs = {"prompt_len": P}
-            if self._gpt.has_state(self.cfg):
+            if self._state_layers:
+                # the layers whose part of the slot this prefill
+                # overwrites whole, whatever the prompt's length
+                attrs["state_layers"] = self._state_layers
+            if "ssm" in (self.cfg.get("mixers") or ()):
                 # the chunks each state-space layer scans the prompt in
                 attrs["chunks"] = -(-P // int(
                     self.cfg.get("ssm_chunk") or 128))
@@ -609,9 +616,10 @@ class DecodeEngine:
     for a model whose cache is latent (``cfg['attn']='mla'``), which the
     multi-token step neither reads nor writes; and for a model whose
     tokens are several residual streams (``cfg['residual']='mhc'``),
-    which it does not carry; and for a model with state-space layers
-    (``'ssm'`` in ``cfg['mixers']``), whose state has no position to cut
-    a prefix at or rewind a draft to.
+    which it does not carry; and for a model with a layer that keeps a
+    state (``'ssm'`` in ``cfg['mixers']``, ``'conv'`` in
+    ``cfg['layer_types']``), which has no position to cut a prefix at or
+    rewind a draft to.
     """
 
     def __init__(self, cfg, params: Optional[Dict[str, np.ndarray]] = None,
@@ -663,12 +671,10 @@ class DecodeEngine:
                     "streams" % (lever, int(model["hc_mult"])))
             if gpt.has_state(model):
                 raise ValueError(
-                    "DecodeEngine: %s cannot serve a model with 'ssm' "
-                    "layers in cfg['mixers']: their caches are a "
-                    "recurrent state with no position axis "
-                    "(gpt_<i>_cache_s, gpt_<i>_cache_x) — a stored prefix "
-                    "cannot be cut out of one at its length, nor a "
-                    "rejected draft rolled back in one" % (lever,))
+                    "DecodeEngine: %s cannot serve a model where %s — a "
+                    "stored prefix cannot be cut out of one at its "
+                    "length, nor a rejected draft rolled back in one"
+                    % (lever, gpt.state_refusal(model)))
             if gpt.has_rings(model, self.max_len):
                 raise ValueError(
                     "DecodeEngine: %s cannot serve a model with "
@@ -1192,6 +1198,7 @@ class DecodeEngine:
         from ..observe.families import (SERVING_DECODE_STEPS,
                                         SERVING_FETCHES,
                                         SERVING_OCCUPANCY,
+                                        SERVING_POSITIONS,
                                         SERVING_SPEC_DRAFT_STEPS,
                                         SERVING_STEP_DISPATCHES)
 
@@ -1227,6 +1234,11 @@ class DecodeEngine:
                 SERVING_STEP_DISPATCHES.labels(
                     dispatch="ahead" if ahead else "sync").inc()
                 SERVING_DECODE_STEPS.inc()
+                # a rider at position p may see rows 0 .. p
+                SERVING_POSITIONS.labels(kind="live").inc(
+                    int(pos.sum()) + len(riders))
+                SERVING_POSITIONS.labels(kind="held").inc(
+                    self.b_max * self.max_len)
                 if advance_draft and self._draft is not None:
                     # keep the draft lane's caches mirror-aligned through
                     # plain iterations: a skipped position would leave a

@@ -383,6 +383,95 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     print("nemotron prefill P=%d:" % P, mem)
 
 
+def _lfm2():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def test_lfm2_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``lfm2-24b-a2b`` serving decode step (64 slots: four
+    layers' two carried rows and ONE layer's slab of 17,408 positions,
+    bf16 matrices, all 64 experts, the head tied to the table) for the
+    described chip: the slab's two in-place Pallas writes, both grouped
+    matmuls of the four expert layers, every carried-rows tensor and
+    both tallies donated, no float32 copy of the table, and 9.97 GB of
+    arguments."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import kv_cache_write as kvw
+    from paddle_tpu.kernels import moe_gmm
+
+    gpt, cfg, serving = _lfm2()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    shapes = {n: tuple(main.global_block().vars[n].shape) for n in caches}
+    assert shapes == dict(
+        {"gpt_%d_cache_x" % i: (B, 2, 2048) for i in (0, 2, 3, 4)},
+        gpt_1_cache_k=(B, 8, S, 64), gpt_1_cache_v=(B, 8, S, 64))
+    lowered, mut = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert sorted(mut) == sorted(caches + [gpt.ROUTED_PAIRS_VAR,
+                                           gpt.EXPERTS_TOUCHED_VAR])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 8
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % kvw.KERNEL, text))) == 2
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+    # neither the table (the head) nor an expert stack is widened whole
+    assert not re.search(r"f32\[65536,2048\][^ ]* (copy|convert)\(", text)
+    assert not re.search(r"f32\[64,2048,1536\][^ ]* (copy|convert)\(",
+                         text)
+    # the slab is read where it lies (the TPU stores it S-minor: a
+    # bitcast inside the attention's fusions, no copy of 2.3 GB)
+    assert [line for line in _cache_sized(text, (B, 8, S, 64))
+            if "bitcast_fusion" not in line] == []
+    mem = compiled.memory_analysis()
+    # 5.40 GB of bf16 matrices, 4.56 GB of slab, 4.2 MB of carried rows
+    assert 9.96e9 < mem.argument_size_in_bytes < 9.98e9, mem
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    print("lfm2 decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [2048, 16384])
+def test_lfm2_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix: ONE flash forward at 32 query and 8 key-value heads of 64 (no
+    [P, P] score tensor), four convolution layers that XLA fuses, a head
+    on ONE row (the [P, 65536] logits would be 4.3 GB), and temporaries
+    that fit beside the 9.97 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.observe.families import FLASH_BLOCK_PLANS
+
+    gpt, cfg, serving = _lfm2()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    types = [op.type for op in main.global_block().ops]
+    assert types.count("causal_conv") == 4
+    assert types.count("fused_attention") == 1
+    plan = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="512x512",
+                                    single_pass="0", layout="heads")
+    before = plan.value
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    assert plan.value == before + 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("flash_fwd") >= 1
+    assert "f32[1,32,%d,%d]" % (P, P) not in text
+    assert "f32[1,%d,65536]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 4.5e9, mem
+    print("lfm2 prefill P=%d:" % P, mem)
+
+
 # (M, K, N, itemsize) of the cells' expert products at their longest
 # prefill: pairs of the longest prompt x the stored width of a weight
 LONGEST_PREFILL_GMM = {

@@ -61,6 +61,11 @@ REACHES = {
         "decode": ("kv_cache_write", "moe_ffn", "ssm_update"),
         "prefill": ("fused_attention", "kv_cache_write", "moe_ffn",
                     "ssm_scan")},
+    # a gated convolution layer has no kernel of its own (causal_conv
+    # without silu or bias is composed, the gates are element-wise)
+    "lfm2-24b-a2b": {
+        "decode": ("kv_cache_write", "moe_ffn"),
+        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -83,6 +88,10 @@ RUNS_ON_THE_CHIP = {
     ("nemotron-3-super-120b-a12b", "moe_ffn"),
     ("nemotron-3-super-120b-a12b", "ssm_scan"),
     ("nemotron-3-super-120b-a12b", "ssm_update"),
+    # my chip runs, PR 44: flash_fwd 512x512, kv_cache_write pallas
+    # rows=1, moe_gmm_up / moe_gmm_down in facts' plan counters
+    ("lfm2-24b-a2b", "fused_attention"), ("lfm2-24b-a2b", "kv_cache_write"),
+    ("lfm2-24b-a2b", "moe_ffn"),
 }
 
 
