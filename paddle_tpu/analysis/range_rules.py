@@ -913,7 +913,7 @@ def _rr_moe_ffn(ctx):
     ctx.set("Out", av_mul(_sym(y), av_interval(0.0, k)))
     if ctx.op.outputs.get("AuxLoss"):
         ctx.set("AuxLoss", AbstractValue(0.0, _INF, finite=x.bounded))
-    for slot in ("CountsOut", "TouchedOut"):
+    for slot in ("CountsOut", "TouchedOut", "CompactOut"):
         if ctx.op.outputs.get(slot):
             ctx.set(slot, AbstractValue(0.0, _INF))
 

@@ -962,7 +962,7 @@ def _r_rms_norm(ctx):
 @register_shape_rule("moe_ffn")
 def _r_moe_ffn(ctx):
     """Out is X's shape (at XE's width where the experts read an input
-    of their own), AuxLoss a float32 scalar, the routing tally its
+    of their own), AuxLoss a float32 scalar, each tally its
     input's shape; the stacked parameters must agree with one another:
     W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [X's width, E], biases
     [E, F] and [E, D]. The group sizes are data: nothing in a shape depends on
@@ -972,14 +972,11 @@ def _r_moe_ffn(ctx):
     if xs is not None:
         ctx.set("Out", xs if xe is None else tuple(xs[:-1]) + (xe[-1],))
     ctx.set("AuxLoss", (), dtype="float32")
-    if "CountsOut" in ctx.op.outputs:
-        cs = ctx.input_shape("Counts")
-        if cs is not None:
-            ctx.set("CountsOut", cs, dtype="int32")
-    if "TouchedOut" in ctx.op.outputs:
-        ts = ctx.input_shape("Touched")
-        if ts is not None:
-            ctx.set("TouchedOut", ts, dtype="int32")
+    for slot in ("Counts", "Touched", "Compact"):
+        if slot + "Out" in ctx.op.outputs:
+            ts = ctx.input_shape(slot)
+            if ts is not None:
+                ctx.set(slot + "Out", ts, dtype="int32")
     w1, w2 = ctx.input_shape("W1"), ctx.input_shape("W2")
     gate = ctx.input_shape("Gate")
     E = int(ctx.attr("n_experts", 0) or 0)

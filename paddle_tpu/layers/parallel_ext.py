@@ -142,7 +142,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             n_shared_expert: int = 0,
             touched: Optional[Variable] = None,
             expert_input: Optional[Variable] = None,
-            norm_topk_eps: Optional[float] = None):
+            norm_topk_eps: Optional[float] = None,
+            compact_calls: Optional[Variable] = None):
     """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
@@ -193,7 +194,12 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     ``<prefix>_shared_{gate,up,down}.w_0``) to the output, whole on
     every share. ``touched`` is a persistable [rows, n_expert_local]
     int32 var: row ``counts_row`` counts the calls in which each held
-    expert was given at least one pair.
+    expert was given at least one pair. A share's call over enough
+    tokens cuts its sorted pair rows at twice the share's even part of
+    them wherever the held pairs fit, and takes the full length where
+    they do not (``ops/moe_ops.py::compact_rows``); ``compact_calls`` is
+    a persistable [rows, 2] int32 var whose row ``counts_row`` counts
+    such calls by the branch they took: column 0 cut, column 1 full.
     """
     if not 1 <= int(top_k) <= int(n_experts):
         raise ValueError(
@@ -289,7 +295,10 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     if touched is not None:
         inputs["Touched"] = [touched]
         outputs["TouchedOut"] = [touched]
-    if counts is not None or touched is not None:
+    if compact_calls is not None:
+        inputs["Compact"] = [compact_calls]
+        outputs["CompactOut"] = [compact_calls]
+    if not (counts is None and touched is None and compact_calls is None):
         attrs["counts_row"] = int(counts_row)
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)

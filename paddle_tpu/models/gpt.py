@@ -334,6 +334,12 @@ ROUTED_PAIRS_VAR = "gpt_moe_routed_pairs"
 # grouped matmul fetches no weights for an empty group, so the bytes a
 # step streams follow this tally, not the count of experts)
 EXPERTS_TOUCHED_VAR = "gpt_moe_experts_touched"
+# the tally of the prefill programs whose expert calls are long enough
+# to carry a bound on the held pairs (``ops/moe_ops.py::compact_rows``:
+# a cfg that holds a share of its experts): per layer, the calls that
+# cut their rows at it (column 0) and that took the full length
+# (column 1): [n_layer, 2] int32
+COMPACT_CALLS_VAR = "gpt_moe_compact_calls"
 
 # the largest |row sum - 1| or |column sum - 1| any residual mapping
 # H_res has shown in the serving decode step (cfg['residual'] = 'mhc'):
@@ -1067,7 +1073,22 @@ def _experts_touched_var(cfg, helper):
         shape=(cfg["n_layer"], cfg["n_expert_local"]), dtype="int32")
 
 
-def _mlp(cfg, h, nm, layer, counts=None, touched=None):
+def _compact_calls_var(cfg, helper, tokens):
+    """The tally of a prefill program whose expert calls (``tokens`` a
+    call) carry a bound on the held pairs: a cfg that holds a share of
+    its experts, at a prompt long enough. None otherwise: such a
+    program is the one it was."""
+    from ..ops.moe_ops import compact_rows
+
+    if not cfg.get("n_expert") or not cfg.get("n_expert_local") \
+            or compact_rows(tokens * cfg["expert_top_k"], cfg["n_expert"],
+                            cfg["n_expert_local"]) is None:
+        return None
+    return helper.create_global_variable(
+        name=COMPACT_CALLS_VAR, shape=(cfg["n_layer"], 2), dtype="int32")
+
+
+def _mlp(cfg, h, nm, layer, counts=None, touched=None, compact=None):
     """The block's second half, behind every builder's one call: the
     dense FFN (every layer of a dense model, the first
     cfg['n_dense_layer'] of a sparse one), or — cfg['n_expert'] —
@@ -1094,7 +1115,7 @@ def _mlp(cfg, h, nm, layer, counts=None, touched=None):
         act=act, dropless=True,
         norm_topk=bool(cfg.get("norm_topk", False)),
         param_prefix=nm + "_moe", counts=counts, counts_row=layer,
-        touched=touched, **extra)
+        touched=touched, compact_calls=compact, **extra)
     if cfg.get("d_expert_in"):
         out = _fc(out, cfg["d_model"], nm + "_moe_lat_up.w_0")
     if cfg.get("d_shared_expert"):
@@ -1502,12 +1523,14 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     latent = has_latent(cfg)
     fused = bool(cfg.get("layer_types")) or latent or bool(cfg.get("mixers"))
     bias = None if fused else _causal_bias(P)
-    routed = None      # only the serving decode step tallies its routing
+    # only the serving decode step tallies its routing; a share's long
+    # prefill tallies which length its expert calls ran at
+    tally = {"compact": _compact_calls_var(cfg, helper, batch * P)}
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         lone = _lone_mixer(cfg, helper, x, nm, i, batch, P, False,
-                           cache_names, counts=routed)
+                           cache_names, **tally)
         if lone is not None:
             x = lone
             continue
@@ -1516,7 +1539,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         if is_conv(cfg, i):
             y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
             cache_names.append(kept)
-            x = _layer_tail(cfg, x, y, nm, i, mix, counts=routed)
+            x = _layer_tail(cfg, x, y, nm, i, mix, **tally)
             continue
         if latent:
             # the expanded form through the flash forward; what stays of
@@ -1538,7 +1561,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.reshape(
                 layers.transpose(ctxv, perm=[0, 2, 1, 3]),
                 [-1, P, n_head * cfg["d_v"]])
-            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, counts=routed)
+            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, **tally)
             continue
         ck = helper.create_global_variable(
             name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
@@ -1577,7 +1600,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, counts=routed)
+        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, **tally)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)

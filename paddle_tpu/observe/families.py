@@ -973,6 +973,20 @@ MOE_EXPERTS_TOUCHED = REGISTRY.gauge(
     "step streams follow this count",
     labels=("layer", "expert"))
 
+MOE_COMPACT_CALLS = REGISTRY.gauge(
+    "paddle_moe_compact_calls",
+    "Expert calls of the prefills of a cfg with n_expert_local that were "
+    "long enough to carry a bound on the pairs this chip's experts hold "
+    "(twice the share's even part of the call's token-expert pairs, "
+    "ops/moe_ops.py compact_rows), by layer and by the branch they took: "
+    "'compact' cut the sorted pair rows at the bound before the gather, "
+    "'full' found more held pairs than the bound and ran every row, as a "
+    "call without a bound does. A copy of the device-side [n_layer, 2] "
+    "int32 the prefill programs add to, refreshed by "
+    "DecodeEngine.routed_pairs() (no fetch an admission). 'full' rising "
+    "= the router sends this share more than twice its even part",
+    labels=("layer", "path"))
+
 SERVING_CACHE_BYTES = REGISTRY.gauge(
     "paddle_serving_cache_bytes",
     "Bytes of the decode caches a serving lane built, by kind: 'ring' "

@@ -32,16 +32,134 @@ contribute zero, aux load-balancing loss.
 
 from __future__ import annotations
 
+import functools
 from typing import List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.registry import register_op
 
 __all__: List[str] = []
+
+
+# A share's prefill cuts its sorted pair rows at ``_COMPACT_FACTOR`` times
+# the share's even part of them (``compact_rows``): the rows past the
+# held pairs belong to no group, and every stage after the sort would
+# still run over them. Under ``_COMPACT_MIN_PAIRS`` pair rows the full
+# length costs what the cut does (on the chip, an expert layer alone:
+# 1.98 ms either way at Nemotron's decode step of 2,112 rows, 2.34
+# against 2.20 at 5,632; docs/KERNELS.md) and a ``cond`` would only sit
+# in the step, so such a program holds none.
+_COMPACT_FACTOR = 2
+_COMPACT_MIN_PAIRS = 4096
+
+
+def compact_rows(M, E, n_local):
+    """The static bound on a share's held pairs, a whole number of the
+    grouped matmul's 128-row tiles, or None where the call keeps the
+    full length: all experts held, fewer than ``_COMPACT_MIN_PAIRS``
+    pair rows, or a bound that cuts nothing."""
+    from ..kernels.common import ceil_to
+
+    if n_local >= E or M < _COMPACT_MIN_PAIRS:
+        return None
+    cap = ceil_to(-(-_COMPACT_FACTOR * M * n_local // E), 128)
+    return cap if cap < M else None
+
+
+def _expert_rows(src, tok, sorted_e, sizes, w1, w1v, b1, w2, b2, act):
+    """The experts over pair rows sorted by group: row ``r`` reads token
+    ``tok[r]`` of ``src`` and belongs to group ``sorted_e[r]``; rows past
+    ``sum(sizes)`` belong to none. Returns ``[rows, D]`` before the
+    gates."""
+    from ..kernels.moe_gmm import KERNEL_DOWN, KERNEL_UP, gmm
+
+    xs = src[tok]                                        # [rows, D]
+    if act == "swiglu":
+        h = gmm(xs, (w1, w1v), sizes, name=KERNEL_UP)
+    elif act == "relu2":
+        h = jnp.square(jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)))
+    else:
+        h = jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)
+                        + b1[sorted_e])
+    y = gmm(h, w2, sizes, name=KERNEL_DOWN)
+    if b2 is not None:
+        y = y + b2[sorted_e]
+    return y
+
+
+def _choices_back(y, order, gate, cut=None):
+    """Sorted rows back to pair order (choice-major), then the k gated
+    terms of a token add in choice order: a gather and a fixed sum, no
+    scatter. ``cut``: ``y`` holds only the first ``cut`` sorted rows, and
+    a pair past them (of no held group) reads a row of zeros."""
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    if cut is not None:
+        back = jnp.minimum(back, cut)
+        y = jnp.concatenate([y, jnp.zeros((1, y.shape[1]), y.dtype)])
+    top_k, T = gate.shape
+    y = y[back].reshape(top_k, T, -1) * gate[:, :, None]
+    return jnp.sum(y, axis=0)
+
+
+def _take(a, idx):
+    """``a[idx]`` along the first axis for ``idx`` known to be in range:
+    the one ``lax.gather``, without the wrapper's index arithmetic."""
+    dnums = lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(1, a.ndim)), collapsed_slice_dims=(0,),
+        start_index_map=(0,))
+    return lax.gather(a, idx[:, None], dnums, (1,) + a.shape[1:],
+                      mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def _shifted(a, d, fill):
+    """``a`` moved ``d`` rows down (up for a negative ``d``), ``fill``
+    in the rows it left."""
+    n = a.shape[0]
+    cut = lax.slice_in_dim(a, 0, n - d) if d > 0 \
+        else lax.slice_in_dim(a, -d, n)
+    edge = (d, 0, 0) if d > 0 else (0, -d, 0)
+    return lax.pad(cut, np.array(fill, a.dtype),
+                   [edge] + [(0, 0, 0)] * (a.ndim - 1))
+
+
+def _tokens_back(z, tok, T, most):
+    """Gated rows ``z [rows, D]`` of tokens ``tok [rows]`` (``T`` for a
+    row of no token) summed a token, ``[T, D]``, where a token owns at
+    the most ``most`` of the rows and most tokens none: the rows sorted
+    by token (stably), each token's run summed by a segmented scan of
+    ``ceil(log2 most)`` doubling steps — a fixed tree over the run,
+    whatever the device — and ONE gather of a token's last row (of a row
+    of zeros where it owns none). No ``[K T, D]`` array exists and
+    nothing scatters a row."""
+    rows = z.shape[0]
+    number = lax.iota(tok.dtype, rows)
+    ts, perm = lax.sort((tok, number), num_keys=1, is_stable=True)
+    z = _take(z, perm)
+    d = 1
+    while d < most:
+        # sorted: the row d above is of this token only if all between are
+        same = lax.eq(ts, _shifted(ts, d, -1))
+        z = z + lax.select(lax.broadcast_in_dim(same, z.shape, (0,)),
+                           _shifted(z, d, 0), lax.full_like(z, 0))
+        d *= 2
+    # where each token's run ends; every other row writes out of range,
+    # each to a place of its own, and is dropped
+    last = lax.ne(ts, _shifted(ts, -1, -1))
+    at = lax.scatter(
+        lax.full((T,), rows, ts.dtype),
+        lax.select(last, ts, number + (T + 1))[:, None], number,
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0,)),
+        unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP)
+    return _take(lax.pad(z, np.array(0, z.dtype),
+                         [(0, 1, 0), (0, 0, 0)]), at)
 
 
 def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
@@ -65,9 +183,20 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     ``xe [T, D']`` is what the experts read where that is not ``x`` (the
     router's input).
 
+    A share's held pairs are the FIRST ``sum(sizes)`` rows of the sorted
+    order. Where ``compact_rows`` gives a bound ``cap``, the rows are
+    cut at it before the gather whenever the held pairs fit (a
+    ``lax.cond`` on the device): the gather, both grouped matmuls and
+    the activation run over ``cap`` rows, and the way back sums the cut
+    rows a token (``_tokens_back``) where that moves fewer rows than the
+    choices' gather over the cut rows (``_choices_back``). An input
+    whose router sends the share more than the bound takes the full
+    length, as every call without a bound does: no pair is ever dropped.
+
     Returns (out [T, D] — ``D'`` with ``xe`` —, aux, pairs given to each of the ``E`` experts
-    the router scores [E] int32 — a share's own groups are its slice)."""
-    from ..kernels.moe_gmm import KERNEL_DOWN, KERNEL_UP, gmm
+    the router scores [E] int32 — a share's own groups are its slice —,
+    and the branch a bounded call took: int32 1 cut, 0 full; None for a
+    call without a bound)."""
     from ..parallel.moe import route_tokens, router
 
     T = x.shape[0]
@@ -85,7 +214,9 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     routed = sizes = jnp.sum(
         flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
         dtype=jnp.int32)                                 # [E]
+    cap = None
     if share is not None:
+        cap = compact_rows(flat_e.shape[0], E, share[1])
         first, E = share              # from here on E counts held groups
         local = flat_e - first
         held = jnp.logical_and(local >= 0, local < E)
@@ -94,23 +225,66 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
         sizes = routed[first:first + E]
     order = jnp.argsort(flat_e, stable=True)             # pair -> sorted
     sorted_e = jnp.minimum(flat_e[order], E - 1)
-    xs = (x if xe is None else xe)[order % T]            # [K*T, D]
-    if act == "swiglu":
-        h = gmm(xs, (w1, w1v), sizes, name=KERNEL_UP)
-    elif act == "relu2":
-        h = jnp.square(jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)))
-    else:
-        h = jax.nn.relu(gmm(xs, w1, sizes, name=KERNEL_UP)
-                        + b1[sorted_e])
-    y = gmm(h, w2, sizes, name=KERNEL_DOWN)
-    if b2 is not None:
-        y = y + b2[sorted_e]
-    # back to pair order (choice-major), then the k gated terms of a
-    # token add in choice order: a gather and a fixed sum, no scatter
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    y = y[back].reshape(top_k, T, -1) * gate[:, :, None]
-    return jnp.sum(y, axis=0), aux, routed
+    src = x if xe is None else xe
+    if cap is None:
+        y = _expert_rows(src, order % T, sorted_e, sizes, w1, w1v, b1, w2,
+                         b2, act)
+        return _choices_back(y, order, gate), aux, routed, None
+    out, took = _bounded(src, order, sorted_e, sizes, gate, w1, w1v, b1,
+                         w2, b2, cap=cap, act=act)
+    return out, aux, routed, took
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "act"))
+def _bounded(src, order, sorted_e, sizes, gate, w1, w1v, b1, w2, b2, *,
+             cap, act):
+    """``_experts`` after the sort for a call with a bound ``cap`` on the
+    held pairs: ``(out, 1)`` from the first ``cap`` sorted rows where the
+    held pairs fit, ``(out, 0)`` from all of them where they do not.
+
+    A jit of its own, because the expert layers of one program hand it
+    the same shapes: the two bodies (four Pallas calls) are traced and
+    lowered once a program, not once a layer — a layer's body twice over
+    is what a prefill program's set-up would otherwise pay
+    (``paddle_moe_gmm_plans_total`` then counts a program's plans once,
+    not once a layer)."""
+    T = src.shape[0]
+    top_k, E = gate.shape[0], sizes.shape[0]
+    M = order.shape[0]
+    n_held = jnp.sum(sizes)
+
+    def full():
+        y = _expert_rows(src, order % T, sorted_e, sizes, w1, w1v, b1, w2,
+                         b2, act)
+        return _choices_back(y, order, gate)
+
+    # a token owns at the most this many of the cut rows
+    most = min(top_k, E)
+    # rows a way back moves, in passes over them: a gather, the scan's
+    # steps and a gather over cap rows against a gather and a sum over
+    # all K T
+    by_token = cap * (most - 1).bit_length() + cap < M
+
+    def compact():
+        # the held pairs are the first rows of the sorted order: every
+        # row past them has a gate of zero
+        rows = lax.slice_in_dim(order, 0, cap)
+        tok = lax.rem(rows, lax.full_like(rows, T))
+        y = _expert_rows(src, tok, lax.slice_in_dim(sorted_e, 0, cap),
+                         sizes, w1, w1v, b1, w2, b2, act)
+        if not by_token:
+            return _choices_back(y, order, gate, cut=cap)
+        g = _take(lax.reshape(gate, (M,)), rows)
+        # the cut rows past the held pairs are pairs of no held group,
+        # up to top_k a token: they go behind every token's run
+        owned = lax.lt(lax.iota(n_held.dtype, cap),
+                       lax.broadcast(n_held, (cap,)))
+        return _tokens_back(y * g[:, None],
+                            lax.select(owned, tok, lax.full_like(tok, T)),
+                            T, most)
+
+    fits = n_held <= cap
+    return lax.cond(fits, compact, full), fits.astype(jnp.int32)
 
 
 @register_op("moe_ffn",
@@ -125,7 +299,7 @@ def _moe_ffn(ctx, ins, attrs):
     x = ins["X"][0]
     w1, w2, gate_w = ins["W1"][0], ins["W2"][0], ins["Gate"][0]
     w1v, b1, b2, counts = opt("W1V"), opt("B1"), opt("B2"), opt("Counts")
-    touched, xe = opt("Touched"), opt("XE")
+    touched, xe, compact = opt("Touched"), opt("XE"), opt("Compact")
     E = int(attrs["n_experts"])
     scoring = {"score": attrs.get("router_score", "softmax"),
                "bias": opt("RouterBias"),
@@ -170,7 +344,7 @@ def _moe_ffn(ctx, ins, attrs):
             "moe_ffn: experts with an input of their own (XE) run on one "
             "device")
     if not use_ep:
-        out, aux, routed = _experts(
+        out, aux, routed, took = _experts(
             xf, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
             norm_topk, z_loss, scoring, share,
             None if xe is None else xe.reshape(T, -1))
@@ -188,6 +362,12 @@ def _moe_ffn(ctx, ins, attrs):
             first = share[0] if share is not None else 0
             outs["TouchedOut"] = touched.at[row].add(
                 (routed[first:first + n_local] > 0).astype(touched.dtype))
+        if compact is not None:
+            # the bounded calls of this layer by the branch they took:
+            # column 0 compact, column 1 full; a call without a bound
+            # leaves the tally as it was
+            outs["CompactOut"] = compact if took is None else \
+                compact.at[row, 1 - took].add(1)
         return outs
 
     def shard_body(xl, w1l, b1l, w2l, b2l, gl):

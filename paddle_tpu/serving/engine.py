@@ -438,6 +438,11 @@ class _Lane:
                 self.scope.set_var(n, out)
         return fetch, last
 
+    def prefill_var(self, name):
+        """What the prefill programs keep under ``name`` in their own
+        scope (None before the first of them ran its startup)."""
+        return self._prefill_scope.find_var(name)
+
     # ---------------------------------------------------------- programs
     def _prefill_program(self, P: int):
         """Batch=1 prefill executable for prompt length P, cached. All
@@ -456,9 +461,12 @@ class _Lane:
                     _BUILD_LOCK, fluid.program_guard(prog, start):
                 self._gpt.build_prefill_step(
                     self.cfg, batch=1, prompt_len=P, max_len=self.max_len)
-            self._run_startup(
-                start, self._prefill_scope,
-                set(self._shared_names(prog, {"tokens"})).__contains__)
+            kept = set(self._shared_names(prog, {"tokens"}))
+            tally = self._gpt.COMPACT_CALLS_VAR
+            if self._prefill_scope.find_var(tally) is not None:
+                # what the earlier prefill programs counted stays
+                kept.add(tally)
+            self._run_startup(start, self._prefill_scope, kept.__contains__)
             self._share_weights(prog, skip={"tokens"})
         SERVING_PREFILL_PROGRAMS.inc()
         self._prefill[P] = prog
@@ -847,6 +855,7 @@ class DecodeEngine:
 
         tally = self._refresh_tally(ROUTED_PAIRS_VAR, MOE_ROUTED_PAIRS)
         self.experts_touched()
+        self.compact_calls()
         return tally
 
     def experts_touched(self) -> Optional[np.ndarray]:
@@ -859,6 +868,29 @@ class DecodeEngine:
         from ..observe.families import MOE_EXPERTS_TOUCHED
 
         return self._refresh_tally(EXPERTS_TOUCHED_VAR, MOE_EXPERTS_TOUCHED)
+
+    def compact_calls(self) -> Optional[np.ndarray]:
+        """``[n_layer, 2]``: the prefills' expert calls long enough to
+        carry a bound on the pairs THIS chip's experts hold
+        (``ops/moe_ops.py::compact_rows``), by the branch they took —
+        column 0 cut their sorted rows at the bound, column 1 found more
+        held pairs than it and ran the full length — for a cfg with
+        ``n_expert_local`` that has prefilled a prompt that long (None
+        otherwise). Those prefill programs add to it on the device; this call (and
+        ``routed_pairs()``) is the one transfer and refreshes
+        ``paddle_moe_compact_calls``."""
+        from ..models.gpt import COMPACT_CALLS_VAR
+        from ..observe.families import MOE_COMPACT_CALLS
+
+        var = self._lane.prefill_var(COMPACT_CALLS_VAR)
+        if var is None:
+            return None
+        tally = np.asarray(var)
+        for layer, row in enumerate(tally):
+            for path, n in zip(("compact", "full"), row):
+                MOE_COMPACT_CALLS.labels(layer=str(layer),
+                                         path=path).set(int(n))
+        return tally
 
     def mhc_res_deviation(self) -> Optional[float]:
         """The largest ``|row sum - 1|`` or ``|column sum - 1|`` any

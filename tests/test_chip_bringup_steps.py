@@ -299,10 +299,13 @@ def _gmm_plans():
              s["labels"]["form"]): s["value"] for s in family["samples"]}
 
 
-def _assert_nemotron_gmm_plans(before, after):
-    """Five expert layers: five Pallas plans a product, none composed,
-    and the width of 2688 = 21 x 128 (the up product's columns, the down
-    product's reduction) never cut in tiles of 128."""
+def _assert_nemotron_gmm_plans(before, after, plans=5):
+    """Five expert layers: five Pallas plans a product (PR 45: a prefill
+    long enough to carry a bound holds the layer cut and at full length
+    inside ONE jit that its five layers share, so two plans a product),
+    none composed, and the width of 2688 = 21 x 128 (the up product's
+    columns, the down product's reduction) never cut in tiles of
+    128."""
     from paddle_tpu.kernels import moe_gmm
 
     new = {k: v - before.get(k, 0) for k, v in after.items()
@@ -310,7 +313,7 @@ def _assert_nemotron_gmm_plans(before, after):
     assert {form for _k, _t, form in new} == {"pallas"}, new
     for kernel, axis in ((moe_gmm.KERNEL_UP, 2), (moe_gmm.KERNEL_DOWN, 1)):
         mine = {tile: n for (k, tile, _f), n in new.items() if k == kernel}
-        assert sum(mine.values()) == 5, new
+        assert sum(mine.values()) == plans, new
         assert all(int(tile.split("x")[axis]) > 128 for tile in mine), new
 
 
@@ -361,6 +364,7 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     temporaries that fit beside the 11.9 GB the engine holds."""
     import paddle_tpu as fluid
     from paddle_tpu.kernels import ssm
+    from paddle_tpu.ops.moe_ops import compact_rows
 
     gpt, cfg, serving = _nemotron()
     main, startup = fluid.Program(), fluid.Program()
@@ -371,7 +375,12 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     after = _ssm_plans()
-    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans())
+    # 2,048 tokens x 22 choices are over the threshold, 128 x 22 under
+    bound = compact_rows(cfg["expert_top_k"] * P, cfg["n_expert"],
+                         cfg["n_expert_local"])
+    assert bound == {128: None, 2048: 22528}[P]
+    _assert_nemotron_gmm_plans(gmm_before, _gmm_plans(),
+                               plans=2 if bound else 5)
     assert after[("scan", "pallas")] - before[("scan", "pallas")] == 5
     compiled = lowered.compile()
     text = compiled.as_text()
@@ -489,9 +498,12 @@ def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
     count holds its reduction whole; the expert layer at that shape (a
     share of 8 of 256 experts, bf16 matrices under float32 rows, gate and
     up in one call) lowers for the described chip under that plan — the
-    counter is read round the lowering — and compiles."""
+    counter is read round the lowering — and compiles. PR 45: a share's
+    call of that length holds the layer twice under a ``conditional``,
+    over the 1,664 rows its bound leaves and over all 26,624, and both
+    take the same plans (a row tile is 128 either way)."""
     from paddle_tpu.kernels import moe_gmm
-    from paddle_tpu.ops.moe_ops import _experts
+    from paddle_tpu.ops.moe_ops import _experts, compact_rows
 
     def reckoned(shape):
         return moe_gmm._vmem_bytes(*moe_gmm.gmm_plan(*shape), shape[3])
@@ -507,9 +519,9 @@ def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
     E, held, k = 256, 8, 8
 
     def layer(x, router, gate, up, down):
-        out, _aux, sizes = _experts(x, gate, up, None, down, None, router,
-                                    E, k, None, "swiglu", True, 0.0,
-                                    share=(0, held))
+        out, _aux, sizes, _took = _experts(
+            x, gate, up, None, down, None, router, E, k, None, "swiglu",
+            True, 0.0, share=(0, held))
         return out, sizes
 
     sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -521,12 +533,16 @@ def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
     after = _gmm_plans()
     assert {key: v - before.get(key, 0) for key, v in after.items()
             if v != before.get(key, 0)} == {
-        (moe_gmm.KERNEL_UP, "%dx%dx%d" % (tm, tk, tn), "pallas"): 1,
+        (moe_gmm.KERNEL_UP, "%dx%dx%d" % (tm, tk, tn), "pallas"): 2,
         (moe_gmm.KERNEL_DOWN,
          "%dx%dx%d" % moe_gmm.gmm_plan(*LONGEST_PREFILL_GMM["pangu_down"]),
-         "pallas"): 1}
+         "pallas"): 2}
+    cap = compact_rows(M, E, held)
+    assert cap == 1664
+    assert moe_gmm.gmm_plan(cap, D, F, item) == (tm, tk, tn)
     text = lowered.compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert " conditional(" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
 
 

@@ -3,6 +3,7 @@
 off a profiler trace (docs/KERNELS.md "Tile plan of the grouped matmul").
 
     python tools/gmm_sweep.py [--reps 10] [--out chiprun_out/gmm_sweep.json]
+    python tools/gmm_sweep.py --layer     # a share's whole expert layer
     JAX_PLATFORMS=cpu python tools/gmm_sweep.py --rehearse   # tiny, no times
 
 A CASE is one product of one configuration's expert layer at one row
@@ -16,6 +17,15 @@ under a kernel name of its own; the table gives the median device time
 of a call, the grid steps, the weight bytes the touched groups own and
 what share of the HBM peak those bytes alone are in that time. A host
 clock round one call would carry ~0.4 ms of dispatch (PERF.md, PR 25).
+
+``--layer`` (PR 45) times the WHOLE expert layer of the three
+configurations that hold a share of their experts
+(``ops/moe_ops.py::_experts``: router, sort, gather, both products, the
+way back) at a decode step's tokens and at prompt lengths of the cell's
+traffic, as the program holds it — the sorted pair rows cut at
+``compact_rows`` wherever the call is long enough — against the full
+length (the threshold lifted out of reach: what every call ran before),
+the device's busy time of a call off a trace of its own.
 """
 
 from __future__ import annotations
@@ -93,6 +103,96 @@ def group_sizes(rng, tokens, top_k, routed, held, sigma=1.0):
     return np.bincount(chosen.reshape(-1), minlength=routed)[:held]
 
 
+# name: (router's width, experts' width or None for the same, F, routed,
+#        held, top_k, act, weight dtype, tokens a call: the decode step's
+#        b_max first)
+LAYERS = {
+    "pangu": (7680, None, 2048, 256, 8, 8, "swiglu", "bfloat16",
+              (64, 256, 512, 1024, 3328)),
+    "trinity": (3072, None, 3072, 256, 8, 4, "swiglu", "float32",
+                (16, 512, 1024, 2048, 8192)),
+    "nemotron": (4096, 1024, 2688, 512, 128, 22, "relu2", "bfloat16",
+                 (96, 256, 512, 2048)),
+}
+
+
+def layer_rows(args):
+    """One row a (configuration, tokens, form): the layer's device time
+    a call with the rows cut and at full length, the branch the cut form
+    took, and how far the two outputs lie apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.lib import xplane
+    from paddle_tpu.ops import moe_ops
+
+    threshold = moe_ops._COMPACT_MIN_PAIRS
+    out = []
+    for name, (D, De, F, E, held, k, act, dtype, tokens) in LAYERS.items():
+        if args.only not in name:
+            continue
+        if args.rehearse:
+            D, De, F, E, held, tokens = 64, De and 32, 128, 32, 4, (16, 1280)
+        Dx = De or D
+        keys = jax.random.split(jax.random.PRNGKey(args.seed), 6)
+
+        def draw(key, shape, fan, dt=jnp.float32):
+            return (jax.random.normal(key, shape) / fan ** 0.5).astype(dt)
+
+        w1 = draw(keys[0], (held, Dx, F), Dx, dtype)
+        w1v = draw(keys[1], (held, Dx, F), Dx, dtype) \
+            if act == "swiglu" else None
+        w2 = draw(keys[2], (held, F, Dx), F, dtype)
+        router = draw(keys[3], (D, E), D)
+
+        def layer(x, xe, w1, w1v, w2, router):
+            got, _aux, routed, took = moe_ops._experts(
+                x, w1, w1v, None, w2, None, router, E, k, None, act, True,
+                0.0, {"score": "sigmoid"}, (0, held), xe)
+            return got, routed, jnp.int32(-1) if took is None else took
+
+        for T in tokens:
+            x = draw(keys[4], (T, D), 1.0)
+            xe = draw(keys[5], (T, De), 1.0) if De else None
+            operands = (x, xe, w1, w1v, w2, router)
+            want = None
+            for form in ("full", "cut"):
+                moe_ops._COMPACT_MIN_PAIRS = \
+                    1 << 62 if form == "full" else threshold
+                cap = moe_ops.compact_rows(k * T, E, held)
+                if form == "cut" and cap is None:
+                    continue
+                # a function of its own a form: jit's cache goes by it,
+                # and the threshold is read while tracing
+                fn = jax.jit(lambda *a: layer(*a))
+                got, routed, took = jax.block_until_ready(fn(*operands))
+                rows = cap or k * T
+                row = {"layer": name, "tokens": T, "pair_rows": k * T,
+                       "form": form, "rows": rows,
+                       "work_tiles": -(-rows // 128) + held - 1,
+                       "held_pairs": int(routed[:held].sum()),
+                       "took": int(took)}
+                if want is None:
+                    want = got
+                else:
+                    row["max_abs_diff_vs_full"] = float(
+                        jnp.max(jnp.abs(got - want)))
+                if not args.rehearse:
+                    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+                    with jax.profiler.trace(TRACE_DIR):
+                        for _ in range(args.reps):
+                            last = fn(*operands)
+                        jax.block_until_ready(last)
+                    ops = xplane.device_ops(
+                        xplane.load(xplane.find_xplane(TRACE_DIR)))
+                    row["layer_ms"] = 1e3 * xplane.busy_seconds(
+                        xplane.leaves(ops[min(ops)])) / args.reps
+                out.append(row)
+    moe_ops._COMPACT_MIN_PAIRS = threshold
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=10)
@@ -106,6 +206,9 @@ def main(argv=None):
                                                   "gmm_sweep.json"))
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny shapes in interpret mode, no trace")
+    ap.add_argument("--layer", action="store_true",
+                    help="a share's whole expert layer, rows cut against "
+                         "full length, in place of the kernels alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -120,9 +223,10 @@ def main(argv=None):
         raise SystemExit("gmm_sweep: times come from a TPU; this is %s"
                          % dev.platform)
     rng = np.random.default_rng(args.seed)
-    rows = []
+    rows = layer_rows(args) if args.layer else []
+    todo = {} if args.layer else cases()
     for cname, (tokens, top_k, routed, E, K, N, n_rhs, dtype, plans) in \
-            cases().items():
+            todo.items():
         if args.only not in cname:
             continue
         if args.rehearse:
