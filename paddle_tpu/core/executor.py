@@ -17,6 +17,7 @@ per-op dispatch, implicit data transform, and the eager-deletion GC.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
 import warnings
@@ -146,10 +147,10 @@ class Executor:
 
         observe_feed_gap()
         t0 = time.perf_counter()
-        with _dispatch_guard(plan, "run", (feeds, const_state, mut_state,
-                                           rng)) as loads:
-            fetches, new_mut, new_pure, new_rng = plan.fn(
-                feeds, const_state, mut_state, rng)
+        with _dispatch_guard(plan, "run",
+                             (feeds, const_state, mut_state, rng),
+                             scope, self.place) as (loads, args):
+            fetches, new_mut, new_pure, new_rng = plan.fn(*args)
         steady = _record_dispatch(plan, "run", "run", 1,
                                   time.perf_counter() - t0, loads)
 
@@ -259,10 +260,10 @@ class Executor:
         observe_feed_gap()
         sig = ("run_repeated",) + key
         t0 = time.perf_counter()
-        with _dispatch_guard(plan, sig, (feeds, const_state, mut_state,
-                                         rng)) as loads:
-            fetches, new_mut, new_pure, new_rng = fn(
-                feeds, const_state, mut_state, rng)
+        with _dispatch_guard(plan, sig,
+                             (feeds, const_state, mut_state, rng),
+                             scope, self.place) as (loads, args):
+            fetches, new_mut, new_pure, new_rng = fn(*args)
         steady = _record_dispatch(plan, sig, "run_repeated",
                                   steps, time.perf_counter() - t0, loads)
         return self._finish(plan, scope, fetches, new_mut, new_pure,
@@ -468,9 +469,9 @@ class Executor:
                 t0 = time.perf_counter()
                 with _dispatch_guard(plan, "run",
                                      (feed_list, const_state, mut_state,
-                                      rng)) as loads:
-                    fetches, new_mut, new_pure, new_rng = plan.fn(
-                        feed_list, const_state, mut_state, rng)
+                                      rng), scope,
+                                     self.place) as (loads, args):
+                    fetches, new_mut, new_pure, new_rng = plan.fn(*args)
                 # sig "run": same executable as run(), so a run()
                 # warmup already paid this signature's compile
                 steady = _record_dispatch(plan, "run",
@@ -516,9 +517,9 @@ class Executor:
                 t0 = time.perf_counter()
                 with _dispatch_guard(plan, sig,
                                      (feed_list, const_state, mut_state,
-                                      rng)) as loads:
-                    fetches, new_mut, new_pure, new_rng = fn(
-                        feed_list, const_state, mut_state, rng)
+                                      rng), scope,
+                                     self.place) as (loads, args):
+                    fetches, new_mut, new_pure, new_rng = fn(*args)
                 dt = time.perf_counter() - t0
                 steady = _record_dispatch(plan, sig, "run_pipelined",
                                           k, dt, loads)
@@ -814,10 +815,17 @@ class Executor:
             self._cache.move_to_end(key)
         const_state = [_require(scope, n) for n in plan.const_state]
         mut_state = [_require(scope, n) for n in plan.mut_state]
-        rng = scope.find_var(RNG_VAR)
-        if rng is None:
-            seed = program.random_seed if program.random_seed is not None else 0
-            rng = jax.random.PRNGKey(seed)
+        if not plan.needs_rng:
+            # the step takes a key and never looks at it: one constant,
+            # so what happens to the scope's key (loose from a startup
+            # program, committed by a train step) is no other signature
+            rng = _unused_key()
+        else:
+            rng = scope.find_var(RNG_VAR)
+            if rng is None:
+                seed = program.random_seed \
+                    if program.random_seed is not None else 0
+                rng = jax.random.PRNGKey(seed)
         feeds = [feed_vals[n] for n in plan.feed_names]
         return plan, feeds, const_state, mut_state, rng
 
@@ -932,6 +940,12 @@ class Executor:
         return True
 
 
+@functools.cache
+def _unused_key():
+    """The key argument of every plan whose program draws nothing."""
+    return jax.random.PRNGKey(0)
+
+
 def _first_computation(cost) -> dict:
     """``cost_analysis()`` returns a dict, a list with one dict per
     computation, or None (no estimate on this backend)."""
@@ -1010,7 +1024,7 @@ def _wait_guard(step=None):
 
 
 @contextlib.contextmanager
-def _dispatch_guard(plan, sig, args=()):
+def _dispatch_guard(plan, sig, args=(), scope=None, place=None):
     """Resilience wrapper around ONE XLA dispatch, shared by run()/
     run_repeated()/run_pipelined(): stamps the process heartbeat (with
     ``compiling=True`` for a plan's first dispatch per signature, so
@@ -1025,13 +1039,18 @@ def _dispatch_guard(plan, sig, args=()):
     (tagged with the plan signature) when the dump lands. Tracing
     disabled is one bool check — no span, no allocations.
 
-    Yields the dispatch's ``LoadScope`` (None with tracing off): what
-    JAX traced, lowered, compiled or loaded inside it, as the listener
-    of observe/trace.py saw it. ``args`` are the call's arguments; they
-    are looked at only when a backend stage ran (``_note_load``)."""
+    Yields ``(loads, args)``. ``loads`` is the dispatch's ``LoadScope``
+    (None with tracing off): what JAX traced, lowered, compiled or
+    loaded inside it, as the listener of observe/trace.py saw it.
+    ``args`` are the call's arguments, ``(feeds, const_state, mut_state,
+    rng)``: as given, but for the FIRST dispatch of a signature by an
+    executor with a ``place``, whose loose state arrays come back
+    committed to it (``_commit_loose``; the span then says how many:
+    ``committed``). They are looked at only then and when a backend
+    stage ran (``_note_load``)."""
     hb = heartbeat()
-    tok = hb.begin("executor.dispatch",
-                   compiling=sig not in plan.compiled_sigs)
+    first = sig not in plan.compiled_sigs
+    tok = hb.begin("executor.dispatch", compiling=first)
     sp = _tr.trace_span("executor.dispatch", plan=plan.sig) \
         if _tr.trace_enabled() else None
     loads = None
@@ -1039,8 +1058,12 @@ def _dispatch_guard(plan, sig, args=()):
         sp.__enter__()
         loads = _tr.open_loads(plan.sig, plan.loads.get(sig, 0) + 1)
     try:
+        if first and place is not None:
+            args, n = _commit_loose(plan, scope, args, place.jax_device())
+            if n and sp is not None:
+                sp.attrs["committed"] = n
         fault_point("executor.dispatch")
-        yield loads
+        yield loads, args
     finally:
         if loads is not None:
             _tr.close_loads(loads)
@@ -1052,14 +1075,72 @@ def _dispatch_guard(plan, sig, args=()):
         hb.end("executor.dispatch", tok)
 
 
+def _is_loose(a) -> bool:
+    """Not committed to a device: a host array, or a ``jax.Array`` that
+    sits where JAX put it by default (what a jit without committed
+    arguments returns) and follows whatever it is computed with."""
+    return not getattr(a, "committed", False)
+
+
+def _commit_loose(plan, scope, args, device):
+    """The first dispatch of a plan signature hands ``jax.jit`` the
+    argument signature every later one has. A step given any committed
+    argument (a feed: ``feeds_to_device`` commits) returns COMMITTED
+    state, so state that arrives loose — what a program with no feeds
+    wrote (a startup program, the scratch startup of a prefill length),
+    weights a caller's own jit drew, host arrays a checkpoint restored —
+    would make the second dispatch another signature, and JAX would
+    lower and load (cold: compile) the same program again. So commit the
+    loose state arrays to the executor's ``device`` with ONE
+    ``jax.device_put`` (no copy for an array already there: the same
+    buffer under a committed array) and put them back in the scope, so
+    the scope holds what is donated and every other plan over the same
+    state finds it committed.
+
+    Decided from the arrays alone, and only where the committed
+    arguments already say ``device``: with one committed anywhere else
+    (somebody's decision: it stays, and the loose ones go on following
+    it) or none at all (a startup program returns loose arrays again:
+    nothing flips, and what it writes reaches a ``ParallelEngine`` as it
+    always did) the arguments are left as they are, as they are for an
+    executor without a place (``device`` None). The key is state only
+    for a plan that draws (``_gather_args`` hands the others one
+    constant key). Returns ``(args, n)``, ``n`` the arrays committed.
+    Never runs in a steady dispatch."""
+    feeds, const_state, mut_state, rng = args
+    names = plan.const_state + plan.mut_state
+    state = const_state + mut_state
+    if plan.needs_rng:
+        names, state = names + [RNG_VAR], state + [rng]
+    loose = [i for i, a in enumerate(state) if _is_loose(a)]
+    held = {d for a in feeds + state if not _is_loose(a)
+            for d in a.devices()}
+    if not loose or held != {device}:
+        return args, 0
+    for i, a in zip(loose, jax.device_put([state[i] for i in loose],
+                                          device)):
+        state[i] = a
+        # a key made for this call (the scope holds none yet) is the
+        # call's alone: the step's own key is written back after it
+        if names[i] != RNG_VAR or scope.find_var(RNG_VAR) is not None:
+            scope.set_var(names[i], a)
+    n_const, n_mut = len(const_state), len(mut_state)
+    return (feeds, state[:n_const], state[n_const:n_const + n_mut],
+            state[-1] if plan.needs_rng else rng), len(loose)
+
+
 def _note_load(plan, sig, args, nth, attrs):
     """A backend stage ran in this dispatch: keep how the arguments sat
     (committed or not, on which sharding: readable of a donated array
     too) and, when the program had been loaded before (``nth`` >= 2),
     put on the dispatch span what differs from the last load —
-    ``uncommitted``: arguments whose committed flag changed (startup
-    arrays are uncommitted, a step's outputs committed), ``resharded``:
-    arguments on another sharding or device. Never runs in a steady
+    ``uncommitted``: arguments whose committed flag changed,
+    ``resharded``: arguments on another sharding or device. A fresh
+    process's second step is no such load any more (``_commit_loose``
+    hands the first dispatch what the second will see); what is left is
+    a change made to a live process: state put into the scope loose or
+    on another device between two dispatches, an executor with no place
+    whose caller feeds committed arrays. Never runs in a steady
     dispatch."""
     now = []
     for group in args:

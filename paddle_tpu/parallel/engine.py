@@ -254,7 +254,7 @@ class ParallelEngine:
         sig = (site, fn)
         t_call = time.perf_counter()
         with _dispatch_guard(plan, sig, (feeds, const_state, mut_state,
-                                         rng)) as loads:
+                                         rng)) as (loads, _):
             fetches, new_mut, new_pure, new_rng = fn(
                 feeds, const_state, mut_state, rng)
         t_done = time.perf_counter()

@@ -627,16 +627,16 @@ def test_run_latency_records_dispatch_and_complete_phases():
         feed = _batches(1)[0]
         for _ in range(4):
             exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        # the dispatches that LOADED the program are compile events: the
-        # first, and the second (the startup's uncommitted arrays came
-        # back committed, so JAX loads the program again; ISSUE 34 files
-        # it by what happened, not by its ordinal). The 2 steady steps
+        # the dispatch that LOADED the program is a compile event (ISSUE
+        # 34 files it by what happened, not by its ordinal): the first,
+        # and no other — it commits the startup's loose arrays, so the
+        # second sees what the first saw (ISSUE 46). The 3 steady steps
         # record BOTH phases (the PR 1 asymmetry recorded only async
         # dispatch here)
         assert _value("paddle_executor_run_seconds", site="run",
-                      phase="dispatch") == d0 + 2
+                      phase="dispatch") == d0 + 3
         assert _value("paddle_executor_run_seconds", site="run",
-                      phase="complete") == c0 + 2
+                      phase="complete") == c0 + 3
 
         # the pipelined site records complete too: once per steady step,
         # when its FetchHandle first blocks (wait() in the window drain

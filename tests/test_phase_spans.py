@@ -139,9 +139,11 @@ def test_trace_off_records_nothing_and_makes_no_annotation(monkeypatch):
     finally:
         trace.set_trace_enabled(prior)
     with scope_guard(scope):
-        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-    # on again: one annotation a span, none for retroactive spans (the
-    # main program's first dispatch loads it: executor.load.*)
+        exe.run(main, feed={"x": np.ones((4, 4), "float32")},
+                fetch_list=[loss], scope=scope)
+    # on again: one annotation a span, none for retroactive spans (a
+    # batch of another size is another plan, whose first dispatch loads
+    # its program: executor.load.*)
     assert sorted(made) == sorted(
         e["site"] for e in _ended()
         if not e["site"].startswith("executor.load."))

@@ -7,7 +7,9 @@
   plan; a steady dispatch has none;
 * a plan that loads its program AGAIN reads ``nth`` 2, the dispatch span
   says what differed (``uncommitted``, ``resharded``), and the dispatch is
-  a compile-time sample, not a run-time one;
+  a compile-time sample, not a run-time one (since ISSUE 46 a fresh
+  process's second step is no such load — ``tests/test_load_once.py`` —
+  so the tests here change the state under a live plan to see one);
 * ``executor.prepare`` spans the plan-cache miss path of ``Executor``,
   ``seed_plan`` and ``ParallelEngine``, which counts its miss;
 * the serving engine's own set-up is ``serving.engine.build`` and
@@ -83,6 +85,24 @@ def _mlp(commit=False):
     return exe, main, scope, loss
 
 
+def _loosen(main, scope):
+    """Swap the program's parameters in the scope for loose copies, as a
+    caller who restores them from its own arrays between two steps does:
+    the next dispatch is another argument signature."""
+    for p in main.global_block().all_parameters():
+        scope.set_var(p.name, jax.device_put(
+            np.asarray(scope.find_var(p.name))))
+
+
+def _run_thrice(exe, main, scope, loss):
+    """Three steps, the parameters swapped for loose ones after the
+    first: the second loads the program again, the third is steady."""
+    _run(exe, main, scope, loss)
+    _loosen(main, scope)
+    _run(exe, main, scope, loss)
+    _run(exe, main, scope, loss)
+
+
 def _run(exe, main, scope, loss, **kw):
     with scope_guard(scope):
         if kw:
@@ -128,21 +148,20 @@ def test_steady_dispatch_records_no_load(kw):
 
 
 def test_second_load_says_nth_2_and_why():
-    """The startup program leaves uncommitted arrays, the first step
-    hands back committed ones: the same plan, the same signature, and
+    """The first step hands back committed parameters, the caller puts
+    loose ones in their place: the same plan, the same signature, and
     JAX loads the program again (PERF.md, set-up)."""
     exe, main, scope, loss = _mlp()
     compile_h = families.EXECUTOR_COMPILE_SECONDS.labels()
     run_h = families.EXECUTOR_RUN_SECONDS.labels(site="run",
                                                 phase="dispatch")
-    for _ in range(3):
-        _run(exe, main, scope, loss)
+    _run_thrice(exe, main, scope, loss)
     first, second, third = _ended("executor.dispatch")
     assert first["attrs"]["plan"] == second["attrs"]["plan"]
     nth = {e["parent"]: e["attrs"]["nth"] for e in _ended(STAGES[2])}
     assert nth == {first["span"]: 1, second["span"]: 2}
     assert second["attrs"]["nth"] == 2
-    # the two parameters the step wrote came back committed
+    # the two parameters the step wrote had come back committed
     assert second["attrs"]["uncommitted"] == 2
     assert second["attrs"]["resharded"] == 0
     assert "uncommitted" not in first["attrs"]
@@ -223,8 +242,7 @@ def test_parallel_engine_counts_its_miss_and_prepares(kw):
 # ------------------------------------------------------------ counters
 def test_counters_say_what_the_spans_say():
     exe, main, scope, loss = _mlp()
-    for _ in range(3):
-        _run(exe, main, scope, loss)
+    _run_thrice(exe, main, scope, loss)
     _run(exe, main, scope, loss, steps=2)
     seconds = families.PROGRAM_LOAD_SECONDS
     for site, stage in zip(STAGES[1:], ("lower", "backend")):
@@ -393,8 +411,7 @@ def test_trace_view_prints_the_program_loads(tmp_path):
     from tools import trace_view
 
     exe, main, scope, loss = _mlp()
-    for _ in range(3):
-        _run(exe, main, scope, loss)
+    _run_thrice(exe, main, scope, loss)
     path = trace.dump_flight_recorder(str(tmp_path / "flight.json"))
     dump = trace_view.load_dump(path)
     assert trace_view.validate(dump) == []
@@ -404,11 +421,15 @@ def test_trace_view_prints_the_program_loads(tmp_path):
     assert mine[0]["plan"] == mine[1]["plan"]
     assert mine[1]["why"] == "uncommitted=2 resharded=0"
     assert mine[0]["why"] == "" and mine[0]["cache"] == "off"
+    # the first load committed what the startup program left loose: the
+    # two parameters and the learning rate
+    assert [r["committed"] for r in mine] == [3, 0]
     assert all(r["backend_s"] > 0 and r["lower_s"] > 0 for r in mine)
     out = io.StringIO()
     trace_view.summarize(dump, out=out)
     assert "program loads" in out.getvalue()
     assert "uncommitted=2" in out.getvalue()
+    assert "committed" in out.getvalue().split("why again")[0]
 
 
 def test_new_sites_are_declared():

@@ -83,8 +83,10 @@ def program_loads(dump: dict):
     """One row a dispatch in which JAX made a program executable, oldest
     first: the plan, JAX's name of the program, which loading dispatch
     of the plan's signature it was (``nth``; 2 = loaded AGAIN), where
-    the executable came from (``cache``), the seconds of each stage, and
-    for a second load what the dispatch span says differed
+    the executable came from (``cache``), the seconds of each stage, how
+    many loose state arrays the dispatch committed to the executor's
+    place before the call (``committed``: a first load's), and for a
+    second load what the dispatch span says differed
     (``uncommitted``/``resharded`` arguments). Programs loaded outside
     any dispatch (no plan: eager helpers, a benchmark's own jits) are
     one row a function, at the end."""
@@ -122,6 +124,7 @@ def program_loads(dump: dict):
             "lower_s": _covered([e for e in stages
                                  if e["site"] == _LOAD + "lower"]),
             "backend_s": _covered(back),
+            "committed": why.get("committed", 0),
             "why": " ".join("%s=%s" % (k, why[k]) for k in
                             ("uncommitted", "resharded") if k in why)})
     rows.sort(key=lambda r: (r["plan"] == "-", r["t"]))
@@ -134,14 +137,14 @@ def print_program_loads(dump: dict, out=sys.stdout) -> None:
         return
     print("\nprogram loads (JAX trace / lower / backend stages, "
           "observe/trace.py):", file=out)
-    print("%-9s %-28s %5s %4s %-8s %9s %9s %10s  %s"
+    print("%-9s %-28s %5s %4s %-8s %9s %9s %10s %9s  %s"
           % ("plan", "fun", "loads", "nth", "cache", "trace(s)",
-             "lower(s)", "backend(s)", "why again"), file=out)
+             "lower(s)", "backend(s)", "committed", "why again"), file=out)
     for r in rows:
-        print("%-9s %-28s %5d %4s %-8s %9.3f %9.3f %10.3f  %s"
+        print("%-9s %-28s %5d %4s %-8s %9.3f %9.3f %10.3f %9d  %s"
               % (r["plan"], r["fun"][:28], r["loads"], r["nth"],
                  r["cache"], r["trace_s"], r["lower_s"], r["backend_s"],
-                 r["why"]), file=out)
+                 r["committed"], r["why"]), file=out)
 
 
 def summarize(dump: dict, out=sys.stdout) -> None:
