@@ -356,8 +356,10 @@ def _cost_moe_ffn(ctx):
     mats = 3 if ctx.n_inputs("W1V") else 2
     k = int(ctx.attr("top_k", 1) or 1)
     # a share computes its held experts' part of the k pairs a token
-    # (held / n_experts of them in expectation); the router scores all
-    n_all = int(ctx.attr("n_experts", 0) or 0) or E
+    # (held / the router's outputs of them in expectation: an identity
+    # pair, ``n_zero``, costs nothing); the router scores all
+    n_all = (int(ctx.attr("n_experts", 0) or 0) or E) \
+        + int(ctx.attr("n_zero", 0) or 0)
     return tokens.scaled(k * mats * 2 * D * F * E // n_all + 2 * D * n_all)
 
 

@@ -910,10 +910,14 @@ def _rr_moe_ffn(ctx):
     # not) before ``route_scale`` multiplies them
     k = float(int(ctx.attr("top_k", 1) or 1)) \
         * abs(float(ctx.attr("route_scale", 1.0) or 1.0))
-    ctx.set("Out", av_mul(_sym(y), av_interval(0.0, k)))
+    out = av_mul(_sym(y), av_interval(0.0, k))
+    if ctx.attr("n_zero"):
+        # an identity expert returns the token: up to all k gates on it
+        out = av_add(out, av_mul(_sym(x), av_interval(0.0, k)))
+    ctx.set("Out", out)
     if ctx.op.outputs.get("AuxLoss"):
         ctx.set("AuxLoss", AbstractValue(0.0, _INF, finite=x.bounded))
-    for slot in ("CountsOut", "TouchedOut", "CompactOut"):
+    for slot in ("CountsOut", "TouchedOut", "CompactOut", "ZeroOut"):
         if ctx.op.outputs.get(slot):
             ctx.set(slot, AbstractValue(0.0, _INF))
 

@@ -967,12 +967,13 @@ def _r_moe_ffn(ctx):
     W1 (and W1V) [E, D, F], W2 [E, F, D], Gate [X's width, E], biases
     [E, F] and [E, D]. The group sizes are data: nothing in a shape depends on
     them. A share (``n_local``) stacks ``n_local`` experts where the
-    router and its selection bias keep all ``n_experts``."""
+    router and its selection bias keep all ``n_experts``, and ``n_zero``
+    identity experts more where the layer has them."""
     xs, xe = ctx.input_shape("X"), ctx.input_shape("XE")
     if xs is not None:
         ctx.set("Out", xs if xe is None else tuple(xs[:-1]) + (xe[-1],))
     ctx.set("AuxLoss", (), dtype="float32")
-    for slot in ("Counts", "Touched", "Compact"):
+    for slot in ("Counts", "Touched", "Compact", "Zero"):
         if slot + "Out" in ctx.op.outputs:
             ts = ctx.input_shape(slot)
             if ts is not None:
@@ -981,6 +982,7 @@ def _r_moe_ffn(ctx):
     gate = ctx.input_shape("Gate")
     E = int(ctx.attr("n_experts", 0) or 0)
     held = int(ctx.attr("n_local", 0) or 0) or E
+    wide = E + int(ctx.attr("n_zero", 0) or 0)     # the router's outputs
     if not all(is_concrete(s) for s in (w1, w2, gate) if s is not None):
         return
     if w1 is not None and w2 is not None and (
@@ -992,8 +994,8 @@ def _r_moe_ffn(ctx):
                        ("B1", None if w1 is None else (w1[0], w1[2])),
                        ("B2", None if w2 is None else (w2[0], w2[2])),
                        ("Gate", None if w1 is None or xs is None
-                        or xs[-1] < 0 else (xs[-1], E or w1[0])),
-                       ("RouterBias", (E,) if E else None)):
+                        or xs[-1] < 0 else (xs[-1], wide or w1[0])),
+                       ("RouterBias", (wide,) if E else None)):
         got = ctx.input_shape(slot)
         if got is not None and want is not None and is_concrete(got) \
                 and tuple(got) != tuple(want):
@@ -1011,8 +1013,8 @@ def _r_moe_ffn(ctx):
         ctx.fail("%s's width %d is not the experts' %d"
                  % ("X" if xe is None else "XE", xin[-1], w1[1]))
     k = int(ctx.attr("top_k", 1) or 1)
-    if E and not 1 <= k <= E:
-        ctx.fail("top_k=%d outside [1, n_experts=%d]" % (k, E))
+    if E and not 1 <= k <= wide:
+        ctx.fail("top_k=%d outside [1, %d experts]" % (k, wide))
 
 
 @register_shape_rule("group_norm")

@@ -143,7 +143,9 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             touched: Optional[Variable] = None,
             expert_input: Optional[Variable] = None,
             norm_topk_eps: Optional[float] = None,
-            compact_calls: Optional[Variable] = None):
+            compact_calls: Optional[Variable] = None,
+            n_zero_expert: int = 0,
+            zero_pairs: Optional[Variable] = None):
     """Mixture-of-experts FFN (see ops/moe_ops.py).
 
     x: [B, D] (or [B, S, D], flattened internally). Returns (out, aux)
@@ -200,11 +202,30 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     they do not (``ops/moe_ops.py::compact_rows``); ``compact_calls`` is
     a persistable [rows, 2] int32 var whose row ``counts_row`` counts
     such calls by the branch they took: column 0 cut, column 1 full.
+
+    ``n_zero_expert`` identity (zero-compute) experts stand BEHIND the
+    ``n_experts`` with weights (``dropless`` only): the router, and its
+    selection bias, are ``n_experts + n_zero_expert`` wide, ``top_k`` is
+    taken over all of them, and a chosen identity expert returns the
+    token itself, so a token's identity gates add up to ONE weight,
+    ``out += w x``. Such a pair is given to no group before the sort, as
+    a share's absent pair is: it is in no group's rows of either grouped
+    matmul and past every cut of the sorted rows, and a share's bound is
+    reckoned over all the router's outputs. A share (``n_expert_local``,
+    of the ``n_experts`` with weights) returns its held experts' part
+    plus the identity part, which every chip of a deployment computes
+    alike for its own tokens: the shares' sum counts it once. ``counts``
+    stays ``n_experts`` wide; ``zero_pairs`` is a persistable [rows, 2]
+    int32 var whose row ``counts_row`` adds the pairs that chose an
+    identity expert (column 0) and keeps the most experts with weights
+    one token chose (column 1, a running maximum).
     """
-    if not 1 <= int(top_k) <= int(n_experts):
+    n_zero = int(n_zero_expert or 0)
+    if not 1 <= int(top_k) <= int(n_experts) + n_zero:
         raise ValueError(
             "moe_ffn top_k must be in [1, n_experts]; got top_k=%s with "
-            "n_experts=%s" % (top_k, n_experts))
+            "n_experts=%s%s" % (top_k, n_experts,
+                                " + %d identity" % n_zero if n_zero else ""))
     if act not in ("relu", "swiglu", "relu2"):
         raise ValueError("moe_ffn act must be 'relu', 'swiglu' or 'relu2'; "
                          "got %r" % (act,))
@@ -221,6 +242,13 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             % (expert_first, int(expert_first) + n_local - 1, n_experts))
     if n_shared_expert and act != "swiglu":
         raise ValueError("moe_ffn: shared experts are swiglu experts")
+    if n_zero and (not dropless or expert_input is not None):
+        raise ValueError(
+            "moe_ffn: n_zero_expert (identity experts) needs dropless=True "
+            "and takes no expert_input: an identity expert returns the "
+            "token the router scored")
+    if zero_pairs is not None and not n_zero:
+        raise ValueError("moe_ffn: zero_pairs tallies n_zero_expert's pairs")
     helper = LayerHelper("moe_ffn", name=name)
     D = int(x.shape[-1])
     mk = helper.create_parameter  # stacked expert weights + router
@@ -258,14 +286,14 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
                            "float32")]
         inputs["B2"] = [mk(ParamAttr(initializer=Constant(0.0)),
                            [n_local, D], "float32", is_bias=True)]
-    inputs["Gate"] = [mk(attr("router"), [D_router, n_experts],
+    inputs["Gate"] = [mk(attr("router"), [D_router, n_experts + n_zero],
                          "float32")]
     if router_bias:
         inputs["RouterBias"] = [mk(
             ParamAttr(name=None if param_prefix is None
                       else param_prefix + "_router_bias",
                       initializer=Constant(0.0)),
-            [n_experts], "float32", is_bias=True)]
+            [n_experts + n_zero], "float32", is_bias=True)]
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference("float32")
     outputs = {"Out": [out], "AuxLoss": [aux]}
@@ -286,6 +314,8 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
         attrs["route_scale"] = float(route_scale)
     if norm_topk_eps:
         attrs["norm_topk_eps"] = float(norm_topk_eps)
+    if n_zero:
+        attrs["n_zero"] = n_zero
     if n_local != int(n_experts):
         attrs["n_local"] = n_local
         attrs["expert_first"] = int(expert_first)
@@ -298,7 +328,11 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     if compact_calls is not None:
         inputs["Compact"] = [compact_calls]
         outputs["CompactOut"] = [compact_calls]
-    if not (counts is None and touched is None and compact_calls is None):
+    if zero_pairs is not None:
+        inputs["Zero"] = [zero_pairs]
+        outputs["ZeroOut"] = [zero_pairs]
+    if not (counts is None and touched is None and compact_calls is None
+            and zero_pairs is None):
         attrs["counts_row"] = int(counts_row)
     helper.append_op(type="moe_ffn", inputs=inputs, outputs=outputs,
                      attrs=attrs)

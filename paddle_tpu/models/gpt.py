@@ -292,7 +292,67 @@ def base_config():
              conv_taps=3, ffn_act="swiglu", d_ff=11776, n_dense_layer=2,
              n_expert=64, expert_top_k=4, d_expert=1536,
              router_score="sigmoid", router_bias=True, norm_topk=True,
-             norm_topk_eps=1e-6, weight_dtype="bfloat16")"""
+             norm_topk_eps=1e-6, weight_dtype="bfloat16")
+
+    Shortcut-connected experts (``shortcut_moe``; serving programs
+    only): a published layer is attention, dense FFN, attention, dense
+    FFN, with ONE routed branch that reads the first attention's
+    post-norm and joins the residual only at the END of the second dense
+    FFN (``N`` a norm with its own scale each time)::
+
+        a0 = x  + attn[l,0](N(x));   m = N(a0);   s = routed[l](m)
+        b0 = a0 + ffn[l,0](m)
+        a1 = b0 + attn[l,1](N(b0))
+        y  = a1 + ffn[l,1](N(a1)) + s
+
+    * ``n_layer`` counts SUB-LAYERS, two a published layer: sub-layer
+      ``j = 2 l + k`` is the ordinary pair — ``gpt_<j>_pre1_ln_s``, the
+      attention's parameters, ``gpt_<j>_pre2_ln_s``, the dense FFN
+      ``gpt_<j>_ffn{1,1v,2}.w_0`` of ``d_ff`` — with a cache of its own
+      (``gpt_<j>_cache_c`` under ``attn="mla"``: two latent slabs a
+      published layer, numbered ``2 l`` and ``2 l + 1``). The branch of
+      published layer ``l`` carries the EVEN sub-layer's number:
+      ``gpt_<2l>_moe_{router,gate,up,down}.w_0``,
+      ``gpt_<2l>_moe_router_bias``. The same names in every build. It is
+      computed once, from the norm the even sub-layer's dense FFN reads,
+      and added once, to the odd sub-layer's dense FFN output. The
+      routing tallies have a row a BRANCH (``expert_rows``).
+    * ``n_zero_expert`` identity (zero-compute) experts stand behind the
+      ``n_expert`` with weights: the router and its selection bias are
+      ``n_expert + n_zero_expert`` wide, ``expert_top_k`` is taken over
+      all of them, and a chosen identity expert returns the token — a
+      token's identity gates add up to one weight, ``s += w m``
+      (``layers.moe_ffn``). ``n_expert_local`` / ``expert_first`` are a
+      share of the experts WITH weights; the identity part is whole on
+      every chip. ``ZERO_PAIRS_VAR`` tallies them in the serving decode
+      step. (The key needs no ``shortcut_moe``.)
+    * ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` (with ``attn="mla"``):
+      ``[q_nope | q_rope]`` times ``sqrt(d_model / q_lora_rank)`` before
+      the rotation; the normed latent ``c`` times ``sqrt(d_model /
+      kv_lora_rank)`` (``k_r`` is not scaled). The cache row holds the
+      scaled ``c``.
+    * it needs an even ``n_layer`` and ``d_ff`` and takes none of
+      ``mixers``, ``residual``, ``sandwich_norm``, ``n_dense_layer``,
+      shared experts, ``d_expert_in`` nor a ``"conv"`` layer; the
+      training build, the multi-token step, a prefix store and a draft
+      model refuse it by name.
+
+    LongCat-Flash (``model_type`` longcat_flash; the language model of
+    LongCat-Flash-Omni), as the worked example — published widths, all
+    28 layers (56 sub-layers), every expert::
+
+        dict(d_model=6144, n_head=64, n_layer=2 * 28, vocab=131072,
+             max_length=131072, dropout=0.0, pos_emb="rope",
+             rope_theta=10000000.0, norm="rms", norm_eps=1e-5,
+             attn="mla", q_lora_rank=1536, kv_lora_rank=512, d_nope=128,
+             d_rope=64, d_v=128, mla_scale_q_lora=True,
+             mla_scale_kv_lora=True, ffn_act="swiglu", d_ff=12288,
+             shortcut_moe=True, n_expert=512, n_zero_expert=256,
+             expert_top_k=12, d_expert=2048, router_score="softmax",
+             router_bias=True, norm_topk=False, route_scale=6.0,
+             weight_dtype="bfloat16")
+
+    (one chip's share adds ``n_expert_local=8, expert_first=0``)."""
     return dict(d_model=768, d_ff=3072, n_head=12, n_layer=12,
                 vocab=50304, max_length=1024, dropout=0.1)
 
@@ -313,6 +373,8 @@ _CFG_KEYS = frozenset([
     "mixers", "ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
     "ssm_conv", "ssm_chunk", "d_expert_in", "d_shared_expert",
     "conv_taps", "norm_topk_eps",
+    "shortcut_moe", "n_zero_expert", "mla_scale_q_lora",
+    "mla_scale_kv_lora",
 ])
 _SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
              "ssm_conv")
@@ -340,6 +402,12 @@ EXPERTS_TOUCHED_VAR = "gpt_moe_experts_touched"
 # cut their rows at it (column 0) and that took the full length
 # (column 1): [n_layer, 2] int32
 COMPACT_CALLS_VAR = "gpt_moe_compact_calls"
+# with identity experts (cfg['n_zero_expert']) the serving decode step
+# also tallies, per expert branch, the pairs that chose one (column 0,
+# summed: they cost nothing) and the most experts WITH weights one token
+# chose (column 1, a running maximum: the straggler's width):
+# [expert_rows, 2] int32
+ZERO_PAIRS_VAR = "gpt_moe_zero_pairs"
 
 # the largest |row sum - 1| or |column sum - 1| any residual mapping
 # H_res has shown in the serving decode step (cfg['residual'] = 'mhc'):
@@ -383,7 +451,7 @@ def _check_cfg(cfg):
         for key in ("expert_top_k", "d_expert"):
             if not cfg.get(key):
                 raise ValueError("cfg['n_expert'] needs cfg[%r]" % key)
-        if not 1 <= cfg["expert_top_k"] <= cfg["n_expert"]:
+        if not 1 <= cfg["expert_top_k"] <= router_width(cfg):
             raise ValueError(
                 "cfg['expert_top_k'] must be in [1, n_expert]; got %r of "
                 "%r" % (cfg["expert_top_k"], cfg["n_expert"]))
@@ -410,7 +478,7 @@ def _check_cfg(cfg):
         for key in ("n_dense_layer", "n_shared_expert", "router_score",
                     "router_bias", "route_scale", "n_expert_local",
                     "expert_first", "d_expert_in", "d_shared_expert",
-                    "norm_topk_eps"):
+                    "norm_topk_eps", "n_zero_expert", "shortcut_moe"):
             if cfg.get(key):
                 raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
     if cfg.get("ffn_act") == "relu2" and (
@@ -420,6 +488,7 @@ def _check_cfg(cfg):
             "gate, no biases): it needs cfg['n_expert'] and takes no "
             "cfg['n_dense_layer'] (no dense FFN of that kind is built)")
     _check_mixers(cfg)
+    _check_shortcut(cfg)
     if has_streams(cfg):
         if not int(cfg.get("hc_mult") or 0) >= 1:
             raise ValueError("cfg['residual']='mhc' needs cfg['hc_mult'] "
@@ -480,7 +549,7 @@ def _check_cfg(cfg):
             if cfg.get(key):
                 raise ValueError("cfg['attn']='mla' takes no cfg[%r]" % key)
         return
-    for key in _MLA_KEYS:
+    for key in _MLA_KEYS + ("mla_scale_q_lora", "mla_scale_kv_lora"):
         if cfg.get(key):
             raise ValueError("cfg[%r] needs cfg['attn']='mla'" % key)
     if _d_head(cfg) % 2 and cfg.get("pos_emb", "learned") == "rope":
@@ -522,6 +591,38 @@ def _check_mixers(cfg):
                 "cfg['ssm_groups']=%r must divide cfg['ssm_heads']=%r, and "
                 "cfg['ssm_conv']=%r be >= 2 taps"
                 % (cfg["ssm_groups"], cfg["ssm_heads"], cfg["ssm_conv"]))
+
+
+def _check_shortcut(cfg):
+    """cfg['shortcut_moe'] and cfg['n_zero_expert'] (``base_config``)."""
+    if cfg.get("n_zero_expert") and (cfg.get("d_expert_in")
+                                     or cfg.get("ffn_act") == "relu2"):
+        raise ValueError(
+            "cfg['n_zero_expert'] (identity experts) takes neither "
+            "cfg['d_expert_in'] nor ffn_act='relu2': an identity expert "
+            "returns the token the router scored, beside SwiGLU experts")
+    if not has_shortcut(cfg):
+        return
+    if cfg["n_layer"] % 2 or "d_ff" not in cfg:
+        raise ValueError(
+            "cfg['shortcut_moe'] needs an even cfg['n_layer'] (it counts "
+            "attention-then-FFN sub-layers, two a published layer) and "
+            "cfg['d_ff'] (each keeps its dense FFN); got n_layer=%r"
+            % (cfg["n_layer"],))
+    for key, why in (
+            ("mixers", "one mixer a layer has no dense FFN to fork beside"),
+            ("residual", "the branch has no mapping onto several streams"),
+            ("sandwich_norm", "no norm is stated for the joined sum"),
+            ("n_dense_layer", "every sub-layer keeps its dense FFN"),
+            ("n_shared_expert", "the dense FFNs stand where one would"),
+            ("d_shared_expert", "the dense FFNs stand where one would"),
+            ("d_expert_in", "the branch reads the token itself")):
+        if cfg.get(key):
+            raise ValueError("cfg['shortcut_moe'] takes no cfg[%r]: %s"
+                             % (key, why))
+    if "conv" in (cfg.get("layer_types") or ()):
+        raise ValueError("cfg['shortcut_moe'] takes no 'conv' layer: the "
+                         "branch forks behind an attention sub-block")
 
 
 def _check_conv(cfg):
@@ -610,6 +711,37 @@ def has_streams(cfg):
     """Whether a token's state is ``hc_mult`` residual streams
     (``residual='mhc'``) and not one vector."""
     return cfg.get("residual") == "mhc"
+
+
+def has_shortcut(cfg):
+    """Whether the routed experts are a shortcut branch
+    (``shortcut_moe``): cfg['n_layer'] counts attention-then-dense-FFN
+    sub-layers, two a published layer, and ONE routed branch a published
+    layer forks at the even sub-layer's post-attention norm and joins
+    the residual at the END of the odd one."""
+    return bool(cfg.get("shortcut_moe"))
+
+
+def router_width(cfg):
+    """Outputs of the router: the experts with weights and, behind them,
+    cfg['n_zero_expert'] identity experts."""
+    return cfg["n_expert"] + int(cfg.get("n_zero_expert") or 0)
+
+
+def expert_rows(cfg):
+    """Rows of the routing tallies: one an expert branch — a layer, or a
+    PAIR of sub-layers under ``shortcut_moe`` (branch ``l`` forks in
+    sub-layer ``2 l``)."""
+    return cfg["n_layer"] // 2 if has_shortcut(cfg) else cfg["n_layer"]
+
+
+def _refuse_shortcut(cfg, who, why):
+    if has_shortcut(cfg):
+        raise ValueError(
+            "%s: cfg['shortcut_moe'] builds layers of two attention "
+            "sub-blocks and two dense FFNs with one routed branch that "
+            "forks after the first and joins after the second, %s"
+            % (who, why))
 
 
 def mixer_kind(cfg, i):
@@ -832,8 +964,12 @@ def _mla_q(cfg, h, nm, S, pos):
         _fc(h, cfg["q_lora_rank"], nm + "_att_qa.w_0"),
         begin_norm_axis=2, epsilon=_rms_eps(cfg),
         param_attr=ParamAttr(name=nm + "_att_qa_ln_s"))
-    q = layers.reshape(_fc(h, n_head * (dn + dr), nm + "_att_qb.w_0"),
-                       [-1, S, n_head, dn + dr])
+    q = _fc(h, n_head * (dn + dr), nm + "_att_qb.w_0")
+    if cfg.get("mla_scale_q_lora"):
+        # both parts of every head, before the rotation
+        q = layers.scale(
+            q, scale=(cfg["d_model"] / float(cfg["q_lora_rank"])) ** 0.5)
+    q = layers.reshape(q, [-1, S, n_head, dn + dr])
     q_nope = layers.slice(q, axes=[3], starts=[0], ends=[dn])
     q_rope = layers.slice(q, axes=[3], starts=[dn], ends=[dn + dr])
     if S > 1:
@@ -856,6 +992,9 @@ def _mla_row(cfg, h, nm, S, pos):
         layers.slice(kv, axes=[2], starts=[0], ends=[dc]),
         begin_norm_axis=2, epsilon=_rms_eps(cfg),
         param_attr=ParamAttr(name=nm + "_att_kva_ln_s"))
+    if cfg.get("mla_scale_kv_lora"):
+        # the latent only: k_r is not scaled
+        c = layers.scale(c, scale=(cfg["d_model"] / float(dc)) ** 0.5)
     k_r = _rope(cfg, layers.reshape(
         layers.slice(kv, axes=[2], starts=[dc], ends=[dc + dr]),
         [-1, 1, S, dr]), pos)
@@ -918,15 +1057,23 @@ def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
                        dev, **tally)
 
 
-def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, **tally):
+def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, branch=None,
+                **tally):
     """A layer after its first sub-block's output ``y`` (attention's
     projection, a gated convolution's): the residual, then the FFN or
-    the experts and theirs."""
+    the experts and theirs. ``branch`` (cfg['shortcut_moe']) is the
+    builder's one dict that carries the routed branch from the even
+    sub-layer, where it forks off the norm the dense FFN reads, to the
+    end of the odd one, where it joins: computed once, added once."""
     x = _residual(cfg, x, y, nm + "_post1", mix)
     if cfg.get("mixers"):
         return x            # the attention was the layer's one mixer
     h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
+    if branch is not None and i % 2 == 0:
+        branch["s"] = _routed(cfg, h2, nm, i // 2, **tally)
     f = _mlp(cfg, h2, nm, i, **tally)
+    if branch is not None and i % 2:
+        f = layers.elementwise_add(f, branch.pop("s"))
     return _residual(cfg, x, f, nm + "_post2", mix2)
 
 
@@ -1050,8 +1197,17 @@ def _routed_pairs_var(cfg, helper):
     if not cfg.get("n_expert"):
         return None
     return helper.create_global_variable(
-        name=ROUTED_PAIRS_VAR, shape=(cfg["n_layer"], cfg["n_expert"]),
+        name=ROUTED_PAIRS_VAR, shape=(expert_rows(cfg), cfg["n_expert"]),
         dtype="int32")
+
+
+def _zero_pairs_var(cfg, helper):
+    """The serving decode step's tally of the pairs that cost nothing
+    (``ZERO_PAIRS_VAR``), None without identity experts."""
+    if not cfg.get("n_zero_expert"):
+        return None
+    return helper.create_global_variable(
+        name=ZERO_PAIRS_VAR, shape=(expert_rows(cfg), 2), dtype="int32")
 
 
 def _mhc_dev_var(cfg, helper):
@@ -1070,7 +1226,7 @@ def _experts_touched_var(cfg, helper):
         return None
     return helper.create_global_variable(
         name=EXPERTS_TOUCHED_VAR,
-        shape=(cfg["n_layer"], cfg["n_expert_local"]), dtype="int32")
+        shape=(expert_rows(cfg), cfg["n_expert_local"]), dtype="int32")
 
 
 def _compact_calls_var(cfg, helper, tokens):
@@ -1081,28 +1237,39 @@ def _compact_calls_var(cfg, helper, tokens):
     from ..ops.moe_ops import compact_rows
 
     if not cfg.get("n_expert") or not cfg.get("n_expert_local") \
-            or compact_rows(tokens * cfg["expert_top_k"], cfg["n_expert"],
+            or compact_rows(tokens * cfg["expert_top_k"],
+                            router_width(cfg),
                             cfg["n_expert_local"]) is None:
         return None
     return helper.create_global_variable(
-        name=COMPACT_CALLS_VAR, shape=(cfg["n_layer"], 2), dtype="int32")
+        name=COMPACT_CALLS_VAR, shape=(expert_rows(cfg), 2), dtype="int32")
 
 
-def _mlp(cfg, h, nm, layer, counts=None, touched=None, compact=None):
+def _mlp(cfg, h, nm, layer, **tally):
     """The block's second half, behind every builder's one call: the
     dense FFN (every layer of a dense model, the first
-    cfg['n_dense_layer'] of a sparse one), or — cfg['n_expert'] —
-    dropless top-k routing over SwiGLU experts, with the shared expert,
-    the router's scoring and the share of the experts this chip holds
-    (the load-balancing loss is not part of the LM loss here)."""
-    if not cfg.get("n_expert") or layer < (cfg.get("n_dense_layer") or 0):
+    cfg['n_dense_layer'] of a sparse one, every sub-layer under
+    cfg['shortcut_moe'], whose experts are ``_layer_tail``'s branch), or
+    — cfg['n_expert'] — the routed experts (``_routed``; ``tally``: its
+    counts)."""
+    if not cfg.get("n_expert") or has_shortcut(cfg) \
+            or layer < (cfg.get("n_dense_layer") or 0):
         return _ffn(h, cfg["d_model"], cfg["d_ff"], nm,
                     act=cfg.get("ffn_act", "relu"),
                     bias=not _new_style(cfg))
+    return _routed(cfg, h, nm, layer, **tally)
+
+
+def _routed(cfg, h, nm, row, counts=None, touched=None, compact=None,
+            zero=None):
+    """Dropless top-k routing over SwiGLU experts on the normed ``h``,
+    with the shared expert, the router's scoring, the identity experts
+    and the share of the experts this chip holds (the load-balancing
+    loss is not part of the LM loss here). ``row`` is the tallies'."""
     extra = {k: cfg[k] for k in ("router_score", "router_bias",
                                  "route_scale", "n_expert_local",
                                  "expert_first", "n_shared_expert",
-                                 "norm_topk_eps")
+                                 "norm_topk_eps", "n_zero_expert")
              if cfg.get(k)}
     act = "relu2" if cfg.get("ffn_act") == "relu2" else "swiglu"
     if cfg.get("d_expert_in"):
@@ -1114,8 +1281,8 @@ def _mlp(cfg, h, nm, layer, counts=None, touched=None, compact=None):
         h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
         act=act, dropless=True,
         norm_topk=bool(cfg.get("norm_topk", False)),
-        param_prefix=nm + "_moe", counts=counts, counts_row=layer,
-        touched=touched, compact_calls=compact, **extra)
+        param_prefix=nm + "_moe", counts=counts, counts_row=row,
+        touched=touched, compact_calls=compact, zero_pairs=zero, **extra)
     if cfg.get("d_expert_in"):
         out = _fc(out, cfg["d_model"], nm + "_moe_lat_up.w_0")
     if cfg.get("d_shared_expert"):
@@ -1274,6 +1441,9 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
     _refuse_state(cfg, "build", "which is the serving programs' (prefill, "
                   "decode steps): the carried-rows convolution has no "
                   "backward")
+    _refuse_shortcut(cfg, "build", "which is the serving programs' "
+                     "(prefill, decode steps): the training build keeps "
+                     "one attention and one FFN-or-experts a layer")
     new_style = _new_style(cfg)
     if new_style:
         # the layers of ``_NEW_LAYER_KEYS`` train on COMPOSED attention
@@ -1526,6 +1696,7 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     # only the serving decode step tallies its routing; a share's long
     # prefill tallies which length its expert calls ran at
     tally = {"compact": _compact_calls_var(cfg, helper, batch * P)}
+    branch = {} if has_shortcut(cfg) else None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
@@ -1561,7 +1732,8 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.reshape(
                 layers.transpose(ctxv, perm=[0, 2, 1, 3]),
                 [-1, P, n_head * cfg["d_v"]])
-            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, **tally)
+            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
+                            **tally)
             continue
         ck = helper.create_global_variable(
             name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
@@ -1600,11 +1772,13 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, **tally)
+        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
+                        **tally)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
-    if has_streams(cfg) or has_state(cfg) or cfg.get("mixers"):
+    if has_streams(cfg) or has_state(cfg) or cfg.get("mixers") \
+            or has_shortcut(cfg):
         # the one row an admission needs, cut BEFORE the head: a plan
         # that fetches the row or its argmax holds a [1, vocab] head,
         # and only one that fetches ``logits`` (``generate``) the
@@ -1725,11 +1899,15 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     routed = _routed_pairs_var(cfg, helper) if per_slot_pos else None
     touched = _experts_touched_var(cfg, helper) if per_slot_pos else None
     dev = _mhc_dev_var(cfg, helper) if per_slot_pos else None
+    # only the serving step tallies its routing
+    tally = {"counts": routed, "touched": touched,
+             "zero": _zero_pairs_var(cfg, helper) if per_slot_pos else None}
+    branch = {} if has_shortcut(cfg) else None
     cache_names = []
     for i in range(cfg["n_layer"]):
         nm = "gpt_%d" % i
         lone = _lone_mixer(cfg, helper, x, nm, i, batch, 1, True,
-                           cache_names, counts=routed, touched=touched)
+                           cache_names, **tally)
         if lone is not None:
             x = lone
             continue
@@ -1738,8 +1916,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             h, mix = _sub_input(cfg, x, nm, 1, dev)
             y, kept = _gated_conv(cfg, helper, h, nm, batch, True)
             cache_names.append(kept)
-            x = _layer_tail(cfg, x, y, nm, i, mix, dev, counts=routed,
-                            touched=touched)
+            x = _layer_tail(cfg, x, y, nm, i, mix, dev, **tally)
             continue
         if latent:
             # the absorbed form: one latent row written, and every head
@@ -1758,7 +1935,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
                 d_v=cfg["d_v"], scale=_mla_scale(cfg),
                 param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
             x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                            counts=routed, touched=touched)
+                            branch=branch, **tally)
             continue
         # GQA: the cache stores n_kv heads — H/Hkv-times less decode
         # HBM, the whole point of grouped-query attention at inference
@@ -1809,7 +1986,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
         ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
         x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                        counts=routed, touched=touched)
+                        branch=branch, **tally)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -1871,6 +2048,10 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     _check_cfg(cfg)
     if max_len is None:
         max_len = cfg["max_length"]
+    _refuse_shortcut(cfg, "build_multi_token_decode_step",
+                     "which the multi-token step (suffix prefill after a "
+                     "prefix hit, speculative verification) does not carry "
+                     "from one sub-layer to the next")
     if has_latent(cfg):
         raise ValueError(
             "build_multi_token_decode_step: cfg['attn']='mla' keeps a "

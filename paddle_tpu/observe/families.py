@@ -987,6 +987,27 @@ MOE_COMPACT_CALLS = REGISTRY.gauge(
     "= the router sends this share more than twice its even part",
     labels=("layer", "path"))
 
+MOE_ZERO_PAIRS = REGISTRY.gauge(
+    "paddle_moe_zero_pairs",
+    "Token-expert pairs of the serving decode step that chose an IDENTITY "
+    "(zero-compute) expert of a cfg with n_zero_expert, by expert branch "
+    "(a layer; a pair of sub-layers under shortcut_moe): they are given "
+    "to no group, cost no row of a grouped matmul and add w x. With the "
+    "layer's paddle_moe_routed_pairs they add up to b_max x expert_top_k "
+    "a step. A copy of column 0 of the device-side [branches, 2] int32, "
+    "refreshed by DecodeEngine.routed_pairs() / zero_pairs() (no fetch a "
+    "step). The even share is n_zero_expert / (n_expert + n_zero_expert)",
+    labels=("layer",))
+
+MOE_REAL_EXPERTS_MAX = REGISTRY.gauge(
+    "paddle_moe_real_experts_max",
+    "The most experts WITH weights one token of a serving decode step "
+    "chose since the engine was built (free slots' rows too), by expert "
+    "branch, for a cfg with n_zero_expert: between 0 and expert_top_k, "
+    "the straggler's width — work a token varies with identity experts. "
+    "A copy of column 1 of the same device-side tally (a running maximum)",
+    labels=("layer",))
+
 SERVING_CACHE_BYTES = REGISTRY.gauge(
     "paddle_serving_cache_bytes",
     "Bytes of the decode caches a serving lane built, by kind: 'ring' "

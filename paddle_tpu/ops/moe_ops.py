@@ -16,6 +16,9 @@ their own width (a latent of the tokens) while the router still scores
 ``X``: the output then has ``XE``'s width. ``dropless`` computes every pair; a
 ``capacity`` drops the overflow exactly as ``route_tokens`` says, by
 giving the dropped pairs to no group before the sort — one path for both.
+``n_zero`` identity (zero-compute) experts stand behind the ``E`` with
+weights in the router's outputs: a pair that chose one is given to no
+group either, and its gate goes into ONE weight a token, ``out += w x``.
 
 Under a ParallelEngine mesh with an 'expert' axis of size E each device
 computes ITS expert on the tokens routed to it and the [capacity, D]
@@ -163,7 +166,8 @@ def _tokens_back(z, tok, T, most):
 
 
 def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
-             norm_topk, z_loss, scoring=None, share=None, xe=None):
+             norm_topk, z_loss, scoring=None, share=None, xe=None,
+             n_zero=0):
     """Single-device path, dropless, capacity-bound or a share: the
     (token, expert) pairs sorted by expert, two grouped matmuls over the
     ragged groups (kernels/moe_gmm.py), the gate-weighted sum back per
@@ -181,7 +185,12 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     part of the layer (the parts of all shares add up to the whole).
     ``scoring`` is ``router``'s ``score``/``bias``/``route_scale``.
     ``xe [T, D']`` is what the experts read where that is not ``x`` (the
-    router's input).
+    router's input). ``n_zero`` identity experts are the router's outputs
+    ``E .. E + n_zero - 1`` (dropless only): a pair that chose one belongs
+    to no group, as a share's absent pair does — it is in no group's rows
+    of either grouped matmul and past every cut — and the token's
+    identity gates add up to one weight, ``out += w x``. A share's bound
+    is reckoned over all ``E + n_zero`` outputs the pairs spread over.
 
     A share's held pairs are the FIRST ``sum(sizes)`` rows of the sorted
     order. Where ``compact_rows`` gives a bound ``cap``, the rows are
@@ -193,16 +202,19 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     whose router sends the share more than the bound takes the full
     length, as every call without a bound does: no pair is ever dropped.
 
-    Returns (out [T, D] — ``D'`` with ``xe`` —, aux, pairs given to each of the ``E`` experts
-    the router scores [E] int32 — a share's own groups are its slice —,
-    and the branch a bounded call took: int32 1 cut, 0 full; None for a
-    call without a bound)."""
+    Returns (out [T, D] — ``D'`` with ``xe`` —, aux, pairs given to each
+    of the ``E + n_zero`` outputs the router scores, int32 — a share's
+    own groups are a slice of the first ``E`` —, the branch a bounded
+    call took: int32 1 cut, 0 full; None for a call without a bound, and
+    the most experts WITH weights one token chose, int32; None without
+    identity experts)."""
     from ..parallel.moe import route_tokens, router
 
     T = x.shape[0]
     scoring = scoring or {}
+    E_all = E + n_zero
     if capacity is None:
-        expert_idx, gate, aux = router(x, gate_w, E, top_k, z_loss,
+        expert_idx, gate, aux = router(x, gate_w, E_all, top_k, z_loss,
                                        norm_topk, **scoring)
         flat_e = expert_idx.reshape(-1)                  # [K*T]
     else:
@@ -212,11 +224,20 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
         flat_e = jnp.where(keep, expert_idx, E).reshape(-1)
         gate = jnp.where(keep, gate, 0)
     routed = sizes = jnp.sum(
-        flat_e[:, None] == jnp.arange(E)[None, :], axis=0,
-        dtype=jnp.int32)                                 # [E]
+        flat_e[:, None] == jnp.arange(E_all)[None, :], axis=0,
+        dtype=jnp.int32)                                 # [E + n_zero]
+    identity = real_most = None
+    if n_zero:
+        ident = expert_idx >= E                          # [K, T]
+        identity = jnp.sum(jnp.where(ident, gate, 0), axis=0)[:, None] * x
+        real_most = jnp.max(jnp.sum(~ident, axis=0, dtype=jnp.int32))
+        # an identity pair belongs to no group: it sorts behind them all
+        flat_e = jnp.minimum(flat_e, E)
+        gate = jnp.where(ident, 0, gate)
+        sizes = routed[:E]
     cap = None
     if share is not None:
-        cap = compact_rows(flat_e.shape[0], E, share[1])
+        cap = compact_rows(flat_e.shape[0], E_all, share[1])
         first, E = share              # from here on E counts held groups
         local = flat_e - first
         held = jnp.logical_and(local >= 0, local < E)
@@ -229,10 +250,13 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     if cap is None:
         y = _expert_rows(src, order % T, sorted_e, sizes, w1, w1v, b1, w2,
                          b2, act)
-        return _choices_back(y, order, gate), aux, routed, None
-    out, took = _bounded(src, order, sorted_e, sizes, gate, w1, w1v, b1,
-                         w2, b2, cap=cap, act=act)
-    return out, aux, routed, took
+        out, took = _choices_back(y, order, gate), None
+    else:
+        out, took = _bounded(src, order, sorted_e, sizes, gate, w1, w1v,
+                             b1, w2, b2, cap=cap, act=act)
+    if identity is not None:
+        out = out + identity
+    return out, aux, routed, took, real_most
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "act"))
@@ -300,7 +324,9 @@ def _moe_ffn(ctx, ins, attrs):
     w1, w2, gate_w = ins["W1"][0], ins["W2"][0], ins["Gate"][0]
     w1v, b1, b2, counts = opt("W1V"), opt("B1"), opt("B2"), opt("Counts")
     touched, xe, compact = opt("Touched"), opt("XE"), opt("Compact")
+    zero = opt("Zero")
     E = int(attrs["n_experts"])
+    n_zero = int(attrs.get("n_zero") or 0)
     scoring = {"score": attrs.get("router_score", "softmax"),
                "bias": opt("RouterBias"),
                "route_scale": float(attrs.get("route_scale", 1.0))}
@@ -331,37 +357,50 @@ def _moe_ffn(ctx, ins, attrs):
             "devices — experts map one-per-device" % (E, axis,
                                                       mesh.shape[axis]))
     plain = share is None and scoring["score"] == "softmax" \
-        and scoring["bias"] is None and scoring["route_scale"] == 1.0
+        and scoring["bias"] is None and scoring["route_scale"] == 1.0 \
+        and not n_zero
+    if n_zero and (not dropless or xe is not None):
+        raise NotImplementedError(
+            "moe_ffn: identity experts (n_zero) are a dropless layer's, "
+            "over the tokens themselves (no XE): a capacity has no rule "
+            "for a pair that costs nothing")
     if use_ep and (dropless or act != "relu" or not plain):
         raise NotImplementedError(
             "moe_ffn: the expert-parallel branch runs ReLU experts under "
             "a capacity with the softmax router; dropless or swiglu "
-            "experts, a share of the experts and the sigmoid router run "
-            "on one device")
+            "experts, a share of the experts, identity experts and the "
+            "sigmoid router run on one device")
 
     if use_ep and xe is not None:
         raise NotImplementedError(
             "moe_ffn: experts with an input of their own (XE) run on one "
             "device")
     if not use_ep:
-        out, aux, routed, took = _experts(
+        out, aux, routed, took, real_most = _experts(
             xf, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
             norm_topk, z_loss, scoring, share,
-            None if xe is None else xe.reshape(T, -1))
+            None if xe is None else xe.reshape(T, -1), n_zero)
         outs = {"Out": out.reshape(x.shape[:-1] + out.shape[-1:]),
                 "AuxLoss": aux}
         row = int(attrs.get("counts_row", 0))
         if counts is not None:
             # the device-side tally of routed pairs: this layer's row,
-            # over all the experts the router scores
+            # over all the experts WITH weights the router scores
             outs["CountsOut"] = counts.at[row].add(
-                routed.astype(counts.dtype))
+                routed[:E].astype(counts.dtype))
         if touched is not None:
             # calls in which each HELD expert was given a pair: the
             # grouped matmul fetches no weights for an empty group
             first = share[0] if share is not None else 0
             outs["TouchedOut"] = touched.at[row].add(
                 (routed[first:first + n_local] > 0).astype(touched.dtype))
+        if zero is not None:
+            # the pairs that chose an identity expert (column 0, summed)
+            # and the most experts with weights one token chose (column
+            # 1, a running maximum): the straggler's width
+            outs["ZeroOut"] = zero.at[row, 0].add(
+                jnp.sum(routed[E:]).astype(zero.dtype)
+            ).at[row, 1].max(real_most.astype(zero.dtype))
         if compact is not None:
             # the bounded calls of this layer by the branch they took:
             # column 0 compact, column 1 full; a call without a bound

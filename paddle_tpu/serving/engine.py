@@ -664,6 +664,10 @@ class DecodeEngine:
             multi += [(self.cfg, "speculative decoding (draft_cfg=)"),
                       (draft_cfg, "a draft model (draft_cfg=)")]
         for model, lever in multi:
+            gpt._refuse_shortcut(
+                model, "DecodeEngine: %s" % lever,
+                "and the multi-token step does not carry the branch from "
+                "one sub-layer to the next")
             if gpt.has_latent(model):
                 raise ValueError(
                     "DecodeEngine: %s cannot serve a model with "
@@ -845,9 +849,12 @@ class DecodeEngine:
     def routed_pairs(self) -> Optional[np.ndarray]:
         """``[n_layer, n_expert]`` (token, expert) pairs the decode step
         has routed since the engine was built, for a model with sparse
-        experts (None for a dense one). The step adds to the tally on
-        the device and nothing fetches it a step: this call is the one
-        transfer, and it refreshes ``paddle_moe_routed_pairs``. Rows of
+        experts (None for a dense one; a row an expert BRANCH under
+        ``shortcut_moe``, ``n_layer / 2`` of them, here and in the two
+        tallies below; identity experts are ``zero_pairs()``'s). The
+        step adds to the tally on the device and nothing fetches it a
+        step: this call is the one transfer, and it refreshes
+        ``paddle_moe_routed_pairs``. Rows of
         free slots are routed like any other, so at low occupancy the
         tally holds their garbage too."""
         from ..models.gpt import ROUTED_PAIRS_VAR
@@ -856,6 +863,29 @@ class DecodeEngine:
         tally = self._refresh_tally(ROUTED_PAIRS_VAR, MOE_ROUTED_PAIRS)
         self.experts_touched()
         self.compact_calls()
+        self.zero_pairs()
+        return tally
+
+    def zero_pairs(self) -> Optional[np.ndarray]:
+        """``[expert branches, 2]`` for a cfg with identity experts
+        (``n_zero_expert``; None otherwise): column 0 the (token, expert)
+        pairs of the decode steps that chose an identity expert — they
+        cost nothing, and with ``routed_pairs()``'s row they add up to
+        ``b_max x expert_top_k`` a step —, column 1 the most experts WITH
+        weights one token of a step chose since the engine was built.
+        Kept on the device; this call (and ``routed_pairs()``) is the one
+        transfer and refreshes ``paddle_moe_zero_pairs`` and
+        ``paddle_moe_real_experts_max``."""
+        from ..models.gpt import ZERO_PAIRS_VAR
+        from ..observe.families import MOE_REAL_EXPERTS_MAX, MOE_ZERO_PAIRS
+
+        var = self._lane.scope.find_var(ZERO_PAIRS_VAR)
+        if var is None:
+            return None
+        tally = np.asarray(var)
+        for layer, (pairs, most) in enumerate(tally):
+            MOE_ZERO_PAIRS.labels(layer=str(layer)).set(int(pairs))
+            MOE_REAL_EXPERTS_MAX.labels(layer=str(layer)).set(int(most))
         return tally
 
     def experts_touched(self) -> Optional[np.ndarray]:

@@ -184,7 +184,7 @@ def test_expert_layer_compiles_for_v5e(tokens, v5e, compiled_kernels):
     D, F, E, k = 2048, 1024, 64, 8
 
     def layer(x, router, gate, up, down):
-        out, _aux, sizes, _took = _experts(
+        out, _aux, sizes, _took, _most = _experts(
             x, gate, up, None, down, None, router, E, k, None, "swiglu",
             False, 0.0)
         return out, sizes

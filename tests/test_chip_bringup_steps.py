@@ -481,6 +481,90 @@ def test_lfm2_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     print("lfm2 prefill P=%d:" % P, mem)
 
 
+def _longcat():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "longcat-flash-omni.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def test_longcat_serving_decode_step_compiles_for_v5e(v5e,
+                                                      compiled_kernels):
+    """The whole ``longcat-flash-omni`` serving decode step (32 slots of
+    4,096 latent rows in EIGHT slabs, two a published layer; bf16
+    matrices; 8 of 512 experts and all 256 identity experts) for the
+    described chip: eight ``mla_decode`` calls and eight in-place row
+    writes, both grouped matmuls of the four routed branches, every slab
+    and the three tallies donated, and 10.35 GB of arguments."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mla_decode, moe_gmm
+
+    gpt, cfg, serving = _longcat()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert caches == ["gpt_%d_cache_c" % j for j in range(8)]
+    types = [op.type for op in main.global_block().ops]
+    assert types.count("moe_ffn") == 4 and types.count("mla_decode") == 8
+    lowered, mut = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert sorted(mut) == sorted(caches + [
+        gpt.ROUTED_PAIRS_VAR, gpt.EXPERTS_TOUCHED_VAR, gpt.ZERO_PAIRS_VAR])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mla_decode.KERNEL,
+                              text))) == 8
+    assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
+    # no dense FFN matrix nor an expert stack is widened whole
+    assert not re.search(r"f32\[6144,12288\][^ ]* (copy|convert)\(", text)
+    assert not re.search(r"f32\[8,6144,2048\][^ ]* (copy|convert)\(", text)
+    mem = compiled.memory_analysis()
+    # 7.93 GB of bf16 matrices, 2.42 GB of latent slabs
+    assert 10.3e9 < mem.argument_size_in_bytes < 10.4e9, mem
+    assert mem.temp_size_in_bytes < 1.0e9, mem
+    print("longcat decode step:", mem)
+
+
+@pytest.mark.parametrize("P", [128, 3328])
+def test_longcat_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
+    """The batch=1 prefill of the shortest and the longest prompt of the
+    mix: eight flash forwards at 64 heads of 192 / 128 (no [P, P] score
+    tensor), four routed branches — at 3,328 x 12 pair rows each behind
+    ``compact_rows``' bound, reckoned over all 768 outputs of the router
+    — a head on ONE row, and temporaries that fit beside the 10.35 GB
+    the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.ops.moe_ops import compact_rows
+
+    gpt, cfg, serving = _longcat()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    types = [op.type for op in main.global_block().ops]
+    assert types.count("fused_attention") == 8
+    assert types.count("moe_ffn") == 4
+    cap = compact_rows(P * 12, 768, 8)
+    assert cap == (None if P == 128 else 896)
+    assert (gpt.COMPACT_CALLS_VAR in main.global_block().vars) \
+        == (cap is not None)
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("flash_fwd") >= 8
+    if P != 128:        # q_nope is [1, 64, P, 128] itself
+        assert "f32[1,64,%d,%d]" % (P, P) not in text
+    assert "f32[1,%d,16384]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.5e9, mem
+    print("longcat prefill P=%d:" % P, mem)
+
+
 # (M, K, N, itemsize) of the cells' expert products at their longest
 # prefill: pairs of the longest prompt x the stored width of a weight
 LONGEST_PREFILL_GMM = {
@@ -489,6 +573,8 @@ LONGEST_PREFILL_GMM = {
     "trinity_down": (32768, 3072, 3072, 4),
     "pangu_up": (26624, 7680, 2048, 2), "pangu_down": (26624, 2048, 7680, 2),
     "xing_up": (32768, 3584, 1024, 2), "xing_down": (32768, 1024, 3584, 2),
+    "longcat_up": (39936, 6144, 2048, 2),
+    "longcat_down": (39936, 2048, 6144, 2),
 }
 
 
@@ -519,7 +605,7 @@ def test_widest_whole_reduction_plan_compiles_for_v5e(v5e, compiled_kernels):
     E, held, k = 256, 8, 8
 
     def layer(x, router, gate, up, down):
-        out, _aux, sizes, _took = _experts(
+        out, _aux, sizes, _took, _most = _experts(
             x, gate, up, None, down, None, router, E, k, None, "swiglu",
             True, 0.0, share=(0, held))
         return out, sizes

@@ -66,6 +66,12 @@ REACHES = {
     "lfm2-24b-a2b": {
         "decode": ("kv_cache_write", "moe_ffn"),
         "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
+    # two latent attentions a published layer (flash at 64 heads of
+    # 192 / 128 in the prefill, mla_decode in the step), one routed
+    # branch (the grouped matmul at 6144 x 2048)
+    "longcat-flash-omni": {
+        "decode": ("kv_cache_write", "mla_decode", "moe_ffn"),
+        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -92,6 +98,10 @@ RUNS_ON_THE_CHIP = {
     # rows=1, moe_gmm_up / moe_gmm_down in facts' plan counters
     ("lfm2-24b-a2b", "fused_attention"), ("lfm2-24b-a2b", "kv_cache_write"),
     ("lfm2-24b-a2b", "moe_ffn"),
+    # my chip runs, PR 47: flash_fwd, mla_decode, moe_gmm_up / down in
+    # the facts' plan counters and the device breakdown
+    ("longcat-flash-omni", "fused_attention"),
+    ("longcat-flash-omni", "mla_decode"), ("longcat-flash-omni", "moe_ffn"),
 }
 
 

@@ -146,7 +146,7 @@ def layer_rows(args):
         router = draw(keys[3], (D, E), D)
 
         def layer(x, xe, w1, w1v, w2, router):
-            got, _aux, routed, took = moe_ops._experts(
+            got, _aux, routed, took, _most = moe_ops._experts(
                 x, w1, w1v, None, w2, None, router, E, k, None, act, True,
                 0.0, {"score": "sigmoid"}, (0, held), xe)
             return got, routed, jnp.int32(-1) if took is None else took
