@@ -22,15 +22,23 @@ Two forms of the one function, as ``gmm_composed`` stands beside
   visibility mask, softmax, the weighted sum. It reads the slab twice
   and writes a ``[B, H, S]`` score tensor; what the CPU runs and what
   the tests compare the kernel with.
-* ``mla_decode_pallas`` — one call a layer a step. The grid is (slots,
-  row blocks); positions are scalar-prefetched and the block index is
-  held at the block of ``pos[b]`` once past it, so the blocks a slot has
-  not reached are neither fetched nor computed. Each block is read ONCE,
+* ``mla_decode_pallas`` — one call a layer a step. The grid is ONE
+  axis over the (slot, block) pairs that hold a visible row and nothing
+  else (``work_list``): slot ``b`` has ``pos[b] // bs + 1`` of them,
+  visited in ascending order, and the grid's bound is their traced
+  count. Positions and the two tables are scalar-prefetched; every index
+  map reads them. So consecutive steps always carry a new block, the
+  pipeline's prefetch of a slot's first block runs under the last block
+  of the slot before, and a step that would move and compute nothing
+  (0.14-0.42 us each on a v5e, two thirds of a ``slots x max_len / bs``
+  grid under the cells' traffic: docs/KERNELS.md has the timings) does
+  not exist. Each block is read ONCE,
   rounded to bfloat16 for the MXU (as every float32 product of a
   compiled step is at the TPU's default precision), used as key over its
   whole width and as value over its first ``d_c`` lanes for all ``H``
   heads, under an online softmax (float32 running max, denominator and
-  accumulator). The block follows the layout the TPU gives the slab
+  accumulator: reset at a slot's block 0, divided out at its last). The
+  block follows the layout the TPU gives the slab
   (``kv_cache_write._s_minor``): a row of 576 values is 4.5 lane tiles,
   so the TPU stores the slab ``S``-minor, ``[W, S]`` in (8, 128) tiles
   with nothing padded, and the kernel works on the ``[B, W, S]`` view (a
@@ -42,7 +50,10 @@ Two forms of the one function, as ``gmm_composed`` stands beside
 ``mla_decode`` chooses: the kernel where Pallas compiles
 (``use_interpret()`` is false: a TPU) and a block plan exists, the
 composed form elsewhere; ``paddle_mla_attention_plans_total`` counts
-which form each lowering took.
+which form each lowering took (block ``"512 live"``: the rows of a step
+and that only live pairs are walked), and the engine counts what the
+walk saves a step in ``paddle_mla_decode_blocks_total``
+(``blocks_of``).
 """
 
 from __future__ import annotations
@@ -56,7 +67,7 @@ from .common import checked_pallas_call, use_interpret
 from .kv_cache_write import _s_minor
 
 __all__ = ["mla_decode", "mla_decode_composed", "mla_decode_pallas",
-           "decode_plan", "KERNEL"]
+           "decode_plan", "work_list", "blocks_of", "KERNEL"]
 
 # the name the device trace and the HLO show the call under
 KERNEL = "mla_decode"
@@ -98,12 +109,36 @@ def decode_plan(shape, dtype, H):
     return None
 
 
-def _kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *, bs,
-            nblk, d_c, scale, s_minor):
+def work_list(pos, bs, nblk):
+    """The (slot, block) pairs that hold a visible row, slot by slot and
+    a slot's blocks ascending: ``slot_of [B * nblk]``, ``blk_of
+    [B * nblk]`` and how many of them count, ``total [1]``. Slot ``b``
+    has ``pos[b] // bs + 1`` pairs (a free slot stands at 0 and keeps
+    its one block); entries past ``total`` repeat the last pair."""
+    B = pos.shape[0]
+    nb = pos // bs + 1
+    ends = jnp.cumsum(nb)
+    t = jnp.minimum(jnp.arange(B * nblk, dtype=jnp.int32), ends[-1] - 1)
+    # the slots that end at or before t: B compares an entry, no loop
+    slot_of = jnp.sum(t[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    blk_of = t - (ends - nb)[slot_of]
+    return slot_of, blk_of, ends[-1:]
+
+
+def blocks_of(pos, bs, max_len):
+    """(live, grid) of one call over host positions ``pos`` (every slot
+    of the lane, a free one at 0): the pairs ``work_list`` walks and the
+    ``slots x max_len / bs`` steps of a grid over whole slabs."""
+    return (int((pos // bs + 1).sum()), int(pos.size) * (max_len // bs))
+
+
+def _kernel(pos_ref, slot_ref, blk_ref, q_ref, c_ref, o_ref, acc_ref, m_ref,
+            l_ref, *, bs, d_c, scale, s_minor):
     from jax.experimental import pallas as pl
 
-    b, j = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[b]
+    t = pl.program_id(0)
+    j = blk_ref[t]
+    pos = pos_ref[slot_ref[t]]
 
     @pl.when(j == 0)
     def _init():
@@ -111,34 +146,31 @@ def _kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *, bs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j * bs <= pos)
-    def _block():
-        # [W, bs] under the S-minor view, [bs, W] else: ``seq`` is the
-        # axis of the block that counts positions
-        rows = c_ref[0].astype(jnp.bfloat16)
-        seq = 1 if s_minor else 0
-        q = q_ref[0].astype(jnp.bfloat16)                  # [H, W]
-        s = jax.lax.dot_general(
-            q, rows, (((1,), (1 - seq,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32) * scale    # [H, bs]
-        at = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(at <= pos, s, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                             # [H, bs] f32
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(jnp.bfloat16),
-            rows[:d_c] if s_minor else rows[:, :d_c],
-            (((1,), (seq,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)            # [H, d_c]
+    # [W, bs] under the S-minor view, [bs, W] else: ``seq`` is the axis
+    # of the block that counts positions
+    rows = c_ref[0].astype(jnp.bfloat16)
+    seq = 1 if s_minor else 0
+    q = q_ref[0].astype(jnp.bfloat16)                      # [H, W]
+    s = jax.lax.dot_general(
+        q, rows, (((1,), (1 - seq,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32) * scale        # [H, bs]
+    at = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(at <= pos, s, _NEG)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                                 # [H, bs] f32
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(jnp.bfloat16),
+        rows[:d_c] if s_minor else rows[:, :d_c],
+        (((1,), (seq,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)                # [H, d_c]
 
-    @pl.when(j == nblk - 1)
+    @pl.when(j == pos // bs)
     def _emit():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
@@ -157,35 +189,37 @@ def mla_decode_pallas(q, cache, pos, *, d_c, scale, interpret=None):
                          "%s" % (q.shape, cache.shape, cache.dtype))
     if interpret is None:
         interpret = use_interpret()
-    nblk = S // bs
     pos = jnp.clip(pos.reshape((-1,)).astype(jnp.int32), 0, S - 1)
+    slot_of, blk_of, total = work_list(pos, bs, S // bs)
 
     s_minor = _s_minor(S, W)
 
-    def rows_of(b, j, pos):
-        # past the slot's position the index stays where it was: a block
-        # index that does not move is not fetched again
-        at = jnp.minimum(j, pos[b] // bs)
-        return (b, 0, at) if s_minor else (b, at, 0)
+    def of_slot(t, pos, slot_of, blk_of):
+        return (slot_of[t], 0, 0)
+
+    def rows_of(t, pos, slot_of, blk_of):
+        return (slot_of[t], 0, blk_of[t]) if s_minor \
+            else (slot_of[t], blk_of[t], 0)
 
     seen = jnp.swapaxes(cache, 2, 3) if s_minor else cache
 
+    # the grid's one bound is traced: a step a pair of the work list
     return checked_pallas_call(
-        functools.partial(_kernel, bs=bs, nblk=nblk, d_c=int(d_c),
-                          scale=float(scale), s_minor=s_minor),
-        name=KERNEL, grid=(B, nblk),
-        in_specs=[pl.BlockSpec((1, H, W), lambda b, j, pos: (b, 0, 0)),
+        functools.partial(_kernel, bs=bs, d_c=int(d_c), scale=float(scale),
+                          s_minor=s_minor),
+        name=KERNEL, grid=(total[0],),
+        in_specs=[pl.BlockSpec((1, H, W), of_slot),
                   pl.BlockSpec((1, W, bs) if s_minor else (1, bs, W),
                                rows_of)],
         operands=(q, seen.reshape((B,) + seen.shape[2:])),
-        out_specs=pl.BlockSpec((1, H, int(d_c)), lambda b, j, pos: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, int(d_c)), of_slot),
         out_shape=jax.ShapeDtypeStruct((B, H, int(d_c)), jnp.float32),
         scratch_shapes=[pltpu.VMEM((H, int(d_c)), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32),
                         pltpu.VMEM((H, 1), jnp.float32)],
-        interpret=interpret, scalar_prefetch=(pos,),
+        interpret=interpret, scalar_prefetch=(pos, slot_of, blk_of),
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")))
+            dimension_semantics=("arbitrary",)))
 
 
 def _note_plan(form, block, widths):
@@ -207,6 +241,6 @@ def mla_decode(q, cache, pos, *, d_c, scale):
     if bs is None:
         _note_plan("composed", "-", widths)
         return mla_decode_composed(q, cache, pos, d_c=d_c, scale=scale)
-    _note_plan("pallas", bs, widths)
+    _note_plan("pallas", "%d live" % bs, widths)
     return mla_decode_pallas(q, cache, pos, d_c=d_c, scale=scale,
                              interpret=False)

@@ -1050,10 +1050,26 @@ MLA_ATTENTION_PLANS = REGISTRY.counter(
     "paddle_flash_block_plans_total's) or 'composed' (the training "
     "build), block '-'; 'absorbed' (the decode step's, scores and values "
     "read out of the latent row itself) counts a call of mla_decode at "
-    "LOWERING, kernel 'pallas' with the rows one grid step takes or "
-    "'composed' for jax.numpy. widths is [q/k]x[v] of the call ('192x128' "
-    "expanded, '576x512' absorbed at the published sizes)",
+    "LOWERING, kernel 'pallas' with the rows one grid step takes and the "
+    "walk ('512 live': only the (slot, block) pairs that hold a visible "
+    "row are grid steps) or 'composed' for jax.numpy. widths is [q/k]x[v] "
+    "of the call ('192x128' expanded, '576x512' absorbed at the published "
+    "sizes)",
     labels=("form", "kernel", "block", "widths"))
+
+MLA_DECODE_BLOCKS = REGISTRY.counter(
+    "paddle_mla_decode_blocks_total",
+    "Grid steps of the absorbed latent-attention kernel "
+    "(kernels/mla_decode.py), summed over the plain decode steps of a lane "
+    "whose cache is latent and over its latent layers: 'live' adds the "
+    "(slot, block) pairs that hold a visible row, pos // block + 1 a slot "
+    "over all b_max slots (a free slot keeps its one block): the steps the "
+    "kernel's work list walks; 'grid' b_max x max_len / block, the steps "
+    "of a grid over whole slabs. live / grid is the share of that grid "
+    "that held a block; host integers, counted at dispatch from the "
+    "block decode_plan gives (on the CPU too, where the composed form "
+    "runs)",
+    labels=("kind",))
 
 RESIDUAL_PLANS = REGISTRY.counter(
     "paddle_residual_plans_total",

@@ -241,16 +241,26 @@ class _Lane:
         convolution rows and a gated convolution's carried rows (no
         position axis), a latent layer's one tensor, rings (shorter than
         ``max_len``) and full slabs."""
+        from ..kernels.mla_decode import decode_plan
         from ..observe.families import SERVING_CACHE_BYTES
 
         held = {"ring": 0, "full": 0, "latent": 0, "state": 0}
+        blocks = []     # the absorbed kernel's block, a latent layer
         for n in self.cache_names:
             var = self._decode_prog.global_block().var(n)
             kind = self._gpt.cache_kind(self.cfg, n, self.max_len)
             held[kind] += int(np.prod(var.shape)) \
                 * np.dtype(var.dtype).itemsize
+            if kind == "latent":
+                blocks.append(decode_plan(var.shape, var.dtype,
+                                          self.cfg["n_head"]))
         for kind, nbytes in held.items():
             SERVING_CACHE_BYTES.labels(kind=kind).set(nbytes)
+        # (latent layers, rows of a block) for
+        # paddle_mla_decode_blocks_total: the slabs are of one shape; None
+        # where no cache is latent or the kernel has no plan for it
+        self.latent_walk = (len(blocks), blocks[0]) \
+            if blocks and blocks[0] is not None else None
 
     def _note_weight_bytes(self) -> None:
         """``paddle_serving_weight_bytes{dtype}``: the decode program's
@@ -1257,7 +1267,9 @@ class DecodeEngine:
         way there an iteration only reads (nothing goes out before the
         ids the host now needs are known), as does the last one of a
         burst."""
-        from ..observe.families import (SERVING_DECODE_STEPS,
+        from ..kernels.mla_decode import blocks_of
+        from ..observe.families import (MLA_DECODE_BLOCKS,
+                                        SERVING_DECODE_STEPS,
                                         SERVING_FETCHES,
                                         SERVING_OCCUPANCY,
                                         SERVING_POSITIONS,
@@ -1301,6 +1313,11 @@ class DecodeEngine:
                     int(pos.sum()) + len(riders))
                 SERVING_POSITIONS.labels(kind="held").inc(
                     self.b_max * self.max_len)
+                if self._lane.latent_walk is not None:
+                    layers, bs = self._lane.latent_walk
+                    live, grid = blocks_of(pos, bs, self.max_len)
+                    MLA_DECODE_BLOCKS.labels(kind="live").inc(layers * live)
+                    MLA_DECODE_BLOCKS.labels(kind="grid").inc(layers * grid)
                 if advance_draft and self._draft is not None:
                     # keep the draft lane's caches mirror-aligned through
                     # plain iterations: a skipped position would leave a

@@ -269,6 +269,37 @@ def _lower_step(main, feeds, fetch, dev, rng=False):
     return lowered, mut_state
 
 
+def _work_list_sources(text, kernel):
+    """What computed the grid bound and the two tables (operands 0, 2 and
+    3, before them ``pos``) of every ``kernel`` call in a compiled step's
+    HLO, each followed back through the copies XLA hands a later call:
+    ``[{names of the bound's sources}, {slot_of's}, {blk_of's}]``. One
+    name a set = the work list is computed once a step, not once a
+    layer."""
+    made = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"^\s*%(\S+) = .*? ([a-z\-]+)\(%([^,)\s]+)", text, re.M)}
+
+    def source(name):
+        while made.get(name, ("", ""))[0] in ("copy", "copy-start",
+                                              "copy-done", "bitcast"):
+            name = made[name][1]
+        return name
+
+    calls = re.findall(r"%%%s[.\d]* = \S+ custom-call\(([^)]*)\)" % kernel,
+                       text)
+    operands = [[re.sub(r"/\*.*?\*/", "", o).strip().lstrip("%")
+                 for o in c.split(",")] for c in calls]
+    assert operands and all(len(o) == 6 for o in operands), operands
+    return [{source(o[i]) for o in operands} for i in (0, 2, 3)]
+
+
+def _slab_relaid(text, B, S, W=576):
+    """A copy, transpose or fusion that writes a whole ``[B, S, W]`` latent
+    slab or its S-minor view in a compiled step's HLO, or None."""
+    return re.search(r"= \S+\[%d,(%d,%d|%d,%d)\]\S* "
+                     r"(copy|transpose|fusion)\(" % (B, W, S, S, W), text)
+
+
 def test_gpt2_medium_serving_decode_step_writes_its_cache_in_place(
         v5e, compiled_kernels):
     """The whole ``gpt2-medium`` serving decode step (32 slots, 1,024
@@ -450,7 +481,8 @@ def test_pangu_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
                for n in caches)
     write = KV_CACHE_WRITE_PLANS.labels(form="pallas", rows="1")
     absorbed = MLA_ATTENTION_PLANS.labels(form="absorbed", kernel="pallas",
-                                          block="512", widths="576x512")
+                                          block="512 live",
+                                          widths="576x512")
     before = write.value, absorbed.value
     lowered, mut = _lower_step(
         main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
@@ -461,14 +493,16 @@ def test_pangu_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
     assert (write.value - before[0], absorbed.value - before[1]) == (5, 5)
     assert text.count('custom_call_target="tpu_custom_call"') == 5 + 5 + 8
     assert len(re.findall(r"%s[.\d]* = " % mla_decode.KERNEL, text)) == 5
+    # the five calls walk ONE work list: the count of live (slot, block)
+    # pairs (the grid's traced bound) and both tables computed once
+    assert [len(s) for s in _work_list_sources(
+        text, mla_decode.KERNEL)] == [1, 1, 1]
     assert len(re.findall(r"%s[.\d]* = " % kvw.KERNEL, text)) == 5
     assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
     # neither the slab nor its S-minor view is copied or relaid
     assert _cache_sized(text, (B, 1, S, 576)) == []
     assert _cache_sized(text, (B, 1, 576, S)) == []
-    relaid = re.compile(r"= \S+\[%d,(576,%d|%d,576)\]\S* "
-                        r"(copy|transpose|fusion)\(" % (B, S, S))
-    assert not relaid.search(text)
+    assert not _slab_relaid(text, B, S)
     # no float32 copy of a stored matrix (the largest: an expert stack)
     assert not re.search(r"f32\[8,7680,2048\][^ ]* (copy|convert)\(", text)
     assert not re.search(r"f32\[7680,18432\][^ ]* (copy|convert)\(", text)

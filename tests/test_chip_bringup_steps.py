@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from test_chip_bringup import (BF16, F32, ROOT, _cache_sized,  # noqa: F401
-                               _compile, _lower_step, compiled_kernels, v5e,
+                               _compile, _lower_step, _slab_relaid,
+                               _work_list_sources, compiled_kernels, v5e,
                                v5e_topology)
 
 
@@ -235,6 +236,11 @@ def test_xing_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
                     (mla_decode.KERNEL, 5)):
         assert len(set(re.findall(r"%%(%s[.\d]*) = " % name, text))) == n, \
             name
+    # 33 blocks of 256 rows a slot: one work list for the five calls, and
+    # no relayout of a slab or of its S-minor view round them
+    assert [len(s) for s in _work_list_sources(
+        text, mla_decode.KERNEL)] == [1, 1, 1]
+    assert not _slab_relaid(text, B, S)
     mem = compiled.memory_analysis()
     assert 11.1e9 < mem.argument_size_in_bytes < 11.4e9, mem
     assert mem.temp_size_in_bytes < 1.0e9, mem
@@ -518,6 +524,10 @@ def test_longcat_serving_decode_step_compiles_for_v5e(v5e,
     text = compiled.as_text()
     assert len(set(re.findall(r"%%(%s[.\d]*) = " % mla_decode.KERNEL,
                               text))) == 8
+    # one work list for the eight calls, no relayout of a slab
+    assert [len(s) for s in _work_list_sources(
+        text, mla_decode.KERNEL)] == [1, 1, 1]
+    assert not _slab_relaid(text, B, S)
     assert moe_gmm.KERNEL_UP in text and moe_gmm.KERNEL_DOWN in text
     # no dense FFN matrix nor an expert stack is widened whole
     assert not re.search(r"f32\[6144,12288\][^ ]* (copy|convert)\(", text)

@@ -402,10 +402,18 @@ def test_weight_and_cache_bytes_are_counted_by_dtype_and_kind():
 # --------------------------------------------------------- the kernels
 MLA_DECODE_CASES = [
     # B, H, S, d_c, d_r, positions
-    (3, 8, 256, 128, 64, (0, 130, 255)),          # rows layout, 2 blocks
+    (3, 8, 256, 128, 64, (0, 130, 255)),          # rows layout, one block
     (2, 16, 512, 64, 8, (5, 511)),                # S-minor layout
     (2, 8, 1024, 512, 64, (300, 1023)),           # the published widths
     (4, 8, 128, 32, 8, (0, 1, 64, 127)),          # one block
+    # the work list's edges (PR 48), three blocks of 128 unless said:
+    (3, 8, 384, 128, 64, (127, 128, 383)),        # bs - 1, bs, S - 1
+    (4, 8, 384, 64, 8, (0, 0, 0, 0)),             # total == B
+    (3, 8, 384, 64, 8, (383, 383, 383)),          # total == B x nblk
+    (3, 8, 640, 128, 64, (600, 0, 639)),          # a free slot between
+    (2, 8, 384, 512, 64, (200, 383)),             # Xing's odd count, scaled
+    (3, 8, 1536, 512, 64, (511, 512, 1100)),      # 512-row blocks' edges
+    (5, 16, 768, 64, 8, (255, 767, 0, 256, 511)),  # 256-row blocks, S-minor
 ]
 
 
@@ -436,6 +444,94 @@ def test_mla_decode_kernel_matches_composed(B, H, S, dc, dr, at):
                                    atol=1e-5, rtol=0)
 
 
+WORK_LIST_CASES = [
+    # bs, nblk, positions
+    (128, 3, (127, 128, 383)),
+    (128, 3, (0, 0, 0, 0)),
+    (128, 3, (383, 383, 383)),
+    (128, 5, (600, 0, 639)),
+    (256, 33, (8447, 0, 3000, 255, 256)),
+    (512, 8, (1190,) * 7 + (4095,)),
+    (128, 1, (0, 5, 127)),
+]
+
+
+@pytest.mark.parametrize("bs,nblk,at", WORK_LIST_CASES)
+def test_work_list_is_the_plain_enumeration_of_live_blocks(bs, nblk, at):
+    """Slot by slot, a slot's blocks ascending, nothing else counted; the
+    entries past the count stay inside the tables' range."""
+    from paddle_tpu.kernels import mla_decode as K
+
+    slot_of, blk_of, total = K.work_list(jnp.asarray(at, jnp.int32), bs,
+                                         nblk)
+    want = [(b, j) for b, p in enumerate(at) for j in range(p // bs + 1)]
+    assert total.shape == (1,) and int(total[0]) == len(want)
+    assert slot_of.shape == blk_of.shape == (len(at) * nblk,)
+    got = list(zip(np.asarray(slot_of).tolist(), np.asarray(blk_of).tolist()))
+    assert got[:len(want)] == want
+    assert set(got[len(want):]) <= {want[-1]}
+    live, grid = K.blocks_of(np.asarray(at).reshape((-1, 1)), bs, bs * nblk)
+    assert (live, grid) == (len(want), len(at) * nblk)
+
+
+def test_mla_decode_sweep_rehearses_on_the_cpu(tmp_path):
+    """``tools/mla_decode_sweep.py`` (the tool behind docs/KERNELS.md's
+    table) runs its cases at a tiny size in interpret mode, writes no
+    time and leaves the kernel's block choices as they were."""
+    import json
+    import sys
+
+    from paddle_tpu.kernels import mla_decode as K
+
+    sys.path.insert(0, os.path.join(HERE, os.pardir, "tools"))
+    try:
+        import mla_decode_sweep
+    finally:
+        sys.path.pop(0)
+    out = tmp_path / "sweep.json"
+    choices = K._BLOCK_CHOICES
+    assert mla_decode_sweep.main(["--rehearse", "--only", "xing", "--draws",
+                                  "1", "--out", str(out)]) == 0
+    assert K._BLOCK_CHOICES == choices
+    rows = json.loads(out.read_text())["rows"]
+    assert [(r["positions"], r["blocks_live"], r["blocks_grid"])
+            for r in rows[1:]] == [("all_0", 4, 12), ("all_full", 12, 12)]
+    assert rows[0]["positions"] == "traffic" and 4 <= rows[0]["blocks_live"]
+    assert all("device_ms" not in r and r["max_abs_diff_vs_composed"] < 2e-2
+               for r in rows)
+
+
+def test_engine_counts_the_blocks_its_latent_steps_walk():
+    """``paddle_mla_decode_blocks_total``: a step adds, for every latent
+    layer, the (slot, block) pairs its positions hold (a free slot one)
+    and the grid of whole slabs; a lane whose slab has no plan counts
+    nothing."""
+    from paddle_tpu.observe import REGISTRY
+
+    def blocks():
+        got = REGISTRY.snapshot()["metrics"].get(
+            "paddle_mla_decode_blocks_total", {"samples": []})
+        return {s["labels"]["kind"]: s["value"] for s in got["samples"]}
+
+    cfg = tiny_cfg(n_head=8, n_layer=2)
+    eng = _engine(cfg, seeded_params(cfg, 53), 3, max_len=384).start()
+    assert eng._lane.latent_walk == (2, 128)
+    before = blocks()
+    try:
+        eng.submit(np.arange(1, 127), 5).result(timeout=300)
+    finally:
+        eng.stop()
+    after = blocks()
+    # alone in the engine: 4 steps at positions 126 .. 129 (the first
+    # token is the prefill's), two of them in the second block; the two
+    # free slots keep a block each; two latent layers
+    assert after["live"] - before.get("live", 0) == 2 * (3 + 3 + 4 + 4)
+    assert after["grid"] - before.get("grid", 0) == 2 * 4 * 3 * 3
+    # four heads: the kernel has no plan, the lane no walk
+    assert _engine(tiny_cfg(), None, 2, max_len=384)._lane.latent_walk \
+        is None
+
+
 def test_mla_decode_plan_and_counter():
     from paddle_tpu.kernels import mla_decode as K
     from paddle_tpu.kernels.kv_cache_write import _s_minor, write_plan
@@ -454,6 +550,26 @@ def test_mla_decode_plan_and_counter():
     before = c.value
     K.mla_decode(jnp.zeros((1, 4, 40)), jnp.zeros((1, 1, 16, 40)),
                  jnp.zeros((1,), jnp.int32), d_c=32, scale=1.0)
+    assert c.value == before + 1
+
+
+def test_the_plan_label_carries_the_walk(monkeypatch):
+    """Where the kernel is lowered the ``block`` label says the rows of a
+    step AND that only live blocks are walked (``"128 live"``)."""
+    from paddle_tpu.kernels import mla_decode as K
+    from paddle_tpu.observe.families import MLA_ATTENTION_PLANS
+
+    monkeypatch.setattr(K, "use_interpret", lambda: False)
+    taken = {}
+    monkeypatch.setattr(
+        K, "mla_decode_pallas",
+        lambda q, cache, pos, **kw: taken.update(kw) or "lowered")
+    c = MLA_ATTENTION_PLANS.labels(form="absorbed", kernel="pallas",
+                                   block="128 live", widths="40x32")
+    before = c.value
+    got = K.mla_decode(jnp.zeros((2, 8, 40)), jnp.zeros((2, 1, 384, 40)),
+                       jnp.zeros((2,), jnp.int32), d_c=32, scale=1.0)
+    assert got == "lowered" and taken["interpret"] is False
     assert c.value == before + 1
 
 
