@@ -112,10 +112,20 @@ def append_backward(
         contribs[name] = [gname]
         return gname
 
+    def scoped(n_before: int, scope: str) -> None:
+        # what was appended since ``n_before`` stands where its forward
+        # op was built (the reference copies op_namescope the same way):
+        # the grad op, and the sum or assign of the output's gradient
+        for made in block.ops[n_before:]:
+            made.name_scope = scope
+
+    # the seed above stands with the op that made the loss
+    scoped(len(block.ops) - 1, path_ops[-1].name_scope if path_ops else "")
     for op in reversed(path_ops):
         opdef = get_op(op.type)
         if opdef.no_grad:
             continue
+        n_before = len(block.ops)
 
         # pick differentiable inputs
         diff: List[Tuple[str, int]] = []
@@ -178,6 +188,7 @@ def append_backward(
         attrs[ATTR_DIFF] = [list(d) for d in diff]
         attrs["__op_role__"] = "backward"
         block.append_op(op.type + "_grad", grad_inputs, grad_outputs, attrs)
+        scoped(n_before, op.name_scope)
 
     params = (
         [block.var(p) if isinstance(p, str) else p for p in parameter_list]
@@ -188,9 +199,15 @@ def append_backward(
     for p in params:
         if not p.trainable or p.name in no_grad:
             continue
+        n_before = len(block.ops)
         g = materialize(p.name)
         if g is not None:
             result.append((p, block.var(g)))
+        if len(block.ops) > n_before:
+            # the sum over a shared parameter's readers: the first's scope
+            scoped(n_before, next(
+                (o.name_scope for o in path_ops
+                 if p.name in o.input_names()), ""))
     program._bump()
     return result
 

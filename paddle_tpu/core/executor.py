@@ -149,7 +149,8 @@ class Executor:
         t0 = time.perf_counter()
         with _dispatch_guard(plan, "run",
                              (feeds, const_state, mut_state, rng),
-                             scope, self.place) as (loads, args):
+                             scope, self.place, plan.fn,
+                             "optimized") as (loads, args):
             fetches, new_mut, new_pure, new_rng = plan.fn(*args)
         steady = _record_dispatch(plan, "run", "run", 1,
                                   time.perf_counter() - t0, loads)
@@ -262,7 +263,7 @@ class Executor:
         t0 = time.perf_counter()
         with _dispatch_guard(plan, sig,
                              (feeds, const_state, mut_state, rng),
-                             scope, self.place) as (loads, args):
+                             scope, self.place, fn) as (loads, args):
             fetches, new_mut, new_pure, new_rng = fn(*args)
         steady = _record_dispatch(plan, sig, "run_repeated",
                                   steps, time.perf_counter() - t0, loads)
@@ -469,8 +470,8 @@ class Executor:
                 t0 = time.perf_counter()
                 with _dispatch_guard(plan, "run",
                                      (feed_list, const_state, mut_state,
-                                      rng), scope,
-                                     self.place) as (loads, args):
+                                      rng), scope, self.place, plan.fn,
+                                     "optimized") as (loads, args):
                     fetches, new_mut, new_pure, new_rng = plan.fn(*args)
                 # sig "run": same executable as run(), so a run()
                 # warmup already paid this signature's compile
@@ -517,8 +518,8 @@ class Executor:
                 t0 = time.perf_counter()
                 with _dispatch_guard(plan, sig,
                                      (feed_list, const_state, mut_state,
-                                      rng), scope,
-                                     self.place) as (loads, args):
+                                      rng), scope, self.place,
+                                     fn) as (loads, args):
                     fetches, new_mut, new_pure, new_rng = fn(*args)
                 dt = time.perf_counter() - t0
                 steady = _record_dispatch(plan, sig, "run_pipelined",
@@ -758,11 +759,15 @@ class Executor:
         scope = scope if scope is not None else global_scope()
         plan, feeds, const_state, mut_state, rng = self._gather(
             program, feed, fetch_list, scope)
+        args = (feeds, const_state, mut_state, rng)
+        if stage == "optimized":
+            from ..observe import device_names
+
+            # the plan's name table reads the same text from the same slot
+            return device_names.optimized_text(
+                plan, stage, lambda: plan.fn.lower(*args))
         if stage not in plan.hlo_text:
-            lowered = plan.fn.lower(feeds, const_state, mut_state, rng)
-            plan.hlo_text[stage] = (
-                lowered.as_text() if stage == "stablehlo"
-                else lowered.compile().as_text())
+            plan.hlo_text[stage] = plan.fn.lower(*args).as_text()
         return plan.hlo_text[stage]
 
     def _gather(self, program, feed, fetch_list, scope):
@@ -1024,7 +1029,8 @@ def _wait_guard(step=None):
 
 
 @contextlib.contextmanager
-def _dispatch_guard(plan, sig, args=(), scope=None, place=None):
+def _dispatch_guard(plan, sig, args=(), scope=None, place=None, fn=None,
+                    hlo_key=None, within=None):
     """Resilience wrapper around ONE XLA dispatch, shared by run()/
     run_repeated()/run_pipelined(): stamps the process heartbeat (with
     ``compiling=True`` for a plan's first dispatch per signature, so
@@ -1047,7 +1053,14 @@ def _dispatch_guard(plan, sig, args=(), scope=None, place=None):
     executor with a ``place``, whose loose state arrays come back
     committed to it (``_commit_loose``; the span then says how many:
     ``committed``). They are looked at only then and when a backend
-    stage ran (``_note_load``)."""
+    stage ran (``_note_load``).
+
+    ``fn`` is the jitted function the caller is about to call with
+    ``args``: a signature's first dispatch (with tracing on) notes the
+    two, abstractly, as the way to the plan's name table
+    (``observe/device_names.py``: ``hlo_key`` the slot of
+    ``plan.hlo_text``, ``within`` the mesh to lower in). Nothing is
+    lowered for it here, and a steady dispatch runs none of it."""
     hb = heartbeat()
     first = sig not in plan.compiled_sigs
     tok = hb.begin("executor.dispatch", compiling=first)
@@ -1062,6 +1075,11 @@ def _dispatch_guard(plan, sig, args=(), scope=None, place=None):
             args, n = _commit_loose(plan, scope, args, place.jax_device())
             if n and sp is not None:
                 sp.attrs["committed"] = n
+        if first and sp is not None and fn is not None:
+            from ..observe import device_names
+
+            device_names.register(plan, sig, fn, args,
+                                  hlo_key or ("optimized", sig), within)
         fault_point("executor.dispatch")
         yield loads, args
     finally:

@@ -10,6 +10,7 @@ transform and the garbage collector all disappear into the compiler.
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 from .program import Block
 from .registry import get_op
 
-__all__ = ["LowerContext", "lower_block"]
+__all__ = ["LowerContext", "lower_block", "op_scope"]
 
 
 class LowerContext:
@@ -69,7 +70,29 @@ class LowerContext:
                             self.seq_axis)
 
 
+_NOT_IN_A_SCOPE = re.compile(r"[^\w./-]+")
+
+
+def op_scope(op) -> str:
+    """``<op.name_scope>/<op.type>``, just ``<op.type>`` for an op built
+    under no ``name_scope``: the ``jax.named_scope`` its lowering runs
+    under, so every instruction XLA makes of it says in its ``op_name``
+    which Program op and which part of the model it came from
+    (``observe/device_names.py`` reads it back). A pass-made op's
+    ``fused:a,b`` (core/ir.py) stands under the first scope it replaced."""
+    scope = getattr(op, "name_scope", "") or ""
+    if scope.startswith("fused:"):
+        scope = scope[len("fused:"):].split(",")[0]
+    scope = _NOT_IN_A_SCOPE.sub("_", scope).strip("/")
+    return "%s/%s" % (scope, op.type) if scope else op.type
+
+
 def lower_op(ctx: LowerContext, op, env: Dict[str, Any]) -> None:
+    with jax.named_scope(op_scope(op)):   # metadata only: no instruction
+        _lower_op(ctx, op, env)
+
+
+def _lower_op(ctx: LowerContext, op, env: Dict[str, Any]) -> None:
     opdef = get_op(op.type)
     ins: Dict[str, List[Any]] = {}
     for slot, names in op.inputs.items():
@@ -106,8 +129,10 @@ def lower_ops(ctx: LowerContext, ops, env: Dict[str, Any]) -> None:
             lower_op(ctx, op, env)
         except Exception as e:
             raise RuntimeError(
-                "while lowering op %r (inputs=%s outputs=%s): %s: %s"
-                % (op.type, op.inputs, op.outputs, type(e).__name__, e)
+                "while lowering op %r in name_scope %r (inputs=%s outputs=%s)"
+                ": %s: %s"
+                % (op.type, getattr(op, "name_scope", "") or "", op.inputs,
+                   op.outputs, type(e).__name__, e)
             ) from e
 
 

@@ -33,6 +33,7 @@ __all__ = [
     "default_startup_program",
     "program_guard",
     "unique_name",
+    "SCOPE_CLASSES",
     "grad_var_name",
     "switch_main_program",
     "switch_startup_program",
@@ -87,6 +88,19 @@ def name_scope(prefix=None):
         yield
     finally:
         stack.pop()
+
+
+# The classes of model sub-block a ``name_scope`` may declare, for every
+# layer that builds, lowers or reads one (models/, ops/moe_ops.py's
+# ``moe.router``, layers/parallel_ext.py's ``moe.shared``,
+# observe/device_names.py): an op built under ``L3/attn.core`` is layer
+# 3's attention core, and the class of a scope path is the LAST of its
+# components that is one of these, so a lowering may refine its op's
+# class (``moe.router`` inside the ``moe_ffn`` op under ``moe.experts``).
+# core/lowering.py::op_scope carries the path to the device's operations.
+SCOPE_CLASSES = ("embed", "attn.qkv", "attn.core", "attn.out", "ffn",
+                 "moe.router", "moe.experts", "moe.shared", "mixer", "conv",
+                 "mhc", "norm", "head", "loss", "opt")
 
 
 def current_name_scope() -> str:

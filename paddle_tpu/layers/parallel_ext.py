@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from typing import Callable, List, Optional
 
-from ..core.program import Variable, unique_name
+from ..core.program import Variable, name_scope, unique_name
 from ..initializer import Constant, Xavier
 from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
@@ -346,9 +346,14 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
                           bias_attr=False, param_attr=attr(part), **kw)
 
         wide = int(n_shared_expert) * int(d_hidden)
-        hid = _nn.elementwise_mul(fc(x, wide, "shared_gate", act="swish"),
-                                  fc(x, wide, "shared_up"))
-        out = _nn.elementwise_add(out, fc(hid, D, "shared_down"))
+        # the always-on experts answer for their device time apart from
+        # the routed ones (core/lowering.py::op_scope; the last class of
+        # core/program.py::SCOPE_CLASSES in a scope path is its class)
+        with name_scope("moe.shared"):
+            hid = _nn.elementwise_mul(
+                fc(x, wide, "shared_gate", act="swish"),
+                fc(x, wide, "shared_up"))
+            out = _nn.elementwise_add(out, fc(hid, D, "shared_down"))
     prog = helper.main_program
     ep = getattr(prog, "_expert_params", None)
     if ep is None:

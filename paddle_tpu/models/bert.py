@@ -9,6 +9,7 @@ gather-based MLM head over statically-shaped masked positions.
 """
 
 from .. import layers
+from ..core.program import name_scope
 from ..param_attr import ParamAttr
 from .transformer import encoder
 
@@ -59,30 +60,38 @@ def build(cfg=None, seq_len=128, max_mask=20, is_test=False,
     mask_label = layers.data("mask_label", [max_mask], dtype="int64")
     mask_weight = layers.data("mask_weight", [max_mask], dtype="float32")
 
-    # [B,S] 0/1 -> [B,1,1,S] additive bias
-    neg = layers.scale(input_mask, scale=1e9, bias=-1e9)  # 1->0, 0->-1e9
-    attn_bias = layers.unsqueeze(layers.unsqueeze(neg, [1]), [1])
+    # every op stands under a name_scope its device operations answer to
+    # (docs/OBSERVABILITY.md "Which part of the model an operation is")
+    with name_scope("attn.core"):
+        # [B,S] 0/1 -> [B,1,1,S] additive bias
+        neg = layers.scale(input_mask, scale=1e9, bias=-1e9)  # 1->0, 0->-1e9
+        attn_bias = layers.unsqueeze(layers.unsqueeze(neg, [1]), [1])
 
-    emb = _bert_embed(src_ids, sent_ids, cfg, seq_len, is_test)
+    with name_scope("embed"):
+        emb = _bert_embed(src_ids, sent_ids, cfg, seq_len, is_test)
     enc = encoder(emb, attn_bias, cfg, is_test, use_fused_attention,
                   checkpoints=checkpoints)
 
-    # MLM head: gather masked positions from the flattened sequence
-    flat = layers.reshape(enc, [-1, cfg["d_model"]])          # [B*S, D]
-    picked = layers.gather(flat, layers.reshape(mask_pos, [-1]))  # [B*M, D]
-    h = layers.fc(picked, cfg["d_model"], act="gelu",
-                  param_attr=ParamAttr(name="mlm_trans.w_0"))
-    h = layers.layer_norm(h, begin_norm_axis=1,
-                          param_attr=ParamAttr(name="mlm_ln_s"),
-                          bias_attr=ParamAttr(name="mlm_ln_b"))
-    logits = layers.fc(h, cfg["vocab"],
-                       param_attr=ParamAttr(name="mlm_out.w_0"))
-    cost = layers.softmax_with_cross_entropy(
-        logits, layers.reshape(mask_label, [-1, 1]))           # [B*M, 1]
-    w = layers.reshape(mask_weight, [-1, 1])
-    loss = layers.elementwise_div(
-        layers.reduce_sum(layers.elementwise_mul(cost, w)),
-        layers.elementwise_add(layers.reduce_sum(w),
-                               layers.fill_constant([1], "float32", 1e-6)))
+    with name_scope("head"):
+        # MLM head: gather masked positions from the flattened sequence
+        flat = layers.reshape(enc, [-1, cfg["d_model"]])          # [B*S, D]
+        picked = layers.gather(flat,
+                               layers.reshape(mask_pos, [-1]))  # [B*M, D]
+        h = layers.fc(picked, cfg["d_model"], act="gelu",
+                      param_attr=ParamAttr(name="mlm_trans.w_0"))
+        h = layers.layer_norm(h, begin_norm_axis=1,
+                              param_attr=ParamAttr(name="mlm_ln_s"),
+                              bias_attr=ParamAttr(name="mlm_ln_b"))
+        logits = layers.fc(h, cfg["vocab"],
+                           param_attr=ParamAttr(name="mlm_out.w_0"))
+    with name_scope("loss"):
+        cost = layers.softmax_with_cross_entropy(
+            logits, layers.reshape(mask_label, [-1, 1]))       # [B*M, 1]
+        w = layers.reshape(mask_weight, [-1, 1])
+        loss = layers.elementwise_div(
+            layers.reduce_sum(layers.elementwise_mul(cost, w)),
+            layers.elementwise_add(
+                layers.reduce_sum(w),
+                layers.fill_constant([1], "float32", 1e-6)))
     feeds = [src_ids, sent_ids, input_mask, mask_pos, mask_label, mask_weight]
     return loss, feeds

@@ -17,6 +17,7 @@ import functools
 import math
 
 from .. import layers
+from ..core.program import name_scope
 from ..layer_helper import stored_dtype
 from ..param_attr import ParamAttr
 from .transformer import (_causal_bias, _ffn, _pad_bias, _prenorm,
@@ -655,18 +656,19 @@ def _lm_head(cfg, x):
     the input embedding (logits = x @ word_emb^T — no gpt_out_proj
     parameter; gradients accumulate into the one table from both the
     lookup and the head), the standard LM weight-tying."""
-    if cfg.get("tie_embeddings"):
-        from ..core.program import default_main_program
+    with name_scope("head"):
+        if cfg.get("tie_embeddings"):
+            from ..core.program import default_main_program
 
-        emb = default_main_program().global_block().var("gpt_word_emb")
-        if emb.dtype != "float32":
-            # a table stored in cfg['weight_dtype'] widens where it
-            # multiplies, as ``fc`` widens its matrix
-            emb = layers.cast(emb, "float32")
-        return layers.matmul(x, emb, transpose_y=True)
-    return layers.fc(x, cfg["vocab"], num_flatten_dims=2,
-                     bias_attr=False,
-                     param_attr=ParamAttr(name="gpt_out_proj.w_0"))
+            emb = default_main_program().global_block().var("gpt_word_emb")
+            if emb.dtype != "float32":
+                # a table stored in cfg['weight_dtype'] widens where it
+                # multiplies, as ``fc`` widens its matrix
+                emb = layers.cast(emb, "float32")
+            return layers.matmul(x, emb, transpose_y=True)
+        return layers.fc(x, cfg["vocab"], num_flatten_dims=2,
+                         bias_attr=False,
+                         param_attr=ParamAttr(name="gpt_out_proj.w_0"))
 
 
 def _expose(var, name):
@@ -683,7 +685,8 @@ def _greedy_token(rows):
     ``rows`` [B, vocab]. The first maximum wins and a NaN counts as one,
     as in ``sample_token``'s ``np.argmax`` over the float64 cast (exact
     and monotone, so the same index, ties and all)."""
-    return _expose(layers.argmax(rows, axis=1), NEXT_TOKEN_VAR)
+    with name_scope("head"):
+        return _expose(layers.argmax(rows, axis=1), NEXT_TOKEN_VAR)
 
 
 def _rms_eps(cfg):
@@ -887,22 +890,40 @@ def _rotates(cfg, i):
         or layer_window(cfg, i) is not None
 
 
+def _layer_scope(cfg, i):
+    """The ``name_scope`` every op of layer ``i`` is built under: ``L<i>``,
+    and under cfg['shortcut_moe'] ``L<l>.<k>``, sub-layer ``k`` of the
+    published layer ``l`` as ``expert_rows`` counts them. The helpers
+    below add the sub-block's class (``attn.qkv``, ``attn.core``,
+    ``attn.out``, ``ffn``, ``moe.experts``, ``moe.shared``, ``mixer``,
+    ``conv``, ``mhc``, ``norm``; outside the layers ``embed``, ``head``,
+    ``loss``; ``core/program.py::SCOPE_CLASSES`` is the list): what a
+    device operation answers to
+    (``core/lowering.py::op_scope``, ``observe/device_names.py``). A
+    scope is no attr: op lists, parameter and cache names stay as they
+    were."""
+    if has_shortcut(cfg):
+        return name_scope("L%d.%d" % (i // 2, i % 2))
+    return name_scope("L%d" % i)
+
+
 def _embed(cfg, tokens, shape):
     """The token rows as ``shape`` (lookup_table squeezes a trailing-1 id
     dim, so the layout is restored explicitly), times cfg['emb_scale']."""
-    word = layers.embedding(tokens, [cfg["vocab"], cfg["d_model"]],
-                            param_attr=ParamAttr(name="gpt_word_emb"))
-    if word.dtype != "float32":
-        # a table stored in cfg['weight_dtype']: the row widens here
-        word = layers.cast(word, "float32")
-    word = layers.reshape(word, shape)
-    if cfg.get("emb_scale"):
-        word = layers.scale(word, scale=float(cfg["emb_scale"]))
-    if has_streams(cfg):
-        # every stream starts as the embedding row
-        word = layers.expand(word, [1] * (len(shape) - 1)
-                             + [int(cfg["hc_mult"])])
-    return word
+    with name_scope("embed"):
+        word = layers.embedding(tokens, [cfg["vocab"], cfg["d_model"]],
+                                param_attr=ParamAttr(name="gpt_word_emb"))
+        if word.dtype != "float32":
+            # a table stored in cfg['weight_dtype']: the row widens here
+            word = layers.cast(word, "float32")
+        word = layers.reshape(word, shape)
+        if cfg.get("emb_scale"):
+            word = layers.scale(word, scale=float(cfg["emb_scale"]))
+        if has_streams(cfg):
+            # every stream starts as the embedding row
+            word = layers.expand(word, [1] * (len(shape) - 1)
+                                 + [int(cfg["hc_mult"])])
+        return word
 
 
 def _qkv(cfg, h, nm):
@@ -911,16 +932,17 @@ def _qkv(cfg, h, nm):
     whole-vector q/k norm where the cfg asks for it."""
     n_kv, _g = _kv_heads_of(cfg)
     d_head = _d_head(cfg)
-    q = layers.fc(h, cfg["n_head"] * d_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=nm + "_att_q.w_0"))
-    k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=nm + "_att_k.w_0"))
-    v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=nm + "_att_v.w_0"))
-    q, k = _qk_norm(cfg, q, k, nm)
+    with name_scope("attn.qkv"):
+        q = layers.fc(h, cfg["n_head"] * d_head, num_flatten_dims=2,
+                      bias_attr=False,
+                      param_attr=ParamAttr(name=nm + "_att_q.w_0"))
+        k = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
+                      bias_attr=False,
+                      param_attr=ParamAttr(name=nm + "_att_k.w_0"))
+        v = layers.fc(h, n_kv * d_head, num_flatten_dims=2,
+                      bias_attr=False,
+                      param_attr=ParamAttr(name=nm + "_att_v.w_0"))
+        q, k = _qk_norm(cfg, q, k, nm)
     return q, k, v
 
 
@@ -939,14 +961,15 @@ def _attn_out(cfg, h, ctxv, nm):
     """The attention sub-block's tail on the merged heads ``ctxv
     [B, S, n_head * d_head]``: cfg['attn_gate'] multiplies by
     ``sigmoid(h Wg)``, then the output projection."""
-    if cfg.get("attn_gate"):
-        gate = layers.fc(h, cfg["n_head"] * _d_head(cfg),
-                         num_flatten_dims=2, bias_attr=False,
-                         param_attr=ParamAttr(name=nm + "_att_g.w_0"))
-        ctxv = layers.elementwise_mul(ctxv, layers.sigmoid(gate))
-    return layers.fc(ctxv, cfg["d_model"], num_flatten_dims=2,
-                     bias_attr=False,
-                     param_attr=ParamAttr(name=nm + "_att_o.w_0"))
+    with name_scope("attn.out"):
+        if cfg.get("attn_gate"):
+            gate = layers.fc(h, cfg["n_head"] * _d_head(cfg),
+                             num_flatten_dims=2, bias_attr=False,
+                             param_attr=ParamAttr(name=nm + "_att_g.w_0"))
+            ctxv = layers.elementwise_mul(ctxv, layers.sigmoid(gate))
+        return layers.fc(ctxv, cfg["d_model"], num_flatten_dims=2,
+                         bias_attr=False,
+                         param_attr=ParamAttr(name=nm + "_att_o.w_0"))
 
 
 def _fc(x, width, name):
@@ -960,25 +983,27 @@ def _mla_q(cfg, h, nm, S, pos):
     ([S], or per-slot [B, 1] where S is 1: the angles then broadcast
     over the head axis)."""
     n_head, dn, dr = cfg["n_head"], cfg["d_nope"], cfg["d_rope"]
-    h = layers.rms_norm(
-        _fc(h, cfg["q_lora_rank"], nm + "_att_qa.w_0"),
-        begin_norm_axis=2, epsilon=_rms_eps(cfg),
-        param_attr=ParamAttr(name=nm + "_att_qa_ln_s"))
-    q = _fc(h, n_head * (dn + dr), nm + "_att_qb.w_0")
-    if cfg.get("mla_scale_q_lora"):
-        # both parts of every head, before the rotation
-        q = layers.scale(
-            q, scale=(cfg["d_model"] / float(cfg["q_lora_rank"])) ** 0.5)
-    q = layers.reshape(q, [-1, S, n_head, dn + dr])
-    q_nope = layers.slice(q, axes=[3], starts=[0], ends=[dn])
-    q_rope = layers.slice(q, axes=[3], starts=[dn], ends=[dn + dr])
-    if S > 1:
-        # positions index the axis before the last: [B, H, S, d_rope]
-        q_rope = layers.transpose(
-            _rope(cfg, layers.transpose(q_rope, perm=[0, 2, 1, 3]), pos),
-            perm=[0, 2, 1, 3])
-    else:
-        q_rope = _rope(cfg, q_rope, pos)
+    with name_scope("attn.qkv"):
+        h = layers.rms_norm(
+            _fc(h, cfg["q_lora_rank"], nm + "_att_qa.w_0"),
+            begin_norm_axis=2, epsilon=_rms_eps(cfg),
+            param_attr=ParamAttr(name=nm + "_att_qa_ln_s"))
+        q = _fc(h, n_head * (dn + dr), nm + "_att_qb.w_0")
+        if cfg.get("mla_scale_q_lora"):
+            # both parts of every head, before the rotation
+            q = layers.scale(
+                q, scale=(cfg["d_model"] / float(cfg["q_lora_rank"])) ** 0.5)
+    with name_scope("attn.core"):
+        q = layers.reshape(q, [-1, S, n_head, dn + dr])
+        q_nope = layers.slice(q, axes=[3], starts=[0], ends=[dn])
+        q_rope = layers.slice(q, axes=[3], starts=[dn], ends=[dn + dr])
+        if S > 1:
+            # positions index the axis before the last: [B, H, S, d_rope]
+            q_rope = layers.transpose(
+                _rope(cfg, layers.transpose(q_rope, perm=[0, 2, 1, 3]), pos),
+                perm=[0, 2, 1, 3])
+        else:
+            q_rope = _rope(cfg, q_rope, pos)
     return q_nope, q_rope
 
 
@@ -987,18 +1012,21 @@ def _mla_row(cfg, h, nm, S, pos):
     ``[B, 1, S, d_c + d_rope]`` = the normed latent ``c`` beside the one
     rotated key part ``k_r`` all heads share."""
     dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
-    kv = _fc(h, dc + dr, nm + "_att_kva.w_0")
-    c = layers.rms_norm(
-        layers.slice(kv, axes=[2], starts=[0], ends=[dc]),
-        begin_norm_axis=2, epsilon=_rms_eps(cfg),
-        param_attr=ParamAttr(name=nm + "_att_kva_ln_s"))
-    if cfg.get("mla_scale_kv_lora"):
-        # the latent only: k_r is not scaled
-        c = layers.scale(c, scale=(cfg["d_model"] / float(dc)) ** 0.5)
-    k_r = _rope(cfg, layers.reshape(
-        layers.slice(kv, axes=[2], starts=[dc], ends=[dc + dr]),
-        [-1, 1, S, dr]), pos)
-    return layers.concat([layers.reshape(c, [-1, 1, S, dc]), k_r], axis=3)
+    with name_scope("attn.qkv"):
+        kv = _fc(h, dc + dr, nm + "_att_kva.w_0")
+        c = layers.rms_norm(
+            layers.slice(kv, axes=[2], starts=[0], ends=[dc]),
+            begin_norm_axis=2, epsilon=_rms_eps(cfg),
+            param_attr=ParamAttr(name=nm + "_att_kva_ln_s"))
+        if cfg.get("mla_scale_kv_lora"):
+            # the latent only: k_r is not scaled
+            c = layers.scale(c, scale=(cfg["d_model"] / float(dc)) ** 0.5)
+    with name_scope("attn.core"):
+        k_r = _rope(cfg, layers.reshape(
+            layers.slice(kv, axes=[2], starts=[dc], ends=[dc + dr]),
+            [-1, 1, S, dr]), pos)
+        return layers.concat([layers.reshape(c, [-1, 1, S, dc]), k_r],
+                             axis=3)
 
 
 def _mla_expanded(cfg, h, nm, S, pos):
@@ -1009,19 +1037,22 @@ def _mla_expanded(cfg, h, nm, S, pos):
     n_head, dn, dv = cfg["n_head"], cfg["d_nope"], cfg["d_v"]
     dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
     row = _mla_row(cfg, h, nm, S, pos)
-    c = layers.reshape(layers.slice(row, axes=[3], starts=[0], ends=[dc]),
-                       [-1, S, dc])
-    kv = layers.transpose(layers.reshape(
-        _fc(c, n_head * (dn + dv), nm + "_att_kvb.w_0"),
-        [-1, S, n_head, dn + dv]), perm=[0, 2, 1, 3])      # [B,H,S,dn+dv]
-    k_r = layers.slice(row, axes=[3], starts=[dc], ends=[dc + dr])
-    k = layers.concat([
-        layers.slice(kv, axes=[3], starts=[0], ends=[dn]),
-        layers.expand(k_r, [1, n_head, 1, 1])], axis=3)
-    v = layers.slice(kv, axes=[3], starts=[dn], ends=[dn + dv])
+    with name_scope("attn.qkv"):
+        c = layers.reshape(
+            layers.slice(row, axes=[3], starts=[0], ends=[dc]), [-1, S, dc])
+        kv = layers.transpose(layers.reshape(
+            _fc(c, n_head * (dn + dv), nm + "_att_kvb.w_0"),
+            [-1, S, n_head, dn + dv]), perm=[0, 2, 1, 3])  # [B,H,S,dn+dv]
+    with name_scope("attn.core"):
+        k_r = layers.slice(row, axes=[3], starts=[dc], ends=[dc + dr])
+        k = layers.concat([
+            layers.slice(kv, axes=[3], starts=[0], ends=[dn]),
+            layers.expand(k_r, [1, n_head, 1, 1])], axis=3)
+        v = layers.slice(kv, axes=[3], starts=[dn], ends=[dn + dv])
     q_nope, q_rope = _mla_q(cfg, h, nm, S, pos)
-    q = layers.transpose(layers.concat([q_nope, q_rope], axis=3),
-                         perm=[0, 2, 1, 3])
+    with name_scope("attn.core"):
+        q = layers.transpose(layers.concat([q_nope, q_rope], axis=3),
+                             perm=[0, 2, 1, 3])
     return q, k, v, row
 
 
@@ -1058,14 +1089,16 @@ def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
 
 
 def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, branch=None,
-                **tally):
+                first="attn.out", **tally):
     """A layer after its first sub-block's output ``y`` (attention's
     projection, a gated convolution's): the residual, then the FFN or
     the experts and theirs. ``branch`` (cfg['shortcut_moe']) is the
     builder's one dict that carries the routed branch from the even
     sub-layer, where it forks off the norm the dense FFN reads, to the
-    end of the odd one, where it joins: computed once, added once."""
-    x = _residual(cfg, x, y, nm + "_post1", mix)
+    end of the odd one, where it joins: computed once, added once.
+    ``first`` is the scope class of that first sub-block, which its
+    residual add stands under; the second's stands under its own."""
+    x = _residual(cfg, x, y, nm + "_post1", mix, first)
     if cfg.get("mixers"):
         return x            # the attention was the layer's one mixer
     h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
@@ -1073,8 +1106,10 @@ def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, branch=None,
         branch["s"] = _routed(cfg, h2, nm, i // 2, **tally)
     f = _mlp(cfg, h2, nm, i, **tally)
     if branch is not None and i % 2:
-        f = layers.elementwise_add(f, branch.pop("s"))
-    return _residual(cfg, x, f, nm + "_post2", mix2)
+        with name_scope("moe.experts"):
+            f = layers.elementwise_add(f, branch.pop("s"))
+    return _residual(cfg, x, f, nm + "_post2", mix2,
+                     "ffn" if _is_dense(cfg, i) else "moe.experts")
 
 
 def _gated_conv(cfg, helper, h, nm, batch, step):
@@ -1084,20 +1119,21 @@ def _gated_conv(cfg, helper, h, nm, batch, step):
     are read and shifted in place; otherwise the prompt overwrites
     them."""
     D, K = cfg["d_model"], int(cfg["conv_taps"])
-    proj = _fc(h, 3 * D, nm + "_conv_in.w_0")
+    with name_scope("conv"):
+        proj = _fc(h, 3 * D, nm + "_conv_in.w_0")
 
-    def cut(k):
-        return layers.slice(proj, axes=[2], starts=[k * D],
-                            ends=[(k + 1) * D])
+        def cut(k):
+            return layers.slice(proj, axes=[2], starts=[k * D],
+                                ends=[(k + 1) * D])
 
-    rows = helper.create_global_variable(
-        name=nm + "_cache_x", shape=(batch, K - 1, D))
-    with stored_dtype(None):      # the taps stay float32, as a vector
-        c = layers.causal_conv(
-            layers.elementwise_mul(cut(0), cut(2)), K, nm + "_conv", rows,
-            step=step, act=False, bias=False)
-    return _fc(layers.elementwise_mul(cut(1), c), D,
-               nm + "_conv_out.w_0"), rows.name
+        rows = helper.create_global_variable(
+            name=nm + "_cache_x", shape=(batch, K - 1, D))
+        with stored_dtype(None):      # the taps stay float32, as a vector
+            c = layers.causal_conv(
+                layers.elementwise_mul(cut(0), cut(2)), K, nm + "_conv",
+                rows, step=step, act=False, bias=False)
+        return _fc(layers.elementwise_mul(cut(1), c), D,
+                   nm + "_conv_out.w_0"), rows.name
 
 
 def _sub_input(cfg, x, nm, k, dev=None):
@@ -1110,24 +1146,29 @@ def _sub_input(cfg, x, nm, k, dev=None):
     reading (``MHC_RES_DEV_VAR``)."""
     mix = None
     if has_streams(cfg):
-        x, mix = layers.mhc_pre(
-            x, cfg["hc_mult"], _rms_eps(cfg),
-            int(cfg.get("hc_sinkhorn_iters") or 20),
-            float(cfg.get("hc_eps") or 1e-6), _hc_clamp(cfg),
-            "%s_hc%d" % (nm, k), dev=dev)
+        with name_scope("mhc"):
+            x, mix = layers.mhc_pre(
+                x, cfg["hc_mult"], _rms_eps(cfg),
+                int(cfg.get("hc_sinkhorn_iters") or 20),
+                float(cfg.get("hc_eps") or 1e-6), _hc_clamp(cfg),
+                "%s_hc%d" % (nm, k), dev=dev)
     return _norm_of(cfg, x, "%s_pre%d" % (nm, k)), mix
 
 
-def _residual(cfg, x, y, prefix, mix=None):
+def _residual(cfg, x, y, prefix, mix=None, scope="ffn"):
     """``x + y``, with cfg['sandwich_norm'] the sub-block's output
     normed first (``<prefix>_ln_s``); over streams (``mix`` from
     ``_sub_input``) every stream takes its doubly stochastic share of
-    the others and its own share of ``y`` (``layers.mhc_post``)."""
+    the others and its own share of ``y`` (``layers.mhc_post``).
+    ``scope`` is the class of the sub-block that made ``y``: the plain
+    add is its last op."""
     if cfg.get("sandwich_norm"):
         y = _norm_of(cfg, y, prefix)
     if mix is not None:
-        return layers.mhc_post(x, y, mix, cfg["hc_mult"])
-    return layers.elementwise_add(x, y)
+        with name_scope("mhc"):
+            return layers.mhc_post(x, y, mix, cfg["hc_mult"])
+    with name_scope(scope):
+        return layers.elementwise_add(x, y)
 
 
 def _visibility_bias(ar_rows, pos, lead):
@@ -1252,12 +1293,18 @@ def _mlp(cfg, h, nm, layer, **tally):
     cfg['shortcut_moe'], whose experts are ``_layer_tail``'s branch), or
     — cfg['n_expert'] — the routed experts (``_routed``; ``tally``: its
     counts)."""
-    if not cfg.get("n_expert") or has_shortcut(cfg) \
-            or layer < (cfg.get("n_dense_layer") or 0):
+    if _is_dense(cfg, layer):
         return _ffn(h, cfg["d_model"], cfg["d_ff"], nm,
                     act=cfg.get("ffn_act", "relu"),
                     bias=not _new_style(cfg))
     return _routed(cfg, h, nm, layer, **tally)
+
+
+def _is_dense(cfg, layer):
+    """Whether ``_mlp`` of ``layer`` is the dense FFN (scope class
+    ``ffn``) and not the routed experts (``moe.*``)."""
+    return not cfg.get("n_expert") or has_shortcut(cfg) \
+        or layer < (cfg.get("n_dense_layer") or 0)
 
 
 def _routed(cfg, h, nm, row, counts=None, touched=None, compact=None,
@@ -1272,21 +1319,26 @@ def _routed(cfg, h, nm, row, counts=None, touched=None, compact=None,
                                  "norm_topk_eps", "n_zero_expert")
              if cfg.get(k)}
     act = "relu2" if cfg.get("ffn_act") == "relu2" else "swiglu"
-    if cfg.get("d_expert_in"):
-        # the routed experts work in a latent of the token; the router
-        # (and the shared expert) read the token itself
-        extra["expert_input"] = _fc(h, int(cfg["d_expert_in"]),
-                                    nm + "_moe_lat_down.w_0")
-    out, _aux = layers.moe_ffn(
-        h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
-        act=act, dropless=True,
-        norm_topk=bool(cfg.get("norm_topk", False)),
-        param_prefix=nm + "_moe", counts=counts, counts_row=row,
-        touched=touched, compact_calls=compact, zero_pairs=zero, **extra)
-    if cfg.get("d_expert_in"):
-        out = _fc(out, cfg["d_model"], nm + "_moe_lat_up.w_0")
+    # one op holds the router, the sort and the grouped matmuls: its
+    # lowering says which is which (``moe.router`` inside ``moe.experts``)
+    with name_scope("moe.experts"):
+        if cfg.get("d_expert_in"):
+            # the routed experts work in a latent of the token; the router
+            # (and the shared expert) read the token itself
+            extra["expert_input"] = _fc(h, int(cfg["d_expert_in"]),
+                                        nm + "_moe_lat_down.w_0")
+        out, _aux = layers.moe_ffn(
+            h, cfg["n_expert"], cfg["d_expert"], top_k=cfg["expert_top_k"],
+            act=act, dropless=True,
+            norm_topk=bool(cfg.get("norm_topk", False)),
+            param_prefix=nm + "_moe", counts=counts, counts_row=row,
+            touched=touched, compact_calls=compact, zero_pairs=zero, **extra)
+        if cfg.get("d_expert_in"):
+            out = _fc(out, cfg["d_model"], nm + "_moe_lat_up.w_0")
     if cfg.get("d_shared_expert"):
-        out = layers.elementwise_add(out, _shared_expert(cfg, h, nm, act))
+        with name_scope("moe.shared"):
+            out = layers.elementwise_add(out,
+                                         _shared_expert(cfg, h, nm, act))
     return out
 
 
@@ -1304,6 +1356,11 @@ def _shared_expert(cfg, h, nm, act):
 
 
 def _ssm_mixer(cfg, helper, h, nm, batch, T, step):
+    with name_scope("mixer"):
+        return _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step)
+
+
+def _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step):
     """A state-space mixer over the normed ``h [B, T, D]`` (``base_config``
     has the equations): ``(out [B, T, D], [the two cache names])``. ``step``
     is the decode form (``T`` = 1): the state and the convolution rows
@@ -1359,10 +1416,16 @@ def _lone_mixer(cfg, helper, x, nm, i, batch, T, step, cache_names,
         cache_names += names
     else:
         y = _mlp(cfg, h, nm, i, **tally)
-    return layers.elementwise_add(x, y)
+    with name_scope("mixer" if kind == "ssm" else "moe.experts"):
+        return layers.elementwise_add(x, y)
 
 
 def _final_norm(cfg, x):
+    with name_scope("head"):
+        return _final_norm_ops(cfg, x)
+
+
+def _final_norm_ops(cfg, x):
     """The shared final norm (training build + decode step use the SAME
     parameter names, so decode can overwrite by name); of the SUM of the
     streams where a token has several."""
@@ -1386,13 +1449,15 @@ def _final_norm(cfg, x):
 def _norm_of(cfg, t, prefix):
     """Per-layer norm for the inference graphs (decode + prefill),
     matching the training build's _prenorm parameter names."""
-    if cfg.get("norm", "layer") == "rms":
-        return layers.rms_norm(t, begin_norm_axis=2,
-                               epsilon=_rms_eps(cfg),
-                               param_attr=ParamAttr(name=prefix + "_ln_s"))
-    return layers.layer_norm(t, begin_norm_axis=2,
-                             param_attr=ParamAttr(name=prefix + "_ln_s"),
-                             bias_attr=ParamAttr(name=prefix + "_ln_b"))
+    with name_scope("norm"):
+        if cfg.get("norm", "layer") == "rms":
+            return layers.rms_norm(
+                t, begin_norm_axis=2, epsilon=_rms_eps(cfg),
+                param_attr=ParamAttr(name=prefix + "_ln_s"))
+        return layers.layer_norm(
+            t, begin_norm_axis=2,
+            param_attr=ParamAttr(name=prefix + "_ln_s"),
+            bias_attr=ParamAttr(name=prefix + "_ln_b"))
 
 
 def _kv_heads_of(cfg):
@@ -1457,10 +1522,66 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
         use_fused_attention = fused_attention_enabled()
     ids = layers.data("ids", [seq_len], dtype="int64")
     seg = pos_feed = None
-    self_seg = None
     if packed:
         seg = layers.data("segment_ids", [seq_len], dtype="int64")
         pos_feed = layers.data("pos_ids", [seq_len], dtype="int64")
+    with name_scope("attn.core"):      # the masks every layer shares
+        self_bias, self_causal, self_seg, pack_bias = _train_masks(
+            ids, seg, seq_len, packed, use_fused_attention)
+
+    use_rope = cfg.get("pos_emb", "learned") == "rope"
+    with name_scope("embed"):
+        x, rope_pos = _train_embed(cfg, ids, pos_feed, seq_len, packed,
+                                   use_rope, is_test)
+
+    norm = cfg.get("norm", "layer")
+    band_bias = {None: self_bias}     # window -> pack + band bias
+    for i in range(cfg["n_layer"]):
+        nm = "gpt_%d" % i
+        if new_style:
+            window = layer_window(cfg, i)
+            if window is not None and window >= seq_len:
+                window = None
+            if window not in band_bias:
+                with name_scope("attn.core"):
+                    band_bias[window] = layers.elementwise_add(
+                        pack_bias, _band_bias(seq_len, window))
+            with _layer_scope(cfg, i):
+                x = _block(cfg, x, i, seq_len, band_bias[window], rope_pos,
+                           is_test)
+            if checkpoints is not None:
+                checkpoints.append(x)
+            continue
+        with _layer_scope(cfg, i):
+            x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
+                h, h, self_bias, cfg["d_model"], cfg["n_head"],
+                cfg["dropout"], is_test, nm + "_att", use_fused_attention,
+                causal=self_causal, n_kv_head=cfg.get("n_kv_head"),
+                rope_pos=rope_pos, segment_ids=self_seg,
+                qk_norm_eps=_rms_eps(cfg) if cfg.get("qk_norm") else None,
+                rope_base=_rope_base(cfg)),
+                cfg["dropout"], is_test, nm + "_pre1", norm=norm,
+                rms_eps=_rms_eps(cfg), tail="attn.out")
+            x = _prenorm(x, lambda h, nm=nm, i=i: _mlp(cfg, h, nm, i),
+                         cfg["dropout"], is_test, nm + "_pre2", norm=norm,
+                         rms_eps=_rms_eps(cfg),
+                         tail="ffn" if _is_dense(cfg, i) else "moe.experts")
+        if checkpoints is not None:
+            checkpoints.append(x)
+    x = _final_norm(cfg, x)
+
+    logits = _lm_head(cfg, x)
+    with name_scope("loss"):
+        avg = _train_loss(logits, ids, seg, seq_len, packed)
+    return avg, (["ids", "segment_ids", "pos_ids"] if packed
+                 else ["ids"])
+
+
+def _train_masks(ids, seg, seq_len, packed, use_fused_attention):
+    """``build``'s attention masks: ``(self_bias, self_causal, self_seg,
+    pack_bias)``; the last is the pad or pack mask alone, which a
+    sliding layer's band is added to."""
+    self_seg = pack_bias = None
     if use_fused_attention:
         if packed:
             # the fused op takes the segment ids DIRECTLY — no [S,S]
@@ -1488,8 +1609,11 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
         self_bias = layers.elementwise_add(pack_bias,
                                            _causal_bias(seq_len))
         self_causal = False
+    return self_bias, self_causal, self_seg, pack_bias
 
-    use_rope = cfg.get("pos_emb", "learned") == "rope"
+
+def _train_embed(cfg, ids, pos_feed, seq_len, packed, use_rope, is_test):
+    """``build``'s input rows: ``(x [B, S, D], rope_pos or None)``."""
     word = layers.embedding(ids, [cfg["vocab"], cfg["d_model"]],
                             param_attr=ParamAttr(name="gpt_word_emb"))
     if cfg.get("emb_scale"):
@@ -1512,41 +1636,11 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
         x = layers.elementwise_add(word, pos)
     if cfg["dropout"]:
         x = layers.dropout(x, cfg["dropout"], is_test=is_test)
+    return x, rope_pos
 
-    norm = cfg.get("norm", "layer")
-    band_bias = {None: self_bias}     # window -> pack + band bias
-    for i in range(cfg["n_layer"]):
-        nm = "gpt_%d" % i
-        if new_style:
-            window = layer_window(cfg, i)
-            if window is not None and window >= seq_len:
-                window = None
-            if window not in band_bias:
-                band_bias[window] = layers.elementwise_add(
-                    pack_bias, _band_bias(seq_len, window))
-            x = _block(cfg, x, i, seq_len, band_bias[window], rope_pos,
-                       is_test)
-            if checkpoints is not None:
-                checkpoints.append(x)
-            continue
-        x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
-            h, h, self_bias, cfg["d_model"], cfg["n_head"], cfg["dropout"],
-            is_test, nm + "_att", use_fused_attention,
-            causal=self_causal, n_kv_head=cfg.get("n_kv_head"),
-            rope_pos=rope_pos, segment_ids=self_seg,
-            qk_norm_eps=_rms_eps(cfg) if cfg.get("qk_norm") else None,
-            rope_base=_rope_base(cfg)),
-            cfg["dropout"], is_test, nm + "_pre1", norm=norm,
-            rms_eps=_rms_eps(cfg))
-        x = _prenorm(x, lambda h, nm=nm, i=i: _mlp(cfg, h, nm, i),
-                     cfg["dropout"], is_test, nm + "_pre2", norm=norm,
-                     rms_eps=_rms_eps(cfg))
-        if checkpoints is not None:
-            checkpoints.append(x)
-    x = _final_norm(cfg, x)
 
-    logits = _lm_head(cfg, x)
-
+def _train_loss(logits, ids, seg, seq_len, packed):
+    """``build``'s next-token cross entropy over the valid positions."""
     def shift_left(t):
         # t[:, 1:] with a 0 (pad) in the vacated last column
         return layers.concat([
@@ -1572,10 +1666,7 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
     total = layers.reduce_sum(layers.elementwise_mul(cost, valid))
     count = layers.elementwise_max(
         layers.reduce_sum(valid), layers.fill_constant([1], "float32", 1.0))
-    avg = layers.elementwise_div(total, count)
-    return avg, (["ids", "segment_ids", "pos_ids"] if packed
-                 else ["ids"])
-
+    return layers.elementwise_div(total, count)
 
 
 def _band_bias(seq_len, window):
@@ -1625,22 +1716,29 @@ def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
                 t = _head_norm(cfg, t, nm, which)
             return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,S,Dh]
 
-        q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), \
-            heads(v, n_kv)
-        if _rotates(cfg, i):
-            q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
-        k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
-        v = repeat_kv_heads(v, n_kv, n_head, seq_len, d_head)
+        with name_scope("attn.core"):
+            q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), \
+                heads(v, n_kv)
+            if _rotates(cfg, i):
+                q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
+            k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
+            v = repeat_kv_heads(v, n_kv, n_head, seq_len, d_head)
         scale = d_head ** -0.5
-    scores = layers.elementwise_add(
-        layers.matmul(q, k, transpose_y=True, alpha=scale), bias)
-    ctxv = layers.matmul(dropped(layers.softmax(scores)), v)
-    ctxv = layers.reshape(layers.transpose(ctxv, perm=[0, 2, 1, 3]),
-                          [-1, seq_len, n_head * d_head])
-    x = _residual(cfg, x, dropped(_attn_out(cfg, h, ctxv, nm)),
-                  nm + "_post1")
+    with name_scope("attn.core"):
+        scores = layers.elementwise_add(
+            layers.matmul(q, k, transpose_y=True, alpha=scale), bias)
+        ctxv = layers.matmul(dropped(layers.softmax(scores)), v)
+        ctxv = layers.reshape(layers.transpose(ctxv, perm=[0, 2, 1, 3]),
+                              [-1, seq_len, n_head * d_head])
+    y = _attn_out(cfg, h, ctxv, nm)
+    with name_scope("attn.out"):
+        y = dropped(y)
+    x = _residual(cfg, x, y, nm + "_post1", scope="attn.out")
+    dense = "ffn" if _is_dense(cfg, i) else "moe.experts"
     f = _mlp(cfg, _norm_of(cfg, x, nm + "_pre2"), nm, i)
-    return _residual(cfg, x, dropped(f), nm + "_post2")
+    with name_scope(dense):
+        f = dropped(f)
+    return _residual(cfg, x, f, nm + "_post2", scope=dense)
 
 
 @_stores_weights
@@ -1663,63 +1761,98 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         max_len = cfg["max_length"]
     P = int(prompt_len)
     assert 0 < P <= max_len, (P, max_len)
-    n_head, d_head = cfg["n_head"], _d_head(cfg)
-    n_kv, _g = _kv_heads_of(cfg)
+    _kv_heads_of(cfg)
     from ..layer_helper import LayerHelper
-    from .transformer import repeat_kv_heads
 
     helper = LayerHelper("gpt_prefill")
     tokens = layers.data("tokens", [P], dtype="int64")
-    zero = layers.fill_constant([1], "int64", 0)
+    with name_scope("attn.core"):       # the cache writes' row 0
+        zero = layers.fill_constant([1], "int64", 0)
 
     learned = cfg.get("pos_emb", "learned") == "learned"
     word = _embed(cfg, tokens, [-1, P, cfg["d_model"]])
-    pos_range = layers.range(0, P, 1, "int64")
-    if not learned:
-        x = word
-    else:
-        pos = layers.reshape(
-            layers.embedding(layers.reshape(pos_range, [1, P]),
-                             [cfg["max_length"], cfg["d_model"]],
-                             param_attr=ParamAttr(name="gpt_pos_emb")),
-            [1, P, cfg["d_model"]])
-        x = layers.elementwise_add(word, pos)
+    with name_scope("embed"):
+        pos_range = layers.range(0, P, 1, "int64")
+        if not learned:
+            x = word
+        else:
+            pos = layers.reshape(
+                layers.embedding(layers.reshape(pos_range, [1, P]),
+                                 [cfg["max_length"], cfg["d_model"]],
+                                 param_attr=ParamAttr(name="gpt_pos_emb")),
+                [1, P, cfg["d_model"]])
+            x = layers.elementwise_add(word, pos)
 
     # a cfg with two kinds of layer prefills through the fused attention
     # op (causal, a window where the prompt is longer than it, grouped
     # heads): the flash forward at P >= flash_min_seq, whose [P, P]
     # scores never exist. Every other cfg composes them, as it did
     # (so does latent attention: its expanded form, q and k wider than v)
-    latent = has_latent(cfg)
-    fused = bool(cfg.get("layer_types")) or latent or bool(cfg.get("mixers"))
-    bias = None if fused else _causal_bias(P)
+    fused = bool(cfg.get("layer_types")) or has_latent(cfg) \
+        or bool(cfg.get("mixers"))
+    with name_scope("attn.core"):
+        bias = None if fused else _causal_bias(P)
     # only the serving decode step tallies its routing; a share's long
     # prefill tallies which length its expert calls ran at
     tally = {"compact": _compact_calls_var(cfg, helper, batch * P)}
     branch = {} if has_shortcut(cfg) else None
     cache_names = []
     for i in range(cfg["n_layer"]):
-        nm = "gpt_%d" % i
-        lone = _lone_mixer(cfg, helper, x, nm, i, batch, P, False,
-                           cache_names, **tally)
-        if lone is not None:
-            x = lone
-            continue
-        rows = cache_rows(cfg, i, max_len)
-        h, mix = _sub_input(cfg, x, nm, 1)
-        if is_conv(cfg, i):
-            y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
-            cache_names.append(kept)
-            x = _layer_tail(cfg, x, y, nm, i, mix, **tally)
-            continue
-        if latent:
-            # the expanded form through the flash forward; what stays of
-            # the prompt is ONE slab of latent rows
-            cc = helper.create_global_variable(
-                name=nm + "_cache_c",
-                shape=(batch, 1, rows, latent_width(cfg)))
-            cache_names.append(cc.name)
-            q, k, v, row = _mla_expanded(cfg, h, nm, P, pos_range)
+        with _layer_scope(cfg, i):
+            x = _prefill_layer(cfg, helper, x, i, batch, P, max_len,
+                               pos_range, zero, fused, bias, tally, branch,
+                               cache_names)
+
+    x = _final_norm(cfg, x)
+    logits = _lm_head(cfg, x)
+    with name_scope("head"):
+        if has_streams(cfg) or has_state(cfg) or cfg.get("mixers") \
+                or has_shortcut(cfg):
+            # the one row an admission needs, cut BEFORE the head: a plan
+            # that fetches the row or its argmax holds a [1, vocab] head,
+            # and only one that fetches ``logits`` (``generate``) the
+            # [P, vocab] one (DCE) — 4.3 GB at P 8,192 and 131,072 ids
+            last = _lm_head(cfg, layers.slice(x, axes=[1], starts=[P - 1],
+                                              ends=[P]))
+        else:
+            # the older configurations' (their op lists are pinned): cut
+            # AFTER the head, the same numbers as logits[:, P - 1]
+            # whatever order the head reduces in
+            last = layers.slice(logits, axes=[1], starts=[P - 1], ends=[P])
+        last = _expose(layers.reshape(last, [-1, cfg["vocab"]]),
+                       LAST_LOGITS_VAR)
+    _greedy_token(last)
+    return logits, cache_names
+
+
+def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
+                   fused, bias, tally, branch, cache_names):
+    """Layer ``i`` of ``build_prefill_step`` over ``x [B, P, D]`` (its
+    caches' names appended to ``cache_names``): ``x`` after it."""
+    from .transformer import repeat_kv_heads
+
+    n_head, d_head = cfg["n_head"], _d_head(cfg)
+    n_kv, _g = _kv_heads_of(cfg)
+    nm = "gpt_%d" % i
+    lone = _lone_mixer(cfg, helper, x, nm, i, batch, P, False,
+                       cache_names, **tally)
+    if lone is not None:
+        return lone
+    rows = cache_rows(cfg, i, max_len)
+    h, mix = _sub_input(cfg, x, nm, 1)
+    if is_conv(cfg, i):
+        y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
+        cache_names.append(kept)
+        return _layer_tail(cfg, x, y, nm, i, mix, first="conv", **tally)
+    if has_latent(cfg):
+        # the expanded form through the flash forward; what stays of
+        # the prompt is ONE slab of latent rows
+        cc = helper.create_global_variable(
+            name=nm + "_cache_c",
+            shape=(batch, 1, rows, latent_width(cfg)))
+        cache_names.append(cc.name)
+        q, k, v, row = _mla_expanded(cfg, h, nm, P, pos_range)
+        with name_scope("attn.core"):
             _prefill_cache_write(cc, row, P, rows, zero)
             # compute-bound at 128 heads: the kernel from one lane tile
             # on (every prompt length of a cell runs, and is measured
@@ -1732,23 +1865,23 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.reshape(
                 layers.transpose(ctxv, perm=[0, 2, 1, 3]),
                 [-1, P, n_head * cfg["d_v"]])
-            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
-                            **tally)
-            continue
-        ck = helper.create_global_variable(
-            name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
-        cv = helper.create_global_variable(
-            name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
-        cache_names += [ck.name, cv.name]
+        return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
+                           **tally)
+    ck = helper.create_global_variable(
+        name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
+    cv = helper.create_global_variable(
+        name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
+    cache_names += [ck.name, cv.name]
 
-        q, k, v = _qkv(cfg, h, nm)
+    q, k, v = _qkv(cfg, h, nm)
 
-        def heads(t, n, which=None):
-            t = layers.reshape(t, [-1, P, n, d_head])
-            if which:
-                t = _head_norm(cfg, t, nm, which)
-            return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,P,Dh]
+    def heads(t, n, which=None):
+        t = layers.reshape(t, [-1, P, n, d_head])
+        if which:
+            t = _head_norm(cfg, t, nm, which)
+        return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,P,Dh]
 
+    with name_scope("attn.core"):
         q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), heads(v, n_kv)
         if _rotates(cfg, i):
             q = _rope(cfg, q, pos_range)
@@ -1772,28 +1905,8 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
-                        **tally)
-
-    x = _final_norm(cfg, x)
-    logits = _lm_head(cfg, x)
-    if has_streams(cfg) or has_state(cfg) or cfg.get("mixers") \
-            or has_shortcut(cfg):
-        # the one row an admission needs, cut BEFORE the head: a plan
-        # that fetches the row or its argmax holds a [1, vocab] head,
-        # and only one that fetches ``logits`` (``generate``) the
-        # [P, vocab] one (DCE) — 4.3 GB at P 8,192 and 131,072 ids
-        last = _lm_head(cfg, layers.slice(x, axes=[1], starts=[P - 1],
-                                          ends=[P]))
-    else:
-        # the older configurations' (their op lists are pinned): cut
-        # AFTER the head, the same numbers as logits[:, P - 1] whatever
-        # order the head reduces in
-        last = layers.slice(logits, axes=[1], starts=[P - 1], ends=[P])
-    last = _expose(layers.reshape(last, [-1, cfg["vocab"]]),
-                   LAST_LOGITS_VAR)
-    _greedy_token(last)
-    return logits, cache_names
+    return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
+                       **tally)
 
 
 def _prefill_cache_write(cache, kv, P, rows, zero):
@@ -1850,7 +1963,7 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             "max_len=%d exceeds the learned position table "
             "(cfg['max_length']=%d) — raise max_length or use "
             "pos_emb='rope'" % (max_len, cfg["max_length"]))
-    d_model, n_head, d_head = cfg["d_model"], cfg["n_head"], _d_head(cfg)
+    d_model = cfg["d_model"]
     from ..layer_helper import LayerHelper
 
     helper = LayerHelper("gpt_decode")
@@ -1866,12 +1979,13 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     if not learned:
         x = word            # positions rotate q/k below, or are not added
     else:
-        pos_ids = pos if per_slot_pos else layers.reshape(pos, [1, 1])
-        posv = layers.reshape(
-            layers.embedding(pos_ids, [cfg["max_length"], d_model],
-                             param_attr=ParamAttr(name="gpt_pos_emb")),
-            [-1, 1, d_model] if per_slot_pos else [1, 1, d_model])
-        x = layers.elementwise_add(word, posv)    # [B, 1, D]
+        with name_scope("embed"):
+            pos_ids = pos if per_slot_pos else layers.reshape(pos, [1, 1])
+            posv = layers.reshape(
+                layers.embedding(pos_ids, [cfg["max_length"], d_model],
+                                 param_attr=ParamAttr(name="gpt_pos_emb")),
+                [-1, 1, d_model] if per_slot_pos else [1, 1, d_model])
+            x = layers.elementwise_add(word, posv)    # [B, 1, D]
 
     # visibility over cache rows: positions <= pos attend, later rows
     # mask out — zeros from init in the lockstep loop; per-slot, row b
@@ -1885,17 +1999,20 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
             cache_rows(cfg, i, max_len)
             for i in range(0 if latent else cfg["n_layer"])
             if _keeps_rows(cfg, i)):
-        ar = layers.reshape(layers.range(0, rows, 1, "int64"), [1, rows])
-        if pos_b is None:
-            pos_b = pos if per_slot_pos else layers.reshape(pos, [1, 1])
-        biases[rows] = _visibility_bias(ar, pos_b,
-                                        -1 if per_slot_pos else 1)
-        if rows < max_len:
-            # the ring row a position lives in
-            ring_pos[rows] = layers.elementwise_mod(
-                pos, layers.fill_constant([1], "int64", rows))
+        with name_scope("attn.core"):    # one a step, for all its layers
+            ar = layers.reshape(layers.range(0, rows, 1, "int64"),
+                                [1, rows])
+            if pos_b is None:
+                pos_b = pos if per_slot_pos \
+                    else layers.reshape(pos, [1, 1])
+            biases[rows] = _visibility_bias(ar, pos_b,
+                                            -1 if per_slot_pos else 1)
+            if rows < max_len:
+                # the ring row a position lives in
+                ring_pos[rows] = layers.elementwise_mod(
+                    pos, layers.fill_constant([1], "int64", rows))
 
-    n_kv, g = _kv_heads_of(cfg)
+    _kv_heads_of(cfg)
     routed = _routed_pairs_var(cfg, helper) if per_slot_pos else None
     touched = _experts_touched_var(cfg, helper) if per_slot_pos else None
     dev = _mhc_dev_var(cfg, helper) if per_slot_pos else None
@@ -1905,55 +2022,75 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     branch = {} if has_shortcut(cfg) else None
     cache_names = []
     for i in range(cfg["n_layer"]):
-        nm = "gpt_%d" % i
-        lone = _lone_mixer(cfg, helper, x, nm, i, batch, 1, True,
-                           cache_names, **tally)
-        if lone is not None:
-            x = lone
-            continue
-        rows = cache_rows(cfg, i, max_len)
-        if is_conv(cfg, i):
-            h, mix = _sub_input(cfg, x, nm, 1, dev)
-            y, kept = _gated_conv(cfg, helper, h, nm, batch, True)
-            cache_names.append(kept)
-            x = _layer_tail(cfg, x, y, nm, i, mix, dev, **tally)
-            continue
-        if latent:
-            # the absorbed form: one latent row written, and every head
-            # reads keys AND values out of the slot's one slab
-            cc = helper.create_global_variable(
-                name=nm + "_cache_c",
-                shape=(batch, 1, rows, latent_width(cfg)))
-            cache_names.append(cc.name)
-            h, mix = _sub_input(cfg, x, nm, 1, dev)
-            cc = layers.kv_cache_write(cc, _mla_row(cfg, h, nm, 1, pos),
-                                       pos)
-            q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
+        with _layer_scope(cfg, i):
+            x = _decode_layer(cfg, helper, x, i, batch, max_len, pos,
+                              biases, ring_pos, dev, tally, branch,
+                              cache_names)
+
+    x = _final_norm(cfg, x)
+    logits = _lm_head(cfg, x)
+    with name_scope("head"):
+        _greedy_token(layers.reshape(logits, [-1, cfg["vocab"]]))
+    return logits, cache_names
+
+
+def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
+                  dev, tally, branch, cache_names):
+    """Layer ``i`` of ``build_decode_step`` over ``x [B, 1, D]`` (its
+    caches' names appended to ``cache_names``): ``x`` after it."""
+    n_head, d_head = cfg["n_head"], _d_head(cfg)
+    n_kv, g = _kv_heads_of(cfg)
+    nm = "gpt_%d" % i
+    lone = _lone_mixer(cfg, helper, x, nm, i, batch, 1, True,
+                       cache_names, **tally)
+    if lone is not None:
+        return lone
+    rows = cache_rows(cfg, i, max_len)
+    if is_conv(cfg, i):
+        h, mix = _sub_input(cfg, x, nm, 1, dev)
+        y, kept = _gated_conv(cfg, helper, h, nm, batch, True)
+        cache_names.append(kept)
+        return _layer_tail(cfg, x, y, nm, i, mix, dev, first="conv",
+                           **tally)
+    if has_latent(cfg):
+        # the absorbed form: one latent row written, and every head
+        # reads keys AND values out of the slot's one slab
+        cc = helper.create_global_variable(
+            name=nm + "_cache_c",
+            shape=(batch, 1, rows, latent_width(cfg)))
+        cache_names.append(cc.name)
+        h, mix = _sub_input(cfg, x, nm, 1, dev)
+        row = _mla_row(cfg, h, nm, 1, pos)
+        with name_scope("attn.core"):
+            cc = layers.kv_cache_write(cc, row, pos)
+        q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
+        with name_scope("attn.core"):
             ctxv = layers.mla_decode(
                 q_nope, q_rope, cc, pos,
-                [cfg["kv_lora_rank"], n_head * (cfg["d_nope"] + cfg["d_v"])],
+                [cfg["kv_lora_rank"],
+                 n_head * (cfg["d_nope"] + cfg["d_v"])],
                 d_v=cfg["d_v"], scale=_mla_scale(cfg),
                 param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
-            x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                            branch=branch, **tally)
-            continue
-        # GQA: the cache stores n_kv heads — H/Hkv-times less decode
-        # HBM, the whole point of grouped-query attention at inference
-        ck = helper.create_global_variable(
-            name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
-        cv = helper.create_global_variable(
-            name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
-        cache_names += [ck.name, cv.name]
+        return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
+                           branch=branch, **tally)
+    # GQA: the cache stores n_kv heads — H/Hkv-times less decode
+    # HBM, the whole point of grouped-query attention at inference
+    ck = helper.create_global_variable(
+        name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
+    cv = helper.create_global_variable(
+        name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
+    cache_names += [ck.name, cv.name]
 
-        h, mix = _sub_input(cfg, x, nm, 1, dev)
-        q, k, v = _qkv(cfg, h, nm)
+    h, mix = _sub_input(cfg, x, nm, 1, dev)
+    q, k, v = _qkv(cfg, h, nm)
 
-        def kv_heads(t, which=None):
-            t = layers.reshape(t, [-1, 1, n_kv, d_head])
-            if which:
-                t = _head_norm(cfg, t, nm, which)
-            return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,Hkv,1,Dh]
+    def kv_heads(t, which=None):
+        t = layers.reshape(t, [-1, 1, n_kv, d_head])
+        if which:
+            t = _head_norm(cfg, t, nm, which)
+        return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,Hkv,1,Dh]
 
+    with name_scope("attn.core"):
         k, v = kv_heads(k, "k"), kv_heads(v)
         rotates = _rotates(cfg, i)
         if rotates:
@@ -1985,13 +2122,8 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
         w = layers.softmax(scores)
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
         ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
-        x = _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                        branch=branch, **tally)
-
-    x = _final_norm(cfg, x)
-    logits = _lm_head(cfg, x)
-    _greedy_token(layers.reshape(logits, [-1, cfg["vocab"]]))
-    return logits, cache_names
+    return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
+                       branch=branch, **tally)
 
 
 @_stores_weights
@@ -2100,27 +2232,29 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
     if use_rope:
         x = word                             # positions rotate q/k below
     else:
-        posv = layers.reshape(
-            layers.embedding(pos, [cfg["max_length"], d_model],
-                             param_attr=ParamAttr(name="gpt_pos_emb")),
-            [-1, S, d_model])
-        x = layers.elementwise_add(word, posv)
+        with name_scope("embed"):
+            posv = layers.reshape(
+                layers.embedding(pos, [cfg["max_length"], d_model],
+                                 param_attr=ParamAttr(name="gpt_pos_emb")),
+                [-1, S, d_model])
+            x = layers.elementwise_add(word, posv)
 
     # per-position [B, 1] position columns + the decode step's exact
     # visibility bias per position: query (b, s) attends cache rows
     # <= pos[b, s]; everything later — a neighbor's rows, this
     # dispatch's own still-speculative writes — masks to an exact zero
     # after softmax
-    ar = layers.reshape(layers.range(0, max_len, 1, "int64"),
-                        [1, max_len])
     pos_cols, biases = [], []
-    for s in range(S):
-        ps = layers.slice(pos, axes=[1], starts=[s], ends=[s + 1])
-        pos_cols.append(ps)                              # [B, 1]
-        vis = layers.cast(layers.less_equal(ar, ps), "float32")
-        b_s = layers.scale(layers.elementwise_sub(
-            layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
-        biases.append(layers.reshape(b_s, [-1, 1, 1, max_len]))
+    with name_scope("attn.core"):       # one a dispatch, for all its layers
+        ar = layers.reshape(layers.range(0, max_len, 1, "int64"),
+                            [1, max_len])
+        for s in range(S):
+            ps = layers.slice(pos, axes=[1], starts=[s], ends=[s + 1])
+            pos_cols.append(ps)                              # [B, 1]
+            vis = layers.cast(layers.less_equal(ar, ps), "float32")
+            b_s = layers.scale(layers.elementwise_sub(
+                layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
+            biases.append(layers.reshape(b_s, [-1, 1, 1, max_len]))
 
     routed = None
     cache_names = []
@@ -2132,45 +2266,48 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
             name=nm + "_cache_v", shape=(batch, n_kv, max_len, d_head))
         cache_names += [ck.name, cv.name]
 
-        h = _norm_of(cfg, x, nm + "_pre1")
-        q, k, v = _qkv(cfg, h, nm)
-
         def kv_heads(t, which=None):
             t = layers.reshape(t, [-1, S, n_kv, d_head])
             if which:
                 t = _head_norm(cfg, t, nm, which)
             return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n_kv,S,Dh]
 
-        k, v = kv_heads(k, "k"), kv_heads(v)
-        rotates = _rotates(cfg, i)
-        if rotates:
-            # [B, S] positions -> per-(row, step) angles broadcast over
-            # the kv-head axis (elementwise — bitwise the per-position
-            # rotation); the cache stores rotated keys
-            k = _rope(cfg, k, pos)
-        # ONE vmapped slab write per cache tensor at the per-row start
-        # (rows are contiguous by contract)
-        ck = layers.kv_cache_write(ck, k, pos_cols[0])
-        cv = layers.kv_cache_write(cv, v, pos_cols[0])
-        # attention per position, in the decode step's exact shapes:
-        # q_s folds to [B, n_kv, g, Dh] and batch-matmuls the n_kv
-        # cache directly — scores/softmax/ctx of position s are the
-        # single-token step's bit for bit (an S-wide GEMM would not be)
-        ctxs = []
-        for s in range(S):
-            q_s = _head_norm(cfg, layers.reshape(
-                layers.slice(q, axes=[1], starts=[s], ends=[s + 1]),
-                [-1, n_kv, g, d_head]), nm, "q")
-            if rotates:
-                q_s = _rope(cfg, q_s, pos_cols[s])
-            scores = layers.matmul(q_s, ck, transpose_y=True,
-                                   alpha=d_head ** -0.5)  # [B,n_kv,g,S']
-            scores = layers.elementwise_add(scores, biases[s])
-            w = layers.softmax(scores)
-            ctxs.append(layers.reshape(layers.matmul(w, cv),
-                                       [-1, 1, n_head * d_head]))
-        ctxv = ctxs[0] if S == 1 else layers.concat(ctxs, axis=1)
-        x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
+        with _layer_scope(cfg, i):
+            h = _norm_of(cfg, x, nm + "_pre1")
+            q, k, v = _qkv(cfg, h, nm)
+            with name_scope("attn.core"):
+                k, v = kv_heads(k, "k"), kv_heads(v)
+                rotates = _rotates(cfg, i)
+                if rotates:
+                    # [B, S] positions -> per-(row, step) angles broadcast
+                    # over the kv-head axis (elementwise — bitwise the
+                    # per-position rotation); the cache stores rotated keys
+                    k = _rope(cfg, k, pos)
+                # ONE vmapped slab write per cache tensor at the per-row
+                # start (rows are contiguous by contract)
+                ck = layers.kv_cache_write(ck, k, pos_cols[0])
+                cv = layers.kv_cache_write(cv, v, pos_cols[0])
+                # attention per position, in the decode step's exact
+                # shapes: q_s folds to [B, n_kv, g, Dh] and batch-matmuls
+                # the n_kv cache directly — scores/softmax/ctx of position
+                # s are the single-token step's bit for bit (an S-wide
+                # GEMM would not be)
+                ctxs = []
+                for s in range(S):
+                    q_s = _head_norm(cfg, layers.reshape(
+                        layers.slice(q, axes=[1], starts=[s], ends=[s + 1]),
+                        [-1, n_kv, g, d_head]), nm, "q")
+                    if rotates:
+                        q_s = _rope(cfg, q_s, pos_cols[s])
+                    scores = layers.matmul(
+                        q_s, ck, transpose_y=True,
+                        alpha=d_head ** -0.5)             # [B,n_kv,g,S']
+                    scores = layers.elementwise_add(scores, biases[s])
+                    w = layers.softmax(scores)
+                    ctxs.append(layers.reshape(layers.matmul(w, cv),
+                                               [-1, 1, n_head * d_head]))
+                ctxv = ctxs[0] if S == 1 else layers.concat(ctxs, axis=1)
+            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)

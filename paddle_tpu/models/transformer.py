@@ -17,6 +17,7 @@ TPU-first choices vs the reference:
 import numpy as np
 
 from .. import layers
+from ..core.program import name_scope
 from ..param_attr import ParamAttr
 from ..initializer import NumpyArrayInitializer
 
@@ -121,23 +122,38 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
     d_head = d_model // n_head
     seq_q = q_in.shape[1]
     seq_kv = kv_in.shape[1]
-    q = layers.fc(q_in, d_model, num_flatten_dims=2, bias_attr=False,
-                  param_attr=ParamAttr(name=name + "_q.w_0"))
-    k = layers.fc(kv_in, n_kv_head * d_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=name + "_k.w_0"))
-    v = layers.fc(kv_in, n_kv_head * d_head, num_flatten_dims=2,
-                  bias_attr=False,
-                  param_attr=ParamAttr(name=name + "_v.w_0"))
-    if qk_norm_eps is not None:
-        q, k = qk_norm(q, k, name, qk_norm_eps)
+    with name_scope("attn.qkv"):
+        q = layers.fc(q_in, d_model, num_flatten_dims=2, bias_attr=False,
+                      param_attr=ParamAttr(name=name + "_q.w_0"))
+        k = layers.fc(kv_in, n_kv_head * d_head, num_flatten_dims=2,
+                      bias_attr=False,
+                      param_attr=ParamAttr(name=name + "_k.w_0"))
+        v = layers.fc(kv_in, n_kv_head * d_head, num_flatten_dims=2,
+                      bias_attr=False,
+                      param_attr=ParamAttr(name=name + "_v.w_0"))
+        if qk_norm_eps is not None:
+            q, k = qk_norm(q, k, name, qk_norm_eps)
+    with name_scope("attn.core"):
+        ctxv = _attention_core(q, k, v, bias, d_model, n_head, n_kv_head,
+                               seq_q, seq_kv, dropout, is_test,
+                               use_fused_attention, causal, rope_pos,
+                               segment_ids, rope_base)
+    with name_scope("attn.out"):
+        return layers.fc(ctxv, d_model, num_flatten_dims=2, bias_attr=False,
+                         param_attr=ParamAttr(name=name + "_o.w_0"))
+
+
+def _attention_core(q, k, v, bias, d_model, n_head, n_kv_head, seq_q, seq_kv,
+                    dropout, is_test, use_fused_attention, causal, rope_pos,
+                    segment_ids, rope_base):
+    """``multi_head_attention`` between its projections: the merged heads
+    ``[B, S, d_model]`` of the three projected inputs."""
+    d_head = d_model // n_head
     if use_fused_attention and rope_pos is None and n_kv_head == n_head:
-        ctxv = layers.fused_attention(q, k, v, bias, scale=d_head ** -0.5,
+        return layers.fused_attention(q, k, v, bias, scale=d_head ** -0.5,
                                       dropout=dropout if not is_test else 0.0,
                                       causal=causal, n_head=n_head,
                                       segment_ids=segment_ids)
-        return layers.fc(ctxv, d_model, num_flatten_dims=2, bias_attr=False,
-                         param_attr=ParamAttr(name=name + "_o.w_0"))
     q = _split_heads(q, seq_q, n_head, d_head)
     k = _split_heads(k, seq_kv, n_kv_head, d_head)
     v = _split_heads(v, seq_kv, n_kv_head, d_head)
@@ -162,9 +178,7 @@ def multi_head_attention(q_in, kv_in, bias, d_model, n_head, dropout,
             weights = layers.dropout(weights, dropout, is_test=is_test)
         ctxv = layers.matmul(weights, v)
     ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
-    ctxv = layers.reshape(ctxv, [-1, seq_q, d_model])
-    return layers.fc(ctxv, d_model, num_flatten_dims=2, bias_attr=False,
-                     param_attr=ParamAttr(name=name + "_o.w_0"))
+    return layers.reshape(ctxv, [-1, seq_q, d_model])
 
 
 def _ffn(x, d_model, d_ff, name, act="relu", bias=True):
@@ -173,32 +187,39 @@ def _ffn(x, d_model, d_ff, name, act="relu", bias=True):
     projections instead of one, all three still plain MXU matmuls.
     ``bias=False`` leaves the three biases out."""
     b = None if bias else False
-    if act == "swiglu":
-        g = layers.fc(x, d_ff, num_flatten_dims=2, act="swish", bias_attr=b,
-                      param_attr=ParamAttr(name=name + "_ffn1.w_0"))
-        u = layers.fc(x, d_ff, num_flatten_dims=2, bias_attr=b,
-                      param_attr=ParamAttr(name=name + "_ffn1v.w_0"))
-        h = layers.elementwise_mul(g, u)
-    else:
-        h = layers.fc(x, d_ff, num_flatten_dims=2, act=act, bias_attr=b,
-                      param_attr=ParamAttr(name=name + "_ffn1.w_0"))
-    return layers.fc(h, d_model, num_flatten_dims=2, bias_attr=b,
-                     param_attr=ParamAttr(name=name + "_ffn2.w_0"))
+    with name_scope("ffn"):
+        if act == "swiglu":
+            g = layers.fc(x, d_ff, num_flatten_dims=2, act="swish",
+                          bias_attr=b,
+                          param_attr=ParamAttr(name=name + "_ffn1.w_0"))
+            u = layers.fc(x, d_ff, num_flatten_dims=2, bias_attr=b,
+                          param_attr=ParamAttr(name=name + "_ffn1v.w_0"))
+            h = layers.elementwise_mul(g, u)
+        else:
+            h = layers.fc(x, d_ff, num_flatten_dims=2, act=act, bias_attr=b,
+                          param_attr=ParamAttr(name=name + "_ffn1.w_0"))
+        return layers.fc(h, d_model, num_flatten_dims=2, bias_attr=b,
+                         param_attr=ParamAttr(name=name + "_ffn2.w_0"))
 
 
 def _prenorm(x, sub_fn, dropout, is_test, name, norm="layer",
-             rms_eps=1e-6):
-    if norm == "rms":
-        h = layers.rms_norm(x, begin_norm_axis=2, epsilon=rms_eps,
-                            param_attr=ParamAttr(name=name + "_ln_s"))
-    else:
-        h = layers.layer_norm(x, begin_norm_axis=2,
-                              param_attr=ParamAttr(name=name + "_ln_s"),
-                              bias_attr=ParamAttr(name=name + "_ln_b"))
+             rms_eps=1e-6, tail="ffn"):
+    """``x + dropout(sub_fn(norm(x)))``. ``sub_fn`` names its own ops'
+    scopes; ``tail`` is the scope class of the dropout and the residual
+    add after it (the sub-block's last: ``attn.out``, ``ffn``, ...)."""
+    with name_scope("norm"):
+        if norm == "rms":
+            h = layers.rms_norm(x, begin_norm_axis=2, epsilon=rms_eps,
+                                param_attr=ParamAttr(name=name + "_ln_s"))
+        else:
+            h = layers.layer_norm(x, begin_norm_axis=2,
+                                  param_attr=ParamAttr(name=name + "_ln_s"),
+                                  bias_attr=ParamAttr(name=name + "_ln_b"))
     h = sub_fn(h)
-    if dropout:
-        h = layers.dropout(h, dropout, is_test=is_test)
-    return layers.elementwise_add(x, h)
+    with name_scope(tail):
+        if dropout:
+            h = layers.dropout(h, dropout, is_test=is_test)
+        return layers.elementwise_add(x, h)
 
 
 def encoder(src_emb, self_bias, cfg, is_test=False, use_fused_attention=False,
@@ -209,15 +230,18 @@ def encoder(src_emb, self_bias, cfg, is_test=False, use_fused_attention=False,
     x = src_emb
     for i in range(cfg["n_layer"]):
         nm = "enc_%d" % i
-        x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
-            h, h, self_bias, cfg["d_model"], cfg["n_head"], cfg["dropout"],
-            is_test, nm + "_att", use_fused_attention),
-            cfg["dropout"], is_test, nm + "_pre1")
-        x = _prenorm(x, lambda h, nm=nm: _ffn(h, cfg["d_model"], cfg["d_ff"], nm),
-                     cfg["dropout"], is_test, nm + "_pre2")
+        with name_scope("L%d" % i):
+            x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
+                h, h, self_bias, cfg["d_model"], cfg["n_head"],
+                cfg["dropout"], is_test, nm + "_att", use_fused_attention),
+                cfg["dropout"], is_test, nm + "_pre1", tail="attn.out")
+            x = _prenorm(x, lambda h, nm=nm: _ffn(h, cfg["d_model"],
+                                                  cfg["d_ff"], nm),
+                         cfg["dropout"], is_test, nm + "_pre2")
         if checkpoints is not None:
             checkpoints.append(x)
-    return layers.layer_norm(x, begin_norm_axis=2)
+    with name_scope("norm"):
+        return layers.layer_norm(x, begin_norm_axis=2)
 
 
 def decoder(trg_emb, enc_out, self_bias, cross_bias, cfg, is_test=False,
@@ -229,20 +253,23 @@ def decoder(trg_emb, enc_out, self_bias, cross_bias, cfg, is_test=False,
     x = trg_emb
     for i in range(cfg["n_layer"]):
         nm = "dec_%d" % i
-        x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
-            h, h, self_bias, cfg["d_model"], cfg["n_head"], cfg["dropout"],
-            is_test, nm + "_satt", use_fused_attention,
-            causal=self_causal),
-            cfg["dropout"], is_test, nm + "_pre1")
-        x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
-            h, enc_out, cross_bias, cfg["d_model"], cfg["n_head"],
-            cfg["dropout"], is_test, nm + "_xatt", use_fused_attention),
-            cfg["dropout"], is_test, nm + "_pre2")
-        x = _prenorm(x, lambda h, nm=nm: _ffn(h, cfg["d_model"], cfg["d_ff"], nm),
-                     cfg["dropout"], is_test, nm + "_pre3")
+        with name_scope("L%d" % i):
+            x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
+                h, h, self_bias, cfg["d_model"], cfg["n_head"],
+                cfg["dropout"], is_test, nm + "_satt", use_fused_attention,
+                causal=self_causal),
+                cfg["dropout"], is_test, nm + "_pre1", tail="attn.out")
+            x = _prenorm(x, lambda h, nm=nm: multi_head_attention(
+                h, enc_out, cross_bias, cfg["d_model"], cfg["n_head"],
+                cfg["dropout"], is_test, nm + "_xatt", use_fused_attention),
+                cfg["dropout"], is_test, nm + "_pre2", tail="attn.out")
+            x = _prenorm(x, lambda h, nm=nm: _ffn(h, cfg["d_model"],
+                                                  cfg["d_ff"], nm),
+                         cfg["dropout"], is_test, nm + "_pre3")
         if checkpoints is not None:
             checkpoints.append(x)
-    return layers.layer_norm(x, begin_norm_axis=2)
+    with name_scope("norm"):
+        return layers.layer_norm(x, begin_norm_axis=2)
 
 
 def _pad_bias(ids, pad_idx=0):

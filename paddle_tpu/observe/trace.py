@@ -84,6 +84,7 @@ import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -624,6 +625,14 @@ def dump_flight_recorder(path: Optional[str] = None, reason: str = "manual",
     if not path:
         return None
     try:
+        names = sys.modules.get(__package__ + ".device_names")
+        asked = {k: v for k, v in names.tables().items() if v} \
+            if names is not None else None
+        if asked:
+            # the name tables somebody asked for go with the spans that
+            # name their plans: tools/trace_view.py joins the two with a
+            # kept device profile
+            extra = dict(extra or {}, device_names=asked)
         RECORDER.dump(path, reason=reason, extra=extra)
         if reason in ("wedge", "crash"):
             _CRITICAL_DUMPED = True

@@ -213,16 +213,21 @@ def _experts(x, w1, w1v, b1, w2, b2, gate_w, E, top_k, capacity, act,
     T = x.shape[0]
     scoring = scoring or {}
     E_all = E + n_zero
-    if capacity is None:
-        expert_idx, gate, aux = router(x, gate_w, E_all, top_k, z_loss,
-                                       norm_topk, **scoring)
-        flat_e = expert_idx.reshape(-1)                  # [K*T]
-    else:
-        expert_idx, gate, _pos, keep, aux = route_tokens(
-            x, gate_w, E, capacity, top_k, z_loss, norm_topk, **scoring)
-        # a dropped pair belongs to no expert: it sorts behind them all
-        flat_e = jnp.where(keep, expert_idx, E).reshape(-1)
-        gate = jnp.where(keep, gate, 0)
+    # the op stands under ``moe.experts`` (models/gpt.py); what scores and
+    # picks says so in the device's operations' names, by a class of
+    # core/program.py::SCOPE_CLASSES
+    with jax.named_scope("moe.router"):
+        if capacity is None:
+            expert_idx, gate, aux = router(x, gate_w, E_all, top_k, z_loss,
+                                           norm_topk, **scoring)
+            flat_e = expert_idx.reshape(-1)              # [K*T]
+        else:
+            expert_idx, gate, _pos, keep, aux = route_tokens(
+                x, gate_w, E, capacity, top_k, z_loss, norm_topk,
+                **scoring)
+            # a dropped pair belongs to no expert: sorts behind them all
+            flat_e = jnp.where(keep, expert_idx, E).reshape(-1)
+            gate = jnp.where(keep, gate, 0)
     routed = sizes = jnp.sum(
         flat_e[:, None] == jnp.arange(E_all)[None, :], axis=0,
         dtype=jnp.int32)                                 # [E + n_zero]

@@ -17,6 +17,7 @@ from .core.program import (
     Variable,
     default_main_program,
     default_startup_program,
+    name_scope,
     program_guard,
     unique_name,
 )
@@ -117,7 +118,9 @@ class Optimizer:
         # partition (core/executor._accum_step) runs it once per applied
         # step, after the microbatch scan
         prog = default_main_program()
-        with prog.op_role_guard("optimize"):
+        # ... and stands under one name_scope, which the device's
+        # operations answer to (core/lowering.py::op_scope)
+        with prog.op_role_guard("optimize"), name_scope("opt"):
             params_grads = append_gradient_clip_ops(params_grads)
             params_grads = append_regularization_ops(
                 params_grads, self.regularization)

@@ -253,8 +253,14 @@ class ParallelEngine:
         # compiles, which the heartbeat tells the watchdog
         sig = (site, fn)
         t_call = time.perf_counter()
+        # the first dispatch also notes the way to the plan's name table
+        # (observe/device_names.py): the one step's text shares
+        # lowered_hlo's slot
         with _dispatch_guard(plan, sig, (feeds, const_state, mut_state,
-                                         rng)) as (loads, _):
+                                         rng), fn=fn,
+                             hlo_key=("optimized", 1, False)
+                             if steps <= 1 else None,
+                             within=self.mesh) as (loads, _):
             fetches, new_mut, new_pure, new_rng = fn(
                 feeds, const_state, mut_state, rng)
         t_done = time.perf_counter()
@@ -293,12 +299,19 @@ class ParallelEngine:
                 validate_stacked_feeds(plan.feed_names, feeds, steps)
             fn, _ = self._multi_fn(plan, steps, feed_stacked)
         key = (stage, steps, feed_stacked)
+
+        args = (feeds, const_state, mut_state, rng)
+        if stage == "optimized":
+            from ..observe import device_names
+
+            def lower():
+                with self.mesh:
+                    return fn.lower(*args)
+
+            return device_names.optimized_text(plan, key, lower)
         if key not in plan.hlo_text:
             with self.mesh:
-                lowered = fn.lower(feeds, const_state, mut_state, rng)
-            plan.hlo_text[key] = (
-                lowered.as_text() if stage == "stablehlo"
-                else lowered.compile().as_text())
+                plan.hlo_text[key] = fn.lower(*args).as_text()
         return plan.hlo_text[key]
 
     def _with_ext_rules(self) -> ShardingRules:

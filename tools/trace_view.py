@@ -10,6 +10,8 @@ This is the post-mortem reader:
     python tools/trace_view.py flight.json --validate # pairing/site checks
     python tools/trace_view.py flight.json --chrome out.json
                                                       # chrome://tracing
+    python tools/trace_view.py flight.json --xplane run.xplane.pb
+                                        # device time by model scope, gaps
 
 The summary leads with what a wedge post-mortem needs first: the dump
 reason, the recorded wedge/fault context, and every OPEN span (a ``B``
@@ -20,6 +22,18 @@ falls out of the same file, and the "program loads" table: one row a
 dispatch in which JAX traced, lowered and compiled or loaded a program
 (plan, function, ``nth`` = which load of the plan's signature, cache
 hit/miss/off, seconds a stage, and why a plan loaded AGAIN).
+
+``--xplane`` joins the dump with a kept ``jax.profiler`` trace of the
+same process (``benchmarks/run.py --keep-trace DIR``): (a) for every
+program the device ran (a module of the ``XLA Modules`` line), its device
+time by scope class and by layer, through the name tables the dump
+carries (``extra.device_names``: the tables somebody asked
+``observe/device_names.py`` for before the dump; the plan is the one
+whose table holds ALL the module's instructions: a sibling program nobody
+asked the table of, another prompt length's prefill, can pass for it); (b) the ten longest idle
+gaps of the first chip, each named by the INNERMOST program span over it
+(every span is a profiler annotation of its site) and the chain of spans
+that contain it.
 
 ``--validate`` holds the dump to the recorder's own grammar: every
 ``E`` has a matching ``B``, durations are non-negative and consistent
@@ -43,8 +57,14 @@ if _ROOT not in sys.path:
 
 
 def load_dump(path: str) -> dict:
-    with open(path) as f:
-        d = json.load(f)
+    if path.endswith(".gz"):
+        import gzip
+
+        with gzip.open(path, "rt") as f:
+            d = json.load(f)
+    else:
+        with open(path) as f:
+            d = json.load(f)
     if "events" not in d:
         raise ValueError("%s is not a flight-recorder dump "
                          "(no 'events' key)" % path)
@@ -147,6 +167,127 @@ def print_program_loads(dump: dict, out=sys.stdout) -> None:
                  r["committed"], r["why"]), file=out)
 
 
+# ------------------------------------------------ a kept device profile
+def _inside(starts, events, lo, hi):
+    import bisect
+
+    return events[bisect.bisect_left(starts, lo):
+                  bisect.bisect_left(starts, hi)]
+
+
+def device_by_scope(dump: dict, planes) -> list:
+    """One row a program the first chip ran (a name of its ``XLA
+    Modules`` line), longest first: the plan whose name table knows the
+    module's instructions and places most of them (None where the dump
+    carries no such table: nobody asked for that plan's), the runs, the device seconds of its leaf
+    operations, the share of them the table places, and those seconds by
+    scope class and by layer."""
+    from benchmarks.lib import xplane
+    from paddle_tpu.observe.device_names import layer_of, scope_class
+
+    tables = (dump.get("extra") or {}).get("device_names") or {}
+    ops = xplane.device_ops(planes)
+    runs = xplane.device_ops(planes, "XLA Modules")
+    if not ops:
+        return []
+    chip = min(ops)
+    leaves = xplane.leaves(ops[chip])
+    starts = [e[1] for e in leaves]
+    by_module = defaultdict(list)
+    for name, start, dur, _op in runs.get(chip, ()):
+        by_module[name].append((start, start + dur))
+    rows = []
+    for module, spans in by_module.items():
+        events = [e for lo, hi in spans
+                  for e in _inside(starts, leaves, lo, hi)]
+        if not events:
+            continue
+        names = {e[0] for e in events}
+        plan, held = None, 0
+        for tag, table in tables.items():
+            # the module's own table knows (nearly) all of its names
+            known = sum(1 for x in names if x in table["names"])
+            n = sum(1 for x in names if table["names"].get(x) is not None)
+            if known == len(names) and n > held:
+                plan, held = tag, n
+        placed = tables[plan]["names"] if plan is not None else {}
+        by_class, by_layer = defaultdict(float), defaultdict(float)
+        for name, _start, dur, _op in events:
+            path = placed.get(name)
+            by_class[scope_class(path) or "unscoped"] += dur
+            by_layer[layer_of(path) or "-"] += dur
+        rows.append({"module": module, "plan": plan, "runs": len(spans),
+                     "seconds": sum(e[2] for e in events),
+                     "placed_pct": 100.0 * held / len(names),
+                     "by_class": dict(by_class),
+                     "by_layer": dict(by_layer)})
+    rows.sort(key=lambda r: -r["seconds"])
+    return rows
+
+
+def idle_gaps(planes, top: int = 10) -> list:
+    """The ``top`` longest stretches of the traced window (the harness's
+    ``bench.window`` annotation; the whole profile without one) in which
+    the first chip ran nothing: ``(seconds, innermost, chain)``, the
+    program spans that hold the gap's middle from the outermost in, the
+    last of them the innermost."""
+    from benchmarks.lib import xplane
+    from paddle_tpu.observe.families import TRACE_SITES
+
+    ops = xplane.device_ops(planes)
+    if not ops:
+        return []
+    events = ops[min(ops)]
+    spans = [s for s in xplane.annotations(planes, "")
+             if s[0] in TRACE_SITES or s[0].startswith("bench.")]
+    window = [s for s in spans if s[0] == "bench.window"]
+    t0 = window[0][1] if window else events[0][1]
+    t1 = t0 + window[0][2] if window else events[-1][1] + events[-1][2]
+    gaps, cursor = [], t0
+    for a, b in xplane.union(xplane.clip(events, t0, t1)) + [(t1, t1)]:
+        if a > cursor:
+            gaps.append((a - cursor, cursor, a))
+        cursor = max(cursor, b)
+    out = []
+    for length, a, b in sorted(gaps, reverse=True)[:top]:
+        mid = (a + b) / 2
+        chain = sorted((s for s in spans if s[0] != "bench.window"
+                        and s[1] <= mid <= s[1] + s[2]),
+                       key=lambda s: -s[2])
+        out.append((length, chain[-1][0] if chain else "-",
+                    [s[0] for s in chain]))
+    return out
+
+
+def print_device_view(dump: dict, xplane_path: str, out=sys.stdout) -> None:
+    from benchmarks.lib import xplane
+
+    planes = xplane.load(xplane_path)
+    rows = device_by_scope(dump, planes)
+    print("device time by model scope, first chip (%s):" % xplane_path,
+          file=out)
+    for r in rows:
+        print("\n%s  plan=%s  runs=%d  %.6f s  (%.1f%% of its "
+              "instructions placed)" % (r["module"], r["plan"], r["runs"],
+                                        r["seconds"], r["placed_pct"]),
+              file=out)
+        for title, part in (("class", r["by_class"]),
+                            ("layer", r["by_layer"])):
+            print("  %-14s %12s %8s %12s" % (title, "seconds", "share",
+                                             "ms a run"), file=out)
+            for k in sorted(part, key=lambda k: -part[k]):
+                print("  %-14s %12.6f %7.1f%% %12.4f"
+                      % (k, part[k], 100 * part[k] / r["seconds"],
+                         part[k] / r["runs"] * 1e3), file=out)
+    print("\nlongest idle gaps of the first chip, by the innermost "
+          "program span over each:", file=out)
+    print("  %10s  %-28s %s" % ("seconds", "innermost", "inside"),
+          file=out)
+    for length, inner, chain in idle_gaps(planes):
+        print("  %10.6f  %-28s %s" % (length, inner, " > ".join(chain)),
+              file=out)
+
+
 def summarize(dump: dict, out=sys.stdout) -> None:
     evs = dump["events"]
     print("flight recorder dump: pid=%s reason=%s events=%d "
@@ -155,6 +296,10 @@ def summarize(dump: dict, out=sys.stdout) -> None:
              dump.get("recorded_total"), dump.get("capacity")), file=out)
     extra = dump.get("extra") or {}
     for k, v in sorted(extra.items()):
+        if k == "device_names":     # whole tables: --xplane reads them
+            v = {plan: "%d instructions (%s)" % (len(t["names"]),
+                                                 t["source"])
+                 for plan, t in v.items()}
         print("  %s: %s" % (k, json.dumps(v, sort_keys=True)), file=out)
     opens = open_spans(dump)
     if opens:
@@ -265,6 +410,10 @@ def main(argv=None) -> int:
     ap.add_argument("--validate", action="store_true",
                     help="check B/E pairing, durations and declared "
                          "sites; exit 1 on violations")
+    ap.add_argument("--xplane", default=None, metavar="XPLANE_PB",
+                    help="a kept jax.profiler trace of the same process: "
+                         "device time by model scope, and the longest "
+                         "idle gaps by their innermost program span")
     ap.add_argument("--chrome", default=None, metavar="OUT",
                     help="write chrome://tracing JSON (open B spans "
                          "render as dangling slices — the wedge)")
@@ -288,6 +437,9 @@ def main(argv=None) -> int:
         return 0
     if args.trace:
         show_trace(dump, args.trace)
+        return 0
+    if args.xplane:
+        print_device_view(dump, args.xplane)
         return 0
     summarize(dump)
     return 0
