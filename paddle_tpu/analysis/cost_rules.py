@@ -492,6 +492,11 @@ def _cost_attention(ctx):
     vs = ctx.input_shape("V")
     v_elems = q_elems if vs is None or len(vs) != len(qs) \
         else ctx.elems(tuple(qs[:-1]) + (vs[-1],)) or q_elems
+    if ctx.op.inputs.get("KR"):
+        # a shared key part: K and V are one tensor a head's keys and
+        # values wide, and the scores contract over Q's width and QR's
+        v_elems = ctx.elems(tuple(qs[:-1]) + (vs[-1] - qs[-1],)) or q_elems
+        q_elems = q_elems + ctx.elems(ctx.input_shape("QR"))
     flops = _contract_scaled(q_elems, ks[-2]) \
         + _contract_scaled(v_elems, ks[-2]) + scores.scaled(10)
     window = int(ctx.attr("window", 0) or 0)

@@ -131,11 +131,14 @@ def _r_fused_attention(ctx):
     width; K is [B, Hkv, Sk, D] and V [B, Hkv, Sk, Dv] with Hkv dividing
     H (grouped heads), and a window is a causal call's. Rank-3 Q, K, V
     are [B, Sq, H*D], [B, Sk, H*D] and [B, Sk, H*D] with ``n_head`` heads
-    and take no window."""
+    and take no window. With a shared key part KR [B, Sk, Dr] (and QR
+    [B, Sq, H*Dr]) K and V are one tensor [B, Sk, H*(D + Dv)] and Out is
+    [B, Sq, H*Dv]."""
     qs, ks, vs = (ctx.input_shape(s) for s in ("Q", "K", "V"))
+    shared = bool(ctx.op.inputs.get("KR"))
     if qs is not None:
         out = qs if vs is None or len(vs) != len(qs) \
-            else tuple(qs[:-1]) + (vs[-1],)
+            else tuple(qs[:-1]) + (vs[-1] - qs[-1] if shared else vs[-1],)
         ctx.set("Out", out)
         if "Mask" in ctx.op.outputs:
             ctx.set("Mask", out)
@@ -143,6 +146,20 @@ def _r_fused_attention(ctx):
         ctx.fail("a window needs causal=True")
     if qs is None or ks is None or not (is_concrete(qs[1:])
                                         and is_concrete(ks[1:])):
+        return
+    if len(qs) == 3 and shared:
+        n_head = int(ctx.attr("n_head", 0) or 0)
+        qr, kr = ctx.input_shape("QR"), ctx.input_shape("KR")
+        if n_head <= 0 or qs[-1] % n_head or len(ks) != 3 \
+                or ks[-1] % n_head or ks[-1] <= qs[-1] \
+                or ctx.attr("window", 0) or ctx.op.inputs["V"] \
+                != ctx.op.inputs["K"] or None in (qr, kr) \
+                or tuple(kr[1:-1]) != tuple(ks[1:-1]) \
+                or tuple(qr[1:]) != (qs[1], n_head * kr[-1]):
+            ctx.fail("Q %s, QR %s, K = V %s and KR %s are not [B, Sq, H*D], "
+                     "[B, Sq, H*Dr], ONE [B, Sk, H*(D + Dv)] and [B, Sk, Dr] "
+                     "with n_head=%d heads and no window"
+                     % (qs, qr, ks, kr, n_head))
         return
     if len(qs) == 3:
         n_head = int(ctx.attr("n_head", 0) or 0)

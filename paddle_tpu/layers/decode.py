@@ -94,9 +94,11 @@ def beam_gather(x, parent_idx, name=None):
     return out
 
 
-def rope(x, pos, base=10000.0, name=None, yarn=None):
+def rope(x, pos, base=10000.0, name=None, yarn=None, heads_last=False):
     """Rotary position embedding on a head tensor [..., S, D] (D even,
-    rotate-half convention): position i rotates pair (x_j, x_{j+D/2})
+    rotate-half convention; ``heads_last``: on [B, S, H, D], the heads
+    where a projection's reshape leaves them, so that no transpose
+    stands round the rotation): position i rotates pair (x_j, x_{j+D/2})
     by angle pos_i * base^(-2j/D). `pos` is a [S] int var (or [1] for
     a decode step, or [B, S] for PACKED sequences whose positions
     reset at segment starts) — runtime positions, one executable for
@@ -121,6 +123,8 @@ def rope(x, pos, base=10000.0, name=None, yarn=None):
                      yarn_low=float(yarn["low"]),
                      yarn_high=float(yarn["high"]),
                      yarn_mscale=float(yarn.get("mscale", 1.0)))
+    if heads_last:
+        attrs["heads_last"] = True
     helper.append_op(type="rope", inputs={"X": [x], "Pos": [pos]},
                      outputs={"Out": [out]}, attrs=attrs)
     out.shape = x.shape

@@ -1294,7 +1294,8 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32", name=None
 
 def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                     causal=False, segment_ids=None, window=None, name=None,
-                    mxu_dtype=None, flash_min_seq=None, n_head=None):
+                    mxu_dtype=None, flash_min_seq=None, n_head=None,
+                    q_r=None, k_r=None):
     """Single-kernel scaled-dot-product attention over [B,H,S,D] tensors
     (Pallas flash kernel; see ops/attention.py). The reference composes
     this from matmul+softmax layer calls — SURVEY §5. ``causal=True``
@@ -1308,8 +1309,9 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     last axis themselves and no transpose stands on either side (the
     rank picks the layout; ``bias`` keeps [B|1,H|1,Sq|1,Sk]). A lowering
     that cannot take the kernel so (a short S's composed form, the ring)
-    splits the heads inside and means the same. The forward-only
-    arguments below are rank-4 only.
+    splits the heads inside and means the same. Of the forward-only
+    arguments below rank 3 takes the shared key part alone (and with it
+    ``mxu_dtype`` and ``flash_min_seq``).
 
     ``segment_ids`` ([B,S] int, 0 = padding — reader.pack_sequences
     layout) restricts attention to same-segment real keys for PACKED
@@ -1324,11 +1326,18 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
     ``k``/``v`` may hold fewer heads than ``q`` (grouped heads, no
     repeated copy) and ``v`` a last axis of its own, which the output
     takes. ``mxu_dtype`` (``"bfloat16"``) rounds float32 operands to it
-    where the kernel multiplies: one MXU pass, the precision XLA's own
-    float32 products have on the chip. ``flash_min_seq`` is this call's
-    threshold for the kernel in place of the static default (the
-    environment's ``PADDLE_TPU_FLASH_MIN_SEQ`` still wins). All of these
-    are forward-only (a serving prefill)."""
+    before the kernel, where XLA folds the convert into whatever wrote
+    them: one MXU pass, the precision XLA's own float32 products have on
+    the chip, on operands of half the bytes. ``flash_min_seq`` is this
+    call's threshold for the kernel in place of the static default (the
+    environment's ``PADDLE_TPU_FLASH_MIN_SEQ`` still wins).
+    ``q_r`` [B,S,H*Dr] with ``k_r`` [B,Sk,Dr] (rank 3) is latent
+    attention's expanded form read where its projections wrote it: a
+    second part of every head's query and the ONE key part all heads
+    share, a score ``q k^T + q_r k_r^T``; ``k`` and ``v`` are then one
+    tensor [B,Sk,H*(D+Dv)] passed as both, head h's keys beside its
+    values, and ``Out`` is [B,S,H*Dv]: no head's keys or values are
+    built. All of these are forward-only (a serving prefill)."""
     if window is not None and (not causal or int(window) < 1):
         raise ValueError("fused_attention: window=%r needs causal=True and "
                          "window >= 1" % (window,))
@@ -1336,6 +1345,10 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
         raise ValueError("fused_attention: rank-3 q, k, v are [B,S,H*D] and "
                          "need n_head, a divisor of the last axis; got "
                          "n_head=%r for %r" % (n_head, tuple(q.shape)))
+    if (q_r is None) != (k_r is None) or (k_r is not None and not (
+            len(q.shape) == 3 and k is v)):
+        raise ValueError("fused_attention: q_r and k_r come together, with "
+                         "rank-3 q and ONE tensor as both k and v")
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     mask = helper.create_variable_for_type_inference(q.dtype)
@@ -1345,6 +1358,8 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
         inputs["Bias"] = [bias]
     if segment_ids is not None:
         inputs["SegmentIds"] = [segment_ids]
+    if k_r is not None:
+        inputs["QR"], inputs["KR"] = [q_r], [k_r]
     helper.append_op(type="fused_attention", inputs=inputs,
                      outputs={"Out": [out], "Mask": [mask]},
                      # is_test: Program.clone(for_test=True) and the
@@ -1361,7 +1376,9 @@ def fused_attention(q, k, v, bias=None, scale=1.0, dropout=0.0,
                                    if flash_min_seq else {}),
                                 **({"n_head": int(n_head)}
                                    if len(q.shape) == 3 else {})))
-    out.shape = tuple(q.shape[:-1]) + tuple(v.shape[-1:])
+    out.shape = tuple(q.shape[:-1]) + (
+        (v.shape[-1] - q.shape[-1],) if k_r is not None
+        else tuple(v.shape[-1:]))
     return out
 
 

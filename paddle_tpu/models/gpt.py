@@ -977,11 +977,12 @@ def _fc(x, width, name):
                      param_attr=ParamAttr(name=name))
 
 
-def _mla_q(cfg, h, nm, S, pos):
+def _mla_q(cfg, h, nm, S, pos, in_place=False):
     """Latent attention's queries of ``h [B, S, D]`` as ``[B, S, H,
     d_nope]`` and ``[B, S, H, d_rope]``, the second rotated at ``pos``
     ([S], or per-slot [B, 1] where S is 1: the angles then broadcast
-    over the head axis)."""
+    over the head axis). ``in_place`` rotates the heads where the
+    projection left them (no transpose either side of the rotation)."""
     n_head, dn, dr = cfg["n_head"], cfg["d_nope"], cfg["d_rope"]
     with name_scope("attn.qkv"):
         h = layers.rms_norm(
@@ -997,7 +998,9 @@ def _mla_q(cfg, h, nm, S, pos):
         q = layers.reshape(q, [-1, S, n_head, dn + dr])
         q_nope = layers.slice(q, axes=[3], starts=[0], ends=[dn])
         q_rope = layers.slice(q, axes=[3], starts=[dn], ends=[dn + dr])
-        if S > 1:
+        if in_place:
+            q_rope = _rope(cfg, q_rope, pos, heads_last=True)
+        elif S > 1:
             # positions index the axis before the last: [B, H, S, d_rope]
             q_rope = layers.transpose(
                 _rope(cfg, layers.transpose(q_rope, perm=[0, 2, 1, 3]), pos),
@@ -1007,10 +1010,10 @@ def _mla_q(cfg, h, nm, S, pos):
     return q_nope, q_rope
 
 
-def _mla_row(cfg, h, nm, S, pos):
-    """What a latent layer keeps of each token of ``h [B, S, D]``:
-    ``[B, 1, S, d_c + d_rope]`` = the normed latent ``c`` beside the one
-    rotated key part ``k_r`` all heads share."""
+def _mla_latent(cfg, h, nm, S, pos):
+    """The two parts of a latent layer's cache row over ``h [B, S, D]``:
+    the normed latent ``c [B, S, d_c]`` and the one rotated key part all
+    heads share, ``k_r [B, 1, S, d_rope]``."""
     dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
     with name_scope("attn.qkv"):
         kv = _fc(h, dc + dr, nm + "_att_kva.w_0")
@@ -1025,18 +1028,28 @@ def _mla_row(cfg, h, nm, S, pos):
         k_r = _rope(cfg, layers.reshape(
             layers.slice(kv, axes=[2], starts=[dc], ends=[dc + dr]),
             [-1, 1, S, dr]), pos)
-        return layers.concat([layers.reshape(c, [-1, 1, S, dc]), k_r],
-                             axis=3)
+    return c, k_r
+
+
+def _mla_row(cfg, c, k_r, S):
+    """What a latent layer keeps of each token: ``[B, 1, S, d_c +
+    d_rope]`` = the normed latent ``c`` beside the one rotated key part
+    ``k_r`` all heads share (``_mla_latent``'s two)."""
+    with name_scope("attn.core"):
+        return layers.concat(
+            [layers.reshape(c, [-1, 1, S, cfg["kv_lora_rank"]]), k_r],
+            axis=3)
 
 
 def _mla_expanded(cfg, h, nm, S, pos):
     """The expanded form's operands over ``h [B, S, D]``: ``(q, k, v,
     row)`` with q and k ``[B, H, S, d_nope + d_rope]``, v ``[B, H, S,
     d_v]`` (every head's keys and values rebuilt from the latent) and
-    ``row`` the cache rows ``_mla_row`` gives."""
+    ``row`` the cache rows ``_mla_row`` gives. The training build's
+    composed attention takes these; the prefill takes ``_mla_packed``'s."""
     n_head, dn, dv = cfg["n_head"], cfg["d_nope"], cfg["d_v"]
     dc, dr = cfg["kv_lora_rank"], cfg["d_rope"]
-    row = _mla_row(cfg, h, nm, S, pos)
+    row = _mla_row(cfg, *_mla_latent(cfg, h, nm, S, pos), S)
     with name_scope("attn.qkv"):
         c = layers.reshape(
             layers.slice(row, axes=[3], starts=[0], ends=[dc]), [-1, S, dc])
@@ -1054,6 +1067,26 @@ def _mla_expanded(cfg, h, nm, S, pos):
         q = layers.transpose(layers.concat([q_nope, q_rope], axis=3),
                              perm=[0, 2, 1, 3])
     return q, k, v, row
+
+
+def _mla_packed(cfg, h, nm, S, pos):
+    """The expanded form's operands over ``h [B, S, D]`` where the
+    projections wrote them, for the fused-attention op's shared key
+    part: ``(q [B, S, H d_nope], q_r [B, S, H d_rope], kv [B, S, H
+    (d_nope + d_v)], k_r [B, S, d_rope], row)``. ``kv`` is ``kvb``'s
+    output as it stands, head ``h``'s keys beside its values; no head's
+    keys or values are built, and nothing is transposed."""
+    n_head, dn, dv, dr = (cfg[k] for k in ("n_head", "d_nope", "d_v",
+                                           "d_rope"))
+    c, k_r = _mla_latent(cfg, h, nm, S, pos)
+    row = _mla_row(cfg, c, k_r, S)
+    with name_scope("attn.qkv"):
+        kv = _fc(c, n_head * (dn + dv), nm + "_att_kvb.w_0")
+    q_nope, q_rope = _mla_q(cfg, h, nm, S, pos, in_place=True)
+    with name_scope("attn.core"):
+        return (layers.reshape(q_nope, [-1, S, n_head * dn]),
+                layers.reshape(q_rope, [-1, S, n_head * dr]), kv,
+                layers.reshape(k_r, [-1, S, dr]), row)
 
 
 def _mla_scale(cfg):
@@ -1218,9 +1251,10 @@ def _yarn(cfg, dim):
                 / _yarn_mscale(factor, rs.get("mscale_all_dim", 0)))
 
 
-def _rope(cfg, x, pos):
+def _rope(cfg, x, pos, heads_last=False):
     return layers.rope(x, pos, base=_rope_base(cfg),
-                       yarn=_yarn(cfg, int(x.shape[-1])))
+                       yarn=_yarn(cfg, int(x.shape[-1])),
+                       heads_last=heads_last)
 
 
 def _qk_norm(cfg, q, k, nm):
@@ -1851,20 +1885,20 @@ def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
             name=nm + "_cache_c",
             shape=(batch, 1, rows, latent_width(cfg)))
         cache_names.append(cc.name)
-        q, k, v, row = _mla_expanded(cfg, h, nm, P, pos_range)
+        q, q_r, kv, k_r, row = _mla_packed(cfg, h, nm, P, pos_range)
         with name_scope("attn.core"):
             _prefill_cache_write(cc, row, P, rows, zero)
             # compute-bound at 128 heads: the kernel from one lane tile
             # on (every prompt length of a cell runs, and is measured
             # as, the one attention form) and on bfloat16 MXU operands,
-            # as kernels/moe_gmm.py rounds its float32 ones
+            # as kernels/moe_gmm.py rounds its float32 ones. The op reads
+            # q, k and v where the projections wrote them and writes the
+            # context where the output projection reads it
             ctxv = layers.fused_attention(
-                q, k, v, scale=_mla_scale(cfg), causal=True,
-                mxu_dtype="bfloat16", flash_min_seq=128)
+                q, kv, kv, scale=_mla_scale(cfg), causal=True,
+                mxu_dtype="bfloat16", flash_min_seq=128, n_head=n_head,
+                q_r=q_r, k_r=k_r)
             _note_mla_expanded(cfg, "fused_attention")
-            ctxv = layers.reshape(
-                layers.transpose(ctxv, perm=[0, 2, 1, 3]),
-                [-1, P, n_head * cfg["d_v"]])
         return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
                            **tally)
     ck = helper.create_global_variable(
@@ -2060,7 +2094,7 @@ def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
             shape=(batch, 1, rows, latent_width(cfg)))
         cache_names.append(cc.name)
         h, mix = _sub_input(cfg, x, nm, 1, dev)
-        row = _mla_row(cfg, h, nm, 1, pos)
+        row = _mla_row(cfg, *_mla_latent(cfg, h, nm, 1, pos), 1)
         with name_scope("attn.core"):
             cc = layers.kv_cache_write(cc, row, pos)
         q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)

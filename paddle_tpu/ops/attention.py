@@ -73,7 +73,10 @@ execution needs the chip (chip_smoke.py).
 Ragged sequence lengths are padded to a whole number of lane tiles (128)
 with key-side additive masking (-1e9), never to a multiple of the block:
 every planned block divides the padded length, so VMEM stays bounded for
-any S and S 640 is not computed as 1024.
+any S and S 640 is not computed as 1024. The one call that pads nothing
+is the causal forward with a shared key part (the latent prefill): its
+last blocks hang over the operands' end, the causal mask is the padded
+keys' mask, and the kernel zeroes the values' rows past the last key.
 
 Layout: q,k,v [B, H, S, D]; bias broadcastable [B|1, H|1, Sq|1, Sk],
 additive (-1e9 at masked positions). By default the bias is a constant
@@ -323,17 +326,30 @@ def _bias_block(b_ref, h):
 #          p^T g, ds^T q, ds k) it runs at the tile's full width and the
 #          head keeps its own lanes of the result when the tile is
 #          written. No lane moves.
+#          The forward-only call takes one more form of it, picked by the
+#          presence of a key part all heads share (``shared``, the latent
+#          prefill): q [B, S, H*D] beside a second part q_r [B, S, H*Dr],
+#          ONE tensor [B, Sk, H*(D + Dv)] with a head's keys beside its
+#          values (one block, two static lane slices: D and Dv are whole
+#          lane tiles) and k_r [B, Sk, Dr], one block a key step for every
+#          head; a score is q k^T + q_r k_r^T, two MXU passes as a
+#          (D + Dr)-deep contraction costs.
 class _Lanes:
-    """Where head h lies in a packed [1, rows, heads*D] block."""
+    """Where head h lies in a packed [1, rows, heads*D] block. ``pitch``
+    and ``first`` place a head's ``D`` lanes inside a wider record a head
+    (``first`` lanes into each ``pitch``: a head's keys and its values
+    side by side in one tensor, whole lane tiles each)."""
 
-    def __init__(self, D):
+    def __init__(self, D, pitch=None, first=0):
         self.D = D
         self.W = D if D % _LANE == 0 else _LANE   # lanes a head is read in
         self.per = self.W // D                    # heads a tile
+        self.pitch, self.first = pitch or self.W, first
 
     def tile(self, h):
         t = h // self.per
-        return slice(t * self.W, (t + 1) * self.W)
+        return slice(t * self.pitch + self.first,
+                     t * self.pitch + self.first + self.W)
 
     def own(self, shape, h):
         """bool ``shape``: the lanes of head ``h`` inside its tile. (This
@@ -657,36 +673,50 @@ def _dot_f32(a, b, ca, cb):
 
 
 def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
-                window=None, mxu_dtype=None, lanes=None):
-    q_ref, k_ref, v_ref = refs[:3]
-
-    def mxu(t):
-        # float32 operands rounded for the MXU where the call asks for it
-        # (one bf16 pass, as XLA's own float32 products at the TPU's
-        # default precision; Mosaic multiplies float32 in several)
-        return t if mxu_dtype is None else t.astype(mxu_dtype)
-
-    b_ref = refs[3] if has_bias else None
-    o_ref, lse_ref = refs[3 + has_bias:5 + has_bias]
+                window=None, lanes=None, shared=None, sk=None):
+    if shared is None:
+        q_ref, k_ref, v_ref = refs[:3]
+        n_in, lk, lv, lo = 3, lanes, lanes, lanes
+    else:
+        # (q, q_r, kv, k_r): a head's keys and values lie side by side in
+        # ONE block, and the key part ``k_r`` all heads share is one more
+        q_ref, qr_ref, k_ref, kr_ref = refs[:4]
+        v_ref = k_ref
+        n_in, (lr, lk, lv, lo) = 4, shared
+    b_ref = refs[n_in] if has_bias else None
+    o_ref, lse_ref = refs[n_in + has_bias:n_in + 2 + has_bias]
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
     def scores(h, masked):
         # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
         # with f32 accumulation; only the softmax state is explicitly f32
-        s = _dot_f32(mxu(_head(q_ref, h, lanes, own=True)),
-                     mxu(_head(k_ref, h, lanes)), 1, 1) * scale  # [bq,bk]
+        s = _dot_f32(_head(q_ref, h, lanes, own=True),
+                     _head(k_ref, h, lk), 1, 1)           # [bq, bk]
+        if shared is not None:
+            s = s + _dot_f32(_head(qr_ref, h, lr), kr_ref[0], 1, 1)
+        s = s * scale
         if b_ref is not None:
             s = s + _bias_block(b_ref, h)
         return _causal_mask(s, iq, ik, bq, bk, window=window) \
             if masked else s
 
+    def values(h, masked):
+        v = _head(v_ref, h, lv)                           # [bk, D]
+        if sk is not None and masked:
+            # a ragged last key block: the rows past the last key hold
+            # whatever the buffer held, and 0 x that is not 0
+            row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jax.lax.select(jax.lax.lt(row, sk - ik * bk), v,
+                               jax.lax.full_like(v, 0))
+        return v
+
     if nk == 1:
         # one block holds every key of the row: one softmax and one
         # write, no running max/denominator/accumulator to carry
-        out = _HeadOut(o_ref, lanes)
+        out = _HeadOut(o_ref, lo)
         for h in range(heads):
-            v = mxu(_head(v_ref, h, lanes))               # [bk, D]
+            v = values(h, causal)
             s = scores(h, causal)
             m = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
             p = jnp.exp(s - m)                            # [bq, bk] f32
@@ -696,7 +726,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
             lse_ref[h] = _to_row(lse) if rows else lse
         return
 
-    acc_ref, m_ref, l_ref = refs[5 + has_bias:]
+    acc_ref, m_ref, l_ref = refs[n_in + 2 + has_bias:]
 
     @pl.when(ik == 0)
     def _init():
@@ -708,7 +738,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         for h in range(heads):
             m_h, l_h, acc_h = (_carry(r, h, lanes)
                                for r in (m_ref, l_ref, acc_ref))
-            v = mxu(_head(v_ref, h, lanes))           # [bk, D]
+            v = values(h, masked)
             s = scores(h, masked)
             m_prev = m_h[...]                         # [bq, 1]
             l_prev = l_h[...]
@@ -724,7 +754,7 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
 
     @pl.when(ik == nk - 1)
     def _emit():
-        out = _HeadOut(o_ref, lanes)
+        out = _HeadOut(o_ref, lo)
         for h in range(heads):
             l = _carry(l_ref, h, lanes)[...]
             out.put(h, _carry(acc_ref, h, lanes)[...] / l)
@@ -740,7 +770,7 @@ def _packed_dims(q, k, n_head):
 
 
 def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
-                    window=None, mxu_dtype=None, n_head=None):
+                    window=None, mxu_dtype=None, n_head=None, shared=None):
     """The forward kernel's call. ``window`` (an int, with ``causal``)
     bands the mask: blocks wholly outside the band are skipped on both
     sides and never fetched (the key block index is held inside the band,
@@ -751,12 +781,33 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     width of its own (latent attention's expanded form: q and k 192 wide,
     v 128): its blocks, the accumulator and the output take ``v``'s, the
     score tile is [bq, bk] whatever the widths. ``mxu_dtype`` rounds the
-    three operands to it where they meet the MXU (``_fwd_kernel``).
+    operands to it HERE, before the call (XLA folds the convert into
+    whatever wrote them, so they are written, and fetched, at half the
+    bytes); the output keeps the operands' own dtype. Interpret mode
+    multiplies float32 exactly, as the composed form does, and rounds
+    nothing.
     Rank-3 operands are the lanes layout ([B, S, H*D] with ``n_head``
     heads, ``flash_attention`` has refused what it does not take): the
-    output comes back [B, S, H*D] too, the statistics [B*H, S] always."""
-    lanes = None
-    if q.ndim == 3:
+    output comes back [B, S, H*D] too, the statistics [B*H, S] always.
+    ``shared = (q_r, k_r)`` (rank 3) is latent attention's expanded form
+    as its projections write it: ``k`` IS ``v``, one [B, Sk, H*(D + Dv)]
+    tensor with head ``h``'s keys beside its values, ``q_r`` [B, S, H*Dr]
+    a second part of every head's query and ``k_r`` [B, Sk, Dr] the ONE
+    key part all heads share; a score is ``q k^T + q_r k_r^T``. A causal
+    call of this form pads nothing: the last blocks are ragged, the mask
+    keeps every key past the last from every real query and the kernel
+    zeroes the values' rows there."""
+    lanes, out_dtype = None, q.dtype
+    if mxu_dtype is not None and not _use_interpret():
+        q, k, v = (t.astype(mxu_dtype) for t in (q, k, v))
+        if shared is not None:
+            shared = tuple(t.astype(mxu_dtype) for t in shared)
+    if shared is not None:
+        B, S, H, Sk = q.shape[0], q.shape[1], int(n_head), k.shape[1]
+        D = q.shape[2] // H
+        Hkv, Dv = H, k.shape[2] // H - D
+        lanes = _Lanes(D)
+    elif q.ndim == 3:
         B, H, S, D, Sk = _packed_dims(q, k, n_head)
         Hkv, Dv, lanes = H, D, _Lanes(D)
     else:
@@ -783,12 +834,18 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     _note_plan(name, bq, bk, nk == 1,
                None if window is None
                else (_band_blocks(nq, nk, bq, bk, window), nq * nk), lanes)
-    bias = _pad_bias(bias, S, Sp, Sk, Skp)
-    heads = 1 if group > 1 \
-        else _heads_per_step(H, nk == 1, bias, width=max(D, Dv), lanes=lanes)
+    # the causal mask is the padded keys' mask where nothing is padded
+    ragged = shared is not None and causal
+    if bias is not None or not ragged:
+        bias = _pad_bias(bias, S, Sp, Sk, Skp)
+    heads = 1 if group > 1 else _heads_per_step(
+        H, nk == 1, bias, width=D + Dv if shared is not None else max(D, Dv),
+        lanes=lanes)
     rows = _stat_rows(bq)
-    q = _pad_axis(q, seq, Sp)
-    k, v = _pad_axis(k, seq, Skp), _pad_axis(v, seq, Skp)
+    if not ragged:
+        q = _pad_axis(q, seq, Sp)
+        k = _pad_axis(k, seq, Skp)
+        v = k if shared is not None else _pad_axis(v, seq, Skp)
     if lanes is None:
         q = q.reshape(B * H, Sp, D)
         k, v = k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, Dv)
@@ -809,26 +866,44 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         return (bh // group if group > 1 else bh, kv_seq(bh, iq, ik), 0)
 
     q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, iq, ik: iq)
-    if lanes is None:
-        kv_specs = [pl.BlockSpec((heads, bk, D), kv_map),
-                    pl.BlockSpec((heads, bk, Dv), kv_map)]
+    if shared is not None:
+        # q_r and k_r in whole lane tiles (a head of q_r a tile, zeros
+        # behind its Dr lanes: no lane moves in the kernel, and the MXU
+        # pass is a tile deep whatever Dr)
+        q_r, k_r = shared
+        Dr, Wr = k_r.shape[2], _ceil_to(k_r.shape[2], _LANE)
+        q_r = _pad_axis(q_r.reshape(B, S, H, Dr), 3, Wr).reshape(B, S, H * Wr)
+        k_r = _pad_axis(k_r, 2, Wr)
+        if not ragged:
+            q_r, k_r = _pad_axis(q_r, 1, Sp), _pad_axis(k_r, 1, Skp)
+        in_specs = [
+            q_spec,
+            _block_spec(lanes, H, heads, bq, Wr, lambda bh, iq, ik: iq),
+            _block_spec(lanes, H, heads, bk, D + Dv, kv_seq),
+            pl.BlockSpec((1, bk, Wr), lambda bh, iq, ik: (
+                jax.lax.div(bh, np.int32(H // heads)), kv_seq(bh, iq, ik),
+                0))]
+        operands = [q, q_r, k, k_r]
+        shared = (_Lanes(Wr), _Lanes(D, D + Dv), _Lanes(Dv, D + Dv, D),
+                  _Lanes(Dv))
     else:
-        kv_specs = [_block_spec(lanes, H, heads, bk, D, kv_seq)] * 2
-    in_specs = [q_spec] + kv_specs
-    operands = [q, k, v]
+        if lanes is None:
+            kv_specs = [pl.BlockSpec((heads, bk, D), kv_map),
+                        pl.BlockSpec((heads, bk, Dv), kv_map)]
+        else:
+            kv_specs = [_block_spec(lanes, H, heads, bk, D, kv_seq)] * 2
+        in_specs = [q_spec] + kv_specs
+        operands = [q, k, v]
     if bias is not None:
         spec, opnd = _bias_spec_and_operand(bias, H, heads, bq, bk, 1, 2)
         in_specs.append(spec)
         operands.append(opnd)
 
-    if mxu_dtype is not None and (jnp.dtype(mxu_dtype) == q.dtype
-                                  or _use_interpret()):
-        mxu_dtype = None    # nothing to round; interpret mode multiplies
-        #                     float32 exactly, as the composed form does
     kern = functools.partial(_fwd_kernel, scale=scale, nk=nk, causal=causal,
                              bq=bq, bk=bk, heads=heads,
                              has_bias=bias is not None, rows=rows,
-                             window=window, mxu_dtype=mxu_dtype, lanes=lanes)
+                             window=window, lanes=lanes, shared=shared,
+                             sk=Sk if ragged and Sk % bk else None)
     # a multi-pass plan carries the output, the row maximum and the
     # denominator in VMEM: one of each a head of the step
     carry = lambda *shape: pltpu.VMEM(  # noqa: E731
@@ -845,19 +920,20 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sp, Dv) if lanes is None
-                                 else (B, Sp, H * Dv), q.dtype),
+                                 else (B, S if ragged else Sp, H * Dv),
+                                 out_dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sp) if rows else (B * H, Sp, 1),
                                  jnp.float32),
         ],
         scratch_shapes=[] if nk == 1 else [
-            carry(bq, Dv if lanes is None else lanes.W),
+            carry(bq, Dv if lanes is None else _Lanes(Dv).W),
             carry(bq, 1),
             carry(bq, 1),
         ],
         interpret=_use_interpret(),
     )
     lse = lse[:, 0, :S] if rows else lse[:, :S, 0]
-    out = out[:, :S]
+    out = out[:, :S]                 # (nothing to cut off a ragged call's)
     return (out.reshape(B, H, S, Dv) if lanes is None else out), lse
 
 
@@ -1239,22 +1315,61 @@ def flash_attention_with_lse(q, k, v, bias=None, scale=1.0, causal=False):
 
 
 _FORWARD_ONLY = ("fused_attention with a window, grouped key/value heads, a "
-                 "value width of its own, mxu_dtype or flash_min_seq is "
-                 "forward-only; a training build composes its band bias "
-                 "(models/gpt.py build)")
+                 "value width of its own, a shared key part, mxu_dtype or "
+                 "flash_min_seq is forward-only; a training build composes "
+                 "its band bias (models/gpt.py build)")
 
 
-def _forward_only(q, k, v, window=None, mxu_dtype=None, min_seq=None):
+def _forward_only(q, k, v, window=None, mxu_dtype=None, min_seq=None,
+                  shared=None):
     """Whether a call asks for what only the serving prefill's forward
-    has: a window, fewer key/value heads, a value width of its own, the
-    MXU's dtype or a threshold of its own. None of it has a backward
-    rule, and none of it is taken in the lanes layout."""
+    has: a window, fewer key/value heads, a value width of its own, a
+    shared key part, the MXU's dtype or a threshold of its own. None of
+    it has a backward rule. The lanes layout takes the shared key part
+    (with it a value width, ``mxu_dtype`` and a threshold: the latent
+    prefill) and refuses the rest."""
     if q.ndim == 3:    # [B, S, H*D]: grouped heads or a value width show
         shaped = (k.shape[-1] != q.shape[-1]    # in the packed width
                   or v.shape[-1] != q.shape[-1])
     else:
         shaped = k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1]
-    return bool(window) or shaped or bool(mxu_dtype) or bool(min_seq)
+    return bool(window) or shaped or bool(mxu_dtype) or bool(min_seq) \
+        or shared is not None
+
+
+def _shared_widths(q, k, v, shared, n_head):
+    """``(D, Dv)`` of a call with a shared key part, checked: q
+    [B, S, H*D], k and v ONE [B, Sk, H*(D + Dv)] tensor, ``shared`` =
+    (q_r [B, S, H*Dr], k_r [B, Sk, Dr])."""
+    q_r, k_r = shared
+    H, D, Dr = n_head, q.shape[-1] // n_head, k_r.shape[-1]
+    Dv = k.shape[-1] // H - D
+    if not (q.ndim == k.ndim == q_r.ndim == k_r.ndim == 3
+            and k.shape == v.shape and k.shape[-1] % H == 0 and Dv > 0
+            and q_r.shape == q.shape[:2] + (H * Dr,)
+            and k_r.shape[:2] == k.shape[:2]):
+        raise ValueError(
+            "a shared key part takes q [B, S, H*D], q_r [B, S, H*Dr], "
+            "k_r [B, Sk, Dr] and ONE tensor [B, Sk, H*(D + Dv)] as both k "
+            "and v; got q %r q_r %r k %r v %r k_r %r with n_head=%d"
+            % (q.shape, q_r.shape, k.shape, v.shape, k_r.shape, H))
+    return D, Dv
+
+
+def _expanded(q, kv, shared, n_head):
+    """The rank-4 operands of a call with a shared key part: q and k
+    [B, H, S, D + Dr], v [B, H, Sk, Dv], every head's keys rebuilt with
+    ``k_r`` behind them — what the kernels' other layout, the composed
+    form and the tests' reference take."""
+    q_r, k_r = shared
+    B, S, H = q.shape[0], q.shape[1], n_head
+    Sk, D = kv.shape[1], q.shape[2] // n_head
+    qh = jnp.concatenate([q.reshape(B, S, H, D), q_r.reshape(B, S, H, -1)],
+                         axis=3).transpose(0, 2, 1, 3)
+    kvh = kv.reshape(B, Sk, H, -1).transpose(0, 2, 1, 3)
+    kh = jnp.concatenate([kvh[..., :D], jnp.broadcast_to(
+        k_r[:, None], (B, H) + k_r.shape[1:])], axis=3)
+    return qh, kh, kvh[..., D:]
 
 
 def _packed_heads(q, n_head):
@@ -1287,7 +1402,7 @@ def _unpacked(fn, q, k, v, n_head):
 
 def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
                     causal=False, window=None, mxu_dtype=None,
-                    min_seq=None, n_head=None):
+                    min_seq=None, n_head=None, shared=None):
     """Fused attention. ``bias`` is a constant additive mask by default
     (non-differentiable: stop_gradient is applied); pass
     ``bias_grad=True`` to get the true bias cotangent, at the cost of an
@@ -1306,9 +1421,10 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
     ``0 <= i - j < window``; ``k``/``v`` with fewer heads than ``q`` are
     grouped heads; ``v`` narrower or wider than ``q``/``k`` gives an
     output of ``v``'s width; ``mxu_dtype`` rounds float32 operands to it
-    where the kernel multiplies (one bf16 MXU pass, as XLA's own float32
-    products at the TPU's default precision; the composed form is left
-    to XLA's). Each is the serving prefill's FORWARD-ONLY call: the
+    before the kernel (one bf16 MXU pass, as XLA's own float32 products
+    at the TPU's default precision, on operands written and fetched at
+    half the bytes; the composed form is left to XLA's). Each is the
+    serving prefill's FORWARD-ONLY call: the
     kernel runs under the name ``flash_fwd_win`` (a window) or
     ``flash_fwd`` and no backward rule exists for it (the training build
     of a windowed layer composes its band bias instead). ``min_seq`` is
@@ -1320,23 +1436,47 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
     kernel runs it blocks the heads along the lanes and nothing is
     transposed round it; below the kernel's threshold, or at a head width
     that does not tile the lanes, the call unpacks to [B, H, S, D] and
-    means the same. ``bias`` keeps its [B|1, H|1, Sq|1, Sk] form. The
-    forward-only features and a trainable bias are rank-4 only."""
+    means the same. ``bias`` keeps its [B|1, H|1, Sq|1, Sk] form.
+
+    ``shared = (q_r, k_r)`` (rank 3, forward-only) is latent attention's
+    expanded form read where its projections wrote it: ``k`` and ``v``
+    are ONE tensor [B, Sk, H*(D + Dv)], head ``h``'s keys beside its
+    values, ``q_r`` [B, S, H*Dr] is a second part of every head's query
+    and ``k_r`` [B, Sk, Dr] the one key part all heads share: a score is
+    ``q k^T + q_r k_r^T``, and no head's keys or values are built. It
+    takes ``mxu_dtype`` and ``min_seq``; where ``D`` or ``Dv`` is not
+    whole lane tiles, or under the threshold, it builds the rank-4
+    operands inside and means the same. The other forward-only features
+    and a trainable bias are rank-4 only."""
     if q.ndim == 3:
         n_head = _packed_heads(q, n_head)
-        if bias_grad or _forward_only(q, k, v, window, mxu_dtype):
+        if shared is not None:
+            D, Dv = _shared_widths(q, k, v, shared, n_head)
+            if window is not None or bias_grad:
+                raise NotImplementedError(
+                    _FORWARD_ONLY + "; a shared key part takes no window "
+                    "and no trainable bias")
+            if not (_flash_decision(q.shape[1], k.shape[1], min_seq)
+                    and D % _LANE == 0 and Dv % _LANE == 0):
+                return _merge_heads(flash_attention(
+                    *_expanded(q, k, shared, n_head), bias, scale,
+                    causal=causal, mxu_dtype=mxu_dtype, min_seq=min_seq))
+        elif bias_grad or _forward_only(q, k, v, window, mxu_dtype):
             raise NotImplementedError(
                 _FORWARD_ONLY + "; [B, S, H*D] operands take none of them, "
                 "nor a trainable bias")
-        if not (_flash_decision(q.shape[1], k.shape[1], min_seq)
-                and _lanes_ok(n_head, q.shape[-1] // n_head)):
+        elif not (_flash_decision(q.shape[1], k.shape[1], min_seq)
+                  and _lanes_ok(n_head, q.shape[-1] // n_head)):
             return _unpacked(
                 lambda a, b, c: flash_attention(
                     a, b, c, bias, scale, causal=causal, min_seq=min_seq),
                 q, k, v, n_head)
-    grouped = q.ndim == 4 and (k.shape[1] != q.shape[1]
-                               or v.shape[-1] != q.shape[-1]
-                               or mxu_dtype is not None)
+    elif shared is not None:
+        raise ValueError("a shared key part takes [B, S, H*D] operands")
+    grouped = shared is not None or (
+        q.ndim == 4 and (k.shape[1] != q.shape[1]
+                         or v.shape[-1] != q.shape[-1]
+                         or mxu_dtype is not None))
     if window is not None or grouped:
         if bias_grad:
             raise ValueError("a window, grouped key/value heads, a value "
@@ -1390,7 +1530,7 @@ def flash_attention(q, k, v, bias=None, scale=1.0, bias_grad=False,
         return _forward_pallas(
             q, k, v, bias, scale, causal=causal, window=window,
             name=KERNEL_FWD_WIN if banded else KERNEL_FWD,
-            mxu_dtype=mxu_dtype)[0]
+            mxu_dtype=mxu_dtype, n_head=n_head, shared=shared)[0]
     if bias is None:
         return _fa_maskbias(q, k, v, None, scale, causal, n_head)
     if bias_grad:
@@ -1411,7 +1551,7 @@ def _seg_mask_full(seg):
 
 def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
                               seg=None, window=None, mxu_dtype=None,
-                              min_seq=None, n_head=None):
+                              min_seq=None, n_head=None, shared=None):
     """Mosaic kernels cannot be auto-partitioned by the SPMD partitioner
     (jax raises at multi-device lowering), so under a ParallelEngine mesh
     the op-level flash call wraps itself in shard_map: batch shards over
@@ -1436,20 +1576,21 @@ def _maybe_shard_mapped_flash(ctx, q, k, v, bias, scale, causal=False,
     rank 4 runs."""
     mesh = getattr(ctx, "mesh", None)
     packed = q.ndim == 3
-    if _forward_only(q, k, v, window, mxu_dtype, min_seq):
-        if packed:
+    if _forward_only(q, k, v, window, mxu_dtype, min_seq, shared):
+        if packed and shared is None:
             raise NotImplementedError(
-                _FORWARD_ONLY + "; [B, S, H*D] operands take none of them")
+                _FORWARD_ONLY + "; [B, S, H*D] operands take none of them "
+                "without a shared key part")
         # the serving prefill's forward-only call: one device, no ids
         if seg is not None or (mesh is not None and mesh.size > 1
                                and not _in_manual_mesh()):
             raise NotImplementedError(
                 "fused_attention with a window, grouped key/value heads, "
-                "a value width of its own, mxu_dtype or flash_min_seq "
-                "runs on one device and takes no segment ids")
+                "a value width of its own, a shared key part, mxu_dtype or "
+                "flash_min_seq runs on one device and takes no segment ids")
         return flash_attention(q, k, v, bias, scale, causal=causal,
                                window=window, mxu_dtype=mxu_dtype,
-                               min_seq=min_seq)
+                               min_seq=min_seq, n_head=n_head, shared=shared)
 
     def local(a, b, c, d=None, heads=n_head):
         return flash_attention(a, b, c, d, scale, causal=causal,
@@ -1550,6 +1691,13 @@ def _in_manual_mesh() -> bool:
         "Manual" in str(t) for t in getattr(cur, "axis_types", ()))
 
 
+def _shared_of(ins):
+    """``(q_r, k_r)`` of an op built with a shared key part, else None."""
+    if not ins.get("KR"):
+        return None
+    return ins["QR"][0], ins["KR"][0]
+
+
 @register_op("fused_attention", diff_inputs=["Q", "K", "V"], uses_rng=True)
 def _fused_attention(ctx, ins, attrs):
     q = ins["Q"][0]
@@ -1567,7 +1715,7 @@ def _fused_attention(ctx, ins, attrs):
         ctx, q, k, v, bias, scale, causal, seg=seg, window=window,
         mxu_dtype=attrs.get("mxu_dtype") or None,
         min_seq=attrs.get("flash_min_seq") or None,
-        n_head=attrs.get("n_head"))
+        n_head=attrs.get("n_head"), shared=_shared_of(ins))
     if dropout and not (attrs.get("is_test", False) or ctx.is_test):
         # dropout on the *output* (weights-dropout does not commute with the
         # fused kernel; divergence from the layer-composed path documented).
@@ -1589,7 +1737,7 @@ def _fused_attention_grad(ctx, ins, attrs):
     mask = (ins.get("Mask") or [None])[0]
     g = ins["Out@GRAD"][0]
     if _forward_only(q, k, v, attrs.get("window"), attrs.get("mxu_dtype"),
-                     attrs.get("flash_min_seq")):
+                     attrs.get("flash_min_seq"), _shared_of(ins)):
         raise NotImplementedError(_FORWARD_ONLY)
     if mask is not None:
         g = (g * mask).astype(q.dtype)

@@ -407,8 +407,10 @@ def _rope(ctx, ins, attrs):
                 "rope with [B, S] positions needs a [B, H, S, D] "
                 "head tensor; got x rank %d" % x.ndim)
         ang = pos.astype(jnp.float32)[..., None] * inv
-        sin = jnp.sin(ang).astype(x.dtype)[:, None]
-        cos = jnp.cos(ang).astype(x.dtype)[:, None]
+        sin = jnp.sin(ang).astype(x.dtype)
+        cos = jnp.cos(ang).astype(x.dtype)
+        if not attrs.get("heads_last"):
+            sin, cos = sin[:, None], cos[:, None]
     else:
         ang = pos.reshape(-1).astype(jnp.float32)[:, None] * inv[None, :]
         sin = jnp.sin(ang).astype(x.dtype)  # [S, half]
@@ -416,6 +418,10 @@ def _rope(ctx, ins, attrs):
     mscale = float(attrs.get("yarn_mscale", 1.0) or 1.0)
     if mscale != 1.0:
         sin, cos = sin * mscale, cos * mscale
+    if attrs.get("heads_last"):
+        # x [B, S, H, D], as a projection leaves it: the head axis stands
+        # between the positions and the pairs, and nothing is transposed
+        sin, cos = sin[..., None, :], cos[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x1 * sin + x2 * cos], axis=-1)

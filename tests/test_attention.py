@@ -740,3 +740,175 @@ def test_rotated_grouped_and_composed_layers_keep_their_four_transposes(
     types = _attention_ops(**kw)
     assert types.count("transpose2") == 4
     assert types.count("fused_attention") == (kind != "composed")
+
+
+# ------------------------------------------- the shared key part (PR 50)
+def _latent_operands(S, H, dn, dr, dv, seed, B=1):
+    """(q [B,S,H*dn], q_r [B,S,H*dr], kv [B,S,H*(dn+dv)], k_r [B,S,dr]):
+    latent attention's expanded form where its projections write it."""
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(B, S, w).astype("float32"))
+                 for w in (H * dn, H * dr, H * (dn + dv), dr))
+
+
+def _shared_call(q, q_r, kv, k_r, H, scale, **kw):
+    return flash_attention(q, kv, kv, None, scale, causal=True,
+                           mxu_dtype="bfloat16", min_seq=128, n_head=H,
+                           shared=(q_r, k_r), **kw)
+
+
+def _expanded_reference(q, q_r, kv, k_r, H, scale):
+    """The composed form over every head's q, k [B,H,S,dn+dr] and v
+    [B,H,S,dv], built as the prefill built them before PR 50."""
+    from paddle_tpu.ops.attention import composed_attention
+
+    B, S, dn, dr = q.shape[0], q.shape[1], q.shape[2] // H, k_r.shape[2]
+    qh = jnp.concatenate([_split(q, H), _split(q_r, H)], axis=3)
+    kvh = _split(kv, H)
+    kh = jnp.concatenate(
+        [kvh[..., :dn], jnp.broadcast_to(k_r[:, None], (B, H, S, dr))],
+        axis=3)
+    return _merge(composed_attention(qh, kh, kvh[..., dn:], None, scale,
+                                     True))
+
+
+SHARED_CASES = [
+    # S, H, d_nope, d_rope, d_v: one lane tile, three, a length that is no
+    # whole block (200; three lane tiles + 8), two / four / six heads (one
+    # and two a grid step), a rotated part a whole lane tile wide, a value
+    # width of two tiles
+    (128, 2, 128, 64, 128), (384, 4, 128, 64, 128), (200, 6, 128, 64, 128),
+    (392, 2, 128, 64, 128), (384, 6, 128, 64, 128), (128, 4, 128, 128, 128),
+    (200, 2, 128, 64, 256),
+]
+
+
+@pytest.mark.parametrize("S,H,dn,dr,dv", SHARED_CASES)
+def test_shared_key_part_equals_composed_on_the_expanded_operands(
+        S, H, dn, dr, dv, monkeypatch):
+    """The rank-3 call with the one key part all heads share (``kvb``'s
+    output as both k and v, q in two parts) against the composed form
+    over every head's rebuilt keys and values; it runs the forward kernel
+    in the lanes layout and pads nothing."""
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    q, q_r, kv, k_r = _latent_operands(S, H, dn, dr, dv, S + H)
+    scale = (dn + dr) ** -0.5
+    before = _lane_plans()
+    got = _shared_call(q, q_r, kv, k_r, H, scale)
+    assert got.shape == (1, S, H * dv) and got.dtype == jnp.float32
+    assert {key for key, n in _lane_plans().items()
+            if n > before.get(key, 0)} == {("flash_fwd", "lanes")}
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(
+        got, _expanded_reference(q, q_r, kv, k_r, H, scale), atol=2e-5,
+        rtol=0)
+
+
+@pytest.mark.parametrize("S,blocks", [(200, None), (1100, None),
+                                      (392, (128, 128)), (392, (256, 128))])
+def test_a_ragged_last_block_reads_nothing_past_the_last_key(
+        S, blocks, monkeypatch):
+    """A causal call with the shared key part pads nothing: its last
+    query and key blocks hang over the operands' end, where the
+    interpreter plants NaN (the chip leaves whatever the buffer held).
+    One block (200), the planned 512 x 512 over 1,100 (the last key block
+    holds 76 keys) and forced blocks with a carry: finite and equal."""
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops import attention
+
+    def probe(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    past = pl.pallas_call(
+        probe, grid=(2,), in_specs=[pl.BlockSpec((128, 128),
+                                                 lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((128, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((256, 128), jnp.float32),
+        interpret=True)(jnp.ones((200, 128), jnp.float32))
+    assert bool(jnp.isnan(past[200:]).all())    # what this test rests on
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    if blocks:
+        monkeypatch.setattr(
+            attention, "_forward_plan",
+            lambda S, Sk, *a, **k: (-(-S // blocks[0]) * blocks[0],
+                                    -(-Sk // blocks[1]) * blocks[1]) + blocks)
+    H, dn, dr, dv = 2, 128, 64, 128
+    q, q_r, kv, k_r = _latent_operands(S, H, dn, dr, dv, S, B=2)
+    pads = []
+    monkeypatch.setattr(attention, "_pad_axis", lambda x, axis, to, *a: (
+        pads.append((x.shape, axis, to)), attention.pad_axis(x, axis, to,
+                                                              *a))[1])
+    got = _shared_call(q, q_r, kv, k_r, H, 0.07)
+    # the one pad left is q_r's and k_r's last axis, to a lane tile
+    assert {axis for _shape, axis, _to in pads} <= {2, 3}
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(
+        got, _expanded_reference(q, q_r, kv, k_r, H, 0.07), atol=2e-5,
+        rtol=0)
+
+
+@pytest.mark.parametrize("why", ["under_min_seq", "width_off_the_tile",
+                                 "not_causal"])
+def test_shared_key_part_means_the_same_where_the_kernel_cannot_take_it(
+        why, monkeypatch):
+    """Under the call's threshold the composed form, at a head width that
+    is no whole lane tile the kernel's other layout, both over operands
+    the lowering builds inside; a call that is not causal keeps the lanes
+    layout and pads (its padded keys need a mask of their own)."""
+    from paddle_tpu.observe.families import KERNEL_DISPATCHES
+    from paddle_tpu.ops.attention import _expanded, composed_attention
+
+    monkeypatch.delenv("PADDLE_TPU_FLASH_MIN_SEQ", raising=False)
+    S, dn, dr, dv = {"under_min_seq": (96, 128, 64, 128),
+                     "width_off_the_tile": (256, 16, 8, 16),
+                     "not_causal": (200, 128, 64, 128)}[why]
+    H = 4
+    q, q_r, kv, k_r = _latent_operands(S, H, dn, dr, dv, 11)
+    composed = KERNEL_DISPATCHES.labels(op="attention", impl="composed")
+    before, plans = composed.value, _lane_plans()
+    causal = why != "not_causal"
+    got = flash_attention(q, kv, kv, None, 0.2, causal=causal, min_seq=128,
+                          n_head=H, shared=(q_r, k_r))
+    lowered = {key for key, n in _lane_plans().items()
+               if n > plans.get(key, 0)}
+    if why == "under_min_seq":
+        assert composed.value > before and not lowered
+    else:
+        assert lowered == {("flash_fwd", "heads" if dn == 16 else "lanes")}
+    want = _merge(composed_attention(*_expanded(q, kv, (q_r, k_r), H), None,
+                                     0.2, causal))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("what", ["window", "two_tensors", "rank_4",
+                                  "trainable_bias", "grad"])
+def test_shared_key_part_refuses_what_it_does_not_take(what):
+    from paddle_tpu.core.lowering import LowerContext
+    from paddle_tpu.core.registry import get_op
+
+    H, S = 2, 128
+    q, q_r, kv, k_r = _latent_operands(S, H, 128, 64, 128, 3)
+    if what == "window":
+        with pytest.raises(NotImplementedError, match="forward-only"):
+            flash_attention(q, kv, kv, None, 0.1, causal=True, window=64,
+                            n_head=H, shared=(q_r, k_r))
+    elif what == "two_tensors":
+        with pytest.raises(ValueError, match="ONE tensor"):
+            flash_attention(q, kv, kv[..., :H * 128], None, 0.1,
+                            causal=True, n_head=H, shared=(q_r, k_r))
+    elif what == "rank_4":
+        with pytest.raises(ValueError, match=r"\[B, S, H\*D\]"):
+            flash_attention(_split(q, H), _split(q, H), _split(q, H), None,
+                            0.1, causal=True, shared=(q_r, k_r))
+    elif what == "trainable_bias":
+        with pytest.raises(NotImplementedError, match="forward-only"):
+            flash_attention(q, kv, kv, jnp.zeros((1, H, S, S)), 0.1,
+                            bias_grad=True, n_head=H, shared=(q_r, k_r))
+    else:
+        ins = {"Q": [q], "K": [kv], "V": [kv], "QR": [q_r], "KR": [k_r],
+               "Out@GRAD": [q]}
+        with pytest.raises(NotImplementedError, match="forward-only"):
+            get_op("fused_attention").grad_lowering(
+                LowerContext(), ins, {"scale": 0.1, "causal": True,
+                                      "n_head": H})

@@ -519,7 +519,11 @@ def test_pangu_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     forwards at q/k 192 and v 128 wide (the kernel at 128 too; single
     pass up to 1,024, where four heads a step overran the scoped VMEM on
     the chip: two a step at 256 lanes of width), no [P, P] score tensor,
-    and temporaries that fit beside the 9.84 GB the engine holds."""
+    and temporaries that fit beside the 9.84 GB the engine holds. Since
+    PR 50 in the lanes layout with the shared key part: no head-major
+    tensor ([1, 128, P, .] or its flattened [128, P', .]) and nothing
+    padded to whole blocks is in the program, and the kernel's context
+    [1, P, 128 * 128] float32 is the output projection's operand."""
     import paddle_tpu as fluid
     from paddle_tpu.observe.families import (FLASH_BLOCK_PLANS,
                                              MLA_ATTENTION_PLANS)
@@ -538,17 +542,25 @@ def test_pangu_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
              3328: "512x512"}[P]       # 3,328 pads to 7 blocks of 512
     plan = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block=block,
                                     single_pass="0" if P == 3328 else "1",
-                                    layout="heads")
-    before = plan.value
+                                    layout="lanes")
+    heads = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block=block,
+                                     single_pass="0" if P == 3328 else "1",
+                                     layout="heads")
+    before = plan.value, heads.value
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
-    assert plan.value == before + 5
+    assert (plan.value, heads.value) == (before[0] + 5, before[1])
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
     assert text.count("flash_fwd") >= 5
     if P > 128:       # [1, 128, 128, 128] is also a head tensor's shape
         assert "f32[1,128,%d,%d]" % (P, P) not in text
+        import re
+
+        assert not re.search(r"\[1,128,%d,\d+\]|\[128,%d,\d+\]|\[1,3584,"
+                             % (P, P), text)
+    assert "f32[1,%d,16384]" % P in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4.5e9, mem
     print("pangu prefill P=%d:" % P, mem)

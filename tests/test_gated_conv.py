@@ -423,6 +423,25 @@ def test_flash_forward_at_grouped_heads_of_64_matches_composed(
                                atol=2e-5, rtol=0)
 
 
+def test_the_prefill_hands_the_kernel_rank_4_operands(monkeypatch):
+    """The attention layers' prefill keeps the heads layout ([B, H, S, D]
+    operands, grouped heads through the block index): the lanes layout
+    of the forward-only call is the latent prefill's alone (PR 50)."""
+    from test_attention import _lane_plans
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_SEQ", "0")
+    cfg = tiny_cfg()
+    (prog, start, logits), _ = _programs(cfg, 21, 64)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = _scope_with(exe, [start], seeded_params(cfg, 0))
+    before = _lane_plans()
+    exe.run(prog, feed={"tokens": np.ones((1, 21), "int64")},
+            fetch_list=[logits], scope=scope)
+    n_att = sum(1 for i in range(cfg["n_layer"]) if not gpt.is_conv(cfg, i))
+    assert {k: n - before.get(k, 0) for k, n in _lane_plans().items()
+            if n > before.get(k, 0)} == {("flash_fwd", "heads"): n_att}
+
+
 # ----------------------------------------------------------------- engine
 @pytest.fixture(scope="module")
 def served():

@@ -501,6 +501,34 @@ def test_mla_decode_sweep_rehearses_on_the_cpu(tmp_path):
                for r in rows)
 
 
+def test_mla_prefill_sweep_rehearses_on_the_cpu(tmp_path):
+    """``tools/mla_prefill_sweep.py`` (the tool behind the table of
+    docs/KERNELS.md "Operand layouts of the flash kernels") runs its five
+    cases at a tiny ragged length in interpret mode and writes no time:
+    the two forms of the sub-block agree, there and at the lengths it
+    checks."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.join(HERE, os.pardir, "tools"))
+    try:
+        import mla_prefill_sweep
+    finally:
+        sys.path.pop(0)
+    out = tmp_path / "sweep.json"
+    assert mla_prefill_sweep.main(["--rehearse", "--only", "longcat",
+                                   "--out", str(out)]) == 0
+    rows = json.loads(out.read_text())["rows"]
+    assert [r.get("case") for r in rows[:5]] == [
+        "block_heads", "heads_f32", "heads_bf16", "block_lanes",
+        "lanes_bf16"]
+    assert all("device_ms" not in r for r in rows)
+    assert rows[3]["max_abs_diff_vs_block_heads"] < 2e-5
+    assert [(r["check"], r["finite"]) for r in rows[5:]] == [
+        (136, True), (200, True)]
+    assert all(r["max_abs_diff"] < 2e-5 for r in rows[5:])
+
+
 def test_engine_counts_the_blocks_its_latent_steps_walk():
     """``paddle_mla_decode_blocks_total``: a step adds, for every latent
     layer, the (slot, block) pairs its positions hold (a free slot one)
@@ -629,6 +657,65 @@ def test_prefill_attention_is_the_flash_forward_at_every_length():
            if op.type == "fused_attention"]
     assert [(op.attrs["mxu_dtype"], op.attrs["flash_min_seq"])
             for op in ops] == [("bfloat16", 128)] * 2
+
+
+def _between(types, first, last):
+    """The op types after the ``first``-th op and before the next ``last``."""
+    return types[first + 1:first + 1 + types[first + 1:].index(last)]
+
+
+@pytest.mark.parametrize("cfg_of", ["pangu", "mscale"])
+def test_the_prefill_builds_no_heads_keys_or_values(cfg_of):
+    """Between ``kvb``'s projection and the fused-attention op a latent
+    prefill holds no ``transpose2``, ``expand`` or ``concat`` (no head's
+    keys or values are built, q is rotated where it lies), the op takes
+    ``kvb``'s output as both K and V beside the shared key part, and its
+    output goes to the output projection as it is."""
+    cfg = tiny_cfg(n_layer=2) if cfg_of == "pangu" else tiny_cfg(
+        n_layer=2, mla_scale_q_lora=True, mla_scale_kv_lora=True)
+    prog, start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, start):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=24, max_len=32)
+    ops = prog.global_block().ops
+    types = [op.type for op in ops]
+    for i in range(2):
+        kvb = [n for n, op in enumerate(ops) if op.type == "mul"
+               and "gpt_%d_att_kvb.w_0" % i in op.inputs["Y"]][0]
+        made = _between(types, kvb, "fused_attention")
+        assert not {"transpose2", "expand", "concat"} & set(made), made
+        att = ops[kvb + 1 + len(made)]
+        assert att.inputs["K"] == att.inputs["V"] == ops[kvb].outputs["Out"]
+        assert sorted(att.inputs) == ["K", "KR", "Q", "QR", "V"]
+        assert (att.attrs["n_head"], att.attrs["mxu_dtype"],
+                att.attrs["flash_min_seq"]) == (4, "bfloat16", 128)
+        # straight into the output projection: nothing is transposed back
+        after = types[kvb + 2 + len(made):]
+        assert after[:after.index("mul")] == []
+
+
+def test_a_prefill_at_whole_lane_tiles_runs_the_lanes_layout():
+    """At widths that are whole lane tiles (the three configurations':
+    128 + 64 and 128) the prefill's kernel reads the projections' outputs
+    in the lanes layout — one plan a layer, none in the heads layout —
+    and the engine answers as the reference does."""
+    from test_attention import _lane_plans
+
+    from paddle_tpu.observe.families import MLA_ATTENTION_PLANS
+
+    cfg = tiny_cfg(n_layer=2, n_head=2, d_nope=128, d_rope=64, d_v=128)
+    params = seeded_params(cfg, 61)
+    form = MLA_ATTENTION_PLANS.labels(form="expanded",
+                                      kernel="fused_attention", block="-",
+                                      widths="192x128")
+    before, built = _lane_plans(), form.value
+    prompt = np.random.default_rng(67).integers(1, 97, 136)
+    eng = _engine(cfg, params, 1, max_len=160)
+    toks, rows = _decode_in_company(eng, [prompt], 4)
+    got = {k: n - before.get(k, 0) for k, n in _lane_plans().items()
+           if n > before.get(k, 0)}
+    assert got == {("flash_fwd", "lanes"): 2}
+    assert form.value - built >= 2
+    _assert_matches_reference(cfg, params, [prompt], toks, rows)
 
 
 @pytest.mark.parametrize("dk,dv", [(16, 16), (24, 16)])
@@ -843,7 +930,21 @@ def test_analysis_rules_know_the_new_op_and_the_two_widths():
         gpt.build_prefill_step(cfg, batch=1, prompt_len=12, max_len=32)
     block = pre.global_block()
     att = [o for o in block.ops if o.type == "fused_attention"][0]
+    # the operands where the projections wrote them (PR 50): four heads
+    # of 16 + 8 and 16 + 16 lanes, kvb's output as both K and V
     assert [tuple(block.var(att.inputs[s][0]).shape)
-            for s in ("Q", "K", "V")] == [
-        (-1, 4, 12, 24), (-1, 4, 12, 24), (-1, 4, 12, 16)]
-    assert tuple(block.var(att.outputs["Out"][0]).shape) == (-1, 4, 12, 16)
+            for s in ("Q", "QR", "K", "V", "KR")] == [
+        (-1, 12, 64), (-1, 12, 32), (-1, 12, 128), (-1, 12, 128),
+        (-1, 12, 8)]
+    assert att.inputs["K"] == att.inputs["V"]
+    assert tuple(block.var(att.outputs["Out"][0]).shape) == (-1, 12, 4 * 16)
+    # the rules take the form: nothing to report, and a head's scores
+    # contract over 16 + 8 where its values are 16 wide
+    from paddle_tpu.analysis.cost import CostAnalysis
+    from paddle_tpu.analysis.infer import verify_program
+
+    assert not [f for f in verify_program(pre, fill=False)
+                if f.severity == "error" or (
+                    f.severity == "warning" and "fused_attention" in f.message)]
+    cost = CostAnalysis(pre)
+    assert not cost.unruled

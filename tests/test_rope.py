@@ -148,3 +148,35 @@ def test_rope_per_row_positions():
         np.testing.assert_allclose(
             o[b], _ref_rope(x[b], pos[b]), atol=1e-5, rtol=1e-5,
             err_msg="row %d" % b)
+
+
+@pytest.mark.parametrize("rows", ["one", "per_row"])
+def test_rope_heads_last_is_the_rotation_of_the_transposed_tensor(rows):
+    """``heads_last`` rotates [B, S, H, D] where a projection's reshape
+    leaves it: the same numbers, bit for bit, as transposing to
+    [B, H, S, D], rotating and transposing back ([S] and [B, S]
+    positions alike)."""
+    rs = np.random.RandomState(6)
+    x = rs.randn(2, 6, 3, 16).astype("float32")
+    pos = np.arange(6).astype("int64") if rows == "one" else np.stack(
+        [np.arange(6), np.array([0, 1, 2, 0, 1, 2])]).astype("int64")
+    yarn = dict(factor=4.0, low=1, high=5, mscale=1.2)
+
+    main, startup = fluid.Program(), fluid.Program()
+    scope = Scope()
+    with scope_guard(scope):
+        with fluid.program_guard(main, startup):
+            xv = layers.data("x", list(x.shape), dtype="float32",
+                             append_batch_size=False)
+            pv = layers.data("p", list(pos.shape), dtype="int64",
+                             append_batch_size=False)
+            here = layers.rope(xv, pv, yarn=yarn, heads_last=True)
+            there = layers.transpose(
+                layers.rope(layers.transpose(xv, perm=[0, 2, 1, 3]), pv,
+                            yarn=yarn), perm=[0, 2, 1, 3])
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup, scope=scope)
+        a, b = exe.run(main, feed={"x": x, "p": pos},
+                       fetch_list=[here, there], scope=scope)
+    assert tuple(here.shape) == x.shape
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
