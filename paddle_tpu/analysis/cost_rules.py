@@ -465,6 +465,56 @@ def _cost_ssm_update(ctx):
     return ctx.out_elems() if state is None else state.scaled(5)
 
 
+def _power_sizes(ctx):
+    """(positions B T as a poly, H, G, D, R) or None."""
+    from ..kernels.power import phi_plan
+
+    qs = ctx.input_shape("Q")
+    H, G = int(ctx.attr("heads", 0) or 0), int(ctx.attr("groups", 0) or 0)
+    if qs is None or not H or not G or qs[-1] < 0:
+        return None
+    pos = ctx.elems(tuple(qs[:-1]))
+    D = qs[-1] // H
+    return None if pos is None else (pos, H, G, D, phi_plan(D)[2])
+
+
+@register_cost_rule("power_scan")
+def _cost_power_scan(ctx):
+    """A position, chunked in Q (``kernels.power.scan_chunk`` of the
+    prompt's length): a head's squared scores against the
+    chunk's keys and their product with its values (4 Q D, and about 6 Q
+    for the decay, the mask and the square), its read of the state before
+    the chunk (2 R D, and R for its symmetric square); a group's feed of
+    the state (2 R D, and R). Bytes: the generic model's (every operand
+    once, ``Y``, the final state and normaliser)."""
+    sizes = _power_sizes(ctx)
+    if sizes is None:
+        return ctx.out_elems()
+    from ..kernels.power import scan_chunk
+
+    pos, H, G, D, R = sizes
+    T = ctx.input_shape("Q")[1]
+    if T < 0:
+        return ctx.out_elems()
+    Q = scan_chunk(T)
+    return pos.scaled(H * (4 * Q * D + 6 * Q + 2 * R * D + R)
+                      + G * (2 * R * D + R))
+
+
+@register_cost_rule("power_update")
+def _cost_power_update(ctx):
+    """Three operations a value of the state (decay, the outer product
+    fed in) and two a value and query head of its group (the readout).
+    Bytes: the generic model's — the state read and written once is what
+    the step costs."""
+    st = ctx.input_shape("State")
+    state = None if st is None else ctx.elems(st)
+    if state is None:
+        return ctx.out_elems()
+    H, G = int(ctx.attr("heads", 1) or 1), int(ctx.attr("groups", 1) or 1)
+    return state.scaled(3 + 2 * (H // G))
+
+
 @register_cost_rule("causal_conv", "causal_conv_step")
 def _cost_causal_conv(ctx):
     """2 K operations a value and about 5 for the silu (attr ``act``)."""

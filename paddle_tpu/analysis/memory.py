@@ -406,6 +406,36 @@ def _fp_ssm_update(ctx):
         + BytesPoly.from_dims((st[0], st[1], st[2], 8), 4)
 
 
+@register_footprint_rule("power_scan")
+def _fp_power_scan(ctx):
+    """The operands regrouped, turned and padded to whole chunks (q
+    twice: in and out; k and v twice: both ways round), and a chunk's
+    [Q, Q] scores a head (the Pallas kernel keeps them in VMEM: an upper
+    bracket either way)."""
+    from ..kernels.power import scan_chunk
+
+    qs, ks = ctx.input_shape("Q"), ctx.input_shape("K")
+    if qs is None or ks is None or qs[1] < 0:
+        return None
+    H = int(ctx.attr("heads", 1) or 1)
+    Q = scan_chunk(qs[1])
+    return BytesPoly.from_dims(tuple(qs), 4).scaled(2) \
+        + BytesPoly.from_dims(tuple(ks), 4).scaled(4) \
+        + BytesPoly.from_dims((qs[0], Q, Q, H), 4)
+
+
+@register_footprint_rule("power_update")
+def _fp_power_update(ctx):
+    """The token's rows and columns the kernel reads beside the state
+    ([B, G, 8, D] and [B, G, D, 8]) and the numerators and partial
+    normalisers it leaves ([B, G, 8, D] each); state and normaliser are
+    updated in place."""
+    st = ctx.input_shape("State")
+    if st is None or len(st) != 4:
+        return None
+    return BytesPoly.from_dims((st[0], st[1], 8, st[3]), 4).scaled(4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

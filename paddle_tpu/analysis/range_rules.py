@@ -863,6 +863,23 @@ def _rr_ssm(ctx):
     ctx.set("StateOut", top)
 
 
+@register_range_rule("power_scan", "power_update")
+def _rr_power(ctx):
+    """The state is a decaying sum (gate <= 1) of ``phi(k) v^T``: finite
+    where the operands are, with no bound that a sequence's length does
+    not move. ``Y`` is a weighted mean of the values seen (weights >= 0,
+    the normaliser at least their sum): inside ``V``'s range wherever
+    that range holds zero."""
+    finite = all(ctx.input_av(s).finite for s in ("Q", "K", "V", "Gate"))
+    if ctx.op.type == "power_update":
+        finite = finite and ctx.input_av("State").finite \
+            and ctx.input_av("Norm").finite
+    top = AbstractValue(-F32_MAX, F32_MAX, finite=finite)
+    ctx.set("Y", top)
+    ctx.set("StateOut", top)
+    ctx.set("NormOut", top)
+
+
 @register_range_rule("causal_conv", "causal_conv_step")
 def _rr_causal_conv(ctx):
     """``silu`` of a K-term sum of products: at least silu's minimum

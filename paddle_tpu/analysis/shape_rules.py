@@ -287,6 +287,54 @@ def _r_ssm_update(ctx):
         ctx.fail("ssm_update takes one position a row; X is %s" % (xs,))
 
 
+def _power_out(ctx, kept):
+    """``Y`` is ``Q``'s shape; ``StateOut [B, G, R, D]`` and ``NormOut
+    [B, G, D, D]`` from the op's widths (``kernels.power.phi_plan``), or
+    the shapes of the ``kept`` inputs where the op has them."""
+    from ..kernels.power import phi_plan
+
+    qs = ctx.input_shape("Q")
+    H, G = int(ctx.attr("heads", 0) or 0), int(ctx.attr("groups", 0) or 0)
+    if H < 1 or G < 1 or H % G:
+        ctx.fail("%s needs heads >= 1 in groups that divide them"
+                 % ctx.op.type)
+        return
+    if qs is None:
+        return
+    ctx.set("Y", qs)
+    wide = qs[-1]
+    if wide >= 0 and wide % H:
+        ctx.fail("Q %s is not [..., %d heads * D]" % (qs, H))
+        return
+    D = wide // H if wide >= 0 else -1
+    st = ctx.input_shape("State") if kept else None
+    nz = ctx.input_shape("Norm") if kept else None
+    if D >= 0:
+        st = st or (qs[0], G, phi_plan(D)[2], D)
+        nz = nz or (qs[0], G, D, D)
+    if st is not None:
+        ctx.set("StateOut", st)
+    if nz is not None:
+        ctx.set("NormOut", nz)
+    for slot, want in (("K", G * D), ("V", G * D), ("Gate", G)):
+        got = ctx.input_shape(slot)
+        if D >= 0 and got is not None and got[-1] >= 0 and got[-1] != want:
+            ctx.fail("%s %s is not [..., %d]" % (slot, got, want))
+
+
+@register_shape_rule("power_scan")
+def _r_power_scan(ctx):
+    _power_out(ctx, False)
+
+
+@register_shape_rule("power_update")
+def _r_power_update(ctx):
+    _power_out(ctx, True)
+    qs = ctx.input_shape("Q")
+    if qs is not None and len(qs) == 3 and qs[1] not in (1, -1):
+        ctx.fail("power_update takes one position a row; Q is %s" % (qs,))
+
+
 @register_shape_rule("causal_conv", "causal_conv_step")
 def _r_causal_conv(ctx):
     """Out is X's shape [B, T, C]; RowsOut [B, K - 1, C] under W [C, K]."""

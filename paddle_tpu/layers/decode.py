@@ -12,7 +12,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post",
-           "ssm_mix", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+           "ssm_mix", "power_retention", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -300,4 +300,31 @@ def ssm_mix(x, dt, bm, cm, state, heads, groups, n_state, prefix,
                      inputs=inputs,
                      outputs={"Y": [y], "StateOut": [state]}, attrs=attrs)
     y.shape = x.shape
+    return y
+
+
+def power_retention(q, k, v, gate, state, norm, heads, groups, step=False,
+                    name=None):
+    """The core of a power-retention layer of degree 2 (ops
+    ``power_scan`` / ``power_update``, kernels/power.py): attention whose
+    weight is the squared scaled score under a decay, ``q [B, T, H D]``
+    (normed and rotated as the model has them), ``k`` / ``v`` ``[B, T, G
+    D]``, ``gate [B, T, G]`` raw (the op takes its sigmoid: one decay a
+    key-value head). ``state`` and ``norm`` are persistable ``[B, G, R,
+    D]`` and ``[B, G, D, D]`` vars (``kernels.power.state_shape`` /
+    ``norm_shape``): a whole prompt (``step=False``) is scanned from
+    zero, in chunks, and leaves both there; one token (``step=True``,
+    ``T`` = 1) updates them in place and is read out of the new state.
+    Returns ``y [B, T, H D]``, normalised. No parameters."""
+    helper = LayerHelper("power_retention", name=name)
+    inputs = {"Q": [q], "K": [k], "V": [v], "Gate": [gate]}
+    attrs = {"heads": int(heads), "groups": int(groups)}
+    if step:
+        inputs["State"], inputs["Norm"] = [state], [norm]
+    y = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="power_update" if step else "power_scan",
+                     inputs=inputs,
+                     outputs={"Y": [y], "StateOut": [state],
+                              "NormOut": [norm]}, attrs=attrs)
+    y.shape = q.shape
     return y

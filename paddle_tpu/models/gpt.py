@@ -295,6 +295,40 @@ def base_config():
              router_score="sigmoid", router_bias=True, norm_topk=True,
              norm_topk_eps=1e-6, weight_dtype="bfloat16")
 
+    Power retention of degree 2 as a layer's FIRST sub-block
+    (``layer_types`` entry ``"retention"``, arXiv:2507.04239; serving
+    programs only): the layer stays the ordinary pair and the projections
+    stay attention's — ``_qkv``, the per-head q/k norm, the rotation,
+    ``_attn_out`` — with one new core between them and one gate
+    projection, ``g = sigmoid(u W_g + b_g)`` (``gpt_<i>_att_gamma.{w,b}_0
+    [D, n_kv_head]``: one decay a key-value head). The core is attention
+    whose weight is the SQUARE of the scaled score under the decay, ``a_tj
+    = (q_t . k_j / sqrt(d_head))^2 prod_{j<l<=t} g_l``, normalised by
+    ``sum_j a_tj + 1e-6`` (``kernels.power.EPS``): no softmax, no maximum. A
+    square is an inner product of symmetric squares, so what a sequence
+    KEEPS of a layer has no position axis: ``gpt_<i>_cache_s [B, n_kv, R,
+    d_head]`` (``R`` = 9,216 rows at ``d_head`` 128 for the exact 8,256
+    pairs: kernels/power.py says how they are laid out) and the
+    normaliser ``gpt_<i>_cache_z [B, n_kv, d_head, d_head]``
+    (``cache_kind`` calls both ``state``). The prefill scans the prompt
+    in chunks that follow from its length (``kernels.power.scan_chunk``;
+    op ``power_scan``) and overwrites both; a decode step updates them
+    in place and reads its query heads out of the new state
+    (``power_update``). The layer has no cfg key of its own: the degree
+    is 2. It stands beside none of ``attn``,
+    ``residual``, ``mixers``, ``shortcut_moe``; the training build, the
+    multi-token step, a prefix store and a draft model refuse it by name.
+
+    Brumby-14B-Base (``model_type`` brumby), as the worked example —
+    published widths, all 40 layers::
+
+        dict(d_model=5120, n_head=40, n_kv_head=8, d_head=128,
+             n_layer=40, vocab=151936, max_length=32768, dropout=0.0,
+             pos_emb="rope", rope_theta=1000000.0, norm="rms",
+             norm_eps=1e-6, qk_norm="head", tie_embeddings=False,
+             layer_types=["retention"] * 40, ffn_act="swiglu",
+             d_ff=17408, weight_dtype="bfloat16")
+
     Shortcut-connected experts (``shortcut_moe``; serving programs
     only): a published layer is attention, dense FFN, attention, dense
     FFN, with ONE routed branch that reads the first attention's
@@ -380,7 +414,7 @@ _CFG_KEYS = frozenset([
 _SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
              "ssm_conv")
 MIXER_KINDS = ("ssm", "attention", "experts")
-LAYER_TYPES = ("sliding", "full", "conv")
+LAYER_TYPES = ("sliding", "full", "conv", "retention")
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
@@ -533,6 +567,7 @@ def _check_cfg(cfg):
     elif cfg.get("window"):
         raise ValueError("cfg['window'] needs cfg['layer_types']")
     _check_conv(cfg)
+    _check_retention(cfg)
     if cfg.get("rope_layers", "all") != "all" \
             and cfg.get("pos_emb", "learned") != "rope":
         raise ValueError("cfg['rope_layers'] needs pos_emb='rope'")
@@ -651,6 +686,22 @@ def _check_conv(cfg):
             "its last cfg['conv_taps'] - 1 rows and has no window")
 
 
+def _check_retention(cfg):
+    """What a ``'retention'`` entry of cfg['layer_types'] cannot stand
+    beside (``base_config``)."""
+    if "retention" not in (cfg.get("layer_types") or ()):
+        return
+    for key, why in (
+            ("attn", "latent attention has no layer kinds"),
+            ("residual", "the retention core is not written over several "
+             "residual streams"),
+            ("shortcut_moe", "the branch forks behind an attention "
+             "sub-block")):
+        if cfg.get(key):
+            raise ValueError("a 'retention' layer takes no cfg[%r]: %s"
+                             % (key, why))
+
+
 def _lm_head(cfg, x):
     """Final projection to vocab logits. ``tie_embeddings=True`` reuses
     the input embedding (logits = x @ word_emb^T — no gpt_out_proj
@@ -762,20 +813,29 @@ def is_conv(cfg, i):
     return bool(types) and types[i] == "conv"
 
 
+def is_retention(cfg, i):
+    """Whether layer ``i``'s first sub-block is power retention (a
+    ``'retention'`` entry of cfg['layer_types'])."""
+    types = cfg.get("layer_types")
+    return bool(types) and types[i] == "retention"
+
+
 def state_layers(cfg):
     """The layers that keep a constant-size state and not rows a
     position, whichever key brought them: an ``'ssm'`` entry of
-    cfg['mixers'] or a ``'conv'`` entry of cfg['layer_types']."""
+    cfg['mixers'], a ``'conv'`` or a ``'retention'`` entry of
+    cfg['layer_types']."""
     return [i for i in range(cfg["n_layer"])
-            if mixer_kind(cfg, i) == "ssm" or is_conv(cfg, i)]
+            if mixer_kind(cfg, i) == "ssm" or is_conv(cfg, i)
+            or is_retention(cfg, i)]
 
 
 def has_state(cfg):
     """Whether some layer keeps a state and not rows a position (an
-    ``'ssm'`` mixer, a ``'conv'`` layer): its caches, ``gpt_<i>_cache_s``
-    and ``gpt_<i>_cache_x``, have no position axis, so nothing can be
-    cut out of them at a prefix's length nor rolled back by a
-    position."""
+    ``'ssm'`` mixer, a ``'conv'`` or a ``'retention'`` layer): its
+    caches, ``gpt_<i>_cache_s``, ``gpt_<i>_cache_x`` and
+    ``gpt_<i>_cache_z``, have no position axis, so nothing can be cut out
+    of them at a prefix's length nor rolled back by a position."""
     return bool(state_layers(cfg))
 
 
@@ -783,7 +843,7 @@ def _keeps_rows(cfg, i):
     """Whether layer ``i`` keeps keys and values a position (a slab or
     a ring): an attention layer that is not latent."""
     return mixer_kind(cfg, i) in (None, "attention") \
-        and not is_conv(cfg, i)
+        and not is_conv(cfg, i) and not is_retention(cfg, i)
 
 
 def ssm_widths(cfg):
@@ -797,10 +857,11 @@ def cache_kind(cfg, name, max_len):
     """What kind of cache tensor ``name`` (one of a builder's
     ``cache_names``) is, from its name and its layer: ``'state'`` (a
     state-space layer's state or convolution rows, a gated convolution's
-    carried rows: no position axis),
+    carried rows, a retention layer's state or normaliser: no position
+    axis),
     ``'latent'`` (a latent layer's one tensor), ``'ring'`` (a sliding
     layer's, shorter than ``max_len``) or ``'full'`` (a slab)."""
-    if name.endswith(("_cache_s", "_cache_x")):
+    if name.endswith(("_cache_s", "_cache_x", "_cache_z")):
         return "state"
     if name.endswith("_cache_c"):
         return "latent"
@@ -815,6 +876,14 @@ def state_refusal(cfg):
         return ("cfg['mixers'] holds 'ssm' layers, whose caches are a "
                 "recurrent state with no position axis (gpt_<i>_cache_s, "
                 "gpt_<i>_cache_x)")
+    if "retention" in (cfg.get("layer_types") or ()):
+        from ..kernels.power import phi_plan
+
+        return ("cfg['layer_types'] holds 'retention' layers, whose caches "
+                "are a power-retention state of %d rows a key-value head "
+                "and its normaliser with no position axis "
+                "(gpt_<i>_cache_s, gpt_<i>_cache_z)"
+                % phi_plan(_d_head(cfg))[2])
     return ("cfg['layer_types'] holds 'conv' layers, whose caches are the "
             "last %d rows of a gated convolution's input with no position "
             "axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
@@ -1167,6 +1236,42 @@ def _gated_conv(cfg, helper, h, nm, batch, step):
                 rows, step=step, act=False, bias=False)
         return _fc(layers.elementwise_mul(cut(1), c), D,
                    nm + "_conv_out.w_0"), rows.name
+
+
+def _retention(cfg, helper, h, nm, i, batch, T, pos, step):
+    """Power retention over the normed ``h [B, T, D]`` (``base_config``
+    has the equations): ``(the merged heads [B, T, n_head * d_head], the
+    caches' names)`` for ``_block_tail``. Attention's projections, head
+    norm and rotation (at ``pos``) in front of the one new core; ``step``
+    is the decode form (``T`` = 1): state and normaliser are updated in
+    place, otherwise the prompt overwrites them."""
+    from ..kernels.power import norm_shape, state_shape
+
+    n_head, d_head = cfg["n_head"], _d_head(cfg)
+    n_kv, _g = _kv_heads_of(cfg)
+    q, k, v = _qkv(cfg, h, nm)
+    with name_scope("attn.qkv"):
+        gate = layers.fc(h, n_kv, num_flatten_dims=2,
+                         param_attr=ParamAttr(name=nm + "_att_gamma.w_0"),
+                         bias_attr=ParamAttr(name=nm + "_att_gamma.b_0"))
+    state = helper.create_global_variable(
+        name=nm + "_cache_s", shape=state_shape(batch, n_kv, d_head))
+    norm = helper.create_global_variable(
+        name=nm + "_cache_z", shape=norm_shape(batch, n_kv, d_head))
+
+    def heads(t, n, which):
+        # normed and rotated where the projection's reshape leaves them
+        t = _head_norm(cfg, layers.reshape(t, [-1, T, n, d_head]), nm,
+                       which)
+        if _rotates(cfg, i):
+            t = _rope(cfg, t, pos, heads_last=True)
+        return layers.reshape(t, [-1, T, n * d_head])
+
+    with name_scope("mixer"):
+        y = layers.power_retention(
+            heads(q, n_head, "q"), heads(k, n_kv, "k"), v, gate, state,
+            norm, n_head, n_kv, step=step)
+    return y, [state.name, norm.name]
 
 
 def _sub_input(cfg, x, nm, k, dev=None):
@@ -1538,7 +1643,7 @@ def build(cfg=None, seq_len=256, is_test=False, use_fused_attention=None,
             "layer has no backward and the training build keeps the "
             "attention-then-FFN pair" % (MIXER_KINDS,))
     _refuse_state(cfg, "build", "which is the serving programs' (prefill, "
-                  "decode steps): the carried-rows convolution has no "
+                  "decode steps): a layer that carries a state has no "
                   "backward")
     _refuse_shortcut(cfg, "build", "which is the serving programs' "
                      "(prefill, decode steps): the training build keeps "
@@ -1878,6 +1983,12 @@ def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
         y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
         cache_names.append(kept)
         return _layer_tail(cfg, x, y, nm, i, mix, first="conv", **tally)
+    if is_retention(cfg, i):
+        y, kept = _retention(cfg, helper, h, nm, i, batch, P, pos_range,
+                             False)
+        cache_names += kept
+        return _block_tail(cfg, x, h, y, nm, i, mix=mix, branch=branch,
+                           **tally)
     if has_latent(cfg):
         # the expanded form through the flash forward; what stays of
         # the prompt is ONE slab of latent rows
@@ -2086,6 +2197,12 @@ def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
         cache_names.append(kept)
         return _layer_tail(cfg, x, y, nm, i, mix, dev, first="conv",
                            **tally)
+    if is_retention(cfg, i):
+        h, mix = _sub_input(cfg, x, nm, 1, dev)
+        y, kept = _retention(cfg, helper, h, nm, i, batch, 1, pos, True)
+        cache_names += kept
+        return _block_tail(cfg, x, h, y, nm, i, mix=mix, dev=dev,
+                           branch=branch, **tally)
     if has_latent(cfg):
         # the absorbed form: one latent row written, and every head
         # reads keys AND values out of the slot's one slab
@@ -2202,8 +2319,8 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
 
     A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is
     one with a layer that keeps a state (an ``'ssm'`` entry of
-    ``mixers``, a ``'conv'`` entry of ``layer_types``: a state has no
-    position to resume at or rewind to) and a
+    ``mixers``, a ``'conv'`` or ``'retention'`` entry of ``layer_types``:
+    a state has no position to resume at or rewind to) and a
     cfg with ring caches (a sliding layer whose window is shorter than
     ``max_len``): the one slab write at
     ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a

@@ -1095,6 +1095,35 @@ SSM_PLANS = REGISTRY.counter(
     "state-space layers lowers L scans, its decode step L updates",
     labels=("op", "kernel", "chunk"))
 
+POWER_PLANS = REGISTRY.counter(
+    "paddle_power_plans_total",
+    "Which form of a power-retention layer's core a program holds (gpt "
+    "cfg['layer_types'] with 'retention' layers, kernels/power.py): one "
+    "count a call of power_scan (a whole prompt, chunked) or power_update "
+    "(one token a slot into the state, in place, the query heads read out "
+    "of it in the same pass) at LOWERING, form 'pallas' or 'composed' "
+    "(jax.numpy: every CPU run, and PADDLE_TPU_KERNELS=0), with the chunk "
+    "the scan takes the attention form in (1 for the update). A prefill "
+    "of a model with L retention layers lowers L scans, its decode step L "
+    "updates",
+    labels=("kernel", "form", "chunk"))
+
+POWER_STATE_BYTES = REGISTRY.gauge(
+    "paddle_power_state_bytes",
+    "Bytes of power-retention state the lane built last holds: the state "
+    "[b_max, G, R, D] and normaliser [b_max, G, D, D] every power_update "
+    "of its decode step reads and writes (the rows R as kept, "
+    "kernels/power.py::phi_plan, not the exact count of pairs). Part of "
+    "paddle_serving_cache_bytes{kind='state'}; 0 for a model without "
+    "such layers")
+
+POWER_CHUNKS = REGISTRY.counter(
+    "paddle_power_chunks_total",
+    "Chunks the admissions' prefills scanned in power-retention layers: "
+    "layers x ceil(prompt / kernels.power.scan_chunk(prompt)) a "
+    "full-prompt prefill (serving/engine.py); the first chunk of a layer "
+    "reads no state")
+
 MHC_RES_DEVIATION = REGISTRY.gauge(
     "paddle_mhc_res_deviation",
     "The largest |row sum - 1| or |column sum - 1| any residual mapping "

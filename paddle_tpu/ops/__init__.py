@@ -25,6 +25,7 @@ from . import (  # noqa: F401
     rnn,
     optimizer_ops,
     pipeline_ops,
+    power_ops,
     scan_ops,
     sequence,
     ssm_ops,

@@ -170,6 +170,9 @@ class _Lane:
         self._warm: set = set()   # (program id, fetch) dispatched once
         self.cfg = cfg
         self._state_layers = len(gpt.state_layers(cfg))
+        # retention layers, whose prefill scans the prompt in chunks
+        self._retention_layers = sum(
+            gpt.is_retention(cfg, i) for i in range(cfg["n_layer"]))
         self.b_max, self.max_len = b_max, max_len
         self.scope = Scope()
         self._prefill_scope = Scope()
@@ -242,20 +245,30 @@ class _Lane:
         position axis), a latent layer's one tensor, rings (shorter than
         ``max_len``) and full slabs."""
         from ..kernels.mla_decode import decode_plan
-        from ..observe.families import SERVING_CACHE_BYTES
+        from ..observe.families import (POWER_STATE_BYTES,
+                                        SERVING_CACHE_BYTES)
+
+        block = self._decode_prog.global_block()
+
+        def size(n):
+            var = block.var(n)
+            return int(np.prod(var.shape)) * np.dtype(var.dtype).itemsize
 
         held = {"ring": 0, "full": 0, "latent": 0, "state": 0}
         blocks = []     # the absorbed kernel's block, a latent layer
         for n in self.cache_names:
-            var = self._decode_prog.global_block().var(n)
+            var = block.var(n)
             kind = self._gpt.cache_kind(self.cfg, n, self.max_len)
-            held[kind] += int(np.prod(var.shape)) \
-                * np.dtype(var.dtype).itemsize
+            held[kind] += size(n)
             if kind == "latent":
                 blocks.append(decode_plan(var.shape, var.dtype,
                                           self.cfg["n_head"]))
         for kind, nbytes in held.items():
             SERVING_CACHE_BYTES.labels(kind=kind).set(nbytes)
+        # of the state: what the retention layers' updates read and write
+        POWER_STATE_BYTES.set(sum(
+            size(n) for op in block.ops if op.type == "power_update"
+            for n in op.input("State") + op.input("Norm")))
         # (latent layers, rows of a block) for
         # paddle_mla_decode_blocks_total: the slabs are of one shape; None
         # where no cache is latent or the kernel has no plan for it
@@ -424,6 +437,12 @@ class _Lane:
                 # the chunks each state-space layer scans the prompt in
                 attrs["chunks"] = -(-P // int(
                     self.cfg.get("ssm_chunk") or 128))
+            if self._retention_layers:
+                from ..kernels.power import scan_chunk
+                from ..observe.families import POWER_CHUNKS
+
+                attrs["chunks"] = -(-P // scan_chunk(P))
+                POWER_CHUNKS.inc(self._retention_layers * attrs["chunks"])
             with _tr.trace_span("serving.engine.prefill", **attrs):
                 with self._scope_guard(self._prefill_scope):
                     (out,) = self._exe.run(

@@ -664,3 +664,95 @@ def test_whole_reduction_plan_matches_composed_on_ragged_groups(n_rhs):
     want = moe_gmm.gmm_composed(lhs, rhs, gs)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
     assert not np.asarray(got[sum(sizes):]).any()
+
+
+def _brumby():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "brumby-14b-base.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+def _power_plans():
+    from paddle_tpu.observe import REGISTRY
+
+    family = REGISTRY.snapshot()["metrics"].get(
+        "paddle_power_plans_total", {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["form"],
+             s["labels"]["chunk"]): s["value"] for s in family["samples"]}
+
+
+def _new_plans(before):
+    return {k: v - before.get(k, 0) for k, v in _power_plans().items()
+            if v != before.get(k, 0)}
+
+
+def test_brumby_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``brumby-14b-base`` serving decode step (32 slots: 6.12
+    GB of retention state and normaliser, no cache with a position axis,
+    bf16 matrices, the untied head) for the described chip: five
+    ``power_update`` Pallas calls, every state and normaliser donated into
+    its output and none copied, and arguments equal to the static bytes
+    the closed form reckons within 1%."""
+    import paddle_tpu as fluid
+    from benchmarks.lib import closed_forms_power
+    from paddle_tpu.kernels import power
+
+    gpt, cfg, serving = _brumby()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    assert [gpt.cache_kind(cfg, n, S) for n in caches] == ["state"] * 10
+    before = _power_plans()
+    lowered, mut_state = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert _new_plans(before) == {("power_update", "pallas", "1"): 5}
+    assert set(caches) <= set(mut_state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % power.KERNEL_UPDATE,
+                              text))) == 5
+    # no second copy of a layer's state: 32 x 8 x 9,216 x 128 float32
+    assert _cache_sized(text, (B, 8, 9216, 128)) == []
+    assert not re.findall(r"%kv_cache_write[.\d]* = ", text)
+    mem = compiled.memory_analysis()
+    static = closed_forms_power.static_bytes(cfg, B, S, 4, 2)
+    # (the token table is an argument too: the step looks its rows up)
+    assert abs(mem.argument_size_in_bytes - static) < 0.01 * static, mem
+    assert mem.alias_size_in_bytes >= closed_forms_power.state_bytes(cfg, B)
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    print("brumby decode step:", mem)
+
+
+def test_brumby_prefill_compiles_for_v5e(v5e, compiled_kernels):
+    """The batch=1 prefill of the longest prompt of the mix (8,192):
+    five ``power_scan`` Pallas calls at the configuration's chunk, no
+    flash forward and no cache write, a head on ONE row, and temporaries
+    that fit beside the 12.5 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import power
+
+    gpt, cfg, serving = _brumby()
+    P = 8192
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    before = _power_plans()
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    assert _new_plans(before) == {
+        ("power_scan", "pallas", str(power.scan_chunk(P))): 5}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % power.KERNEL_SCAN,
+                              text))) == 5
+    assert not re.findall(r"%(flash_fwd|kv_cache_write)[.\d]* = ", text)
+    assert "f32[1,%d,151936]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2.5e9, mem
+    print("brumby prefill P=%d:" % P, mem)

@@ -72,6 +72,10 @@ REACHES = {
     "longcat-flash-omni": {
         "decode": ("kv_cache_write", "mla_decode", "moe_ffn"),
         "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
+    # every layer power retention: no cache write, no flash forward, no
+    # expert: the first configuration that reaches neither
+    "brumby-14b-base": {"decode": ("power_update",),
+                        "prefill": ("power_scan",)},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -102,6 +106,9 @@ RUNS_ON_THE_CHIP = {
     # the facts' plan counters and the device breakdown
     ("longcat-flash-omni", "fused_attention"),
     ("longcat-flash-omni", "mla_decode"), ("longcat-flash-omni", "moe_ffn"),
+    # my chip runs, PR 51: power_scan pallas chunk=1024 and power_update
+    # pallas in the facts' plan counter, both names in the device trace
+    ("brumby-14b-base", "power_scan"), ("brumby-14b-base", "power_update"),
 }
 
 
@@ -352,6 +359,36 @@ def _check_fused_attention(block, op, batch, must):
     return plans
 
 
+def _check_power_update(block, op, batch, must):
+    from paddle_tpu.kernels import power
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    shape, _ = _operand(block, op, "State", batch)
+    norm, _ = _operand(block, op, "Norm", batch)
+    takes = power._update_plan(shape, int(op.attrs["heads"]))
+    assert takes or not must, shape
+    if takes:
+        # a (slot, key-value head) block of the state and of the normaliser
+        assert mosaic_ok((1, 1) + shape[2:], shape)
+        assert mosaic_ok((1, 1) + norm[2:], norm)
+        assert 4 * int(np.prod(shape[2:])) * 4 <= power._VMEM_LIMIT_BYTES
+    return takes
+
+
+def _check_power_scan(block, op, batch, must):
+    from paddle_tpu.kernels import power
+
+    (_B, T, HD), _ = _operand(block, op, "Q", batch)
+    H, G = int(op.attrs["heads"]), int(op.attrs["groups"])
+    chunk = power.scan_chunk(T)
+    Q = power._scan_plan(H, G, HD // H, chunk)
+    assert Q is not None or not must, (T, H, G, HD // H, chunk)
+    if Q is not None:
+        # every prompt of the cell is whole chunks: nothing is padded
+        assert Q == chunk and Q % 128 == 0 and T % Q == 0
+    return Q
+
+
 CHECKS = {
     "fused_attention": _check_fused_attention,
     "kv_cache_write": _check_kv_cache_write,
@@ -359,6 +396,7 @@ CHECKS = {
     "mla_decode": _check_mla_decode,
     "moe_ffn": _check_moe_ffn,
     "ssm_scan": _check_ssm_scan, "ssm_update": _check_ssm_update,
+    "power_scan": _check_power_scan, "power_update": _check_power_update,
 }
 
 
