@@ -37,16 +37,20 @@ i]`` (zero where ``i``'s tile is past ``j``'s): ``[B, G, D, D]``.
   tile and its columns as one ``[D, 8]`` tile. Bound by bytes.
 * ``power_scan`` — a whole prompt (the prefill), CHUNKED: inside a chunk
   of ``Q`` positions the attention form (scores squared under the decay
-  and the causal mask, two matrix products a head), across chunks the
-  state in VMEM scratch: read by the chunk's queries (not by the first
-  chunk's) and then fed the chunk's keys and values, a ``[Q, D] x [D,
-  D]`` product a ``j``. The grid is (slot x group, chunk), chunks
-  innermost and sequential. ``Q`` follows from the prompt's length
-  (``scan_chunk``: the largest the kernel takes, since the chip sweep
-  found the largest chunk the fastest at every length,
-  docs/KERNELS.md). A prompt that is no multiple of ``Q`` is padded
-  with positions of ``k = v = 0`` and gate 1, which neither decay nor
-  feed the state.
+  and the causal mask, two matrix products a head and block of queries,
+  against the keys at or before the block), across chunks the state in
+  VMEM scratch: read by the chunk's queries (not by the first chunk's)
+  and then fed the chunk's keys and values. Both walk the state's 9,216
+  rows in whole MXU tiles: ``phi`` of a tile column of the queries — of
+  the keys — is built once into scratch, its blocks packed as the
+  state's are, and multiplied against the column's rows ``_RUN`` at a
+  time, so no product holds a row that the layout keeps at zero. The
+  grid is (slot x group, chunk), chunks innermost and sequential. ``Q``
+  follows from the prompt's length (``scan_chunk``: the largest the
+  kernel takes, since the chip sweep found the largest chunk the fastest
+  at every length, docs/KERNELS.md). A prompt that is no multiple of
+  ``Q`` is padded with positions of ``k = v = 0`` and gate 1, which
+  neither decay nor feed the state.
 
 Each has a composed ``jax.numpy`` form with the same signature and the
 same state layout: what the CPU runs, what ``PADDLE_TPU_KERNELS=0`` runs
@@ -82,6 +86,16 @@ _LANES = 128
 # the longest chunk of a scan: the largest the chip sweep tried (128 ...
 # 1,024) and the fastest at every prompt length (docs/KERNELS.md)
 _CHUNK_MAX = 1024
+# the rows of the state one product of the scan's read or feed takes: two
+# MXU tiles, which every tile column (256 (Jt + 1) rows) is a whole
+# number of (the chip sweep: 128 is 4% slower at a prompt of 8,192; a
+# whole tile column 3.5% faster at three times the compile time and code)
+_RUN = 256
+# the positions a side of the blocks a chunk's scores are cut in (the
+# same sweep: 36.6 / 36.9 / 37.7 / 39.1 ms at 128 / 256 / 512 / uncut;
+# 128 is twice the blocks to trace and lower for its 0.8%, and a prefill
+# program lowers the kernel five times on the host's clock: 256)
+_SUB = 256
 _VMEM_LIMIT_BYTES = 100 << 20
 _HI = jax.lax.Precision.HIGHEST
 _SQRT2 = 2.0 ** 0.5
@@ -357,14 +371,32 @@ def power_scan_composed(q, k, v, lg, *, chunk):
     return y, S, Z
 
 
+def _column(Jt):
+    """(first row, rows a block, rows) of tile column ``Jt`` of the
+    state: block ``b`` is that of ``j = 16 Jt + b``, and the column's 16
+    blocks are contiguous, ``256 (Jt + 1)`` rows from a multiple of 256."""
+    n = TILE * (Jt + 1)
+    return TILE * TILE * Jt * (Jt + 1) // 2, n, TILE * n
+
+
+def _scan_scratch(J, Q, D=_LANES):
+    """The scan's VMEM scratch: the group's state and normaliser, a
+    head's denominators, the read's accumulator, and ``phi`` of one tile
+    column of the chunk's queries or keys (the widest: ``16 D`` rows)."""
+    return [(phi_plan(D)[2], D), (D, D), (J, 1, Q), (D, Q), (TILE * D, Q)]
+
+
 def _scan_kernel(qt_ref, k_ref, kt_ref, v_ref, vt_ref, lcol_ref, lrow_ref,
                  tcol_ref, trow_ref, yt_ref, so_ref, zo_ref, s_ref, z_ref,
-                 den_ref, acc_ref, *, J, Q):
+                 den_ref, acc_ref, phi_ref, *, J, Q):
     """One chunk of one group, TRANSPOSED (positions along the lanes):
     the queries and the output ``[J, D, Q]``, the scores ``[s, t]``. So
     the column of ``q`` or ``k`` that a block of the state is built from
-    is a ROW here, cut at a dynamic sublane, and the loops over the
-    state's 128 blocks stay loops."""
+    is a ROW here, cut at a dynamic sublane. The state's rows are
+    contiguous over tile columns and blocks, so ``phi`` of a whole tile
+    column is built once into ``phi_ref`` (block ``b`` at the sublane
+    offset ``b n``) and meets the state's rows ``_RUN`` at a time: no
+    row of a product is a zero the layout is known to hold."""
     from jax.experimental import pallas as pl
 
     D = _LANES
@@ -381,36 +413,76 @@ def _scan_kernel(qt_ref, k_ref, kt_ref, v_ref, vt_ref, lcol_ref, lrow_ref,
             a, b, (((lhs_dim,), (0,)), ((), ())), precision=_HI,
             preferred_element_type=jnp.float32)
 
-    def blocks(Jt):
-        """(first row, rows a block) of the state's blocks of tile
-        column ``Jt``: block ``b`` is that of ``j = 16 Jt + b``."""
-        return TILE * TILE * Jt * (Jt + 1) // 2, TILE * (Jt + 1)
-
-    sub_t = jax.lax.broadcasted_iota(jnp.int32, (D, 1), 0) // TILE
-    jt = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0) // TILE
-    it = jax.lax.broadcasted_iota(jnp.int32, (D, D), 1) // TILE
-    cm = _tile_coef(jt, it, D)
-    k, kt, v, vt = k_ref[0], kt_ref[0], v_ref[0], vt_ref[0]
-    lcol, lrow = lcol_ref[0], lrow_ref[0]              # [Q, 1], [1, Q]
-    s_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    t_i = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    decay = jnp.exp(jnp.where(t_i >= s_i, lrow - lcol, -1e30))   # [s, t]
-    def heads(body):
-        """``body(h)`` for every query head of the group: a loop, so
-        that the kernel's code does not grow with the group."""
-        def step(h, _):
-            body(h)
+    def loop(n, body):
+        """``body(i)`` for ``i < n``, as a loop: the kernel's code grows
+        with neither the group's heads nor the state's rows."""
+        def step(i, _):
+            body(i)
             return 0
 
-        jax.lax.fori_loop(0, J, step, 0)
+        jax.lax.fori_loop(0, n, step, 0)
+
+    def expand(rows_of, Jt):
+        """``phi`` of tile column ``Jt`` into ``phi_ref``'s first rows,
+        from ``rows_of(slice)`` of the transposed operand ``[D(i), Q]``:
+        block ``b`` is the operand's first ``n`` rows, under the pair
+        coefficient, times its row ``16 Jt + b``."""
+        _base, n, _rows = _column(Jt)
+        # (``_tile_coef`` of a static ``Jt`` as ONE compare: the host
+        # traces and lowers this kernel five times a prefill program, on
+        # the clock of the cell's set-up)
+        row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+        m = rows_of(pl.ds(0, n)) * jnp.where(
+            row < TILE * Jt, _SQRT2 * D ** -0.5, D ** -0.5)
+
+        def block(b):
+            phi_ref[pl.ds(pl.multiple_of(b * n, TILE), n), :] = \
+                m * rows_of(pl.ds(TILE * Jt + b, 1))
+
+        loop(TILE, block)
+
+    def runs(Jt, body):
+        """``body(row of the state, row of phi_ref)`` for every run of
+        ``_RUN`` rows of tile column ``Jt``."""
+        base, _n, rows = _column(Jt)
+
+        def run(p):
+            r = pl.multiple_of(p * _RUN, _RUN)
+            body(base + r, r)
+
+        loop(rows // _RUN, run)
+
+    cm = _tile_coef(*(jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, (D, D), axis),
+        TILE.bit_length() - 1) for axis in (0, 1)), D)
+    lcol, lrow = lcol_ref[0], lrow_ref[0]              # [Q, 1], [1, Q]
+    # a chunk's square of scores [s, t] in blocks of ``_SUB`` positions:
+    # a block of queries meets the keys at or before it, under plain
+    # ``exp(lrow - lcol)`` below the diagonal block and the causal mask
+    # on it
+    cuts = [(t0, min(t0 + _SUB, Q)) for t0 in range(0, Q, _SUB)]
+    decays, causal = [], {}
+    for t0, t1 in cuts:
+        w = t1 - t0
+        if w not in causal:
+            causal[w] = jax.lax.broadcasted_iota(jnp.int32, (w, w), 1) \
+                >= jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+        lr = lrow_ref[0, :, t0:t1]
+        on = jnp.exp(jnp.where(causal[w], lr - lcol_ref[0, t0:t1, :],
+                               -1e30))
+        decays.append(on if t0 == 0 else jnp.concatenate(
+            [jnp.exp(lr - lcol_ref[0, :t0, :]), on], axis=0))
 
     def inside(h):
-        s = dot(k, qt_ref[0, h]) * D ** -0.5           # [s, t]
-        a = s * s * decay
-        yt_ref[0, h] = dot(vt, a)                      # [D, t]
-        den_ref[h] = jnp.sum(a, axis=0, keepdims=True)
+        for (t0, t1), decay in zip(cuts, decays):
+            at = pl.ds(t0, t1 - t0)
+            s = dot(k_ref[0, :t1, :], qt_ref[0, h, :, at]) * D ** -0.5
+            a = s * s * decay                          # [s <= t1, t]
+            yt_ref[0, h, :, at] = dot(vt_ref[0, :, :t1], a)
+            den_ref[h, :, at] = jnp.sum(a, axis=0, keepdims=True)
 
-    heads(inside)
+    with jax.named_scope("inside"):
+        loop(J, inside)
 
     @pl.when(c > 0)
     def _():
@@ -421,47 +493,42 @@ def _scan_kernel(qt_ref, k_ref, kt_ref, v_ref, vt_ref, lcol_ref, lrow_ref,
         def before(h):
             qt = qt_ref[0, h]                          # [D(i), Q]
             acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            def read(r_state, r_phi):
+                acc_ref[...] += dot(s_ref[pl.ds(r_state, _RUN), :],
+                                    phi_ref[pl.ds(r_phi, _RUN), :],
+                                    lhs_dim=0)
+
             for Jt in range(tiles):
-                base, n = blocks(Jt)
-                qm = qt * _tile_coef(Jt, sub_t, D)     # zero past the tile
-
-                def read(b, _, Jt=Jt, base=base, n=n, qm=qm):
-                    r0 = pl.multiple_of(base + b * n, TILE)
-                    # rows past the block's meet the zeros of ``qm``
-                    acc_ref[...] += dot(
-                        s_ref[pl.ds(r0, D), :],
-                        qm * qt_ref[0, h, pl.ds(TILE * Jt + b, 1), :],
-                        lhs_dim=0)
-                    return 0
-
-                jax.lax.fori_loop(0, TILE, read, 0)
+                expand(lambda rows: qt_ref[0, h, rows, :], Jt)
+                runs(Jt, read)
             yt_ref[0, h] = yt_ref[0, h] + elc * acc_ref[...]
             den_ref[h] = den_ref[h] + elc * jnp.sum(
                 qt * dot(cz, qt), axis=0, keepdims=True)
 
-        heads(before)
+        with jax.named_scope("read"):
+            loop(J, before)
 
     def normalise(h):
         yt_ref[0, h] = yt_ref[0, h] / (den_ref[h] + EPS)
 
-    heads(normalise)
+    loop(J, normalise)
 
     # the chunk's keys and values into the state
-    wv = jnp.exp(tcol_ref[0] - lcol) * v               # [Q, D]
+    k, kt = k_ref[0], kt_ref[0]
+    wv = jnp.exp(tcol_ref[0] - lcol) * v_ref[0]        # [Q, D]
     trow = trow_ref[0]                                 # [1, Q]
     etot = jnp.exp(trow[:, :D])
-    for Jt in range(tiles):
-        base, n = blocks(Jt)
-        km = kt * _tile_coef(Jt, sub_t, D)             # [D(i), Q]
 
-        def feed(b, _, Jt=Jt, base=base, n=n, km=km):
-            r0 = pl.multiple_of(base + b * n, TILE)
-            upd = dot(km * kt_ref[0, pl.ds(TILE * Jt + b, 1), :], wv)
-            s_ref[pl.ds(r0, n), :] = etot * s_ref[pl.ds(r0, n), :] \
-                + upd[:n]
-            return 0
+    def feed(r_state, r_phi):
+        at = pl.ds(r_state, _RUN)
+        s_ref[at, :] = etot * s_ref[at, :] \
+            + dot(phi_ref[pl.ds(r_phi, _RUN), :], wv)
 
-        jax.lax.fori_loop(0, TILE, feed, 0)
+    with jax.named_scope("feed"):
+        for Jt in range(tiles):
+            expand(lambda rows: kt_ref[0, rows, :], Jt)
+            runs(Jt, feed)
     z_ref[...] = etot * z_ref[...] + cm * dot(kt * jnp.exp(trow - lrow), k)
 
     @pl.when(c == pl.num_programs(1) - 1)
@@ -529,10 +596,8 @@ def power_scan_pallas(q, k, v, lg, *, chunk, interpret=None):
         out_shape=[jax.ShapeDtypeStruct((BG, J, D, Tp), jnp.float32),
                    jax.ShapeDtypeStruct((BG, R, D), jnp.float32),
                    jax.ShapeDtypeStruct((BG, D, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((R, D), jnp.float32),
-                        pltpu.VMEM((D, D), jnp.float32),
-                        pltpu.VMEM((J, 1, Q), jnp.float32),
-                        pltpu.VMEM((D, Q), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                        for shape in _scan_scratch(J, Q)],
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

@@ -730,9 +730,10 @@ def test_brumby_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
 
 def test_brumby_prefill_compiles_for_v5e(v5e, compiled_kernels):
     """The batch=1 prefill of the longest prompt of the mix (8,192):
-    five ``power_scan`` Pallas calls at the configuration's chunk, no
-    flash forward and no cache write, a head on ONE row, and temporaries
-    that fit beside the 12.5 GB the engine holds."""
+    five ``power_scan`` Pallas calls at the configuration's chunk, every
+    state an output no copy follows, no flash forward and no cache write,
+    a head on ONE row, and temporaries that fit beside the 12.5 GB the
+    engine holds."""
     import paddle_tpu as fluid
     from paddle_tpu.kernels import power
 
@@ -752,6 +753,11 @@ def test_brumby_prefill_compiles_for_v5e(v5e, compiled_kernels):
     assert len(set(re.findall(r"%%(%s[.\d]*) = " % power.KERNEL_SCAN,
                               text))) == 5
     assert not re.findall(r"%(flash_fwd|kv_cache_write)[.\d]* = ", text)
+    # a layer's state leaves its kernel as the program's output: nothing
+    # copies or relays 8 x 9,216 x 128 float32 on the way (that the
+    # kernel's scratch fits its VMEM limit is Mosaic's verdict here, and
+    # tests/test_kernel_plans.py's from the plan's own numbers)
+    assert _cache_sized(text, (1, 8, 9216, 128)) == []
     assert "f32[1,%d,151936]" % P not in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem

@@ -386,6 +386,19 @@ def _check_power_scan(block, op, batch, must):
     if Q is not None:
         # every prompt of the cell is whole chunks: nothing is padded
         assert Q == chunk and Q % 128 == 0 and T % Q == 0
+        # what a grid step holds in VMEM: the kernel's scratch (state,
+        # normaliser, denominators, the read's accumulator, phi of one
+        # tile column) and every block twice (queries and output [J, D,
+        # Q], k and v both ways round, four gate sums a lane or sublane
+        # tile wide, the state and the normaliser as outputs)
+        J, D = H // G, HD // H
+        scratch = power._scan_scratch(J, Q)
+        R = power.phi_plan(D)[2]
+        assert scratch[0] == (R, D) and scratch[-1] == (16 * D, Q)
+        blocks = 2 * J * D * Q + 4 * Q * D + 2 * Q * 128 + 2 * 8 * Q \
+            + R * D + D * D
+        held = 4 * (sum(int(np.prod(s)) for s in scratch) + 2 * blocks)
+        assert held <= power._VMEM_LIMIT_BYTES // 2, held
     return Q
 
 

@@ -173,6 +173,105 @@ def test_the_kernels_are_their_composed_forms():
         == [128, 128, 256, 1024, 1024, 1024]
 
 
+SCAN_CASES = {
+    # id: (B, T, chunk, J, G, gate). The state has all eight tile columns
+    # at D 128 whatever the rest, so every read and feed crosses them.
+    "one_chunk_no_state_read": (1, 128, 128, 5, 1, (0.99, 0.9995)),
+    "three_chunks": (1, 384, 128, 5, 1, (0.99, 0.9995)),
+    "ragged_prompt_two_groups": (2, 300, 128, 1, 2, (0.99, 0.9995)),
+    "eight_heads_gate_0.5": (1, 256, 128, 8, 1, (0.5, 0.5)),
+    # longer chunks, so that a chunk's scores are several blocks a side
+    "three_score_blocks_gate_0.5": (1, 700, 384, 1, 1, (0.5, 0.5)),
+    "four_score_blocks": (1, 1024, 512, 1, 1, (0.99, 0.9995)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_the_scan_kernel_is_the_composed_and_the_attention_form(case):
+    """``power_scan_pallas`` (interpret mode) where its walk over whole
+    tiles of the kept rows and its score blocks can go wrong: no state
+    read, a read and a feed a chunk, padded positions, one, five and
+    eight query heads a key-value head, both gates — against the
+    composed form (output, state, normaliser) and against the
+    reference's attention form over the whole sequence (output; 5e-5
+    there: at a gate of 0.5 the COMPOSED form stands 3e-5 from it, the
+    recurrence summing in another order under small denominators)."""
+    B, T, chunk, J, G, gate = SCAN_CASES[case]
+    q, k, v, lg = _operands(len(case), B, T, J * G, G, 128, gate)
+    y, S, Z = power.power_scan_composed(q, k, v, lg, chunk=chunk)
+    yp, Sp, Zp = power.power_scan_pallas(q, k, v, lg, chunk=chunk,
+                                         interpret=True)
+    scale = float(jnp.abs(S).max())
+    np.testing.assert_allclose(np.asarray(yp), np.asarray(y), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(Sp), np.asarray(S),
+                               atol=1e-6 * scale)
+    np.testing.assert_allclose(np.asarray(Zp), np.asarray(Z),
+                               atol=1e-6 * scale)
+    np.testing.assert_allclose(
+        np.asarray(yp), np.asarray(_attention_form(q, k, v, lg)), atol=5e-5)
+
+
+def _kernel_dots(jaxpr, scope="", times=1):
+    """``(scopes, trips, eqn)`` of every ``dot_general`` a kernel's
+    jaxpr holds: the ``jax.named_scope``s round it and how often the
+    loops round it run it."""
+    for eqn in jaxpr.eqns:
+        here = "%s/%s" % (scope, eqn.source_info.name_stack)
+        if eqn.primitive.name == "dot_general":
+            yield here, times, eqn
+            continue
+        trips = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_dots(sub, here, trips)
+
+
+@pytest.mark.parametrize("Q", [128, 512, 640, 1024])
+def test_the_scan_multiplies_the_kept_rows_and_the_lower_triangle(Q):
+    """One head and one chunk of the kernel, read off its jaxpr: the
+    state's read contracts over 9,216 rows and the feed writes 9,216 (128
+    padded blocks of 128 were 16,384), each in whole ``_RUN``s, and the
+    scores inside the chunk are the blocks at or under the diagonal."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["jaxpr"]
+            for val in eqn.params.values():
+                sub = getattr(val, "jaxpr", val)
+                found = find(sub) if hasattr(sub, "eqns") else None
+                if found is not None:
+                    return found
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    kernel = find(jax.make_jaxpr(lambda *a: power.power_scan_pallas(
+        *a, chunk=Q, interpret=True))(
+            sd(1, 2 * Q, 1, 128), sd(1, 2 * Q, 1, 128),
+            sd(1, 2 * Q, 1, 128), sd(1, 2 * Q, 1)).jaxpr)
+    read = feed = pairs = inside = 0
+    for scope, trips, eqn in _kernel_dots(kernel):
+        lhs, rhs = (v.aval.shape for v in eqn.invars)
+        (lc, rc), _batch = eqn.params["dimension_numbers"]
+        if "read" in scope and lc == (0,):
+            assert lhs == (power._RUN, 128) and rhs == (power._RUN, Q)
+            read += trips * lhs[0]
+        elif "feed" in scope:
+            assert lhs == (power._RUN, Q) and rhs == (Q, 128)
+            feed += trips * lhs[0]
+        elif "inside" in scope:
+            # k [s, D] x q^T [D, t], then v^T [D, s] x a [s, t]: the
+            # (key, query) pairs of either are its products over D
+            pairs += trips * lhs[0] * lhs[1] * rhs[1] // 128
+            inside += 1
+    assert read == feed == power.phi_plan(128)[2] == 9216
+    assert inside == 2 * -(-Q // power._SUB)
+    pairs //= 2
+    # whole blocks at or under the diagonal, and no more
+    assert Q * (Q + 128) // 2 <= pairs <= Q * (Q + power._SUB) // 2
+
+
 # ------------------------------------------------------------ the engine
 @pytest.fixture(scope="module")
 def served():
