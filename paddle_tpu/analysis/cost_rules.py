@@ -515,6 +515,50 @@ def _cost_power_update(ctx):
     return state.scaled(3 + 2 * (H // G))
 
 
+def _delta_sizes(ctx):
+    """(positions B T as a poly, Hv, Dk, Dv) or None."""
+    qs, vs = ctx.input_shape("Q"), ctx.input_shape("V")
+    Hk = int(ctx.attr("k_heads", 0) or 0)
+    Hv = int(ctx.attr("v_heads", 0) or 0)
+    if qs is None or vs is None or not Hk or not Hv or qs[-1] < 0 \
+            or vs[-1] < 0:
+        return None
+    pos = ctx.elems(tuple(qs[:-1]))
+    return None if pos is None else (pos, Hv, qs[-1] // Hk, vs[-1] // Hv)
+
+
+@register_cost_rule("delta_scan")
+def _cost_delta_scan(ctx):
+    """A position and value head, chunked in Q (``kernels.delta.
+    scan_chunk``): the chunk's ``K K^T`` and ``Q K^T`` (4 Q Dk, shared by
+    a key head's value heads: counted whole, an upper bracket), the state
+    read at its keys and queries (4 Dk Dv), the inverse by halves (4 Q^2
+    (log2 Q - 1)) and its use (2 Q Dv), the readout within the chunk (2
+    Q Dv) and the feed of the state (2 Dk Dv). Bytes: the generic
+    model's."""
+    sizes = _delta_sizes(ctx)
+    T = (ctx.input_shape("Q") or (0, -1))[1]
+    if sizes is None or T < 0:
+        return ctx.out_elems()
+    from ..kernels.delta import scan_chunk
+
+    pos, Hv, Dk, Dv = sizes
+    Q = scan_chunk(T)
+    halves = max(Q.bit_length() - 2, 0)
+    return pos.scaled(Hv * (4 * Q * Dk + 6 * Dk * Dv + 4 * Q * Q * halves
+                            + 4 * Q * Dv))
+
+
+@register_cost_rule("delta_update")
+def _cost_delta_update(ctx):
+    """Seven operations a value of the state: the decay, ``S^T k``, the
+    rank-one correction, ``S^T q``. Bytes: the generic model's — the state
+    read and written once is what the step costs."""
+    st = ctx.input_shape("State")
+    state = None if st is None else ctx.elems(st)
+    return ctx.out_elems() if state is None else state.scaled(7)
+
+
 @register_cost_rule("causal_conv", "causal_conv_step")
 def _cost_causal_conv(ctx):
     """2 K operations a value and about 5 for the silu (attr ``act``)."""

@@ -436,6 +436,35 @@ def _fp_power_update(ctx):
     return BytesPoly.from_dims((st[0], st[1], 8, st[3]), 4).scaled(4)
 
 
+@register_footprint_rule("delta_scan")
+def _fp_delta_scan(ctx):
+    """The operands regrouped by key head and padded to whole chunks (q
+    once, k twice: both ways round; v and ``Y`` once each), and a chunk's
+    [Q, Q] matrices a head (the Pallas kernel keeps them in VMEM: an
+    upper bracket either way)."""
+    from ..kernels.delta import scan_chunk
+
+    qs, vs = ctx.input_shape("Q"), ctx.input_shape("V")
+    if qs is None or vs is None or qs[1] < 0:
+        return None
+    Hv = int(ctx.attr("v_heads", 1) or 1)
+    Q = scan_chunk(qs[1])
+    return BytesPoly.from_dims(tuple(qs), 4).scaled(3) \
+        + BytesPoly.from_dims(tuple(vs), 4).scaled(2) \
+        + BytesPoly.from_dims((qs[0], Q, Q, Hv), 4).scaled(4)
+
+
+@register_footprint_rule("delta_update")
+def _fp_delta_update(ctx):
+    """The token's rows ([B, 3 Hv, Dv]) and columns ([B, Dk, 128]) the
+    kernel reads beside the state; the state is updated in place."""
+    st = ctx.input_shape("State")
+    if st is None or len(st) != 4:
+        return None
+    return BytesPoly.from_dims((st[0], 3 * st[1], st[3]), 4) \
+        + BytesPoly.from_dims((st[0], st[2], 128), 4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

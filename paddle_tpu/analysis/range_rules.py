@@ -880,6 +880,20 @@ def _rr_power(ctx):
     ctx.set("NormOut", top)
 
 
+@register_range_rule("delta_scan", "delta_update")
+def _rr_delta(ctx):
+    """The state is a decaying sum (``exp(g) <= 1``) of corrections ``k
+    u^T`` with unit keys and ``beta < 1``: finite where the operands are,
+    with no bound that a sequence's length does not move."""
+    slots = ["Q", "K", "V", "Beta", "A"]
+    if ctx.op.type == "delta_update":
+        slots.append("State")
+    top = AbstractValue(-F32_MAX, F32_MAX,
+                        finite=all(ctx.input_av(s).finite for s in slots))
+    ctx.set("Y", top)
+    ctx.set("StateOut", top)
+
+
 @register_range_rule("causal_conv", "causal_conv_step")
 def _rr_causal_conv(ctx):
     """``silu`` of a K-term sum of products: at least silu's minimum

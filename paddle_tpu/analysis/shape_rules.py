@@ -335,6 +335,49 @@ def _r_power_update(ctx):
         ctx.fail("power_update takes one position a row; Q is %s" % (qs,))
 
 
+def _delta_out(ctx, kept):
+    """``Y`` is ``V``'s shape; ``StateOut [B, Hv, Dk, Dv]`` from the op's
+    widths, or the shape of the ``kept`` input where the op has it."""
+    qs, vs = ctx.input_shape("Q"), ctx.input_shape("V")
+    Hk = int(ctx.attr("k_heads", 0) or 0)
+    Hv = int(ctx.attr("v_heads", 0) or 0)
+    if Hk < 1 or Hv < 1 or Hv % Hk:
+        ctx.fail("%s needs value heads >= 1 over key heads that divide "
+                 "them" % ctx.op.type)
+        return
+    if vs is not None:
+        ctx.set("Y", vs)
+    st = ctx.input_shape("State") if kept else None
+    if st is None and qs is not None and vs is not None \
+            and qs[-1] >= 0 and vs[-1] >= 0:
+        if qs[-1] % Hk or vs[-1] % Hv:
+            ctx.fail("Q %s / V %s are not [..., %d / %d heads * D]"
+                     % (qs, vs, Hk, Hv))
+            return
+        st = (vs[0], Hv, qs[-1] // Hk, vs[-1] // Hv)
+    if st is not None:
+        ctx.set("StateOut", st)
+    for slot, want in (("K", qs[-1] if qs is not None else -1),
+                       ("Beta", Hv), ("A", Hv)):
+        got = ctx.input_shape(slot)
+        if want >= 0 and got is not None and got[-1] >= 0 \
+                and got[-1] != want:
+            ctx.fail("%s %s is not [..., %d]" % (slot, got, want))
+
+
+@register_shape_rule("delta_scan")
+def _r_delta_scan(ctx):
+    _delta_out(ctx, False)
+
+
+@register_shape_rule("delta_update")
+def _r_delta_update(ctx):
+    _delta_out(ctx, True)
+    qs = ctx.input_shape("Q")
+    if qs is not None and len(qs) == 3 and qs[1] not in (1, -1):
+        ctx.fail("delta_update takes one position a row; Q is %s" % (qs,))
+
+
 @register_shape_rule("causal_conv", "causal_conv_step")
 def _r_causal_conv(ctx):
     """Out is X's shape [B, T, C]; RowsOut [B, K - 1, C] under W [C, K]."""

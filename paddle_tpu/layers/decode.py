@@ -12,7 +12,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post",
-           "ssm_mix", "power_retention", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+           "ssm_mix", "power_retention", "delta_rule", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -94,7 +94,8 @@ def beam_gather(x, parent_idx, name=None):
     return out
 
 
-def rope(x, pos, base=10000.0, name=None, yarn=None, heads_last=False):
+def rope(x, pos, base=10000.0, name=None, yarn=None, heads_last=False,
+         rotary_dim=None):
     """Rotary position embedding on a head tensor [..., S, D] (D even,
     rotate-half convention; ``heads_last``: on [B, S, H, D], the heads
     where a projection's reshape leaves them, so that no transpose
@@ -109,12 +110,18 @@ def rope(x, pos, base=10000.0, name=None, yarn=None, heads_last=False):
     dimension j keeps ``base^(-2j/D)`` below ``low``, has it divided by
     ``factor`` above ``high`` and a linear blend between, and cos and
     sin are multiplied by ``mscale``; factor 1 is the plain rotation bit
-    for bit."""
+    for bit. ``rotary_dim`` (even, below D) rotates only the first that
+    many values of a head — pairs ``(x_j, x_{j + rotary_dim / 2})`` at
+    ``base^(-2j / rotary_dim)`` — and passes the others."""
     if x.shape is not None and x.shape[-1] is not None \
             and int(x.shape[-1]) % 2:
         raise ValueError(
             "rope needs an even head dim (rotate-half pairs); got %s"
             % (x.shape[-1],))
+    if rotary_dim is not None and (
+            int(rotary_dim) % 2 or not 0 < int(rotary_dim) <= x.shape[-1]):
+        raise ValueError("rope rotary_dim must be even and in (0, %s]; got "
+                         "%r" % (x.shape[-1], rotary_dim))
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     attrs = {"base": float(base)}
@@ -125,6 +132,8 @@ def rope(x, pos, base=10000.0, name=None, yarn=None, heads_last=False):
                      yarn_mscale=float(yarn.get("mscale", 1.0)))
     if heads_last:
         attrs["heads_last"] = True
+    if rotary_dim is not None and int(rotary_dim) < x.shape[-1]:
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op(type="rope", inputs={"X": [x], "Pos": [pos]},
                      outputs={"Out": [out]}, attrs=attrs)
     out.shape = x.shape
@@ -327,4 +336,45 @@ def power_retention(q, k, v, gate, state, norm, heads, groups, step=False,
                      outputs={"Y": [y], "StateOut": [state],
                               "NormOut": [norm]}, attrs=attrs)
     y.shape = q.shape
+    return y
+
+
+def delta_rule(q, k, v, beta, a, state, k_heads, v_heads, prefix,
+               step=False, name=None):
+    """The core of a gated delta-rule layer (ops ``delta_scan`` /
+    ``delta_update``, kernels/delta.py): a state ``S [Dk, Dv]`` a value
+    head that is decayed, read at the key, corrected by what it got wrong
+    of the value and read at the query, ``S <- exp(g) S; S <- S + k (beta
+    (v - S^T k))^T; o = S^T q``. ``q`` / ``k`` ``[B, T, Hk Dk]`` and ``v
+    [B, T, Hv Dv]`` as the convolution leaves them (the op takes the l2
+    norm of every query and key head; value head ``h`` reads key head ``h
+    // (Hv / Hk)``), ``beta`` / ``a`` ``[B, T, Hv]`` raw (the op takes
+    ``sigmoid(beta)`` and ``g = -exp(a_log) softplus(a + dt_b)``).
+    ``state`` is a persistable ``[B, Hv, Dk, Dv]`` var
+    (``kernels.delta.state_shape``): a whole prompt (``step=False``) is
+    scanned from zero, in chunks, and leaves its final state there; one
+    token (``step=True``, ``T`` = 1) updates it in place and is read out
+    of the new state. Returns ``y [B, T, Hv Dv]``. Parameters
+    ``<prefix>_a_log`` and ``<prefix>_dt_b``, each ``[Hv]``."""
+    from ..initializer import Constant
+
+    helper = LayerHelper("delta_rule", name=name)
+    Hv = int(v_heads)
+
+    def vec(part):
+        return helper.create_parameter(
+            ParamAttr(name="%s_%s" % (prefix, part),
+                      initializer=Constant(0.0)),
+            [Hv], dtype="float32", is_bias=True)
+
+    inputs = {"Q": [q], "K": [k], "V": [v], "Beta": [beta], "A": [a],
+              "ALog": [vec("a_log")], "DtBias": [vec("dt_b")]}
+    if step:
+        inputs["State"] = [state]
+    y = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="delta_update" if step else "delta_scan",
+                     inputs=inputs,
+                     outputs={"Y": [y], "StateOut": [state]},
+                     attrs={"k_heads": int(k_heads), "v_heads": Hv})
+    y.shape = v.shape
     return y

@@ -140,6 +140,7 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             route_scale: float = 1.0,
             n_expert_local: Optional[int] = None, expert_first: int = 0,
             n_shared_expert: int = 0,
+            shared_expert_gate: bool = False,
             touched: Optional[Variable] = None,
             expert_input: Optional[Variable] = None,
             norm_topk_eps: Optional[float] = None,
@@ -194,7 +195,9 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
     layer). ``n_shared_expert`` adds that many always-on swiglu experts
     of width ``d_hidden`` (one bias-free SwiGLU of their joint width,
     ``<prefix>_shared_{gate,up,down}.w_0``) to the output, whole on
-    every share. ``touched`` is a persistable [rows, n_expert_local]
+    every share; ``shared_expert_gate`` multiplies that sum by the
+    token's own scalar ``sigmoid(x w)`` (``<prefix>_shared_sgate.w_0 [D,
+    1]``). ``touched`` is a persistable [rows, n_expert_local]
     int32 var: row ``counts_row`` counts the calls in which each held
     expert was given at least one pair. A share's call over enough
     tokens cuts its sorted pair rows at twice the share's even part of
@@ -242,6 +245,9 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             % (expert_first, int(expert_first) + n_local - 1, n_experts))
     if n_shared_expert and act != "swiglu":
         raise ValueError("moe_ffn: shared experts are swiglu experts")
+    if shared_expert_gate and not n_shared_expert:
+        raise ValueError("moe_ffn: shared_expert_gate gates "
+                         "n_shared_expert's sum")
     if n_zero and (not dropless or expert_input is not None):
         raise ValueError(
             "moe_ffn: n_zero_expert (identity experts) needs dropless=True "
@@ -353,7 +359,11 @@ def moe_ffn(x: Variable, n_experts: int, d_hidden: int,
             hid = _nn.elementwise_mul(
                 fc(x, wide, "shared_gate", act="swish"),
                 fc(x, wide, "shared_up"))
-            out = _nn.elementwise_add(out, fc(hid, D, "shared_down"))
+            shared = fc(hid, D, "shared_down")
+            if shared_expert_gate:
+                shared = _nn.elementwise_mul(
+                    shared, fc(x, 1, "shared_sgate", act="sigmoid"))
+            out = _nn.elementwise_add(out, shared)
     prog = helper.main_program
     ep = getattr(prog, "_expert_params", None)
     if ep is None:

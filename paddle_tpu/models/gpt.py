@@ -329,6 +329,62 @@ def base_config():
              layer_types=["retention"] * 40, ffn_act="swiglu",
              d_ff=17408, weight_dtype="bfloat16")
 
+    The gated delta rule as a layer's FIRST sub-block (``layer_types``
+    entry ``"delta"``, arXiv:2412.06464; serving programs only) beside
+    ``"full"`` attention layers: the layer stays the ordinary pair and
+    only what stands where attention stood changes. ``delta_k_heads``
+    (Hk) key heads of ``delta_k_dim`` (Dk), ``delta_v_heads`` (Hv, a
+    multiple of Hk) value heads of ``delta_v_dim`` (Dv); value head ``j``
+    reads key head ``j // (Hv / Hk)``. With ``u`` the normed input: ``[q |
+    k | v | z] = u W_in`` (``Hk Dk | Hk Dk | Hv Dv | Hv Dv``), ``[b | a] =
+    u W_ba`` (``Hv | Hv``); ``[q | k | v]`` pass a causal depth-wise
+    convolution of ``kernels.delta.CONV_TAPS`` (4) taps without bias and
+    a silu, ``z`` does not. Per head ``q <- q / sqrt(sum q^2 + 1e-6) /
+    sqrt(Dk)``, ``k <- k / sqrt(sum k^2 + 1e-6)``, ``beta = sigmoid(b)``,
+    ``g = -exp(a_log) softplus(a + dt_b)``, and with ``S [Dk, Dv]`` a
+    value head: ``S <- exp(g) S``; ``S <- S + k (beta (v - S^T k))^T``;
+    ``o = S^T q`` — the state is READ at the key before it is written.
+    Then ``y = scale * (o / rms(o)) * silu(z)`` over each head's ``Dv``
+    values (one ``[Dv]`` scale the heads share) and ``W_out``. What a
+    sequence KEEPS of such a layer has no position axis: the state
+    ``gpt_<i>_cache_s [B, Hv, Dk, Dv]`` (kernels/delta.py says why it
+    lies so) and the last three rows of the un-convolved ``[q | k | v]``,
+    ``gpt_<i>_cache_x [B, 3, 2 Hk Dk + Hv Dv]`` (``cache_kind`` calls
+    both ``state``), in ONE lane with the slabs of the ``"full"`` layers.
+    The prefill scans the prompt in chunks (op ``delta_scan``) and
+    overwrites both; a decode step updates them in place
+    (``delta_update``, ``causal_conv_step``). Parameters
+    ``gpt_<i>_delta_in.w_0``, ``gpt_<i>_delta_ba.w_0``,
+    ``gpt_<i>_delta_conv.w_0 [C, 4]`` (the taps: float32 whatever
+    ``weight_dtype``), ``gpt_<i>_delta_{a_log,dt_b} [Hv]``,
+    ``gpt_<i>_delta_norm_s [Dv]``, ``gpt_<i>_delta_out.w_0``. It takes
+    none of ``attn``, ``residual``, ``mixers``, ``shortcut_moe``; the
+    training build, the multi-token step, a prefix store and a draft
+    model refuse it by name. Two keys came with it for the attention
+    layers and the experts: ``rope_dim`` (even, at most ``d_head``) — only
+    the first that many values of a head rotate, rotate-half inside them;
+    ``shared_expert_gate`` — the shared experts' sum times the token's
+    ``sigmoid(h w)`` (``gpt_<i>_moe_shared_sgate.w_0 [D, 1]``).
+
+    Qwen3-Next-80B-A3B-Instruct (``model_type`` qwen3_next), as the
+    worked example — published widths, all 48 layers, every expert (the
+    published RMSNorm multiplies by ``1 + w``: one parameterisation with
+    the scale here)::
+
+        dict(d_model=2048, n_head=16, n_kv_head=2, d_head=256,
+             n_layer=48, vocab=151936, max_length=262144, dropout=0.0,
+             pos_emb="rope", rope_theta=10000000.0, rope_dim=64,
+             norm="rms", norm_eps=1e-6, qk_norm="head", attn_gate=True,
+             tie_embeddings=False,
+             layer_types=["delta", "delta", "delta", "full"] * 12,
+             delta_k_heads=16, delta_v_heads=32, delta_k_dim=128,
+             delta_v_dim=128, ffn_act="swiglu", n_expert=512,
+             expert_top_k=10, d_expert=512, n_shared_expert=1,
+             shared_expert_gate=True, router_score="softmax",
+             norm_topk=True, weight_dtype="bfloat16")
+
+    (one chip's share adds ``n_expert_local=64, expert_first=0``).
+
     Shortcut-connected experts (``shortcut_moe``; serving programs
     only): a published layer is attention, dense FFN, attention, dense
     FFN, with ONE routed branch that reads the first attention's
@@ -410,11 +466,15 @@ _CFG_KEYS = frozenset([
     "conv_taps", "norm_topk_eps",
     "shortcut_moe", "n_zero_expert", "mla_scale_q_lora",
     "mla_scale_kv_lora",
+    "delta_k_heads", "delta_v_heads", "delta_k_dim", "delta_v_dim",
+    "rope_dim", "shared_expert_gate",
 ])
 _SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
              "ssm_conv")
 MIXER_KINDS = ("ssm", "attention", "experts")
-LAYER_TYPES = ("sliding", "full", "conv", "retention")
+LAYER_TYPES = ("sliding", "full", "conv", "retention", "delta")
+_DELTA_KEYS = ("delta_k_heads", "delta_v_heads", "delta_k_dim",
+               "delta_v_dim")
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
@@ -503,6 +563,9 @@ def _check_cfg(cfg):
                              "; got %r" % (cfg["n_dense_layer"],))
         if cfg.get("n_dense_layer") and "d_ff" not in cfg:
             raise ValueError("cfg['n_dense_layer'] needs cfg['d_ff']")
+        if cfg.get("shared_expert_gate") and not cfg.get("n_shared_expert"):
+            raise ValueError("cfg['shared_expert_gate'] gates the sum of "
+                             "cfg['n_shared_expert'] shared experts")
         if cfg.get("d_shared_expert") and cfg.get("n_shared_expert"):
             raise ValueError(
                 "cfg['d_shared_expert'] is the width of the ONE shared "
@@ -513,7 +576,8 @@ def _check_cfg(cfg):
         for key in ("n_dense_layer", "n_shared_expert", "router_score",
                     "router_bias", "route_scale", "n_expert_local",
                     "expert_first", "d_expert_in", "d_shared_expert",
-                    "norm_topk_eps", "n_zero_expert", "shortcut_moe"):
+                    "norm_topk_eps", "n_zero_expert", "shortcut_moe",
+                    "shared_expert_gate"):
             if cfg.get(key):
                 raise ValueError("cfg[%r] needs cfg['n_expert']" % key)
     if cfg.get("ffn_act") == "relu2" and (
@@ -568,6 +632,17 @@ def _check_cfg(cfg):
         raise ValueError("cfg['window'] needs cfg['layer_types']")
     _check_conv(cfg)
     _check_retention(cfg)
+    _check_delta(cfg)
+    if cfg.get("rope_dim") is not None:
+        dim = int(cfg["rope_dim"])
+        if cfg.get("pos_emb", "learned") != "rope" or has_latent(cfg) \
+                or cfg.get("rope_scaling") \
+                or dim % 2 or not 0 < dim <= _d_head(cfg):
+            raise ValueError(
+                "cfg['rope_dim'] is how many of a head's cfg['d_head'] "
+                "values rotate: even, in (0, %d], with pos_emb='rope' and "
+                "neither attn='mla' (whose d_rope it is) nor rope_scaling; "
+                "got %r" % (_d_head(cfg), cfg["rope_dim"]))
     if cfg.get("rope_layers", "all") != "all" \
             and cfg.get("pos_emb", "learned") != "rope":
         raise ValueError("cfg['rope_layers'] needs pos_emb='rope'")
@@ -702,6 +777,34 @@ def _check_retention(cfg):
                              % (key, why))
 
 
+def _check_delta(cfg):
+    """A ``'delta'`` entry of cfg['layer_types'] and the keys that go
+    with it (``base_config``)."""
+    if "delta" not in (cfg.get("layer_types") or ()):
+        for key in _DELTA_KEYS:
+            if cfg.get(key):
+                raise ValueError("cfg[%r] needs a 'delta' layer in "
+                                 "cfg['layer_types']" % key)
+        return
+    for key in _DELTA_KEYS:
+        if not int(cfg.get(key) or 0) >= 1:
+            raise ValueError("a 'delta' layer needs cfg[%r] >= 1" % key)
+    if cfg["delta_v_heads"] % cfg["delta_k_heads"]:
+        raise ValueError(
+            "cfg['delta_k_heads']=%r must divide cfg['delta_v_heads']=%r"
+            % (cfg["delta_k_heads"], cfg["delta_v_heads"]))
+    for key, why in (
+            ("attn", "latent attention has no layer kinds"),
+            ("residual", "the delta rule is not written over several "
+             "residual streams"),
+            ("mixers", "one mixer a layer has no first sub-block"),
+            ("shortcut_moe", "the branch forks behind an attention "
+             "sub-block")):
+        if cfg.get(key):
+            raise ValueError("a 'delta' layer takes no cfg[%r]: %s"
+                             % (key, why))
+
+
 def _lm_head(cfg, x):
     """Final projection to vocab logits. ``tie_embeddings=True`` reuses
     the input embedding (logits = x @ word_emb^T — no gpt_out_proj
@@ -806,34 +909,68 @@ def mixer_kind(cfg, i):
     return kinds[i] if kinds else None
 
 
+def layer_type(cfg, i):
+    """Layer ``i``'s entry of cfg['layer_types'], None without the key."""
+    types = cfg.get("layer_types")
+    return types[i] if types else None
+
+
 def is_conv(cfg, i):
     """Whether layer ``i``'s first sub-block is the gated short
     convolution (a ``'conv'`` entry of cfg['layer_types'])."""
-    types = cfg.get("layer_types")
-    return bool(types) and types[i] == "conv"
+    return layer_type(cfg, i) == "conv"
 
 
 def is_retention(cfg, i):
     """Whether layer ``i``'s first sub-block is power retention (a
     ``'retention'`` entry of cfg['layer_types'])."""
-    types = cfg.get("layer_types")
-    return bool(types) and types[i] == "retention"
+    return layer_type(cfg, i) == "retention"
+
+
+def _retention_kept(cfg):
+    from ..kernels.power import phi_plan
+
+    return ("a power-retention state of %d rows a key-value head and its "
+            "normaliser with no position axis (gpt_<i>_cache_s, "
+            "gpt_<i>_cache_z)" % phi_plan(_d_head(cfg))[2])
+
+
+def _delta_kept(cfg):
+    from ..kernels.delta import CONV_TAPS
+
+    return ("a delta-rule state of %d x %d a value head and the last %d "
+            "rows of its convolution's input with no position axis "
+            "(gpt_<i>_cache_s, gpt_<i>_cache_x)"
+            % (int(cfg["delta_k_dim"]), int(cfg["delta_v_dim"]),
+               CONV_TAPS - 1))
+
+
+def _conv_kept(cfg):
+    return ("the last %d rows of a gated convolution's input with no "
+            "position axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
+
+
+# the entries of cfg['layer_types'] whose layer keeps a constant-size
+# state and not rows a position, each with what ``state_refusal`` says its
+# caches are (in the order it looks for them)
+_STATE_TYPES = {"retention": _retention_kept, "delta": _delta_kept,
+                "conv": _conv_kept}
 
 
 def state_layers(cfg):
     """The layers that keep a constant-size state and not rows a
     position, whichever key brought them: an ``'ssm'`` entry of
-    cfg['mixers'], a ``'conv'`` or a ``'retention'`` entry of
-    cfg['layer_types']."""
+    cfg['mixers'] or a ``_STATE_TYPES`` entry (``'conv'``,
+    ``'retention'``, ``'delta'``) of cfg['layer_types']."""
     return [i for i in range(cfg["n_layer"])
-            if mixer_kind(cfg, i) == "ssm" or is_conv(cfg, i)
-            or is_retention(cfg, i)]
+            if mixer_kind(cfg, i) == "ssm"
+            or layer_type(cfg, i) in _STATE_TYPES]
 
 
 def has_state(cfg):
     """Whether some layer keeps a state and not rows a position (an
-    ``'ssm'`` mixer, a ``'conv'`` or a ``'retention'`` layer): its
-    caches, ``gpt_<i>_cache_s``, ``gpt_<i>_cache_x`` and
+    ``'ssm'`` mixer, a ``'conv'``, a ``'retention'`` or a ``'delta'``
+    layer): its caches, ``gpt_<i>_cache_s``, ``gpt_<i>_cache_x`` and
     ``gpt_<i>_cache_z``, have no position axis, so nothing can be cut out
     of them at a prefix's length nor rolled back by a position."""
     return bool(state_layers(cfg))
@@ -843,7 +980,15 @@ def _keeps_rows(cfg, i):
     """Whether layer ``i`` keeps keys and values a position (a slab or
     a ring): an attention layer that is not latent."""
     return mixer_kind(cfg, i) in (None, "attention") \
-        and not is_conv(cfg, i) and not is_retention(cfg, i)
+        and layer_type(cfg, i) not in _STATE_TYPES
+
+
+def delta_widths(cfg):
+    """``(Hk, Dk, Hv, Dv, the convolution's width)`` of a 'delta'
+    layer."""
+    Hk, Dk = int(cfg["delta_k_heads"]), int(cfg["delta_k_dim"])
+    Hv, Dv = int(cfg["delta_v_heads"]), int(cfg["delta_v_dim"])
+    return Hk, Dk, Hv, Dv, 2 * Hk * Dk + Hv * Dv
 
 
 def ssm_widths(cfg):
@@ -876,17 +1021,10 @@ def state_refusal(cfg):
         return ("cfg['mixers'] holds 'ssm' layers, whose caches are a "
                 "recurrent state with no position axis (gpt_<i>_cache_s, "
                 "gpt_<i>_cache_x)")
-    if "retention" in (cfg.get("layer_types") or ()):
-        from ..kernels.power import phi_plan
-
-        return ("cfg['layer_types'] holds 'retention' layers, whose caches "
-                "are a power-retention state of %d rows a key-value head "
-                "and its normaliser with no position axis "
-                "(gpt_<i>_cache_s, gpt_<i>_cache_z)"
-                % phi_plan(_d_head(cfg))[2])
-    return ("cfg['layer_types'] holds 'conv' layers, whose caches are the "
-            "last %d rows of a gated convolution's input with no position "
-            "axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
+    kind, kept = next((t, kept) for t, kept in _STATE_TYPES.items()
+                      if t in cfg["layer_types"])
+    return "cfg['layer_types'] holds %r layers, whose caches are %s" \
+        % (kind, kept(cfg))
 
 
 def _refuse_state(cfg, who, why):
@@ -953,7 +1091,7 @@ def _rotates(cfg, i):
     layer's kind among cfg['rope_layers'])."""
     if cfg.get("pos_emb", "learned") != "rope":
         return False
-    if is_conv(cfg, i):
+    if layer_type(cfg, i) in ("conv", "delta"):
         return False        # no attention, so nothing to rotate
     return cfg.get("rope_layers", "all") == "all" \
         or layer_window(cfg, i) is not None
@@ -1274,6 +1412,46 @@ def _retention(cfg, helper, h, nm, i, batch, T, pos, step):
     return y, [state.name, norm.name]
 
 
+def _delta_mixer(cfg, helper, h, nm, batch, T, step):
+    """The gated delta rule over the normed ``h [B, T, D]``
+    (``base_config`` has the equations): ``(out [B, T, D], [the two cache
+    names])``. ``step`` is the decode form (``T`` = 1): the state and the
+    convolution rows are read and updated in place; otherwise the prompt
+    is scanned from a zero state and both are overwritten."""
+    from ..kernels.delta import CONV_TAPS, state_shape
+
+    Hk, Dk, Hv, Dv, d_conv = delta_widths(cfg)
+    with name_scope("mixer"):
+        proj = _fc(h, d_conv + Hv * Dv, nm + "_delta_in.w_0")
+        ba = _fc(h, 2 * Hv, nm + "_delta_ba.w_0")
+
+        def cut(t, lo, hi):
+            return layers.slice(t, axes=[2], starts=[lo], ends=[hi])
+
+        rows = helper.create_global_variable(
+            name=nm + "_cache_x", shape=(batch, CONV_TAPS - 1, d_conv))
+        state = helper.create_global_variable(
+            name=nm + "_cache_s", shape=state_shape(batch, Hv, Dk, Dv))
+        with stored_dtype(None):      # the taps stay float32, as a vector
+            qkv = layers.causal_conv(cut(proj, 0, d_conv), CONV_TAPS,
+                                     nm + "_delta_conv", rows, step=step,
+                                     bias=False)
+        y = layers.delta_rule(
+            cut(qkv, 0, Hk * Dk), cut(qkv, Hk * Dk, 2 * Hk * Dk),
+            cut(qkv, 2 * Hk * Dk, d_conv), cut(ba, 0, Hv),
+            cut(ba, Hv, 2 * Hv), state, Hk, Hv, nm + "_delta", step=step)
+        # the norm a head, then the gate: one [Dv] scale the heads share
+        y = layers.rms_norm(
+            layers.reshape(y, [-1, T, Hv, Dv]), begin_norm_axis=3,
+            epsilon=_rms_eps(cfg),
+            param_attr=ParamAttr(name=nm + "_delta_norm_s"))
+        y = layers.elementwise_mul(
+            layers.reshape(y, [-1, T, Hv * Dv]),
+            layers.swish(cut(proj, d_conv, d_conv + Hv * Dv)))
+        return _fc(y, cfg["d_model"], nm + "_delta_out.w_0"), \
+            [rows.name, state.name]
+
+
 def _sub_input(cfg, x, nm, k, dev=None):
     """What sub-block ``k`` (1: attention, 2: the FFN or the experts) of
     layer ``nm`` reads: ``(the layer's pre-norm of it, mix)``. One
@@ -1359,7 +1537,7 @@ def _yarn(cfg, dim):
 def _rope(cfg, x, pos, heads_last=False):
     return layers.rope(x, pos, base=_rope_base(cfg),
                        yarn=_yarn(cfg, int(x.shape[-1])),
-                       heads_last=heads_last)
+                       heads_last=heads_last, rotary_dim=cfg.get("rope_dim"))
 
 
 def _qk_norm(cfg, q, k, nm):
@@ -1455,7 +1633,8 @@ def _routed(cfg, h, nm, row, counts=None, touched=None, compact=None,
     extra = {k: cfg[k] for k in ("router_score", "router_bias",
                                  "route_scale", "n_expert_local",
                                  "expert_first", "n_shared_expert",
-                                 "norm_topk_eps", "n_zero_expert")
+                                 "shared_expert_gate", "norm_topk_eps",
+                                 "n_zero_expert")
              if cfg.get(k)}
     act = "relu2" if cfg.get("ffn_act") == "relu2" else "swiglu"
     # one op holds the router, the sort and the grouped matmuls: its
@@ -1983,6 +2162,10 @@ def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
         y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
         cache_names.append(kept)
         return _layer_tail(cfg, x, y, nm, i, mix, first="conv", **tally)
+    if layer_type(cfg, i) == "delta":
+        y, kept = _delta_mixer(cfg, helper, h, nm, batch, P, False)
+        cache_names += kept
+        return _layer_tail(cfg, x, y, nm, i, mix, first="mixer", **tally)
     if is_retention(cfg, i):
         y, kept = _retention(cfg, helper, h, nm, i, batch, P, pos_range,
                              False)
@@ -2197,6 +2380,12 @@ def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
         cache_names.append(kept)
         return _layer_tail(cfg, x, y, nm, i, mix, dev, first="conv",
                            **tally)
+    if layer_type(cfg, i) == "delta":
+        h, mix = _sub_input(cfg, x, nm, 1, dev)
+        y, kept = _delta_mixer(cfg, helper, h, nm, batch, 1, True)
+        cache_names += kept
+        return _layer_tail(cfg, x, y, nm, i, mix, dev, first="mixer",
+                           **tally)
     if is_retention(cfg, i):
         h, mix = _sub_input(cfg, x, nm, 1, dev)
         y, kept = _retention(cfg, helper, h, nm, i, batch, 1, pos, True)
@@ -2319,8 +2508,9 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
 
     A cfg with a latent cache (``attn='mla'``) is REFUSED here, as is
     one with a layer that keeps a state (an ``'ssm'`` entry of
-    ``mixers``, a ``'conv'`` or ``'retention'`` entry of ``layer_types``:
-    a state has no position to resume at or rewind to) and a
+    ``mixers``, a ``'conv'``, ``'retention'`` or ``'delta'`` entry of
+    ``layer_types``: a state has no position to resume at or rewind to)
+    and a
     cfg with ring caches (a sliding layer whose window is shorter than
     ``max_len``): the one slab write at
     ``pos[:, 0]`` would run over a ring's end, and a stored prefix or a

@@ -1124,6 +1124,35 @@ POWER_CHUNKS = REGISTRY.counter(
     "full-prompt prefill (serving/engine.py); the first chunk of a layer "
     "reads no state")
 
+DELTA_PLANS = REGISTRY.counter(
+    "paddle_delta_plans_total",
+    "Which form of a gated delta-rule layer's core a program holds (gpt "
+    "cfg['layer_types'] with 'delta' layers, kernels/delta.py): one count "
+    "a call of delta_scan (a whole prompt, chunked: a unit lower "
+    "triangular solve a chunk and head) or delta_update (one token a slot "
+    "into the state, in place: read at the key, corrected, read at the "
+    "query in the same pass) at LOWERING, form 'pallas' or 'composed' "
+    "(jax.numpy: every CPU run, and PADDLE_TPU_KERNELS=0), with the chunk "
+    "of the scan (1 for the update). A prefill of a model with L delta "
+    "layers lowers L scans, its decode step L updates",
+    labels=("kernel", "form", "chunk"))
+
+DELTA_STATE_BYTES = REGISTRY.gauge(
+    "paddle_delta_state_bytes",
+    "Bytes of delta-rule state the lane built last holds: the state "
+    "[b_max, Hv, Dk, Dv] every delta_update of its decode step reads and "
+    "writes, and the rows [b_max, taps - 1, C] of the convolution in "
+    "front of it that the layer's causal_conv_step shifts. Part of "
+    "paddle_serving_cache_bytes{kind='state'}; 0 for a model without "
+    "such layers")
+
+DELTA_CHUNKS = REGISTRY.counter(
+    "paddle_delta_chunks_total",
+    "Chunks the admissions' prefills scanned in delta-rule layers: "
+    "layers x ceil(prompt / kernels.delta.scan_chunk(prompt)) a "
+    "full-prompt prefill (serving/engine.py): one triangular solve a "
+    "chunk and value head")
+
 MHC_RES_DEVIATION = REGISTRY.gauge(
     "paddle_mhc_res_deviation",
     "The largest |row sum - 1| or |column sum - 1| any residual mapping "

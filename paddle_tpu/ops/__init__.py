@@ -11,6 +11,7 @@ from . import (  # noqa: F401
     basic,
     beam_search_ops,
     control_flow_ops,
+    delta_ops,
     detection_ops,
     distributed_ops,
     fused_ops,

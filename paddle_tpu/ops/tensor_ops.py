@@ -384,7 +384,12 @@ def _rope(ctx, ins, attrs):
     GPT family uses with cfg['pos_emb']='rope'."""
     x, pos = ins["X"][0], ins["Pos"][0]
     base = float(attrs.get("base", 10000.0))
-    d = x.shape[-1]
+    # attr ``rotary_dim``: only the first that many values of a head
+    # rotate (rotate-half inside them), the others pass
+    whole = x
+    d = int(attrs.get("rotary_dim", 0) or 0) or x.shape[-1]
+    if d < x.shape[-1]:
+        x = x[..., :d]
     half = d // 2
     inv = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     factor = float(attrs.get("yarn_factor", 0.0) or 0.0)
@@ -424,5 +429,7 @@ def _rope(ctx, ins, attrs):
         sin, cos = sin[..., None, :], cos[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin,
-                           x1 * sin + x2 * cos], axis=-1)
+                           x1 * sin + x2 * cos]
+                          + ([whole[..., d:]] if d < whole.shape[-1] else []),
+                          axis=-1)
     return {"Out": [out]}

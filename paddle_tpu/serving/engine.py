@@ -173,6 +173,8 @@ class _Lane:
         # retention layers, whose prefill scans the prompt in chunks
         self._retention_layers = sum(
             gpt.is_retention(cfg, i) for i in range(cfg["n_layer"]))
+        # delta-rule layers, likewise
+        self._delta_layers = (cfg.get("layer_types") or []).count("delta")
         self.b_max, self.max_len = b_max, max_len
         self.scope = Scope()
         self._prefill_scope = Scope()
@@ -245,7 +247,8 @@ class _Lane:
         position axis), a latent layer's one tensor, rings (shorter than
         ``max_len``) and full slabs."""
         from ..kernels.mla_decode import decode_plan
-        from ..observe.families import (POWER_STATE_BYTES,
+        from ..observe.families import (DELTA_STATE_BYTES,
+                                        POWER_STATE_BYTES,
                                         SERVING_CACHE_BYTES)
 
         block = self._decode_prog.global_block()
@@ -269,6 +272,17 @@ class _Lane:
         POWER_STATE_BYTES.set(sum(
             size(n) for op in block.ops if op.type == "power_update"
             for n in op.input("State") + op.input("Norm")))
+        # and the delta layers': the state, and the rows of the convolution
+        # whose output the update reads (through the slice that cuts q)
+        made = {n: op for op in block.ops for n in op.output_names()}
+
+        def conv_rows(update):
+            cut = made[update.input("Q")[0]]
+            return made[cut.input_names()[0]].input("Rows")[0]
+
+        DELTA_STATE_BYTES.set(sum(
+            size(op.input("State")[0]) + size(conv_rows(op))
+            for op in block.ops if op.type == "delta_update"))
         # (latent layers, rows of a block) for
         # paddle_mla_decode_blocks_total: the slabs are of one shape; None
         # where no cache is latent or the kernel has no plan for it
@@ -443,6 +457,12 @@ class _Lane:
 
                 attrs["chunks"] = -(-P // scan_chunk(P))
                 POWER_CHUNKS.inc(self._retention_layers * attrs["chunks"])
+            if self._delta_layers:
+                from ..kernels.delta import scan_chunk
+                from ..observe.families import DELTA_CHUNKS
+
+                attrs["chunks"] = -(-P // scan_chunk(P))
+                DELTA_CHUNKS.inc(self._delta_layers * attrs["chunks"])
             with _tr.trace_span("serving.engine.prefill", **attrs):
                 with self._scope_guard(self._prefill_scope):
                     (out,) = self._exe.run(

@@ -675,17 +675,17 @@ def _brumby():
     return gpt, conf["model"], conf["serving"]
 
 
-def _power_plans():
+def _power_plans(family="paddle_power_plans_total"):
     from paddle_tpu.observe import REGISTRY
 
-    family = REGISTRY.snapshot()["metrics"].get(
-        "paddle_power_plans_total", {"samples": []})
+    family = REGISTRY.snapshot()["metrics"].get(family, {"samples": []})
     return {(s["labels"]["kernel"], s["labels"]["form"],
              s["labels"]["chunk"]): s["value"] for s in family["samples"]}
 
 
-def _new_plans(before):
-    return {k: v - before.get(k, 0) for k, v in _power_plans().items()
+def _new_plans(before, family="paddle_power_plans_total"):
+    return {k: v - before.get(k, 0)
+            for k, v in _power_plans(family).items()
             if v != before.get(k, 0)}
 
 
@@ -762,3 +762,85 @@ def test_brumby_prefill_compiles_for_v5e(v5e, compiled_kernels):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2.5e9, mem
     print("brumby prefill P=%d:" % P, mem)
+
+
+def _qwen3next():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+DELTA_PLANS = "paddle_delta_plans_total"
+
+
+def test_qwen3next_serving_decode_step_compiles_for_v5e(v5e,
+                                                        compiled_kernels):
+    """The whole ``qwen3-next-80b-a3b`` serving decode step (128 slots:
+    2.53 GB of delta state and convolution rows beside 4.03 GB of slab in
+    ONE lane, 64 of 512 experts a layer, bf16 matrices) for the described
+    chip: nine ``delta_update`` Pallas calls, every state donated into its
+    output and none copied, and arguments equal to the static bytes the
+    closed form reckons within 1%."""
+    import paddle_tpu as fluid
+    from benchmarks.lib import closed_forms_delta
+    from paddle_tpu.kernels import delta
+
+    gpt, cfg, serving = _qwen3next()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    kinds = [gpt.cache_kind(cfg, n, S) for n in caches]
+    assert kinds.count("state") == 18 and kinds.count("full") == 6
+    before = _power_plans(DELTA_PLANS)
+    lowered, mut_state = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert _new_plans(before, DELTA_PLANS) == {
+        ("delta_update", "pallas", "1"): 9}
+    assert set(caches) <= set(mut_state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % delta.KERNEL_UPDATE,
+                              text))) == 9
+    # no second copy of a layer's state: 128 x 32 x 128 x 128 float32
+    assert _cache_sized(text, (B, 32, 128, 128)) == []
+    mem = compiled.memory_analysis()
+    static = closed_forms_delta.static_bytes(cfg, B, S, 4, 2)
+    assert abs(mem.argument_size_in_bytes - static) < 0.01 * static, mem
+    assert mem.alias_size_in_bytes >= closed_forms_delta.state_bytes(cfg, B)
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    print("qwen3-next decode step:", mem)
+
+
+def test_qwen3next_prefill_compiles_for_v5e(v5e, compiled_kernels):
+    """The batch=1 prefill of the longest prompt of the mix (2,048): nine
+    ``delta_scan`` Pallas calls at the kernel's chunk, the flash forward
+    at a head of 256 in the three full layers, a head on ONE row, and
+    temporaries that fit beside the 12.4 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import delta
+
+    gpt, cfg, serving = _qwen3next()
+    P = 2048
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    before = _power_plans(DELTA_PLANS)
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    assert _new_plans(before, DELTA_PLANS) == {
+        ("delta_scan", "pallas", str(delta.scan_chunk(P))): 9}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % delta.KERNEL_SCAN,
+                              text))) == 9
+    assert len(set(re.findall(r"%(flash_fwd[.\d]*) = ", text))) == 3
+    assert "f32[1,%d,18992]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    print("qwen3-next prefill P=%d:" % P, mem)

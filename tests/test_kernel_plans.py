@@ -76,6 +76,13 @@ REACHES = {
     # expert: the first configuration that reaches neither
     "brumby-14b-base": {"decode": ("power_update",),
                         "prefill": ("power_scan",)},
+    # nine delta layers (the in-place update, the chunked scan) beside
+    # three gated attention layers of 16 heads over 2 of 256, the widest
+    # head the flash forward is given, over a share of the experts
+    "qwen3-next-80b-a3b": {
+        "decode": ("delta_update", "kv_cache_write", "moe_ffn"),
+        "prefill": ("delta_scan", "fused_attention", "kv_cache_write",
+                    "moe_ffn")},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -109,6 +116,13 @@ RUNS_ON_THE_CHIP = {
     # my chip runs, PR 51: power_scan pallas chunk=1024 and power_update
     # pallas in the facts' plan counter, both names in the device trace
     ("brumby-14b-base", "power_scan"), ("brumby-14b-base", "power_update"),
+    # my chip runs, PR 53: delta_scan pallas chunk=64 and delta_update
+    # pallas in the facts' plan counter, flash_fwd at a head of 256,
+    # moe_gmm_up / moe_gmm_down, all in the device trace
+    ("qwen3-next-80b-a3b", "delta_scan"),
+    ("qwen3-next-80b-a3b", "delta_update"),
+    ("qwen3-next-80b-a3b", "fused_attention"),
+    ("qwen3-next-80b-a3b", "moe_ffn"),
 }
 
 
@@ -402,7 +416,51 @@ def _check_power_scan(block, op, batch, must):
     return Q
 
 
+def _check_delta_update(block, op, batch, must):
+    from paddle_tpu.kernels import delta
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    shape, _ = _operand(block, op, "State", batch)
+    takes = delta._update_plan(shape)
+    assert takes or not must, shape
+    if takes:
+        # all of a slot's heads a step, their keys and queries the
+        # columns of ONE lane tile; in and out, double-buffered
+        Hv, Dk, Dv = shape[1:]
+        assert takes == (1,) + tuple(shape[1:]) and 2 * Hv <= 128
+        assert mosaic_ok(takes, shape)
+        assert mosaic_ok((1, 3 * Hv, Dv), (shape[0], 3 * Hv, Dv))
+        assert mosaic_ok((1, Dk, 128), (shape[0], Dk, 128))
+        held = 4 * (4 * Hv * Dk * Dv + 2 * (3 * Hv * Dv + Dk * 128
+                                            + Hv * Dv))
+        assert held <= delta._VMEM_LIMIT_BYTES // 2, held
+    return takes
+
+
+def _check_delta_scan(block, op, batch, must):
+    from paddle_tpu.kernels import delta
+
+    (_B, T, KD), _ = _operand(block, op, "Q", batch)
+    (_B, _T, VD), _ = _operand(block, op, "V", batch)
+    Hk, Hv = int(op.attrs["k_heads"]), int(op.attrs["v_heads"])
+    Q = delta.scan_chunk(T)
+    takes = delta._scan_plan(Hk, KD // Hk, Hv, VD // Hv, Q)
+    assert takes or not must, (T, Hk, Hv, KD // Hk, VD // Hv, Q)
+    if takes:
+        # every prompt of the cell is whole chunks: nothing is padded
+        assert Q == delta.CHUNK and T % Q == 0
+        J, Dk, Dv = Hv // Hk, KD // Hk, VD // Hv
+        # q, k, k turned, v and y of a chunk, the narrow operands padded
+        # to a lane tile, double-buffered; the states in scratch and out;
+        # a handful of [Q, Q] and [Q, Dv] temporaries a head
+        blocks = 3 * Q * Dk + 2 * J * Q * Dv + 3 * 8 * 128 + Q * 128
+        held = 4 * (2 * blocks + 3 * J * Dk * Dv + 8 * Q * Q + 6 * Q * Dv)
+        assert held <= delta._VMEM_LIMIT_BYTES // 2, held
+    return takes
+
+
 CHECKS = {
+    "delta_scan": _check_delta_scan, "delta_update": _check_delta_update,
     "fused_attention": _check_fused_attention,
     "kv_cache_write": _check_kv_cache_write,
     "mhc_post": _check_mhc, "mhc_pre": _check_mhc,
