@@ -15,8 +15,6 @@ __all__ = [
     "append_gradient_clip_ops",
 ]
 
-_global_clip = None
-
 
 class BaseGradientClipAttr:
     def _process(self, params_grads):
@@ -108,17 +106,20 @@ class GradientClipByGlobalNorm(BaseGradientClipAttr):
 
 
 def set_gradient_clip(clip, param_list=None, program=None):
-    global _global_clip
-    _global_clip = clip
-    if param_list:
-        for p in param_list:
-            p.gradient_clip_attr = clip
+    """Clip the gradients of ``param_list`` — by default every parameter
+    ``program`` (the default main program) holds when this is called — by
+    ``clip``. The clip is kept on the parameters (``gradient_clip_attr``),
+    as the reference keeps it: a program's clip is no other program's."""
+    if param_list is None:
+        from .core.program import default_main_program
+
+        param_list = (program or default_main_program()) \
+            .global_block().all_parameters()
+    for p in param_list:
+        p.gradient_clip_attr = clip
 
 
 def append_gradient_clip_ops(params_grads):
-    # per-param attr wins; else the global clip
-    if _global_clip is not None:
-        return _global_clip._process(params_grads)
     clip_groups = {}
     plain = []
     for p, g in params_grads:

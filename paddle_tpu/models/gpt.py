@@ -15,10 +15,13 @@ masked out of the loss.
 
 import functools
 import math
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 from .. import layers
 from ..core.program import name_scope
 from ..layer_helper import stored_dtype
+from ..observe.families import (DELTA_CHUNKS, DELTA_STATE_BYTES,
+                                POWER_CHUNKS, POWER_STATE_BYTES)
 from ..param_attr import ParamAttr
 from .transformer import (_causal_bias, _ffn, _pad_bias, _prenorm,
                           multi_head_attention, qk_norm)
@@ -630,9 +633,7 @@ def _check_cfg(cfg):
             raise ValueError("a 'sliding' layer needs cfg['window'] >= 1")
     elif cfg.get("window"):
         raise ValueError("cfg['window'] needs cfg['layer_types']")
-    _check_conv(cfg)
-    _check_retention(cfg)
-    _check_delta(cfg)
+    _check_kinds(cfg, "layer_types")
     if cfg.get("rope_dim") is not None:
         dim = int(cfg["rope_dim"])
         if cfg.get("pos_emb", "learned") != "rope" or has_latent(cfg) \
@@ -669,17 +670,14 @@ def _check_cfg(cfg):
 
 
 def _check_mixers(cfg):
-    """cfg['mixers'] and the keys that need it (``base_config``)."""
+    """cfg['mixers'] as a whole (``base_config``); what each of its
+    kinds needs is ``_check_kinds``'."""
     kinds = cfg.get("mixers") or ()
-    if "ssm" not in kinds:
-        for key in _SSM_KEYS + ("ssm_chunk",):
-            if cfg.get(key):
-                raise ValueError("cfg[%r] needs an 'ssm' layer in "
-                                 "cfg['mixers']" % key)
-        if cfg.get("pos_emb") == "none":
-            raise ValueError(
-                "cfg['pos_emb']='none' needs an 'ssm' layer in "
-                "cfg['mixers']: nothing else here orders the tokens")
+    _check_kinds(cfg, "mixers")
+    if "ssm" not in kinds and cfg.get("pos_emb") == "none":
+        raise ValueError(
+            "cfg['pos_emb']='none' needs an 'ssm' layer in "
+            "cfg['mixers']: nothing else here orders the tokens")
     if not kinds:
         return
     if len(kinds) != cfg["n_layer"] \
@@ -691,17 +689,62 @@ def _check_mixers(cfg):
                 "sandwich_norm", "n_shared_expert"):
         if cfg.get(key):
             raise ValueError("cfg['mixers'] takes no cfg[%r]" % key)
-    if "experts" in kinds and not cfg.get("n_expert"):
+
+
+def _check_kinds(cfg, key):
+    """The rows of ``LAYER_KINDS`` that an entry of cfg[``key``] names
+    (``base_config``), each by the same three rules: a key of the row's
+    needs a layer of it; such a layer needs the row's keys >= 1, passes
+    the row's own check and takes none of what the row refuses."""
+    held = cfg.get(key) or ()
+    for kind in LAYER_KINDS.values():
+        if kind.key != key:
+            continue
+        if kind.name not in held:
+            for k in kind.needs + kind.extra:
+                if cfg.get(k):
+                    raise ValueError("cfg[%r] needs %s in cfg[%r]"
+                                     % (k, kind.layer, key))
+            continue
+        for k in kind.needs:
+            if not int(cfg.get(k) or 0) >= 1:
+                raise ValueError("%s needs cfg[%r] >= 1" % (kind.layer, k))
+        if kind.check is not None:
+            kind.check(cfg)
+        for k, why in kind.refuses:
+            if cfg.get(k):
+                raise ValueError("%s takes no cfg[%r]: %s"
+                                 % (kind.layer, k, why))
+
+
+def _check_ssm(cfg):
+    if cfg["ssm_heads"] % cfg["ssm_groups"] or cfg["ssm_conv"] < 2:
+        raise ValueError(
+            "cfg['ssm_groups']=%r must divide cfg['ssm_heads']=%r, and "
+            "cfg['ssm_conv']=%r be >= 2 taps"
+            % (cfg["ssm_groups"], cfg["ssm_heads"], cfg["ssm_conv"]))
+
+
+def _check_experts(cfg):
+    if not cfg.get("n_expert"):
         raise ValueError("an 'experts' layer needs cfg['n_expert']")
-    if "ssm" in kinds:
-        for key in _SSM_KEYS:
-            if not int(cfg.get(key) or 0) >= 1:
-                raise ValueError("an 'ssm' layer needs cfg[%r] >= 1" % key)
-        if cfg["ssm_heads"] % cfg["ssm_groups"] or cfg["ssm_conv"] < 2:
-            raise ValueError(
-                "cfg['ssm_groups']=%r must divide cfg['ssm_heads']=%r, and "
-                "cfg['ssm_conv']=%r be >= 2 taps"
-                % (cfg["ssm_groups"], cfg["ssm_heads"], cfg["ssm_conv"]))
+
+
+def _check_conv(cfg):
+    if not int(cfg.get("conv_taps") or 0) >= 2:
+        raise ValueError("a 'conv' layer needs cfg['conv_taps'] >= 2 (the "
+                         "taps of its causal depth-wise convolution)")
+    if cfg.get("window") and "sliding" not in cfg["layer_types"]:
+        raise ValueError(
+            "cfg['window'] needs a 'sliding' layer: a 'conv' layer keeps "
+            "its last cfg['conv_taps'] - 1 rows and has no window")
+
+
+def _check_delta(cfg):
+    if cfg["delta_v_heads"] % cfg["delta_k_heads"]:
+        raise ValueError(
+            "cfg['delta_k_heads']=%r must divide cfg['delta_v_heads']=%r"
+            % (cfg["delta_k_heads"], cfg["delta_v_heads"]))
 
 
 def _check_shortcut(cfg):
@@ -734,75 +777,6 @@ def _check_shortcut(cfg):
     if "conv" in (cfg.get("layer_types") or ()):
         raise ValueError("cfg['shortcut_moe'] takes no 'conv' layer: the "
                          "branch forks behind an attention sub-block")
-
-
-def _check_conv(cfg):
-    """A ``'conv'`` entry of cfg['layer_types'] and the keys that go
-    with it (``base_config``)."""
-    if "conv" not in (cfg.get("layer_types") or ()):
-        if cfg.get("conv_taps"):
-            raise ValueError("cfg['conv_taps'] needs a 'conv' layer in "
-                             "cfg['layer_types']")
-        return
-    if not int(cfg.get("conv_taps") or 0) >= 2:
-        raise ValueError("a 'conv' layer needs cfg['conv_taps'] >= 2 (the "
-                         "taps of its causal depth-wise convolution)")
-    for key, why in (
-            ("attn", "latent attention has no layer kinds"),
-            ("residual", "the gated convolution is not written over "
-             "several residual streams"),
-            ("mixers", "one mixer a layer has no first sub-block")):
-        if cfg.get(key):
-            raise ValueError("a 'conv' layer takes no cfg[%r]: %s"
-                             % (key, why))
-    if cfg.get("window") and "sliding" not in cfg["layer_types"]:
-        raise ValueError(
-            "cfg['window'] needs a 'sliding' layer: a 'conv' layer keeps "
-            "its last cfg['conv_taps'] - 1 rows and has no window")
-
-
-def _check_retention(cfg):
-    """What a ``'retention'`` entry of cfg['layer_types'] cannot stand
-    beside (``base_config``)."""
-    if "retention" not in (cfg.get("layer_types") or ()):
-        return
-    for key, why in (
-            ("attn", "latent attention has no layer kinds"),
-            ("residual", "the retention core is not written over several "
-             "residual streams"),
-            ("shortcut_moe", "the branch forks behind an attention "
-             "sub-block")):
-        if cfg.get(key):
-            raise ValueError("a 'retention' layer takes no cfg[%r]: %s"
-                             % (key, why))
-
-
-def _check_delta(cfg):
-    """A ``'delta'`` entry of cfg['layer_types'] and the keys that go
-    with it (``base_config``)."""
-    if "delta" not in (cfg.get("layer_types") or ()):
-        for key in _DELTA_KEYS:
-            if cfg.get(key):
-                raise ValueError("cfg[%r] needs a 'delta' layer in "
-                                 "cfg['layer_types']" % key)
-        return
-    for key in _DELTA_KEYS:
-        if not int(cfg.get(key) or 0) >= 1:
-            raise ValueError("a 'delta' layer needs cfg[%r] >= 1" % key)
-    if cfg["delta_v_heads"] % cfg["delta_k_heads"]:
-        raise ValueError(
-            "cfg['delta_k_heads']=%r must divide cfg['delta_v_heads']=%r"
-            % (cfg["delta_k_heads"], cfg["delta_v_heads"]))
-    for key, why in (
-            ("attn", "latent attention has no layer kinds"),
-            ("residual", "the delta rule is not written over several "
-             "residual streams"),
-            ("mixers", "one mixer a layer has no first sub-block"),
-            ("shortcut_moe", "the branch forks behind an attention "
-             "sub-block")):
-        if cfg.get(key):
-            raise ValueError("a 'delta' layer takes no cfg[%r]: %s"
-                             % (key, why))
 
 
 def _lm_head(cfg, x):
@@ -901,30 +875,16 @@ def _refuse_shortcut(cfg, who, why):
             % (who, why))
 
 
-def mixer_kind(cfg, i):
-    """Layer ``i``'s one mixer (``'ssm'`` | ``'attention'`` |
-    ``'experts'``) under cfg['mixers'], None for a cfg whose layers are
-    the attention-then-FFN pair."""
-    kinds = cfg.get("mixers")
-    return kinds[i] if kinds else None
-
-
-def layer_type(cfg, i):
-    """Layer ``i``'s entry of cfg['layer_types'], None without the key."""
-    types = cfg.get("layer_types")
-    return types[i] if types else None
-
-
-def is_conv(cfg, i):
-    """Whether layer ``i``'s first sub-block is the gated short
-    convolution (a ``'conv'`` entry of cfg['layer_types'])."""
-    return layer_type(cfg, i) == "conv"
-
-
-def is_retention(cfg, i):
-    """Whether layer ``i``'s first sub-block is power retention (a
-    ``'retention'`` entry of cfg['layer_types'])."""
-    return layer_type(cfg, i) == "retention"
+def kind_of(cfg, i):
+    """Layer ``i``'s row of ``LAYER_KINDS``: what its first sub-block is,
+    by its entry of cfg['mixers'] or cfg['layer_types'] (the two refuse
+    each other). An attention layer (``'attention'``, ``'sliding'``,
+    ``'full'``, or neither key) is ``'latent'`` under ``attn='mla'``."""
+    entries = cfg.get("mixers") or cfg.get("layer_types")
+    kind = LAYER_KINDS.get(entries[i] if entries else None)
+    if kind is None:
+        kind = LAYER_KINDS["latent" if has_latent(cfg) else "attention"]
+    return kind
 
 
 def _retention_kept(cfg):
@@ -950,21 +910,17 @@ def _conv_kept(cfg):
             "position axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
 
 
-# the entries of cfg['layer_types'] whose layer keeps a constant-size
-# state and not rows a position, each with what ``state_refusal`` says its
-# caches are (in the order it looks for them)
-_STATE_TYPES = {"retention": _retention_kept, "delta": _delta_kept,
-                "conv": _conv_kept}
+def _ssm_kept(cfg):
+    return ("a recurrent state with no position axis (gpt_<i>_cache_s, "
+            "gpt_<i>_cache_x)")
 
 
 def state_layers(cfg):
     """The layers that keep a constant-size state and not rows a
     position, whichever key brought them: an ``'ssm'`` entry of
-    cfg['mixers'] or a ``_STATE_TYPES`` entry (``'conv'``,
-    ``'retention'``, ``'delta'``) of cfg['layer_types']."""
-    return [i for i in range(cfg["n_layer"])
-            if mixer_kind(cfg, i) == "ssm"
-            or layer_type(cfg, i) in _STATE_TYPES]
+    cfg['mixers'] or a ``'conv'``, ``'retention'`` or ``'delta'`` entry
+    of cfg['layer_types'] (the rows of ``LAYER_KINDS`` with ``kept``)."""
+    return [i for i in range(cfg["n_layer"]) if kind_of(cfg, i).kept]
 
 
 def has_state(cfg):
@@ -974,13 +930,6 @@ def has_state(cfg):
     ``gpt_<i>_cache_z``, have no position axis, so nothing can be cut out
     of them at a prefix's length nor rolled back by a position."""
     return bool(state_layers(cfg))
-
-
-def _keeps_rows(cfg, i):
-    """Whether layer ``i`` keeps keys and values a position (a slab or
-    a ring): an attention layer that is not latent."""
-    return mixer_kind(cfg, i) in (None, "attention") \
-        and layer_type(cfg, i) not in _STATE_TYPES
 
 
 def delta_widths(cfg):
@@ -1000,31 +949,27 @@ def ssm_widths(cfg):
 
 def cache_kind(cfg, name, max_len):
     """What kind of cache tensor ``name`` (one of a builder's
-    ``cache_names``) is, from its name and its layer: ``'state'`` (a
-    state-space layer's state or convolution rows, a gated convolution's
-    carried rows, a retention layer's state or normaliser: no position
-    axis),
-    ``'latent'`` (a latent layer's one tensor), ``'ring'`` (a sliding
-    layer's, shorter than ``max_len``) or ``'full'`` (a slab)."""
-    if name.endswith(("_cache_s", "_cache_x", "_cache_z")):
-        return "state"
-    if name.endswith("_cache_c"):
-        return "latent"
+    ``cache_names``) is, from its layer's row of ``LAYER_KINDS`` and its
+    suffix: ``'state'`` (a state-space layer's state or convolution
+    rows, a gated convolution's carried rows, a retention or delta
+    layer's state, normaliser or rows: no position axis), ``'latent'`` (a
+    latent layer's one tensor), ``'ring'`` (a sliding layer's, shorter
+    than ``max_len``) or ``'full'`` (a slab)."""
     layer = int(name.split("_")[1])
+    kind = kind_of(cfg, layer).caches[name[name.index("_cache_"):]]
+    if kind != "rows":
+        return kind
     return "ring" if cache_rows(cfg, layer, max_len) < max_len else "full"
 
 
 def state_refusal(cfg):
-    """What to say of a cfg whose layers keep a state, by the key that
-    brought them: ``"<the layers>, whose caches are <what> (<names>)"``."""
-    if "ssm" in (cfg.get("mixers") or ()):
-        return ("cfg['mixers'] holds 'ssm' layers, whose caches are a "
-                "recurrent state with no position axis (gpt_<i>_cache_s, "
-                "gpt_<i>_cache_x)")
-    kind, kept = next((t, kept) for t, kept in _STATE_TYPES.items()
-                      if t in cfg["layer_types"])
-    return "cfg['layer_types'] holds %r layers, whose caches are %s" \
-        % (kind, kept(cfg))
+    """What to say of a cfg whose layers keep a state, by the first row
+    of ``LAYER_KINDS`` that it holds: ``"<the layers>, whose caches are
+    <what> (<names>)"``."""
+    held = {kind_of(cfg, i).name for i in state_layers(cfg)}
+    kind = next(k for k in LAYER_KINDS.values() if k.name in held)
+    return "cfg[%r] holds %r layers, whose caches are %s" \
+        % (kind.key, kind.name, kind.kept(cfg))
 
 
 def _refuse_state(cfg, who, why):
@@ -1091,8 +1036,8 @@ def _rotates(cfg, i):
     layer's kind among cfg['rope_layers'])."""
     if cfg.get("pos_emb", "learned") != "rope":
         return False
-    if layer_type(cfg, i) in ("conv", "delta"):
-        return False        # no attention, so nothing to rotate
+    if not kind_of(cfg, i).merged:
+        return False        # no heads of q and k, so nothing to rotate
     return cfg.get("rope_layers", "all") == "all" \
         or layer_window(cfg, i) is not None
 
@@ -1162,6 +1107,15 @@ def _head_norm(cfg, t, nm, which):
     return layers.rms_norm(
         t, begin_norm_axis=len(t.shape) - 1, epsilon=_rms_eps(cfg),
         param_attr=ParamAttr(name="%s_att_%snorm_s" % (nm, which)))
+
+
+def _heads(cfg, t, nm, S, n, which=None):
+    """``t [B, S, n * d_head]`` as ``n`` heads ``[B, n, S, d_head]``; q or
+    k (``which``) normed a head first where the cfg asks (``_head_norm``)."""
+    t = layers.reshape(t, [-1, S, n, _d_head(cfg)])
+    if which:
+        t = _head_norm(cfg, t, nm, which)
+    return layers.transpose(t, perm=[0, 2, 1, 3])
 
 
 def _attn_out(cfg, h, ctxv, nm):
@@ -1318,29 +1272,21 @@ def _note_mla_expanded(cfg, kernel):
         widths="%dx%d" % (cfg["d_nope"] + cfg["d_rope"], cfg["d_v"])).inc()
 
 
-def _block_tail(cfg, x, h, ctxv, nm, i, mix=None, dev=None, **tally):
-    """What follows a layer's attention in the serving programs: the
-    output projection on the merged heads ``ctxv``, the residual
-    (``mix``: the attention sub-block's mappings from ``_sub_input``),
-    then the FFN or the experts (``tally``: ``_mlp``'s counts) and
-    theirs."""
-    return _layer_tail(cfg, x, _attn_out(cfg, h, ctxv, nm), nm, i, mix,
-                       dev, **tally)
-
-
 def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, branch=None,
                 first="attn.out", **tally):
     """A layer after its first sub-block's output ``y`` (attention's
-    projection, a gated convolution's): the residual, then the FFN or
-    the experts and theirs. ``branch`` (cfg['shortcut_moe']) is the
-    builder's one dict that carries the routed branch from the even
-    sub-layer, where it forks off the norm the dense FFN reads, to the
-    end of the odd one, where it joins: computed once, added once.
+    projection, a gated convolution's, a lone mixer's): the residual
+    (``mix``: that sub-block's mappings from ``_sub_input``), then the
+    FFN or the experts (``tally``: ``_mlp``'s counts) and theirs.
+    ``branch`` (cfg['shortcut_moe']) is the builder's one dict that
+    carries the routed branch from the even sub-layer, where it forks off
+    the norm the dense FFN reads, to the end of the odd one, where it
+    joins: computed once, added once.
     ``first`` is the scope class of that first sub-block, which its
     residual add stands under; the second's stands under its own."""
     x = _residual(cfg, x, y, nm + "_post1", mix, first)
     if cfg.get("mixers"):
-        return x            # the attention was the layer's one mixer
+        return x            # the first sub-block was the layer's one mixer
     h2, mix2 = _sub_input(cfg, x, nm, 2, dev)
     if branch is not None and i % 2 == 0:
         branch["s"] = _routed(cfg, h2, nm, i // 2, **tally)
@@ -1352,12 +1298,11 @@ def _layer_tail(cfg, x, y, nm, i, mix=None, dev=None, branch=None,
                      "ffn" if _is_dense(cfg, i) else "moe.experts")
 
 
-def _gated_conv(cfg, helper, h, nm, batch, step):
+def _gated_conv(cfg, step, h, nm, i):
     """The gated short convolution over the normed ``h [B, T, D]``
-    (``base_config`` has the equations): ``(out [B, T, D], the cache's
-    name)``. ``step`` is the decode form (``T`` = 1): the carried rows
-    are read and shifted in place; otherwise the prompt overwrites
-    them."""
+    (``base_config`` has the equations): ``(out [B, T, D], [the cache's
+    name])``. In the decode form (``T`` = 1) the carried rows are read
+    and shifted in place; otherwise the prompt overwrites them."""
     D, K = cfg["d_model"], int(cfg["conv_taps"])
     with name_scope("conv"):
         proj = _fc(h, 3 * D, nm + "_conv_in.w_0")
@@ -1366,25 +1311,26 @@ def _gated_conv(cfg, helper, h, nm, batch, step):
             return layers.slice(proj, axes=[2], starts=[k * D],
                                 ends=[(k + 1) * D])
 
-        rows = helper.create_global_variable(
-            name=nm + "_cache_x", shape=(batch, K - 1, D))
+        rows = step.helper.create_global_variable(
+            name=nm + "_cache_x", shape=(step.batch, K - 1, D))
         with stored_dtype(None):      # the taps stay float32, as a vector
             c = layers.causal_conv(
                 layers.elementwise_mul(cut(0), cut(2)), K, nm + "_conv",
-                rows, step=step, act=False, bias=False)
+                rows, step=step.decode, act=False, bias=False)
         return _fc(layers.elementwise_mul(cut(1), c), D,
-                   nm + "_conv_out.w_0"), rows.name
+                   nm + "_conv_out.w_0"), [rows.name]
 
 
-def _retention(cfg, helper, h, nm, i, batch, T, pos, step):
+def _retention(cfg, step, h, nm, i):
     """Power retention over the normed ``h [B, T, D]`` (``base_config``
     has the equations): ``(the merged heads [B, T, n_head * d_head], the
-    caches' names)`` for ``_block_tail``. Attention's projections, head
-    norm and rotation (at ``pos``) in front of the one new core; ``step``
-    is the decode form (``T`` = 1): state and normaliser are updated in
+    caches' names)`` for ``_attn_out``. Attention's projections, head
+    norm and rotation (at ``step.pos``) in front of the one new core; in
+    the decode form (``T`` = 1) state and normaliser are updated in
     place, otherwise the prompt overwrites them."""
     from ..kernels.power import norm_shape, state_shape
 
+    helper, batch, T = step.helper, step.batch, step.T
     n_head, d_head = cfg["n_head"], _d_head(cfg)
     n_kv, _g = _kv_heads_of(cfg)
     q, k, v = _qkv(cfg, h, nm)
@@ -1402,24 +1348,25 @@ def _retention(cfg, helper, h, nm, i, batch, T, pos, step):
         t = _head_norm(cfg, layers.reshape(t, [-1, T, n, d_head]), nm,
                        which)
         if _rotates(cfg, i):
-            t = _rope(cfg, t, pos, heads_last=True)
+            t = _rope(cfg, t, step.pos, heads_last=True)
         return layers.reshape(t, [-1, T, n * d_head])
 
     with name_scope("mixer"):
         y = layers.power_retention(
             heads(q, n_head, "q"), heads(k, n_kv, "k"), v, gate, state,
-            norm, n_head, n_kv, step=step)
+            norm, n_head, n_kv, step=step.decode)
     return y, [state.name, norm.name]
 
 
-def _delta_mixer(cfg, helper, h, nm, batch, T, step):
+def _delta_mixer(cfg, step, h, nm, i):
     """The gated delta rule over the normed ``h [B, T, D]``
     (``base_config`` has the equations): ``(out [B, T, D], [the two cache
-    names])``. ``step`` is the decode form (``T`` = 1): the state and the
+    names])``. In the decode form (``T`` = 1) the state and the
     convolution rows are read and updated in place; otherwise the prompt
     is scanned from a zero state and both are overwritten."""
     from ..kernels.delta import CONV_TAPS, state_shape
 
+    helper, batch, T = step.helper, step.batch, step.T
     Hk, Dk, Hv, Dv, d_conv = delta_widths(cfg)
     with name_scope("mixer"):
         proj = _fc(h, d_conv + Hv * Dv, nm + "_delta_in.w_0")
@@ -1434,12 +1381,13 @@ def _delta_mixer(cfg, helper, h, nm, batch, T, step):
             name=nm + "_cache_s", shape=state_shape(batch, Hv, Dk, Dv))
         with stored_dtype(None):      # the taps stay float32, as a vector
             qkv = layers.causal_conv(cut(proj, 0, d_conv), CONV_TAPS,
-                                     nm + "_delta_conv", rows, step=step,
-                                     bias=False)
+                                     nm + "_delta_conv", rows,
+                                     step=step.decode, bias=False)
         y = layers.delta_rule(
             cut(qkv, 0, Hk * Dk), cut(qkv, Hk * Dk, 2 * Hk * Dk),
             cut(qkv, 2 * Hk * Dk, d_conv), cut(ba, 0, Hv),
-            cut(ba, Hv, 2 * Hv), state, Hk, Hv, nm + "_delta", step=step)
+            cut(ba, Hv, 2 * Hv), state, Hk, Hv, nm + "_delta",
+            step=step.decode)
         # the norm a head, then the gate: one [Dv] scale the heads share
         y = layers.rms_norm(
             layers.reshape(y, [-1, T, Hv, Dv]), begin_norm_axis=3,
@@ -1673,20 +1621,17 @@ def _shared_expert(cfg, h, nm, act):
     return _fc(hid, cfg["d_model"], nm + "_moe_shared_down.w_0")
 
 
-def _ssm_mixer(cfg, helper, h, nm, batch, T, step):
-    with name_scope("mixer"):
-        return _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step)
-
-
-def _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step):
+@name_scope("mixer")
+def _ssm_mixer(cfg, step, h, nm, i):
     """A state-space mixer over the normed ``h [B, T, D]`` (``base_config``
-    has the equations): ``(out [B, T, D], [the two cache names])``. ``step``
-    is the decode form (``T`` = 1): the state and the convolution rows
-    are read and updated in place; otherwise the prompt is scanned from
-    a zero state and both are overwritten."""
+    has the equations): ``(out [B, T, D], [the two cache names])``. In the
+    decode form (``T`` = 1) the state and the convolution rows are read
+    and updated in place; otherwise the prompt is scanned from a zero
+    state and both are overwritten."""
     from ..initializer import Constant
     from ..kernels.ssm import state_shape
 
+    helper, batch, T = step.helper, step.batch, step.T
     H, P, G, N, K, d_in, d_conv = ssm_widths(cfg)
     proj = _fc(h, 2 * d_in + 2 * G * N + H, nm + "_ssm_in.w_0")
 
@@ -1699,12 +1644,12 @@ def _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step):
     state = helper.create_global_variable(
         name=nm + "_cache_s", shape=state_shape(batch, H, P, G, N))
     xbc = layers.causal_conv(cut(proj, d_in, d_in + d_conv), K,
-                             nm + "_ssm_conv", rows, step=step)
+                             nm + "_ssm_conv", rows, step=step.decode)
     y = layers.ssm_mix(
         cut(xbc, 0, d_in), cut(proj, d_in + d_conv, d_in + d_conv + H),
         cut(xbc, d_in, d_in + G * N), cut(xbc, d_in + G * N, d_conv),
         state, H, G, N, nm + "_ssm",
-        chunk=int(cfg.get("ssm_chunk") or 128), step=step)
+        chunk=_ssm_chunk(cfg), step=step.decode)
     # the gate BEFORE the norm, and the norm a group of d_in / G values
     y = layers.reshape(layers.elementwise_mul(y, layers.swish(z)),
                        [-1, T, G, d_in // G])
@@ -1720,30 +1665,8 @@ def _ssm_mixer_ops(cfg, helper, h, nm, batch, T, step):
         [rows.name, state.name]
 
 
-def _lone_mixer(cfg, helper, x, nm, i, batch, T, step, cache_names,
-                **tally):
-    """Layer ``i`` of a cfg['mixers'] model where its one mixer is not
-    attention: ``x`` after it, or None for an attention layer (the
-    builder's own code) and for a cfg without mixers."""
-    kind = mixer_kind(cfg, i)
-    if kind not in ("ssm", "experts"):
-        return None
-    h = _norm_of(cfg, x, nm + "_pre1")
-    if kind == "ssm":
-        y, names = _ssm_mixer(cfg, helper, h, nm, batch, T, step)
-        cache_names += names
-    else:
-        y = _mlp(cfg, h, nm, i, **tally)
-    with name_scope("mixer" if kind == "ssm" else "moe.experts"):
-        return layers.elementwise_add(x, y)
-
-
+@name_scope("head")
 def _final_norm(cfg, x):
-    with name_scope("head"):
-        return _final_norm_ops(cfg, x)
-
-
-def _final_norm_ops(cfg, x):
     """The shared final norm (training build + decode step use the SAME
     parameter names, so decode can overwrite by name); of the SUM of the
     streams where a token has several."""
@@ -2027,16 +1950,10 @@ def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
         _note_mla_expanded(cfg, "composed")
     else:
         q, k, v = _qkv(cfg, h, nm)
-
-        def heads(t, n, which=None):
-            t = layers.reshape(t, [-1, seq_len, n, d_head])
-            if which:
-                t = _head_norm(cfg, t, nm, which)
-            return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,S,Dh]
-
         with name_scope("attn.core"):
-            q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), \
-                heads(v, n_kv)
+            q = _heads(cfg, q, nm, seq_len, n_head, "q")
+            k = _heads(cfg, k, nm, seq_len, n_kv, "k")
+            v = _heads(cfg, v, nm, seq_len, n_kv)
             if _rotates(cfg, i):
                 q, k = _rope(cfg, q, rope_pos), _rope(cfg, k, rope_pos)
             k = repeat_kv_heads(k, n_kv, n_head, seq_len, d_head)
@@ -2057,6 +1974,27 @@ def _block(cfg, x, i, seq_len, bias, rope_pos, is_test):
     with name_scope(dense):
         f = dropped(f)
     return _residual(cfg, x, f, nm + "_post2", scope=dense)
+
+
+class _Step(NamedTuple):
+    """What a builder hands every layer of its program: the decode form
+    (``T`` = 1, caches updated in place at ``pos``) or the prefill
+    (``T`` = the prompt's length, caches overwritten from row 0)."""
+    helper: Any
+    batch: int
+    T: int
+    decode: bool
+    max_len: int
+    pos: Any            # the step's position feed; the prompt's range
+    tally: dict         # ``_mlp``'s counts
+    branch: Optional[dict]      # ``_layer_tail``'s, under shortcut_moe
+    cache_names: list           # every layer's, as ``_layer`` adds them
+    zero: Any = None    # prefill: the cache writes' row 0
+    fused: bool = False         # prefill: attention through the fused op
+    bias: Any = None    # prefill: the causal bias where it is composed
+    biases: Any = None          # decode: cache rows -> visibility bias
+    ring_pos: Any = None        # decode: ring rows -> the row of ``pos``
+    dev: Any = None     # decode: ``MHC_RES_DEV_VAR``
 
 
 @_stores_weights
@@ -2113,13 +2051,13 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
     # only the serving decode step tallies its routing; a share's long
     # prefill tallies which length its expert calls ran at
     tally = {"compact": _compact_calls_var(cfg, helper, batch * P)}
-    branch = {} if has_shortcut(cfg) else None
-    cache_names = []
+    step = _Step(helper, batch, T=P, decode=False, max_len=max_len,
+                 pos=pos_range, tally=tally,
+                 branch={} if has_shortcut(cfg) else None, cache_names=[],
+                 zero=zero, fused=fused, bias=bias)
     for i in range(cfg["n_layer"]):
         with _layer_scope(cfg, i):
-            x = _prefill_layer(cfg, helper, x, i, batch, P, max_len,
-                               pos_range, zero, fused, bias, tally, branch,
-                               cache_names)
+            x = _layer(cfg, step, x, i)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
@@ -2140,84 +2078,41 @@ def build_prefill_step(cfg=None, batch=1, prompt_len=8, max_len=None):
         last = _expose(layers.reshape(last, [-1, cfg["vocab"]]),
                        LAST_LOGITS_VAR)
     _greedy_token(last)
-    return logits, cache_names
+    return logits, step.cache_names
 
 
-def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
-                   fused, bias, tally, branch, cache_names):
-    """Layer ``i`` of ``build_prefill_step`` over ``x [B, P, D]`` (its
-    caches' names appended to ``cache_names``): ``x`` after it."""
+def _kv_caches(cfg, step, nm, i):
+    """Layer ``i``'s key and value caches and their rows (a slab's or a
+    ring's). GQA: they store n_kv heads — H/Hkv-times less decode HBM,
+    the whole point of grouped-query attention at inference."""
+    rows = cache_rows(cfg, i, step.max_len)
+    shape = (step.batch, _kv_heads_of(cfg)[0], rows, _d_head(cfg))
+    return [step.helper.create_global_variable(name=nm + sfx, shape=shape)
+            for sfx in ("_cache_k", "_cache_v")] + [rows]
+
+
+def _attention_prompt(cfg, step, h, nm, i):
+    """Keys-and-values attention over a prompt's normed ``h [B, P, D]``:
+    ``(the merged heads [B, P, n_head * d_head], the two caches'
+    names)``, the prompt's rotated keys and its values left in the
+    layer's slab or ring."""
     from .transformer import repeat_kv_heads
 
-    n_head, d_head = cfg["n_head"], _d_head(cfg)
+    n_head, d_head, P = cfg["n_head"], _d_head(cfg), step.T
     n_kv, _g = _kv_heads_of(cfg)
-    nm = "gpt_%d" % i
-    lone = _lone_mixer(cfg, helper, x, nm, i, batch, P, False,
-                       cache_names, **tally)
-    if lone is not None:
-        return lone
-    rows = cache_rows(cfg, i, max_len)
-    h, mix = _sub_input(cfg, x, nm, 1)
-    if is_conv(cfg, i):
-        y, kept = _gated_conv(cfg, helper, h, nm, batch, False)
-        cache_names.append(kept)
-        return _layer_tail(cfg, x, y, nm, i, mix, first="conv", **tally)
-    if layer_type(cfg, i) == "delta":
-        y, kept = _delta_mixer(cfg, helper, h, nm, batch, P, False)
-        cache_names += kept
-        return _layer_tail(cfg, x, y, nm, i, mix, first="mixer", **tally)
-    if is_retention(cfg, i):
-        y, kept = _retention(cfg, helper, h, nm, i, batch, P, pos_range,
-                             False)
-        cache_names += kept
-        return _block_tail(cfg, x, h, y, nm, i, mix=mix, branch=branch,
-                           **tally)
-    if has_latent(cfg):
-        # the expanded form through the flash forward; what stays of
-        # the prompt is ONE slab of latent rows
-        cc = helper.create_global_variable(
-            name=nm + "_cache_c",
-            shape=(batch, 1, rows, latent_width(cfg)))
-        cache_names.append(cc.name)
-        q, q_r, kv, k_r, row = _mla_packed(cfg, h, nm, P, pos_range)
-        with name_scope("attn.core"):
-            _prefill_cache_write(cc, row, P, rows, zero)
-            # compute-bound at 128 heads: the kernel from one lane tile
-            # on (every prompt length of a cell runs, and is measured
-            # as, the one attention form) and on bfloat16 MXU operands,
-            # as kernels/moe_gmm.py rounds its float32 ones. The op reads
-            # q, k and v where the projections wrote them and writes the
-            # context where the output projection reads it
-            ctxv = layers.fused_attention(
-                q, kv, kv, scale=_mla_scale(cfg), causal=True,
-                mxu_dtype="bfloat16", flash_min_seq=128, n_head=n_head,
-                q_r=q_r, k_r=k_r)
-            _note_mla_expanded(cfg, "fused_attention")
-        return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
-                           **tally)
-    ck = helper.create_global_variable(
-        name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
-    cv = helper.create_global_variable(
-        name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
-    cache_names += [ck.name, cv.name]
-
+    ck, cv, rows = _kv_caches(cfg, step, nm, i)
     q, k, v = _qkv(cfg, h, nm)
-
-    def heads(t, n, which=None):
-        t = layers.reshape(t, [-1, P, n, d_head])
-        if which:
-            t = _head_norm(cfg, t, nm, which)
-        return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n,P,Dh]
-
     with name_scope("attn.core"):
-        q, k, v = heads(q, n_head, "q"), heads(k, n_kv, "k"), heads(v, n_kv)
+        q = _heads(cfg, q, nm, P, n_head, "q")
+        k = _heads(cfg, k, nm, P, n_kv, "k")
+        v = _heads(cfg, v, nm, P, n_kv)
         if _rotates(cfg, i):
-            q = _rope(cfg, q, pos_range)
-            k = _rope(cfg, k, pos_range)
+            q = _rope(cfg, q, step.pos)
+            k = _rope(cfg, k, step.pos)
         # one slab write per layer: the cache holds rotated keys
-        _prefill_cache_write(ck, k, P, rows, zero)
-        _prefill_cache_write(cv, v, P, rows, zero)
-        if fused:
+        _prefill_cache_write(ck, k, P, rows, step.zero)
+        _prefill_cache_write(cv, v, P, rows, step.zero)
+        if step.fused:
             window = layer_window(cfg, i)
             ctxv = layers.fused_attention(
                 q, k, v, scale=d_head ** -0.5, causal=True,
@@ -2228,13 +2123,38 @@ def _prefill_layer(cfg, helper, x, i, batch, P, max_len, pos_range, zero,
             vr = repeat_kv_heads(v, n_kv, n_head, P, d_head)
             scores = layers.matmul(q, kr, transpose_y=True,
                                    alpha=d_head ** -0.5)   # [B,H,P,P]
-            scores = layers.elementwise_add(scores, bias)
+            scores = layers.elementwise_add(scores, step.bias)
             w = layers.softmax(scores)
             ctxv = layers.matmul(w, vr)                    # [B,H,P,Dh]
         ctxv = layers.transpose(ctxv, perm=[0, 2, 1, 3])
         ctxv = layers.reshape(ctxv, [-1, P, n_head * d_head])
-    return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, branch=branch,
-                       **tally)
+    return ctxv, [ck.name, cv.name]
+
+
+def _latent_prompt(cfg, step, h, nm, i):
+    """Latent attention over a prompt's normed ``h [B, P, D]``, the
+    expanded form through the flash forward: ``(the merged heads, [the
+    cache's name])``. What stays of the prompt is ONE slab of latent
+    rows."""
+    P, rows = step.T, cache_rows(cfg, i, step.max_len)
+    cc = step.helper.create_global_variable(
+        name=nm + "_cache_c",
+        shape=(step.batch, 1, rows, latent_width(cfg)))
+    q, q_r, kv, k_r, row = _mla_packed(cfg, h, nm, P, step.pos)
+    with name_scope("attn.core"):
+        _prefill_cache_write(cc, row, P, rows, step.zero)
+        # compute-bound at 128 heads: the kernel from one lane tile
+        # on (every prompt length of a cell runs, and is measured
+        # as, the one attention form) and on bfloat16 MXU operands,
+        # as kernels/moe_gmm.py rounds its float32 ones. The op reads
+        # q, k and v where the projections wrote them and writes the
+        # context where the output projection reads it
+        ctxv = layers.fused_attention(
+            q, kv, kv, scale=_mla_scale(cfg), causal=True,
+            mxu_dtype="bfloat16", flash_min_seq=128, n_head=cfg["n_head"],
+            q_r=q_r, k_r=k_r)
+        _note_mla_expanded(cfg, "fused_attention")
+    return ctxv, [cc.name]
 
 
 def _prefill_cache_write(cache, kv, P, rows, zero):
@@ -2320,13 +2240,12 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     # attends to `cache row <= pos[b]`, so a retired neighbor's stale
     # rows never leak into a live slot's attention. One bias a cache
     # shape: a slab's over max_len rows, a ring's over its window
-    # (``_visibility_bias`` says why the same test serves a ring)
+    # (``_visibility_bias`` says why the same test serves a ring; a
+    # latent layer's visibility is ``mla_decode``'s own)
     pos_b, biases, ring_pos = None, {}, {}
-    latent = has_latent(cfg)     # its visibility is ``mla_decode``'s own
     for rows in dict.fromkeys(
-            cache_rows(cfg, i, max_len)
-            for i in range(0 if latent else cfg["n_layer"])
-            if _keeps_rows(cfg, i)):
+            cache_rows(cfg, i, max_len) for i in range(cfg["n_layer"])
+            if kind_of(cfg, i).name == "attention"):
         with name_scope("attn.core"):    # one a step, for all its layers
             ar = layers.reshape(layers.range(0, rows, 1, "int64"),
                                 [1, rows])
@@ -2347,91 +2266,55 @@ def build_decode_step(cfg=None, batch=1, max_len=None,
     # only the serving step tallies its routing
     tally = {"counts": routed, "touched": touched,
              "zero": _zero_pairs_var(cfg, helper) if per_slot_pos else None}
-    branch = {} if has_shortcut(cfg) else None
-    cache_names = []
+    step = _Step(helper, batch, T=1, decode=True, max_len=max_len, pos=pos,
+                 tally=tally, branch={} if has_shortcut(cfg) else None,
+                 cache_names=[], biases=biases, ring_pos=ring_pos, dev=dev)
     for i in range(cfg["n_layer"]):
         with _layer_scope(cfg, i):
-            x = _decode_layer(cfg, helper, x, i, batch, max_len, pos,
-                              biases, ring_pos, dev, tally, branch,
-                              cache_names)
+            x = _layer(cfg, step, x, i)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)
     with name_scope("head"):
         _greedy_token(layers.reshape(logits, [-1, cfg["vocab"]]))
-    return logits, cache_names
+    return logits, step.cache_names
 
 
-def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
-                  dev, tally, branch, cache_names):
-    """Layer ``i`` of ``build_decode_step`` over ``x [B, 1, D]`` (its
-    caches' names appended to ``cache_names``): ``x`` after it."""
-    n_head, d_head = cfg["n_head"], _d_head(cfg)
-    n_kv, g = _kv_heads_of(cfg)
-    nm = "gpt_%d" % i
-    lone = _lone_mixer(cfg, helper, x, nm, i, batch, 1, True,
-                       cache_names, **tally)
-    if lone is not None:
-        return lone
-    rows = cache_rows(cfg, i, max_len)
-    if is_conv(cfg, i):
-        h, mix = _sub_input(cfg, x, nm, 1, dev)
-        y, kept = _gated_conv(cfg, helper, h, nm, batch, True)
-        cache_names.append(kept)
-        return _layer_tail(cfg, x, y, nm, i, mix, dev, first="conv",
-                           **tally)
-    if layer_type(cfg, i) == "delta":
-        h, mix = _sub_input(cfg, x, nm, 1, dev)
-        y, kept = _delta_mixer(cfg, helper, h, nm, batch, 1, True)
-        cache_names += kept
-        return _layer_tail(cfg, x, y, nm, i, mix, dev, first="mixer",
-                           **tally)
-    if is_retention(cfg, i):
-        h, mix = _sub_input(cfg, x, nm, 1, dev)
-        y, kept = _retention(cfg, helper, h, nm, i, batch, 1, pos, True)
-        cache_names += kept
-        return _block_tail(cfg, x, h, y, nm, i, mix=mix, dev=dev,
-                           branch=branch, **tally)
-    if has_latent(cfg):
-        # the absorbed form: one latent row written, and every head
-        # reads keys AND values out of the slot's one slab
-        cc = helper.create_global_variable(
-            name=nm + "_cache_c",
-            shape=(batch, 1, rows, latent_width(cfg)))
-        cache_names.append(cc.name)
-        h, mix = _sub_input(cfg, x, nm, 1, dev)
-        row = _mla_row(cfg, *_mla_latent(cfg, h, nm, 1, pos), 1)
-        with name_scope("attn.core"):
-            cc = layers.kv_cache_write(cc, row, pos)
-        q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
-        with name_scope("attn.core"):
-            ctxv = layers.mla_decode(
-                q_nope, q_rope, cc, pos,
-                [cfg["kv_lora_rank"],
-                 n_head * (cfg["d_nope"] + cfg["d_v"])],
-                d_v=cfg["d_v"], scale=_mla_scale(cfg),
-                param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
-        return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                           branch=branch, **tally)
-    # GQA: the cache stores n_kv heads — H/Hkv-times less decode
-    # HBM, the whole point of grouped-query attention at inference
-    ck = helper.create_global_variable(
-        name=nm + "_cache_k", shape=(batch, n_kv, rows, d_head))
-    cv = helper.create_global_variable(
-        name=nm + "_cache_v", shape=(batch, n_kv, rows, d_head))
-    cache_names += [ck.name, cv.name]
-
-    h, mix = _sub_input(cfg, x, nm, 1, dev)
-    q, k, v = _qkv(cfg, h, nm)
-
-    def kv_heads(t, which=None):
-        t = layers.reshape(t, [-1, 1, n_kv, d_head])
-        if which:
-            t = _head_norm(cfg, t, nm, which)
-        return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,Hkv,1,Dh]
-
+def _latent_step(cfg, step, h, nm, i):
+    """Latent attention of a decode step over the normed ``h [B, 1,
+    D]``, the absorbed form: one latent row written, and every head
+    reads keys AND values out of the slot's one slab."""
+    pos = step.pos
+    cc = step.helper.create_global_variable(
+        name=nm + "_cache_c",
+        shape=(step.batch, 1, cache_rows(cfg, i, step.max_len),
+               latent_width(cfg)))
+    row = _mla_row(cfg, *_mla_latent(cfg, h, nm, 1, pos), 1)
     with name_scope("attn.core"):
-        k, v = kv_heads(k, "k"), kv_heads(v)
+        cc = layers.kv_cache_write(cc, row, pos)
+    q_nope, q_rope = _mla_q(cfg, h, nm, 1, pos)
+    with name_scope("attn.core"):
+        ctxv = layers.mla_decode(
+            q_nope, q_rope, cc, pos,
+            [cfg["kv_lora_rank"],
+             cfg["n_head"] * (cfg["d_nope"] + cfg["d_v"])],
+            d_v=cfg["d_v"], scale=_mla_scale(cfg),
+            param_attr=ParamAttr(name=nm + "_att_kvb.w_0"))
+    return ctxv, [cc.name]
+
+
+def _attention_step(cfg, step, h, nm, i):
+    """Keys-and-values attention of a decode step over the normed ``h
+    [B, 1, D]``: ``(the merged heads [B, 1, n_head * d_head], the two
+    caches' names)``."""
+    n_head, d_head, pos = cfg["n_head"], _d_head(cfg), step.pos
+    n_kv, g = _kv_heads_of(cfg)
+    ck, cv, rows = _kv_caches(cfg, step, nm, i)
+    names = [ck.name, cv.name]
+    q, k, v = _qkv(cfg, h, nm)
+    with name_scope("attn.core"):
+        k = _heads(cfg, k, nm, 1, n_kv, "k")            # [B,Hkv,1,Dh]
+        v = _heads(cfg, v, nm, 1, n_kv)
         rotates = _rotates(cfg, i)
         if rotates:
             # rotate at THIS position; the cache stores rotated keys,
@@ -2439,7 +2322,7 @@ def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
             # Per-slot [B, 1] positions broadcast per-row angles over
             # the head axis — each slot rotates at ITS position
             k = _rope(cfg, k, pos)
-        at = ring_pos.get(rows, pos)
+        at = step.ring_pos.get(rows, pos)
         ck = layers.kv_cache_write(ck, k, at)    # per-slot rows when
         cv = layers.kv_cache_write(cv, v, at)    # pos is [B]/[B, 1]
         # GQA grouped attention: query heads fold as [B, Hkv, g, Dh]
@@ -2458,12 +2341,134 @@ def _decode_layer(cfg, helper, x, i, batch, max_len, pos, biases, ring_pos,
             q = _rope(cfg, q, pos)
         scores = layers.matmul(q, ck, transpose_y=True,
                                alpha=d_head ** -0.5)    # [B,Hkv,g,S]
-        scores = layers.elementwise_add(scores, biases[rows])
+        scores = layers.elementwise_add(scores, step.biases[rows])
         w = layers.softmax(scores)
         ctxv = layers.matmul(w, cv)                     # [B,Hkv,g,Dh]
         ctxv = layers.reshape(ctxv, [-1, 1, n_head * d_head])
-    return _block_tail(cfg, x, h, ctxv, nm, i, mix=mix, dev=dev,
-                       branch=branch, **tally)
+    return ctxv, names
+
+
+def _by_form(prompt, decode):
+    """A row's builder where the two forms share no op: ``prompt`` over
+    a whole prompt, ``decode`` over one token against the caches."""
+    def build(cfg, step, h, nm, i):
+        return (decode if step.decode else prompt)(cfg, step, h, nm, i)
+    return build
+
+
+def _experts_mixer(cfg, step, h, nm, i):
+    return _mlp(cfg, h, nm, i, **step.tally), []
+
+
+def _ssm_chunk(cfg, P=None):
+    return int(cfg.get("ssm_chunk") or 128)
+
+
+def _power_chunk(cfg, P):
+    from ..kernels.power import scan_chunk
+
+    return scan_chunk(P)
+
+
+def _delta_chunk(cfg, P):
+    from ..kernels.delta import scan_chunk
+
+    return scan_chunk(P)
+
+
+def _power_state(update, made):
+    return update.input("State") + update.input("Norm")
+
+
+def _delta_state(update, made):
+    """The state, and the rows of the convolution whose output the
+    update reads (through the slice that cuts q)."""
+    cut = made[update.input("Q")[0]]
+    return update.input("State") \
+        + made[cut.input_names()[0]].input("Rows")
+
+
+class _LayerKind(NamedTuple):
+    """One kind of first sub-block a layer can have: a row of
+    ``LAYER_KINDS``."""
+    name: str           # its entry of cfg[key]
+    layer: str          # what the checks call a layer of it
+    key: Optional[str]  # 'mixers' | 'layer_types'; None: without an entry
+    build: Callable     # (cfg, step, normed input, nm, i) -> (y, [caches])
+    scope: str          # the class its residual add stands under
+    merged: bool        # y is the merged heads: ``_attn_out`` projects it
+    caches: Dict[str, str]      # suffix -> 'rows' | 'latent' | 'state'
+    kept: Optional[Callable] = None     # (cfg) -> state_refusal's words
+    needs: Tuple[str, ...] = ()         # cfg keys a layer needs >= 1
+    extra: Tuple[str, ...] = ()         # its other keys: none without it
+    refuses: Tuple[Tuple[str, str], ...] = ()   # (key it takes none of, why)
+    check: Optional[Callable] = None    # (cfg): its own arithmetic
+    chunk: Optional[Callable] = None    # (cfg, P) -> its prefill scan's chunk
+    chunks: Any = None                  # the counter of chunks scanned
+    state_bytes: Any = None             # the gauge of its state's bytes
+    update_op: Optional[str] = None     # the decode op that updates the state
+    state: Optional[Callable] = None    # (that op, {variable: the op that
+    #                                     made it}) -> the state's variables
+
+
+_NO_KINDS = ("attn", "latent attention has no layer kinds")
+_NO_FIRST = ("mixers", "one mixer a layer has no first sub-block")
+_NO_FORK = ("shortcut_moe", "the branch forks behind an attention sub-block")
+LAYER_KINDS = {kind.name: kind for kind in (
+    _LayerKind("attention", "an attention layer", None,
+               _by_form(_attention_prompt, _attention_step), "attn.out",
+               True, {"_cache_k": "rows", "_cache_v": "rows"}),
+    _LayerKind("latent", "a latent attention layer", None,
+               _by_form(_latent_prompt, _latent_step), "attn.out", True,
+               {"_cache_c": "latent"}),
+    _LayerKind("ssm", "an 'ssm' layer", "mixers", _ssm_mixer, "mixer", False,
+               {"_cache_x": "state", "_cache_s": "state"}, _ssm_kept,
+               needs=_SSM_KEYS, extra=("ssm_chunk",), check=_check_ssm,
+               chunk=_ssm_chunk),
+    _LayerKind("experts", "an 'experts' layer", "mixers", _experts_mixer,
+               "moe.experts", False, {}, check=_check_experts),
+    _LayerKind("retention", "a 'retention' layer", "layer_types", _retention,
+               "attn.out", True, {"_cache_s": "state", "_cache_z": "state"},
+               _retention_kept,
+               refuses=(_NO_KINDS,
+                        ("residual", "the retention core is not written over "
+                         "several residual streams"), _NO_FORK),
+               chunk=_power_chunk, chunks=POWER_CHUNKS,
+               state_bytes=POWER_STATE_BYTES, update_op="power_update",
+               state=_power_state),
+    _LayerKind("delta", "a 'delta' layer", "layer_types", _delta_mixer,
+               "mixer", False, {"_cache_x": "state", "_cache_s": "state"},
+               _delta_kept, needs=_DELTA_KEYS, check=_check_delta,
+               refuses=(_NO_KINDS,
+                        ("residual", "the delta rule is not written over "
+                         "several residual streams"), _NO_FIRST, _NO_FORK),
+               chunk=_delta_chunk, chunks=DELTA_CHUNKS,
+               state_bytes=DELTA_STATE_BYTES, update_op="delta_update",
+               state=_delta_state),
+    _LayerKind("conv", "a 'conv' layer", "layer_types", _gated_conv, "conv",
+               False, {"_cache_x": "state"}, _conv_kept,
+               extra=("conv_taps",), check=_check_conv,
+               refuses=(_NO_KINDS,
+                        ("residual", "the gated convolution is not written "
+                         "over several residual streams"), _NO_FIRST)),
+)}
+
+
+def _layer(cfg, step, x, i):
+    """Layer ``i`` of ``build_prefill_step`` and of ``build_decode_step``
+    over ``x [B, T, D]``: ``x`` after it, its caches' names added to
+    ``step.cache_names``. The layer's row of ``LAYER_KINDS`` builds the
+    first sub-block from the normed input; the rest is every layer's
+    (under cfg['mixers'] ``_sub_input`` is the plain norm and
+    ``_layer_tail`` ends at the first residual)."""
+    kind, nm = kind_of(cfg, i), "gpt_%d" % i
+    h, mix = _sub_input(cfg, x, nm, 1, step.dev)
+    y, kept = kind.build(cfg, step, h, nm, i)
+    step.cache_names.extend(kept)
+    if kind.merged:
+        y = _attn_out(cfg, h, y, nm)
+    return _layer_tail(cfg, x, y, nm, i, mix, step.dev, step.branch,
+                       kind.scope, **step.tally)
 
 
 @_stores_weights
@@ -2592,10 +2597,7 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         for s in range(S):
             ps = layers.slice(pos, axes=[1], starts=[s], ends=[s + 1])
             pos_cols.append(ps)                              # [B, 1]
-            vis = layers.cast(layers.less_equal(ar, ps), "float32")
-            b_s = layers.scale(layers.elementwise_sub(
-                layers.fill_constant([1], "float32", 1.0), vis), scale=-1e9)
-            biases.append(layers.reshape(b_s, [-1, 1, 1, max_len]))
+            biases.append(_visibility_bias(ar, ps, -1))
 
     routed = None
     cache_names = []
@@ -2606,18 +2608,12 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
         cv = helper.create_global_variable(
             name=nm + "_cache_v", shape=(batch, n_kv, max_len, d_head))
         cache_names += [ck.name, cv.name]
-
-        def kv_heads(t, which=None):
-            t = layers.reshape(t, [-1, S, n_kv, d_head])
-            if which:
-                t = _head_norm(cfg, t, nm, which)
-            return layers.transpose(t, perm=[0, 2, 1, 3])  # [B,n_kv,S,Dh]
-
         with _layer_scope(cfg, i):
             h = _norm_of(cfg, x, nm + "_pre1")
             q, k, v = _qkv(cfg, h, nm)
             with name_scope("attn.core"):
-                k, v = kv_heads(k, "k"), kv_heads(v)
+                k = _heads(cfg, k, nm, S, n_kv, "k")    # [B,n_kv,S,Dh]
+                v = _heads(cfg, v, nm, S, n_kv)
                 rotates = _rotates(cfg, i)
                 if rotates:
                     # [B, S] positions -> per-(row, step) angles broadcast
@@ -2648,7 +2644,8 @@ def build_multi_token_decode_step(cfg=None, batch=1, steps=2,
                     ctxs.append(layers.reshape(layers.matmul(w, cv),
                                                [-1, 1, n_head * d_head]))
                 ctxv = ctxs[0] if S == 1 else layers.concat(ctxs, axis=1)
-            x = _block_tail(cfg, x, h, ctxv, nm, i, counts=routed)
+            x = _layer_tail(cfg, x, _attn_out(cfg, h, ctxv, nm), nm, i,
+                            counts=routed)
 
     x = _final_norm(cfg, x)
     logits = _lm_head(cfg, x)

@@ -87,10 +87,20 @@ def _build(name: str) -> str:
                 or os.path.getmtime(so) < max(os.path.getmtime(d)
                                               for d in deps)):
             extra = _EXTRA_FLAGS.get(name)
+            # linked beside the library and renamed over it: the lock
+            # above is this process's, and another process (a test
+            # worker, a trainer) that finds the file while the linker
+            # writes it would load a part of it ("file too short")
+            tmp = os.path.join(_DIR, "lib%s.%d.tmp.so" % (name, os.getpid()))
             cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
                     "-pthread"] + srcs + (extra() if extra else [])
-                   + ["-o", so])
-            subprocess.run(cmd, check=True, capture_output=True)
+                   + ["-o", tmp])
+            try:
+                subprocess.run(cmd, check=True, capture_output=True)
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
     return so
 
 

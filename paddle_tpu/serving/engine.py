@@ -170,11 +170,12 @@ class _Lane:
         self._warm: set = set()   # (program id, fetch) dispatched once
         self.cfg = cfg
         self._state_layers = len(gpt.state_layers(cfg))
-        # retention layers, whose prefill scans the prompt in chunks
-        self._retention_layers = sum(
-            gpt.is_retention(cfg, i) for i in range(cfg["n_layer"]))
-        # delta-rule layers, likewise
-        self._delta_layers = (cfg.get("layer_types") or []).count("delta")
+        # (kind, its layers) of the kinds whose prefill scans the prompt
+        # in chunks
+        held = [gpt.kind_of(cfg, i).name for i in range(cfg["n_layer"])]
+        self._scans = [(kind, held.count(kind.name))
+                       for kind in gpt.LAYER_KINDS.values()
+                       if kind.chunk is not None and kind.name in held]
         self.b_max, self.max_len = b_max, max_len
         self.scope = Scope()
         self._prefill_scope = Scope()
@@ -247,9 +248,7 @@ class _Lane:
         position axis), a latent layer's one tensor, rings (shorter than
         ``max_len``) and full slabs."""
         from ..kernels.mla_decode import decode_plan
-        from ..observe.families import (DELTA_STATE_BYTES,
-                                        POWER_STATE_BYTES,
-                                        SERVING_CACHE_BYTES)
+        from ..observe.families import SERVING_CACHE_BYTES
 
         block = self._decode_prog.global_block()
 
@@ -268,21 +267,14 @@ class _Lane:
                                           self.cfg["n_head"]))
         for kind, nbytes in held.items():
             SERVING_CACHE_BYTES.labels(kind=kind).set(nbytes)
-        # of the state: what the retention layers' updates read and write
-        POWER_STATE_BYTES.set(sum(
-            size(n) for op in block.ops if op.type == "power_update"
-            for n in op.input("State") + op.input("Norm")))
-        # and the delta layers': the state, and the rows of the convolution
-        # whose output the update reads (through the slice that cuts q)
+        # of the state: what the updates of each kind that has a gauge
+        # read and write (0 for a model without such layers)
         made = {n: op for op in block.ops for n in op.output_names()}
-
-        def conv_rows(update):
-            cut = made[update.input("Q")[0]]
-            return made[cut.input_names()[0]].input("Rows")[0]
-
-        DELTA_STATE_BYTES.set(sum(
-            size(op.input("State")[0]) + size(conv_rows(op))
-            for op in block.ops if op.type == "delta_update"))
+        for kind in self._gpt.LAYER_KINDS.values():
+            if kind.state_bytes is not None:
+                kind.state_bytes.set(sum(
+                    size(n) for op in block.ops if op.type == kind.update_op
+                    for n in kind.state(op, made)))
         # (latent layers, rows of a block) for
         # paddle_mla_decode_blocks_total: the slabs are of one shape; None
         # where no cache is latent or the kernel has no plan for it
@@ -447,22 +439,11 @@ class _Lane:
                 # the layers whose part of the slot this prefill
                 # overwrites whole, whatever the prompt's length
                 attrs["state_layers"] = self._state_layers
-            if "ssm" in (self.cfg.get("mixers") or ()):
-                # the chunks each state-space layer scans the prompt in
-                attrs["chunks"] = -(-P // int(
-                    self.cfg.get("ssm_chunk") or 128))
-            if self._retention_layers:
-                from ..kernels.power import scan_chunk
-                from ..observe.families import POWER_CHUNKS
-
-                attrs["chunks"] = -(-P // scan_chunk(P))
-                POWER_CHUNKS.inc(self._retention_layers * attrs["chunks"])
-            if self._delta_layers:
-                from ..kernels.delta import scan_chunk
-                from ..observe.families import DELTA_CHUNKS
-
-                attrs["chunks"] = -(-P // scan_chunk(P))
-                DELTA_CHUNKS.inc(self._delta_layers * attrs["chunks"])
+            for kind, n_layers in self._scans:
+                # the chunks each such layer scans the prompt in
+                attrs["chunks"] = -(-P // kind.chunk(self.cfg, P))
+                if kind.chunks is not None:
+                    kind.chunks.inc(n_layers * attrs["chunks"])
             with _tr.trace_span("serving.engine.prefill", **attrs):
                 with self._scope_guard(self._prefill_scope):
                     (out,) = self._exe.run(
@@ -1521,7 +1502,7 @@ class DecodeEngine:
         # additive, not set(): N router replicas share the process-wide
         # gauge, so each engine contributes its delta and the gauge
         # reads the fleet total
-        delta = self._n_active - self._gauge_contrib
-        if delta:
-            SERVING_SLOTS_ACTIVE.inc(delta)
+        change = self._n_active - self._gauge_contrib
+        if change:
+            SERVING_SLOTS_ACTIVE.inc(change)
             self._gauge_contrib = self._n_active

@@ -472,9 +472,12 @@ def test_state_layer_table_names_every_state_bearing_type():
     """One table of the layer types that keep a state: the helpers that
     used to test each type by name all go by it."""
     cfg = tiny_cfg()
-    assert set(gpt._STATE_TYPES) == {"conv", "retention", "delta"}
+    assert {k.name for k in gpt.LAYER_KINDS.values()
+            if k.kept and k.key == "layer_types"} \
+        == {"conv", "retention", "delta"}
     assert gpt.state_layers(cfg) == [0, 1, 2] and gpt.has_state(cfg)
-    assert [gpt._keeps_rows(cfg, i) for i in range(4)] == [False] * 3 + [True]
+    assert ["rows" in gpt.kind_of(cfg, i).caches.values()
+            for i in range(4)] == [False] * 3 + [True]
     assert [gpt._rotates(cfg, i) for i in range(4)] == [False] * 3 + [True]
     assert gpt.delta_widths(cfg) == (2, 16, 4, 16, 128)
 

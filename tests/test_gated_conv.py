@@ -437,7 +437,8 @@ def test_the_prefill_hands_the_kernel_rank_4_operands(monkeypatch):
     before = _lane_plans()
     exe.run(prog, feed={"tokens": np.ones((1, 21), "int64")},
             fetch_list=[logits], scope=scope)
-    n_att = sum(1 for i in range(cfg["n_layer"]) if not gpt.is_conv(cfg, i))
+    n_att = sum(1 for i in range(cfg["n_layer"])
+                if gpt.kind_of(cfg, i).name != "conv")
     assert {k: n - before.get(k, 0) for k, n in _lane_plans().items()
             if n > before.get(k, 0)} == {("flash_fwd", "heads"): n_att}
 

@@ -164,3 +164,29 @@ class TestGradAccum:
             (v,) = exe.run(main, feed={"x": X, "y": Y}, fetch_list=[loss],
                            scope=scope)
             assert np.isfinite(float(v))
+
+
+def test_a_programs_gradient_clip_is_no_other_programs():
+    """``set_gradient_clip`` keeps the clip on the parameters of the
+    program it is called under, as the reference does. It used to keep it
+    in a module global as well, so every later ``minimize`` of the process
+    clipped by it: the clip this file sets grew the trainer program of
+    ``test_dist_ps.py``'s sparse table with a global norm over
+    ``emb_w@GRAD``, which that program never makes (red under six
+    workers whenever the two files shared one, green alone)."""
+    def minimized(clip):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data("x", shape=[16], dtype="float32")
+            y = fluid.layers.data("y", shape=[1], dtype="float32")
+            loss = fluid.layers.mean(fluid.layers.square_error_cost(
+                fluid.layers.fc(x, size=1), y))
+            if clip is not None:
+                fluid.clip.set_gradient_clip(clip)
+            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        return [op.type for op in main.global_block().ops]
+
+    plain = minimized(None)
+    clipped = minimized(fluid.clip.GradientClipByGlobalNorm(1.0))
+    assert len(clipped) > len(plain) and "sqrt" in clipped
+    assert minimized(None) == plain

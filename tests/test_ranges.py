@@ -417,15 +417,11 @@ def test_model_zoo_range_analyzes_clean(model):
             assert op_type not in WIDEN_TO_TOP
 
 
-def test_model_zoo_finite_fraction_pinned(monkeypatch):
+def test_model_zoo_finite_fraction_pinned():
     """With startup-initialized scope weights and one calibrated
     synthetic feed batch, a pinned model subset proves finite intervals
     on >= 60% of non-T-declared vars (the acceptance floor), and the
     train+startup aggregate across the subset holds >= 60% too."""
-    # hermetic: a prior test's set_gradient_clip leaks through the
-    # module-level default and would grow every minimize() with clip
-    # chains the pinned fractions were not measured against
-    monkeypatch.setattr(fluid.clip, "_global_clip", None)
     models = ("mnist", "gpt", "ctr", "transformer", "vit")
     rng = np.random.RandomState(0)
     agg_n = agg_d = 0

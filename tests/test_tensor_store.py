@@ -171,3 +171,41 @@ def test_truncated_checkpoint_rejected(tmp_path):
         f.write(blob[:-7])
     with pytest.raises(IOError, match="truncated"):
         load_tensors(path)
+
+
+def test_a_library_appears_under_its_name_only_whole(tmp_path, monkeypatch):
+    """Several processes build in one checkout (six test workers, a
+    trainer beside its servers), and the build lock is a process's own:
+    one that found ``lib<name>.so`` while another's linker was writing it
+    loaded a part of it (``OSError: file too short``, one start in ten
+    with ten processes 0.6 s apart on a fresh checkout). The linker
+    writes a temporary beside the library, which is renamed over the
+    name; a failed build leaves nothing."""
+    import ctypes
+    import shutil
+    import subprocess
+
+    from paddle_tpu import native
+
+    shutil.copy(os.path.join(native._DIR, "tensor_store.cc"), tmp_path)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    so = str(tmp_path / "libtensor_store.so")
+    real, outputs = subprocess.run, []
+
+    def run(cmd, **kw):
+        outputs.append(cmd[cmd.index("-o") + 1])
+        done = real(cmd, **kw)
+        assert not os.path.exists(so)       # not before it is whole
+        return done
+
+    monkeypatch.setattr(native.subprocess, "run", run)
+    assert native._build("tensor_store") == so
+    assert len(outputs) == 1 and outputs[0] != so
+    assert sorted(os.listdir(tmp_path)) == ["libtensor_store.so",
+                                            "tensor_store.cc"]
+    assert ctypes.CDLL(so).ts_write_begin is not None
+    os.remove(so)
+    (tmp_path / "tensor_store.cc").write_text("this is not C++\n")
+    with pytest.raises(subprocess.CalledProcessError):
+        native._build("tensor_store")
+    assert os.listdir(tmp_path) == ["tensor_store.cc"]
