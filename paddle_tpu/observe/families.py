@@ -925,6 +925,17 @@ FLASH_BLOCK_PLANS = REGISTRY.counter(
     "axis: no transpose round the call)",
     labels=("kernel", "block", "single_pass", "layout"))
 
+FLASH_STEP_HEADS = REGISTRY.counter(
+    "paddle_flash_step_heads_total",
+    "Flash-attention Pallas calls lowered, by kernel name, whether a "
+    "single block covered the kernel's reduction axis ('1') and how many "
+    "(batch, head) rows ONE grid step takes, unrolled in the step so one "
+    "head's MXU passes run beside another's softmax. Counted at LOWERING "
+    "time beside paddle_flash_block_plans_total. The forward's count is "
+    "ops/attention.py _forward_heads: from the widths, the dtype, the "
+    "group and the plan, held to the VMEM a kernel is compiled under",
+    labels=("kernel", "single_pass", "heads"))
+
 DROPOUT_MASK_PLANS = REGISTRY.counter(
     "paddle_dropout_mask_plans_total",
     "Dropout keep masks lowered, by the op that draws (site 'dropout' or "

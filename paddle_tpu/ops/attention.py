@@ -31,6 +31,23 @@ at trace time:
     (no bias, or a key mask), so the per-step cost is paid once for them
     and one head's matmuls overlap the next head's softmax.
 
+Heads a grid step, in both plans, follow the call's static shapes and
+nothing else. A single-pass kernel takes the largest count up to
+``_HEADS_PER_STEP`` that divides H, fewer by the lane tiles its widest
+operand takes (``_heads_per_step``). The MULTI-PASS forward (keys past
+1,024, or a window narrower than the keys: the long serving prefills)
+takes the largest count up to ``_HEADS_PER_STEP`` that divides H — the
+group, for grouped heads, whose query heads then share ONE K/V block,
+fetched once for them — and whose VMEM account (``_forward_vmem``: the
+blocks twice, two heads' score and probability tiles, the carry) fits
+the 16 MiB a kernel here is compiled under (``_forward_heads``, the one
+rule ``_forward_pallas`` and tests/test_kernel_plans.py read). The heads
+are unrolled in the step with a carry each: a block's three MXU passes
+take 1.0 us at the v5e's peak and its float32 softmax 1.4 us on the
+vector unit, and one head alone runs them in turn (2.46 us a block at
+Xing's 8,192 keys, 1.78 at four heads: docs/KERNELS.md). A full
+[Sq, Sk] bias and the multi-pass backward kernels keep one head.
+
 The dK/dV kernel computes the scores TRANSPOSED ([bk, bq] = k q^T)
 whenever the bias is absent or a key mask: dV = p^T g and dK = ds^T q
 then contract over the minor axis of p^T/ds^T like any matmul, where the
@@ -114,7 +131,9 @@ import os as _os
 _LANE = 128        # sequences pad to whole lane tiles, blocks are made of them
 _MAX_BLOCK = 512   # longest block edge: a 512x512 float32 score tile is 1 MiB
                    # of VMEM, and each kernel holds a handful of them
-_HEADS_PER_STEP = 4  # most heads one grid step of a single-pass kernel takes
+_HEADS_PER_STEP = 4  # most heads one grid step takes
+# scoped VMEM a kernel here is compiled under (no call asks Mosaic for more)
+_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 _MASK = -1e9  # additive mask for padded key columns
@@ -406,10 +425,10 @@ class _HeadOut:
         self.parts = []
 
 
-def _carry(ref, h, lanes):
-    """Head ``h``'s running state: the lanes layout keeps one a head of
-    the step (a multi-pass plan of the heads layout takes one head)."""
-    return ref if lanes is None else ref.at[h]
+def _carry(ref, h):
+    """Head ``h``'s running state: a multi-pass plan keeps one a head of
+    the step, in both layouts."""
+    return ref.at[h]
 
 
 def _block_spec(lanes, H, heads, rows, width, seq_of):
@@ -533,7 +552,9 @@ def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE,
     the largest count that divides H, so that a group stays inside one
     batch entry and one [B,1,1,S] bias block serves it: the per-step
     overhead is paid once, and one head's matmuls overlap the next
-    head's softmax. Every other kernel keeps one head a step (in the
+    head's softmax. Every other kernel this rule serves (a forward with
+    a full bias, the multi-pass backward kernels; the multi-pass forward
+    has ``_forward_heads``) keeps one head a step (in the
     lanes layout, the heads of one lane tile, ``lanes.per``: two at D 64,
     each with a carry of its own; every count is a multiple of it): a
     [heads, bq, bk] float32 bias or ds block would not fit VMEM.
@@ -550,12 +571,74 @@ def _heads_per_step(H, single_pass, bias, want_db=False, width=_LANE,
     return max(g for g in range(least, most + 1, least) if H % g == 0)
 
 
-def _note_plan(kernel, bq, bk, single_pass, visited=None, lanes=None):
+def _forward_vmem(heads, bq, bk, single_pass, D, Dv, itemsize, out_itemsize,
+                  lanes=None, Dr=0, one_kv=False):
+    """Bytes of VMEM one grid step of the forward holds at ``heads`` heads,
+    by this module's account: every operand and output block twice (the
+    pipeline's two buffers) at its dtype's bytes and its width padded to
+    whole lane tiles; the float32 score and probability tiles [bq, bk] of
+    the TWO heads the unrolled step has in flight (one head's matmuls
+    beside another's softmax); a head's rescaled accumulator and its
+    ``p v`` product, float32 [bq, Dv] each; the carry of a multi-pass plan
+    (accumulator, row maximum and denominator a head, the two columns a
+    lane tile wide each). No bias block: a key mask is a row, and a full
+    [Sq, Sk] bias keeps the least count whatever fits. ``Dr`` is the width of a shared key part (its blocks a lane tile
+    wide), ``one_kv`` a grouped call whose heads read ONE K/V block. An
+    upper bound on what Mosaic allotted at every shape and count compiled
+    for a described v5e (docs/KERNELS.md has the table), which is what
+    the count rests on; tests/test_chip_bringup*.py compile the cells'."""
+    tile = lambda w: _ceil_to(int(w), _LANE)  # noqa: E731
+    if lanes is None:
+        kv_heads = 1 if one_kv else heads
+        q_w, k_w, v_w, o_w = (heads * tile(D), kv_heads * tile(D),
+                              kv_heads * tile(Dv), heads * tile(Dv))
+    else:       # [1, rows, heads * D]: a block's lanes are packed
+        q_w, k_w, v_w, o_w = (tile(heads * D), tile(heads * D),
+                              tile(heads * Dv), tile(heads * Dv))
+    r_w = tile(Dr) if Dr else 0
+    blocks = itemsize * (bq * (q_w + heads * r_w) + bk * (k_w + v_w + r_w)) \
+        + out_itemsize * bq * o_w + 4 * heads * 8 * bq
+    tiles = 2 * 4 * bq * bk * min(heads, 2) + 2 * 4 * heads * bq * tile(Dv)
+    carry = 0 if single_pass else 4 * heads * bq * (tile(Dv) + 2 * _LANE)
+    return 2 * blocks + tiles + carry
+
+
+def _forward_heads(H, group, bq, bk, single_pass, bias, D, Dv, itemsize,
+                   out_itemsize, lanes=None, Dr=0):
+    """How many query heads one grid step of the FORWARD takes: the one
+    rule, read by ``_forward_pallas`` and by tests/test_kernel_plans.py.
+
+    A single-pass plan keeps ``_heads_per_step``'s count (one head for a
+    grouped call). A multi-pass plan (keys past 1,024, or a window
+    narrower than the keys) takes the largest count, up to
+    ``_HEADS_PER_STEP``, that divides ``H`` — ``group`` for a grouped
+    call, whose heads then share ONE K/V block fetched once for them — is
+    a multiple of the heads a lane tile holds in the lanes layout, and
+    whose ``_forward_vmem`` account fits ``_VMEM_LIMIT_BYTES``, the scoped
+    VMEM the call is compiled under: the heads are unrolled in the step,
+    so one head's MXU passes are issued beside another's softmax. A full
+    [Sq, Sk] bias keeps the least count, as everywhere. From the call's
+    static shapes alone."""
+    least = 1 if lanes is None else lanes.per
+    if single_pass:
+        return 1 if group > 1 else _heads_per_step(
+            H, True, bias, width=D + Dv if Dr else max(D, Dv), lanes=lanes)
+    if bias is not None and bias.shape[2] > 1:
+        return least
+    fits = [g for g in range(least, _HEADS_PER_STEP + 1, least)
+            if (group if group > 1 else H) % g == 0
+            and _forward_vmem(g, bq, bk, False, D, Dv, itemsize, out_itemsize,
+                              lanes=lanes, Dr=Dr, one_kv=group > 1)
+            <= _VMEM_LIMIT_BYTES]
+    return max(fits, default=least)
+
+
+def _note_plan(kernel, bq, bk, single_pass, heads, visited=None, lanes=None):
     """``visited`` = (blocks a windowed forward computes, blocks in the
     square): it rides the block label, ``"512x512 76of256"``. ``layout``
     says how the operands came: ``heads`` [B,H,S,D] or ``lanes``
     [B,S,H*D]."""
-    from ..observe.families import FLASH_BLOCK_PLANS
+    from ..observe.families import FLASH_BLOCK_PLANS, FLASH_STEP_HEADS
 
     block = "%dx%d" % (bq, bk)
     if visited is not None:
@@ -564,6 +647,9 @@ def _note_plan(kernel, bq, bk, single_pass, visited=None, lanes=None):
                              single_pass="1" if single_pass else "0",
                              layout="heads" if lanes is None
                              else "lanes").inc()
+    FLASH_STEP_HEADS.labels(kernel=kernel,
+                            single_pass="1" if single_pass else "0",
+                            heads=str(heads)).inc()
 
 
 def _band_blocks(nq, nk, bq, bk, window):
@@ -575,21 +661,35 @@ def _band_blocks(nq, nk, bq, bk, window):
 
 
 # --------------------------------------------------------------- causal
-def _causal_mask(s, iq, ik, bq, bk, transposed=False, window=None):
-    """Lower-triangular mask for the (iq, ik) block: a score survives iff
-    its global query position iq*bq+r >= its key position ik*bk+c — and,
-    under a ``window``, iff the key is also among the query's last
-    ``window`` positions (qpos - kpos < window).
-    Queries run along the rows of ``s``, or along its columns when the
-    kernel computed the scores transposed."""
-    qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                              1 if transposed else 0)
-    kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
-                                              0 if transposed else 1)
-    keep = qpos >= kpos
+def _causal_keep(shape, iq, ik, bq, bk, transposed=False, window=None):
+    """bool ``shape``: which scores of the (iq, ik) block survive the
+    lower-triangular mask — global query position iq*bq+r >= key position
+    ik*bk+c — and, under a ``window``, whose key is also among the
+    query's last ``window`` positions (qpos - kpos < window). Queries run
+    along the rows, or along the columns when the kernel computes the
+    scores transposed. ``jax.lax`` primitives, as ``_Lanes.own``: a
+    multi-pass step applies one mask to every head it holds."""
+    lax = jax.lax
+    qpos = lax.add(lax.broadcasted_iota(jnp.int32, shape,
+                                        1 if transposed else 0), iq * bq)
+    kpos = lax.add(lax.broadcasted_iota(jnp.int32, shape,
+                                        0 if transposed else 1), ik * bk)
+    keep = lax.ge(qpos, kpos)
     if window is not None:
-        keep = jnp.logical_and(keep, qpos - kpos < window)
-    return jnp.where(keep, s, _MASK)
+        keep = lax.bitwise_and(
+            keep, lax.lt(lax.sub(qpos, kpos), np.int32(window)))
+    return keep
+
+
+def _masked(s, keep):
+    """``s`` with ``_MASK`` where ``keep`` is false."""
+    return jax.lax.select(keep, s, jax.lax.full_like(s, _MASK))
+
+
+def _causal_mask(s, iq, ik, bq, bk, transposed=False, window=None):
+    """``s`` of the (iq, ik) block under ``_causal_keep``'s mask."""
+    return _masked(s, _causal_keep(s.shape, iq, ik, bq, bk, transposed,
+                                   window))
 
 
 def _for_block(body, causal, iq, ik, bq, bk, window=None):
@@ -673,7 +773,10 @@ def _dot_f32(a, b, ca, cb):
 
 
 def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
-                window=None, lanes=None, shared=None, sk=None):
+                window=None, lanes=None, shared=None, sk=None, one_kv=False):
+    # ``one_kv``: the step's heads are query heads of ONE group and the
+    # K/V blocks hold the one head they all read (grouped heads)
+    kv = (lambda h: 0) if one_kv else (lambda h: h)
     if shared is None:
         q_ref, k_ref, v_ref = refs[:3]
         n_in, lk, lv, lo = 3, lanes, lanes, lanes
@@ -688,21 +791,30 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
-    def scores(h, masked):
+    def scores(h, keep):
         # dots run at the INPUT dtype (bf16 hits the MXU at full rate)
-        # with f32 accumulation; only the softmax state is explicitly f32
+        # with f32 accumulation; only the softmax state is explicitly f32.
+        # ``keep`` is the causal mask of a block the diagonal (or the
+        # band's edge) crosses, None elsewhere. From here down to the
+        # carry the per-head code is ``jax.lax`` primitives, as
+        # ``_Lanes.own`` says why: a multi-pass step traces it once a head
+        # and branch, in every layer of every prefill program.
+        lax = jax.lax
         s = _dot_f32(_head(q_ref, h, lanes, own=True),
-                     _head(k_ref, h, lk), 1, 1)           # [bq, bk]
+                     _head(k_ref, kv(h), lk), 1, 1)       # [bq, bk]
         if shared is not None:
-            s = s + _dot_f32(_head(qr_ref, h, lr), kr_ref[0], 1, 1)
-        s = s * scale
+            s = lax.add(s, _dot_f32(_head(qr_ref, h, lr), kr_ref[0], 1, 1))
+        s = lax.mul(s, np.float32(scale))
         if b_ref is not None:
-            s = s + _bias_block(b_ref, h)
-        return _causal_mask(s, iq, ik, bq, bk, window=window) \
-            if masked else s
+            s = lax.add(s, _bias_block(b_ref, h))
+        return s if keep is None else _masked(s, keep)
+
+    def mask(masked):
+        return _causal_keep((bq, bk), iq, ik, bq, bk, window=window) \
+            if masked else None
 
     def values(h, masked):
-        v = _head(v_ref, h, lv)                           # [bk, D]
+        v = _head(v_ref, kv(h), lv)                       # [bk, D]
         if sk is not None and masked:
             # a ragged last key block: the rows past the last key hold
             # whatever the buffer held, and 0 x that is not 0
@@ -715,9 +827,10 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         # one block holds every key of the row: one softmax and one
         # write, no running max/denominator/accumulator to carry
         out = _HeadOut(o_ref, lo)
+        keep = mask(causal)
         for h in range(heads):
             v = values(h, causal)
-            s = scores(h, causal)
+            s = scores(h, keep)
             m = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
             p = jnp.exp(s - m)                            # [bq, bk] f32
             l = jnp.sum(p, axis=-1, keepdims=True)
@@ -735,31 +848,45 @@ def _fwd_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _compute(masked):
+        # the step's heads unrolled: nothing of one head waits for
+        # another, so the scheduler issues one head's MXU passes beside
+        # another head's softmax on the vector unit
+        lax = jax.lax
+        keep = mask(masked)
         for h in range(heads):
-            m_h, l_h, acc_h = (_carry(r, h, lanes)
+            m_h, l_h, acc_h = (_carry(r, h)
                                for r in (m_ref, l_ref, acc_ref))
             v = values(h, masked)
-            s = scores(h, masked)
+            s = scores(h, keep)
             m_prev = m_h[...]                         # [bq, 1]
             l_prev = l_h[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                    # [bq, bk] f32
-            l_h[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            m_new = lax.max(m_prev, _row_stat(lax.reduce_max, s))
+            alpha = lax.exp(lax.sub(m_prev, m_new))
+            p = lax.exp(lax.sub(s, m_new))            # [bq, bk] f32
+            l_h[...] = lax.add(lax.mul(alpha, l_prev),
+                               _row_stat(lax.reduce_sum, p))
             m_h[...] = m_new
-            acc_h[...] = acc_h[...] * alpha \
-                + _dot_f32(p.astype(v.dtype), v, 1, 0)
+            acc_h[...] = lax.add(
+                lax.mul(acc_h[...], alpha),
+                _dot_f32(lax.convert_element_type(p, v.dtype), v, 1, 0))
 
     _for_block(_compute, causal, iq, ik, bq, bk, window)
 
     @pl.when(ik == nk - 1)
     def _emit():
+        lax = jax.lax
         out = _HeadOut(o_ref, lo)
         for h in range(heads):
-            l = _carry(l_ref, h, lanes)[...]
-            out.put(h, _carry(acc_ref, h, lanes)[...] / l)
-            lse = _carry(m_ref, h, lanes)[...] + jnp.log(l)   # [bq, 1]
+            l = _carry(l_ref, h)[...]
+            out.put(h, lax.div(_carry(acc_ref, h)[...], l))
+            lse = lax.add(_carry(m_ref, h)[...], lax.log(l))  # [bq, 1]
             lse_ref[h] = _to_row(lse) if rows else lse
+
+
+def _row_stat(reduce, x):
+    """``reduce`` (``lax.reduce_max`` / ``lax.reduce_sum``) of [r, c] ``x``
+    along its rows, kept as a [r, 1] column."""
+    return jax.lax.expand_dims(reduce(x, (1,)), (1,))
 
 
 def _packed_dims(q, k, n_head):
@@ -798,6 +925,9 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
     keeps every key past the last from every real query and the kernel
     zeroes the values' rows there."""
     lanes, out_dtype = None, q.dtype
+    # what an operand block weighs on the chip (interpret mode rounds
+    # nothing, and plans as the chip does)
+    itemsize = jnp.dtype(mxu_dtype or q.dtype).itemsize
     if mxu_dtype is not None and not _use_interpret():
         q, k, v = (t.astype(mxu_dtype) for t in (q, k, v))
         if shared is not None:
@@ -831,17 +961,18 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
             window = None      # the band is the whole triangle
     Sp, Skp, bq, bk = _forward_plan(S, Sk, D, q.dtype, causal, window)
     nq, nk = Sp // bq, Skp // bk
-    _note_plan(name, bq, bk, nk == 1,
-               None if window is None
-               else (_band_blocks(nq, nk, bq, bk, window), nq * nk), lanes)
     # the causal mask is the padded keys' mask where nothing is padded
     ragged = shared is not None and causal
     if bias is not None or not ragged:
         bias = _pad_bias(bias, S, Sp, Sk, Skp)
-    heads = 1 if group > 1 else _heads_per_step(
-        H, nk == 1, bias, width=D + Dv if shared is not None else max(D, Dv),
-        lanes=lanes)
+    heads = _forward_heads(
+        H, group, bq, bk, nk == 1, bias, D, Dv, itemsize,
+        jnp.dtype(out_dtype).itemsize, lanes=lanes,
+        Dr=shared[1].shape[2] if shared is not None else 0)
     rows = _stat_rows(bq)
+    _note_plan(name, bq, bk, nk == 1, heads,
+               None if window is None
+               else (_band_blocks(nq, nk, bq, bk, window), nq * nk), lanes)
     if not ragged:
         q = _pad_axis(q, seq, Sp)
         k = _pad_axis(k, seq, Skp)
@@ -863,7 +994,10 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
         return ik
 
     def kv_map(bh, iq, ik):
-        return (bh // group if group > 1 else bh, kv_seq(bh, iq, ik), 0)
+        # a grouped call's step holds ``heads`` query heads of one group
+        # and the ONE K/V head they read; else a K/V head a query head
+        return (bh * heads // group if group > 1 else bh,
+                kv_seq(bh, iq, ik), 0)
 
     q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, iq, ik: iq)
     if shared is not None:
@@ -888,8 +1022,9 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
                   _Lanes(Dv))
     else:
         if lanes is None:
-            kv_specs = [pl.BlockSpec((heads, bk, D), kv_map),
-                        pl.BlockSpec((heads, bk, Dv), kv_map)]
+            kv_heads = 1 if group > 1 else heads
+            kv_specs = [pl.BlockSpec((kv_heads, bk, D), kv_map),
+                        pl.BlockSpec((kv_heads, bk, Dv), kv_map)]
         else:
             kv_specs = [_block_spec(lanes, H, heads, bk, D, kv_seq)] * 2
         in_specs = [q_spec] + kv_specs
@@ -903,11 +1038,12 @@ def _forward_pallas(q, k, v, bias, scale, causal=False, name=KERNEL_FWD,
                              bq=bq, bk=bk, heads=heads,
                              has_bias=bias is not None, rows=rows,
                              window=window, lanes=lanes, shared=shared,
-                             sk=Sk if ragged and Sk % bk else None)
+                             sk=Sk if ragged and Sk % bk else None,
+                             one_kv=group > 1)
     # a multi-pass plan carries the output, the row maximum and the
     # denominator in VMEM: one of each a head of the step
     carry = lambda *shape: pltpu.VMEM(  # noqa: E731
-        shape if lanes is None else (heads,) + shape, jnp.float32)
+        (heads,) + shape, jnp.float32)
     out, lse = _checked_pallas_call(
         kern,
         name=name,
@@ -1000,8 +1136,8 @@ def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
     def _compute(masked):
         for h in range(heads):
             dk, dv = grads(h, masked)
-            _carry(dk_acc, h, lanes)[...] += dk
-            _carry(dv_acc, h, lanes)[...] += dv
+            _carry(dk_acc, h)[...] += dk
+            _carry(dv_acc, h)[...] += dv
 
     _for_block(_compute, causal, iq, ik, bq, bk)
 
@@ -1009,8 +1145,8 @@ def _dkv_kernel(*refs, scale, nq, causal, bq, bk, heads, has_bias, want_db,
     def _emit():
         dk_out, dv_out = _HeadOut(dk_ref, lanes), _HeadOut(dv_ref, lanes)
         for h in range(heads):
-            dk_out.put(h, _carry(dk_acc, h, lanes)[...])
-            dv_out.put(h, _carry(dv_acc, h, lanes)[...])
+            dk_out.put(h, _carry(dk_acc, h)[...])
+            dv_out.put(h, _carry(dv_acc, h)[...])
 
 
 def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
@@ -1054,7 +1190,7 @@ def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
 
     def _compute(masked):
         for h in range(heads):
-            _carry(dq_acc, h, lanes)[...] += grad(h, masked)
+            _carry(dq_acc, h)[...] += grad(h, masked)
 
     _for_block(_compute, causal, iq, ik, bq, bk)
 
@@ -1062,7 +1198,7 @@ def _dq_kernel(*refs, scale, nk, causal, bq, bk, heads, has_bias, rows,
     def _emit():
         out = _HeadOut(dq_ref, lanes)
         for h in range(heads):
-            out.put(h, _carry(dq_acc, h, lanes)[...])
+            out.put(h, _carry(dq_acc, h)[...])
 
 
 def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
@@ -1109,7 +1245,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     # dK/dV: one key block per (bh, ik), sweep query blocks innermost
     nq, nk = Sp // bq, Skp // bk
     heads = _heads_per_step(H, nq == 1, bias, want_db, lanes=lanes)
-    _note_plan(KERNEL_BWD_DKV, bq, bk, nq == 1, lanes=lanes)
+    _note_plan(KERNEL_BWD_DKV, bq, bk, nq == 1, heads, lanes=lanes)
     # transposed scores take the statistics as [1, bq] rows (one block
     # over all of a short S is legal too) and a bias that is a key mask;
     # a [Sq, Sk] bias, and the ds tile a trainable one wants back, keep
@@ -1134,8 +1270,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
                  jax.ShapeDtypeStruct(vf.shape, v.dtype)]
     # a multi-pass plan accumulates in VMEM: one accumulator a head
     carry = lambda rows_: pltpu.VMEM(  # noqa: E731
-        (rows_, D) if lanes is None else (heads, rows_, lanes.W),
-        jnp.float32)
+        (heads, rows_, D if lanes is None else lanes.W), jnp.float32)
     if want_db:
         # per-block score grads, written once per grid cell (O(S^2) HBM —
         # only materialized when a trainable bias asks for it)
@@ -1169,7 +1304,7 @@ def _backward_pallas(q, k, v, bias, o, lse, g, scale, want_db=False,
     _, _, bq, bk = plan(KERNEL_BWD_DQ)
     nq, nk = Sp // bq, Skp // bk
     heads = _heads_per_step(H, nk == 1, bias, lanes=lanes)
-    _note_plan(KERNEL_BWD_DQ, bq, bk, nk == 1, lanes=lanes)
+    _note_plan(KERNEL_BWD_DQ, bq, bk, nk == 1, heads, lanes=lanes)
     q_spec = _block_spec(lanes, H, heads, bq, D, lambda bh, iq, ik: iq)
     k_spec = _block_spec(lanes, H, heads, bk, D, lambda bh, iq, ik: ik)
     in_specs = [q_spec, k_spec, k_spec]

@@ -912,3 +912,72 @@ def test_shared_key_part_refuses_what_it_does_not_take(what):
             get_op("fused_attention").grad_lowering(
                 LowerContext(), ins, {"scale": 0.1, "causal": True,
                                       "n_head": H})
+
+
+# --------------------- several heads a multi-pass grid step (PR 57)
+def _step_heads(kernel):
+    """{heads label: multi-pass lowerings of ``kernel`` counted so far}."""
+    from paddle_tpu.observe import REGISTRY
+
+    return {s["labels"]["heads"]: s["value"] for s in REGISTRY.snapshot()[
+        "metrics"]["paddle_flash_step_heads_total"]["samples"]
+        if s["labels"]["kernel"] == kernel
+        and s["labels"]["single_pass"] == "0" and s["value"]}
+
+
+def _multi_pass_call(form):
+    """``(call, kernel name, heads a step the rule gives)`` of one
+    multi-pass forward a form; ``call()`` returns (out, lse)."""
+    from paddle_tpu.ops import attention as A
+
+    rs = np.random.RandomState(57)
+    draw = lambda *shape: jnp.asarray(  # noqa: E731
+        rs.randn(*shape).astype("float32"))
+    if form == "latent_ragged_causal":
+        # 1,300 keys: three 512-blocks, the last 276 keys long
+        H = 4
+        q, q_r, kv, k_r = _latent_operands(1300, H, 128, 64, 128, 57)
+        return (lambda: A._forward_pallas(
+            q, kv, kv, None, 0.07, causal=True, mxu_dtype="bfloat16",
+            n_head=H, shared=(q_r, k_r))), A.KERNEL_FWD, 4
+    if form == "grouped_heads_under_a_window":
+        # twelve query heads over two key/value heads (group 6: three a
+        # step, two steps a K/V head), a window that is no whole block
+        q, k, v = draw(1, 12, 1200, 64), draw(1, 2, 1200, 64), \
+            draw(1, 2, 1200, 64)
+        return (lambda: A._forward_pallas(
+            q, k, v, None, 0.125, causal=True, window=300,
+            name=A.KERNEL_FWD_WIN)), A.KERNEL_FWD_WIN, 3
+    q, k, v = (draw(2, 4, 1100, 64) for _ in range(3))
+    return (lambda: A._forward_pallas(q, k, v, None, 0.125, causal=True)), \
+        A.KERNEL_FWD, 4
+
+
+@pytest.mark.parametrize("form", ["latent_ragged_causal",
+                                  "grouped_heads_under_a_window",
+                                  "ungrouped_causal"])
+def test_multi_pass_heads_a_step_equal_one_head_a_step(form, monkeypatch):
+    """The multi-pass forward at the count ``_forward_heads`` gives
+    against the same call held to one head a step (the test puts its own
+    function in the rule's place): a head's arithmetic and its order of
+    key blocks do not depend on what else the step holds, so output and
+    logsumexp are equal to the last bit."""
+    from paddle_tpu.ops import attention as A
+
+    call, kernel, want = _multi_pass_call(form)
+    before = _step_heads(kernel)
+    out, lse = call()
+    counted = {h for h, n in _step_heads(kernel).items()
+               if n > before.get(h, 0)}
+    assert counted == {str(want)}
+    rule = A._forward_heads
+    monkeypatch.setattr(
+        A, "_forward_heads", lambda H, group, bq, bk, single_pass, *a, **kw:
+        rule(H, group, bq, bk, single_pass, *a, **kw) if single_pass else 1)
+    before = _step_heads(kernel)
+    out_1, lse_1 = call()
+    assert {h for h, n in _step_heads(kernel).items()
+            if n > before.get(h, 0)} == {"1"}
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(lse).all())
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_1))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_1))

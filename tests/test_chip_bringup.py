@@ -269,6 +269,37 @@ def _lower_step(main, feeds, fetch, dev, rng=False):
     return lowered, mut_state
 
 
+def _step_heads():
+    """``{(kernel, single_pass, heads a step): flash lowerings so far}``
+    off ``paddle_flash_step_heads_total``."""
+    from paddle_tpu.observe import REGISTRY
+
+    return {(s["labels"]["kernel"], s["labels"]["single_pass"],
+             int(s["labels"]["heads"])): s["value"]
+            for s in REGISTRY.snapshot()["metrics"][
+                "paddle_flash_step_heads_total"]["samples"]}
+
+
+def _new_step_heads(before):
+    """What ``_step_heads`` counted since ``before``."""
+    return {key: n - before.get(key, 0) for key, n in _step_heads().items()
+            if n > before.get(key, 0)}
+
+
+def _assert_multi_pass_heads(before, want):
+    """The lowering since ``before`` counted ``want`` = {kernel: calls}
+    multi-pass forwards, every one at more than one head a grid step: the
+    compile that follows holds the count to the described chip's VMEM."""
+    new = {(kernel, heads): n
+           for (kernel, single, heads), n in _new_step_heads(before).items()
+           if single == "0"}
+    assert all(heads > 1 for _kernel, heads in new), new
+    got = {}
+    for (kernel, _heads), n in new.items():
+        got[kernel] = got.get(kernel, 0) + n
+    assert got == want, new
+
+
 def _work_list_sources(text, kernel):
     """What computed the grid bound and the two tables (operands 0, 2 and
     3, before them ``pos``) of every ``kernel`` call in a compiled step's
@@ -433,8 +464,13 @@ def test_trinity_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     with fluid.program_guard(main, startup):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
+    # 8,192: four banded layers and the full one, several query heads of
+    # a group a step over ONE K/V block; 512 is a single pass
+    _assert_multi_pass_heads(stepped, {A.KERNEL_FWD_WIN: 4, A.KERNEL_FWD: 1}
+                             if P == 8192 else {})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
@@ -547,9 +583,11 @@ def test_pangu_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
                                      single_pass="0" if P == 3328 else "1",
                                      layout="heads")
     before = plan.value, heads.value
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     assert (plan.value, heads.value) == (before[0] + 5, before[1])
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 5} if P == 3328 else {})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 5

@@ -19,8 +19,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from test_chip_bringup import (BF16, F32, ROOT, _cache_sized,  # noqa: F401
-                               _compile, _lower_step, _slab_relaid,
+from test_chip_bringup import (BF16, F32, ROOT,  # noqa: F401
+                               _assert_multi_pass_heads, _cache_sized,
+                               _compile, _lower_step, _new_step_heads,
+                               _slab_relaid, _step_heads,
                                _work_list_sources, compiled_kernels, v5e,
                                v5e_topology)
 
@@ -92,7 +94,7 @@ def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
     plans = {site: DROPOUT_MASK_PLANS.labels(site=site, bits="rbg_u32")
              for site in ("dropout", "fused_attention")}
     before = {site: c.value for site, c in plans.items()}
-    flash_before = _flash_plans_by_layout()
+    flash_before, heads_before = _flash_plans_by_layout(), _step_heads()
     feeds = {"src_ids": (batch, seq), "sent_ids": (batch, seq),
              "input_mask": ((batch, seq), jnp.float32),
              "mask_pos": (batch, masks), "mask_label": (batch, masks),
@@ -107,6 +109,12 @@ def test_bert_train_step_draws_each_mask_once_for_v5e(cell, v5e,
     flash_now = _flash_plans_by_layout()
     assert {lay: flash_now.get(lay, 0) - flash_before.get(lay, 0)
             for lay in ("lanes", "heads")} == {"lanes": n_calls, "heads": 0}
+    # PR 57 left the single-pass count alone: four heads a step in each
+    # of the S 512 step's four kernels a layer, as on its parent
+    assert _new_step_heads(heads_before) == (
+        {(kern, "1", 4): n_layer for kern in (
+            "flash_fwd", "flash_refwd", "flash_bwd_dkv", "flash_bwd_dq")}
+        if n_calls else {})
     drawn = _mask_sized(text, "rng-bit-generator")
     assert len(drawn) == 3 * n_layer + 1 == 37
     assert {int(np.prod(d)) for d in drawn} == {batch * seq * 768}
@@ -262,8 +270,10 @@ def test_xing_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     with fluid.program_guard(main, startup):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 5} if P == 8192 else {})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert len(set(re.findall(r"%%(%s[.\d]*) = " % mhc.KERNEL_PRE,
@@ -378,9 +388,13 @@ def test_nemotron_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
     before, gmm_before = _ssm_plans(), _gmm_plans()
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     after = _ssm_plans()
+    # sixteen query heads of 128 a key/value head: 2,048 keys are four
+    # blocks (128 are under the threshold: composed)
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 1} if P == 2048 else {})
     # 2,048 tokens x 22 choices are over the threshold, 128 x 22 under
     bound = compact_rows(cfg["expert_top_k"] * P, cfg["n_expert"],
                          cfg["n_expert_local"])
@@ -474,9 +488,11 @@ def test_lfm2_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     plan = FLASH_BLOCK_PLANS.labels(kernel="flash_fwd", block="512x512",
                                     single_pass="0", layout="heads")
     before = plan.value
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     assert plan.value == before + 1
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 1})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("flash_fwd") >= 1
@@ -562,8 +578,10 @@ def test_longcat_prefill_compiles_for_v5e(P, v5e, compiled_kernels):
     assert cap == (None if P == 128 else 896)
     assert (gpt.COMPACT_CALLS_VAR in main.global_block().vars) \
         == (cap is not None)
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 8} if P == 3328 else {})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("flash_fwd") >= 8
@@ -835,10 +853,13 @@ def test_qwen3next_prefill_compiles_for_v5e(v5e, compiled_kernels):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
     before = _power_plans(DELTA_PLANS)
+    stepped = _step_heads()
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     assert _new_plans(before, DELTA_PLANS) == {
         ("delta_scan", "pallas", str(delta.scan_chunk(P))): 9}
+    # eight query heads of 256 a key/value head, four key blocks
+    _assert_multi_pass_heads(stepped, {"flash_fwd": 3})
     compiled = lowered.compile()
     text = compiled.as_text()
     assert len(set(re.findall(r"%%(%s[.\d]*) = " % delta.KERNEL_SCAN,

@@ -322,17 +322,24 @@ def _check_ssm_scan(block, op, batch, must):
 def _check_fused_attention(block, op, batch, must):
     """The forward's plan (banded and latent forms included) and, where
     the program holds the grad op, both backward kernels'."""
+    import jax.numpy as jnp
+
     from paddle_tpu.ops import attention as A
 
     q, dtype = _operand(block, op, "Q", batch)
     k, _ = _operand(block, op, "K", batch)
     v, _ = _operand(block, op, "V", batch)
     attrs = op.attrs
-    lanes = None
+    lanes, Dr = None, 0
     if len(q) == 3:                                  # [B, S, H*D]
         H = int(attrs["n_head"])
         S, Sk, D = q[1], k[1], q[2] // H
         Dv, Hkv = D, H
+        if op.inputs.get("KR"):
+            # the shared key part: k IS v, a head's keys beside its values
+            Dr = _operand(block, op, "KR", batch)[0][2]
+            Dv = k[2] // H - D
+            assert k == v and Dv > 0
         assert A._lanes_ok(H, D)
         lanes = A._Lanes(D)
     else:
@@ -364,12 +371,20 @@ def _check_fused_attention(block, op, batch, must):
         assert bq * bk <= A._MAX_BLOCK * A._MAX_BLOCK
         if window is not None and kern == A.KERNEL_FWD:
             assert bk <= A._pad_len(window, A._LANE)
+    # heads a grid step: the forward's one rule at the call's own shapes,
+    # and what such a step holds against the VMEM it is compiled under
     Sp, Skp, bq, bk = plans[A.KERNEL_FWD]
-    heads = 1 if H != Hkv else A._heads_per_step(
-        H, Skp == bk, None, width=max(D, Dv), lanes=lanes)
-    assert H % heads == 0
-    assert heads * -(-max(D, Dv) // A._LANE) <= max(
-        A._HEADS_PER_STEP, 1 if lanes is None else lanes.per)
+    group, single = H // Hkv, Skp == bk
+    itemsize = jnp.dtype(attrs.get("mxu_dtype") or dtype).itemsize
+    shape = dict(lanes=lanes, Dr=Dr)
+    heads = A._forward_heads(H, group, bq, bk, single, None, D, Dv,
+                             itemsize, dtype.itemsize, **shape)
+    assert (group if group > 1 else H) % heads == 0
+    assert 1 <= heads <= A._HEADS_PER_STEP
+    assert lanes is None or heads % lanes.per == 0
+    held = A._forward_vmem(heads, bq, bk, single, D, Dv, itemsize,
+                           dtype.itemsize, one_kv=group > 1, **shape)
+    assert held <= A._VMEM_LIMIT_BYTES, (heads, held)
     return plans
 
 

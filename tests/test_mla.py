@@ -524,9 +524,17 @@ def test_mla_prefill_sweep_rehearses_on_the_cpu(tmp_path):
         "lanes_bf16"]
     assert all("device_ms" not in r for r in rows)
     assert rows[3]["max_abs_diff_vs_block_heads"] < 2e-5
-    assert [(r["check"], r["finite"]) for r in rows[5:]] == [
+    # the kernel alone at the rule's count and at 1, 2 and 4 heads a
+    # multi-pass step (1,300 keys), every one equal to the first
+    stepped = [r for r in rows if "equal_to_the_rule" in r]
+    assert [(r.get("rule_heads"), r.get("heads")) for r in stepped] == [
+        (4, None), (None, 1), (None, 2), (None, 4)]
+    assert all(r["equal_to_the_rule"] for r in stepped)
+    checks = [r for r in rows if "check" in r]
+    assert rows == rows[:5] + stepped + checks
+    assert [(r["check"], r["finite"]) for r in checks] == [
         (136, True), (200, True)]
-    assert all(r["max_abs_diff"] < 2e-5 for r in rows[5:])
+    assert all(r["max_abs_diff"] < 2e-5 for r in checks)
 
 
 def test_engine_counts_the_blocks_its_latent_steps_walk():
@@ -744,16 +752,67 @@ def test_a_causal_length_with_no_whole_block_pads_to_whole_blocks(dk, dv):
     assert (whole.value - before[0], one.value - before[1]) == (1, 1)
 
 
-def test_heads_a_step_follow_the_operands_width():
-    from paddle_tpu.ops.attention import _heads_per_step
-
-    # four at one lane tile of width, as every older caller has them
-    assert _heads_per_step(128, True, None) == 4
-    assert _heads_per_step(12, True, None, width=64) == 4
+HEADS_A_STEP = {
+    # case: (H, group, single_pass, D, Dv, itemsize, extra, heads a step)
+    # a single-pass plan: four at one lane tile of width, as every older
+    # caller has them
+    "single_pass_one_tile": (128, 1, True, 128, 128, 2, {}, 4),
+    "single_pass_d64": (12, 1, True, 64, 64, 2, {}, 4),
     # q/k 192 wide take 256 lanes in VMEM: two
-    assert _heads_per_step(128, True, None, width=192) == 2
-    assert _heads_per_step(128, True, None, width=576) == 1
-    assert _heads_per_step(128, False, None, width=64) == 1
+    "single_pass_192_wide": (128, 1, True, 192, 128, 2, {}, 2),
+    "single_pass_576_wide": (128, 1, True, 576, 512, 2, {}, 1),
+    # a multi-pass plan took one head until PR 57: now what its VMEM
+    # account lets in, at most four
+    "multi_pass_d64": (128, 1, False, 64, 64, 2, {}, 4),
+    # the latent prefill past 1,024 keys (Pangu, Xing, LongCat): bf16
+    # operands, the shared key part a lane tile, a float32 context
+    "multi_pass_latent": (128, 1, False, 128, 128, 2, {"Dr": 64}, 4),
+    # float32 operands at a head of 64: 17.1 MiB at four
+    "multi_pass_f32_d64": (16, 1, False, 64, 64, 4, {}, 2),
+    # grouped float32 heads share ONE K/V block: a divisor of the group
+    "multi_pass_group_6": (48, 6, False, 128, 128, 4, {}, 3),
+    "multi_pass_group_4_d64": (32, 4, False, 64, 64, 4, {}, 4),
+    "multi_pass_group_16": (32, 16, False, 128, 128, 4, {}, 4),
+    # a head of two lane tiles
+    "multi_pass_group_8_d256": (16, 8, False, 256, 256, 4, {}, 2),
+    # a grouped single-pass call keeps its one head
+    "single_pass_grouped": (32, 4, True, 64, 64, 4, {}, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HEADS_A_STEP))
+def test_heads_a_step_follow_the_operands_width(case):
+    """``_forward_heads``, the forward's one rule, at 512 x 512 blocks
+    with a float32 output; the count fits the VMEM a call is compiled
+    under by the rule's own account, and the next divisor does not (or
+    is past the most a step takes)."""
+    from paddle_tpu.ops import attention as A
+
+    H, group, single, D, Dv, itemsize, extra, want = HEADS_A_STEP[case]
+    got = A._forward_heads(H, group, 512, 512, single, None, D, Dv,
+                           itemsize, 4, **extra)
+    assert got == want and (group if group > 1 else H) % got == 0
+    held = lambda g: A._forward_vmem(  # noqa: E731
+        g, 512, 512, single, D, Dv, itemsize, 4, one_kv=group > 1, **extra)
+    assert held(got) <= A._VMEM_LIMIT_BYTES
+    if not single and got < A._HEADS_PER_STEP:
+        more = min(g for g in range(got + 1, 2 * A._HEADS_PER_STEP)
+                   if (group if group > 1 else H) % g == 0)
+        assert more > A._HEADS_PER_STEP or held(more) > A._VMEM_LIMIT_BYTES
+
+
+def test_a_full_bias_and_the_backward_keep_one_head_a_multi_pass_step():
+    from paddle_tpu.ops import attention as A
+
+    full = jnp.zeros((1, 1, 2048, 2048), jnp.float32)
+    assert A._forward_heads(16, 1, 512, 512, False, full, 64, 64, 2,
+                            2) == 1
+    key_mask = jnp.zeros((2, 1, 1, 2048), jnp.float32)
+    assert A._forward_heads(16, 1, 512, 512, False, key_mask, 64, 64, 2,
+                            2) == 4
+    # the backward kernels' rule is what it was
+    assert A._heads_per_step(128, False, None, width=64) == 1
+    assert A._heads_per_step(12, True, None, width=64) == 4
 
 
 # ------------------------------------------------------------ the share

@@ -26,7 +26,15 @@ and the kernel alone, on operands that exist before the timed call:
 
 ``device_ms`` is every device operation of a call, ``kernel_ms`` those
 named ``flash_fwd`` alone: their difference is the data movement round
-the kernel. Run from a checkout without the shared key part (copy this
+the kernel. ``--heads 1 2 4`` (the default) times ``lanes_bf16`` again at
+each count of heads a multi-pass grid step, by putting a function of the
+sweep's own in the place of ``ops/attention.py::_forward_heads`` (the
+library has no flag for it): column ``heads``, with ``us_a_block`` =
+``kernel_ms`` over the visited 512 x 512 blocks of all heads; a row
+without the column ran at the rule's own count (``rule_heads``).
+``--grouped`` adds the rank-4 grouped calls of ``trinity_serve_mixed``
+(banded and full) and ``lfm2_serve_long_ctx`` at their longest prompts,
+float32 operands, at every divisor of the group. Run from a checkout without the shared key part (copy this
 file into it) the ``lanes`` cases are left out and ``heads_f32`` is the
 kernel that rounds in its body. ``--check`` lengths compare the two forms
 at ragged lengths where they run.
@@ -53,6 +61,13 @@ CELLS = {
     "longcat_serve_reason": (64, 3328),
 }
 D_NOPE, D_ROPE, D_V = 128, 64, 128
+# the grouped rank-4 calls: (query heads, key/value heads, head width,
+# longest prompt, window)
+GROUPED = {
+    "trinity_serve_mixed.win": (48, 8, 128, 8192, 4096),
+    "trinity_serve_mixed.full": (48, 8, 128, 8192, None),
+    "lfm2_serve_long_ctx": (32, 8, 64, 16384, None),
+}
 
 
 def _rotate(x, pos, heads_last):
@@ -132,7 +147,69 @@ def forms(A, H, P, dn=D_NOPE, dr=D_ROPE, dv=D_V):
     return out
 
 
-def timed(fn, operands, reps):
+class step_heads:
+    """``with step_heads(A, n):`` every multi-pass forward lowered inside
+    takes ``n`` heads a grid step (``None``: the rule's own); ``.seen`` is
+    the count the last lowering took."""
+
+    def __init__(self, A, n):
+        self.A, self.n, self.seen = A, n, None
+
+    def __enter__(self):
+        self.rule = rule = self.A._forward_heads
+
+        def forced(H, group, bq, bk, single_pass, *a, **kw):
+            got = rule(H, group, bq, bk, single_pass, *a, **kw)
+            self.seen = got if single_pass or self.n is None else self.n
+            return self.seen
+
+        self.A._forward_heads = forced
+        return self
+
+    def __exit__(self, *exc):
+        self.A._forward_heads = self.rule
+
+
+def visited_blocks(A, H, P, window=None):
+    """(bq, bk)-blocks the causal forward computes at ``P`` keys, all
+    ``H`` heads."""
+    Sp, Skp, bq, bk = A._forward_plan(P, P, 128, None, True, window)
+    nq, nk = Sp // bq, Skp // bk
+    if window is None or window >= P:
+        return H * sum(1 for iq in range(nq) for ik in range(nk)
+                       if ik * bk <= iq * bq + bq - 1)
+    return H * A._band_blocks(nq, nk, bq, bk, window)
+
+
+def stepped_rows(A, args, base, fn, operands, counts, kernel, blocks):
+    """One row for the rule's own count of heads a multi-pass step and one
+    for each of ``counts``: ``fn(*operands)`` lowered afresh under
+    ``step_heads``, compared with the first and timed. A count the chip's
+    compiler refuses (over the VMEM) is a row that says so."""
+    import jax
+
+    rows, want = [], None
+    for n in [None] + list(counts):
+        with step_heads(A, n) as rule:
+            jitted = jax.jit(lambda *a: fn(*a))
+            try:
+                got = jax.block_until_ready(jitted(*operands))
+            except Exception as exc:  # noqa: BLE001 — what Mosaic said
+                rows.append(dict(base, heads=n, refused=str(
+                    exc).splitlines()[0][:200]))
+                continue
+            row = dict(base, **{"heads" if n else "rule_heads": rule.seen})
+            want = got if want is None else want
+            row["equal_to_the_rule"] = bool((got == want).all())
+            if not args.rehearse:
+                row["device_ms"], row["kernel_ms"] = timed(
+                    jitted, operands, args.reps, kernel)
+                row["us_a_block"] = 1e3 * row["kernel_ms"] / blocks
+        rows.append(row)
+    return rows
+
+
+def timed(fn, operands, reps, kernel=None):
     """``(device_ms, kernel_ms)`` a call of the jitted ``fn``: the leaf
     device operations of ``reps`` traced calls, all of them and those of
     the flash forward."""
@@ -141,6 +218,7 @@ def timed(fn, operands, reps):
     from benchmarks.lib import xplane
     from paddle_tpu.ops.attention import KERNEL_FWD
 
+    kernel = kernel or KERNEL_FWD
     jax.block_until_ready(fn(*operands))
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     with jax.profiler.trace(TRACE_DIR):
@@ -151,7 +229,7 @@ def timed(fn, operands, reps):
     leaves = xplane.leaves(ops[min(ops)])
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     return (1e3 * sum(e[2] for e in leaves) / reps,
-            1e3 * sum(e[2] for e in leaves if KERNEL_FWD in e[0]) / reps)
+            1e3 * sum(e[2] for e in leaves if kernel in e[0]) / reps)
 
 
 def case_rows(args, A):
@@ -182,6 +260,33 @@ def case_rows(args, A):
                     fn, operands, args.reps)
             rows.append(row)
             del operands, got
+    stepped = hasattr(A, "_forward_heads")
+    for cell, H, P in cells if stepped else []:
+        # the kernel alone at each count of heads a multi-pass step
+        if args.rehearse:
+            H, P = 4, 1300          # three key blocks, the last ragged
+        fn, make = forms(A, H, P)["lanes_bf16"]
+        rows += stepped_rows(
+            A, args, {"cell": cell, "H": H, "P": P, "case": "lanes_bf16"},
+            fn, make(key), args.heads, A.KERNEL_FWD, visited_blocks(A, H, P))
+    for cell, (H, Hkv, D, P, window) in GROUPED.items() \
+            if stepped and args.grouped else []:
+        if args.only not in cell:
+            continue
+        if args.rehearse:
+            H, Hkv, P, window = H // Hkv * 2, 2, 1200, window and 300
+        operands = tuple(jax.random.normal(k, (1, h, P, D), jnp.float32)
+                         for k, h in zip(jax.random.split(key, 3),
+                                         (H, Hkv, Hkv)))
+        fn = lambda q, k, v, D=D, window=window: A.flash_attention(  # noqa: E731
+            q, k, v, scale=D ** -0.5, causal=True, window=window)
+        group = H // Hkv
+        rows += stepped_rows(
+            A, args, {"cell": cell, "H": H, "Hkv": Hkv, "P": P,
+                      "case": "heads_f32"}, fn, operands,
+            [g for g in range(1, group + 1) if group % g == 0],
+            A.KERNEL_FWD_WIN if window else A.KERNEL_FWD,
+            visited_blocks(A, H, P, window))
     for P in args.check:
         # a ragged length: the two forms over one draw
         got = {case: jax.jit(fn)(*make(key))
@@ -202,6 +307,11 @@ def main(argv=None):
     ap.add_argument("--check", type=int, nargs="*",
                     default=[136, 200, 392, 1100, 1408],
                     help="ragged lengths at which the two forms are compared")
+    ap.add_argument("--heads", type=int, nargs="*", default=[1, 2, 4],
+                    help="heads a multi-pass grid step the kernel alone "
+                    "is timed at")
+    ap.add_argument("--grouped", action="store_true",
+                    help="also the grouped rank-4 calls of Trinity and LFM2")
     ap.add_argument("--out", default=os.path.join(
         REPO, "chiprun_out", "mla_prefill_sweep.json"))
     ap.add_argument("--rehearse", action="store_true",
