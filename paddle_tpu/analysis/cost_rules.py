@@ -172,7 +172,8 @@ _MOVE_ONLY = (
     "assign", "assign_value", "cast", "concat", "crop", "expand",
     "expand_as", "fill_any_like", "fill_constant",
     "fill_constant_batch_size_like", "gather", "gaussian_random",
-    "kv_cache_write", "lookup_table", "lookup_table_v2", "one_hot",
+    "kv_cache_write", "lookup_table", "lookup_table_v2", "materialize",
+    "one_hot",
     "pad", "pad2d", "range", "reverse", "roll", "sampling_id",
     "scatter", "shard_index", "slice", "split", "stack", "tile",
     "transpose", "transpose2", "truncated_gaussian_random",
@@ -557,6 +558,21 @@ def _cost_delta_update(ctx):
     st = ctx.input_shape("State")
     state = None if st is None else ctx.elems(st)
     return ctx.out_elems() if state is None else state.scaled(7)
+
+
+@register_cost_rule("mamba_scan", "mamba_update")
+def _cost_mamba(ctx):
+    """Nine operations a position, channel and state (``dt A``, the
+    exponential, the state's two products and sum, the product with ``C``
+    and its sum, the channel's ``dt u`` and ``D u``), all on the vector
+    unit: no chunk of this recurrence is a matrix product. Bytes: the
+    generic model's — for the update the state read and written once is
+    what the step costs."""
+    xs, al = ctx.input_shape("X"), ctx.input_shape("ALog")
+    pos = None if xs is None else ctx.elems(tuple(xs))
+    if pos is None or al is None or len(al) != 2 or al[1] < 0:
+        return ctx.out_elems()
+    return pos.scaled(9 * al[1])
 
 
 @register_cost_rule("causal_conv", "causal_conv_step")

@@ -465,6 +465,37 @@ def _fp_delta_update(ctx):
         + BytesPoly.from_dims((st[0], st[2], 128), 4)
 
 
+@register_footprint_rule("mamba_scan")
+def _fp_mamba_scan(ctx):
+    """The operands padded to whole blocks and regrouped into channel
+    tiles (``u``, ``dt`` and ``Y`` once each), ``B`` and ``C`` turned
+    positions-last; ``exp(dt A)`` — ``[T, C, N]`` — is formed in the
+    kernel's registers and takes nothing (the composed form holds a block
+    of it: ``[block, C, N]`` three times over)."""
+    from ..kernels.mamba import scan_block
+
+    xs, al = ctx.input_shape("X"), ctx.input_shape("ALog")
+    if xs is None or al is None or xs[1] < 0 or len(al) != 2:
+        return None
+    return BytesPoly.from_dims(tuple(xs), 4).scaled(3) \
+        + BytesPoly.from_dims((xs[0], xs[1], 2 * al[1]), 4) \
+        + BytesPoly.from_dims((xs[0], scan_block(xs[1]), al[0], al[1]),
+                              4).scaled(3)
+
+
+@register_footprint_rule("mamba_update")
+def _fp_mamba_update(ctx):
+    """The token's rows ([B, 8, C]) and columns ([B, N, 8]) and ``A``
+    turned ([N, C]) that the kernel reads beside the state; the state is
+    updated in place."""
+    st = ctx.input_shape("State")
+    if st is None or len(st) != 4:
+        return None
+    return BytesPoly.from_dims((st[0], 8, st[3]), 4) \
+        + BytesPoly.from_dims((st[0], st[2], 8), 4) \
+        + BytesPoly.from_dims((st[2], st[3]), 4)
+
+
 @register_footprint_rule("moe_ffn")
 def _fp_moe_ffn(ctx):
     """The sorted pairs: top_k copies of the tokens at width D (the

@@ -425,6 +425,7 @@ def _rr_increment(ctx):
 
 
 register_range_rule("assign")(_same("X"))
+register_range_rule("materialize")(_same("X"))
 register_range_rule("share_data")(_same("X"))
 
 
@@ -887,6 +888,20 @@ def _rr_delta(ctx):
     with no bound that a sequence's length does not move."""
     slots = ["Q", "K", "V", "Beta", "A"]
     if ctx.op.type == "delta_update":
+        slots.append("State")
+    top = AbstractValue(-F32_MAX, F32_MAX,
+                        finite=all(ctx.input_av(s).finite for s in slots))
+    ctx.set("Y", top)
+    ctx.set("StateOut", top)
+
+
+@register_range_rule("mamba_scan", "mamba_update")
+def _rr_mamba(ctx):
+    """The state is a decaying sum (``exp(dt A) <= 1``) of ``dt B u``:
+    finite where the operands are, with no bound that a sequence's length
+    does not move."""
+    slots = ["X", "Dt", "Bm", "Cm", "ALog", "D", "DtBias"]
+    if ctx.op.type == "mamba_update":
         slots.append("State")
     top = AbstractValue(-F32_MAX, F32_MAX,
                         finite=all(ctx.input_av(s).finite for s in slots))

@@ -117,7 +117,7 @@ _ACTIVATIONS = (
 register_shape_rule(*_ACTIVATIONS)(_same_shape("X"))
 
 for _t in ("scale", "clip", "clip_by_norm", "sign", "increment",
-           "assign", "share_data", "cumsum", "reverse", "roll",
+           "assign", "materialize", "share_data", "cumsum", "reverse", "roll",
            "shard_index", "label_smooth",
            "sigmoid_cross_entropy_with_logits"):
     register_shape_rule(_t)(_same_shape("X"))
@@ -376,6 +376,41 @@ def _r_delta_update(ctx):
     qs = ctx.input_shape("Q")
     if qs is not None and len(qs) == 3 and qs[1] not in (1, -1):
         ctx.fail("delta_update takes one position a row; Q is %s" % (qs,))
+
+
+def _mamba_out(ctx, kept):
+    """``Y`` is ``X``'s shape; ``StateOut [B, 1, N, C]`` from ``ALog [C,
+    N]``, or the shape of the ``State`` input where the op has it."""
+    xs, al = ctx.input_shape("X"), ctx.input_shape("ALog")
+    if xs is not None:
+        ctx.set("Y", xs)
+    st = ctx.input_shape("State") if kept else None
+    if st is None and xs is not None and al is not None and len(al) == 2:
+        st = (xs[0], 1, al[1], al[0])
+    if st is not None:
+        ctx.set("StateOut", st)
+    C = xs[-1] if xs is not None else -1
+    N = al[1] if al is not None and len(al) == 2 else -1
+    for slot, want in (("Dt", C), ("Bm", N), ("Cm", N), ("D", C),
+                       ("DtBias", C), ("ALog", C)):
+        got = ctx.input_shape(slot)
+        have = got[0 if slot == "ALog" else -1] if got else -1
+        if want >= 0 and have >= 0 and have != want:
+            ctx.fail("%s %s does not fit %d channels of %d states"
+                     % (slot, got, C, N))
+
+
+@register_shape_rule("mamba_scan")
+def _r_mamba_scan(ctx):
+    _mamba_out(ctx, False)
+
+
+@register_shape_rule("mamba_update")
+def _r_mamba_update(ctx):
+    _mamba_out(ctx, True)
+    xs = ctx.input_shape("X")
+    if xs is not None and len(xs) == 3 and xs[1] not in (1, -1):
+        ctx.fail("mamba_update takes one position a row; X is %s" % (xs,))
 
 
 @register_shape_rule("causal_conv", "causal_conv_step")

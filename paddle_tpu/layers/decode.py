@@ -12,7 +12,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["kv_cache_write", "mla_decode", "mhc_pre", "mhc_post",
-           "ssm_mix", "power_retention", "delta_rule", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
+           "ssm_mix", "mamba_mix", "power_retention", "delta_rule", "causal_conv", "rope", "beam_search", "beam_search_decode", "beam_gather", "py_func"]
 
 
 def beam_search(pre_ids, pre_scores, scores, beam_size, end_id, name=None,
@@ -308,6 +308,47 @@ def ssm_mix(x, dt, bm, cm, state, heads, groups, n_state, prefix,
     helper.append_op(type="ssm_update" if step else "ssm_scan",
                      inputs=inputs,
                      outputs={"Y": [y], "StateOut": [state]}, attrs=attrs)
+    y.shape = x.shape
+    return y
+
+
+def mamba_mix(x, dt, bm, cm, state, n_state, prefix, step=False, name=None):
+    """The selective state-space recurrence of a Mamba-1 layer (ops
+    ``mamba_scan`` / ``mamba_update``, kernels/mamba.py): ``S[c, n] <-
+    exp(dt[c] A[c, n]) S[c, n] + dt[c] B[n] x[c]``, ``y[c] = sum_n S[c, n]
+    C[n] + D[c] x[c]`` — one decay a channel AND state, so no chunk of it
+    is a matrix product. ``x [B, T, C]`` (the convolved channels), ``dt
+    [B, T, C]`` (raw), ``bm`` / ``cm`` ``[B, T, N]``. ``state`` is a
+    persistable ``[B, 1, N, C]`` var (``kernels.mamba.state_shape``): a
+    whole prompt (``step=False``) is scanned from a zero state, position
+    by position, and leaves its final state there; one token
+    (``step=True``, ``T`` = 1) updates it in place. Returns ``y [B, T,
+    C]``, the skip included. Parameters, float32 whatever the stored
+    dtype: ``<prefix>_a_log [C, N]`` (``A = -exp(a_log)``), ``<prefix>_d``
+    and ``<prefix>_dt_b`` ``[C]`` (``dt = softplus(dt + dt_b)``)."""
+    from ..initializer import Constant
+    from ..layer_helper import stored_dtype
+
+    helper = LayerHelper("mamba_mix", name=name)
+    C, N = int(x.shape[-1]), int(n_state)
+
+    def vec(part, value, shape):
+        return helper.create_parameter(
+            ParamAttr(name="%s_%s" % (prefix, part),
+                      initializer=Constant(value)),
+            shape, dtype="float32", is_bias=True)
+
+    with stored_dtype(None):      # A_log is [C, N] and stays float32
+        a_log = vec("a_log", 0.0, [C, N])
+    inputs = {"X": [x], "Dt": [dt], "Bm": [bm], "Cm": [cm],
+              "ALog": [a_log], "D": [vec("d", 1.0, [C])],
+              "DtBias": [vec("dt_b", 0.0, [C])]}
+    if step:
+        inputs["State"] = [state]
+    y = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="mamba_update" if step else "mamba_scan",
+                     inputs=inputs,
+                     outputs={"Y": [y], "StateOut": [state]})
     y.shape = x.shape
     return y
 

@@ -19,6 +19,7 @@ __all__ = [
     "concat",
     "sums",
     "assign",
+    "materialize",
     "fill_constant",
     "fill_constant_batch_size_like",
     "ones",
@@ -101,6 +102,22 @@ def sums(input, out=None):
         out = helper.create_variable_for_type_inference(input[0].dtype)
     helper.append_op(type="sum", inputs={"X": input}, outputs={"Out": [out]})
     out.shape = input[0].shape
+    return out
+
+
+def materialize(x):
+    """``x`` itself, as a value XLA has to write (op ``materialize``: an
+    optimization barrier, no arithmetic and no copy). A residual stream
+    is a chain of adds that XLA fuses into every reader, the last one
+    included: the prefill's final norm then re-adds EVERY layer's output
+    from the embedding up, and each of them — ``[P, d_model]`` float32 —
+    stays alive to the end of the program. Behind this op a reader starts
+    from the materialised sum (models/gpt.py ``build_prefill_step``)."""
+    helper = LayerHelper("materialize")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="materialize", inputs={"X": [x]},
+                     outputs={"Out": [out]})
+    out.shape = x.shape
     return out
 
 

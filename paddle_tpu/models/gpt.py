@@ -21,6 +21,7 @@ from .. import layers
 from ..core.program import name_scope
 from ..layer_helper import stored_dtype
 from ..observe.families import (DELTA_CHUNKS, DELTA_STATE_BYTES,
+                                MAMBA_CHUNKS, MAMBA_STATE_BYTES,
                                 POWER_CHUNKS, POWER_STATE_BYTES)
 from ..param_attr import ParamAttr
 from .transformer import (_causal_bias, _ffn, _pad_bias, _prenorm,
@@ -388,6 +389,55 @@ def base_config():
 
     (one chip's share adds ``n_expert_local=64, expert_first=0``).
 
+    A Mamba-1 mixer as a layer's FIRST sub-block (``layer_types`` entry
+    ``"mamba"``, arXiv:2312.00752 as Jamba has it, arXiv:2403.19887;
+    serving programs only) beside ``"full"`` attention layers: the layer
+    stays the ordinary pair — the mixer, then the FFN of ``d_ff`` — and
+    only what stands where attention stood changes. ``mamba_inner`` (C)
+    channels of ``mamba_state`` (N) states each, a ``dt`` projection of
+    rank ``mamba_dt_rank`` (R), ``ssm_conv`` (K) taps. With ``h`` the
+    normed input: ``[u | z] = h W_in`` (``C | C``); ``u <- silu(conv_K(u)
+    + b_conv)``, causal and depth-wise (``z`` does not pass it); ``[delta
+    | B | C] = u W_x`` (``R | N | N``) of the CONVOLVED ``u``, each
+    through an RMSNorm of its own (one scale vector each, the cfg's
+    ``norm_eps``); ``dt = softplus(delta W_dt + b_dt)``; ``A =
+    -exp(A_log) [C, N]``; a channel ``c`` and state ``n``: ``S[c, n] <-
+    exp(dt[c] A[c, n]) S[c, n] + dt[c] B[n] u[c]``, ``y[c] = sum_n S[c, n]
+    C[n] + D[c] u[c]``; ``out = (y * silu(z)) W_out``. The decay is one
+    number a channel AND state, so — unlike the ``'ssm'`` mixer's, one
+    scalar a head — no chunk of the recurrence is a matrix product: the
+    prefill walks the prompt position by position inside its kernel (op
+    ``mamba_scan``, kernels/mamba.py). What a sequence KEEPS of such a
+    layer has no position axis: the state ``gpt_<i>_cache_s [B, 1, N, C]``
+    and the last ``K - 1`` rows of the un-convolved ``u``,
+    ``gpt_<i>_cache_x [B, K - 1, C]`` (``cache_kind`` calls both
+    ``state``), in ONE lane with the slabs of the ``"full"`` layers. The
+    prefill overwrites both; a decode step updates them in place
+    (``mamba_update``, ``causal_conv_step``). Parameters
+    ``gpt_<i>_mamba_{in,x,dt,out}.w_0``, ``gpt_<i>_mamba_conv.{w,b}_0``
+    and, float32 whatever ``weight_dtype``, ``gpt_<i>_mamba_a_log [C,
+    N]``, ``gpt_<i>_mamba_{d,dt_b} [C]``,
+    ``gpt_<i>_mamba_{dt,b,c}norm_s``. ``pos_emb="none"`` stands beside
+    such a layer as beside an ``'ssm'`` mixer: the recurrence orders the
+    tokens. It takes none of ``attn``, ``residual``, ``mixers``,
+    ``shortcut_moe``; the training build, the multi-token step, a prefix
+    store and a draft model refuse it by name.
+
+    AI21-Jamba2-3B (``model_type`` jamba), as the worked example —
+    published widths, all 28 layers, attention at layers 7 and 21
+    (``attn_layer_period`` 14, ``attn_layer_offset`` 7), 20 query heads
+    over ONE key-value head, the token table as the head::
+
+        dict(d_model=2560, n_head=20, n_kv_head=1, d_head=128,
+             n_layer=28, vocab=65536, max_length=262144, dropout=0.0,
+             pos_emb="none", norm="rms", norm_eps=1e-6,
+             tie_embeddings=True,
+             layer_types=["full" if i % 14 == 7 else "mamba"
+                          for i in range(28)],
+             mamba_inner=5120, mamba_state=16, mamba_dt_rank=160,
+             ssm_conv=4, ffn_act="swiglu", d_ff=8192,
+             weight_dtype="bfloat16")
+
     Shortcut-connected experts (``shortcut_moe``; serving programs
     only): a published layer is attention, dense FFN, attention, dense
     FFN, with ONE routed branch that reads the first attention's
@@ -471,13 +521,15 @@ _CFG_KEYS = frozenset([
     "mla_scale_kv_lora",
     "delta_k_heads", "delta_v_heads", "delta_k_dim", "delta_v_dim",
     "rope_dim", "shared_expert_gate",
+    "mamba_inner", "mamba_state", "mamba_dt_rank",
 ])
 _SSM_KEYS = ("ssm_heads", "ssm_head_dim", "ssm_groups", "ssm_state",
              "ssm_conv")
 MIXER_KINDS = ("ssm", "attention", "experts")
-LAYER_TYPES = ("sliding", "full", "conv", "retention", "delta")
+LAYER_TYPES = ("sliding", "full", "conv", "retention", "delta", "mamba")
 _DELTA_KEYS = ("delta_k_heads", "delta_v_heads", "delta_k_dim",
                "delta_v_dim")
+_MAMBA_KEYS = ("mamba_inner", "mamba_state", "mamba_dt_rank", "ssm_conv")
 _MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "d_nope", "d_rope", "d_v")
 # the keys after which a dense FFN carries no biases and the training
 # build composes its attention here (``_attention``)
@@ -674,10 +726,14 @@ def _check_mixers(cfg):
     kinds needs is ``_check_kinds``'."""
     kinds = cfg.get("mixers") or ()
     _check_kinds(cfg, "mixers")
-    if "ssm" not in kinds and cfg.get("pos_emb") == "none":
+    if cfg.get("pos_emb") == "none" and not any(
+            kind.orders and kind.name in (cfg.get(kind.key) or ())
+            for kind in LAYER_KINDS.values()):
         raise ValueError(
-            "cfg['pos_emb']='none' needs an 'ssm' layer in "
-            "cfg['mixers']: nothing else here orders the tokens")
+            "cfg['pos_emb']='none' needs %s: nothing else here orders the "
+            "tokens" % " or ".join(
+                "%s in cfg[%r]" % (kind.layer, kind.key)
+                for kind in LAYER_KINDS.values() if kind.orders))
     if not kinds:
         return
     if len(kinds) != cfg["n_layer"] \
@@ -691,10 +747,18 @@ def _check_mixers(cfg):
             raise ValueError("cfg['mixers'] takes no cfg[%r]" % key)
 
 
+def _rows_naming(k):
+    """The rows of ``LAYER_KINDS`` that name cfg key ``k`` (``ssm_conv``
+    is the taps of an ``'ssm'`` mixer's AND of a ``'mamba'`` layer's
+    convolution, one ``causal_conv``; every other key has one row)."""
+    return [kind for kind in LAYER_KINDS.values()
+            if k in kind.needs + kind.extra]
+
+
 def _check_kinds(cfg, key):
     """The rows of ``LAYER_KINDS`` that an entry of cfg[``key``] names
     (``base_config``), each by the same three rules: a key of the row's
-    needs a layer of it; such a layer needs the row's keys >= 1, passes
+    needs a layer of it (of a row that names it); such a layer needs the row's keys >= 1, passes
     the row's own check and takes none of what the row refuses."""
     held = cfg.get(key) or ()
     for kind in LAYER_KINDS.values():
@@ -702,9 +766,12 @@ def _check_kinds(cfg, key):
             continue
         if kind.name not in held:
             for k in kind.needs + kind.extra:
-                if cfg.get(k):
-                    raise ValueError("cfg[%r] needs %s in cfg[%r]"
-                                     % (k, kind.layer, key))
+                rows = _rows_naming(k)
+                if cfg.get(k) and not any(
+                        row.name in (cfg.get(row.key) or ()) for row in rows):
+                    raise ValueError("cfg[%r] needs %s" % (k, " or ".join(
+                        "%s in cfg[%r]" % (row.layer, row.key)
+                        for row in rows)))
             continue
         for k in kind.needs:
             if not int(cfg.get(k) or 0) >= 1:
@@ -745,6 +812,14 @@ def _check_delta(cfg):
         raise ValueError(
             "cfg['delta_k_heads']=%r must divide cfg['delta_v_heads']=%r"
             % (cfg["delta_k_heads"], cfg["delta_v_heads"]))
+
+
+def _check_mamba(cfg):
+    if cfg["ssm_conv"] < 2 or "d_ff" not in cfg:
+        raise ValueError(
+            "a 'mamba' layer needs cfg['ssm_conv']=%r >= 2 taps and "
+            "cfg['d_ff'] (the dense FFN behind the mixer)"
+            % (cfg["ssm_conv"],))
 
 
 def _check_shortcut(cfg):
@@ -905,6 +980,14 @@ def _delta_kept(cfg):
                CONV_TAPS - 1))
 
 
+def _mamba_kept(cfg):
+    C, N, _R, K = mamba_widths(cfg)
+    return ("a selective-scan state of %d states a channel over %d "
+            "channels and the last %d rows of its convolution's input with "
+            "no position axis (gpt_<i>_cache_s, gpt_<i>_cache_x)"
+            % (N, C, K - 1))
+
+
 def _conv_kept(cfg):
     return ("the last %d rows of a gated convolution's input with no "
             "position axis (gpt_<i>_cache_x)" % (int(cfg["conv_taps"]) - 1))
@@ -918,15 +1001,16 @@ def _ssm_kept(cfg):
 def state_layers(cfg):
     """The layers that keep a constant-size state and not rows a
     position, whichever key brought them: an ``'ssm'`` entry of
-    cfg['mixers'] or a ``'conv'``, ``'retention'`` or ``'delta'`` entry
-    of cfg['layer_types'] (the rows of ``LAYER_KINDS`` with ``kept``)."""
+    cfg['mixers'] or a ``'conv'``, ``'retention'``, ``'delta'`` or
+    ``'mamba'`` entry of cfg['layer_types'] (the rows of ``LAYER_KINDS``
+    with ``kept``)."""
     return [i for i in range(cfg["n_layer"]) if kind_of(cfg, i).kept]
 
 
 def has_state(cfg):
     """Whether some layer keeps a state and not rows a position (an
-    ``'ssm'`` mixer, a ``'conv'``, a ``'retention'`` or a ``'delta'``
-    layer): its caches, ``gpt_<i>_cache_s``, ``gpt_<i>_cache_x`` and
+    ``'ssm'`` mixer, a ``'conv'``, a ``'retention'``, a ``'delta'`` or a
+    ``'mamba'`` layer): its caches, ``gpt_<i>_cache_s``, ``gpt_<i>_cache_x`` and
     ``gpt_<i>_cache_z``, have no position axis, so nothing can be cut out
     of them at a prefix's length nor rolled back by a position."""
     return bool(state_layers(cfg))
@@ -938,6 +1022,12 @@ def delta_widths(cfg):
     Hk, Dk = int(cfg["delta_k_heads"]), int(cfg["delta_k_dim"])
     Hv, Dv = int(cfg["delta_v_heads"]), int(cfg["delta_v_dim"])
     return Hk, Dk, Hv, Dv, 2 * Hk * Dk + Hv * Dv
+
+
+def mamba_widths(cfg):
+    """``(C, N, R, K)`` of a 'mamba' layer: inner channels, states a
+    channel, the rank of ``dt``'s projection, the convolution's taps."""
+    return tuple(int(cfg[k]) for k in _MAMBA_KEYS)
 
 
 def ssm_widths(cfg):
@@ -1397,6 +1487,47 @@ def _delta_mixer(cfg, step, h, nm, i):
             layers.reshape(y, [-1, T, Hv * Dv]),
             layers.swish(cut(proj, d_conv, d_conv + Hv * Dv)))
         return _fc(y, cfg["d_model"], nm + "_delta_out.w_0"), \
+            [rows.name, state.name]
+
+
+def _mamba_mixer(cfg, step, h, nm, i):
+    """A Mamba-1 mixer over the normed ``h [B, T, D]`` (``base_config``
+    has the equations): ``(out [B, T, D], [the two cache names])``. In the
+    decode form (``T`` = 1) the state and the convolution rows are read
+    and updated in place; otherwise the prompt is scanned from a zero
+    state, position by position, and both are overwritten."""
+    from ..kernels.mamba import state_shape
+
+    helper, batch = step.helper, step.batch
+    C, N, R, K = mamba_widths(cfg)
+    with name_scope("mixer"):
+        proj = _fc(h, 2 * C, nm + "_mamba_in.w_0")
+
+        def cut(t, lo, hi):
+            return layers.slice(t, axes=[2], starts=[lo], ends=[hi])
+
+        def inner_norm(t, which):
+            return layers.rms_norm(
+                t, begin_norm_axis=2, epsilon=_rms_eps(cfg),
+                param_attr=ParamAttr(name="%s_mamba_%snorm_s" % (nm, which)))
+
+        rows = helper.create_global_variable(
+            name=nm + "_cache_x", shape=(batch, K - 1, C))
+        state = helper.create_global_variable(
+            name=nm + "_cache_s", shape=state_shape(batch, C, N))
+        with stored_dtype(None):      # the taps stay float32, as a vector
+            u = layers.causal_conv(cut(proj, 0, C), K, nm + "_mamba_conv",
+                                   rows, step=step.decode)
+        # [delta | B | C] of the CONVOLVED u, each through its own norm
+        dbc = _fc(u, R + 2 * N, nm + "_mamba_x.w_0")
+        dt = _fc(inner_norm(cut(dbc, 0, R), "dt"), C, nm + "_mamba_dt.w_0")
+        y = layers.mamba_mix(
+            u, dt, inner_norm(cut(dbc, R, R + N), "b"),
+            inner_norm(cut(dbc, R + N, R + 2 * N), "c"), state, N,
+            nm + "_mamba", step=step.decode)
+        # the gate does not pass the convolution; no norm behind the scan
+        y = layers.elementwise_mul(y, layers.swish(cut(proj, C, 2 * C)))
+        return _fc(y, cfg["d_model"], nm + "_mamba_out.w_0"), \
             [rows.name, state.name]
 
 
@@ -2376,6 +2507,18 @@ def _delta_chunk(cfg, P):
     return scan_chunk(P)
 
 
+def _mamba_chunk(cfg, P):
+    from ..kernels.mamba import scan_block
+
+    return scan_block(P)
+
+
+def _mamba_state(update, made):
+    """The state, and the rows of the convolution whose output the update
+    reads."""
+    return update.input("State") + made[update.input("X")[0]].input("Rows")
+
+
 def _power_state(update, made):
     return update.input("State") + update.input("Norm")
 
@@ -2409,6 +2552,8 @@ class _LayerKind(NamedTuple):
     update_op: Optional[str] = None     # the decode op that updates the state
     state: Optional[Callable] = None    # (that op, {variable: the op that
     #                                     made it}) -> the state's variables
+    orders: bool = False    # a recurrence whose model carries no position:
+    #                         beside it ``pos_emb='none'`` stands
 
 
 _NO_KINDS = ("attn", "latent attention has no layer kinds")
@@ -2424,7 +2569,7 @@ LAYER_KINDS = {kind.name: kind for kind in (
     _LayerKind("ssm", "an 'ssm' layer", "mixers", _ssm_mixer, "mixer", False,
                {"_cache_x": "state", "_cache_s": "state"}, _ssm_kept,
                needs=_SSM_KEYS, extra=("ssm_chunk",), check=_check_ssm,
-               chunk=_ssm_chunk),
+               chunk=_ssm_chunk, orders=True),
     _LayerKind("experts", "an 'experts' layer", "mixers", _experts_mixer,
                "moe.experts", False, {}, check=_check_experts),
     _LayerKind("retention", "a 'retention' layer", "layer_types", _retention,
@@ -2445,6 +2590,16 @@ LAYER_KINDS = {kind.name: kind for kind in (
                chunk=_delta_chunk, chunks=DELTA_CHUNKS,
                state_bytes=DELTA_STATE_BYTES, update_op="delta_update",
                state=_delta_state),
+    _LayerKind("mamba", "a 'mamba' layer", "layer_types", _mamba_mixer,
+               "mixer", False, {"_cache_x": "state", "_cache_s": "state"},
+               _mamba_kept, needs=_MAMBA_KEYS, check=_check_mamba,
+               refuses=(_NO_KINDS,
+                        ("residual", "the selective scan is not written "
+                         "over several residual streams"), _NO_FIRST,
+                        _NO_FORK),
+               chunk=_mamba_chunk, chunks=MAMBA_CHUNKS,
+               state_bytes=MAMBA_STATE_BYTES, update_op="mamba_update",
+               state=_mamba_state, orders=True),
     _LayerKind("conv", "a 'conv' layer", "layer_types", _gated_conv, "conv",
                False, {"_cache_x": "state"}, _conv_kept,
                extra=("conv_taps",), check=_check_conv,
@@ -2467,8 +2622,18 @@ def _layer(cfg, step, x, i):
     step.cache_names.extend(kept)
     if kind.merged:
         y = _attn_out(cfg, h, y, nm)
-    return _layer_tail(cfg, x, y, nm, i, mix, step.dev, step.branch,
-                       kind.scope, **step.tally)
+    x = _layer_tail(cfg, x, y, nm, i, mix, step.dev, step.branch,
+                    kind.scope, **step.tally)
+    if not step.decode:
+        # a prefill writes its residual stream after every layer: XLA
+        # fuses the stream's adds into every reader, the LAST one then
+        # re-adds all ``2 n_layer`` sub-block outputs from the embedding
+        # up and each ``[batch, P, d_model]`` float32 lives to the end of
+        # the program (9.4 GB at 28 layers of 2,560 and 16,384 positions,
+        # where the live set is 2.7 GB)
+        with name_scope("norm"):    # no instruction: the next norm's input
+            x = layers.materialize(x)
+    return x
 
 
 @_stores_weights

@@ -1164,6 +1164,36 @@ DELTA_CHUNKS = REGISTRY.counter(
     "full-prompt prefill (serving/engine.py): one triangular solve a "
     "chunk and value head")
 
+MAMBA_PLANS = REGISTRY.counter(
+    "paddle_mamba_plans_total",
+    "Which form of a Mamba-1 layer's recurrence a program holds (gpt "
+    "cfg['layer_types'] with 'mamba' layers, kernels/mamba.py): one count "
+    "a call of mamba_scan (a whole prompt, walked position by position "
+    "inside the kernel: the decay is one number a channel AND state, so "
+    "no chunk of it is a matrix product) or mamba_update (one token a "
+    "slot into the state, in place) at LOWERING, form 'pallas' or "
+    "'composed' (jax.numpy: every CPU run, and PADDLE_TPU_KERNELS=0), "
+    "with the block of positions a grid step of the scan holds (1 for "
+    "the update). A prefill of a model with L mamba layers lowers L "
+    "scans, its decode step L updates",
+    labels=("kernel", "form", "block"))
+
+MAMBA_STATE_BYTES = REGISTRY.gauge(
+    "paddle_mamba_state_bytes",
+    "Bytes of Mamba-1 state the lane built last holds: the state [b_max, "
+    "1, N, C] every mamba_update of its decode step reads and writes, and "
+    "the rows [b_max, taps - 1, C] of the convolution in front of it that "
+    "the layer's causal_conv_step shifts. Part of "
+    "paddle_serving_cache_bytes{kind='state'}; 0 for a model without "
+    "such layers")
+
+MAMBA_CHUNKS = REGISTRY.counter(
+    "paddle_mamba_chunks_total",
+    "Blocks of positions the admissions' prefills scanned in mamba "
+    "layers: layers x ceil(prompt / kernels.mamba.scan_block(prompt)) a "
+    "full-prompt prefill (serving/engine.py); inside a block the kernel "
+    "walks the positions one by one")
+
 MHC_RES_DEVIATION = REGISTRY.gauge(
     "paddle_mhc_res_deviation",
     "The largest |row sum - 1| or |column sum - 1| any residual mapping "

@@ -16,6 +16,7 @@ from . import (  # noqa: F401
     distributed_ops,
     fused_ops,
     loss_ops,
+    mamba_ops,
     math,
     metrics,
     misc_ops,

@@ -99,6 +99,15 @@ def _assign(ctx, ins, attrs):
     return {"Out": [ins["X"][0]]}
 
 
+@register_op("materialize", no_grad=True)
+def _materialize(ctx, ins, attrs):
+    """``X`` as it is, behind ``jax.lax.optimization_barrier``: XLA may
+    neither fuse what made ``X`` into what reads ``Out`` nor recompute
+    it there, so ``X`` is written once and its producers' operands die
+    where it is made (``layers.materialize`` says who needs that)."""
+    return {"Out": [jax.lax.optimization_barrier(ins["X"][0])]}
+
+
 @register_op("assign_value", no_grad=True)
 def _assign_value(ctx, ins, attrs):
     vals = attrs["values"]

@@ -538,7 +538,10 @@ def test_a_cfg_without_the_new_keys_builds_the_parents_program(shape, build):
     grouped heads, so its two layers hand q, k, v to ``fused_attention``
     as [B, S, H*D] with ``n_head`` and lost their eight ``reshape2`` and
     eight ``transpose2`` (75 ops -> 59); ``olmoe``'s rotates and keeps
-    them."""
+    them. PR 58's
+    residual pins (one ``materialize`` a layer of every prefill: a barrier,
+    no arithmetic) are left out of the list, and counted by
+    ``tests/test_gpt_programs_pinned.py``."""
     with open(os.path.join(HERE, "references",
                            "gpt_op_lists_parent.json")) as f:
         want = json.load(f)[shape][build]
@@ -548,7 +551,7 @@ def test_a_cfg_without_the_new_keys_builds_the_parents_program(shape, build):
     ops = [[op.type, sorted(op.inputs), sorted(op.outputs),
             sorted((k, repr(v)) for k, v in op.attrs.items()
                    if not k.startswith("_") and k != "op_callstack")]
-           for op in prog.global_block().ops]
+           for op in prog.global_block().ops if op.type != "materialize"]
     assert len(ops) == want["n_ops"]
     assert hashlib.sha256(json.dumps(ops, sort_keys=True).encode()) \
         .hexdigest() == want["sha256"]

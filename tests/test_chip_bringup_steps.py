@@ -869,3 +869,108 @@ def test_qwen3next_prefill_compiles_for_v5e(v5e, compiled_kernels):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.5e9, mem
     print("qwen3-next prefill P=%d:" % P, mem)
+
+
+def _jamba():
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "ai21-jamba2-3b.json")) as f:
+        conf = json.load(f)
+    return gpt, conf["model"], conf["serving"]
+
+
+MAMBA_PLANS = "paddle_mamba_plans_total"
+
+
+def _mamba_plans():
+    from paddle_tpu.observe import REGISTRY
+
+    family = REGISTRY.snapshot()["metrics"].get(MAMBA_PLANS, {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["form"],
+             s["labels"]["block"]): s["value"] for s in family["samples"]}
+
+
+def _new_mamba_plans(before):
+    return {k: v - before.get(k, 0) for k, v in _mamba_plans().items()
+            if v != before.get(k, 0)}
+
+
+def test_jamba_serving_decode_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The whole ``ai21-jamba2-3b`` serving decode step (32 slots: 0.32 GB
+    of selective-scan state and convolution rows beside 1.09 GB of slab in
+    ONE lane, all 28 layers, the whole token table as the head, bf16
+    matrices) for the described chip: 26 ``mamba_update`` Pallas calls,
+    every state donated into its output and none copied, and arguments
+    equal to the static bytes the closed form reckons within 1%."""
+    import paddle_tpu as fluid
+    from benchmarks.lib import closed_forms_mamba
+    from paddle_tpu.kernels import mamba
+
+    gpt, cfg, serving = _jamba()
+    B, S = serving["b_max"], serving["max_len"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _logits, caches = gpt.build_serving_decode_step(cfg, batch=B,
+                                                        max_len=S)
+    kinds = [gpt.cache_kind(cfg, n, S) for n in caches]
+    assert kinds.count("state") == 52 and kinds.count("full") == 4
+    before = _mamba_plans()
+    lowered, mut_state = _lower_step(
+        main, {"token": (B, 1), "pos": (B, 1)}, gpt.NEXT_TOKEN_VAR, v5e)
+    assert _new_mamba_plans(before) == {("mamba_update", "pallas", "1"): 26}
+    assert set(caches) <= set(mut_state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mamba.KERNEL_UPDATE,
+                              text))) == 26
+    # no second copy of a layer's state: 32 x 1 x 16 x 5120 float32
+    assert _cache_sized(text, (B, 1, 16, 5120)) == []
+    mem = compiled.memory_analysis()
+    static = closed_forms_mamba.static_bytes(cfg, B, S, 4, 2)
+    assert abs(mem.argument_size_in_bytes - static) < 0.01 * static, mem
+    assert static >= 0.25 * 16e9
+    assert mem.alias_size_in_bytes >= closed_forms_mamba.state_bytes(cfg, B)
+    assert mem.temp_size_in_bytes < 1.5e9, mem
+    print("jamba decode step:", mem)
+
+
+def test_jamba_prefill_compiles_for_v5e(v5e, compiled_kernels):
+    """The batch=1 prefill of the longest prompt of the mix (16,384): 26
+    ``mamba_scan`` Pallas calls at the kernel's block, the flash forward
+    at 20 query heads over one key-value head in the two full layers, a
+    head on ONE row, NO ``[T, 5120, 16]`` tensor (``exp(dt A)`` is formed
+    in the kernel's registers: 5.4 GB a layer were it written), the
+    residual stream written after every layer (28 ``materialize`` ops:
+    without them XLA keeps all 56 sub-block outputs to the end, 10.6 GB
+    of temporaries for a live set of 2.7), and temporaries that fit
+    beside the 7.5 GB the engine holds."""
+    import paddle_tpu as fluid
+    from paddle_tpu.kernels import mamba
+
+    gpt, cfg, serving = _jamba()
+    P = 16384
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
+                               max_len=serving["max_len"])
+    assert [op.type for op in main.global_block().ops].count(
+        "materialize") == 28
+    before = _mamba_plans()
+    lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
+                             v5e)
+    assert _new_mamba_plans(before) == {
+        ("mamba_scan", "pallas", str(mamba.scan_block(P))): 26}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%%(%s[.\d]*) = " % mamba.KERNEL_SCAN,
+                              text))) == 26
+    assert len(set(re.findall(r"%(flash_fwd[.\d]*) = ", text))) == 2
+    for dims in ("%d,5120,16" % P, "%d,16,5120" % P, "5120,16,%d" % P,
+                 "16,5120,%d" % P, "5120,%d,16" % P, "16,%d,5120" % P):
+        assert "f32[1,%s]" % dims not in text and "f32[%s]" % dims \
+            not in text, dims
+    assert "f32[1,%d,65536]" % P not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3.5e9, mem
+    print("jamba prefill P=%d:" % P, mem)

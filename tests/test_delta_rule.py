@@ -474,7 +474,7 @@ def test_state_layer_table_names_every_state_bearing_type():
     cfg = tiny_cfg()
     assert {k.name for k in gpt.LAYER_KINDS.values()
             if k.kept and k.key == "layer_types"} \
-        == {"conv", "retention", "delta"}
+        == {"conv", "retention", "delta", "mamba"}
     assert gpt.state_layers(cfg) == [0, 1, 2] and gpt.has_state(cfg)
     assert ["rows" in gpt.kind_of(cfg, i).caches.values()
             for i in range(4)] == [False] * 3 + [True]

@@ -221,7 +221,7 @@ def test_the_analyses_rule_every_op_of_the_programs(which):
     (dict(conv_taps=None), "a 'conv' layer needs cfg['conv_taps'] >= 2"),
     (dict(conv_taps=1), "a 'conv' layer needs cfg['conv_taps'] >= 2"),
     (dict(layer_types=["full"] * 5), "cfg['conv_taps'] needs a 'conv'"),
-    (dict(layer_types=["conv", "mamba", "conv", "conv", "conv"]),
+    (dict(layer_types=["conv", "hyena", "conv", "conv", "conv"]),
      "must name one of"),
     (dict(residual="mhc", hc_mult=2),
      "a 'conv' layer takes no cfg['residual']"),

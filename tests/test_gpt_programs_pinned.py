@@ -23,6 +23,13 @@ they stand in: a cache's zero fill draws nothing, so where it stands
 among the parameters' draws changes no value). Programs only: nothing is
 compiled or run.
 
+Since PR 58 every prefill writes its residual stream after every layer
+(one ``materialize`` op a layer: an optimization barrier, no arithmetic).
+The file is still the parent's: a prefill is digested WITH THOSE OPS
+TAKEN OUT and their readers handed the value they passed on, after the
+case has counted them (``n_layer`` in a prefill, none in a decode step),
+so everything else of the program is still held to what PR 55 built.
+
 ``python tests/test_gpt_programs_pinned.py <commit>`` prints the digests
 of the tree it runs on under that commit's name (it writes no file).
 """
@@ -72,11 +79,26 @@ def _case_id(case):
 
 
 def _ops(program, scoped):
-    return [[op.type, sorted(op.inputs.items()), sorted(op.outputs.items()),
+    """The block's ops without the residual pins (module docstring)."""
+    passed = {op.outputs["Out"][0]: op.inputs["X"][0]
+              for op in program.global_block().ops
+              if op.type == "materialize"}
+
+    def slots(named):
+        return sorted((slot, [passed.get(n, n) for n in names])
+                      for slot, names in named.items())
+
+    return [[op.type, slots(op.inputs), slots(op.outputs),
              sorted((k, repr(v)) for k, v in op.attrs.items()
                     if not k.startswith("_") and k != "op_callstack")]
             + ([op.name_scope] if scoped else [])
-            for op in program.global_block().ops]
+            for op in program.global_block().ops if op.type != "materialize"]
+
+
+def _n_layer(name):
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        return json.load(f)["model"]["n_layer"]
 
 
 def _sha(what):
@@ -102,6 +124,7 @@ def digest(case):
                       for v in variables)
 
     return {"n_ops": len(ops), "sha256": _sha(ops),
+            "pins": len(block.ops) - len(ops),
             "params": _sha(described(block.all_parameters())),
             "n_params": len(block.all_parameters()),
             "caches": [[n, list(block.var(n).shape), str(block.var(n).dtype)]
@@ -114,6 +137,8 @@ def test_the_serving_program_is_the_parents(case):
     with open(REFERENCE) as f:
         want = json.load(f)["cases"][_case_id(case)]
     got = digest(case)
+    layers = got.pop("pins")
+    assert layers == (0 if case[2] is None else _n_layer(case[0]))
     for key in ("caches", "n_params", "params", "startup",
                 "n_ops", "sha256"):
         assert got[key] == want[key], key

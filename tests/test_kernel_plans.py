@@ -83,6 +83,13 @@ REACHES = {
         "decode": ("delta_update", "kv_cache_write", "moe_ffn"),
         "prefill": ("delta_scan", "fused_attention", "kv_cache_write",
                     "moe_ffn")},
+    # 26 mamba layers (the in-place update whose decay is a block, the
+    # scan that walks time inside the kernel) beside two attention layers
+    # of 20 query heads over ONE key-value head: the widest group the
+    # flash forward's grouped multi-pass plan is given
+    "ai21-jamba2-3b": {
+        "decode": ("kv_cache_write", "mamba_update"),
+        "prefill": ("fused_attention", "kv_cache_write", "mamba_scan")},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -123,6 +130,12 @@ RUNS_ON_THE_CHIP = {
     ("qwen3-next-80b-a3b", "delta_update"),
     ("qwen3-next-80b-a3b", "fused_attention"),
     ("qwen3-next-80b-a3b", "moe_ffn"),
+    # my chip runs, PR 58: mamba_scan pallas block=256 and mamba_update
+    # pallas in the facts' plan counter, flash_fwd at 20 heads over one,
+    # all in the device trace
+    ("ai21-jamba2-3b", "mamba_scan"), ("ai21-jamba2-3b", "mamba_update"),
+    ("ai21-jamba2-3b", "fused_attention"),
+    ("ai21-jamba2-3b", "kv_cache_write"),
 }
 
 
@@ -474,7 +487,49 @@ def _check_delta_scan(block, op, batch, must):
     return takes
 
 
+def _check_mamba_update(block, op, batch, must):
+    from paddle_tpu.kernels import mamba
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    shape, _ = _operand(block, op, "State", batch)
+    tile = mamba._update_plan(shape)
+    assert tile or not must, shape
+    if tile:
+        # a slot's [N, tile] of the state in and out and of A^T, the
+        # token's rows [8, tile] and y [1 -> 8, tile], double-buffered
+        B, G, N, C = shape
+        assert G == 1 and C % tile == 0
+        assert mosaic_ok((1, 1, N, tile), shape)
+        assert mosaic_ok((N, tile), (N, C))
+        assert mosaic_ok((1, 8, tile), (B, 8, C))
+        assert mosaic_ok((1, N, 8), (B, N, 8))
+        held = 4 * 2 * (3 * N * tile + 2 * 8 * tile + N * 128)
+        assert held <= mamba._VMEM_LIMIT_BYTES // 2, held
+    return tile
+
+
+def _check_mamba_scan(block, op, batch, must):
+    from paddle_tpu.kernels import mamba
+
+    (_B, T, C), _ = _operand(block, op, "X", batch)
+    (_C, N), _ = _operand(block, op, "ALog", batch)
+    takes = mamba._scan_plan(T, C, N)
+    assert takes or not must, (T, C, N)
+    if takes:
+        Q, lanes, unroll = takes
+        # every prompt of the cell is whole blocks: nothing is padded
+        assert Q == mamba.BLOCK and T % Q == 0 and Q % unroll == 0
+        assert C % (8 * lanes) == 0 and N * lanes // 128 <= 80
+        # u, dt and y of a block of positions, double-buffered, A^T's
+        # tile twice, the state in scratch and out; B and C in SMEM
+        held = 4 * (2 * 3 * Q * 8 * lanes + 4 * N * 8 * lanes)
+        assert held <= mamba._VMEM_LIMIT_BYTES // 2, held
+        assert 4 * 2 * 2 * N * Q <= 1 << 18        # SMEM: 256 KiB
+    return takes
+
+
 CHECKS = {
+    "mamba_scan": _check_mamba_scan, "mamba_update": _check_mamba_update,
     "delta_scan": _check_delta_scan, "delta_update": _check_delta_update,
     "fused_attention": _check_fused_attention,
     "kv_cache_write": _check_kv_cache_write,

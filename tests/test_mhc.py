@@ -481,7 +481,10 @@ def test_a_cfg_without_the_new_keys_builds_the_parents_program(shape, build):
     and nothing else (201 -> 153 ops over four layers: the latent prefill
     hands the fused-attention op ``kvb``'s output, the shared key part
     and q rotated where it lies; twelve transposes, slices, expands and
-    concats a layer went)."""
+    concats a layer went). PR 58's
+    residual pins (one ``materialize`` a layer of every prefill: a barrier,
+    no arithmetic) are left out of the list, and counted by
+    ``tests/test_gpt_programs_pinned.py``."""
     with open(os.path.join(HERE, "references",
                            "gpt_op_lists_pr35.json")) as f:
         want = json.load(f)[shape][build]
@@ -491,7 +494,7 @@ def test_a_cfg_without_the_new_keys_builds_the_parents_program(shape, build):
     ops = [[op.type, sorted(op.inputs), sorted(op.outputs),
             sorted((k, repr(v)) for k, v in op.attrs.items()
                    if not k.startswith("_") and k != "op_callstack")]
-           for op in prog.global_block().ops]
+           for op in prog.global_block().ops if op.type != "materialize"]
     assert len(ops) == want["n_ops"]
     assert hashlib.sha256(json.dumps(ops, sort_keys=True).encode()) \
         .hexdigest() == want["sha256"]
