@@ -579,6 +579,9 @@ def _cost_mamba(ctx):
 def _cost_causal_conv(ctx):
     """2 K operations a value and about 5 for the silu (attr ``act``)."""
     xs, ws = ctx.input_shape("X"), ctx.input_shape("W")
+    columns = ctx.attr("columns", None)
+    if xs is not None and columns:      # those columns of a wider X
+        xs = tuple(xs[:-1]) + (int(columns[1]) - int(columns[0]),)
     x = None if xs is None else ctx.elems(xs)
     if x is None or ws is None or len(ws) != 2:
         return ctx.out_elems()

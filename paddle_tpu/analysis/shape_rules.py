@@ -415,8 +415,14 @@ def _r_mamba_update(ctx):
 
 @register_shape_rule("causal_conv", "causal_conv_step")
 def _r_causal_conv(ctx):
-    """Out is X's shape [B, T, C]; RowsOut [B, K - 1, C] under W [C, K]."""
+    """Out is X's shape [B, T, C] (of X's columns [lo, hi) with attr
+    ``columns``); RowsOut [B, K - 1, C] under W [C, K]."""
     xs, ws = ctx.input_shape("X"), ctx.input_shape("W")
+    columns = ctx.attr("columns", None)
+    if xs is not None and columns and len(xs) == 3:
+        if xs[2] >= 0 and not 0 <= columns[0] < columns[1] <= xs[2]:
+            ctx.fail("columns %s lie outside X %s" % (list(columns), xs))
+        xs = tuple(xs[:2]) + (int(columns[1]) - int(columns[0]),)
     if xs is not None:
         ctx.set("Out", xs)
     if xs is None or ws is None or len(xs) != 3 or len(ws) != 2:

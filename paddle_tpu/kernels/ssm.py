@@ -40,6 +40,14 @@ tests compare the kernels with (the scan's composed form is chunked too,
 a ``lax.scan`` over chunks: the token-by-token form is the reference's,
 benchmarks/references/). ``paddle_ssm_plans_total`` counts which form
 and which chunk each lowering took.
+
+* ``conv_prefill`` — the convolution of a whole prompt, for every mixer
+  that has one in front (``ssm``, ``mamba``, ``delta``, the gated
+  convolution): ONE pass in a Pallas kernel, a grid over (batch, channel
+  tile, block of positions) with the rows in front of a block carried in
+  VMEM scratch, where the shape has a block plan; ``K`` shifted
+  ``jax.numpy`` passes elsewhere. The same sums in the same order, which
+  is ``conv_step``'s. ``paddle_conv_plans_total`` counts the form.
 """
 
 from __future__ import annotations
@@ -53,12 +61,14 @@ from .common import ceil_to, checked_pallas_call, pad_axis, use_interpret
 
 __all__ = ["ssm_update", "ssm_scan", "ssm_update_composed",
            "ssm_update_pallas", "ssm_scan_composed", "ssm_scan_pallas",
-           "conv_prefill", "conv_step", "state_shape", "KERNEL_UPDATE",
-           "KERNEL_SCAN"]
+           "conv_prefill", "conv_prefill_composed", "conv_prefill_pallas",
+           "conv_step", "state_shape", "KERNEL_UPDATE", "KERNEL_SCAN",
+           "KERNEL_CONV"]
 
 # the names the device trace and the HLO show the calls under
 KERNEL_UPDATE = "ssm_update"
 KERNEL_SCAN = "ssm_scan"
+KERNEL_CONV = "conv_prefill"
 
 _LANES = 128
 _VMEM_LIMIT_BYTES = 64 << 20
@@ -355,22 +365,149 @@ def ssm_scan(x, dt, a, bm, cm, *, chunk=128):
 
 
 # ---------------------------------------------------------- convolution
-def conv_prefill(x, w, b, *, act=True):
-    """The causal depth-wise convolution of a whole prompt: ``x [B, T,
-    C]``, ``w [C, K]`` (tap ``K - 1`` meets the position itself), ``b
-    [C]`` or None, zeros before the sequence. Returns ``(out [B, T, C],
-    rows [B, K - 1, C])``: ``rows`` are the last ``K - 1`` positions of
-    ``x`` itself (zeros where the prompt is shorter), what the next
-    token's convolution needs of the past."""
+def conv_prefill_composed(x, w, b, *, act=True, columns=None):
+    """``conv_prefill`` in ``jax.numpy``: ``K`` slices of the padded
+    prompt, each shifted by a row, tap by tap."""
     K = w.shape[1]
-    T = x.shape[1]
+    if columns is not None:
+        x = x[..., columns[0]:columns[1]]
     x = x.astype(jnp.float32)
+    T = x.shape[1]
     wide = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     out = sum(wide[:, j:j + T] * w[:, j].astype(jnp.float32)
               for j in range(K))
     if b is not None:
         out = out + b.astype(jnp.float32)
     return (jax.nn.silu(out) if act else out), wide[:, T:]
+
+
+# the kernel's block of positions, its widest channel tile and the rows a
+# piece of the block's walk holds (docs/KERNELS.md "Causal convolution of a
+# prompt" has the sweep)
+_CONV_BLOCK = 512
+_CONV_TILE = 1024
+_CONV_PIECE = 64
+
+
+def _conv_kernel(x_ref, wb_ref, o_ref, seam_ref, *, K, Q, bias, act):
+    """One ``[Q, tile]`` block of a prompt. ``seam_ref [16, tile]``: rows
+    0-7 the eight rows in front of the block (zeros in front of the
+    prompt), rows 8-15 the block's first eight, so that every row's
+    ``K - 1`` predecessors lie at an offset of the scratch or of the
+    block itself."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        seam_ref[0:8] = jnp.zeros((8,) + seam_ref.shape[1:], jnp.float32)
+
+    seam_ref[8:16] = x_ref[0, 0:8]
+
+    def taps(view, n):
+        # ``conv_prefill_composed``'s sums, in its order
+        out = view(0, n) * wb_ref[0:1]
+        for j in range(1, K):
+            out = out + view(j, n) * wb_ref[j:j + 1]
+        if bias:
+            out = out + wb_ref[K:K + 1]
+        return jax.nn.silu(out) if act else out
+
+    o_ref[0, 0:8] = taps(
+        lambda j, n: seam_ref[pl.ds(8 - (K - 1) + j, n)], 8)
+    for r in range(8, Q, _CONV_PIECE):
+        n = min(_CONV_PIECE, Q - r)
+        o_ref[0, r:r + n] = taps(
+            lambda j, n, r=r: x_ref[0, pl.ds(r - (K - 1) + j, n)], n)
+    seam_ref[0:8] = x_ref[0, Q - 8:Q]
+
+
+def _conv_plan(T, C, K, lo=0, in_place=True):
+    """``(block of positions, channel tile)`` of the Pallas form for a
+    prompt of ``T`` positions over ``C`` channels that start at column
+    ``lo`` of ``x``, or None where the composed form runs: a ``C`` or an
+    ``lo`` that is no whole number of lane tiles, more taps than the eight
+    rows of the seam hold, a prompt under one block — and an ``x`` that is
+    not read ``in_place`` (as the columns of a wider tensor that exists
+    anyway): in front of a kernel XLA writes out the slice or the product
+    it fuses into the composed form, and the extra pass costs what the
+    kernel wins."""
+    T, C, K, lo = int(T), int(C), int(K), int(lo)
+    if not in_place or C % _LANES or lo % _LANES or not 2 <= K <= 7 \
+            or T < _CONV_BLOCK:
+        return None
+    tile = max(t for t in range(_LANES, _CONV_TILE + 1, _LANES)
+               if C % t == 0 and lo % t == 0)
+    return _CONV_BLOCK, tile
+
+
+def conv_prefill_pallas(x, w, b, *, act=True, columns=None, plan=None,
+                        interpret=None):
+    """``conv_prefill`` in ONE pass: a grid over (batch, channel tile,
+    block of positions), blocks innermost and sequential; a step reads
+    its ``[Q, tile]`` of ``x`` once — where it lies in a wider ``x``
+    (``columns``) —, takes the ``K`` shifted views in VMEM (the eight
+    rows in front of the block carried in scratch: the pad is never
+    built), and writes the block of ``out`` once, silu included. The taps
+    and the bias arrive as one ``[8, tile]`` tile a channel tile. A ragged
+    last block reads past the prompt into rows that only rows past the
+    prompt depend on, which are not written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T = x.shape[:2]
+    lo, hi = (0, x.shape[2]) if columns is None else columns
+    C, K = hi - lo, w.shape[1]
+    Q, tile = plan or _conv_plan(T, C, K, lo) or (0, _LANES)
+    if not 8 <= Q <= T or Q % 8 or lo % tile or C % tile \
+            or not 2 <= K <= 7 or x.dtype != jnp.float32:
+        raise ValueError("conv_prefill: no block plan for x %s %s, columns "
+                         "%d-%d, %d taps" % (x.shape, x.dtype, lo, hi, K))
+    if interpret is None:
+        interpret = use_interpret()
+    wb = [w.astype(jnp.float32).T]
+    if b is not None:
+        wb.append(b.astype(jnp.float32)[None])
+    wb = pad_axis(jnp.concatenate(wb, axis=0), 0, 8)
+    out = checked_pallas_call(
+        functools.partial(_conv_kernel, K=K, Q=Q, bias=b is not None,
+                          act=act),
+        name=KERNEL_CONV, grid=(B, C // tile, -(-T // Q)),
+        in_specs=[pl.BlockSpec((1, Q, tile),
+                               lambda i, c, t: (i, t, lo // tile + c)),
+                  pl.BlockSpec((8, tile), lambda i, c, t: (0, c))],
+        operands=(x, wb),
+        out_specs=pl.BlockSpec((1, Q, tile), lambda i, c, t: (i, t, c)),
+        out_shape=jax.ShapeDtypeStruct((B, T, C), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((16, tile), jnp.float32)],
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES))
+    return out, x[:, T - (K - 1):, lo:hi]
+
+
+def conv_prefill(x, w, b, *, act=True, columns=None):
+    """The causal depth-wise convolution of a whole prompt: ``x [B, T,
+    C]`` (or the columns ``[lo, hi)`` of a wider one), ``w [C, K]`` (tap
+    ``K - 1`` meets the position itself), ``b [C]`` or None, zeros before
+    the sequence. Returns ``(out [B, T, C], rows [B, K - 1, C])``:
+    ``rows`` are the last ``K - 1`` positions of ``x`` itself (zeros where
+    the prompt is shorter), what the next token's convolution needs of
+    the past. One pass in a Pallas kernel where Pallas compiles and the
+    shape has a block plan (``_conv_plan``), ``K`` shifted passes in
+    ``jax.numpy`` elsewhere: the same sums in the same order."""
+    from ..observe.families import CONV_PLANS
+
+    lo, hi = (0, x.shape[2]) if columns is None else columns
+    plan = _conv_plan(x.shape[1], hi - lo, w.shape[1], lo,
+                      in_place=columns is not None) \
+        if _kernels_on() and x.dtype == jnp.float32 else None
+    if plan is not None:
+        CONV_PLANS.labels(kernel="pallas", chunk=str(plan[0])).inc()
+        return conv_prefill_pallas(x, w, b, act=act, columns=columns,
+                                   plan=plan, interpret=False)
+    CONV_PLANS.labels(kernel="composed", chunk="0").inc()
+    return conv_prefill_composed(x, w, b, act=act, columns=columns)
 
 
 def conv_step(x, rows, w, b, *, act=True):

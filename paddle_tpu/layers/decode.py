@@ -240,7 +240,7 @@ def kv_cache_write(cache, update, pos, name=None):
 
 
 def causal_conv(x, width, prefix, rows, step=False, act=True, bias=True,
-                name=None):
+                columns=None, name=None):
     """Causal depth-wise convolution over the sequence axis of ``x
     [B, T, C]`` (ops ``causal_conv`` / ``causal_conv_step``,
     kernels/ssm.py): ``out[t] = silu(sum_j w[:, j] x[t - width + 1 + j]
@@ -249,12 +249,22 @@ def causal_conv(x, width, prefix, rows, step=False, act=True, bias=True,
     a persistable ``[B, width - 1, C]`` var that keeps the last ``width -
     1`` positions of ``x`` itself: a whole prompt (``step=False``)
     overwrites it, one token (``step=True``, ``T`` = 1) reads the past
-    out of it and shifts it. Parameters ``<prefix>.w_0 [C, width]`` and
+    out of it and shifts it. ``columns=(lo, hi)`` convolves those columns
+    of a wider ``x``: a whole prompt reads them where they lie (the op's
+    attr ``columns``: a slice in front of a kernel is a copy), one token
+    is cut first. Parameters ``<prefix>.w_0 [C, width]`` and
     ``<prefix>.b_0 [C]``."""
     from ..initializer import Constant
+    from .nn import slice as cut
 
+    attrs = {"act": bool(act)}
+    if columns is not None and step:
+        x = cut(x, axes=[2], starts=[columns[0]], ends=[columns[1]])
+    elif columns is not None:
+        attrs["columns"] = [int(c) for c in columns]
     helper = LayerHelper("causal_conv", name=name)
-    C = int(x.shape[-1])
+    lo, hi = attrs.get("columns", (0, int(x.shape[-1])))
+    C = hi - lo
     w = helper.create_parameter(ParamAttr(name=prefix + ".w_0"),
                                 [C, int(width)], dtype="float32")
     inputs = {"X": [x], "W": [w]}
@@ -268,8 +278,8 @@ def causal_conv(x, width, prefix, rows, step=False, act=True, bias=True,
     helper.append_op(type="causal_conv_step" if step else "causal_conv",
                      inputs=inputs,
                      outputs={"Out": [out], "RowsOut": [rows]},
-                     attrs={"act": bool(act)})
-    out.shape = x.shape
+                     attrs=attrs)
+    out.shape = tuple(x.shape[:-1]) + (C,)
     return out
 
 

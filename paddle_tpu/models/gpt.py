@@ -1516,8 +1516,8 @@ def _mamba_mixer(cfg, step, h, nm, i):
         state = helper.create_global_variable(
             name=nm + "_cache_s", shape=state_shape(batch, C, N))
         with stored_dtype(None):      # the taps stay float32, as a vector
-            u = layers.causal_conv(cut(proj, 0, C), K, nm + "_mamba_conv",
-                                   rows, step=step.decode)
+            u = layers.causal_conv(proj, K, nm + "_mamba_conv", rows,
+                                   step=step.decode, columns=(0, C))
         # [delta | B | C] of the CONVOLVED u, each through its own norm
         dbc = _fc(u, R + 2 * N, nm + "_mamba_x.w_0")
         dt = _fc(inner_norm(cut(dbc, 0, R), "dt"), C, nm + "_mamba_dt.w_0")

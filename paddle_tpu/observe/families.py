@@ -1106,6 +1106,21 @@ SSM_PLANS = REGISTRY.counter(
     "state-space layers lowers L scans, its decode step L updates",
     labels=("op", "kernel", "chunk"))
 
+CONV_PLANS = REGISTRY.counter(
+    "paddle_conv_plans_total",
+    "Which form of the causal depth-wise convolution of a whole prompt a "
+    "program holds (kernels/ssm.py conv_prefill, the program's op "
+    "causal_conv in front of an ssm, mamba, delta or gated-convolution "
+    "mixer): one count a call at LOWERING. kernel 'pallas' reads and "
+    "writes the prompt once, in blocks of chunk positions; 'composed' "
+    "(chunk 0) is K shifted jax.numpy passes: every CPU run, "
+    "PADDLE_TPU_KERNELS=0, an x not read in place (no attr columns), a "
+    "prompt under one block, a width that is no whole number of lane "
+    "tiles. A family of its own and not op='conv' of "
+    "paddle_ssm_plans_total, whose samples the state-space cell's facts "
+    "list whole. A prefill lowers one a layer with such a mixer",
+    labels=("kernel", "chunk"))
+
 POWER_PLANS = REGISTRY.counter(
     "paddle_power_plans_total",
     "Which form of a power-retention layer's core a program holds (gpt "

@@ -78,11 +78,15 @@ def _causal_conv(ctx, ins, attrs):
     """Causal depth-wise convolution of a whole prompt ``X [B, T, C]``
     under ``W [C, K]`` and ``Bias [C]``, then silu (attr ``act``):
     ``Out`` and ``RowsOut [B, K - 1, C]``, the last ``K - 1`` positions
-    of ``X`` itself for the next token, written whole."""
+    of ``X`` itself for the next token, written whole. With attr
+    ``columns`` ``[lo, hi]`` the prompt is those columns of a wider ``X``,
+    read where they lie (no slice is made in front of the kernel)."""
     from ..kernels.ssm import conv_prefill
 
+    columns = attrs.get("columns")
     out, rows = conv_prefill(ins["X"][0], ins["W"][0], _bias(ins),
-                             act=bool(attrs.get("act", True)))
+                             act=bool(attrs.get("act", True)),
+                             columns=columns and tuple(map(int, columns)))
     return {"Out": [out], "RowsOut": [rows]}
 
 

@@ -940,32 +940,43 @@ def test_jamba_prefill_compiles_for_v5e(v5e, compiled_kernels):
     ``mamba_scan`` Pallas calls at the kernel's block, the flash forward
     at 20 query heads over one key-value head in the two full layers, a
     head on ONE row, NO ``[T, 5120, 16]`` tensor (``exp(dt A)`` is formed
-    in the kernel's registers: 5.4 GB a layer were it written), the
-    residual stream written after every layer (28 ``materialize`` ops:
+    in the kernel's registers: 5.4 GB a layer were it written), 26
+    ``conv_prefill`` Pallas calls that read ``W_in``'s ``[T, 10240]``
+    product where it lies (no slice of its first 5,120 columns is made in
+    front of them), the residual stream written after every layer (28 ``materialize`` ops:
     without them XLA keeps all 56 sub-block outputs to the end, 10.6 GB
     of temporaries for a live set of 2.7), and temporaries that fit
     beside the 7.5 GB the engine holds."""
     import paddle_tpu as fluid
-    from paddle_tpu.kernels import mamba
+    from paddle_tpu.kernels import mamba, ssm
+    from paddle_tpu.observe.families import CONV_PLANS
 
     gpt, cfg, serving = _jamba()
     P = 16384
+    conv = CONV_PLANS.labels(kernel="pallas", chunk=str(ssm._CONV_BLOCK))
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         gpt.build_prefill_step(cfg, batch=1, prompt_len=P,
                                max_len=serving["max_len"])
     assert [op.type for op in main.global_block().ops].count(
         "materialize") == 28
-    before = _mamba_plans()
+    before, conv_before = _mamba_plans(), conv.value
     lowered, _ = _lower_step(main, {"tokens": (1, P)}, gpt.NEXT_TOKEN_VAR,
                              v5e)
     assert _new_mamba_plans(before) == {
         ("mamba_scan", "pallas", str(mamba.scan_block(P))): 26}
+    assert conv.value == conv_before + 26
     compiled = lowered.compile()
     text = compiled.as_text()
     assert len(set(re.findall(r"%%(%s[.\d]*) = " % mamba.KERNEL_SCAN,
                               text))) == 26
     assert len(set(re.findall(r"%(flash_fwd[.\d]*) = ", text))) == 2
+    calls = [line for line in text.splitlines()
+             if re.search(r"%%%s[.\d]* = " % ssm.KERNEL_CONV, line)]
+    assert len(calls) == 26
+    assert all("operand_layout_constraints={f32[1,%d,10240]" % P in line
+               for line in calls)
+    assert "slice={[0:1], [0:%d], [0:5120]}" % P not in text
     for dims in ("%d,5120,16" % P, "%d,16,5120" % P, "5120,16,%d" % P,
                  "16,5120,%d" % P, "5120,%d,16" % P, "16,%d,5120" % P):
         assert "f32[1,%s]" % dims not in text and "f32[%s]" % dims \

@@ -59,13 +59,16 @@ REACHES = {
                     "mhc_pre", "moe_ffn")},
     "nemotron-3-super-120b-a12b": {
         "decode": ("kv_cache_write", "moe_ffn", "ssm_update"),
-        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn",
-                    "ssm_scan")},
-    # a gated convolution layer has no kernel of its own (causal_conv
-    # without silu or bias is composed, the gates are element-wise)
+        "prefill": ("causal_conv", "fused_attention", "kv_cache_write",
+                    "moe_ffn", "ssm_scan")},
+    # a gated convolution layer's gates are element-wise; its prompt's
+    # convolution (no silu, no bias, three taps) is the one op
+    # ``causal_conv`` every mixer's is, over a product no kernel reads in
+    # place: composed (a token's, ``causal_conv_step``, has no kernel)
     "lfm2-24b-a2b": {
         "decode": ("kv_cache_write", "moe_ffn"),
-        "prefill": ("fused_attention", "kv_cache_write", "moe_ffn")},
+        "prefill": ("causal_conv", "fused_attention", "kv_cache_write",
+                    "moe_ffn")},
     # two latent attentions a published layer (flash at 64 heads of
     # 192 / 128 in the prefill, mla_decode in the step), one routed
     # branch (the grouped matmul at 6144 x 2048)
@@ -81,15 +84,16 @@ REACHES = {
     # head the flash forward is given, over a share of the experts
     "qwen3-next-80b-a3b": {
         "decode": ("delta_update", "kv_cache_write", "moe_ffn"),
-        "prefill": ("delta_scan", "fused_attention", "kv_cache_write",
-                    "moe_ffn")},
+        "prefill": ("causal_conv", "delta_scan", "fused_attention",
+                    "kv_cache_write", "moe_ffn")},
     # 26 mamba layers (the in-place update whose decay is a block, the
     # scan that walks time inside the kernel) beside two attention layers
     # of 20 query heads over ONE key-value head: the widest group the
     # flash forward's grouped multi-pass plan is given
     "ai21-jamba2-3b": {
         "decode": ("kv_cache_write", "mamba_update"),
-        "prefill": ("fused_attention", "kv_cache_write", "mamba_scan")},
+        "prefill": ("causal_conv", "fused_attention", "kv_cache_write",
+                    "mamba_scan")},
 }
 KERNEL_OPS = frozenset(t for kinds in REACHES.values()
                        for types in kinds.values() for t in types)
@@ -136,6 +140,10 @@ RUNS_ON_THE_CHIP = {
     ("ai21-jamba2-3b", "mamba_scan"), ("ai21-jamba2-3b", "mamba_update"),
     ("ai21-jamba2-3b", "fused_attention"),
     ("ai21-jamba2-3b", "kv_cache_write"),
+    # my chip runs, PR 60: paddle_conv_plans_total pallas chunk=512
+    # at every prompt of the mix (2,048-16,384), ``conv_prefill``
+    # in the device trace
+    ("ai21-jamba2-3b", "causal_conv"),
 }
 
 
@@ -528,7 +536,31 @@ def _check_mamba_scan(block, op, batch, must):
     return takes
 
 
+def _check_causal_conv(block, op, batch, must):
+    from paddle_tpu.kernels import ssm
+    from paddle_tpu.kernels.common import mosaic_ok
+
+    (B, T, Cx), dtype = _operand(block, op, "X", batch)
+    (C, K), _ = _operand(block, op, "W", batch)
+    lo, hi = op.attrs.get("columns", (0, Cx))
+    assert hi - lo == C and dtype == np.float32
+    plan = ssm._conv_plan(T, C, K, lo, in_place="columns" in op.attrs)
+    assert plan is not None or not must, (T, C, K, lo)
+    if plan is not None:
+        Q, tile = plan
+        assert T >= Q and Q % 8 == 0 and tile % 128 == 0
+        assert C % tile == 0 and lo % tile == 0
+        assert mosaic_ok((1, Q, tile), (B, T, Cx))
+        assert mosaic_ok((1, Q, tile), (B, T, C))
+        # a block of x and of out, double-buffered; taps and bias twice;
+        # the seam
+        assert 4 * (4 * Q * tile + 2 * 8 * tile + 16 * tile) \
+            <= ssm._VMEM_LIMIT_BYTES // 2
+    return plan
+
+
 CHECKS = {
+    "causal_conv": _check_causal_conv,
     "mamba_scan": _check_mamba_scan, "mamba_update": _check_mamba_update,
     "delta_scan": _check_delta_scan, "delta_update": _check_delta_update,
     "fused_attention": _check_fused_attention,
@@ -575,3 +607,85 @@ def test_the_case_list_covers_every_configuration_of_the_manifest():
     # every cell's traffic contributed its programs
     for w in MANIFEST["workloads"]:
         assert _programs(w["config"])
+
+
+# ------------------------------------- the prompt's convolution (PR 60)
+@pytest.mark.parametrize("T,C,K,lo,in_place,takes", [
+    (16384, 5120, 4, 0, True, True),     # Jamba's longest prompt
+    (2048, 5120, 4, 0, True, True),      # and its shortest
+    (512, 5120, 4, 0, True, True),       # exactly one block
+    (511, 5120, 4, 0, True, False),      # a prompt under one block
+    (2048, 10240, 4, 0, False, False),   # Nemotron's longest, cut out of
+    (2048, 8192, 4, 0, False, False),    # proj; Qwen3-Next's q, k, v
+    (16384, 2048, 3, 0, False, False),   # LFM2: a product, three taps
+    (16384, 2048, 3, 0, True, True),     # (were it read in place)
+    (4096, 5000, 4, 0, True, False),     # no whole number of lane tiles
+    (4096, 5120, 4, 64, True, False),    # columns that start inside one
+    (4096, 5120, 9, 0, True, False),     # more taps than the seam holds
+    (4096, 5120, 1, 0, True, False),     # no past to carry
+    (4096, 384, 4, 128, True, True),     # a tile as narrow as the offset
+])
+def test_the_prompt_convolution_takes_the_shapes_it_says(T, C, K, lo,
+                                                         in_place, takes):
+    from paddle_tpu.kernels import ssm
+
+    plan = ssm._conv_plan(T, C, K, lo, in_place)
+    assert (plan is not None) == takes
+    if plan is not None:
+        Q, tile = plan
+        assert Q == ssm._CONV_BLOCK and T >= Q
+        assert tile % 128 == 0 and tile <= ssm._CONV_TILE
+        assert C % tile == 0 and lo % tile == 0
+        # the widest tile the rule allows: nothing wider divides both
+        assert not [t for t in range(tile + 128, ssm._CONV_TILE + 1, 128)
+                    if C % t == 0 and lo % t == 0]
+
+
+def _conv_counts():
+    from paddle_tpu.observe import REGISTRY
+
+    got = REGISTRY.snapshot()["metrics"].get(
+        "paddle_conv_plans_total", {"samples": []})
+    return {(s["labels"]["kernel"], s["labels"]["chunk"]): s["value"]
+            for s in got["samples"]}
+
+
+@pytest.mark.parametrize("where,T,C,dtype,columns,want", [
+    ("cpu", 1024, 256, "float32", True, ("composed", "0")),
+    ("chip", 1024, 256, "float32", True, ("pallas", "512")),
+    ("chip", 1024, 256, "float32", False, ("composed", "0")),
+    ("chip", 16384, 256, "float32", False, ("composed", "0")),
+    ("chip", 1024, 200, "float32", True, ("composed", "0")),
+    ("chip", 300, 256, "float32", True, ("composed", "0")),
+    ("chip", 1024, 256, "bfloat16", True, ("composed", "0")),
+    ("chip_kernels_off", 1024, 256, "float32", True, ("composed", "0")),
+])
+def test_a_convolutions_lowering_counts_the_form_it_took(
+        where, T, C, dtype, columns, want, monkeypatch):
+    """``paddle_conv_plans_total``: one count a lowering, under
+    the form and the block ``conv_prefill`` chose from the shapes — as the
+    CPU decides and as a TPU process does (traced only: nothing runs)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import ssm
+
+    if where != "cpu":
+        monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "0")
+    if where == "chip_kernels_off":
+        monkeypatch.setenv("PADDLE_TPU_KERNELS", "0")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_KERNELS", raising=False)
+    before = _conv_counts()
+    x = jax.ShapeDtypeStruct((2, T, 2 * C if columns else C),
+                             jnp.dtype(dtype))
+    w = jax.ShapeDtypeStruct((C, 4), jnp.float32)
+    b = jax.ShapeDtypeStruct((C,), jnp.float32)
+    out, rows = jax.eval_shape(
+        lambda x, w, b: ssm.conv_prefill(
+            x, w, b, columns=(C, 2 * C) if columns else None), x, w, b)
+    assert out.shape == (2, T, C) and rows.shape == (2, 3, C)
+    assert out.dtype == rows.dtype == jnp.float32
+    after = _conv_counts()
+    assert {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)} == {want: 1}

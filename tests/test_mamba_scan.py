@@ -8,6 +8,7 @@ table as the head — the blocked and in-place forms the system runs
 against the token-by-token recurrence the reference runs."""
 
 import importlib.util
+import json
 import os
 
 import jax
@@ -459,6 +460,69 @@ def test_analysis_rules_know_the_two_ops():
         ma = MemoryAnalysis(prog, site="serving")
         assert ma.tensors["gpt_1_cache_s"].poly.at(1) == 2 * 8 * 128 * 4
         assert ma.tensors["gpt_1_cache_x"].poly.at(1) == 2 * 3 * 128 * 4
+
+
+# tests/test_gpt_programs_pinned.py::digest of ``ai21-jamba2-3b`` at the
+# parent of PR 60 (commit 756e27c): the two decode steps, which that PR's
+# convolution kernel is not to move
+_PARENT_STEPS = {
+    "serving_decode": {
+        "n_ops": 720, "n_params": 462,
+        "sha256": "be50a8f97c2c8c9e4398415d3ee9486e"
+                  "17317fc5f8499e5075bd40798b077241",
+        "params": "6de14a13dc50fff1951464ea2c9237ba"
+                  "1383206a33ace899db6b9bd57194ab4b",
+        "startup": "755a45c7d35278ed4503c516ca882a12"
+                   "4ac607cb754230fd34ab26ae8add0575"},
+    "decode": {
+        "n_ops": 721, "n_params": 462,
+        "sha256": "41221257a978bef00cd64cdd091d2971"
+                  "fa29e5bfc8163fdaa80af819837dd87c",
+        "params": "6de14a13dc50fff1951464ea2c9237ba"
+                  "1383206a33ace899db6b9bd57194ab4b",
+        "startup": "1ba32a1c647733af40b104da3ca78859"
+                   "41bbedee0d406d26fc7ddabbb85ef278"},
+}
+
+
+@pytest.mark.parametrize("build", ["serving_decode", "decode", "prefill"])
+def test_published_programs_round_the_prompts_convolution(build):
+    """At the published widths: the decode steps are the parent's op for
+    op (a token's convolution is cut out of ``proj`` and stepped as it
+    was); the prefill holds one ``causal_conv`` a mamba layer that reads
+    its 5,120 columns where ``W_in`` wrote them (attr ``columns``, which no
+    other configuration's op carries: tests/test_gpt_programs_pinned.py)
+    and no slice of ``proj`` in front of it."""
+    from test_gpt_programs_pinned import digest
+
+    if build != "prefill":
+        got = digest(("ai21-jamba2-3b", build, None))
+        assert got["pins"] == 0
+        assert {k: got[k] for k in _PARENT_STEPS[build]} \
+            == _PARENT_STEPS[build]
+        return
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "ai21-jamba2-3b.json")) as f:
+        conf = json.load(f)
+    prog = fluid.Program()
+    with fluid.program_guard(prog, fluid.Program()):
+        gpt.build_prefill_step(conf["model"], batch=1, prompt_len=2048,
+                               max_len=conf["serving"]["max_len"])
+    block = prog.global_block()
+    convs = [op for op in block.ops if op.type == "causal_conv"]
+    mamba = [i for i, t in enumerate(conf["model"]["layer_types"])
+             if t == "mamba"]
+    assert [op.name_scope for op in convs] == ["L%d/mixer" % i for i in mamba]
+    assert len(convs) == 26
+    made_by = {n: op for op in block.ops
+               for names in op.outputs.values() for n in names}
+    for op in convs:
+        assert op.attrs == {"act": True, "columns": [0, 5120]}
+        x = op.inputs["X"][0]
+        assert tuple(block.var(x).shape)[1:] == (2048, 10240)
+        assert made_by[x].type != "slice"
+        assert tuple(block.var(op.outputs["Out"][0]).shape)[1:] \
+            == (2048, 5120)
 
 
 def test_reference_copies_are_bit_equal():

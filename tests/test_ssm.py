@@ -183,6 +183,72 @@ def test_convolution_carries_its_rows(T):
     np.testing.assert_array_equal(jnp.concatenate(outs, 1), whole)
 
 
+# what each case of the convolution's kernel test changes of: batch 2, 70
+# positions in blocks of 32 (two whole blocks and a ragged third), 256
+# channels in tiles of 128, four taps, a bias and the silu
+_CONV_KERNEL_CASES = {
+    "one_block": dict(T=32),
+    "several_blocks": dict(T=96),
+    "ragged_last_block": dict(),
+    "seam_from_the_previous_block": dict(seam=True, act=False, bias=False),
+    "three_taps": dict(K=3),
+    "no_silu": dict(act=False),
+    "no_bias": dict(bias=False),
+    "one_tile_of_all_channels": dict(tile=256),
+    "columns_of_a_wider_x": dict(wide=640, lo=128),
+    "columns_without_bias_or_silu": dict(wide=512, lo=256, K=3, act=False,
+                                         bias=False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_KERNEL_CASES))
+def test_convolution_kernel_is_the_composed_form_bit_for_bit(case):
+    """Interpret mode against the composed form under ``jit`` (what a
+    program runs: XLA's CPU backend contracts a fused multiply and add,
+    op-by-op dispatch does not), ``assert_array_equal``: the taps are
+    summed in ``conv_prefill_composed``'s order, which is ``conv_step``'s,
+    so steps from the kernel's rows continue the kernel's own convolution
+    of the whole sequence bit for bit."""
+    kw = dict(T=70, K=4, act=True, bias=True, tile=128, wide=256, lo=0,
+              seam=False)
+    kw.update(_CONV_KERNEL_CASES[case])
+    T, K, Q, C, n = kw["T"], kw["K"], 32, 256, 5
+    lo, act = kw["lo"], kw["act"]
+    rs = np.random.RandomState(sum(map(ord, case)))
+    x = rs.randn(2, T + n, kw["wide"]).astype(np.float32)
+    if kw["seam"]:
+        # one value, in the last row of the first block: the next K - 1
+        # rows can have it from nowhere but the carried seam
+        x[:] = 0.0
+        x[:, Q - 1] = rs.randn(2, kw["wide"]) + 3.0
+    x = jnp.asarray(x)
+    w = jnp.asarray(rs.randn(C, K) * 0.5, jnp.float32)
+    b = jnp.asarray(rs.randn(C) * 0.1, jnp.float32) if kw["bias"] else None
+    columns = None if kw["wide"] == C else (lo, lo + C)
+
+    def kernel(x):
+        return ssm.conv_prefill_pallas(x, w, b, act=act, columns=columns,
+                                       plan=(Q, kw["tile"]), interpret=True)
+
+    composed = jax.jit(lambda x: ssm.conv_prefill_composed(
+        x, w, b, act=act, columns=columns))
+    step = jax.jit(lambda x, rows: ssm.conv_step(x, rows, w, b, act=act))
+    out, rows = kernel(x[:, :T])
+    want, want_rows = composed(x[:, :T])
+    np.testing.assert_array_equal(out, want)
+    np.testing.assert_array_equal(rows, want_rows)
+    np.testing.assert_array_equal(rows, x[:, T - (K - 1):T, lo:lo + C])
+    if kw["seam"]:
+        assert np.abs(np.asarray(out[:, Q:Q + K - 1])).min() > 0
+        assert not np.asarray(out[:, Q + K - 1:]).any()
+    whole, _ = kernel(x)
+    outs = [out]
+    for t in range(T, T + n):
+        o, rows = step(x[:, t:t + 1, lo:lo + C], rows)
+        outs.append(o)
+    np.testing.assert_array_equal(jnp.concatenate(outs, 1), whole)
+
+
 @pytest.mark.parametrize("T", [128, 300])
 def test_scan_kernel_matches_composed(T):
     """Interpret mode, at the smallest shapes the kernel has a plan for
